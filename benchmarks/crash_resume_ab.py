@@ -87,7 +87,9 @@ def start(port: int, jdir: str | None, fsync: str = "always"):
     return subprocess.Popen(
         [sys.executable, "-m", "mlmicroservicetemplate_tpu.serve"],
         env=server_env(port, jdir, fsync),
-        stdout=subprocess.DEVNULL, stderr=subprocess.DEVNULL,
+        # stderr stays attached: a server child that cannot reach the
+        # chip (or dies at boot) must say so in this run's output.
+        stdout=subprocess.DEVNULL,
     )
 
 

@@ -33,7 +33,8 @@ The streams-lost ledger (``streams_lost_total`` /
 ``streams_recovered_total`` deltas per arm) rides along so the table
 shows WHERE the failed arm's tokens went.
 
-HONEST-NEGATIVE NOTE (BASELINE.md round 24): on CPU the 8 virtual
+HONEST-NEGATIVE NOTE (the pre-round BASELINE record (removed in PR 22) round
+24): on CPU the 8 virtual
 host devices share ONE core, so the fleet-spare arm's two TP groups
 add dispatch + collective overhead with zero added FLOP throughput —
 its goodput ceiling is BELOW single-clean by construction.  The CPU

@@ -51,7 +51,8 @@ FAULT_SPEC = os.environ.get(
 # Watchdog deadline for the supervised arm: must sit ABOVE this host's
 # honest dispatch time (real gpt2 on a 1-vCPU CPU backend runs ~2-5 s
 # per batched dispatch; a too-tight deadline crash-loops on false
-# positives — measured, see BASELINE.md round 9) and BELOW the hang.
+# positives — measured, see the pre-round BASELINE record (removed in PR 22)
+# round 9) and BELOW the hang.
 TIMEOUT_S = os.environ.get("FAULT_AB_TIMEOUT_S", "20")
 
 PROMPTS = [

@@ -125,7 +125,8 @@ async def run_shape(shape: str, queue_depth: str, deadline_factor: float,
         # must COVER it or every stream silently routes to the legacy
         # per-stream path, where the deadline queue, priorities and
         # preemption never bind (round 7 ran with SEQ_BUCKETS=32 and
-        # measured exactly that — recorded in BASELINE.md r8).
+        # measured exactly that — recorded in the pre-round BASELINE record
+        # (removed in PR 22) r8).
         "SEQ_BUCKETS": "32,64",
         "MAX_DECODE_LEN": "8",
         # Narrow slot pool + deep wait queue: time spent waiting lands

@@ -1,6 +1,7 @@
 """Perf-regression ledger: structural counters, not wall-clock.
 
-Every BASELINE.md round since r12 carries the same caveat — CPU
+Every the pre-round BASELINE record (removed in PR 22) round since r12
+carries the same caveat — CPU
 wall-clock numbers on the contended 1-vCPU box are weather, not
 signal.  What IS stable there is the *structure* of the work: host
 syncs per generated token, XLA compiles paid during serving, staged
@@ -12,9 +13,9 @@ hitting) and they are immune to box noise by construction.
 Two consumers:
 
 - ``benchmarks/run_all.py`` appends one JSONL row per measured config
-  to ``PERF_LEDGER.jsonl`` (env ``PERF_LEDGER`` overrides the path,
+  to ``benchmarks/counter_ledger.jsonl`` (env ``PERF_LEDGER`` overrides the path,
   ``PERF_LEDGER=0`` disables) — the longitudinal record each
-  BASELINE.md round can diff against the last;
+  the pre-round BASELINE record (removed in PR 22) round can diff against the last;
 - ``scripts/perf_smoke.py`` (the ``PERF_SMOKE`` stage in
   ``scripts/check.sh``) runs a deterministic tiny workload and FAILS
   on regression against the committed ``benchmarks/perf_baseline.json``.
@@ -35,7 +36,7 @@ def default_path() -> str | None:
     if v:
         return v
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    return os.path.join(root, "PERF_LEDGER.jsonl")
+    return os.path.join(root, "benchmarks", "counter_ledger.jsonl")
 
 
 def structural_counters(engine, cdl=None) -> dict:

@@ -1,6 +1,7 @@
 """Replica-DP scaling curve on the 8-way virtual CPU mesh.
 
-Round-1 verdict: BASELINE.md row 4 labeled a 1-device number as the
+Round-1 verdict: the pre-round BASELINE record (removed in PR 22) row 4
+labeled a 1-device number as the
 multi-replica config. This script produces the honest curve: the same
 bert-base engine at replicas {1, 2, 4, 8} on a virtual CPU mesh,
 fixed total batch, engine-level dispatch (no HTTP noise).

@@ -4,8 +4,7 @@ Wall time of K on-device iterations inside ONE executable is
 ``K x device_time + RTT``.  Timing scans of K and 2K iterations and
 differencing makes the per-dispatch round-trip cancel EXACTLY —
 instead of subtracting a separately-sampled RTT that jitters ±10 ms
-through the relay (the round-2 verdict's weak #1 against
-pallas_ab.py's old method).
+(the weakness of pallas_ab.py's old method).
 
 Every scan body carries a scalar data dependency into the next
 iteration (input + carry*0 — numerically a no-op XLA must still
@@ -24,7 +23,7 @@ def device_time_per_call(fn, args, carry_idx: int = -1, iters: int = 8,
     """Median device-seconds per ``fn(*args)`` call.
 
     Returns (per_call_s, noisy): ``noisy`` means the 2K scan measured
-    no slower than the K scan (relay jitter swamped the signal) and the
+    no slower than the K scan (host jitter swamped the signal) and the
     value fell back to wall_K / K — an UPPER bound, flagged so tables
     can say so.
     """
@@ -78,7 +77,7 @@ def chunked_time_per_step(jit_chunk, params, state, iters: int | None = None,
     or content), so ``jit_chunk`` must not donate its state argument.
 
     iters defaults to CHUNK_ITERS (64): per-step times are fractions of
-    a millisecond, so short chunks drown in relay jitter — K must be
+    a millisecond, so short chunks drown in host jitter — K must be
     large enough that K x step_time clears ±10 ms.  Steps past the
     decode budget are harmless (token/cache writes are mode="drop").
     """

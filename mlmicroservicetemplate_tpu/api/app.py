@@ -1414,6 +1414,7 @@ async def handle_status(request: web.Request) -> web.Response:
         "kind": bundle.kind,
         "ready": app[K_READY].is_set(),
         "device": jax.default_backend(),
+        "device_kind": jax.devices()[0].device_kind,
         "n_devices": engine.replicas.n_devices,
         "max_batch": app[K_CFG].max_batch,
         "uptime_s": round(time.time() - app[K_STARTED_AT], 1),

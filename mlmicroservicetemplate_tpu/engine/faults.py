@@ -77,7 +77,7 @@ KINDS = ("transient", "fatal", "hang", "oob", "device_lost")
 
 
 class TransientDeviceError(Exception):
-    """A dispatch failed in a way a retry can fix (flaky link, relay
+    """A dispatch failed in a way a retry can fix (flaky link, transport
     hiccup).  The watchdog retries these with backoff."""
 
 

@@ -404,6 +404,16 @@ class ReplicaFleet:
             import jax
 
             n_dev = len(jax.devices())
+            ids = [int(d.id) for d in jax.devices()]
+            if ids != list(range(n_dev)):
+                # Groups are carved by POSITION in jax.devices() and
+                # placed by indexing it (serving_tp_mesh), while the
+                # base group above is read off as device IDS: the two
+                # only agree when ids are positions.
+                raise ValueError(
+                    f"FLEET_TP_GROUPS needs device ids 0..{n_dev - 1} in "
+                    f"jax.devices() order, got {ids}"
+                )
             for w in widths[1:]:
                 free = [d for d in range(n_dev) if d not in taken]
                 if len(free) < w:
@@ -956,7 +966,8 @@ class ReplicaFleet:
         }
         if breakdown:
             # Scale-up latency attribution (benchmarks/autoscale_ab.py,
-            # BASELINE.md): where the spin-up wall went — engine build
+            # the pre-round BASELINE record (removed in PR 22)): where the
+            # spin-up wall went — engine build
             # + donor broadcast, loop warm, probe dispatch, budget
             # rebalance — plus the XLA compiles the whole event paid
             # (zero once a sibling replica populated the

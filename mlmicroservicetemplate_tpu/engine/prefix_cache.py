@@ -6,7 +6,8 @@ growing history — not one global system prompt.  This cache lets every
 request reuse the KV of the longest previously-computed prefix of its
 own token sequence: TTFT then pays only the suffix prefill, the same
 O(S)-not-O(P+S) economics the global knob measured at 1.52× on
-llama-1.1B (BASELINE.md round 3), but granted at request time to any
+llama-1.1B (the pre-round BASELINE record (removed in PR 22) round 3), but
+granted at request time to any
 recurring prefix.
 
 TPU-first constraints shape the design:

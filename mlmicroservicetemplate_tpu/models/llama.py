@@ -72,7 +72,8 @@ class LlamaConfig:
     # attention matmuls (common.mha_attention_kv8) — halves the KV
     # HBM term of batched long-context decode.  Generation is NOT
     # bit-identical to the bf16 cache (quantization is lossy); the
-    # knob ships measured (BASELINE.md) and default-off.
+    # knob ships measured (the pre-round BASELINE record (removed in PR 22))
+    # and default-off.
     kv_quant: bool = False
     # Pallas decode attention (USE_PALLAS_DECODE=1): the single-token
     # decode step's cache attention runs as one kernel gridded over

@@ -305,12 +305,20 @@ def _pallas_backend_ok(svc_cfg) -> bool:
     the explicit escape hatch (CPU CI, the pallas_ab bench)."""
     if getattr(svc_cfg, "pallas_interpret", False):
         return True
-    try:
-        import jax as _jax
+    import jax
 
-        return _jax.default_backend() == "tpu"
-    except Exception:
-        return False
+    return jax.default_backend() == "tpu"
+
+
+def _pallas_decode_unavailable(why: str):
+    """An explicit USE_PALLAS_DECODE=1 that cannot be honoured fails
+    the boot: serving the jnp path under a warning would hide which
+    path a run exercised."""
+    return RuntimeError(
+        f"USE_PALLAS_DECODE=1 cannot be honoured: {why} (unset the knob "
+        "to follow the backend, or set PALLAS_INTERPRET=1 for the CPU "
+        "interpret path)"
+    )
 
 
 def _tp_placement(svc_cfg, model_cfg, family: str, devices=None):
@@ -620,13 +628,11 @@ def _build_gpt(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     gpt_pallas: dict = dict(_pallas_knobs(svc_cfg))
     env_pd = _os.environ.get("USE_PALLAS_DECODE", "").lower()
     if env_pd in ("1", "true", "yes"):
-        if _pallas_backend_ok(svc_cfg):
-            gpt_pallas["pallas_decode"] = True
-        else:
-            log.warning(
-                "USE_PALLAS_DECODE requested but unavailable (backend!="
-                "tpu and PALLAS_INTERPRET off); using gather_pages+mha"
+        if not _pallas_backend_ok(svc_cfg):
+            raise _pallas_decode_unavailable(
+                "backend is not tpu and PALLAS_INTERPRET is off"
             )
+        gpt_pallas["pallas_decode"] = True
     cfg = gpt_mod.GPTConfig(
         eos_id=int(tokenizer.eos_id), pad_id=int(tokenizer.pad_id),
         **gpt_pallas,
@@ -789,15 +795,17 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     if getattr(svc_cfg, "quant_kv", None) == "int8":
         overrides["kv_quant"] = True
     # Pallas decode attention (ops/attention.decode_attention).
-    # Measured policy (benchmarks/kv_quant_ab.py, v5e, llama-1.1B
+    # Policy from a pre-round record (removed in PR 22, to be
+    # re-measured on the chip; benchmarks/kv_quant_ab.py, llama-1.1B
     # int8 weights, B=8): int8-KV through the fused kernel beats the
     # dense XLA path 1.32-1.58x across contexts 512-1792 — in-kernel
     # dequant is what flips round-4's 0.89-0.90x XLA kv-quant loss —
     # while the DENSE kernel variant loses slightly (0.86-0.96x).  So
     # the default follows the measurement: ON exactly when the int8 KV
     # cache is on.  USE_PALLAS_DECODE=1 forces it for dense too,
-    # =0 disables.  TPU-gated like use_pallas_attention — the kernel
-    # has no CPU lowering, so a CPU run must fall back, not crash.
+    # =0 disables.  TPU-gated like use_pallas_attention: the DEFAULT
+    # follows the backend (the kernel has no CPU lowering), while an
+    # explicit =1 that cannot be honoured fails the boot.
     env_pd = _os.environ.get("USE_PALLAS_DECODE", "").lower()
     want_pd = (
         env_pd in ("1", "true", "yes")
@@ -827,15 +835,19 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         t_est = p_est + max(svc_cfg.seq_buckets) + int(
             _math.ceil(svc_cfg.max_decode_len / chunk) * chunk
         )
-        if _pallas_backend_ok(svc_cfg) and decode_kernel_fits(
-            t_est, probe.num_kv_heads, probe.head_dim
-        ):
+        explicit = env_pd in ("1", "true", "yes")
+        backend_ok = _pallas_backend_ok(svc_cfg)
+        fits = decode_kernel_fits(t_est, probe.num_kv_heads, probe.head_dim)
+        if backend_ok and fits:
             overrides["pallas_decode"] = True
-        elif env_pd in ("1", "true", "yes"):
-            log.warning(
-                "USE_PALLAS_DECODE requested but unavailable "
-                "(backend!=tpu or slab exceeds VMEM at T=%d); using the "
-                "jnp cache-attention path", t_est,
+        elif explicit and not backend_ok:
+            raise _pallas_decode_unavailable(
+                "backend is not tpu and PALLAS_INTERPRET is off"
+            )
+        elif explicit:
+            raise _pallas_decode_unavailable(
+                f"the KV slab at T={t_est} exceeds "
+                "DECODE_KERNEL_VMEM_BUDGET_MB"
             )
     overrides.update(_pallas_knobs(svc_cfg))
     cfg = llama_mod.LlamaConfig(**overrides)

@@ -1,7 +1,8 @@
 """Self-drafting speculative decoding (prompt-lookup / n-gram).
 
 At batch=1 a decoder's step time is pinned to the HBM ceiling: every
-token streams the full weight set once (measured in BASELINE.md —
+token streams the full weight set once (measured in the pre-round BASELINE
+record (removed in PR 22) —
 llama-1.1B at 2.58 ms/step bf16 ≈ 853 GB/s, the v5e wire).  No tuning
 beats that wall except not paying one weight pass PER token: draft
 several candidate tokens cheaply, then verify them all in ONE forward

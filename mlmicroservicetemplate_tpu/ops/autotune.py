@@ -130,18 +130,26 @@ def lookup(kind: str, *, b: int, kvh: int, n_rep: int, d: int,
 def paged_vmem_bytes(var: Variant, *, bs: int, kvh: int, d: int,
                      n_rep: int, payload_bytes: int, quant: bool) -> int:
     """Per-program VMEM for one paged grid step under ``var`` —
-    generalizes ``attention.decode_kernel_fits`` to the tuned axes:
-    K raw K/V blocks (+scales), the dequant/upcast f32 copies
-    (``native_mxu`` skips them), q/out tiles, the online-softmax
-    scratch at its configured width and the score/prob temporaries."""
+    generalizes ``attention.decode_kernel_fits`` to the tuned axes.
+    Tiles are lane-dense ``[K*BS, KVH*D]`` (``_fold_block``), so bytes
+    are what the arrays hold, with no (8, 128) padding blow-up to model:
+    K raw K/V blocks (+scales), DOUBLE-buffered by the pipeline; the
+    dequant/upcast f32 copies (``native_mxu`` skips them); q/out tiles
+    and online-softmax scratch — ``[H, KVH*D]`` wide when
+    ``head_batched`` (block-diagonal q, diagonal read-out), ``[H, D]``
+    otherwise; and the score/prob temporaries.  The model agrees with
+    the v5e's compiler on every enumerated variant at the default Llama
+    decode shapes (tests/test_chip_compile.py)."""
     kb = var.blocks_per_step * bs
-    payload = 2 * kb * kvh * d * payload_bytes
-    scales = 2 * kb * kvh * 4 if quant else 0
+    payload = 2 * 2 * kb * kvh * d * payload_bytes
+    scales = 2 * 2 * kb * kvh * 4 if quant else 0
     f32_copies = 0 if (var.native_mxu and not quant) else 2 * kb * kvh * d * 4
-    q_out = 2 * kvh * n_rep * d * 4
+    h = kvh * n_rep
+    cols = kvh * d if var.head_batched else d
+    q_out = 2 * 2 * h * cols * 4
     acc = 4 if var.acc_dtype == "f32" else 2
-    scratch = (2 * kvh * n_rep + kvh * n_rep * d) * acc
-    scores = 2 * kvh * n_rep * kb * 4  # s and p live together briefly
+    scratch = (2 * h + h * cols) * acc
+    scores = 2 * h * kb * 4  # s and p live together briefly
     return payload + scales + f32_copies + q_out + scratch + scores
 
 
@@ -358,16 +366,28 @@ def _verify(out, ref, dtype: str) -> bool:
     return bool(np.allclose(a, b, rtol=tol, atol=tol))
 
 
-def default_table_path() -> str | None:
-    """PALLAS_TUNE_TABLE, else alongside the persistent XLA disk cache
-    (COMPILE_CACHE_DIR) so both tuning artifacts survive restarts
-    together; None = in-memory only."""
+def default_table_path(device: str | None = None,
+                       cache_dir: str | None = None) -> str | None:
+    """PALLAS_TUNE_TABLE, else ``pallas_tune.json`` inside the resolved
+    compile-cache directory (``runtime/device.resolve_cache_dir``: the
+    JAX_COMPILATION_CACHE_DIR / COMPILE_CACHE_DIR / DEVICE=tpu-default
+    chain), so the tuned-variant choices and the executables they
+    select survive restarts TOGETHER — a table entry whose executable
+    is also disk-cached costs a restart zero compiles
+    (docs/kernel_tuning.md).  No cache dir -> None, in-memory only: the
+    sweep re-runs per process, the right default for tests and CPU
+    golden runs that want cold, hermetic state.  Config-less callers
+    leave the arguments None and get the DEVICE / COMPILE_CACHE_DIR
+    env vars."""
     p = os.environ.get("PALLAS_TUNE_TABLE")
     if p:
         return p
-    from ..runtime.device import tune_table_default
+    from ..runtime.device import resolve_cache_dir
 
-    return tune_table_default(os.environ.get("COMPILE_CACHE_DIR"))
+    if device is None:
+        device = os.environ.get("DEVICE", "tpu").lower()
+    resolved = resolve_cache_dir(device, cache_dir)
+    return os.path.join(resolved, "pallas_tune.json") if resolved else None
 
 
 def _load_table(path: str | None) -> None:
@@ -449,6 +469,7 @@ def _sweep(kind: str, key: str, *, b, kvh, n_rep, d, block_size, t,
                        bs=block_size or t, t=t, dtype=dtype, quant=quant)
     call_args = tuple(a for a in args if a is not None)
     timings: dict[str, float] = {}
+    errors: dict[str, str] = {}
     any_noisy = False
     best_key, best_t = "b1", float("inf")
     for var in cands:
@@ -462,10 +483,11 @@ def _sweep(kind: str, key: str, *, b, kvh, n_rep, d, block_size, t,
                 _event("reject_verify")
                 continue
             per, noisy = _time_per_call(fn, call_args, iters, SWEEP_REPS)
-        except Exception:
+        except Exception as e:
             with _LOCK:
                 _COUNTS["reject_error"] += 1
             _event("reject_error")
+            errors[vkey] = f"{type(e).__name__}: {e}"
             continue
         with _LOCK:
             _COUNTS["timed"] += 1
@@ -473,6 +495,18 @@ def _sweep(kind: str, key: str, *, b, kvh, n_rep, d, block_size, t,
         timings[vkey] = per
         if per < best_t:
             best_key, best_t = vkey, per
+    if not timings:
+        # Nothing survived to measurement: there is no winner to
+        # install or persist.  "b1" by default would put an unverified
+        # (possibly uncompilable) kernel into the tuning table.
+        first = next(iter(errors.items()), None)
+        raise RuntimeError(
+            f"autotune sweep for {key}: none of {len(cands)} candidate "
+            f"variant(s) could be verified and timed "
+            f"({len(errors)} raised, {len(cands) - len(errors)} "
+            f"mismatched the reference)"
+            + (f"; first error [{first[0]}]: {first[1][:500]}" if first else "")
+        )
     with _LOCK:
         _RESULTS[key] = {
             "winner": best_key,

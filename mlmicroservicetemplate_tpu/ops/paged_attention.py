@@ -63,8 +63,11 @@ class Variant:
       registers when a single group's R·D tile is narrow).
     - ``native_mxu``: feed bf16 payloads to the MXU at storage width
       (bf16 x bf16 -> f32 via ``preferred_element_type``) instead of
-      upcasting to f32 copies in VMEM first.  Exact — f32 accumulation
-      either way — and a no-op unless q and the pools are bf16.
+      upcasting to f32 copies in VMEM first.  The QK products are exact
+      and every accumulation is f32; the probabilities are rounded to
+      bf16 for the PV issue (Mosaic wants one operand dtype), exactly as
+      the XLA path does (``common.mha_attention``).  A no-op unless q
+      and the pools are bf16.
     - ``fold_scales``: int8 path — keep payloads UNscaled through the
       QK/PV dots and fold the per-token-head scales into the score
       matrix / probability weights instead of dequantizing whole
@@ -160,13 +163,120 @@ def scatter_pages(
     return flat.reshape(pool.shape)
 
 
+def head_batched_q(q: jax.Array, kvh: int) -> jax.Array:
+    """``[B, H, D]`` -> block-diagonal ``[B, H, KVH*D]``: head h's
+    vector in its KV group's lane slice, zeros elsewhere — the q
+    operand of the ``head_batched`` kernels (see ``_fold_block``)."""
+    b, h, d = q.shape
+    group = jnp.arange(h) // (h // kvh)
+    diag = (group[:, None] == jnp.arange(kvh)[None, :]).astype(q.dtype)
+    return (q[:, :, None, :] * diag[None, :, :, None]).reshape(b, h, kvh * d)
+
+
+def head_batched_out(out: jax.Array, kvh: int) -> jax.Array:
+    """Inverse read-out: ``[B, H, KVH*D]`` -> ``[B, H, D]``, each
+    head's output taken from its group's diagonal ``[R, D]`` block."""
+    b, h, gd = out.shape
+    group = jnp.arange(h) // (h // kvh)
+    return jnp.take_along_axis(
+        out.reshape(b, h, kvh, gd // kvh), group[None, :, None, None], axis=2
+    )[:, :, 0]
+
+
+def softmax_scratch(q_block: tuple, dtype) -> list:
+    """m/l/acc VMEM scratch for a q block ``[*rows, C]`` (its leading
+    batch-1 dim dropped): statistics ``[*rows, 1]``, accumulator
+    ``[*rows, C]``."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = tuple(q_block[:-1])
+    return [
+        pltpu.VMEM(rows + (1,), dtype),
+        pltpu.VMEM(rows + (1,), dtype),
+        pltpu.VMEM(tuple(q_block), dtype),
+    ]
+
+
+def _group_onehot(rows: int, kvh: int, n_rep: int, g: int | None):
+    """[rows, KVH] f32 one-hot of each query row's KV group: row r of
+    the head-batched tile belongs to group ``r // n_rep``; a single
+    group's tile (``g`` given) belongs to ``g`` throughout.  Built from
+    2-D iotas (Mosaic has no 1-D iota)."""
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, kvh), 1)
+    if g is not None:
+        return (col == g).astype(jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, kvh), 0)
+    lo = col * n_rep
+    return ((row >= lo) & (row < lo + n_rep)).astype(jnp.float32)
+
+
+def _scales_t(onehot, s_blk):
+    """Per-key scales laid along lanes: ``[rows, KVH] x [KB, KVH] ->
+    [rows, KB]``, each row carrying its group's column of ``s_blk``.
+    The pool keeps scales key-major ([KB, KVH], keys on sublanes); the
+    score matrix wants them key-minor.  An NT matmul against a one-hot
+    is the transpose Mosaic lowers at any KVH — exact at HIGHEST
+    precision (one non-zero product per output)."""
+    return jax.lax.dot_general(
+        onehot, s_blk, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=jax.lax.Precision.HIGHEST,
+    )
+
+
+def _attend_tile(q, k, v, ks_t, vs_t, valid, m_prev, l_prev, a_prev, *,
+                 scale: float):
+    """One online-softmax fold of a ``[rows, C] x [KB, C]`` tile pair.
+    ``ks_t``/``vs_t`` ([rows, KB], or None) are folded scales; ``valid``
+    is the block's [1, KB] mask.  Everything stays 2-D with keepdims
+    statistics — Mosaic's layout inference rejects 1-D vectors."""
+    f32 = jnp.float32
+    s = jax.lax.dot_general(
+        q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=f32,
+    )  # [rows, KB]
+    if ks_t is not None:
+        s = s * ks_t
+    s = s * scale
+    s = jnp.where(valid != 0, s, f32(-1e30))
+    m_new = jnp.maximum(m_prev, s.max(axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_new = l_prev * corr + p.sum(axis=-1, keepdims=True)
+    if vs_t is not None:
+        p = p * vs_t
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=f32,
+    )  # [rows, C]
+    return m_new, l_new, a_prev * corr + pv
+
+
 def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
-                a_scr, *, scale: float, kvh: int, var: Variant):
-    """Fold one [KB, KVH, D] key/value block into the online-softmax
-    accumulators.  ``k_blk``/``v_blk`` are raw payloads (f32/bf16, or
-    int8 when ``ks_blk``/``vs_blk`` carry the [KB, KVH] f32 scales);
-    ``valid`` is the block's [KB] mask.  Scratch m/l [KVH, R] and
-    acc [KVH, R, D] read/write in ``var.acc_dtype``."""
+                a_scr, *, scale: float, kvh: int, n_rep: int, d: int,
+                var: Variant):
+    """Fold one key/value block into the online-softmax accumulators.
+
+    Tiles are 2-D and lane-dense: ``k_blk``/``v_blk`` are ``[KB, KVH*D]``
+    (the pool's trailing ``[KVH, D]`` merged — a free reshape in HBM),
+    raw payloads (f32/bf16, or int8 when ``ks_blk``/``vs_blk`` carry
+    the ``[KB, KVH]`` f32 scales); ``valid`` is the block's ``[1, KB]``
+    mask.  A ``[KB, KVH, D]`` tile would pad its (KVH, D) minor dims to
+    a full (8, 128) register tile — 4x the VMEM at KVH=4, D=64 — and
+    its per-head slices are sublane-strided.
+
+    - default: static loop over groups; group g's keys are the lane
+      slice ``[g*D, (g+1)*D)``, q/out tiles ``[R, D]`` of the
+      ``[1, KVH, R, D]`` blocks, scratch m/l ``[KVH, R, 1]`` and acc
+      ``[KVH, R, D]``.
+    - ``head_batched``: q arrives block-diagonal ``[H, KVH*D]`` (head
+      h's vector in its group's lane slice, zeros elsewhere), so ONE
+      ``[H, KVH*D] x [KB, KVH*D]`` MXU issue scores every head and one
+      ``[H, KB] x [KB, KVH*D]`` issue forms every head's output in the
+      diagonal ``[R, D]`` blocks of acc ``[H, KVH*D]`` (the wrapper
+      reads the diagonal; off-diagonal blocks are finite garbage).
+      Scratch m/l are ``[H, 1]``.
+    """
     f32 = jnp.float32
     quant = ks_blk is not None
     native = var.native_mxu and not quant and (
@@ -176,85 +286,58 @@ def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
     def up(x):  # payload -> dot operand
         return x if native else x.astype(f32)
 
-    if quant and not var.fold_scales:
-        k_blk = k_blk.astype(f32) * ks_blk[:, :, None]
-        v_blk = v_blk.astype(f32) * vs_blk[:, :, None]
-        quant = False  # dequantized: downstream treats as dense
-    elif quant:
-        k_blk = k_blk.astype(f32)
-        v_blk = v_blk.astype(f32)
+    def cols(x, g):  # group g's lane slice of a [KB, KVH*D] tile
+        return x[:, g * d:(g + 1) * d]
+
+    def dequant(x, s_blk, groups):
+        # [KB, n*D] int8 payload x its [KB, KVH] scales, group by group
+        # (each scale column lane-broadcasts over its D lanes).
+        parts = [
+            cols(x, g).astype(f32) * s_blk[:, g:g + 1] for g in groups
+        ]
+        return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+    def fold(sl, q, k, v, ks_t, vs_t):
+        m, l, a = _attend_tile(
+            q, k, v, ks_t, vs_t, valid,
+            m_scr[sl].astype(f32), l_scr[sl].astype(f32),
+            a_scr[sl].astype(f32), scale=scale,
+        )
+        m_scr[sl] = m.astype(m_scr.dtype)
+        l_scr[sl] = l.astype(l_scr.dtype)
+        a_scr[sl] = a.astype(a_scr.dtype)
+
+    fold_scales = quant and var.fold_scales
+
+    def tiles(g):
+        """(k, v, ks_t, vs_t) dot operands: group ``g``'s lane slice,
+        or every group's (the whole tile) when ``g`` is None."""
+        groups = range(kvh) if g is None else (g,)
+        sel = (lambda x: x) if g is None else (lambda x: cols(x, g))
+        if fold_scales:
+            onehot = _group_onehot(len(groups) * n_rep, kvh, n_rep, g)
+            return (sel(k_blk).astype(f32), sel(v_blk).astype(f32),
+                    _scales_t(onehot, ks_blk), _scales_t(onehot, vs_blk))
+        if quant:
+            return (dequant(k_blk, ks_blk, groups),
+                    dequant(v_blk, vs_blk, groups), None, None)
+        return up(sel(k_blk)), up(sel(v_blk)), None, None
 
     if var.head_batched:
-        q = up(q_ref[0])  # [KVH, R, D]
-        # Batched over KVH: q [KVH, R, D] x k [KB, KVH, D] -> [KVH, R, KB]
-        s = jax.lax.dot_general(
-            q, up(k_blk),
-            dimension_numbers=(((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=f32,
-        )
-        if quant:  # fold_scales: ks [KB, KVH] -> [KVH, 1, KB]
-            s = s * jnp.transpose(ks_blk)[:, None, :]
-        s = s * scale
-        s = jnp.where(valid[None, None, :] != 0, s, f32(-1e30))
-        m_prev = m_scr[...].astype(f32)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[..., None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = (
-            l_scr[...].astype(f32) * corr + p.sum(axis=-1)
-        ).astype(l_scr.dtype)
-        if quant:  # fold_scales: vs [KB, KVH] -> [KVH, 1, KB]
-            p = p * jnp.transpose(vs_blk)[:, None, :]
-        # p [KVH, R, KB] x v [KB, KVH, D] -> [KVH, R, D]
-        pv = jax.lax.dot_general(
-            p, up(v_blk),
-            dimension_numbers=(((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=f32,
-        )
-        a_scr[...] = (
-            a_scr[...].astype(f32) * corr[..., None] + pv
-        ).astype(a_scr.dtype)
-        m_scr[...] = m_new.astype(m_scr.dtype)
+        fold(slice(None), up(q_ref[0]), *tiles(None))
         return
-
     for g in range(kvh):
-        q = up(q_ref[0, g])  # [R, D]
-        k = up(k_blk[:, g])  # [KB, D]
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=f32,
-        )  # [R, KB]
-        if quant:
-            s = s * ks_blk[None, :, g]
-        s = s * scale
-        s = jnp.where(valid[None, :] != 0, s, f32(-1e30))
-        m_prev = m_scr[g].astype(f32)
-        m_new = jnp.maximum(m_prev, s.max(axis=-1))
-        p = jnp.exp(s - m_new[:, None])
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[g] = (l_scr[g].astype(f32) * corr + p.sum(axis=-1)).astype(
-            l_scr.dtype
-        )
-        if quant:
-            p = p * vs_blk[None, :, g]
-        pv = jax.lax.dot_general(
-            p, up(v_blk[:, g]),
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=f32,
-        )
-        a_scr[g] = (a_scr[g].astype(f32) * corr[:, None] + pv).astype(
-            a_scr.dtype
-        )
-        m_scr[g] = m_new.astype(m_scr.dtype)
+        fold(g, up(q_ref[0, g]), *tiles(g))
 
 
-def _paged_kernel_v(*refs, scale: float, kvh: int, bs: int, quant: bool,
-                    var: Variant):
+def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
+                    quant: bool, var: Variant):
     """Grid step (b, j): fold blocks ``table[b, j*K .. j*K+K-1]`` into
     row b's accumulators; finalize on the last step.  Ref layout:
-    tbl (prefetch), q [1, KVH, R, D], then K k-blocks [1, BS, KVH, D]
-    (+K [1, BS, KVH] k-scales when quant), K v-blocks (+K v-scales),
-    valid [1, 1, K*BS], output, then m/l/acc scratch."""
+    tbl (prefetch), q ([1, KVH, R, D], or block-diagonal [1, H, KVH*D]
+    when head-batched), then K k-blocks [1, BS, KVH*D] (+K [1, BS, KVH]
+    k-scales when quant), K v-blocks (+K v-scales), valid
+    [1, 1, 1, K*BS], output (shaped like q), then m/l/acc scratch."""
     from jax.experimental import pallas as pl
 
     K = var.blocks_per_step
@@ -278,33 +361,22 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, bs: int, quant: bool,
         l_scr[...] = jnp.zeros_like(l_scr)
         a_scr[...] = jnp.zeros_like(a_scr)
 
-    if K == 1:
-        k_blk = k_refs[0][0]
-        v_blk = v_refs[0][0]
-        ks_blk = ks_refs[0][0].astype(jnp.float32) if quant else None
-        vs_blk = vs_refs[0][0].astype(jnp.float32) if quant else None
-    else:
-        k_blk = jnp.concatenate([r[0] for r in k_refs], axis=0)
-        v_blk = jnp.concatenate([r[0] for r in v_refs], axis=0)
-        ks_blk = (
-            jnp.concatenate([r[0] for r in ks_refs], axis=0).astype(
-                jnp.float32
-            ) if quant else None
-        )
-        vs_blk = (
-            jnp.concatenate([r[0] for r in vs_refs], axis=0).astype(
-                jnp.float32
-            ) if quant else None
-        )
-    valid = valid_ref[0, 0]  # [K*BS]
+    def cat(blk_refs):
+        blks = [r[0] for r in blk_refs]
+        return blks[0] if K == 1 else jnp.concatenate(blks, axis=0)
+
+    k_blk, v_blk = cat(k_refs), cat(v_refs)
+    ks_blk = cat(ks_refs).astype(jnp.float32) if quant else None
+    vs_blk = cat(vs_refs).astype(jnp.float32) if quant else None
+    valid = valid_ref[0, 0]  # [1, K*BS]
     _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
-                a_scr, scale=scale, kvh=kvh, var=var)
+                a_scr, scale=scale, kvh=kvh, n_rep=n_rep, d=d, var=var)
 
     @pl.when(j == nsteps - 1)
     def _finalize():
         acc = a_scr[...].astype(jnp.float32)
         l = l_scr[...].astype(jnp.float32)
-        o_ref[0] = (acc / jnp.maximum(l, 1e-20)[..., None]).astype(o_ref.dtype)
+        o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
 def tp_shard_attention(
@@ -320,10 +392,7 @@ def tp_shard_attention(
 
     The wrapper is only reachable at TP>1 — TP=1 call sites never
     build a mesh (the no-mesh pin in tests/test_tp_serving.py)."""
-    from jax.experimental.shard_map import shard_map
-    from jax.sharding import PartitionSpec as P
-
-    from ..parallel.tpserve import serving_tp_mesh
+    from jax.sharding import AbstractMesh, PartitionSpec as P
 
     h = q.shape[1]
     kvh = kv_args[0].shape[2]
@@ -339,10 +408,16 @@ def tp_shard_attention(
         + [P(*([None] * a.ndim)) for a in rep_args]
         + [heads4] * len(scale_args)
     )
-    mesh = serving_tp_mesh(tp)
-    return shard_map(
+    # The ABSTRACT mesh: axis names and sizes, no devices.  The traced
+    # program is then the same for every TP group of a fleet — jit
+    # shares one trace of a model fn across its wrappers, so a mesh of
+    # concrete devices baked in by whichever group traced first would
+    # pin every later group to that group's chips — and each group's
+    # executable takes its devices from its own committed operands.
+    mesh = AbstractMesh((1, tp), ("replica", "tp"))
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=tuple(in_specs),
-        out_specs=P(None, "tp", None), check_rep=False,
+        out_specs=P(None, "tp", None), check_vma=False,
     )(*args)
 
 
@@ -374,8 +449,9 @@ def paged_decode_attention(
     ``variant`` selects a tuning point (see :class:`Variant`); K must
     divide the table width T (``ops/autotune.py`` only enumerates
     divisors, so serving never needs a pad-block path).  VMEM per
-    program is K [BS, KVH, D] K+V block pairs + [KVH, R, D] f32
-    accumulators — ``autotune.paged_vmem_bytes`` is the budget model.
+    program is K lane-dense [BS, KVH*D] K+V block pairs (double-
+    buffered) + f32 accumulators — ``autotune.paged_vmem_bytes`` is
+    the budget model.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -410,47 +486,55 @@ def paged_decode_attention(
         scale = 1.0 / math.sqrt(d)
     quant = k_scale is not None
     acc_jnp = jnp.float32 if var.acc_dtype == "f32" else jnp.bfloat16
-    qg = q.reshape(b, kvh, n_rep, d)
+    gd = kvh * d
     tbl = jnp.clip(table, 0, nb_pool - 1).astype(jnp.int32)
-    validb = key_valid.astype(jnp.int32).reshape(b, tsteps, K * bs)
+    # Mosaic wants a block's last two dims (8, 128)-divisible or whole:
+    # the mask rides as [B, T/K, 1, K*BS] with whole (1, K*BS) blocks
+    # (as fused_attention carries its mask), and the pools as
+    # [NB, BS, KVH*D] — trailing dims merged, a bitcast in HBM.
+    validb = key_valid.astype(jnp.int32).reshape(b, tsteps, 1, K * bs)
+    if var.head_batched:
+        qk = head_batched_q(q, kvh)
+        q_spec = pl.BlockSpec((1, h, gd), lambda i, j, tb: (i, 0, 0))
+    else:
+        qk = q.reshape(b, kvh, n_rep, d)
+        q_spec = pl.BlockSpec(
+            (1, kvh, n_rep, d), lambda i, j, tb: (i, 0, 0, 0)
+        )
 
-    q_spec = pl.BlockSpec((1, kvh, n_rep, d), lambda i, j, tb: (i, 0, 0, 0))
-    kv_specs = [
-        pl.BlockSpec(
-            (1, bs, kvh, d),
-            functools.partial(
-                lambda i, j, tb, _m: (tb[i, j * K + _m], 0, 0, 0), _m=m
-            ),
-        )
-        for m in range(K)
-    ]
-    sc_specs = [
-        pl.BlockSpec(
-            (1, bs, kvh),
-            functools.partial(
-                lambda i, j, tb, _m: (tb[i, j * K + _m], 0, 0), _m=m
-            ),
-        )
-        for m in range(K)
-    ]
-    valid_spec = pl.BlockSpec((1, 1, K * bs), lambda i, j, tb: (i, j, 0))
-    scratch = [
-        pltpu.VMEM((kvh, n_rep), acc_jnp),
-        pltpu.VMEM((kvh, n_rep), acc_jnp),
-        pltpu.VMEM((kvh, n_rep, d), acc_jnp),
-    ]
-    kernel = functools.partial(
-        _paged_kernel_v, scale=scale, kvh=kvh, bs=bs, quant=quant, var=var
+    def pool_specs(width):
+        return [
+            pl.BlockSpec(
+                (1, bs, width),
+                functools.partial(
+                    lambda i, j, tb, _m: (tb[i, j * K + _m], 0, 0), _m=m
+                ),
+            )
+            for m in range(K)
+        ]
+
+    kv_specs, sc_specs = pool_specs(gd), pool_specs(kvh)
+    valid_spec = pl.BlockSpec(
+        (1, 1, 1, K * bs), lambda i, j, tb: (i, j, 0, 0)
     )
+    scratch = softmax_scratch(qk.shape[1:], acc_jnp)
+    kernel = functools.partial(
+        _paged_kernel_v, scale=scale, kvh=kvh, n_rep=n_rep, d=d,
+        quant=quant, var=var,
+    )
+    kp = k_pool.reshape(nb_pool, bs, gd)
+    vp = v_pool.reshape(nb_pool, bs, gd)
     if not quant:
         in_specs = [q_spec, *kv_specs, *kv_specs, valid_spec]
-        args = (tbl, qg, *([k_pool] * K), *([v_pool] * K), validb)
+        args = (tbl, qk, *([kp] * K), *([vp] * K), validb)
     else:
         in_specs = [q_spec, *kv_specs, *sc_specs, *kv_specs, *sc_specs,
                     valid_spec]
+        ks = k_scale.reshape(nb_pool, bs, kvh)
+        vs = v_scale.reshape(nb_pool, bs, kvh)
         args = (
-            tbl, qg, *([k_pool] * K), *([k_scale[..., 0]] * K),
-            *([v_pool] * K), *([v_scale[..., 0]] * K), validb,
+            tbl, qk, *([kp] * K), *([ks] * K), *([vp] * K), *([vs] * K),
+            validb,
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -462,9 +546,11 @@ def paged_decode_attention(
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, kvh, n_rep, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct(qk.shape, q.dtype),
         interpret=interpret,
     )(*args)
+    if var.head_batched:
+        return head_batched_out(out, kvh)
     return out.reshape(b, h, d)
 
 

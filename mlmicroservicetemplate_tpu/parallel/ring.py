@@ -159,21 +159,12 @@ def make_ring_attention(mesh, axis: str = "sp"):
         )
         seq_sharded = P(batch_axis, axis, None, None)
         in_specs = (seq_sharded, seq_sharded, seq_sharded, P(batch_axis, axis))
-        if hasattr(jax, "shard_map"):
-            return jax.shard_map(
-                body,
-                mesh=mesh,
-                in_specs=in_specs,
-                out_specs=seq_sharded,
-                check_vma=False,
-            )(q, k, v, key_mask)
-        # jax < 0.5: shard_map lives in experimental and the replication
-        # check is spelled check_rep.
-        from jax.experimental.shard_map import shard_map as _sm
-
-        return _sm(
-            body, mesh=mesh, in_specs=in_specs, out_specs=seq_sharded,
-            check_rep=False,
+        return jax.shard_map(
+            body,
+            mesh=mesh,
+            in_specs=in_specs,
+            out_specs=seq_sharded,
+            check_vma=False,
         )(q, k, v, key_mask)
 
     return fn

@@ -5,19 +5,19 @@ q/k/v + mlp-up, row-parallel attn-out + mlp-down) as PartitionSpec
 pytrees; ``parallel/mesh.py`` owns the placement objects.  This module
 is the small trace-time surface the REST of the serving stack needs:
 
-- ``serving_tp_mesh(tp)`` — the cached ``('replica','tp')`` mesh an
-  ops-level ``shard_map`` wrapper reconstructs at trace time from the
-  STATIC tp width in the model config (model fns are pure; they cannot
-  reach the engine's placement object, but the mesh over the first
-  ``tp`` visible devices is deterministic and identical to the one
-  ``make_replica_tp_mesh(tp, 1)`` built for the engine).  Multi-chip
-  fleets place TP groups on NON-prefix device sets (replica 1 on
-  devices (2,3), …): the fleet's executables run under
-  ``use_trace_group`` (runtime/compile_cache.py wraps every shared
-  executable), and ``serving_tp_mesh`` consults that thread-local so a
-  trace on replica 1's thread reconstructs the mesh over replica 1's
-  OWN devices.  The default (prefix) group normalizes to the original
-  cache key, so single-group serving stays byte-identical.
+- ``serving_tp_mesh(tp, replicas, group)`` — the cached
+  ``('replica','tp')`` mesh placements are built over: the first
+  ``tp`` visible devices by default, or the ``group`` of global device
+  ids a multi-chip fleet carved for one replica (replica 1 on devices
+  (2,3), …).  The default (prefix) group normalizes to the original
+  cache key, so single-group serving stays byte-identical.  The
+  ops-level ``shard_map`` wrapper (``ops/paged_attention.
+  tp_shard_attention``) does NOT reconstruct this mesh: it traces
+  against the abstract ('replica','tp') mesh, because jit shares one
+  trace of a model fn across every replica's wrapper and concrete
+  devices baked into it would pin all groups to the first tracer's
+  chips.  ``use_trace_group`` (the thread-local the executable proxies
+  set) only steers a group-less ``serving_tp_mesh`` call.
 - ``device_group(placement)`` — a placement's global device-id tuple
   (None for single-device and default-prefix placements), the value
   the executable proxies feed ``use_trace_group``.
@@ -217,20 +217,18 @@ def collective_probe(mesh, d_model: int, dtype="float32") -> dict:
     x = jnp.ones((max(1, d_model // tp), max(8, d_model)), dtype)
     xs = jax.device_put(x, NamedSharding(mesh, P("tp", None)))
 
-    from jax.experimental.shard_map import shard_map
-
-    # check_rep=False: the static replication checker cannot infer
+    # check_vma=False: the static replication checker cannot infer
     # out-replication over 'tp' for these one-op bodies on a 2-D mesh;
     # the probe is a timing harness, not a correctness surface.
-    psum = jax.jit(shard_map(
+    psum = jax.jit(jax.shard_map(
         lambda v: jax.lax.psum(v, "tp"), mesh=mesh,
         in_specs=P("tp", None), out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     ))
-    gather = jax.jit(shard_map(
+    gather = jax.jit(jax.shard_map(
         lambda v: jax.lax.all_gather(v, "tp", axis=0, tiled=True),
         mesh=mesh, in_specs=P("tp", None), out_specs=P(None, None),
-        check_rep=False,
+        check_vma=False,
     ))
     out = {}
     for op, fn in (("all_reduce", psum), ("all_gather", gather)):

@@ -16,7 +16,7 @@ no headers and the default config the observable behavior degrades to
 exactly the seed's FIFO + 503 contract.
 
 Dequeue is gated on dispatch capacity (``pipeline_depth`` batches in
-flight): the wait queue is the REAL queue, not a relay into an
+flight): the wait queue is the REAL queue, not a hand-off into an
 invisible unbounded executor backlog — which is what makes deadlines,
 priorities and the KV budget actually bind.
 

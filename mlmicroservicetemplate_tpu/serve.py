@@ -7,8 +7,9 @@ stack from env vars (12-factor) with optional CLI overrides, e.g.::
     python -m mlmicroservicetemplate_tpu.serve --model bert-base --device cpu --port 8080
 
 Import discipline: ``apply_device_env`` runs before any model/engine
-import so DEVICE=cpu can still steer the (possibly pre-imported) jax
-platform; torch never appears on this path (BASELINE.json:5).
+import touches a device, so DEVICE=cpu can still steer the platform
+and DEVICE=tpu is verified before anything is built; torch never
+appears on this path (BASELINE.json:5).
 """
 
 from __future__ import annotations
@@ -22,7 +23,7 @@ def parse_args(argv: list[str] | None = None) -> dict:
     p = argparse.ArgumentParser(description="TPU-native inference microservice")
     p.add_argument(
         "--model", dest="MODEL_NAME",
-        help="resnet50 | bert-base | bert-long | t5-small | gpt2",
+        help="resnet50 | bert-base | bert-long | t5-small | gpt2 | llama",
     )
     p.add_argument("--device", dest="DEVICE", help="tpu | cpu")
     p.add_argument("--host", dest="HOST")

@@ -14,7 +14,7 @@ violation classes are flagged:
   deadlock potential, caught on the *edge*, long before a real
   interleaving wedges the fleet;
 - **lock held across a dispatch boundary**: a lock is held while
-  ``dispatch_guard`` submits device work.  A relay RTT (or a watchdog
+  ``dispatch_guard`` submits device work.  A dispatch round-trip (or a watchdog
   deadline) under a lock stalls every thread that needs it; only
   explicitly allowed locks (the engine's own dispatch-serialization
   lock, registered via ``allow_across_dispatch``) may do this.
@@ -141,7 +141,7 @@ class LockTracer:
                     "site": site,
                     "detail": (
                         f"lock {self._names.get(uid, '?')} held across "
-                        f"dispatch_guard({site!r}) — a relay RTT under "
+                        f"dispatch_guard({site!r}) — a dispatch round-trip under "
                         f"this lock stalls every thread that needs it "
                         f"(allow_across_dispatch() if deliberate)"
                     ),

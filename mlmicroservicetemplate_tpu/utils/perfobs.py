@@ -4,7 +4,8 @@ Round 11 left the repo with a blind spot this module closes: per-site
 HOST time is always measured (``dispatch_host_seconds{site}``), but the
 DEVICE half was only visible under ``TRACE=1`` attribution mode, whose
 ``block_until_ready`` serializes the dispatch pipeline (8–15%
-overhead, BASELINE.md r11) — so no production run and no headline
+overhead, the pre-round BASELINE record (removed in PR 22) r11) — so no
+production run and no headline
 BENCH pass has carried device-side numbers since r05.  The estimator
 here derives device occupancy from timestamps the serving loop
 **already touches**, in the spirit of the benchmark-methodology
@@ -142,7 +143,10 @@ _PEAK_BY_KIND = (
 
 def peak_flops(cfg=None) -> float:
     """Peak FLOP/s for the MFU denominator: the PEAK_TFLOPS knob when
-    set, else a device-kind lookup, else 0.0 (unknown)."""
+    set, else a device-kind lookup.  A CPU backend has no entry and
+    resolves 0.0 (MFU unknown); a TPU whose ``device_kind`` is not in
+    the table raises — a silent 0.0 there would read as "MFU 0" on a
+    chip that is busy."""
     knob = float(getattr(cfg, "peak_tflops", 0.0) or 0.0) if cfg is not None \
         else 0.0
     if not knob:
@@ -152,15 +156,18 @@ def peak_flops(cfg=None) -> float:
             knob = 0.0
     if knob:
         return knob * 1e12
-    try:
-        import jax
+    import jax
 
-        kind = str(jax.devices()[0].device_kind).lower()
-    except Exception:
-        return 0.0
+    dev = jax.devices()[0]
+    kind = str(dev.device_kind).lower()
     for frag, peak in _PEAK_BY_KIND:
         if frag in kind:
             return peak
+    if dev.platform == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s known for TPU device_kind {dev.device_kind!r}; "
+            "add it to utils/perfobs._PEAK_BY_KIND or set PEAK_TFLOPS"
+        )
     return 0.0
 
 

@@ -19,7 +19,8 @@ dependency):
   When ON, dispatch spans additionally ``block_until_ready`` the
   dispatch result to attribute device time — which serializes the
   chunk-chain pipeline.  TRACE=1 is an attribution mode, not a
-  production default; the A/B cost is recorded in BASELINE.md.
+  production default; the A/B cost is recorded in the pre-round BASELINE
+  record (removed in PR 22).
 
 - **Flight recorder** (``FLIGHT_RING``, default on): a bounded ring of
   the engine loop's last N iterations (batch composition, slot

@@ -293,11 +293,14 @@ else
     echo "== multi-chip smoke skipped (MULTICHIP_SMOKE=0) =="
 fi
 
-echo "== tier-1 tests (ROADMAP.md) =="
+echo "== tier-1 tests (as the driver runs them: six xdist workers) =="
+# Every worker imports every test file, so a suite that passes with
+# -p no:xdist has not been shown to pass here: nothing may load libtpu
+# or decide which tests exist at import (tests/test_chip_compile.py).
 rm -f /tmp/_t1.log
-timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
+timeout -k 10 1470 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \
-    -p no:xdist -p no:randomly 2>&1 | tee /tmp/_t1.log
+    -p xdist -n 6 --dist loadfile -p no:randomly 2>&1 | tee /tmp/_t1.log
 rc=${PIPESTATUS[0]}
 echo "DOTS_PASSED=$(grep -aE '^[.FEsx]+( *\[ *[0-9]+%\])?$' /tmp/_t1.log | tr -cd . | wc -c)"
 exit $rc

@@ -26,7 +26,8 @@ committed ``benchmarks/perf_baseline.json``:
 Wall-clock appears nowhere — the gate is CPU-noise-immune by
 construction.  ``PERF_SMOKE_UPDATE=1`` rewrites the baseline (do this
 deliberately, in the PR that changes the structure, with the why in
-the commit).  Every run also appends a row to ``PERF_LEDGER.jsonl``.
+the commit).  Every run also appends a row to ``benchmarks/counter_ledger.jsonl``
+(repo-root ``PERF_LEDGER.jsonl`` is the driver's chip ledger, not ours).
 
 Usage (scripts/check.sh runs it after LINT):
     JAX_PLATFORMS=cpu python scripts/perf_smoke.py

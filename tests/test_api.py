@@ -160,6 +160,7 @@ def test_health_status_metrics():
         assert st["model"] == "bert-base"
         assert st["ready"] is True
         assert st["device"] == "cpu"
+        assert st["device_kind"] == "cpu"  # beside `device`, as JAX reports it
         # issue one request so metrics have content
         await client.post("/predict", json={"text": "hi"})
         resp = await client.get("/metrics")

@@ -1,0 +1,244 @@
+"""The serving kernels compile for the chip — asked of the chip's own
+compiler, without the chip.
+
+libtpu is installed here and compiles for a *described* TPU v5e
+(``topologies.get_topology_desc``), so every case below raises exactly
+what the attached chip's compiler would raise: a block shape Mosaic's
+(8, 128) tiling rule refuses, a kernel that wants more VMEM than it
+may take.  Interpret mode (every other kernel test in this suite) sees
+neither.  Nothing runs and nothing is timed: a compile that passes is
+not a chip run (``chip_smoke.py`` is).
+
+This is the ONLY file that describes the chip, and it does so inside a
+module-scoped fixture, never at import: one process at a time may load
+libtpu, the tier-1 command runs six xdist workers that each import
+every test file, and a module that touched the TPU library (or decided
+which tests exist) while being imported would give the workers
+different collections — xdist then runs nothing at all.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+
+from mlmicroservicetemplate_tpu.models.llama import LlamaConfig
+from mlmicroservicetemplate_tpu.ops import autotune
+from mlmicroservicetemplate_tpu.ops.attention import (
+    decode_attention,
+    fused_attention,
+)
+from mlmicroservicetemplate_tpu.ops.paged_attention import (
+    paged_decode_attention,
+    parse_variant,
+)
+
+# Default Llama decode shapes (models/llama.py LlamaConfig; the
+# continuous loop's defaults: MAX_STREAMS=8, KV_BLOCK_SIZE=16).
+_CFG = LlamaConfig()
+H, KVH, D = _CFG.num_heads, _CFG.num_kv_heads, _CFG.head_dim
+B, BS = 8, 16
+T_BLOCKS, POOL = 64, 1024  # table width / pool size of the paged cases
+T_SLAB = 1024  # cache width of the whole-slab cases
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described device is written to the persistent
+    # cache but cannot be read back without the chip (the next one
+    # warns and recompiles): keep the cache off around this module.
+    # conftest's matmul precision ("highest", for CPU goldens) is not
+    # what serving compiles on the chip either.
+    prev_cache = jax.config.jax_enable_compilation_cache
+    prev_prec = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_enable_compilation_cache", False)
+    jax.config.update("jax_default_matmul_precision", None)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", prev_cache)
+    jax.config.update("jax_default_matmul_precision", prev_prec)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def chip(topo):
+    """``chip(shape, dtype)`` -> a ShapeDtypeStruct on the first
+    described v5e device, plus a per-module memo of compiled texts."""
+    from jax.sharding import SingleDeviceSharding
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    spec.device_kind = topo.devices[0].device_kind
+    spec.memo = {}
+    return spec
+
+
+def _compiled_text(chip, key, fn, *args) -> str:
+    """Compile ``fn`` for the described chip (memoized per ``key``) and
+    return the executable's text."""
+    if key not in chip.memo:
+        chip.memo[key] = jax.jit(fn).lower(*args).compile().as_text()
+    return chip.memo[key]
+
+
+def _paged_text(chip, quant: bool, variant: str, t: int = T_BLOCKS,
+                pool: int = POOL) -> str:
+    variant = parse_variant(variant).key()  # "" and "b1": one memo entry
+    q = chip((B, H, D), jnp.bfloat16)
+    payload = chip((pool, BS, KVH, D), jnp.int8 if quant else jnp.bfloat16)
+    table = chip((B, t), jnp.int32)
+    valid = chip((B, t * BS), jnp.int32)
+    if quant:
+        # Scale pools ride at the compute dtype, as init_paged_state
+        # allocates them.
+        sc = chip((pool, BS, KVH, 1), jnp.bfloat16)
+        return _compiled_text(
+            chip, ("paged", True, variant, t),
+            lambda q, k, v, tb, m, ks, vs: paged_decode_attention(
+                q, k, v, tb, m, BS, ks, vs, variant=variant),
+            q, payload, payload, table, valid, sc, sc,
+        )
+    return _compiled_text(
+        chip, ("paged", False, variant, t),
+        lambda q, k, v, tb, m: paged_decode_attention(
+            q, k, v, tb, m, BS, variant=variant),
+        q, payload, payload, table, valid,
+    )
+
+
+def _slab_text(chip, quant: bool, variant: str, t: int = T_SLAB) -> str:
+    variant = parse_variant(variant).key()
+    q = chip((B, H, D), jnp.bfloat16)
+    payload = chip((B, t, KVH, D), jnp.int8 if quant else jnp.bfloat16)
+    mask = chip((B, t), jnp.int32)
+    if quant:
+        sc = chip((B, t, KVH, 1), jnp.bfloat16)
+        return _compiled_text(
+            chip, ("slab", True, variant, t),
+            lambda q, k, v, m, ks, vs: decode_attention(
+                q, k, v, m, ks, vs, variant=variant),
+            q, payload, payload, mask, sc, sc,
+        )
+    return _compiled_text(
+        chip, ("slab", False, variant, t),
+        lambda q, k, v, m: decode_attention(q, k, v, m, variant=variant),
+        q, payload, payload, mask,
+    )
+
+
+def test_described_chip_is_a_v5e(chip):
+    assert "v5" in chip.device_kind.lower()
+
+
+@pytest.mark.parametrize("quant,variant", [
+    (False, ""), (False, "b4"), (False, "b4-hb"), (False, "b8-hb-nat"),
+    (True, ""), (True, "b2-fs"), (True, "b1-hb"), (True, "b4-hb-fs"),
+])
+def test_paged_decode_kernel_compiles(chip, quant, variant):
+    assert "tpu_custom_call" in _paged_text(chip, quant, variant)
+
+
+@pytest.mark.parametrize("group", [(0, 1), (2, 3)])
+def test_paged_decode_kernel_compiles_under_tp2(topo, group):
+    """The kernel under ``shard_map`` (TP=2: each shard sees H=16,
+    KVH=2) on a fleet's device pairs — the non-prefix pair included:
+    the traced program names no device, each group's executable takes
+    them from its operands."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(
+        np.array([topo.devices[i] for i in group]).reshape(1, 2),
+        ("replica", "tp"),
+    )
+
+    def spec(shape, dtype, *axes):
+        return jax.ShapeDtypeStruct(
+            shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
+
+    pool = spec((POOL, BS, KVH, D), jnp.bfloat16, None, None, "tp", None)
+    compiled = jax.jit(
+        lambda q, k, v, tb, m: paged_decode_attention(
+            q, k, v, tb, m, BS, variant="b2-hb", tp=2)
+    ).lower(
+        spec((B, H, D), jnp.bfloat16, None, "tp", None), pool, pool,
+        spec((B, T_BLOCKS), jnp.int32), spec((B, T_BLOCKS * BS), jnp.int32),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("quant,variant", [
+    (False, ""), (False, "b1-hb"), (True, ""), (True, "b1-hb"),
+])
+def test_slab_decode_kernel_compiles(chip, quant, variant):
+    assert "tpu_custom_call" in _slab_text(chip, quant, variant)
+
+
+@pytest.mark.parametrize("b,s,h,bias", [
+    (32, 128, 12, False),  # BERT-base
+    (32, 512, 12, False),
+    (8, 512, 8, True),  # T5-small encoder + relative-position bias
+])
+def test_fused_attention_compiles(chip, b, s, h, bias):
+    q = chip((b, s, h, 64), jnp.bfloat16)
+    mask = chip((b, s), jnp.int32)
+    if bias:
+        text = _compiled_text(
+            chip, ("fused", b, s, h, True),
+            lambda q, k, v, m, bi: fused_attention(q, k, v, m, bi),
+            q, q, q, mask, chip((1, h, s, s), jnp.float32),
+        )
+    else:
+        text = _compiled_text(
+            chip, ("fused", b, s, h, False),
+            lambda q, k, v, m: fused_attention(q, k, v, m),
+            q, q, q, mask,
+        )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kind,quant", [
+    ("paged_decode", False), ("paged_decode", True),
+    ("decode", False), ("decode", True),
+])
+def test_every_enumerated_variant_compiles(chip, kind, quant):
+    """What the autotuner may sweep (and so install) at the default
+    Llama decode shapes, the chip's compiler accepts: no variant is
+    refused for tiling or VMEM behind the cost model's back.  The paged
+    table width is the default deployment's (512-token bucket + 64
+    decode tokens at BS=16)."""
+    paged = kind == "paged_decode"
+    t = 36 if paged else T_SLAB
+    cands = autotune.enumerate_variants(
+        kind, t=t, bs=BS if paged else t, kvh=KVH, d=D, n_rep=H // KVH,
+        dtype="bfloat16", quant=quant,
+    )
+    want = 12 if paged else 4  # folds {1,2,4} x hb x (nat | fs); hb x (nat | fs)
+    assert len(cands) == want, [v.key() for v in cands]
+    refused = {}
+    for var in cands:
+        try:
+            text = (
+                _paged_text(chip, quant, var.key(), t=t, pool=8 * t)
+                if paged else _slab_text(chip, quant, var.key(), t=t)
+            )
+            assert "tpu_custom_call" in text
+        except Exception as e:  # collect them all: one run, whole picture
+            refused[var.key()] = str(e).splitlines()[0][:200]
+    assert not refused, refused

@@ -525,10 +525,10 @@ def test_depth_from_pins():
     d = ContinuousDecodeLoop.depth_from
     assert d(0.0, 0.005) == 1  # direct-attached: no pipelining
     assert d(0.010, 0.005) == 2
-    assert d(0.100, 0.012) == 8  # relay regime
+    assert d(0.100, 0.012) == 8  # long round-trip regime
     assert d(1.0, 0.001) == 8  # clamp
     assert d(0.0, 0.0) == 1  # zero-compute guard (no div-by-zero)
-    assert d(0.001, 0.0) == 8  # zero compute floors at 1e-4 -> relay-like
+    assert d(0.001, 0.0) == 8  # zero compute floors at 1e-4 -> long-round-trip-like
 
 
 def test_status_surfaces_chain_depth_and_window_stats():

@@ -455,22 +455,3 @@ def test_exec_cache_waiver_and_scope():
         rel="mlmicroservicetemplate_tpu/ops/y.py",
     )
     assert fs == []
-
-
-# ---------------------------------------------------------------------------
-# 5. bench relay-weather probe (r05 regression)
-
-
-def test_weather_zero_probe_rejected():
-    import bench
-
-    out = bench.sanity_check_weather({"relay_rtt_ms": 0.0}, {})
-    assert out == {"relay_probe_rejected": True}
-    # sub-ms against a slow measured wire: also rejected
-    out = bench.sanity_check_weather(
-        {"relay_rtt_ms": 0.4}, {"rtt_ms": 114.8}
-    )
-    assert out == {"relay_probe_rejected": True}
-    # a plausible probe passes through untouched
-    w = {"relay_rtt_ms": 1.8}
-    assert bench.sanity_check_weather(w, {"rtt_ms": 114.8}) is w

@@ -355,7 +355,7 @@ def test_dispatch_failure_errors_streams_and_recovers():
     def flaky(p, state, n, sample):
         if boom["armed"] and not boom["fired"]:
             boom["fired"] = True
-            raise RuntimeError("injected relay failure")
+            raise RuntimeError("injected link failure")
         return real_chunk(p, state, n, sample)
 
     eng._gen_chunk = flaky
@@ -363,7 +363,7 @@ def test_dispatch_failure_errors_streams_and_recovers():
 
     async def body():
         boom["armed"] = True
-        with pytest.raises(RuntimeError, match="injected relay failure"):
+        with pytest.raises(RuntimeError, match="injected link failure"):
             await _consume(cdl, dict(feats))
         assert boom["fired"]
         boom["armed"] = False

@@ -9,7 +9,7 @@ Two checks:
 2. **Guarded-site classification** (``engine/`` + ``scheduler/``): an
    ``except`` handler whose ``try`` body runs a
    ``dispatch_guard``/watchdog call must route the exception through
-   the fault taxonomy — reference ``faults.is_transient`` /
+   the fault classes — reference ``faults.is_transient`` /
    ``is_fatal_device`` / ``classify``, delegate to a classify-routing
    helper (``_fail_streams`` / ``_recover``), or re-``raise``.  A
    handler that reacts identically to a poison request and a dead
