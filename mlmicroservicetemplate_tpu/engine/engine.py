@@ -77,10 +77,8 @@ class InferenceEngine:
             int(getattr(cfg, "flight_ring", 256))
         )
         # Per-site host-dispatch accounting (always on — two clock
-        # reads per dispatch): {site: [count, host_seconds,
-        # device_seconds]} where the device half only accumulates under
-        # TRACE=1 (it costs a block_until_ready).  bench.py records
-        # this split, so "the dispatch path dominates" is a measurement.
+        # reads per dispatch): {site: [count, host_seconds]}.  bench.py
+        # records it; device time per site is the profiler trace's.
         self.dispatch_stats: dict[str, list] = {}
         self._dispatch_stats_lock = threading.Lock()
         # Perf observatory (r20; utils/perfobs.py): always-on device
@@ -186,8 +184,10 @@ class InferenceEngine:
             # and performs zero XLA compiles at warm.
             self._gen_chunk = self._shared_jit(
                 "gen_chunk",
-                lambda: jax.jit(bundle.generate_chunk_fn,
-                                static_argnums=(2, 3)),
+                lambda: jax.jit(
+                    tracing.scoped("decode_chunk", bundle.generate_chunk_fn),
+                    static_argnums=(2, 3),
+                ),
             )
 
             # encode + cache init + first decode chunk fused into ONE
@@ -201,6 +201,7 @@ class InferenceEngine:
                 state = bundle.init_state_fn(p, enc, mask, max_len, sample=sp)
                 return bundle.generate_chunk_fn(p, state, n_steps, sample)
 
+            start = tracing.scoped("prefill_wave", start)
             self._start = self._shared_jit(
                 "start", lambda: jax.jit(start, static_argnums=(4, 5, 6))
             )
@@ -819,68 +820,46 @@ class InferenceEngine:
         callable is functional — jitted calls and fetches with no
         donation — so a retry is token-identical by construction.
 
-        Attribution: host submit→return time always feeds
+        Attribution: host submit→return time feeds
         ``dispatch_host_seconds{site}`` and the per-site stats bench.py
-        records.  Under TRACE=1 the result is additionally
-        ``block_until_ready``'d to measure the device half — the
-        host-vs-device split per site — at the documented cost of
-        serializing the dispatch pipeline (attribution mode)."""
+        records, and the call is one ``dispatch:<site>`` phase
+        (utils/tracing.py) — on the profiler's host plane whenever a
+        session runs, in the TRACE=1 ring with ``host_ms``.  It never
+        waits for the device: device time per site is read from the
+        profiler's device trace, which shares the annotation's clock."""
         if locktrace.is_active():
             # LOCKTRACE=1: flag locks held across this dispatch (a
             # dispatch round-trip under a lock stalls every thread needing it).
             locktrace.note_dispatch(site)
-        tr = tracing.tracer()
-        if tr is None:
+        with tracing.phase(f"dispatch:{site}", cat="dispatch") as ph:
             t0 = time.perf_counter()
             out = self.watchdog.run(site, fn)
             t1 = time.perf_counter()
-            self._note_dispatch(site, t1 - t0, None)
+            self._note_dispatch(site, t1 - t0)
             # Perf observatory submit stamp: the SAME two clock reads
             # the host attribution above already paid — no extra
             # reads, no syncs (utils/perfobs.py).
             self.perf.on_guard(site, t0, t1)
-            return out
-        with tr.span(f"dispatch:{site}", cat="dispatch") as sp:
-            t0 = time.perf_counter()
-            out = self.watchdog.run(site, fn)
-            host_s = time.perf_counter() - t0
-            self.perf.on_guard(site, t0, t0 + host_s)
-            device_s = None
-            try:
-                import jax
-
-                jax.block_until_ready(out)
-                device_s = time.perf_counter() - t0 - host_s
-            except Exception:
-                pass  # non-array results (already-fetched numpy): host-only
-            sp.set(host_ms=round(host_s * 1e3, 3))
-            if device_s is not None:
-                sp.set(device_ms=round(device_s * 1e3, 3))
-            self._note_dispatch(site, host_s, device_s)
+            ph.set(host_ms=round((t1 - t0) * 1e3, 3))
         return out
 
-    def _note_dispatch(self, site: str, host_s: float,
-                       device_s: float | None) -> None:
+    def _note_dispatch(self, site: str, host_s: float) -> None:
         metrics.DISPATCH_HOST.labels(self.bundle.name, site).observe(host_s)
         with self._dispatch_stats_lock:
-            st = self.dispatch_stats.setdefault(site, [0, 0.0, 0.0])
+            st = self.dispatch_stats.setdefault(site, [0, 0.0])
             st[0] += 1
             st[1] += host_s
-            if device_s is not None:
-                st[2] += device_s
 
     def dispatch_attribution(self) -> dict:
         """Per-site dispatch accounting for the BENCH payload:
-        ``{site: {count, host_s, host_ms_avg, device_s}}`` — device_s
-        stays 0.0 unless a TRACE=1 window measured it."""
+        ``{site: {count, host_s, host_ms_avg}}``."""
         out = {}
         with self._dispatch_stats_lock:
-            for site, (n, host, dev) in sorted(self.dispatch_stats.items()):
+            for site, (n, host) in sorted(self.dispatch_stats.items()):
                 out[site] = {
                     "count": n,
                     "host_s": round(host, 4),
                     "host_ms_avg": round(host / n * 1e3, 3) if n else 0.0,
-                    "device_s": round(dev, 4),
                 }
         return out
 

@@ -98,7 +98,7 @@ class _Stream:
         "budget", "klass", "deadline", "started", "kv", "kv_held",
         "skip", "tokens", "preempted", "t_in", "_removed",
         "blocks", "s_base", "s_lo", "shared_ids", "swap",
-        "rid", "t_queued", "t_emit", "done_journaled",
+        "rid", "t_queued", "t_reserved", "t_emit", "done_journaled",
         "tenant", "adapter_slot",
     )
 
@@ -147,9 +147,12 @@ class _Stream:
         # Observability: the request id (span/log correlation key —
         # the API stamps it on the feats dict), when this stream was
         # last (re-)queued (queue-wait span start) and when its last
-        # chunk was delivered (stream_tbt_seconds cadence).
+        # chunk was delivered (stream_tbt_seconds cadence); when it
+        # last left the queue (stream_admit_seconds runs from there to
+        # its first emit).
         self.rid = str(feats.get("request_id") or "")
         self.t_queued = self.t_in
+        self.t_reserved = self.t_in
         self.t_emit = 0.0
         # Write-ahead terminal marker: the journal's ``done`` record
         # must land BEFORE the consumer can observe the stream's end
@@ -632,11 +635,7 @@ class ContinuousDecodeLoop:
         st = _Stream(
             feats, asyncio.get_running_loop(), self.engine.budget_for(feats)
         )
-        tr = tracing.tracer()
-        sp = tracing.NOOP if tr is None else tr.span(
-            "admission", cat="sched", rid=st.rid
-        )
-        with sp:
+        with tracing.phase("admission", cat="sched", rid=st.rid) as sp:
             if adm is not None:
                 klass, deadline = adm.classify(feats)
                 try:
@@ -815,14 +814,17 @@ class ContinuousDecodeLoop:
         return self.admission is None or self.admission.fits(st)
 
     def _reserve(self, st: _Stream) -> None:
+        # The stream just left the wait queue: its queue-wait interval
+        # is [t_queued, now] (re-stamped on every checkpoint requeue,
+        # so resumes get their own observation and span).
+        st.t_reserved = time.monotonic()
+        wait = max(0.0, st.t_reserved - st.t_queued)
+        metrics.STREAM_QUEUE_WAIT.labels(self.engine.bundle.name).observe(wait)
         tr = tracing.tracer()
         if tr is not None:
-            # The stream just left the wait queue: its queue-wait
-            # interval is [t_queued, now] (re-stamped on every
-            # checkpoint requeue, so resumes get their own span).
             tr.add(
                 "queue_wait", cat="sched", rid=st.rid, t0=st.t_queued,
-                klass=st.klass, resumed=bool(st.started),
+                dur=wait, klass=st.klass, resumed=bool(st.started),
             )
         if self.admission is not None:
             self.admission.reserve(st)
@@ -960,7 +962,8 @@ class ContinuousDecodeLoop:
                     and not self._swapping
                     and self.queue.qsize() == 0
                 ):
-                    st = self.queue.pop(timeout=0.05, fits=self._fits)
+                    with tracing.phase("loop/queue_pop"):
+                        st = self.queue.pop(timeout=0.05, fits=self._fits)
                     if st is None:
                         continue
                     self._reserve(st)
@@ -1007,27 +1010,31 @@ class ContinuousDecodeLoop:
                 # stragglers that window.
                 if wave and not self.active and not self._inflight_chunks:
                     deadline = time.monotonic() + self._admit_grace_s
-                    while len(wave) < self.n_slots:
-                        remaining = deadline - time.monotonic()
-                        if remaining <= 0:
-                            break
-                        st = self.queue.pop(
-                            timeout=remaining, fits=self._fits
-                        )
-                        if st is None:
-                            break
-                        self._reserve(st)
-                        wave.append(st)
+                    with tracing.phase("loop/queue_pop"):
+                        while len(wave) < self.n_slots:
+                            remaining = deadline - time.monotonic()
+                            if remaining <= 0:
+                                break
+                            st = self.queue.pop(
+                                timeout=remaining, fits=self._fits
+                            )
+                            if st is None:
+                                break
+                            self._reserve(st)
+                            wave.append(st)
                 self._class_gauges()
                 if wave and not self.overlap_admission:
                     # Round-3 blocking order, kept for A/B
                     # (ADMIT_OVERLAP=0): prefill + fetch + insert all
                     # before the next chunk dispatch.
+                    t_wave = time.monotonic() if self.active else None
                     self._pending_wave = wave
-                    self._pending_admissions = self._admit_dispatch(wave)
+                    with tracing.phase("loop/wave_dispatch"):
+                        self._pending_admissions = self._admit_dispatch(wave)
                     self._pending_wave = []
                     self._admit_complete(self._pending_admissions)
                     self._pending_admissions = []
+                    self._note_wave_stall(t_wave)
                     wave = []
                 # Depth-D pipeline: keep up to chain_depth chunks in
                 # flight — chunk k's ~RTT-long fetch overlaps later
@@ -1040,8 +1047,12 @@ class ContinuousDecodeLoop:
                 dispatched = False
                 self._pending_wave = wave
                 if self.active and self._work_remains():
-                    self._dispatch_chunk()
+                    with tracing.phase("loop/chunk_dispatch"):
+                        self._dispatch_chunk()
                     dispatched = True
+                t_wave = (
+                    time.monotonic() if wave and self.active else None
+                )
                 if wave:
                     # Overlapped admission, AFTER the live chunk's
                     # dispatch: the wave's batched prefill queues
@@ -1052,11 +1063,13 @@ class ContinuousDecodeLoop:
                     # size.  The prefill FETCH then also rides behind
                     # the chunk dispatch (async host copies started at
                     # dispatch).
-                    self._pending_admissions = self._admit_dispatch(wave)
+                    with tracing.phase("loop/wave_dispatch"):
+                        self._pending_admissions = self._admit_dispatch(wave)
                 self._pending_wave = []
                 if self._pending_admissions:
                     self._admit_complete(self._pending_admissions)
                     self._pending_admissions = []
+                self._note_wave_stall(t_wave)
                 # Chunked prefill rides BEHIND the decode dispatch and
                 # the wave admission: live streams' next chunk is
                 # already queued on the device, so a window here delays
@@ -1069,7 +1082,8 @@ class ContinuousDecodeLoop:
                 # table upload now — host prep rides the device's
                 # compute window instead of the gap between dispatches.
                 if dispatched:
-                    self._stage_host_prep()
+                    with tracing.phase("loop/stage_prep"):
+                        self._stage_host_prep()
                 if len(self._inflight_chunks) > self.chain_depth:
                     self._deliver_oldest()
                 elif self._inflight_chunks and not dispatched:
@@ -1846,6 +1860,11 @@ class ContinuousDecodeLoop:
                 if self.tenants is not None:
                     self.tenants.note_latency(st.tenant, "tbt", st.klass, gap)
             else:
+                # Reservation to first emit: the wave, its fetch, and
+                # this stream's place in the emit/insert order.
+                metrics.STREAM_ADMIT.labels(self.engine.bundle.name).observe(
+                    max(0.0, now - st.t_reserved)
+                )
                 ttft = now - st.t_in
                 self.ttft_ewma_s = (
                     ttft if not self.ttft_ewma_s
@@ -1888,6 +1907,25 @@ class ContinuousDecodeLoop:
         return self.adapters.overlay(self.engine.params, rows)
 
     # -- admission -----------------------------------------------------
+
+    def _note_wave_fill(self, real_tokens: int, rows: int, width: int) -> None:
+        """One prefill executable just ran ``rows x width`` token
+        positions for ``real_tokens`` prompt tokens (prefill_wave_fill:
+        useful over attempted work)."""
+        metrics.PREFILL_WAVE_FILL.labels(self.engine.bundle.name).observe(
+            real_tokens / max(1, rows * width)
+        )
+
+    def _note_wave_stall(self, t_wave: float | None) -> None:
+        """A monolithic wave (dispatch, fetch, emit + inserts) just
+        held the loop thread since ``t_wave`` while streams were live:
+        no decode chunk could be dispatched for that long (None = no
+        wave, or nobody was live to stall)."""
+        if t_wave is None:
+            return
+        dt = time.monotonic() - t_wave
+        self.prefill_stall_s += dt
+        metrics.PREFILL_STALL.labels(self.engine.bundle.name).inc(dt)
 
     def _admit_dispatch(self, wave: list[_Stream]) -> list:
         """Phase 1 of admission: queue the wave's prefill work on the
@@ -1971,15 +2009,19 @@ class ContinuousDecodeLoop:
                     except Exception as e:
                         self._fail_streams([st], e)
                         continue
-                    if self.paged:
-                        from .engine import bucket_for
+                    from .engine import bucket_for
 
+                    # The request's own bucket (a contiguous prefix-
+                    # cache hit ran only its suffix: counted as a miss).
+                    L = max(int(st.feats["length"]), 1)
+                    s_own = bucket_for(
+                        L, eng.seq_buckets, eng.replicas.seq_multiple()
+                    )
+                    if self.paged:
                         st.s_lo = 0
-                        st.s_base = bucket_for(
-                            max(int(st.feats["length"]), 1),
-                            eng.seq_buckets, eng.replicas.seq_multiple(),
-                        )
+                        st.s_base = s_own
                     self.prefill_dispatches += 1
+                    self._note_wave_fill(L, 1, s_own)
                     prefetch_to_host(toks, state1.done)
                     started.append((st, state1, toks, sampled, 0, None, None))
                 return started
@@ -2012,6 +2054,10 @@ class ContinuousDecodeLoop:
                 self._fail_streams(ok, e)
                 return started
             self.prefill_dispatches += 1
+            self._note_wave_fill(
+                sum(int(st.feats["length"]) for st in ok),
+                int(ids.shape[0]), int(ids.shape[1]),
+            )
             prefetch_to_host(toks, state1.done)
             for row, st in enumerate(ok):
                 # Slot sampling is PER ROW, not the wave-level flag the
@@ -2088,11 +2134,15 @@ class ContinuousDecodeLoop:
                 {"input_ids": np.zeros(0, np.int32), "length": np.int32(0)}
             ] * (pad_to - len(feats_list))
 
-        def record(state1, toks, streams, ids=None, mask=None):
+        def record(state1, toks, streams, ids, mask, real_tokens: int):
             # ``ids``/``mask`` are the COLLATED (suffix, for hits)
             # prompt arrays the spec insert feeds to init_spec_fn; the
-            # plain insert ignores them.
+            # plain insert ignores them.  ``real_tokens``: the prompt
+            # (suffix) tokens this executable run prefilled.
             self.prefill_dispatches += 1
+            self._note_wave_fill(
+                real_tokens, int(ids.shape[0]), int(ids.shape[1])
+            )
             prefetch_to_host(toks, state1.done)
             for row, st in enumerate(streams):
                 row_sampled = float(st.feats.get("temperature", 0.0)) > 0.0
@@ -2141,7 +2191,8 @@ class ContinuousDecodeLoop:
                         st.s_lo = 0
                         st.s_base = int(ids.shape[1])
                     donate(state1, row, row_ids, L, None)
-                record(state1, toks, [st for st, _, _ in misses], ids, mask)
+                record(state1, toks, [st for st, _, _ in misses], ids, mask,
+                       sum(L for _, _, L in misses))
 
         # Hit groups: one batched prefixed start per (prefix, suffix)
         # bucket pair; multi-member groups pad to the slot count so
@@ -2184,7 +2235,8 @@ class ContinuousDecodeLoop:
                 # Growing conversations keep donating from the hit path
                 # (start_fused's rule, applied per row).
                 donate(state1, row, row_ids, L, pl)
-            record(state1, toks, [st for st, *_ in members], ids, mask)
+            record(state1, toks, [st for st, *_ in members], ids, mask,
+                   sum(L - pl for _, _, L, pl, _ in members))
         return started
 
     def _admit_complete(self, started: list) -> None:
@@ -2200,7 +2252,7 @@ class ContinuousDecodeLoop:
         uniq: dict[int, Any] = {}
         for _, state1, toks, _, _, _, _ in started:
             uniq.setdefault(id(toks), (toks, state1.done))
-        with eng._lock:
+        with tracing.phase("loop/wave_fetch"), eng._lock:
             try:
                 fetched = dict(zip(
                     uniq.keys(),
@@ -2216,6 +2268,13 @@ class ContinuousDecodeLoop:
         # completed on the device (the combined fetch synchronized
         # with all of them).
         self._perf_complete("prefill", len(uniq))
+        with tracing.phase("loop/insert"):
+            self._emit_and_insert(started, fetched)
+
+    def _emit_and_insert(self, started: list, fetched: dict) -> None:
+        """The wave's tail: each admitted stream's first chunk goes
+        out, then its prefill row scatters into a free slot."""
+        eng = self.engine
         for st, state1, toks, sampled, row, ids, mask in started:
             toks_np, done_np = fetched[id(toks)]
             st.produced = eng.chunk_tokens
@@ -2240,15 +2299,19 @@ class ContinuousDecodeLoop:
                 else:
                     with eng._lock:
                         if self.spec:
-                            self._state = self._insert_fn()(
-                                self._state, state1, ids, mask,
-                                self._hist_row(st.feats, toks_np[row]),
-                                np.int32(slot), np.int32(row),
+                            hist_row = self._hist_row(st.feats, toks_np[row])
+                            self._state = eng.dispatch_guard(
+                                "insert", lambda: self._insert_fn()(
+                                    self._state, state1, ids, mask, hist_row,
+                                    np.int32(slot), np.int32(row),
+                                )
                             )
                         else:
-                            self._state = self._insert_fn()(
-                                self._state, state1, np.int32(slot),
-                                np.int32(row)
+                            self._state = eng.dispatch_guard(
+                                "insert", lambda: self._insert_fn()(
+                                    self._state, state1, np.int32(slot),
+                                    np.int32(row),
+                                )
                             )
             except OutOfBlocks:
                 # The fits() gate raced another reservation and the
@@ -2266,6 +2329,10 @@ class ContinuousDecodeLoop:
                     self.admission.release(st)
                 self._requeue_preempted(st)
                 continue
+            # The stream is not active yet: an insert failure ends this
+            # consumer only; a dead device resurfaces at the next
+            # guarded chunk dispatch, which the supervisor owns.
+            # graftlint: except(pre-active insert failure errors only this stream; the supervisor owns the next chunk dispatch)
             except Exception as e:
                 if slot is not None:
                     self.free.append(slot)
@@ -2536,12 +2603,10 @@ class ContinuousDecodeLoop:
         c = self.prefill_chunk
         start = job.consumed
         end = min(start + c, job.L)
-        tr = tracing.tracer()
-        sp = tracing.NOOP if tr is None else tr.span(
+        with tracing.phase(
             "prefill_window", cat="engine", rid=job.st.rid,
             start=start, end=end, total=job.L, paged=self.paged,
-        )
-        with sp:
+        ):
             ids_w = np.zeros((1, c), np.int32)
             mask_w = np.zeros((1, c), np.int32)
             ids_w[0, : end - start] = job.ids[start:end]
@@ -3090,7 +3155,9 @@ class ContinuousDecodeLoop:
                     return type(batched)(base=base, history=hist)
 
                 self._insert = self._shared_jit(
-                    "insert_spec", lambda: jax.jit(insert_spec)
+                    "insert_spec", lambda: jax.jit(
+                        tracing.scoped("slot_insert", insert_spec)
+                    )
                 )
             else:
                 def insert(batched, single, slot, row):
@@ -3104,7 +3171,9 @@ class ContinuousDecodeLoop:
                 # toks/done fetch later); donation would invalidate
                 # them mid-flight.
                 self._insert = self._shared_jit(
-                    "insert", lambda: jax.jit(insert)
+                    "insert", lambda: jax.jit(
+                        tracing.scoped("slot_insert", insert)
+                    )
                 )
         return self._insert
 
@@ -3116,8 +3185,12 @@ class ContinuousDecodeLoop:
 
             self._paged_chunk = self._shared_jit(
                 "paged_chunk",
-                lambda: jax.jit(self.engine.bundle.paged_chunk_fn,
-                                static_argnums=(3, 4)),
+                lambda: jax.jit(
+                    tracing.scoped(
+                        "decode_chunk", self.engine.bundle.paged_chunk_fn
+                    ),
+                    static_argnums=(3, 4),
+                ),
                 # The traced program embeds the tuned kernel variant
                 # (resolved at trace time via ops/autotune.lookup) —
                 # replicas tuned differently must not share a wrapper.
@@ -3125,19 +3198,20 @@ class ContinuousDecodeLoop:
             )
         return self._paged_chunk
 
-    def paged_chunk_hlo(self) -> str:
+    def paged_chunk_hlo(self, debug_info: bool = False) -> str:
         """Lowered text of the paged decode chunk at this loop's
         serving shapes — the program the chunk dispatches run.  What
         ``chip_smoke.py`` reads to show which attention path is in the
         step: the Pallas kernel lowers to a ``tpu_custom_call``, the
-        ``gather_pages`` path to none."""
+        ``gather_pages`` path to none.  ``debug_info`` adds each
+        operation's location, which carries its ``named_scope`` path."""
         import jax.numpy as jnp
 
         with self.engine._lock:
             return self._paged_chunk_fn().lower(
                 self._mp(n=self.n_slots), self._state,
                 jnp.asarray(self._table), self.engine.chunk_tokens, False,
-            ).as_text()
+            ).as_text(debug_info=debug_info)
 
     def _paged_insert_fn(self):
         """Paged slot insert: scatter rows [s_lo, s_cut) of one
@@ -3209,7 +3283,10 @@ class ContinuousDecodeLoop:
 
             self._paged_insert = self._shared_jit(
                 "paged_insert",
-                lambda: jax.jit(insert, static_argnums=(5, 6)),
+                lambda: jax.jit(
+                    tracing.scoped("slot_insert", insert),
+                    static_argnums=(5, 6),
+                ),
                 statics=(bs,),
             )
         return self._paged_insert
@@ -3271,9 +3348,11 @@ class ContinuousDecodeLoop:
             table_row = np.full(self.nb_max, self.pool.num_blocks, np.int32)
             table_row[: len(sb.ids)] = sb.ids
             with eng._lock:
-                new_state = self._paged_insert_fn()(
-                    self._state, state1, jnp.asarray(table_row),
-                    np.int32(slot), np.int32(row), st.s_lo, s_cut,
+                new_state = eng.dispatch_guard(
+                    "insert", lambda: self._paged_insert_fn()(
+                        self._state, state1, jnp.asarray(table_row),
+                        np.int32(slot), np.int32(row), st.s_lo, s_cut,
+                    )
                 )
         except BaseException:
             sb.release()
@@ -4236,7 +4315,7 @@ class ContinuousDecodeLoop:
         table_np = self._table.copy()
         # Growth + table assembly host seconds land on the prep site
         # too (the guarded upload below notes its own share).
-        eng._note_dispatch("prep", time.perf_counter() - t0, None)
+        eng._note_dispatch("prep", time.perf_counter() - t0)
         import jax.numpy as jnp
 
         with eng._lock:
@@ -4307,12 +4386,16 @@ class ContinuousDecodeLoop:
         w = self._pick_window()
         self.last_window = w
         tr = tracing.tracer()
-        sp = tracing.NOOP if tr is None else tr.span(
+        if tr is None:
+            self._dispatch_chunk_inner(eng, w)
+            return
+        # Ring only (TRACE=1): which requests rode this chunk.  The
+        # profiler's trace names the interval ``loop/chunk_dispatch``.
+        with tr.span(
             "decode_chunk", cat="engine", n_streams=len(self.active),
             streams=[st.rid for st in self.active.values()],
             paged=self.paged, window=w,
-        )
-        with sp:
+        ):
             self._dispatch_chunk_inner(eng, w)
 
     def _note_dispatched(self, entry) -> None:
@@ -4432,15 +4515,16 @@ class ContinuousDecodeLoop:
         if not self._inflight_chunks:
             return
         fetchables, snapshot, w = self._inflight_chunks.pop(0)
-        fetched = self.engine.dispatch_guard(
-            "fetch", lambda: jax.device_get(fetchables)
-        )
-        # Perf-observatory completion seam (utils/perfobs.py): the
-        # fetch just synchronized with the oldest in-flight chunk
-        # dispatch finishing on the device — a timestamp the loop was
-        # already paying for, now also a device-occupancy sample.
-        self._perf_complete("chunk")
-        self._route_entry(fetched, snapshot, w)
+        with tracing.phase("loop/deliver"):
+            fetched = self.engine.dispatch_guard(
+                "fetch", lambda: jax.device_get(fetchables)
+            )
+            # Perf-observatory completion seam (utils/perfobs.py): the
+            # fetch just synchronized with the oldest in-flight chunk
+            # dispatch finishing on the device — a timestamp the loop
+            # was already paying for, now also a device-occupancy sample.
+            self._perf_complete("chunk")
+            self._route_entry(fetched, snapshot, w)
 
     def _deliver_all(self) -> None:
         """Drain every in-flight dispatch with ONE combined device_get."""
@@ -4450,13 +4534,14 @@ class ContinuousDecodeLoop:
             return
         entries = self._inflight_chunks
         self._inflight_chunks = []
-        fetched = self.engine.dispatch_guard(
-            "fetch",
-            lambda: jax.device_get([f for f, _, _ in entries]),
-        )
-        self._perf_complete("chunk", len(entries))
-        for (_, snapshot, w), got in zip(entries, fetched):
-            self._route_entry(got, snapshot, w)
+        with tracing.phase("loop/deliver"):
+            fetched = self.engine.dispatch_guard(
+                "fetch",
+                lambda: jax.device_get([f for f, _, _ in entries]),
+            )
+            self._perf_complete("chunk", len(entries))
+            for (_, snapshot, w), got in zip(entries, fetched):
+                self._route_entry(got, snapshot, w)
 
     def _perf_complete(self, site: str, n: int = 1) -> None:
         """Feed one fetch-seam completion sample to the engine's

@@ -184,6 +184,40 @@ def _split(x: jax.Array, n_heads: int) -> jax.Array:
     return x.reshape(b, s, n_heads, d // n_heads)
 
 
+def _mlp_block(cfg: "LlamaConfig", layer, x):
+    """Pre-norm SwiGLU block with its residual, under the ``mlp`` scope
+    (the device trace's name for it in every step kind)."""
+    with jax.named_scope("mlp"):
+        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
+        m = layer["mlp"]
+        return x + dense(
+            m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h)
+        )
+
+
+def _select_next(params: Params, cfg: "LlamaConfig", state, x_last,
+                 sample: bool):
+    """Final-normed hidden rows → (next token, sample params, done,
+    tokens): the ``lm_head`` and ``sample`` scopes of a decode step."""
+    rows = jnp.arange(state.last_token.shape[0])
+    with jax.named_scope("lm_head"):
+        logits = lm_head_logits(
+            x_last, params["lm_head"]["kernel"], transposed=False
+        )
+    with jax.named_scope("sample"):
+        if sample:
+            from .sampling import select_token
+
+            next_tok, sp = select_token(logits, state.sample)
+        else:
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            sp = state.sample
+        next_tok = jnp.where(state.done, jnp.int32(cfg.pad_id), next_tok)
+        done = state.done | (next_tok == cfg.eos_id)
+        tokens = state.tokens.at[rows, state.pos].set(next_tok, mode="drop")
+    return next_tok, sp, done, tokens
+
+
 def _aproj(a, ad, name: str, li: int, x):
     """One attention projection (+ per-row LoRA delta when serving a
     ``__adapters__`` overlay; models/lora.py)."""
@@ -251,7 +285,8 @@ def forward_hidden(
     cost is O(S), not O(P+S)."""
     b, s = input_ids.shape
     p_len = 0 if prefix_kv is None else _prefix_entry_len(prefix_kv[0][0])
-    x = embed(params["embed"], input_ids, dtype)
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], input_ids, dtype)
     pos = jnp.arange(p_len, p_len + s, dtype=jnp.int32)
     cos, sin = _rope_tables(cfg, pos, dtype)  # [S, D_h]
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
@@ -265,29 +300,30 @@ def forward_hidden(
     ad = lora.adapter_tables(params)
     kv = []
     for li, layer in enumerate(params["layers"]):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         a = layer["attn"]
-        q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-        k = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-        v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        with jax.named_scope("qkv_rope"):
+            h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+            q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
+            k = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
+            v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
         if collect_kv:
             kv.append((k, v))
-        if p_len:
-            pk = _dequant_prefix(prefix_kv[li][0], k.dtype)
-            pv = _dequant_prefix(prefix_kv[li][1], v.dtype)
-            k = jnp.concatenate(
-                [jnp.broadcast_to(pk, (b,) + pk.shape[1:]), k], axis=1
+        with jax.named_scope("attn"):
+            if p_len:
+                pk = _dequant_prefix(prefix_kv[li][0], k.dtype)
+                pv = _dequant_prefix(prefix_kv[li][1], v.dtype)
+                k = jnp.concatenate(
+                    [jnp.broadcast_to(pk, (b,) + pk.shape[1:]), k], axis=1
+                )
+                v = jnp.concatenate(
+                    [jnp.broadcast_to(pv, (b,) + pv.shape[1:]), v], axis=1
+                )
+            ctx = mha_attention(
+                q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep), mask=mask
             )
-            v = jnp.concatenate(
-                [jnp.broadcast_to(pv, (b,) + pv.shape[1:]), v], axis=1
-            )
-        ctx = mha_attention(
-            q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep), mask=mask
-        )
-        x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
-        m = layer["mlp"]
-        x = x + dense(m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h))
+        with jax.named_scope("attn_out"):
+            x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
+        x = _mlp_block(cfg, layer, x)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     return (x, kv) if collect_kv else x
 
@@ -335,45 +371,46 @@ def init_decode_state(
         collect_kv=True, prefix_kv=prefix_kv,
     )
     cache_k, cache_v = [], []
-    for li, (k, v) in enumerate(kv):
-        if cfg.kv_quant:
-            # Scales stored in the COMPUTE dtype: the decode step
-            # recovers its working dtype from the state (the int8
-            # payload can't carry it), and mha_attention_kv8 upcasts
-            # scales into the f32 logits anyway.  Prefix rows (global
-            # PROMPT_PREFIX or a per-request cache hit) land as int8 +
-            # scale too — already-quantized entries copy bit-exact,
-            # dense ones quantize with the cache's own scheme — so the
-            # whole slab stays uniform for the fused decode kernel.
-            shape = (b, total, cfg.num_kv_heads, cfg.head_dim)
-            k8, ks = kv_quantize(k)
-            v8, vs = kv_quantize(v)
-            ck8 = jnp.zeros(shape, jnp.int8)
-            cks = jnp.ones(shape[:3] + (1,), dtype)
-            cv8 = jnp.zeros(shape, jnp.int8)
-            cvs = jnp.ones(shape[:3] + (1,), dtype)
+    with jax.named_scope("kv_write"):
+        for li, (k, v) in enumerate(kv):
+            if cfg.kv_quant:
+                # Scales stored in the COMPUTE dtype: the decode step
+                # recovers its working dtype from the state (the int8
+                # payload can't carry it), and mha_attention_kv8 upcasts
+                # scales into the f32 logits anyway.  Prefix rows (global
+                # PROMPT_PREFIX or a per-request cache hit) land as int8 +
+                # scale too — already-quantized entries copy bit-exact,
+                # dense ones quantize with the cache's own scheme — so the
+                # whole slab stays uniform for the fused decode kernel.
+                shape = (b, total, cfg.num_kv_heads, cfg.head_dim)
+                k8, ks = kv_quantize(k)
+                v8, vs = kv_quantize(v)
+                ck8 = jnp.zeros(shape, jnp.int8)
+                cks = jnp.ones(shape[:3] + (1,), dtype)
+                cv8 = jnp.zeros(shape, jnp.int8)
+                cvs = jnp.ones(shape[:3] + (1,), dtype)
+                if p_len:
+                    pk8, pks = _quant_prefix_entry(prefix_kv[li][0], dtype)
+                    pv8, pvs = _quant_prefix_entry(prefix_kv[li][1], dtype)
+                    ck8 = ck8.at[:, :p_len].set(pk8)
+                    cks = cks.at[:, :p_len].set(pks)
+                    cv8 = cv8.at[:, :p_len].set(pv8)
+                    cvs = cvs.at[:, :p_len].set(pvs)
+                ck8 = ck8.at[:, p_len : p_len + s].set(k8)
+                cks = cks.at[:, p_len : p_len + s].set(ks.astype(dtype))
+                cv8 = cv8.at[:, p_len : p_len + s].set(v8)
+                cvs = cvs.at[:, p_len : p_len + s].set(vs.astype(dtype))
+                cache_k.append((ck8, cks))
+                cache_v.append((cv8, cvs))
+                continue
+            ck = jnp.zeros((b, total, cfg.num_kv_heads, cfg.head_dim), k.dtype)
+            cv = ck
             if p_len:
-                pk8, pks = _quant_prefix_entry(prefix_kv[li][0], dtype)
-                pv8, pvs = _quant_prefix_entry(prefix_kv[li][1], dtype)
-                ck8 = ck8.at[:, :p_len].set(pk8)
-                cks = cks.at[:, :p_len].set(pks)
-                cv8 = cv8.at[:, :p_len].set(pv8)
-                cvs = cvs.at[:, :p_len].set(pvs)
-            ck8 = ck8.at[:, p_len : p_len + s].set(k8)
-            cks = cks.at[:, p_len : p_len + s].set(ks.astype(dtype))
-            cv8 = cv8.at[:, p_len : p_len + s].set(v8)
-            cvs = cvs.at[:, p_len : p_len + s].set(vs.astype(dtype))
-            cache_k.append((ck8, cks))
-            cache_v.append((cv8, cvs))
-            continue
-        ck = jnp.zeros((b, total, cfg.num_kv_heads, cfg.head_dim), k.dtype)
-        cv = ck
-        if p_len:
-            pk, pv = prefix_kv[li]
-            ck = ck.at[:, :p_len].set(pk.astype(ck.dtype))
-            cv = cv.at[:, :p_len].set(pv.astype(cv.dtype))
-        cache_k.append(ck.at[:, p_len : p_len + s].set(k))
-        cache_v.append(cv.at[:, p_len : p_len + s].set(v))
+                pk, pv = prefix_kv[li]
+                ck = ck.at[:, :p_len].set(pk.astype(ck.dtype))
+                cv = cv.at[:, :p_len].set(pv.astype(cv.dtype))
+            cache_k.append(ck.at[:, p_len : p_len + s].set(k))
+            cache_v.append(cv.at[:, p_len : p_len + s].set(v))
     lengths = attention_mask.sum(axis=-1).astype(jnp.int32)
     key_valid = jnp.zeros((b, total), jnp.int32)
     if p_len:
@@ -457,7 +494,8 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
     b = state.last_token.shape[0]
     rows = jnp.arange(b)
     t = state.write_idx  # [B] per-row position
-    x = embed(params["embed"], state.last_token[:, None], dtype)  # [B,1,D]
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], state.last_token[:, None], dtype)  # [B,1,D]
     # Per-row rotary tables at each row's own position (clamped for
     # long-dead continuous-batching rows whose writes drop anyway).
     cos, sin = _rope_tables(cfg, jnp.minimum(t, cfg.max_position - 1), dtype)
@@ -468,32 +506,24 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         a = layer["attn"]
-        q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-        k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-        v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
-        ck = _write_kv(state.cache_k[li], rows, t, k1[:, 0], dtype)
-        cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
+        with jax.named_scope("qkv_rope"):
+            h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+            q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
+            k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
+            v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        with jax.named_scope("kv_write"):
+            ck = _write_kv(state.cache_k[li], rows, t, k1[:, 0], dtype)
+            cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
         new_k.append(ck)
         new_v.append(cv)
-        ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
-        x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
-        m = layer["mlp"]
-        x = x + dense(m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h))
+        with jax.named_scope("attn"):
+            ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
+        with jax.named_scope("attn_out"):
+            x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
+        x = _mlp_block(cfg, layer, x)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
-    logits = lm_head_logits(x[:, 0], params["lm_head"]["kernel"], transposed=False)
-
-    if sample:
-        from .sampling import select_token
-
-        next_tok, sp = select_token(logits, state.sample)
-    else:
-        next_tok, sp = jnp.argmax(logits, axis=-1).astype(jnp.int32), state.sample
-    next_tok = jnp.where(state.done, jnp.int32(cfg.pad_id), next_tok)
-    done = state.done | (next_tok == cfg.eos_id)
-    tokens = state.tokens.at[rows, state.pos].set(next_tok, mode="drop")
+    next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
         GPTState(
             cache_k=new_k,
@@ -548,9 +578,7 @@ def multi_step(
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
-        m = layer["mlp"]
-        x = x + dense(m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h))
+        x = _mlp_block(cfg, layer, x)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     logits = lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
     return new_k, new_v, logits  # [B, D, V]
@@ -683,7 +711,8 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
     b = state.last_token.shape[0]
     rows = jnp.arange(b)
     t = state.write_idx
-    x = embed(params["embed"], state.last_token[:, None], dtype)
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], state.last_token[:, None], dtype)
     cos, sin = _rope_tables(cfg, jnp.minimum(t, cfg.max_position - 1), dtype)
     cos, sin = cos[:, None, None, :], sin[:, None, None, :]
     key_valid = state.key_valid.at[rows, t].set(1, mode="drop")
@@ -691,32 +720,24 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         a = layer["attn"]
-        q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-        k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-        v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
-        ck = _paged_write_kv(state.cache_k[li], table, t, k1[:, 0], bs, dtype)
-        cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
+        with jax.named_scope("qkv_rope"):
+            h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+            q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
+            k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
+            v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        with jax.named_scope("kv_write"):
+            ck = _paged_write_kv(state.cache_k[li], table, t, k1[:, 0], bs, dtype)
+            cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
         new_k.append(ck)
         new_v.append(cv)
-        ctx = _paged_cache_attention(cfg, q, ck, cv, table, key_valid, bs)
-        x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
-        m = layer["mlp"]
-        x = x + dense(m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h))
+        with jax.named_scope("attn"):
+            ctx = _paged_cache_attention(cfg, q, ck, cv, table, key_valid, bs)
+        with jax.named_scope("attn_out"):
+            x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
+        x = _mlp_block(cfg, layer, x)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
-    logits = lm_head_logits(x[:, 0], params["lm_head"]["kernel"], transposed=False)
-
-    if sample:
-        from .sampling import select_token
-
-        next_tok, sp = select_token(logits, state.sample)
-    else:
-        next_tok, sp = jnp.argmax(logits, axis=-1).astype(jnp.int32), state.sample
-    next_tok = jnp.where(state.done, jnp.int32(cfg.pad_id), next_tok)
-    done = state.done | (next_tok == cfg.eos_id)
-    tokens = state.tokens.at[rows, state.pos].set(next_tok, mode="drop")
+    next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
         PagedState(
             cache_k=new_k, cache_v=new_v, key_valid=key_valid,
@@ -839,9 +860,7 @@ def prefill_chunk(
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
-        m = layer["mlp"]
-        x = x + dense(m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h))
+        x = _mlp_block(cfg, layer, x)
     key_valid = state.key_valid.at[rows, pos_w].set(
         chunk_mask.astype(jnp.int32), mode="drop"
     )
@@ -919,9 +938,7 @@ def paged_prefill_chunk(
                 mask=mask,
             )
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
-        m = layer["mlp"]
-        x = x + dense(m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h))
+        x = _mlp_block(cfg, layer, x)
     return state._replace(cache_k=new_k, cache_v=new_v)
 
 

@@ -84,6 +84,12 @@ def enable_compilation_cache(device: str,
     # restart then pays no compile at all, small executables included.
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    # Debug metadata (operation names, so the ``jax.named_scope`` paths
+    # a device trace groups operations by) is part of the key: left out
+    # — JAX's default — an executable cached by an older commit with
+    # the same arithmetic comes back with THAT commit's names, or none
+    # (seen on the chip, PR 25: ``jit_insert`` without ``slot_insert``).
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     return resolved
 
 

@@ -490,9 +490,9 @@ class ServiceConfig(BaseModel):
     # Always-on device-time attribution: every guarded dispatch is
     # stamped at submit and completion is sampled at the loop's
     # existing fetch seams — device busy/bubble, prep overlap and a
-    # rolling MFU estimate with ZERO extra device syncs (the TRACE=1
-    # block_until_ready attribution mode stays the high-resolution
-    # debugging tool).  0 = the layer keeps no timestamps at all and
+    # rolling MFU estimate with ZERO extra device syncs (an estimate
+    # from host clocks: a profiler trace gives the real device times).
+    # 0 = the layer keeps no timestamps at all and
     # the compile cache skips cost analysis (pinned).
     perf_obs: bool = True
     # Peak chip TFLOP/s for the MFU denominator; 0 = auto (TPU
@@ -534,12 +534,12 @@ class ServiceConfig(BaseModel):
     # HTTP error bodies — utils/tracing.JsonLogFormatter).
     log_format: str = "text"
     # Request-level span tracing (utils/tracing.py): spans at the
-    # request / admission / queue-wait / prefill-window / decode-chunk
-    # / dispatch-site seams, exported as Chrome trace-event JSON at
-    # GET /debug/trace.  Off = zero overhead (no span objects on the
-    # hot path).  ON additionally block_until_ready's each dispatch to
-    # split host vs device time — an attribution mode that serializes
-    # the chunk pipeline; see docs/observability.md.
+    # request / admission / queue-wait / loop-phase / prefill-window /
+    # decode-chunk / dispatch-site seams, kept in a ring and exported
+    # as Chrome trace-event JSON at GET /debug/trace.  Off = no span
+    # objects on the hot path.  On or off, the same phase names go to
+    # the profiler's trace whenever a session runs, and nothing waits
+    # for the device; see docs/observability.md.
     trace: bool = False
     # Completed spans kept in the trace ring.
     trace_ring: int = 4096

@@ -110,7 +110,29 @@ TOKENS = Counter("generated_tokens_total", "Seq2seq tokens generated", ["model"]
 STREAM_BATCH = Histogram(
     "stream_batch_size",
     "Live streams served per continuous-batching chunk dispatch",
-    ["model"], buckets=(1, 2, 4, 8, 16, 32),
+    ["model"], buckets=(1, 2, 4, 8, 16, 32, 64, 128),
+)
+STREAM_QUEUE_WAIT = Histogram(
+    "stream_queue_wait_seconds",
+    "Seconds a stream waited in the decode loop's queue: (re-)queued "
+    "to the reservation that puts it in an admission wave (one "
+    "observation per reservation, so a checkpoint resume counts again)",
+    ["model"], buckets=_LATENCY_BUCKETS,
+)
+STREAM_ADMIT = Histogram(
+    "stream_admit_seconds",
+    "Seconds from a stream's reservation to its first emitted chunk: "
+    "the prefill wave, its fetch, and the stream's place in the "
+    "wave's emit/insert order",
+    ["model"], buckets=_LATENCY_BUCKETS,
+)
+PREFILL_WAVE_FILL = Histogram(
+    "prefill_wave_fill",
+    "Useful share of one prefill executable run: real prompt tokens "
+    "of the wave / (rows x bucket length) the executable ran",
+    ["model"],
+    buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8,
+             0.9, 1.0),
 )
 DECODE_STEPS = Histogram(
     "seq2seq_decode_steps",
@@ -145,10 +167,11 @@ PREFILL_CHUNKS = Counter(
 )
 PREFILL_STALL = Counter(
     "prefill_stall_seconds",
-    "Host time spent dispatching prefill windows while decode streams "
-    "were live — the decode-cadence delay chunked prefill bounds to "
-    "one window (device-side serialization rides behind the decode "
-    "dispatch, so this is the interleaving overhead, not a full stall)",
+    "Host seconds in which the decode loop could dispatch no decode "
+    "chunk while streams were live, because it was admitting: a "
+    "monolithic prefill wave from its dispatch to the end of its "
+    "emit/insert loop (the loop thread waits for the wave's fetch), "
+    "plus the time spent dispatching PREFILL_CHUNK windows",
     ["model"],
 )
 PREFILL_BACKLOG = Gauge(
@@ -428,8 +451,7 @@ DEVICE_BUSY = Counter(
     "device_busy_seconds",
     "Estimated device-busy seconds by dispatch site, derived from "
     "submit timestamps + the loop's existing fetch seams (zero extra "
-    "syncs, always on — the production replacement for the TRACE=1 "
-    "block_until_ready attribution mode)",
+    "syncs, always on; host clocks, not the device trace)",
     ["model", "site"],
 )
 DEVICE_BUBBLE = Counter(

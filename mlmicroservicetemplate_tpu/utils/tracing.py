@@ -1,26 +1,32 @@
 """Request-level span tracing + the engine flight recorder.
 
-Two instruments, both import-safe and OFF by default (mirroring the
-``metrics.py`` stub pattern — no OpenTelemetry or any other hard
-dependency):
+Two instruments, both import-safe (mirroring the ``metrics.py`` stub
+pattern — no OpenTelemetry or any other hard dependency beyond JAX):
 
-- **Span tracer** (``TRACE=1``): lightweight wall-clock spans opened at
-  the serving layers' seams — the HTTP request (keyed by
-  ``X-Request-Id``), admission/classify, queue wait, each prefill
-  window, each decode chunk, and every ``dispatch_guard`` site (with
-  the host submit→return vs device ``block_until_ready`` split) — kept
-  in a bounded ring (``TRACE_RING``) and exported as Chrome
-  trace-event JSON from ``GET /debug/trace`` (loadable in Perfetto or
-  ``chrome://tracing``).  When off, the module-level tracer is ``None``
-  and every call site takes a no-allocation fast path: ``span()``
-  returns one shared no-op context manager, so the decode hot loop
-  never constructs a span object (pinned by test).
+- **Phases** (``phase(name, ...)``): the ONE call every serving seam
+  goes through — admission/classify, the streaming loop's host phases
+  (``loop/queue_pop``, ``loop/wave_dispatch``, ``loop/wave_fetch``,
+  ``loop/insert``, ``loop/chunk_dispatch``, ``loop/stage_prep``,
+  ``loop/deliver``), each prefill window and every ``dispatch_guard``
+  site (``dispatch:<site>``).  A phase writes into two sinks:
 
-  When ON, dispatch spans additionally ``block_until_ready`` the
-  dispatch result to attribute device time — which serializes the
-  chunk-chain pipeline.  TRACE=1 is an attribution mode, not a
-  production default; the A/B cost is recorded in the pre-round BASELINE
-  record (removed in PR 22).
+  * always a ``jax.profiler.TraceAnnotation(name)``: it records only
+    while a profiler session runs (``POST /debug/profile``, a
+    benchmark's traced run) and lands on the xplane's host plane, on
+    the device trace's own clock — so a device idle gap reads
+    ``loop/insert``, not ``PjitFunction(insert)``.  With no session an
+    enter + exit costs about a microsecond.  The annotation's name is
+    the bare phase name; arguments go to the ring only.
+  * with ``TRACE=1`` also a ``Span`` (request id, parent, arguments)
+    in a bounded ring (``TRACE_RING``), exported as Chrome trace-event
+    JSON from ``GET /debug/trace`` (loadable in Perfetto or
+    ``chrome://tracing``).  When off, the module-level tracer is
+    ``None`` and no ``Span`` is ever constructed (pinned by test).
+
+  Neither sink synchronises with the device: ``TRACE=1`` observes the
+  chunk pipeline without serialising it.  Device time per program part
+  comes from the profiler's device trace (``jax.named_scope`` names in
+  ``models/llama.py`` and on the loop's step kinds), not from here.
 
 - **Flight recorder** (``FLIGHT_RING``, default on): a bounded ring of
   the engine loop's last N iterations (batch composition, slot
@@ -39,10 +45,14 @@ configure time so trace events correlate with log lines.
 from __future__ import annotations
 
 import collections
+import functools
 import json
 import logging
 import threading
 import time
+
+import jax
+from jax.profiler import TraceAnnotation
 
 log = logging.getLogger(__name__)
 
@@ -51,25 +61,6 @@ _now = time.monotonic
 
 # ---------------------------------------------------------------------------
 # span tracer
-
-
-class _NoopSpan:
-    """Shared do-nothing span: the TRACE=0 hot path enters/exits this
-    singleton instead of allocating anything."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def set(self, **kw):
-        return self
-
-
-NOOP = _NoopSpan()
 
 
 class Span:
@@ -243,15 +234,61 @@ def configure(enabled: bool, ring: int = 4096) -> Tracer | None:
     return _TRACER
 
 
-def span(name: str, cat: str = "app", rid: str = "", **args):
-    """Convenience: a context-manager span, or the shared no-op when
-    tracing is off.  NOTE: kwargs are evaluated by the caller either
-    way — hot paths that build expensive args should check ``tracer()``
-    themselves."""
+class _Phase:
+    """One ``phase()``: the profiler annotation plus, under TRACE=1,
+    the ring ``Span`` of the same name."""
+
+    __slots__ = ("_ann", "_span")
+
+    def __init__(self, name: str, span: Span | None):
+        self._ann = TraceAnnotation(name)
+        self._span = span
+
+    def set(self, **kw) -> "_Phase":
+        if self._span is not None:
+            self._span.args.update(kw)
+        return self
+
+    def __enter__(self) -> "_Phase":
+        self._ann.__enter__()
+        if self._span is not None:
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        if self._span is not None:
+            self._span.__exit__(etype, exc, tb)
+        self._ann.__exit__(etype, exc, tb)
+        return False
+
+
+def phase(name: str, cat: str = "app", rid: str = "", **args) -> _Phase:
+    """A context manager naming what the program does from here to its
+    exit, in the profiler's trace (always; recorded only while a
+    profiler session runs) and in the TRACE=1 ring (same name, plus
+    ``rid`` and ``args``).  Phases on one thread are FLAT siblings
+    wherever a device idle gap should be attributable: never wrap a
+    whole loop iteration.  NOTE: kwargs are evaluated by the caller
+    either way — a hot path with expensive args checks ``tracer()``
+    and adds them with ``.set()``."""
     tr = _TRACER
-    if tr is None:
-        return NOOP
-    return tr.span(name, cat, rid, **args)
+    return _Phase(name, None if tr is None else tr.span(name, cat, rid, **args))
+
+
+def scoped(name: str, fn):
+    """``fn`` traced under ``jax.named_scope(name)`` — one scope per
+    step kind (``prefill_wave``, ``slot_insert``, ``decode_chunk``), so
+    the device trace's operations carry it in their path.  Trace-time
+    only: metadata on the compiled operations, no run-time cost.  The
+    wrapper keeps ``fn``'s name, which is the executable's name
+    (``jit_<fn>``) in logs and traces."""
+
+    @functools.wraps(fn)
+    def inner(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+
+    return inner
 
 
 # ---------------------------------------------------------------------------

@@ -204,8 +204,12 @@ def test_persistent_cache_writes_entries(tmp_path, monkeypatch):
     prev_dir = jax.config.jax_compilation_cache_dir
     prev_min_t = jax.config.jax_persistent_cache_min_compile_time_secs
     prev_min_b = jax.config.jax_persistent_cache_min_entry_size_bytes
+    prev_meta = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
         assert enable_compilation_cache("cpu", cache_dir) == cache_dir
+        # named_scope paths are part of the key: an executable cached
+        # by another commit never comes back under that commit's names
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
 
         @jax.jit
         def f(x):
@@ -223,6 +227,9 @@ def test_persistent_cache_writes_entries(tmp_path, monkeypatch):
         )
         jax.config.update(
             "jax_persistent_cache_min_entry_size_bytes", prev_min_b
+        )
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", prev_meta
         )
 
 

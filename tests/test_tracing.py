@@ -15,9 +15,16 @@ The judged contracts:
    and the flight recorder dumps automatically on the fatal fault.
 5. The flight recorder captures loop iterations (slot occupancy, KV
    pool state) and scheduling/fault events (retries, requeues).
+6. The SAME phase names land on the profiler's host plane whenever a
+   ``jax.profiler`` session runs (TRACE=0 included), as flat siblings
+   on the loop thread; TRACE=1 never waits for the device; the
+   wave/queue counters observe where the work happens; the lowered
+   paged decode chunk carries the model's scope names.
 """
 
 import asyncio
+import glob
+import os
 import time
 
 import numpy as np
@@ -115,9 +122,10 @@ def test_span_tree_and_timing_sanity(traced):
     chunk_sids = {s.sid for s in by["decode_chunk"]}
     assert all(s.parent in chunk_sids for s in by["dispatch:chunk"])
 
-    # Host-vs-device attribution on dispatch spans.
+    # Host attribution on dispatch spans — and no device half: the
+    # guard never waits for the device (device time is the profiler's).
     for s in by["dispatch:chunk"]:
-        assert "host_ms" in s.args and "device_ms" in s.args
+        assert "host_ms" in s.args and "device_ms" not in s.args
 
     # Timing sanity: the stream span is the end-to-end interval; the
     # top-level stage spans (queue wait, prefill windows, decode
@@ -213,8 +221,200 @@ def test_trace_off_allocates_no_spans(monkeypatch):
     # The always-on host-dispatch accounting still ran.
     attr = eng.dispatch_attribution()
     assert attr.get("chunk", {}).get("count", 0) > 0
-    # ... but nobody paid the device-side block (attribution mode only).
-    assert attr["chunk"]["device_s"] == 0.0
+    # ... host seconds only: no device half exists to pay for.
+    assert "device_s" not in attr["chunk"]
+
+
+# ---------------------------------------------------------------------------
+# the profiler sink, the no-sync guard, the wave/queue counters, scopes
+
+LOOP_PHASES = ("loop/wave_dispatch", "loop/wave_fetch", "loop/insert",
+               "loop/chunk_dispatch", "loop/deliver")
+
+
+def _paged_llama(**kw):
+    cfg = _cfg(paged_kv=True, kv_block_size=4, **kw)
+    bundle = tiny_llama_bundle()
+    eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+    return bundle, eng, ContinuousDecodeLoop(eng, cfg)
+
+
+def test_profiler_session_holds_phase_names(tmp_path):
+    """TRACE=0, a ``jax.profiler`` session around one tiny stream: the
+    xplane's host plane holds the loop's phases and the dispatch sites
+    under the program's own names, and the ``loop/`` phases of the
+    loop thread are flat siblings — none encloses another, so none
+    encloses a whole iteration (an idle gap is attributed to the span
+    that overlaps it most; an enclosing one would swallow them all)."""
+    import jax
+    from jax.profiler import ProfileData
+
+    tracing.configure(False)
+    bundle, eng, cdl = _paged_llama()
+    feats = text_feats(bundle.tokenizer, "the quick brown fox")
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    try:
+        _consume(cdl, feats)  # compile outside the session
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            toks = _consume(cdl, feats)
+        finally:
+            jax.profiler.stop_trace()
+    finally:
+        cdl.stop()
+    assert len(toks) > 0
+    (path,) = glob.glob(
+        os.path.join(str(tmp_path), "plugins", "profile", "*", "*.xplane.pb"))
+    host = [p for p in ProfileData.from_file(path).planes
+            if p.name.startswith("/host:CPU")]
+    lines = [[(e.name, e.start_ns, e.start_ns + e.duration_ns)
+              for e in line.events] for p in host for line in p.lines]
+    names = {n for evs in lines for n, _, _ in evs}
+    for want in LOOP_PHASES + ("dispatch:chunk", "dispatch:insert",
+                               "dispatch:prefill", "dispatch:fetch",
+                               "admission"):
+        assert want in names, f"missing {want} (have {sorted(names)})"
+    loop_line = next(evs for evs in lines
+                     if any(n == "loop/chunk_dispatch" for n, _, _ in evs))
+    phases = [e for e in loop_line if e[0].startswith("loop/")]
+    for a in phases:
+        for b in phases:
+            # (b[2] > a[1]: a zero-length neighbour that ended in the
+            # nanosecond ``a`` began is beside it, not inside it)
+            if a is not b and b[2] > a[1]:
+                assert not (a[1] <= b[1] and b[2] <= a[2]), (a, b)
+    # Every dispatch on the loop thread sits inside one of its phases
+    # (a phase the session's start or end cut in two is not recorded:
+    # look between the first and the last that are).
+    first, last = min(p[1] for p in phases), max(p[2] for p in phases)
+    for n, s, e in loop_line:
+        if n.startswith("dispatch:") and first <= s < last:
+            assert any(ps <= s and e <= pe for _, ps, pe in phases), n
+
+
+def test_trace_on_same_names_and_never_syncs(traced, monkeypatch):
+    """TRACE=1: the ring holds the phase names the profiler's trace
+    holds, and nothing waits for the device on their account — not one
+    ``block_until_ready`` more than with TRACE=0 (the empty-state
+    build's), and as many chunks in flight."""
+    import jax
+
+    synced = []
+    orig = jax.block_until_ready
+    monkeypatch.setattr(
+        jax, "block_until_ready",
+        lambda x: (synced.append(1), orig(x))[1],
+    )
+
+    def run() -> tuple[int, int]:
+        synced.clear()
+        cfg = _cfg(max_decode_len=32)
+        bundle = tiny_gpt_bundle()
+        eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+        cdl = ContinuousDecodeLoop(eng, cfg)
+        depth = []
+        noted = cdl._note_dispatched
+
+        def spy(entry):
+            noted(entry)
+            depth.append(len(cdl._inflight_chunks))
+
+        cdl._note_dispatched = spy
+        try:
+            assert _consume(cdl, text_feats(bundle.tokenizer, "hello world"))
+        finally:
+            cdl.stop()
+        return max(depth), len(synced)
+
+    on = run()
+    by = _spans_by_name(traced.snapshot())
+    for name in LOOP_PHASES + ("dispatch:chunk", "dispatch:insert",
+                               "dispatch:prefill", "admission"):
+        assert name in by, f"missing {name} (have {sorted(by)})"
+    tracing.configure(False)
+    off = run()
+    assert on == off and on[0] >= 1
+
+
+def _sample(name: str, model: str) -> float:
+    from prometheus_client import REGISTRY
+
+    return REGISTRY.get_sample_value(name, {"model": model}) or 0.0
+
+
+def test_wave_and_queue_counters():
+    """A 2-stream wave at 8 slots: ``prefill_wave_fill`` observes
+    (L1 + L2) / (8 x S) exactly, ``stream_queue_wait_seconds`` and
+    ``stream_admit_seconds`` count one per reservation / stream, and
+    ``prefill_stall_seconds`` does not move for a wave nobody was live
+    to be stalled by — then grows for a wave admitted while they decode."""
+    tracing.configure(False)
+    bundle, eng, cdl = _paged_llama(max_streams=8, max_decode_len=64,
+                                    seq_buckets=(32,))
+    cdl._admit_grace_s = 1.0  # both submits land in ONE wave
+    name = bundle.name
+    f1 = text_feats(bundle.tokenizer, "the quick brown fox")
+    f2 = text_feats(bundle.tokenizer, "jumps over the lazy dog again")
+    l1, l2 = int(f1["length"]), int(f2["length"])
+    fams = ("prefill_wave_fill_sum", "prefill_wave_fill_count",
+            "stream_queue_wait_seconds_count", "stream_admit_seconds_count",
+            "prefill_stall_seconds_total")
+    before = {k: _sample(k, name) for k in fams}
+
+    async def body():
+        async def consume(feats, first: asyncio.Event | None = None):
+            n = 0
+            async for c in cdl.submit_stream(dict(feats)):
+                n += int(np.asarray(c).size)
+                if first is not None:
+                    first.set()
+            return n
+
+        first = asyncio.Event()
+        a = asyncio.ensure_future(consume(f1, first))
+        b = asyncio.ensure_future(consume(f2))
+        await first.wait()
+        mid = {k: _sample(k, name) for k in fams}
+        live = len(cdl.active) > 0
+        c = await consume(f1)  # admitted while the first two decode
+        return mid, live, await a, await b, c
+
+    try:
+        mid, live, *counts = asyncio.run(body())
+    finally:
+        cdl.stop()
+    assert all(n > 0 for n in counts)
+    d = lambda snap, k: snap[k] - before[k]  # noqa: E731
+    assert d(mid, "prefill_wave_fill_count") == 1
+    assert d(mid, "prefill_wave_fill_sum") == pytest.approx(
+        (l1 + l2) / (8 * 32), rel=1e-12)
+    assert d(mid, "stream_queue_wait_seconds_count") == 2
+    assert d(mid, "prefill_stall_seconds_total") == 0.0
+    after = {k: _sample(k, name) for k in fams}
+    assert d(after, "stream_queue_wait_seconds_count") == 3
+    assert d(after, "stream_admit_seconds_count") == 3
+    assert d(after, "prefill_wave_fill_count") == 2
+    if live:  # the third stream's wave held the loop from live streams
+        assert d(after, "prefill_stall_seconds_total") > 0.0
+        assert cdl.prefill_stall_s > 0.0
+
+
+def test_paged_decode_chunk_carries_scope_names():
+    """The lowered paged decode chunk (debug info on) names the model's
+    parts: the scopes a device trace's operations are grouped by."""
+    tracing.configure(False)
+    bundle, eng, cdl = _paged_llama()
+    try:
+        _consume(cdl, text_feats(bundle.tokenizer, "hello world"))
+        hlo = cdl.paged_chunk_hlo(debug_info=True)
+        bare = cdl.paged_chunk_hlo()
+    finally:
+        cdl.stop()
+    for scope in ("decode_chunk", "embed", "qkv_rope", "kv_write", "attn",
+                  "attn_out", "mlp", "lm_head", "sample"):
+        assert f'"{scope}/' in hlo or f"/{scope}/" in hlo, scope
+    assert "kv_write" not in bare  # names are metadata, not operations
 
 
 # ---------------------------------------------------------------------------
