@@ -78,6 +78,24 @@ def prefetch_to_host(*arrays) -> None:
             pass
 
 
+# A batched prefill wave runs the smallest rung that holds it, not
+# ``n_slots`` rows: lone, a small wave, or the slot count.  Every rung is
+# one more start + insert executable per seq bucket to load at every boot
+# (~2.6 s a pair on a v5e at Mistral-7B widths, PERF.md section 6, PR 26),
+# so there is ONE rung between: a wave of up to 4 rows costs live streams
+# about one decode chunk; a larger one still runs the slot count.
+_SMALL_WAVE_ROWS = 4
+
+
+def wave_rungs(n_slots: int, multiple: int = 1) -> tuple[int, ...]:
+    """Row counts a prefill wave may run at: 1 and ``_SMALL_WAVE_ROWS``,
+    each rounded up to the placement's pad ``multiple`` and kept where
+    below ``n_slots``, then ``n_slots`` itself (already a multiple: the
+    loop rounds it)."""
+    below = {-(-r // multiple) * multiple for r in (1, _SMALL_WAVE_ROWS)}
+    return tuple(sorted(r for r in below if r < n_slots)) + (n_slots,)
+
+
 class StreamClosedError(Exception):
     """The decode loop is shutting down."""
 
@@ -342,6 +360,7 @@ class ContinuousDecodeLoop:
         # Slot count must divide over the replica mesh's batch axis.
         mult = engine.replicas.pad_multiple()
         self.n_slots = -(-self.max_streams // mult) * mult
+        self._wave_rungs = wave_rungs(self.n_slots, mult)
         # Block-paged KV (PAGED_KV=1): per-layer KV pools shared by all
         # slots + a host-owned per-slot block table that rides into
         # every dispatch as a traced argument.  Insert scatters a
@@ -1908,13 +1927,30 @@ class ContinuousDecodeLoop:
 
     # -- admission -----------------------------------------------------
 
+    def _wave_rows(self, k: int) -> int:
+        """Rows a prefill wave of ``k`` streams runs: the smallest rung
+        that holds it, so the start and insert executables it meets are
+        the ones the warm grids compiled."""
+        return next(r for r in self._wave_rungs if r >= k)
+
+    def _pad_wave(self, feats_list: list[dict]) -> list[dict]:
+        """The wave's rows plus zero-length pad rows up to its rung
+        (they collate to all-zero masks: born-done rows that never
+        insert)."""
+        pad = {"input_ids": np.zeros(0, np.int32), "length": np.int32(0)}
+        return feats_list + [pad] * (
+            self._wave_rows(len(feats_list)) - len(feats_list)
+        )
+
     def _note_wave_fill(self, real_tokens: int, rows: int, width: int) -> None:
         """One prefill executable just ran ``rows x width`` token
         positions for ``real_tokens`` prompt tokens (prefill_wave_fill:
-        useful over attempted work)."""
-        metrics.PREFILL_WAVE_FILL.labels(self.engine.bundle.name).observe(
+        useful over attempted work; prefill_wave_rows: the rows)."""
+        name = self.engine.bundle.name
+        metrics.PREFILL_WAVE_FILL.labels(name).observe(
             real_tokens / max(1, rows * width)
         )
+        metrics.PREFILL_WAVE_ROWS.labels(name).observe(rows)
 
     def _note_wave_stall(self, t_wave: float | None) -> None:
         """A monolithic wave (dispatch, fetch, emit + inserts) just
@@ -1934,8 +1970,8 @@ class ContinuousDecodeLoop:
         chunk dispatch in front of the fetch round-trip.
 
         A multi-stream wave prefills as ONE batched ``_start`` dispatch
-        (rows padded to the widest prompt bucket in the wave): each
-        dispatch costs a host<->device round-trip, and a wave pays
+        (``_wave_rows`` rows, at the widest prompt bucket in the wave):
+        each dispatch costs a host<->device round-trip, and a wave pays
         one dispatch + one fetch TOTAL, not per stream.  Under the
         per-request prefix cache, waves group by (prefix, suffix)
         bucket instead — one batched prefixed start per hit group, one
@@ -2026,17 +2062,13 @@ class ContinuousDecodeLoop:
                     started.append((st, state1, toks, sampled, 0, None, None))
                 return started
             try:
-                # Pad the wave to the full slot count so every wave
-                # size shares ONE (B, S) executable per seq bucket
-                # (zero-length pad rows collate to all-zero masks =
-                # born-done rows that never insert).  Spec mode admits
-                # solo streams at B=1 (no pad) but through the same
+                # Pad the wave to its rung: a small wave no longer
+                # computes ``n_slots`` rows, and every wave size meets a
+                # warmed (B, S) executable.  Spec mode admits solo
+                # streams here too (the lowest rung), through the same
                 # collated path: the insert needs the ids/mask to build
                 # the row's spec base.
-                pad_to = 1 if (self.spec and len(ok) == 1) else self.n_slots
-                feats_list = [st.feats for st in ok] + [
-                    {"input_ids": np.zeros(0, np.int32), "length": np.int32(0)}
-                ] * (pad_to - len(ok))
+                feats_list = self._pad_wave([st.feats for st in ok])
                 ids, mask, _ = eng._collate_text(feats_list)
                 sp, sampled = eng._collate_sample(feats_list, ids.shape[0])
                 ids, mask = eng.replicas.place_batch(ids, mask)
@@ -2128,12 +2160,6 @@ class ContinuousDecodeLoop:
             ids, mask = eng.replicas.place_batch(ids, mask)
             return ids, mask, sp, sampled
 
-        def pad_feats(feats_list):
-            pad_to = 1 if len(feats_list) == 1 else self.n_slots
-            return feats_list + [
-                {"input_ids": np.zeros(0, np.int32), "length": np.int32(0)}
-            ] * (pad_to - len(feats_list))
-
         def record(state1, toks, streams, ids, mask, real_tokens: int):
             # ``ids``/``mask`` are the COLLATED (suffix, for hits)
             # prompt arrays the spec insert feeds to init_spec_fn; the
@@ -2171,7 +2197,7 @@ class ContinuousDecodeLoop:
         if misses:
             try:
                 ids, mask, sp, sampled = collate_place(
-                    pad_feats([st.feats for st, _, _ in misses])
+                    self._pad_wave([st.feats for st, _, _ in misses])
                 )
                 mrows = [st.adapter_slot for st, _, _ in misses]
                 mrows += [0] * (int(ids.shape[0]) - len(mrows))
@@ -2195,8 +2221,8 @@ class ContinuousDecodeLoop:
                        sum(L for _, _, L in misses))
 
         # Hit groups: one batched prefixed start per (prefix, suffix)
-        # bucket pair; multi-member groups pad to the slot count so
-        # every group size shares the pair's ONE executable.
+        # bucket pair; multi-member groups pad to their rung, like the
+        # miss wave.
         for (p_len, s_suf), members in groups.items():
             suffix_feats = [
                 dict(st.feats, input_ids=row_ids[p_len:],
@@ -2205,7 +2231,7 @@ class ContinuousDecodeLoop:
             ]
             try:
                 ids, mask, sp, sampled = collate_place(
-                    pad_feats(suffix_feats)
+                    self._pad_wave(suffix_feats)
                 )
                 hrows = [st.adapter_slot for st, *_ in members]
                 hrows += [0] * (int(ids.shape[0]) - len(hrows))
@@ -4694,6 +4720,22 @@ class ContinuousDecodeLoop:
                 self.chain_depth
             )
 
+    def _warm_wave(self, s: int, n_batch: int, sampled: bool = False):
+        """Run the batched start for ``n_batch`` full rows of bucket
+        ``s`` (caller holds ``eng._lock``): (state1, ids, mask)."""
+        eng = self.engine
+        feats_list = [
+            {"input_ids": np.ones(s, np.int32), "length": np.int32(s)}
+        ] * n_batch
+        ids, mask, _ = eng._collate_text(feats_list)
+        sp, _ = eng._collate_sample(feats_list, ids.shape[0])
+        ids, mask = eng.replicas.place_batch(ids, mask)
+        state1, _ = eng._start(
+            self._mp(n=int(ids.shape[0])), ids, mask, sp,
+            eng.max_decode_len, eng.chunk_tokens, sampled,
+        )
+        return state1, ids, mask
+
     def _warm_inner(self) -> None:
         import jax
 
@@ -4726,29 +4768,17 @@ class ContinuousDecodeLoop:
                     self._state, state1, np.int32(0), np.int32(0)
                 )
 
-        # Wave sizes to warm: solo (1) and the batched full-wave shape
-        # every multi-stream wave pads to.  Under the prefix cache the
-        # full wave still serves grouped MISSES (hits go through the
-        # grouped prefixed waves warmed below).
-        wave_sizes = [1]
-        if self.n_slots > 1:
-            wave_sizes.append(self.n_slots)
+        # Wave sizes to warm: every rung a wave can run at (the lowest
+        # is the solo shape).  Under the prefix cache these still serve
+        # grouped MISSES (hits go through the grouped prefixed waves
+        # warmed below).
         for s in eng.seq_buckets:
-            for n_batch in wave_sizes:
-                feats_list = [
-                    {"input_ids": np.ones(s, np.int32), "length": np.int32(s)}
-                ] * n_batch
+            for n_batch in self._wave_rungs:
                 for flag in (False, True) if (
                     warm_sampled and n_batch > 1
                 ) else (False,):
                     with eng._lock:
-                        ids, mask, _ = eng._collate_text(feats_list)
-                        sp, _ = eng._collate_sample(feats_list, ids.shape[0])
-                        ids, mask = eng.replicas.place_batch(ids, mask)
-                        state1, _ = eng._start(
-                            self._mp(n=int(ids.shape[0])), ids, mask, sp,
-                            eng.max_decode_len, eng.chunk_tokens, flag,
-                        )
+                        state1, ids, mask = self._warm_wave(s, n_batch, flag)
                         do_insert(state1, ids, mask, s)
         for flag in (False, True) if (warm_sampled or not self.spec) else (
             False,
@@ -4774,18 +4804,9 @@ class ContinuousDecodeLoop:
         # path), which would otherwise land on the first admission
         # after serving starts.
         for s in eng.seq_buckets:
-            for n_batch in wave_sizes:
-                feats_list = [
-                    {"input_ids": np.ones(s, np.int32), "length": np.int32(s)}
-                ] * n_batch
+            for n_batch in self._wave_rungs:
                 with eng._lock:
-                    ids, mask, _ = eng._collate_text(feats_list)
-                    sp, _ = eng._collate_sample(feats_list, ids.shape[0])
-                    ids, mask = eng.replicas.place_batch(ids, mask)
-                    state1, _ = eng._start(
-                        self._mp(n=int(ids.shape[0])), ids, mask, sp,
-                        eng.max_decode_len, eng.chunk_tokens, False,
-                    )
+                    state1, ids, mask = self._warm_wave(s, n_batch)
                     do_insert(state1, ids, mask, s)
                     # Miss-wave donation slicers specialize on the
                     # batched state shape — warm them here so the first
@@ -4809,18 +4830,8 @@ class ContinuousDecodeLoop:
         # for is sample-agnostic — state shapes don't depend on it.)
         if eng.prefix_cache is not None:
             s_max = max(eng.seq_buckets)
-            feats_max = {
-                "input_ids": np.ones(s_max, np.int32),
-                "length": np.int32(s_max),
-            }
             with eng._lock:
-                ids, mask, _ = eng._collate_text([feats_max])
-                sp, _ = eng._collate_sample([feats_max], ids.shape[0])
-                ids, mask = eng.replicas.place_batch(ids, mask)
-                template, _ = eng._start(
-                    self._mp(n=int(ids.shape[0])), ids, mask, sp,
-                    eng.max_decode_len, eng.chunk_tokens, False,
-                )
+                template, _, _ = self._warm_wave(s_max, 1)
             for p_len in eng.seq_buckets:
                 if p_len > s_max - 1:
                     continue
@@ -4845,8 +4856,10 @@ class ContinuousDecodeLoop:
                         # insert against the hit-state shape (full
                         # prompt = prefix + suffix for the hist row).
                         do_insert(st1, sids, smask, p_len + s_suf)
-                    if self.n_slots > 1:
-                        wfeats = [sfeats] * self.n_slots
+                    for n_batch in self._wave_rungs:
+                        if n_batch < 2:
+                            continue  # solo hits: the B=1 start above
+                        wfeats = [sfeats] * n_batch
                         with eng._lock:
                             wids, wmask, _ = eng._collate_text(wfeats)
                             wsp, _ = eng._collate_sample(
@@ -4925,54 +4938,71 @@ class ContinuousDecodeLoop:
         )
 
     def _warm_paged(self, warm_sampled: bool) -> None:
-        """Paged-mode warmup: the paged insert per (wave size × seq
-        bucket) and the paged chunk in both sample variants, against
-        temporarily-allocated blocks that are returned (and the state
-        reset) before serving.  The prefixed-hit insert variants
+        """Paged-mode warmup: the start and the paged insert per (wave
+        rung × seq bucket) and the paged chunk in both sample variants,
+        against temporarily-allocated blocks that are returned (and the
+        state reset) before serving.  The prefixed-hit insert variants
         ((s_lo, s_cut) pairs) compile on first hit — paged deployments
         restrict SEQ_BUCKETS anyway (the PREFIX_CACHE guidance), and a
         one-off compile beats warming a grid most cells of which are
         never served."""
+        from concurrent.futures import ThreadPoolExecutor
+
         import jax
         import jax.numpy as jnp
 
-        from .kv_blocks import OutOfBlocks, StreamBlocks
+        from .kv_blocks import OutOfBlocks, StreamBlocks, blocks_for
 
         self._autotune_kernel()
         eng = self.engine
-        wave_sizes = [1]
-        if self.n_slots > 1:
-            wave_sizes.append(self.n_slots)
-        for s in eng.seq_buckets:
-            for n_batch in wave_sizes:
-                feats_list = [
-                    {"input_ids": np.ones(s, np.int32), "length": np.int32(s)}
-                ] * n_batch
-                sb = StreamBlocks(self.pool, self.block_size)
-                try:
-                    sb.ensure(s + eng.chunk_tokens)
-                except OutOfBlocks:
-                    continue  # pool smaller than this bucket: unservable
-                table_row = np.full(
-                    self.nb_max, self.pool.num_blocks, np.int32
-                )
-                table_row[: len(sb.ids)] = sb.ids
-                try:
-                    with eng._lock:
-                        ids, mask, _ = eng._collate_text(feats_list)
-                        sp, _ = eng._collate_sample(feats_list, ids.shape[0])
-                        ids, mask = eng.replicas.place_batch(ids, mask)
-                        state1, _ = eng._start(
-                            self._mp(n=int(ids.shape[0])), ids, mask, sp,
-                            eng.max_decode_len, eng.chunk_tokens, False,
-                        )
-                        self._state = self._paged_insert_fn()(
-                            self._state, state1, jnp.asarray(table_row),
-                            np.int32(0), np.int32(0), 0,
-                            s + eng.chunk_tokens,
-                        )
-                finally:
-                    sb.release()
+
+        # One scratch block list serves the whole grid (the inserts'
+        # results are dropped: warm-up resets the state below); a bucket
+        # the pool cannot hold is unservable and stays cold.
+        sb = StreamBlocks(self.pool, self.block_size)
+        grid = []
+        for s in sorted(eng.seq_buckets):
+            try:
+                sb.ensure(s + eng.chunk_tokens)
+            except OutOfBlocks:
+                break
+            grid += [(s, n_batch) for n_batch in self._wave_rungs]
+
+        def table_row(s: int):
+            n_blocks = blocks_for(s + eng.chunk_tokens, self.block_size)
+            row = np.full(self.nb_max, self.pool.num_blocks, np.int32)
+            row[:n_blocks] = sb.ids[:n_blocks]
+            return jnp.asarray(row)
+
+        insert = self._paged_insert_fn()
+        one_insert = threading.Lock()
+
+        def warm_one(cell: tuple[int, int]) -> None:
+            s, n_batch = cell
+            with eng._lock:
+                state1 = self._warm_wave(s, n_batch)[0]
+                # One insert at a time, and done before the next: each
+                # makes a whole new state (it is not donated).
+                with one_insert:
+                    jax.block_until_ready(insert(
+                        self._state, state1, table_row(s),
+                        np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
+                    ))
+
+        # A warm start is tracing plus the runtime loading a cached
+        # executable of tens of MB, ~2.6 s a (rung, bucket) pair, and the
+        # ladder's extra pairs cost a boot more than ``setup_s`` may move
+        # when they load one after another.  On three threads the loads
+        # overlap (the tracing does not): the grid's extra cost falls to
+        # less than half (PERF.md section 6, PR 26).  Largest first, so
+        # no thread starts the longest load last; up to three wave
+        # states are alive at once instead of one.
+        grid.sort(key=lambda cell: -cell[0] * cell[1])
+        try:
+            with ThreadPoolExecutor(3, "warm-rung") as pool:
+                list(pool.map(warm_one, grid))  # list(): raise what failed
+        finally:
+            sb.release()
         for flag in (False, True) if warm_sampled else (False,):
             with eng._lock:
                 self._state, toks = self._paged_chunk_fn()(

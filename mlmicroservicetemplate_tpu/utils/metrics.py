@@ -134,6 +134,12 @@ PREFILL_WAVE_FILL = Histogram(
     buckets=(0.01, 0.02, 0.05, 0.1, 0.2, 0.3, 0.4, 0.5, 0.6, 0.7, 0.8,
              0.9, 1.0),
 )
+PREFILL_WAVE_ROWS = Histogram(
+    "prefill_wave_rows",
+    "Rows one prefill executable ran (a lone admission 1, a wave the "
+    "smallest rung that holds it: 4 or the slot count)",
+    ["model"], buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
+)
 DECODE_STEPS = Histogram(
     "seq2seq_decode_steps",
     "Decode steps executed per non-streaming seq2seq dispatch "

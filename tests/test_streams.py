@@ -11,6 +11,7 @@ The contract under test:
 """
 
 import asyncio
+import time
 from typing import NamedTuple
 
 import numpy as np
@@ -703,3 +704,163 @@ def test_spec_continuous_hist_row_full_budget_chunk():
         )
     finally:
         cdl.stop()
+
+
+# ---------------------------------------------------------------------------
+# The row ladder: a prefill wave runs the smallest rung that holds it.
+
+
+@pytest.mark.parametrize("multiple", [1, 2])
+@pytest.mark.parametrize("max_streams", [1, 2, 8, 12, 64])
+def test_wave_rows_ladder(max_streams, multiple):
+    """Every wave size 1..n_slots maps to a rung >= it, <= n_slots, a
+    multiple of the placement's pad multiple, monotone in the size, and
+    n_slots itself is reachable; a small wave runs the small rung, not
+    the slot count."""
+    from types import SimpleNamespace
+
+    from mlmicroservicetemplate_tpu.engine.streams import (
+        _SMALL_WAVE_ROWS,
+        wave_rungs,
+    )
+
+    n_slots = -(-max_streams // multiple) * multiple  # as the loop rounds it
+    rungs = wave_rungs(n_slots, multiple)
+    assert rungs == tuple(sorted(set(rungs))) and rungs[-1] == n_slots
+    loop_like = SimpleNamespace(_wave_rungs=rungs, n_slots=n_slots)
+    rows = [ContinuousDecodeLoop._wave_rows(loop_like, k)
+            for k in range(1, n_slots + 1)]
+    small = -(-_SMALL_WAVE_ROWS // multiple) * multiple
+    for k, r in zip(range(1, n_slots + 1), rows):
+        assert k <= r <= n_slots and r % multiple == 0 and r in rungs
+        if k <= small:
+            assert r <= small
+    assert rows == sorted(rows) and rows[-1] == n_slots
+    assert set(rows) == set(rungs)  # no rung is warmed for nothing
+
+
+def _llama_loop(paged: bool, **kw):
+    from helpers import tiny_llama_bundle
+
+    if paged:
+        kw.update(paged_kv=True, kv_block_size=4)
+    # (a deep queue: ``asyncio.run`` closes each wave's event loop, which
+    # can drop a finished stream's admission-count callback)
+    cfg = _cfg(batch_buckets=(1,), max_streams=8, max_stream_queue=256, **kw)
+    bundle = tiny_llama_bundle()
+    eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+    return bundle, eng, ContinuousDecodeLoop(eng, cfg)
+
+
+def _spy_waves(cdl) -> list[tuple[int, int]]:
+    """Record (rows, width) of every prefill executable run."""
+    ran: list[tuple[int, int]] = []
+    note = cdl._note_wave_fill
+
+    def spy(real_tokens, rows, width):
+        ran.append((rows, width))
+        note(real_tokens, rows, width)
+
+    cdl._note_wave_fill = spy
+    return ran
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "contiguous"])
+def test_wave_of_three_at_eight_slots_matches_solo(paged):
+    """Three streams admitted as ONE wave at 8 slots run their rung
+    (4 rows, not 8) and emit the tokens each emits served alone."""
+    bundle, eng, cdl = _llama_loop(paged)
+    cdl._admit_grace_s = 0.5  # all three submits land in one wave
+    ran = _spy_waves(cdl)
+    feats = [text_feats(bundle.tokenizer, t) for t in
+             ("the quick brown fox", "hi", "jumps over the lazy dog again")]
+    try:
+        outs = _run_concurrent(cdl, feats)
+    finally:
+        cdl.stop()
+    assert ran == [(cdl._wave_rows(3), 32)] and ran[0][0] < cdl.n_slots
+    for f, got in zip(feats, outs):
+        np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+
+
+@pytest.mark.parametrize("mode", ["paged", "contiguous-prefix"])
+def test_warm_covers_every_wave_size(mode, monkeypatch):
+    """After ``warm()``, waves of every size 1..n_slots at every seq
+    bucket (under PREFIX_CACHE: as misses, then again as one hit group)
+    compile nothing: each meets a start and an insert the grid warmed."""
+    from mlmicroservicetemplate_tpu.runtime.compile_cache import CompileWindow
+
+    monkeypatch.setenv("WARMUP_SAMPLING", "0")
+    prefix = mode == "contiguous-prefix"
+    bundle, eng, cdl = _llama_loop(
+        not prefix, max_decode_len=8, prefix_cache=prefix
+    )
+    tok = bundle.tokenizer
+    want, ran = [], None
+    try:
+        cdl.warm()
+        cdl._admit_grace_s = 0.15
+        ran = _spy_waves(cdl)
+        with CompileWindow() as w:
+            for s, body in ((16, "x" * 6), (32, "x" * 20)):
+                passes = 2 if (prefix and s == 32) else 1
+                for k in range(1, cdl.n_slots + 1):
+                    # Distinct leading bytes: distinct prefixes, so the
+                    # first pass misses and the second hits per row.
+                    feats = [text_feats(tok, f"{k}{i}-{body}")
+                             for i in range(k)]
+                    for hit in range(passes):
+                        t_end = time.monotonic() + 5.0
+                        while cdl.active:  # the last wave's slots
+                            assert time.monotonic() < t_end
+                            time.sleep(0.002)
+                        outs = _run_concurrent(cdl, feats)
+                        assert all(len(o) > 0 for o in outs)
+                        # (a lone admission notes its own bucket, hit
+                        # or miss; a hit group its suffix bucket)
+                        want.append(
+                            (1, s) if k == 1
+                            else (cdl._wave_rows(k), 16 if hit else s)
+                        )
+    finally:
+        cdl.stop()
+    assert ran == want
+    assert w.compiles == 0, f"{w.compiles} compiles on the admission path"
+
+
+def test_prefill_wave_rows_observes_the_rung():
+    """``prefill_wave_rows`` counts the rows the executable ran: a wave
+    of three at 8 slots observes its rung (4), a lone admission 1, a
+    wave of five the slot count."""
+    from prometheus_client import REGISTRY
+
+    bundle = _echo_bundle()
+    cfg = _cfg(max_streams=8)
+    eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+    cdl = ContinuousDecodeLoop(eng, cfg)
+    cdl._admit_grace_s = 0.5
+
+    def read():
+        return tuple(
+            REGISTRY.get_sample_value(
+                f"prefill_wave_rows_{k}", {"model": bundle.name}
+            ) or 0.0
+            for k in ("sum", "count")
+        )
+
+    feats = [text_feats(bundle.tokenizer, t)
+             for t in ("abc", "de", "fgh", "ij", "klm")]
+    try:
+        s0, c0 = read()
+        _run_concurrent(cdl, feats[:3])
+        s1, c1 = read()
+        _run_concurrent(cdl, feats[:1])
+        s2, c2 = read()
+        rung = cdl._wave_rows(3)
+        _run_concurrent(cdl, feats)
+        s3, c3 = read()
+    finally:
+        cdl.stop()
+    assert (s1 - s0, c1 - c0) == (float(rung), 1.0) and rung < cdl.n_slots
+    assert (s2 - s1, c2 - c1) == (1.0, 1.0)
+    assert (s3 - s2, c3 - c2) == (float(cdl.n_slots), 1.0)

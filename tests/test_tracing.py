@@ -344,8 +344,8 @@ def _sample(name: str, model: str) -> float:
 
 
 def test_wave_and_queue_counters():
-    """A 2-stream wave at 8 slots: ``prefill_wave_fill`` observes
-    (L1 + L2) / (8 x S) exactly, ``stream_queue_wait_seconds`` and
+    """A 2-stream wave at 8 slots runs its rung R < 8: ``prefill_wave_fill``
+    observes (L1 + L2) / (R x S) exactly, ``stream_queue_wait_seconds`` and
     ``stream_admit_seconds`` count one per reservation / stream, and
     ``prefill_stall_seconds`` does not move for a wave nobody was live
     to be stalled by — then grows for a wave admitted while they decode."""
@@ -388,7 +388,8 @@ def test_wave_and_queue_counters():
     d = lambda snap, k: snap[k] - before[k]  # noqa: E731
     assert d(mid, "prefill_wave_fill_count") == 1
     assert d(mid, "prefill_wave_fill_sum") == pytest.approx(
-        (l1 + l2) / (8 * 32), rel=1e-12)
+        (l1 + l2) / (cdl._wave_rows(2) * 32), rel=1e-12)
+    assert cdl._wave_rows(2) < cdl.n_slots == 8
     assert d(mid, "stream_queue_wait_seconds_count") == 2
     assert d(mid, "prefill_stall_seconds_total") == 0.0
     after = {k: _sample(k, name) for k in fams}
