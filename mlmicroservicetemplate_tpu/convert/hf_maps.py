@@ -230,6 +230,11 @@ def llama_state_to_pytree(state: State, n_layers: int | None = None) -> dict:
     RMSNorm weight vectors; ``lm_head.weight`` [V, D] transposes to the
     untied [D, V] kernel.  Tied-embedding checkpoints (no ``lm_head``
     key) fall back to the embedding table transposed.
+
+    OLMoE-style layers are recognised by their names: ``mlp.gate.weight``
+    [E, D] is the router (→ [D, E]), ``mlp.experts.N.{gate,up,down}_proj``
+    stack over N into [E, D, W] / [E, W, D], and
+    ``self_attn.{q,k}_norm.weight`` are the q/k-norm scales.
     """
     if n_layers is None:
         n_layers = 1 + max(
@@ -249,23 +254,29 @@ def llama_state_to_pytree(state: State, n_layers: int | None = None) -> dict:
         "final_ln": {"scale": state["model.norm.weight"]},
         "lm_head": {"kernel": _lin(head)},
     }
+    def experts(b: str, name: str) -> dict:
+        n = state[f"{b}.mlp.gate.weight"].shape[0]  # the router is [E, D]
+        return {"kernel": np.stack(
+            [_lin(state[f"{b}.mlp.experts.{e}.{name}.weight"]) for e in range(n)]
+        )}
+
     for i in range(n_layers):
         b = f"model.layers.{i}"
+        attn = {n: lin(f"{b}.self_attn.{n}_proj") for n in "qkvo"}
+        for n in ("q_norm", "k_norm"):
+            if f"{b}.self_attn.{n}.weight" in state:
+                attn[n] = {"scale": state[f"{b}.self_attn.{n}.weight"]}
+        if f"{b}.mlp.gate.weight" in state:
+            mlp = {"router": lin(f"{b}.mlp.gate")}
+            mlp.update({n: experts(b, f"{n}_proj") for n in ("gate", "up", "down")})
+        else:
+            mlp = {n: lin(f"{b}.mlp.{n}_proj") for n in ("gate", "up", "down")}
         p["layers"].append(
             {
                 "attn_ln": {"scale": state[f"{b}.input_layernorm.weight"]},
-                "attn": {
-                    "q": lin(f"{b}.self_attn.q_proj"),
-                    "k": lin(f"{b}.self_attn.k_proj"),
-                    "v": lin(f"{b}.self_attn.v_proj"),
-                    "o": lin(f"{b}.self_attn.o_proj"),
-                },
+                "attn": attn,
                 "mlp_ln": {"scale": state[f"{b}.post_attention_layernorm.weight"]},
-                "mlp": {
-                    "gate": lin(f"{b}.mlp.gate_proj"),
-                    "up": lin(f"{b}.mlp.up_proj"),
-                    "down": lin(f"{b}.mlp.down_proj"),
-                },
+                "mlp": mlp,
             }
         )
     return p

@@ -70,8 +70,11 @@ _END = object()
 def prefetch_to_host(*arrays) -> None:
     """Best-effort async device→host copy start: the later blocking
     fetch finds the data (mostly) on this side of the wire.  Backends
-    without async copies just pay the round-trip at fetch time."""
-    for arr in arrays:
+    without async copies just pay the round-trip at fetch time.  An
+    argument may be a pytree (an expert model's (tokens, counts))."""
+    import jax
+
+    for arr in jax.tree.leaves(arrays):
         try:
             arr.copy_to_host_async()
         except Exception:
@@ -4471,6 +4474,8 @@ class ContinuousDecodeLoop:
                             eng.chunk_tokens, use_sample,
                         ),
                     )
+                    # With experts ``toks`` is (tokens, counts): the
+                    # chunk's [L, E] routing counts ride the same fetch.
                     done = self._state.done
                     prefetch_to_host(toks, done)
                     entry = ((toks, done), dict(self.active), 1)
@@ -4529,11 +4534,31 @@ class ContinuousDecodeLoop:
             self._route_window(toks_np, hist_np, int(nc), snapshot, w)
         else:
             toks_np, done_np = fetched
+            if isinstance(toks_np, tuple) and not self.spec:
+                toks_np, counts = toks_np
+                self._note_moe(counts)
             self._route_chunk(toks_np, done_np, snapshot)
         if self.on_ok is not None:
             # One successfully fetched-and-routed dispatch closes the
             # replica's breaker fault streak (engine/fleet.py).
             self.on_ok()
+
+    def _note_moe(self, counts) -> None:
+        """One delivered paged chunk's per-expert assignment counts
+        ([L, E], a row a layer: models/llama.generate_chunk_paged) into
+        the routing metrics.  Imbalance and experts hit are a LAYER's
+        (a grouped matmul's load is one layer's), a mean over layers."""
+        per_layer = counts.sum(axis=1)
+        if int(per_layer.min()) <= 0:  # every row done: nothing was routed
+            return
+        name = self.engine.bundle.name
+        metrics.MOE_ASSIGNMENTS.labels(name).inc(int(per_layer.sum()))
+        metrics.MOE_EXPERTS_HIT.labels(name).set(
+            float((counts > 0).sum(axis=1).mean())
+        )
+        metrics.MOE_LOAD_IMBALANCE.labels(name).observe(
+            float((counts.max(axis=1) * counts.shape[1] / per_layer).mean())
+        )
 
     def _deliver_oldest(self) -> None:
         import jax

@@ -12,7 +12,10 @@ Architecture: pre-norm RMSNorm blocks, rotary position embeddings
 (HF rotate-half convention), grouped-query attention (num_kv_heads <
 num_heads; K/V cached at KV width and broadcast to query heads at
 attention time), SwiGLU MLP (down(silu(gate)·up)), no biases anywhere,
-untied LM head.
+untied LM head.  Two published variations of the block are config
+fields, off by default: a sparse expert FFN in place of the MLP
+(``num_experts``; ops/moe.py — OLMoE-1B-7B: 64 experts, top-8) and an
+RMSNorm on q and k before RoPE (``qk_norm``).
 
 Decode reuses ``gpt.GPTState`` verbatim — the per-row
 (write_idx/key_valid/pos/rng) state contract is what the continuous
@@ -98,6 +101,32 @@ class LlamaConfig:
     # Run Pallas kernels in interpret mode (CPU serving/CI; TPU runs
     # compiled Mosaic).  Registry plumbs PALLAS_INTERPRET.
     pallas_interpret: bool = False
+    # Sparse expert FFN (OLMoE-style; 0 = today's dense SwiGLU block):
+    # ``num_experts`` experts of width ``d_ff`` each, every token runs
+    # its ``experts_per_token`` most probable ones (router softmax over
+    # ALL experts in f32; weights renormalised over the chosen ones
+    # only under ``norm_topk_prob``).  ``_mlp_block`` is the one seam.
+    num_experts: int = 0
+    experts_per_token: int = 0
+    norm_topk_prob: bool = False
+    # Learned-scale RMSNorm over the WHOLE q and k projections, before
+    # the head split and RoPE (OLMoE / OLMo-2); ``_qkv_rope``.
+    qk_norm: bool = False
+    # Prompts start with the tokenizer's BOS (the Llama / Mistral
+    # convention; registry._build_llama applies it to the tokenizer).
+    # OLMoE's tokenizer has no BOS: a token every stream shares at
+    # position 0 is also what collapses a seeded random router onto a
+    # few experts (PERF.md section 6, PR 27).
+    add_bos: bool = True
+
+    def __post_init__(self):
+        if self.num_experts and not (
+            0 < self.experts_per_token <= self.num_experts
+        ):
+            raise ValueError(
+                f"experts_per_token={self.experts_per_token} must lie in "
+                f"1..num_experts={self.num_experts}"
+            )
 
     @property
     def head_dim(self) -> int:
@@ -112,32 +141,69 @@ class LlamaConfig:
 # init
 
 
-def init_params(key, cfg: LlamaConfig = LlamaConfig()) -> Params:
+def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
+    """Seeded random tree.  Every leaf is drawn in float32 and cast to
+    ``dtype`` AT ONCE (None keeps float32), so the boot peak is the
+    serving-dtype tree plus one float32 leaf — not a whole float32 tree
+    cast afterwards (6 bytes a parameter; OLMoE at 8 layers would not
+    boot).  Same keys, same draws: bit-identical to cast-after."""
+
+    def cast(tree):
+        if dtype is None:
+            return tree
+        return jax.tree.map(lambda a: a.astype(dtype), tree)
+
+    def lin(k, d_in, d_out):
+        return cast(dense_init(k, d_in, d_out, bias=False, std=0.02))
+
+    def norm_scale(k, n):
+        return cast({"scale": 1.0 + normal_init(k, (n,), std=0.25)})
+
+    def experts(k, shape):
+        # The key ``dense_init`` would draw a [d_in, d_out] kernel from.
+        return {"kernel": cast(normal_init(jax.random.split(k)[0], shape, std=0.02))}
+
     keys = jax.random.split(key, cfg.num_layers + 2)
     d, kv_dim = cfg.d_model, cfg.num_kv_heads * cfg.head_dim
+    e, w = cfg.num_experts, cfg.d_ff
     params: Params = {
-        "embed": {"embedding": normal_init(keys[0], (cfg.vocab_size, d), std=0.02)},
+        "embed": {"embedding": cast(normal_init(keys[0], (cfg.vocab_size, d), std=0.02))},
         "layers": [],
-        "final_ln": rmsnorm_init(d),
-        "lm_head": {"kernel": normal_init(keys[1], (d, cfg.vocab_size), std=0.02)},
+        "final_ln": cast(rmsnorm_init(d)),
+        "lm_head": {"kernel": cast(normal_init(keys[1], (d, cfg.vocab_size), std=0.02))},
     }
     for i in range(cfg.num_layers):
         k = jax.random.split(keys[2 + i], 7)
+        attn = {
+            "q": lin(k[0], d, d),
+            "k": lin(k[1], d, kv_dim),
+            "v": lin(k[2], d, kv_dim),
+            "o": lin(k[3], d, d),
+        }
+        if cfg.qk_norm:
+            # Learned scales have no reason to be 1: drawn about it, so a
+            # served path that drops the norm departs from one that has it.
+            attn["q_norm"] = norm_scale(jax.random.fold_in(keys[2 + i], 8), d)
+            attn["k_norm"] = norm_scale(jax.random.fold_in(keys[2 + i], 9), kv_dim)
+        if e:
+            mlp = {
+                "router": lin(jax.random.fold_in(keys[2 + i], 7), d, e),
+                "gate": experts(k[4], (e, d, w)),
+                "up": experts(k[5], (e, d, w)),
+                "down": experts(k[6], (e, w, d)),
+            }
+        else:
+            mlp = {
+                "gate": lin(k[4], d, w),
+                "up": lin(k[5], d, w),
+                "down": lin(k[6], w, d),
+            }
         params["layers"].append(
             {
-                "attn_ln": rmsnorm_init(d),
-                "attn": {
-                    "q": dense_init(k[0], d, d, bias=False, std=0.02),
-                    "k": dense_init(k[1], d, kv_dim, bias=False, std=0.02),
-                    "v": dense_init(k[2], d, kv_dim, bias=False, std=0.02),
-                    "o": dense_init(k[3], d, d, bias=False, std=0.02),
-                },
-                "mlp_ln": rmsnorm_init(d),
-                "mlp": {
-                    "gate": dense_init(k[4], d, cfg.d_ff, bias=False, std=0.02),
-                    "up": dense_init(k[5], d, cfg.d_ff, bias=False, std=0.02),
-                    "down": dense_init(k[6], cfg.d_ff, d, bias=False, std=0.02),
-                },
+                "attn_ln": cast(rmsnorm_init(d)),
+                "attn": attn,
+                "mlp_ln": cast(rmsnorm_init(d)),
+                "mlp": mlp,
             }
         )
     return params
@@ -184,12 +250,28 @@ def _split(x: jax.Array, n_heads: int) -> jax.Array:
     return x.reshape(b, s, n_heads, d // n_heads)
 
 
-def _mlp_block(cfg: "LlamaConfig", layer, x):
-    """Pre-norm SwiGLU block with its residual, under the ``mlp`` scope
-    (the device trace's name for it in every step kind)."""
+def _mlp_block(cfg: "LlamaConfig", layer, x, valid, tally=None):
+    """Pre-norm FFN block with its residual, under the ``mlp`` scope
+    (the device trace's name for it in every step kind): the dense
+    SwiGLU, or with ``cfg.num_experts`` the sparse expert FFN
+    (ops/moe.py).  ``valid`` [B, S] marks the rows that are neither
+    padding nor finished — only they get expert work; ``tally`` (a
+    list) receives the layer's [E] count of their assignments."""
     with jax.named_scope("mlp"):
         h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
         m = layer["mlp"]
+        if cfg.num_experts:
+            from ..ops.moe import expert_ffn
+
+            b, s, d = x.shape
+            out, counts = expert_ffn(
+                h.reshape(b * s, d), m, cfg.experts_per_token,
+                cfg.norm_topk_prob, jnp.broadcast_to(valid, (b, s)).reshape(-1),
+                interpret=cfg.pallas_interpret,
+            )
+            if tally is not None:
+                tally.append(counts)
+            return x + out.reshape(b, s, d)
         return x + dense(
             m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h)
         )
@@ -222,6 +304,25 @@ def _aproj(a, ad, name: str, li: int, x):
     """One attention projection (+ per-row LoRA delta when serving a
     ``__adapters__`` overlay; models/lora.py)."""
     return lora.apply(ad, name, li, x, dense(a[name], x))
+
+
+def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
+    """Rotated q [.., H, Dh] and k, and v [.., KVH, Dh] of one layer from
+    the residual stream x [B, S, D] — the ``qkv_rope`` scope of every
+    step kind.  Under ``cfg.qk_norm`` q and k pass a learned-scale
+    RMSNorm over the whole projection before the head split."""
+    a = layer["attn"]
+    with jax.named_scope("qkv_rope"):
+        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+        q = _aproj(a, ad, "q", li, h)
+        k = _aproj(a, ad, "k", li, h)
+        if cfg.qk_norm:
+            q = rmsnorm(a["q_norm"], q, eps=cfg.rms_eps)
+            k = rmsnorm(a["k_norm"], k, eps=cfg.rms_eps)
+        q = _apply_rope(_split(q, cfg.num_heads), cos, sin)
+        k = _apply_rope(_split(k, cfg.num_kv_heads), cos, sin)
+        v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+    return q, k, v
 
 
 # ---------------------------------------------------------------------------
@@ -301,11 +402,7 @@ def forward_hidden(
     kv = []
     for li, layer in enumerate(params["layers"]):
         a = layer["attn"]
-        with jax.named_scope("qkv_rope"):
-            h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
-            q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-            k = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-            v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        q, k, v = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         if collect_kv:
             kv.append((k, v))
         with jax.named_scope("attn"):
@@ -323,7 +420,7 @@ def forward_hidden(
             )
         with jax.named_scope("attn_out"):
             x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x)
+        x = _mlp_block(cfg, layer, x, attention_mask != 0)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     return (x, kv) if collect_kv else x
 
@@ -507,11 +604,7 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
         a = layer["attn"]
-        with jax.named_scope("qkv_rope"):
-            h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
-            q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-            k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-            v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
             ck = _write_kv(state.cache_k[li], rows, t, k1[:, 0], dtype)
             cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
@@ -521,7 +614,7 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
             ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
         with jax.named_scope("attn_out"):
             x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x)
+        x = _mlp_block(cfg, layer, x, ~state.done[:, None])
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
@@ -567,18 +660,15 @@ def multi_step(
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         a = layer["attn"]
-        q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-        k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-        v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         ck = _write_kv(state.cache_k[li], rows, pos_w, k1, dtype)
         cv = _write_kv(state.cache_v[li], rows, pos_w, v1, dtype)
         new_k.append(ck)
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x)
+        x = _mlp_block(cfg, layer, x, ~state.done[:, None])
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     logits = lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
     return new_k, new_v, logits  # [B, D, V]
@@ -702,7 +792,9 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
                        sample: bool = False):
     """One paged decode step: ``_decode_step`` with cache reads/writes
     resolved through the block table (RoPE, GQA, sampling and EOS
-    logic unchanged — physical layout is the only difference)."""
+    logic unchanged — physical layout is the only difference).  With
+    experts the step's second output is ``(next_tok, counts)``: the
+    [L, E] assignments of the rows still decoding, a row a layer."""
     from .gpt import PagedState
 
     entry = state.cache_k[0]
@@ -718,14 +810,10 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
     key_valid = state.key_valid.at[rows, t].set(1, mode="drop")
 
     ad = lora.adapter_tables(params)
-    new_k, new_v = [], []
+    new_k, new_v, moe_tally = [], [], []
     for li, layer in enumerate(params["layers"]):
         a = layer["attn"]
-        with jax.named_scope("qkv_rope"):
-            h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
-            q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-            k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-            v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
             ck = _paged_write_kv(state.cache_k[li], table, t, k1[:, 0], bs, dtype)
             cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
@@ -735,7 +823,7 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
             ctx = _paged_cache_attention(cfg, q, ck, cv, table, key_valid, bs)
         with jax.named_scope("attn_out"):
             x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x)
+        x = _mlp_block(cfg, layer, x, ~state.done[:, None], moe_tally)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
@@ -744,19 +832,26 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
             write_idx=t + 1, pos=state.pos + 1, last_token=next_tok,
             done=done, tokens=tokens, sample=sp,
         ),
-        next_tok,
+        (next_tok, jnp.stack(moe_tally)) if moe_tally else next_tok,
     )
 
 
 def generate_chunk_paged(params: Params, cfg: LlamaConfig, state, table,
                          n_steps: int, sample: bool = False):
-    """``n_steps`` paged decode steps in one compiled scan."""
+    """``n_steps`` paged decode steps in one compiled scan ->
+    (state, tokens [B, n_steps]); with experts the second output is
+    ``(tokens, counts)``, counts the [L, E] int32 assignments of the
+    chunk, a row a layer (each sums to steps x live rows x k; a grouped
+    matmul's load is one layer's) — it rides the tokens' fetch."""
 
     def step(s, _):
         return _paged_decode_step(params, cfg, s, table, sample)
 
-    state, toks = jax.lax.scan(step, state, None, length=n_steps)
-    return state, jnp.transpose(toks)
+    state, out = jax.lax.scan(step, state, None, length=n_steps)
+    if cfg.num_experts:
+        toks, counts = out
+        return state, (jnp.transpose(toks), jnp.sum(counts, axis=0))
+    return state, jnp.transpose(out)
 
 
 def generate_window_paged(params: Params, cfg: LlamaConfig, state, table,
@@ -767,10 +862,11 @@ def generate_window_paged(params: Params, cfg: LlamaConfig, state, table,
     the ledger reconciles at the window boundary)."""
     from .window import decode_window
 
-    return decode_window(
-        lambda s: generate_chunk_paged(params, cfg, s, table, n_steps, sample),
-        state, n_steps, max_chunks, cfg.pad_id,
-    )
+    def chunk(s):
+        s, out = generate_chunk_paged(params, cfg, s, table, n_steps, sample)
+        return s, (out[0] if cfg.num_experts else out)  # a window counts nothing
+
+    return decode_window(chunk, state, n_steps, max_chunks, cfg.pad_id)
 
 
 # ---------------------------------------------------------------------------
@@ -849,18 +945,15 @@ def prefill_chunk(
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         a = layer["attn"]
-        q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-        k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-        v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         ck = _write_kv(state.cache_k[li], rows, pos_w, k1, dtype)
         cv = _write_kv(state.cache_v[li], rows, pos_w, v1, dtype)
         new_k.append(ck)
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x)
+        x = _mlp_block(cfg, layer, x, chunk_mask != 0)
     key_valid = state.key_valid.at[rows, pos_w].set(
         chunk_mask.astype(jnp.int32), mode="drop"
     )
@@ -912,11 +1005,8 @@ def paged_prefill_chunk(
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         a = layer["attn"]
-        q = _apply_rope(_split(_aproj(a, ad, "q", li, h), cfg.num_heads), cos, sin)
-        k1 = _apply_rope(_split(_aproj(a, ad, "k", li, h), cfg.num_kv_heads), cos, sin)
-        v1 = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         ck = _paged_scatter_entry(state.cache_k[li], table_row, k1[0], bs, start, dtype)
         cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
         new_k.append(ck)
@@ -938,7 +1028,7 @@ def paged_prefill_chunk(
                 mask=mask,
             )
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x)
+        x = _mlp_block(cfg, layer, x, chunk_mask != 0)
     return state._replace(cache_k=new_k, cache_v=new_v)
 
 

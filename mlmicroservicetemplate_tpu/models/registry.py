@@ -103,6 +103,11 @@ class ModelBundle:
     # per dispatch only (DECODE_WINDOW>1 rejects at build).
     window_fn: Callable | None = None
     paged_window_fn: Callable | None = None
+    # Every position's next-token logits, jittable: (params, input_ids
+    # [B, S], attention_mask [B, S]) -> [B, S, V] — the non-generative
+    # forward of a decoder family, what a reference comparison holds to
+    # its own logits where served tokens cannot tell a rule apart.
+    logits_fn: Callable | None = None
 
     # -- host-side single-item pre/post ------------------------------------
     def preprocess(self, item: "RawItem") -> dict[str, np.ndarray]:
@@ -775,18 +780,21 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     # <s> (BOS) and must NOT end in </s> — a trailing EOS conditions the
     # model on end-of-document and derails generation.  SentencePiece
     # assets get the convention natively; other paths use the for_t5
-    # fallback (byte fallback/eos layouts, bos-less).
-    tok_path = svc_cfg.tokenizer_path
-    if tok_path and tok_path.endswith((".model", ".tsv", ".vocab")):
-        from .sentencepiece import load_sentencepiece
-
-        tokenizer = load_sentencepiece(tok_path, add_eos=False, add_bos=True)
-    else:
-        tokenizer = build_tokenizer(tok_path, for_t5=True)
+    # fallback (byte fallback/eos layouts, bos-less).  A family whose
+    # tokenizer has no BOS (OLMoE) says so in its config: ``add_bos``.
     overrides = {}
     env_cfg = _os.environ.get("LLAMA_CONFIG")
     if env_cfg:
         overrides = _json.loads(env_cfg)
+    tok_path = svc_cfg.tokenizer_path
+    if tok_path and tok_path.endswith((".model", ".tsv", ".vocab")):
+        from .sentencepiece import load_sentencepiece
+
+        tokenizer = load_sentencepiece(
+            tok_path, add_eos=False, add_bos=bool(overrides.get("add_bos", True))
+        )
+    else:
+        tokenizer = build_tokenizer(tok_path, for_t5=True)
     # Model-side EOS/pad must be the TOKENIZER's ids (gpt2 precedent):
     # a mismatch would leave streams decoding the full budget while the
     # detokenizer silently truncates at its own eos.
@@ -864,9 +872,38 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             f"eos_id={cfg.eos_id}/pad_id={cfg.pad_id} outside llama vocab "
             f"of {cfg.vocab_size}"
         )
-    params = _load_or_init("llama", svc_cfg.model_path,
-                           functools.partial(llama_mod.init_params, cfg=cfg),
-                           llama_state_to_pytree)
+    if cfg.num_experts or cfg.qk_norm:
+        # The expert leaves ([E, d, w] kernels, a router) and the q/k-norm
+        # (an RMS over the whole projection) are in no TP param spec and
+        # no int8 scheme: refuse what is not covered, never serve it wrong.
+        what = "an expert FFN" if cfg.num_experts else "the q/k-norm"
+        if int(getattr(svc_cfg, "tp", 0) or 0) > 1:
+            raise ValueError(
+                f"TP={svc_cfg.tp} is not supported for a llama config with "
+                f"{what} (parallel/tp.llama_param_spec shards neither the "
+                "stacked experts nor a norm over the whole q/k width); serve "
+                "it on one chip"
+            )
+        if getattr(svc_cfg, "quantize", None):
+            raise ValueError(
+                f"QUANTIZE={svc_cfg.quantize} is not supported for a llama "
+                f"config with {what} (models/quant.py has no per-expert "
+                "scale for [E, d, w] kernels)"
+            )
+    if cfg.num_experts and not _pallas_backend_ok(svc_cfg):
+        raise RuntimeError(
+            "the expert FFN's grouped matmul (ops/moe.py) is a Pallas TPU "
+            "kernel and the backend is not tpu: set PALLAS_INTERPRET=1 for "
+            "the CPU interpret path"
+        )
+    # Each leaf is drawn in float32 and cast at once (llama.init_params):
+    # the boot peak is the serving-dtype tree plus one leaf, not a whole
+    # float32 tree.  A loaded checkpoint is cast here as before.
+    params = _load_or_init(
+        "llama", svc_cfg.model_path,
+        functools.partial(llama_mod.init_params, cfg=cfg,
+                          dtype=policy.param_jnp),
+        llama_state_to_pytree)
     params = cast_pytree(params, policy.param_jnp)
     params = _maybe_quantize(params, svc_cfg)
 
@@ -950,6 +987,11 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             cfg.eos_id, cfg.pad_id, sample,
         )
 
+    def logits_fn(p, input_ids, attention_mask):
+        return llama_mod.lm_logits(
+            p, cfg, input_ids, attention_mask, dtype=policy.compute_jnp
+        )
+
     return ModelBundle(
         name="llama",
         kind=KIND_SEQ2SEQ,
@@ -973,6 +1015,7 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         paged_prefill_chunk_fn=paged_prefill_chunk_fn,
         window_fn=window_fn,
         paged_window_fn=paged_window_fn,
+        logits_fn=logits_fn,
     )
 
 

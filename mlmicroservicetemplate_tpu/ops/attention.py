@@ -221,9 +221,10 @@ def _decode_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     )
 
 
-# Per-program VMEM for the whole-slab decode kernel: K+V f32 copies
-# dominate (2·T·KVH·D·4B) on top of the raw blocks.  Guard the
-# auto-enable against configs whose slabs cannot fit, mirroring
+# Per-program VMEM for the whole-slab decode kernel: the raw K+V slabs,
+# double-buffered by the pipeline, dominate (2·2·T·KVH·D·2B); the f32
+# upcasts are one KV head's [T, D] at a time (``_decode_body``'s loop).
+# Guard the auto-enable against configs whose slabs cannot fit, mirroring
 # use_pallas_attention's single-block guard.  Default for the
 # DECODE_KERNEL_VMEM_BUDGET_MB env knob (``decode_vmem_budget_bytes``
 # validates; ServiceConfig mirrors).
@@ -253,15 +254,21 @@ def decode_vmem_budget_bytes() -> int:
 
 def decode_kernel_fits(t: int, kvh: int, d: int) -> bool:
     """True when the per-program slabs of ``decode_attention`` fit the
-    VMEM budget at cache width ``t`` (f32 K+V copies + raw payloads).
-    At the default Llama widths the v5e's compiler accepts every
-    enumerated variant wherever this says "fits" (checked up to its
-    boundary, T=2560, at the default 10 MB budget; T=1024 is pinned in
-    tests/test_chip_compile.py) — the variant kernels' lane-dense
-    ``[T, KVH*D]`` slabs hold what the arrays hold, no tile padding."""
-    f32_copies = 2 * t * kvh * d * 4
-    payloads = 2 * t * kvh * d * 4  # double-buffered bf16/int8 slabs + scales
-    return f32_copies + payloads <= decode_vmem_budget_bytes()
+    VMEM budget at cache width ``t``: the raw K and V payloads, each
+    double-buffered (8 bytes an element at bf16 — int8 payloads + scales
+    stay under it), plus ONE KV head's f32 K and V upcasts: the kernel
+    upcasts ``[T, D]`` a head inside its loop, never the whole slab.
+    The default kernel's ``[T, KVH, D]`` slab pads D to the 128 lanes,
+    so a head of 64 costs what one of 128 does.  The v5e's compiler
+    agrees: its own 16 MiB limit reads as these 8 bytes an element at 8
+    and 16 KV heads of 128 (T up to 1984 and 1024) and as 16 at 4 KV
+    heads of 64, and it accepts the kernel wherever this says "fits"
+    (the boundaries at the default 10 MB and at 12 MB are pinned in
+    tests/test_chip_compile.py)."""
+    lanes = -(-d // 128) * 128
+    payloads = 2 * t * kvh * lanes * 4  # K + V, double-buffered, <= 2 B each
+    f32_head = 2 * t * lanes * 4
+    return payloads + f32_head <= decode_vmem_budget_bytes()
 
 
 @functools.partial(

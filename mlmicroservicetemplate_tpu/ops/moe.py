@@ -1,0 +1,88 @@
+"""Sparse expert FFN: top-k routing, one grouped matmul over the
+assignments sorted by expert, weighted combine back into token order.
+
+One implementation for every step kind (a 64-row decode step has 512
+assignments, a 64 x 512 prefill rung 262 144): compute is the chosen
+experts' only, never a dense pass over all experts under a mask.
+
+    p = softmax_f32(h W_r)                     router, over ALL experts
+    (w_1..w_k, e_1..e_k) = top_k(p)            weights as they are, or
+                                               renormalised (norm_topk)
+    out = sum_j w_j * down_{e_j}(silu(gate_{e_j} h) * up_{e_j} h)
+
+Rows that are padding or finished (``valid`` false) are sorted past the
+last group: the grouped matmul gives them no expert, their (undefined)
+output rows are zeroed before the combine, and they are not counted.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+
+def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
+                   interpret: bool = False) -> jax.Array:
+    """``lhs`` [M, K] rows sorted by group, ``rhs`` [G, K, N], ``group_sizes``
+    [G] int32 -> [M, N] in lhs's dtype: row i of group g is multiplied by
+    ``rhs[g]`` (float32 accumulation).  Rows past ``sum(group_sizes)``
+    belong to no group and come back UNDEFINED: the caller masks them.
+
+    The Pallas grouped matmul ``megablox.gmm``, kept over
+    ``jax.lax.ragged_dot`` by a chip measurement at the two shapes the
+    benchmark's expert cell runs (PERF.md section 6, PR 27: 1.21 against
+    2.61 ms for a decode step's 512 assignments, 7.48 against 9.64 ms for
+    a 64 x 128 wave's 65 536; d 2048, width 1024, 64 experts, one layer's
+    three matmuls).  Row tile 128 while the mean group is small (each
+    visited tile streams one expert's weights: HBM-bound), 512 once it
+    holds 512 rows (MXU-bound); the kernel wants M a multiple of the tile."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+    m, k = lhs.shape
+    g, _, n = rhs.shape
+    tm = 512 if m >= 512 * g else 128
+    pad = -m % tm
+    if pad:
+        lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
+    out = gmm(
+        lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
+        tiling=(tm, min(k, 1024), min(n, 1024)), interpret=interpret,
+    )
+    return out[:m] if pad else out
+
+
+def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
+               interpret: bool = False):
+    """h [T, D] normed tokens, ``mlp`` the layer's expert leaves (router
+    [D, E]; gate, up [E, D, W]; down [E, W, D]), valid [T] bool ->
+    (out [T, D] in h's dtype, counts [E] int32 of valid assignments).
+    ``interpret`` runs the kernel in interpret mode (CPU tests)."""
+    t, d = h.shape
+    gate, up, down = (mlp[n]["kernel"] for n in ("gate", "up", "down"))
+    n_exp = gate.shape[0]
+    with jax.named_scope("moe_route"):
+        logits = h.astype(jnp.float32) @ mlp["router"]["kernel"].astype(jnp.float32)
+        w, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)  # [T, k]
+        if norm_topk:
+            w = w / jnp.sum(w, axis=-1, keepdims=True)
+        # An invalid row's assignments take group id E: past every real
+        # group in the sort, in no group's count.
+        e = jnp.where(valid[:, None], e, n_exp).reshape(-1)
+        order = jnp.argsort(e)  # stable: assignment i of token i // k
+        counts = jnp.sum(
+            e[:, None] == jnp.arange(n_exp, dtype=e.dtype)[None, :], axis=0,
+            dtype=jnp.int32,
+        )
+        xs = jnp.take(h, order // k, axis=0)  # [T*k, D], sorted by expert
+    with jax.named_scope("moe_experts"):
+        mm = functools.partial(grouped_matmul, interpret=interpret)
+        act = jax.nn.silu(mm(xs, gate, counts)) * mm(xs, up, counts)
+        ys = mm(act, down, counts)  # [T*k, D]
+    with jax.named_scope("moe_combine"):
+        in_group = jnp.arange(t * k) < jnp.sum(counts)
+        ys = jnp.where(in_group[:, None], ys, 0)
+        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(t, k, d)
+        out = jnp.sum(back.astype(jnp.float32) * w[:, :, None], axis=1)
+    return out.astype(h.dtype), counts

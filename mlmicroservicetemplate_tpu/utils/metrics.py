@@ -140,6 +140,29 @@ PREFILL_WAVE_ROWS = Histogram(
     "smallest rung that holds it: 4 or the slot count)",
     ["model"], buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
 )
+MOE_LOAD_IMBALANCE = Histogram(
+    "moe_load_imbalance",
+    "Expert FFN: the busiest expert's share of a LAYER's assignments "
+    "in one delivered paged decode chunk over the mean share (1.0 = "
+    "even), summed over the chunk's steps, a mean over the layers",
+    ["model"],
+    buckets=(1.0, 1.1, 1.2, 1.35, 1.5, 1.75, 2.0, 2.5, 3.0, 4.0, 6.0, 8.0,
+             16.0, 32.0, 64.0),
+)
+MOE_ASSIGNMENTS = Counter(
+    "moe_assignments_total",
+    "Expert FFN: (token, expert) assignments computed by delivered "
+    "paged decode chunks (rows still decoding x layers x "
+    "experts_per_token x steps)",
+    ["model"],
+)
+MOE_EXPERTS_HIT = Gauge(
+    "moe_experts_hit",
+    "Expert FFN: distinct experts of a layer with at least one "
+    "assignment in the last delivered paged decode chunk, a mean over "
+    "the layers",
+    ["model"],
+)
 DECODE_STEPS = Histogram(
     "seq2seq_decode_steps",
     "Decode steps executed per non-streaming seq2seq dispatch "

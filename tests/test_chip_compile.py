@@ -242,3 +242,88 @@ def test_every_enumerated_variant_compiles(chip, kind, quant):
         except Exception as e:  # collect them all: one run, whole picture
             refused[var.key()] = str(e).splitlines()[0][:200]
     assert not refused, refused
+
+
+# -- OLMoE-1B-7B shapes (cellbench/configs/olmoe-1b-7b-d8.json) -----------
+# 16 heads = 16 KV heads of 128 (the paged kernel's first MHA run:
+# n_rep 1), 64 streams, a 512-token bucket + 192 decode tokens at BS 16;
+# 64 experts of width 1024 over d 2048, top-8.
+
+
+@pytest.mark.parametrize("variant", ["", "b4-hb"])
+def test_paged_decode_kernel_compiles_at_16_kv_heads(chip, variant):
+    from mlmicroservicetemplate_tpu.ops.paged_attention import (
+        paged_decode_attention as kernel,
+    )
+
+    b, h, t = 64, 16, 44
+    q = chip((b, h, 128), jnp.bfloat16)
+    pool = chip((b * t, 16, h, 128), jnp.bfloat16)
+    text = _compiled_text(
+        chip, ("paged-mha", variant),
+        lambda q, k, v, tb, m: kernel(q, k, v, tb, m, 16, variant=variant),
+        q, pool, pool, chip((b, t), jnp.int32), chip((b, t * 16), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("assignments", [512, 65536])
+def test_grouped_matmul_compiles(chip, assignments):
+    """A decode step's 512 assignments and a 64 x 128 wave's 65 536
+    through both expert matmul shapes (gate/up and down)."""
+    from mlmicroservicetemplate_tpu.ops.moe import grouped_matmul
+
+    e, d, w = 64, 2048, 1024
+    sizes = chip((e,), jnp.int32)
+    for k, n in ((d, w), (w, d)):
+        text = _compiled_text(
+            chip, ("gmm", assignments, k), grouped_matmul,
+            chip((assignments, k), jnp.bfloat16), chip((e, k, n), jnp.bfloat16),
+            sizes,
+        )
+        assert "tpu_custom_call" in text
+
+
+def _largest_fit(kvh: int, d: int) -> int:
+    """The widest cache (a multiple of 64) the boot's gate lets the
+    whole-slab kernel take at these heads, under the budget in force."""
+    from mlmicroservicetemplate_tpu.ops.attention import decode_kernel_fits
+
+    t = 64
+    while decode_kernel_fits(t + 64, kvh, d):
+        t += 64
+    return t
+
+
+def test_slab_gate_counts_what_the_kernel_holds(monkeypatch):
+    """K and V at bf16, double-buffered, and ONE head's f32 upcasts: 16
+    KV heads of 128 at a 256 bucket + 192 tokens (OLMoE's start) fit
+    Mistral's 12 MB, as 8 KV heads at 704 do; the count stays a limit."""
+    from mlmicroservicetemplate_tpu.ops.attention import decode_kernel_fits
+
+    monkeypatch.setenv("DECODE_KERNEL_VMEM_BUDGET_MB", "12")
+    assert decode_kernel_fits(448, 16, 128)  # 7.34 MB + 0.46 MB
+    assert decode_kernel_fits(704, 16, 128)  # 11.53 MB + 0.72 MB of 12.58
+    assert decode_kernel_fits(704, 8, 128)
+    assert not decode_kernel_fits(768, 16, 128)
+    assert _largest_fit(8, 128) == 1344  # 12.58e6 / (8*1024*8 + 1024)
+
+
+@pytest.mark.parametrize("kvh,n_rep,d,budget_mb,variant", [
+    (16, 1, 128, 12, ""), (16, 1, 128, 12, "b1-hb"),  # OLMoE, Mistral's budget
+    (8, 4, 128, 12, ""),  # Mistral-7B
+    (KVH, H // KVH, D, 10, ""),  # the defaults: a head of 64 pads to the lanes
+])
+def test_compiler_takes_the_slab_kernel_wherever_the_gate_says_fits(
+        chip, monkeypatch, kvh, n_rep, d, budget_mb, variant):
+    """At the gate's own boundary for each head layout, 64 rows."""
+    monkeypatch.setenv("DECODE_KERNEL_VMEM_BUDGET_MB", str(budget_mb))
+    t = _largest_fit(kvh, d)
+    kv = chip((64, t, kvh, d), jnp.bfloat16)
+    text = _compiled_text(
+        chip, ("slab-gate", kvh, d, t, variant),
+        lambda q, k, v, m: decode_attention(q, k, v, m, variant=variant),
+        chip((64, kvh * n_rep, d), jnp.bfloat16), kv, kv,
+        chip((64, t), jnp.int32),
+    )
+    assert "tpu_custom_call" in text

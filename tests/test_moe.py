@@ -1,0 +1,541 @@
+"""The expert FFN (OLMoE-style: router softmax -> top-k -> grouped
+matmul over assignments sorted by expert -> weighted combine) and the
+q/k-norm through ``models/llama.py``, held to the benchmark's plain
+reference (``cellbench/references/olmoe.py``) at a tiny size on the CPU
+in float32.
+
+TOL: model and reference both compute in float32 and differ only in the
+order of sums (a grouped matmul against a masked loop over experts):
+1e-6 on logits of size ~0.6, measured 2e-7.  Every rule a served path
+could get wrong moves a logit by 5e-3 or more at this size (top-(k-1)
+5.9e-3, renormalised top-k 2.3e-2, no q/k-norm and bf16 arithmetic more:
+each shown failing below), so 1e-4 separates them with room both ways.
+"""
+
+import dataclasses
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from cellbench import spec as bench_spec
+from mlmicroservicetemplate_tpu.models import llama as llama_mod
+from mlmicroservicetemplate_tpu.ops import moe
+
+TOL = 1e-4
+TINY = dict(
+    vocab_size=128, d_model=64, num_heads=4, num_kv_heads=4, num_layers=2,
+    d_ff=32, max_position=128, num_experts=8, experts_per_token=2,
+    qk_norm=True, eos_id=1, pad_id=0, pallas_interpret=True,
+)
+HP = {"heads": 4, "kv_heads": 4, "head_dim": 16, "theta": 10000.0,
+      "eps": 1e-5, "top_k": 2, "norm_topk": False}
+
+
+@pytest.fixture(scope="module")
+def ref():
+    return bench_spec.load_module(
+        bench_spec.HERE + "/references/olmoe.py", "cellbench_reference_olmoe")
+
+
+@pytest.fixture(scope="module")
+def cfg():
+    return llama_mod.LlamaConfig(**TINY)
+
+
+@pytest.fixture(scope="module")
+def params(cfg):
+    # The init draws the q/k-norm scales about 1 (not all ones), so a
+    # dropped q/k-norm shows: test_qk_norm_scales_are_drawn_not_ones.
+    return llama_mod.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _prompts(lens, seed=0, vocab=120):
+    rng = np.random.default_rng(seed)
+    ids = np.zeros((len(lens), max(lens)), np.int32)
+    mask = np.zeros_like(ids)
+    for i, n in enumerate(lens):
+        ids[i, :n] = rng.integers(3, vocab, n)
+        mask[i, :n] = 1
+    return jnp.asarray(ids), jnp.asarray(mask)
+
+
+def _close(got, want):
+    return float(jnp.max(jnp.abs(jnp.asarray(got) - jnp.asarray(want))))
+
+
+# ---------------------------------------------------------------------------
+# the whole model against the reference
+
+
+def test_full_forward_matches_the_reference(ref, cfg, params):
+    ids, mask = _prompts([12, 12])
+    got = llama_mod.lm_logits(params, cfg, ids, mask)
+    assert _close(got, ref.logits(params, HP, ids)) < TOL
+
+
+@pytest.mark.parametrize("wrong", [
+    {"top_k": 1}, {"norm_topk": True}, "no_qk_norm", "bf16"])
+def test_the_tolerance_fails_a_changed_rule(ref, cfg, params, wrong):
+    """Top-(k-1), a renormalised top-k, a dropped q/k-norm and bf16
+    arithmetic each land outside TOL."""
+    ids, mask = _prompts([12, 12])
+    want = ref.logits(params, HP, ids)
+    if wrong == "no_qk_norm":
+        got = llama_mod.lm_logits(
+            params, dataclasses.replace(cfg, qk_norm=False), ids, mask)
+    elif wrong == "bf16":
+        got = llama_mod.lm_logits(params, cfg, ids, mask, dtype=jnp.bfloat16)
+    else:
+        got = ref.logits(params, {**HP, **wrong}, ids)
+    assert _close(got, want) > 10 * TOL
+
+
+class _Logits:
+    """Every ``lm_head_logits`` a step makes, kept (steps run eagerly)."""
+
+    def __init__(self, monkeypatch):
+        self.seen = []
+        real = llama_mod.lm_head_logits
+
+        def keep(*a, **kw):
+            self.seen.append(real(*a, **kw))
+            return self.seen[-1]
+
+        monkeypatch.setattr(llama_mod, "lm_head_logits", keep)
+
+
+def _teacher_forced(ref, params, ids, lens, steps_tokens):
+    """Reference logits of each decode position: row b's step j sees its
+    prompt plus the j tokens decoded before."""
+    out = []
+    for b, n in enumerate(lens):
+        seq = list(np.asarray(ids[b, :n])) + [int(t[b]) for t in steps_tokens]
+        full = ref.logits(params, HP, jnp.asarray([seq], jnp.int32))[0]
+        out.append(full[n - 1: n - 1 + len(steps_tokens)])
+    return jnp.stack(out)  # [B, steps, V]
+
+
+def test_prefill_then_decode_through_the_contiguous_cache(
+        ref, cfg, params, monkeypatch):
+    lens, steps = [5, 9, 7], 4
+    ids, mask = _prompts(lens, seed=1)
+    state = llama_mod.init_decode_state(params, cfg, ids, mask, steps)
+    seen, toks = _Logits(monkeypatch), []
+    for _ in range(steps):
+        state, tok = llama_mod._decode_step(params, cfg, state)
+        toks.append(np.asarray(tok))
+    want = _teacher_forced(ref, params, ids, lens, toks)
+    got = jnp.stack(seen.seen, axis=1)
+    assert _close(got, want) < TOL
+
+
+def test_paged_prefill_chunks_then_paged_decode(ref, cfg, params, monkeypatch):
+    """Prompt windows written straight into pool blocks, then decode
+    through the block table and the paged kernel (interpret mode)."""
+    from mlmicroservicetemplate_tpu.models.gpt import PagedState
+    from mlmicroservicetemplate_tpu.models.sampling import greedy_params
+
+    kcfg = dataclasses.replace(cfg, pallas_decode=True)
+    n, bs, window, steps = 11, 4, 8, 4
+    ids, _ = _prompts([n], seed=2)
+    nb = 6
+    table = jnp.asarray([[4, 1, 5, 0, 2, 3]], jnp.int32)
+    shape = (nb, bs, cfg.num_kv_heads, cfg.head_dim)
+    state = PagedState(
+        cache_k=[jnp.zeros(shape) for _ in range(cfg.num_layers)],
+        cache_v=[jnp.zeros(shape) for _ in range(cfg.num_layers)],
+        key_valid=jnp.zeros((1, nb * bs), jnp.int32),
+        write_idx=jnp.zeros((1,), jnp.int32), pos=jnp.zeros((1,), jnp.int32),
+        last_token=jnp.zeros((1,), jnp.int32), done=jnp.zeros((1,), bool),
+        tokens=jnp.zeros((1, steps), jnp.int32), sample=greedy_params(1),
+    )
+    for start in range(0, 16, window):
+        w_ids = jnp.zeros((1, window), jnp.int32).at[0, : max(n - start, 0)].set(
+            ids[0, start:start + window])
+        w_mask = (jnp.arange(window)[None] + start < n).astype(jnp.int32)
+        state = llama_mod.paged_prefill_chunk(
+            params, kcfg, state, table[0], w_ids, w_mask, start)
+    state = state._replace(
+        key_valid=state.key_valid.at[0, : n - 1].set(1),
+        write_idx=jnp.asarray([n - 1]), last_token=ids[:, n - 1])
+    seen, toks = _Logits(monkeypatch), []
+    for _ in range(steps):
+        state, (tok, counts) = llama_mod._paged_decode_step(
+            params, kcfg, state, table)
+        toks.append(np.asarray(tok))
+        np.testing.assert_array_equal(  # [L, E]: one live row, k a layer
+            np.asarray(counts.sum(axis=1)), [cfg.experts_per_token] * cfg.num_layers)
+    want = _teacher_forced(ref, params, ids, [n], toks)
+    assert _close(jnp.stack(seen.seen, axis=1), want) < TOL
+
+
+def test_wave_rungs_give_the_same_logits(cfg, params):
+    """A prompt run alone, in a rung of 4 and in one of 8 rows (the rest
+    padding) reads the same logits, and padding rows are finite."""
+    ids, mask = _prompts([10], seed=3)
+    alone = llama_mod.lm_logits(params, cfg, ids, mask)
+    for rows in (4, 8):
+        ids_r = jnp.zeros((rows, 10), jnp.int32).at[0].set(ids[0])
+        mask_r = jnp.zeros((rows, 10), jnp.int32).at[0].set(1)
+        got = llama_mod.lm_logits(params, cfg, ids_r, mask_r)
+        assert bool(jnp.isfinite(got).all())
+        assert _close(got[0], alone[0]) < 1e-5
+
+
+# ---------------------------------------------------------------------------
+# the expert block alone
+
+
+def _mlp(key, d=16, w=8, e=6):
+    ks = jax.random.split(key, 4)
+    return {
+        "router": {"kernel": jax.random.normal(ks[0], (d, e))},
+        "gate": {"kernel": jax.random.normal(ks[1], (e, d, w)) * 0.3},
+        "up": {"kernel": jax.random.normal(ks[2], (e, d, w)) * 0.3},
+        "down": {"kernel": jax.random.normal(ks[3], (e, w, d)) * 0.3},
+    }
+
+
+def _dense_masked(h, mlp, k, norm_topk):
+    """Every expert on every token, then a mask: the 8x-FLOPs form the
+    served path must not be, good for checking it."""
+    p = jax.nn.softmax(h @ mlp["router"]["kernel"], axis=-1)
+    w, e = jax.lax.top_k(p, k)
+    if norm_topk:
+        w = w / w.sum(-1, keepdims=True)
+    gate, up, down = (mlp[n]["kernel"] for n in ("gate", "up", "down"))
+    y = jnp.einsum(
+        "tew,ewd->ted",
+        jax.nn.silu(jnp.einsum("td,edw->tew", h, gate))
+        * jnp.einsum("td,edw->tew", h, up), down)
+    weight = jnp.sum(
+        jnp.where(e[:, :, None] == jnp.arange(gate.shape[0]), w[:, :, None], 0.0),
+        axis=1)  # [T, E]
+    return jnp.einsum("te,ted->td", weight, y)
+
+
+@pytest.mark.parametrize("norm_topk", [False, True])
+def test_grouped_path_equals_a_dense_masked_einsum(norm_topk):
+    mlp = _mlp(jax.random.PRNGKey(1))
+    h = jax.random.normal(jax.random.PRNGKey(2), (20, 16))
+    out, counts = moe.expert_ffn(h, mlp, 3, norm_topk, jnp.ones((20,), bool), interpret=True)
+    assert _close(out, _dense_masked(h, mlp, 3, norm_topk)) < 1e-5
+    assert int(counts.sum()) == 20 * 3
+
+
+def test_every_token_to_one_expert_and_experts_with_no_token():
+    mlp = _mlp(jax.random.PRNGKey(3))
+    # A router that sends everything to expert 4 first, then 1.
+    mlp["router"]["kernel"] = jnp.zeros((16, 6)).at[:, 4].set(1.0).at[:, 1].set(0.5)
+    h = jnp.abs(jax.random.normal(jax.random.PRNGKey(4), (10, 16)))
+    out, counts = moe.expert_ffn(h, mlp, 2, False, jnp.ones((10,), bool), interpret=True)
+    np.testing.assert_array_equal(np.asarray(counts), [0, 10, 0, 0, 10, 0])
+    assert _close(out, _dense_masked(h, mlp, 2, False)) < 1e-5
+    out1, counts1 = moe.expert_ffn(h, mlp, 1, False, jnp.ones((10,), bool), interpret=True)
+    np.testing.assert_array_equal(np.asarray(counts1), [0, 0, 0, 0, 10, 0])
+    assert bool(jnp.isfinite(out1).all())
+
+
+def test_invalid_rows_are_neither_counted_nor_nan():
+    mlp = _mlp(jax.random.PRNGKey(5))
+    h = jax.random.normal(jax.random.PRNGKey(6), (12, 16))
+    valid = jnp.arange(12) % 3 != 0  # 8 of 12
+    out, counts = moe.expert_ffn(h, mlp, 2, False, valid, interpret=True)
+    assert int(counts.sum()) == 8 * 2
+    assert bool(jnp.isfinite(out).all())
+    np.testing.assert_array_equal(np.asarray(out[~valid]), 0.0)
+    want = _dense_masked(h, mlp, 2, False)
+    assert _close(out[valid], want[valid]) < 1e-5
+    # No valid row at all: nothing routed, nothing NaN.
+    out0, counts0 = moe.expert_ffn(h, mlp, 2, False, jnp.zeros((12,), bool), interpret=True)
+    assert int(counts0.sum()) == 0 and not bool(jnp.any(out0))
+
+
+def test_chunk_counters_add_up_and_skip_done_rows(cfg, params):
+    """The paged chunk's counts, a row a layer: each layer's = live rows
+    x k x steps; a row that is ``done`` decodes pad tokens and is not
+    counted."""
+    lens, steps, bs = [6, 9, 4], 4, 4
+    ids, mask = _prompts(lens, seed=7)
+    nb_row = -(-(ids.shape[1] + steps) // bs)
+    table = jnp.arange(3 * nb_row, dtype=jnp.int32).reshape(3, nb_row)
+    state = llama_mod.init_paged_state(
+        params, cfg, ids, mask, steps, table, 3 * nb_row, bs)
+    st, (toks, counts) = llama_mod.generate_chunk_paged(
+        params, cfg, state, table, steps)
+    assert toks.shape == (3, steps)
+    assert counts.shape == (cfg.num_layers, cfg.num_experts)
+    assert counts.dtype == jnp.int32
+    per_row = cfg.experts_per_token * steps
+    np.testing.assert_array_equal(
+        np.asarray(counts.sum(axis=1)), [3 * per_row] * cfg.num_layers)
+    st2, (toks2, counts2) = llama_mod.generate_chunk_paged(
+        params, cfg, state._replace(done=jnp.asarray([False, True, False])),
+        table, steps)
+    np.testing.assert_array_equal(
+        np.asarray(counts2.sum(axis=1)), [2 * per_row] * cfg.num_layers)
+    np.testing.assert_array_equal(np.asarray(toks2[1]), cfg.pad_id)
+    np.testing.assert_array_equal(np.asarray(toks2[0]), np.asarray(toks[0]))
+    # A fused window keeps the (state, tokens) contract and counts nothing.
+    out = llama_mod.generate_window_paged(params, cfg, state, table, 2, 2)
+    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(toks))
+
+
+def test_dense_paged_chunk_returns_nothing_new():
+    from tests.helpers import TINY_LLAMA
+
+    dcfg = llama_mod.LlamaConfig(**TINY_LLAMA)
+    dparams = llama_mod.init_params(jax.random.PRNGKey(0), dcfg)
+    ids, mask = _prompts([5], seed=8)
+    table = jnp.arange(4, dtype=jnp.int32)[None]
+    state = llama_mod.init_paged_state(dparams, dcfg, ids, mask, 4, table, 4, 4)
+    _, toks = llama_mod.generate_chunk_paged(dparams, dcfg, state, table, 4)
+    assert isinstance(toks, jax.Array) and toks.shape == (1, 4)
+
+
+def test_loop_delivers_the_counts_into_the_metrics():
+    """The continuous loop's chunk fetch carries the counts home:
+    ``moe_assignments_total`` grows by what the delivered chunks routed,
+    ``moe_experts_hit`` and ``moe_load_imbalance`` are observed."""
+    import asyncio
+
+    from mlmicroservicetemplate_tpu.engine import InferenceEngine
+    from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
+    from mlmicroservicetemplate_tpu.parallel import make_mesh
+    from mlmicroservicetemplate_tpu.parallel.mesh import ReplicaSet
+    from mlmicroservicetemplate_tpu.utils import metrics
+    from mlmicroservicetemplate_tpu.utils.config import ServiceConfig
+    from tests.helpers import tiny_llama_bundle
+
+    over = {k: v for k, v in TINY.items() if k not in ("eos_id", "pad_id")}
+    bundle = tiny_llama_bundle(**{**over, "vocab_size": 300})
+    svc = ServiceConfig(
+        device="cpu", warmup=False, batch_buckets=(1, 2, 4),
+        seq_buckets=(16, 32), max_decode_len=8, stream_chunk_tokens=4,
+        max_streams=4, paged_kv=True, kv_block_size=8)
+    eng = InferenceEngine(bundle, svc, ReplicaSet(make_mesh(1)))
+    cdl = ContinuousDecodeLoop(eng, svc)
+
+    def total():
+        return metrics.MOE_ASSIGNMENTS.labels("llama")._value.get()
+
+    before = total()
+
+    async def one(n):
+        ids = np.random.default_rng(n).integers(5, 250, n).astype(np.int32)
+        feats = {"input_ids": ids, "length": np.int32(n)}
+        return [c async for c in cdl.submit_stream(feats)]
+
+    async def body():
+        return await asyncio.gather(one(6), one(14))
+
+    try:
+        chunks = asyncio.run(body())
+    finally:
+        cdl.stop()
+    assert all(chunks)
+    grown = total() - before
+    per_step = bundle.cfg.num_layers * bundle.cfg.experts_per_token
+    assert grown > 0 and grown % per_step == 0
+    hit = metrics.MOE_EXPERTS_HIT.labels("llama")._value.get()
+    assert bundle.cfg.experts_per_token <= hit <= bundle.cfg.num_experts
+    body, _ = metrics.render()
+    assert b"moe_load_imbalance_count" in body
+
+
+def test_imbalance_and_experts_hit_are_a_layers_not_the_sum_over_layers():
+    """Two layers, each with ALL its assignments on one expert, but not
+    the same one: summed over layers that reads 2x the mean of 4 experts;
+    a grouped matmul sees 4x, twice."""
+    from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
+    from mlmicroservicetemplate_tpu.utils import metrics
+
+    loop = ContinuousDecodeLoop.__new__(ContinuousDecodeLoop)
+    loop.engine = type("E", (), {"bundle": type("B", (), {"name": "moe-unit"})})()
+    hist = metrics.MOE_LOAD_IMBALANCE.labels("moe-unit")
+    loop._note_moe(np.asarray([[8, 0, 0, 0], [0, 0, 8, 0]], np.int32))
+    assert hist._sum.get() == pytest.approx(4.0)
+    assert metrics.MOE_EXPERTS_HIT.labels("moe-unit")._value.get() == 1.0
+    assert metrics.MOE_ASSIGNMENTS.labels("moe-unit")._value.get() == 16
+    loop._note_moe(np.asarray([[2, 2, 2, 2], [4, 4, 0, 0]], np.int32))
+    assert hist._sum.get() == pytest.approx(4.0 + (1.0 + 2.0) / 2)
+    assert metrics.MOE_EXPERTS_HIT.labels("moe-unit")._value.get() == 3.0
+    loop._note_moe(np.zeros((2, 4), np.int32))  # every row done: not observed
+    assert metrics.MOE_ASSIGNMENTS.labels("moe-unit")._value.get() == 32
+
+
+# ---------------------------------------------------------------------------
+# init, registry, converter
+
+
+@pytest.mark.parametrize("experts", [False, True])
+def test_per_leaf_cast_init_is_bit_identical_to_cast_after(experts):
+    from mlmicroservicetemplate_tpu.models.common import cast_pytree
+    from tests.helpers import TINY_LLAMA
+
+    c = llama_mod.LlamaConfig(**(TINY if experts else TINY_LLAMA))
+    key = jax.random.PRNGKey(0)
+    after = cast_pytree(llama_mod.init_params(key, c), jnp.bfloat16)
+    at_once = llama_mod.init_params(key, c, dtype=jnp.bfloat16)
+    assert jax.tree.structure(after) == jax.tree.structure(at_once)
+    for a, b in zip(jax.tree.leaves(after), jax.tree.leaves(at_once)):
+        assert a.dtype == b.dtype == jnp.bfloat16
+        np.testing.assert_array_equal(
+            np.asarray(a.astype(jnp.float32)), np.asarray(b.astype(jnp.float32)))
+
+
+def test_dense_init_draws_are_what_they_were():
+    """The expert leaves took no key from the dense tree: a dense
+    config's float32 draws are the ones every run since PR 24 served."""
+    from tests.helpers import TINY_LLAMA
+
+    c = llama_mod.LlamaConfig(**TINY_LLAMA)
+    p = llama_mod.init_params(jax.random.PRNGKey(0), c)
+    keys = jax.random.split(jax.random.PRNGKey(0), c.num_layers + 2)
+    k = jax.random.split(keys[2], 7)
+    want = jax.random.normal(jax.random.split(k[4])[0], (32, 64)) * 0.02
+    np.testing.assert_array_equal(
+        np.asarray(p["layers"][0]["mlp"]["gate"]["kernel"]), np.asarray(want))
+
+
+def _svc(monkeypatch, **kw):
+    import json
+
+    from mlmicroservicetemplate_tpu.utils.config import ServiceConfig
+
+    over = {k: v for k, v in TINY.items()
+            if k not in ("eos_id", "pad_id", "pallas_interpret")}
+    over["vocab_size"] = 300
+    monkeypatch.setenv("LLAMA_CONFIG", json.dumps(over))
+    kw.setdefault("pallas_interpret", True)
+    return ServiceConfig(device="cpu", model_name="llama", warmup=False,
+                         seq_buckets=(16, 32), max_decode_len=8, **kw)
+
+
+def test_registry_builds_the_expert_config(monkeypatch):
+    from mlmicroservicetemplate_tpu.models.registry import build_model
+
+    bundle = build_model(_svc(monkeypatch))
+    assert bundle.cfg.num_experts == 8 and bundle.cfg.qk_norm
+    mlp = bundle.params["layers"][0]["mlp"]
+    assert mlp["gate"]["kernel"].shape == (8, 64, 32)
+    assert mlp["router"]["kernel"].shape == (64, 8)
+
+
+def test_the_bundle_hands_out_the_programs_own_logits(ref, monkeypatch):
+    """``bundle.logits_fn``: every position's logits through the prefill
+    forward — what the benchmark's check holds to its reference where a
+    served token cannot tell a rule apart (top-(k-1))."""
+    from mlmicroservicetemplate_tpu.models.registry import build_model
+
+    bundle = build_model(_svc(monkeypatch))
+    ids, mask = _prompts([9, 14], seed=11)
+    got = jax.jit(bundle.logits_fn)(bundle.params, ids, mask)
+    want = ref.logits(bundle.params, HP, np.asarray(ids))
+    assert got.shape == (2, 14, 300)
+    assert ref.logit_rms_error(want, got, [9, 14]) < 1e-5
+    seven = dataclasses.replace(bundle.cfg, experts_per_token=1)
+    wrong = llama_mod.lm_logits(bundle.params, seven, ids, mask)
+    assert ref.logit_rms_error(want, wrong, [9, 14]) > 5e-4
+
+
+def test_qk_norm_scales_are_drawn_not_ones(cfg, params):
+    """A scale of all ones would make a dropped norm nearly invisible
+    (q = y W_q has an rms near 1 already); every layer draws its own."""
+    scales = [np.asarray(layer["attn"][n]["scale"], np.float32)
+              for layer in params["layers"] for n in ("q_norm", "k_norm")]
+    for s in scales:
+        assert 0.15 < s.std() < 0.35 and abs(s.mean() - 1.0) < 0.1
+    assert not np.array_equal(scales[0], scales[1])
+    assert not np.array_equal(scales[0], scales[2])
+    np.testing.assert_array_equal(
+        np.asarray(params["layers"][0]["attn_ln"]["scale"]), 1.0)
+
+
+@pytest.mark.parametrize("add_bos", [True, False])
+def test_a_family_without_a_bos_gets_none(add_bos, monkeypatch, tmp_path):
+    """``LlamaConfig.add_bos`` reaches the tokenizer: OLMoE's has no
+    BOS, and a prompt is then its own tokens and no more."""
+    import json
+
+    from mlmicroservicetemplate_tpu.models.registry import build_model
+
+    table = tmp_path / "pieces.tsv"
+    table.write_text("<unk>\t0\n<s>\t0\n</s>\t0\n" + "".join(
+        f"\u2581w{i}\t-1\n" for i in range(3, 300)), encoding="utf-8")
+    svc = _svc(monkeypatch, tokenizer_path=str(table))
+    over = json.loads(os.environ["LLAMA_CONFIG"])
+    monkeypatch.setenv("LLAMA_CONFIG", json.dumps({**over, "add_bos": add_bos}))
+    bundle = build_model(svc)
+    assert bundle.cfg.add_bos is add_bos
+    ids, mask = bundle.tokenizer.encode("w7 w8 w9", 16)
+    want = ([1] if add_bos else []) + [7, 8, 9]
+    assert [int(t) for t in ids[: int(mask.sum())]] == want
+
+
+@pytest.mark.parametrize("knob,needle", [
+    ({"tp": 2}, "TP=2 is not supported"),
+    ({"quantize": "int8"}, "QUANTIZE=int8 is not supported"),
+])
+def test_registry_refuses_what_the_experts_do_not_cover(monkeypatch, knob, needle):
+    from mlmicroservicetemplate_tpu.models.registry import build_model
+
+    with pytest.raises(ValueError, match=needle):
+        build_model(_svc(monkeypatch, **knob))
+
+
+def test_a_lora_target_inside_the_experts_is_refused(cfg, params):
+    """Adapters name attention projections only; one that names an
+    expert matrix fails the boot's shape check."""
+    from mlmicroservicetemplate_tpu.tenancy.adapters import AdapterPool
+
+    pool = AdapterPool.__new__(AdapterPool)
+    pool.projections = ("gate",)
+    pool._stacks, pool.num_layers = {}, cfg.num_layers
+    with pytest.raises(ValueError, match="adapters target projection 'gate'"):
+        pool.validate_against(params)
+
+
+def test_registry_refuses_the_kernel_off_tpu_without_interpret(monkeypatch):
+    from mlmicroservicetemplate_tpu.models.registry import build_model
+
+    with pytest.raises(RuntimeError, match="PALLAS_INTERPRET=1"):
+        build_model(_svc(monkeypatch, pallas_interpret=False))
+
+
+def test_bad_experts_per_token_is_refused():
+    with pytest.raises(ValueError, match="experts_per_token"):
+        llama_mod.LlamaConfig(num_experts=8, experts_per_token=9)
+
+
+def test_converter_round_trip(cfg, params):
+    """HF OLMoE names -> the stacked leaves, back to what was exported."""
+    from mlmicroservicetemplate_tpu.convert import llama_state_to_pytree
+
+    t = lambda a: np.ascontiguousarray(np.asarray(a).T)  # noqa: E731
+    state = {
+        "model.embed_tokens.weight": np.asarray(params["embed"]["embedding"]),
+        "model.norm.weight": np.asarray(params["final_ln"]["scale"]),
+        "lm_head.weight": t(params["lm_head"]["kernel"]),
+    }
+    for i, layer in enumerate(params["layers"]):
+        b = f"model.layers.{i}"
+        a, m = layer["attn"], layer["mlp"]
+        state[f"{b}.input_layernorm.weight"] = np.asarray(layer["attn_ln"]["scale"])
+        state[f"{b}.post_attention_layernorm.weight"] = np.asarray(
+            layer["mlp_ln"]["scale"])
+        for n in "qkvo":
+            state[f"{b}.self_attn.{n}_proj.weight"] = t(a[n]["kernel"])
+        for n in ("q_norm", "k_norm"):
+            state[f"{b}.self_attn.{n}.weight"] = np.asarray(a[n]["scale"])
+        state[f"{b}.mlp.gate.weight"] = t(m["router"]["kernel"])
+        for e in range(cfg.num_experts):
+            for n in ("gate", "up", "down"):
+                state[f"{b}.mlp.experts.{e}.{n}_proj.weight"] = t(m[n]["kernel"][e])
+    back = llama_state_to_pytree(state)
+    assert jax.tree.structure(back) == jax.tree.structure(params)
+    for x, y in zip(jax.tree.leaves(back), jax.tree.leaves(params)):
+        np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
