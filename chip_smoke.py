@@ -316,7 +316,8 @@ def kernel_vs_reference(svc: "Service", variant: str) -> dict:
     rng = np.random.default_rng(0)
     nb = b * t
     q = jnp.asarray(rng.normal(size=(b, cfg.num_heads, d)), dt)
-    k, v = (jnp.asarray(rng.normal(size=(nb, bs, kvh, d)), dt) for _ in "kv")
+    # The pool's layout: [NB, BS, KVH*D] (ops/paged_attention.py).
+    k, v = (jnp.asarray(rng.normal(size=(nb, bs, kvh * d)), dt) for _ in "kv")
     table = jnp.asarray(rng.permutation(nb).reshape(b, t), jnp.int32)
     valid = jnp.asarray(rng.random((b, t * bs)) < 0.9, jnp.int32)
     got = paged_decode_attention(
@@ -330,6 +331,21 @@ def kernel_vs_reference(svc: "Service", variant: str) -> dict:
           f"paged kernel {variant!r} vs jnp reference: max |d| {err}")
     return {"variant": variant, "shape": {"b": b, "t": t, "bs": bs},
             "max_abs_err": err, "tolerance": KERNEL_ATOL}
+
+
+def pool_relayouts_in_step(cdl) -> list[str]:
+    """Pool-sized ``reshape``/``copy``/``transpose`` instructions inside
+    the compiled paged chunk's step loop (the state is not donated, so
+    ENTRY copies each pool once a chunk: not counted).  The pool's
+    layout rule (ops/paged_attention.py) says there are none."""
+    import jax
+
+    from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
+
+    st = cdl._state
+    sizes = {int(x.size) for x in jax.tree.leaves((st.cache_k, st.cache_v))}
+    return pool_relayouts(
+        cdl.paged_chunk_hlo(compiled=True), sizes, in_loop_only=True)
 
 
 async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
@@ -353,6 +369,7 @@ async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
     counts = dec.get("autotune", {})
     hlo_calls = [cdl.paged_chunk_hlo().count("tpu_custom_call")
                  for cdl in svc.decode_loops()]
+    relayouts = [pool_relayouts_in_step(cdl) for cdl in svc.decode_loops()]
     mean_batch = (s1 - s0) / max(c1 - c0, 1.0)
     check(all(f["tokens_generated"] > 0 for f in finals), "a stream was empty")
     check(mean_batch > 1.0,
@@ -373,6 +390,7 @@ async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
             ),
             "autotune": counts,
             "tpu_custom_calls_in_decode_step": hlo_calls,
+            "pool_relayouts_in_decode_step": relayouts,
             "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
             "peak_flops": svc.engine.perf.peak_flops,
             "device": st.get("device"),
@@ -418,6 +436,10 @@ async def stream_phase(a) -> None:
     if not a.rehearse:  # interpret mode lowers no custom call
         check(all(n > 0 for n in facts["tpu_custom_calls_in_decode_step"]),
               "the Pallas paged kernel is not in the compiled decode step")
+        # (the interpreter's emulation copies its operands: chip only)
+        check(not any(facts["pool_relayouts_in_decode_step"]),
+              "the compiled paged chunk moves a whole KV pool every step: "
+              f"{facts['pool_relayouts_in_decode_step']}")
     async with Service(base, {**env, "USE_PALLAS_DECODE": "0"}) as svc:
         require_device(await svc.status(), a.device)
         ref = await run_streams(svc, prompts, "llama")

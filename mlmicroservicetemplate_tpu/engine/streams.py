@@ -395,6 +395,7 @@ class ContinuousDecodeLoop:
             self._paged_chunk = None
             self._paged_insert = None
             self._gather_prefix_fns: dict[int, Any] = {}
+            self._kv_tails: list[tuple] = []  # set with the pools
             self._dispatched_steps: dict[int, int] = {}
             if not self.prefill_chunk:
                 self._paged_handoff = None  # swap-resume handoff seam
@@ -3032,9 +3033,20 @@ class ContinuousDecodeLoop:
                 eng._host_demote_on = prev
         bs = self.block_size
         nbp = self.pool.num_blocks
+        # A token's dims as the contiguous prefill state carries them
+        # ((KVH, D) payload, (KVH, 1) scale), in _host_leaf_specs'
+        # leaf order: what a dense gather unmerges (_gather_prefix).
+        self._kv_tails = [
+            tuple(int(d) for d in x.shape[2:])
+            for x in jax.tree.leaves((template.cache_k, template.cache_v))
+        ]
 
         def pool_leaf(x, fill):
-            arr = np.zeros((nbp, bs) + tuple(x.shape[2:]), x.dtype)
+            # ops/paged_attention's layout rule: [NB, BS, C], a token's
+            # trailing dims merged from allocation on.
+            arr = np.zeros(
+                (nbp, bs, int(np.prod(x.shape[2:], dtype=np.int64))), x.dtype
+            )
             if fill:
                 arr[...] = 1
             return arr
@@ -3065,8 +3077,8 @@ class ContinuousDecodeLoop:
                 template.sample,
             ),
         )
-        # Pool leaves commit sharded over 'tp' on the heads axis under
-        # a TP placement (one logical pool, per-shard buffers — block
+        # Pool leaves commit sharded over 'tp' on the merged heads axis
+        # under a TP placement (one logical pool, per-shard buffers — block
         # ids and the ledger stay device-agnostic); everything else
         # keeps the slot sharding.
         place = getattr(eng.replicas, "place_decode_state", None)
@@ -3227,20 +3239,26 @@ class ContinuousDecodeLoop:
             )
         return self._paged_chunk
 
-    def paged_chunk_hlo(self, debug_info: bool = False) -> str:
+    def paged_chunk_hlo(self, debug_info: bool = False,
+                        compiled: bool = False) -> str:
         """Lowered text of the paged decode chunk at this loop's
         serving shapes — the program the chunk dispatches run.  What
         ``chip_smoke.py`` reads to show which attention path is in the
         step: the Pallas kernel lowers to a ``tpu_custom_call``, the
         ``gather_pages`` path to none.  ``debug_info`` adds each
-        operation's location, which carries its ``named_scope`` path."""
+        operation's location, which carries its ``named_scope`` path;
+        ``compiled`` gives the backend's optimised text instead (layouts
+        assigned: where a pool-sized relayout would show)."""
         import jax.numpy as jnp
 
         with self.engine._lock:
-            return self._paged_chunk_fn().lower(
+            lowered = self._paged_chunk_fn().lower(
                 self._mp(n=self.n_slots), self._state,
                 jnp.asarray(self._table), self.engine.chunk_tokens, False,
-            ).as_text(debug_info=debug_info)
+            )
+            if compiled:
+                return lowered.compile().as_text()
+            return lowered.as_text(debug_info=debug_info)
 
     def _paged_insert_fn(self):
         """Paged slot insert: scatter rows [s_lo, s_cut) of one
@@ -3335,19 +3353,17 @@ class ContinuousDecodeLoop:
         if p_len not in self._gather_prefix_fns:
             bs = self.block_size
 
+            tails = self._kv_tails
+
             def gather(state, blocks):
-                def one(pool):
-                    return gather_pages(pool, blocks[None], bs)[:, :p_len]
-
-                def entry(c):
-                    if isinstance(c, tuple):
-                        return tuple(one(x) for x in c)
-                    return one(c)
-
-                return {
-                    "k": [entry(c) for c in state.cache_k],
-                    "v": [entry(c) for c in state.cache_v],
-                }
+                pools, treedef = jax.tree.flatten(
+                    (state.cache_k, state.cache_v)
+                )
+                k, v = jax.tree.unflatten(treedef, [
+                    gather_pages(pool, blocks[None], bs, tail)[:, :p_len]
+                    for pool, tail in zip(pools, tails)
+                ])
+                return {"k": k, "v": v}
 
             self._gather_prefix_fns[p_len] = self._shared_jit(
                 "gather_prefix", lambda: jax.jit(gather),

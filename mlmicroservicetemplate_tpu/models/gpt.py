@@ -432,7 +432,8 @@ class PagedState(NamedTuple):
 
     Identical to ``GPTState`` except the caches: instead of per-row
     contiguous ``[B, W, H, D]`` slabs, K/V live in pools of
-    ``block_size``-token blocks ``[NB, BS, H, D]`` shared by every
+    ``block_size``-token blocks ``[NB, BS, H*D]`` (a token's dims
+    merged: ops/paged_attention's layout rule) shared by every
     row, and logical position ``p`` of row ``b`` resolves through a
     host-owned block table (``table[b, p // BS]``) that rides into
     each dispatch as a traced argument — NOT part of this state, so
@@ -442,7 +443,7 @@ class PagedState(NamedTuple):
     contiguous layout: positions, masks and sampling never change,
     only where a KV row physically lives."""
 
-    cache_k: Any  # per layer [NB, BS, H, D] pool ((int8, scale) under QUANT_KV)
+    cache_k: Any  # per layer [NB, BS, H*D] pool ((int8, [NB, BS, H] scale) under QUANT_KV)
     cache_v: Any
     key_valid: jax.Array  # [B, W] int32 over LOGICAL positions (W = T*BS)
     write_idx: jax.Array  # [B]
@@ -466,12 +467,11 @@ def _paged_dest(table: jax.Array, t: jax.Array, bs: int, nb: int) -> jax.Array:
 
 
 def paged_write_token(pool, table, t, val, bs: int):
-    """Scatter one new K (or V) row per batch row into a dense pool."""
-    nb = pool.shape[0]
-    flat = pool.reshape((nb * bs,) + pool.shape[2:])
-    dest = _paged_dest(table, t, bs, nb)
-    flat = flat.at[dest].set(val.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+    """Scatter one new K (or V) row per batch row ``[B, ...]`` into a
+    pool ``[NB, BS, C]`` through the table."""
+    from ..ops.paged_attention import scatter_rows
+
+    return scatter_rows(pool, _paged_dest(table, t, bs, pool.shape[0]), val)
 
 
 def _paged_decode_step(
@@ -508,7 +508,7 @@ def _paged_decode_step(
             from ..ops.paged_attention import paged_decode_attention
 
             vkey = cfg.pallas_variant or autotune.lookup(
-                "paged_decode", b=b, kvh=ck.shape[2], n_rep=1,
+                "paged_decode", b=b, kvh=cfg.num_heads, n_rep=1,
                 d=q.shape[3], block_size=bs, t=table.shape[1],
                 dtype=str(q.dtype), quant=False, tp=cfg.tp,
             )
@@ -517,8 +517,9 @@ def _paged_decode_step(
                 interpret=cfg.pallas_interpret, variant=vkey, tp=cfg.tp,
             )[:, None]
         else:
-            kd = gather_pages(ck, table, bs)
-            vd = gather_pages(cv, table, bs)
+            tail = (cfg.num_heads, cfg.head_dim)
+            kd = gather_pages(ck, table, bs, tail)
+            vd = gather_pages(cv, table, bs, tail)
             ctx = mha_attention(q, kd, vd, mask=attn_mask)
         x = x + _attn_out(layer["attn"], merge_heads(ctx), ad, li)
         h = layernorm(layer["ln2"], x, eps=cfg.ln_eps)
@@ -720,8 +721,9 @@ def paged_prefill_chunk(
         cv = scatter_pages(state.cache_v[li], table_row, v1[0], bs, start=start)
         new_k.append(ck)
         new_v.append(cv)
-        kd = gather_pages(ck, table_row[None], bs)
-        vd = gather_pages(cv, table_row[None], bs)
+        tail = (cfg.num_heads, cfg.head_dim)
+        kd = gather_pages(ck, table_row[None], bs, tail)
+        vd = gather_pages(cv, table_row[None], bs, tail)
         ctx = mha_attention(q, kd, vd, mask=mask)
         x = x + _attn_out(layer["attn"], merge_heads(ctx), ad, li)
         h = layernorm(layer["ln2"], x, eps=cfg.ln_eps)
@@ -753,7 +755,7 @@ def init_paged_state(
     )
     cache_k, cache_v = [], []
     for k, v in kv:
-        shape = (num_blocks, block_size, cfg.num_heads, cfg.head_dim)
+        shape = (num_blocks, block_size, cfg.num_heads * cfg.head_dim)
         ck = jnp.zeros(shape, k.dtype)
         cv = jnp.zeros(shape, v.dtype)
         for row in range(b):

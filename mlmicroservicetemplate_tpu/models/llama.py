@@ -750,10 +750,9 @@ def _paged_cache_attention(cfg: LlamaConfig, q, ck, cv, table, key_valid,
         from ..ops.paged_attention import paged_decode_attention
 
         quant = isinstance(ck, tuple)
-        kpool = ck[0] if quant else ck
         vkey = cfg.pallas_variant or autotune.lookup(
-            "paged_decode", b=q.shape[0], kvh=kpool.shape[2],
-            n_rep=q.shape[2] // kpool.shape[2], d=q.shape[3],
+            "paged_decode", b=q.shape[0], kvh=cfg.num_kv_heads,
+            n_rep=cfg.n_rep, d=q.shape[3],
             block_size=bs, t=table.shape[1], dtype=str(q.dtype), quant=quant,
             tp=cfg.tp,
         )
@@ -768,24 +767,29 @@ def _paged_cache_attention(cfg: LlamaConfig, q, ck, cv, table, key_valid,
                                          bs, interpret=cfg.pallas_interpret,
                                          variant=vkey, tp=cfg.tp)
         return ctx[:, None]
+    return _gathered_attention(
+        cfg, q, ck, cv, table, bs, (key_valid != 0)[:, None, None, :]
+    )
+
+
+def _gathered_attention(cfg: LlamaConfig, q, ck, cv, table, bs: int, mask):
+    """The XLA path over the pool: gather the rows' blocks, unmerge
+    ``(KVH, D)`` on the gathered view (never on the pool) and run the
+    contiguous path's attention."""
     from ..ops.paged_attention import gather_pages
 
-    mask = (key_valid != 0)[:, None, None, :]
+    def dense(pool, last):
+        return _repeat_kv(
+            gather_pages(pool, table, bs, (cfg.num_kv_heads, last)), cfg.n_rep
+        )
+
+    d = cfg.head_dim
     if isinstance(ck, tuple):
         return mha_attention_kv8(
-            q,
-            _repeat_kv(gather_pages(ck[0], table, bs), cfg.n_rep),
-            _repeat_kv(gather_pages(ck[1], table, bs), cfg.n_rep),
-            _repeat_kv(gather_pages(cv[0], table, bs), cfg.n_rep),
-            _repeat_kv(gather_pages(cv[1], table, bs), cfg.n_rep),
-            mask=mask,
+            q, dense(ck[0], d), dense(ck[1], 1), dense(cv[0], d),
+            dense(cv[1], 1), mask=mask,
         )
-    return mha_attention(
-        q,
-        _repeat_kv(gather_pages(ck, table, bs), cfg.n_rep),
-        _repeat_kv(gather_pages(cv, table, bs), cfg.n_rep),
-        mask=mask,
-    )
+    return mha_attention(q, dense(ck, d), dense(cv, d), mask=mask)
 
 
 def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
@@ -987,8 +991,6 @@ def paged_prefill_chunk(
     """One prompt window straight into pool blocks (see
     ``gpt.paged_prefill_chunk``), at GQA width and composed with the
     int8 pool pairs."""
-    from ..ops.paged_attention import gather_pages
-
     from .gpt import _window_mask
 
     b, c = chunk_ids.shape  # b == 1
@@ -1011,22 +1013,7 @@ def paged_prefill_chunk(
         cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
         new_k.append(ck)
         new_v.append(cv)
-        if isinstance(ck, tuple):
-            ctx = mha_attention_kv8(
-                q,
-                _repeat_kv(gather_pages(ck[0], table_row[None], bs), cfg.n_rep),
-                _repeat_kv(gather_pages(ck[1], table_row[None], bs), cfg.n_rep),
-                _repeat_kv(gather_pages(cv[0], table_row[None], bs), cfg.n_rep),
-                _repeat_kv(gather_pages(cv[1], table_row[None], bs), cfg.n_rep),
-                mask=mask,
-            )
-        else:
-            ctx = mha_attention(
-                q,
-                _repeat_kv(gather_pages(ck, table_row[None], bs), cfg.n_rep),
-                _repeat_kv(gather_pages(cv, table_row[None], bs), cfg.n_rep),
-                mask=mask,
-            )
+        ctx = _gathered_attention(cfg, q, ck, cv, table_row[None], bs, mask)
         x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
         x = _mlp_block(cfg, layer, x, chunk_mask != 0)
     return state._replace(cache_k=new_k, cache_v=new_v)
@@ -1058,15 +1045,18 @@ def init_paged_state(
         params, cfg, input_ids, attention_mask, dtype, collect_kv=True
     )
     cache_k, cache_v = [], []
-    shape = (num_blocks, block_size, cfg.num_kv_heads, cfg.head_dim)
+    # ops/paged_attention's layout rule: payload [NB, BS, KVH*D],
+    # scales [NB, BS, KVH].
+    shape = (num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
+    sc_shape = (num_blocks, block_size, cfg.num_kv_heads)
     for k, v in kv:
         if cfg.kv_quant:
             k8, ks = kv_quantize(k)
             v8, vs = kv_quantize(v)
             ck8 = jnp.zeros(shape, jnp.int8)
-            cks = jnp.ones(shape[:3] + (1,), dtype)
+            cks = jnp.ones(sc_shape, dtype)
             cv8 = jnp.zeros(shape, jnp.int8)
-            cvs = jnp.ones(shape[:3] + (1,), dtype)
+            cvs = jnp.ones(sc_shape, dtype)
             for row in range(b):
                 ck8 = scatter_pages(ck8, table[row], k8[row], block_size)
                 cks = scatter_pages(cks, table[row], ks[row].astype(dtype), block_size)

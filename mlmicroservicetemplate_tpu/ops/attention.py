@@ -315,7 +315,9 @@ def decode_attention(
                 interpret=interpret, variant=variant,
             )
 
-        return tp_shard_attention(local, tp, q, (k, v), (mask,), opt)
+        return tp_shard_attention(
+            local, tp, q, (k, v), (mask,), opt, kvh=k.shape[2]
+        )
 
     var = parse_variant(variant)
     b, h, d = q.shape

@@ -271,17 +271,19 @@ def _probe(kind: str, *, b: int, kvh: int, n_rep: int, d: int, bs: int,
         ).astype(np.int32)
         valid = np.ones((b, t * bs), np.int32)
         valid[:, -max(bs // 2, 1):] = 0  # a part-filled tail block
+        def pool(x, dt):  # the pool's layout: [NB, BS, C], token dims merged
+            return jnp.asarray(x.reshape(nb_pool, bs, -1), dtype=dt)
+
         if quant:
             ks = (np.abs(kf).max(axis=3, keepdims=True) / 127.0 + 1e-6)
             vs = (np.abs(vf).max(axis=3, keepdims=True) / 127.0 + 1e-6)
-            k8 = np.clip(np.round(kf / ks), -127, 127).astype(np.int8)
-            v8 = np.clip(np.round(vf / vs), -127, 127).astype(np.int8)
-            args = (q, jnp.asarray(k8), jnp.asarray(v8),
+            k8 = np.clip(np.round(kf / ks), -127, 127)
+            v8 = np.clip(np.round(vf / vs), -127, 127)
+            args = (q, pool(k8, jnp.int8), pool(v8, jnp.int8),
                     jnp.asarray(table), jnp.asarray(valid),
-                    jnp.asarray(ks.astype(np.float32)),
-                    jnp.asarray(vs.astype(np.float32)))
+                    pool(ks, jnp.float32), pool(vs, jnp.float32))
         else:
-            args = (q, jnp.asarray(kf, dtype=jdt), jnp.asarray(vf, dtype=jdt),
+            args = (q, pool(kf, jdt), pool(vf, jdt),
                     jnp.asarray(table), jnp.asarray(valid), None, None)
         from .paged_attention import paged_attention_ref
 

@@ -1,17 +1,30 @@
 """Paged-attention decode: fused attention over a block-paged KV pool.
 
 Paged mode (``PAGED_KV=1``) stores the KV cache as a pool of
-fixed-size token blocks ``[NB, BS, KVH, D]`` shared by every live
-stream, with a per-row block table mapping logical position
-``p -> pool[table[row, p // BS], p % BS]``.  This module is the
-device-side half:
+fixed-size token blocks shared by every live stream, with a per-row
+block table mapping logical position
+``p -> pool[table[row, p // BS], p % BS]``.
+
+**The pool's layout** (stated here once; docs/kernel_tuning.md repeats
+it): a pool leaf is ``[NB, BS, C]``, ``C`` the product of a token's
+trailing dims — ``KVH*D`` for a payload, ``KVH`` for an int8 pool's
+scales — from allocation (``engine/streams._build_empty_paged``) to the
+last reader.  That is the kernel's own block view: a ``[BS, KVH*D]``
+tile is lane-dense, and the TPU tiles a ``[.., KVH, D]`` array over
+(KV head, lane) but ``[.., BS, KVH*D]`` over (token, lane), so merging
+the trailing dims of a whole pool is a relayout of every byte of it,
+not a bitcast.  No 4-D pool exists beside this one.  Writers merge the
+few rows they write (``scatter_rows``); readers that want
+``[.., KVH, D]`` unmerge the rows they gathered, never the pool.
+
+This module is the device-side half:
 
 - ``gather_pages``: XLA fallback — materialize a row's dense
   ``[B, W, KVH, D]`` view through the table (one ``take``; XLA fuses
-  it into the consumer).  The models' paged decode steps attend over
-  this view with their EXISTING attention code, which is what makes
-  paged decode token-identical to the contiguous layout by
-  construction.
+  it into the consumer; ``tail`` unmerges the gathered rows).  The
+  models' paged decode steps attend over this view with their EXISTING
+  attention code, which is what makes paged decode token-identical to
+  the contiguous layout by construction.
 - ``paged_decode_attention``: Pallas kernel — grid ``(B, T/K)`` with
   the block table as a scalar-prefetch operand, so each program DMAs
   exactly K of its row's blocks HBM->VMEM (the gather never
@@ -129,38 +142,55 @@ def parse_variant(key: str | None) -> Variant:
     return Variant(blocks, hb, nat, fs, acc)
 
 
-def gather_pages(pool: jax.Array, table: jax.Array, block_size: int) -> jax.Array:
-    """Dense view of each row's blocks: ``[NB, BS, ...] x [B, T]`` ->
-    ``[B, T*BS, ...]``.  Out-of-range table ids (the freed-slot
-    sentinel) clamp to the last block; callers mask those positions
-    with ``key_valid``, and clamped garbage is finite (pools are
-    zero-initialized), so a masked softmax stays well-behaved."""
-    nb = pool.shape[0]
-    flat = pool.reshape((nb * block_size,) + pool.shape[2:])
+def scatter_rows(pool: jax.Array, dest: jax.Array, values: jax.Array) -> jax.Array:
+    """Write token rows ``values`` ``[N, ...]`` at flat positions
+    ``dest`` ``[N]`` (``block * BS + offset``; out of range drops) of a
+    pool ``[NB, BS, C]``, where it lies: the N rows merge their token
+    dims (``[N, KVH, D]`` payload, ``[N, KVH, 1]`` scale rows), the
+    pool is only re-viewed ``[NB*BS, C]`` (a bitcast on the chip at
+    the serving block size: tests/test_chip_compile.py) and scattered
+    into in place."""
+    nb, bs, c = pool.shape
+    flat = pool.reshape(nb * bs, c).at[dest].set(
+        values.reshape(values.shape[0], c).astype(pool.dtype), mode="drop"
+    )
+    return flat.reshape(pool.shape)
+
+
+def gather_pages(pool: jax.Array, table: jax.Array, block_size: int,
+                 tail: tuple = ()) -> jax.Array:
+    """Dense view of each row's blocks: ``[NB, BS, C] x [B, T]`` ->
+    ``[B, T*BS, C]``, or ``[B, T*BS, *tail]`` when the caller names the
+    token dims to unmerge (``(KVH, D)``; ``(KVH, 1)`` for scales) — on
+    the gathered rows, never on the pool.  Out-of-range table ids (the
+    freed-slot sentinel) clamp to the last block; callers mask those
+    positions with ``key_valid``, and clamped garbage is finite (pools
+    are zero-initialized), so a masked softmax stays well-behaved."""
+    nb, _, c = pool.shape
+    flat = pool.reshape(nb * block_size, c)
     idx = (
         jnp.clip(table, 0, nb - 1)[:, :, None] * block_size
         + jnp.arange(block_size)[None, None, :]
     )  # [B, T, BS]
     b, t, _ = idx.shape
-    return jnp.take(flat, idx.reshape(b, t * block_size), axis=0)
+    out = jnp.take(flat, idx.reshape(b, t * block_size), axis=0)
+    return out.reshape(out.shape[:2] + tuple(tail)) if tail else out
 
 
 def scatter_pages(
     pool: jax.Array, table_row: jax.Array, values: jax.Array,
     block_size: int, start: int = 0,
 ) -> jax.Array:
-    """Write ``values`` ``[W, ...]`` at logical positions
-    ``start..start+W-1`` of ONE row's blocks.  Positions whose table
-    entry is out of range (sentinel) drop — the paged insert relies on
-    this for pad regions and freed slots."""
-    nb = pool.shape[0]
-    w = values.shape[0]
-    flat = pool.reshape((nb * block_size,) + pool.shape[2:])
+    """Write ``values`` ``[W, ...]`` (token dims merged here, on the W
+    rows) at logical positions ``start..start+W-1`` of ONE row's
+    blocks.  Positions whose table entry is out of range (sentinel)
+    drop — the paged insert relies on this for pad regions and freed
+    slots."""
+    nb, w = pool.shape[0], values.shape[0]
     p = start + jnp.arange(w)
     blk = jnp.take(table_row, p // block_size, mode="fill", fill_value=nb)
-    dest = blk * block_size + p % block_size  # OOB where sentinel
-    flat = flat.at[dest].set(values.astype(pool.dtype), mode="drop")
-    return flat.reshape(pool.shape)
+    # OOB where sentinel
+    return scatter_rows(pool, blk * block_size + p % block_size, values)
 
 
 def head_batched_q(q: jax.Array, kvh: int) -> jax.Array:
@@ -258,7 +288,7 @@ def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
     """Fold one key/value block into the online-softmax accumulators.
 
     Tiles are 2-D and lane-dense: ``k_blk``/``v_blk`` are ``[KB, KVH*D]``
-    (the pool's trailing ``[KVH, D]`` merged — a free reshape in HBM),
+    (the pool's own layout — see the module docstring),
     raw payloads (f32/bf16, or int8 when ``ks_blk``/``vs_blk`` carry
     the ``[KB, KVH]`` f32 scales); ``valid`` is the block's ``[1, KB]``
     mask.  A ``[KB, KVH, D]`` tile would pad its (KVH, D) minor dims to
@@ -381,32 +411,37 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
 
 def tp_shard_attention(
     fn, tp: int, q, kv_args: tuple, rep_args: tuple,
-    scale_args: tuple = (),
+    scale_args: tuple = (), *, kvh: int,
 ):
     """Run a decode-attention kernel under ``shard_map`` over the
     serving TP mesh: each shard attends over its LOCAL heads (q axis 1,
-    KV heads axis 2) — attention is embarrassingly parallel across
-    heads, so the body carries no collective; the row-parallel
-    all-reduce lands after the attn-out matmul, where XLA's sharding
-    propagation puts it.  ``rep_args`` (tables, masks) replicate.
+    KV heads axis 2 — a slab's ``[B, S, KVH, D]`` heads axis, or a
+    pool leaf's merged ``[NB, BS, KVH*D]`` axis, which splits on head
+    boundaries because ``kvh % tp == 0``) — attention is embarrassingly
+    parallel across heads, so the body carries no collective; the
+    row-parallel all-reduce lands after the attn-out matmul, where
+    XLA's sharding propagation puts it.  ``rep_args`` (tables, masks)
+    replicate.
 
     The wrapper is only reachable at TP>1 — TP=1 call sites never
     build a mesh (the no-mesh pin in tests/test_tp_serving.py)."""
     from jax.sharding import AbstractMesh, PartitionSpec as P
 
     h = q.shape[1]
-    kvh = kv_args[0].shape[2]
     if h % tp or kvh % tp:
         raise ValueError(
             f"TP={tp} must divide query heads ({h}) and KV heads ({kvh})"
         )
-    heads4 = P(None, None, "tp", None)
+
+    def heads(a):  # axis 2 over 'tp', whatever trails it
+        return P(None, None, "tp", *([None] * (a.ndim - 3)))
+
     args = (q,) + tuple(kv_args) + tuple(rep_args) + tuple(scale_args)
     in_specs = (
         [P(None, "tp", None)]
-        + [heads4] * len(kv_args)
+        + [heads(a) for a in kv_args]
         + [P(*([None] * a.ndim)) for a in rep_args]
-        + [heads4] * len(scale_args)
+        + [heads(a) for a in scale_args]
     )
     # The ABSTRACT mesh: axis names and sizes, no devices.  The traced
     # program is then the same for every TP group of a fleet — jit
@@ -427,12 +462,12 @@ def tp_shard_attention(
 )
 def paged_decode_attention(
     q: jax.Array,  # [B, H, D] — one query per row
-    k_pool: jax.Array,  # [NB, BS, KVH, D] dense, or int8 payload
+    k_pool: jax.Array,  # [NB, BS, KVH*D] dense, or int8 payload
     v_pool: jax.Array,
     table: jax.Array,  # [B, T] block ids (caller clamps sentinels)
     key_valid: jax.Array,  # [B, T*BS] 1 = attend
     block_size: int,
-    k_scale: jax.Array | None = None,  # [NB, BS, KVH, 1] -> int8 path
+    k_scale: jax.Array | None = None,  # [NB, BS, KVH] -> int8 path
     v_scale: jax.Array | None = None,
     scale: float | None = None,
     interpret: bool = False,
@@ -456,6 +491,9 @@ def paged_decode_attention(
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    b, h, d = q.shape
+    nb_pool, bs, gd = k_pool.shape
+    kvh = gd // d
     if tp > 1:
         opt = () if k_scale is None else (k_scale, v_scale)
 
@@ -467,13 +505,12 @@ def paged_decode_attention(
             )
 
         return tp_shard_attention(
-            local, tp, q, (k_pool, v_pool), (table, key_valid), opt
+            local, tp, q, (k_pool, v_pool), (table, key_valid), opt,
+            kvh=kvh,
         )
 
     var = parse_variant(variant)
     K = var.blocks_per_step
-    b, h, d = q.shape
-    nb_pool, bs, kvh, _ = k_pool.shape
     t = table.shape[1]
     n_rep = h // kvh
     if t % K != 0:
@@ -486,12 +523,11 @@ def paged_decode_attention(
         scale = 1.0 / math.sqrt(d)
     quant = k_scale is not None
     acc_jnp = jnp.float32 if var.acc_dtype == "f32" else jnp.bfloat16
-    gd = kvh * d
     tbl = jnp.clip(table, 0, nb_pool - 1).astype(jnp.int32)
     # Mosaic wants a block's last two dims (8, 128)-divisible or whole:
     # the mask rides as [B, T/K, 1, K*BS] with whole (1, K*BS) blocks
-    # (as fused_attention carries its mask), and the pools as
-    # [NB, BS, KVH*D] — trailing dims merged, a bitcast in HBM.
+    # (as fused_attention carries its mask); the pools go in as they
+    # lie, [NB, BS, KVH*D] with whole (BS, KVH*D) blocks.
     validb = key_valid.astype(jnp.int32).reshape(b, tsteps, 1, K * bs)
     if var.head_batched:
         qk = head_batched_q(q, kvh)
@@ -522,19 +558,15 @@ def paged_decode_attention(
         _paged_kernel_v, scale=scale, kvh=kvh, n_rep=n_rep, d=d,
         quant=quant, var=var,
     )
-    kp = k_pool.reshape(nb_pool, bs, gd)
-    vp = v_pool.reshape(nb_pool, bs, gd)
     if not quant:
         in_specs = [q_spec, *kv_specs, *kv_specs, valid_spec]
-        args = (tbl, qk, *([kp] * K), *([vp] * K), validb)
+        args = (tbl, qk, *([k_pool] * K), *([v_pool] * K), validb)
     else:
         in_specs = [q_spec, *kv_specs, *sc_specs, *kv_specs, *sc_specs,
                     valid_spec]
-        ks = k_scale.reshape(nb_pool, bs, kvh)
-        vs = v_scale.reshape(nb_pool, bs, kvh)
         args = (
-            tbl, qk, *([kp] * K), *([ks] * K), *([vp] * K), *([vs] * K),
-            validb,
+            tbl, qk, *([k_pool] * K), *([k_scale] * K), *([v_pool] * K),
+            *([v_scale] * K), validb,
         )
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
@@ -564,18 +596,53 @@ def paged_attention_ref(
     and run masked softmax attention in f32.  Also the XLA serving
     fallback shape the models reproduce inline."""
     b, h, d = q.shape
-    kvh = k_pool.shape[2]
+    kvh = k_pool.shape[2] // d
     n_rep = h // kvh
     if scale is None:
         scale = 1.0 / math.sqrt(d)
-    kd = gather_pages(k_pool, table, block_size).astype(jnp.float32)
-    vd = gather_pages(v_pool, table, block_size).astype(jnp.float32)
+
+    def dense(pool, tail):
+        return gather_pages(pool, table, block_size, tail).astype(jnp.float32)
+
+    kd, vd = dense(k_pool, (kvh, d)), dense(v_pool, (kvh, d))
     if k_scale is not None:
-        kd = kd * gather_pages(k_scale, table, block_size).astype(jnp.float32)
-        vd = vd * gather_pages(v_scale, table, block_size).astype(jnp.float32)
+        kd = kd * dense(k_scale, (kvh, 1))
+        vd = vd * dense(v_scale, (kvh, 1))
     qg = q.reshape(b, kvh, n_rep, d).astype(jnp.float32)
     s = jnp.einsum("bgrd,btgd->bgrt", qg, kd) * scale
     s = jnp.where(key_valid[:, None, None, :] != 0, s, jnp.float32(-1e30))
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bgrt,btgd->bgrd", p, vd)
     return o.reshape(b, h, d).astype(q.dtype)
+
+
+def pool_relayouts(hlo_text: str, pool_elems, in_loop_only: bool = False) -> list:
+    """The instructions of a COMPILED program's text (``.compile()
+    .as_text()``) that move a whole pool: every ``reshape``, ``copy`` or
+    ``transpose`` with a result of a pool leaf's element count
+    (``pool_elems``: the counts to look for).  On the chip a reshape
+    that survives to the optimised HLO is a relayout (the free ones
+    become ``bitcast``).  ``in_loop_only`` skips the ENTRY computation:
+    a state that is not donated is copied once on the way in, which is
+    not a step's cost.  What ``tests/test_chip_compile.py`` and
+    ``chip_smoke.py`` hold the layout rule to."""
+    import re
+
+    want = {int(n) for n in pool_elems}
+    inst = re.compile(r"=\s+(.*?)\s([a-z][a-z0-9\-]*)\(")
+    hits, in_entry = [], False
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY "):
+            in_entry = True
+        elif line.startswith("}"):
+            in_entry = False
+        m = inst.search(line)
+        if not m or m.group(2) not in ("reshape", "copy", "transpose"):
+            continue
+        if in_entry and in_loop_only:
+            continue
+        for dims in re.findall(r"\[([\d,]+)\]", m.group(1)):
+            if math.prod(int(x) for x in dims.split(",")) in want:
+                hits.append(line.strip()[:200])
+                break
+    return hits

@@ -219,8 +219,9 @@ class TensorParallelSet(ReplicaSet):
 
     def place_decode_state(self, state, paged: bool = False):
         """KV-cache leaves shard their heads axis over 'tp' (pool
-        blocks ``[NB, BS, H, D]`` and contiguous slabs ``[B, S, H, D]``
-        alike — parallel/tpserve.kv_head_spec); every other field
+        leaves ``[NB, BS, H*D]`` — the merged axis splits on head
+        boundaries — and contiguous slabs ``[B, S, H, D]`` alike:
+        parallel/tpserve.kv_head_spec); every other field
         keeps the DP slot sharding.  Spec slot states shard their
         ``base`` the same way (the drafting history has no head axis).
         One logical pool, per-shard buffers: block ids, tables and the
@@ -232,12 +233,12 @@ class TensorParallelSet(ReplicaSet):
 
         def kv_shard(x):
             # Heads axis must split evenly (registry validates real TP
-            # configs; duck-typed test states just replicate).
+            # configs — KV heads % tp, which is what makes a pool's
+            # merged H*D axis split between heads; duck-typed test
+            # states just replicate).
             if (getattr(x, "ndim", 0) >= 3
                     and x.shape[2] % self.tp_width == 0):
-                return NamedSharding(
-                    self.mesh, kv_head_spec(paged, x.ndim)
-                )
+                return NamedSharding(self.mesh, kv_head_spec(paged))
             return self.batch_sharding
 
         def shardings(st):

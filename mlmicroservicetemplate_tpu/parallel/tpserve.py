@@ -22,10 +22,13 @@ is the small trace-time surface the REST of the serving stack needs:
   (None for single-device and default-prefix placements), the value
   the executable proxies feed ``use_trace_group``.
 - ``kv_head_spec(paged)`` — the one KV-cache layout rule: every cache
-  leaf (contiguous ``[B, S, H, D]`` slab, pool ``[NB, BS, H, D]``
-  block, or int8 scale ``[..., H]``) shards its HEADS axis (axis 2)
-  over 'tp'.  Block ids, tables, free-lists and refcounts never see a
-  device axis — the pool stays one logical pool with one ledger.
+  leaf (contiguous ``[B, S, H, D]`` slab and its ``[B, S, H, 1]``
+  scales; pool leaf ``[NB, BS, H*D]`` and its ``[NB, BS, H]`` scales)
+  shards its HEADS axis (axis 2) over 'tp'.  A pool's merged axis is
+  head-major, so shard ``i`` of ``tp`` holds heads ``i*H/tp ..``
+  whole (``H % tp == 0``, which the registry demands).  Block ids,
+  tables, free-lists and refcounts never see a device axis — the pool
+  stays one logical pool with one ledger.
 - ``placement_fingerprint(placement)`` — a short stable string naming
   the mesh topology + param layout, mixed into the executable-cache
   and autotuner keys so TP executables can never alias single-device
@@ -155,17 +158,16 @@ def device_group(placement):
     return _normalize_group(ids, len(ids))
 
 
-def kv_head_spec(paged: bool, ndim: int = 4):
-    """PartitionSpec for one KV-cache leaf: heads axis (2) over 'tp'.
+def kv_head_spec(paged: bool):
+    """PartitionSpec for one KV-cache leaf: heads axis (2) over 'tp',
+    whatever trails it (a slab's D, nothing on a pool leaf).
 
     Contiguous slabs additionally shard their batch axis (0) over
     'replica'; pool leaves must NOT (axis 0 is the block id space —
     device-agnostic by contract, and PAGED_KV pins REPLICAS=1)."""
     from jax.sharding import PartitionSpec as P
 
-    lead = None if paged else "replica"
-    tail = [None] * max(0, ndim - 3)
-    return P(lead, None, "tp", *tail)
+    return P(None if paged else "replica", None, "tp")
 
 
 def placement_fingerprint(placement) -> str:

@@ -101,13 +101,14 @@ def _paged_text(chip, quant: bool, variant: str, t: int = T_BLOCKS,
                 pool: int = POOL) -> str:
     variant = parse_variant(variant).key()  # "" and "b1": one memo entry
     q = chip((B, H, D), jnp.bfloat16)
-    payload = chip((pool, BS, KVH, D), jnp.int8 if quant else jnp.bfloat16)
+    # The pool's layout (ops/paged_attention.py): [NB, BS, KVH*D].
+    payload = chip((pool, BS, KVH * D), jnp.int8 if quant else jnp.bfloat16)
     table = chip((B, t), jnp.int32)
     valid = chip((B, t * BS), jnp.int32)
     if quant:
-        # Scale pools ride at the compute dtype, as init_paged_state
-        # allocates them.
-        sc = chip((pool, BS, KVH, 1), jnp.bfloat16)
+        # Scale pools [NB, BS, KVH] ride at the compute dtype, as
+        # init_paged_state allocates them.
+        sc = chip((pool, BS, KVH), jnp.bfloat16)
         return _compiled_text(
             chip, ("paged", True, variant, t),
             lambda q, k, v, tb, m, ks, vs: paged_decode_attention(
@@ -163,6 +164,8 @@ def test_paged_decode_kernel_compiles_under_tp2(topo, group):
     import numpy as np
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+    from mlmicroservicetemplate_tpu.parallel.tpserve import kv_head_spec
+
     mesh = Mesh(
         np.array([topo.devices[i] for i in group]).reshape(1, 2),
         ("replica", "tp"),
@@ -172,7 +175,7 @@ def test_paged_decode_kernel_compiles_under_tp2(topo, group):
         return jax.ShapeDtypeStruct(
             shape, dtype, sharding=NamedSharding(mesh, P(*axes)))
 
-    pool = spec((POOL, BS, KVH, D), jnp.bfloat16, None, None, "tp", None)
+    pool = spec((POOL, BS, KVH * D), jnp.bfloat16, *kv_head_spec(paged=True))
     compiled = jax.jit(
         lambda q, k, v, tb, m: paged_decode_attention(
             q, k, v, tb, m, BS, variant="b2-hb", tp=2)
@@ -258,13 +261,62 @@ def test_paged_decode_kernel_compiles_at_16_kv_heads(chip, variant):
 
     b, h, t = 64, 16, 44
     q = chip((b, h, 128), jnp.bfloat16)
-    pool = chip((b * t, 16, h, 128), jnp.bfloat16)
+    pool = chip((b * t, 16, h * 128), jnp.bfloat16)
     text = _compiled_text(
         chip, ("paged-mha", variant),
         lambda q, k, v, tb, m: kernel(q, k, v, tb, m, 16, variant=variant),
         q, pool, pool, chip((b, t), jnp.int32), chip((b, t * 16), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kvh,h,nb,t,quant", [
+    (8, 32, 3051, 44, False),  # mistral-7b-d8: KV_BUDGET_MB 1600, 512 + 192
+    (16, 16, 1810, 28, False),  # olmoe-1b-7b-d8: 1900, 256 + 192
+    (8, 32, 3051, 44, True),  # QUANT_KV=int8: payload + its scale pool
+], ids=["mistral", "olmoe", "mistral-int8"])
+def test_no_pool_sized_relayout_in_a_decode_step(chip, kvh, h, nb, t, quant):
+    """The decode chunk in small: a scan whose carry is a layer's K and
+    V pool, each step one token write through the table and one
+    ``paged_decode_attention``, pools donated, at both cells' shapes.
+    The chip's compiler must take the pool into the kernel as it lies
+    and scatter into it in place: no ``reshape``, ``copy`` or
+    ``transpose`` in the optimised HLO has a pool's element count (with
+    ``[NB, BS, KVH, D]`` pools it held two ``reshape(bf16[48816,8,128]
+    -> bf16[3051,16,1024])`` a step, 27-30 % of both cells' step:
+    PERF.md section 6, PR 28)."""
+    from mlmicroservicetemplate_tpu.models import llama as llama_mod
+    from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
+
+    b, bs, d, dt = 64, 16, 128, jnp.bfloat16
+    payload = chip((nb, bs, kvh * d), jnp.int8 if quant else dt)
+    entry = (payload, chip((nb, bs, kvh), dt)) if quant else payload
+
+    def chunk(k_entry, v_entry, q, k1, v1, table, valid, t0):
+        def step(carry, i):
+            ck, cv = carry
+            pos = t0 + i
+            ck = llama_mod._paged_write_kv(ck, table, pos, k1, bs, dt)
+            cv = llama_mod._paged_write_kv(cv, table, pos, v1, bs, dt)
+            scales = dict(k_scale=ck[1], v_scale=cv[1]) if quant else {}
+            ctx = paged_decode_attention(
+                q, ck[0] if quant else ck, cv[0] if quant else cv, table,
+                valid, bs, variant="b4-hb", **scales)
+            return (ck, cv), ctx
+
+        return jax.lax.scan(step, (k_entry, v_entry), jnp.arange(4))
+
+    text = jax.jit(chunk, donate_argnums=(0, 1)).lower(
+        entry, entry, chip((b, h, d), dt), chip((b, kvh, d), dt),
+        chip((b, kvh, d), dt), chip((b, t), jnp.int32),
+        chip((b, t * bs), jnp.int32), chip((b,), jnp.int32),
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 1
+    payload_n, scale_n = nb * bs * kvh * d, nb * bs * kvh
+    assert not pool_relayouts(text, [payload_n])
+    # The 0.8 MB scale pool the compiler may park in fast memory on the
+    # way in and out (a `copy` into S(1) in ENTRY); never in a step.
+    assert not pool_relayouts(text, [payload_n, scale_n], in_loop_only=True)
 
 
 @pytest.mark.parametrize("assignments", [512, 65536])

@@ -143,7 +143,7 @@ def test_paged_prefill_chunks_then_paged_decode(ref, cfg, params, monkeypatch):
     ids, _ = _prompts([n], seed=2)
     nb = 6
     table = jnp.asarray([[4, 1, 5, 0, 2, 3]], jnp.int32)
-    shape = (nb, bs, cfg.num_kv_heads, cfg.head_dim)
+    shape = (nb, bs, cfg.num_kv_heads * cfg.head_dim)  # the pool's layout
     state = PagedState(
         cache_k=[jnp.zeros(shape) for _ in range(cfg.num_layers)],
         cache_v=[jnp.zeros(shape) for _ in range(cfg.num_layers)],

@@ -70,8 +70,9 @@ def test_paged_kernel_matches_reference(quant):
     POOL = 8
     rng = np.random.default_rng(0)
     q = rng.normal(size=(B, KVH * NREP, D)).astype(np.float32)
-    kp = rng.normal(size=(POOL, BS, KVH, D)).astype(np.float32)
-    vp = rng.normal(size=(POOL, BS, KVH, D)).astype(np.float32)
+    # The pool's layout: [NB, BS, KVH*D], scales [NB, BS, KVH].
+    kp = rng.normal(size=(POOL, BS, KVH * D)).astype(np.float32)
+    vp = rng.normal(size=(POOL, BS, KVH * D)).astype(np.float32)
     table = np.array([[0, 2, 5], [7, 1, 3]], np.int32)
     valid = (rng.random((B, NB * BS)) > 0.3).astype(np.int32)
     valid[:, 0] = 1  # never a fully-masked row
@@ -79,8 +80,8 @@ def test_paged_kernel_matches_reference(quant):
     if quant:
         kp8 = np.clip(np.round(kp * 16), -127, 127).astype(np.int8)
         vp8 = np.clip(np.round(vp * 16), -127, 127).astype(np.int8)
-        ks = (np.abs(rng.normal(size=(POOL, BS, KVH, 1))) + 0.01).astype(np.float32)
-        vs = (np.abs(rng.normal(size=(POOL, BS, KVH, 1))) + 0.01).astype(np.float32)
+        ks = (np.abs(rng.normal(size=(POOL, BS, KVH))) + 0.01).astype(np.float32)
+        vs = (np.abs(rng.normal(size=(POOL, BS, KVH))) + 0.01).astype(np.float32)
         kp, vp = kp8, vp8
     want = paged_attention_ref(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
@@ -99,13 +100,89 @@ def test_paged_kernel_matches_reference(quant):
 
 
 def test_gather_pages_clamps_sentinel():
-    pool = jnp.arange(4 * 2 * 1 * 1, dtype=jnp.float32).reshape(4, 2, 1, 1)
+    pool = jnp.arange(4 * 2 * 1, dtype=jnp.float32).reshape(4, 2, 1)
     table = jnp.asarray([[1, 4]], jnp.int32)  # 4 == sentinel (out of range)
     out = gather_pages(pool, table, 2)
-    assert out.shape == (1, 4, 1, 1)
+    assert out.shape == (1, 4, 1)
+    assert gather_pages(pool, table, 2, (1, 1)).shape == (1, 4, 1, 1)
     np.testing.assert_array_equal(
-        np.asarray(out[0, :2, 0, 0]), [2.0, 3.0]
+        np.asarray(out[0, :2, 0]), [2.0, 3.0]
     )  # block 1
+
+
+def test_kernel_reads_the_pool_as_it_lies():
+    """The CPU twin of test_chip_compile's scan case: in the traced
+    wrapper the ``pallas_call``'s pool operands are the function's own
+    inputs — nothing (reshape, transpose, copy, convert) stands between
+    the pool a decode state carries and the kernel."""
+    b, h, kvh, d, bs, t, nb = 2, 4, 2, 16, 8, 4, 9
+    args = (
+        jnp.zeros((b, h, d)), jnp.zeros((nb, bs, kvh * d), jnp.int8),
+        jnp.zeros((nb, bs, kvh * d), jnp.int8), jnp.zeros((b, t), jnp.int32),
+        jnp.ones((b, t * bs), jnp.int32),
+        jnp.ones((nb, bs, kvh)), jnp.ones((nb, bs, kvh)),
+    )
+    for variant, k_blocks in (("", 1), ("b2-hb", 2)):
+        jaxpr = jax.make_jaxpr(  # the wrapper's own body, under its jit
+            lambda *a: paged_decode_attention.__wrapped__(
+                *a[:5], bs, *a[5:], interpret=True, variant=variant)
+        )(*args).jaxpr
+        (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+        pools = [jaxpr.invars[i] for i in (1, 2, 5, 6)]
+        fed = [v for v in call.invars if v in pools]
+        assert len(fed) == 4 * k_blocks, variant  # each pool, K block views
+
+
+# ---------------------------------------------------------------------------
+# the pool's layout [NB, BS, C]: writers and readers against the
+# contiguous cache, and the TP spec on a pool leaf
+
+
+@pytest.mark.parametrize("quant", [False, True], ids=["dense", "int8"])
+@pytest.mark.parametrize("kvh,d", [(8, 128), (16, 128), (4, 64)])
+def test_pool_layout_round_trips_against_the_contiguous_cache(kvh, d, quant):
+    from mlmicroservicetemplate_tpu.models.common import kv_quantize
+    from mlmicroservicetemplate_tpu.ops.paged_attention import scatter_pages
+    from mlmicroservicetemplate_tpu.parallel.tpserve import kv_head_spec
+
+    b, bs, s_pre, steps = 3, 4, 6, 3
+    total = s_pre + steps
+    table, nb = _shuffled_table(b, total, bs, seed=kvh)
+    table = jnp.asarray(table)
+    rng = np.random.default_rng(d)
+    kv = jnp.asarray(rng.normal(size=(b, total, kvh, d)).astype(np.float32))
+    # The contiguous cache's leaves, [B, W, KVH, D] (+ [B, W, KVH, 1]).
+    leaves = list(kv_quantize(kv)) if quant else [kv]
+    pools = [
+        jnp.zeros((nb, bs, int(np.prod(x.shape[2:]))), x.dtype) for x in leaves
+    ]
+    assert [p.shape[2] for p in pools] == ([kvh * d, kvh] if quant else [kvh * d])
+    for i, (pool, x) in enumerate(zip(pools, leaves)):
+        for row in range(b):  # the insert: a [W, KVH, D] slice of one row
+            pool = scatter_pages(pool, table[row], x[row, 2:s_pre], bs, start=2)
+            pool = scatter_pages(pool, table[row], x[row, :2], bs)
+        for t in range(s_pre, total):  # the decode step's [B, KVH, D] rows
+            pool = gpt_mod.paged_write_token(
+                pool, table, jnp.full((b,), t, jnp.int32), x[:, t], bs)
+        assert pool.shape == pools[i].shape
+        merged = gather_pages(pool, table, bs)
+        assert merged.shape == (b, table.shape[1] * bs, pool.shape[2])
+        back = gather_pages(pool, table, bs, x.shape[2:])[:, :total]
+        np.testing.assert_array_equal(np.asarray(back), np.asarray(x))
+        # TP: shard i of a pool leaf's merged axis is heads i*KVH/tp ..
+        # whole — what the shard_map'd kernel's local view must hold.
+        spec = kv_head_spec(paged=True)
+        assert tuple(spec) == (None, None, "tp")
+        for tp in (2, 4):
+            c = pool.shape[2] // tp
+            for i_sh in range(tp):
+                lo = i_sh * kvh // tp
+                shard = gather_pages(
+                    pool[:, :, i_sh * c:(i_sh + 1) * c], table, bs,
+                    (kvh // tp,) + tuple(x.shape[3:]),
+                )[:, :total]
+                np.testing.assert_array_equal(
+                    np.asarray(shard), np.asarray(x[:, :, lo:lo + kvh // tp]))
 
 
 # ---------------------------------------------------------------------------

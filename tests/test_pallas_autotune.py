@@ -74,8 +74,13 @@ def _paged_problem(b=2, kvh=2, n_rep=2, d=8, bs=4, t=4, quant=False,
         vf = np.clip(np.round(vf / vsf), -127, 127).astype(np.int8)
         ks = jnp.asarray(ksf.astype(np.float32))
         vs = jnp.asarray(vsf.astype(np.float32))
-    args = (q, jnp.asarray(kf), jnp.asarray(vf), jnp.asarray(table),
-            jnp.asarray(valid))
+
+    def pool(x):  # the pool's layout: [NB, BS, C], token dims merged
+        return jnp.asarray(x.reshape(nb_pool, bs, -1))
+
+    if quant:
+        ks, vs = pool(ks), pool(vs)
+    args = (q, pool(kf), pool(vf), jnp.asarray(table), jnp.asarray(valid))
     ref = paged_attention_ref(*args, bs, k_scale=ks, v_scale=vs)
     return args, ks, vs, ref
 
