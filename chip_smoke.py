@@ -392,7 +392,6 @@ async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
             "tpu_custom_calls_in_decode_step": hlo_calls,
             "pool_relayouts_in_decode_step": relayouts,
             "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
-            "peak_flops": svc.engine.perf.peak_flops,
             "device": st.get("device"),
             "device_kind": st.get("device_kind"),
         },

@@ -133,7 +133,6 @@ def build_app(cfg, bundle: ModelBundle, engine, batcher: Batcher) -> web.Applica
     app.router.add_get("/metrics", handle_metrics)
     app.router.add_get("/debug/trace", handle_trace)
     app.router.add_get("/debug/engine", handle_engine_debug)
-    app.router.add_get("/debug/perf", handle_perf_debug)
     app.router.add_post("/debug/profile", handle_profile)
 
     # Bulk inference lane (JOBS_ENABLED; jobs/api.py): the /v1/batches
@@ -1545,30 +1544,11 @@ async def handle_status(request: web.Request) -> web.Response:
         tstat = batcher.tenancy_status()
         if tstat is not None:
             body["tenancy"] = tstat
-    # Perf observatory (r20; utils/perfobs.py, docs/observability.md):
-    # always-on device busy/bubble + MFU estimate, SLO burn rates —
-    # the compact operator view (/debug/perf has the full detail).
-    perf = getattr(engine, "perf", None)
-    if perf is not None:
-        if fleet is not None:
-            psnap = fleet.perf_status()
-            body["perf"] = {
-                k: v for k, v in psnap.items() if k != "per_replica"
-            }
-        else:
-            psnap = perf.snapshot()
-            body["perf"] = {
-                "enabled": psnap["enabled"],
-                "device_busy_total_s": psnap["device_busy_total_s"],
-                "device_bubble_s": psnap["device_bubble_s"],
-                "busy_ratio": psnap["busy_ratio"],
-                "prep_overlap_s": psnap["prep_overlap_s"],
-                "mfu_estimate": psnap["mfu_estimate"],
-                "modeled_flops_total": psnap["modeled_flops_total"],
-            }
-            slo = getattr(cdl, "slo", None) if cdl is not None else None
-            if slo is not None:
-                body["perf"]["slo"] = slo.snapshot()
+    # SLO burn rates (SLO_TTFT_MS / SLO_TBT_MS; scheduler/policy.py).
+    # A fleet shares ONE tracker, and replica 0's loop holds it.
+    slo = getattr(cdl, "slo", None)
+    if slo is not None:
+        body["slo"] = slo.snapshot()
     tr = tracing.tracer()
     body["observability"] = {
         "trace": tr is not None,
@@ -1690,36 +1670,6 @@ async def handle_engine_debug(request: web.Request) -> web.Response:
     cdl = getattr(batcher, "_cdl", None)
     if cdl is not None:
         body["loop"] = _loop_summary(cdl)
-    return web.json_response(body)
-
-
-async def handle_perf_debug(request: web.Request) -> web.Response:
-    """``GET /debug/perf`` (r20 perf observatory) — the full always-on
-    attribution detail: per-site device busy/bubble estimates, prep
-    overlap, modeled FLOPs by executable kind, the rolling MFU
-    estimate with its raw components, SLO burn rates, and per-replica
-    breakdown in fleet mode (docs/observability.md)."""
-    from ..runtime.compile_cache import cost_stats
-
-    engine = request.app[K_ENGINE]
-    batcher = request.app[K_BATCHER]
-    perf = getattr(engine, "perf", None)
-    if perf is None:
-        raise web.HTTPNotFound(reason="engine has no perf estimator")
-    fleet = getattr(batcher, "fleet", None)
-    if fleet is not None:
-        body = fleet.perf_status()
-    else:
-        body = perf.snapshot()
-        cdl = getattr(batcher, "_cdl", None)
-        slo = getattr(cdl, "slo", None) if cdl is not None else None
-        if slo is not None:
-            body["slo"] = slo.snapshot()
-    body["analyzed_signatures"] = cost_stats()
-    body["dispatch_attribution"] = (
-        engine.dispatch_attribution()
-        if hasattr(engine, "dispatch_attribution") else {}
-    )
     return web.json_response(body)
 
 

@@ -29,7 +29,7 @@ import numpy as np
 
 from ..models.registry import KIND_IMAGE, KIND_SEQ2SEQ, KIND_TEXT, ModelBundle
 from ..parallel import ReplicaSet, make_mesh
-from ..utils import locktrace, metrics, perfobs, tracing
+from ..utils import locktrace, metrics, tracing
 
 log = logging.getLogger(__name__)
 
@@ -77,21 +77,10 @@ class InferenceEngine:
             int(getattr(cfg, "flight_ring", 256))
         )
         # Per-site host-dispatch accounting (always on — two clock
-        # reads per dispatch): {site: [count, host_seconds]}.  bench.py
-        # records it; device time per site is the profiler trace's.
+        # reads per dispatch): {site: [count, host_seconds]}, served
+        # by /debug/engine; device time per site is the profiler trace's.
         self.dispatch_stats: dict[str, list] = {}
         self._dispatch_stats_lock = threading.Lock()
-        # Perf observatory (r20; utils/perfobs.py): always-on device
-        # busy/bubble estimation from submit stamps + the loop's
-        # existing fetch seams — zero extra syncs, PERF_OBS=0 keeps no
-        # timestamps at all (pinned).  The process-level switch also
-        # gates the compile cache's cost-analysis accrual.
-        perfobs.configure(bool(getattr(cfg, "perf_obs", True)))
-        self.perf = perfobs.DeviceOccupancy(
-            bundle.name,
-            enabled=bool(getattr(cfg, "perf_obs", True)),
-            peak_flops=perfobs.peak_flops(cfg),
-        )
         self.faults = FaultInjector.from_spec(
             getattr(cfg, "fault_spec", None),
             int(getattr(cfg, "fault_seed", 0) or 0),
@@ -821,9 +810,9 @@ class InferenceEngine:
         donation — so a retry is token-identical by construction.
 
         Attribution: host submit→return time feeds
-        ``dispatch_host_seconds{site}`` and the per-site stats bench.py
-        records, and the call is one ``dispatch:<site>`` phase
-        (utils/tracing.py) — on the profiler's host plane whenever a
+        ``dispatch_host_seconds{site}`` and the per-site stats
+        ``/debug/engine`` serves, and the call is one
+        ``dispatch:<site>`` phase (utils/tracing.py) — on the profiler's host plane whenever a
         session runs, in the TRACE=1 ring with ``host_ms``.  It never
         waits for the device: device time per site is read from the
         profiler's device trace, which shares the annotation's clock."""
@@ -836,10 +825,6 @@ class InferenceEngine:
             out = self.watchdog.run(site, fn)
             t1 = time.perf_counter()
             self._note_dispatch(site, t1 - t0)
-            # Perf observatory submit stamp: the SAME two clock reads
-            # the host attribution above already paid — no extra
-            # reads, no syncs (utils/perfobs.py).
-            self.perf.on_guard(site, t0, t1)
             ph.set(host_ms=round((t1 - t0) * 1e3, 3))
         return out
 
@@ -851,7 +836,7 @@ class InferenceEngine:
             st[1] += host_s
 
     def dispatch_attribution(self) -> dict:
-        """Per-site dispatch accounting for the BENCH payload:
+        """Per-site dispatch accounting (``/debug/engine``):
         ``{site: {count, host_s, host_ms_avg}}``."""
         out = {}
         with self._dispatch_stats_lock:
@@ -1145,9 +1130,6 @@ class InferenceEngine:
                 toks_np, done_np = self.dispatch_guard(
                     "fetch", lambda: jax.device_get((toks, state.done))
                 )
-                # Completion seam: the fetch returned, so the fused
-                # prefill it consumed has finished on the device.
-                self.perf.note_complete("prefill")
                 chunk, done = toks_np[0], bool(done_np[0])
             # Request max_tokens bounds chunk spending, and the final
             # chunk trims to the exact budget — raw emission never
@@ -1171,7 +1153,6 @@ class InferenceEngine:
                         "fetch",
                         lambda: jax.device_get((toks, state.done)),
                     )
-                    self.perf.note_complete("chunk")
                     chunk, done = toks_np[0], bool(done_np[0])
                 yield chunk[: budget - produced]
                 produced += self.chunk_tokens
@@ -1263,7 +1244,6 @@ class InferenceEngine:
             out_np, ns_np, done_np = self.dispatch_guard(
                 "fetch", lambda: jax.device_get((out, ns, ss.base.done))
             )
-            self.perf.note_complete("prefill")
         chunk = flatten_emitted(out_np, ns_np, 0)
         metrics.SPEC_EMITTED.labels(self.bundle.name).observe(
             int(chunk.size) / max(1, n_verify)
@@ -1308,7 +1288,6 @@ class InferenceEngine:
                     "fetch",
                     lambda: jax.device_get((out, ns, ss.base.done)),
                 )
-                self.perf.note_complete("chunk")
             chunk = flatten_emitted(out_np, ns_np, 0)
             metrics.SPEC_EMITTED.labels(self.bundle.name).observe(
                 int(chunk.size) / max(1, n_verify)

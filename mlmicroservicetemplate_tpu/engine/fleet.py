@@ -965,11 +965,10 @@ class ReplicaFleet:
             "duration_s": round(dt, 3),
         }
         if breakdown:
-            # Scale-up latency attribution (benchmarks/autoscale_ab.py,
-            # the pre-round BASELINE record (removed in PR 22)): where the
-            # spin-up wall went — engine build
-            # + donor broadcast, loop warm, probe dispatch, budget
-            # rebalance — plus the XLA compiles the whole event paid
+            # Scale-up latency attribution: where the spin-up wall
+            # went — engine build + donor broadcast, loop warm, probe
+            # dispatch, budget rebalance — plus the XLA compiles the
+            # whole event paid
             # (zero once a sibling replica populated the
             # ExecutableCache; docs/compilation.md).
             event["breakdown"] = {
@@ -1254,8 +1253,8 @@ class ReplicaFleet:
     def scale_tick(self) -> None:
         """One governor period: sweep breaker evictions, rebuild
         rejoin-due corpses, then act on the governor's load decision.
-        The scaler thread calls this every SCALE_PERIOD_S; tests and
-        benchmarks may call it directly."""
+        The scaler thread calls this every SCALE_PERIOD_S; tests may
+        call it directly."""
         if not self.elastic:
             return
         if self.draining:
@@ -1365,29 +1364,6 @@ class ReplicaFleet:
             rep.cdl.stop()
 
     # -- observability -------------------------------------------------
-
-    def perf_status(self) -> dict:
-        """Fleet-wide device-occupancy rollup (r20 perf observatory):
-        the per-replica estimator snapshots aggregated, plus the
-        replica-tagged detail — what /status.perf and /debug/perf
-        serve in fleet mode."""
-        from ..utils import perfobs
-
-        per = {}
-        snaps = []
-        for rep in self.replicas:
-            p = getattr(rep.engine, "perf", None)
-            if p is None:
-                continue
-            snap = p.snapshot()
-            per[str(rep.id)] = snap
-            if not rep.dead:
-                snaps.append(snap)
-        out = perfobs.merge_snapshots(snaps)
-        out["per_replica"] = per
-        if self._shared_slo is not None:
-            out["slo"] = self._shared_slo.snapshot()
-        return out
 
     @staticmethod
     def _mesh_shape(rep: Replica) -> dict:

@@ -2294,10 +2294,6 @@ class ContinuousDecodeLoop:
             except Exception as e:
                 self._fail_streams([st for st, *_ in started], e)
                 return
-        # One prefill dispatch per distinct (toks, done) pair just
-        # completed on the device (the combined fetch synchronized
-        # with all of them).
-        self._perf_complete("prefill", len(uniq))
         with tracing.phase("loop/insert"):
             self._emit_and_insert(started, fetched)
 
@@ -4586,11 +4582,6 @@ class ContinuousDecodeLoop:
             fetched = self.engine.dispatch_guard(
                 "fetch", lambda: jax.device_get(fetchables)
             )
-            # Perf-observatory completion seam (utils/perfobs.py): the
-            # fetch just synchronized with the oldest in-flight chunk
-            # dispatch finishing on the device — a timestamp the loop
-            # was already paying for, now also a device-occupancy sample.
-            self._perf_complete("chunk")
             self._route_entry(fetched, snapshot, w)
 
     def _deliver_all(self) -> None:
@@ -4606,17 +4597,8 @@ class ContinuousDecodeLoop:
                 "fetch",
                 lambda: jax.device_get([f for f, _, _ in entries]),
             )
-            self._perf_complete("chunk", len(entries))
             for (_, snapshot, w), got in zip(entries, fetched):
                 self._route_entry(got, snapshot, w)
-
-    def _perf_complete(self, site: str, n: int = 1) -> None:
-        """Feed one fetch-seam completion sample to the engine's
-        device-occupancy estimator (duck-typed test engines without
-        one record nowhere)."""
-        p = getattr(self.engine, "perf", None)
-        if p is not None:
-            p.note_complete(site, n)
 
     def _deliver_ready(self) -> None:
         """Opportunistic delivery of in-flight work whose buffers are

@@ -804,8 +804,7 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         overrides["kv_quant"] = True
     # Pallas decode attention (ops/attention.decode_attention).
     # Policy from a pre-round record (removed in PR 22, to be
-    # re-measured on the chip; benchmarks/kv_quant_ab.py, llama-1.1B
-    # int8 weights, B=8): int8-KV through the fused kernel beats the
+    # re-measured on the chip; llama-1.1B int8 weights, B=8): int8-KV through the fused kernel beats the
     # dense XLA path 1.32-1.58x across contexts 512-1792 — in-kernel
     # dequant is what flips round-4's 0.89-0.90x XLA kv-quant loss —
     # while the DENSE kernel variant loses slightly (0.86-0.96x).  So

@@ -20,8 +20,8 @@ so instead of hardcoding one choice this module measures:
    online softmax, work only rearranged); this step enforces it at
    runtime against compiler surprises.
 3. **Time** survivors with the two-scan-length method
-   (``benchmarks/timing.py``: K vs 2K iterations inside one
-   executable, differenced so the dispatch RTT cancels exactly), and
+   (``_time_per_call``: K vs 2K iterations inside one executable,
+   differenced so the dispatch RTT cancels exactly), and
 4. **Install** the winner into the fleet-shared
    ``runtime/compile_cache.ExecutableCache`` keyed by (shape key,
    variant) and journal it into a persistent tuning table, so replica
@@ -38,8 +38,8 @@ reachable only through a pin.
 Import-light (no jax at module import), thread-safe, and counters-
 first: every decision (sweep/hit/pin/install/reject) increments a
 process counter surfaced through ``stats()`` -> /status.decode, the
-``pallas_autotune_events_total`` metric and the PERF_SMOKE structural
-gate.
+``pallas_autotune_events_total`` metric and the structural-counter
+tests (tests/test_structural_counters.py).
 """
 
 from __future__ import annotations
@@ -202,17 +202,14 @@ def enumerate_variants(kind: str, *, t: int, bs: int, kvh: int, d: int,
 
 
 def _time_per_call(fn, args, iters: int, reps: int):
-    """Two-scan-length device time (benchmarks/timing.py).  The
-    benchmarks tree is not a package inside a deployed service, so
-    fall back to an inline copy of the same method when the repo
-    checkout is not importable."""
-    try:
-        from benchmarks.timing import device_time_per_call
-
-        return device_time_per_call(fn, args, carry_idx=0, iters=iters,
-                                    reps=reps)
-    except ImportError:
-        pass
+    """Two-scan-length device time: the wall time of K iterations
+    inside ONE executable is K x device time + one round trip, so the
+    difference of a K and a 2K scan cancels the round trip exactly.
+    The scan body carries a scalar dependency into the next iteration
+    (input + carry*0: a no-op XLA must still honor), so the loop can
+    be neither collapsed nor hoisted.  Returns (seconds a call, noisy):
+    ``noisy`` = the 2K scan measured no slower than the K scan, and
+    the value is wall_K / K, an upper bound."""
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -575,8 +572,8 @@ def ensure_tuned(kind: str, bundle, replicas, *, b: int, kvh: int,
 
 
 def stats() -> dict:
-    """Counters + table + last sweep details: /status.decode.autotune,
-    the PERF_SMOKE gate and BENCH json all read this one snapshot."""
+    """Counters + table + last sweep details: /status.decode.autotune
+    and tests/test_structural_counters.py read this one snapshot."""
     with _LOCK:
         return {
             "counts": dict(_COUNTS),

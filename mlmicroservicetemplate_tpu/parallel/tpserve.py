@@ -46,7 +46,7 @@ _MESH_CACHE: dict = {}
 _LOCK = threading.Lock()
 
 # Thread-local device group for trace-time mesh reconstruction.  The
-# fleet's executable proxies (runtime/compile_cache._CostedExecutable)
+# fleet's executable proxies (runtime/compile_cache._GroupPinned)
 # set this around every call/lower so model-fn shard_maps traced on a
 # non-prefix replica rebuild the mesh over THAT replica's devices.
 # Thread-local (not a plain global) because the watchdog runs dispatches

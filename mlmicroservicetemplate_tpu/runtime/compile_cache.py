@@ -57,7 +57,7 @@ import time
 from collections import OrderedDict
 from typing import Any, Callable
 
-from ..utils import metrics, perfobs
+from ..utils import metrics
 
 _LOCK = threading.RLock()
 _CACHE: OrderedDict[tuple, Any] = OrderedDict()
@@ -184,127 +184,36 @@ def placement_key(replicas: Any) -> tuple:
     return ("replicas", id(replicas))
 
 
-# -- modeled-cost accounting (r20 perf observatory) --------------------
-#
-# Every shared executable is wrapped in a thin proxy that, on the
-# first call per argument signature, runs ``Lowered.cost_analysis()``
-# — a trace + lower with ZERO backend compiles (the zero-compile spawn
-# pins stay intact) and zero dispatches — and from then on accrues the
-# memoized modeled FLOPs/bytes into the perfobs book on every call.
-# "Analyzed once per executable": the proxy lives in the process-level
-# cache, so every engine/replica sharing the wrapper shares the memo;
-# a (wrapper, signature) pair IS one XLA executable.  PERF_OBS=0 skips
-# everything past one boolean check per call.
+class _GroupPinned:
+    """Call-transparent proxy for an executable built for a non-prefix
+    device group (a multi-chip fleet replica): it re-enters its
+    group's thread-local around every call and lower, so a model-fn
+    ``shard_map`` traced from ANY thread (continuous loop, watchdog
+    daemon, warmers) reconstructs ``serving_tp_mesh`` over the
+    replica's own devices — parallel/tpserve.py.  A single-group
+    deployment never sees one: the cache hands out the jitted
+    function itself."""
 
-#: Distinct call signatures analyzed per wrapper before the proxy
-#: stops analyzing new ones (a signature that never memoizes — e.g. a
-#: pathological pytree — must not re-pay a trace+lower per dispatch).
-MAX_SIGS = 16
+    __slots__ = ("_fn", "_group")
 
-
-def _sig_item(a: Any) -> Any:
-    """Cheap hashable shape signature for one call argument: scalars by
-    value, arrays by (shape, dtype), containers recursively (lists of
-    per-layer cache entries stay cheap), opaque pytrees by identity
-    (``params`` is a stable dict on the engine)."""
-    if a is None or isinstance(a, (bool, int, float, str)):
-        return a
-    shp = getattr(a, "shape", None)
-    dt = getattr(a, "dtype", None)
-    if shp is not None and dt is not None:
-        return (tuple(shp), str(dt))
-    if isinstance(a, dict):
-        return ("dict", id(a))
-    if isinstance(a, (tuple, list)) and len(a) <= 64:
-        return (type(a).__name__,) + tuple(_sig_item(x) for x in a)
-    if hasattr(a, "_fields"):  # NamedTuple decode states
-        return ("nt",) + tuple(_sig_item(getattr(a, f)) for f in a._fields)
-    return ("obj", id(a))
-
-
-class _CostedExecutable:
-    """Call-transparent proxy accruing modeled FLOPs per dispatch.
-
-    Also the trace-group pin: an executable built for a non-prefix
-    device group (multi-chip fleet replica) re-enters its group's
-    thread-local around every call/lower, so a model-fn ``shard_map``
-    traced from ANY thread (continuous loop, watchdog daemon, warmers)
-    reconstructs ``serving_tp_mesh`` over the replica's own devices —
-    parallel/tpserve.py.  ``_group is None`` (every single-group
-    serving stack) costs one attribute check per call."""
-
-    __slots__ = ("_fn", "_kind", "_model", "_costs", "_costs_lock",
-                 "_group")
-
-    def __init__(self, fn: Any, kind: str, model: str, group=None):
+    def __init__(self, fn: Any, group: tuple):
         self._fn = fn
-        self._kind = kind
-        self._model = model
-        self._costs: dict = {}
-        self._costs_lock = threading.Lock()
         self._group = group
 
     def __call__(self, *args, **kwargs):
-        if self._group is not None:
-            from ..parallel.tpserve import use_trace_group
+        from ..parallel.tpserve import use_trace_group
 
-            with use_trace_group(self._group):
-                out = self._fn(*args, **kwargs)
-        else:
-            out = self._fn(*args, **kwargs)
-        if perfobs.enabled():
-            sig = tuple(_sig_item(a) for a in args)
-            c = self._costs.get(sig)
-            if c is None:
-                c = self._analyze(sig, args, kwargs)
-            if c[0] or c[1]:
-                perfobs.note_cost(self._model, self._kind, c[0], c[1])
-        return out
+        with use_trace_group(self._group):
+            return self._fn(*args, **kwargs)
 
-    def _analyze(self, sig, args, kwargs) -> tuple[float, float]:
-        with self._costs_lock:
-            if sig in self._costs:
-                return self._costs[sig]
-            if len(self._costs) >= MAX_SIGS:
-                return (0.0, 0.0)  # saturated: stop analyzing new sigs
-            try:
-                if self._group is not None:
-                    from ..parallel.tpserve import use_trace_group
+    def lower(self, *args, **kwargs):
+        from ..parallel.tpserve import use_trace_group
 
-                    with use_trace_group(self._group):
-                        ca = self._fn.lower(
-                            *args, **kwargs).cost_analysis()
-                else:
-                    ca = self._fn.lower(*args, **kwargs).cost_analysis()
-                if isinstance(ca, (list, tuple)):
-                    ca = ca[0] if ca else {}
-                cost = (
-                    float(ca.get("flops", 0.0) or 0.0),
-                    float(ca.get("bytes accessed", 0.0) or 0.0),
-                )
-            except Exception:
-                # Backends without HLO cost analysis (or un-lowerable
-                # duck-typed test fns): this executable just accrues
-                # nothing — the estimator degrades, serving does not.
-                cost = (0.0, 0.0)
-            self._costs[sig] = cost
-            return cost
+        with use_trace_group(self._group):
+            return self._fn.lower(*args, **kwargs)
 
     def __getattr__(self, name: str):
-        # Transparent for .lower()/.trace()/attribute probes.
         return getattr(self._fn, name)
-
-
-def cost_stats() -> dict:
-    """Analyzed-signature counts per cached wrapper kind (/status +
-    tests): {kind: n_signatures}."""
-    out: dict[str, int] = {}
-    with _LOCK:
-        entries = list(_CACHE.items())
-    for key, fn in entries:
-        if isinstance(fn, _CostedExecutable):
-            out[key[1]] = out.get(key[1], 0) + len(fn._costs)
-    return out
 
 
 def shared_executable(kind: str, bundle: Any, replicas: Any,
@@ -318,7 +227,6 @@ def shared_executable(kind: str, bundle: Any, replicas: Any,
         bundle_fingerprint(bundle), kind, tuple(statics),
         placement_key(replicas),
     )
-    model = getattr(bundle, "name", "?")
     with _LOCK:
         fn = _CACHE.get(key)
         if fn is not None:
@@ -339,9 +247,9 @@ def shared_executable(kind: str, bundle: Any, replicas: Any,
         # Build (and later call/lower) under the placement's device
         # group so any eager trace lands on the right mesh.
         with use_trace_group(grp):
-            fn = _CostedExecutable(build(), kind, model, group=grp)
+            fn = _GroupPinned(build(), grp)
     else:
-        fn = _CostedExecutable(build(), kind, model)
+        fn = build()
     with _LOCK:
         # A racing builder may have inserted meanwhile: last wins is
         # fine (both wrappers are correct; one just goes unshared), but
@@ -356,12 +264,11 @@ def shared_executable(kind: str, bundle: Any, replicas: Any,
         while len(_CACHE) > MAX_ENTRIES:
             _CACHE.popitem(last=False)
     metrics.EXEC_CACHE_EVENTS.labels("insert").inc()
-    _ = model  # model kept out of the series: ≤1 label, bounded
     return fn
 
 
 def cache_stats() -> dict:
-    """{entries, hit, miss, insert} — /status.compile + BENCH json."""
+    """{entries, hit, miss, insert} — /status.compile."""
     with _LOCK:
         return {"entries": len(_CACHE), **_COUNTS}
 
@@ -388,8 +295,8 @@ def clear() -> None:
 
 def note_warm_phase(model: str, phase: str, seconds: float) -> None:
     """Record one warm phase's wall seconds: feeds
-    ``engine_warm_seconds{phase}`` and the process totals bench.py's
-    ``warmup`` block reads."""
+    ``engine_warm_seconds{phase}`` and the process totals
+    ``/status.compile`` reports."""
     metrics.WARM_SECONDS.labels(model, phase).observe(seconds)
     with _WARM_LOCK:
         _WARM_PHASES[phase] = _WARM_PHASES.get(phase, 0.0) + seconds
@@ -413,6 +320,6 @@ class warm_phase:
 
 
 def warm_stats() -> dict:
-    """Accumulated per-phase warm seconds for /status + BENCH."""
+    """Accumulated per-phase warm seconds for /status.compile."""
     with _WARM_LOCK:
         return {k: round(v, 4) for k, v in sorted(_WARM_PHASES.items())}

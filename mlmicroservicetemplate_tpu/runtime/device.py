@@ -121,7 +121,7 @@ def apply_device_env(device: str, compile_cache_dir: str | None = None
         return
     os.environ["JAX_PLATFORMS"] = "cpu"
     # jax reads JAX_PLATFORMS once, at import, and callers of
-    # build_service (tests, benchmarks) have usually imported it by
+    # build_service (tests, cellbench) have usually imported it by
     # now — so set the config too.  The backend initializes lazily:
     # this works any time before the first device use; afterwards we
     # can only verify.

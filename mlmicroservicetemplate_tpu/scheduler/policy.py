@@ -298,7 +298,7 @@ class SLOTracker:
                 )
 
     def snapshot(self) -> dict:
-        """/status.perf.slo + /debug/perf: objectives + burn rates."""
+        """/status.slo: objectives + burn rates."""
         now = self._clock()
         out: dict = {
             "target": self.target,

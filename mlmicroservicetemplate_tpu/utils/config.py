@@ -43,7 +43,7 @@ class ServiceConfig(BaseModel):
     # OFF on cpu — CPU compiles are fast and golden tests want cold
     # compiles).  A path enables it anywhere; "0"/"off" disables even
     # on tpu.  The same setting is also read from the
-    # COMPILE_CACHE_DIR env var for pre-config callers (benchmarks).
+    # COMPILE_CACHE_DIR env var for pre-config callers.
     compile_cache_dir: str | None = None
 
     # HTTP surface (L4).
@@ -154,12 +154,10 @@ class ServiceConfig(BaseModel):
     # accepted-token multiplier instead of losing drafting beyond
     # spec_max_streams.  Costs a (spec_k+1)-wide window per row per
     # round — wins on quoting/repetitive traffic, can lose on
-    # low-acceptance traffic at high width (measure before enabling:
-    # benchmarks/streams_scaling.py prints the spec_continuous column
-    # by default; BENCH_SPEC=0 skips it).  Stacks with PREFIX_CACHE
-    # (round 6): hit admissions recast through init_spec_fn at
-    # slot-insert time, so prefix-hit streams join the spec slot
-    # batch (benchmarks/compose_ab.py measures the stack).  With
+    # low-acceptance traffic at high width (not measured on this
+    # chip: no cell yet).  Stacks with PREFIX_CACHE (round 6): hit
+    # admissions recast through init_spec_fn at slot-insert time, so
+    # prefix-hit streams join the spec slot batch.  With
     # SPEC_SAMPLED=0, sampled streams bypass the loop to the
     # per-stream chunked path so the strict seed contract holds.
     spec_continuous: bool = False
@@ -242,8 +240,8 @@ class ServiceConfig(BaseModel):
     # Contiguous-slab Pallas attention cutover: prompts at or under
     # this length run the single-block fused kernel (ops/attention.
     # use_pallas_attention); longer prompts take the XLA path.  Env is
-    # read by ops/attention directly (config-less callers: benchmarks,
-    # unit tests); this field validates it at boot.
+    # read by ops/attention directly (config-less callers: unit
+    # tests); this field validates it at boot.
     pallas_single_block_max_seq: int = 512
     # VMEM budget (MB) the decode-kernel fit gate AND the autotuner's
     # variant cost model filter against (ops/attention.
@@ -486,19 +484,6 @@ class ServiceConfig(BaseModel):
     # restores the seed's error-every-stream behavior on a fault.
     supervise: bool = True
 
-    # Perf observatory (r20; utils/perfobs.py, docs/observability.md).
-    # Always-on device-time attribution: every guarded dispatch is
-    # stamped at submit and completion is sampled at the loop's
-    # existing fetch seams — device busy/bubble, prep overlap and a
-    # rolling MFU estimate with ZERO extra device syncs (an estimate
-    # from host clocks: a profiler trace gives the real device times).
-    # 0 = the layer keeps no timestamps at all and
-    # the compile cache skips cost analysis (pinned).
-    perf_obs: bool = True
-    # Peak chip TFLOP/s for the MFU denominator; 0 = auto (TPU
-    # device-kind table; unknown on CPU, so mfu_estimate stays 0 and
-    # /debug/perf carries the raw FLOP components instead).
-    peak_tflops: float = 0.0
     # Latency histogram bucket edges (comma-separated ascending
     # seconds) for the request/TTFT latency families in
     # utils/metrics.py; unset = the built-in defaults, which since r20
@@ -1021,16 +1006,16 @@ class ServiceConfig(BaseModel):
             raise ValueError("TRACE_RING/FLIGHT_RING must be >= 0")
         return v
 
-    @field_validator("peak_tflops", "slo_ttft_ms", "slo_tbt_ms",
+    @field_validator("slo_ttft_ms", "slo_tbt_ms",
                      "slo_batch_ttft_ms", "slo_batch_tbt_ms",
                      "scale_up_slo_burn")
     @classmethod
-    def _check_perf_nonneg(cls, v: float) -> float:
+    def _check_slo_nonneg(cls, v: float) -> float:
         if v < 0:
             raise ValueError(
-                "PEAK_TFLOPS/SLO_TTFT_MS/SLO_TBT_MS/SLO_BATCH_TTFT_MS/"
+                "SLO_TTFT_MS/SLO_TBT_MS/SLO_BATCH_TTFT_MS/"
                 "SLO_BATCH_TBT_MS/SCALE_UP_SLO_BURN must be >= 0 "
-                "(0 = off/auto)"
+                "(0 = off)"
             )
         return v
 
@@ -1107,7 +1092,7 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
       SCALE_UP_KV_FRAC, SCALE_UP_TTFT_MS, SCALE_UP_COOLDOWN_S,
       SCALE_DOWN_LOAD, SCALE_DOWN_COOLDOWN_S, SCALE_PERIOD_S,
       TRACE, TRACE_RING, FLIGHT_RING, PROFILE_DIR, LOG_FORMAT,
-      COMPILE_CACHE_DIR, HOST_PREP_DOUBLE, PERF_OBS, PEAK_TFLOPS,
+      COMPILE_CACHE_DIR, HOST_PREP_DOUBLE,
       LATENCY_BUCKETS, SLO_TTFT_MS, SLO_TBT_MS, SLO_BATCH_TTFT_MS,
       SLO_BATCH_TBT_MS, SLO_TARGET, SLO_WINDOWS_S, SCALE_UP_SLO_BURN.
     """
@@ -1219,7 +1204,6 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
         ("scale_down_cooldown_s", "SCALE_DOWN_COOLDOWN_S"),
         ("scale_period_s", "SCALE_PERIOD_S"),
         ("engine_restart_window_s", "ENGINE_RESTART_WINDOW_S"),
-        ("peak_tflops", "PEAK_TFLOPS"),
         ("slo_ttft_ms", "SLO_TTFT_MS"),
         ("slo_tbt_ms", "SLO_TBT_MS"),
         ("slo_batch_ttft_ms", "SLO_BATCH_TTFT_MS"),
@@ -1230,9 +1214,6 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
         v = get(var)
         if v is not None:
             kwargs[field] = float(v)
-    v = get("PERF_OBS")
-    if v is not None:
-        kwargs["perf_obs"] = v.lower() not in ("0", "false", "no")
     v = get("PREEMPT")
     if v is not None:
         kwargs["preempt"] = v.lower() not in ("0", "false", "no")

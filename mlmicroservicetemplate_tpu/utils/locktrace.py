@@ -60,8 +60,7 @@ def _creation_site() -> str:
         fn = f.f_code.co_filename
         if "locktrace" not in fn and "threading" not in fn:
             short = fn
-            for marker in ("mlmicroservicetemplate_tpu", "tests",
-                           "benchmarks", "tools"):
+            for marker in ("mlmicroservicetemplate_tpu", "tests", "tools"):
                 idx = fn.find(marker)
                 if idx >= 0:
                     short = fn[idx:]

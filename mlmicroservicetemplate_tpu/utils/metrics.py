@@ -475,35 +475,7 @@ TBT = Histogram(
     "the decode-cadence series the chunked-prefill A/B judges",
     ["model"], buckets=_FINE_BUCKETS,
 )
-# -- perf observatory (r20; utils/perfobs.py, docs/observability.md) --
-DEVICE_BUSY = Counter(
-    "device_busy_seconds",
-    "Estimated device-busy seconds by dispatch site, derived from "
-    "submit timestamps + the loop's existing fetch seams (zero extra "
-    "syncs, always on; host clocks, not the device trace)",
-    ["model", "site"],
-)
-DEVICE_BUBBLE = Counter(
-    "device_bubble_seconds",
-    "Estimated device idle gaps between attributed busy intervals "
-    "(time the chip sat waiting on host dispatch/prep — the quantity "
-    "the host-side levers shrink)",
-    ["model"],
-)
-MODELED_FLOPS = Counter(
-    "modeled_flops_total",
-    "Modeled FLOPs accrued per dispatched executable kind "
-    "(XLA cost_analysis, analyzed once per executable at the shared "
-    "compile cache — runtime/compile_cache.py)",
-    ["model", "kind"],
-)
-MFU = Gauge(
-    "mfu_estimate",
-    "Rolling model-FLOPs-utilization estimate: modeled FLOP rate over "
-    "peak chip FLOPs (PEAK_TFLOPS knob or device-kind table; 0 when "
-    "the peak is unknown — /debug/perf carries the raw components)",
-    ["model"],
-)
+# -- SLO burn rates (scheduler/policy.SLOTracker, docs/observability.md) --
 SLO_TTFT_BURN = Gauge(
     "slo_ttft_burn_rate",
     "Per-priority-class TTFT SLO burn rate by window (fast/slow): "

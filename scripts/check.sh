@@ -20,7 +20,7 @@ set -o pipefail
 cd "$(dirname "$0")/.."
 
 echo "== compileall =="
-python -m compileall -q mlmicroservicetemplate_tpu tests benchmarks tools || exit 1
+python -m compileall -q mlmicroservicetemplate_tpu tests tools || exit 1
 
 # graftlint: the repo-specific invariants no generic linter knows —
 # dispatch-guard coverage, write-ahead ordering, clock injection, knob
@@ -40,9 +40,9 @@ fi
 if [ "${RUFF:-1}" != "0" ]; then
     echo "== ruff =="
     if command -v ruff >/dev/null 2>&1; then
-        ruff check mlmicroservicetemplate_tpu tests benchmarks tools || exit 1
+        ruff check mlmicroservicetemplate_tpu tests tools || exit 1
     elif python -c "import ruff" >/dev/null 2>&1; then
-        python -m ruff check mlmicroservicetemplate_tpu tests benchmarks tools || exit 1
+        python -m ruff check mlmicroservicetemplate_tpu tests tools || exit 1
     else
         echo "ruff binary absent; skipping (nothing may be pip-installed here)"
     fi
@@ -53,22 +53,6 @@ fi
 if [ "$1" = "--fast" ]; then
     echo "== tier-1 tests skipped (--fast) =="
     exit 0
-fi
-
-# Perf-regression gate (r20, docs/observability.md): a deterministic
-# tiny workload's STRUCTURAL counters — chunk/prefill dispatch counts,
-# serving-path XLA compiles (must be 0), host syncs per token, staged
-# host-prep activity — diffed against the committed
-# benchmarks/perf_baseline.json.  Wall-clock appears nowhere, so the
-# gate is CPU-noise-immune by construction.  PERF_SMOKE=0 skips;
-# PERF_SMOKE_UPDATE=1 rewrites the baseline (deliberately, in the PR
-# that changes the structure).
-if [ "${PERF_SMOKE:-1}" != "0" ]; then
-    echo "== perf smoke (structural counters vs committed baseline) =="
-    timeout -k 10 240 env JAX_PLATFORMS=cpu PERF_LEDGER="${PERF_LEDGER:-0}" \
-        python scripts/perf_smoke.py || exit 1
-else
-    echo "== perf smoke skipped (PERF_SMOKE=0) =="
 fi
 
 # Chaos tier: the fault-injection/recovery suite (kept OUT of tier-1 by

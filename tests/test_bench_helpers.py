@@ -1,38 +1,14 @@
-"""benchmarks/harness.py scrape helpers: the A/B harnesses now read
-``stream_tbt_seconds`` from a real ``/metrics`` scrape, so the
-text-format parsing and the bucket-percentile arithmetic get pinned
+"""The benchmark's ``/metrics`` readers (``cellbench/reduce.py``): every
+``prom_hist`` per-layer metric is a scrape before and after the window,
+``hist_delta`` between them and ``hist_pctile`` over the difference, so
+the text-format parsing and the bucket-percentile arithmetic are pinned
 here (pure logic, no service)."""
 
 import math
-import os
-import sys
 
 import pytest
 
-sys.path.insert(
-    0, os.path.join(os.path.dirname(os.path.dirname(__file__)), "benchmarks")
-)
-from harness import hist_delta, hist_pctile, scrape_histogram  # noqa: E402
-
-
-class _FakeResp:
-    status = 200
-
-    def __init__(self, text):
-        self._text = text
-
-    async def text(self):
-        return self._text
-
-
-class _FakeClient:
-    def __init__(self, text):
-        self._text = text
-
-    async def get(self, path):
-        assert path == "/metrics"
-        return _FakeResp(self._text)
-
+from cellbench.reduce import hist_delta, hist_pctile, parse_prom
 
 SCRAPE = """\
 # HELP stream_tbt_seconds Streaming inter-chunk delivery gap
@@ -49,9 +25,7 @@ other_series_total{model="gpt2"} 5.0
 
 
 def _scrape(text):
-    import asyncio
-
-    return asyncio.run(scrape_histogram(_FakeClient(text), "stream_tbt_seconds"))
+    return parse_prom(text)["stream_tbt_seconds"]
 
 
 def test_scrape_histogram_parses_family():
@@ -78,6 +52,7 @@ def test_hist_delta_isolates_section():
     after = {
         "count": 14.0,
         "sum": 5.0,
+        "value": 0.0,
         "buckets": {0.001: 2.0, 0.01: 8.0, 1.0: 13.0, math.inf: 14.0},
     }
     d = hist_delta(after, before)

@@ -165,6 +165,49 @@ def test_cache_keying_never_aliases():
     assert cc.bundle_fingerprint(b1) != cc.bundle_fingerprint(b2)
 
 
+def test_shared_executable_is_bare_without_group():
+    """A single-group deployment dispatches straight into JAX: the
+    cache hands out the jitted function itself.  Only a non-prefix
+    device group (a multi-chip fleet replica) gets the proxy, which
+    pins its trace group around every call and lower."""
+    import jax
+
+    from mlmicroservicetemplate_tpu.parallel import TensorParallelSet
+    from mlmicroservicetemplate_tpu.parallel.tp import gpt_param_spec
+    from mlmicroservicetemplate_tpu.parallel.tpserve import (
+        current_trace_group,
+        serving_tp_mesh,
+    )
+
+    b = tiny_gpt_bundle(tp=2)
+    spec = gpt_param_spec(b.cfg)
+    built, traced_under = [], []
+
+    def build():
+        def f(x):
+            traced_under.append(current_trace_group())
+            return x + 1
+
+        built.append(jax.jit(f))
+        return built[-1]
+
+    assert cc.shared_executable(
+        "bare", b, ReplicaSet(make_mesh(1)), build) is built[0]
+    # The default prefix group is no group (device_group normalizes it).
+    assert cc.shared_executable(
+        "bare", b, TensorParallelSet(serving_tp_mesh(2, 1), spec), build
+    ) is built[1]
+    pinned = cc.shared_executable(
+        "bare", b, TensorParallelSet(serving_tp_mesh(2, 1, (4, 5)), spec),
+        build,
+    )
+    assert pinned is not built[2] and pinned._fn is built[2]
+    assert int(pinned(1)) == 2
+    pinned.lower(1.0)  # another dtype: traced again
+    assert traced_under == [(4, 5), (4, 5)]
+    assert current_trace_group() is None
+
+
 # ---------------------------------------------------------------------------
 # 3. COMPILE_CACHE_DIR as a ServiceConfig knob + the disk layer
 

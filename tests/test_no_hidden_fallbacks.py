@@ -13,7 +13,6 @@ import pytest
 
 from mlmicroservicetemplate_tpu.ops import autotune
 from mlmicroservicetemplate_tpu.runtime import device as device_mod
-from mlmicroservicetemplate_tpu.utils import perfobs
 from mlmicroservicetemplate_tpu.utils.config import load_config
 
 REPO = pathlib.Path(__file__).resolve().parent.parent
@@ -125,35 +124,6 @@ def test_all_error_sweep_raises_and_persists_nothing(tmp_path, monkeypatch):
         ) == ""
     finally:
         autotune.clear()
-
-
-# -- perfobs: an unknown TPU is an error, not MFU 0 ---------------------------
-
-
-class _Dev:
-    def __init__(self, platform, kind):
-        self.platform, self.device_kind = platform, kind
-
-
-@pytest.mark.parametrize("platform,kind,want", [
-    ("tpu", "TPU v5 lite", 197e12),
-    ("tpu", "TPU v9x", ValueError),
-    ("cpu", "cpu", 0.0),
-])
-def test_peak_flops_by_device_kind(monkeypatch, platform, kind, want):
-    monkeypatch.delenv("PEAK_TFLOPS", raising=False)
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(platform, kind)])
-    if want is ValueError:
-        with pytest.raises(ValueError, match="no peak FLOP/s known"):
-            perfobs.peak_flops()
-    else:
-        assert perfobs.peak_flops() == want
-
-
-def test_peak_tflops_knob_overrides_unknown_kind(monkeypatch):
-    monkeypatch.setenv("PEAK_TFLOPS", "100")
-    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev("tpu", "TPU v9x")])
-    assert perfobs.peak_flops() == 100e12
 
 
 # -- the compile cache is placed from outside --------------------------------

@@ -394,7 +394,7 @@ def test_pin_skips_sweep_and_zero_serve_compiles():
 
 
 def test_sweep_records_timings_for_ab():
-    """benchmarks/pallas_ab.py reads per-variant µs out of stats() —
+    """/status.decode.autotune serves per-variant µs out of stats() —
     the sweep must journal them."""
     autotune.ensure_tuned(
         "paged_decode", _Bundle(), None, **_SHAPE,

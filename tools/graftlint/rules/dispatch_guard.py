@@ -59,17 +59,6 @@ _DIRECT_RE = re.compile(
 
 _WARM_RE = re.compile(r"^_?warm")
 
-# Perf-observatory timestamp-capture APIs (r20, utils/perfobs.py):
-# submit stamps and completion samples are only honest when they ride
-# the dispatch_guard boundary or a fetch seam — a capture site in a
-# function that never dispatches under the guard is inventing device
-# timestamps the estimator will faithfully mis-account.  The
-# ``_perf_complete`` helper is the streams-side seam wrapper; its
-# CALLERS are checked, its own body is the definition.
-_PERF_CAPTURE = {"note_submit", "note_complete", "on_guard",
-                 "_perf_complete"}
-_PERF_EXEMPT_FUNCS = {"dispatch_guard", "_perf_complete"}
-
 _SCOPES = (
     "mlmicroservicetemplate_tpu/engine/",
     "mlmicroservicetemplate_tpu/scheduler/",
@@ -98,10 +87,7 @@ class DispatchGuardRule:
     waiver = "unguarded"
     doc = ("device dispatches in engine//scheduler/ must run under "
            "dispatch_guard(site, ...) — else the watchdog, fault "
-           "injection, breaker and attribution never see them; perf "
-           "timestamp-capture calls (note_submit/note_complete/"
-           "on_guard) must live in functions that dispatch under the "
-           "guard (the r20 zero-sync estimator's honesty contract)")
+           "injection, breaker and attribution never see them")
 
     def applies(self, rel: str) -> bool:
         return (
@@ -163,56 +149,6 @@ class DispatchGuardRule:
                 f"device dispatch `{surface}` outside dispatch_guard — "
                 f"the watchdog/fault-injector/attribution never see it "
                 f"(wrap it, or waive: # graftlint: unguarded(reason))",
-                end_line=getattr(node, "end_lineno", node.lineno),
-            ))
-        findings.extend(self._check_perf_capture(ctx))
-        return findings
-
-    def _check_perf_capture(self, ctx: Context) -> list[Finding]:
-        """Perf-observatory capture sites (r20): a ``note_submit`` /
-        ``note_complete`` / ``on_guard`` / ``_perf_complete`` call must
-        sit inside a function that itself dispatches under
-        ``dispatch_guard`` (the fetch/dispatch seams) — anywhere else
-        the timestamp it captures describes no device event."""
-        # Functions whose body contains a dispatch_guard/watchdog-run
-        # call: the legitimate seams.
-        guard_fns: set[str] = set()
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                continue
-            for sub in ast.walk(node):
-                if isinstance(sub, ast.Call):
-                    name = callee_name(sub)
-                    if name in ("dispatch_guard", "guard") or (
-                        name == "run"
-                        and "watchdog" in dotted_name(sub.func).lower()
-                    ):
-                        guard_fns.add(node.name)
-                        break
-        findings: list[Finding] = []
-        for node in ast.walk(ctx.tree):
-            if not isinstance(node, ast.Call):
-                continue
-            name = callee_name(node)
-            if name not in _PERF_CAPTURE:
-                continue
-            enclosing = None
-            for anc in ctx.ancestors(node):
-                if isinstance(anc, (ast.FunctionDef, ast.AsyncFunctionDef)):
-                    enclosing = anc.name
-                    break
-            if enclosing is not None and (
-                enclosing in guard_fns
-                or enclosing in _PERF_EXEMPT_FUNCS
-                or _WARM_RE.match(enclosing)
-            ):
-                continue
-            findings.append(Finding(
-                self.id, ctx.rel, node.lineno,
-                f"perf capture `{name}` in a function that never "
-                f"dispatches under dispatch_guard — the timestamp "
-                f"describes no device event (move it to a guard/fetch "
-                f"seam, or waive: # graftlint: unguarded(reason))",
                 end_line=getattr(node, "end_lineno", node.lineno),
             ))
         return findings
