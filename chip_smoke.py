@@ -333,22 +333,30 @@ def kernel_vs_reference(svc: "Service", variant: str) -> dict:
             "max_abs_err": err, "tolerance": KERNEL_ATOL}
 
 
-def pool_relayouts_in_step(cdl) -> list[str]:
-    """Pool-sized ``reshape``/``copy``/``transpose`` instructions inside
-    the compiled paged chunk's step loop (the state is not donated, so
-    ENTRY copies each pool once a chunk: not counted).  The pool's
-    layout rule (ops/paged_attention.py) says there are none."""
+def pool_moves(cdl, bucket: int) -> tuple[list[str], list[str]]:
+    """Pool-sized ``reshape``/``copy``/``transpose`` instructions of the
+    compiled serving programs: (inside the paged chunk's step loop —
+    the pool's layout rule, ops/paged_attention.py, says there are none;
+    anywhere in the chunk and in a slot insert of ``bucket`` tokens,
+    ENTRY included — the decode state is donated, engine/streams.py, so
+    the compiler aliases every pool's input to its output and none is
+    copied on the way in)."""
     import jax
 
     from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
 
     st = cdl._state
     sizes = {int(x.size) for x in jax.tree.leaves((st.cache_k, st.cache_v))}
-    return pool_relayouts(
-        cdl.paged_chunk_hlo(compiled=True), sizes, in_loop_only=True)
+    chunk = cdl.paged_chunk_hlo(compiled=True)
+    return (
+        pool_relayouts(chunk, sizes, in_loop_only=True),
+        pool_relayouts(chunk, sizes)
+        + pool_relayouts(cdl.paged_insert_hlo(bucket), sizes),
+    )
 
 
-async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
+async def run_streams(svc: Service, prompts: list[str], model: str,
+                      bucket: int) -> dict:
     """Serve ``prompts`` concurrently (at most MAX_STREAMS per replica
     in flight: past that the server sheds 503 by design); return the
     phase facts."""
@@ -369,7 +377,7 @@ async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
     counts = dec.get("autotune", {})
     hlo_calls = [cdl.paged_chunk_hlo().count("tpu_custom_call")
                  for cdl in svc.decode_loops()]
-    relayouts = [pool_relayouts_in_step(cdl) for cdl in svc.decode_loops()]
+    moves = [pool_moves(cdl, bucket) for cdl in svc.decode_loops()]
     mean_batch = (s1 - s0) / max(c1 - c0, 1.0)
     check(all(f["tokens_generated"] > 0 for f in finals), "a stream was empty")
     check(mean_batch > 1.0,
@@ -390,7 +398,8 @@ async def run_streams(svc: Service, prompts: list[str], model: str) -> dict:
             ),
             "autotune": counts,
             "tpu_custom_calls_in_decode_step": hlo_calls,
-            "pool_relayouts_in_decode_step": relayouts,
+            "pool_relayouts_in_decode_step": [m[0] for m in moves],
+            "pool_copies_at_entry": [m[1] for m in moves],
             "peak_bytes_in_use": [s.get("peak_bytes_in_use") for s in stats],
             "device": st.get("device"),
             "device_kind": st.get("device_kind"),
@@ -429,7 +438,7 @@ async def stream_phase(a) -> None:
     kern = {**base, "PALLAS_AUTOTUNE": "1"}
     async with Service(kern, {**env, "USE_PALLAS_DECODE": "1"}) as svc:
         require_device(await svc.status(), a.device)
-        got = await run_streams(svc, prompts, "llama")
+        got = await run_streams(svc, prompts, "llama", bucket)
         kernel = kernel_vs_reference(svc, got["facts"]["kernel_variant"])
     facts = got["facts"]
     if not a.rehearse:  # interpret mode lowers no custom call
@@ -439,9 +448,13 @@ async def stream_phase(a) -> None:
         check(not any(facts["pool_relayouts_in_decode_step"]),
               "the compiled paged chunk moves a whole KV pool every step: "
               f"{facts['pool_relayouts_in_decode_step']}")
+        check(not any(facts["pool_copies_at_entry"]),
+              "the compiled paged chunk or insert copies a KV pool it "
+              f"should alias (state not donated?): "
+              f"{facts['pool_copies_at_entry']}")
     async with Service(base, {**env, "USE_PALLAS_DECODE": "0"}) as svc:
         require_device(await svc.status(), a.device)
-        ref = await run_streams(svc, prompts, "llama")
+        ref = await run_streams(svc, prompts, "llama", bucket)
     check(not any(ref["facts"]["tpu_custom_calls_in_decode_step"]),
           "the reference service is not on the gather_pages path")
     emit({"phase": "stream", "model": "llama",
@@ -508,7 +521,7 @@ async def four_chip_phase(a) -> None:
              "FLEET_TP_GROUPS": "2,2"}
     async with Service(fleet, env) as svc:
         require_device(await svc.status(), a.device)
-        got = await run_streams(svc, prompts, "llama")
+        got = await run_streams(svc, prompts, "llama", bucket)
         st = await svc.status()
         groups = [tuple(r["devices"]) for r in st["fleet"]["per_replica"]]
         served = [cdl.tokens_emitted for cdl in svc.decode_loops()]
@@ -530,7 +543,7 @@ async def four_chip_phase(a) -> None:
     # TP=1 on ONE chip: REPLICAS=1 keeps the placement off the other
     # three (the default spreads data-parallel over every visible one).
     async with Service({**base, "REPLICAS": "1"}, env) as svc:
-        ref = await run_streams(svc, prompts, "llama")
+        ref = await run_streams(svc, prompts, "llama", bucket)
     emit({"phase": "four_chip", "model": "llama",
           "widths": "tiny (rehearsal)" if a.rehearse else "default",
           "placement": "TP=2 FLEET_REPLICAS=2 FLEET_TP_GROUPS=2,2",

@@ -18,6 +18,7 @@ Replaces the reference's ``InferenceWorker.run_batch()`` hot loop
 
 from __future__ import annotations
 
+import functools
 import logging
 import math
 import os
@@ -30,6 +31,7 @@ import numpy as np
 from ..models.registry import KIND_IMAGE, KIND_SEQ2SEQ, KIND_TEXT, ModelBundle
 from ..parallel import ReplicaSet, make_mesh
 from ..utils import locktrace, metrics, tracing
+from .faults import guard_donation
 
 log = logging.getLogger(__name__)
 
@@ -42,6 +44,22 @@ def bucket_for(n: int, buckets: tuple[int, ...], multiple: int = 1) -> int:
         if b >= lo and b % multiple == 0:
             return b
     return int(math.ceil(max(buckets + (lo,)) / multiple)) * multiple
+
+
+
+def chunk_with_done(chunk_fn, done_of=lambda state: state.done):
+    """``chunk_fn(params, state, ...) -> (state', *outs)`` with
+    ``state'``'s done flags as one more output: a buffer of its own that
+    outlives ``state'`` when the next dispatch donates it (a pipelined
+    caller fetches a chunk's ``done`` after dispatching the next).  Keeps
+    ``chunk_fn``'s name — the executable's (``jit_<fn>``) in traces."""
+
+    @functools.wraps(chunk_fn)
+    def inner(params, state, *args):
+        out = chunk_fn(params, state, *args)
+        return (*out, done_of(out[0]))
+
+    return inner
 
 
 class InferenceEngine:
@@ -162,10 +180,14 @@ class InferenceEngine:
         )
 
         if bundle.kind == KIND_SEQ2SEQ:
-            # static: n_steps, sample-path flag.  NOT donated: the
-            # continuous-batching loop pipelines chunk dispatches and
-            # holds the previous state's `done`/token buffers across the
-            # next call — donation would invalidate them mid-flight.
+            # static: n_steps, sample-path flag.  The state is DONATED,
+            # like every state an executable replaces (engine/streams.py
+            # has the rule): the chunk writes its KV rows in place
+            # instead of copying the caches, and leaves it does not touch
+            # (T5's encoder output) alias through.  The callers pipeline
+            # dispatches, so what they fetch later — tokens, ``done`` —
+            # are outputs of their own (``chunk_with_done``), never
+            # leaves of a state the next call consumes.
             # Every wrapper below routes through the process-level
             # ExecutableCache (runtime/compile_cache.py): a second
             # engine over the SAME bundle + placement (fleet spawns,
@@ -174,8 +196,11 @@ class InferenceEngine:
             self._gen_chunk = self._shared_jit(
                 "gen_chunk",
                 lambda: jax.jit(
-                    tracing.scoped("decode_chunk", bundle.generate_chunk_fn),
-                    static_argnums=(2, 3),
+                    tracing.scoped(
+                        "decode_chunk",
+                        chunk_with_done(bundle.generate_chunk_fn),
+                    ),
+                    static_argnums=(2, 3), donate_argnums=(1,),
                 ),
             )
 
@@ -264,8 +289,12 @@ class InferenceEngine:
                 )
                 self._spec_chunk = self._shared_jit(
                     "spec_chunk",
-                    lambda: jax.jit(bundle.spec_chunk_fn,
-                                    static_argnums=(2, 3, 4)),
+                    lambda: jax.jit(
+                        chunk_with_done(
+                            bundle.spec_chunk_fn, lambda ss: ss.base.done
+                        ),
+                        static_argnums=(2, 3, 4), donate_argnums=(1,),
+                    ),
                 )
 
                 # Non-streaming greedy batches take the speculative
@@ -803,11 +832,16 @@ class InferenceEngine:
             kind, self.bundle, self.replicas, build, statics
         )
 
-    def dispatch_guard(self, site: str, fn):
+    def dispatch_guard(self, site: str, fn, donates=None):
         """Run one device-dispatch callable under the fault injector
         and the watchdog (deadline + transient retry).  Every guarded
-        callable is functional — jitted calls and fetches with no
-        donation — so a retry is token-identical by construction.
+        callable is a pure function of its inputs, so a retry on the
+        same inputs is token-identical.  ``donates`` names the state a
+        state -> state executable consumes (a state that is replaced is
+        donated): an injected fault fires before ``fn`` and the retry
+        finds the state live; a failure that left it consumed is raised
+        as ``StateConsumedError`` — fatal, not retried — and the caller's
+        rebuild path runs (engine/faults.guard_donation).
 
         Attribution: host submit→return time feeds
         ``dispatch_host_seconds{site}`` and the per-site stats
@@ -820,6 +854,8 @@ class InferenceEngine:
             # LOCKTRACE=1: flag locks held across this dispatch (a
             # dispatch round-trip under a lock stalls every thread needing it).
             locktrace.note_dispatch(site)
+        if donates is not None:
+            fn = guard_donation(fn, donates)
         with tracing.phase(f"dispatch:{site}", cat="dispatch") as ph:
             t0 = time.perf_counter()
             out = self.watchdog.run(site, fn)
@@ -1143,15 +1179,16 @@ class InferenceEngine:
                 return
             while produced < budget:
                 with self._lock:
-                    state, toks = self.dispatch_guard(
+                    state, toks, done_d = self.dispatch_guard(
                         "chunk",
                         lambda: self._gen_chunk(
                             self.params, state, self.chunk_tokens, sampled
                         ),
+                        donates=state,
                     )
                     toks_np, done_np = self.dispatch_guard(
                         "fetch",
-                        lambda: jax.device_get((toks, state.done)),
+                        lambda: jax.device_get((toks, done_d)),
                     )
                     chunk, done = toks_np[0], bool(done_np[0])
                 yield chunk[: budget - produced]
@@ -1260,33 +1297,36 @@ class InferenceEngine:
         # overlaps the next chunk's compute.  At most one dispatched
         # chunk is wasted at the tail (EOS/budget), and the optimistic
         # dispatch is skipped once the budget could already be covered.
+        # (The chunk donates ``ss``: what is fetched after the next
+        # dispatch — ``out``, ``ns``, ``done_d`` — are outputs of their
+        # own, and ``ss`` is only ever the newest state.)
+        def spec_chunk():
+            return self.dispatch_guard(
+                "chunk",
+                lambda: self._spec_chunk(
+                    self.params, ss, n_verify, self.spec_k, sampled
+                ),
+                donates=ss,
+            )
+
         ahead = None
         while not done and produced < budget:
             with self._lock:
                 if ahead is None:
-                    ahead = self.dispatch_guard(
-                        "chunk",
-                        lambda: self._spec_chunk(
-                            self.params, ss, n_verify, self.spec_k, sampled
-                        ),
-                    )
-                ss, out, ns = ahead
+                    ahead = spec_chunk()
+                ss, out, ns, done_d = ahead
                 ahead = None
                 if produced + n_verify < budget:  # ≥1 token per round
-                    ahead = self.dispatch_guard(
-                        "chunk",
-                        lambda: self._spec_chunk(
-                            self.params, ss, n_verify, self.spec_k, sampled
-                        ),
-                    )
-                for arr in (out, ns, ss.base.done):
+                    ahead = spec_chunk()
+                    ss = ahead[0]
+                for arr in (out, ns, done_d):
                     try:
                         arr.copy_to_host_async()
                     except Exception:
                         pass
                 out_np, ns_np, done_np = self.dispatch_guard(
                     "fetch",
-                    lambda: jax.device_get((out, ns, ss.base.done)),
+                    lambda: jax.device_get((out, ns, done_d)),
                 )
             chunk = flatten_emitted(out_np, ns_np, 0)
             metrics.SPEC_EMITTED.labels(self.bundle.name).observe(
@@ -1366,7 +1406,7 @@ class InferenceEngine:
                             self.params, ids, mask, sp,
                             self.max_decode_len, self.chunk_tokens, flag,
                         )
-                        state, toks = self._gen_chunk(
+                        state, toks, _ = self._gen_chunk(
                             self.params, state, self.chunk_tokens, flag
                         )
                         jax.device_get(toks)
@@ -1440,7 +1480,7 @@ class InferenceEngine:
                                             self.chunk_tokens, self.spec_k,
                                             sflag,
                                         )
-                                        ss3, out3, _ = self._spec_chunk(
+                                        ss3, out3, _, _ = self._spec_chunk(
                                             self.params, ss3,
                                             self.chunk_tokens, self.spec_k,
                                             sflag,
@@ -1465,7 +1505,7 @@ class InferenceEngine:
                                 self.max_decode_len, self.chunk_tokens,
                                 self.spec_k, sflag,
                             )
-                            ss, out, ns = self._spec_chunk(
+                            ss, out, ns, _ = self._spec_chunk(
                                 self.params, ss, self.chunk_tokens,
                                 self.spec_k, sflag,
                             )

@@ -15,10 +15,16 @@ Two cooperating pieces, both OFF by default:
 - **Watchdog** (``DISPATCH_TIMEOUT_S`` / ``DISPATCH_RETRIES`` /
   ``DISPATCH_BACKOFF_S``): runs one dispatch callable under a
   monitored deadline and retries transient failures with capped
-  exponential backoff.  Every guarded callable here is functional
-  (jitted calls and fetches: same inputs → same outputs, no donation
-  on these paths), so a retry is token-identical by construction.
-  A deadline overrun raises ``DispatchTimeoutError`` — classified
+  exponential backoff.  Every guarded callable is a pure function of
+  its inputs (jitted calls and fetches), so a retry WITH THE SAME
+  INPUTS is token-identical.  The executables that replace a decode
+  state donate it (engine/streams.py: a state that is replaced is
+  donated), so the inputs exist only until the runtime takes them: an
+  injected fault fires BEFORE the callable (``_attempt``), the state is
+  still live and the retry is exact; a real error raised after the
+  state was consumed is re-raised as ``StateConsumedError`` — fatal,
+  never retried on deleted arrays — and ends in the rebuild path
+  (``guard_donation``).  A deadline overrun raises ``DispatchTimeoutError`` — classified
   FATAL, because a wedged dispatch on the same device state will not
   unwedge by retrying; the supervisor (engine/supervisor.py) rebuilds
   instead.  With timeout 0 and retries 0 and no injector, ``run`` is
@@ -100,6 +106,14 @@ class DeviceLostError(FatalDeviceError):
         self.device_index = int(device_index)
 
 
+class StateConsumedError(FatalDeviceError):
+    """A dispatch that donates its state raised AFTER the runtime took
+    the buffers: the arrays it would be retried on are deleted, so the
+    error is fatal whatever its cause looked like (``__cause__`` keeps
+    it).  The loop rebuilds the state and the streams resume by
+    recompute — their KV went with the buffers, so no swap-out runs."""
+
+
 class DispatchTimeoutError(Exception):
     """A dispatch exceeded ``DISPATCH_TIMEOUT_S``.  Classified fatal:
     the dispatch thread may be wedged forever, so recovery means a
@@ -119,6 +133,35 @@ def is_fatal_device(exc: BaseException) -> bool:
     return isinstance(
         exc, (FatalDeviceError, DispatchTimeoutError)
     ) or is_device_loss(exc)
+
+
+def is_consumed(tree) -> bool:
+    """True when any array of ``tree`` was deleted (donated to a
+    dispatch).  Host leaves (numpy) cannot be."""
+    import jax
+
+    return any(
+        getattr(x, "is_deleted", bool)() for x in jax.tree.leaves(tree)
+    )
+
+
+def guard_donation(fn, donated):
+    """``fn`` for the watchdog, where ``fn`` donates ``donated``: a
+    failure that left ``donated`` consumed becomes
+    ``StateConsumedError``, so ``Watchdog.run`` never retries it."""
+
+    def call():
+        try:
+            return fn()
+        except Exception as e:
+            if is_consumed(donated):
+                raise StateConsumedError(
+                    f"dispatch consumed its state, then failed: "
+                    f"{type(e).__name__}: {e}"
+                ) from e
+            raise
+
+    return call
 
 
 # Real runtimes surface a dead chip as an XlaRuntimeError (or peer)

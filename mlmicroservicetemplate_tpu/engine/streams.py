@@ -15,8 +15,19 @@ step 40 of a 300-token prompt while row j starts step 0 of a 16-token
 one.  Admission is a compiled scatter: the freshly prefilled batch=1
 state (one ``_start`` dispatch at the request's own prompt bucket —
 TTFT unchanged) is zero-padded up to the slot shapes and written into
-row i with ``dynamic_update_slice`` (``donate_argnums`` keeps the big
-KV buffers in place).
+row i with ``dynamic_update_slice``.
+
+One rule for the decode state: **a state that is replaced is donated**.
+Every executable that takes the batched state and returns its successor
+(chunk, window, insert, handoff, chunked-prefill window, host-tier
+scatter) donates it, so the compiler aliases each KV buffer's input to
+its output and writes a row in place; without it every call copied all
+of the caches in and out (PERF.md section 6, PR 30).  What follows from
+it: nothing outside ``self._state`` holds a leaf of a state — whatever
+the loop fetches after a later dispatch (tokens, ``done``, a window's
+history) is an output of its own; executables that only read the state
+(prefix and swap gathers) do not donate; and a dispatch that fails after
+its state was consumed is fatal, not retried (engine/faults.py).
 
 Dispatch economics: with S streams live, tokens/dispatch goes from
 ``chunk`` to ``S × chunk`` — where a dispatch's round-trip dominates
@@ -97,6 +108,75 @@ def wave_rungs(n_slots: int, multiple: int = 1) -> tuple[int, ...]:
     loop rounds it)."""
     below = {-(-r // multiple) * multiple for r in (1, _SMALL_WAVE_ROWS)}
     return tuple(sorted(r for r in below if r < n_slots)) + (n_slots,)
+
+
+def _ins_row(dst, src, slot, row):
+    """Row ``row`` of ``src`` (a lone or batched prefill state's leaf:
+    a wave prefills as one batch and each row lands in its own slot),
+    zero-padded to the slot shape, written into row ``slot`` of ``dst``.
+    One row only: a full-width dynamic_update_slice would clobber the
+    adjacent live slots."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    src = lax.dynamic_slice_in_dim(src, row, 1, axis=0)
+    pad = [(0, 0)] + [
+        (0, int(d) - int(s)) for d, s in zip(dst.shape[1:], src.shape[1:])
+    ]
+    srcp = jnp.pad(src.astype(dst.dtype), pad)
+    return lax.dynamic_update_slice(
+        dst, srcp, (slot,) + (0,) * (dst.ndim - 1)
+    )
+
+
+def paged_insert(block_size: int):
+    """The paged slot insert as a function to jit: scatter rows
+    [s_lo, s_cut) of one prefill-state row into this slot's blocks
+    (cache leaves route through the table; CoW prefix rows [0, s_lo) are
+    the donor's blocks and are never rewritten), logical per-row fields
+    land via the same dynamic_update_slice as the contiguous insert."""
+    import jax
+    from jax import lax
+
+    from ..models.gpt import PagedState
+    from ..ops.paged_attention import scatter_pages
+
+    def insert(batched, single, table_row, slot, row, s_lo: int, s_cut: int):
+        def scat(pool, src):
+            srow = lax.dynamic_slice_in_dim(src, row, 1, axis=0)[0]
+            return scatter_pages(
+                pool, table_row, srow[s_lo:s_cut], block_size, start=s_lo
+            )
+
+        def scat_entry(pc, sc):
+            if isinstance(pc, tuple):
+                return (scat(pc[0], sc[0]), scat(pc[1], sc[1]))
+            return scat(pc, sc)
+
+        return PagedState(
+            cache_k=[
+                scat_entry(d, s)
+                for d, s in zip(batched.cache_k, single.cache_k)
+            ],
+            cache_v=[
+                scat_entry(d, s)
+                for d, s in zip(batched.cache_v, single.cache_v)
+            ],
+            key_valid=_ins_row(batched.key_valid, single.key_valid, slot, row),
+            write_idx=_ins_row(batched.write_idx, single.write_idx, slot, row),
+            pos=_ins_row(batched.pos, single.pos, slot, row),
+            last_token=_ins_row(
+                batched.last_token, single.last_token, slot, row
+            ),
+            done=_ins_row(batched.done, single.done, slot, row),
+            tokens=_ins_row(batched.tokens, single.tokens, slot, row),
+            sample=jax.tree.map(
+                lambda d, s: _ins_row(d, s, slot, row),
+                batched.sample, single.sample,
+            ),
+        )
+
+    return insert
 
 
 class StreamClosedError(Exception):
@@ -1284,7 +1364,7 @@ class ContinuousDecodeLoop:
         for the next iteration.  Anything else (a poisoned request, a
         per-wave shape bug) error-terminates just these consumers, so
         one bad request can never take the loop down."""
-        from .faults import is_fatal_device
+        from .faults import StateConsumedError, is_fatal_device
 
         if self.supervisor is not None and is_fatal_device(exc):
             for st in streams:
@@ -1292,6 +1372,23 @@ class ContinuousDecodeLoop:
             self._fault_pending = exc
             return
         for st in streams:
+            self._finish(st, exc)
+        if isinstance(exc, StateConsumedError):
+            # Unsupervised, but the decode state went with the failed
+            # dispatch: the loop's handler ends what lived in it and
+            # rebuilds lazily, at the next iteration top.
+            self._fault_pending = exc
+
+    def _fail_preactive(self, st: _Stream, exc: Exception) -> None:
+        """An insert or handoff failed for a stream not yet in a slot:
+        the failure is this consumer's alone — unless the dispatch had
+        already consumed the batched state, which every live stream
+        shares: that takes ``_fail_streams``' fatal route."""
+        from .faults import StateConsumedError
+
+        if isinstance(exc, StateConsumedError):
+            self._fail_streams([st], exc)
+        else:
             self._finish(st, exc)
 
     def _recover(self, exc: Exception) -> bool:
@@ -1342,14 +1439,18 @@ class ContinuousDecodeLoop:
             "decode loop fault (%s: %s); supervised engine restart %d/%d",
             type(exc).__name__, exc, sup.restarts, sup.max_restarts,
         )
-        # Host KV tier: the pre-fault pools are still addressable (the
-        # failed dispatch never assigned into self._state), so active
-        # streams' resume KV can swap out during the checkpoint below
-        # — UNLESS the fault was a watchdog cut, where the device may
-        # be wedged and a gather could hang too.
-        from .faults import DispatchTimeoutError
+        # Host KV tier: a fault injected BEFORE its dispatch (or raised
+        # by a fetch) leaves the pre-fault pools addressable, so active
+        # streams' resume KV can swap out during the checkpoint below.
+        # Held in two cases, where the streams resume by recompute
+        # instead: a watchdog cut (the device may be wedged and a gather
+        # could hang too), and a dispatch that failed AFTER consuming
+        # the state it donates — the pools went with it.
+        from .faults import DispatchTimeoutError, is_consumed
 
-        self._swap_hold = isinstance(exc, DispatchTimeoutError)
+        self._swap_hold = isinstance(
+            exc, DispatchTimeoutError
+        ) or is_consumed(self._state)
         # A staged host-prep plan names blocks of the pools being torn
         # down: discard it plain (each stream's checkpoint/release
         # below returns its WHOLE block list, staged grants included).
@@ -2308,6 +2409,11 @@ class ContinuousDecodeLoop:
             if bool(done_np[row]) or st.produced >= st.budget:
                 self._finish(st)
                 continue
+            if self._fault_pending is not None:
+                # An earlier row's insert took the batched state with
+                # it: the rows behind it go the same way, uninserted.
+                self._fail_streams([st], self._fault_pending)
+                continue
             # Any failure from here (empty-state build OOM, insert
             # compile) must terminate THIS consumer and return the slot
             # — the _run handler only reaches streams in self.active.
@@ -2330,14 +2436,16 @@ class ContinuousDecodeLoop:
                                 "insert", lambda: self._insert_fn()(
                                     self._state, state1, ids, mask, hist_row,
                                     np.int32(slot), np.int32(row),
-                                )
+                                ),
+                                donates=self._state,
                             )
                         else:
                             self._state = eng.dispatch_guard(
                                 "insert", lambda: self._insert_fn()(
                                     self._state, state1, np.int32(slot),
                                     np.int32(row),
-                                )
+                                ),
+                                donates=self._state,
                             )
             except OutOfBlocks:
                 # The fits() gate raced another reservation and the
@@ -2357,12 +2465,14 @@ class ContinuousDecodeLoop:
                 continue
             # The stream is not active yet: an insert failure ends this
             # consumer only; a dead device resurfaces at the next
-            # guarded chunk dispatch, which the supervisor owns.
+            # guarded chunk dispatch, which the supervisor owns.  An
+            # insert that consumed the batched state takes the shared
+            # fatal route (requeue + rebuild at the next iteration top).
             # graftlint: except(pre-active insert failure errors only this stream; the supervisor owns the next chunk dispatch)
             except Exception as e:
                 if slot is not None:
                     self.free.append(slot)
-                self._finish(st, e)
+                self._fail_preactive(st, e)
                 continue
             self.active[slot] = st
             if sampled:
@@ -2395,7 +2505,8 @@ class ContinuousDecodeLoop:
 
             self._prefill_jit = self._shared_jit(
                 "prefill_chunk",
-                lambda: jax.jit(self.engine.bundle.prefill_chunk_fn),
+                lambda: jax.jit(self.engine.bundle.prefill_chunk_fn,
+                                donate_argnums=(1,)),
             )
         return self._prefill_jit
 
@@ -2405,7 +2516,8 @@ class ContinuousDecodeLoop:
 
             self._paged_prefill_jit = self._shared_jit(
                 "paged_prefill_chunk",
-                lambda: jax.jit(self.engine.bundle.paged_prefill_chunk_fn),
+                lambda: jax.jit(self.engine.bundle.paged_prefill_chunk_fn,
+                                donate_argnums=(1,)),
             )
         return self._paged_prefill_jit
 
@@ -2444,7 +2556,8 @@ class ContinuousDecodeLoop:
                 )
 
             self._seed_prefix_fns[p_len] = self._shared_jit(
-                "seed_prefix", lambda: jax.jit(seed), statics=(p_len,)
+                "seed_prefix", lambda: jax.jit(seed, donate_argnums=(0,)),
+                statics=(p_len,),
             )
         return self._seed_prefix_fns[p_len](state, pkv)
 
@@ -2482,7 +2595,8 @@ class ContinuousDecodeLoop:
                 )
 
             self._paged_handoff = self._shared_jit(
-                "paged_handoff", lambda: jax.jit(handoff)
+                "paged_handoff",
+                lambda: jax.jit(handoff, donate_argnums=(0,)),
             )
         return self._paged_handoff
 
@@ -2654,6 +2768,7 @@ class ContinuousDecodeLoop:
                             jnp.asarray(job.table_row), ids_w, mask_w,
                             np.int32(start),
                         ),
+                        donates=self._state,
                     )
                 if self.admission is not None:
                     self.admission.note_pool()
@@ -2666,6 +2781,7 @@ class ContinuousDecodeLoop:
                             jparams, job.state, ids_w, mask_w,
                             np.int32(start)
                         ),
+                        donates=job.state,
                     )
             job.consumed = end
             self.prefill_chunk_dispatches += 1
@@ -2714,6 +2830,7 @@ class ContinuousDecodeLoop:
                             self._state, kv_row, w_idx, zero, last,
                             not_done, toks_row, sp, np.int32(slot),
                         ),
+                        donates=self._state,
                     )
                 st.blocks = job.sb
                 job.sb = None
@@ -2732,6 +2849,7 @@ class ContinuousDecodeLoop:
                         lambda: self._insert_fn()(
                             self._state, final, np.int32(slot), np.int32(0)
                         ),
+                        donates=self._state,
                     )
         # The stream is not active yet, so there is no checkpoint to
         # classify-route; a dead device resurfaces at the next guarded
@@ -2741,7 +2859,7 @@ class ContinuousDecodeLoop:
             if slot is not None:
                 self.free.append(slot)
             self._drop_job_resources(job)
-            self._finish(st, e)
+            self._fail_preactive(st, e)
             return False
         self.active[slot] = st
         if sampled:
@@ -3153,21 +3271,6 @@ class ContinuousDecodeLoop:
             import jax.numpy as jnp
             from jax import lax
 
-            def ins_row(dst, src, slot, row):
-                # ``row`` picks ONE row of the (possibly batched)
-                # prefill state — a wave of admissions prefills as
-                # one batch and each row lands in its own slot; a
-                # full-width dynamic_update_slice would clobber the
-                # adjacent live slots.
-                src = lax.dynamic_slice_in_dim(src, row, 1, axis=0)
-                pad = [(0, 0)] + [
-                    (0, int(d) - int(s))
-                    for d, s in zip(dst.shape[1:], src.shape[1:])
-                ]
-                srcp = jnp.pad(src.astype(dst.dtype), pad)
-                start = (slot,) + (0,) * (dst.ndim - 1)
-                return lax.dynamic_update_slice(dst, srcp, start)
-
             if self.spec:
                 bundle = self.engine.bundle
 
@@ -3182,7 +3285,7 @@ class ContinuousDecodeLoop:
                     # already has the slot's exact layout.
                     ss = bundle.init_spec_fn(single, ids, mask)
                     base = jax.tree.map(
-                        lambda d, s: ins_row(d, s, slot, row),
+                        lambda d, s: _ins_row(d, s, slot, row),
                         batched.base, ss.base,
                     )
                     hist = lax.dynamic_update_slice(
@@ -3193,23 +3296,26 @@ class ContinuousDecodeLoop:
 
                 self._insert = self._shared_jit(
                     "insert_spec", lambda: jax.jit(
-                        tracing.scoped("slot_insert", insert_spec)
+                        tracing.scoped("slot_insert", insert_spec),
+                        donate_argnums=(0,),
                     )
                 )
             else:
                 def insert(batched, single, slot, row):
                     return jax.tree.map(
-                        lambda d, s: ins_row(d, s, slot, row),
+                        lambda d, s: _ins_row(d, s, slot, row),
                         batched, single,
                     )
 
-                # NOT donated: in-flight pipelined chunks still
-                # reference buffers of the pre-insert state (their
-                # toks/done fetch later); donation would invalidate
-                # them mid-flight.
+                # The batched state is donated (the module docstring's
+                # rule): one row is written in place.  In-flight chunks
+                # hold outputs of their own (toks, done), never a leaf
+                # of the pre-insert state.  ``single`` is a wave's
+                # prefill state, read by every row's insert: not donated.
                 self._insert = self._shared_jit(
                     "insert", lambda: jax.jit(
-                        tracing.scoped("slot_insert", insert)
+                        tracing.scoped("slot_insert", insert),
+                        donate_argnums=(0,),
                     )
                 )
         return self._insert
@@ -3220,13 +3326,16 @@ class ContinuousDecodeLoop:
         if self._paged_chunk is None:
             import jax
 
+            from .engine import chunk_with_done
+
             self._paged_chunk = self._shared_jit(
                 "paged_chunk",
                 lambda: jax.jit(
                     tracing.scoped(
-                        "decode_chunk", self.engine.bundle.paged_chunk_fn
+                        "decode_chunk",
+                        chunk_with_done(self.engine.bundle.paged_chunk_fn),
                     ),
-                    static_argnums=(3, 4),
+                    static_argnums=(3, 4), donate_argnums=(1,),
                 ),
                 # The traced program embeds the tuned kernel variant
                 # (resolved at trace time via ops/autotune.lookup) —
@@ -3256,79 +3365,38 @@ class ContinuousDecodeLoop:
                 return lowered.compile().as_text()
             return lowered.as_text(debug_info=debug_info)
 
+    def paged_insert_hlo(self, s: int) -> str:
+        """The backend's optimised text of one slot insert of a
+        ``s``-token bucket's lone prefill into this loop's state — the
+        chunk's twin for ``chip_smoke.py``: with the state donated no
+        pool is copied in it either."""
+        import jax.numpy as jnp
+
+        eng = self.engine
+        with eng._lock:
+            state1 = self._warm_wave(s, 1)[0]
+            return self._paged_insert_fn().lower(
+                self._state, state1,
+                jnp.full(self.nb_max, self.pool.num_blocks, jnp.int32),
+                np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
+            ).compile().as_text()
+
     def _paged_insert_fn(self):
-        """Paged slot insert: scatter rows [s_lo, s_cut) of one
-        prefill-state row into this slot's blocks (cache leaves route
-        through the table; CoW prefix rows [0, s_lo) are the donor's
-        blocks and are never rewritten), logical per-row fields land
-        via the same dynamic_update_slice as the contiguous insert.
-        One executable per static (s_lo, s_cut) pair — the (prefix
-        bucket, suffix bucket) grid, like the prefixed starts."""
+        """Paged slot insert (``paged_insert``): one executable per
+        static (s_lo, s_cut) pair — the (prefix bucket, suffix bucket)
+        grid, like the prefixed starts.  The batched state is donated
+        (the module docstring's rule): the scatters write the stream's
+        blocks in place; ``single``, a wave's prefill state, is read by
+        every row's insert and is not."""
         if self._paged_insert is None:
             import jax
-            import jax.numpy as jnp
-            from jax import lax
-
-            from ..models.gpt import PagedState
-            from ..ops.paged_attention import scatter_pages
 
             bs = self.block_size
-
-            def ins_row(dst, src, slot, row):
-                src = lax.dynamic_slice_in_dim(src, row, 1, axis=0)
-                pad = [(0, 0)] + [
-                    (0, int(d) - int(s))
-                    for d, s in zip(dst.shape[1:], src.shape[1:])
-                ]
-                srcp = jnp.pad(src.astype(dst.dtype), pad)
-                start = (slot,) + (0,) * (dst.ndim - 1)
-                return lax.dynamic_update_slice(dst, srcp, start)
-
-            def insert(batched, single, table_row, slot, row,
-                       s_lo: int, s_cut: int):
-                def scat(pool, src):
-                    srow = lax.dynamic_slice_in_dim(src, row, 1, axis=0)[0]
-                    return scatter_pages(
-                        pool, table_row, srow[s_lo:s_cut], bs, start=s_lo
-                    )
-
-                def scat_entry(pc, sc):
-                    if isinstance(pc, tuple):
-                        return (scat(pc[0], sc[0]), scat(pc[1], sc[1]))
-                    return scat(pc, sc)
-
-                return PagedState(
-                    cache_k=[
-                        scat_entry(d, s)
-                        for d, s in zip(batched.cache_k, single.cache_k)
-                    ],
-                    cache_v=[
-                        scat_entry(d, s)
-                        for d, s in zip(batched.cache_v, single.cache_v)
-                    ],
-                    key_valid=ins_row(
-                        batched.key_valid, single.key_valid, slot, row
-                    ),
-                    write_idx=ins_row(
-                        batched.write_idx, single.write_idx, slot, row
-                    ),
-                    pos=ins_row(batched.pos, single.pos, slot, row),
-                    last_token=ins_row(
-                        batched.last_token, single.last_token, slot, row
-                    ),
-                    done=ins_row(batched.done, single.done, slot, row),
-                    tokens=ins_row(batched.tokens, single.tokens, slot, row),
-                    sample=jax.tree.map(
-                        lambda d, s: ins_row(d, s, slot, row),
-                        batched.sample, single.sample,
-                    ),
-                )
-
             self._paged_insert = self._shared_jit(
                 "paged_insert",
                 lambda: jax.jit(
-                    tracing.scoped("slot_insert", insert),
-                    static_argnums=(5, 6),
+                    tracing.scoped("slot_insert", paged_insert(bs)),
+                    static_argnums=(5, 6), donate_argnums=(0,),
                 ),
                 statics=(bs,),
             )
@@ -3393,7 +3461,8 @@ class ContinuousDecodeLoop:
                     "insert", lambda: self._paged_insert_fn()(
                         self._state, state1, jnp.asarray(table_row),
                         np.int32(slot), np.int32(row), st.s_lo, s_cut,
-                    )
+                    ),
+                    donates=self._state,
                 )
         except BaseException:
             sb.release()
@@ -3580,7 +3649,8 @@ class ContinuousDecodeLoop:
                 return state._replace(cache_k=ck, cache_v=cv)
 
             self._swap_scatter_jit = self._shared_jit(
-                "swap_scatter", lambda: jax.jit(scatter)
+                "swap_scatter",
+                lambda: jax.jit(scatter, donate_argnums=(0,)),
             )
         return self._swap_scatter_jit
 
@@ -3814,6 +3884,7 @@ class ContinuousDecodeLoop:
         self._state = self.engine.dispatch_guard(
             "swap",
             lambda: self._swap_scatter_fn()(self._state, ids_p, vals_p),
+            donates=self._state,
         )
 
     def _start_swapin(self, st: _Stream) -> bool:
@@ -4254,7 +4325,8 @@ class ContinuousDecodeLoop:
                 self._paged_window_jit = self._shared_jit(
                     "paged_window",
                     lambda: jax.jit(self.engine.bundle.paged_window_fn,
-                                    static_argnums=(3, 4, 5)),
+                                    static_argnums=(3, 4, 5),
+                                    donate_argnums=(1,)),
                     statics=(self.kernel_variant,),  # see _paged_chunk_fn
                 )
             return self._paged_window_jit
@@ -4262,7 +4334,8 @@ class ContinuousDecodeLoop:
             self._window_jit = self._shared_jit(
                 "window",
                 lambda: jax.jit(self.engine.bundle.window_fn,
-                                static_argnums=(2, 3, 4)),
+                                static_argnums=(2, 3, 4),
+                                donate_argnums=(1,)),
             )
         return self._window_jit
 
@@ -4475,20 +4548,24 @@ class ContinuousDecodeLoop:
                             dparams, self._state, table,
                             eng.chunk_tokens, w, use_sample,
                         ),
+                        donates=self._state,
                     )
                     prefetch_to_host(toks, hist, nc)
                     entry = ((toks, hist, nc), dict(self.active), w)
                 else:
-                    self._state, toks = eng.dispatch_guard(
+                    # ``done`` is an output of the chunk, as ``toks`` is:
+                    # the entry is fetched after later dispatches have
+                    # consumed this state.  With experts ``toks`` is
+                    # (tokens, counts): the chunk's [L, E] routing counts
+                    # ride the same fetch.
+                    self._state, toks, done = eng.dispatch_guard(
                         "chunk",
                         lambda: self._paged_chunk_fn()(
                             dparams, self._state, table,
                             eng.chunk_tokens, use_sample,
                         ),
+                        donates=self._state,
                     )
-                    # With experts ``toks`` is (tokens, counts): the
-                    # chunk's [L, E] routing counts ride the same fetch.
-                    done = self._state.done
                     prefetch_to_host(toks, done)
                     entry = ((toks, done), dict(self.active), 1)
             self._note_dispatched(entry)
@@ -4502,14 +4579,14 @@ class ContinuousDecodeLoop:
             if self.spec:
                 # One batched draft→verify chunk: every live row emits
                 # chunk_tokens..chunk_tokens·(spec_k+1) tokens.
-                self._state, out, ns = eng.dispatch_guard(
+                self._state, out, ns, done = eng.dispatch_guard(
                     "chunk",
                     lambda: eng._spec_chunk(
                         eng.params, self._state, eng.chunk_tokens,
                         eng.spec_k, use_sample,
                     ),
+                    donates=self._state,
                 )
-                done = self._state.base.done
                 prefetch_to_host(out, ns, done)
                 entry = (((out, ns), done), dict(self.active), 1)
             elif w > 1:
@@ -4519,18 +4596,19 @@ class ContinuousDecodeLoop:
                         dparams, self._state, eng.chunk_tokens, w,
                         use_sample,
                     ),
+                    donates=self._state,
                 )
                 prefetch_to_host(toks, hist, nc)
                 entry = ((toks, hist, nc), dict(self.active), w)
             else:
-                self._state, toks = eng.dispatch_guard(
+                self._state, toks, done = eng.dispatch_guard(
                     "chunk",
                     lambda: eng._gen_chunk(
                         dparams, self._state, eng.chunk_tokens,
                         use_sample,
                     ),
+                    donates=self._state,
                 )
-                done = self._state.done
                 # Start the host copies now so the fetch in
                 # _deliver_oldest finds the data (mostly) already on
                 # this side of the wire.
@@ -4808,13 +4886,13 @@ class ContinuousDecodeLoop:
         ):
             with eng._lock:
                 if self.spec:
-                    self._state, out, ns = eng._spec_chunk(
+                    self._state, out, _, _ = eng._spec_chunk(
                         eng.params, self._state, eng.chunk_tokens,
                         eng.spec_k, flag,
                     )
                     jax.device_get(out)
                 else:
-                    self._state, toks = eng._gen_chunk(
+                    self._state, toks, _ = eng._gen_chunk(
                         self._mp(n=self.n_slots), self._state,
                         eng.chunk_tokens, flag,
                     )
@@ -4979,9 +5057,9 @@ class ContinuousDecodeLoop:
         self._autotune_kernel()
         eng = self.engine
 
-        # One scratch block list serves the whole grid (the inserts'
-        # results are dropped: warm-up resets the state below); a bucket
-        # the pool cannot hold is unservable and stays cold.
+        # One scratch block list serves the whole grid (every insert
+        # writes slot 0; warm-up resets the state below); a bucket the
+        # pool cannot hold is unservable and stays cold.
         sb = StreamBlocks(self.pool, self.block_size)
         grid = []
         for s in sorted(eng.seq_buckets):
@@ -5004,13 +5082,14 @@ class ContinuousDecodeLoop:
             s, n_batch = cell
             with eng._lock:
                 state1 = self._warm_wave(s, n_batch)[0]
-                # One insert at a time, and done before the next: each
-                # makes a whole new state (it is not donated).
+                # One insert at a time: it consumes the state (donated)
+                # and the next thread's takes its successor.
                 with one_insert:
-                    jax.block_until_ready(insert(
+                    self._state = insert(
                         self._state, state1, table_row(s),
                         np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
-                    ))
+                    )
+                    jax.block_until_ready(self._state.done)
 
         # A warm start is tracing plus the runtime loading a cached
         # executable of tens of MB, ~2.6 s a (rung, bucket) pair, and the
@@ -5028,7 +5107,7 @@ class ContinuousDecodeLoop:
             sb.release()
         for flag in (False, True) if warm_sampled else (False,):
             with eng._lock:
-                self._state, toks = self._paged_chunk_fn()(
+                self._state, toks, _ = self._paged_chunk_fn()(
                     self._mp(n=self.n_slots), self._state,
                     jnp.asarray(self._table), eng.chunk_tokens, flag,
                 )
@@ -5140,15 +5219,13 @@ class ContinuousDecodeLoop:
         def wall(k: int) -> float:
             t0 = _time.perf_counter()
             with eng._lock:
-                s = self._state
                 for _ in range(k):
                     # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                    s, toks = self._paged_chunk_fn()(
-                        wp, s, table, eng.chunk_tokens, False
+                    self._state, toks, _ = self._paged_chunk_fn()(
+                        wp, self._state, table, eng.chunk_tokens, False
                     )
                 # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
                 jax.device_get(toks)
-            self._state = s
             return _time.perf_counter() - t0
 
         wall(1)
@@ -5195,22 +5272,20 @@ class ContinuousDecodeLoop:
         def wall(k: int) -> float:
             t0 = _time.perf_counter()
             with eng._lock:
-                s = self._state
                 for _ in range(k):
                     if self.spec:
                         # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                        s, toks, _ = eng._spec_chunk(
-                            eng.params, s, eng.chunk_tokens, eng.spec_k,
-                            False,
+                        self._state, toks, _, _ = eng._spec_chunk(
+                            eng.params, self._state, eng.chunk_tokens,
+                            eng.spec_k, False,
                         )
                     else:
                         # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                        s, toks = eng._gen_chunk(
-                            wp, s, eng.chunk_tokens, False
+                        self._state, toks, _ = eng._gen_chunk(
+                            wp, self._state, eng.chunk_tokens, False
                         )
                 # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
                 jax.device_get(toks)
-            self._state = s
             return _time.perf_counter() - t0
 
         wall(1)  # prime any lazy transfer

@@ -622,10 +622,12 @@ def pool_relayouts(hlo_text: str, pool_elems, in_loop_only: bool = False) -> lis
     ``transpose`` with a result of a pool leaf's element count
     (``pool_elems``: the counts to look for).  On the chip a reshape
     that survives to the optimised HLO is a relayout (the free ones
-    become ``bitcast``).  ``in_loop_only`` skips the ENTRY computation:
-    a state that is not donated is copied once on the way in, which is
-    not a step's cost.  What ``tests/test_chip_compile.py`` and
-    ``chip_smoke.py`` hold the layout rule to."""
+    become ``bitcast``).  With the state donated (engine/streams.py) no
+    payload pool may show at all, ENTRY included; ``in_loop_only`` skips
+    the ENTRY computation for the one thing left there — an int8 pair's
+    small scale pool, which the compiler re-tiles once on the way in and
+    out of a program.  What ``tests/test_chip_compile.py`` and
+    ``chip_smoke.py`` hold the layout and the donation rule to."""
     import re
 
     want = {int(n) for n in pool_elems}

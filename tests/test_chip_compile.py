@@ -314,9 +314,125 @@ def test_no_pool_sized_relayout_in_a_decode_step(chip, kvh, h, nb, t, quant):
     assert text.count("tpu_custom_call") >= 1
     payload_n, scale_n = nb * bs * kvh * d, nb * bs * kvh
     assert not pool_relayouts(text, [payload_n])
-    # The 0.8 MB scale pool the compiler may park in fast memory on the
-    # way in and out (a `copy` into S(1) in ENTRY); never in a step.
+    # The 0.8 MB scale pool the compiler may re-tile on the way in and
+    # out (a `copy` in ENTRY); never in a step.
     assert not pool_relayouts(text, [payload_n, scale_n], in_loop_only=True)
+
+
+#: The cells' serving shapes (cellbench/configs/*.json): LlamaConfig
+#: overrides, pool blocks (KV_BUDGET_MB), table width, largest bucket.
+_CELLS = {
+    "mistral": (dict(vocab_size=32000, d_model=4096, num_heads=32,
+                     num_kv_heads=8, num_layers=8, d_ff=14336,
+                     max_position=32768), 3051, 44, 512),
+    "olmoe": (dict(vocab_size=50304, d_model=2048, num_heads=16,
+                   num_kv_heads=16, num_layers=8, d_ff=1024,
+                   max_position=4096, num_experts=64, experts_per_token=8,
+                   qk_norm=True, add_bos=False), 1811, 28, 256),
+}
+_CELLS["mistral-int8"] = (
+    dict(_CELLS["mistral"][0], kv_quant=True), *_CELLS["mistral"][1:])
+
+
+def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
+    """The text of the loop's own executable compiled for the described
+    chip at a cell's shapes — ``what`` = the paged chunk
+    (``chunk_with_done(generate_chunk_paged)``, as ``_paged_chunk_fn``
+    jits it) or one slot insert (``streams.paged_insert``) — and the
+    element counts of a payload and a scale pool.  Shapes only: no
+    weight is made."""
+    from mlmicroservicetemplate_tpu.engine.engine import chunk_with_done
+    from mlmicroservicetemplate_tpu.engine.streams import paged_insert
+    from mlmicroservicetemplate_tpu.models import llama as llama_mod
+    from mlmicroservicetemplate_tpu.models.gpt import PagedState
+    from mlmicroservicetemplate_tpu.models.sampling import greedy_params
+
+    over, nb, t, s_max = _CELLS[cell]
+    cfg = LlamaConfig(**over, pallas_decode=True, pallas_variant="b4-hb")
+    b, bs, dt, budget, steps = 64, 16, jnp.bfloat16, 192, 4
+    c, kvh = cfg.num_kv_heads * cfg.head_dim, cfg.num_kv_heads
+    counts = (nb * bs * c, nb * bs * kvh)
+    key = ("serving", cell, what, donate)
+    if key in chip.memo:
+        return chip.memo[key], counts
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+
+    def pool():
+        if cfg.kv_quant:
+            return (jnp.zeros((nb, bs, c), jnp.int8), jnp.ones((nb, bs, kvh), dt))
+        return jnp.zeros((nb, bs, c), dt)
+
+    def batched():
+        return PagedState(
+            cache_k=[pool() for _ in range(cfg.num_layers)],
+            cache_v=[pool() for _ in range(cfg.num_layers)],
+            key_valid=jnp.zeros((b, t * bs), jnp.int32),
+            write_idx=jnp.zeros((b,), jnp.int32),
+            pos=jnp.zeros((b,), jnp.int32),
+            last_token=jnp.zeros((b,), jnp.int32),
+            done=jnp.ones((b,), bool),
+            tokens=jnp.zeros((b, budget), jnp.int32),
+            sample=greedy_params(b),
+        )
+
+    params = on_chip(jax.eval_shape(
+        lambda: llama_mod.init_params(jax.random.PRNGKey(0), cfg, dtype=dt)))
+    state = on_chip(jax.eval_shape(batched))
+    donated = dict(donate_argnums=(1 if what == "chunk" else 0,)) if donate else {}
+    if what == "chunk":
+        lowered = jax.jit(
+            chunk_with_done(lambda p, s, tb, n, sample: (
+                llama_mod.generate_chunk_paged(p, cfg, s, tb, n, sample))),
+            static_argnums=(3, 4), **donated,
+        ).lower(params, state, chip((b, t), jnp.int32), steps, False)
+    else:
+        ones = jnp.ones((1, s_max), jnp.int32)
+        single = on_chip(jax.eval_shape(
+            lambda p: llama_mod.generate_chunk(p, cfg, llama_mod.init_decode_state(
+                p, cfg, ones, ones, budget, dtype=dt), steps, False)[0], params))
+        lowered = jax.jit(
+            paged_insert(bs), static_argnums=(5, 6), **donated,
+        ).lower(state, single, chip((t,), jnp.int32), chip((), jnp.int32),
+                chip((), jnp.int32), 0, s_max + steps)
+    chip.memo[key] = lowered.compile().as_text()
+    return chip.memo[key], counts
+
+
+@pytest.mark.parametrize("what", ["chunk", "insert"])
+@pytest.mark.parametrize("cell", list(_CELLS))
+def test_donated_state_is_not_copied_at_entry(chip, cell, what):
+    """The decode state is donated (engine/streams.py's rule), so the
+    loop's chunk and a slot insert, compiled at the cells' own shapes,
+    alias every pool's input to its output: no ``copy`` / ``reshape`` /
+    ``transpose`` of a payload pool's element count anywhere in the
+    optimised program, ENTRY included.  Until PR 30 neither donated:
+    ``copy(bf16[3051,16,1024])`` x 16 at the chunk's entry (1.11 / 1.45
+    ms a step) and in every insert (4.9-5.8 of its 5.3-6.2 ms): PERF.md
+    section 6."""
+    from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
+
+    text, (payload_n, scale_n) = _serving_program(chip, cell, what)
+    if what == "chunk":
+        assert text.count("tpu_custom_call") >= 1
+    assert "input_output_alias" in text
+    assert not pool_relayouts(text, [payload_n])
+    # An int8 pair's 0.8 MB scale pool is given another tiling on the
+    # way in and out (a `copy` in ENTRY); never inside a step.
+    assert not pool_relayouts(text, [payload_n, scale_n], in_loop_only=True)
+
+
+def test_an_undonated_insert_copies_every_pool(chip):
+    """What the reader is for: the same insert without donation copies
+    the pools (the program every insert ran until PR 30; 14 of the 16
+    show as a plain ``copy``) — so an empty list above is the donation,
+    not a blind spot."""
+    from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
+
+    text, (payload_n, _) = _serving_program(chip, "mistral", "insert", False)
+    hits = pool_relayouts(text, [payload_n])
+    assert len(hits) >= 8 and all(" copy(" in h for h in hits)
 
 
 @pytest.mark.parametrize("assignments", [512, 65536])
