@@ -457,6 +457,13 @@ class ContinuousDecodeLoop:
         # tenant (the paged mirror of the contiguous mode="drop"
         # clamp).
         self.paged = bool(getattr(engine, "paged_kv", False))
+        # (window layers, window) of a per-layer pattern, or None.
+        bcfg = getattr(engine.bundle, "cfg", None)
+        types = getattr(bcfg, "layer_types", ())
+        self._window_layers = (
+            (types.count("window"), int(bcfg.window))
+            if "window" in types else None
+        )
         if self.paged:
             from .kv_blocks import blocks_for
 
@@ -3063,7 +3070,12 @@ class ContinuousDecodeLoop:
         eng = self.engine
         # Chunked prefill widens the admissible prompt ceiling past the
         # largest bucket; slots must hold the widest insertable state.
-        s_max = self.max_prompt
+        # The paged state takes only a token's dims and the per-row
+        # fields' shapes from the template (the pool and the table width
+        # come from the ledger), so its template is one bucket wide: a
+        # monolithic prefill of ``max_prompt`` tokens (6016 in the
+        # long-document cell: a 4.6 GB score tensor) is never run.
+        s_max = max(eng.seq_buckets) if self.paged else self.max_prompt
         feats = {"input_ids": np.ones(s_max, np.int32), "length": np.int32(s_max)}
         with eng._lock:
             ids, mask, _ = eng._collate_text([feats])
@@ -4518,6 +4530,8 @@ class ContinuousDecodeLoop:
         metrics.STREAM_BATCH.labels(eng.bundle.name).observe(len(self.active))
         w = entry[2]
         metrics.DECODE_WINDOW_CHUNKS.labels(eng.bundle.name).observe(w)
+        if self.paged and self._window_layers:
+            self._note_window_keys(eng.chunk_tokens * w)
         if w > 1:
             self.window_dispatches += 1
         self._inflight_chunks.append(entry)
@@ -4633,9 +4647,27 @@ class ContinuousDecodeLoop:
             # replica's breaker fault streak (engine/fleet.py).
             self.on_ok()
 
+    def _note_window_keys(self, steps: int) -> None:
+        """Keys the window layers read in the chunk just dispatched, and
+        keys of live context behind their windows that they did not —
+        from the host's own stream lengths, a step and a window layer at
+        a time (a stream's step over ``n`` keys reads ``min(n, window)``
+        of them through the view and leaves ``n - window`` behind)."""
+        n_layers, window = self._window_layers
+        read = behind = 0
+        for slot, st in self.active.items():
+            first = st.s_base + self._dispatched_steps.get(slot, steps) - steps
+            for n in range(first, first + steps):
+                read += min(n, window)
+                behind += max(n - window, 0)
+        name = self.engine.bundle.name
+        metrics.KV_WINDOW_KEYS_READ.labels(name).inc(read * n_layers)
+        metrics.KV_WINDOW_KEYS_BEHIND.labels(name).inc(behind * n_layers)
+
     def _note_moe(self, counts) -> None:
         """One delivered paged chunk's per-expert assignment counts
-        ([L, E], a row a layer: models/llama.generate_chunk_paged) into
+        ([L, E], a row an EXPERT layer — a dense layer of a per-layer
+        pattern has none: models/llama.generate_chunk_paged) into
         the routing metrics.  Imbalance and experts hit are a LAYER's
         (a grouped matmul's load is one layer's), a mean over layers."""
         per_layer = counts.sum(axis=1)
@@ -5027,16 +5059,28 @@ class ContinuousDecodeLoop:
             getattr(scfg, "compile_cache_dir", None),
         )
         kvh = int(getattr(bcfg, "num_kv_heads", bcfg.num_heads))
-        self.kernel_variant = autotune.ensure_tuned(
-            "paged_decode", eng.bundle, eng.replicas,
-            b=self.n_slots, kvh=kvh,
-            n_rep=int(bcfg.num_heads) // kvh, d=int(bcfg.head_dim),
-            block_size=self.block_size, t=self.nb_max,
-            dtype=str(np_.dtype(eng.bundle.policy.compute_jnp)),
-            quant=bool(getattr(bcfg, "kv_quant", False)),
-            interpret=bool(getattr(bcfg, "pallas_interpret", False)),
-            pin=pin, table_path=path,
-        )
+
+        def tuned(t: int) -> str:
+            return autotune.ensure_tuned(
+                "paged_decode", eng.bundle, eng.replicas,
+                b=self.n_slots, kvh=kvh,
+                n_rep=int(bcfg.num_heads) // kvh, d=int(bcfg.head_dim),
+                block_size=self.block_size, t=t,
+                dtype=str(np_.dtype(eng.bundle.policy.compute_jnp)),
+                quant=bool(getattr(bcfg, "kv_quant", False)),
+                interpret=bool(getattr(bcfg, "pallas_interpret", False)),
+                pin=pin, table_path=path,
+            )
+
+        self.kernel_variant = tuned(self.nb_max)
+        if getattr(bcfg, "window", 0):
+            # A window layer's kernel runs at the width of its table
+            # view (models/llama.window_view): a tuning problem of its own.
+            from ..models.llama import window_view_blocks
+
+            tw = window_view_blocks(bcfg.window, self.block_size, self.nb_max)
+            if tw != self.nb_max:
+                tuned(tw)
 
     def _warm_paged(self, warm_sampled: bool) -> None:
         """Paged-mode warmup: the start and the paged insert per (wave

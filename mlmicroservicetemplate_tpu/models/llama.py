@@ -30,7 +30,9 @@ weights transposed to [in,out]).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -111,13 +113,49 @@ class LlamaConfig:
     norm_topk_prob: bool = False
     # Learned-scale RMSNorm over the WHOLE q and k projections, before
     # the head split and RoPE (OLMoE / OLMo-2); ``_qkv_rope``.
-    qk_norm: bool = False
+    qk_norm: bool | str = False
     # Prompts start with the tokenizer's BOS (the Llama / Mistral
     # convention; registry._build_llama applies it to the tokenizer).
     # OLMoE's tokenizer has no BOS: a token every stream shares at
     # position 0 is also what collapses a seeded random router onto a
     # few experts (PERF.md section 6, PR 27).
     add_bos: bool = True
+    # A per-layer pattern (Trinity / AFMoE-style; every field's default is
+    # the one-kind block above).  ``layer_kind(li)`` is the one place that
+    # answers "what is layer li"; every step kind asks it.
+    #   attention: ``layer_types`` names each layer "window" or "full"
+    #   (HF's "sliding_attention" / "full_attention" are accepted; empty =
+    #   all full); a window layer's query at position i sees keys
+    #   i-window+1..i.
+    layer_types: tuple = ()
+    window: int = 0
+    #   FFN: the first ``num_dense_layers`` layers are dense SwiGLU of width
+    #   ``d_ff_dense`` whatever ``num_experts`` says; the others follow it.
+    num_dense_layers: int = 0
+    d_ff_dense: int = 0
+    # Width of one head where it is a size of its own (0 = d_model //
+    # num_heads): q is then num_heads * head_dim wide, not d_model.
+    head_dim: int = 0
+    # The router (ops/moe.py): ``router_score`` softmax | sigmoid over all
+    # experts in f32; ``router_bias`` adds a per-expert bias to the scores
+    # for the SELECTION only; the chosen scores (renormalised under
+    # ``norm_topk_prob``) are multiplied by ``route_scale``;
+    # ``num_shared_experts`` experts of width ``d_ff`` run on every token.
+    num_shared_experts: int = 0
+    router_score: str = "softmax"
+    route_scale: float = 1.0
+    router_bias: bool = False
+    # Block variants: a learned-scale RMSNorm AFTER the attention and after
+    # the FFN as well as before (``sandwich_norm``); a sigmoid gate
+    # ``sigmoid(y W_g)`` on the attention output before ``W_o``
+    # (``attn_gate``); ``qk_norm="head"`` = the q/k RMSNorm per head over
+    # ``head_dim`` (True = OLMoE's, over the whole projection); no
+    # positional encoding on full-attention layers (``nope_on_full``); the
+    # embedding scaled by sqrt(d_model) (``mup_embed``).
+    sandwich_norm: bool = False
+    attn_gate: bool = False
+    nope_on_full: bool = False
+    mup_embed: bool = False
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -127,14 +165,68 @@ class LlamaConfig:
                 f"experts_per_token={self.experts_per_token} must lie in "
                 f"1..num_experts={self.num_experts}"
             )
-
-    @property
-    def head_dim(self) -> int:
-        return self.d_model // self.num_heads
+        if not self.head_dim:
+            object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
+        hf = {"sliding_attention": "window", "full_attention": "full"}
+        # A published pattern cut in depth: its first num_layers entries.
+        types = tuple(hf.get(t, t) for t in self.layer_types)[: self.num_layers]
+        object.__setattr__(self, "layer_types", types)
+        if types and (len(types) != self.num_layers
+                      or set(types) - {"window", "full"}):
+            raise ValueError(
+                f"layer_types must name each of the {self.num_layers} layers "
+                f"'window' or 'full', got {types}"
+            )
+        if ("window" in types) != bool(self.window):
+            raise ValueError(
+                f"window={self.window} and layer_types={types}: a window "
+                "needs layers that use it, and window layers need a window"
+            )
+        if self.num_dense_layers and not self.d_ff_dense:
+            raise ValueError("num_dense_layers needs d_ff_dense")
+        if self.num_experts and self.num_dense_layers >= self.num_layers:
+            raise ValueError("num_dense_layers leaves no expert layer")
+        if self.router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"router_score={self.router_score!r}")
+        if self.qk_norm not in (False, True, "head"):
+            raise ValueError(f"qk_norm={self.qk_norm!r} (False, True, 'head')")
 
     @property
     def n_rep(self) -> int:
         return self.num_heads // self.num_kv_heads
+
+    @property
+    def q_dim(self) -> int:
+        return self.num_heads * self.head_dim
+
+    def layer_kind(self, li: int) -> "LayerKind":
+        window = self.window if (
+            self.layer_types and self.layer_types[li] == "window") else 0
+        dense = li < self.num_dense_layers
+        return LayerKind(
+            window=window,
+            rope=bool(window) or not self.nope_on_full,
+            experts=bool(self.num_experts) and not dense,
+            d_ff=self.d_ff_dense if dense else self.d_ff,
+        )
+
+    @property
+    def expert_layers(self) -> tuple:
+        return tuple(li for li in range(self.num_layers)
+                     if self.layer_kind(li).experts)
+
+
+@dataclasses.dataclass(frozen=True)
+class LayerKind:
+    """What one layer is: ``window`` keys a query sees (0 = all before
+    it), whether q and k are rotated, and its FFN (``experts``: the
+    sparse expert block of experts ``d_ff`` wide; else a dense SwiGLU of
+    width ``d_ff``)."""
+
+    window: int
+    rope: bool
+    experts: bool
+    d_ff: int
 
 
 # ---------------------------------------------------------------------------
@@ -151,7 +243,11 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
     def cast(tree):
         if dtype is None:
             return tree
-        return jax.tree.map(lambda a: a.astype(dtype), tree)
+        # Waited for, leaf by leaf: dispatch runs far ahead of the device,
+        # and every float32 draw still in flight holds its buffer (twelve
+        # expert stacks of 1.07 GB each at Trinity's widths).
+        return jax.block_until_ready(
+            jax.tree.map(lambda a: a.astype(dtype), tree))
 
     def lin(k, d_in, d_out):
         return cast(dense_init(k, d_in, d_out, bias=False, std=0.02))
@@ -164,8 +260,8 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         return {"kernel": cast(normal_init(jax.random.split(k)[0], shape, std=0.02))}
 
     keys = jax.random.split(key, cfg.num_layers + 2)
-    d, kv_dim = cfg.d_model, cfg.num_kv_heads * cfg.head_dim
-    e, w = cfg.num_experts, cfg.d_ff
+    d, qd, kv_dim = cfg.d_model, cfg.q_dim, cfg.num_kv_heads * cfg.head_dim
+    e = cfg.num_experts
     params: Params = {
         "embed": {"embedding": cast(normal_init(keys[0], (cfg.vocab_size, d), std=0.02))},
         "layers": [],
@@ -173,39 +269,61 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         "lm_head": {"kernel": cast(normal_init(keys[1], (d, cfg.vocab_size), std=0.02))},
     }
     for i in range(cfg.num_layers):
-        k = jax.random.split(keys[2 + i], 7)
+        lk = keys[2 + i]
+        k = jax.random.split(lk, 7)
+        kind, w = cfg.layer_kind(i), cfg.layer_kind(i).d_ff
+
+        def extra(n):  # leaves newer than the 7-way split: their own keys
+            return jax.random.fold_in(lk, n)
+
         attn = {
-            "q": lin(k[0], d, d),
+            "q": lin(k[0], d, qd),
             "k": lin(k[1], d, kv_dim),
             "v": lin(k[2], d, kv_dim),
-            "o": lin(k[3], d, d),
+            "o": lin(k[3], qd, d),
         }
         if cfg.qk_norm:
             # Learned scales have no reason to be 1: drawn about it, so a
             # served path that drops the norm departs from one that has it.
-            attn["q_norm"] = norm_scale(jax.random.fold_in(keys[2 + i], 8), d)
-            attn["k_norm"] = norm_scale(jax.random.fold_in(keys[2 + i], 9), kv_dim)
-        if e:
+            per_head = cfg.qk_norm == "head"
+            attn["q_norm"] = norm_scale(extra(8), cfg.head_dim if per_head else qd)
+            attn["k_norm"] = norm_scale(extra(9), cfg.head_dim if per_head else kv_dim)
+        if cfg.attn_gate:
+            attn["gate"] = lin(extra(10), d, qd)
+        if kind.experts:
             mlp = {
-                "router": lin(jax.random.fold_in(keys[2 + i], 7), d, e),
+                "router": lin(extra(7), d, e),
                 "gate": experts(k[4], (e, d, w)),
                 "up": experts(k[5], (e, d, w)),
                 "down": experts(k[6], (e, w, d)),
             }
+            if cfg.router_bias:
+                # The buffer of aux-loss-free balancing: large enough
+                # beside sigmoid scores that dropping it moves the choice.
+                mlp["router_bias"] = cast(normal_init(extra(13), (e,), std=0.05))
+            if cfg.num_shared_experts:
+                ws = cfg.num_shared_experts * w
+                mlp["shared"] = {
+                    "gate": lin(extra(14), d, ws),
+                    "up": lin(extra(15), d, ws),
+                    "down": lin(extra(16), ws, d),
+                }
         else:
             mlp = {
                 "gate": lin(k[4], d, w),
                 "up": lin(k[5], d, w),
                 "down": lin(k[6], w, d),
             }
-        params["layers"].append(
-            {
-                "attn_ln": cast(rmsnorm_init(d)),
-                "attn": attn,
-                "mlp_ln": cast(rmsnorm_init(d)),
-                "mlp": mlp,
-            }
-        )
+        layer = {
+            "attn_ln": cast(rmsnorm_init(d)),
+            "attn": attn,
+            "mlp_ln": cast(rmsnorm_init(d)),
+            "mlp": mlp,
+        }
+        if cfg.sandwich_norm:
+            layer["attn_post_ln"] = norm_scale(extra(11), d)
+            layer["mlp_post_ln"] = norm_scale(extra(12), d)
+        params["layers"].append(layer)
     return params
 
 
@@ -250,31 +368,48 @@ def _split(x: jax.Array, n_heads: int) -> jax.Array:
     return x.reshape(b, s, n_heads, d // n_heads)
 
 
-def _mlp_block(cfg: "LlamaConfig", layer, x, valid, tally=None):
-    """Pre-norm FFN block with its residual, under the ``mlp`` scope
-    (the device trace's name for it in every step kind): the dense
-    SwiGLU, or with ``cfg.num_experts`` the sparse expert FFN
-    (ops/moe.py).  ``valid`` [B, S] marks the rows that are neither
-    padding nor finished — only they get expert work; ``tally`` (a
-    list) receives the layer's [E] count of their assignments."""
+def _embed(params: Params, cfg: "LlamaConfig", ids, dtype):
+    """Token rows of the embedding table (scaled by sqrt(d_model) under
+    ``cfg.mup_embed``) — the ``embed`` scope of every step kind."""
+    with jax.named_scope("embed"):
+        x = embed(params["embed"], ids, dtype)
+        if cfg.mup_embed:
+            x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    return x
+
+
+def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
+    """Pre-norm FFN block of layer ``li`` with its residual, under the
+    ``mlp`` scope (the device trace's name for it in every step kind):
+    the dense SwiGLU, or on an expert layer (``cfg.layer_kind``) the
+    sparse expert FFN (ops/moe.py); under ``cfg.sandwich_norm`` the
+    block's output is normed again before the residual.  ``valid``
+    [B, S] marks the rows that are neither padding nor finished — only
+    they get expert work; ``tally`` (a list) receives an expert layer's
+    [E] count of their assignments."""
     with jax.named_scope("mlp"):
         h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
         m = layer["mlp"]
-        if cfg.num_experts:
+        if cfg.layer_kind(li).experts:
             from ..ops.moe import expert_ffn
 
             b, s, d = x.shape
             out, counts = expert_ffn(
                 h.reshape(b * s, d), m, cfg.experts_per_token,
                 cfg.norm_topk_prob, jnp.broadcast_to(valid, (b, s)).reshape(-1),
-                interpret=cfg.pallas_interpret,
+                interpret=cfg.pallas_interpret, score=cfg.router_score,
+                route_scale=cfg.route_scale,
             )
             if tally is not None:
                 tally.append(counts)
-            return x + out.reshape(b, s, d)
-        return x + dense(
-            m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h)
-        )
+            out = out.reshape(b, s, d)
+        else:
+            out = dense(
+                m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h)
+            )
+        if cfg.sandwich_norm:
+            out = rmsnorm(layer["mlp_post_ln"], out, eps=cfg.rms_eps)
+        return x + out
 
 
 def _select_next(params: Params, cfg: "LlamaConfig", state, x_last,
@@ -307,22 +442,63 @@ def _aproj(a, ad, name: str, li: int, x):
 
 
 def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
-    """Rotated q [.., H, Dh] and k, and v [.., KVH, Dh] of one layer from
-    the residual stream x [B, S, D] — the ``qkv_rope`` scope of every
-    step kind.  Under ``cfg.qk_norm`` q and k pass a learned-scale
-    RMSNorm over the whole projection before the head split."""
+    """q [.., H, Dh], k and v [.., KVH, Dh] of layer ``li`` from the
+    residual stream x [B, S, D], and the attention output's gate
+    [.., H*Dh] (None without ``cfg.attn_gate``) — the ``qkv_rope`` scope
+    of every step kind.  ``cfg.qk_norm``: a learned-scale RMSNorm on q
+    and k before RoPE — True over the whole projection before the head
+    split (OLMoE), "head" per head over Dh (Trinity).  q and k are
+    rotated unless the layer's kind says not (``cfg.nope_on_full``)."""
     a = layer["attn"]
     with jax.named_scope("qkv_rope"):
         h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         q = _aproj(a, ad, "q", li, h)
         k = _aproj(a, ad, "k", li, h)
-        if cfg.qk_norm:
+        if cfg.qk_norm is True:
             q = rmsnorm(a["q_norm"], q, eps=cfg.rms_eps)
             k = rmsnorm(a["k_norm"], k, eps=cfg.rms_eps)
-        q = _apply_rope(_split(q, cfg.num_heads), cos, sin)
-        k = _apply_rope(_split(k, cfg.num_kv_heads), cos, sin)
+        q, k = _split(q, cfg.num_heads), _split(k, cfg.num_kv_heads)
+        if cfg.qk_norm == "head":
+            q = rmsnorm(a["q_norm"], q, eps=cfg.rms_eps)
+            k = rmsnorm(a["k_norm"], k, eps=cfg.rms_eps)
+        if cfg.layer_kind(li).rope:
+            q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
         v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
-    return q, k, v
+        g = jax.nn.sigmoid(_aproj(a, ad, "gate", li, h)) if cfg.attn_gate else None
+    return q, k, v, g
+
+
+def _attn_out(cfg: "LlamaConfig", layer, ad, li: int, x, ctx, g):
+    """The attention block's end, with its residual — the ``attn_out``
+    scope of every step kind: heads merged, gated (``g`` from
+    ``_qkv_rope``), projected by ``W_o`` and, under
+    ``cfg.sandwich_norm``, normed again before the residual."""
+    with jax.named_scope("attn_out"):
+        y = merge_heads(ctx)
+        if g is not None:
+            y = y * g
+        y = _aproj(layer["attn"], ad, "o", li, y)
+        if cfg.sandwich_norm:
+            y = rmsnorm(layer["attn_post_ln"], y, eps=cfg.rms_eps)
+        return x + y
+
+
+def _attn_scope(cfg: "LlamaConfig", li: int):
+    """``attn`` and, for a config with a layer pattern, the layer's kind
+    inside it (``attn_window`` / ``attn_full``): the scope a layer's
+    attention runs under in every step kind."""
+    stack = contextlib.ExitStack()
+    stack.enter_context(jax.named_scope("attn"))
+    if cfg.layer_types:
+        stack.enter_context(jax.named_scope(
+            "attn_window" if cfg.layer_kind(li).window else "attn_full"))
+    return stack
+
+
+def _band(q_pos, k_pos, window: int):
+    """[..., Q, K] bool: key position within ``window`` keys of the
+    query's (itself included); the causal side is the caller's mask."""
+    return q_pos[..., :, None] - k_pos[..., None, :] < window
 
 
 # ---------------------------------------------------------------------------
@@ -386,8 +562,7 @@ def forward_hidden(
     cost is O(S), not O(P+S)."""
     b, s = input_ids.shape
     p_len = 0 if prefix_kv is None else _prefix_entry_len(prefix_kv[0][0])
-    with jax.named_scope("embed"):
-        x = embed(params["embed"], input_ids, dtype)
+    x = _embed(params, cfg, input_ids, dtype)
     pos = jnp.arange(p_len, p_len + s, dtype=jnp.int32)
     cos, sin = _rope_tables(cfg, pos, dtype)  # [S, D_h]
     cos, sin = cos[None, :, None, :], sin[None, :, None, :]
@@ -398,14 +573,14 @@ def forward_hidden(
         mask = jnp.concatenate(
             [jnp.broadcast_to(pre, (b, 1, s, p_len)), mask], axis=-1
         )
+    band = mask & _band(pos, jnp.arange(p_len + s), cfg.window) if cfg.window else None
     ad = lora.adapter_tables(params)
     kv = []
     for li, layer in enumerate(params["layers"]):
-        a = layer["attn"]
-        q, k, v = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        q, k, v, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         if collect_kv:
             kv.append((k, v))
-        with jax.named_scope("attn"):
+        with _attn_scope(cfg, li):
             if p_len:
                 pk = _dequant_prefix(prefix_kv[li][0], k.dtype)
                 pv = _dequant_prefix(prefix_kv[li][1], v.dtype)
@@ -416,11 +591,11 @@ def forward_hidden(
                     [jnp.broadcast_to(pv, (b,) + pv.shape[1:]), v], axis=1
                 )
             ctx = mha_attention(
-                q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep), mask=mask
+                q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep),
+                mask=band if cfg.layer_kind(li).window else mask,
             )
-        with jax.named_scope("attn_out"):
-            x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x, attention_mask != 0)
+        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+        x = _mlp_block(cfg, layer, li, x, attention_mask != 0)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     return (x, kv) if collect_kv else x
 
@@ -591,8 +766,7 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
     b = state.last_token.shape[0]
     rows = jnp.arange(b)
     t = state.write_idx  # [B] per-row position
-    with jax.named_scope("embed"):
-        x = embed(params["embed"], state.last_token[:, None], dtype)  # [B,1,D]
+    x = _embed(params, cfg, state.last_token[:, None], dtype)  # [B,1,D]
     # Per-row rotary tables at each row's own position (clamped for
     # long-dead continuous-batching rows whose writes drop anyway).
     cos, sin = _rope_tables(cfg, jnp.minimum(t, cfg.max_position - 1), dtype)
@@ -603,8 +777,7 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        a = layer["attn"]
-        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
             ck = _write_kv(state.cache_k[li], rows, t, k1[:, 0], dtype)
             cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
@@ -612,9 +785,8 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
         new_v.append(cv)
         with jax.named_scope("attn"):
             ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
-        with jax.named_scope("attn_out"):
-            x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x, ~state.done[:, None])
+        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+        x = _mlp_block(cfg, layer, li, x, ~state.done[:, None])
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
@@ -646,7 +818,7 @@ def multi_step(
     rows = jnp.arange(b)[:, None]  # [B, 1]
     t = state.write_idx  # [B]
     pos_w = t[:, None] + jnp.arange(d_w)[None]  # [B, D]
-    x = embed(params["embed"], tokens, dtype)  # [B, D, Dm]
+    x = _embed(params, cfg, tokens, dtype)  # [B, D, Dm]
     cos, sin = _rope_tables(
         cfg, jnp.minimum(pos_w, cfg.max_position - 1), dtype
     )  # [B, D, Dh]
@@ -660,15 +832,14 @@ def multi_step(
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        a = layer["attn"]
-        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         ck = _write_kv(state.cache_k[li], rows, pos_w, k1, dtype)
         cv = _write_kv(state.cache_v[li], rows, pos_w, v1, dtype)
         new_k.append(ck)
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
-        x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x, ~state.done[:, None])
+        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+        x = _mlp_block(cfg, layer, li, x, ~state.done[:, None])
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     logits = lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
     return new_k, new_v, logits  # [B, D, V]
@@ -792,6 +963,45 @@ def _gathered_attention(cfg: LlamaConfig, q, ck, cv, table, bs: int, mask):
     return mha_attention(q, dense(ck, d), dense(cv, d), mask=mask)
 
 
+#: A window layer's table view is a multiple of this many blocks wide, so
+#: that every block fold the autotuner enumerates divides it.
+VIEW_BLOCK_MULTIPLE = 8
+
+
+def window_view_blocks(window: int, bs: int, t_width: int) -> int:
+    """Table entries a window layer's view holds: the blocks ``window``
+    consecutive keys can lie in (129 at 2048 keys in blocks of 16),
+    rounded up; ``t_width`` (no view: the layer walks the whole table,
+    masked) where the table is no wider than that."""
+    span = (window - 1 + bs - 1) // bs + 1
+    tw = -(-span // VIEW_BLOCK_MULTIPLE) * VIEW_BLOCK_MULTIPLE
+    return tw if tw < t_width else t_width
+
+
+def window_view(table, key_valid, t, window: int, bs: int):
+    """What a window layer attends over in a decode step whose newest
+    key lies at position ``t`` [B]: ``(table [B, Tw], key_valid
+    [B, Tw*bs])`` — per row the ``Tw`` consecutive table ENTRIES from the
+    block that holds key ``t-window+1`` on (the pool is never touched),
+    and the matching slice of ``key_valid`` with every key older than
+    ``t-window+1`` cleared.  The kernel and the gathered path take it in
+    place of the whole table: ``Tw/K`` programs a row, not ``T/K``."""
+    t_width = table.shape[1]
+    tw = window_view_blocks(window, bs, t_width)
+    lo = jnp.maximum(t - window + 1, 0)  # oldest key in the window
+    first = jnp.minimum(lo // bs, t_width - tw)  # [B] first block of the view
+    pos = first[:, None] * bs + jnp.arange(tw * bs)[None, :]  # key positions
+
+    def rows(a, start, n):  # a row's n consecutive entries from its own start
+        return jax.vmap(
+            lambda r, s: jax.lax.dynamic_slice_in_dim(r, s, n))(a, start)
+
+    valid = rows(key_valid, first * bs, tw * bs) * (pos >= lo[:, None])
+    if tw == t_width:
+        return table, valid
+    return rows(table, first, tw), valid
+
+
 def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
                        sample: bool = False):
     """One paged decode step: ``_decode_step`` with cache reads/writes
@@ -807,27 +1017,31 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
     b = state.last_token.shape[0]
     rows = jnp.arange(b)
     t = state.write_idx
-    with jax.named_scope("embed"):
-        x = embed(params["embed"], state.last_token[:, None], dtype)
+    x = _embed(params, cfg, state.last_token[:, None], dtype)
     cos, sin = _rope_tables(cfg, jnp.minimum(t, cfg.max_position - 1), dtype)
     cos, sin = cos[:, None, None, :], sin[:, None, None, :]
     key_valid = state.key_valid.at[rows, t].set(1, mode="drop")
+    full = (table, key_valid)
+    if cfg.window:
+        # Once a step, shared by the window layers.
+        with jax.named_scope("attn"), jax.named_scope("attn_window_view"):
+            view = window_view(table, key_valid, t, cfg.window, bs)
 
     ad = lora.adapter_tables(params)
     new_k, new_v, moe_tally = [], [], []
     for li, layer in enumerate(params["layers"]):
-        a = layer["attn"]
-        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
             ck = _paged_write_kv(state.cache_k[li], table, t, k1[:, 0], bs, dtype)
             cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
         new_k.append(ck)
         new_v.append(cv)
-        with jax.named_scope("attn"):
-            ctx = _paged_cache_attention(cfg, q, ck, cv, table, key_valid, bs)
-        with jax.named_scope("attn_out"):
-            x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x, ~state.done[:, None], moe_tally)
+        with _attn_scope(cfg, li):
+            ctx = _paged_cache_attention(
+                cfg, q, ck, cv, *(view if cfg.layer_kind(li).window else full), bs
+            )
+        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+        x = _mlp_block(cfg, layer, li, x, ~state.done[:, None], moe_tally)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
@@ -939,7 +1153,7 @@ def prefill_chunk(
     b, c = chunk_ids.shape
     rows = jnp.arange(b)[:, None]
     pos_w = jnp.broadcast_to(start + jnp.arange(c)[None, :], (b, c))
-    x = embed(params["embed"], chunk_ids, dtype)
+    x = _embed(params, cfg, chunk_ids, dtype)
     cos, sin = _rope_tables(
         cfg, jnp.minimum(pos_w, cfg.max_position - 1), dtype
     )  # [B, C, Dh]
@@ -949,15 +1163,14 @@ def prefill_chunk(
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        a = layer["attn"]
-        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         ck = _write_kv(state.cache_k[li], rows, pos_w, k1, dtype)
         cv = _write_kv(state.cache_v[li], rows, pos_w, v1, dtype)
         new_k.append(ck)
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
-        x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x, chunk_mask != 0)
+        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+        x = _mlp_block(cfg, layer, li, x, chunk_mask != 0)
     key_valid = state.key_valid.at[rows, pos_w].set(
         chunk_mask.astype(jnp.int32), mode="drop"
     )
@@ -978,6 +1191,32 @@ def _paged_scatter_entry(cache, table_row, vals, bs: int, start, dtype):
     return scatter_pages(cache, table_row, vals, bs, start=start)
 
 
+#: A full layer's prompt window attends over the narrowest of at most this
+#: many widths of the table that holds its keys (``paged_prefill_chunk``).
+PREFILL_KEY_WIDTHS = 8
+
+
+def _prefill_mask(kpos, chunk_mask, start, window: int):
+    """[B, 1, C, K] attention mask of one prompt window over the keys at
+    positions ``kpos`` [K]: every position before the window (``start``,
+    traced: earlier windows or an adopted prefix) plus the causal,
+    pad-gated part of the window itself — ``gpt._window_mask`` for keys
+    that are a slice of the row — and, with ``window``, only the
+    ``window`` keys up to each query's own."""
+    b, c = chunk_mask.shape
+    off = kpos[None, :] - start  # [1, K] key offset into the window
+    wvalid = jnp.take_along_axis(
+        chunk_mask.astype(jnp.int32),
+        jnp.clip(jnp.broadcast_to(off, (b, kpos.shape[0])), 0, c - 1), axis=1,
+    )
+    win_keys = (off >= 0) & (off < c) & (wvalid != 0)  # [B, K]
+    causal = off[:, None, :] <= jnp.arange(c)[None, :, None]  # [1, C, K]
+    mask = (off < 0)[:, None, :] | (win_keys[:, None, :] & causal)
+    if window:
+        mask &= jnp.arange(c)[None, :, None] - off[:, None, :] < window
+    return mask[:, None]
+
+
 def paged_prefill_chunk(
     params: Params,
     cfg: LlamaConfig,
@@ -990,32 +1229,61 @@ def paged_prefill_chunk(
 ):
     """One prompt window straight into pool blocks (see
     ``gpt.paged_prefill_chunk``), at GQA width and composed with the
-    int8 pool pairs."""
-    from .gpt import _window_mask
-
+    int8 pool pairs.  The window's queries attend over the part of the
+    row that can hold their keys, not the whole table (a [H, C, T*BS]
+    float32 score tensor at every layer otherwise: 0.8 GB a layer at
+    C = 1024 under a 6272-token table): a window layer over the
+    ``C + window`` keys that end with the window's last query, a full
+    layer over the narrowest of ``PREFILL_KEY_WIDTHS`` prefixes of the
+    row that reaches it (one ``lax.switch``; ``start`` stays traced and
+    one executable serves every window of every prompt)."""
     b, c = chunk_ids.shape  # b == 1
     entry = state.cache_k[0]
     bs = entry[0].shape[1] if isinstance(entry, tuple) else entry.shape[1]
     pos_w = jnp.broadcast_to(start + jnp.arange(c)[None, :], (b, c))
-    x = embed(params["embed"], chunk_ids, dtype)
+    x = _embed(params, cfg, chunk_ids, dtype)
     cos, sin = _rope_tables(cfg, jnp.minimum(pos_w, cfg.max_position - 1), dtype)
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    total = table_row.shape[0] * bs
-    base_valid = jnp.broadcast_to(jnp.arange(total)[None, :] < start, (b, total))
-    mask = _window_mask(base_valid, chunk_mask, start)
+    t_w = table_row.shape[0]
+
+    def attend(q, ck, cv, first, n_blocks: int, window: int):
+        """Over ``n_blocks`` (static) table entries from ``first`` on."""
+        rows = jax.lax.dynamic_slice_in_dim(table_row, first, n_blocks)
+        kpos = first * bs + jnp.arange(n_blocks * bs)
+        mask = _prefill_mask(kpos, chunk_mask, start, window)
+        return _gathered_attention(cfg, q, ck, cv, rows[None], bs, mask)
+
+    # Full layers: keys 0 .. start + C - 1, in prefixes of whole steps.
+    step = -(-max(c, -(-t_w * bs // PREFILL_KEY_WIDTHS)) // bs)  # blocks
+    widths = sorted({min(t_w, k * step) for k in range(1, -(-t_w // step) + 1)})
+    reach = jnp.minimum((start + c - 1) // (step * bs), len(widths) - 1)
+    # Window layers: keys start - window + 1 .. start + C - 1.
+    n_win = min(t_w, -(-(c + cfg.window - 1) // bs) + 1)
+    first_win = jnp.clip((start - cfg.window + 1) // bs, 0, t_w - n_win)
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
-        a = layer["attn"]
-        q, k1, v1 = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
-        ck = _paged_scatter_entry(state.cache_k[li], table_row, k1[0], bs, start, dtype)
-        cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
+        q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        with jax.named_scope("kv_write"):
+            ck = _paged_scatter_entry(state.cache_k[li], table_row, k1[0], bs, start, dtype)
+            cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
         new_k.append(ck)
         new_v.append(cv)
-        ctx = _gathered_attention(cfg, q, ck, cv, table_row[None], bs, mask)
-        x = x + _aproj(a, ad, "o", li, merge_heads(ctx))
-        x = _mlp_block(cfg, layer, x, chunk_mask != 0)
+        with _attn_scope(cfg, li):
+            if cfg.layer_kind(li).window:
+                ctx = attend(q, ck, cv, first_win, n_win, cfg.window)
+            elif len(widths) == 1:
+                ctx = attend(q, ck, cv, 0, t_w, 0)
+            else:
+                ctx = jax.lax.switch(
+                    reach,
+                    [functools.partial(attend, first=0, n_blocks=w, window=0)
+                     for w in widths],
+                    q, ck, cv,
+                )
+        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+        x = _mlp_block(cfg, layer, li, x, chunk_mask != 0)
     return state._replace(cache_k=new_k, cache_v=new_v)
 
 

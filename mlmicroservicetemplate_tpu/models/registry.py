@@ -871,11 +871,21 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             f"eos_id={cfg.eos_id}/pad_id={cfg.pad_id} outside llama vocab "
             f"of {cfg.vocab_size}"
         )
-    if cfg.num_experts or cfg.qk_norm:
-        # The expert leaves ([E, d, w] kernels, a router) and the q/k-norm
-        # (an RMS over the whole projection) are in no TP param spec and
-        # no int8 scheme: refuse what is not covered, never serve it wrong.
-        what = "an expert FFN" if cfg.num_experts else "the q/k-norm"
+    variants = [
+        name for name, on in (
+            ("an expert FFN", cfg.num_experts), ("the q/k-norm", cfg.qk_norm),
+            ("a layer pattern", cfg.layer_types or cfg.num_dense_layers),
+            ("a head_dim of its own", cfg.q_dim != cfg.d_model),
+            ("an attention gate", cfg.attn_gate),
+            ("sandwich norms", cfg.sandwich_norm),
+        ) if on
+    ]
+    if variants:
+        # The expert leaves ([E, d, w] kernels, a router, a shared expert),
+        # the q/k-norm, the gate, the post-norms and a q projection wider
+        # than d_model are in no TP param spec and no int8 scheme: refuse
+        # what is not covered, never serve it wrong.
+        what = variants[0]
         if int(getattr(svc_cfg, "tp", 0) or 0) > 1:
             raise ValueError(
                 f"TP={svc_cfg.tp} is not supported for a llama config with "
@@ -889,6 +899,29 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
                 f"config with {what} (models/quant.py has no per-expert "
                 "scale for [E, d, w] kernels)"
             )
+    if cfg.window:
+        # The window binds in the prefill waves, the chunked paged prefill
+        # and the paged decode step (kernel and gathered path:
+        # models/llama.py).  Every other reader of the cache would attend
+        # over ALL the keys and serve another model in silence: refuse it.
+        for on, knob, why in (
+            (not getattr(svc_cfg, "paged_kv", False), "PAGED_KV=0",
+             "the contiguous slab's decode step, chunked prefill and fused "
+             "decode window apply no window: set PAGED_KV=1"),
+            (getattr(svc_cfg, "spec_decode", None), "SPEC_DECODE",
+             "speculative verification (llama.multi_step) applies no window"),
+            (getattr(svc_cfg, "quant_kv", None), "QUANT_KV",
+             "the int8 pool pairs were never run under a window view"),
+            (getattr(svc_cfg, "prefix_cache", False), "PREFIX_CACHE",
+             "a prefix hit's gathers and prefixed prefill apply no window"),
+            (getattr(svc_cfg, "prompt_prefix", None), "PROMPT_PREFIX",
+             "the prefix overlay's prefill applies no window"),
+        ):
+            if on:
+                raise ValueError(
+                    f"{knob} is not supported for a llama config with window "
+                    f"layers (layer_types / window={cfg.window}): {why}"
+                )
     if cfg.num_experts and not _pallas_backend_ok(svc_cfg):
         raise RuntimeError(
             "the expert FFN's grouped matmul (ops/moe.py) is a Pallas TPU "

@@ -5,10 +5,14 @@ One implementation for every step kind (a 64-row decode step has 512
 assignments, a 64 x 512 prefill rung 262 144): compute is the chosen
 experts' only, never a dense pass over all experts under a mask.
 
-    p = softmax_f32(h W_r)                     router, over ALL experts
-    (w_1..w_k, e_1..e_k) = top_k(p)            weights as they are, or
-                                               renormalised (norm_topk)
+    p = softmax_f32(h W_r)  |  sigmoid_f32(h W_r)    router scores, over ALL
+                                               experts (``score``)
+    e_1..e_k = top_k(p + b)                    b: a per-expert bias, in the
+                                               SELECTION only (``router_bias``)
+    w_j = route_scale * p[e_j]                 as they are, or renormalised
+                                               over the chosen (norm_topk)
     out = sum_j w_j * down_{e_j}(silu(gate_{e_j} h) * up_{e_j} h)
+          + down_s(silu(gate_s h) * up_s h)    a shared expert, every token
 
 Rows that are padding or finished (``valid`` false) are sorted past the
 last group: the grouped matmul gives them no expert, their (undefined)
@@ -54,19 +58,30 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
 
 
 def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
-               interpret: bool = False):
+               interpret: bool = False, score: str = "softmax",
+               route_scale: float = 1.0):
     """h [T, D] normed tokens, ``mlp`` the layer's expert leaves (router
-    [D, E]; gate, up [E, D, W]; down [E, W, D]), valid [T] bool ->
-    (out [T, D] in h's dtype, counts [E] int32 of valid assignments).
-    ``interpret`` runs the kernel in interpret mode (CPU tests)."""
+    [D, E]; gate, up [E, D, W]; down [E, W, D]; where the model has them
+    ``router_bias`` [E] and ``shared``, a dense SwiGLU's gate/up/down),
+    valid [T] bool -> (out [T, D] in h's dtype, counts [E] int32 of valid
+    assignments).  ``interpret`` runs the kernel in interpret mode (CPU
+    tests)."""
     t, d = h.shape
     gate, up, down = (mlp[n]["kernel"] for n in ("gate", "up", "down"))
     n_exp = gate.shape[0]
     with jax.named_scope("moe_route"):
         logits = h.astype(jnp.float32) @ mlp["router"]["kernel"].astype(jnp.float32)
-        w, e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)  # [T, k]
+        p = jax.nn.softmax(logits, axis=-1) if score == "softmax" else (
+            jax.nn.sigmoid(logits))
+        if "router_bias" in mlp:
+            _, e = jax.lax.top_k(p + mlp["router_bias"].astype(jnp.float32), k)
+            w = jnp.take_along_axis(p, e, axis=-1)
+        else:
+            w, e = jax.lax.top_k(p, k)  # [T, k]
         if norm_topk:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
+        if route_scale != 1.0:
+            w = w * route_scale
         # An invalid row's assignments take group id E: past every real
         # group in the sort, in no group's count.
         e = jnp.where(valid[:, None], e, n_exp).reshape(-1)
@@ -85,4 +100,12 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
         ys = jnp.where(in_group[:, None], ys, 0)
         back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(t, k, d)
         out = jnp.sum(back.astype(jnp.float32) * w[:, :, None], axis=1)
-    return out.astype(h.dtype), counts
+    out = out.astype(h.dtype)
+    if "shared" in mlp:
+        with jax.named_scope("moe_shared"):
+            sh = mlp["shared"]
+            out = out + (
+                jax.nn.silu(h @ sh["gate"]["kernel"].astype(h.dtype))
+                * (h @ sh["up"]["kernel"].astype(h.dtype))
+            ) @ sh["down"]["kernel"].astype(h.dtype)
+    return out, counts

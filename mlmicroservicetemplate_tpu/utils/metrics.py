@@ -163,6 +163,20 @@ MOE_EXPERTS_HIT = Gauge(
     "the layers",
     ["model"],
 )
+KV_WINDOW_KEYS_READ = Counter(
+    "kv_window_keys_read_total",
+    "Window attention: keys the window layers of a per-layer pattern "
+    "read in dispatched paged decode chunks (min(context, window) a "
+    "stream a step a window layer; from the host's stream lengths)",
+    ["model"],
+)
+KV_WINDOW_KEYS_BEHIND = Counter(
+    "kv_window_keys_behind_total",
+    "Window attention: keys of live context BEHIND the window that the "
+    "window layers did not read (context - window a stream a step a "
+    "window layer): what the table view saves over walking the table",
+    ["model"],
+)
 DECODE_STEPS = Histogram(
     "seq2seq_decode_steps",
     "Decode steps executed per non-streaming seq2seq dispatch "

@@ -332,6 +332,18 @@ _CELLS = {
 }
 _CELLS["mistral-int8"] = (
     dict(_CELLS["mistral"][0], kv_quant=True), *_CELLS["mistral"][1:])
+# Trinity-Mini cut to 5 layers (PR 31): a per-layer pattern, 32 slots of
+# up to 6272 tokens (392 table entries; a window layer's view holds 136).
+_CELLS["trinity"] = (
+    dict(vocab_size=200192, d_model=2048, num_heads=32, num_kv_heads=4,
+         head_dim=128, num_layers=5, d_ff=1024, num_dense_layers=1,
+         d_ff_dense=6144, max_position=131072, window=2048,
+         layer_types=("window", "window", "window", "full", "window"),
+         num_experts=128, experts_per_token=8, num_shared_experts=1,
+         router_score="sigmoid", norm_topk_prob=True, route_scale=2.826,
+         router_bias=True, qk_norm="head", sandwich_norm=True, attn_gate=True,
+         nope_on_full=True, mup_embed=True, add_bos=False), 12573, 392, 128)
+_SLOTS = {"trinity": 32}  # MAX_STREAMS where it is not 64
 
 
 def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
@@ -349,7 +361,7 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
 
     over, nb, t, s_max = _CELLS[cell]
     cfg = LlamaConfig(**over, pallas_decode=True, pallas_variant="b4-hb")
-    b, bs, dt, budget, steps = 64, 16, jnp.bfloat16, 192, 4
+    b, bs, dt, budget, steps = _SLOTS.get(cell, 64), 16, jnp.bfloat16, 192, 4
     c, kvh = cfg.num_kv_heads * cfg.head_dim, cfg.num_kv_heads
     counts = (nb * bs * c, nb * bs * kvh)
     key = ("serving", cell, what, donate)
@@ -421,6 +433,21 @@ def test_donated_state_is_not_copied_at_entry(chip, cell, what):
     # An int8 pair's 0.8 MB scale pool is given another tiling on the
     # way in and out (a `copy` in ENTRY); never inside a step.
     assert not pool_relayouts(text, [payload_n, scale_n], in_loop_only=True)
+
+
+def test_window_layers_run_the_kernel_at_their_views_width(chip):
+    """Trinity's chunk at the cell's shapes holds the paged kernel at BOTH
+    table widths: the full layer walks T = 392 entries a row (98 programs
+    at K = 4), the four window layers their view's 136 (34 programs) —
+    the mask rides as [B, T/K, 1, K*BS].  The view is a gather of table
+    ENTRIES: no pool moves (the parametrised case above)."""
+    import re
+
+    text, _ = _serving_program(chip, "trinity", "chunk")
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
+    masks = [re.findall(r"s32\[32,(\d+),1,64\]", ln) for ln in calls]
+    widths = sorted(int(m[0]) for m in masks if m)
+    assert widths == [34, 34, 34, 34, 98], widths
 
 
 def test_an_undonated_insert_copies_every_pool(chip):

@@ -288,3 +288,28 @@ def test_spm_bpe_vocab_driven_merges():
     ids, mask = tok.encode("the", 16)
     n = int(mask.sum())
     assert [tok.pieces[i][0] for i in ids[:n]] == ["▁the"]
+
+
+import pytest  # noqa: E402
+
+
+@pytest.mark.parametrize("extra,atomic", [
+    ([], True),  # whole words where they win, Viterbi inside the others
+    ([("▁he", -0.4, TYPE_NORMAL), ("llo", -0.3, TYPE_NORMAL)], True),  # a split that BEATS the whole word
+    ([("lo▁wor", -0.1, TYPE_NORMAL)], False),  # a piece across a word boundary: no shortcut at all
+])
+def test_word_by_word_segmentation_is_the_whole_strings(extra, atomic):
+    """``_segment`` (word by word, a word that is a piece taken whole when
+    no two pieces can outscore it) gives what one Viterbi pass over the
+    whole normalized string gives — known words, words that split better
+    than they stand, OOV characters, a bare meta symbol."""
+    tok = SentencePieceTokenizer(_pieces() + extra)
+    assert tok._word_atomic is atomic
+    for text in ("hello world", "the quick hello", "hellox wor ld ▁ zq é",
+                 "hello " * 50 + "world"):
+        s = tok._normalize(text)
+        assert tok._segment(s) == tok._viterbi(s), text
+    # ``▁hello`` (-1.0) beats any two pieces (best -0.3 - 0.3 at most with the extras)?
+    whole = tok.vocab["▁hello"]
+    taken_whole = tok.scores[whole] > tok._two_piece_best
+    assert taken_whole == (not extra)
