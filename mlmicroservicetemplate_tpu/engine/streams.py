@@ -464,6 +464,7 @@ class ContinuousDecodeLoop:
             (types.count("window"), int(bcfg.window))
             if "window" in types else None
         )
+        self._attn_layers = int(getattr(bcfg, "num_layers", 0))
         if self.paged:
             from .kv_blocks import blocks_for
 
@@ -4530,8 +4531,10 @@ class ContinuousDecodeLoop:
         metrics.STREAM_BATCH.labels(eng.bundle.name).observe(len(self.active))
         w = entry[2]
         metrics.DECODE_WINDOW_CHUNKS.labels(eng.bundle.name).observe(w)
-        if self.paged and self._window_layers:
-            self._note_window_keys(eng.chunk_tokens * w)
+        if self.paged:
+            self._note_table_blocks(eng.chunk_tokens * w)
+            if self._window_layers:
+                self._note_window_keys(eng.chunk_tokens * w)
         if w > 1:
             self.window_dispatches += 1
         self._inflight_chunks.append(entry)
@@ -4663,6 +4666,41 @@ class ContinuousDecodeLoop:
         name = self.engine.bundle.name
         metrics.KV_WINDOW_KEYS_READ.labels(name).inc(read * n_layers)
         metrics.KV_WINDOW_KEYS_BEHIND.labels(name).inc(behind * n_layers)
+
+    def _note_table_blocks(self, steps: int) -> None:
+        """Block-table entries of the chunk just dispatched, a step and an
+        attention layer at a time: live = entries that hold a key the
+        layer attends to (a step over ``n`` keys, its own included, reads
+        blocks ``0 .. (n-1)//bs``; a window layer those from the block of
+        key ``n - window`` on, through its view), dead = the rest of
+        ``n_slots x`` the table's (or the view's) width — what the paged
+        kernel's live bounds skip (ops/paged_attention.live_programs).
+        From the host's own stream lengths, like ``_note_window_keys``;
+        a stream's keys start at its prompt's length, not at ``s_base``,
+        which is the collated bucket's width where a wave prefilled it
+        (the table grows off that; the keys do not)."""
+        bs, width = self.block_size, self.nb_max
+        n_win, window = self._window_layers or (0, 0)
+        n_full = self._attn_layers - n_win
+        first = np.asarray([
+            min(st.s_base, st.s_lo + int(st.feats.get("length", st.s_base)))
+            + self._dispatched_steps.get(slot, steps) - steps
+            for slot, st in self.active.items()
+        ], np.int64)
+        n = np.clip(first[:, None] + np.arange(steps), 1, width * bs)
+        last = (n - 1) // bs
+        full = int((last + 1).sum())
+        win = int((last - np.maximum(n - window, 0) // bs + 1).sum())
+        view = width
+        if n_win:
+            from ..models.llama import window_view_blocks
+
+            view = window_view_blocks(window, bs, width)
+        live = full * n_full + win * n_win
+        total = self.n_slots * steps * (width * n_full + view * n_win)
+        name = self.engine.bundle.name
+        metrics.KV_TABLE_BLOCKS_LIVE.labels(name).inc(live)
+        metrics.KV_TABLE_BLOCKS_DEAD.labels(name).inc(total - live)
 
     def _note_moe(self, counts) -> None:
         """One delivered paged chunk's per-expert assignment counts

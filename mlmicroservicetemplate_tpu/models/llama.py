@@ -985,7 +985,8 @@ def window_view(table, key_valid, t, window: int, bs: int):
     block that holds key ``t-window+1`` on (the pool is never touched),
     and the matching slice of ``key_valid`` with every key older than
     ``t-window+1`` cleared.  The kernel and the gathered path take it in
-    place of the whole table: ``Tw/K`` programs a row, not ``T/K``."""
+    place of the whole table: a row's live range lies within ``Tw/K``
+    programs, and its mask and table row are ``Tw`` wide, not ``T``."""
     t_width = table.shape[1]
     tw = window_view_blocks(window, bs, t_width)
     lo = jnp.maximum(t - window + 1, 0)  # oldest key in the window

@@ -128,21 +128,28 @@ def lookup(kind: str, *, b: int, kvh: int, n_rep: int, d: int,
 
 
 def paged_vmem_bytes(var: Variant, *, bs: int, kvh: int, d: int,
-                     n_rep: int, payload_bytes: int, quant: bool) -> int:
-    """Per-program VMEM for one paged grid step under ``var`` —
-    generalizes ``attention.decode_kernel_fits`` to the tuned axes.
-    Tiles are lane-dense ``[K*BS, KVH*D]`` (``_fold_block``), so bytes
-    are what the arrays hold, with no (8, 128) padding blow-up to model:
-    K raw K/V blocks (+scales), DOUBLE-buffered by the pipeline; the
+                     n_rep: int, payload_bytes: int, quant: bool,
+                     t: int = 0) -> int:
+    """VMEM of one paged program (a row: ops/paged_attention.
+    _paged_kernel_v) under ``var`` — generalizes
+    ``attention.decode_kernel_fits`` to the tuned axes.  Tiles are
+    lane-dense ``[K*BS, KVH*D]`` (``_fold_block``), so bytes are what the
+    arrays hold, with no (8, 128) padding blow-up to model: TWO slots of
+    K raw K/V blocks (a trip's, and the next trip's in flight); the
     dequant/upcast f32 copies (``native_mxu`` skips them); q/out tiles
     and online-softmax scratch — ``[H, KVH*D]`` wide when
     ``head_batched`` (block-diagonal q, diagonal read-out), ``[H, D]``
-    otherwise; and the score/prob temporaries.  The model agrees with
-    the v5e's compiler on every enumerated variant at the default Llama
-    decode shapes (tests/test_chip_compile.py)."""
+    otherwise; and the score/prob temporaries.  What rides a row at a
+    time grows with the table's width ``t`` (0: not counted), double-
+    buffered by the pipeline with its minor dim padded to a lane tile:
+    the mask ``[T/K, K*BS]`` and, when quant, the row's gathered K and V
+    scales ``[T*BS, KVH]``.  The model agrees with the v5e's compiler on
+    every enumerated variant at the default Llama decode shapes and at
+    the benchmark cells' (tests/test_chip_compile.py)."""
     kb = var.blocks_per_step * bs
     payload = 2 * 2 * kb * kvh * d * payload_bytes
-    scales = 2 * 2 * kb * kvh * 4 if quant else 0
+    mask = 2 * (t // var.blocks_per_step) * max(kb, 128) * 4
+    scales = 2 * 2 * t * bs * max(kvh, 128) * 4 if quant else 0
     f32_copies = 0 if (var.native_mxu and not quant) else 2 * kb * kvh * d * 4
     h = kvh * n_rep
     cols = kvh * d if var.head_batched else d
@@ -150,19 +157,19 @@ def paged_vmem_bytes(var: Variant, *, bs: int, kvh: int, d: int,
     acc = 4 if var.acc_dtype == "f32" else 2
     scratch = (2 * h + h * cols) * acc
     scores = 2 * h * kb * 4  # s and p live together briefly
-    return payload + scales + f32_copies + q_out + scratch + scores
+    return payload + mask + scales + f32_copies + q_out + scratch + scores
 
 
 def variant_fits(var: Variant, *, bs: int, kvh: int, d: int, n_rep: int,
                  payload_bytes: int, quant: bool,
-                 budget: int | None = None) -> bool:
+                 budget: int | None = None, t: int = 0) -> bool:
     from .attention import decode_vmem_budget_bytes
 
     if budget is None:
         budget = decode_vmem_budget_bytes()
     return paged_vmem_bytes(
         var, bs=bs, kvh=kvh, d=d, n_rep=n_rep,
-        payload_bytes=payload_bytes, quant=quant,
+        payload_bytes=payload_bytes, quant=quant, t=t,
     ) <= budget
 
 
@@ -191,7 +198,7 @@ def enumerate_variants(kind: str, *, t: int, bs: int, kvh: int, d: int,
                     if variant_fits(
                         var, bs=bs, kvh=kvh, d=d, n_rep=n_rep,
                         payload_bytes=payload_bytes, quant=quant,
-                        budget=budget,
+                        budget=budget, t=t if kind == "paged_decode" else 0,
                     ):
                         out.append(var)
                     else:

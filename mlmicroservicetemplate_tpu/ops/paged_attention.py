@@ -25,16 +25,39 @@ This module is the device-side half:
   models' paged decode steps attend over this view with their EXISTING
   attention code, which is what makes paged decode token-identical to
   the contiguous layout by construction.
-- ``paged_decode_attention``: Pallas kernel — grid ``(B, T/K)`` with
-  the block table as a scalar-prefetch operand, so each program DMAs
-  exactly K of its row's blocks HBM->VMEM (the gather never
-  materializes in HBM) and folds them into an online-softmax
+- ``paged_decode_attention``: Pallas kernel — grid ``(B,)``, a row a
+  program, with the block table as a scalar-prefetch operand and the
+  pools left where they lie: the program copies exactly its row's LIVE
+  blocks HBM->VMEM itself, K at a time into two slots (the gather never
+  materializes in HBM), and folds each into an online-softmax
   accumulator, FlashAttention-style.  Composes with ``QUANT_KV=int8``:
-  payloads cross at int8 width with per-token-head f32 scales riding
-  in their own paged pool, dequantized in VMEM like
-  ``ops/attention.decode_attention``.  ``interpret=True`` runs the
-  same kernel on CPU (the test/fallback path, same pattern as
-  ``parallel/ring.py``).
+  payloads cross at int8 width and dequantize in VMEM like
+  ``ops/attention.decode_attention``; their per-token-head scales (a
+  ``1/D`` of the bytes) ride as the rows' gathered ``[B, T*BS, KVH]``.
+  ``interpret=True`` runs the same kernel on CPU (the test/fallback
+  path, same pattern as ``parallel/ring.py``).
+
+**The kernel's work follows each row's live keys** (PR 32), not the
+table's width.  A serving table is mostly not keys: the loop pads a
+row past its stream's blocks with the sentinel and points a freed
+slot's whole row at it, and a live row's blocks are allocated a chunk
+ahead of its keys.  ``live_programs`` reads, from the two operands the
+kernel gets anyway, each row's ``[first, last]`` range of K-block
+programs that hold a key which is valid in ``key_valid`` AND lies in a
+real block of the UNCLAMPED ``table`` (``0 <= id < NB``) — both,
+because nothing clears a freed slot's mask on the device, and a range,
+because a window view (``models/llama.window_view``) has dead keys at
+its head too; a hole inside the range is folded masked.  The range is
+the second scalar-prefetch operand and the trip count of the row's
+block loop: a table entry outside it costs no program, no copy and no
+fold, and a row with no live entry costs one empty grid step (~0.6 us
+on a v5e: its q, mask and output blocks still ride) and reads zeros.
+A fully masked fold after live ones leaves ``m``, ``l`` and ``acc``
+bit for bit as they were, so every live row's output is what a walk of
+the whole table computes, to the last bit
+(tests/test_pallas_autotune.py).  The walk itself — grid ``(B, T/K)``,
+a pipelined program every K table entries — ran ~0.5 us of grid-step
+bookkeeping a program, live or not (PERF.md section 6, PR 32).
 
 The kernel is parameterized by a :class:`Variant` (docs/
 kernel_tuning.md): the axes ``ops/autotune.py`` sweeps at warmup.
@@ -46,9 +69,10 @@ WHAT is accumulated, which is what keeps each one token-identical to
 (``accbf16`` scratch) is excluded from sweeps and reachable solely
 through an explicit ``PALLAS_VARIANT`` pin.
 
-Sentinel table entries (freed slots) must be clamped to a real block
-id by the caller — out-of-range ids would index past the pool — and
-masked via ``key_valid``; ``gather_pages`` clamps internally.
+Sentinel table entries (freed slots, a row's tail) come to the kernel
+as they are — out of range is how it tells them from blocks; it clamps
+them itself before any lookup, and no program reads one.  ``key_valid``
+masks within the live range; ``gather_pages`` clamps internally.
 """
 
 from __future__ import annotations
@@ -65,11 +89,12 @@ import jax.numpy as jnp
 class Variant:
     """One point in the paged/slab decode-kernel tuning space.
 
-    - ``blocks_per_step``: K sequential pool blocks folded per grid
-      step — the online-softmax fold then runs over ``K*BS`` keys at
-      once (fewer, larger MXU issues; K must divide the table width so
-      no pad-block path exists).  Paged kernel only; the whole-slab
-      kernel has no block axis.
+    - ``blocks_per_step``: K sequential pool blocks copied and folded
+      per trip of a row's block loop — the online-softmax fold then runs
+      over ``K*BS`` keys at once (fewer, larger MXU issues; a row's live
+      range is counted in K-block programs; K must divide the table
+      width so no pad-block path exists).  Paged kernel only; the
+      whole-slab kernel has no block axis.
     - ``head_batched``: replace the static ``for g in range(kvh)``
       Python loop with ONE kvh-batched ``dot_general`` so every head's
       ``n_rep x D`` tile is in flight together (packs full 128-lane
@@ -360,53 +385,117 @@ def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
         fold(g, up(q_ref[0, g]), *tiles(g))
 
 
+def live_programs(table: jax.Array, key_valid: jax.Array, nb_pool: int,
+                  block_size: int, k: int) -> jax.Array:
+    """``[B, 2]`` int32: per row the first and last grid program (``k``
+    table entries each) that holds a key which is valid AND lies in a
+    real block (``0 <= table < NB``: the freed-slot sentinel is out of
+    range on purpose).  Both operands are needed — a freed slot's
+    ``key_valid`` is stale (nothing clears it on the device), and a live
+    row's table is allocated a chunk ahead of its keys.  A range, not a
+    length: a window view (``models/llama.window_view``) has dead keys
+    at its head too.  A row with no such key reads ``(1, 0)``: a range
+    of ``last - first + 1 = 0`` programs."""
+    b, t = table.shape
+    real = (table >= 0) & (table < nb_pool)
+    held = (key_valid != 0).reshape(b, t // k, k, block_size)
+    live = (held & real.reshape(b, t // k, k, 1)).any(axis=(2, 3))
+    j = jnp.arange(t // k, dtype=jnp.int32)
+    first = jnp.min(jnp.where(live, j, t // k), axis=1)
+    last = jnp.max(jnp.where(live, j, -1), axis=1)
+    some = last >= 0
+    return jnp.stack(
+        [jnp.where(some, first, 1), jnp.where(some, last, 0)], axis=1
+    ).astype(jnp.int32)
+
+
 def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
-                    quant: bool, var: Variant):
-    """Grid step (b, j): fold blocks ``table[b, j*K .. j*K+K-1]`` into
-    row b's accumulators; finalize on the last step.  Ref layout:
-    tbl (prefetch), q ([1, KVH, R, D], or block-diagonal [1, H, KVH*D]
-    when head-batched), then K k-blocks [1, BS, KVH*D] (+K [1, BS, KVH]
-    k-scales when quant), K v-blocks (+K v-scales), valid
-    [1, 1, 1, K*BS], output (shaped like q), then m/l/acc scratch."""
+                    quant: bool, var: Variant, bs: int):
+    """Grid step b: fold row b's live programs (``live_programs``) into
+    its accumulators, K blocks ``table[b, j*K .. j*K+K-1]`` a trip of a
+    ``fori_loop`` whose trip count is the row's own, then finalize.  The
+    K+V blocks come by ``make_async_copy`` from the pools where they lie
+    into two VMEM slots: a trip starts the next trip's copies — on the
+    row's last trip the NEXT row's first — before it waits for its own,
+    so a copy always flies under a fold; the slot parity runs on across
+    rows (a row's first trip is the batch's ``base``-th).  A row with no
+    live program starts no copy, folds nothing and writes zeros (acc = 0,
+    l = 0).  Ref layout: tbl and the rows' ``(first, last, base)``
+    (prefetch), q ([1, KVH, R, D], or block-diagonal
+    [1, H, KVH*D] when head-batched), the k pool, the v pool (any memory
+    space), when quant the row's k and v scales [1, T*BS, KVH], the row's
+    mask [1, T/K, K*BS], the output (shaped like q), then m/l/acc
+    scratch, the k and v slots [2, K*BS, KVH*D] and the DMA semaphores
+    [2]."""
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
     K = var.blocks_per_step
     it = iter(refs)
-    next(it)  # tbl_ref: consumed by the index maps, not the body
-    q_ref = next(it)
-    k_refs = [next(it) for _ in range(K)]
-    ks_refs = [next(it) for _ in range(K)] if quant else [None] * K
-    v_refs = [next(it) for _ in range(K)]
-    vs_refs = [next(it) for _ in range(K)] if quant else [None] * K
-    valid_ref = next(it)
-    o_ref = next(it)
+    tbl_ref, live_ref, q_ref, k_hbm, v_hbm = (next(it) for _ in range(5))
+    ks_ref, vs_ref = (next(it), next(it)) if quant else (None, None)
+    valid_ref, o_ref = next(it), next(it)
     m_scr, l_scr, a_scr = next(it), next(it), next(it)
+    kbuf, vbuf, sem = next(it), next(it), next(it)
 
-    j = pl.program_id(1)
-    nsteps = pl.num_programs(1)
+    i, rows = pl.program_id(0), pl.num_programs(0)
+    first, base = live_ref[i, 0], live_ref[i, 2]
+    n = live_ref[i, 1] - first + 1  # 0 for a row with no live program
 
-    @pl.when(j == 0)
-    def _init():
-        m_scr[...] = jnp.full_like(m_scr, -1e30)
-        l_scr[...] = jnp.zeros_like(l_scr)
-        a_scr[...] = jnp.zeros_like(a_scr)
+    def copies(row, j, slot):
+        return [
+            pltpu.make_async_copy(
+                pool.at[tbl_ref[row, j * K + m]],
+                buf.at[slot, pl.ds(m * bs, bs)], sem.at[slot],
+            )
+            for m in range(K) for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf))
+        ]
 
-    def cat(blk_refs):
-        blks = [r[0] for r in blk_refs]
-        return blks[0] if K == 1 else jnp.concatenate(blks, axis=0)
+    def is_live(row):
+        return live_ref[row, 1] >= live_ref[row, 0]
 
-    k_blk, v_blk = cat(k_refs), cat(v_refs)
-    ks_blk = cat(ks_refs).astype(jnp.float32) if quant else None
-    vs_blk = cat(vs_refs).astype(jnp.float32) if quant else None
-    valid = valid_ref[0, 0]  # [1, K*BS]
-    _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
-                a_scr, scale=scale, kvh=kvh, n_rep=n_rep, d=d, var=var)
+    nxt = jnp.minimum(i + 1, rows - 1)
 
-    @pl.when(j == nsteps - 1)
-    def _finalize():
-        acc = a_scr[...].astype(jnp.float32)
-        l = l_scr[...].astype(jnp.float32)
-        o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+    # The row before started this row's first copies, if it had a trip.
+    @pl.when((n > 0) & ((i == 0) | jnp.logical_not(is_live(jnp.maximum(i - 1, 0)))))
+    def _own_first():
+        for c in copies(i, first, base % 2):
+            c.start()
+
+    m_scr[...] = jnp.full_like(m_scr, -1e30)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    a_scr[...] = jnp.zeros_like(a_scr)
+
+    def trip(s, carry):
+        j, slot = first + s, (base + s) % 2
+
+        @pl.when(s + 1 < n)
+        def _next_trip():
+            for c in copies(i, j + 1, 1 - slot):
+                c.start()
+
+        @pl.when((s + 1 == n) & (i + 1 < rows) & is_live(nxt))
+        def _next_row():
+            for c in copies(nxt, live_ref[nxt, 0], 1 - slot):
+                c.start()
+
+        for c in copies(i, j, slot):
+            c.wait()
+        ks_blk = vs_blk = None
+        if quant:
+            keys = pl.ds(pl.multiple_of(j * (K * bs), K * bs), K * bs)
+            ks_blk = ks_ref[0, keys, :].astype(jnp.float32)
+            vs_blk = vs_ref[0, keys, :].astype(jnp.float32)
+        valid = valid_ref[0, pl.ds(j, 1), :]  # [1, K*BS]
+        _fold_block(q_ref, kbuf[slot], ks_blk, vbuf[slot], vs_blk, valid,
+                    m_scr, l_scr, a_scr, scale=scale, kvh=kvh, n_rep=n_rep,
+                    d=d, var=var)
+        return carry
+
+    jax.lax.fori_loop(0, n, trip, 0)
+    acc = a_scr[...].astype(jnp.float32)
+    l = l_scr[...].astype(jnp.float32)
+    o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
 
 def tp_shard_attention(
@@ -464,7 +553,7 @@ def paged_decode_attention(
     q: jax.Array,  # [B, H, D] — one query per row
     k_pool: jax.Array,  # [NB, BS, KVH*D] dense, or int8 payload
     v_pool: jax.Array,
-    table: jax.Array,  # [B, T] block ids (caller clamps sentinels)
+    table: jax.Array,  # [B, T] block ids, unclamped: out of range = no block
     key_valid: jax.Array,  # [B, T*BS] 1 = attend
     block_size: int,
     k_scale: jax.Array | None = None,  # [NB, BS, KVH] -> int8 path
@@ -476,16 +565,17 @@ def paged_decode_attention(
 ) -> jax.Array:
     """Fused paged decode attention; returns ``[B, H, D]``.
 
-    Grid (B, T/K): program (b, j) DMAs blocks ``table[b, j*K..]`` of
-    the pool into VMEM via the scalar-prefetched table — HBM traffic
-    is exactly the row's live blocks, never a materialized dense
-    gather — and accumulates FlashAttention-style (the block axis is
-    sequential on TPU, so the VMEM scratch carries m/l/acc across it).
+    Grid (B,): program b copies the live blocks of ``table[b]`` out of
+    the pool into VMEM, K (``variant``'s ``blocks_per_step``) a trip,
+    and accumulates FlashAttention-style — HBM traffic is exactly the
+    row's live blocks (``live_programs``; the module docstring), never a
+    materialized dense gather, never an entry past the row's last key.
+    ``table`` comes UNCLAMPED (the sentinel marks what is no block).
     ``variant`` selects a tuning point (see :class:`Variant`); K must
     divide the table width T (``ops/autotune.py`` only enumerates
     divisors, so serving never needs a pad-block path).  VMEM per
-    program is K lane-dense [BS, KVH*D] K+V block pairs (double-
-    buffered) + f32 accumulators — ``autotune.paged_vmem_bytes`` is
+    program is two slots of K lane-dense [BS, KVH*D] K+V block pairs +
+    f32 accumulators + the row's mask — ``autotune.paged_vmem_bytes`` is
     the budget model.
     """
     from jax.experimental import pallas as pl
@@ -523,64 +613,56 @@ def paged_decode_attention(
         scale = 1.0 / math.sqrt(d)
     quant = k_scale is not None
     acc_jnp = jnp.float32 if var.acc_dtype == "f32" else jnp.bfloat16
+    live = live_programs(table, key_valid, nb_pool, bs, K)
+    trips = live[:, 1] - live[:, 0] + 1
+    live = jnp.concatenate(  # + the trips of the rows before: slot parity
+        [live, (jnp.cumsum(trips) - trips)[:, None]], axis=1
+    )
     tbl = jnp.clip(table, 0, nb_pool - 1).astype(jnp.int32)
-    # Mosaic wants a block's last two dims (8, 128)-divisible or whole:
-    # the mask rides as [B, T/K, 1, K*BS] with whole (1, K*BS) blocks
-    # (as fused_attention carries its mask); the pools go in as they
-    # lie, [NB, BS, KVH*D] with whole (BS, KVH*D) blocks.
-    validb = key_valid.astype(jnp.int32).reshape(b, tsteps, 1, K * bs)
+    # The row's mask rides whole, [1, T/K, K*BS]: trip j reads sublane j.
+    validb = key_valid.astype(jnp.int32).reshape(b, tsteps, K * bs)
     if var.head_batched:
         qk = head_batched_q(q, kvh)
-        q_spec = pl.BlockSpec((1, h, gd), lambda i, j, tb: (i, 0, 0))
     else:
         qk = q.reshape(b, kvh, n_rep, d)
-        q_spec = pl.BlockSpec(
-            (1, kvh, n_rep, d), lambda i, j, tb: (i, 0, 0, 0)
-        )
 
-    def pool_specs(width):
-        return [
-            pl.BlockSpec(
-                (1, bs, width),
-                functools.partial(
-                    lambda i, j, tb, _m: (tb[i, j * K + _m], 0, 0), _m=m
-                ),
-            )
-            for m in range(K)
-        ]
+    def row_spec(shape):  # one row's whole [1, ...] slice of a [B, ...] array
+        zeros = (0,) * (len(shape) - 1)
+        return pl.BlockSpec((1, *shape[1:]), lambda i, tb, lv: (i, *zeros))
 
-    kv_specs, sc_specs = pool_specs(gd), pool_specs(kvh)
-    valid_spec = pl.BlockSpec(
-        (1, 1, 1, K * bs), lambda i, j, tb: (i, j, 0, 0)
-    )
-    scratch = softmax_scratch(qk.shape[1:], acc_jnp)
+    q_spec = row_spec(qk.shape)
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)  # read where it lies
     kernel = functools.partial(
         _paged_kernel_v, scale=scale, kvh=kvh, n_rep=n_rep, d=d,
-        quant=quant, var=var,
+        quant=quant, var=var, bs=bs,
     )
-    if not quant:
-        in_specs = [q_spec, *kv_specs, *kv_specs, valid_spec]
-        args = (tbl, qk, *([k_pool] * K), *([v_pool] * K), validb)
-    else:
-        in_specs = [q_spec, *kv_specs, *sc_specs, *kv_specs, *sc_specs,
-                    valid_spec]
-        args = (
-            tbl, qk, *([k_pool] * K), *([k_scale] * K), *([v_pool] * K),
-            *([v_scale] * K), validb,
-        )
+    args = [tbl, live, qk, k_pool, v_pool]
+    in_specs = [q_spec, pool_spec, pool_spec]
+    if quant:
+        # Mosaic copies no [BS, KVH] slab out of a pool whose minor dim
+        # is under a lane tile: the rows' scales (1/D of the payload's
+        # bytes) are gathered here and ride a row at a time.
+        scales = [gather_pages(sc, tbl, bs) for sc in (k_scale, v_scale)]
+        args += scales
+        in_specs += [row_spec(sc.shape) for sc in scales]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(b, tsteps),
-        in_specs=in_specs,
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[*in_specs, row_spec(validb.shape)],
         out_specs=q_spec,
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            *softmax_scratch(qk.shape[1:], acc_jnp),
+            pltpu.VMEM((2, K * bs, gd), k_pool.dtype),
+            pltpu.VMEM((2, K * bs, gd), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct(qk.shape, q.dtype),
         interpret=interpret,
-    )(*args)
+    )(*args, validb)
     if var.head_batched:
         return head_batched_out(out, kvh)
     return out.reshape(b, h, d)

@@ -177,6 +177,22 @@ KV_WINDOW_KEYS_BEHIND = Counter(
     "window layer): what the table view saves over walking the table",
     ["model"],
 )
+KV_TABLE_BLOCKS_LIVE = Counter(
+    "kv_table_blocks_live_total",
+    "Paged decode: block-table entries that held a key an attention "
+    "layer attended to in dispatched paged decode chunks (a stream a "
+    "step a layer; through the table view for a window layer; from the "
+    "host's stream lengths): what the paged kernel has to read",
+    ["model"],
+)
+KV_TABLE_BLOCKS_DEAD = Counter(
+    "kv_table_blocks_dead_total",
+    "Paged decode: the other entries of the slots' tables (or views) in "
+    "the same chunks — past a stream's last key, before a window, or a "
+    "slot with no stream: dead / (live + dead) is the share of a walk "
+    "of the table's width that the kernel's live bounds never run",
+    ["model"],
+)
 DECODE_STEPS = Histogram(
     "seq2seq_decode_steps",
     "Decode steps executed per non-streaming seq2seq dispatch "

@@ -107,3 +107,52 @@ def test_latency_buckets_env_overrides_defaults():
     # ServiceConfig's validator is the strict boot-time gate.
     assert m.parse_buckets("garbage") is None
     assert m.parse_buckets("2,1") is None
+
+
+# ---------------------------------------------------------------------------
+# table_blocks_dead_pct.* (PR 32): the entries resolve in their cells, and
+# the reader finds the program's counters under the names it exports
+
+
+@pytest.mark.parametrize("metric,moves,cells", [
+    ("table_blocks_dead_pct.decode", "tbt_p95_ms",
+     ["mistral-7b-d8.decode-closed", "olmoe-1b-7b-d8.decode-closed"]),
+    ("table_blocks_dead_pct.chat", "tbt_p99_ms",
+     ["mistral-7b-d8.chat-open", "trinity-mini-d5.longdoc-closed"]),
+])
+def test_table_blocks_dead_pct_resolves_in_its_cells(metric, moves, cells):
+    from cellbench import spec
+
+    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == metric]
+    assert entry["workloads"] == cells and entry["moves"] == moves
+    assert entry["layer"] == "kernels" and entry["source"] == "program_counter"
+    for cell in cells:
+        resolved = spec.resolve(cell)
+        assert metric in [m.name for m in resolved.per_layer]
+        assert moves in [m.name for m in resolved.end_to_end]
+
+
+def test_table_blocks_dead_pct_reads_the_programs_counters():
+    """The families as ``/metrics`` exports them, through the reader the
+    entries name; a program without them (the parent) reads no value."""
+    import types
+
+    from cellbench.readers import prom_counter_ratio
+    from mlmicroservicetemplate_tpu.utils import metrics  # registers the families
+    from prometheus_client import generate_latest
+
+    metrics.KV_TABLE_BLOCKS_LIVE.labels("reader-unit").inc(25)
+    metrics.KV_TABLE_BLOCKS_DEAD.labels("reader-unit").inc(75)
+    after = parse_prom(generate_latest().decode())
+    base = {f: dict(after[f], value=after[f]["value"] - v) for f, v in
+            (("kv_table_blocks_live", 25.0), ("kv_table_blocks_dead", 75.0))}
+
+    def ctx(after, before):
+        return types.SimpleNamespace(
+            notes={}, prom_delta=lambda fam: (
+                None if fam not in after
+                else hist_delta(after[fam], before.get(fam))))
+
+    args = ("kv_table_blocks_dead", ["kv_table_blocks_live"])
+    assert prom_counter_ratio.read(ctx(after, base), *args) == 75.0
+    assert prom_counter_ratio.read(ctx({}, {}), *args) is None

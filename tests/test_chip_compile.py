@@ -270,6 +270,46 @@ def test_paged_decode_kernel_compiles_at_16_kv_heads(chip, variant):
     assert "tpu_custom_call" in text
 
 
+#: The paged kernel's problem in each benchmark cell: slots, KV heads,
+#: n_rep, table width, pool blocks (head 128, blocks of 16, bf16, the
+#: cells' DECODE_KERNEL_VMEM_BUDGET_MB=12).
+_KERNEL_CELLS = {
+    "mistral": (64, 8, 4, 44, 3051),
+    "olmoe": (64, 16, 1, 28, 1810),
+    "trinity-full": (32, 4, 8, 392, 12573),
+    "trinity-view": (32, 4, 8, 136, 12573),
+}
+
+
+@pytest.mark.parametrize("nat", [False, True], ids=["", "nat"])
+@pytest.mark.parametrize("hb", [False, True], ids=["", "hb"])
+@pytest.mark.parametrize("cell,fold", [
+    (c, k) for c, shape in _KERNEL_CELLS.items()
+    for k in autotune.BLOCK_FOLDS if shape[3] % k == 0
+])
+def test_the_cells_kernels_compile_at_every_enumerated_variant(
+        chip, cell, fold, hb, nat):
+    """The live-bounded kernel (a row a program, the block loop and its
+    copies inside) at each cell's shapes, for every variant the tuner
+    enumerates there: the chip's compiler takes what
+    ``autotune.paged_vmem_bytes`` admits under the cells' budget."""
+    b, kvh, n_rep, t, nb = _KERNEL_CELLS[cell]
+    var = autotune.Variant(fold, hb, nat)
+    assert var in autotune.enumerate_variants(
+        "paged_decode", t=t, bs=BS, kvh=kvh, d=128, n_rep=n_rep,
+        dtype="bfloat16", quant=False, budget=12 << 20,
+    )
+    pool = chip((nb, BS, kvh * 128), jnp.bfloat16)
+    text = _compiled_text(
+        chip, ("cell-kernel", cell, var.key()),
+        lambda q, k, v, tb, m: paged_decode_attention(
+            q, k, v, tb, m, BS, variant=var.key()),
+        chip((b, kvh * n_rep, 128), jnp.bfloat16), pool, pool,
+        chip((b, t), jnp.int32), chip((b, t * BS), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("kvh,h,nb,t,quant", [
     (8, 32, 3051, 44, False),  # mistral-7b-d8: KV_BUDGET_MB 1600, 512 + 192
     (16, 16, 1810, 28, False),  # olmoe-1b-7b-d8: 1900, 256 + 192
@@ -437,15 +477,15 @@ def test_donated_state_is_not_copied_at_entry(chip, cell, what):
 
 def test_window_layers_run_the_kernel_at_their_views_width(chip):
     """Trinity's chunk at the cell's shapes holds the paged kernel at BOTH
-    table widths: the full layer walks T = 392 entries a row (98 programs
-    at K = 4), the four window layers their view's 136 (34 programs) —
-    the mask rides as [B, T/K, 1, K*BS].  The view is a gather of table
+    table widths: the full layer's rows are T = 392 entries (98 trips at
+    most at K = 4), the four window layers' their view's 136 (34) —
+    the row's mask rides as [B, T/K, K*BS].  The view is a gather of table
     ENTRIES: no pool moves (the parametrised case above)."""
     import re
 
     text, _ = _serving_program(chip, "trinity", "chunk")
     calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln and " = " in ln]
-    masks = [re.findall(r"s32\[32,(\d+),1,64\]", ln) for ln in calls]
+    masks = [re.findall(r"s32\[32,(\d+),64\]", ln) for ln in calls]
     widths = sorted(int(m[0]) for m in masks if m)
     assert widths == [34, 34, 34, 34, 98], widths
 

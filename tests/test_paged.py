@@ -112,9 +112,11 @@ def test_gather_pages_clamps_sentinel():
 
 def test_kernel_reads_the_pool_as_it_lies():
     """The CPU twin of test_chip_compile's scan case: in the traced
-    wrapper the ``pallas_call``'s pool operands are the function's own
-    inputs — nothing (reshape, transpose, copy, convert) stands between
-    the pool a decode state carries and the kernel."""
+    wrapper the ``pallas_call``'s payload-pool operands are the
+    function's own inputs, once each — nothing (reshape, transpose,
+    copy, convert) stands between the pool a decode state carries and
+    the kernel, which copies a row's live blocks out of it itself.  (An
+    int8 pair's scales ride as the rows' gathered [B, T*BS, KVH].)"""
     b, h, kvh, d, bs, t, nb = 2, 4, 2, 16, 8, 4, 9
     args = (
         jnp.zeros((b, h, d)), jnp.zeros((nb, bs, kvh * d), jnp.int8),
@@ -122,15 +124,15 @@ def test_kernel_reads_the_pool_as_it_lies():
         jnp.ones((b, t * bs), jnp.int32),
         jnp.ones((nb, bs, kvh)), jnp.ones((nb, bs, kvh)),
     )
-    for variant, k_blocks in (("", 1), ("b2-hb", 2)):
+    for variant in ("", "b2-hb"):
         jaxpr = jax.make_jaxpr(  # the wrapper's own body, under its jit
             lambda *a: paged_decode_attention.__wrapped__(
                 *a[:5], bs, *a[5:], interpret=True, variant=variant)
         )(*args).jaxpr
         (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
-        pools = [jaxpr.invars[i] for i in (1, 2, 5, 6)]
-        fed = [v for v in call.invars if v in pools]
-        assert len(fed) == 4 * k_blocks, variant  # each pool, K block views
+        payloads = [jaxpr.invars[i] for i in (1, 2)]
+        assert [v for v in call.invars if v in payloads] == payloads, variant
+        assert [v.aval.shape for v in call.invars].count((b, t * bs, kvh)) == 2
 
 
 # ---------------------------------------------------------------------------
@@ -310,6 +312,71 @@ def test_paged_loop_llama_identity():
         assert _run(cdl, feats) == solos
     finally:
         cdl.stop()
+
+
+def _table_block_counts(name="llama"):
+    from mlmicroservicetemplate_tpu.utils import metrics
+
+    return (metrics.KV_TABLE_BLOCKS_LIVE.labels(name)._value.get(),
+            metrics.KV_TABLE_BLOCKS_DEAD.labels(name)._value.get())
+
+
+def test_table_block_counters_cover_every_slots_table():
+    """kv_table_blocks_live_total + kv_table_blocks_dead_total: every
+    dispatched chunk adds n_slots x T x steps x layers between them, and
+    two streams of 6 and 14 prompt tokens in 4 slots of 6-entry tables
+    leave most of that dead."""
+    bundle = tiny_llama_bundle()
+    cfgp = _cfg(paged_kv=True, kv_block_size=8)
+    engp = InferenceEngine(bundle, cfgp, ReplicaSet(make_mesh(1)))
+    rng = np.random.default_rng(3)
+    feats = [
+        {"input_ids": p, "length": np.int32(len(p))}
+        for p in (rng.integers(5, 250, n).astype(np.int32) for n in (6, 14))
+    ]
+    cdl = ContinuousDecodeLoop(engp, cfgp)
+    live0, dead0 = _table_block_counts()
+    try:
+        _run(cdl, feats)
+        chunks = cdl.chunk_dispatches
+    finally:
+        cdl.stop()
+    live, dead = (a - b for a, b in zip(_table_block_counts(), (live0, dead0)))
+    steps = chunks * cfgp.stream_chunk_tokens
+    assert chunks >= 2 and cdl.nb_max == 6  # (32 + 12) / 8
+    assert live + dead == cdl.n_slots * cdl.nb_max * steps * bundle.cfg.num_layers
+    # A step of a stream reads 1-4 of its 6 entries; two slots hold no stream.
+    assert 0 < live <= 2 * 4 * steps * bundle.cfg.num_layers
+    assert dead > live
+
+
+@pytest.mark.parametrize("pattern,nb_max,want", [
+    (None, 8, (75, 309)),
+    ((2, 8), 16, (63, 449)),  # two window-8 layers through an 8-entry view + one full
+], ids=["full", "window"])
+def test_table_block_counters_count_through_the_view(pattern, nb_max, want):
+    """One chunk of 4 steps, blocks of 4, three layers, 4 slots, streams
+    of 5 and 14 prompt tokens (the first prefilled by a wave in a
+    16-wide bucket: its table grows off 16, its keys off 5): step k
+    attends over prompt + k keys (step 0 rewrites the last prompt
+    token's), (n-1)//4 + 1 live entries in a full layer — 2, 2, 2, 2 and
+    4, 4, 4, 5 — and in a window-8 layer those from the block of key
+    n - 8 on — 2, 2, 2, 2 and 3, 3, 2, 3 — of a view 8 entries wide."""
+    import types
+
+    def stream(s_base, length):
+        return types.SimpleNamespace(s_base=s_base, s_lo=0, feats={"length": length})
+
+    loop = types.SimpleNamespace(
+        block_size=4, nb_max=nb_max, n_slots=4, _window_layers=pattern,
+        _attn_layers=3, active={0: stream(16, 5), 2: stream(14, 14)},
+        _dispatched_steps={0: 4, 2: 4},
+        engine=types.SimpleNamespace(bundle=types.SimpleNamespace(name="blocks-unit")),
+    )
+    before = _table_block_counts("blocks-unit")
+    ContinuousDecodeLoop._note_table_blocks(loop, 4)
+    got = tuple(a - b for a, b in zip(_table_block_counts("blocks-unit"), before))
+    assert got == want
 
 
 def test_paged_prefix_hit_shares_blocks_cow():

@@ -127,13 +127,111 @@ def test_default_variant_is_bit_identical_to_empty_key():
 def test_all_invalid_row_stays_finite(vkey):
     """A stream whose whole table is the -1 sentinel (admitted but not
     yet prefilled) must produce finite output — the no-pad-block design
-    exists exactly so folded variants cannot read a phantom block."""
+    exists exactly so folded variants cannot read a phantom block.  No
+    program of such a row is live: it ends at acc = 0, l = 0 and writes
+    zeros (the reference's mean of a clamped block is as arbitrary; both
+    are discarded); the row beside it is untouched."""
     args, ks, vs, ref = _paged_problem(all_invalid=True)
-    got = paged_decode_attention(*args, 4, interpret=True, variant=vkey)
-    assert np.isfinite(np.asarray(got)).all()
-    np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), rtol=2e-6, atol=2e-5
+    got = np.asarray(
+        paged_decode_attention(*args, 4, interpret=True, variant=vkey)
     )
+    assert np.isfinite(got).all()
+    assert (got[0] == 0).all()
+    np.testing.assert_allclose(got[1:], np.asarray(ref)[1:], rtol=2e-6, atol=2e-5)
+
+
+# Ragged rows: what a serving table looks like.  Keys a row (BS = 4, the
+# table 16 entries wide, allocated one block past the keys, the rest the
+# sentinel NB): one block; a part-filled tail block; the whole table; a
+# freed slot (all sentinel, the last tenant's mask still set); a window
+# view (dead keys at its head and its tail); no valid key at all.
+_RAGGED = {"one_block": (0, 3), "part_tail": (0, 14), "full": (0, 64),
+           "freed": None, "window": (9, 22), "no_key": (0, 0)}
+_RAGGED_T, _RAGGED_BS = 16, 4
+
+
+def _ragged_problem(quant: bool, t: int = _RAGGED_T, rows=_RAGGED, seed=5):
+    """``(args, ks, vs, live)``: the rows of ``rows`` over a pool of
+    ``64`` blocks, in a table ``t`` entries wide; ``live`` marks the rows
+    that hold a key.  The same rows get the same blocks at every ``t``."""
+    bs, nb = _RAGGED_BS, 64
+    base, ks, vs, _ = _paged_problem(
+        b=len(rows), t=nb - 2, bs=bs, quant=quant, seed=seed
+    )
+    q, kp, vp = base[0], base[1], base[2]
+    rng = np.random.default_rng(seed)
+    table = np.full((len(rows), t), nb, np.int32)
+    valid = np.zeros((len(rows), t * bs), np.int32)
+    for r, span in enumerate(rows.values()):
+        if span is None:
+            valid[r, : 5 * bs] = 1  # stale: nothing clears a freed slot's mask
+            continue
+        lo, hi = span
+        nblk = min(hi // bs + 1, t)
+        table[r, :nblk] = rng.permutation(nb)[:nblk]
+        valid[r, lo:hi] = 1
+    live = np.asarray([s is not None and s[1] > s[0] for s in rows.values()])
+    return (q, kp, vp, jnp.asarray(table), jnp.asarray(valid)), ks, vs, live
+
+
+def _variant_cases():
+    return [
+        pytest.param(quant, vkey, id=f"{'int8' if quant else 'dense'}-{vkey}")
+        for quant in (False, True)
+        for vkey in _enumerable_keys(_RAGGED_T, quant)
+    ]
+
+
+@pytest.mark.parametrize("quant,vkey", _variant_cases())
+def test_ragged_rows_match_reference(quant, vkey):
+    """Every enumerated variant on ragged rows: a live row reads the
+    reference's answer whatever lies past (or before) its keys, a row
+    with no live program reads zeros."""
+    args, ks, vs, live = _ragged_problem(quant)
+    ref = np.asarray(paged_attention_ref(*args, _RAGGED_BS, k_scale=ks, v_scale=vs))
+    got = np.asarray(paged_decode_attention(
+        *args, _RAGGED_BS, k_scale=ks, v_scale=vs, interpret=True, variant=vkey
+    ))
+    np.testing.assert_allclose(got[live], ref[live], rtol=2e-6, atol=2e-5)
+    assert (got[~live] == 0).all()
+
+
+@pytest.mark.parametrize("quant,vkey", _variant_cases())
+def test_live_rows_do_not_see_the_tables_width(quant, vkey):
+    """The property the live bounds rest on: a row's output is bit for
+    bit the same in a table padded with sentinels as in one exactly as
+    wide as the longest row — entries past a stream's last key add no
+    program that changes m, l or acc."""
+    rows = {k: v for k, v in _RAGGED.items() if k != "full"}  # longest: 22 keys
+    wide = _ragged_problem(quant, t=_RAGGED_T, rows=rows)
+    snug = _ragged_problem(quant, t=8, rows=rows)
+    assert np.array_equal(np.asarray(wide[0][3])[:, :8], np.asarray(snug[0][3]))
+
+    def run(problem):
+        args, ks, vs, _ = problem
+        return np.asarray(paged_decode_attention(
+            *args, _RAGGED_BS, k_scale=ks, v_scale=vs, interpret=True,
+            variant=vkey,
+        ))
+
+    np.testing.assert_array_equal(run(wide), run(snug))
+
+
+def test_live_programs_need_a_valid_key_in_a_real_block():
+    """The range is read from BOTH operands: the mask alone makes a freed
+    slot look as long as its last tenant, the table alone a live row a
+    block longer than it is."""
+    from mlmicroservicetemplate_tpu.ops.paged_attention import live_programs
+
+    args, _, _, _ = _ragged_problem(False)
+    table, valid = args[3], args[4]
+    for k, want in [
+        (1, [(0, 0), (0, 3), (0, 15), (1, 0), (2, 5), (1, 0)]),
+        (4, [(0, 0), (0, 0), (0, 3), (1, 0), (0, 1), (1, 0)]),
+        (16, [(0, 0), (0, 0), (0, 0), (1, 0), (0, 0), (1, 0)]),
+    ]:
+        got = np.asarray(live_programs(table, valid, 64, _RAGGED_BS, k))
+        assert [tuple(r) for r in got.tolist()] == want, k
 
 
 @pytest.mark.parametrize("kvh,n_rep", [(1, 4), (2, 1), (2, 4)])
