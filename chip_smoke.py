@@ -78,6 +78,22 @@ TINY_LLAMA = {
 }
 
 
+# A latent-cache Llama (attention='mla'; models/llama.py): DeepSeek-V2's
+# head sizes and ranks on a narrow, shallow, dense-FFN stack — the pool is
+# [NB, BS, 640] a layer, one leaf, no V pool.  Tiny under ``--rehearse``.
+LATENT_LLAMA = {
+    "vocab_size": 32000, "d_model": 1024, "num_heads": 16, "num_kv_heads": 16,
+    "num_layers": 2, "d_ff": 2816, "max_position": 4096, "attention": "mla",
+    "q_lora_rank": 384, "kv_lora_rank": 512, "qk_nope_head_dim": 128,
+    "qk_rope_head_dim": 64, "v_head_dim": 128,
+}
+TINY_LATENT_LLAMA = {
+    **TINY_LLAMA, "num_kv_heads": 4, "attention": "mla", "q_lora_rank": 24,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+    "v_head_dim": 8,
+}
+
+
 class PhaseFailed(RuntimeError):
     pass
 
@@ -333,6 +349,40 @@ def kernel_vs_reference(svc: "Service", variant: str) -> dict:
             "max_abs_err": err, "tolerance": KERNEL_ATOL}
 
 
+def latent_kernel_vs_reference(svc: "Service", variant: str) -> dict:
+    """The latent kernel against ``paged_attention_ref``'s latent form on
+    this device, at the decode loop's serving shapes, on a seeded pool."""
+    import jax.numpy as jnp
+    import numpy as np
+
+    from mlmicroservicetemplate_tpu.ops.paged_attention import (
+        latent_decode_attention,
+        paged_attention_ref,
+    )
+
+    cdl, cfg = svc.decode_loops()[0], svc.bundle.cfg
+    b, t, bs = cdl.n_slots, cdl.nb_max, cdl.block_size
+    dt = svc.bundle.policy.compute_jnp
+    rng = np.random.default_rng(0)
+    nb = b * t
+    q = jnp.asarray(rng.normal(size=(b, cfg.num_heads, cfg.latent_lanes)) * 0.3, dt)
+    pool = jnp.asarray(rng.normal(size=(nb, bs, cfg.latent_lanes)), dt)
+    table = jnp.asarray(rng.permutation(nb).reshape(b, t), jnp.int32)
+    valid = jnp.asarray(rng.random((b, t * bs)) < 0.9, jnp.int32)
+    got = latent_decode_attention(
+        q, pool, table, valid, bs, cfg.kv_lora_rank, cfg.attn_scale,
+        variant=variant, interpret=cfg.pallas_interpret)
+    ref = paged_attention_ref(q, pool, None, table, valid, bs,
+                              scale=cfg.attn_scale, v_dim=cfg.kv_lora_rank)
+    err = float(jnp.max(jnp.abs(got.astype(jnp.float32)
+                                - ref.astype(jnp.float32))))
+    check(np.isfinite(err) and err <= KERNEL_ATOL,
+          f"latent kernel {variant!r} vs jnp reference: max |d| {err}")
+    return {"variant": variant, "shape": {"b": b, "t": t, "bs": bs,
+                                          "lanes": cfg.latent_lanes},
+            "max_abs_err": err, "tolerance": KERNEL_ATOL}
+
+
 def pool_moves(cdl, bucket: int) -> tuple[list[str], list[str]]:
     """Pool-sized ``reshape``/``copy``/``transpose`` instructions of the
     compiled serving programs: (inside the paged chunk's step loop —
@@ -467,6 +517,45 @@ async def stream_phase(a) -> None:
           "gather_pages_xla_compile_s": ref["facts"]["xla_compile_s"]})
 
 
+async def latent_phase(a) -> None:
+    """The same streams through a latent-cache Llama: the absorbed decode
+    step through the latent kernel against the gathered path, the kernel
+    against its reference, and the layout and donation rules on the ONE
+    pool leaf a layer."""
+    prompts, base, env, bucket = llama_setup(a, N_STREAMS)
+    env = {**env, "LLAMA_CONFIG": json.dumps(
+        TINY_LATENT_LLAMA if a.rehearse else LATENT_LLAMA)}
+    kern = {**base, "PALLAS_AUTOTUNE": "1"}
+    async with Service(kern, {**env, "USE_PALLAS_DECODE": "1"}) as svc:
+        require_device(await svc.status(), a.device)
+        got = await run_streams(svc, prompts, "llama", bucket)
+        kernel = latent_kernel_vs_reference(svc, got["facts"]["kernel_variant"])
+        st = svc.decode_loops()[0]._state
+        check(st.cache_v == [] and all(
+            c.shape[2] == svc.bundle.cfg.latent_lanes for c in st.cache_k),
+            "the latent model's state holds more than one latent pool a layer")
+    facts = got["facts"]
+    if not a.rehearse:
+        check(all(n > 0 for n in facts["tpu_custom_calls_in_decode_step"]),
+              "the latent kernel is not in the compiled decode step")
+        check(not any(facts["pool_relayouts_in_decode_step"]),
+              "the compiled paged chunk moves a whole latent pool every "
+              f"step: {facts['pool_relayouts_in_decode_step']}")
+        check(not any(facts["pool_copies_at_entry"]),
+              "the compiled paged chunk or insert copies a latent pool it "
+              f"should alias: {facts['pool_copies_at_entry']}")
+    async with Service(base, {**env, "USE_PALLAS_DECODE": "0"}) as svc:
+        require_device(await svc.status(), a.device)
+        ref = await run_streams(svc, prompts, "llama", bucket)
+    check(not any(ref["facts"]["tpu_custom_calls_in_decode_step"]),
+          "the latent reference service is not on the gathered path")
+    emit({"phase": "latent_stream", "model": "llama (attention='mla')",
+          "widths": "tiny (rehearsal)" if a.rehearse else "latent 512 + 64, 16 heads",
+          "seq_bucket": bucket, "streams": N_STREAMS, **facts,
+          "kernel_vs_jnp_reference": kernel,
+          "vs_gathered_path": compare_streams(got["finals"], ref["finals"])})
+
+
 async def unary_phase(a) -> None:
     rng = random.Random(a.seed + 1)
     texts = [
@@ -597,6 +686,7 @@ def main() -> int:
                 asyncio.run(four_chip_phase(a))
             else:
                 asyncio.run(stream_phase(a))
+                asyncio.run(latent_phase(a))
                 asyncio.run(unary_phase(a))
     except PhaseFailed as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)

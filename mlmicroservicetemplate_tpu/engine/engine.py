@@ -723,7 +723,8 @@ class InferenceEngine:
         from .kv_blocks import kv_token_bytes
 
         layers, heads, head_dim, elt, quant = self._kv_dims()
-        return kv_token_bytes(layers, heads, head_dim, elt, quant)
+        latent = int(getattr(self.bundle.cfg, "latent_lanes", 0) or 0)
+        return kv_token_bytes(layers, heads, head_dim, elt, quant, latent)
 
     def kv_block_bytes(self) -> int:
         """Bytes one ``KV_BLOCK_SIZE``-token block costs (paged mode)."""

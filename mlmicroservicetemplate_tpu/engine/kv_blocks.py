@@ -511,12 +511,16 @@ class PagedPrefix:
 
 def kv_token_bytes(
     layers: int, kv_heads: int, head_dim: int, elt_bytes: int,
-    quant_int8: bool = False, scale_bytes: int = 4,
+    quant_int8: bool = False, latent_lanes: int = 0, scale_bytes: int = 4,
 ) -> int:
     """KV bytes per token position: K and V across all layers, at the
     cache element width (int8 payload + one scale per token-head under
-    QUANT_KV=int8).  Shared by the admission estimate and the paged
-    block ledger so the two accountings can never drift."""
+    QUANT_KV=int8) — or, for a latent cache (``latent_lanes``: multi-head
+    latent attention), ONE row of that many lanes a layer, no heads and
+    no V.  Shared by the admission estimate and the paged block ledger so
+    the two accountings can never drift."""
+    if latent_lanes:
+        return layers * latent_lanes * elt_bytes
     if quant_int8:
         per_head = head_dim * 1 + scale_bytes
     else:

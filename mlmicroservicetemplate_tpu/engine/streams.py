@@ -465,6 +465,14 @@ class ContinuousDecodeLoop:
             if "window" in types else None
         )
         self._attn_layers = int(getattr(bcfg, "num_layers", 0))
+        # Layers whose cache is a latent row a token (every layer, or none).
+        self._latent_layers = (
+            self._attn_layers if getattr(bcfg, "latent_lanes", 0) else 0)
+        # (first, held) of the experts this tree holds: a chip's share.
+        self._experts_held = (
+            int(getattr(bcfg, "expert_first", 0) or 0),
+            int(getattr(bcfg, "held", 0) or 0),
+        )
         if self.paged:
             from .kv_blocks import blocks_for
 
@@ -4701,24 +4709,40 @@ class ContinuousDecodeLoop:
         name = self.engine.bundle.name
         metrics.KV_TABLE_BLOCKS_LIVE.labels(name).inc(live)
         metrics.KV_TABLE_BLOCKS_DEAD.labels(name).inc(total - live)
+        latent_layers = getattr(self, "_latent_layers", 0)
+        if latent_layers:
+            # A latent layer's step over n keys reads n cached rows, once.
+            metrics.KV_LATENT_KEYS_READ.labels(name).inc(
+                int(n.sum()) * latent_layers)
 
     def _note_moe(self, counts) -> None:
         """One delivered paged chunk's per-expert assignment counts
         ([L, E], a row an EXPERT layer — a dense layer of a per-layer
         pattern has none: models/llama.generate_chunk_paged) into
         the routing metrics.  Imbalance and experts hit are a LAYER's
-        (a grouped matmul's load is one layer's), a mean over layers."""
+        (a grouped matmul's load is one layer's), a mean over layers —
+        over the experts this tree HOLDS (all of them, or a chip's share:
+        the counts are over the published experts, so the assignments
+        that landed elsewhere — what an expert-parallel exchange would
+        carry — are counted apart)."""
         per_layer = counts.sum(axis=1)
         if int(per_layer.min()) <= 0:  # every row done: nothing was routed
             return
         name = self.engine.bundle.name
+        first, n_held = getattr(self, "_experts_held", (0, 0))
+        held = counts[:, first:first + n_held] if n_held else counts
+        here = held.sum(axis=1)
         metrics.MOE_ASSIGNMENTS.labels(name).inc(int(per_layer.sum()))
+        metrics.MOE_ASSIGNMENTS_HELD.labels(name).inc(int(here.sum()))
+        metrics.MOE_ASSIGNMENTS_ABSENT.labels(name).inc(
+            int(per_layer.sum() - here.sum()))
         metrics.MOE_EXPERTS_HIT.labels(name).set(
-            float((counts > 0).sum(axis=1).mean())
+            float((held > 0).sum(axis=1).mean())
         )
-        metrics.MOE_LOAD_IMBALANCE.labels(name).observe(
-            float((counts.max(axis=1) * counts.shape[1] / per_layer).mean())
-        )
+        if int(here.min()) > 0:
+            metrics.MOE_LOAD_IMBALANCE.labels(name).observe(
+                float((held.max(axis=1) * held.shape[1] / here).mean())
+            )
 
     def _deliver_oldest(self) -> None:
         import jax
@@ -5097,12 +5121,16 @@ class ContinuousDecodeLoop:
             getattr(scfg, "compile_cache_dir", None),
         )
         kvh = int(getattr(bcfg, "num_kv_heads", bcfg.num_heads))
+        kind, d = "paged_decode", int(bcfg.head_dim)
+        if getattr(bcfg, "latent_lanes", 0):
+            # One KV "head" every query head shares, as wide as the pool.
+            kind, kvh, d = "latent_decode", 1, int(bcfg.latent_lanes)
 
         def tuned(t: int) -> str:
             return autotune.ensure_tuned(
-                "paged_decode", eng.bundle, eng.replicas,
+                kind, eng.bundle, eng.replicas,
                 b=self.n_slots, kvh=kvh,
-                n_rep=int(bcfg.num_heads) // kvh, d=int(bcfg.head_dim),
+                n_rep=int(bcfg.num_heads) // kvh, d=d,
                 block_size=self.block_size, t=t,
                 dtype=str(np_.dtype(eng.bundle.policy.compute_jnp)),
                 quant=bool(getattr(bcfg, "kv_quant", False)),

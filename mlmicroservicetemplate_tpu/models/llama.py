@@ -156,6 +156,37 @@ class LlamaConfig:
     attn_gate: bool = False
     nope_on_full: bool = False
     mup_embed: bool = False
+    # The attention kind: "gqa" (everything above) | "mla", multi-head
+    # latent attention (DeepSeek-V2): q through a ``q_lora_rank``-wide
+    # bottleneck with its own RMSNorm; K and V through ONE
+    # ``kv_lora_rank``-wide latent a token with its own RMSNorm plus one
+    # ``qk_rope_head_dim``-wide rotary key shared by every head; heads of
+    # ``qk_nope_head_dim + qk_rope_head_dim`` for scores (``head_dim`` is
+    # set to that) and ``v_head_dim`` for values.  The cache holds the
+    # latent row [c ; k_rope], once a token a layer, no heads axis and no
+    # V pool (``latent_lanes``); the decode step is absorbed, prefill
+    # expanded (``_mla_*`` below).  ``num_kv_heads`` is not read.
+    attention: str = "gqa"
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # Rotary scaling: None, or YaRN's keys as published (``type`` "yarn",
+    # ``factor``, ``original_max_position_embeddings``, ``beta_fast``,
+    # ``beta_slow``, ``mscale``, ``mscale_all_dim``): blended frequencies
+    # in ``_rope_tables``, ``mscale(all_dim)^2`` in the softmax scale.
+    # A dict is stored as its sorted items (the config is hashed).
+    rope_scaling: Any = None
+    # The router's group limit (ops/moe.group_limited): ``n_group`` groups
+    # of consecutive experts, the ``topk_group`` best kept (0/1 = none).
+    n_group: int = 0
+    topk_group: int = 0
+    # A chip's share of the experts: the tree holds ``experts_held``
+    # experts from ``expert_first`` on (0 = all, as ever); the router
+    # stays ``num_experts`` wide and selects over all of them.
+    experts_held: int = 0
+    expert_first: int = 0
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -165,6 +196,53 @@ class LlamaConfig:
                 f"experts_per_token={self.experts_per_token} must lie in "
                 f"1..num_experts={self.num_experts}"
             )
+        if self.attention not in ("gqa", "mla"):
+            raise ValueError(f"attention={self.attention!r} ('gqa', 'mla')")
+        mla_dims = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
+                    self.qk_rope_head_dim, self.v_head_dim)
+        if self.mla:
+            if not all(d > 0 for d in mla_dims) or self.qk_rope_head_dim % 2:
+                raise ValueError(
+                    "attention='mla' needs q_lora_rank, kv_lora_rank, "
+                    "qk_nope_head_dim, qk_rope_head_dim (even) and v_head_dim"
+                    f", got {mla_dims}")
+            object.__setattr__(
+                self, "head_dim", self.qk_nope_head_dim + self.qk_rope_head_dim)
+        elif any(mla_dims):
+            raise ValueError(
+                f"latent-attention sizes {mla_dims} need attention='mla'")
+        if isinstance(self.rope_scaling, dict):
+            object.__setattr__(
+                self, "rope_scaling", tuple(sorted(self.rope_scaling.items())))
+        if self.rope_scaling is not None:
+            y = dict(self.rope_scaling)
+            need = {"type", "factor", "original_max_position_embeddings",
+                    "beta_fast", "beta_slow", "mscale", "mscale_all_dim"}
+            if y.get("type") != "yarn" or set(y) != need:
+                raise ValueError(
+                    f"rope_scaling must be None or YaRN's keys {sorted(need)}, "
+                    f"got {y}")
+            if not self.mla:
+                raise ValueError(
+                    "rope_scaling (YaRN) is carried by attention='mla' only: "
+                    "its mscale^2 lives in that path's softmax scale")
+        if self.n_group > 1:
+            per = self.num_experts // self.n_group
+            if (self.num_experts % self.n_group
+                    or not 0 < self.topk_group <= self.n_group
+                    or self.experts_per_token > self.topk_group * per):
+                raise ValueError(
+                    f"n_group={self.n_group} / topk_group={self.topk_group}: "
+                    f"groups must divide num_experts={self.num_experts} and "
+                    "the kept groups must hold experts_per_token experts")
+        if self.experts_held or self.expert_first:
+            if not (0 < self.experts_held
+                    and 0 <= self.expert_first
+                    and self.expert_first + self.experts_held <= self.num_experts):
+                raise ValueError(
+                    f"experts_held={self.experts_held} from expert_first="
+                    f"{self.expert_first} must lie within num_experts="
+                    f"{self.num_experts}")
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         hf = {"sliding_attention": "window", "full_attention": "full"}
@@ -199,6 +277,51 @@ class LlamaConfig:
     def q_dim(self) -> int:
         return self.num_heads * self.head_dim
 
+    @property
+    def mla(self) -> bool:
+        return self.attention == "mla"
+
+    @property
+    def rope_dim(self) -> int:
+        """Width of what is rotated: a whole head, or MLA's rotary part."""
+        return self.qk_rope_head_dim if self.mla else self.head_dim
+
+    @property
+    def o_dim(self) -> int:
+        """Input width of ``W_o``: the heads' VALUES merged."""
+        return self.num_heads * (self.v_head_dim if self.mla else self.head_dim)
+
+    @property
+    def latent_dim(self) -> int:
+        """Values of one cached latent row [c ; k_rope] (0: no latent)."""
+        return self.kv_lora_rank + self.qk_rope_head_dim if self.mla else 0
+
+    @property
+    def latent_lanes(self) -> int:
+        """Width of the latent POOL: ``latent_dim`` rounded up to whole
+        128-lane tiles, zeros past the values.  The chip's compiler lays a
+        576-wide minor dim out 640 wide in HBM anyway and Mosaic refuses to
+        slice a block out of it at 576 ("Slice shape along dimension 2
+        must be aligned to tiling (128)": compiled for a described v5e,
+        PR 33), so the pad costs no byte and is what lets the kernel copy
+        a block where it lies."""
+        return -(-self.latent_dim // 128) * 128
+
+    @property
+    def attn_scale(self) -> float:
+        """The softmax scale: ``head_dim^-1/2`` and, under YaRN,
+        ``mscale(factor, mscale_all_dim)^2`` (DeepSeek-V2: 1.2608^2)."""
+        scale = self.head_dim ** -0.5
+        if self.rope_scaling is not None:
+            y = dict(self.rope_scaling)
+            scale *= yarn_mscale(y["factor"], y["mscale_all_dim"]) ** 2
+        return scale
+
+    @property
+    def held(self) -> int:
+        """Experts the tree holds (all of them unless ``experts_held``)."""
+        return self.experts_held or self.num_experts
+
     def layer_kind(self, li: int) -> "LayerKind":
         window = self.window if (
             self.layer_types and self.layer_types[li] == "window") else 0
@@ -208,6 +331,7 @@ class LlamaConfig:
             rope=bool(window) or not self.nope_on_full,
             experts=bool(self.num_experts) and not dense,
             d_ff=self.d_ff_dense if dense else self.d_ff,
+            attention=self.attention,
         )
 
     @property
@@ -219,14 +343,23 @@ class LlamaConfig:
 @dataclasses.dataclass(frozen=True)
 class LayerKind:
     """What one layer is: ``window`` keys a query sees (0 = all before
-    it), whether q and k are rotated, and its FFN (``experts``: the
+    it), whether q and k are rotated, its FFN (``experts``: the
     sparse expert block of experts ``d_ff`` wide; else a dense SwiGLU of
-    width ``d_ff``)."""
+    width ``d_ff``) and its ``attention`` kind ("gqa" | "mla")."""
 
     window: int
     rope: bool
     experts: bool
     d_ff: int
+    attention: str = "gqa"
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention temperature: ``0.1 * mscale * ln(factor) + 1``
+    (1 for a factor of at most 1)."""
+    import math
+
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 # ---------------------------------------------------------------------------
@@ -261,7 +394,7 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
 
     keys = jax.random.split(key, cfg.num_layers + 2)
     d, qd, kv_dim = cfg.d_model, cfg.q_dim, cfg.num_kv_heads * cfg.head_dim
-    e = cfg.num_experts
+    e, held = cfg.num_experts, cfg.held
     params: Params = {
         "embed": {"embedding": cast(normal_init(keys[0], (cfg.vocab_size, d), std=0.02))},
         "layers": [],
@@ -276,12 +409,31 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         def extra(n):  # leaves newer than the 7-way split: their own keys
             return jax.random.fold_in(lk, n)
 
-        attn = {
-            "q": lin(k[0], d, qd),
-            "k": lin(k[1], d, kv_dim),
-            "v": lin(k[2], d, kv_dim),
-            "o": lin(k[3], qd, d),
-        }
+        if cfg.mla:
+            # W_UKV [r, H x (nope + v)] is drawn whole and split ONCE, here,
+            # into the two operands the absorbed step contracts against:
+            # k_b [H, nope, r] (q_nope -> the latent's space) and v_b
+            # [H, r, v] (the latent's space -> values).  No step re-lays it.
+            r, dn, dv = cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.v_head_dim
+            hn = cfg.num_heads
+            kv_b = lin(k[2], r, hn * (dn + dv))["kernel"].reshape(r, hn, dn + dv)
+            attn = {
+                "q_a": lin(k[0], d, cfg.q_lora_rank),
+                "q_a_norm": norm_scale(extra(17), cfg.q_lora_rank),
+                "q_b": lin(extra(18), cfg.q_lora_rank, qd),
+                "kv_a": lin(k[1], d, cfg.latent_dim),
+                "kv_a_norm": norm_scale(extra(19), r),
+                "k_b": {"kernel": cast(jnp.transpose(kv_b[:, :, :dn], (1, 2, 0)))},
+                "v_b": {"kernel": cast(jnp.transpose(kv_b[:, :, dn:], (1, 0, 2)))},
+                "o": lin(k[3], cfg.o_dim, d),
+            }
+        else:
+            attn = {
+                "q": lin(k[0], d, qd),
+                "k": lin(k[1], d, kv_dim),
+                "v": lin(k[2], d, kv_dim),
+                "o": lin(k[3], qd, d),
+            }
         if cfg.qk_norm:
             # Learned scales have no reason to be 1: drawn about it, so a
             # served path that drops the norm departs from one that has it.
@@ -292,10 +444,10 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
             attn["gate"] = lin(extra(10), d, qd)
         if kind.experts:
             mlp = {
-                "router": lin(extra(7), d, e),
-                "gate": experts(k[4], (e, d, w)),
-                "up": experts(k[5], (e, d, w)),
-                "down": experts(k[6], (e, w, d)),
+                "router": lin(extra(7), d, e),  # the published width
+                "gate": experts(k[4], (held, d, w)),
+                "up": experts(k[5], (held, d, w)),
+                "down": experts(k[6], (held, w, d)),
             }
             if cfg.router_bias:
                 # The buffer of aux-loss-free balancing: large enough
@@ -332,15 +484,44 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
 
 
 def _rope_tables(cfg: LlamaConfig, positions: jax.Array, dtype):
-    """cos/sin [..., head_dim] for integer positions [...]."""
-    half = cfg.head_dim // 2
+    """cos/sin [..., rope_dim] for integer positions [...].  Under
+    ``cfg.rope_scaling`` (YaRN) the frequencies are blended: dimension
+    pair ``d`` keeps ``theta^(-2d/D)`` where its wavelength makes more
+    than ``beta_fast`` turns in the original context, takes it divided by
+    ``factor`` where it makes fewer than ``beta_slow``, a linear ramp
+    between; cos and sin carry ``mscale / mscale_all_dim``'s ratio."""
+    dim = cfg.rope_dim
+    half = dim // 2
     inv_freq = 1.0 / (
         cfg.rope_theta
-        ** (jnp.arange(0, half, dtype=jnp.float32) * 2.0 / cfg.head_dim)
+        ** (jnp.arange(0, half, dtype=jnp.float32) * 2.0 / dim)
     )
+    amp = None
+    if cfg.rope_scaling is not None:
+        import math
+
+        y = dict(cfg.rope_scaling)
+
+        def pair_of(turns):  # the pair whose wavelength makes ``turns`` turns
+            return (dim * math.log(y["original_max_position_embeddings"]
+                                   / (turns * 2 * math.pi))
+                    / (2 * math.log(cfg.rope_theta)))
+
+        lo = max(math.floor(pair_of(y["beta_fast"])), 0)
+        hi = min(math.ceil(pair_of(y["beta_slow"])), dim - 1)
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - lo) / max(hi - lo, 0.001),
+            0.0, 1.0)
+        inv_freq = inv_freq / y["factor"] * ramp + inv_freq * (1.0 - ramp)
+        ratio = (yarn_mscale(y["factor"], y["mscale"])
+                 / yarn_mscale(y["factor"], y["mscale_all_dim"]))
+        amp = None if ratio == 1.0 else ratio
     angles = positions.astype(jnp.float32)[..., None] * inv_freq  # [..., half]
-    emb = jnp.concatenate([angles, angles], axis=-1)  # [..., head_dim]
-    return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
+    emb = jnp.concatenate([angles, angles], axis=-1)  # [..., rope_dim]
+    cos, sin = jnp.cos(emb), jnp.sin(emb)
+    if amp is not None:
+        cos, sin = cos * amp, sin * amp
+    return cos.astype(dtype), sin.astype(dtype)
 
 
 def _rotate_half(x: jax.Array) -> jax.Array:
@@ -398,7 +579,8 @@ def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
                 h.reshape(b * s, d), m, cfg.experts_per_token,
                 cfg.norm_topk_prob, jnp.broadcast_to(valid, (b, s)).reshape(-1),
                 interpret=cfg.pallas_interpret, score=cfg.router_score,
-                route_scale=cfg.route_scale,
+                route_scale=cfg.route_scale, n_group=cfg.n_group,
+                topk_group=cfg.topk_group, expert_first=cfg.expert_first,
             )
             if tally is not None:
                 tally.append(counts)
@@ -450,6 +632,8 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
     split (OLMoE), "head" per head over Dh (Trinity).  q and k are
     rotated unless the layer's kind says not (``cfg.nope_on_full``)."""
     a = layer["attn"]
+    if cfg.mla:
+        return _mla_qkv(cfg, layer, x, cos, sin)
     with jax.named_scope("qkv_rope"):
         h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
         q = _aproj(a, ad, "q", li, h)
@@ -466,6 +650,137 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
         v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
         g = jax.nn.sigmoid(_aproj(a, ad, "gate", li, h)) if cfg.attn_gate else None
     return q, k, v, g
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (``cfg.attention == "mla"``; DeepSeek-V2)
+#
+#   c_q = RMSNorm(y W_DQ)        [qn_h ; qr_h] = split_h(c_q W_UQ)   qr rotated
+#   [c ; kr] = y W_DKV           c = RMSNorm(c)    kr rotated: ONE rotary key
+#                                a token, shared by every head
+#   the cache holds [c ; kr] (``latent_dim`` values in ``latent_lanes``
+#   lanes), after norm and rotation, once a token a layer.
+#   expanded (prefill):  [kn_h ; v_h] = split_h(c W_UKV)
+#       s_hij = (qn_hi . kn_hj + qr_hi . kr_j) * attn_scale
+#   absorbed (decode; the same numbers):  ql_hi = qn_hi W_UK_h^T
+#       s_hij = (ql_hi . c_j + qr_hi . kr_j) * attn_scale
+#       ol_hi = sum_j softmax(s)_hij c_j      a_hi = ol_hi W_UV_h
+#   ``k_b`` [H, nope, r] = W_UK, ``v_b`` [H, r, v] = W_UV: W_UKV split once
+#   at init (``init_params``).
+
+#: Heads a block of the expanded attention holds keys, values and float32
+#: scores for: 128 heads x 1024 queries x 6272 keys would be 3.3 GB.
+MLA_HEAD_BLOCK = 16
+
+
+def _mla_qkv(cfg: "LlamaConfig", layer, x, cos, sin):
+    """``((qn [.., H, nope], qr [.., H, rope]), latent [.., lanes], None,
+    None)`` of an MLA layer from the residual stream x [B, S, D] — its
+    ``qkv_rope`` scope: ``mla_q`` (down, norm, up, rotary) and ``mla_kv``
+    (down, norm, rotary; the row the cache holds, zero past
+    ``latent_dim``)."""
+    a, r = layer["attn"], cfg.kv_lora_rank
+    with jax.named_scope("qkv_rope"):
+        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+        with jax.named_scope("mla_q"):
+            cq = rmsnorm(a["q_a_norm"], dense(a["q_a"], h), eps=cfg.rms_eps)
+            q = _split(dense(a["q_b"], cq), cfg.num_heads)
+            qn = q[..., : cfg.qk_nope_head_dim]
+            qr = _apply_rope(q[..., cfg.qk_nope_head_dim:], cos, sin)
+        with jax.named_scope("mla_kv"):
+            ckr = dense(a["kv_a"], h)
+            c = rmsnorm(a["kv_a_norm"], ckr[..., :r], eps=cfg.rms_eps)
+            kr = _apply_rope(ckr[..., None, r:], cos, sin)[..., 0, :]
+            latent = _latent_row(cfg, c, kr)
+    return (qn, qr), latent, None, None
+
+
+def _latent_row(cfg: "LlamaConfig", c, kr):
+    """[c ; kr] padded with zeros to the pool's ``latent_lanes``: a cached
+    row, or the absorbed q that scores it."""
+    pad = cfg.latent_lanes - cfg.latent_dim
+    zeros = [jnp.zeros(c.shape[:-1] + (pad,), c.dtype)] if pad else []
+    return jnp.concatenate([c, kr] + zeros, axis=-1)
+
+
+def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask):
+    """Expanded attention of q = (qn [B, Sq, H, nope], qr [B, Sq, H, rope])
+    over the keys ``latent`` [B, Sk, lanes] under ``mask`` [B, 1, Sq, Sk]
+    -> [B, Sq, H, v]: the prefill form, ``MLA_HEAD_BLOCK`` heads at a
+    time — a block expands its heads' keys and values from the latents
+    (``mla_expand``), scores them in float32 and weighs its values."""
+    a, r = layer["attn"], cfg.kv_lora_rank
+    qn, qr = q
+    b, sq, hn, dn = qn.shape
+    hb = min(hn, MLA_HEAD_BLOCK)
+    nblk = hn // hb
+    c, kr = latent[..., :r], latent[..., r:cfg.latent_dim]
+    f32 = jnp.float32
+
+    def blocks(x, axis):  # the heads axis -> [nblk, ..., hb, ...], leading
+        shape = x.shape[:axis] + (nblk, hb) + x.shape[axis + 1:]
+        return jnp.moveaxis(x.reshape(shape), axis, 0)
+
+    def block(args):
+        qn_h, qr_h, kb, vb = args
+        with jax.named_scope("mla_expand"):
+            kn = jnp.einsum("bkr,hnr->bkhn", c, kb.astype(c.dtype))
+            v = jnp.einsum("bkr,hrv->bkhv", c, vb.astype(c.dtype))
+        s = (jnp.einsum("bqhn,bkhn->bhqk", qn_h, kn, preferred_element_type=f32)
+             + jnp.einsum("bqhd,bkd->bhqk", qr_h, kr, preferred_element_type=f32)
+             ) * cfg.attn_scale
+        s = jnp.where(mask, s, f32(-1e9))
+        p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bkhv->bqhv", p, v)
+
+    out = jax.lax.map(block, (
+        blocks(qn, 2), blocks(qr, 2),
+        blocks(a["k_b"]["kernel"], 0), blocks(a["v_b"]["kernel"], 0)))
+    # [nblk, B, Sq, hb, v] -> [B, Sq, H, v]
+    return jnp.moveaxis(out, 0, 2).reshape(b, sq, hn, cfg.v_head_dim)
+
+
+def _mla_decode_attention(cfg: "LlamaConfig", layer, q, pool, table,
+                          key_valid, bs: int):
+    """The absorbed decode step's attention, q = (qn, qr) [B, 1, H, .]
+    over the latent pool -> [B, 1, H, v]: ``mla_absorb`` (qn through
+    W_UK into the latent's space: q is then [B, H, lanes] as the cache
+    lies), ``attn_latent`` (the Pallas latent kernel — each cached row
+    read once, keys = all its lanes, values = its first ``kv_lora_rank``
+    — or, without ``cfg.pallas_decode``, the same sums over the rows'
+    gathered blocks in XLA) and ``mla_unabsorb`` (through W_UV).
+    ``table`` None: ``pool`` is a contiguous latent slab [B, T, lanes]
+    (the unary path's ``_decode_step``), attended in XLA as it lies."""
+    a, r = layer["attn"], cfg.kv_lora_rank
+    qn, qr = q[0][:, 0], q[1][:, 0]
+    with jax.named_scope("mla_absorb"):
+        ql = jnp.einsum("bhn,hnr->bhr", qn, a["k_b"]["kernel"].astype(qn.dtype))
+        q_lat = _latent_row(cfg, ql, qr)
+    with jax.named_scope("attn_latent"):
+        if cfg.pallas_decode and table is not None:
+            from ..ops import autotune
+            from ..ops.paged_attention import latent_decode_attention
+
+            vkey = cfg.pallas_variant or autotune.lookup(
+                "latent_decode", b=q_lat.shape[0], kvh=1, n_rep=cfg.num_heads,
+                d=cfg.latent_lanes, block_size=bs, t=table.shape[1],
+                dtype=str(q_lat.dtype), quant=False, tp=cfg.tp,
+            )
+            ol = latent_decode_attention(
+                q_lat, pool, table, key_valid, bs, r, cfg.attn_scale,
+                interpret=cfg.pallas_interpret, variant=vkey)
+        else:
+            from ..ops.paged_attention import gather_pages
+
+            keys = pool if table is None else gather_pages(pool, table, bs)
+            s = jnp.einsum("bhc,btc->bht", q_lat, keys,
+                           preferred_element_type=jnp.float32) * cfg.attn_scale
+            s = jnp.where((key_valid != 0)[:, None, :], s, jnp.float32(-1e9))
+            p = jax.nn.softmax(s, axis=-1).astype(keys.dtype)
+            ol = jnp.einsum("bht,btr->bhr", p, keys[..., :r])
+    with jax.named_scope("mla_unabsorb"):
+        ctx = jnp.einsum("bhr,hrv->bhv", ol, a["v_b"]["kernel"].astype(ol.dtype))
+    return ctx[:, None]
 
 
 def _attn_out(cfg: "LlamaConfig", layer, ad, li: int, x, ctx, g):
@@ -580,6 +895,12 @@ def forward_hidden(
         q, k, v, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         if collect_kv:
             kv.append((k, v))
+        if cfg.mla:  # k is the window's latent rows: expanded attention
+            with _attn_scope(cfg, li):
+                ctx = _mla_expanded_attention(cfg, layer, q, k, mask)
+            x = _attn_out(cfg, layer, ad, li, x, ctx, g)
+            x = _mlp_block(cfg, layer, li, x, attention_mask != 0)
+            continue
         with _attn_scope(cfg, li):
             if p_len:
                 pk = _dequant_prefix(prefix_kv[li][0], k.dtype)
@@ -645,6 +966,10 @@ def init_decode_state(
     cache_k, cache_v = [], []
     with jax.named_scope("kv_write"):
         for li, (k, v) in enumerate(kv):
+            if cfg.mla:  # one latent slab a layer, no V (the paged insert's source)
+                ck = jnp.zeros((b, total, cfg.latent_lanes), k.dtype)
+                cache_k.append(ck.at[:, :s].set(k))
+                continue
             if cfg.kv_quant:
                 # Scales stored in the COMPUTE dtype: the decode step
                 # recovers its working dtype from the state (the int8
@@ -780,11 +1105,15 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
             ck = _write_kv(state.cache_k[li], rows, t, k1[:, 0], dtype)
-            cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
+            if not cfg.mla:
+                cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
+                new_v.append(cv)
         new_k.append(ck)
-        new_v.append(cv)
         with jax.named_scope("attn"):
-            ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
+            if cfg.mla:  # the latent slab, absorbed, in XLA (unary requests)
+                ctx = _mla_decode_attention(cfg, layer, q, ck, None, key_valid, 0)
+            else:
+                ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
         x = _attn_out(cfg, layer, ad, li, x, ctx, g)
         x = _mlp_block(cfg, layer, li, x, ~state.done[:, None])
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
@@ -1034,13 +1363,17 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
             ck = _paged_write_kv(state.cache_k[li], table, t, k1[:, 0], bs, dtype)
-            cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
+            if not cfg.mla:  # a latent row is written once: there is no V pool
+                cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
+                new_v.append(cv)
         new_k.append(ck)
-        new_v.append(cv)
         with _attn_scope(cfg, li):
-            ctx = _paged_cache_attention(
-                cfg, q, ck, cv, *(view if cfg.layer_kind(li).window else full), bs
-            )
+            if cfg.mla:
+                ctx = _mla_decode_attention(cfg, layer, q, ck, *full, bs)
+            else:
+                ctx = _paged_cache_attention(
+                    cfg, q, ck, cv, *(view if cfg.layer_kind(li).window else full), bs
+                )
         x = _attn_out(cfg, layer, ad, li, x, ctx, g)
         x = _mlp_block(cfg, layer, li, x, ~state.done[:, None], moe_tally)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
@@ -1247,11 +1580,21 @@ def paged_prefill_chunk(
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
     t_w = table_row.shape[0]
 
-    def attend(q, ck, cv, first, n_blocks: int, window: int):
+    def attend(q, ck, cv, first, n_blocks: int, window: int, layer=None):
         """Over ``n_blocks`` (static) table entries from ``first`` on."""
         rows = jax.lax.dynamic_slice_in_dim(table_row, first, n_blocks)
         kpos = first * bs + jnp.arange(n_blocks * bs)
         mask = _prefill_mask(kpos, chunk_mask, start, window)
+        if cfg.mla:
+            # The row's latents, earlier windows' and this one's, as the
+            # pool holds them, expanded again here: 0.017 GFLOP a key a
+            # layer against 196 kFLOP more a query-key pair scored
+            # absorbed — at 1024 queries a window expansion is the cheaper
+            # by 6x (PERF.md section 6, PR 33).
+            from ..ops.paged_attention import gather_pages
+
+            return _mla_expanded_attention(
+                cfg, layer, q, gather_pages(ck, rows[None], bs), mask)
         return _gathered_attention(cfg, q, ck, cv, rows[None], bs, mask)
 
     # Full layers: keys 0 .. start + C - 1, in prefixes of whole steps.
@@ -1266,20 +1609,23 @@ def paged_prefill_chunk(
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        cv = None
         with jax.named_scope("kv_write"):
             ck = _paged_scatter_entry(state.cache_k[li], table_row, k1[0], bs, start, dtype)
-            cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
+            if not cfg.mla:
+                cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
+                new_v.append(cv)
         new_k.append(ck)
-        new_v.append(cv)
         with _attn_scope(cfg, li):
             if cfg.layer_kind(li).window:
                 ctx = attend(q, ck, cv, first_win, n_win, cfg.window)
             elif len(widths) == 1:
-                ctx = attend(q, ck, cv, 0, t_w, 0)
+                ctx = attend(q, ck, cv, 0, t_w, 0, layer=layer)
             else:
                 ctx = jax.lax.switch(
                     reach,
-                    [functools.partial(attend, first=0, n_blocks=w, window=0)
+                    [functools.partial(attend, first=0, n_blocks=w, window=0,
+                                       layer=layer)
                      for w in widths],
                     q, ck, cv,
                 )
@@ -1319,6 +1665,12 @@ def init_paged_state(
     shape = (num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
     sc_shape = (num_blocks, block_size, cfg.num_kv_heads)
     for k, v in kv:
+        if cfg.mla:  # one latent pool a layer
+            ck = jnp.zeros((num_blocks, block_size, cfg.latent_lanes), k.dtype)
+            for row in range(b):
+                ck = scatter_pages(ck, table[row], k[row], block_size)
+            cache_k.append(ck)
+            continue
         if cfg.kv_quant:
             k8, ks = kv_quantize(k)
             v8, vs = kv_quantize(v)

@@ -276,6 +276,23 @@ def _decode_position_budget(svc_cfg, max_position: int, p_len: int,
     return max_prompt
 
 
+def _refuse_cache_readers(svc_cfg, what: str, whys: dict) -> None:
+    """Raise for the first configured reader of the KV cache that a llama
+    config with ``what`` cannot be served through (``whys``: knob -> why)."""
+    on = {
+        "PAGED_KV=0": not getattr(svc_cfg, "paged_kv", False),
+        "SPEC_DECODE": getattr(svc_cfg, "spec_decode", None),
+        "QUANT_KV": getattr(svc_cfg, "quant_kv", None),
+        "PREFIX_CACHE": getattr(svc_cfg, "prefix_cache", False),
+        "PROMPT_PREFIX": getattr(svc_cfg, "prompt_prefix", None),
+    }
+    for knob, why in whys.items():
+        if on[knob]:
+            raise ValueError(
+                f"{knob} is not supported for a llama config with {what}: {why}"
+            )
+
+
 def _pallas_knobs(svc_cfg) -> dict:
     """Kernel-selection knobs every decoder-only family plumbs into its
     (frozen) model config at build time (docs/kernel_tuning.md):
@@ -844,7 +861,11 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         )
         explicit = env_pd in ("1", "true", "yes")
         backend_ok = _pallas_backend_ok(svc_cfg)
-        fits = decode_kernel_fits(t_est, probe.num_kv_heads, probe.head_dim)
+        # A latent cache has no slab kernel to fit (it serves paged only:
+        # refused below otherwise); its paged kernel's VMEM is the
+        # autotuner's gate (ops/autotune.latent_vmem_bytes).
+        fits = probe.mla or decode_kernel_fits(
+            t_est, probe.num_kv_heads, probe.head_dim)
         if backend_ok and fits:
             overrides["pallas_decode"] = True
         elif explicit and not backend_ok:
@@ -878,6 +899,9 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             ("a head_dim of its own", cfg.q_dim != cfg.d_model),
             ("an attention gate", cfg.attn_gate),
             ("sandwich norms", cfg.sandwich_norm),
+            ("latent attention", cfg.mla),
+            ("a group limit on the router", cfg.n_group > 1),
+            ("a chip's share of the experts", cfg.experts_held),
         ) if on
     ]
     if variants:
@@ -904,24 +928,38 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         # and the paged decode step (kernel and gathered path:
         # models/llama.py).  Every other reader of the cache would attend
         # over ALL the keys and serve another model in silence: refuse it.
-        for on, knob, why in (
-            (not getattr(svc_cfg, "paged_kv", False), "PAGED_KV=0",
-             "the contiguous slab's decode step, chunked prefill and fused "
-             "decode window apply no window: set PAGED_KV=1"),
-            (getattr(svc_cfg, "spec_decode", None), "SPEC_DECODE",
-             "speculative verification (llama.multi_step) applies no window"),
-            (getattr(svc_cfg, "quant_kv", None), "QUANT_KV",
-             "the int8 pool pairs were never run under a window view"),
-            (getattr(svc_cfg, "prefix_cache", False), "PREFIX_CACHE",
-             "a prefix hit's gathers and prefixed prefill apply no window"),
-            (getattr(svc_cfg, "prompt_prefix", None), "PROMPT_PREFIX",
-             "the prefix overlay's prefill applies no window"),
-        ):
-            if on:
-                raise ValueError(
-                    f"{knob} is not supported for a llama config with window "
-                    f"layers (layer_types / window={cfg.window}): {why}"
-                )
+        _refuse_cache_readers(
+            svc_cfg, f"window layers (layer_types / window={cfg.window})", {
+                "PAGED_KV=0": "the contiguous slab's decode step, chunked "
+                "prefill and fused decode window apply no window: set PAGED_KV=1",
+                "SPEC_DECODE": "speculative verification (llama.multi_step) "
+                "applies no window",
+                "QUANT_KV": "the int8 pool pairs were never run under a window view",
+                "PREFIX_CACHE": "a prefix hit's gathers and prefixed prefill "
+                "apply no window",
+                "PROMPT_PREFIX": "the prefix overlay's prefill applies no window",
+            })
+    if cfg.mla:
+        # The cache is one latent row a token a layer (no heads axis, no V
+        # pool), read by the prefill waves, the chunked paged prefill and
+        # the paged decode step (kernel and gathered path: models/llama.py).
+        # Every other reader of the cache expects K and V per KV head and
+        # would cache or attend something else in silence: refuse it.
+        # TP>1 (the latent would replicate) and QUANTIZE refuse above.
+        _refuse_cache_readers(
+            svc_cfg, "latent attention (attention='mla')", {
+                "PAGED_KV=0": "the contiguous slab's chunked prefill, fused "
+                "decode window and streaming loop read K and V per head: set "
+                "PAGED_KV=1",
+                "SPEC_DECODE": "speculative verification (llama.multi_step) "
+                "reads K and V per head",
+                "QUANT_KV": "the int8 pool pairs quantise per token-head; a "
+                "latent has no heads",
+                "PREFIX_CACHE": "a prefix hit's gathers and prefixed prefill "
+                "read K and V per head",
+                "PROMPT_PREFIX": "the prefix overlay's prefill reads K and V "
+                "per head",
+            })
     if cfg.num_experts and not _pallas_backend_ok(svc_cfg):
         raise RuntimeError(
             "the expert FFN's grouped matmul (ops/moe.py) is a Pallas TPU "

@@ -55,6 +55,14 @@ from .paged_attention import Variant, parse_variant
 #: table-width divisibility and the VMEM model).
 BLOCK_FOLDS = (1, 2, 4, 8)
 
+#: the latent kernel's folds (``latent_decode``): one pool, 128 query rows
+#: a key, so a trip of 8 blocks is a quarter of a microsecond of MXU work
+#: and of copy alike under ~0.7 us of trip overhead — the folds reach up to
+#: the divisors of the serving table's width (392 = 8 x 49: 14, 28, 56).
+LATENT_BLOCK_FOLDS = (4, 8, 14, 16, 28, 32, 56)
+#: softmax scale of the latent probe (the model's own is its config's).
+LATENT_PROBE_SCALE = 0.1
+
 #: scan lengths for the two-scan timing (small: the sweep times a
 #: single fused kernel, not a serving chunk; interpret-mode CPU sweeps
 #: stay affordable).  PALLAS_AUTOTUNE_ITERS overrides.
@@ -160,13 +168,44 @@ def paged_vmem_bytes(var: Variant, *, bs: int, kvh: int, d: int,
     return payload + mask + scales + f32_copies + q_out + scratch + scores
 
 
+def _latent_v_dim(d: int) -> int:
+    """Values of a PROBE's latent row of ``d`` lanes: its lanes but the
+    last tile's rotary part (DeepSeek-V2: 512 of 640).  The serving call
+    takes the model's own ``kv_lora_rank``."""
+    return d - 128 if d > 128 else max(d // 2, 1)
+
+
+def latent_vmem_bytes(var: Variant, *, bs: int, d: int, n_rep: int,
+                      payload_bytes: int, t: int = 0) -> int:
+    """VMEM of one program of the LATENT kernel
+    (ops/paged_attention.latent_decode_attention): ONE slot pair of K
+    raw blocks ``[K*BS, d]`` (keys and values are the same tile), its
+    f32 copy unless ``native_mxu``, q ``[H, d]`` and the output ``[H, v]``
+    double-buffered, m / l / acc ``[H, v]`` scratch, the score and
+    probability temporaries ``[H, K*BS]`` and the row's mask."""
+    kb = var.blocks_per_step * bs
+    v = _latent_v_dim(d)
+    payload = 2 * kb * d * payload_bytes
+    f32_copy = 0 if var.native_mxu else kb * d * 4
+    mask = 2 * (t // var.blocks_per_step) * max(kb, 128) * 4
+    q_out = 2 * n_rep * (d + v) * payload_bytes
+    scratch = (2 * n_rep + n_rep * v) * 4
+    scores = 2 * n_rep * kb * 4
+    return payload + f32_copy + mask + q_out + scratch + scores
+
+
 def variant_fits(var: Variant, *, bs: int, kvh: int, d: int, n_rep: int,
                  payload_bytes: int, quant: bool,
-                 budget: int | None = None, t: int = 0) -> bool:
+                 budget: int | None = None, t: int = 0,
+                 latent: bool = False) -> bool:
     from .attention import decode_vmem_budget_bytes
 
     if budget is None:
         budget = decode_vmem_budget_bytes()
+    if latent:
+        return latent_vmem_bytes(
+            var, bs=bs, d=d, n_rep=n_rep, payload_bytes=payload_bytes, t=t,
+        ) <= budget
     return paged_vmem_bytes(
         var, bs=bs, kvh=kvh, d=d, n_rep=n_rep,
         payload_bytes=payload_bytes, quant=quant, t=t,
@@ -182,23 +221,28 @@ def enumerate_variants(kind: str, *, t: int, bs: int, kvh: int, d: int,
     table width) — axes that would be no-ops are never enumerated, so
     every candidate the sweep times is a genuinely distinct kernel."""
     payload_bytes = 1 if quant else (2 if dtype == "bfloat16" else 4)
+    latent = kind == "latent_decode"
     folds = [1]
-    if kind == "paged_decode":
-        folds = [k for k in BLOCK_FOLDS if k <= max(t, 1) and t % k == 0]
+    if kind == "paged_decode" or latent:
+        folds = [k for k in (LATENT_BLOCK_FOLDS if latent else BLOCK_FOLDS)
+                 if k <= max(t, 1) and t % k == 0]
         if not folds:
             folds = [1]
     nats = [False, True] if (dtype == "bfloat16" and not quant) else [False]
     fss = [False, True] if quant else [False]
     out: list[Variant] = []
     for k in folds:
-        for hb in (False, True):
+        # the latent kernel has one KV head: nothing to batch heads over
+        for hb in ((False,) if latent else (False, True)):
             for nat in nats:
                 for fs in fss:
                     var = Variant(k, hb, nat, fs)
                     if variant_fits(
                         var, bs=bs, kvh=kvh, d=d, n_rep=n_rep,
                         payload_bytes=payload_bytes, quant=quant,
-                        budget=budget, t=t if kind == "paged_decode" else 0,
+                        budget=budget,
+                        t=t if kind in ("paged_decode", "latent_decode") else 0,
+                        latent=latent,
                     ):
                         out.append(var)
                     else:
@@ -266,6 +310,25 @@ def _probe(kind: str, *, b: int, kvh: int, n_rep: int, d: int, bs: int,
     h = kvh * n_rep
     jdt = jnp.bfloat16 if dtype == "bfloat16" else jnp.float32
     q = jnp.asarray(rng.normal(size=(b, h, d)).astype(np.float32), dtype=jdt)
+    if kind == "latent_decode":
+        # Rows of unequal lengths in a table allocated ahead of them, as a
+        # serving batch is (the kernel's time follows the live keys): from
+        # a third of the table's width up, a slot in eight empty.
+        from .paged_attention import paged_attention_ref
+
+        nb_pool = b * t + 2
+        pool = jnp.asarray(rng.normal(size=(nb_pool, bs, d)).astype(np.float32) * 0.3,
+                           dtype=jdt)
+        table = rng.permutation(nb_pool)[: b * t].reshape(b, t).astype(np.int32)
+        lens = rng.integers(max(t * bs // 3, 1), t * bs, b)
+        lens[::8] = 0
+        valid = (np.arange(t * bs)[None, :] < lens[:, None]).astype(np.int32)
+        table[np.arange(t)[None, :] * bs >= lens[:, None] + 4 * bs] = nb_pool
+        args = (q, pool, jnp.asarray(table), jnp.asarray(valid))
+        ref = paged_attention_ref(q, pool, None, args[2], args[3], bs,
+                                  scale=LATENT_PROBE_SCALE,
+                                  v_dim=_latent_v_dim(d))
+        return args, jnp.where((lens > 0)[:, None, None], ref, 0)
     if kind == "paged_decode":
         nb_pool = t + 2  # a couple of free blocks, like a live pool
         kf = rng.normal(size=(nb_pool, bs, kvh, d)).astype(np.float32)
@@ -342,6 +405,16 @@ def _slab_ref(q, k, v, mask, ks, vs):
 def _make_call(kind: str, vkey: str, block_size: int, interpret: bool):
     """A positional-args callable running the kernel at one variant —
     the object the sweep times and the ExecutableCache installs."""
+    if kind == "latent_decode":
+        from .paged_attention import latent_decode_attention
+
+        def call(q, pool, tbl, valid):
+            return latent_decode_attention(
+                q, pool, tbl, valid, block_size, _latent_v_dim(q.shape[-1]),
+                LATENT_PROBE_SCALE, interpret=interpret, variant=vkey,
+            )
+
+        return call
     if kind == "paged_decode":
         from .paged_attention import paged_decode_attention
 
@@ -547,7 +620,8 @@ def ensure_tuned(kind: str, bundle, replicas, *, b: int, kvh: int,
     path = default_table_path() if table_path == "" else table_path
     if pin:
         var = parse_variant(pin)  # ValueError on junk: fail at boot
-        if kind == "paged_decode" and t and t % var.blocks_per_step != 0:
+        if (kind in ("paged_decode", "latent_decode") and t
+                and t % var.blocks_per_step != 0):
             raise ValueError(
                 f"PALLAS_VARIANT={pin!r}: blocks_per_step="
                 f"{var.blocks_per_step} does not divide table width {t}"

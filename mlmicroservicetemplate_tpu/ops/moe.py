@@ -9,6 +9,11 @@ experts' only, never a dense pass over all experts under a mask.
                                                experts (``score``)
     e_1..e_k = top_k(p + b)                    b: a per-expert bias, in the
                                                SELECTION only (``router_bias``)
+                                               under a group limit (``n_group``
+                                               groups of consecutive experts, a
+                                               group's score the max of its
+                                               experts'): among the experts of
+                                               the ``topk_group`` best groups
     w_j = route_scale * p[e_j]                 as they are, or renormalised
                                                over the chosen (norm_topk)
     out = sum_j w_j * down_{e_j}(silu(gate_{e_j} h) * up_{e_j} h)
@@ -17,6 +22,16 @@ experts' only, never a dense pass over all experts under a mask.
 Rows that are padding or finished (``valid`` false) are sorted past the
 last group: the grouped matmul gives them no expert, their (undefined)
 output rows are zeroed before the combine, and they are not counted.
+
+**A chip's share of the experts.**  The router is as wide as the
+PUBLISHED expert count and the selection runs over all of them; the
+stacks ``gate`` / ``up`` / ``down`` may hold fewer (``[held, ...]``:
+experts ``expert_first .. expert_first + held - 1``, one chip of an
+expert-parallel deployment).  An assignment to an expert not held takes
+the same seam as an invalid row — past the last group, zeroed, adding
+nothing — but IS counted: ``counts`` stays ``[E]`` over the published
+experts, so held and absent assignments (what the exchange would carry)
+are both known.  Nothing stands in for the absent chips.
 """
 
 from __future__ import annotations
@@ -57,46 +72,74 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     return out[:m] if pad else out
 
 
+def group_limited(sel: jax.Array, n_group: int, topk_group: int) -> jax.Array:
+    """``sel`` [T, E] selection scores with every expert outside a
+    token's ``topk_group`` best groups set to 0 (``group_limited_greedy``:
+    ``n_group`` groups of E / n_group CONSECUTIVE experts, a group's score
+    the max of its experts'; a masked score is 0, never -inf, as the
+    model code has it, so a tie at 0 can only choose a masked expert when
+    fewer than k scores in the kept groups are positive)."""
+    t, e = sel.shape
+    g = jnp.max(sel.reshape(t, n_group, e // n_group), axis=-1)  # [T, G]
+    _, gi = jax.lax.top_k(g, topk_group)
+    keep = jnp.any(gi[:, :, None] == jnp.arange(n_group)[None, None, :], axis=1)
+    return jnp.where(jnp.repeat(keep, e // n_group, axis=1), sel, 0.0)
+
+
 def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
                interpret: bool = False, score: str = "softmax",
-               route_scale: float = 1.0):
+               route_scale: float = 1.0, n_group: int = 0,
+               topk_group: int = 0, expert_first: int = 0):
     """h [T, D] normed tokens, ``mlp`` the layer's expert leaves (router
-    [D, E]; gate, up [E, D, W]; down [E, W, D]; where the model has them
-    ``router_bias`` [E] and ``shared``, a dense SwiGLU's gate/up/down),
-    valid [T] bool -> (out [T, D] in h's dtype, counts [E] int32 of valid
-    assignments).  ``interpret`` runs the kernel in interpret mode (CPU
-    tests)."""
+    [D, E]; gate, up [held, D, W]; down [held, W, D], held = E unless the
+    tree holds a chip's share; where the model has them ``router_bias``
+    [E] and ``shared``, a dense SwiGLU's gate/up/down), valid [T] bool ->
+    (out [T, D] in h's dtype, counts [E] int32 of valid assignments over
+    the PUBLISHED experts).  ``interpret`` runs the kernel in interpret
+    mode (CPU tests)."""
     t, d = h.shape
     gate, up, down = (mlp[n]["kernel"] for n in ("gate", "up", "down"))
-    n_exp = gate.shape[0]
+    n_exp = gate.shape[0]  # held
+    n_pub = mlp["router"]["kernel"].shape[1]
     with jax.named_scope("moe_route"):
         logits = h.astype(jnp.float32) @ mlp["router"]["kernel"].astype(jnp.float32)
         p = jax.nn.softmax(logits, axis=-1) if score == "softmax" else (
             jax.nn.sigmoid(logits))
-        if "router_bias" in mlp:
-            _, e = jax.lax.top_k(p + mlp["router_bias"].astype(jnp.float32), k)
-            w = jnp.take_along_axis(p, e, axis=-1)
-        else:
+        sel = p + mlp["router_bias"].astype(jnp.float32) if (
+            "router_bias" in mlp) else p
+        if n_group > 1:
+            sel = group_limited(sel, n_group, topk_group)
+        if sel is p:
             w, e = jax.lax.top_k(p, k)  # [T, k]
+        else:
+            _, e = jax.lax.top_k(sel, k)
+            w = jnp.take_along_axis(p, e, axis=-1)
         if norm_topk:
             w = w / jnp.sum(w, axis=-1, keepdims=True)
         if route_scale != 1.0:
             w = w * route_scale
         # An invalid row's assignments take group id E: past every real
         # group in the sort, in no group's count.
-        e = jnp.where(valid[:, None], e, n_exp).reshape(-1)
-        order = jnp.argsort(e)  # stable: assignment i of token i // k
+        e = jnp.where(valid[:, None], e, n_pub).reshape(-1)
         counts = jnp.sum(
-            e[:, None] == jnp.arange(n_exp, dtype=e.dtype)[None, :], axis=0,
+            e[:, None] == jnp.arange(n_pub, dtype=e.dtype)[None, :], axis=0,
             dtype=jnp.int32,
         )
+        sizes = counts
+        if n_exp != n_pub:
+            # A chip's share: an assignment to an expert held elsewhere
+            # joins no group here either (counted above, computed nowhere).
+            held = (e >= expert_first) & (e < expert_first + n_exp)
+            e = jnp.where(held, e - expert_first, n_exp)
+            sizes = counts[expert_first:expert_first + n_exp]
+        order = jnp.argsort(e)  # stable: assignment i of token i // k
         xs = jnp.take(h, order // k, axis=0)  # [T*k, D], sorted by expert
     with jax.named_scope("moe_experts"):
         mm = functools.partial(grouped_matmul, interpret=interpret)
-        act = jax.nn.silu(mm(xs, gate, counts)) * mm(xs, up, counts)
-        ys = mm(act, down, counts)  # [T*k, D]
+        act = jax.nn.silu(mm(xs, gate, sizes)) * mm(xs, up, sizes)
+        ys = mm(act, down, sizes)  # [T*k, D]
     with jax.named_scope("moe_combine"):
-        in_group = jnp.arange(t * k) < jnp.sum(counts)
+        in_group = jnp.arange(t * k) < jnp.sum(sizes)
         ys = jnp.where(in_group[:, None], ys, 0)
         back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(t, k, d)
         out = jnp.sum(back.astype(jnp.float32) * w[:, :, None], axis=1)

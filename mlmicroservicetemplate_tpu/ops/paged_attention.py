@@ -36,6 +36,11 @@ This module is the device-side half:
   ``1/D`` of the bytes) ride as the rows' gathered ``[B, T*BS, KVH]``.
   ``interpret=True`` runs the same kernel on CPU (the test/fallback
   path, same pattern as ``parallel/ring.py``).
+- ``latent_decode_attention``: the same kernel body over a LATENT pool
+  (multi-head latent attention, ``models/llama.py`` ``attention="mla"``):
+  one leaf a layer, ``C`` = the latent row's lanes, one KV head that
+  every query head shares; a block is copied once and folded as keys
+  (all lanes) and values (its first ``v_dim`` lanes).
 
 **The kernel's work follows each row's live keys** (PR 32), not the
 table's width.  A serving table is mostly not keys: the loop pads a
@@ -410,7 +415,7 @@ def live_programs(table: jax.Array, key_valid: jax.Array, nb_pool: int,
 
 
 def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
-                    quant: bool, var: Variant, bs: int):
+                    quant: bool, var: Variant, bs: int, latent: int = 0):
     """Grid step b: fold row b's live programs (``live_programs``) into
     its accumulators, K blocks ``table[b, j*K .. j*K+K-1]`` a trip of a
     ``fori_loop`` whose trip count is the row's own, then finalize.  The
@@ -426,17 +431,23 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     space), when quant the row's k and v scales [1, T*BS, KVH], the row's
     mask [1, T/K, K*BS], the output (shaped like q), then m/l/acc
     scratch, the k and v slots [2, K*BS, KVH*D] and the DMA semaphores
-    [2]."""
+    [2].  ``latent`` (``latent_decode_attention``): ONE pool and one slot
+    pair — no v pool and no v slot among the refs; a block is copied
+    once and its first ``latent`` lanes are the fold's values."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     K = var.blocks_per_step
     it = iter(refs)
-    tbl_ref, live_ref, q_ref, k_hbm, v_hbm = (next(it) for _ in range(5))
+    tbl_ref, live_ref, q_ref, k_hbm = (next(it) for _ in range(4))
+    v_hbm = None if latent else next(it)
     ks_ref, vs_ref = (next(it), next(it)) if quant else (None, None)
     valid_ref, o_ref = next(it), next(it)
     m_scr, l_scr, a_scr = next(it), next(it), next(it)
-    kbuf, vbuf, sem = next(it), next(it), next(it)
+    kbuf = next(it)
+    vbuf = None if latent else next(it)
+    sem = next(it)
+    pools = ((k_hbm, kbuf),) if latent else ((k_hbm, kbuf), (v_hbm, vbuf))
 
     i, rows = pl.program_id(0), pl.num_programs(0)
     first, base = live_ref[i, 0], live_ref[i, 2]
@@ -448,7 +459,7 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
                 pool.at[tbl_ref[row, j * K + m]],
                 buf.at[slot, pl.ds(m * bs, bs)], sem.at[slot],
             )
-            for m in range(K) for pool, buf in ((k_hbm, kbuf), (v_hbm, vbuf))
+            for m in range(K) for pool, buf in pools
         ]
 
     def is_live(row):
@@ -487,7 +498,9 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
             ks_blk = ks_ref[0, keys, :].astype(jnp.float32)
             vs_blk = vs_ref[0, keys, :].astype(jnp.float32)
         valid = valid_ref[0, pl.ds(j, 1), :]  # [1, K*BS]
-        _fold_block(q_ref, kbuf[slot], ks_blk, vbuf[slot], vs_blk, valid,
+        k_blk = kbuf[slot]
+        v_blk = k_blk[:, :latent] if latent else vbuf[slot]
+        _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid,
                     m_scr, l_scr, a_scr, scale=scale, kvh=kvh, n_rep=n_rep,
                     d=d, var=var)
         return carry
@@ -496,6 +509,30 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     acc = a_scr[...].astype(jnp.float32)
     l = l_scr[...].astype(jnp.float32)
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
+
+
+def _row_spec(shape):
+    """BlockSpec of one row's whole ``[1, ...]`` slice of a ``[B, ...]``
+    array under the kernels' grid ``(B,)`` and two prefetch operands."""
+    from jax.experimental import pallas as pl
+
+    zeros = (0,) * (len(shape) - 1)
+    return pl.BlockSpec((1, *shape[1:]), lambda i, tb, lv: (i, *zeros))
+
+
+def _table_operands(table, key_valid, nb_pool: int, bs: int, K: int):
+    """``(tbl, live, validb)`` of a kernel call: the table clamped for the
+    copies' lookups, the rows' ``(first, last, base)`` live ranges (``base``
+    = the trips of the rows before: the slot parity), and the rows' masks
+    ``[B, T/K, K*BS]`` — trip j reads sublane j."""
+    b, t = table.shape
+    live = live_programs(table, key_valid, nb_pool, bs, K)
+    trips = live[:, 1] - live[:, 0] + 1
+    live = jnp.concatenate(
+        [live, (jnp.cumsum(trips) - trips)[:, None]], axis=1
+    )
+    tbl = jnp.clip(table, 0, nb_pool - 1).astype(jnp.int32)
+    return tbl, live, key_valid.astype(jnp.int32).reshape(b, t // K, K * bs)
 
 
 def tp_shard_attention(
@@ -608,28 +645,17 @@ def paged_decode_attention(
             f"variant {var.key()!r}: blocks_per_step={K} does not divide "
             f"table width {t}"
         )
-    tsteps = t // K
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     quant = k_scale is not None
     acc_jnp = jnp.float32 if var.acc_dtype == "f32" else jnp.bfloat16
-    live = live_programs(table, key_valid, nb_pool, bs, K)
-    trips = live[:, 1] - live[:, 0] + 1
-    live = jnp.concatenate(  # + the trips of the rows before: slot parity
-        [live, (jnp.cumsum(trips) - trips)[:, None]], axis=1
-    )
-    tbl = jnp.clip(table, 0, nb_pool - 1).astype(jnp.int32)
-    # The row's mask rides whole, [1, T/K, K*BS]: trip j reads sublane j.
-    validb = key_valid.astype(jnp.int32).reshape(b, tsteps, K * bs)
+    tbl, live, validb = _table_operands(table, key_valid, nb_pool, bs, K)
     if var.head_batched:
         qk = head_batched_q(q, kvh)
     else:
         qk = q.reshape(b, kvh, n_rep, d)
 
-    def row_spec(shape):  # one row's whole [1, ...] slice of a [B, ...] array
-        zeros = (0,) * (len(shape) - 1)
-        return pl.BlockSpec((1, *shape[1:]), lambda i, tb, lv: (i, *zeros))
-
+    row_spec = _row_spec
     q_spec = row_spec(qk.shape)
     pool_spec = pl.BlockSpec(memory_space=pl.ANY)  # read where it lies
     kernel = functools.partial(
@@ -668,15 +694,92 @@ def paged_decode_attention(
     return out.reshape(b, h, d)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("block_size", "v_dim", "scale", "interpret", "variant"),
+)
+def latent_decode_attention(
+    q: jax.Array,  # [B, H, C] — one query per row, as it lies
+    pool: jax.Array,  # [NB, BS, C]: one latent row a token
+    table: jax.Array,  # [B, T] block ids, unclamped: out of range = no block
+    key_valid: jax.Array,  # [B, T*BS] 1 = attend
+    block_size: int,
+    v_dim: int,
+    scale: float,
+    interpret: bool = False,
+    variant: str = "",
+) -> jax.Array:
+    """Paged decode attention over a LATENT pool (multi-head latent
+    attention, absorbed form); returns ``[B, H, v_dim]``.
+
+    The cache holds one row a token, shared by every head: ``C`` lanes
+    (DeepSeek-V2: ``[c_kv (512) ; k_rope (64)]`` = 576) that are the KEY
+    of all ``H`` query rows, and whose first ``v_dim`` lanes are their
+    VALUE.  So there is one pool operand and one KV head: ``q`` is
+    ``[H, C]`` as it lies (``n_rep`` = H: nothing to make block-diagonal),
+    the program copies each live block ONCE into one slot pair and folds
+    it as keys (all ``C`` lanes: an ``[H, C] x [K*BS, C]`` score issue)
+    and as values (the tile's first ``v_dim`` lanes: ``[H, K*BS] x
+    [K*BS, v_dim]``) — ``paged_decode_attention`` called with the latent
+    as K and again as V would read every cached byte twice.  The row loop,
+    the live range (``live_programs``), the slot parity across rows, the
+    masked online softmax in f32 and the zeros of a row with no live key
+    are ``_paged_kernel_v``'s, unchanged.  ``scale`` has no default: the
+    score width is not the softmax scale's (DeepSeek-V2: 192^-1/2 x
+    YaRN's mscale^2).  ``variant``: ``b<K>[-nat]``; K divides T.  VMEM:
+    ``autotune.latent_vmem_bytes``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, c = q.shape
+    nb_pool, bs, _ = pool.shape
+    var = dataclasses.replace(parse_variant(variant), head_batched=True)
+    K = var.blocks_per_step
+    t = table.shape[1]
+    if t % K != 0:
+        raise ValueError(
+            f"variant {var.key()!r}: blocks_per_step={K} does not divide "
+            f"table width {t}"
+        )
+    tbl, live, validb = _table_operands(table, key_valid, nb_pool, bs, K)
+    row_spec = _row_spec
+    acc = jnp.float32 if var.acc_dtype == "f32" else jnp.bfloat16
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[row_spec(q.shape), pl.BlockSpec(memory_space=pl.ANY),
+                  row_spec(validb.shape)],
+        out_specs=row_spec((b, h, v_dim)),
+        scratch_shapes=[
+            *softmax_scratch((h, v_dim), acc),
+            pltpu.VMEM((2, K * bs, c), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _paged_kernel_v, scale=scale, kvh=1, n_rep=h, d=c, quant=False,
+            var=var, bs=bs, latent=v_dim,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_dim), q.dtype),
+        interpret=interpret,
+        name="latent_decode_attention",
+    )(tbl, live, q, pool, validb)
+
+
 def paged_attention_ref(
-    q: jax.Array, k_pool: jax.Array, v_pool: jax.Array, table: jax.Array,
-    key_valid: jax.Array, block_size: int,
+    q: jax.Array, k_pool: jax.Array, v_pool: jax.Array | None,
+    table: jax.Array, key_valid: jax.Array, block_size: int,
     k_scale: jax.Array | None = None, v_scale: jax.Array | None = None,
-    scale: float | None = None,
+    scale: float | None = None, v_dim: int = 0,
 ) -> jax.Array:
     """jnp reference for the kernel: gather the dense view, dequantize,
     and run masked softmax attention in f32.  Also the XLA serving
-    fallback shape the models reproduce inline."""
+    fallback shape the models reproduce inline.  With ``v_dim`` (and no
+    ``v_pool``) the reference of ``latent_decode_attention``: one pool,
+    one KV head, values = the first ``v_dim`` lanes of the keys; returns
+    ``[B, H, v_dim]``."""
     b, h, d = q.shape
     kvh = k_pool.shape[2] // d
     n_rep = h // kvh
@@ -686,7 +789,8 @@ def paged_attention_ref(
     def dense(pool, tail):
         return gather_pages(pool, table, block_size, tail).astype(jnp.float32)
 
-    kd, vd = dense(k_pool, (kvh, d)), dense(v_pool, (kvh, d))
+    kd = dense(k_pool, (kvh, d))
+    vd = kd[..., :v_dim] if v_dim else dense(v_pool, (kvh, d))
     if k_scale is not None:
         kd = kd * dense(k_scale, (kvh, 1))
         vd = vd * dense(v_scale, (kvh, 1))
@@ -695,7 +799,7 @@ def paged_attention_ref(
     s = jnp.where(key_valid[:, None, None, :] != 0, s, jnp.float32(-1e30))
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bgrt,btgd->bgrd", p, vd)
-    return o.reshape(b, h, d).astype(q.dtype)
+    return o.reshape(b, h, vd.shape[-1]).astype(q.dtype)
 
 
 def pool_relayouts(hlo_text: str, pool_elems, in_loop_only: bool = False) -> list:

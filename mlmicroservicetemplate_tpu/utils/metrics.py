@@ -156,11 +156,25 @@ MOE_ASSIGNMENTS = Counter(
     "experts_per_token x steps)",
     ["model"],
 )
+MOE_ASSIGNMENTS_HELD = Counter(
+    "moe_assignments_held_total",
+    "Expert FFN: the assignments of moe_assignments_total that landed on "
+    "an expert this tree holds (all of them unless the model holds a "
+    "chip's share of its experts: LLAMA_CONFIG experts_held)",
+    ["model"],
+)
+MOE_ASSIGNMENTS_ABSENT = Counter(
+    "moe_assignments_absent_total",
+    "Expert FFN: the assignments that landed on an expert held elsewhere "
+    "— counted, computed nowhere: what an expert-parallel exchange would "
+    "carry off this chip",
+    ["model"],
+)
 MOE_EXPERTS_HIT = Gauge(
     "moe_experts_hit",
-    "Expert FFN: distinct experts of a layer with at least one "
-    "assignment in the last delivered paged decode chunk, a mean over "
-    "the layers",
+    "Expert FFN: distinct experts of a layer (of those this tree holds) "
+    "with at least one assignment in the last delivered paged decode "
+    "chunk, a mean over the layers",
     ["model"],
 )
 KV_WINDOW_KEYS_READ = Counter(
@@ -175,6 +189,13 @@ KV_WINDOW_KEYS_BEHIND = Counter(
     "Window attention: keys of live context BEHIND the window that the "
     "window layers did not read (context - window a stream a step a "
     "window layer): what the table view saves over walking the table",
+    ["model"],
+)
+KV_LATENT_KEYS_READ = Counter(
+    "kv_latent_keys_read_total",
+    "Paged decode over a latent cache (attention='mla'): cached latent "
+    "rows read in dispatched paged decode chunks (a stream's context a "
+    "step a layer, each row once; from the host's stream lengths)",
     ["model"],
 )
 KV_TABLE_BLOCKS_LIVE = Counter(

@@ -156,3 +156,98 @@ def test_table_blocks_dead_pct_reads_the_programs_counters():
     args = ("kv_table_blocks_dead", ["kv_table_blocks_live"])
     assert prom_counter_ratio.read(ctx(after, base), *args) == 75.0
     assert prom_counter_ratio.read(ctx({}, {}), *args) is None
+
+
+# ---------------------------------------------------------------------------
+# deepseek-v2-ep4-d5 (PR 33): the configuration, the cell and its sixteen
+# per-layer entries, held by NAME (cellbench/tests/*::test_entries_resolve_by_name
+# want older lists to be the tail of theirs)
+
+DSV2_CELL = "deepseek-v2-ep4-d5.longdoc-closed"
+DSV2_ENTRIES = [
+    ("decode_step_ms.dsv2", "ms", "device_trace", "model step", "trace_module_ms"),
+    ("decode_step_roofline.dsv2", "%", "device_trace", "model step", "mla_roofline"),
+    ("decode_attn_latent_ms.dsv2", "ms", "device_trace", "model step", "trace_scope_ms"),
+    ("mla_absorb_ms.dsv2", "ms", "device_trace", "model step", "trace_subscope_ms"),
+    ("mla_proj_ms.dsv2", "ms", "device_trace", "model step", "trace_scope_ms"),
+    ("latent_decode_attention_roofline.dsv2", "%", "device_trace", "kernels", "mla_roofline"),
+    ("decode_moe_ms.dsv2", "ms", "device_trace", "model step", "trace_scope_ms"),
+    ("moe_experts_roofline.dsv2", "%", "device_trace", "kernels", "mla_roofline"),
+    ("moe_overhead_ms.dsv2", "ms", "device_trace", "model step", "trace_subscope_ms"),
+    ("moe_shared_ms.dsv2", "ms", "device_trace", "model step", "trace_subscope_ms"),
+    ("moe_held_share_pct.dsv2", "%", "program_counter", "model step", "prom_counter_ratio"),
+    ("moe_imbalance.dsv2", "ratio", "program_counter", "engine", "prom_hist"),
+    ("table_blocks_dead_pct.dsv2", "%", "program_counter", "kernels", "prom_counter_ratio"),
+    ("streams_per_chunk.dsv2", "streams", "program_counter", "engine", "prom_hist"),
+    ("prefill_stall_ms.dsv2", "ms/s", "program_counter", "engine", "prom_counter_rate"),
+    ("device_idle_pct.dsv2", "%", "device_trace", "device", "trace_idle_pct"),
+]
+
+
+def test_dsv2_configuration_and_cell_are_in_the_benchmark():
+    from cellbench import spec
+
+    bench = spec.load_benchmark()
+    (cfg,) = [c for c in bench["configs"] if c["name"] == "deepseek-v2-ep4-d5"]
+    assert cfg["file"] == "cellbench/configs/deepseek-v2-ep4-d5.json"
+    assert cfg["source"] == (
+        "https://huggingface.co/deepseek-ai/DeepSeek-V2/blob/main/config.json")
+    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    (cell,) = [w for w in bench["workloads"] if w["name"] == DSV2_CELL]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "deepseek-v2-ep4-d5", "longdoc-closed", 1)
+    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
+    on = [m["name"] for m in bench["end_to_end"]
+          if "workloads" not in m or DSV2_CELL in m["workloads"]]
+    assert on == ["tbt_p99_ms", "setup_s"]
+    # PR 32's lists keep the cells they had (this cell has an entry of its own)
+    for m in bench["per_layer"]:
+        if m["name"].startswith("table_blocks_dead_pct.") and not m["name"].endswith("dsv2"):
+            assert DSV2_CELL not in m["workloads"]
+
+
+@pytest.mark.parametrize("name,unit,source,layer,reader", DSV2_ENTRIES)
+def test_dsv2_per_layer_entry_resolves(name, unit, source, layer, reader):
+    from cellbench import spec
+
+    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": unit,
+        "better": entry["better"], "source": source, "layer": layer,
+        "moves": "tbt_p99_ms", "workloads": [DSV2_CELL]}
+    assert entry["better"] in ("lower", "higher")
+    (resolved,) = [m for m in spec.resolve(DSV2_CELL).per_layer if m.name == name]
+    assert resolved.reader == reader and callable(resolved.read)
+
+
+def test_dsv2_held_share_reads_the_programs_counters():
+    """``moe_held_share_pct.dsv2`` through the reader its entry names, off
+    the families as ``/metrics`` exports them; a program without them (the
+    parent) reads no value."""
+    import types
+
+    from cellbench import spec
+    from cellbench.readers import prom_counter_ratio
+    from mlmicroservicetemplate_tpu.utils import metrics
+    from prometheus_client import generate_latest
+
+    metrics.MOE_ASSIGNMENTS_HELD.labels("reader-unit").inc(30)
+    metrics.MOE_ASSIGNMENTS_ABSENT.labels("reader-unit").inc(90)
+    metrics.KV_LATENT_KEYS_READ.labels("reader-unit").inc(7)
+    after = parse_prom(generate_latest().decode())
+    assert after["kv_latent_keys_read"]["value"] >= 7.0
+    base = {f: dict(after[f], value=after[f]["value"] - v) for f, v in
+            (("moe_assignments_held", 30.0), ("moe_assignments_absent", 90.0))}
+
+    def ctx(after, before):
+        return types.SimpleNamespace(
+            notes={}, prom_delta=lambda fam: (
+                None if fam not in after
+                else hist_delta(after[fam], before.get(fam))))
+
+    (m,) = [m for m in spec.resolve(DSV2_CELL).per_layer
+            if m.name == "moe_held_share_pct.dsv2"]
+    assert m.args == {"part": "moe_assignments_held",
+                      "rest": ["moe_assignments_absent"]}
+    assert prom_counter_ratio.read(ctx(after, base), **m.args) == 25.0
+    assert prom_counter_ratio.read(ctx({}, {}), **m.args) is None

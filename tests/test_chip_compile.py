@@ -562,3 +562,46 @@ def test_compiler_takes_the_slab_kernel_wherever_the_gate_says_fits(
         chip((64, t), jnp.int32),
     )
     assert "tpu_custom_call" in text
+
+
+# ---------------------------------------------------------------------------
+# The latent kernel (PR 33) at the DeepSeek-V2 cell's shapes: 32 slots, 128
+# query rows a key, a 392-entry table, blocks of 16 tokens.
+
+
+def _latent_text(chip, lanes: int, variant: str) -> str:
+    from mlmicroservicetemplate_tpu.ops.paged_attention import latent_decode_attention
+
+    b, h, bs, t = 32, 128, 16, 392
+    return _compiled_text(
+        chip, ("latent", lanes, variant),
+        lambda q, p, tb, m: latent_decode_attention(
+            q, p, tb, m, bs, 512, 0.1147, variant=variant),
+        chip((b, h, lanes), jnp.bfloat16), chip((b * t, bs, lanes), jnp.bfloat16),
+        chip((b, t), jnp.int32), chip((b, t * bs), jnp.int32),
+    )
+
+
+def test_every_enumerated_latent_variant_compiles(chip):
+    """Each fold the sweep would time at the cell's shapes, within the
+    cell's VMEM budget by the model and by the chip's compiler alike; no
+    pool-sized copy beside the kernel."""
+    from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
+
+    cands = autotune.enumerate_variants(
+        "latent_decode", t=392, bs=16, kvh=1, d=640, n_rep=128,
+        dtype="bfloat16", quant=False, budget=12 << 20)
+    assert {v.blocks_per_step for v in cands} == {4, 8, 14, 28, 56}
+    assert not any(v.head_batched for v in cands)  # one KV head: no such axis
+    for var in cands:
+        text = _latent_text(chip, 640, var.key())
+        assert "tpu_custom_call" in text, var.key()
+        assert pool_relayouts(text, [32 * 392 * 16 * 640]) == [], var.key()
+
+
+def test_a_576_lane_latent_pool_is_refused_by_the_chips_compiler(chip):
+    """Why the pool is 640 lanes wide (``LlamaConfig.latent_lanes``): the
+    chip lays 576 lanes out as 640 in HBM and Mosaic will not slice a
+    block out of it at 576."""
+    with pytest.raises(Exception, match="aligned to tiling"):
+        _latent_text(chip, 576, "b8-nat")
