@@ -2788,6 +2788,7 @@ class ContinuousDecodeLoop:
                     )
                 if self.admission is not None:
                     self.admission.note_pool()
+                self._note_prefill_tiles(start, end)
             else:
                 jparams = self._mp(rows=[job.st.adapter_slot])
                 with eng._lock:
@@ -4657,6 +4658,26 @@ class ContinuousDecodeLoop:
             # One successfully fetched-and-routed dispatch closes the
             # replica's breaker fault streak (engine/fleet.py).
             self.on_ok()
+
+    def _note_prefill_tiles(self, start: int, end: int) -> None:
+        """(Query tile, key tile) pairs of the paged prompt window just
+        dispatched that the prompt-window kernel runs, and the rest of
+        its queries x gathered keys rectangles that it never does — from
+        the window's own numbers, the way the step lays them out
+        (models/llama.prefill_tile_counts); nothing where the window
+        runs in XLA."""
+        bcfg = getattr(self.engine.bundle, "cfg", None)
+        if not (getattr(bcfg, "pallas_decode", False)
+                and hasattr(bcfg, "layer_kind")):
+            return
+        from ..models.llama import prefill_tile_counts
+
+        live, total = prefill_tile_counts(
+            bcfg, self.prefill_chunk, self.nb_max, self.block_size,
+            start, end - start)
+        name = self.engine.bundle.name
+        metrics.PREFILL_KEY_TILES_LIVE.labels(name).inc(live)
+        metrics.PREFILL_KEY_TILES_DEAD.labels(name).inc(total - live)
 
     def _note_window_keys(self, steps: int) -> None:
         """Keys the window layers read in the chunk just dispatched, and

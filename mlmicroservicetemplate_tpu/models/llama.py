@@ -32,7 +32,6 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-import functools
 from typing import Any
 
 import jax
@@ -703,12 +702,17 @@ def _latent_row(cfg: "LlamaConfig", c, kr):
     return jnp.concatenate([c, kr] + zeros, axis=-1)
 
 
-def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask):
+def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask,
+                            window_at=None):
     """Expanded attention of q = (qn [B, Sq, H, nope], qr [B, Sq, H, rope])
-    over the keys ``latent`` [B, Sk, lanes] under ``mask`` [B, 1, Sq, Sk]
-    -> [B, Sq, H, v]: the prefill form, ``MLA_HEAD_BLOCK`` heads at a
-    time — a block expands its heads' keys and values from the latents
-    (``mla_expand``), scores them in float32 and weighs its values."""
+    over the keys ``latent`` [B, Sk, lanes] -> [B, Sq, H, v]: the prefill
+    form, ``MLA_HEAD_BLOCK`` heads at a time — a block expands its heads'
+    keys and values from the latents (``mla_expand``), scores them in
+    float32 and weighs its values: in XLA under ``mask`` [B, 1, Sq, Sk]
+    (a prefill wave), or, for one prompt window (``window_at`` =
+    ``(kpos0, start, chunk_mask [C])``, B = 1, no ``mask``), through the
+    prompt-window kernel with the expanded heads as its KV heads — a
+    head's key is its 128 nope dims beside the token's one rotary key."""
     a, r = layer["attn"], cfg.kv_lora_rank
     qn, qr = q
     b, sq, hn, dn = qn.shape
@@ -721,8 +725,36 @@ def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask):
         shape = x.shape[:axis] + (nblk, hb) + x.shape[axis + 1:]
         return jnp.moveaxis(x.reshape(shape), axis, 0)
 
+    def window_block(qn_h, qr_h, kb, vb):
+        """A head block through the prompt-window kernel.  Keys and values
+        are built 2-D and lane-dense, ``[K, hb * lanes]`` as the kernel
+        reads them — a head's ``nope`` dims, the token's rotary key, zero
+        lanes up to a multiple of 128 (192 -> 256: the MXU contracts 256
+        in the passes 192 take): on the chip a ``[K, hb, lanes]`` array is
+        tiled over (head, lane) and merging its trailing dims is a
+        relayout of every byte (51 MB a block, PERF.md section 6, PR 34)."""
+        from ..ops.prefill_attention import prefill_attention
+
+        k_len, dr, dv = c.shape[1], kr.shape[-1], cfg.v_head_dim
+        pad = -(dn + dr) % 128
+        with jax.named_scope("mla_expand"):
+            kn = jnp.einsum("kr,mr->km", c[0], kb.reshape(hb * dn, r).astype(c.dtype))
+            v = jnp.einsum("kr,mr->km", c[0], jnp.swapaxes(vb, 1, 2).reshape(
+                hb * dv, r).astype(c.dtype))
+            kr_p = jnp.pad(kr[0], ((0, 0), (0, pad)))
+            k = jnp.concatenate(
+                [x for h in range(hb) for x in (kn[:, h * dn:(h + 1) * dn], kr_p)],
+                axis=1)
+        qh = jnp.pad(jnp.concatenate([qn_h[0], qr_h[0]], axis=-1),
+                     ((0, 0), (0, 0), (0, pad)))
+        return prefill_attention(
+            qh, k.reshape(k_len, hb, -1), v.reshape(k_len, hb, dv), *window_at,
+            scale=cfg.attn_scale, interpret=cfg.pallas_interpret)[None]
+
     def block(args):
         qn_h, qr_h, kb, vb = args
+        if window_at is not None:
+            return window_block(qn_h, qr_h, kb, vb)
         with jax.named_scope("mla_expand"):
             kn = jnp.einsum("bkr,hnr->bkhn", c, kb.astype(c.dtype))
             v = jnp.einsum("bkr,hrv->bkhv", c, vb.astype(c.dtype))
@@ -1525,11 +1557,6 @@ def _paged_scatter_entry(cache, table_row, vals, bs: int, start, dtype):
     return scatter_pages(cache, table_row, vals, bs, start=start)
 
 
-#: A full layer's prompt window attends over the narrowest of at most this
-#: many widths of the table that holds its keys (``paged_prefill_chunk``).
-PREFILL_KEY_WIDTHS = 8
-
-
 def _prefill_mask(kpos, chunk_mask, start, window: int):
     """[B, 1, C, K] attention mask of one prompt window over the keys at
     positions ``kpos`` [K]: every position before the window (``start``,
@@ -1551,6 +1578,43 @@ def _prefill_mask(kpos, chunk_mask, start, window: int):
     return mask[:, None]
 
 
+def prefill_key_blocks(c: int, t_w: int, bs: int, start, window: int):
+    """``(first, n)``: the table entries a prompt window of ``c`` queries
+    from position ``start`` attends over — a full layer the row's whole
+    table (the kernel's key loop stops at the last live key tile), a
+    window layer the ``n`` entries (static) that can hold the
+    ``c + window - 1`` keys ending with the window's last query.
+    ``start`` traced (the step) or an int (the host's counters)."""
+    if not window:
+        return 0, t_w
+    n = min(t_w, -(-(c + window - 1) // bs) + 1)
+    lo = (start - window + 1) // bs
+    if isinstance(start, jax.Array):
+        return jnp.clip(lo, 0, t_w - n), n
+    return min(max(int(lo), 0), t_w - n), n
+
+
+def prefill_tile_counts(cfg: LlamaConfig, c: int, t_w: int, bs: int,
+                        start: int, n_valid: int) -> tuple[int, int]:
+    """``(live, total)`` (q tile, key tile) pairs of the prompt-window
+    kernel for one window of ``n_valid`` real tokens at ``start``, a KV
+    head of every layer together: what ``paged_prefill_chunk`` hands
+    ``ops/prefill_attention`` (``(0, 0)`` where the window runs in XLA)."""
+    from ..ops.prefill_attention import count_live_tiles, tile_sizes
+
+    if not cfg.pallas_decode or cfg.kv_quant:
+        return 0, 0
+    live = total = 0
+    for li in range(cfg.num_layers):
+        window = cfg.layer_kind(li).window
+        first, n = prefill_key_blocks(c, t_w, bs, start, window)
+        tq, tk = tile_sizes(c, 1 if cfg.mla else cfg.n_rep, n * bs)
+        lv, tot = count_live_tiles(
+            start, n_valid, c, first * bs, n * bs, window, tq, tk)
+        live, total = live + lv, total + tot
+    return live, total
+
+
 def paged_prefill_chunk(
     params: Params,
     cfg: LlamaConfig,
@@ -1563,14 +1627,17 @@ def paged_prefill_chunk(
 ):
     """One prompt window straight into pool blocks (see
     ``gpt.paged_prefill_chunk``), at GQA width and composed with the
-    int8 pool pairs.  The window's queries attend over the part of the
-    row that can hold their keys, not the whole table (a [H, C, T*BS]
-    float32 score tensor at every layer otherwise: 0.8 GB a layer at
-    C = 1024 under a 6272-token table): a window layer over the
-    ``C + window`` keys that end with the window's last query, a full
-    layer over the narrowest of ``PREFILL_KEY_WIDTHS`` prefixes of the
-    row that reaches it (one ``lax.switch``; ``start`` stays traced and
-    one executable serves every window of every prompt)."""
+    int8 pool pairs.  The window's queries attend over the row's gathered
+    keys (``prefill_key_blocks``) through the prompt-window kernel
+    (``ops/prefill_attention``: tile by tile, the scores never in HBM, a
+    tile no query sees never run) wherever the decode step runs its
+    kernels (``cfg.pallas_decode``); ``start`` stays traced and one
+    executable serves every window of every prompt.  Without the kernels,
+    and over an int8 pool, the same keys in XLA under ``_prefill_mask``
+    ([H, C, K] float32 scores: the tests' reference, no served path on
+    the chip)."""
+    from ..ops.paged_attention import gather_pages
+
     b, c = chunk_ids.shape  # b == 1
     entry = state.cache_k[0]
     bs = entry[0].shape[1] if isinstance(entry, tuple) else entry.shape[1]
@@ -1579,31 +1646,32 @@ def paged_prefill_chunk(
     cos, sin = _rope_tables(cfg, jnp.minimum(pos_w, cfg.max_position - 1), dtype)
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
     t_w = table_row.shape[0]
+    kernel = cfg.pallas_decode and not isinstance(entry, tuple)
 
-    def attend(q, ck, cv, first, n_blocks: int, window: int, layer=None):
-        """Over ``n_blocks`` (static) table entries from ``first`` on."""
-        rows = jax.lax.dynamic_slice_in_dim(table_row, first, n_blocks)
-        kpos = first * bs + jnp.arange(n_blocks * bs)
-        mask = _prefill_mask(kpos, chunk_mask, start, window)
+    def attend(layer, q, ck, cv, window: int):
+        first, n = prefill_key_blocks(c, t_w, bs, start, window)
+        rows = jax.lax.dynamic_slice_in_dim(table_row, first, n)[None]
+        window_at = (first * bs, start, chunk_mask[0])
+        mask = None if kernel else _prefill_mask(
+            first * bs + jnp.arange(n * bs), chunk_mask, start, window)
         if cfg.mla:
             # The row's latents, earlier windows' and this one's, as the
             # pool holds them, expanded again here: 0.017 GFLOP a key a
             # layer against 196 kFLOP more a query-key pair scored
             # absorbed — at 1024 queries a window expansion is the cheaper
             # by 6x (PERF.md section 6, PR 33).
-            from ..ops.paged_attention import gather_pages
-
             return _mla_expanded_attention(
-                cfg, layer, q, gather_pages(ck, rows[None], bs), mask)
-        return _gathered_attention(cfg, q, ck, cv, rows[None], bs, mask)
+                cfg, layer, q, gather_pages(ck, rows, bs), mask,
+                window_at if kernel else None)
+        if not kernel:
+            return _gathered_attention(cfg, q, ck, cv, rows, bs, mask)
+        from ..ops.prefill_attention import prefill_attention
 
-    # Full layers: keys 0 .. start + C - 1, in prefixes of whole steps.
-    step = -(-max(c, -(-t_w * bs // PREFILL_KEY_WIDTHS)) // bs)  # blocks
-    widths = sorted({min(t_w, k * step) for k in range(1, -(-t_w // step) + 1)})
-    reach = jnp.minimum((start + c - 1) // (step * bs), len(widths) - 1)
-    # Window layers: keys start - window + 1 .. start + C - 1.
-    n_win = min(t_w, -(-(c + cfg.window - 1) // bs) + 1)
-    first_win = jnp.clip((start - cfg.window + 1) // bs, 0, t_w - n_win)
+        tail = (cfg.num_kv_heads, cfg.head_dim)
+        return prefill_attention(
+            q[0], gather_pages(ck, rows, bs, tail)[0],
+            gather_pages(cv, rows, bs, tail)[0], *window_at, window=window,
+            interpret=cfg.pallas_interpret)[None]
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
@@ -1617,18 +1685,7 @@ def paged_prefill_chunk(
                 new_v.append(cv)
         new_k.append(ck)
         with _attn_scope(cfg, li):
-            if cfg.layer_kind(li).window:
-                ctx = attend(q, ck, cv, first_win, n_win, cfg.window)
-            elif len(widths) == 1:
-                ctx = attend(q, ck, cv, 0, t_w, 0, layer=layer)
-            else:
-                ctx = jax.lax.switch(
-                    reach,
-                    [functools.partial(attend, first=0, n_blocks=w, window=0,
-                                       layer=layer)
-                     for w in widths],
-                    q, ck, cv,
-                )
+            ctx = attend(layer, q, ck, cv, cfg.layer_kind(li).window)
         x = _attn_out(cfg, layer, ad, li, x, ctx, g)
         x = _mlp_block(cfg, layer, li, x, chunk_mask != 0)
     return state._replace(cache_k=new_k, cache_v=new_v)

@@ -214,6 +214,23 @@ KV_TABLE_BLOCKS_DEAD = Counter(
     "of the table's width that the kernel's live bounds never run",
     ["model"],
 )
+PREFILL_KEY_TILES_LIVE = Counter(
+    "prefill_key_tiles_live_total",
+    "Chunked paged prefill through the prompt-window kernel: (query "
+    "tile, key tile) pairs that held a key some query of the tile sees, "
+    "a KV head of every layer of a dispatched window together (from the "
+    "window's start, its real tokens and the layers' kinds): what the "
+    "kernel's key loop runs",
+    ["model"],
+)
+PREFILL_KEY_TILES_DEAD = Counter(
+    "prefill_key_tiles_dead_total",
+    "Chunked paged prefill: the other pairs of the same windows' "
+    "queries x gathered keys rectangles — above the diagonal, behind a "
+    "window layer's band, past the window's last real token: dead / "
+    "(live + dead) is the share of the rectangle the kernel never runs",
+    ["model"],
+)
 DECODE_STEPS = Histogram(
     "seq2seq_decode_steps",
     "Decode steps executed per non-streaming seq2seq dispatch "

@@ -251,3 +251,48 @@ def test_dsv2_held_share_reads_the_programs_counters():
                       "rest": ["moe_assignments_absent"]}
     assert prom_counter_ratio.read(ctx(after, base), **m.args) == 25.0
     assert prom_counter_ratio.read(ctx({}, {}), **m.args) is None
+
+
+# ---------------------------------------------------------------------------
+# prefill_window_ms.* (PR 34): two entries, data files only, read by the
+# reader the benchmark has, off the prompt-window executable's own name
+
+
+@pytest.mark.parametrize("name,cell", [
+    ("prefill_window_ms.trinity", "trinity-mini-d5.longdoc-closed"),
+    ("prefill_window_ms.dsv2", DSV2_CELL),
+])
+def test_prefill_window_ms_resolves_in_its_cell(name, cell):
+    from cellbench import spec
+
+    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": "ms", "better": "lower", "source": "device_trace",
+        "layer": "model step", "moves": "tbt_p99_ms", "workloads": [cell]}
+    (resolved,) = [m for m in spec.resolve(cell).per_layer if m.name == name]
+    assert resolved.reader == "trace_module_ms" and callable(resolved.read)
+    assert resolved.args == {"module": "jit_paged_prefill_chunk_fn", "per": "run"}
+
+
+def test_prefill_window_ms_is_a_window_executables_mean_time():
+    """Through the reader: the device seconds of the runs of the module
+    the loop jits ``bundle.paged_prefill_chunk_fn`` as, a run at a time;
+    a trace without such a module (no chunked prefill) reads no value."""
+    import types
+
+    from cellbench.readers import trace_module_ms
+    from mlmicroservicetemplate_tpu.models import registry
+
+    src = open(registry.__file__).read()
+    assert "def paged_prefill_chunk_fn(" in src  # the name the module carries
+
+    def ctx(table):
+        return types.SimpleNamespace(
+            notes={}, engine={"chunk_tokens": 4},
+            trace=types.SimpleNamespace(
+                module_time=lambda pat: table.get(pat, (0.0, 0))))
+
+    args = {"module": "jit_paged_prefill_chunk_fn", "per": "run"}
+    assert trace_module_ms.read(
+        ctx({"jit_paged_prefill_chunk_fn": (0.30, 20)}), **args) == pytest.approx(15.0)
+    assert trace_module_ms.read(ctx({}), **args) is None

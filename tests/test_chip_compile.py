@@ -383,7 +383,24 @@ _CELLS["trinity"] = (
          router_score="sigmoid", norm_topk_prob=True, route_scale=2.826,
          router_bias=True, qk_norm="head", sandwich_norm=True, attn_gate=True,
          nope_on_full=True, mup_embed=True, add_bos=False), 12573, 392, 128)
-_SLOTS = {"trinity": 32}  # MAX_STREAMS where it is not 64
+_SLOTS = {"trinity": 32, "dsv2": 32}  # MAX_STREAMS where it is not 64
+#: PREFILL_CHUNK of the cells that set one (a prompt window's queries).
+_WINDOWS = {"trinity": 1024, "dsv2": 2048}
+
+
+def _cell_config(cell: str) -> tuple:
+    """A cell's ``_CELLS`` entry; DeepSeek-V2's from the benchmark's own
+    configuration file (32 slots x 392 table entries of 16 latent rows)."""
+    if cell != "dsv2":
+        return _CELLS[cell]
+    import json
+
+    from cellbench import spec as bench_spec
+
+    config = bench_spec.load_json(
+        bench_spec.HERE + "/configs/deepseek-v2-ep4-d5.json")
+    over = json.loads(bench_spec.service_env(config)["LLAMA_CONFIG"])
+    return over, 32 * 392, 392, 128
 
 
 def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
@@ -399,10 +416,12 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
     from mlmicroservicetemplate_tpu.models.gpt import PagedState
     from mlmicroservicetemplate_tpu.models.sampling import greedy_params
 
-    over, nb, t, s_max = _CELLS[cell]
+    over, nb, t, s_max = _cell_config(cell)
     cfg = LlamaConfig(**over, pallas_decode=True, pallas_variant="b4-hb")
     b, bs, dt, budget, steps = _SLOTS.get(cell, 64), 16, jnp.bfloat16, 192, 4
     c, kvh = cfg.num_kv_heads * cfg.head_dim, cfg.num_kv_heads
+    if cfg.mla:  # one latent pool a layer, no V
+        c = cfg.latent_lanes
     counts = (nb * bs * c, nb * bs * kvh)
     key = ("serving", cell, what, donate)
     if key in chip.memo:
@@ -419,7 +438,7 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
     def batched():
         return PagedState(
             cache_k=[pool() for _ in range(cfg.num_layers)],
-            cache_v=[pool() for _ in range(cfg.num_layers)],
+            cache_v=[] if cfg.mla else [pool() for _ in range(cfg.num_layers)],
             key_valid=jnp.zeros((b, t * bs), jnp.int32),
             write_idx=jnp.zeros((b,), jnp.int32),
             pos=jnp.zeros((b,), jnp.int32),
@@ -432,13 +451,20 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
     params = on_chip(jax.eval_shape(
         lambda: llama_mod.init_params(jax.random.PRNGKey(0), cfg, dtype=dt)))
     state = on_chip(jax.eval_shape(batched))
-    donated = dict(donate_argnums=(1 if what == "chunk" else 0,)) if donate else {}
+    donated = dict(donate_argnums=(0 if what == "insert" else 1,)) if donate else {}
     if what == "chunk":
         lowered = jax.jit(
             chunk_with_done(lambda p, s, tb, n, sample: (
                 llama_mod.generate_chunk_paged(p, cfg, s, tb, n, sample))),
             static_argnums=(3, 4), **donated,
         ).lower(params, state, chip((b, t), jnp.int32), steps, False)
+    elif what == "prefill":  # registry.paged_prefill_chunk_fn, as _paged_prefill_fn jits it
+        w = _WINDOWS[cell]
+        lowered = jax.jit(
+            lambda p, s, row, ids, mask, start: llama_mod.paged_prefill_chunk(
+                p, cfg, s, row, ids, mask, start, dtype=dt), **donated,
+        ).lower(params, state, chip((t,), jnp.int32), chip((1, w), jnp.int32),
+                chip((1, w), jnp.int32), chip((), jnp.int32))
     else:
         ones = jnp.ones((1, s_max), jnp.int32)
         single = on_chip(jax.eval_shape(
@@ -488,6 +514,68 @@ def test_window_layers_run_the_kernel_at_their_views_width(chip):
     masks = [re.findall(r"s32\[32,(\d+),64\]", ln) for ln in calls]
     widths = sorted(int(m[0]) for m in masks if m)
     assert widths == [34, 34, 34, 34, 98], widths
+
+
+@pytest.mark.parametrize("cell,heads", [("trinity", 32), ("dsv2", 16)])
+def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads):
+    """The prompt-window executable at the cells' shapes (Trinity: 1024
+    queries, 32 heads, a window layer over 3088 gathered keys and the full
+    layer over the table's 6272; DeepSeek-V2: 2048 queries, 16 expanded
+    heads at a time over 6272) holds the prompt-window kernel at every
+    layer and NO float32 array of heads x queries x keys
+    (``prefill_scores_in_hbm: []``): until PR 34 XLA wrote and re-read one
+    a layer (0.4-0.8 GB each).  The ``lax.switch`` over key widths is
+    gone with it: one attention a layer, no ``conditional``."""
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
+
+    text, (payload_n, _) = _serving_program(chip, cell, "prefill")
+    w = _WINDOWS[cell]
+    assert scores_in_hbm(text, heads * w * w) == []
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "prefill_attention" in ln]
+    assert calls, "the prompt-window kernel is not in the executable"
+    assert " conditional(" not in text
+    assert "input_output_alias" in text
+    from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
+
+    assert not pool_relayouts(text, [payload_n])
+
+
+@pytest.mark.parametrize("h,kvh,d,dt", [
+    (H, KVH, D, jnp.bfloat16),  # the default widths: heads of 64 (half a lane tile)
+    (32, 8, 128, jnp.float32),  # Mistral's heads past a bucket, float32
+    (16, 16, 128, jnp.bfloat16),  # OLMoE's: a KV head a query head
+], ids=["d64", "f32", "n_rep1"])
+def test_prompt_window_kernel_compiles_at_other_widths(chip, h, kvh, d, dt):
+    """Any Llama-shaped deployment with ``PREFILL_CHUNK`` past its buckets
+    reaches the kernel: heads narrower than the 128 lanes (padded in the
+    wrapper — Mosaic slices no 64-lane KV head out of ``[K, KVH*64]``),
+    float32 operands, ``n_rep`` 1."""
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import prefill_attention
+
+    text = _compiled_text(
+        chip, ("prefill_kernel", h, kvh, d, str(dt)),
+        lambda q, k, v, kp0, st, cm: prefill_attention(q, k, v, kp0, st, cm),
+        chip((512, h, d), dt), chip((2048, kvh, d), dt), chip((2048, kvh, d), dt),
+        chip((), jnp.int32), chip((), jnp.int32), chip((512,), jnp.int32))
+    assert "tpu_custom_call" in text
+
+
+def test_the_scores_reader_sees_xlas_scores(chip):
+    """What the reader is for: the XLA form of one Trinity window layer
+    (``prefill_attention_ref``) holds the ``[32, 1024, 3088]`` float32
+    scores — so an empty list above is the kernel, not a blind spot."""
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import (
+        prefill_attention_ref, scores_in_hbm)
+
+    bf = jnp.bfloat16
+    text = _compiled_text(
+        chip, ("prefill_ref",),
+        lambda q, k, v, kp0, st, cm: prefill_attention_ref(q, k, v, kp0, st, cm, 2048),
+        chip((1024, 32, 128), bf), chip((3088, 4, 128), bf),
+        chip((3088, 4, 128), bf), chip((), jnp.int32), chip((), jnp.int32),
+        chip((1024,), jnp.int32))
+    assert scores_in_hbm(text, 32 * 1024 * 1024)
 
 
 def test_an_undonated_insert_copies_every_pool(chip):

@@ -159,10 +159,9 @@ def _paged_state(cfg, rows, nb, bs, steps):
     )
 
 
-def _serve(params, cfg, prompts, steps, chunk, monkeypatch, bs=2, t_w=16):
+def _prefill(params, cfg, prompts, steps, chunk, bs=2, t_w=16):
     """Chunked paged prefill of each prompt (windows of ``chunk`` tokens
-    straight into pool blocks), then ``steps`` greedy paged decode steps
-    of all rows together -> (logits [B, steps, V], tokens, state)."""
+    straight into pool blocks) -> (state, table)."""
     rows = len(prompts)
     perm = np.random.default_rng(5).permutation(rows * t_w).astype(np.int32)
     table = jnp.asarray(perm.reshape(rows, t_w))
@@ -176,6 +175,14 @@ def _serve(params, cfg, prompts, steps, chunk, monkeypatch, bs=2, t_w=16):
             state = llama_mod.paged_prefill_chunk(
                 params, cfg, state, table[b], jnp.asarray(w_ids),
                 jnp.asarray(w_mask), start)
+    return state, table
+
+
+def _serve(params, cfg, prompts, steps, chunk, monkeypatch, bs=2, t_w=16):
+    """``_prefill``, then ``steps`` greedy paged decode steps of all rows
+    together -> (logits [B, steps, V], tokens, state)."""
+    rows = len(prompts)
+    state, table = _prefill(params, cfg, prompts, steps, chunk, bs, t_w)
     assert state.cache_v == [] and len(state.cache_k) == cfg.num_layers
     lens = np.asarray([len(p) for p in prompts])
     valid = (np.arange(t_w * bs)[None] < (lens - 1)[:, None]).astype(np.int32)
@@ -265,6 +272,37 @@ def test_absorbed_is_expanded_on_the_same_weights(cfg, params):
         c = dataclasses.replace(cfg, pallas_decode=pallas)
         absorbed = llama_mod._mla_decode_attention(c, layer, q1, pool, table, valid, bs)
         assert _close(absorbed, expanded) < 1e-5, pallas
+
+
+def test_the_prompt_window_kernel_is_the_xla_window(cfg, params, monkeypatch):
+    """``paged_prefill_chunk`` through the prompt-window kernel
+    (``cfg.pallas_decode``: the row's latents expanded 16 heads at a time)
+    against the XLA form under ``_prefill_mask``, in windows of 8 over
+    prompts that end inside a window, at its end and in a second one:
+    every pool holds the same rows, and the next token's logits (one
+    gathered decode step on either state) agree within the file's
+    tolerance."""
+    prompts = [_ids(n, 30 + n) for n in (5, 8, 13)]
+    states = {}
+    for kernels in (True, False):
+        kcfg = dataclasses.replace(cfg, pallas_decode=kernels)
+        states[kernels], table = _prefill(params, kcfg, prompts, 1, 8)
+    pools = [jax.tree.leaves((st.cache_k, st.cache_v)) for st in states.values()]
+    assert len(pools[0]) == len(pools[1]) > 0
+    for got, want in zip(*pools):
+        assert got.shape == want.shape and _close(got, want) < TOL
+        np.testing.assert_array_equal(np.asarray(got) == 0, np.asarray(want) == 0)
+    lens = np.asarray([len(p) for p in prompts])
+    valid = (np.arange(table.shape[1] * 2)[None] < (lens - 1)[:, None]).astype(np.int32)
+    seen = _Logits(monkeypatch)
+    for st in states.values():
+        st = st._replace(
+            key_valid=jnp.asarray(valid), write_idx=jnp.asarray(lens - 1),
+            last_token=jnp.asarray([p[-1] for p in prompts]))
+        llama_mod._paged_decode_step(
+            params, dataclasses.replace(cfg, pallas_decode=False), st, table)
+    kernel_logits, xla_logits = seen.seen
+    assert _close(kernel_logits, xla_logits) < TOL
 
 
 # ---------------------------------------------------------------------------
