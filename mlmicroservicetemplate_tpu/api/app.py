@@ -198,8 +198,15 @@ async def _on_startup(app: web.Application) -> None:
             app[K_STATE]["ready_error"] = f"{type(e).__name__}: {e}"
             log.exception("warmup/canary failed; server will stay not-ready")
             return
+        # The boot timeline closes here: total, unnamed and the phases
+        # go out (/status.compile.boot, boot_phase_seconds), and an
+        # executable compiled from now on is a recompile, reported as one.
+        from ..runtime.compile_cache import mark_ready
+
+        boot = mark_ready(app[K_BUNDLE].name)
         app[K_READY].set()
-        log.info("model %s ready", app[K_BUNDLE].name)
+        log.info("model %s ready (boot %.1f s, %.1f s of it under no phase)",
+                 app[K_BUNDLE].name, boot["total_s"], boot["unnamed_s"])
         # Durable serving (JOURNAL_DIR): replay the write-ahead journal
         # AFTER warmup so resumed streams never pay request-path
         # compiles, re-admitting every incomplete stream for
@@ -340,6 +347,10 @@ async def _on_cleanup(app: web.Application) -> None:
             except (asyncio.CancelledError, Exception):
                 pass
     await app[K_BATCHER].stop()
+    # The app's life is over: what this process compiles next is no
+    # recompile of a serving step, and a service built after this one
+    # (chip_smoke.py, tests) boots into an open table.
+    tracing.boot_table().begin(None)
 
 
 # ---------------------------------------------------------------------------

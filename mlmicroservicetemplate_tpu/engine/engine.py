@@ -94,6 +94,11 @@ class InferenceEngine:
         self.flight = tracing.FlightRecorder(
             int(getattr(cfg, "flight_ring", 256))
         )
+        # An executable compiled or loaded after readiness becomes a
+        # ``compile`` event here, with its name (runtime/compile_cache).
+        from ..runtime.compile_cache import report_to
+
+        report_to(self.flight)
         # Per-site host-dispatch accounting (always on — two clock
         # reads per dispatch): {site: [count, host_seconds]}, served
         # by /debug/engine; device time per site is the profiler trace's.
@@ -1347,10 +1352,12 @@ class InferenceEngine:
         seconds spent; call at startup, before readiness flips true."""
         import jax
 
-        from ..runtime.compile_cache import warm_phase
+        from ..runtime.compile_cache import note_warm_phase
 
-        with warm_phase(self.bundle.name, "engine"):
-            return self._warmup_inner(jax)
+        with tracing.boot_phase("boot/warm/engine") as ph:
+            seconds = self._warmup_inner(jax)
+        note_warm_phase(self.bundle.name, "engine", ph.seconds)
+        return seconds
 
     def _warmup_inner(self, jax) -> float:
         t0 = time.monotonic()

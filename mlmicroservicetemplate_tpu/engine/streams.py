@@ -4901,10 +4901,20 @@ class ContinuousDecodeLoop:
         exist (a sibling replica built it), in which case this whole
         pass is dispatches only — zero XLA compiles, the property the
         spawn fast-path banks on (docs/compilation.md)."""
-        from ..runtime.compile_cache import warm_phase
+        from ..runtime.compile_cache import note_warm_phase
 
-        with warm_phase(self.engine.bundle.name, "loop"):
+        model = self.engine.bundle.name
+        if self._state is None:
+            with tracing.boot_phase("boot/engine_build", what="empty_state"):
+                self._build_empty_state()
+        # Before the paged executables trace: the winner lands in the
+        # tuning table their kernel call sites resolve at trace time.
+        with tracing.boot_phase("boot/warm/autotune") as ph:
+            self._autotune_kernel()
+        note_warm_phase(model, "autotune", ph.seconds)
+        with tracing.boot_phase("boot/warm/loop") as ph:
             self._warm_inner()
+        note_warm_phase(model, "loop", ph.seconds)
 
     def warm_spawn(self, donor: "ContinuousDecodeLoop | None" = None
                    ) -> None:
@@ -4925,9 +4935,9 @@ class ContinuousDecodeLoop:
         if donor is None:
             self.warm()
             return
-        from ..runtime.compile_cache import warm_phase
+        from ..runtime.compile_cache import note_warm_phase
 
-        with warm_phase(self.engine.bundle.name, "loop"):
+        with tracing.boot_phase("boot/warm/loop", spawn=True) as ph:
             if self._state is None:
                 self._build_empty_state()
             self.chain_depth = max(1, int(donor.chain_depth))
@@ -4935,6 +4945,7 @@ class ContinuousDecodeLoop:
             metrics.CHAIN_DEPTH.labels(self.engine.bundle.name).set(
                 self.chain_depth
             )
+        note_warm_phase(self.engine.bundle.name, "loop", ph.seconds)
 
     def _warm_wave(self, s: int, n_batch: int, sampled: bool = False):
         """Run the batched start for ``n_batch`` full rows of bucket
@@ -5106,9 +5117,11 @@ class ContinuousDecodeLoop:
                         jax.tree.leaves(self._state)[0]
                     )
         if self.prefill_chunk:
-            self._warm_prefill()
+            with tracing.boot_phase("boot/warm/loop/prefill_window"):
+                self._warm_prefill()
         if self._auto_depth:
-            self._tune_chain_depth()
+            with tracing.boot_phase("boot/warm/loop/chain_depth"):
+                self._tune_chain_depth()
         # Reset to all-dead so warm inserts never leak into serving.
         self._build_empty_state()
 
@@ -5185,7 +5198,6 @@ class ContinuousDecodeLoop:
 
         from .kv_blocks import OutOfBlocks, StreamBlocks, blocks_for
 
-        self._autotune_kernel()
         eng = self.engine
 
         # One scratch block list serves the whole grid (every insert
@@ -5208,10 +5220,12 @@ class ContinuousDecodeLoop:
 
         insert = self._paged_insert_fn()
         one_insert = threading.Lock()
+        parent = tracing.boot_current()
 
         def warm_one(cell: tuple[int, int]) -> None:
             s, n_batch = cell
-            with eng._lock:
+            with tracing.boot_phase("boot/warm/loop/grid", parent,
+                                    bucket=s, rung=n_batch), eng._lock:
                 state1 = self._warm_wave(s, n_batch)[0]
                 # One insert at a time: it consumes the state (donated)
                 # and the next thread's takes its successor.
@@ -5237,19 +5251,25 @@ class ContinuousDecodeLoop:
         finally:
             sb.release()
         for flag in (False, True) if warm_sampled else (False,):
-            with eng._lock:
+            with tracing.boot_phase("boot/warm/loop/chunk", sampled=flag), \
+                    eng._lock:
                 self._state, toks, _ = self._paged_chunk_fn()(
                     self._mp(n=self.n_slots), self._state,
                     jnp.asarray(self._table), eng.chunk_tokens, flag,
                 )
                 jax.device_get(toks)
-        self._warm_windows(warm_sampled)
-        self._warm_swap()
+        with tracing.boot_phase("boot/warm/loop/windows"):
+            self._warm_windows(warm_sampled)
+        with tracing.boot_phase("boot/warm/loop/swap"):
+            self._warm_swap()
         if self.prefill_chunk:
-            self._warm_prefill()
+            with tracing.boot_phase("boot/warm/loop/prefill_window"):
+                self._warm_prefill()
         if self._auto_depth:
-            self._tune_chain_depth_paged()
-        self._build_empty_state()
+            with tracing.boot_phase("boot/warm/loop/chain_depth"):
+                self._tune_chain_depth_paged()
+        with tracing.boot_phase("boot/warm/loop/empty_state"):
+            self._build_empty_state()
 
     def _warm_swap(self) -> None:
         """Compile the host-tier swap executables off the request path

@@ -215,6 +215,37 @@ def _maybe_quantize(params, svc_cfg):
     return quantize_pytree(params, mode)
 
 
+def _load_weights(name: str, svc_cfg, policy: DtypePolicy, init_fn,
+                  converter, check: Callable | None = None):
+    """The serving tree under the boot phase ``boot/weights``: read the
+    converted checkpoint or draw the seeded init (``check`` sees that
+    tree and raises on one it cannot serve), cast to the serving dtype,
+    quantize (QUANTIZE).  The row carries the tree's size."""
+    import jax
+
+    from ..utils import tracing
+    from .common import cast_pytree
+
+    with tracing.boot_phase("boot/weights") as ph:
+        params = _load_or_init(name, svc_cfg.model_path, init_fn, converter)
+        if check is not None:
+            check(params)
+        params = cast_pytree(params, policy.param_jnp)
+        params = _maybe_quantize(params, svc_cfg)
+        leaves = jax.tree.leaves(params)
+        ph.set(parameters=sum(int(x.size) for x in leaves),
+               bytes=sum(int(x.nbytes) for x in leaves))
+    return params
+
+
+def _tokenizer(build: Callable, *args, **kwargs):
+    """``build(*args, **kwargs)`` under the boot phase ``boot/tokenizer``."""
+    from ..utils import tracing
+
+    with tracing.boot_phase("boot/tokenizer"):
+        return build(*args, **kwargs)
+
+
 def _attach_prompt_prefix(params, tokenizer, svc_cfg, compute_fn,
                           max_positions: int) -> int:
     """Cache a shared system-prompt prefix's KV into the params pytree
@@ -395,14 +426,11 @@ def _tp_placement(svc_cfg, model_cfg, family: str, devices=None):
 
 def _build_resnet(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     from ..convert import resnet_state_to_pytree
-    from .common import cast_pytree
 
     cfg = resnet_mod.ResNetConfig()
-    params = _load_or_init("resnet50", svc_cfg.model_path,
+    params = _load_weights("resnet50", svc_cfg, policy,
                            functools.partial(resnet_mod.init_params, cfg=cfg),
                            resnet_state_to_pytree)
-    params = cast_pytree(params, policy.param_jnp)
-    params = _maybe_quantize(params, svc_cfg)
 
     def forward(p, images):
         # images arrive uint8; normalize on device, then cast for the MXU.
@@ -424,14 +452,11 @@ def _build_resnet(svc_cfg, policy: DtypePolicy) -> ModelBundle:
 
 def _build_bert(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     from ..convert import bert_state_to_pytree
-    from .common import cast_pytree
 
     cfg = bert_mod.BertConfig()
-    params = _load_or_init("bert-base", svc_cfg.model_path,
+    params = _load_weights("bert-base", svc_cfg, policy,
                            functools.partial(bert_mod.init_params, cfg=cfg),
                            bert_state_to_pytree)
-    params = cast_pytree(params, policy.param_jnp)
-    params = _maybe_quantize(params, svc_cfg)
 
     # TP=<n>: Megatron-shard the params over a ('replica','tp') mesh.
     make_placement = _tp_placement(svc_cfg, cfg, "bert")
@@ -459,7 +484,7 @@ def _build_bert(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         cfg=cfg,
         params=params,
         policy=policy,
-        tokenizer=build_tokenizer(svc_cfg.tokenizer_path, for_t5=False),
+        tokenizer=_tokenizer(build_tokenizer, svc_cfg.tokenizer_path, for_t5=False),
         labels=load_labels(getattr(svc_cfg, "labels_path", None)),
         forward=forward,
         make_placement=make_placement,
@@ -481,27 +506,27 @@ def _build_bert_long(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     from ..convert import bert_state_to_pytree
     from ..parallel import SeqParallelSet, make_sp_mesh
     from ..parallel.ring import make_ring_attention
-    from .common import cast_pytree
 
     max_pos = max(max(svc_cfg.seq_buckets), 512)
     cfg = bert_mod.BertConfig(max_position=max_pos)
-    params = _load_or_init("bert-long", svc_cfg.model_path,
+
+    def covers_buckets(params) -> None:
+        # A loaded checkpoint's position table must actually cover the long
+        # buckets: jnp.take CLAMPS out-of-range indices, so an undersized
+        # table would silently reuse its last row for every position past
+        # it — confidently wrong logits, no error. Fail at startup instead.
+        pos_rows = params["embeddings"]["position"]["embedding"].shape[0]
+        if pos_rows < max_pos:
+            raise ValueError(
+                f"bert-long needs a position-embedding table with >= {max_pos} "
+                f"rows for SEQ_BUCKETS={svc_cfg.seq_buckets}, but the loaded "
+                f"checkpoint has {pos_rows}; extend the table (e.g. interpolate) "
+                "or lower the buckets"
+            )
+
+    params = _load_weights("bert-long", svc_cfg, policy,
                            functools.partial(bert_mod.init_params, cfg=cfg),
-                           bert_state_to_pytree)
-    # A loaded checkpoint's position table must actually cover the long
-    # buckets: jnp.take CLAMPS out-of-range indices, so an undersized
-    # table would silently reuse its last row for every position past
-    # it — confidently wrong logits, no error. Fail at startup instead.
-    pos_rows = params["embeddings"]["position"]["embedding"].shape[0]
-    if pos_rows < max_pos:
-        raise ValueError(
-            f"bert-long needs a position-embedding table with >= {max_pos} "
-            f"rows for SEQ_BUCKETS={svc_cfg.seq_buckets}, but the loaded "
-            f"checkpoint has {pos_rows}; extend the table (e.g. interpolate) "
-            "or lower the buckets"
-        )
-    params = cast_pytree(params, policy.param_jnp)
-    params = _maybe_quantize(params, svc_cfg)
+                           bert_state_to_pytree, check=covers_buckets)
 
     # bert-long scales with SP (+ REPLICAS), never TP — fail loudly so
     # a TP knob is not silently swallowed by the SP placement below
@@ -557,7 +582,7 @@ def _build_bert_long(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         cfg=cfg,
         params=params,
         policy=policy,
-        tokenizer=build_tokenizer(svc_cfg.tokenizer_path, for_t5=False),
+        tokenizer=_tokenizer(build_tokenizer, svc_cfg.tokenizer_path, for_t5=False),
         labels=load_labels(getattr(svc_cfg, "labels_path", None)),
         forward=forward,
         make_placement=lambda: SeqParallelSet(mesh),
@@ -566,14 +591,11 @@ def _build_bert_long(svc_cfg, policy: DtypePolicy) -> ModelBundle:
 
 def _build_t5(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     from ..convert import t5_state_to_pytree
-    from .common import cast_pytree
 
     cfg = t5_mod.T5Config()
-    params = _load_or_init("t5-small", svc_cfg.model_path,
+    params = _load_weights("t5-small", svc_cfg, policy,
                            functools.partial(t5_mod.init_params, cfg=cfg),
                            t5_state_to_pytree)
-    params = cast_pytree(params, policy.param_jnp)
-    params = _maybe_quantize(params, svc_cfg)
 
     # Same serving-only Pallas opt-in as BERT (the kernel has no VJP;
     # the rel-pos bias rides into the fused kernel as a [1,H,S,S] block).
@@ -615,7 +637,7 @@ def _build_t5(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         cfg=cfg,
         params=params,
         policy=policy,
-        tokenizer=build_tokenizer(svc_cfg.tokenizer_path, for_t5=True),
+        tokenizer=_tokenizer(build_tokenizer, svc_cfg.tokenizer_path, for_t5=True),
         labels=None,
         forward=None,
         encode_fn=encode_fn,
@@ -637,9 +659,8 @@ def _build_gpt(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     """
     from ..convert import gpt2_state_to_pytree
     from . import gpt as gpt_mod
-    from .common import cast_pytree
 
-    tokenizer = build_tokenizer(svc_cfg.tokenizer_path, for_t5=True)
+    tokenizer = _tokenizer(build_tokenizer, svc_cfg.tokenizer_path, for_t5=True)
     # Fused paged-decode kernel (MHA corner of the llama kernel):
     # USE_PALLAS_DECODE opt-in, TPU-or-interpret gated.  The paged
     # kernel's VMEM footprint is per block-group, not per slab, so the
@@ -677,11 +698,9 @@ def _build_gpt(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             f"tokenizer eos_id={cfg.eos_id}/pad_id={cfg.pad_id} outside "
             f"gpt2 vocab of {cfg.vocab_size}"
         )
-    params = _load_or_init("gpt2", svc_cfg.model_path,
+    params = _load_weights("gpt2", svc_cfg, policy,
                            functools.partial(gpt_mod.init_params, cfg=cfg),
                            gpt2_state_to_pytree)
-    params = cast_pytree(params, policy.param_jnp)
-    params = _maybe_quantize(params, svc_cfg)
 
     # Optional shared system prompt: cached KV in the params pytree.
     p_len = _attach_prompt_prefix(
@@ -791,7 +810,6 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
 
     from ..convert import llama_state_to_pytree
     from . import llama as llama_mod
-    from .common import cast_pytree
 
     # Llama input convention is the INVERSE of T5's: prompts start with
     # <s> (BOS) and must NOT end in </s> — a trailing EOS conditions the
@@ -807,11 +825,12 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     if tok_path and tok_path.endswith((".model", ".tsv", ".vocab")):
         from .sentencepiece import load_sentencepiece
 
-        tokenizer = load_sentencepiece(
-            tok_path, add_eos=False, add_bos=bool(overrides.get("add_bos", True))
+        tokenizer = _tokenizer(
+            load_sentencepiece, tok_path, add_eos=False,
+            add_bos=bool(overrides.get("add_bos", True)),
         )
     else:
-        tokenizer = build_tokenizer(tok_path, for_t5=True)
+        tokenizer = _tokenizer(build_tokenizer, tok_path, for_t5=True)
     # Model-side EOS/pad must be the TOKENIZER's ids (gpt2 precedent):
     # a mismatch would leave streams decoding the full budget while the
     # detokenizer silently truncates at its own eos.
@@ -969,13 +988,11 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     # Each leaf is drawn in float32 and cast at once (llama.init_params):
     # the boot peak is the serving-dtype tree plus one leaf, not a whole
     # float32 tree.  A loaded checkpoint is cast here as before.
-    params = _load_or_init(
-        "llama", svc_cfg.model_path,
+    params = _load_weights(
+        "llama", svc_cfg, policy,
         functools.partial(llama_mod.init_params, cfg=cfg,
                           dtype=policy.param_jnp),
         llama_state_to_pytree)
-    params = cast_pytree(params, policy.param_jnp)
-    params = _maybe_quantize(params, svc_cfg)
 
     # Optional shared system prompt (cached KV).  The prefix carries
     # the BOS; request suffixes must then NOT get their own.

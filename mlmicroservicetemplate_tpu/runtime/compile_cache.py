@@ -44,20 +44,30 @@ shardings) sits below this table; the persistent XLA disk cache
 (``COMPILE_CACHE_DIR``, runtime/device.py) sits below BOTH and is what
 carries compiles across process restarts.
 
-This module is import-light (no jax at import time) and thread-safe:
-fleet replicas warm concurrently and jitted callables are themselves
-thread-safe.
+The module is thread-safe: fleet replicas warm concurrently and jitted
+callables are themselves thread-safe.
+
+It also keeps the process's ONE ``jax.monitoring`` listener: a record
+per executable the backend hands back (``compiled`` or ``loaded`` from
+the persistent cache, with its trace / lower / backend seconds), which
+``CompileWindow``, ``/status.compile`` and ``xla_executables_total``
+read, and the export of the boot timeline (``utils/tracing.boot_phase``)
+at readiness.
 """
 
 from __future__ import annotations
 
+import collections
 import itertools
+import logging
+import os
 import threading
 import time
+import weakref
 from collections import OrderedDict
 from typing import Any, Callable
 
-from ..utils import metrics
+from ..utils import metrics, tracing
 
 _LOCK = threading.RLock()
 _CACHE: OrderedDict[tuple, Any] = OrderedDict()
@@ -69,21 +79,156 @@ MAX_ENTRIES = 1024
 
 _fp_counter = itertools.count()
 
-# -- warm-phase accounting (engine_warm_seconds{phase}) ----------------
-_WARM_LOCK = threading.Lock()
-_WARM_PHASES: dict[str, float] = {}
+# -- XLA executable accounting (jax.monitoring) -------------------------
+#
+# One record per executable the backend hands back, from JAX's own
+# events.  ``backend_compile_duration`` wraps
+# ``compiler.compile_or_get_cached``: it fires for a persistent-cache
+# HIT as for a real compile, so the hit is told apart by the
+# ``cache_hits`` event JAX announces on the same thread just before it
+# (pinned by tests/test_boot_timeline.py on the installed JAX).
+_EVENT_TRACE = "/jax/core/compile/jaxpr_trace_duration"
+_EVENT_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_EVENT_BACKEND = "/jax/core/compile/backend_compile_duration"
+_EVENT_HIT = "/jax/compilation_cache/cache_hits"
+_EVENT_WRITE = "/jax/compilation_cache/cache_misses"
+_EVENT_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+_EVENT_SAVED = "/jax/compilation_cache/compile_time_saved_sec"
+OUTCOMES = ("compiled", "loaded")
+WHENS = ("boot", "serving")  # before readiness, after
+STAGES = ("trace", "lower", "backend")
+#: Records kept one by one, then names kept with their totals; past
+#: both an executable still counts in the totals by outcome.
+MAX_RECORDS = 512
+MAX_NAMES = 1024
+COSTLIEST = 20
 
-# -- XLA compile accounting (jax.monitoring) ---------------------------
+log = logging.getLogger(__name__)
+
+
+def _zero() -> dict:
+    return {"count": 0, "trace_s": 0.0, "lower_s": 0.0, "backend_s": 0.0,
+            "retrieval_s": 0.0}
+
+
+def _tally() -> dict:
+    return {"compiled": 0, "loaded": 0, "seconds": 0.0}
+
+
+class _Executables:
+    """The process's executable records: a bounded list in order, totals
+    by name, totals by (when, outcome).  ``when`` is ``boot`` until the
+    boot table closes at readiness, ``serving`` after."""
+
+    def __init__(self):
+        self.lock = threading.Lock()
+        self.local = threading.local()  # a thread's stages so far
+        self.seq = 0
+        self.records: collections.deque = collections.deque(maxlen=MAX_RECORDS)
+        self.by_name: dict[str, dict] = {}
+        self.by_phase: dict[str, dict] = {}  # keyed by boot phase: few
+        self.totals = {(w, o): _zero() for w in WHENS for o in OUTCOMES}
+        self.cache_writes = 0
+        self.saved_s = 0.0
+        self.flights: weakref.WeakSet = weakref.WeakSet()
+
+
+_EXE = _Executables()
 _MON_LOCK = threading.Lock()
 _MON_INSTALLED = False
-_COMPILES = {"count": 0, "seconds": 0.0}
-_BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
+def _on_event(name: str, **kw) -> None:
+    if name == _EVENT_HIT:
+        _EXE.local.hit = True
+    elif name == _EVENT_WRITE:
+        with _EXE.lock:
+            _EXE.cache_writes += 1
+
+
+def _on_duration(name: str, dur: float, **kw) -> None:
+    tl = _EXE.local
+    if name == _EVENT_TRACE:
+        tl.trace = (kw.get("fun_name"), float(dur))
+    elif name == _EVENT_LOWER:
+        tl.lower = (kw.get("fun_name"), float(dur))
+    elif name == _EVENT_RETRIEVAL:
+        tl.retrieval = float(dur)
+    elif name == _EVENT_SAVED:
+        with _EXE.lock:
+            _EXE.saved_s += float(dur)
+    elif name == _EVENT_BACKEND:
+        _record(str(kw.get("fun_name", "?")), float(dur))
+
+
+def _stage(slot: tuple | None, name: str) -> float:
+    """A thread's last trace / lower time if it was this executable's
+    (tracing names the function, lowering and the backend its module:
+    ``paged_chunk_fn`` -> ``jit(paged_chunk_fn)``)."""
+    if slot is None or slot[0] is None:
+        return 0.0
+    return slot[1] if name == slot[0] or name.endswith(f"({slot[0]})") else 0.0
+
+
+def _record(name: str, backend_s: float) -> None:
+    """The backend handed an executable back: close its record."""
+    tl = _EXE.local
+    rec = {
+        "name": name,
+        "outcome": "loaded" if getattr(tl, "hit", False) else "compiled",
+        "trace_s": _stage(getattr(tl, "trace", None), name),
+        "lower_s": _stage(getattr(tl, "lower", None), name),
+        "backend_s": backend_s,
+        "retrieval_s": getattr(tl, "retrieval", 0.0),
+        "thread": threading.current_thread().name,
+        "phase": tracing.boot_current(),
+        "after_ready": tracing.boot_table().closed,
+    }
+    tl.hit, tl.retrieval, tl.trace, tl.lower = False, 0.0, None, None
+    spent = rec["trace_s"] + rec["lower_s"] + backend_s
+    rec["start"] = time.monotonic() - spent
+    when = "serving" if rec["after_ready"] else "boot"
+    with _EXE.lock:
+        _EXE.seq += 1
+        rec["seq"] = _EXE.seq
+        _EXE.records.append(rec)
+        tallies = [_EXE.by_phase.setdefault(rec["phase"] or when, _tally())]
+        if name in _EXE.by_name or len(_EXE.by_name) < MAX_NAMES:
+            tallies.append(_EXE.by_name.setdefault(name, _tally()))
+        for tot in tallies:
+            tot[rec["outcome"]] += 1
+            tot["seconds"] += spent
+        tot = _EXE.totals[when, rec["outcome"]]
+        tot["count"] += 1
+        for key in ("trace_s", "lower_s", "backend_s", "retrieval_s"):
+            tot[key] += rec[key]
+        flights = list(_EXE.flights) if rec["after_ready"] else ()
+    metrics.XLA_EXECUTABLES.labels(rec["outcome"], when).inc()
+    for stage in STAGES:
+        metrics.XLA_EXECUTABLE_SECONDS.labels(
+            rec["outcome"], stage, when).inc(rec[stage + "_s"])
+    if not rec["after_ready"]:
+        return
+    # After readiness an operator has to see WHICH step recompiled
+    # without DEBUG logs: a flight event, one line, a ring span (the
+    # enclosing dispatch:<site> phase names the device's idle gap).
+    log.warning("XLA %s %s after readiness: %.3f s (trace %.3f, lower %.3f, "
+                "backend %.3f) on %s", rec["outcome"], name, spent,
+                rec["trace_s"], rec["lower_s"], backend_s, rec["thread"])
+    for flight in flights:
+        flight.event("compile", name=name, outcome=rec["outcome"],
+                     seconds=round(spent, 4))
+    tr = tracing.tracer()
+    if tr is not None:
+        tr.add(f"compile:{name}", cat="compile", t0=rec["start"], dur=spent,
+               outcome=rec["outcome"])
 
 
 def _install_monitor() -> None:
-    """Register ONE process-wide jax.monitoring listener that counts
-    backend (XLA) compiles and their wall seconds.  Idempotent; the
-    listener cannot be unregistered, so it accumulates for the process
+    """Register the process's ONE pair of jax.monitoring listeners (the
+    staged durations that carry ``fun_name`` and the persistent
+    cache's events).  Idempotent; a listener cannot be told apart from
+    another once registered, so it accumulates for the process
     lifetime and consumers read deltas (``CompileWindow``)."""
     global _MON_INSTALLED
     with _MON_LOCK:
@@ -91,22 +236,40 @@ def _install_monitor() -> None:
             return
         import jax
 
-        def on_duration(name: str, dur: float, **kw) -> None:
-            if name != _BACKEND_COMPILE_EVENT:
-                return
-            with _MON_LOCK:
-                _COMPILES["count"] += 1
-                _COMPILES["seconds"] += float(dur)
-
-        jax.monitoring.register_event_duration_secs_listener(on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        # Every child exists from here on: a warm boot reads 0 compiled,
+        # not an absent sample.
+        for when in WHENS:
+            for outcome in OUTCOMES:
+                metrics.XLA_EXECUTABLES.labels(outcome, when)
+                for stage in STAGES:
+                    metrics.XLA_EXECUTABLE_SECONDS.labels(outcome, stage, when)
         _MON_INSTALLED = True
 
 
+def report_to(flight: Any) -> None:
+    """Send every executable compiled or loaded after readiness to this
+    flight recorder too (held weakly: an engine's recorder goes with it)."""
+    with _EXE.lock:
+        _EXE.flights.add(flight)
+
+
 def compile_counters() -> dict:
-    """Process-lifetime XLA compile totals ``{count, seconds}`` (zeros
-    until the first shared executable installs the monitor)."""
-    with _MON_LOCK:
-        return dict(_COMPILES)
+    """Process-lifetime totals ``{count, seconds, compiled, loaded,
+    seq}`` of executables the backend handed back (zeros until the
+    first shared executable installs the monitor).  ``count`` and
+    ``seconds`` take a load from the persistent cache and a real
+    compile alike, as the ``backend_compile_duration`` event does;
+    ``compiled`` / ``loaded`` split them."""
+    with _EXE.lock:
+        by = {o: sum(t["count"] for (_, oo), t in _EXE.totals.items()
+                     if oo == o) for o in OUTCOMES}
+        return {
+            "count": by["compiled"] + by["loaded"],
+            "seconds": sum(t["backend_s"] for t in _EXE.totals.values()),
+            "seq": _EXE.seq, **by,
+        }
 
 
 class CompileWindow:
@@ -116,11 +279,18 @@ class CompileWindow:
             replica.cdl.warm()
         assert w.compiles == 0          # the zero-compile spawn pin
         breakdown["compile_s"] = w.seconds
-    """
+
+    ``compiles`` / ``seconds`` count every executable the backend
+    handed back (loaded from the persistent cache or compiled);
+    ``compiled`` / ``loaded`` split the count and ``names`` lists them
+    in order (the last ``MAX_RECORDS`` of a long window)."""
 
     def __init__(self):
         self.compiles = 0
         self.seconds = 0.0
+        self.compiled = 0
+        self.loaded = 0
+        self.names: list[str] = []
         self._base: dict | None = None
 
     def __enter__(self) -> "CompileWindow":
@@ -132,6 +302,42 @@ class CompileWindow:
         now = compile_counters()
         self.compiles = now["count"] - self._base["count"]
         self.seconds = now["seconds"] - self._base["seconds"]
+        self.compiled = now["compiled"] - self._base["compiled"]
+        self.loaded = now["loaded"] - self._base["loaded"]
+        with _EXE.lock:
+            self.names = [r["name"] for r in _EXE.records
+                          if r["seq"] > self._base["seq"]]
+
+
+def executables_status() -> dict:
+    """/status.compile.executables: totals by when, outcome and stage,
+    the costliest names, every name compiled (not loaded) so far, and
+    the work by the boot phase open on the compiling thread (outside a
+    boot phase: ``boot`` or ``serving``)."""
+    with _EXE.lock:
+        totals = {
+            when: {o: {k: (v if k == "count" else round(v, 4))
+                       for k, v in _EXE.totals[when, o].items()}
+                   for o in OUTCOMES}
+            for when in WHENS}
+        names = sorted(_EXE.by_name.items(), key=lambda kv: -kv[1]["seconds"])
+        return {
+            "totals": totals,
+            "costliest": [dict(name=n, **{**t, "seconds": round(t["seconds"], 4)})
+                          for n, t in names[:COSTLIEST]],
+            "compiled": {n: t["compiled"] for n, t in names if t["compiled"]},
+            "by_phase": {ph: {**t, "seconds": round(t["seconds"], 4)}
+                         for ph, t in _EXE.by_phase.items()},
+            "cache_writes": _EXE.cache_writes,
+            "compile_time_saved_s": round(_EXE.saved_s, 4),
+            "records_kept": len(_EXE.records),
+        }
+
+
+def executable_records() -> list[dict]:
+    """The records kept, oldest first (tests, tools)."""
+    with _EXE.lock:
+        return [dict(r) for r in _EXE.records]
 
 
 def bundle_fingerprint(bundle: Any) -> str:
@@ -294,32 +500,82 @@ def clear() -> None:
 
 
 def note_warm_phase(model: str, phase: str, seconds: float) -> None:
-    """Record one warm phase's wall seconds: feeds
-    ``engine_warm_seconds{phase}`` and the process totals
-    ``/status.compile`` reports."""
+    """One warm phase's wall seconds into ``engine_warm_seconds{phase}``:
+    the boot's ``boot/warm/<phase>`` timings and the fleet's spawn
+    breakdown (``spawn_build`` / ``spawn_warm`` / ``spawn_probe``)."""
     metrics.WARM_SECONDS.labels(model, phase).observe(seconds)
-    with _WARM_LOCK:
-        _WARM_PHASES[phase] = _WARM_PHASES.get(phase, 0.0) + seconds
 
 
-class warm_phase:
-    """``with warm_phase(model, "loop"): cdl.warm()`` timing helper."""
-
-    def __init__(self, model: str, phase: str):
-        self.model = model
-        self.phase = phase
-        self.seconds = 0.0
-
-    def __enter__(self) -> "warm_phase":
-        self._t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.seconds = time.perf_counter() - self._t0
-        note_warm_phase(self.model, self.phase, self.seconds)
+# -- the persistent cache's directory, and the boot's export ------------
+_PERSISTENT: dict = {}
 
 
-def warm_stats() -> dict:
-    """Accumulated per-phase warm seconds for /status.compile."""
-    with _WARM_LOCK:
-        return {k: round(v, 4) for k, v in sorted(_WARM_PHASES.items())}
+def _scan(path: str) -> dict:
+    """``{bytes, entries}`` of a cache directory: one ``os.scandir`` pass."""
+    size = entries = 0
+    try:
+        with os.scandir(path) as it:
+            for e in it:
+                if e.is_file(follow_symlinks=False):
+                    entries += 1
+                    size += e.stat(follow_symlinks=False).st_size
+    except OSError:
+        return {"bytes": None, "entries": None}
+    return {"bytes": size, "entries": entries}
+
+
+def note_persistent_cache(path: str | None) -> None:
+    """``apply_device_env`` chose this directory (None = off): remember
+    it with JAX's size limit for it and what it holds now.  With the
+    reading at readiness a boot says whether the cache stood at its
+    cap and whether this boot pushed entries out."""
+    _install_monitor()  # a boot's first compile is already a record
+    _PERSISTENT.clear()
+    _PERSISTENT["dir"] = path
+    if path is None:
+        return
+    import jax
+
+    _PERSISTENT["max_size_bytes"] = int(jax.config.jax_compilation_cache_max_size)
+    _PERSISTENT["at_device"] = _scan(path)
+
+
+def mark_ready(model: str) -> dict:
+    """Readiness: close the boot table, read the cache directory again
+    and set ``boot_phase_seconds{model, phase}`` from the table's
+    top-level phases (by the segment after ``boot/``, so the three
+    ``boot/warm/*`` phases are one child), ``unnamed``, ``total`` and
+    ``pre_build``.  Returns the table's snapshot."""
+    table = tracing.boot_table()
+    table.ready()
+    if _PERSISTENT.get("dir"):
+        _PERSISTENT["at_ready"] = _scan(_PERSISTENT["dir"])
+    snap = table.snapshot()
+    groups: dict[str, float] = {}
+    for name, seconds in snap["phases"].items():
+        parts = name.split("/")
+        key = parts[1] if parts[0] == "boot" and len(parts) > 1 else name
+        groups[key] = groups.get(key, 0.0) + seconds
+    groups.pop("ready", None)
+    groups["unnamed"] = snap["unnamed_s"]
+    groups["total"] = snap["total_s"]
+    if "pre_build_s" in snap:
+        groups["pre_build"] = snap["pre_build_s"]
+    for key, seconds in groups.items():
+        metrics.BOOT_PHASE_SECONDS.labels(model, key).set(seconds)
+    return snap
+
+
+def boot_status() -> dict:
+    """/status.compile's ``boot``, ``executables``, ``persistent_cache``
+    and ``warm_phases_s`` (the wall seconds of the boot's
+    ``boot/warm/<phase>`` rows by ``<phase>``)."""
+    boot = tracing.boot_table().snapshot()
+    warm = {name[len("boot/warm/"):]: s for name, s in boot["phases"].items()
+            if name.startswith("boot/warm/")}
+    return {
+        "warm_phases_s": dict(sorted(warm.items())),
+        "boot": boot,
+        "executables": executables_status(),
+        "persistent_cache": dict(_PERSISTENT),
+    }

@@ -105,9 +105,13 @@ def apply_device_env(device: str, compile_cache_dir: str | None = None
 
     Also enables the persistent compilation cache (see
     ``enable_compilation_cache``; ``compile_cache_dir`` is the
-    ServiceConfig knob, None = env-var fallback).
+    ServiceConfig knob, None = env-var fallback) and tells
+    ``runtime/compile_cache`` which directory that is
+    (``/status.compile.persistent_cache``).
     """
-    enable_compilation_cache(device, compile_cache_dir)
+    from .compile_cache import note_persistent_cache
+
+    note_persistent_cache(enable_compilation_cache(device, compile_cache_dir))
     import jax
 
     if device != "cpu":

@@ -335,22 +335,26 @@ class Batcher:
             self._cdl.warm()
 
     def compile_status(self) -> dict:
-        """/status.compile: the executable-cache counters, accumulated
-        warm-phase seconds and process XLA compile totals — the
-        operator answer to "what did warming cost and is the cache
-        actually sharing" (docs/compilation.md)."""
+        """/status.compile: the executable-cache counters, the boot
+        timeline (``boot``, and ``warm_phases_s`` out of it), the
+        process's XLA executables (``xla_compiles`` / ``xla_compile_s``
+        count a load from the persistent cache and a real compile
+        alike; ``executables`` splits them, by name) and the
+        persistent cache's directory — the operator answer to "where
+        did the boot go, what did warming cost, which step recompiled"
+        (docs/compilation.md)."""
         from ..runtime.compile_cache import (
+            boot_status,
             cache_stats,
             compile_counters,
-            warm_stats,
         )
 
         comp = compile_counters()
         return {
             "executable_cache": cache_stats(),
-            "warm_phases_s": warm_stats(),
             "xla_compiles": comp["count"],
             "xla_compile_s": round(comp["seconds"], 3),
+            **boot_status(),
         }
 
     def tenancy_status(self) -> dict | None:

@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 import logging
 import sys
+import time
 
 
 def parse_args(argv: list[str] | None = None) -> dict:
@@ -47,51 +48,62 @@ def parse_args(argv: list[str] | None = None) -> dict:
 
 
 def build_service(overrides: dict | None = None):
-    """Assemble (cfg, bundle, engine, batcher, app) without running it."""
+    """Assemble (cfg, bundle, engine, batcher, app) without running it.
+
+    The boot timeline starts here (``utils/tracing.boot_phase``,
+    ``/status.compile.boot``): every step below runs under a phase, the
+    import blocks under ``boot/imports``."""
+    t_entry = time.monotonic()
     # LOCKTRACE=1: install the lock-order detector BEFORE any engine
     # lock exists (docs/static-analysis.md) — locks created earlier
     # stay untraced.
     from .utils import locktrace
 
     locktrace.auto_install()
+    from .utils import tracing
     from .utils.config import load_config
 
-    cfg = load_config(overrides)
-    logging.basicConfig(
-        level=getattr(logging, cfg.log_level.upper(), logging.INFO),
-        format="%(asctime)s %(levelname)s %(name)s: %(message)s",
-    )
-    from .utils import tracing
+    tracing.boot_table().begin(t_entry)
+    tracing.boot_table().add("boot/imports", t_entry,
+                             time.monotonic() - t_entry)
+    with tracing.boot_phase("boot/config"):
+        cfg = load_config(overrides)
+        logging.basicConfig(
+            level=getattr(logging, cfg.log_level.upper(), logging.INFO),
+            format="%(asctime)s %(levelname)s %(name)s: %(message)s",
+        )
+        if cfg.log_format == "json":
+            # One JSON object per line, request_id-correlated with spans
+            # and HTTP error bodies (utils/tracing.JsonLogFormatter).
+            for h in logging.getLogger().handlers:
+                h.setFormatter(tracing.JsonLogFormatter())
+        # TRACE=1 installs the process span tracer before any engine or
+        # request work so startup dispatches are attributable too.
+        tracing.configure(cfg.trace, cfg.trace_ring)
 
-    if cfg.log_format == "json":
-        # One JSON object per line, request_id-correlated with spans
-        # and HTTP error bodies (utils/tracing.JsonLogFormatter).
-        for h in logging.getLogger().handlers:
-            h.setFormatter(tracing.JsonLogFormatter())
-    # TRACE=1 installs the process span tracer before any engine or
-    # request work so startup dispatches are attributable too.
-    tracing.configure(cfg.trace, cfg.trace_ring)
+    with tracing.boot_phase("boot/device"):
+        # Multi-host rendezvous (JAX_COORDINATOR/NUM_PROCESSES/PROCESS_ID;
+        # no-op single-host) — must precede apply_device_env, whose backend
+        # probe would latch initialization before the processes rendezvous.
+        from .runtime.distributed import maybe_init_distributed
 
-    # Multi-host rendezvous (JAX_COORDINATOR/NUM_PROCESSES/PROCESS_ID;
-    # no-op single-host) — must precede apply_device_env, whose backend
-    # probe would latch initialization before the processes rendezvous.
-    from .runtime.distributed import maybe_init_distributed
+        maybe_init_distributed()
 
-    maybe_init_distributed()
+        from .runtime.device import apply_device_env
 
-    from .runtime.device import apply_device_env
+        apply_device_env(cfg.device, cfg.compile_cache_dir)
 
-    apply_device_env(cfg.device, cfg.compile_cache_dir)
+    with tracing.boot_phase("boot/imports"):
+        from .api import build_app
+        from .engine import InferenceEngine
+        from .models.registry import build_model
+        from .scheduler import Batcher
 
-    from .api import build_app
-    from .engine import InferenceEngine
-    from .models.registry import build_model
-    from .scheduler import Batcher
-
-    bundle = build_model(cfg)
-    engine = InferenceEngine(bundle, cfg)
-    batcher = Batcher(engine, cfg)
-    app = build_app(cfg, bundle, engine, batcher)
+    bundle = build_model(cfg)  # boot/tokenizer, boot/weights
+    with tracing.boot_phase("boot/engine_build"):
+        engine = InferenceEngine(bundle, cfg)
+        batcher = Batcher(engine, cfg)
+        app = build_app(cfg, bundle, engine, batcher)
     return cfg, bundle, engine, batcher, app
 
 

@@ -511,12 +511,38 @@ JOURNAL_FSYNC = Histogram(
 WARM_SECONDS = Histogram(
     "engine_warm_seconds",
     "Wall seconds one warm phase took (engine = engine.warmup bucket "
-    "grid, loop = ContinuousDecodeLoop.warm, spawn_build / spawn_warm "
+    "grid, autotune = the kernel-variant resolution, loop = "
+    "ContinuousDecodeLoop.warm's grid, spawn_build / spawn_warm "
     "/ spawn_probe = the fleet scale-up breakdown) — with the "
     "fleet-shared executable cache a second replica's loop/spawn "
     "phases collapse to dispatch time, zero XLA compiles",
     ["model", "phase"],
     buckets=(0.1, 0.5, 1.0, 2.5, 5.0, 10.0, 30.0, 60.0, 120.0, 300.0),
+)
+BOOT_PHASE_SECONDS = Gauge(
+    "boot_phase_seconds",
+    "Wall seconds of the last boot by top-level phase (the segment "
+    "after boot/ of utils/tracing.boot_phase: imports, config, device, "
+    "tokenizer, weights, engine_build, warm), plus unnamed (inside "
+    "total, under no phase), total (entry of serve.build_service to "
+    "readiness) and pre_build (process start to that entry) — set once, "
+    "at readiness; /status.compile.boot has the rows",
+    ["model", "phase"],
+)
+XLA_EXECUTABLES = Counter(
+    "xla_executables_total",
+    "Executables the XLA backend handed back, by outcome (compiled, or "
+    "loaded from the persistent cache) and by when (boot = before "
+    "readiness, serving = after: a recompile) — "
+    "runtime/compile_cache.py, one record each in /status.compile",
+    ["outcome", "when"],
+)
+XLA_EXECUTABLE_SECONDS = Counter(
+    "xla_executable_seconds_total",
+    "Seconds those executables took by stage (trace = jaxpr tracing, "
+    "lower = jaxpr to MLIR, backend = the XLA compile or the cache "
+    "load), summed over threads: work, not wall",
+    ["outcome", "stage", "when"],
 )
 EXEC_CACHE_EVENTS = Counter(
     "executable_cache_events_total",

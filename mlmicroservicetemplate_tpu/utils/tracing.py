@@ -28,6 +28,13 @@ pattern — no OpenTelemetry or any other hard dependency beyond JAX):
   comes from the profiler's device trace (``jax.named_scope`` names in
   ``models/llama.py`` and on the loop's step kinds), not from here.
 
+- **Boot timeline** (``boot_phase(name, ...)``): ``phase()`` plus an
+  always-on row (name, start, seconds, thread, parent) in a small
+  table that closes at readiness — a boot runs with ``TRACE=0`` and no
+  profiler session, so the ring and the xplane are both empty there.
+  ``/status.compile.boot`` and ``boot_phase_seconds{phase}`` read it
+  (runtime/compile_cache.py exports it; docs/compilation.md).
+
 - **Flight recorder** (``FLIGHT_RING``, default on): a bounded ring of
   the engine loop's last N iterations (batch composition, slot
   occupancy, KV pool state) plus scheduling/fault events (admission
@@ -48,6 +55,7 @@ import collections
 import functools
 import json
 import logging
+import os
 import threading
 import time
 
@@ -273,6 +281,188 @@ def phase(name: str, cat: str = "app", rid: str = "", **args) -> _Phase:
     and adds them with ``.set()``."""
     tr = _TRACER
     return _Phase(name, None if tr is None else tr.span(name, cat, rid, **args))
+
+
+# ---------------------------------------------------------------------------
+# boot timeline
+
+
+@functools.cache
+def _process_start() -> float | None:
+    """When this process started, on ``time.monotonic()``'s scale, where
+    Linux says (``/proc/self/stat`` field 22, ticks since the machine's
+    boot); None elsewhere.  Read once: it does not move."""
+    try:
+        with open("/proc/self/stat", "rb") as f:
+            ticks = int(f.read().rsplit(b")", 1)[1].split()[19])
+        age = time.clock_gettime(time.CLOCK_BOOTTIME) - (
+            ticks / os.sysconf("SC_CLK_TCK"))
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+    return _now() - age if age >= 0.0 else None
+
+
+class BootTable:
+    """The rows of one boot: every ``boot_phase`` that closed between
+    ``begin`` (the entry of ``serve.build_service``) and ``ready``.
+    Rows on one thread nest by ``parent``; a row without one is a
+    top-level phase.  ``total`` runs from ``begin`` to ``ready``, and
+    what no top-level row covers inside it is ``unnamed``.  Bounded:
+    past ``MAX_ROWS`` a row is counted, not kept."""
+
+    MAX_ROWS = 256
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        self._local = threading.local()
+        self.begin(None)
+
+    def begin(self, t0: float | None) -> None:
+        """A new boot starts at ``t0`` (None: at its first row)."""
+        with self._lock:
+            self.rows: list[dict] = []
+            self.dropped = 0
+            self.t0 = t0
+            self.t_ready: float | None = None
+
+    @property
+    def closed(self) -> bool:
+        return self.t_ready is not None
+
+    def stack(self) -> list[str]:
+        """Names of the boot phases open on this thread, outermost first."""
+        st = getattr(self._local, "stack", None)
+        if st is None:
+            st = self._local.stack = []
+        return st
+
+    def add(self, name: str, start: float, seconds: float,
+            parent: str | None = None, **args) -> None:
+        row = {"name": name, "start": start, "seconds": seconds,
+               "thread": threading.current_thread().name, "parent": parent}
+        if args:
+            row["args"] = args
+        with self._lock:
+            if self.closed:
+                return
+            if self.t0 is None:
+                self.t0 = start
+            if len(self.rows) < self.MAX_ROWS:
+                self.rows.append(row)
+            else:
+                self.dropped += 1
+
+    def ready(self) -> None:
+        """Close the table: the instant row ``boot/ready`` ends ``total``."""
+        now = _now()
+        self.add("boot/ready", now, 0.0)
+        with self._lock:
+            if self.t_ready is None:
+                self.t_ready = now
+
+    def snapshot(self) -> dict:
+        """``{rows, phases, unnamed_s, total_s, pre_build_s, ready}``:
+        rows in order of their start, seconds from ``begin``;
+        ``phases`` sums the top-level rows by name; ``total_s`` runs to
+        readiness (to now while the boot is open)."""
+        with self._lock:
+            rows = sorted(self.rows, key=lambda r: r["start"])
+            t0, t_ready, dropped = self.t0, self.t_ready, self.dropped
+        if t0 is None:
+            return {"rows": [], "phases": {}, "unnamed_s": 0.0,
+                    "total_s": 0.0, "ready": False}
+        end = t_ready if t_ready is not None else _now()
+        phases: dict[str, float] = {}
+        covered, edge = 0.0, t0
+        for r in rows:
+            if r["parent"] is not None:
+                continue
+            phases[r["name"]] = phases.get(r["name"], 0.0) + r["seconds"]
+            # the union of the top-level rows inside [t0, end]: phases
+            # on two threads that overlap are covered once
+            lo = max(r["start"], edge)
+            hi = min(r["start"] + r["seconds"], end)
+            if hi > lo:
+                covered += hi - lo
+                edge = hi
+        out = {
+            "rows": [dict(r, start=round(r["start"] - t0, 4),
+                          seconds=round(r["seconds"], 4)) for r in rows],
+            "phases": {k: round(v, 4) for k, v in phases.items()},
+            "unnamed_s": round(max(end - t0 - covered, 0.0), 4),
+            "total_s": round(end - t0, 4),
+            "ready": t_ready is not None,
+        }
+        if dropped:
+            out["rows_dropped"] = dropped
+        started = _process_start()
+        if started is not None and started <= t0:
+            out["pre_build_s"] = round(t0 - started, 4)
+        return out
+
+
+_BOOT = BootTable()
+
+
+def boot_table() -> BootTable:
+    """The process's boot table (one process, one boot at a time)."""
+    return _BOOT
+
+
+class _BootPhase(_Phase):
+    """``phase()`` that also leaves a row in the boot table."""
+
+    __slots__ = ("_name", "_parent", "_args", "_t0", "seconds")
+
+    def __init__(self, name: str, span: Span | None, parent: str | None,
+                 args: dict):
+        super().__init__(name, span)
+        self._name = name
+        self._parent = parent
+        self._args = args
+        self.seconds = 0.0
+
+    def set(self, **kw) -> "_BootPhase":
+        self._args.update(kw)
+        return self
+
+    def __enter__(self) -> "_BootPhase":
+        stack = _BOOT.stack()
+        if self._parent is None and stack:
+            self._parent = stack[-1]
+        stack.append(self._name)
+        super().__enter__()
+        self._t0 = _now()
+        return self
+
+    def __exit__(self, etype, exc, tb):
+        self.seconds = _now() - self._t0
+        if self._span is not None:
+            self._span.args.update(self._args)
+        super().__exit__(etype, exc, tb)
+        stack = _BOOT.stack()
+        if stack and stack[-1] == self._name:
+            stack.pop()
+        _BOOT.add(self._name, self._t0, self.seconds, self._parent,
+                  **self._args)
+        return False
+
+
+def boot_phase(name: str, parent: str | None = None, **args) -> _BootPhase:
+    """``phase(name)`` plus an always-on row in the boot table while a
+    boot is open (after readiness: the phase alone).  ``parent``
+    defaults to the boot phase open on this thread; a worker thread of
+    a phase names it (``boot_current()`` read on the spawning thread).
+    ``.seconds`` holds the wall time after exit."""
+    tr = _TRACER
+    span = None if tr is None else tr.span(name, "boot", "")
+    return _BootPhase(name, span, parent, args)
+
+
+def boot_current() -> str | None:
+    """The innermost boot phase open on this thread, if any."""
+    stack = _BOOT.stack()
+    return stack[-1] if stack else None
 
 
 def scoped(name: str, fn):

@@ -296,3 +296,85 @@ def test_prefill_window_ms_is_a_window_executables_mean_time():
     assert trace_module_ms.read(
         ctx({"jit_paged_prefill_chunk_fn": (0.30, 20)}), **args) == pytest.approx(15.0)
     assert trace_module_ms.read(ctx({}), **args) is None
+
+
+# ---------------------------------------------------------------------------
+# boot_* (PR 35): the first per-layer entries that move setup_s — seven
+# entries, seven data files, one reader (prom_labelled), nothing edited
+
+
+BOOT_CELLS = [
+    "mistral-7b-d8.decode-closed", "mistral-7b-d8.chat-open",
+    "olmoe-1b-7b-d8.decode-closed", "trinity-mini-d5.longdoc-closed", DSV2_CELL]
+BOOT_ENTRIES = [
+    ("boot_imports_s", "s", "boot_phase_seconds", {"phase": "imports"}),
+    ("boot_weights_s", "s", "boot_phase_seconds", {"phase": "weights"}),
+    ("boot_warm_s", "s", "boot_phase_seconds", {"phase": "warm"}),
+    ("boot_xla_compiled", "executables", "xla_executables_total",
+     {"outcome": "compiled", "when": "boot"}),
+    ("boot_xla_compile_s", "s", "xla_executable_seconds_total",
+     {"outcome": "compiled", "stage": "backend", "when": "boot"}),
+    ("boot_xla_load_s", "s", "xla_executable_seconds_total",
+     {"outcome": "loaded", "stage": "backend", "when": "boot"}),
+    ("boot_unnamed_pct", "%", "boot_phase_seconds", {"phase": "unnamed"}),
+]
+
+
+@pytest.mark.parametrize("name,unit,family,labels", BOOT_ENTRIES)
+def test_boot_entry_resolves_in_every_cell(name, unit, family, labels):
+    from cellbench import spec
+    from mlmicroservicetemplate_tpu.utils import metrics
+
+    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": unit, "better": "lower", "source": "program_counter",
+        "layer": "compile", "moves": "setup_s", "workloads": BOOT_CELLS}
+    for cell in BOOT_CELLS:
+        (resolved,) = [m for m in spec.resolve(cell).per_layer if m.name == name]
+        assert resolved.reader == "prom_labelled" and callable(resolved.read)
+        assert resolved.args["family"] == family
+        assert resolved.args["labels"] == labels
+    # the family is one the program declares, with the labels the file picks by
+    declared = {getattr(getattr(metrics, a), "_name", None): getattr(metrics, a)
+                for a in dir(metrics)}
+    fam = declared[family[:-len("_total")] if family.endswith("_total") else family]
+    assert set(labels) <= set(fam._labelnames)
+
+
+def test_boot_entries_are_the_tail_and_the_only_ones_that_move_setup_s():
+    from cellbench import spec
+
+    per_layer = spec.load_benchmark()["per_layer"]
+    assert [m["name"] for m in per_layer[-7:]] == [e[0] for e in BOOT_ENTRIES]
+    assert [m["name"] for m in per_layer if m["moves"] == "setup_s"] == [
+        e[0] for e in BOOT_ENTRIES]
+    assert [m["name"] for m in per_layer if m["layer"] == "compile"] == [
+        e[0] for e in BOOT_ENTRIES]
+
+
+def test_prom_labelled_keeps_children_apart_and_reads_nothing_from_a_parent():
+    """Over a scrape text: one child by its labels, children that match
+    summed, a share through ``over``; an absent family (the parent
+    commit) or an absent child reads no value, never 0."""
+    from cellbench.readers import prom_labelled
+
+    text = """\
+# HELP boot_phase_seconds x
+boot_phase_seconds{model="llama",phase="imports"} 9.5
+boot_phase_seconds{model="llama",phase="unnamed"} 0.5
+boot_phase_seconds{model="llama",phase="total"} 50.0
+xla_executables_total{outcome="compiled",when="boot"} 0.0
+xla_executables_total{outcome="loaded",when="boot"} 41.0
+xla_executables_total{outcome="loaded",when="serving"} 9.0
+xla_executables_created{outcome="loaded",when="boot"} 1.7e+09
+"""
+    kids = prom_labelled.children(text, "xla_executables_total")
+    assert len(kids) == 3
+    assert prom_labelled.pick(kids, {"outcome": "compiled", "when": "boot"}) == 0.0
+    assert prom_labelled.pick(kids, {"outcome": "loaded", "when": "boot"}) == 41.0
+    assert prom_labelled.pick(kids, {"outcome": "loaded"}) == 50.0
+    assert prom_labelled.pick(kids, {"outcome": "compiled", "when": "serving"}) is None
+    assert prom_labelled.children(text, "xla_executable_seconds_total") == []
+    phases = prom_labelled.children(text, "boot_phase_seconds")
+    assert prom_labelled.pick(phases, {"phase": "imports"}) == 9.5
+    assert parse_prom(text)["boot_phase_seconds"]["value"] == 60.0  # summed there

@@ -467,6 +467,9 @@ def test_warm_and_cache_series_have_samples(monkeypatch):
     cfg = _cfg(max_decode_len=8)
     eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
     cdl = ContinuousDecodeLoop(eng, cfg)
+    from mlmicroservicetemplate_tpu.utils import tracing
+
+    tracing.boot_table().begin(None)  # a boot of this test's own
     cdl.warm()
     body, _ = metrics.render()
     text = body.decode()
@@ -476,6 +479,10 @@ def test_warm_and_cache_series_have_samples(monkeypatch):
             in text, f"no {event} sample"
     # The /status.compile payload reads from the same counters.
     assert cc.cache_stats()["entries"] > 0
-    assert "loop" in cc.warm_stats()
-    assert cc.compile_counters()["count"] >= 0
+    status = cc.boot_status()
+    assert "loop" in status["warm_phases_s"]  # one timing: the boot table's
+    assert [r["name"] for r in status["boot"]["rows"] if r["parent"] is None][-2:] \
+        == ["boot/warm/autotune", "boot/warm/loop"]
+    comp = cc.compile_counters()
+    assert comp["count"] == comp["compiled"] + comp["loaded"] >= 0
     cdl.stop()

@@ -204,7 +204,11 @@ def test_hang_cut_by_watchdog_and_recovered():
     """An injected hang longer than DISPATCH_TIMEOUT_S is cut off by
     the watchdog (classified fatal) instead of stalling the loop; the
     stream still finishes, token-identically, within a bounded wall."""
-    cfg = _cfg(fault_spec="chunk:hang(30)@2", dispatch_timeout_s=0.3,
+    # The timeout also covers each site's FIRST dispatch, compile included
+    # (the insert's is 0.2-0.45 s with six workers busy): 0.3 s cut that
+    # one about one run in five on a loaded machine, 1.5 s still cuts the
+    # 30 s hang well inside the wall below.
+    cfg = _cfg(fault_spec="chunk:hang(30)@2", dispatch_timeout_s=1.5,
                max_decode_len=16)
     bundle, eng = _echo_engine(cfg)
     feats = text_feats(bundle.tokenizer, "hang survivor")

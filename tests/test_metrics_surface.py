@@ -120,8 +120,22 @@ def test_every_declared_series_present_and_bounded():
         "generated_tokens_total", "stream_ttft_seconds_count",
         "stream_tbt_seconds_count", "stream_batch_size_count",
         "dispatch_host_seconds_count", "requests_shed_total",
+        # set-up reports itself (ISSUE 35): readiness set the boot's
+        # phases, and every outcome x when child exists from the first
+        # shared executable on (a warm boot reads 0, not nothing)
+        "boot_phase_seconds", "xla_executables_total",
+        "xla_executable_seconds_total",
     ):
         assert need in sampled, f"{need} has no samples after smoke"
+    for phase in ("total", "unnamed"):
+        assert f'boot_phase_seconds{{model="gpt2",phase="{phase}"}}' in text
+    for outcome in ("compiled", "loaded"):
+        for when in ("boot", "serving"):
+            assert (f'xla_executables_total{{outcome="{outcome}",'
+                    f'when="{when}"}}') in text
+            for stage in ("trace", "lower", "backend"):
+                assert (f'xla_executable_seconds_total{{outcome="{outcome}",'
+                        f'stage="{stage}",when="{when}"}}') in text
 
     # 3. Bounded label cardinality per family.
     from collections import defaultdict
