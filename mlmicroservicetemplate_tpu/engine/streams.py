@@ -457,6 +457,15 @@ class ContinuousDecodeLoop:
         # tenant (the paged mirror of the contiguous mode="drop"
         # clamp).
         self.paged = bool(getattr(engine, "paged_kv", False))
+        # Prompt windows one prefill dispatch holds at most: the windows
+        # of different prompts a boundary's budget admits go out as ONE
+        # batched paged_prefill_chunk (the contiguous slab: one job a
+        # dispatch, each owns its state).  Follows from the two values
+        # the deployment states; no option of its own.
+        self._prefill_width = (
+            -(-self.prefill_budget // self.prefill_chunk)
+            if self.paged and self.prefill_chunk else 1
+        )
         # (window layers, window) of a per-layer pattern, or None.
         bcfg = getattr(engine.bundle, "cfg", None)
         types = getattr(bcfg, "layer_types", ())
@@ -2747,62 +2756,120 @@ class ContinuousDecodeLoop:
         self._drop_job_resources(job)
         self._fail_streams([job.st], exc)
 
-    def _dispatch_prefill_window(self, job: _PrefillJob) -> None:
-        """One PREFILL_CHUNK window for ``job``: pad the prompt slice,
-        grow the block table to cover it (paged — the chunk-by-chunk
-        allocation that replaces whole-prompt reservation), dispatch
-        under the ``prefill_chunk`` fault site.  Raises OutOfBlocks
-        (caller checkpoints) or the dispatch's own failure."""
+    def _stall_prefill_job(self, job: _PrefillJob) -> None:
+        """Pool dry mid-prefill: checkpoint the job and re-queue it for a
+        token-identical restart when blocks free up — the prefill mirror
+        of _grow_for_dispatch's preemption."""
+        metrics.KV_GROWTH_STALLS.labels(self.engine.bundle.name).inc()
+        if self._flight is not None:
+            self._flight.event(
+                "kv_growth_stall", rid=job.st.rid, site="prefill"
+            )
+        self._prefilling.remove(job)
+        self._checkpoint_job(job)
+
+    def _dispatch_prefill_window(
+        self, jobs: list[_PrefillJob]
+    ) -> list[_PrefillJob]:
+        """ONE dispatch for the next PREFILL_CHUNK window of each of
+        ``jobs`` — windows of different prompts, ``[B, C]`` tokens: pad
+        each prompt slice into its row, grow each job's block table to
+        cover it (paged — the chunk-by-chunk allocation that replaces
+        whole-prompt reservation; a job the pool has no blocks for is
+        checkpointed and leaves the batch, the others go on), dispatch
+        under the ``prefill_chunk`` fault site — ``B`` is 1 or
+        ``_prefill_width``, fewer jobs filled up with masked rows.  The
+        contiguous slab takes one job (each owns its detached state).
+        Returns the jobs whose window ran; raises the dispatch's own
+        failure, which is every job's of the batch."""
         import jax.numpy as jnp
+
+        from .kv_blocks import OutOfBlocks
 
         eng = self.engine
         c = self.prefill_chunk
-        start = job.consumed
-        end = min(start + c, job.L)
-        with tracing.phase(
-            "prefill_window", cat="engine", rid=job.st.rid,
-            start=start, end=end, total=job.L, paged=self.paged,
-        ):
-            ids_w = np.zeros((1, c), np.int32)
-            mask_w = np.zeros((1, c), np.int32)
-            ids_w[0, : end - start] = job.ids[start:end]
-            mask_w[0, : end - start] = 1
-            if self.paged:
-                # Fault-injection point, like decode growth: an injected
-                # OutOfBlocks exercises the mid-prefill checkpoint path.
-                eng.fault_point("grow")
-                self._reclaim_then_ensure(job.sb, end)
+
+        def window_end(job):
+            return min(job.consumed + c, job.L)
+
+        if self.paged:
+            grown = []
+            for job in jobs:
+                try:
+                    # Fault-injection point, like decode growth: an
+                    # injected OutOfBlocks exercises the mid-prefill
+                    # checkpoint path.
+                    eng.fault_point("grow")
+                    self._reclaim_then_ensure(job.sb, window_end(job))
+                except OutOfBlocks:
+                    self._stall_prefill_job(job)
+                    continue
                 job.table_row[: len(job.sb.ids)] = job.sb.ids
+                grown.append(job)
+            jobs = grown
+            if not jobs:
+                return jobs
+        n = len(jobs)
+        # Two executables whatever the budget: a window alone, or the
+        # full width — a batch in between rides the full width with
+        # masked rows (no token, no table entry: nothing written, no
+        # expert row, no key tile), so set-up loads two, not one a width.
+        rows = n if n == 1 else self._prefill_width
+        ends = [window_end(job) for job in jobs]
+        starts = np.zeros(rows, np.int32)
+        starts[:n] = [job.consumed for job in jobs]
+        with tracing.phase(
+            "prefill_window", cat="engine",
+            rid=jobs[0].st.rid if n == 1 else "",
+            windows=n, streams=[job.st.rid for job in jobs],
+            starts=starts[:n].tolist(), ends=ends, paged=self.paged,
+        ):
+            ids_w = np.zeros((rows, c), np.int32)
+            mask_w = np.zeros((rows, c), np.int32)
+            for r, (job, end) in enumerate(zip(jobs, ends)):
+                ids_w[r, : end - job.consumed] = job.ids[job.consumed:end]
+                mask_w[r, : end - job.consumed] = 1
+            jparams = self._mp(
+                rows=[job.st.adapter_slot for job in jobs] + [0] * (rows - n))
+            if self.paged:
                 if self._state is None:
                     self._build_empty_state()
-                jparams = self._mp(rows=[job.st.adapter_slot])
+                tables = np.full(
+                    (rows, self.nb_max), self.pool.num_blocks, np.int32)
+                tables[:n] = [job.table_row for job in jobs]
                 with eng._lock:
                     self._state = eng.dispatch_guard(
                         "prefill_chunk",
                         lambda: self._paged_prefill_fn()(
-                            jparams, self._state,
-                            jnp.asarray(job.table_row), ids_w, mask_w,
-                            np.int32(start),
+                            jparams, self._state, jnp.asarray(tables),
+                            ids_w, mask_w, starts,
                         ),
                         donates=self._state,
                     )
                 if self.admission is not None:
                     self.admission.note_pool()
-                self._note_prefill_tiles(start, end)
             else:
-                jparams = self._mp(rows=[job.st.adapter_slot])
+                (job,) = jobs
                 with eng._lock:
                     job.state = eng.dispatch_guard(
                         "prefill_chunk",
                         lambda: self._prefill_fn()(
-                            jparams, job.state, ids_w, mask_w,
-                            np.int32(start)
+                            jparams, job.state, ids_w, mask_w, starts[0]
                         ),
                         donates=job.state,
                     )
-            job.consumed = end
-            self.prefill_chunk_dispatches += 1
-            metrics.PREFILL_CHUNKS.labels(eng.bundle.name).inc()
+            name = eng.bundle.name
+            for job, end in zip(jobs, ends):
+                if self.paged:
+                    self._note_prefill_tiles(job.consumed, end)
+                job.consumed = end
+            self.prefill_chunk_dispatches += n
+            metrics.PREFILL_CHUNKS.labels(name).inc(n)
+            # Both children exist from the first dispatch on: a share
+            # of 0 reads 0, not nothing.
+            metrics.PREFILL_WINDOWS_BATCHED.labels(name).inc(n if n > 1 else 0)
+            metrics.PREFILL_WINDOWS_ALONE.labels(name).inc(n if n == 1 else 0)
+        return jobs
 
     def _handoff_job(self, job: _PrefillJob) -> bool:
         """Prompt exhausted: flip the stream live in a slot — the
@@ -2910,8 +2977,13 @@ class ContinuousDecodeLoop:
         tokens of window compute per chunk boundary (the head-of-line
         bound this feature exists for), idle compute backfills the
         backlog unbounded, and the pacer starves batch-class prefill
-        while interactive decode runs.  Returns True when any window
-        dispatched or handoff completed (the loop must not sleep)."""
+        while interactive decode runs.  The windows chosen — one a job,
+        of different prompts — go out together: ``[B, C]`` tokens in ONE
+        paged dispatch, ``B`` up to what the budget admits
+        (``_prefill_width``; past it, as when idle compute backfills,
+        further dispatches of at most that width).  Returns True when any
+        window dispatched or handoff completed (the loop must not
+        sleep)."""
         if not self.prefill_chunk:
             return False
         eng = self.engine
@@ -2919,7 +2991,6 @@ class ContinuousDecodeLoop:
             metrics.PREFILL_BACKLOG.labels(eng.bundle.name).set(0)
             return False
         from ..scheduler.policy import INTERACTIVE, DeadlineExceededError
-        from .kv_blocks import OutOfBlocks
 
         advanced = False
         t0 = time.monotonic()
@@ -2969,38 +3040,36 @@ class ContinuousDecodeLoop:
                 j.t_in,
             ),
         )
+        chosen = []
         for job in jobs:
             if budget <= 0:
                 break
             if live and not self._pacer.allow(job.st.klass, interactive_live):
                 continue
+            chosen.append(job)
+            budget -= self.prefill_chunk
+        # The chosen windows go out together, ``_prefill_width`` a
+        # dispatch: what a boundary's budget admits is one dispatch.
+        width = self._prefill_width
+        for i in range(0, len(chosen), width):
+            batch = chosen[i:i + width]
             try:
-                self._dispatch_prefill_window(job)
-            except OutOfBlocks:
-                # Pool dry mid-prefill: checkpoint and re-queue for a
-                # token-identical restart when blocks free up — the
-                # prefill mirror of _grow_for_dispatch's preemption.
-                metrics.KV_GROWTH_STALLS.labels(eng.bundle.name).inc()
-                if self._flight is not None:
-                    self._flight.event(
-                        "kv_growth_stall", rid=job.st.rid, site="prefill"
-                    )
-                self._prefilling.remove(job)
-                self._checkpoint_job(job)
-                continue
+                batch = self._dispatch_prefill_window(batch)
             except Exception as e:
-                self._prefilling.remove(job)
-                self._fail_prefill_job(job, e)
+                for job in batch:
+                    if job in self._prefilling:  # not stalled at growth
+                        self._prefilling.remove(job)
+                        self._fail_prefill_job(job, e)
                 if self._fault_pending is not None:
                     break  # shared recovery runs at the iteration top
                 continue
-            advanced = True
-            budget -= self.prefill_chunk
-            if job.consumed >= job.L:
-                job.ready = True
-                if self.free:
-                    self._prefilling.remove(job)
-                    self._handoff_job(job)
+            for job in batch:
+                advanced = True
+                if job.consumed >= job.L:
+                    job.ready = True
+                    if self.free:
+                        self._prefilling.remove(job)
+                        self._handoff_job(job)
         if live and advanced:
             # Host-observed decode-cadence delay: the time this chunk
             # boundary spent on prefill dispatches while streams were
@@ -3017,10 +3086,11 @@ class ContinuousDecodeLoop:
     def _warm_prefill(self) -> None:
         """Compile the chunked-prefill executables off the request
         path: the empty-state builder + window forward per bucket
-        width (contiguous) or the pool-writing window + row handoff
-        (paged).  Long prompts past the bucket list still compile
-        their width on first admission — the documented cost of
-        lifting the prompt ceiling."""
+        width (contiguous) or the pool-writing window at both batch
+        widths a dispatch can have + row handoff (paged).  Long prompts
+        past the bucket list still compile their width on first
+        admission (contiguous) — the documented cost of lifting the
+        prompt ceiling."""
         import jax.numpy as jnp
 
         eng = self.engine
@@ -3042,10 +3112,17 @@ class ContinuousDecodeLoop:
                     [{"input_ids": ids_w[0], "length": np.int32(c)}], 1
                 )
                 with eng._lock:
-                    self._state = self._paged_prefill_fn()(
-                        self._mp(n=1), self._state, jnp.asarray(table_row),
-                        ids_w, mask_w, np.int32(0),
-                    )
+                    # The two widths a dispatch has (a window alone, and
+                    # what a boundary's budget admits), so no window
+                    # compiles while serving; the rows write the same
+                    # warm blocks, which is harmless here.
+                    for b in sorted({1, self._prefill_width}):
+                        self._state = self._paged_prefill_fn()(
+                            self._mp(n=b), self._state,
+                            jnp.asarray(np.tile(table_row, (b, 1))),
+                            np.tile(ids_w, (b, 1)), np.tile(mask_w, (b, 1)),
+                            np.zeros(b, np.int32),
+                        )
                     self._state = self._paged_handoff_fn()(
                         self._state,
                         np.zeros((1, self.nb_max * self.block_size), np.int32),
@@ -4566,7 +4643,10 @@ class ContinuousDecodeLoop:
             dparams = self._mp()
             with eng._lock:
                 if table is None:
-                    table = jnp.asarray(self._table)
+                    # A snapshot: the dispatch is asynchronous and the
+                    # next handoff, growth or release writes ``_table``
+                    # (the CPU backend aliases an aligned host buffer).
+                    table = jnp.asarray(self._table.copy())
                 if w > 1:
                     self._state, toks, hist, nc = eng.dispatch_guard(
                         "chunk",

@@ -620,8 +620,9 @@ def _window_mask(base_valid: jax.Array, chunk_mask: jax.Array, start):
     """[B, 1, C, total] attention mask for one prefill window: every
     already-valid cache position (``base_valid`` [B, total] bool —
     previous windows, or an adopted/seeded prefix) plus the causal,
-    pad-gated in-window prefix.  ``start`` is traced, so one
-    executable serves every window of a prompt."""
+    pad-gated in-window prefix.  ``start`` is traced (a scalar, or
+    [B, 1]: each row's own), so one executable serves every window of a
+    prompt."""
     b, c = chunk_mask.shape
     total = base_valid.shape[1]
     pos_k = jnp.arange(total)[None, :]  # [1, total]
@@ -685,45 +686,48 @@ def paged_prefill_chunk(
     params: Params,
     cfg: GPTConfig,
     state: PagedState,
-    table_row: jax.Array,  # [T] this stream's block table (sentinel-padded)
-    chunk_ids: jax.Array,  # [1, C]
-    chunk_mask: jax.Array,  # [1, C]
-    start,
+    table_rows: jax.Array,  # [B, T] each stream's block table (sentinel-padded)
+    chunk_ids: jax.Array,  # [B, C]
+    chunk_mask: jax.Array,  # [B, C]
+    starts: jax.Array,  # [B]
     dtype=jnp.float32,
 ) -> PagedState:
-    """One prompt window written straight into the stream's pool
-    blocks (PREFILL_CHUNK × PAGED_KV): K/V scatter through the block
-    table at absolute positions; attention reads back through a dense
-    gather of the stream's own blocks (adopted CoW prefix blocks
-    included, so a prefix-cache hit suffix-prefills in chunks with no
-    KV copy).  Only the pool leaves change — the slot rows' logical
-    fields belong to OTHER streams and are untouched; this stream's
-    row fields land at handoff (engine/streams.py).  Valid keys are
-    exactly the positions below ``start``: the prompt is contiguous
-    from 0, so no per-row key_valid is needed mid-prefill."""
+    """One prompt window each of ``B`` different streams written
+    straight into their pool blocks (PREFILL_CHUNK × PAGED_KV): a row's
+    K/V scatter through its own block table at absolute positions;
+    attention reads back through a dense gather of each stream's own
+    blocks (adopted CoW prefix blocks included, so a prefix-cache hit
+    suffix-prefills in chunks with no KV copy).  Only the pool leaves
+    change — the slot rows' logical fields belong to OTHER streams and
+    are untouched; a stream's row fields land at handoff
+    (engine/streams.py).  A row's valid keys are exactly the positions
+    below its ``starts`` entry: the prompt is contiguous from 0, so no
+    per-row key_valid is needed mid-prefill."""
     from ..ops.paged_attention import gather_pages, scatter_pages
 
-    b, c = chunk_ids.shape  # b == 1: prefill windows are per-stream
+    b, c = chunk_ids.shape
     bs = state.cache_k[0].shape[1]
-    pos_w = jnp.broadcast_to(start + jnp.arange(c)[None, :], (b, c))
+    first = starts[:, None]  # [B, 1]: each row's own start
+    pos_w = first + jnp.arange(c)[None, :]
     x = embed(params["wte"], chunk_ids, dtype)
     x = x + embed(params["wpe"], jnp.minimum(pos_w, cfg.max_position - 1), dtype)
-    total = table_row.shape[0] * bs
-    base_valid = jnp.broadcast_to(jnp.arange(total)[None, :] < start, (b, total))
-    mask = _window_mask(base_valid, chunk_mask, start)
+    total = table_rows.shape[1] * bs
+    mask = _window_mask(jnp.arange(total)[None, :] < first, chunk_mask, first)
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
+    tail = (cfg.num_heads, cfg.head_dim)
     for li, layer in enumerate(params["layers"]):
         h = layernorm(layer["ln1"], x, eps=cfg.ln_eps)
         q, k1, v1 = _qkv(layer["attn"], cfg, h, ad, li)
-        ck = scatter_pages(state.cache_k[li], table_row, k1[0], bs, start=start)
-        cv = scatter_pages(state.cache_v[li], table_row, v1[0], bs, start=start)
+        ck, cv = state.cache_k[li], state.cache_v[li]
+        for r in range(b):
+            ck = scatter_pages(ck, table_rows[r], k1[r], bs, start=starts[r])
+            cv = scatter_pages(cv, table_rows[r], v1[r], bs, start=starts[r])
         new_k.append(ck)
         new_v.append(cv)
-        tail = (cfg.num_heads, cfg.head_dim)
-        kd = gather_pages(ck, table_row[None], bs, tail)
-        vd = gather_pages(cv, table_row[None], bs, tail)
+        kd = gather_pages(ck, table_rows, bs, tail)
+        vd = gather_pages(cv, table_rows, bs, tail)
         ctx = mha_attention(q, kd, vd, mask=mask)
         x = x + _attn_out(layer["attn"], merge_heads(ctx), ad, li)
         h = layernorm(layer["ln2"], x, eps=cfg.ln_eps)

@@ -1619,41 +1619,49 @@ def paged_prefill_chunk(
     params: Params,
     cfg: LlamaConfig,
     state,  # gpt.PagedState
-    table_row: jax.Array,
-    chunk_ids: jax.Array,  # [1, C]
-    chunk_mask: jax.Array,
-    start,
+    table_rows: jax.Array,  # [B, T]
+    chunk_ids: jax.Array,  # [B, C]
+    chunk_mask: jax.Array,  # [B, C]
+    starts: jax.Array,  # [B]
     dtype=jnp.float32,
 ):
-    """One prompt window straight into pool blocks (see
-    ``gpt.paged_prefill_chunk``), at GQA width and composed with the
-    int8 pool pairs.  The window's queries attend over the row's gathered
-    keys (``prefill_key_blocks``) through the prompt-window kernel
+    """One prompt window each of ``B`` different prompts straight into
+    pool blocks (see ``gpt.paged_prefill_chunk``), at GQA width and
+    composed with the int8 pool pairs.  What is a matmul over rows — the
+    embedding, the projections, the FFN (on an expert layer the router
+    and the grouped matmuls: the experts stream once for all ``B x C``
+    rows) — runs once over the batch, RoPE at each row's own ``starts``;
+    what belongs to one prompt — the scatter through its table and the
+    attention — runs once a row, in a static loop inside the one
+    executable.  A row's queries attend over that row's gathered keys
+    (``prefill_key_blocks``) through the prompt-window kernel
     (``ops/prefill_attention``: tile by tile, the scores never in HBM, a
     tile no query sees never run) wherever the decode step runs its
-    kernels (``cfg.pallas_decode``); ``start`` stays traced and one
-    executable serves every window of every prompt.  Without the kernels,
-    and over an int8 pool, the same keys in XLA under ``_prefill_mask``
-    ([H, C, K] float32 scores: the tests' reference, no served path on
-    the chip)."""
+    kernels (``cfg.pallas_decode``); ``starts`` stays traced and one
+    executable a batch width serves every window of every prompt.
+    Without the kernels, and over an int8 pool, the same keys in XLA
+    under ``_prefill_mask`` ([H, C, K] float32 scores: the tests'
+    reference, no served path on the chip)."""
     from ..ops.paged_attention import gather_pages
 
-    b, c = chunk_ids.shape  # b == 1
+    b, c = chunk_ids.shape
     entry = state.cache_k[0]
     bs = entry[0].shape[1] if isinstance(entry, tuple) else entry.shape[1]
-    pos_w = jnp.broadcast_to(start + jnp.arange(c)[None, :], (b, c))
+    pos_w = starts[:, None] + jnp.arange(c)[None, :]
     x = _embed(params, cfg, chunk_ids, dtype)
     cos, sin = _rope_tables(cfg, jnp.minimum(pos_w, cfg.max_position - 1), dtype)
     cos, sin = cos[:, :, None, :], sin[:, :, None, :]
-    t_w = table_row.shape[0]
+    t_w = table_rows.shape[1]
     kernel = cfg.pallas_decode and not isinstance(entry, tuple)
 
-    def attend(layer, q, ck, cv, window: int):
+    def attend(layer, r: int, q, ck, cv, window: int):
+        """Row ``r``'s window: q [1, C, ...] over its own table."""
+        start = starts[r]
         first, n = prefill_key_blocks(c, t_w, bs, start, window)
-        rows = jax.lax.dynamic_slice_in_dim(table_row, first, n)[None]
-        window_at = (first * bs, start, chunk_mask[0])
+        rows = jax.lax.dynamic_slice_in_dim(table_rows[r], first, n)[None]
+        window_at = (first * bs, start, chunk_mask[r])
         mask = None if kernel else _prefill_mask(
-            first * bs + jnp.arange(n * bs), chunk_mask, start, window)
+            first * bs + jnp.arange(n * bs), chunk_mask[r:r + 1], start, window)
         if cfg.mla:
             # The row's latents, earlier windows' and this one's, as the
             # pool holds them, expanded again here: 0.017 GFLOP a key a
@@ -1677,15 +1685,22 @@ def paged_prefill_chunk(
     new_k, new_v = [], []
     for li, layer in enumerate(params["layers"]):
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
-        cv = None
+        # Every row's keys land before any row attends: the pool is
+        # written in place, then only read.
+        ck, cv = state.cache_k[li], None if cfg.mla else state.cache_v[li]
         with jax.named_scope("kv_write"):
-            ck = _paged_scatter_entry(state.cache_k[li], table_row, k1[0], bs, start, dtype)
-            if not cfg.mla:
-                cv = _paged_scatter_entry(state.cache_v[li], table_row, v1[0], bs, start, dtype)
-                new_v.append(cv)
+            for r in range(b):
+                ck = _paged_scatter_entry(ck, table_rows[r], k1[r], bs, starts[r], dtype)
+                if not cfg.mla:
+                    cv = _paged_scatter_entry(cv, table_rows[r], v1[r], bs, starts[r], dtype)
         new_k.append(ck)
+        if not cfg.mla:
+            new_v.append(cv)
         with _attn_scope(cfg, li):
-            ctx = attend(layer, q, ck, cv, cfg.layer_kind(li).window)
+            window = cfg.layer_kind(li).window
+            ctx = [attend(layer, r, jax.tree.map(lambda a: a[r:r + 1], q), ck, cv, window)
+                   for r in range(b)]
+            ctx = ctx[0] if b == 1 else jnp.concatenate(ctx, axis=0)
         x = _attn_out(cfg, layer, ad, li, x, ctx, g)
         x = _mlp_block(cfg, layer, li, x, chunk_mask != 0)
     return state._replace(cache_k=new_k, cache_v=new_v)

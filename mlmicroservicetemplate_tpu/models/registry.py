@@ -86,11 +86,12 @@ class ModelBundle:
     # max_len) -> all-dead decode state sized for a chunked prefill;
     # prefill_chunk_fn(params, state, ids, mask, start) consumes one
     # [B, C] prompt window at absolute position ``start`` (traced);
-    # paged_prefill_chunk_fn(params, paged_state, table_row, ids,
-    # mask, start) is the PAGED_KV variant writing straight into the
-    # stream's pool blocks.  None = family does not support
-    # PREFILL_CHUNK (encoder-decoders prefill the decoder from a start
-    # token — there is no prompt to chunk).
+    # paged_prefill_chunk_fn(params, paged_state, table_rows [B, T],
+    # ids [B, C], mask [B, C], starts [B]) is the PAGED_KV variant: one
+    # window each of B different prompts in one dispatch, each written
+    # straight into its own stream's pool blocks.  None = family does
+    # not support PREFILL_CHUNK (encoder-decoders prefill the decoder
+    # from a start token — there is no prompt to chunk).
     empty_state_fn: Callable | None = None
     prefill_chunk_fn: Callable | None = None
     paged_prefill_chunk_fn: Callable | None = None
@@ -740,9 +741,9 @@ def _build_gpt(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             p, cfg, state, ids, mask, start, dtype=policy.compute_jnp
         )
 
-    def paged_prefill_chunk_fn(p, state, table_row, ids, mask, start):
+    def paged_prefill_chunk_fn(p, state, table_rows, ids, mask, starts):
         return gpt_mod.paged_prefill_chunk(
-            p, cfg, state, table_row, ids, mask, start, dtype=policy.compute_jnp
+            p, cfg, state, table_rows, ids, mask, starts, dtype=policy.compute_jnp
         )
 
     def window_fn(p, state, n_steps: int, max_chunks: int,
@@ -1045,9 +1046,9 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             p, cfg, state, ids, mask, start, dtype=policy.compute_jnp
         )
 
-    def paged_prefill_chunk_fn(p, state, table_row, ids, mask, start):
+    def paged_prefill_chunk_fn(p, state, table_rows, ids, mask, starts):
         return llama_mod.paged_prefill_chunk(
-            p, cfg, state, table_row, ids, mask, start, dtype=policy.compute_jnp
+            p, cfg, state, table_rows, ids, mask, starts, dtype=policy.compute_jnp
         )
 
     def window_fn(p, state, n_steps: int, max_chunks: int,

@@ -262,6 +262,21 @@ PREFILL_CHUNKS = Counter(
     "Prompt windows dispatched by chunked prefill (PREFILL_CHUNK)",
     ["model"],
 )
+PREFILL_WINDOWS_BATCHED = Counter(
+    "prefill_windows_batched_total",
+    "Prompt windows that shared their prefill dispatch with at least one "
+    "other prompt's window (PREFILL_BUDGET admits several PREFILL_CHUNK "
+    "windows a chunk boundary: one batched dispatch, the experts and "
+    "the other weights streamed once for all of them)",
+    ["model"],
+)
+PREFILL_WINDOWS_ALONE = Counter(
+    "prefill_windows_alone_total",
+    "Prompt windows that were their prefill dispatch's only one: "
+    "batched / (batched + alone) is how often the batched dispatch "
+    "engages",
+    ["model"],
+)
 PREFILL_STALL = Counter(
     "prefill_stall_seconds",
     "Host seconds in which the decode loop could dispatch no decode "

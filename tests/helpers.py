@@ -181,8 +181,8 @@ def tiny_gpt_bundle(seed: int = 0, **cfg_overrides) -> ModelBundle:
             p, cfg, st, i, m, start
         ),
         paged_prefill_chunk_fn=(
-            lambda p, st, tr, i, m, start: gpt_mod.paged_prefill_chunk(
-                p, cfg, st, tr, i, m, start
+            lambda p, st, tr, i, m, starts: gpt_mod.paged_prefill_chunk(
+                p, cfg, st, tr, i, m, starts
             )
         ),
         window_fn=lambda p, s, n, w, sample=False: gpt_mod.generate_window(
@@ -229,8 +229,8 @@ def tiny_llama_bundle(seed: int = 0, kv_quant: bool = False,
             p, cfg, st, i, m, start
         ),
         paged_prefill_chunk_fn=(
-            lambda p, st, tr, i, m, start: llama_mod.paged_prefill_chunk(
-                p, cfg, st, tr, i, m, start
+            lambda p, st, tr, i, m, starts: llama_mod.paged_prefill_chunk(
+                p, cfg, st, tr, i, m, starts
             )
         ),
         window_fn=lambda p, s, n, w, sample=False: llama_mod.generate_window(

@@ -341,11 +341,13 @@ def test_boot_entry_resolves_in_every_cell(name, unit, family, labels):
     assert set(labels) <= set(fam._labelnames)
 
 
-def test_boot_entries_are_the_tail_and_the_only_ones_that_move_setup_s():
+def test_boot_entries_stand_together_and_are_the_only_ones_that_move_setup_s():
     from cellbench import spec
 
     per_layer = spec.load_benchmark()["per_layer"]
-    assert [m["name"] for m in per_layer[-7:]] == [e[0] for e in BOOT_ENTRIES]
+    names = [m["name"] for m in per_layer]
+    first = names.index(BOOT_ENTRIES[0][0])  # by name: later PRs append after them
+    assert names[first:first + 7] == [e[0] for e in BOOT_ENTRIES]
     assert [m["name"] for m in per_layer if m["moves"] == "setup_s"] == [
         e[0] for e in BOOT_ENTRIES]
     assert [m["name"] for m in per_layer if m["layer"] == "compile"] == [
@@ -378,3 +380,61 @@ xla_executables_created{outcome="loaded",when="boot"} 1.7e+09
     phases = prom_labelled.children(text, "boot_phase_seconds")
     assert prom_labelled.pick(phases, {"phase": "imports"}) == 9.5
     assert parse_prom(text)["boot_phase_seconds"]["value"] == 60.0  # summed there
+
+
+# ---------------------------------------------------------------------------
+# prefill_windows_batched_pct.* (PR 36): how often a boundary's prompt
+# windows share their dispatch — two entries, two data files, the reader
+# the benchmark has (prom_counter_ratio), nothing edited
+
+
+@pytest.mark.parametrize("name,cell", [
+    ("prefill_windows_batched_pct.trinity", "trinity-mini-d5.longdoc-closed"),
+    ("prefill_windows_batched_pct.dsv2", DSV2_CELL),
+])
+def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
+    from cellbench import spec
+
+    per_layer = spec.load_benchmark()["per_layer"]
+    (entry,) = [m for m in per_layer if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": "%", "better": "higher", "source": "program_counter",
+        "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
+    assert entry in per_layer[-2:]  # appended: nothing before them moved
+    resolved = spec.resolve(cell)
+    (metric,) = [m for m in resolved.per_layer if m.name == name]
+    assert metric.reader == "prom_counter_ratio" and callable(metric.read)
+    assert metric.args == {
+        "part": "prefill_windows_batched", "rest": ["prefill_windows_alone"]}
+    assert "tbt_p99_ms" in [m.name for m in resolved.end_to_end]
+
+
+def test_prefill_windows_batched_pct_reads_the_programs_counters():
+    """The two families as ``/metrics`` exports them, through the reader:
+    the batched share of the windows dispatched in the window; a cell whose
+    every dispatch holds one window reads 0 (both children exist from the
+    first dispatch on), a program without the families (the parent) no
+    value."""
+    import types
+
+    from cellbench.readers import prom_counter_ratio
+    from mlmicroservicetemplate_tpu.utils import metrics  # registers the families
+    from prometheus_client import generate_latest
+
+    def ctx(after, before):
+        return types.SimpleNamespace(
+            notes={}, prom_delta=lambda fam: (
+                None if fam not in after
+                else hist_delta(after[fam], before.get(fam))))
+
+    before = parse_prom(generate_latest().decode())
+    metrics.PREFILL_WINDOWS_BATCHED.labels("reader-unit-36").inc(9)
+    metrics.PREFILL_WINDOWS_ALONE.labels("reader-unit-36").inc(3)
+    after = parse_prom(generate_latest().decode())
+    args = ("prefill_windows_batched", ["prefill_windows_alone"])
+    assert prom_counter_ratio.read(ctx(after, before), *args) == 75.0
+    metrics.PREFILL_WINDOWS_BATCHED.labels("reader-unit-36").inc(0)
+    metrics.PREFILL_WINDOWS_ALONE.labels("reader-unit-36").inc(5)
+    later = parse_prom(generate_latest().decode())
+    assert prom_counter_ratio.read(ctx(later, after), *args) == 0.0
+    assert prom_counter_ratio.read(ctx({}, {}), *args) is None

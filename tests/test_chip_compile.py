@@ -403,12 +403,13 @@ def _cell_config(cell: str) -> tuple:
     return over, 32 * 392, 392, 128
 
 
-def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
+def _serving_program(chip, cell: str, what: str, donate: bool = True,
+                     windows: int = 1) -> tuple:
     """The text of the loop's own executable compiled for the described
     chip at a cell's shapes — ``what`` = the paged chunk
     (``chunk_with_done(generate_chunk_paged)``, as ``_paged_chunk_fn``
-    jits it) or one slot insert (``streams.paged_insert``) — and the
-    element counts of a payload and a scale pool.  Shapes only: no
+    jits it), one slot insert (``streams.paged_insert``) or the prompt
+    windows of ``windows`` prompts in one dispatch — and the element counts of a payload and a scale pool.  Shapes only: no
     weight is made."""
     from mlmicroservicetemplate_tpu.engine.engine import chunk_with_done
     from mlmicroservicetemplate_tpu.engine.streams import paged_insert
@@ -423,7 +424,7 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
     if cfg.mla:  # one latent pool a layer, no V
         c = cfg.latent_lanes
     counts = (nb * bs * c, nb * bs * kvh)
-    key = ("serving", cell, what, donate)
+    key = ("serving", cell, what, donate, windows)
     if key in chip.memo:
         return chip.memo[key], counts
 
@@ -461,10 +462,11 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True) -> tuple:
     elif what == "prefill":  # registry.paged_prefill_chunk_fn, as _paged_prefill_fn jits it
         w = _WINDOWS[cell]
         lowered = jax.jit(
-            lambda p, s, row, ids, mask, start: llama_mod.paged_prefill_chunk(
-                p, cfg, s, row, ids, mask, start, dtype=dt), **donated,
-        ).lower(params, state, chip((t,), jnp.int32), chip((1, w), jnp.int32),
-                chip((1, w), jnp.int32), chip((), jnp.int32))
+            lambda p, s, rows, ids, mask, starts: llama_mod.paged_prefill_chunk(
+                p, cfg, s, rows, ids, mask, starts, dtype=dt), **donated,
+        ).lower(params, state, chip((windows, t), jnp.int32),
+                chip((windows, w), jnp.int32), chip((windows, w), jnp.int32),
+                chip((windows,), jnp.int32))
     else:
         ones = jnp.ones((1, s_max), jnp.int32)
         single = on_chip(jax.eval_shape(
@@ -516,8 +518,11 @@ def test_window_layers_run_the_kernel_at_their_views_width(chip):
     assert widths == [34, 34, 34, 34, 98], widths
 
 
-@pytest.mark.parametrize("cell,heads", [("trinity", 32), ("dsv2", 16)])
-def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads):
+@pytest.mark.parametrize("cell,heads,windows", [
+    ("trinity", 32, 1), ("dsv2", 16, 1),
+    ("trinity", 32, 3),  # PREFILL_BUDGET / PREFILL_CHUNK: a boundary's dispatch
+], ids=["trinity", "dsv2", "trinity-x3"])
+def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads, windows):
     """The prompt-window executable at the cells' shapes (Trinity: 1024
     queries, 32 heads, a window layer over 3088 gathered keys and the full
     layer over the table's 6272; DeepSeek-V2: 2048 queries, 16 expanded
@@ -525,15 +530,25 @@ def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads):
     layer and NO float32 array of heads x queries x keys
     (``prefill_scores_in_hbm: []``): until PR 34 XLA wrote and re-read one
     a layer (0.4-0.8 GB each).  The ``lax.switch`` over key widths is
-    gone with it: one attention a layer, no ``conditional``."""
+    gone with it: one attention a layer, no ``conditional``.  A batched
+    dispatch (``windows`` prompts' windows, PR 36) holds the kernel once a
+    row a layer and still writes every row's keys into the pool in
+    place: donated, aliased, no pool-sized copy."""
     from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
 
-    text, (payload_n, _) = _serving_program(chip, cell, "prefill")
+    text, (payload_n, _) = _serving_program(chip, cell, "prefill", windows=windows)
     w = _WINDOWS[cell]
-    assert scores_in_hbm(text, heads * w * w) == []
+    # (three windows' 24 576 routed rows x 2048 in float32, inside the
+    # expert block's combine fusion, are as many elements and no score)
+    assert [h for h in scores_in_hbm(text, heads * w * w) if "/mlp/" not in h] == []
     calls = [ln for ln in text.splitlines()
              if "tpu_custom_call" in ln and "prefill_attention" in ln]
     assert calls, "the prompt-window kernel is not in the executable"
+    if windows > 1:  # the kernel once a row wherever one window holds it
+        alone, _ = _serving_program(chip, cell, "prefill")
+        assert len(calls) == windows * sum(
+            "tpu_custom_call" in ln and "prefill_attention" in ln
+            for ln in alone.splitlines())
     assert " conditional(" not in text
     assert "input_output_alias" in text
     from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts

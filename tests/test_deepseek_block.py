@@ -173,8 +173,8 @@ def _prefill(params, cfg, prompts, steps, chunk, bs=2, t_w=16):
             w_ids[0, : min(chunk, n - start)] = ids[start:start + chunk]
             w_mask = (np.arange(chunk)[None] + start < n).astype(np.int32)
             state = llama_mod.paged_prefill_chunk(
-                params, cfg, state, table[b], jnp.asarray(w_ids),
-                jnp.asarray(w_mask), start)
+                params, cfg, state, table[b:b + 1], jnp.asarray(w_ids),
+                jnp.asarray(w_mask), jnp.asarray([start]))
     return state, table
 
 

@@ -157,7 +157,7 @@ def test_paged_prefill_chunks_then_paged_decode(ref, cfg, params, monkeypatch):
             ids[0, start:start + window])
         w_mask = (jnp.arange(window)[None] + start < n).astype(jnp.int32)
         state = llama_mod.paged_prefill_chunk(
-            params, kcfg, state, table[0], w_ids, w_mask, start)
+            params, kcfg, state, table, w_ids, w_mask, jnp.asarray([start]))
     state = state._replace(
         key_valid=state.key_valid.at[0, : n - 1].set(1),
         write_idx=jnp.asarray([n - 1]), last_token=ids[:, n - 1])

@@ -248,8 +248,9 @@ def _step_handoff(eng, cdl):
 def _step_prefill_window(eng, cdl):
     c = cdl.prefill_chunk
     return (cdl._paged_prefill_fn()(
-        cdl._mp(n=1), cdl._state, _table_row(cdl, 2),
-        np.ones((1, c), np.int32), np.ones((1, c), np.int32), np.int32(0),
+        cdl._mp(n=1), cdl._state, _table_row(cdl, 2)[None],
+        np.ones((1, c), np.int32), np.ones((1, c), np.int32),
+        np.zeros(1, np.int32),
     ),)
 
 
