@@ -1487,6 +1487,16 @@ async def handle_status(request: web.Request) -> web.Response:
         # Pallas kernel selection (PALLAS_AUTOTUNE / PALLAS_VARIANT;
         # docs/kernel_tuning.md): the active variant ("" = default
         # kernel) and the autotuner's decision counters.
+        if getattr(cdl, "_ssm_free", None) is not None:
+            # Recurrent state beside the paged KV (a model with Mamba
+            # layers): a fixed size a stream, rows by holder.
+            held = cdl.n_slots - len(cdl._ssm_free)
+            body["decode"]["recurrent_state"] = {
+                "rows": cdl.n_slots, "rows_held": held,
+                "row_bytes": engine.stream_fixed_bytes(),
+                "bytes_held": held * engine.stream_fixed_bytes(),
+                "kv_token_bytes": engine.kv_token_bytes(),
+            }
         kv_var = getattr(cdl, "kernel_variant", "")
         if kv_var or getattr(
                 getattr(engine, "cfg", None), "pallas_autotune", False):

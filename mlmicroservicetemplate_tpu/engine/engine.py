@@ -691,6 +691,9 @@ class InferenceEngine:
         paged block ledger read model dims, so they can never drift."""
         cfg = self.bundle.cfg
         layers = int(getattr(cfg, "num_layers", 0) or 12)
+        if getattr(cfg, "layer_pattern", ""):
+            # Mixer-or-FFN layers: only the attention layers cache keys.
+            layers = cfg.layer_pattern.count("*")
         heads = int(
             getattr(cfg, "num_kv_heads", 0)
             or getattr(cfg, "num_heads", 0) or 12
@@ -731,6 +734,11 @@ class InferenceEngine:
         latent = int(getattr(self.bundle.cfg, "latent_lanes", 0) or 0)
         return kv_token_bytes(layers, heads, head_dim, elt, quant, latent)
 
+    def stream_fixed_bytes(self) -> int:
+        """Bytes a stream holds whatever its length: a row of recurrent
+        state (a model with Mamba layers; 0 otherwise)."""
+        return int(getattr(self.bundle.cfg, "ssm_row_bytes", 0) or 0)
+
     def kv_block_bytes(self) -> int:
         """Bytes one ``KV_BLOCK_SIZE``-token block costs (paged mode)."""
         return self.kv_token_bytes() * self.kv_block_size
@@ -764,7 +772,9 @@ class InferenceEngine:
         )
         width = self._global_prefix_len() + s + self.max_decode_len
         per_tok = self.kv_token_bytes()
-        total = width * per_tok
+        # A stream's fixed bytes beside what grows a token at a time: its
+        # row of recurrent state (0 without Mamba layers).
+        total = width * per_tok + self.stream_fixed_bytes()
         if getattr(cfg, "d_kv", None) is not None:
             # Encoder-decoder: cross-attention K/V over the encoder seq.
             total += s * per_tok

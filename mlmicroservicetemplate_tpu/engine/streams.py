@@ -141,7 +141,8 @@ def paged_insert(block_size: int):
     from ..models.gpt import PagedState
     from ..ops.paged_attention import scatter_pages
 
-    def insert(batched, single, table_row, slot, row, s_lo: int, s_cut: int):
+    def insert(batched, single, table_row, slot, row, s_lo: int, s_cut: int,
+               ssm_row=None):
         def scat(pool, src):
             srow = lax.dynamic_slice_in_dim(src, row, 1, axis=0)[0]
             return scatter_pages(
@@ -174,9 +175,31 @@ def paged_insert(block_size: int):
                 lambda d, s: _ins_row(d, s, slot, row),
                 batched.sample, single.sample,
             ),
+            **_insert_ssm(batched, single, slot, row, ssm_row),
         )
 
     return insert
+
+
+def _insert_ssm(batched, single, slot, row, ssm_row) -> dict:
+    """A wave row's recurrent state into state row ``ssm_row`` (past the
+    last row: dropped — warm-up), and the slot pointed at it; nothing for
+    a model without recurrent layers (its ``ssm`` is the empty default)."""
+    if ssm_row is None:
+        return {"ssm": batched.ssm}
+    import jax
+
+    def put(dst, src):  # [R, ...] <- row ``row`` of [Bw, ...]
+        return dst.at[ssm_row].set(
+            jax.lax.dynamic_index_in_dim(src, row, keepdims=False).astype(dst.dtype),
+            mode="drop")
+
+    b, s = batched.ssm, single.ssm
+    return {"ssm": b._replace(
+        conv=[put(d, x) for d, x in zip(b.conv, s.conv)],
+        state=[put(d, x) for d, x in zip(b.state, s.state)],
+        row=b.row.at[slot].set(ssm_row),
+    )}
 
 
 class StreamClosedError(Exception):
@@ -200,7 +223,7 @@ class _Stream:
         "skip", "tokens", "preempted", "t_in", "_removed",
         "blocks", "s_base", "s_lo", "shared_ids", "swap",
         "rid", "t_queued", "t_reserved", "t_emit", "done_journaled",
-        "tenant", "adapter_slot",
+        "tenant", "adapter_slot", "ssm_row",
     )
 
     # Admission-ledger marker: paged mode accounts streams via the
@@ -267,6 +290,10 @@ class _Stream:
         # at submit/adopt, released exactly once in _release.
         self.tenant = str(feats.get("tenant") or "")
         self.adapter_slot = 0
+        # Its row of the recurrent state (a model with Mamba layers): from
+        # its prompt's first window or its wave's insert until its blocks
+        # go back (_ssm_take / _ssm_give).
+        self.ssm_row: int | None = None
 
     def emit(self, item: Any) -> None:
         try:
@@ -466,8 +493,13 @@ class ContinuousDecodeLoop:
             -(-self.prefill_budget // self.prefill_chunk)
             if self.paged and self.prefill_chunk else 1
         )
-        # (window layers, window) of a per-layer pattern, or None.
         bcfg = getattr(engine.bundle, "cfg", None)
+        # Recurrent state rows (``_ssm_take``): one a slot — admission keeps
+        # the streams that can hold one (live or in prefill) to ``n_slots``.
+        self._ssm_free = None
+        if self.paged and getattr(bcfg, "mamba_layers", ()):
+            self._ssm_free = list(range(self.n_slots))[::-1]
+        # (window layers, window) of a per-layer pattern, or None.
         types = getattr(bcfg, "layer_types", ())
         self._window_layers = (
             (types.count("window"), int(bcfg.window))
@@ -1932,7 +1964,10 @@ class ContinuousDecodeLoop:
             self._swap_out(st)
             st.blocks.release()
         # A checkpointed stream holds NO ledger commitment while it
-        # waits (its reservation was released above by the caller).
+        # waits (its reservation was released above by the caller); its
+        # recurrent state row goes back too — the resume's prefill of the
+        # prompt above rebuilds the state by recompute.
+        self._ssm_give(st)
         st.blocks = None
         st.shared_ids = []
         st.s_lo = st.s_base = 0
@@ -2081,6 +2116,8 @@ class ContinuousDecodeLoop:
             real_tokens / max(1, rows * width)
         )
         metrics.PREFILL_WAVE_ROWS.labels(name).observe(rows)
+        if self._ssm_free is not None:
+            self._note_ssm_scan(rows * width, real_tokens)
 
     def _note_wave_stall(self, t_wave: float | None) -> None:
         """A monolithic wave (dispatch, fetch, emit + inserts) just
@@ -2606,7 +2643,12 @@ class ContinuousDecodeLoop:
                 return lax.dynamic_update_slice(dst, srcp, start)
 
             def handoff(batched, kv_row, w_idx, pos, last, done, toks, sp,
-                        slot):
+                        slot, ssm_row=None):
+                if ssm_row is not None:
+                    # The state is the stream's already (its windows wrote
+                    # its row): the slot is pointed at it, nothing moves.
+                    batched = batched._replace(ssm=batched.ssm._replace(
+                        row=batched.ssm.row.at[slot].set(ssm_row)))
                 return batched._replace(
                     key_valid=ins_row(batched.key_valid, kv_row, slot),
                     write_idx=ins_row(batched.write_idx, w_idx, slot),
@@ -2647,6 +2689,74 @@ class ContinuousDecodeLoop:
             return p_len + s_suf <= self.max_prompt
 
         return usable
+
+    # -- recurrent state rows (a model with Mamba layers) ---------------
+    # The decode state holds ``n_slots`` rows of recurrent state a Mamba
+    # layer (models/llama.SsmState).  Rows are streams', not slots': a
+    # stream takes one beside its blocks — at its prompt's first window,
+    # or at its wave's insert, before it has a slot — and gives it back
+    # with them.  There are as many rows as slots and no more, because a
+    # row's holder is live or in ``_prefilling`` and the admission loop
+    # keeps wave + active + prefilling + swapping <= n_slots; every decode
+    # step reads and writes every row, so a spare one would cost.  The row
+    # index rides into each window dispatch beside the block tables, and
+    # into the insert / handoff that points a slot at it; the decode step
+    # needs no host word (it reads liveness off the table it already
+    # gets).  ``_ssm_free`` is None for every other model: no row, no
+    # argument, no counter.
+
+    def _ssm_take(self, st: _Stream) -> None:
+        if self._ssm_free is None or st.ssm_row is not None:
+            return
+        st.ssm_row = self._ssm_free.pop()
+        if st.started:  # checkpointed earlier: this prefill rebuilds its state
+            metrics.SSM_STATE_RECOMPUTES.labels(self.engine.bundle.name).inc()
+        self._note_ssm_rows()
+
+    def _ssm_give(self, st: _Stream) -> None:
+        if self._ssm_free is None or st.ssm_row is None:
+            return
+        self._ssm_free.append(st.ssm_row)
+        st.ssm_row = None
+        self._note_ssm_rows()
+
+    def _note_ssm_rows(self) -> None:
+        if self._ssm_free is None:
+            return
+        name = self.engine.bundle.name
+        held = self.n_slots - len(self._ssm_free)
+        live = sum(1 for s in self.active.values() if s.ssm_row is not None)
+        for state, n in (("live", live), ("prefill", held - live),
+                         ("free", len(self._ssm_free))):
+            metrics.SSM_STATE_ROWS.labels(name, state).set(n)
+        metrics.SSM_STATE_BYTES.labels(name).set(
+            held * self.engine.bundle.cfg.ssm_row_bytes)
+
+    def _note_ssm_scan(self, scanned: int, real: int) -> None:
+        name = self.engine.bundle.name
+        metrics.SSM_SCAN_TOKENS.labels(name).inc(scanned)
+        metrics.SSM_SCAN_MASKED.labels(name).inc(scanned - real)
+
+    def _ssm_window_args(self, rows: int, jobs=(), ends=()) -> tuple:
+        """The trailing argument of a window dispatch: ``[rows, 2]`` —
+        each prompt's state row and how many of the window's tokens fold
+        into it (all, but for a prompt's last window, which leaves the
+        prompt's last token to the first decode step); a filled-up row,
+        and warm-up, name the row past the last and fold nothing."""
+        if self._ssm_free is None:
+            return ()
+        arg = np.zeros((rows, 2), np.int32)
+        arg[:, 0] = self.n_slots
+        for r, (job, end) in enumerate(zip(jobs, ends)):
+            arg[r] = (job.st.ssm_row, end - job.consumed - (end == job.L))
+        return (arg,)
+
+    def _ssm_row_arg(self, st: _Stream | None = None) -> tuple:
+        """The trailing argument of an insert or a handoff: the stream's
+        state row (warm-up: the row past the last)."""
+        if self._ssm_free is None:
+            return ()
+        return (np.int32(self.n_slots if st is None else st.ssm_row),)
 
     def _start_prefill_job(self, st: _Stream) -> None:
         """Create one chunked-prefill backlog job: match the prefix
@@ -2702,6 +2812,7 @@ class ContinuousDecodeLoop:
                     self.nb_max, self.pool.num_blocks, np.int32
                 )
                 job.table_row[: len(job.sb.ids)] = job.sb.ids
+                self._ssm_take(st)
             else:
                 from .engine import bucket_for
 
@@ -2733,6 +2844,7 @@ class ContinuousDecodeLoop:
             job.sb = None
             if self.admission is not None:
                 self.admission.note_pool()
+        self._ssm_give(job.st)
         job.state = None
 
     def _checkpoint_job(self, job: _PrefillJob) -> bool:
@@ -2843,11 +2955,14 @@ class ContinuousDecodeLoop:
                         lambda: self._paged_prefill_fn()(
                             jparams, self._state, jnp.asarray(tables),
                             ids_w, mask_w, starts,
+                            *self._ssm_window_args(rows, jobs, ends),
                         ),
                         donates=self._state,
                     )
                 if self.admission is not None:
                     self.admission.note_pool()
+                if self._ssm_free is not None:
+                    self._note_ssm_scan(rows * c, int(mask_w.sum()))
             else:
                 (job,) = jobs
                 with eng._lock:
@@ -2913,6 +3028,7 @@ class ContinuousDecodeLoop:
                         lambda: self._paged_handoff_fn()(
                             self._state, kv_row, w_idx, zero, last,
                             not_done, toks_row, sp, np.int32(slot),
+                            *self._ssm_row_arg(st),
                         ),
                         donates=self._state,
                     )
@@ -2946,6 +3062,7 @@ class ContinuousDecodeLoop:
             self._fail_preactive(st, e)
             return False
         self.active[slot] = st
+        self._note_ssm_rows()
         if sampled:
             self.sampled_slots.add(slot)
         # Chunked streams donate like monolithic admissions do at
@@ -3121,7 +3238,7 @@ class ContinuousDecodeLoop:
                             self._mp(n=b), self._state,
                             jnp.asarray(np.tile(table_row, (b, 1))),
                             np.tile(ids_w, (b, 1)), np.tile(mask_w, (b, 1)),
-                            np.zeros(b, np.int32),
+                            np.zeros(b, np.int32), *self._ssm_window_args(b),
                         )
                     self._state = self._paged_handoff_fn()(
                         self._state,
@@ -3129,7 +3246,7 @@ class ContinuousDecodeLoop:
                         np.zeros(1, np.int32), np.zeros(1, np.int32),
                         np.zeros(1, np.int32), np.ones(1, bool),
                         np.zeros((1, eng.max_decode_len), np.int32),
-                        sp, np.int32(0),
+                        sp, np.int32(0), *self._ssm_row_arg(),
                     )
             finally:
                 sb.release()
@@ -3290,6 +3407,23 @@ class ContinuousDecodeLoop:
                 template.sample,
             ),
         )
+        if self._ssm_free is not None:
+            # Recurrent state: zeroed rows, every slot pointed past the
+            # last.  The host's ledger of rows is not touched: a rebuild's
+            # streams give theirs back as they are dropped, and a row's
+            # content never outlives its holder (a first window starts
+            # from zeros, an insert overwrites).
+            t = template.ssm
+
+            def rows(leaves):  # [Bw, ...] a layer -> zeroed [R, ...]
+                return [np.zeros((self.n_slots,) + tuple(x.shape[1:]), x.dtype)
+                        for x in leaves]
+
+            empty = empty._replace(ssm=t._replace(
+                conv=rows(t.conv), state=rows(t.state),
+                row=np.full((self.n_slots,), self.n_slots, np.int32),
+            ))
+            self._note_ssm_rows()
         # Pool leaves commit sharded over 'tp' on the merged heads axis
         # under a TP placement (one logical pool, per-shard buffers — block
         # ids and the ledger stay device-agnostic); everything else
@@ -3478,6 +3612,7 @@ class ContinuousDecodeLoop:
                 self._state, state1,
                 jnp.full(self.nb_max, self.pool.num_blocks, jnp.int32),
                 np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
+                *self._ssm_row_arg(),
             ).compile().as_text()
 
     def _paged_insert_fn(self):
@@ -3555,16 +3690,19 @@ class ContinuousDecodeLoop:
             self._reclaim_then_ensure(sb, s_cut)
             table_row = np.full(self.nb_max, self.pool.num_blocks, np.int32)
             table_row[: len(sb.ids)] = sb.ids
+            self._ssm_take(st)
             with eng._lock:
                 new_state = eng.dispatch_guard(
                     "insert", lambda: self._paged_insert_fn()(
                         self._state, state1, jnp.asarray(table_row),
                         np.int32(slot), np.int32(row), st.s_lo, s_cut,
+                        *self._ssm_row_arg(st),
                     ),
                     donates=self._state,
                 )
         except BaseException:
             sb.release()
+            self._ssm_give(st)
             raise
         st.blocks = sb
         self._table[slot] = table_row
@@ -3611,6 +3749,8 @@ class ContinuousDecodeLoop:
         if st is not None and st.blocks is not None:
             st.blocks.release()
             st.blocks = None
+        if st is not None:
+            self._ssm_give(st)
         self._table[slot, :] = self.pool.num_blocks
         self._dispatched_steps.pop(slot, None)
         if self.admission is not None:
@@ -5313,6 +5453,7 @@ class ContinuousDecodeLoop:
                     self._state = insert(
                         self._state, state1, table_row(s),
                         np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
+                        *self._ssm_row_arg(),
                     )
                     jax.block_until_ready(self._state.done)
 

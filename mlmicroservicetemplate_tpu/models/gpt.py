@@ -228,6 +228,9 @@ class GPTState(NamedTuple):
     done: jax.Array  # [B] bool
     tokens: jax.Array  # [B, Tmax] generated tokens (pad-filled)
     sample: Any  # sampling.SampleParams, all [B]-shaped
+    # Recurrent state beside the cache (llama.SsmState; a config with
+    # Mamba layers): no leaf, and no trace in a lowered program, otherwise.
+    ssm: Any = ()
 
 
 def init_decode_state(
@@ -452,6 +455,9 @@ class PagedState(NamedTuple):
     done: jax.Array  # [B]
     tokens: jax.Array  # [B, Tmax]
     sample: Any
+    # llama.SsmState with its own R rows (rows are streams, not slots:
+    # ``row`` [B] names each slot's), or nothing: see GPTState.ssm.
+    ssm: Any = ()
 
 
 def _paged_dest(table: jax.Array, t: jax.Array, bs: int, nb: int) -> jax.Array:

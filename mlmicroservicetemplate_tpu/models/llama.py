@@ -32,7 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
-from typing import Any
+from typing import Any, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -186,6 +186,33 @@ class LlamaConfig:
     # stays ``num_experts`` wide and selects over all of them.
     experts_held: int = 0
     expert_first: int = 0
+    # Layers that are a mixer OR an FFN alone (Nemotron-H style):
+    # ``layer_pattern`` is one letter a layer, the published string cut to
+    # ``num_layers`` — "M" a Mamba-2 mixer (``ssm_heads`` heads of
+    # ``ssm_head_dim``, ``ssm_groups`` groups of B and C, ``ssm_state``
+    # wide, a causal depthwise convolution over ``ssm_conv`` taps, the scan
+    # in chunks of ``ssm_chunk``; ops/ssm.py), "*" an attention
+    # (``attention``; rotated unless ``nope_on_full``), "E" the expert FFN.
+    # Each is pre-norm with its own residual.  Only "*" layers have a cache
+    # entry, only "M" layers a recurrent state (``SsmState``), only "E"
+    # layers a row of the routing tally.  Empty = every layer is attention
+    # then FFN, as ever.
+    layer_pattern: str = ""
+    ssm_heads: int = 0
+    ssm_head_dim: int = 0
+    ssm_groups: int = 0
+    ssm_state: int = 0
+    ssm_conv: int = 4
+    ssm_chunk: int = 128
+    # The expert block's shape (ops/moe.py): ``expert_act`` "silu" (gated:
+    # gate, up, down) | "relu2" (non-gated ``down(relu(up x)^2)``, no gate
+    # stack), the shared expert alike; ``moe_latent`` > 0 puts the routed
+    # experts in a latent that wide between a down- and an up-projection
+    # the layer shares; ``d_ff_shared`` the shared expert's width where it
+    # is a size of its own (0 = ``num_shared_experts * d_ff``).
+    expert_act: str = "silu"
+    moe_latent: int = 0
+    d_ff_shared: int = 0
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -267,6 +294,35 @@ class LlamaConfig:
             raise ValueError(f"router_score={self.router_score!r}")
         if self.qk_norm not in (False, True, "head"):
             raise ValueError(f"qk_norm={self.qk_norm!r} (False, True, 'head')")
+        if self.expert_act not in ("silu", "relu2"):
+            raise ValueError(f"expert_act={self.expert_act!r} ('silu', 'relu2')")
+        pattern = self.layer_pattern[: self.num_layers]
+        object.__setattr__(self, "layer_pattern", pattern)
+        ssm_dims = (self.ssm_heads, self.ssm_head_dim, self.ssm_groups,
+                    self.ssm_state)
+        if pattern:
+            if (len(pattern) != self.num_layers or set(pattern) - set("M*E")
+                    or "*" not in pattern):
+                raise ValueError(
+                    f"layer_pattern must name each of the {self.num_layers} "
+                    "layers 'M' (Mamba-2), '*' (attention) or 'E' (experts) "
+                    f"with at least one '*' (the paged pool's layer), got "
+                    f"{pattern!r}")
+            if types or self.num_dense_layers or self.mla:
+                raise ValueError(
+                    "layer_pattern stands instead of layer_types / "
+                    "num_dense_layers, over attention='gqa'")
+            if "E" in pattern and not self.num_experts:
+                raise ValueError("an 'E' layer needs num_experts")
+            if "M" in pattern and (
+                    not all(d > 0 for d in ssm_dims) or self.ssm_conv < 2
+                    or self.ssm_heads % self.ssm_groups
+                    or self.ssm_inner % self.ssm_groups):
+                raise ValueError(
+                    "an 'M' layer needs ssm_heads (a multiple of ssm_groups), "
+                    f"ssm_head_dim, ssm_groups and ssm_state, got {ssm_dims}")
+        if "M" not in pattern and any(ssm_dims):
+            raise ValueError(f"Mamba sizes {ssm_dims} need an 'M' layer")
 
     @property
     def n_rep(self) -> int:
@@ -321,7 +377,38 @@ class LlamaConfig:
         """Experts the tree holds (all of them unless ``experts_held``)."""
         return self.experts_held or self.num_experts
 
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a Mamba layer's inner stream (its heads merged)."""
+        return self.ssm_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_dim(self) -> int:
+        """What the convolution runs over: [x ; B ; C]."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def mamba_layers(self) -> tuple:
+        return tuple(li for li, c in enumerate(self.layer_pattern) if c == "M")
+
+    @property
+    def ssm_row_bytes(self) -> int:
+        """Bytes of recurrent state one stream holds (0: no Mamba layer):
+        a layer's [H, P, N] float32 state and its K-1 convolution taps in
+        the cache's two-byte dtype."""
+        return len(self.mamba_layers) * (
+            self.ssm_inner * self.ssm_state * 4
+            + (self.ssm_conv - 1) * self.ssm_conv_dim * 2)
+
     def layer_kind(self, li: int) -> "LayerKind":
+        if self.layer_pattern:
+            c = self.layer_pattern[li]
+            return LayerKind(
+                window=0, rope=c == "*" and not self.nope_on_full,
+                experts=c == "E", d_ff=self.d_ff if c == "E" else 0,
+                attention=self.attention if c == "*" else "",
+                mamba=c == "M", ffn=c == "E",
+            )
         window = self.window if (
             self.layer_types and self.layer_types[li] == "window") else 0
         dense = li < self.num_dense_layers
@@ -344,13 +431,49 @@ class LayerKind:
     """What one layer is: ``window`` keys a query sees (0 = all before
     it), whether q and k are rotated, its FFN (``experts``: the
     sparse expert block of experts ``d_ff`` wide; else a dense SwiGLU of
-    width ``d_ff``) and its ``attention`` kind ("gqa" | "mla")."""
+    width ``d_ff``) and its ``attention`` kind ("gqa" | "mla").  Under a
+    ``layer_pattern`` a layer is ONE of these alone: ``mamba`` (a Mamba-2
+    mixer; ``attention`` is then "") or an attention, or — ``ffn`` — the
+    FFN (``attention`` "" as well)."""
 
     window: int
     rope: bool
     experts: bool
     d_ff: int
     attention: str = "gqa"
+    mamba: bool = False
+    ffn: bool = True
+
+    @property
+    def mixer(self) -> str | None:
+        return "mamba2" if self.mamba else (self.attention or None)
+
+
+class SsmState(NamedTuple):
+    """The recurrent state of a config with Mamba layers, a decode
+    state's ``ssm`` field: per Mamba layer the convolution's taps ``conv``
+    [R, K-1, conv_dim] and the state ``state`` [R, H, P, N] float32, R
+    rows.  A wave's or the contiguous slab's state has a row a batch row.
+    The paged loop's has its own R (the streams it can hold, prompts in
+    prefill among them) and ``row`` [B] names each slot's row: a stream
+    takes a row when its prompt's first window runs and keeps it until it
+    ends, so going live moves no state."""
+
+    conv: Any
+    state: Any
+    row: jax.Array
+
+
+def zero_ssm(cfg: "LlamaConfig", rows: int, dtype):
+    """``rows`` zeroed state rows, ``row`` the identity (``()``, a decode
+    state's empty default, for a config without Mamba layers)."""
+    m = len(cfg.mamba_layers)
+    if not m:
+        return ()
+    conv = jnp.zeros((rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
+    state = jnp.zeros(
+        (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
+    return SsmState([conv] * m, [state] * m, jnp.arange(rows, dtype=jnp.int32))
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -408,7 +531,12 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         def extra(n):  # leaves newer than the 7-way split: their own keys
             return jax.random.fold_in(lk, n)
 
-        if cfg.mla:
+        if kind.mamba:
+            params["layers"].append(_init_mamba(cfg, extra, lin, norm_scale, cast))
+            continue
+        if not kind.attention:
+            attn = None
+        elif cfg.mla:
             # W_UKV [r, H x (nope + v)] is drawn whole and split ONCE, here,
             # into the two operands the absorbed step contracts against:
             # k_b [H, nope, r] (q_nope -> the latent's space) and v_b
@@ -433,49 +561,88 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
                 "v": lin(k[2], d, kv_dim),
                 "o": lin(k[3], qd, d),
             }
-        if cfg.qk_norm:
+        if cfg.qk_norm and attn is not None:
             # Learned scales have no reason to be 1: drawn about it, so a
             # served path that drops the norm departs from one that has it.
             per_head = cfg.qk_norm == "head"
             attn["q_norm"] = norm_scale(extra(8), cfg.head_dim if per_head else qd)
             attn["k_norm"] = norm_scale(extra(9), cfg.head_dim if per_head else kv_dim)
-        if cfg.attn_gate:
+        if cfg.attn_gate and attn is not None:
             attn["gate"] = lin(extra(10), d, qd)
-        if kind.experts:
+        gated = cfg.expert_act == "silu"
+        if not kind.ffn:
+            mlp = None
+        elif kind.experts:
+            dl = cfg.moe_latent or d  # the width the routed experts see
             mlp = {
                 "router": lin(extra(7), d, e),  # the published width
-                "gate": experts(k[4], (held, d, w)),
-                "up": experts(k[5], (held, d, w)),
-                "down": experts(k[6], (held, w, d)),
+                "up": experts(k[5], (held, dl, w)),
+                "down": experts(k[6], (held, w, dl)),
             }
+            if gated:
+                mlp["gate"] = experts(k[4], (held, dl, w))
+            if cfg.moe_latent:
+                mlp["latent_down"] = lin(extra(20), d, dl)
+                mlp["latent_up"] = lin(extra(21), dl, d)
             if cfg.router_bias:
                 # The buffer of aux-loss-free balancing: large enough
                 # beside sigmoid scores that dropping it moves the choice.
                 mlp["router_bias"] = cast(normal_init(extra(13), (e,), std=0.05))
             if cfg.num_shared_experts:
-                ws = cfg.num_shared_experts * w
+                ws = cfg.d_ff_shared or cfg.num_shared_experts * w
                 mlp["shared"] = {
-                    "gate": lin(extra(14), d, ws),
                     "up": lin(extra(15), d, ws),
                     "down": lin(extra(16), ws, d),
                 }
+                if gated:
+                    mlp["shared"]["gate"] = lin(extra(14), d, ws)
         else:
             mlp = {
                 "gate": lin(k[4], d, w),
                 "up": lin(k[5], d, w),
                 "down": lin(k[6], w, d),
             }
-        layer = {
-            "attn_ln": cast(rmsnorm_init(d)),
-            "attn": attn,
-            "mlp_ln": cast(rmsnorm_init(d)),
-            "mlp": mlp,
-        }
+        layer = {}
+        if attn is not None:
+            layer.update(attn_ln=cast(rmsnorm_init(d)), attn=attn)
+        if mlp is not None:
+            layer.update(mlp_ln=cast(rmsnorm_init(d)), mlp=mlp)
         if cfg.sandwich_norm:
             layer["attn_post_ln"] = norm_scale(extra(11), d)
             layer["mlp_post_ln"] = norm_scale(extra(12), d)
         params["layers"].append(layer)
     return params
+
+
+def _init_mamba(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
+    """One Mamba-2 layer's leaves.  ``in`` is [z | x B C | dt] wide.  Drawn
+    so that each rule of the block shows in the output when broken:
+    ``A_log`` with ``-exp(A_log)`` uniform in [-16, -1]; ``dt_bias`` the
+    inverse softplus of a step log-uniform in [0.001, 0.1] (what the
+    published ``time_step_min / max`` initialise; they clamp nothing);
+    ``D`` ones; the convolution's taps normal 0.4 and its bias normal 0.5
+    beside inputs of about unit size; the gated norm's scale about 1."""
+    d, inner, h = cfg.d_model, cfg.ssm_inner, cfg.ssm_heads
+    step = jnp.exp(jax.random.uniform(
+        extra(25), (h,), minval=jnp.log(0.001), maxval=jnp.log(0.1)))
+    return {
+        "ssm_ln": cast(rmsnorm_init(d)),
+        "ssm": {
+            "in": lin(extra(22), d, inner + cfg.ssm_conv_dim + h),
+            "conv": {
+                "kernel": cast(normal_init(
+                    extra(23), (cfg.ssm_conv, cfg.ssm_conv_dim), std=0.4)),
+                "bias": cast(normal_init(extra(24), (cfg.ssm_conv_dim,), std=0.5)),
+            },
+            # float32 whatever the tree's dtype: a head's scalars.
+            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jax.random.uniform(
+                extra(26), (h,), minval=1.0, maxval=16.0)),
+            "D": jnp.ones((h,), jnp.float32),
+            "norm": norm_scale(extra(27), inner),
+            "out": lin(extra(28), inner, d),
+        },
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -580,6 +747,7 @@ def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
                 interpret=cfg.pallas_interpret, score=cfg.router_score,
                 route_scale=cfg.route_scale, n_group=cfg.n_group,
                 topk_group=cfg.topk_group, expert_first=cfg.expert_first,
+                act=cfg.expert_act,
             )
             if tally is not None:
                 tally.append(counts)
@@ -836,7 +1004,7 @@ def _attn_scope(cfg: "LlamaConfig", li: int):
     attention runs under in every step kind."""
     stack = contextlib.ExitStack()
     stack.enter_context(jax.named_scope("attn"))
-    if cfg.layer_types:
+    if cfg.layer_types or cfg.layer_pattern:
         stack.enter_context(jax.named_scope(
             "attn_window" if cfg.layer_kind(li).window else "attn_full"))
     return stack
@@ -846,6 +1014,109 @@ def _band(q_pos, k_pos, window: int):
     """[..., Q, K] bool: key position within ``window`` keys of the
     query's (itself included); the causal side is the caller's mask."""
     return q_pos[..., :, None] - k_pos[..., None, :] < window
+
+
+def _ssm_delta(dt, bias):
+    """A Mamba head's step: ``softplus(dt + dt_bias)``, positive."""
+    return jax.nn.softplus(dt + bias)
+
+
+def _ssm_gate_norm(y, z, scale, groups: int, eps: float):
+    """``RMSNorm_group(y * silu(z)) * scale`` on [..., inner] float32: the
+    gate FIRST, then a norm over each group's share of the width."""
+    shape = y.shape
+    y = (y * jax.nn.silu(z)).reshape(*shape[:-1], groups, shape[-1] // groups)
+    y = y * jax.lax.rsqrt(jnp.mean(jnp.square(y), axis=-1, keepdims=True) + eps)
+    return y.reshape(shape) * scale
+
+
+def _mamba_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
+    """Pre-norm Mamba-2 mixer with its residual, under the ``ssm`` scope:
+    x [B, L, D] from each row's taps ``conv`` [B, K-1, conv_dim] and state
+    ``s`` [B, H, P, N] -> (x + out, conv', s').  ``mask`` [B, L] (1 on a
+    prefix of real tokens): a window or a wave, the chunked scan;
+    ``live`` [B] instead (L = 1): the decode step's one-token update, a
+    row that is not live neither moving its state nor reading it wrong
+    (ops/ssm.py).  ``y = RMSNorm_group(y * silu(z))``: the gate first,
+    then a norm over each group's share of the inner width."""
+    from ..ops import ssm
+
+    m = layer["ssm"]
+    b, length = x.shape[:2]
+    inner, cd = cfg.ssm_inner, cfg.ssm_conv_dim
+    hn, g, n = cfg.ssm_heads, cfg.ssm_groups, cfg.ssm_state
+    f32 = jnp.float32
+    with jax.named_scope("ssm"):
+        u = rmsnorm(layer["ssm_ln"], x, eps=cfg.rms_eps)
+        with jax.named_scope("ssm_in_proj"):
+            zxd = dense(m["in"], u)
+            z, xbc, dt = zxd[..., :inner], zxd[..., inner:inner + cd], zxd[..., inner + cd:]
+        w, bias = m["conv"]["kernel"], m["conv"]["bias"]
+        with jax.named_scope("ssm_conv"):
+            if live is None:
+                xbc, conv = ssm.conv_scan(xbc, conv, w, bias, mask)
+            else:
+                y1, conv = ssm.conv_step(xbc[:, 0], conv, w, bias, live)
+                xbc = y1[:, None]
+        xs = xbc[..., :inner].reshape(b, length, hn, cfg.ssm_head_dim)
+        bm = xbc[..., inner:inner + g * n].reshape(b, length, g, n)
+        cm = xbc[..., inner + g * n:].reshape(b, length, g, n)
+        dt = _ssm_delta(dt.astype(f32), m["dt_bias"].astype(f32))
+        a = -jnp.exp(m["A_log"].astype(f32))
+        if live is None:
+            with jax.named_scope("ssm_scan"):
+                y, s = ssm.ssm_scan(xs, dt, a, bm, cm, m["D"], s, mask,
+                                    chunk=cfg.ssm_chunk)
+        else:
+            with jax.named_scope("ssm_step"):
+                y, s = ssm.ssm_step(xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
+                                    m["D"], s, live)
+                y = y[:, None]
+        with jax.named_scope("ssm_gate_norm"):
+            y = _ssm_gate_norm(
+                y.reshape(b, length, inner), z.astype(f32),
+                m["norm"]["scale"].astype(f32), g, cfg.rms_eps).astype(x.dtype)
+        with jax.named_scope("ssm_out_proj"):
+            return x + dense(m["out"], y), conv, s
+
+
+def _layers(params: Params, cfg: "LlamaConfig", x, attend, mamba, valid,
+            tally=None):
+    """x through every layer, each running the sub-blocks its kind has
+    (``cfg.layer_kind``): its mixer — ``mamba(layer, x)`` or ``attend(li,
+    layer, x)``, the step kind's own closures over its cache and state,
+    each with its residual — then its FFN (``_mlp_block``; ``valid()``
+    gives its rows' mask, asked for where an FFN runs).  The one walk every
+    step kind makes."""
+    for li, layer in enumerate(params["layers"]):
+        kind = cfg.layer_kind(li)
+        if kind.mamba:
+            x = mamba(layer, x)
+        elif kind.attention:
+            x = attend(li, layer, x)
+        if kind.ffn:
+            x = _mlp_block(cfg, layer, li, x, valid(), tally)
+    return x
+
+
+def _ssm_walker(cfg: "LlamaConfig", ssm, run):
+    """``(mamba, done)``: ``mamba(layer, x)`` runs the next Mamba layer
+    through ``run(layer, x, conv, s) -> (x, conv', s')`` on that layer's
+    entries of ``ssm``; ``done()`` is ``ssm`` with what the layers left
+    (``()`` for a config without any)."""
+    convs, states = [], []
+
+    def mamba(layer, x):
+        i = len(convs)
+        x, conv, s = run(layer, x, ssm.conv[i], ssm.state[i])
+        convs.append(conv)
+        states.append(s)
+        return x
+
+    def done():
+        return ssm._replace(conv=convs, state=states) if convs else ssm
+
+    return mamba, done
 
 
 # ---------------------------------------------------------------------------
@@ -900,8 +1171,17 @@ def forward_hidden(
     dtype=jnp.float32,
     collect_kv: bool = False,
     prefix_kv=None,  # optional list[(k,v)] of [1, P, KVH, D] cached prefix
+    ssm_out: list | None = None,
 ):
-    """Hidden states [B, S, D] (+ per-layer ROTATED prompt K / V).
+    """Hidden states [B, S, D] (+ per-layer ROTATED prompt K / V, an entry
+    an ATTENTION layer).  A list given as ``ssm_out`` receives the
+    ``SsmState`` (B rows) a decode state starts from: what the Mamba
+    layers' scans from zeros leave BEFORE each row's last real token —
+    the first decode step embeds that token again (``write_idx`` is its
+    position), which a cache entry bears and a recurrence does not.  The
+    hidden states at that one position are then no forward pass's (its
+    keys are written again by that step): a call that reads them gives
+    no ``ssm_out``.
 
     With ``prefix_kv`` the batch is the SUFFIX of a shared cached
     prompt prefix: tokens take rotary positions P.., queries attend to
@@ -923,32 +1203,41 @@ def forward_hidden(
     band = mask & _band(pos, jnp.arange(p_len + s), cfg.window) if cfg.window else None
     ad = lora.adapter_tables(params)
     kv = []
-    for li, layer in enumerate(params["layers"]):
+
+    def attend(li, layer, x):
         q, k, v, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         if collect_kv:
             kv.append((k, v))
-        if cfg.mla:  # k is the window's latent rows: expanded attention
-            with _attn_scope(cfg, li):
-                ctx = _mla_expanded_attention(cfg, layer, q, k, mask)
-            x = _attn_out(cfg, layer, ad, li, x, ctx, g)
-            x = _mlp_block(cfg, layer, li, x, attention_mask != 0)
-            continue
         with _attn_scope(cfg, li):
-            if p_len:
-                pk = _dequant_prefix(prefix_kv[li][0], k.dtype)
-                pv = _dequant_prefix(prefix_kv[li][1], v.dtype)
-                k = jnp.concatenate(
-                    [jnp.broadcast_to(pk, (b,) + pk.shape[1:]), k], axis=1
+            if cfg.mla:  # k is the window's latent rows: expanded attention
+                ctx = _mla_expanded_attention(cfg, layer, q, k, mask)
+            else:
+                if p_len:
+                    pk = _dequant_prefix(prefix_kv[li][0], k.dtype)
+                    pv = _dequant_prefix(prefix_kv[li][1], v.dtype)
+                    k = jnp.concatenate(
+                        [jnp.broadcast_to(pk, (b,) + pk.shape[1:]), k], axis=1
+                    )
+                    v = jnp.concatenate(
+                        [jnp.broadcast_to(pv, (b,) + pv.shape[1:]), v], axis=1
+                    )
+                ctx = mha_attention(
+                    q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep),
+                    mask=band if cfg.layer_kind(li).window else mask,
                 )
-                v = jnp.concatenate(
-                    [jnp.broadcast_to(pv, (b,) + pv.shape[1:]), v], axis=1
-                )
-            ctx = mha_attention(
-                q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep),
-                mask=band if cfg.layer_kind(li).window else mask,
-            )
-        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
-        x = _mlp_block(cfg, layer, li, x, attention_mask != 0)
+        return _attn_out(cfg, layer, ad, li, x, ctx, g)
+
+    ssm_mask = attention_mask
+    if ssm_out is not None and cfg.mamba_layers:
+        ssm_mask = attention_mask * (
+            jnp.arange(s)[None, :] < attention_mask.sum(axis=-1, keepdims=True) - 1)
+    mamba, ssm_done = _ssm_walker(
+        cfg, zero_ssm(cfg, b, dtype),
+        lambda layer, x, conv, st: _mamba_block(
+            cfg, layer, x, conv, st, mask=ssm_mask))
+    x = _layers(params, cfg, x, attend, mamba, lambda: attention_mask != 0)
+    if ssm_out is not None:
+        ssm_out.append(ssm_done())
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     return (x, kv) if collect_kv else x
 
@@ -991,9 +1280,10 @@ def init_decode_state(
     p_len = _prefix_entry_len(pre["k"][0]) if pre is not None else 0
     prefix_kv = list(zip(pre["k"], pre["v"])) if pre is not None else None
     total = p_len + s + max_len
+    ssm_out: list = []
     _, kv = forward_hidden(
         params, cfg, input_ids, attention_mask, dtype,
-        collect_kv=True, prefix_kv=prefix_kv,
+        collect_kv=True, prefix_kv=prefix_kv, ssm_out=ssm_out,
     )
     cache_k, cache_v = [], []
     with jax.named_scope("kv_write"):
@@ -1059,6 +1349,7 @@ def init_decode_state(
         done=lengths == 0,
         tokens=jnp.full((b, max_len), cfg.pad_id, jnp.int32),
         sample=sample if sample is not None else greedy_params(b),
+        ssm=ssm_out[0],
     )
 
 
@@ -1133,12 +1424,14 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
-    for li, layer in enumerate(params["layers"]):
+
+    def attend(li, layer, x):
+        ai = len(new_k)  # the layer's cache entry: one an attention layer
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
-            ck = _write_kv(state.cache_k[li], rows, t, k1[:, 0], dtype)
+            ck = _write_kv(state.cache_k[ai], rows, t, k1[:, 0], dtype)
             if not cfg.mla:
-                cv = _write_kv(state.cache_v[li], rows, t, v1[:, 0], dtype)
+                cv = _write_kv(state.cache_v[ai], rows, t, v1[:, 0], dtype)
                 new_v.append(cv)
         new_k.append(ck)
         with jax.named_scope("attn"):
@@ -1146,8 +1439,12 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
                 ctx = _mla_decode_attention(cfg, layer, q, ck, None, key_valid, 0)
             else:
                 ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
-        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
-        x = _mlp_block(cfg, layer, li, x, ~state.done[:, None])
+        return _attn_out(cfg, layer, ad, li, x, ctx, g)
+
+    mamba, ssm_done = _ssm_walker(
+        cfg, state.ssm, lambda layer, x, conv, st: _mamba_block(
+            cfg, layer, x, conv, st, live=~state.done))
+    x = _layers(params, cfg, x, attend, mamba, lambda: ~state.done[:, None])
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
@@ -1161,6 +1458,7 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
             done=done,
             tokens=tokens,
             sample=sp,
+            ssm=ssm_done(),
         ),
         next_tok,
     )
@@ -1391,12 +1689,14 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
 
     ad = lora.adapter_tables(params)
     new_k, new_v, moe_tally = [], [], []
-    for li, layer in enumerate(params["layers"]):
+
+    def attend(li, layer, x):
+        ai = len(new_k)  # the layer's pool: one an attention layer
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         with jax.named_scope("kv_write"):
-            ck = _paged_write_kv(state.cache_k[li], table, t, k1[:, 0], bs, dtype)
+            ck = _paged_write_kv(state.cache_k[ai], table, t, k1[:, 0], bs, dtype)
             if not cfg.mla:  # a latent row is written once: there is no V pool
-                cv = _paged_write_kv(state.cache_v[li], table, t, v1[:, 0], bs, dtype)
+                cv = _paged_write_kv(state.cache_v[ai], table, t, v1[:, 0], bs, dtype)
                 new_v.append(cv)
         new_k.append(ck)
         with _attn_scope(cfg, li):
@@ -1406,18 +1706,54 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
                 ctx = _paged_cache_attention(
                     cfg, q, ck, cv, *(view if cfg.layer_kind(li).window else full), bs
                 )
-        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
-        x = _mlp_block(cfg, layer, li, x, ~state.done[:, None], moe_tally)
+        return _attn_out(cfg, layer, ad, li, x, ctx, g)
+
+    mamba, ssm_done = _ssm_walker(
+        cfg, state.ssm, _paged_ssm_step(cfg, state, table) if cfg.mamba_layers
+        else None)
+    x = _layers(params, cfg, x, attend, mamba, lambda: ~state.done[:, None],
+                moe_tally)
     x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
         PagedState(
             cache_k=new_k, cache_v=new_v, key_valid=key_valid,
             write_idx=t + 1, pos=state.pos + 1, last_token=next_tok,
-            done=done, tokens=tokens, sample=sp,
+            done=done, tokens=tokens, sample=sp, ssm=ssm_done(),
         ),
         (next_tok, jnp.stack(moe_tally)) if moe_tally else next_tok,
     )
+
+
+def _paged_ssm_step(cfg: LlamaConfig, state, table):
+    """A paged decode step's Mamba layer, ``run`` of ``_ssm_walker``.  The
+    state rows stay where they lie and are updated in place, ALL ``R`` of
+    them under a mask: the step's small per-slot rows (the residual
+    stream) are gathered to their state rows and the layer's output back
+    to the slots — a stream's 4 MB a layer is never gathered.  A slot is
+    live while it decodes (``done`` false) and holds blocks (its table row
+    is not the sentinel: the host clears it when the stream ends, so a
+    freed slot's stale ``row`` can touch no row given to another prompt);
+    the rows of dead slots, of prompts in prefill and the free ones do not
+    move."""
+    b = state.done.shape[0]
+    nb = jax.tree.leaves(state.cache_k)[0].shape[0]
+    n_rows = state.ssm.state[0].shape[0]
+    live = ~state.done & (table[:, 0] < nb)
+    slot_of = jnp.full((n_rows,), b, jnp.int32).at[
+        jnp.where(live, state.ssm.row, n_rows)].set(
+            jnp.arange(b, dtype=jnp.int32), mode="drop")
+    live_r = slot_of < b
+    from_slot = jnp.minimum(slot_of, b - 1)
+    to_slot = jnp.minimum(state.ssm.row, n_rows - 1)
+
+    def run(layer, x, conv, st):
+        y, conv, st = _mamba_block(
+            cfg, layer, jnp.take(x, from_slot, axis=0), conv, st, live=live_r)
+        return jnp.where(
+            live[:, None, None], jnp.take(y, to_slot, axis=0), x), conv, st
+
+    return run
 
 
 def generate_chunk_paged(params: Params, cfg: LlamaConfig, state, table,
@@ -1475,17 +1811,18 @@ def empty_decode_state(
 
     total = s_total + max_len
     shape = (batch, total, cfg.num_kv_heads, cfg.head_dim)
+    cached = [li for li in range(cfg.num_layers) if cfg.layer_kind(li).attention]
     if cfg.kv_quant:
         cache_k = [
             (jnp.zeros(shape, jnp.int8), jnp.ones(shape[:3] + (1,), dtype))
-            for _ in params["layers"]
+            for _ in cached
         ]
         cache_v = [
             (jnp.zeros(shape, jnp.int8), jnp.ones(shape[:3] + (1,), dtype))
-            for _ in params["layers"]
+            for _ in cached
         ]
     else:
-        cache_k = [jnp.zeros(shape, dtype) for _ in params["layers"]]
+        cache_k = [jnp.zeros(shape, dtype) for _ in cached]
         cache_v = list(cache_k)
     return GPTState(
         cache_k=cache_k,
@@ -1497,6 +1834,7 @@ def empty_decode_state(
         done=jnp.ones((batch,), bool),
         tokens=jnp.full((batch, max_len), cfg.pad_id, jnp.int32),
         sample=greedy_params(batch),
+        ssm=zero_ssm(cfg, batch, dtype),
     )
 
 
@@ -1528,15 +1866,23 @@ def prefill_chunk(
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
-    for li, layer in enumerate(params["layers"]):
+
+    def attend(li, layer, x):
+        ai = len(new_k)
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
-        ck = _write_kv(state.cache_k[li], rows, pos_w, k1, dtype)
-        cv = _write_kv(state.cache_v[li], rows, pos_w, v1, dtype)
+        ck = _write_kv(state.cache_k[ai], rows, pos_w, k1, dtype)
+        cv = _write_kv(state.cache_v[ai], rows, pos_w, v1, dtype)
         new_k.append(ck)
         new_v.append(cv)
         ctx = _cache_attention(cfg, q, ck, cv, mask)
-        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
-        x = _mlp_block(cfg, layer, li, x, chunk_mask != 0)
+        return _attn_out(cfg, layer, ad, li, x, ctx, g)
+
+    if cfg.mamba_layers:
+        # registry refuses PAGED_KV=0 for it: this window has no way to
+        # leave a prompt's last token out of the state (forward_hidden).
+        raise NotImplementedError(
+            "Mamba layers prefill in windows through paged_prefill_chunk only")
+    _layers(params, cfg, x, attend, None, lambda: chunk_mask != 0)
     key_valid = state.key_valid.at[rows, pos_w].set(
         chunk_mask.astype(jnp.int32), mode="drop"
     )
@@ -1624,6 +1970,7 @@ def paged_prefill_chunk(
     chunk_mask: jax.Array,  # [B, C]
     starts: jax.Array,  # [B]
     dtype=jnp.float32,
+    ssm_rows: jax.Array | None = None,  # [B, 2] each prompt's (state row, tokens to fold)
 ):
     """One prompt window each of ``B`` different prompts straight into
     pool blocks (see ``gpt.paged_prefill_chunk``), at GQA width and
@@ -1641,7 +1988,15 @@ def paged_prefill_chunk(
     executable a batch width serves every window of every prompt.
     Without the kernels, and over an int8 pool, the same keys in XLA
     under ``_prefill_mask`` ([H, C, K] float32 scores: the tests'
-    reference, no served path on the chip)."""
+    reference, no served path on the chip).  With Mamba layers each row's
+    scan continues the state its prompt's earlier windows left in row
+    ``ssm_rows[r, 0]`` of ``state.ssm`` — from zeros where ``starts[r]`` is
+    0, whatever the row held: a finished stream's state cannot leak — and
+    writes it back there, having folded in the window's first
+    ``ssm_rows[r, 1]`` tokens: all of them, but for a prompt's LAST window,
+    which leaves the prompt's last token to the first decode step
+    (``forward_hidden``'s rule).  A filled-up row (no token; its row index
+    past the last row) reads a clamped row and writes none."""
     from ..ops.paged_attention import gather_pages
 
     b, c = chunk_ids.shape
@@ -1683,11 +2038,13 @@ def paged_prefill_chunk(
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
-    for li, layer in enumerate(params["layers"]):
+
+    def attend_rows(li, layer, x):
+        ai = len(new_k)
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
         # Every row's keys land before any row attends: the pool is
         # written in place, then only read.
-        ck, cv = state.cache_k[li], None if cfg.mla else state.cache_v[li]
+        ck, cv = state.cache_k[ai], None if cfg.mla else state.cache_v[ai]
         with jax.named_scope("kv_write"):
             for r in range(b):
                 ck = _paged_scatter_entry(ck, table_rows[r], k1[r], bs, starts[r], dtype)
@@ -1701,9 +2058,25 @@ def paged_prefill_chunk(
             ctx = [attend(layer, r, jax.tree.map(lambda a: a[r:r + 1], q), ck, cv, window)
                    for r in range(b)]
             ctx = ctx[0] if b == 1 else jnp.concatenate(ctx, axis=0)
-        x = _attn_out(cfg, layer, ad, li, x, ctx, g)
-        x = _mlp_block(cfg, layer, li, x, chunk_mask != 0)
-    return state._replace(cache_k=new_k, cache_v=new_v)
+        return _attn_out(cfg, layer, ad, li, x, ctx, g)
+
+    def scan(layer, x, conv, st):
+        at, fold = ssm_rows[:, 0], ssm_rows[:, 1]
+
+        def rows_of(a):  # each prompt's row; zeros for a first window
+            first = (starts == 0).reshape((b,) + (1,) * (a.ndim - 1))
+            return jnp.where(first, 0, jnp.take(a, at, axis=0, mode="clip"))
+
+        x, conv1, st1 = _mamba_block(
+            cfg, layer, x, rows_of(conv), rows_of(st),
+            mask=jnp.arange(c)[None, :] < fold[:, None])
+        return (x, conv.at[at].set(conv1, mode="drop"),
+                st.at[at].set(st1, mode="drop"))
+
+    mamba, ssm_done = _ssm_walker(cfg, state.ssm, scan)
+    _layers(params, cfg, x, attend_rows, mamba, lambda: chunk_mask != 0)
+    return state._replace(cache_k=new_k, cache_v=new_v,
+                          ssm=ssm_done())
 
 
 def init_paged_state(
@@ -1728,8 +2101,10 @@ def init_paged_state(
 
     b, s = input_ids.shape
     t_w = table.shape[1]
+    ssm_out: list = []
     _, kv = forward_hidden(
-        params, cfg, input_ids, attention_mask, dtype, collect_kv=True
+        params, cfg, input_ids, attention_mask, dtype, collect_kv=True,
+        ssm_out=ssm_out,
     )
     cache_k, cache_v = [], []
     # ops/paged_attention's layout rule: payload [NB, BS, KVH*D],
@@ -1780,4 +2155,5 @@ def init_paged_state(
         done=lengths == 0,
         tokens=jnp.full((b, max_len), cfg.pad_id, jnp.int32),
         sample=sample if sample is not None else greedy_params(b),
+        ssm=ssm_out[0],
     )

@@ -317,6 +317,8 @@ def _refuse_cache_readers(svc_cfg, what: str, whys: dict) -> None:
         "QUANT_KV": getattr(svc_cfg, "quant_kv", None),
         "PREFIX_CACHE": getattr(svc_cfg, "prefix_cache", False),
         "PROMPT_PREFIX": getattr(svc_cfg, "prompt_prefix", None),
+        "KV_HOST_BUDGET_MB": getattr(svc_cfg, "kv_host_budget_mb", 0),
+        "KV_DISK_BUDGET_MB": getattr(svc_cfg, "kv_disk_budget_mb", 0),
     }
     for knob, why in whys.items():
         if on[knob]:
@@ -922,6 +924,7 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             ("latent attention", cfg.mla),
             ("a group limit on the router", cfg.n_group > 1),
             ("a chip's share of the experts", cfg.experts_held),
+            ("mixer-or-FFN layers (layer_pattern)", cfg.layer_pattern),
         ) if on
     ]
     if variants:
@@ -979,6 +982,33 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
                 "read K and V per head",
                 "PROMPT_PREFIX": "the prefix overlay's prefill reads K and V "
                 "per head",
+            })
+    if cfg.mamba_layers:
+        # A Mamba layer's state is a fixed-size row a stream beside the
+        # cache, carried by the prefill waves, the chunked paged prefill
+        # and the paged decode step (models/llama.py, engine/streams.py's
+        # state rows).  Every other reader or mover of a stream's state
+        # knows keys and values only and would drop, share or skip the
+        # recurrence in silence: refuse it.  TP>1 (no spec shards Mamba
+        # heads) and QUANTIZE refuse above.
+        _refuse_cache_readers(
+            svc_cfg, "Mamba layers (layer_pattern 'M')", {
+                "PAGED_KV=0": "the contiguous slab's chunked prefill cannot "
+                "leave a prompt's last token out of the recurrent state: set "
+                "PAGED_KV=1",
+                "SPEC_DECODE": "speculative verification (llama.multi_step) "
+                "cannot roll a recurrent state back over rejected tokens",
+                "QUANT_KV": "the int8 pool pairs were never run beside a "
+                "recurrent state",
+                "PREFIX_CACHE": "a prefix hit shares blocks of keys; the "
+                "recurrent state at the prefix's end is kept nowhere",
+                "PROMPT_PREFIX": "the prefix overlay holds keys and values, "
+                "no recurrent state at its end",
+                "KV_HOST_BUDGET_MB": "the swap tiers move blocks of keys; a "
+                "resumed stream's recurrent state would be missing (it is "
+                "rebuilt by recompute instead: leave the tiers off)",
+                "KV_DISK_BUDGET_MB": "the disk tier moves blocks of keys, no "
+                "recurrent state",
             })
     if cfg.num_experts and not _pallas_backend_ok(svc_cfg):
         raise RuntimeError(
@@ -1046,9 +1076,11 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             p, cfg, state, ids, mask, start, dtype=policy.compute_jnp
         )
 
-    def paged_prefill_chunk_fn(p, state, table_rows, ids, mask, starts):
+    def paged_prefill_chunk_fn(p, state, table_rows, ids, mask, starts,
+                               ssm_rows=None):
         return llama_mod.paged_prefill_chunk(
-            p, cfg, state, table_rows, ids, mask, starts, dtype=policy.compute_jnp
+            p, cfg, state, table_rows, ids, mask, starts,
+            dtype=policy.compute_jnp, ssm_rows=ssm_rows,
         )
 
     def window_fn(p, state, n_steps: int, max_chunks: int,

@@ -19,6 +19,14 @@ experts' only, never a dense pass over all experts under a mask.
     out = sum_j w_j * down_{e_j}(silu(gate_{e_j} h) * up_{e_j} h)
           + down_s(silu(gate_s h) * up_s h)    a shared expert, every token
 
+The block's shape is data.  ``act`` "relu2": an expert is the non-gated
+``down(relu(up v)^2)`` and the tree holds no ``gate`` stack, the shared
+expert likewise.  ``latent_down`` / ``latent_up`` in the tree (LatentMoE):
+the routed experts live in a narrower latent between one down- and one
+up-projection the layer shares — ``v = h W_down`` BEFORE the row gather,
+so latent-wide rows are gathered and sorted, ``W_up`` after the weighted
+combine; the router and the shared expert read the full-width ``h``.
+
 Rows that are padding or finished (``valid`` false) are sorted past the
 last group: the grouped matmul gives them no expert, their (undefined)
 output rows are zeroed before the combine, and they are not counted.
@@ -56,7 +64,9 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     a 64 x 128 wave's 65 536; d 2048, width 1024, 64 experts, one layer's
     three matmuls).  Row tile 128 while the mean group is small (each
     visited tile streams one expert's weights: HBM-bound), 512 once it
-    holds 512 rows (MXU-bound); the kernel wants M a multiple of the tile."""
+    holds 512 rows (MXU-bound); the kernel wants M a multiple of the tile.
+    K and N are tiled by ``min(size, 1024)`` — or, for a size that 1024
+    does not divide and a chip measurement exists for, by ``_TILE``'s."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = lhs.shape
@@ -67,9 +77,26 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = gmm(
         lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-        tiling=(tm, min(k, 1024), min(n, 1024)), interpret=interpret,
+        tiling=(tm, _tile(k), _tile(n)), interpret=interpret,
     )
     return out[:m] if pad else out
+
+
+def _relu2(x: jax.Array) -> jax.Array:
+    return jnp.square(jax.nn.relu(x))
+
+
+#: K / N tile of ``grouped_matmul`` for a width that 1024 does not divide,
+#: by a measurement on the chip at the shapes the cell that has it runs
+#: (PERF.md section 6, PR 40: 2688 = 21 x 128 divides by 384 and 896).  A
+#: table and not the rule "the largest multiple of 128 up to 1024 that
+#: divides": that rule gives 896 here but 768 for the 1536 that runs at
+#: 1024 today (a ragged last tile), and no chip run has compared those.
+_TILE = {2688: 896}
+
+
+def _tile(size: int) -> int:
+    return _TILE.get(size, min(size, 1024))
 
 
 def group_limited(sel: jax.Array, n_group: int, topk_group: int) -> jax.Array:
@@ -89,18 +116,25 @@ def group_limited(sel: jax.Array, n_group: int, topk_group: int) -> jax.Array:
 def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
                interpret: bool = False, score: str = "softmax",
                route_scale: float = 1.0, n_group: int = 0,
-               topk_group: int = 0, expert_first: int = 0):
+               topk_group: int = 0, expert_first: int = 0,
+               act: str = "silu"):
     """h [T, D] normed tokens, ``mlp`` the layer's expert leaves (router
     [D, E]; gate, up [held, D, W]; down [held, W, D], held = E unless the
     tree holds a chip's share; where the model has them ``router_bias``
-    [E] and ``shared``, a dense SwiGLU's gate/up/down), valid [T] bool ->
-    (out [T, D] in h's dtype, counts [E] int32 of valid assignments over
-    the PUBLISHED experts).  ``interpret`` runs the kernel in interpret
-    mode (CPU tests)."""
+    [E], ``shared`` (a dense expert's gate/up/down) and the latent pair
+    ``latent_down`` [D, Dl] / ``latent_up`` [Dl, D], the stacks then Dl
+    wide where they were D; ``act`` "relu2": no ``gate``), valid [T] bool
+    -> (out [T, D] in h's dtype, counts [E] int32 of valid assignments
+    over the PUBLISHED experts).  ``interpret`` runs the kernel in
+    interpret mode (CPU tests)."""
     t, d = h.shape
-    gate, up, down = (mlp[n]["kernel"] for n in ("gate", "up", "down"))
-    n_exp = gate.shape[0]  # held
+    up, down = mlp["up"]["kernel"], mlp["down"]["kernel"]
+    n_exp = up.shape[0]  # held
     n_pub = mlp["router"]["kernel"].shape[1]
+    rows = h
+    if "latent_down" in mlp:
+        with jax.named_scope("moe_latent_down"):
+            rows = h @ mlp["latent_down"]["kernel"].astype(h.dtype)
     with jax.named_scope("moe_route"):
         logits = h.astype(jnp.float32) @ mlp["router"]["kernel"].astype(jnp.float32)
         p = jax.nn.softmax(logits, axis=-1) if score == "softmax" else (
@@ -133,22 +167,32 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
             e = jnp.where(held, e - expert_first, n_exp)
             sizes = counts[expert_first:expert_first + n_exp]
         order = jnp.argsort(e)  # stable: assignment i of token i // k
-        xs = jnp.take(h, order // k, axis=0)  # [T*k, D], sorted by expert
+        xs = jnp.take(rows, order // k, axis=0)  # [T*k, D], sorted by expert
     with jax.named_scope("moe_experts"):
         mm = functools.partial(grouped_matmul, interpret=interpret)
-        act = jax.nn.silu(mm(xs, gate, sizes)) * mm(xs, up, sizes)
-        ys = mm(act, down, sizes)  # [T*k, D]
+        if act == "relu2":
+            mid = _relu2(mm(xs, up, sizes))
+        else:
+            mid = jax.nn.silu(mm(xs, mlp["gate"]["kernel"], sizes)) * mm(xs, up, sizes)
+        ys = mm(mid, down, sizes)  # [T*k, D]
     with jax.named_scope("moe_combine"):
         in_group = jnp.arange(t * k) < jnp.sum(sizes)
         ys = jnp.where(in_group[:, None], ys, 0)
-        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(t, k, d)
+        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(
+            t, k, rows.shape[1])
         out = jnp.sum(back.astype(jnp.float32) * w[:, :, None], axis=1)
     out = out.astype(h.dtype)
+    if "latent_up" in mlp:
+        with jax.named_scope("moe_latent_up"):
+            out = out @ mlp["latent_up"]["kernel"].astype(h.dtype)
     if "shared" in mlp:
         with jax.named_scope("moe_shared"):
             sh = mlp["shared"]
-            out = out + (
-                jax.nn.silu(h @ sh["gate"]["kernel"].astype(h.dtype))
-                * (h @ sh["up"]["kernel"].astype(h.dtype))
-            ) @ sh["down"]["kernel"].astype(h.dtype)
+
+            def proj(name):
+                return h @ sh[name]["kernel"].astype(h.dtype)
+
+            mid = _relu2(proj("up")) if act == "relu2" else (
+                jax.nn.silu(proj("gate")) * proj("up"))
+            out = out + mid @ sh["down"]["kernel"].astype(h.dtype)
     return out, counts

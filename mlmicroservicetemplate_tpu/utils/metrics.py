@@ -170,6 +170,43 @@ MOE_ASSIGNMENTS_ABSENT = Counter(
     "carry off this chip",
     ["model"],
 )
+SSM_STATE_BYTES = Gauge(
+    "ssm_state_bytes",
+    "Recurrent layers: bytes of recurrent state (a layer's float32 state "
+    "and its convolution taps, every such layer) held by streams that "
+    "have a state row — decoding or mid-prefill; a fixed size a stream, "
+    "beside the paged KV that grows a token at a time",
+    ["model"],
+)
+SSM_STATE_ROWS = Gauge(
+    "ssm_state_rows",
+    "Recurrent layers: rows of the recurrent state by what holds them: "
+    "live (a stream decoding in a slot), prefill (a prompt between its "
+    "first window and going live), free",
+    ["model", "state"],
+)
+SSM_SCAN_TOKENS = Counter(
+    "ssm_scan_tokens_total",
+    "Recurrent layers: token positions the chunked scan ran over in "
+    "prompt-window and prefill-wave dispatches (rows x width, padding "
+    "and filled-up rows included)",
+    ["model"],
+)
+SSM_SCAN_MASKED = Counter(
+    "ssm_scan_masked_tokens_total",
+    "Recurrent layers: the positions of ssm_scan_tokens_total that were "
+    "padding or a filled-up row of a batched dispatch: scanned, moving "
+    "no state",
+    ["model"],
+)
+SSM_STATE_RECOMPUTES = Counter(
+    "ssm_state_recomputes_total",
+    "Recurrent layers: recurrent states rebuilt by recomputing a "
+    "stream's prompt and the tokens it had emitted (a preempted, "
+    "failed-over or replayed stream taking a state row again: no tier "
+    "carries a state row)",
+    ["model"],
+)
 MOE_EXPERTS_HIT = Gauge(
     "moe_experts_hit",
     "Expert FFN: distinct experts of a layer (of those this tree holds) "

@@ -305,7 +305,8 @@ def test_prefill_window_ms_is_a_window_executables_mean_time():
 
 BOOT_CELLS = [
     "mistral-7b-d8.decode-closed", "mistral-7b-d8.chat-open",
-    "olmoe-1b-7b-d8.decode-closed", "trinity-mini-d5.longdoc-closed", DSV2_CELL]
+    "olmoe-1b-7b-d8.decode-closed", "trinity-mini-d5.longdoc-closed", DSV2_CELL,
+    "nemotron3-super-ep4-d11.longdoc-closed"]  # PR 40 appended its cell
 BOOT_ENTRIES = [
     ("boot_imports_s", "s", "boot_phase_seconds", {"phase": "imports"}),
     ("boot_weights_s", "s", "boot_phase_seconds", {"phase": "weights"}),
@@ -400,7 +401,8 @@ def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
     assert entry == {
         "name": name, "unit": "%", "better": "higher", "source": "program_counter",
         "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
-    assert entry in per_layer[-2:]  # appended: nothing before them moved
+    # appended by PR 36 (nothing before them moved); PR 40's 24 follow them
+    assert entry in per_layer[-26:-24]
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_counter_ratio" and callable(metric.read)
@@ -438,3 +440,114 @@ def test_prefill_windows_batched_pct_reads_the_programs_counters():
     later = parse_prom(generate_latest().decode())
     assert prom_counter_ratio.read(ctx(later, after), *args) == 0.0
     assert prom_counter_ratio.read(ctx({}, {}), *args) is None
+
+
+# ---------------------------------------------------------------------------
+# nemotron3-super-ep4-d11 (PR 40): the configuration, the cell and its
+# twenty-four per-layer entries, held by NAME
+
+NEMO_CELL = "nemotron3-super-ep4-d11.longdoc-closed"
+_T, _C = "device_trace", "program_counter"
+NEMO_ENTRIES = [
+    ("decode_ssm_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("ssm_step_roofline", "%", _T, "kernels", "nemotron_roofline"),
+    ("ssm_proj_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("prefill_ssm_scan_ms", "ms", _T, "model step", "nemotron_roofline"),
+    ("ssm_scan_roofline", "%", _T, "kernels", "nemotron_roofline"),
+    ("moe_latent_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("ssm_state_share_pct", "%", _C, "engine", "nemotron_roofline"),
+    ("ssm_scan_masked_pct", "%", _C, "engine", "nemotron_roofline"),
+    ("decode_step_ms", "ms", _T, "model step", "trace_module_ms"),
+    ("decode_step_roofline", "%", _T, "model step", "nemotron_roofline"),
+    ("decode_moe_ms", "ms", _T, "model step", "trace_scope_ms"),
+    ("moe_experts_roofline", "%", _T, "kernels", "nemotron_roofline"),
+    ("moe_overhead_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("moe_shared_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("moe_held_share_pct", "%", _C, "model step", "prom_counter_ratio"),
+    ("moe_imbalance", "ratio", _C, "engine", "prom_hist"),
+    ("decode_attn_ms", "ms", _T, "model step", "trace_scope_ms"),
+    ("paged_decode_attention_roofline", "%", _T, "kernels", "nemotron_roofline"),
+    ("table_blocks_dead_pct", "%", _C, "kernels", "prom_counter_ratio"),
+    ("streams_per_chunk", "streams", _C, "engine", "prom_hist"),
+    ("prefill_stall_ms", "ms/s", _C, "engine", "prom_counter_rate"),
+    ("prefill_window_ms", "ms", _T, "model step", "trace_module_ms"),
+    ("prefill_windows_batched_pct", "%", _C, "engine", "prom_counter_ratio"),
+    ("device_idle_pct", "%", _T, "device", "trace_idle_pct"),
+]
+
+
+def test_nemotron_configuration_and_cell_are_in_the_benchmark():
+    from cellbench import spec
+
+    bench = spec.load_benchmark()
+    assert bench["configs"][-1]["name"] == "nemotron3-super-ep4-d11"  # appended
+    cfg = bench["configs"][-1]
+    assert cfg["file"] == "cellbench/configs/nemotron3-super-ep4-d11.json"
+    assert cfg["source"] == (
+        "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16"
+        "/blob/main/config.json")
+    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
+    cell = bench["workloads"][-1]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        NEMO_CELL, "nemotron3-super-ep4-d11", "longdoc-closed", 1)
+    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
+    on = [m["name"] for m in bench["end_to_end"]
+          if "workloads" not in m or NEMO_CELL in m["workloads"]]
+    assert on == ["tbt_p99_ms", "setup_s"]
+    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
+    assert len(boots) == 7 and all(m["workloads"][-1] == NEMO_CELL for m in boots)
+    file = spec.load_json(spec.REPO + "/" + cfg["file"])
+    assert set(file["reduced"]) == set(cfg["reduced"])
+    assert (file["num_hidden_layers"], file["n_routed_experts"],
+            file["vocab_size"]) == (11, 128, 32768)
+    assert file["hybrid_override_pattern"][:11] == "MEMEMEM*EME"
+    assert "deployment" in file["assumed"] and "mtp" in file["assumed"]
+
+
+@pytest.mark.parametrize("name,unit,source,layer,reader", NEMO_ENTRIES)
+def test_nemotron_per_layer_entry_resolves(name, unit, source, layer, reader):
+    from cellbench import spec
+
+    name += ".nemotron"
+    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": unit,
+        "better": entry["better"], "source": source, "layer": layer,
+        "moves": "tbt_p99_ms", "workloads": [NEMO_CELL]}
+    assert entry["better"] in ("lower", "higher")
+    (resolved,) = [m for m in spec.resolve(NEMO_CELL).per_layer if m.name == name]
+    assert resolved.reader == reader and callable(resolved.read)
+
+
+def test_nemotron_counter_readings_read_the_programs_families():
+    """``ssm_scan_masked_pct`` and ``ssm_state_share_pct`` through the
+    reader their entries name, off the families as ``/metrics`` exports
+    them; a program without them (the parent) reads no value and does not
+    raise."""
+    import types
+
+    from cellbench import spec
+    from mlmicroservicetemplate_tpu.utils import metrics
+    from prometheus_client import generate_latest
+
+    metrics.SSM_SCAN_TOKENS.labels("reader-unit").inc(3072)
+    metrics.SSM_SCAN_MASKED.labels("reader-unit").inc(768)
+    after = parse_prom(generate_latest().decode())
+    base = {f: dict(after[f], value=after[f]["value"] - v) for f, v in
+            (("ssm_scan_tokens", 3072.0), ("ssm_scan_masked_tokens", 768.0))}
+
+    def ctx(after, before):
+        return types.SimpleNamespace(
+            notes={}, prom_after=after, trace=None, peaks=None, prom_delta=lambda fam: (
+                None if fam not in after
+                else hist_delta(after[fam], before.get(fam))))
+
+    by_name = {m.name: m for m in spec.resolve(NEMO_CELL).per_layer}
+    masked = by_name["ssm_scan_masked_pct.nemotron"]
+    assert masked.read(ctx(after, base), **masked.args) == 25.0
+    share = by_name["ssm_state_share_pct.nemotron"]
+    gauges = {"ssm_state_bytes": {"value": 3.0e8}, "kv_committed_bytes": {"value": 1.0e8}}
+    assert share.read(ctx(gauges, {}), **share.args) == 75.0
+    for m in by_name.values():
+        if m.reader == "nemotron_roofline":
+            assert m.read(ctx({}, {}), **m.args) is None

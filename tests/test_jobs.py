@@ -125,7 +125,10 @@ def test_job_store_roundtrip_exactly_once_and_ttl(tmp_path):
     store2.close()
 
     # TTL: a terminal job past its TTL purges at sweep AND at open.
-    store3 = JobStore(d, fsync="off", model="t", ttl_s=0.01)
+    # The TTL is set after the open: on a busy machine the job is already
+    # 10 ms old by then, the OPEN purges it and the sweep finds nothing.
+    store3 = JobStore(d, fsync="off", model="t", ttl_s=3600)
+    store3.ttl_s = 0.01
     time.sleep(0.05)
     assert store3.sweep() == 1
     assert store3.get(job.id) is None and "k1" not in store3.by_key
