@@ -53,6 +53,7 @@ import tempfile
 import time
 
 N_STREAMS = 8
+WAVE_ROWS = 64  # the benchmark cells' largest wave: one insert lands it
 N_SPECIAL = 3  # <unk>, <s>, </s>
 STREAM_TOKENS = 32
 N_UNARY_SEQ = 4
@@ -387,10 +388,10 @@ def pool_moves(cdl, bucket: int) -> tuple[list[str], list[str]]:
     """Pool-sized ``reshape``/``copy``/``transpose`` instructions of the
     compiled serving programs: (inside the paged chunk's step loop —
     the pool's layout rule, ops/paged_attention.py, says there are none;
-    anywhere in the chunk and in a slot insert of ``bucket`` tokens,
-    ENTRY included — the decode state is donated, engine/streams.py, so
-    the compiler aliases every pool's input to its output and none is
-    copied on the way in)."""
+    anywhere in the chunk and in the insert of a lone start and of a
+    wave of ``WAVE_ROWS`` rows, of ``bucket`` tokens, ENTRY included — the
+    decode state is donated, engine/streams.py, so the compiler aliases
+    every pool's input to its output and none is copied on the way in)."""
     import jax
 
     from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
@@ -401,7 +402,8 @@ def pool_moves(cdl, bucket: int) -> tuple[list[str], list[str]]:
     return (
         pool_relayouts(chunk, sizes, in_loop_only=True),
         pool_relayouts(chunk, sizes)
-        + pool_relayouts(cdl.paged_insert_hlo(bucket), sizes),
+        + pool_relayouts(cdl.paged_insert_hlo(bucket), sizes)
+        + pool_relayouts(cdl.paged_insert_hlo(bucket, WAVE_ROWS), sizes),
     )
 
 

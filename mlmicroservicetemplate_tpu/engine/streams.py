@@ -15,7 +15,9 @@ step 40 of a 300-token prompt while row j starts step 0 of a 16-token
 one.  Admission is a compiled scatter: the freshly prefilled batch=1
 state (one ``_start`` dispatch at the request's own prompt bucket —
 TTFT unchanged) is zero-padded up to the slot shapes and written into
-row i with ``dynamic_update_slice``.
+row i with ``dynamic_update_slice``.  Over the paged pool a prefill
+wave's rows land in their slots through ONE such dispatch
+(``paged_insert``, ``_insert_wave``), not one a row.
 
 One rule for the decode state: **a state that is replaced is donated**.
 Every executable that takes the batched state and returns its successor
@@ -129,30 +131,60 @@ def _ins_row(dst, src, slot, row):
     )
 
 
+def _ins_rows(dst, src, slots):
+    """Every row of ``src`` (a wave's prefill state's leaf), zero-padded
+    to the slot shape, written into row ``slots[i]`` of ``dst``; a row
+    whose slot is out of range drops."""
+    import jax.numpy as jnp
+
+    pad = [(0, 0)] + [
+        (0, int(d) - int(s)) for d, s in zip(dst.shape[1:], src.shape[1:])
+    ]
+    return dst.at[slots].set(jnp.pad(src.astype(dst.dtype), pad), mode="drop")
+
+
 def paged_insert(block_size: int):
-    """The paged slot insert as a function to jit: scatter rows
-    [s_lo, s_cut) of one prefill-state row into this slot's blocks
-    (cache leaves route through the table; CoW prefix rows [0, s_lo) are
-    the donor's blocks and are never rewritten), logical per-row fields
-    land via the same dynamic_update_slice as the contiguous insert."""
+    """The paged slot insert as a function to jit: a wave's rows land in
+    their slots in ONE dispatch.  Row ``i`` of ``single`` (the wave's
+    prefill state, ``Bw`` rows: its rung; a lone start is the rung of 1)
+    scatters its positions [s_lo, s_cut) into the blocks ``table_rows[i]``
+    names (CoW prefix rows [0, s_lo) are the donor's blocks and are never
+    rewritten) — one ``scatter_rows`` a pool over the wave's ``Bw * W``
+    positions — and its per-row fields land in slot ``slots[i]``.  A row
+    that does not insert (a pad row of the rung, a row that finished in
+    its first chunk or was re-queued) carries an all-sentinel table row
+    and an out-of-range slot (and state row): every one of its writes
+    drops."""
     import jax
-    from jax import lax
+    import jax.numpy as jnp
 
     from ..models.gpt import PagedState
-    from ..ops.paged_attention import scatter_pages
+    from ..ops.paged_attention import scatter_rows
 
-    def insert(batched, single, table_row, slot, row, s_lo: int, s_cut: int,
-               ssm_row=None):
+    def insert(batched, single, table_rows, slots, s_lo: int, s_cut: int,
+               ssm_rows=None):
+        # One destination a position of the wave, for every pool (one
+        # pool geometry serves all layers): out of range where sentinel.
+        p = s_lo + jnp.arange(s_cut - s_lo)
+        blk = jnp.take(  # [Bw, W]
+            table_rows, p // block_size, axis=1, mode="fill",
+            fill_value=jax.tree.leaves(batched.cache_k)[0].shape[0],
+        )
+        dest = (blk * block_size + p % block_size).reshape(-1)
+
         def scat(pool, src):
-            srow = lax.dynamic_slice_in_dim(src, row, 1, axis=0)[0]
-            return scatter_pages(
-                pool, table_row, srow[s_lo:s_cut], block_size, start=s_lo
+            vals = src[:, s_lo:s_cut]
+            return scatter_rows(
+                pool, dest, vals.reshape((-1,) + vals.shape[2:])
             )
 
         def scat_entry(pc, sc):
             if isinstance(pc, tuple):
                 return (scat(pc[0], sc[0]), scat(pc[1], sc[1]))
             return scat(pc, sc)
+
+        def rows(d, s):
+            return _ins_rows(d, s, slots)
 
         return PagedState(
             cache_k=[
@@ -163,42 +195,35 @@ def paged_insert(block_size: int):
                 scat_entry(d, s)
                 for d, s in zip(batched.cache_v, single.cache_v)
             ],
-            key_valid=_ins_row(batched.key_valid, single.key_valid, slot, row),
-            write_idx=_ins_row(batched.write_idx, single.write_idx, slot, row),
-            pos=_ins_row(batched.pos, single.pos, slot, row),
-            last_token=_ins_row(
-                batched.last_token, single.last_token, slot, row
-            ),
-            done=_ins_row(batched.done, single.done, slot, row),
-            tokens=_ins_row(batched.tokens, single.tokens, slot, row),
-            sample=jax.tree.map(
-                lambda d, s: _ins_row(d, s, slot, row),
-                batched.sample, single.sample,
-            ),
-            **_insert_ssm(batched, single, slot, row, ssm_row),
+            key_valid=rows(batched.key_valid, single.key_valid),
+            write_idx=rows(batched.write_idx, single.write_idx),
+            pos=rows(batched.pos, single.pos),
+            last_token=rows(batched.last_token, single.last_token),
+            done=rows(batched.done, single.done),
+            tokens=rows(batched.tokens, single.tokens),
+            sample=jax.tree.map(rows, batched.sample, single.sample),
+            **_insert_ssm(batched, single, slots, ssm_rows),
         )
 
     return insert
 
 
-def _insert_ssm(batched, single, slot, row, ssm_row) -> dict:
-    """A wave row's recurrent state into state row ``ssm_row`` (past the
-    last row: dropped — warm-up), and the slot pointed at it; nothing for
-    a model without recurrent layers (its ``ssm`` is the empty default)."""
-    if ssm_row is None:
+def _insert_ssm(batched, single, slots, ssm_rows) -> dict:
+    """Each wave row's recurrent state into state row ``ssm_rows[i]``
+    (past the last row: dropped — warm-up, a row that does not insert),
+    and its slot pointed at it; nothing for a model without recurrent
+    layers (its ``ssm`` is the empty default)."""
+    if ssm_rows is None:
         return {"ssm": batched.ssm}
-    import jax
 
-    def put(dst, src):  # [R, ...] <- row ``row`` of [Bw, ...]
-        return dst.at[ssm_row].set(
-            jax.lax.dynamic_index_in_dim(src, row, keepdims=False).astype(dst.dtype),
-            mode="drop")
+    def put(dst, src):  # [R, ...] <- [Bw, ...]
+        return dst.at[ssm_rows].set(src.astype(dst.dtype), mode="drop")
 
     b, s = batched.ssm, single.ssm
     return {"ssm": b._replace(
         conv=[put(d, x) for d, x in zip(b.conv, s.conv)],
         state=[put(d, x) for d, x in zip(b.state, s.state)],
-        row=b.row.at[slot].set(ssm_row),
+        row=b.row.at[slots].set(ssm_rows, mode="drop"),
     )}
 
 
@@ -2044,8 +2069,9 @@ class ContinuousDecodeLoop:
                 if self.tenants is not None:
                     self.tenants.note_latency(st.tenant, "tbt", st.klass, gap)
             else:
-                # Reservation to first emit: the wave, its fetch, and
-                # this stream's place in the emit/insert order.
+                # Reservation to first emit: the wave, its fetch, its
+                # insert dispatch and this stream's place in the emit
+                # order.
                 metrics.STREAM_ADMIT.labels(self.engine.bundle.name).observe(
                     max(0.0, now - st.t_reserved)
                 )
@@ -2461,8 +2487,170 @@ class ContinuousDecodeLoop:
             self._emit_and_insert(started, fetched)
 
     def _emit_and_insert(self, started: list, fetched: dict) -> None:
-        """The wave's tail: each admitted stream's first chunk goes
-        out, then its prefill row scatters into a free slot."""
+        """The wave's tail: each admitted stream's first chunk goes out
+        and its prefill row lands in a free slot — paged, a wave's rows in
+        ONE insert dispatch (``_insert_wave``; a boundary's lone start
+        and each of its waves is a group of its own: another prefill
+        state, another (s_lo, s_cut)); on the contiguous slab, a row at
+        a time."""
+        if not self.paged:
+            self._emit_and_insert_slab(started, fetched)
+            return
+        waves: dict[int, list] = {}
+        for entry in started:
+            waves.setdefault(id(entry[1]), []).append(entry)
+        for wave in waves.values():
+            self._insert_wave(wave, fetched)
+
+    def _insert_wave(self, wave: list, fetched: dict) -> None:
+        """One prefill state's rows into their slots, in three passes:
+        the host's part a row (done?, a slot, its blocks, its state
+        row); ONE guarded ``paged_insert`` for every row that lands;
+        then every row's first chunk goes out — the device scatters
+        while the host emits — and each row is settled: live in its
+        slot, finished, re-queued, or failed."""
+        from .kv_blocks import OutOfBlocks
+
+        eng = self.engine
+        name = eng.bundle.name
+        st0, state1, toks = wave[0][:3]
+        toks_np, done_np = fetched[id(toks)]
+        s_lo, s_cut = st0.s_lo, st0.s_base + eng.chunk_tokens
+        bw = int(state1.done.shape[0])
+        # A row that does not land (a pad row of the rung, a row done or
+        # re-queued below) keeps the sentinel and the slot past the last:
+        # every one of its writes drops.
+        table_rows = np.full((bw, self.nb_max), self.pool.num_blocks, np.int32)
+        slots = np.full(bw, self.n_slots, np.int32)
+        ssm_rows = (
+            None if self._ssm_free is None
+            else np.full(bw, self.n_slots, np.int32)
+        )
+        landing: list[tuple] = []  # (st, slot, sb, sampled)
+        settle: list[tuple] = []  # (st, row, what, arg), in the wave's order
+        for st, _, _, sampled, row, _, _ in wave:
+            st.produced = eng.chunk_tokens
+            if bool(done_np[row]) or st.produced >= st.budget:
+                settle.append((st, row, "done", None))
+                continue
+            if self._fault_pending is not None:
+                # An earlier wave's insert took the batched state with
+                # it: the rows behind it go the same way, uninserted.
+                settle.append((st, row, "fault", self._fault_pending))
+                continue
+            try:
+                slot, sb = self._reserve_slot(st, s_cut)
+            except OutOfBlocks:
+                settle.append((st, row, "dry", None))
+                continue
+            # Any failure here (empty-state build OOM, an injected
+            # fault) must terminate THIS consumer and return the slot —
+            # the _run handler only reaches streams in self.active.
+            # graftlint: except(pre-active insert failure errors only this stream; the supervisor owns the next chunk dispatch)
+            except Exception as e:
+                settle.append((st, row, "error", e))
+                continue
+            table_rows[row, : len(sb.ids)] = sb.ids
+            slots[row] = slot
+            if ssm_rows is not None:
+                ssm_rows[row] = st.ssm_row
+            landing.append((st, slot, sb, sampled))
+            settle.append((st, row, "live", landing[-1]))
+        if landing:
+            ssm_arg = () if ssm_rows is None else (ssm_rows,)
+            try:
+                with eng._lock:
+                    self._state = eng.dispatch_guard(
+                        "insert", lambda: self._paged_insert_fn()(
+                            self._state, state1, table_rows, slots,
+                            s_lo, s_cut, *ssm_arg,
+                        ),
+                        donates=self._state,
+                    )
+                metrics.STREAM_INSERT_ROWS.labels(name).observe(len(landing))
+            except BaseException as e:
+                # The dispatch was every landing row's: each gives back
+                # what it took.  The streams are not active yet, so the
+                # failure ends these consumers only (a dead device
+                # resurfaces at the next guarded chunk dispatch, which
+                # the supervisor owns) — unless it consumed the batched
+                # state: that takes the shared fatal route (requeue +
+                # rebuild at the next iteration top).
+                for st, slot, sb, _ in reversed(landing):
+                    sb.release()
+                    self._ssm_give(st)
+                    self.free.append(slot)
+                landing = []
+                if not isinstance(e, Exception):
+                    raise
+                settle = [
+                    (st, row, "error", e) if what == "live"
+                    else (st, row, what, arg)
+                    for st, row, what, arg in settle
+                ]
+        for st, row, _, _ in settle:
+            self._emit_tokens(st, toks_np[row])
+        for st, row, what, arg in settle:
+            if what == "done":
+                self._finish(st)
+            elif what == "fault":
+                self._fail_streams([st], arg)
+            elif what == "error":
+                self._fail_preactive(st, arg)
+            elif what == "dry":
+                # The fits() gate raced another reservation and the
+                # pool is momentarily dry: checkpoint the first chunk
+                # (already delivered) and re-queue — token-identical
+                # resume when blocks free up, never a dropped stream.
+                metrics.KV_GROWTH_STALLS.labels(name).inc()
+                if self._flight is not None:
+                    self._flight.event(
+                        "kv_growth_stall", rid=st.rid, site="insert"
+                    )
+                if self.admission is not None:
+                    self.admission.release(st)
+                self._requeue_preempted(st)
+            else:
+                _, slot, sb, sampled = arg
+                st.blocks = sb
+                self._table[slot] = table_rows[row]
+                self._dispatched_steps[slot] = eng.chunk_tokens
+                self.active[slot] = st
+                if sampled:
+                    self.sampled_slots.add(slot)
+                if eng.prefix_cache is not None:
+                    self._donate_paged(st, slot)
+        if landing and self.admission is not None:
+            self.admission.note_pool()
+
+    def _reserve_slot(self, st: _Stream, s_cut: int) -> tuple:
+        """The host's part of a row's paged insert: a free slot, the
+        stream's initial blocks (adopting CoW prefix blocks first) and
+        its state row.  Raises ``OutOfBlocks`` (after trying to reclaim
+        prefix pins), or whatever else failed, with nothing leaked."""
+        from .kv_blocks import StreamBlocks
+
+        if self._state is None:
+            self._build_empty_state()
+        slot = self.free.pop()
+        sb = StreamBlocks(self.pool, self.block_size)
+        try:
+            self.engine.fault_point("grow")
+            if st.shared_ids:
+                sb.adopt(st.shared_ids)
+            self._reclaim_then_ensure(sb, s_cut)
+            self._ssm_take(st)
+        except BaseException:
+            sb.release()
+            self._ssm_give(st)
+            self.free.append(slot)
+            raise
+        return slot, sb
+
+    def _emit_and_insert_slab(self, started: list, fetched: dict) -> None:
+        """The contiguous slab's tail, a row at a time: the stream's
+        first chunk goes out, then its prefill row is written into a
+        free slot."""
         eng = self.engine
         for st, state1, toks, sampled, row, ids, mask in started:
             toks_np, done_np = fetched[id(toks)]
@@ -2479,52 +2667,29 @@ class ContinuousDecodeLoop:
             # Any failure from here (empty-state build OOM, insert
             # compile) must terminate THIS consumer and return the slot
             # — the _run handler only reaches streams in self.active.
-            from .kv_blocks import OutOfBlocks
-
             slot = None
             try:
                 if self._state is None:
                     self._build_empty_state()
                 slot = self.free.pop()
-                if self.paged:
-                    self._state = self._insert_paged_slot(
-                        st, state1, slot, row
-                    )
-                else:
-                    with eng._lock:
-                        if self.spec:
-                            hist_row = self._hist_row(st.feats, toks_np[row])
-                            self._state = eng.dispatch_guard(
-                                "insert", lambda: self._insert_fn()(
-                                    self._state, state1, ids, mask, hist_row,
-                                    np.int32(slot), np.int32(row),
-                                ),
-                                donates=self._state,
-                            )
-                        else:
-                            self._state = eng.dispatch_guard(
-                                "insert", lambda: self._insert_fn()(
-                                    self._state, state1, np.int32(slot),
-                                    np.int32(row),
-                                ),
-                                donates=self._state,
-                            )
-            except OutOfBlocks:
-                # The fits() gate raced another reservation and the
-                # pool is momentarily dry: checkpoint the first chunk
-                # (already delivered) and re-queue — token-identical
-                # resume when blocks free up, never a dropped stream.
-                if slot is not None:
-                    self.free.append(slot)
-                metrics.KV_GROWTH_STALLS.labels(eng.bundle.name).inc()
-                if self._flight is not None:
-                    self._flight.event(
-                        "kv_growth_stall", rid=st.rid, site="insert"
-                    )
-                if self.admission is not None:
-                    self.admission.release(st)
-                self._requeue_preempted(st)
-                continue
+                with eng._lock:
+                    if self.spec:
+                        hist_row = self._hist_row(st.feats, toks_np[row])
+                        self._state = eng.dispatch_guard(
+                            "insert", lambda: self._insert_fn()(
+                                self._state, state1, ids, mask, hist_row,
+                                np.int32(slot), np.int32(row),
+                            ),
+                            donates=self._state,
+                        )
+                    else:
+                        self._state = eng.dispatch_guard(
+                            "insert", lambda: self._insert_fn()(
+                                self._state, state1, np.int32(slot),
+                                np.int32(row),
+                            ),
+                            donates=self._state,
+                        )
             # The stream is not active yet: an insert failure ends this
             # consumer only; a dead device resurfaces at the next
             # guarded chunk dispatch, which the supervisor owns.  An
@@ -2539,8 +2704,6 @@ class ContinuousDecodeLoop:
             self.active[slot] = st
             if sampled:
                 self.sampled_slots.add(slot)
-            if self.paged and eng.prefix_cache is not None:
-                self._donate_paged(st, slot)
 
     # -- chunked prefill (PREFILL_CHUNK) -------------------------------
 
@@ -3598,30 +3761,41 @@ class ContinuousDecodeLoop:
                 return lowered.compile().as_text()
             return lowered.as_text(debug_info=debug_info)
 
-    def paged_insert_hlo(self, s: int) -> str:
-        """The backend's optimised text of one slot insert of a
-        ``s``-token bucket's lone prefill into this loop's state — the
-        chunk's twin for ``chip_smoke.py``: with the state donated no
-        pool is copied in it either."""
-        import jax.numpy as jnp
-
-        eng = self.engine
-        with eng._lock:
-            state1 = self._warm_wave(s, 1)[0]
+    def paged_insert_hlo(self, s: int, rows: int = 1) -> str:
+        """The backend's optimised text of the insert of a ``rows``-row
+        wave of a ``s``-token bucket (1: a lone prefill) into this
+        loop's state — the chunk's twin for ``chip_smoke.py``: with the
+        state donated no pool is copied in it either."""
+        with self.engine._lock:
+            state1 = self._warm_wave(s, rows)[0]
             return self._paged_insert_fn().lower(
-                self._state, state1,
-                jnp.full(self.nb_max, self.pool.num_blocks, jnp.int32),
-                np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
-                *self._ssm_row_arg(),
+                *self._warm_insert_args(state1, s)
             ).compile().as_text()
 
+    def _warm_insert_args(self, state1, s: int, ids=()) -> tuple:
+        """The arguments of a warm-up insert of ``state1``, a wave of
+        bucket ``s``: row 0 into slot 0 and the blocks ``ids``, every
+        other row — and every row's recurrent state — dropped."""
+        rows = int(state1.done.shape[0])
+        table_rows = np.full(
+            (rows, self.nb_max), self.pool.num_blocks, np.int32
+        )
+        table_rows[0, : len(ids)] = ids
+        past = np.full(rows, self.n_slots, np.int32)
+        slots = past.copy()
+        slots[0] = 0
+        ssm = () if self._ssm_free is None else (past,)
+        return (self._state, state1, table_rows, slots,
+                0, s + self.engine.chunk_tokens, *ssm)
+
     def _paged_insert_fn(self):
-        """Paged slot insert (``paged_insert``): one executable per
-        static (s_lo, s_cut) pair — the (prefix bucket, suffix bucket)
-        grid, like the prefixed starts.  The batched state is donated
-        (the module docstring's rule): the scatters write the stream's
-        blocks in place; ``single``, a wave's prefill state, is read by
-        every row's insert and is not."""
+        """Paged wave insert (``paged_insert``): one executable per
+        wave rung and static (s_lo, s_cut) pair — the (prefix bucket,
+        suffix bucket) grid, like the prefixed starts.  The batched
+        state is donated (the module docstring's rule): the scatters
+        write the streams' blocks in place; ``single``, the wave's
+        prefill state, is not: no leaf of it has an output's shape to
+        alias, and it dies with the wave's ``started`` entries."""
         if self._paged_insert is None:
             import jax
 
@@ -3630,7 +3804,7 @@ class ContinuousDecodeLoop:
                 "paged_insert",
                 lambda: jax.jit(
                     tracing.scoped("slot_insert", paged_insert(bs)),
-                    static_argnums=(5, 6), donate_argnums=(0,),
+                    static_argnums=(4, 5), donate_argnums=(0,),
                 ),
                 statics=(bs,),
             )
@@ -3669,47 +3843,6 @@ class ContinuousDecodeLoop:
             )
         blocks = jnp.asarray(np.asarray(block_ids, np.int32))
         return self._gather_prefix_fns[p_len](self._state, blocks)
-
-    def _insert_paged_slot(self, st: _Stream, state1, slot: int, row: int):
-        """Allocate the stream's initial blocks (adopting CoW prefix
-        blocks first), point the slot's table row at them, and scatter
-        the prefill state in.  Raises ``OutOfBlocks`` (after trying to
-        reclaim prefix pins) with nothing leaked — the caller
-        re-queues the stream."""
-        import jax.numpy as jnp
-
-        from .kv_blocks import StreamBlocks
-
-        eng = self.engine
-        s_cut = st.s_base + eng.chunk_tokens
-        sb = StreamBlocks(self.pool, self.block_size)
-        try:
-            eng.fault_point("grow")
-            if st.shared_ids:
-                sb.adopt(st.shared_ids)
-            self._reclaim_then_ensure(sb, s_cut)
-            table_row = np.full(self.nb_max, self.pool.num_blocks, np.int32)
-            table_row[: len(sb.ids)] = sb.ids
-            self._ssm_take(st)
-            with eng._lock:
-                new_state = eng.dispatch_guard(
-                    "insert", lambda: self._paged_insert_fn()(
-                        self._state, state1, jnp.asarray(table_row),
-                        np.int32(slot), np.int32(row), st.s_lo, s_cut,
-                        *self._ssm_row_arg(st),
-                    ),
-                    donates=self._state,
-                )
-        except BaseException:
-            sb.release()
-            self._ssm_give(st)
-            raise
-        st.blocks = sb
-        self._table[slot] = table_row
-        self._dispatched_steps[slot] = eng.chunk_tokens
-        if self.admission is not None:
-            self.admission.note_pool()
-        return new_state
 
     def _donate_paged(self, st: _Stream, slot: int) -> None:
         """Paged prefix donation: pin the slot's prompt blocks by
@@ -5432,12 +5565,6 @@ class ContinuousDecodeLoop:
                 break
             grid += [(s, n_batch) for n_batch in self._wave_rungs]
 
-        def table_row(s: int):
-            n_blocks = blocks_for(s + eng.chunk_tokens, self.block_size)
-            row = np.full(self.nb_max, self.pool.num_blocks, np.int32)
-            row[:n_blocks] = sb.ids[:n_blocks]
-            return jnp.asarray(row)
-
         insert = self._paged_insert_fn()
         one_insert = threading.Lock()
         parent = tracing.boot_current()
@@ -5450,11 +5577,10 @@ class ContinuousDecodeLoop:
                 # One insert at a time: it consumes the state (donated)
                 # and the next thread's takes its successor.
                 with one_insert:
-                    self._state = insert(
-                        self._state, state1, table_row(s),
-                        np.int32(0), np.int32(0), 0, s + eng.chunk_tokens,
-                        *self._ssm_row_arg(),
-                    )
+                    n_blocks = blocks_for(s + eng.chunk_tokens, self.block_size)
+                    self._state = insert(*self._warm_insert_args(
+                        state1, s, sb.ids[:n_blocks]
+                    ))
                     jax.block_until_ready(self._state.done)
 
         # A warm start is tracing plus the runtime loading a cached

@@ -122,8 +122,8 @@ STREAM_QUEUE_WAIT = Histogram(
 STREAM_ADMIT = Histogram(
     "stream_admit_seconds",
     "Seconds from a stream's reservation to its first emitted chunk: "
-    "the prefill wave, its fetch, and the stream's place in the "
-    "wave's emit/insert order",
+    "the prefill wave, its fetch, its insert dispatch and the stream's "
+    "place in the wave's emit order",
     ["model"], buckets=_LATENCY_BUCKETS,
 )
 PREFILL_WAVE_FILL = Histogram(
@@ -138,6 +138,12 @@ PREFILL_WAVE_ROWS = Histogram(
     "prefill_wave_rows",
     "Rows one prefill executable ran (a lone admission 1, a wave the "
     "smallest rung that holds it: 4 or the slot count)",
+    ["model"], buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
+)
+STREAM_INSERT_ROWS = Histogram(
+    "stream_insert_rows",
+    "Rows one paged insert dispatch landed in their slots (a wave's rows "
+    "that neither finished in their first chunk nor were re-queued)",
     ["model"], buckets=(1, 2, 4, 8, 16, 32, 64, 128, 256),
 )
 MOE_LOAD_IMBALANCE = Histogram(

@@ -401,8 +401,9 @@ def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
     assert entry == {
         "name": name, "unit": "%", "better": "higher", "source": "program_counter",
         "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
-    # appended by PR 36 (nothing before them moved); PR 40's 24 follow them
-    assert entry in per_layer[-26:-24]
+    # appended by PR 36 (nothing before them moved); PR 40's 24 and PR 41's
+    # two follow them
+    assert entry in per_layer[-28:-26]
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_counter_ratio" and callable(metric.read)
@@ -551,3 +552,61 @@ def test_nemotron_counter_readings_read_the_programs_families():
     for m in by_name.values():
         if m.reader == "nemotron_roofline":
             assert m.read(ctx({}, {}), **m.args) is None
+
+
+# ---------------------------------------------------------------------------
+# insert_rows_per_dispatch.* (PR 41): how many rows a paged insert dispatch
+# lands — two entries, two data files, the reader the benchmark has
+# (prom_hist), nothing edited
+
+
+@pytest.mark.parametrize("name,cell", [
+    ("insert_rows_per_dispatch.decode", "mistral-7b-d8.decode-closed"),
+    ("insert_rows_per_dispatch.olmoe", "olmoe-1b-7b-d8.decode-closed"),
+])
+def test_insert_rows_per_dispatch_resolves_in_its_cell(name, cell):
+    from cellbench import spec
+
+    per_layer = spec.load_benchmark()["per_layer"]
+    (entry,) = [m for m in per_layer if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": "rows", "better": "higher",
+        "source": "program_counter", "layer": "engine", "moves": "tokens_per_s",
+        "workloads": [cell]}
+    assert entry in per_layer[-2:]  # appended: nothing before them moved
+    resolved = spec.resolve(cell)
+    (metric,) = [m for m in resolved.per_layer if m.name == name]
+    assert metric.reader == "prom_hist" and callable(metric.read)
+    assert metric.args == {"family": "stream_insert_rows", "stat": "mean"}
+    assert "tokens_per_s" in [m.name for m in resolved.end_to_end]
+    others = [w["name"] for w in spec.load_benchmark()["workloads"] if w["name"] != cell]
+    for other in others:  # read in its own cell only
+        assert name not in [m.name for m in spec.resolve(other).per_layer]
+
+
+def test_insert_rows_per_dispatch_reads_the_programs_histogram():
+    """The family as ``/metrics`` exports it, through the reader: the mean
+    rows a dispatch over the window (a lone start, a wave of 3 and one of
+    60: 64 rows over 3 dispatches); a window with no insert, and a program
+    without the family (the parent), no value."""
+    import types
+
+    from cellbench.readers import prom_hist
+    from mlmicroservicetemplate_tpu.utils import metrics  # registers the family
+    from prometheus_client import generate_latest
+
+    def ctx(after, before):
+        return types.SimpleNamespace(
+            notes={}, prom_delta=lambda fam: (
+                None if fam not in after
+                else hist_delta(after[fam], before.get(fam))))
+
+    metrics.STREAM_INSERT_ROWS.labels("reader-unit-41").observe(7)
+    before = parse_prom(generate_latest().decode())
+    for rows in (1, 3, 60):
+        metrics.STREAM_INSERT_ROWS.labels("reader-unit-41").observe(rows)
+    after = parse_prom(generate_latest().decode())
+    args = ("stream_insert_rows", "mean")
+    assert prom_hist.read(ctx(after, before), *args) == pytest.approx(64 / 3)
+    assert prom_hist.read(ctx(after, after), *args) is None
+    assert prom_hist.read(ctx({}, {}), *args) is None

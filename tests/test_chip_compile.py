@@ -408,7 +408,8 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True,
     """The text of the loop's own executable compiled for the described
     chip at a cell's shapes — ``what`` = the paged chunk
     (``chunk_with_done(generate_chunk_paged)``, as ``_paged_chunk_fn``
-    jits it), one slot insert (``streams.paged_insert``) or the prompt
+    jits it), the insert of a lone start or (``insert_wave``) of a wave at
+    the slot count (``streams.paged_insert``) or the prompt
     windows of ``windows`` prompts in one dispatch — and the element counts of a payload and a scale pool.  Shapes only: no
     weight is made."""
     from mlmicroservicetemplate_tpu.engine.engine import chunk_with_done
@@ -452,7 +453,8 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True,
     params = on_chip(jax.eval_shape(
         lambda: llama_mod.init_params(jax.random.PRNGKey(0), cfg, dtype=dt)))
     state = on_chip(jax.eval_shape(batched))
-    donated = dict(donate_argnums=(0 if what == "insert" else 1,)) if donate else {}
+    insert = what.startswith("insert")
+    donated = dict(donate_argnums=(0 if insert else 1,)) if donate else {}
     if what == "chunk":
         lowered = jax.jit(
             chunk_with_done(lambda p, s, tb, n, sample: (
@@ -468,24 +470,26 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True,
                 chip((windows, w), jnp.int32), chip((windows, w), jnp.int32),
                 chip((windows,), jnp.int32))
     else:
-        ones = jnp.ones((1, s_max), jnp.int32)
+        rows = b if what == "insert_wave" else 1
+        ones = jnp.ones((rows, s_max), jnp.int32)
         single = on_chip(jax.eval_shape(
             lambda p: llama_mod.generate_chunk(p, cfg, llama_mod.init_decode_state(
                 p, cfg, ones, ones, budget, dtype=dt), steps, False)[0], params))
         lowered = jax.jit(
-            paged_insert(bs), static_argnums=(5, 6), **donated,
-        ).lower(state, single, chip((t,), jnp.int32), chip((), jnp.int32),
-                chip((), jnp.int32), 0, s_max + steps)
+            paged_insert(bs), static_argnums=(4, 5), **donated,
+        ).lower(state, single, chip((rows, t), jnp.int32),
+                chip((rows,), jnp.int32), 0, s_max + steps)
     chip.memo[key] = lowered.compile().as_text()
     return chip.memo[key], counts
 
 
-@pytest.mark.parametrize("what", ["chunk", "insert"])
+@pytest.mark.parametrize("what", ["chunk", "insert", "insert_wave"])
 @pytest.mark.parametrize("cell", list(_CELLS))
 def test_donated_state_is_not_copied_at_entry(chip, cell, what):
     """The decode state is donated (engine/streams.py's rule), so the
-    loop's chunk and a slot insert, compiled at the cells' own shapes,
-    alias every pool's input to its output: no ``copy`` / ``reshape`` /
+    loop's chunk and an insert — a lone start's, and a wave's at the slot
+    count: one scatter a pool over all its rows (PR 41) — compiled at the
+    cells' own shapes, alias every pool's input to its output: no ``copy`` / ``reshape`` /
     ``transpose`` of a payload pool's element count anywhere in the
     optimised program, ENTRY included.  Until PR 30 neither donated:
     ``copy(bf16[3051,16,1024])`` x 16 at the chunk's entry (1.11 / 1.45

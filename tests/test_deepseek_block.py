@@ -249,6 +249,19 @@ def test_prefill_wave_state_inserts_as_one_latent_slab_a_layer(cfg, params):
     assert ps.cache_v == [] and ps.cache_k[0].shape == (12, 2, cfg.latent_lanes)
     np.testing.assert_array_equal(  # row 1's first block = its first two tokens
         np.asarray(ps.cache_k[2][6]), np.asarray(st.cache_k[2][1, :2]))
+    # ... and the wave's ONE insert lands both rows' slabs in those blocks
+    from mlmicroservicetemplate_tpu.engine.streams import paged_insert
+
+    empty = ps._replace(
+        cache_k=[jnp.zeros_like(c) for c in ps.cache_k],
+        key_valid=jnp.zeros_like(ps.key_valid), done=jnp.ones_like(ps.done))
+    got = paged_insert(2)(empty, st, table, jnp.asarray([1, 0]), 0, 12)
+    assert got.cache_v == []
+    for g, w in zip(got.cache_k, ps.cache_k):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+    np.testing.assert_array_equal(  # wave row 0 went to slot 1
+        np.asarray(got.key_valid[1]), np.asarray(ps.key_valid[0]))
+    assert not bool(got.done[0]) and not bool(got.done[1])
 
 
 def test_absorbed_is_expanded_on_the_same_weights(cfg, params):

@@ -233,10 +233,10 @@ def _step_window(eng, cdl):
 def _step_insert(eng, cdl):
     state1 = cdl._warm_wave(16, 1)[0]
     new = cdl._paged_insert_fn()(
-        cdl._state, state1, _table_row(cdl, 3), np.int32(0), np.int32(0),
-        0, 16 + eng.chunk_tokens,
+        cdl._state, state1, np.asarray(_table_row(cdl, 3))[None],
+        np.zeros(1, np.int32), 0, 16 + eng.chunk_tokens,
     )
-    # A wave's prefill state is read by every row's insert: not donated.
+    # The wave's prefill state is read after its insert: not donated.
     assert not is_consumed(state1)
     return (new,)
 
@@ -503,9 +503,23 @@ def test_failure_after_consumption_rebuilds_and_holds_the_swap(attr, nth):
     retries0 = _retries()
     try:
         async def drive():
-            return await asyncio.gather(
-                *[_consume(cdl.submit_stream(dict(f))) for f in feats]
-            )
+            tasks = [
+                asyncio.ensure_future(_consume(cdl.submit_stream(dict(f))))
+                for f in feats[:2]
+            ]
+            if attr == "_paged_insert":
+                # A wave is ONE insert: the third stream comes as a wave
+                # of its own, and its insert (the nth) consumes the
+                # state the first wave's streams live in.
+                for _ in range(400):
+                    if cdl.chunk_dispatches >= 1:
+                        break
+                    await asyncio.sleep(0.01)
+            tasks += [
+                asyncio.ensure_future(_consume(cdl.submit_stream(dict(f))))
+                for f in feats[2:]
+            ]
+            return await asyncio.gather(*tasks)
 
         assert asyncio.run(drive()) == solos  # resumed by recompute
         assert len(seen) > nth and not any(seen), (
