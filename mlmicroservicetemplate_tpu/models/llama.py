@@ -1058,16 +1058,18 @@ def _mamba_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
             else:
                 y1, conv = ssm.conv_step(xbc[:, 0], conv, w, bias, live)
                 xbc = y1[:, None]
-        xs = xbc[..., :inner].reshape(b, length, hn, cfg.ssm_head_dim)
-        bm = xbc[..., inner:inner + g * n].reshape(b, length, g, n)
-        cm = xbc[..., inner + g * n:].reshape(b, length, g, n)
         dt = _ssm_delta(dt.astype(f32), m["dt_bias"].astype(f32))
         a = -jnp.exp(m["A_log"].astype(f32))
         if live is None:
+            # x, B and C where the convolution left them: the kernel reads
+            # them through block index maps, no heads-major copy.
             with jax.named_scope("ssm_scan"):
-                y, s = ssm.ssm_scan(xs, dt, a, bm, cm, m["D"], s, mask,
-                                    chunk=cfg.ssm_chunk)
+                y, s = ssm.ssm_scan(
+                    xbc, dt, a, m["D"], s, mask, groups=g, state=n,
+                    chunk=cfg.ssm_chunk, kernel=cfg.pallas_decode,
+                    interpret=cfg.pallas_interpret)
         else:
+            xs, bm, cm = ssm.split_xbc(xbc, hn, g, n)
             with jax.named_scope("ssm_step"):
                 y, s = ssm.ssm_step(xs[:, 0], dt[:, 0], a, bm[:, 0], cm[:, 0],
                                     m["D"], s, live)

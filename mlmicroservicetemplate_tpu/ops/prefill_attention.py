@@ -321,10 +321,11 @@ def prefill_attention(
     return out.reshape(c, h, dv)[..., :dv_out]
 
 
-def scores_in_hbm(hlo_text: str, n_elems: int) -> list:
+def scores_in_hbm(hlo_text: str, n_elems: int, exact: bool = False) -> list:
     """The instructions of a COMPILED program's text whose result holds a
-    float32 array of at least ``n_elems`` elements — a window's
-    ``[H, C, K]`` scores, where XLA runs its attention: what
+    float32 array of at least (``exact``: of just) ``n_elems`` elements —
+    a window's ``[H, C, K]`` scores, where XLA runs its attention; the
+    prompt scan's decay matrices, where XLA runs that: what
     ``tests/test_chip_compile.py`` holds the prompt-window executables to
     (``prefill_scores_in_hbm: []``)."""
     import re
@@ -332,8 +333,9 @@ def scores_in_hbm(hlo_text: str, n_elems: int) -> list:
     hits = []
     for line in hlo_text.splitlines():
         m = re.search(r" = (\(.*?\)|\S+) [\w\-]+\(", line)  # the result's type(s)
-        if m and any(math.prod(map(int, dims.split(","))) >= n_elems
-                     for dims in re.findall(r"f32\[([\d,]+)\]", m.group(1))):
+        if m and any((n == n_elems if exact else n >= n_elems) for n in (
+                math.prod(map(int, dims.split(",")))
+                for dims in re.findall(r"f32\[([\d,]+)\]", m.group(1)))):
             hits.append(line.strip()[:200])
     return hits
 

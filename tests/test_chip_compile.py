@@ -597,6 +597,95 @@ def test_the_scores_reader_sees_xlas_scores(chip):
     assert scores_in_hbm(text, 32 * 1024 * 1024)
 
 
+@pytest.mark.parametrize("rows", [3, 1])
+def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows):
+    """The chunked scan of a Mamba-2 layer at Nemotron's widths (128 heads
+    of 64 in 8 groups, state 128, chunks of 128) over a dispatch of
+    ``rows`` windows of 1024 tokens, x, B and C read from the
+    convolution's ``[rows, 1024, 10240]`` bfloat16 where they lie: with
+    ``kernel`` ONE Mosaic kernel named ``ssm_scan`` and no float32 array
+    of ``rows * 8 * 128 * 128 * 128`` elements (the decay matrices
+    ``[B, nc, G, r, Q, K]``, 201 MB at three windows, which the
+    ``jax.numpy`` form writes and reads back: the reader sees them
+    there)."""
+    from mlmicroservicetemplate_tpu.ops import ssm
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
+
+    h, p, g, n, q, length = 128, 64, 8, 128, 128, 1024
+    f32 = jnp.float32
+    args = (chip((rows, length, h * p + 2 * g * n), jnp.bfloat16),
+            chip((rows, length, h), f32), chip((h,), f32), chip((h,), f32),
+            chip((rows, h, p, n), f32), chip((rows, length), jnp.int32))
+
+    def text(kernel):
+        return _compiled_text(
+            chip, ("ssm_scan", rows, kernel),
+            lambda *a: ssm.ssm_scan(*a, groups=g, state=n, chunk=q, kernel=kernel),
+            *args)
+
+    decay = rows * (length // q) * h * q * q
+    fused = text(True)
+    calls = [ln for ln in fused.splitlines()
+             if "tpu_custom_call" in ln and "ssm_scan" in ln]
+    assert len(calls) == 1 and scores_in_hbm(fused, decay) == []
+    assert scores_in_hbm(text(False), decay)
+
+
+def test_the_toy_windows_scan_holds_no_decay_matrix_with_kernels_on():
+    """The prompt-window executable of the toy Nemotron configuration
+    (``tests/test_nemotron_serving.py``'s: 8 heads of 8 in 2 groups, state
+    16, here chunks of 10), three windows of 30 tokens — sizes at which no
+    other array of the program has either element count —, compiled here: with
+    kernels on (interpret mode) no float32 array of ``B nc H Q Q``
+    elements (the decay matrices) nor of ``B nc H P N`` (the state every
+    chunk starts from, ``s_in``) is in the program; with kernels off both
+    are — the reference is the reference."""
+    import json
+
+    from cellbench import spec as bench_spec
+    from mlmicroservicetemplate_tpu.models import llama as llama_mod
+    from mlmicroservicetemplate_tpu.models.gpt import PagedState
+    from mlmicroservicetemplate_tpu.models.sampling import greedy_params
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
+    from test_nemotron_block import TOY
+
+    real = bench_spec.load_json(
+        bench_spec.HERE + "/configs/nemotron3-super-ep4-d11.json")
+    kw = json.loads(bench_spec.service_env(
+        {**real, **TOY, "chunk_size": 10})["LLAMA_CONFIG"])
+    b, c, bs, nb, t_w, q = 3, 30, 4, 40, 12, 10
+
+    def text(kernels: bool) -> str:
+        cfg = llama_mod.LlamaConfig(**{
+            **kw, "layer_pattern": "ME*M", "num_layers": 4, "eos_id": 1,
+            "pad_id": 0, "pallas_interpret": True, "pallas_decode": kernels})
+        params = jax.eval_shape(
+            lambda: llama_mod.init_params(jax.random.PRNGKey(0), cfg))
+        width = cfg.num_kv_heads * cfg.head_dim
+        state = jax.eval_shape(lambda: PagedState(
+            cache_k=[jnp.zeros((nb, bs, width))], cache_v=[jnp.zeros((nb, bs, width))],
+            key_valid=jnp.zeros((b, t_w * bs), jnp.int32),
+            write_idx=jnp.zeros((b,), jnp.int32), pos=jnp.zeros((b,), jnp.int32),
+            last_token=jnp.zeros((b,), jnp.int32), done=jnp.ones((b,), bool),
+            tokens=jnp.zeros((b, 8), jnp.int32), sample=greedy_params(b),
+            ssm=llama_mod.zero_ssm(cfg, 5, jnp.float32)))
+        i32 = jnp.int32
+        return jax.jit(
+            lambda p, s, tabs, ids, mask, starts, rows: llama_mod.paged_prefill_chunk(
+                p, cfg, s, tabs, ids, mask, starts, ssm_rows=rows),
+        ).lower(params, state, jax.ShapeDtypeStruct((b, t_w), i32),
+                jax.ShapeDtypeStruct((b, c), i32), jax.ShapeDtypeStruct((b, c), i32),
+                jax.ShapeDtypeStruct((b,), i32),
+                jax.ShapeDtypeStruct((b, 2), i32)).compile().as_text()
+
+    h, p, n = kw["ssm_heads"], kw["ssm_head_dim"], kw["ssm_state"]
+    decay, s_in = b * (c // q) * h * q * q, b * (c // q) * h * p * n
+    on, off = text(True), text(False)
+    for count in (decay, s_in):
+        assert scores_in_hbm(on, count, exact=True) == []
+        assert scores_in_hbm(off, count, exact=True)
+
+
 def test_an_undonated_insert_copies_every_pool(chip):
     """What the reader is for: the same insert without donation copies
     the pools (the program every insert ran until PR 30; 14 of the 16

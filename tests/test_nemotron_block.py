@@ -95,7 +95,23 @@ def _scan_inputs(length, b=2, h=8, p=4, g=2, n=16, seed=0):
     )
 
 
-_scan = jax.jit(ssm.ssm_scan, static_argnames=("chunk",))
+@functools.partial(jax.jit, static_argnames=("chunk", "kernel"))
+def _scan(i, mask, chunk=8, kernel=False, s0=None):
+    """``ssm_scan`` on ``_scan_inputs``' arrays: x, B and C side by side
+    as the convolution leaves them; ``kernel``: the fused chunk kernel in
+    interpret mode, else the ``jax.numpy`` form."""
+    length = i["x"].shape[1]
+    xbc = jnp.concatenate(
+        [v.reshape(v.shape[0], length, -1) for v in (i["x"], i["b"], i["c"])], -1)
+    return ssm.ssm_scan(
+        xbc, i["dt"], i["a"], i["d"], i["s0"] if s0 is None else s0, mask,
+        groups=i["b"].shape[2], state=i["b"].shape[3], chunk=chunk,
+        kernel=kernel, interpret=True)
+
+
+def _upto(i, n):
+    """The inputs' first ``n`` tokens."""
+    return {k: v[:, :n] if k in ("x", "dt", "b", "c") else v for k, v in i.items()}
 
 
 @jax.jit
@@ -112,8 +128,9 @@ def _token_by_token(i, mask, round_to=None):
     return jnp.moveaxis(ys, 0, 1), s
 
 
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
 @pytest.mark.parametrize("length", [1, 8, 9, 37])
-def test_the_chunked_scan_is_the_recurrence(length):
+def test_the_chunked_scan_is_the_recurrence(length, kernel):
     """Lengths that the chunk (8) divides and does not, a non-zero initial
     state, row 0 with a masked tail, row 1 masked whole: outputs at the
     real tokens and both final states equal the one-token update run a
@@ -121,26 +138,68 @@ def test_the_chunked_scan_is_the_recurrence(length):
     i = _scan_inputs(length)
     mask = jnp.ones((2, length), jnp.int32).at[0, max(1, length - 3):].set(
         0).at[1].set(0)
-    y, s = _scan(i["x"], i["dt"], i["a"], i["b"], i["c"], i["d"],
-                 i["s0"], mask, chunk=8)
+    y, s = _scan(i, mask, kernel=kernel)
     want_y, want_s = _token_by_token(i, mask)
     n = max(1, length - 3)
-    assert _close(y[0, :n], want_y[0, :n]) < 1e-4
+    assert y.shape == (2, length, 8 * 4) and s.dtype == jnp.float32
+    assert _close(y[0, :n], want_y[0, :n].reshape(n, -1)) < 1e-4
     assert _close(s, want_s) < 1e-4
     assert _close(s[1], i["s0"][1]) == 0.0
 
 
-def test_a_step_after_a_scan_is_one_longer_scan():
+@pytest.mark.parametrize("length", [1, 37, 128, 300])
+@pytest.mark.parametrize("b", [1, 3])
+def test_the_fused_kernel_is_the_scan_and_the_recurrence(b, length):
+    """The kernel (interpret mode) against the ``jax.numpy`` scan AND the
+    token-by-token update: 8 heads in 2 groups (G < H), a non-zero initial
+    state, chunks of 16 that divide no length here but 128; row 0's last
+    real chunk partly real, row 1 (of three) with no real token — its
+    state comes back bit for bit —, row 2's real prefix two fifths of the
+    row, so its last chunks are all fill and run no matmul.  The state
+    handed back is float32."""
+    i = _scan_inputs(length, b=b, seed=length)
+    real = [max(1, length - 3), 0, max(1, 2 * length // 5)][:b]
+    mask = (jnp.arange(length)[None, :] < jnp.asarray(real)[:, None]).astype(jnp.int32)
+    y, s = _scan(i, mask, chunk=16, kernel=True)
+    ref_y, ref_s = _scan(i, mask, chunk=16)
+    tok_y, tok_s = _token_by_token(i, mask)
+    assert s.dtype == jnp.float32 and y.dtype == jnp.float32
+    assert s.shape == i["s0"].shape and y.shape == ref_y.shape
+    for row, n in enumerate(real):
+        if not n:
+            assert _close(s[row], i["s0"][row]) == 0.0
+            continue
+        assert _close(y[row, :n], ref_y[row, :n]) < 1e-4
+        assert _close(y[row, :n], tok_y[row, :n].reshape(n, -1)) < 1e-4
+    assert _close(s, ref_s) < 1e-4 and _close(s, tok_s) < 1e-4
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_a_step_after_a_scan_is_one_longer_scan(kernel):
     i = _scan_inputs(21)
     ones = jnp.ones((2, 21), jnp.int32)
-    args = lambda n: (i["x"][:, :n], i["dt"][:, :n], i["a"], i["b"][:, :n],  # noqa: E731
-                      i["c"][:, :n], i["d"], i["s0"], ones[:, :n])
-    y20, s20 = _scan(*args(20), chunk=8)
+    y20, s20 = _scan(_upto(i, 20), ones[:, :20], kernel=kernel)
     y1, s21 = ssm.ssm_step(i["x"][:, 20], i["dt"][:, 20], i["a"], i["b"][:, 20],
                            i["c"][:, 20], i["d"], s20, jnp.ones((2,), bool))
-    y21, want = _scan(*args(21), chunk=8)
-    assert _close(s21, want) < 1e-4 and _close(y1, y21[:, 20]) < 1e-4
+    y21, want = _scan(i, ones, kernel=kernel)
+    assert _close(s21, want) < 1e-4 and _close(y1.reshape(2, -1), y21[:, 20]) < 1e-4
     assert _close(y20, y21[:, :20]) < 1e-4
+
+
+@pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
+def test_two_windows_in_sequence_are_one_scan_of_both(kernel):
+    """A prompt's second window continues the state its first left: 24 +
+    19 tokens (neither a multiple of the chunk) equal one scan of 43, a
+    row whose second window is all fill keeping the first's state."""
+    i = _scan_inputs(43, seed=5)
+    mask = jnp.ones((2, 43), jnp.int32).at[1, 24:].set(0)
+    first = _upto(i, 24)
+    y_a, s_a = _scan(first, mask[:, :24], kernel=kernel)
+    second = {k: v[:, 24:] if k in ("x", "dt", "b", "c") else v for k, v in i.items()}
+    y_b, s_b = _scan(second, mask[:, 24:], kernel=kernel, s0=s_a)
+    y, s = _scan(i, mask, kernel=kernel)
+    assert _close(s_b, s) < 1e-4 and _close(s_b[1], s_a[1]) == 0.0
+    assert _close(y_a, y[:, :24]) < 1e-4 and _close(y_b[0], y[0, 24:]) < 1e-4
 
 
 def test_the_convolution_keeps_the_last_real_inputs():

@@ -135,6 +135,28 @@ def test_windows_beside_unequal_mates_leave_the_one_shot_state(cfg, params, n): 
         assert moved.tolist() == [False, False, True, False, False]
 
 
+@pytest.mark.parametrize("n", [21, 24])
+def test_windows_through_the_kernels_leave_the_one_shot_state(cfg, params, n):  # noqa: F811
+    """The same dispatches with the kernels on (``pallas_decode``, interpret
+    mode: the fused scan of ``ops/ssm.py`` and the prompt-window attention):
+    the prompt's state row and taps are the one pass's (the ``jax.numpy``
+    scan's), a filled-up row's and every other row's bit for bit as they
+    were, float32."""
+    import dataclasses
+
+    kernels = dataclasses.replace(cfg, pallas_decode=True)
+    ids, mate = _ids(n, 11), _ids(40, 12)
+    state = _windows(params, kernels, _paged(cfg), ids, 2, _table(3), mate, 4, _table(20))
+    out: list = []
+    llama_mod.forward_hidden(params, cfg, ids[None], np.ones((1, n), np.int32),
+                             ssm_out=out)
+    for got, want in zip(state.ssm.state, out[0].state):
+        assert got.dtype == jnp.float32 and _close(got[2], want[0]) < 1e-5
+        assert _close(got[0], 3.0) == 0.0 and _close(got[3], 3.0) == 0.0
+    for got, want in zip(state.ssm.conv, out[0].conv):
+        assert _close(got[2], want[0]) < 1e-6
+
+
 def test_a_done_or_freed_slot_moves_no_state(cfg, params):  # noqa: F811
     """A slot that is done, and a slot whose table row the host has
     cleared (a freed slot with a STALE row index naming a row since given
