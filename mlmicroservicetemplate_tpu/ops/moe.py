@@ -50,6 +50,47 @@ import jax
 import jax.numpy as jnp
 
 
+#: Row tile of ``grouped_matmul``: the MXU's height.  The kernel visits
+#: ``M / tile + groups - 1`` row tiles and multiplies every one of them in
+#: full, so a smaller tile pads less; with K whole a group's weights stay
+#: put over its visits and a larger tile saves nothing (PERF.md section 6,
+#: PR 45: 128 beat 512 at every shape a cell runs, a 64 x 128 wave's 1024
+#: rows a group included).
+ROW_TILE = 128
+
+#: What the kernel's blocks may take of the 16 MiB of scoped VMEM a Pallas
+#: call gets on a v5e, by ``tile_bytes``'s count.  The widest blocks the
+#: cells' shapes take count 13.6 MiB and compile; 16.0 MiB by this count is
+#: refused (Mosaic adds ~0.6 MiB of its own; compiled for a described v5e,
+#: ``tests/test_chip_compile.py``).
+VMEM_BUDGET = 14 * 2**20
+
+
+def tile_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """VMEM the grouped matmul's blocks hold at a tiling: weight, row and
+    out blocks twice (the pipeline's two slots) and the float32
+    accumulator."""
+    return 2 * itemsize * (tk * tn + tm * tk + tm * tn) + 4 * tm * tn
+
+
+def _tile_sizes(size: int) -> list[int]:
+    """The tiles an axis may take, widest first: the axis whole, then its
+    divisors that are multiples of 128 (a lane tile)."""
+    return [size] + [t for t in range(size - size % 128, 0, -128)
+                     if t < size and size % t == 0]
+
+
+def matmul_tiles(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
+    """``(tm, tk, tn)`` of ``grouped_matmul`` for ``[M, K] x [G, K, N]``:
+    K WHOLE and the widest N tile whose blocks fit ``VMEM_BUDGET``; a K so
+    wide that no N tile fits beside it is tiled as well, widest first."""
+    for tk in _tile_sizes(k):
+        for tn in _tile_sizes(n):
+            if tile_bytes(ROW_TILE, tk, tn, itemsize) <= VMEM_BUDGET:
+                return ROW_TILE, tk, tn
+    raise ValueError(f"no tiling of a [{k}, {n}] expert fits {VMEM_BUDGET} B")
+
+
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
                    interpret: bool = False) -> jax.Array:
     """``lhs`` [M, K] rows sorted by group, ``rhs`` [G, K, N], ``group_sizes``
@@ -62,41 +103,41 @@ def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
     benchmark's expert cell runs (PERF.md section 6, PR 27: 1.21 against
     2.61 ms for a decode step's 512 assignments, 7.48 against 9.64 ms for
     a 64 x 128 wave's 65 536; d 2048, width 1024, 64 experts, one layer's
-    three matmuls).  Row tile 128 while the mean group is small (each
-    visited tile streams one expert's weights: HBM-bound), 512 once it
-    holds 512 rows (MXU-bound); the kernel wants M a multiple of the tile.
-    K and N are tiled by ``min(size, 1024)`` — or, for a size that 1024
-    does not divide and a chip measurement exists for, by ``_TILE``'s."""
+    three matmuls).
+
+    **Tiles** (``matmul_tiles``; PERF.md section 6, PR 45).  The kernel's
+    grid is (N tiles, row-tile visits, K tiles), K innermost, and its
+    pipeline fetches a weight block again whenever the block's index
+    changes between two steps.  With ONE K tile the consecutive row tiles
+    of a group keep the index ``(group, 0, n)`` and an expert's ``[K, tn]``
+    slab crosses HBM once a group; with two or more the index changes
+    every step and the slab is fetched again for every row tile the
+    group's rows touch — a prompt dispatch's 77-192 rows an expert against
+    a 128-row tile read each expert twice or more.  So K is never tiled
+    while a block of it fits: the N tile shrinks instead (N is the
+    outermost axis: a narrower tile re-reads the rows, megabytes against
+    the experts' gigabytes).  On the chip, one layer's matmuls at the
+    shapes the cells run, the tiles of before (K and N by 1024, rows by
+    512 from 512 rows a group) against these: a three-window prompt
+    dispatch 5.62 -> 4.32 ms (Trinity), 5.19 -> 3.66 (DeepSeek-V2's share),
+    4.97 -> 3.17 (Nemotron's); a decode step level or a few percent
+    better; the kernel wants M a multiple of the row tile."""
     from jax.experimental.pallas.ops.tpu.megablox import gmm
 
     m, k = lhs.shape
-    g, _, n = rhs.shape
-    tm = 512 if m >= 512 * g else 128
+    tm, tk, tn = matmul_tiles(k, rhs.shape[2], lhs.dtype.itemsize)
     pad = -m % tm
     if pad:
         lhs = jnp.pad(lhs, ((0, pad), (0, 0)))
     out = gmm(
         lhs, rhs, group_sizes, preferred_element_type=lhs.dtype,
-        tiling=(tm, _tile(k), _tile(n)), interpret=interpret,
+        tiling=(tm, tk, tn), interpret=interpret,
     )
     return out[:m] if pad else out
 
 
 def _relu2(x: jax.Array) -> jax.Array:
     return jnp.square(jax.nn.relu(x))
-
-
-#: K / N tile of ``grouped_matmul`` for a width that 1024 does not divide,
-#: by a measurement on the chip at the shapes the cell that has it runs
-#: (PERF.md section 6, PR 40: 2688 = 21 x 128 divides by 384 and 896).  A
-#: table and not the rule "the largest multiple of 128 up to 1024 that
-#: divides": that rule gives 896 here but 768 for the 1536 that runs at
-#: 1024 today (a ragged last tile), and no chip run has compared those.
-_TILE = {2688: 896}
-
-
-def _tile(size: int) -> int:
-    return _TILE.get(size, min(size, 1024))
 
 
 def group_limited(sel: jax.Array, n_group: int, topk_group: int) -> jax.Array:

@@ -698,18 +698,25 @@ def test_an_undonated_insert_copies_every_pool(chip):
     assert len(hits) >= 8 and all(" copy(" in h for h in hits)
 
 
-@pytest.mark.parametrize("assignments", [512, 65536])
-def test_grouped_matmul_compiles(chip, assignments):
-    """A decode step's 512 assignments and a 64 x 128 wave's 65 536
-    through both expert matmul shapes (gate/up and down)."""
+@pytest.mark.parametrize("rows,experts,d,w", [
+    (512, 64, 2048, 1024), (65536, 64, 2048, 1024), (24576, 128, 2048, 1024),
+    (12288, 40, 5120, 1536), (67584, 128, 1024, 2688),
+], ids=["olmoe-step", "olmoe-wave", "trinity-dispatch", "dsv2-window",
+        "nemotron-dispatch"])
+def test_grouped_matmul_compiles(chip, rows, experts, d, w):
+    """A decode step's 512 assignments, a 64 x 128 wave's 65 536 and the
+    prompt dispatches of the long-document cells (three windows of 1024
+    tokens x top-8 over Trinity's 128 experts, one of 2048 x top-6 over
+    DeepSeek-V2's 40 held, three x top-22 over Nemotron's 128 held)
+    through both expert matmul shapes (gate / up and down): a tiling
+    over the scoped VMEM limit fails here, not in a cell's boot."""
     from mlmicroservicetemplate_tpu.ops.moe import grouped_matmul
 
-    e, d, w = 64, 2048, 1024
-    sizes = chip((e,), jnp.int32)
+    sizes = chip((experts,), jnp.int32)
     for k, n in ((d, w), (w, d)):
         text = _compiled_text(
-            chip, ("gmm", assignments, k), grouped_matmul,
-            chip((assignments, k), jnp.bfloat16), chip((e, k, n), jnp.bfloat16),
+            chip, ("gmm", rows, experts, k, n), grouped_matmul,
+            chip((rows, k), jnp.bfloat16), chip((experts, k, n), jnp.bfloat16),
             sizes,
         )
         assert "tpu_custom_call" in text

@@ -254,6 +254,69 @@ def test_invalid_rows_are_neither_counted_nor_nan():
     assert int(counts0.sum()) == 0 and not bool(jnp.any(out0))
 
 
+# One expert layer's matmuls (gate / up [d, w], down [w, d]) in the
+# benchmark's cells: rows M (every assignment, held or not: a chip's share —
+# DeepSeek-V2's, Nemotron's quarter of the experts — sorts three rows in
+# four past the last group) over G held experts -> (d, w, the N tile of
+# gate / up, the N tile of down) in bf16.  A prompt dispatch's mean group is
+# 77-192 rows, two 128-row tiles or more; a wave's 1024; a step's 1-8.
+CELL_SHAPES = {
+    "trinity-prompt-dispatch-24576-rows-128-experts": (2048, 1024, 1024, 2048),
+    "dsv2-prompt-window-12288-rows-40-experts": (5120, 1536, 512, 1280),
+    "nemotron-prompt-dispatch-67584-rows-128-experts": (1024, 2688, 2688, 1024),
+    "olmoe-wave-65536-rows-64-experts": (2048, 1024, 1024, 2048),
+    "olmoe-decode-step-512-rows-64-experts": (2048, 1024, 1024, 2048),
+    "trinity-decode-step-256-rows-128-experts": (2048, 1024, 1024, 2048),
+    "dsv2-decode-step-192-rows-40-experts": (5120, 1536, 512, 1280),
+    "nemotron-decode-step-704-rows-128-experts": (1024, 2688, 2688, 1024),
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+def test_the_tiles_keep_an_experts_k_whole_at_the_shapes_the_cells_run(shape):
+    """K is one tile (so a group's weight block keeps its index over the
+    group's row tiles and crosses HBM once), every tile is a multiple of
+    128 that divides its axis, the blocks fit the budget and the next
+    wider N tile would not have."""
+    d, w, tn_in, tn_out = CELL_SHAPES[shape]
+    for (k, n), tn_want in (((d, w), tn_in), ((w, d), tn_out)):
+        tm, tk, tn = moe.matmul_tiles(k, n, 2)
+        assert (tm, tk, tn) == (moe.ROW_TILE, k, tn_want)
+        assert tm % 128 == 0 and tk % 128 == 0 and tn % 128 == 0 and n % tn == 0
+        assert moe.tile_bytes(tm, tk, tn, 2) <= moe.VMEM_BUDGET
+        wider = [t for t in moe._tile_sizes(n) if t > tn]
+        assert all(moe.tile_bytes(tm, tk, t, 2) > moe.VMEM_BUDGET for t in wider)
+
+
+def test_a_k_no_n_tile_fits_beside_is_tiled_and_an_odd_axis_stays_whole(monkeypatch):
+    assert moe.matmul_tiles(14336, 4096, 2) == (128, 7168, 256)
+    assert moe.matmul_tiles(16, 8, 4) == (128, 16, 8)  # the tests' sizes
+    monkeypatch.setattr(moe, "VMEM_BUDGET", 1 << 16)
+    with pytest.raises(ValueError, match="no tiling"):
+        moe.matmul_tiles(1000, 72, 2)
+
+
+def test_grouped_matmul_over_three_row_tiles_an_empty_group_and_invalid_rows(
+        monkeypatch):
+    """The kernel under the rule's tiling (K whole, N in three tiles, M
+    padded to the row tile) against a dense masked einsum: a group that
+    spans three row tiles, an empty group, rows past the last group."""
+    monkeypatch.setattr(moe, "VMEM_BUDGET", 1 << 20)
+    m, k, n = 500, 256, 384
+    assert moe.matmul_tiles(k, n, 4) == (128, 256, 128)
+    sizes = jnp.asarray([70, 300, 0, 60], jnp.int32)  # group 1: rows 70..369
+    valid = int(sizes.sum())
+    lhs = jax.random.normal(jax.random.PRNGKey(7), (m, k))
+    rhs = jax.random.normal(jax.random.PRNGKey(8), (4, k, n)) * 0.1
+    got = moe.grouped_matmul(lhs, rhs, sizes, interpret=True)
+    assert got.shape == (m, n)
+    group = jnp.repeat(jnp.arange(4), sizes, total_repeat_length=valid)
+    want = jnp.einsum(
+        "mgn,mg->mn", jnp.einsum("mk,gkn->mgn", lhs[:valid], rhs),
+        jax.nn.one_hot(group, 4))
+    assert _close(got[:valid], want) < 1e-4
+
+
 def test_chunk_counters_add_up_and_skip_done_rows(cfg, params):
     """The paged chunk's counts, a row a layer: each layer's = live rows
     x k x steps; a row that is ``done`` decodes pad tokens and is not

@@ -422,11 +422,12 @@ def test_a_config_without_a_pattern_builds_the_kinds_it_built(over):
     assert st.ssm == () and llama_mod.zero_ssm(c, 2, jnp.float32) == ()
 
 
-def test_a_width_1024_does_not_divide_takes_the_measured_tile():
-    assert moe._tile(2688) == 896 and 2688 % moe._tile(2688) == 0
-    # the widths the other configurations run keep the tile they had
-    assert [moe._tile(n) for n in (1024, 2048, 1536, 4096, 512)] == [
-        1024, 1024, 1024, 1024, 512]
+def test_a_width_1024_does_not_divide_is_tiled_by_its_own_divisors():
+    """2688 = 21 x 128 and DeepSeek-V2's 1536 (which ran a ragged 1024
+    until PR 45): whole, or by a divisor that is a multiple of 128 — never
+    a ragged last tile (``tests/test_moe.py`` pins what the cells take)."""
+    assert moe._tile_sizes(2688) == [2688, 896, 384, 128]
+    assert moe._tile_sizes(1536) == [1536, 768, 512, 384, 256, 128]
 
 
 # ---------------------------------------------------------------------------
