@@ -1457,17 +1457,11 @@ async def handle_status(request: web.Request) -> web.Response:
     if cdl is not None:
         # Decode dispatch shape: the auto-tuned chunk-chain pipelining
         # depth (STREAM_PIPELINE=0 picks it from measured RTT/compute
-        # at warmup — invisible until now) and the fused decode-window
-        # stats (DECODE_WINDOW; docs/decode-fusion.md).
+        # at warmup).
         body["decode"] = {
             "chain_depth": cdl.chain_depth,
             "chain_depth_auto": cdl._auto_depth,
             "chunk_tokens": engine.chunk_tokens,
-            "window_cap": getattr(cdl, "decode_window", 1),
-            "last_window": getattr(cdl, "last_window", 1),
-            "window_dispatches": getattr(cdl, "window_dispatches", 0),
-            "window_chunks": getattr(cdl, "window_chunks", 0),
-            "window_early_exits": getattr(cdl, "window_early_exits", 0),
             "chunk_dispatches": cdl.chunk_dispatches,
             "tokens_emitted": getattr(cdl, "tokens_emitted", 0),
             # Double-buffered host prep (HOST_PREP_DOUBLE;
@@ -1477,8 +1471,7 @@ async def handle_status(request: web.Request) -> web.Response:
             "prep_staged": getattr(cdl, "prep_staged", 0),
             "prep_hits": getattr(cdl, "prep_hits", 0),
             "prep_misses": getattr(cdl, "prep_misses", 0),
-            # Per-site host-sync counts (the quantity DECODE_WINDOW
-            # divides); the fusion A/B reads the chunk+fetch deltas.
+            # Per-site host-sync counts.
             "dispatch_counts": {
                 site: a["count"]
                 for site, a in engine.dispatch_attribution().items()

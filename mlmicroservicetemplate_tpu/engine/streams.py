@@ -21,13 +21,13 @@ wave's rows land in their slots through ONE such dispatch
 
 One rule for the decode state: **a state that is replaced is donated**.
 Every executable that takes the batched state and returns its successor
-(chunk, window, insert, handoff, chunked-prefill window, host-tier
+(chunk, insert, handoff, chunked-prefill window, host-tier
 scatter) donates it, so the compiler aliases each KV buffer's input to
 its output and writes a row in place; without it every call copied all
 of the caches in and out (PERF.md section 6, PR 30).  What follows from
 it: nothing outside ``self._state`` holds a leaf of a state — whatever
-the loop fetches after a later dispatch (tokens, ``done``, a window's
-history) is an output of its own; executables that only read the state
+the loop fetches after a later dispatch (tokens, ``done``) is an
+output of its own; executables that only read the state
 (prefix and swap gathers) do not donate; and a dispatch that fails after
 its state was consumed is fatal, not retried (engine/faults.py).
 
@@ -571,17 +571,6 @@ class ContinuousDecodeLoop:
             # (it survives reset_device_state; a fleet shares one).
             self._swap_gather_jit = None
             self._swap_scatter_jit = None
-        # Fused decode windows (DECODE_WINDOW; docs/decode-fusion.md):
-        # up to W chunk scans fuse into ONE dispatch (lax.while_loop
-        # with on-device EOS early exit, models/window.py), so the
-        # host submits once, fetches once and reconciles once per W
-        # chunks — the direct attack on the round-11 attribution's
-        # host_share ≈ 1.0 at the chunk/fetch sites.  W is picked per
-        # dispatch by the governor (scheduler/policy.py): deep for
-        # batch-class / idle backfill, 1 whenever interactive streams
-        # are live or waiting (their TBT and the admission/preemption
-        # cadence bind at chunk boundaries).  1 = off, exactly the
-        # seed's per-chunk dispatch path.
         # Swap-resume jobs + swap-out copies pending materialization
         # (exist in contiguous mode too so the shared loop code never
         # branches on their presence; only paged loops populate them).
@@ -608,11 +597,11 @@ class ContinuousDecodeLoop:
         # between dispatches.  The staged plan is consumed at the next
         # dispatch only if the loop state it derived from is
         # bit-identical (same tenants, same dispatched-step cursors,
-        # same table bytes, same window); anything that moved rolls
-        # the staged grants back and re-preps inline — so the
-        # dispatched table is identical either way and token identity
-        # is structural, not probabilistic.  Contiguous mode has no
-        # growth/table prep to stage; the knob is a no-op there.
+        # same table bytes); anything that moved rolls the staged
+        # grants back and re-preps inline — so the dispatched table is
+        # identical either way and token identity is structural, not
+        # probabilistic.  Contiguous mode has no growth/table prep to
+        # stage; the knob is a no-op there.
         self.host_prep_double = bool(
             getattr(cfg, "host_prep_double", True)
         )
@@ -620,39 +609,11 @@ class ContinuousDecodeLoop:
         self.prep_staged = 0
         self.prep_hits = 0
         self.prep_misses = 0
-        self.decode_window = max(1, int(getattr(cfg, "decode_window", 1) or 1))
-        if self.decode_window > 1:
-            if self.spec:
-                raise ValueError(
-                    "DECODE_WINDOW>1 does not compose with SPEC_CONTINUOUS "
-                    "(spec rounds are their own fused dispatch shape)"
-                )
-            bundle = engine.bundle
-            if getattr(bundle, "window_fn", None) is None or (
-                self.paged and getattr(bundle, "paged_window_fn", None) is None
-            ):
-                raise ValueError(
-                    f"DECODE_WINDOW={self.decode_window} needs a window-"
-                    f"capable family (gpt2/llama); {bundle.name} decodes "
-                    "one chunk per dispatch"
-                )
-        from ..scheduler.policy import DecodeWindowGovernor
-
-        self._window_gov = DecodeWindowGovernor(
-            self.decode_window, bool(getattr(cfg, "decode_window_auto", True))
-        )
-        self._window_jit = None
-        self._paged_window_jit = None
         # Active Pallas decode-kernel variant ("" = default kernel).
         # Resolved once at warm time (_autotune_kernel) BEFORE the
         # paged executables trace; also the statics entry that keys
         # those executables in the shared cache (docs/kernel_tuning.md).
         self.kernel_variant = ""
-        # Window observability (/status.decode + bench window stats).
-        self.window_dispatches = 0
-        self.window_chunks = 0
-        self.window_early_exits = 0
-        self.last_window = 1
         self.tokens_emitted = 0
         # SLA scheduling (scheduler/policy.py): the old unbounded
         # handoff Queue + instant reject past max_streams is now a
@@ -696,7 +657,7 @@ class ContinuousDecodeLoop:
         # inter-chunk cadence drops to ~max(RTT/D, chunk compute)
         # (the round-3 loop was fixed at depth 1, which is why it lost
         # to N overlapped legacy chains at a long dispatch round-trip).
-        # Each entry: (toks, done, {slot: stream at dispatch time}).
+        # Each entry: ((toks, done), {slot: stream at dispatch time}).
         # Snapshots keep late-arriving tokens from leaking into a
         # slot's next tenant.  Depth starts at the configured value
         # (min 1); STREAM_PIPELINE=0 means warm() auto-tunes it from
@@ -742,16 +703,8 @@ class ContinuousDecodeLoop:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._thread_lock = threading.Lock()
-        # Admission overlap (default on): prefill FETCHES ride behind
-        # the next shared chunk dispatch instead of stalling it — the
-        # round-2→3 loop blocked every live stream for ~(N×prefill +
-        # RTT) whenever anyone joined (round-3 verdict missing #2).
-        # ADMIT_OVERLAP=0 restores the blocking order for A/B.
         import os
 
-        self.overlap_admission = os.environ.get(
-            "ADMIT_OVERLAP", "1"
-        ).lower() not in ("0", "false", "no")
         # Idle-burst admission grace (ms): how long an idle loop waits
         # for the rest of a concurrent burst before admitting the wave.
         self._admit_grace_s = float(os.environ.get("ADMIT_GRACE_MS", "8")) / 1e3
@@ -775,7 +728,6 @@ class ContinuousDecodeLoop:
         self._flight = getattr(engine, "flight", None)
         if self.prefill_chunk:
             self._pacer.recorder = self._flight
-        self._window_gov.recorder = self._flight
         # SLO burn-rate tracker (r20; scheduler/policy.SLOTracker):
         # per-priority-class TTFT/TBT objectives from the SLO_* knobs
         # feed multi-window burn-rate gauges and the optional
@@ -1208,19 +1160,6 @@ class ContinuousDecodeLoop:
                             self._reserve(st)
                             wave.append(st)
                 self._class_gauges()
-                if wave and not self.overlap_admission:
-                    # Round-3 blocking order, kept for A/B
-                    # (ADMIT_OVERLAP=0): prefill + fetch + insert all
-                    # before the next chunk dispatch.
-                    t_wave = time.monotonic() if self.active else None
-                    self._pending_wave = wave
-                    with tracing.phase("loop/wave_dispatch"):
-                        self._pending_admissions = self._admit_dispatch(wave)
-                    self._pending_wave = []
-                    self._admit_complete(self._pending_admissions)
-                    self._pending_admissions = []
-                    self._note_wave_stall(t_wave)
-                    wave = []
                 # Depth-D pipeline: keep up to chain_depth chunks in
                 # flight — chunk k's ~RTT-long fetch overlaps later
                 # chunks' dispatch + compute + async host copy, so the
@@ -1398,7 +1337,6 @@ class ContinuousDecodeLoop:
             inflight_chunks=len(self._inflight_chunks),
             chunk_dispatches=self.chunk_dispatches,
             prefill_dispatches=self.prefill_dispatches,
-            window=self.last_window,
             slots={
                 str(slot): {
                     "rid": st.rid, "klass": st.klass,
@@ -3305,13 +3243,7 @@ class ContinuousDecodeLoop:
             self._prefilling.remove(job)
             if self._handoff_job(job):
                 advanced = True
-        # Window boundaries are up to last_window× rarer than chunk
-        # boundaries: scale the per-boundary budget so prefill keeps
-        # the same share of the interleave under deep fusion.
-        budget = (
-            self.prefill_budget * max(1, self.last_window)
-            if live else (1 << 30)
-        )
+        budget = self.prefill_budget if live else (1 << 30)
         jobs = sorted(
             [j for j in self._prefilling if not j.ready],
             key=lambda j: (
@@ -4610,17 +4542,12 @@ class ContinuousDecodeLoop:
 
     # -- decode --------------------------------------------------------
 
-    def _inflight_chunks_ahead(self) -> int:
-        """Upper bound on chunks the in-flight dispatches will deliver
-        (windows count their full cap — early exit only shortens)."""
-        return sum(w for _, _, w in self._inflight_chunks)
-
     def _work_remains(self) -> bool:
         """True while some active stream still needs tokens beyond
         what the in-flight dispatches will already deliver
         (``produced`` only advances at delivery, so count in-flight
-        coverage — window dispatches cover up to W chunks each)."""
-        ahead = self._inflight_chunks_ahead() * self.engine.chunk_tokens
+        coverage — one chunk a dispatch)."""
+        ahead = len(self._inflight_chunks) * self.engine.chunk_tokens
         return any(
             st.produced + ahead < st.budget for st in self.active.values()
         )
@@ -4643,11 +4570,11 @@ class ContinuousDecodeLoop:
 
     def interactive_load(self) -> tuple[bool, bool]:
         """(interactive decode live, interactive work waiting) — the
-        class-pressure signal shared by the decode-window governor and
-        the bulk-job ``BackfillGovernor`` (scheduler/policy.py): live
-        means an interactive stream occupies a slot; waiting means one
-        sits in the deadline queue or mid-prefill/swap-in.  Safe to
-        read from the event loop (all plain reads)."""
+        class-pressure signal of the bulk-job ``BackfillGovernor``
+        (scheduler/policy.py): live means an interactive stream
+        occupies a slot; waiting means one sits in the deadline queue
+        or mid-prefill/swap-in.  Safe to read from the event loop (all
+        plain reads)."""
         from ..scheduler.policy import INTERACTIVE
 
         live = any(
@@ -4661,61 +4588,9 @@ class ContinuousDecodeLoop:
         )
         return live, waiting
 
-    def _pick_window(self, preview: bool = False) -> int:
-        """Fused-window depth for the NEXT dispatch: the governor's
-        class policy, clamped to the chunks any live stream still
-        needs beyond what is already in flight.  ``preview`` stages
-        without governor side effects (double-buffered prep)."""
-        if self.decode_window <= 1 or self.spec:
-            return 1
-        chunk = self.engine.chunk_tokens
-        ahead = self._inflight_chunks_ahead() * chunk
-        need = max(
-            (
-                st.budget - st.produced - ahead
-                for st in self.active.values()
-                if not st.cancelled.is_set()
-            ),
-            default=0,
-        )
-        interactive_live, interactive_waiting = self.interactive_load()
-        fn = self._window_gov.preview if preview else self._window_gov.pick
-        return fn(
-            max_chunks=-(-need // chunk),
-            interactive_live=interactive_live,
-            interactive_waiting=interactive_waiting,
-        )
-
-    def _window_fn(self):
-        """Jitted fused-window executable (static n_steps/max_chunks/
-        sample — one executable per (W, sample) pair, W power-of-two
-        bounded by the governor)."""
-        import jax
-
-        if self.paged:
-            if self._paged_window_jit is None:
-                self._paged_window_jit = self._shared_jit(
-                    "paged_window",
-                    lambda: jax.jit(self.engine.bundle.paged_window_fn,
-                                    static_argnums=(3, 4, 5),
-                                    donate_argnums=(1,)),
-                    statics=(self.kernel_variant,),  # see _paged_chunk_fn
-                )
-            return self._paged_window_jit
-        if self._window_jit is None:
-            self._window_jit = self._shared_jit(
-                "window",
-                lambda: jax.jit(self.engine.bundle.window_fn,
-                                static_argnums=(2, 3, 4),
-                                donate_argnums=(1,)),
-            )
-        return self._window_jit
-
-    def _grow_for_dispatch(self, n_chunks: int = 1) -> None:
+    def _grow_for_dispatch(self) -> None:
         """Block-by-block growth at the dispatch boundary: every live
-        row's table must cover the positions the NEXT ``n_chunks``
-        chunks will write (a fused window pre-provisions its whole
-        depth up front; the ledger reconciles at the window boundary).
+        row's table must cover the positions the NEXT chunk will write.
         A row whose growth finds the pool dry — after reclaiming
         prefix pins — is checkpointed and re-queued (token-identical
         resume when blocks free), the paged equivalent of vLLM's
@@ -4724,7 +4599,7 @@ class ContinuousDecodeLoop:
         from .kv_blocks import OutOfBlocks
 
         eng = self.engine
-        chunk = eng.chunk_tokens * max(1, int(n_chunks))
+        chunk = eng.chunk_tokens
         grew = False
         for slot, st in list(self.active.items()):
             if st.cancelled.is_set() or st.blocks is None:
@@ -4788,10 +4663,9 @@ class ContinuousDecodeLoop:
         if not self._work_remains():
             return
         eng = self.engine
-        w = self._pick_window(preview=True)
         t0 = time.perf_counter()
         steps0 = dict(self._dispatched_steps)
-        self._grow_for_dispatch(w)
+        self._grow_for_dispatch()
         if not self.active:  # every row checkpointed on a dry pool
             return
         deltas = {
@@ -4809,7 +4683,6 @@ class ContinuousDecodeLoop:
                 "prep", lambda: jnp.asarray(table_np)
             )
         self._staged_prep = {
-            "w": w,
             "tenants": dict(self.active),
             "steps": dict(self._dispatched_steps),
             "deltas": deltas,
@@ -4821,9 +4694,9 @@ class ContinuousDecodeLoop:
     def _rollback_staged_prep(self) -> None:
         """Return a stale staged plan's grants: subtract each still-
         live tenant's staged step delta and trim the over-granted tail
-        blocks (mirrors ``_reconcile_window``).  Tenants that left the
-        active set since staging released their whole block list
-        already — nothing to return for them."""
+        blocks.  Tenants that left the active set since staging
+        released their whole block list already — nothing to return
+        for them."""
         staged, self._staged_prep = self._staged_prep, None
         if staged is None:
             return
@@ -4844,19 +4717,18 @@ class ContinuousDecodeLoop:
         if trimmed and self.admission is not None:
             self.admission.note_pool()
 
-    def _consume_staged_prep(self, w: int):
+    def _consume_staged_prep(self):
         """The staged device table for this dispatch, or None.  Valid
         ONLY when the loop state still matches the staged snapshot
-        bit-for-bit — same window, same tenants (by identity), same
-        dispatched-step cursors, same table bytes.  A mismatch rolls
-        the staged grants back so the inline re-prep starts from the
-        exact pre-staging state."""
+        bit-for-bit — same tenants (by identity), same dispatched-step
+        cursors, same table bytes.  A mismatch rolls the staged grants
+        back so the inline re-prep starts from the exact pre-staging
+        state."""
         staged = self._staged_prep
         if staged is None:
             return None
         if (
-            staged["w"] == w
-            and staged["tenants"] == dict(self.active)
+            staged["tenants"] == dict(self.active)
             and staged["steps"] == dict(self._dispatched_steps)
             and np.array_equal(staged["table_np"], self._table)
         ):
@@ -4869,45 +4741,37 @@ class ContinuousDecodeLoop:
 
     def _dispatch_chunk(self) -> None:
         eng = self.engine
-        w = self._pick_window()
-        self.last_window = w
         tr = tracing.tracer()
         if tr is None:
-            self._dispatch_chunk_inner(eng, w)
+            self._dispatch_chunk_inner(eng)
             return
         # Ring only (TRACE=1): which requests rode this chunk.  The
         # profiler's trace names the interval ``loop/chunk_dispatch``.
         with tr.span(
             "decode_chunk", cat="engine", n_streams=len(self.active),
             streams=[st.rid for st in self.active.values()],
-            paged=self.paged, window=w,
+            paged=self.paged,
         ):
-            self._dispatch_chunk_inner(eng, w)
+            self._dispatch_chunk_inner(eng)
 
     def _note_dispatched(self, entry) -> None:
         eng = self.engine
         self.chunk_dispatches += 1
         metrics.STREAM_BATCH.labels(eng.bundle.name).observe(len(self.active))
-        w = entry[2]
-        metrics.DECODE_WINDOW_CHUNKS.labels(eng.bundle.name).observe(w)
         if self.paged:
-            self._note_table_blocks(eng.chunk_tokens * w)
+            self._note_table_blocks(eng.chunk_tokens)
             if self._window_layers:
-                self._note_window_keys(eng.chunk_tokens * w)
-        if w > 1:
-            self.window_dispatches += 1
+                self._note_window_keys(eng.chunk_tokens)
         self._inflight_chunks.append(entry)
 
-    def _dispatch_chunk_inner(self, eng, w: int = 1) -> None:
+    def _dispatch_chunk_inner(self, eng) -> None:
         if self.paged:
             # Double-buffered prep: the staged plan (growth already
             # ran, table already uploading) is used when still valid;
-            # otherwise fall back to the inline pass.  A fused window
-            # pre-provisions blocks for its whole depth up front: one
-            # growth pass per window, not per chunk — either way.
-            table = self._consume_staged_prep(w)
+            # otherwise fall back to the inline pass.
+            table = self._consume_staged_prep()
             if table is None:
-                self._grow_for_dispatch(w)
+                self._grow_for_dispatch()
                 if not self.active:  # every row checkpointed, dry pool
                     return
             use_sample = bool(self.sampled_slots)
@@ -4920,34 +4784,21 @@ class ContinuousDecodeLoop:
                     # next handoff, growth or release writes ``_table``
                     # (the CPU backend aliases an aligned host buffer).
                     table = jnp.asarray(self._table.copy())
-                if w > 1:
-                    self._state, toks, hist, nc = eng.dispatch_guard(
-                        "chunk",
-                        lambda: self._window_fn()(
-                            dparams, self._state, table,
-                            eng.chunk_tokens, w, use_sample,
-                        ),
-                        donates=self._state,
-                    )
-                    prefetch_to_host(toks, hist, nc)
-                    entry = ((toks, hist, nc), dict(self.active), w)
-                else:
-                    # ``done`` is an output of the chunk, as ``toks`` is:
-                    # the entry is fetched after later dispatches have
-                    # consumed this state.  With experts ``toks`` is
-                    # (tokens, counts): the chunk's [L, E] routing counts
-                    # ride the same fetch.
-                    self._state, toks, done = eng.dispatch_guard(
-                        "chunk",
-                        lambda: self._paged_chunk_fn()(
-                            dparams, self._state, table,
-                            eng.chunk_tokens, use_sample,
-                        ),
-                        donates=self._state,
-                    )
-                    prefetch_to_host(toks, done)
-                    entry = ((toks, done), dict(self.active), 1)
-            self._note_dispatched(entry)
+                # ``done`` is an output of the chunk, as ``toks`` is:
+                # the entry is fetched after later dispatches have
+                # consumed this state.  With experts ``toks`` is
+                # (tokens, counts): the chunk's [L, E] routing counts
+                # ride the same fetch.
+                self._state, toks, done = eng.dispatch_guard(
+                    "chunk",
+                    lambda: self._paged_chunk_fn()(
+                        dparams, self._state, table,
+                        eng.chunk_tokens, use_sample,
+                    ),
+                    donates=self._state,
+                )
+                prefetch_to_host(toks, done)
+            self._note_dispatched(((toks, done), dict(self.active)))
             return
         use_sample = bool(self.sampled_slots)
         # Spec mode stays on the base tree: adapters do not compose
@@ -4967,18 +4818,7 @@ class ContinuousDecodeLoop:
                     donates=self._state,
                 )
                 prefetch_to_host(out, ns, done)
-                entry = (((out, ns), done), dict(self.active), 1)
-            elif w > 1:
-                self._state, toks, hist, nc = eng.dispatch_guard(
-                    "chunk",
-                    lambda: self._window_fn()(
-                        dparams, self._state, eng.chunk_tokens, w,
-                        use_sample,
-                    ),
-                    donates=self._state,
-                )
-                prefetch_to_host(toks, hist, nc)
-                entry = ((toks, hist, nc), dict(self.active), w)
+                entry = (((out, ns), done), dict(self.active))
             else:
                 self._state, toks, done = eng.dispatch_guard(
                     "chunk",
@@ -4992,21 +4832,16 @@ class ContinuousDecodeLoop:
                 # _deliver_oldest finds the data (mostly) already on
                 # this side of the wire.
                 prefetch_to_host(toks, done)
-                entry = ((toks, done), dict(self.active), 1)
+                entry = ((toks, done), dict(self.active))
         self._note_dispatched(entry)
 
-    def _route_entry(self, fetched, snapshot, w: int) -> None:
-        """Route one fetched in-flight entry: a (toks, done) pair from
-        the per-chunk path, or a (toks, done_hist, n_chunks) window."""
-        if len(fetched) == 3:
-            toks_np, hist_np, nc = fetched
-            self._route_window(toks_np, hist_np, int(nc), snapshot, w)
-        else:
-            toks_np, done_np = fetched
-            if isinstance(toks_np, tuple) and not self.spec:
-                toks_np, counts = toks_np
-                self._note_moe(counts)
-            self._route_chunk(toks_np, done_np, snapshot)
+    def _route_entry(self, fetched, snapshot) -> None:
+        """Route one fetched in-flight entry: a chunk's (toks, done)."""
+        toks_np, done_np = fetched
+        if isinstance(toks_np, tuple) and not self.spec:
+            toks_np, counts = toks_np
+            self._note_moe(counts)
+        self._route_chunk(toks_np, done_np, snapshot)
         if self.on_ok is not None:
             # One successfully fetched-and-routed dispatch closes the
             # replica's breaker fault streak (engine/fleet.py).
@@ -5123,12 +4958,12 @@ class ContinuousDecodeLoop:
 
         if not self._inflight_chunks:
             return
-        fetchables, snapshot, w = self._inflight_chunks.pop(0)
+        fetchables, snapshot = self._inflight_chunks.pop(0)
         with tracing.phase("loop/deliver"):
             fetched = self.engine.dispatch_guard(
                 "fetch", lambda: jax.device_get(fetchables)
             )
-            self._route_entry(fetched, snapshot, w)
+            self._route_entry(fetched, snapshot)
 
     def _deliver_all(self) -> None:
         """Drain every in-flight dispatch with ONE combined device_get."""
@@ -5141,16 +4976,16 @@ class ContinuousDecodeLoop:
         with tracing.phase("loop/deliver"):
             fetched = self.engine.dispatch_guard(
                 "fetch",
-                lambda: jax.device_get([f for f, _, _ in entries]),
+                lambda: jax.device_get([f for f, _ in entries]),
             )
-            for (_, snapshot, w), got in zip(entries, fetched):
-                self._route_entry(got, snapshot, w)
+            for (_, snapshot), got in zip(entries, fetched):
+                self._route_entry(got, snapshot)
 
     def _deliver_ready(self) -> None:
         """Opportunistic delivery of in-flight work whose buffers are
         ALREADY on this side of the wire (``is_ready`` — the async
         host copies started at dispatch): paged mode frees EOS'd rows'
-        blocks at fetch/reconcile time, BEFORE the next dispatch's
+        blocks at fetch time, BEFORE the next dispatch's
         growth pass would keep granting blocks to rows the device
         already finished.  Costs nothing when data is still in flight
         (no sync — the depth-D cadence is untouched)."""
@@ -5168,50 +5003,6 @@ class ContinuousDecodeLoop:
             except AttributeError:  # backend without is_ready probes
                 return
             self._deliver_oldest()
-
-    def _route_window(self, toks_np, hist_np, nc: int, snapshot,
-                      w: int) -> None:
-        """Window delivery = the per-chunk routing replayed over the
-        ``nc`` chunks the device actually ran: same chunk segments,
-        same per-boundary done flags (``done_hist``), same budget
-        cursor — token-identical to fetching each chunk separately,
-        at one host sync for the lot."""
-        chunk = self.engine.chunk_tokens
-        self.window_chunks += nc
-        if nc < w:
-            self.window_early_exits += 1
-            metrics.WINDOW_EARLY_EXITS.labels(self.engine.bundle.name).inc()
-            if self._flight is not None:
-                self._flight.event("window_early_exit", ran=nc, window=w)
-        for c in range(nc):
-            self._route_chunk(
-                toks_np[:, c * chunk : (c + 1) * chunk], hist_np[c], snapshot
-            )
-        if nc < w and self.paged:
-            self._reconcile_window(snapshot, w - nc)
-
-    def _reconcile_window(self, snapshot, unran_chunks: int) -> None:
-        """Window-boundary ledger reconcile: chunks an early-exited
-        window never ran were still pre-provisioned at dispatch — walk
-        the snapshot's still-live tenants, roll their dispatched-step
-        cursor back and return the over-granted tail blocks to the
-        pool.  (Rows that EOS'd or finished their budget were already
-        fully freed by the routing above.)"""
-        chunk = self.engine.chunk_tokens
-        trimmed = False
-        for slot, st in snapshot.items():
-            if self.active.get(slot) is not st or st.blocks is None:
-                continue
-            steps = max(
-                0, self._dispatched_steps.get(slot, 0) - unran_chunks * chunk
-            )
-            self._dispatched_steps[slot] = steps
-            need = min(st.s_base + steps, st.s_base + st.budget)
-            trimmed |= bool(st.blocks.trim(need))
-            n = len(st.blocks.ids)
-            self._table[slot, n:] = self.pool.num_blocks
-        if trimmed and self.admission is not None:
-            self.admission.note_pool()
 
     def _route_chunk(self, toks_np, done_np, snapshot) -> None:
         eng = self.engine
@@ -5376,7 +5167,6 @@ class ContinuousDecodeLoop:
                         eng.chunk_tokens, flag,
                     )
                     jax.device_get(toks)
-        self._warm_windows(warm_sampled)
         # Re-warm the inserts in SERVING order — against a chunk-OUTPUT
         # batched state.  The first such call in a process pays a
         # one-time cost of seconds (pre-round record; absent when
@@ -5605,8 +5395,6 @@ class ContinuousDecodeLoop:
                     jnp.asarray(self._table), eng.chunk_tokens, flag,
                 )
                 jax.device_get(toks)
-        with tracing.boot_phase("boot/warm/loop/windows"):
-            self._warm_windows(warm_sampled)
         with tracing.boot_phase("boot/warm/loop/swap"):
             self._warm_swap()
         if self.prefill_chunk:
@@ -5670,37 +5458,6 @@ class ContinuousDecodeLoop:
                     sp, np.int32(0),
                 )
             jax.block_until_ready(jax.tree.leaves(self._state)[0])
-
-    def _warm_windows(self, warm_sampled: bool) -> None:
-        """Compile the fused-window executables off the request path:
-        one per (power-of-two W ≤ cap, sample flag) — exactly the grid
-        the governor can pick from (it floors to a power of two for
-        this reason).  The all-dead warm state exits every window at
-        chunk 0, so each warm call costs one compile + one dispatch."""
-        if self.decode_window <= 1 or self.spec:
-            return
-        import jax
-        import jax.numpy as jnp
-
-        eng = self.engine
-        flags = (False, True) if warm_sampled else (False,)
-        w = 2
-        while w <= self.decode_window:
-            for flag in flags:
-                with eng._lock:
-                    if self.paged:
-                        self._state, toks, _, _ = self._window_fn()(
-                            self._mp(n=self.n_slots), self._state,
-                            jnp.asarray(self._table), eng.chunk_tokens, w,
-                            flag,
-                        )
-                    else:
-                        self._state, toks, _, _ = self._window_fn()(
-                            self._mp(n=self.n_slots), self._state,
-                            eng.chunk_tokens, w, flag,
-                        )
-                    jax.device_get(toks)
-            w *= 2
 
     def _tune_chain_depth_paged(self) -> None:
         """Paged variant of ``_tune_chain_depth`` (the chunk takes the

@@ -395,23 +395,6 @@ def generate_chunk(
     return state, jnp.transpose(toks)
 
 
-def generate_window(
-    params: Params, cfg: GPTConfig, state: GPTState, n_steps: int,
-    max_chunks: int, sample: bool = False,
-):
-    """Up to ``max_chunks`` chunk scans fused into ONE dispatch with
-    on-device EOS early exit (models/window.py) — the engine's fused
-    decode-window contract (DECODE_WINDOW).  Body == ``generate_chunk``
-    verbatim, so the window is token-identical to dispatching the same
-    chunks one by one."""
-    from .window import decode_window
-
-    return decode_window(
-        lambda s: generate_chunk(params, cfg, s, n_steps, sample),
-        state, n_steps, max_chunks, cfg.pad_id,
-    )
-
-
 def greedy_generate(
     params: Params,
     cfg: GPTConfig,
@@ -564,23 +547,6 @@ def generate_chunk_paged(
 
     state, toks = jax.lax.scan(step, state, None, length=n_steps)
     return state, jnp.transpose(toks)
-
-
-def generate_window_paged(
-    params: Params, cfg: GPTConfig, state: PagedState, table: jax.Array,
-    n_steps: int, max_chunks: int, sample: bool = False,
-):
-    """Paged fused decode window: up to ``max_chunks`` paged chunk
-    scans in one dispatch, EOS early exit on device.  The block table
-    is constant across the window — the engine pre-provisions blocks
-    for all ``max_chunks`` chunks up front and reconciles the ledger
-    at the window boundary."""
-    from .window import decode_window
-
-    return decode_window(
-        lambda s: generate_chunk_paged(params, cfg, s, table, n_steps, sample),
-        state, n_steps, max_chunks, cfg.pad_id,
-    )
 
 
 # ---------------------------------------------------------------------------

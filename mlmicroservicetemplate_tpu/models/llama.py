@@ -1519,22 +1519,6 @@ def generate_chunk(
     return state, jnp.transpose(toks)
 
 
-def generate_window(
-    params: Params, cfg: LlamaConfig, state: GPTState, n_steps: int,
-    max_chunks: int, sample: bool = False,
-):
-    """Fused decode window (DECODE_WINDOW): up to ``max_chunks`` chunk
-    scans in ONE dispatch with on-device EOS early exit — the llama
-    twin of ``gpt.generate_window`` (int8 KV cache entries ride the
-    while_loop carry as (payload, scale) tuples unchanged)."""
-    from .window import decode_window
-
-    return decode_window(
-        lambda s: generate_chunk(params, cfg, s, n_steps, sample),
-        state, n_steps, max_chunks, cfg.pad_id,
-    )
-
-
 def greedy_generate(
     params: Params,
     cfg: LlamaConfig,
@@ -1774,21 +1758,6 @@ def generate_chunk_paged(params: Params, cfg: LlamaConfig, state, table,
         toks, counts = out
         return state, (jnp.transpose(toks), jnp.sum(counts, axis=0))
     return state, jnp.transpose(out)
-
-
-def generate_window_paged(params: Params, cfg: LlamaConfig, state, table,
-                          n_steps: int, max_chunks: int,
-                          sample: bool = False):
-    """Paged fused decode window over a constant block table (blocks
-    for all ``max_chunks`` chunks are pre-provisioned by the engine;
-    the ledger reconciles at the window boundary)."""
-    from .window import decode_window
-
-    def chunk(s):
-        s, out = generate_chunk_paged(params, cfg, s, table, n_steps, sample)
-        return s, (out[0] if cfg.num_experts else out)  # a window counts nothing
-
-    return decode_window(chunk, state, n_steps, max_chunks, cfg.pad_id)
 
 
 # ---------------------------------------------------------------------------

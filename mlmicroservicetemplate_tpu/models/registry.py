@@ -95,15 +95,6 @@ class ModelBundle:
     empty_state_fn: Callable | None = None
     prefill_chunk_fn: Callable | None = None
     paged_prefill_chunk_fn: Callable | None = None
-    # Fused decode windows (DECODE_WINDOW; models/window.py).
-    # window_fn(params, state, n_steps, max_chunks, sample=False) ->
-    # (state, tokens [B, max_chunks*n_steps], done_hist [max_chunks, B],
-    # n_chunks) runs up to ``max_chunks`` chunk scans in ONE dispatch
-    # with on-device EOS early exit; paged_window_fn adds the traced
-    # block table after ``state``.  None = family decodes one chunk
-    # per dispatch only (DECODE_WINDOW>1 rejects at build).
-    window_fn: Callable | None = None
-    paged_window_fn: Callable | None = None
     # Every position's next-token logits, jittable: (params, input_ids
     # [B, S], attention_mask [B, S]) -> [B, S, V] — the non-generative
     # forward of a decoder family, what a reference comparison holds to
@@ -748,18 +739,6 @@ def _build_gpt(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             p, cfg, state, table_rows, ids, mask, starts, dtype=policy.compute_jnp
         )
 
-    def window_fn(p, state, n_steps: int, max_chunks: int,
-                  sample: bool = False):
-        return gpt_mod.generate_window(
-            p, cfg, state, n_steps, max_chunks, sample
-        )
-
-    def paged_window_fn(p, state, table, n_steps: int, max_chunks: int,
-                        sample: bool = False):
-        return gpt_mod.generate_window_paged(
-            p, cfg, state, table, n_steps, max_chunks, sample
-        )
-
     from . import spec as spec_mod
 
     init_spec_fn = spec_mod.make_init_spec_fn(p_len)
@@ -794,8 +773,6 @@ def _build_gpt(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         empty_state_fn=empty_state_fn,
         prefill_chunk_fn=prefill_chunk_fn,
         paged_prefill_chunk_fn=paged_prefill_chunk_fn,
-        window_fn=window_fn,
-        paged_window_fn=paged_window_fn,
     )
 
 
@@ -1083,18 +1060,6 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             dtype=policy.compute_jnp, ssm_rows=ssm_rows,
         )
 
-    def window_fn(p, state, n_steps: int, max_chunks: int,
-                  sample: bool = False):
-        return llama_mod.generate_window(
-            p, cfg, state, n_steps, max_chunks, sample
-        )
-
-    def paged_window_fn(p, state, table, n_steps: int, max_chunks: int,
-                        sample: bool = False):
-        return llama_mod.generate_window_paged(
-            p, cfg, state, table, n_steps, max_chunks, sample
-        )
-
     from . import spec as spec_mod
 
     init_spec_fn = spec_mod.make_init_spec_fn(p_len)
@@ -1133,8 +1098,6 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         empty_state_fn=empty_state_fn,
         prefill_chunk_fn=prefill_chunk_fn,
         paged_prefill_chunk_fn=paged_prefill_chunk_fn,
-        window_fn=window_fn,
-        paged_window_fn=paged_window_fn,
         logits_fn=logits_fn,
     )
 

@@ -451,71 +451,6 @@ class ScalingGovernor:
         }
 
 
-class DecodeWindowGovernor:
-    """Pick the fused decode-window depth W for one dispatch
-    (DECODE_WINDOW; engine/streams.py, docs/decode-fusion.md).
-
-    The tradeoff it governs is the SLA-constrained batching one
-    (arXiv 2503.05248), applied to the fusion axis instead of batch
-    size: a deep window divides host round-trips per token by W
-    (throughput), but widens every host-visible boundary — token
-    delivery, admission, preemption, prefill interleave — to W chunks
-    (latency).  Policy, mirroring the queue's class split:
-
-    - interactive streams live OR waiting → W=1 (their TBT and their
-      admission/preemption cadence bind at chunk granularity — the
-      acceptance bar is "interactive TBT p99 no worse than per-chunk");
-    - batch-only traffic and idle backfill → fuse to the cap;
-    - never fuse past the work that remains (a window covering chunks
-      no live stream needs wastes device time and delays completion
-      detection), rounded DOWN to a power of two so the executable set
-      stays {1, 2, 4, ...} instead of one compile per remaining-budget
-      value.
-
-    ``auto=False`` always fuses to the cap (dedicated throughput lanes
-    with no interactive SLA).
-    """
-
-    def __init__(self, cap: int, auto: bool = True):
-        self.cap = max(1, int(cap))
-        self.auto = bool(auto)
-        # Optional flight recorder (wired by the decode loop): depth
-        # drops land in the post-mortem ring like pacer decisions do.
-        self.recorder = None
-        self._last = 1
-
-    def pick(self, max_chunks: int, interactive_live: bool,
-             interactive_waiting: bool) -> int:
-        if self.cap <= 1 or max_chunks <= 1:
-            return 1
-        if self.auto and (interactive_live or interactive_waiting):
-            if self._last > 1 and self.recorder is not None:
-                self.recorder.event(
-                    "window_drop",
-                    live=bool(interactive_live),
-                    waiting=bool(interactive_waiting),
-                )
-            self._last = 1
-            return 1
-        w = min(self.cap, int(max_chunks))
-        w = 1 << (w.bit_length() - 1)  # power-of-two floor
-        self._last = w
-        return w
-
-    def preview(self, max_chunks: int, interactive_live: bool,
-                interactive_waiting: bool) -> int:
-        """``pick`` without the side effects (no ``_last`` transition,
-        no recorder event): the double-buffered host prep stages the
-        NEXT dispatch's window with it, so the real ``pick`` at
-        dispatch time stays the single source of governor telemetry."""
-        if self.cap <= 1 or max_chunks <= 1:
-            return 1
-        if self.auto and (interactive_live or interactive_waiting):
-            return 1
-        w = min(self.cap, int(max_chunks))
-        return 1 << (w.bit_length() - 1)
-
-
 class DeadlineQueue:
     """Bounded two-class EDF wait queue (see module docstring).
 

@@ -365,22 +365,6 @@ class ServiceConfig(BaseModel):
     # the continuous loop's slot width (contiguous mode) / block-table
     # width (paged), so cap it when HBM is tight.
     prefill_max_prompt: int = 0
-    # Fused decode windows (docs/decode-fusion.md): cap on how many
-    # decode chunks fuse into ONE device dispatch (a lax.while_loop
-    # over whole chunk scans with on-device EOS early exit), so the
-    # host submits/fetches once per window instead of per chunk — the
-    # knob that attacks the host-round-trip ceiling the round-11
-    # attribution measured (host_share ≈ 1.0 at the chunk/fetch
-    # sites).  1 = off (the seed's one-chunk dispatches, exactly).
-    # Requires a window-capable family (gpt2/llama); rejected with
-    # SPEC_CONTINUOUS (spec rounds have their own fused shape).
-    decode_window: int = 1
-    # Auto window policy: drop to W=1 whenever interactive streams are
-    # live or waiting (their TBT/admission cadence binds at chunk
-    # granularity), fuse up to DECODE_WINDOW for batch-class and idle
-    # backfill.  0 = always fuse to the cap (throughput lanes with no
-    # interactive SLA).
-    decode_window_auto: bool = True
     # Double-buffered host dispatch prep (engine/streams.py,
     # docs/compilation.md): while chunk N is in flight, the loop
     # stages iteration N+1's host-side prep — the paged block-growth
@@ -827,13 +811,6 @@ class ServiceConfig(BaseModel):
             raise ValueError("KV_PREFETCH_BLOCKS must be in [1, 4096]")
         return v
 
-    @field_validator("decode_window")
-    @classmethod
-    def _check_decode_window(cls, v: int) -> int:
-        if not (1 <= v <= 64):
-            raise ValueError("DECODE_WINDOW must be in [1, 64]")
-        return v
-
     @field_validator("fleet_replicas")
     @classmethod
     def _check_fleet_replicas(cls, v: int) -> int:
@@ -1082,8 +1059,7 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
       JOB_RESULT_TTL_S, TENANTS, TENANTS_FILE, TENANT_DEFAULT_WEIGHT,
       TENANT_WINDOW_S, TENANT_METRICS_TOPK, ADAPTER_DIR,
       ADAPTER_SLOTS, PREFILL_CHUNK,
-      PREFILL_BUDGET, PREFILL_MAX_PROMPT, DECODE_WINDOW,
-      DECODE_WINDOW_AUTO, FAULT_SPEC, FAULT_SEED,
+      PREFILL_BUDGET, PREFILL_MAX_PROMPT, FAULT_SPEC, FAULT_SEED,
       DISPATCH_TIMEOUT_S, DISPATCH_RETRIES, DISPATCH_BACKOFF_S,
       ENGINE_RESTARTS_MAX, ENGINE_RESTART_WINDOW_S, SUPERVISE,
       FLEET_REPLICAS, FLEET_ROUTE, FLEET_BREAKER_N, FLEET_EVICT_S,
@@ -1161,7 +1137,6 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
         "prefill_chunk": "PREFILL_CHUNK",
         "prefill_budget": "PREFILL_BUDGET",
         "prefill_max_prompt": "PREFILL_MAX_PROMPT",
-        "decode_window": "DECODE_WINDOW",
         "fleet_min_replicas": "FLEET_MIN_REPLICAS",
         "fleet_max_replicas": "FLEET_MAX_REPLICAS",
         "fault_seed": "FAULT_SEED",
@@ -1220,9 +1195,6 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
     v = get("HOST_PREP_DOUBLE")
     if v is not None:
         kwargs["host_prep_double"] = v.lower() not in ("0", "false", "no")
-    v = get("DECODE_WINDOW_AUTO")
-    if v is not None:
-        kwargs["decode_window_auto"] = v.lower() not in ("0", "false", "no")
     v = get("PAGED_KV")
     if v is not None:
         kwargs["paged_kv"] = v.lower() not in ("0", "false", "no")

@@ -448,19 +448,6 @@ CHAIN_DEPTH = Gauge(
     "vs chunk compute when 0)",
     ["model"],
 )
-DECODE_WINDOW_CHUNKS = Histogram(
-    "decode_window_chunks",
-    "Decode chunks fused per window dispatch (DECODE_WINDOW; 1 = the "
-    "unfused per-chunk path) — host syncs per token scale inversely "
-    "with this",
-    ["model"], buckets=(1, 2, 4, 8, 16, 32, 64),
-)
-WINDOW_EARLY_EXITS = Counter(
-    "decode_window_early_exits_total",
-    "Fused decode windows that exited on-device before their chunk cap "
-    "because every live row hit EOS",
-    ["model"],
-)
 KV_HOST_POOL_BLOCKS = Gauge(
     "kv_host_pool_blocks",
     "Host-RAM KV tier blocks by state (KV_HOST_BUDGET_MB; used = "

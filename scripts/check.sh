@@ -92,26 +92,9 @@ else
     echo "== chunked-prefill smoke skipped (PREFILL_SMOKE=0) =="
 fi
 
-# Fused-decode smoke: 3-point DECODE_WINDOW matrix, each run under a
-# chunk-site transient FAULT_SPEC through the watchdog retry path,
-# expecting token-identical completion and a drained block pool
-# (chaos tier, so it stays out of tier-1).  FUSE_SMOKE=0 skips.
-if [ "${FUSE_SMOKE:-1}" != "0" ]; then
-    echo "== fused-decode smoke matrix =="
-    for w in 1 2 4; do
-        echo "-- FUSE_SMOKE_WINDOW=$w (chunk:transient@2)"
-        timeout -k 10 240 env JAX_PLATFORMS=cpu FUSE_SMOKE_WINDOW="$w" \
-            FUSE_SMOKE_SPEC="chunk:transient@2" \
-            python -m pytest tests/test_decode_window.py::test_decode_window_smoke \
-            -q -m chaos -p no:cacheprovider -p no:xdist -p no:randomly || exit 1
-    done
-else
-    echo "== fused-decode smoke skipped (FUSE_SMOKE=0) =="
-fi
-
 # Fleet-failover smoke: R=2 replicas, a replica-scoped fatal schedule
-# (r0:chunk:fatal@2) that exhausts replica 0's restart window mid-
-# fused-window (paged, int8, DECODE_WINDOW=4), asserting ZERO streams
+# (r0:chunk:fatal@2) that exhausts replica 0's restart window with
+# chunks in flight (paged, int8), asserting ZERO streams
 # lost — every stream completes token-identically on the survivor and
 # the dead replica's block ledger drains to zero (chaos tier, so it
 # stays out of tier-1).  FLEET_SMOKE=0 skips.
@@ -120,7 +103,7 @@ if [ "${FLEET_SMOKE:-1}" != "0" ]; then
     timeout -k 10 240 env JAX_PLATFORMS=cpu LOCKTRACE=1 \
         FLEET_SMOKE_SPEC="${FLEET_SMOKE_SPEC:-r0:chunk:fatal@2}" \
         python -m pytest \
-        tests/test_fleet.py::test_fleet_failover_chaos_paged_int8_window \
+        tests/test_fleet.py::test_fleet_failover_chaos_paged_int8 \
         -q -m chaos -p no:cacheprovider -p no:xdist -p no:randomly || exit 1
 else
     echo "== fleet-failover smoke skipped (FLEET_SMOKE=0) =="

@@ -185,14 +185,6 @@ def tiny_gpt_bundle(seed: int = 0, **cfg_overrides) -> ModelBundle:
                 p, cfg, st, tr, i, m, starts
             )
         ),
-        window_fn=lambda p, s, n, w, sample=False: gpt_mod.generate_window(
-            p, cfg, s, n, w, sample
-        ),
-        paged_window_fn=(
-            lambda p, s, t, n, w, sample=False: gpt_mod.generate_window_paged(
-                p, cfg, s, t, n, w, sample
-            )
-        ),
         supports_prefix=True,
     )
 
@@ -231,14 +223,6 @@ def tiny_llama_bundle(seed: int = 0, kv_quant: bool = False,
         paged_prefill_chunk_fn=(
             lambda p, st, tr, i, m, starts: llama_mod.paged_prefill_chunk(
                 p, cfg, st, tr, i, m, starts
-            )
-        ),
-        window_fn=lambda p, s, n, w, sample=False: llama_mod.generate_window(
-            p, cfg, s, n, w, sample
-        ),
-        paged_window_fn=(
-            lambda p, s, t, n, w, sample=False: llama_mod.generate_window_paged(
-                p, cfg, s, t, n, w, sample
             )
         ),
         supports_prefix=True,

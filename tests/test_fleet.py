@@ -18,8 +18,8 @@ their integration into the supervisor, decode loop, batcher and API):
    sheds first while degraded.
 6. Bit-identity guard: FLEET_REPLICAS=1 (default) builds no fleet.
 
-The full chaos scenario (R=2, paged, int8, DECODE_WINDOW=4, kill one
-replica mid-fused-window) lives in the chaos tier — scripts/check.sh
+The full chaos scenario (R=2, paged, int8, kill one replica with
+chunks in flight) lives in the chaos tier — scripts/check.sh
 FLEET_SMOKE runs it.
 """
 
@@ -625,24 +625,21 @@ def test_fleet_config_knobs_and_validators():
 
 
 @pytest.mark.chaos
-def test_fleet_failover_chaos_paged_int8_window():
-    """R=2, paged KV, int8 KV quant, DECODE_WINDOW=4 (fused windows):
-    a replica-scoped fatal schedule exhausts replica 0's restart
-    window mid-fused-window.  Every in-flight stream must resume and
-    complete token-identically on the survivor, zero streams lost, and
-    the dead replica's block-pool ledger must drain to zero — the
-    r7 × r9 × PR7 interaction pinned in one scenario."""
+def test_fleet_failover_chaos_paged_int8():
+    """R=2, paged KV, int8 KV quant: a replica-scoped fatal schedule
+    exhausts replica 0's restart window with chunks in flight.  Every
+    in-flight stream must resume and complete token-identically on the
+    survivor, zero streams lost, and the dead replica's block-pool
+    ledger must drain to zero."""
     import os
 
     spec = os.environ.get("FLEET_SMOKE_SPEC", "r0:chunk:fatal@2")
-    # Budget = 8 chunks at DECODE_WINDOW=4 → two fused window
-    # dispatches per stream; the @2 fatal lands on replica 0's SECOND
-    # window, i.e. mid-stream with ~16 tokens already delivered.
+    # Budget = 8 chunks a stream; the @2 fatal lands on replica 0's
+    # SECOND chunk dispatch, i.e. mid-stream.
     cfg = _cfg(
         fleet_replicas=2, fault_spec=spec, engine_restarts_max=0,
         engine_restart_window_s=60.0,
         paged_kv=True, kv_block_size=8,
-        decode_window=4, decode_window_auto=False,
         max_decode_len=32, seq_buckets=(16, 32), max_streams=4,
     )
     bundle = tiny_llama_bundle(kv_quant=True)

@@ -342,9 +342,6 @@ def test_chunk_counters_add_up_and_skip_done_rows(cfg, params):
         np.asarray(counts2.sum(axis=1)), [2 * per_row] * cfg.num_layers)
     np.testing.assert_array_equal(np.asarray(toks2[1]), cfg.pad_id)
     np.testing.assert_array_equal(np.asarray(toks2[0]), np.asarray(toks[0]))
-    # A fused window keeps the (state, tokens) contract and counts nothing.
-    out = llama_mod.generate_window_paged(params, cfg, state, table, 2, 2)
-    np.testing.assert_array_equal(np.asarray(out[1]), np.asarray(toks))
 
 
 def test_dense_paged_chunk_returns_nothing_new():

@@ -189,9 +189,7 @@ def test_pipelined_contiguous_loop_is_token_identical(family):
 
 def _paged_loop(**kw):
     bundle = tiny_llama_bundle()
-    cfg = _cfg(
-        prefill_chunk=16, decode_window=2, kv_host_budget_mb=1.0, **kw
-    )
+    cfg = _cfg(prefill_chunk=16, kv_host_budget_mb=1.0, **kw)
     eng = _engine(bundle, cfg)
     cdl = ContinuousDecodeLoop(eng, cfg)
     cdl._build_empty_state()
@@ -220,13 +218,6 @@ def _step_chunk(eng, cdl):
     return cdl._paged_chunk_fn()(
         cdl._mp(n=cdl.n_slots), cdl._state, jnp.asarray(cdl._table),
         eng.chunk_tokens, False,
-    )
-
-
-def _step_window(eng, cdl):
-    return cdl._window_fn()(
-        cdl._mp(n=cdl.n_slots), cdl._state, jnp.asarray(cdl._table),
-        eng.chunk_tokens, 2, False,
     )
 
 
@@ -264,9 +255,8 @@ def _step_swap_scatter(eng, cdl):
 
 
 STEPS = {
-    "chunk": _step_chunk, "window": _step_window, "insert": _step_insert,
-    "handoff": _step_handoff, "prefill_window": _step_prefill_window,
-    "swap_scatter": _step_swap_scatter,
+    "chunk": _step_chunk, "insert": _step_insert, "handoff": _step_handoff,
+    "prefill_window": _step_prefill_window, "swap_scatter": _step_swap_scatter,
 }
 
 

@@ -12,8 +12,8 @@ none the wiser.
 
 This rule flags calls inside ``engine/`` and ``scheduler/`` that hit a
 device-dispatch surface — registry decode/prefill executables
-(``generate_chunk*``, ``prefill_chunk*``, ``*_window*``), the repo's
-immediately-invoked jit accessors (``self._window_fn()(…)``,
+(``generate_chunk*``, ``prefill_chunk*``), the repo's
+immediately-invoked jit accessors (``self._paged_chunk_fn()(…)``,
 ``self._paged_handoff_fn()(…)``, …) and host↔device syncs
 (``jax.device_get`` / ``device_put`` / ``block_until_ready``) — unless
 the call sits inside a callable passed to ``dispatch_guard`` (or the
@@ -46,12 +46,12 @@ from ..core import Context, Finding, callee_name, dotted_name
 
 # Immediately-invoked jit-accessor idiom: ``self._paged_chunk_fn()(…)``.
 _ACCESSOR_RE = re.compile(
-    r"^_?[a-z0-9_]*(chunk|prefill|window|handoff|scatter|gather|swap)"
+    r"^_?[a-z0-9_]*(chunk|prefill|handoff|scatter|gather|swap)"
     r"[a-z0-9_]*_fn$"
 )
 # Direct dispatch / sync surfaces.
 _DIRECT_RE = re.compile(
-    r"^(generate_chunk\w*|generate_window\w*|prefill_chunk\w*|"
+    r"^(generate_chunk\w*|prefill_chunk\w*|"
     r"paged_prefill\w*|device_get|device_put|block_until_ready|"
     r"_gen_chunk|_spec_chunk|_start|start_fused|_start_prefixed\w*|"
     r"run_batch)$"
