@@ -691,9 +691,10 @@ class InferenceEngine:
         paged block ledger read model dims, so they can never drift."""
         cfg = self.bundle.cfg
         layers = int(getattr(cfg, "num_layers", 0) or 12)
-        if getattr(cfg, "layer_pattern", ""):
-            # Mixer-or-FFN layers: only the attention layers cache keys.
-            layers = cfg.layer_pattern.count("*")
+        if hasattr(cfg, "cache_layers"):
+            # Only the attention layers cache keys (a recurrent or an
+            # FFN-only layer has no pool).
+            layers = len(cfg.cache_layers)
         heads = int(
             getattr(cfg, "num_kv_heads", 0)
             or getattr(cfg, "num_heads", 0) or 12

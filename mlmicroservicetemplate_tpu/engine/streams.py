@@ -522,7 +522,7 @@ class ContinuousDecodeLoop:
         # Recurrent state rows (``_ssm_take``): one a slot — admission keeps
         # the streams that can hold one (live or in prefill) to ``n_slots``.
         self._ssm_free = None
-        if self.paged and getattr(bcfg, "mamba_layers", ()):
+        if self.paged and getattr(bcfg, "recurrent_layers", ()):
             self._ssm_free = list(range(self.n_slots))[::-1]
         # (window layers, window) of a per-layer pattern, or None.
         types = getattr(bcfg, "layer_types", ())
@@ -530,8 +530,12 @@ class ContinuousDecodeLoop:
             (types.count("window"), int(bcfg.window))
             if "window" in types else None
         )
-        self._attn_layers = int(getattr(bcfg, "num_layers", 0))
-        # Layers whose cache is a latent row a token (every layer, or none).
+        # Layers that cache keys: the attention layers (a recurrent or an
+        # FFN-only layer has no pool), every layer of a model without kinds.
+        self._attn_layers = (
+            len(bcfg.cache_layers) if hasattr(bcfg, "cache_layers")
+            else int(getattr(bcfg, "num_layers", 0)))
+        # Those whose cache is a latent row a token (all of them, or none).
         self._latent_layers = (
             self._attn_layers if getattr(bcfg, "latent_lanes", 0) else 0)
         # (first, held) of the experts this tree holds: a chip's share.

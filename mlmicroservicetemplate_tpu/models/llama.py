@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 from typing import Any, NamedTuple
 
 import jax
@@ -213,6 +214,30 @@ class LlamaConfig:
     expert_act: str = "silu"
     moe_latent: int = 0
     d_ff_shared: int = 0
+    # A Gated-DeltaNet mixer (ops/ssm.py; GigaChat3.5-style) on the layers
+    # ``layer_types`` names "linear" (HF's "linear_attention"): such a layer
+    # is that mixer THEN its FFN (dense or experts, as ``num_dense_layers``
+    # says), the other layers ``attention`` then theirs.  ``gdn_key_heads``
+    # heads of ``gdn_key_dim`` for q and k, ``gdn_value_heads`` (a multiple)
+    # of ``gdn_value_dim`` for v and the gate z, a causal depthwise
+    # convolution of ``gdn_conv`` taps without bias over [q | k | v], the
+    # output gate ``gdn_gate_scale * sigmoid(z)`` (the published
+    # ``linear_sigmoid_gate_scale``).  Its state — a [Dv, Dk] float32 matrix
+    # a value head and the taps — lives in the same state rows as Mamba's
+    # (``SsmState``).
+    gdn_key_heads: int = 0
+    gdn_value_heads: int = 0
+    gdn_key_dim: int = 0
+    gdn_value_dim: int = 0
+    gdn_conv: int = 4
+    gdn_gate_scale: float = 2.0
+    # Every SwiGLU (dense, routed, shared) clamped where > 0:
+    # ``silu(min(gate, limit)) * clip(up, -limit, limit)``.
+    swiglu_limit: float = 0.0
+    # The learned scale of the block's norms (pre, post, final): 0 = the
+    # leaf itself; w > 0 = ``w * sigmoid(leaf)`` (a zero-centred gated
+    # norm: a leaf of 0 scales by w / 2), the leaves then drawn about 0.
+    norm_gate_weight: float = 0.0
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -271,16 +296,30 @@ class LlamaConfig:
                     f"{self.num_experts}")
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
-        hf = {"sliding_attention": "window", "full_attention": "full"}
+        hf = {"sliding_attention": "window", "full_attention": "full",
+              "linear_attention": "linear"}
         # A published pattern cut in depth: its first num_layers entries.
         types = tuple(hf.get(t, t) for t in self.layer_types)[: self.num_layers]
         object.__setattr__(self, "layer_types", types)
         if types and (len(types) != self.num_layers
-                      or set(types) - {"window", "full"}):
+                      or set(types) - {"window", "full", "linear"}):
             raise ValueError(
                 f"layer_types must name each of the {self.num_layers} layers "
-                f"'window' or 'full', got {types}"
+                f"'window', 'full' or 'linear', got {types}"
             )
+        gdn_dims = (self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
+                    self.gdn_value_dim)
+        if "linear" in types:
+            if (not all(d > 0 for d in gdn_dims) or self.gdn_conv < 2
+                    or self.gdn_value_heads % self.gdn_key_heads
+                    or set(types) == {"linear"}):
+                raise ValueError(
+                    "a 'linear' layer needs gdn_key_heads, gdn_value_heads (a "
+                    "multiple), gdn_key_dim and gdn_value_dim, and the pattern "
+                    f"one attention layer (the paged pool's), got {gdn_dims}")
+        elif any(gdn_dims):
+            raise ValueError(
+                f"Gated-DeltaNet sizes {gdn_dims} need a 'linear' layer")
         if ("window" in types) != bool(self.window):
             raise ValueError(
                 f"window={self.window} and layer_types={types}: a window "
@@ -388,17 +427,45 @@ class LlamaConfig:
         return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state
 
     @property
-    def mamba_layers(self) -> tuple:
-        return tuple(li for li, c in enumerate(self.layer_pattern) if c == "M")
+    def gdn_conv_dim(self) -> int:
+        """What a Gated-DeltaNet layer's convolution runs over: [q | k | v]."""
+        return (2 * self.gdn_key_heads * self.gdn_key_dim
+                + self.gdn_value_heads * self.gdn_value_dim)
+
+    @property
+    def cache_layers(self) -> tuple:
+        """The layers with a cache entry (a pool): those whose mixer is an
+        attention, in order."""
+        return tuple(li for li in range(self.num_layers)
+                     if self.layer_kind(li).attention)
+
+    @property
+    def recurrent_layers(self) -> tuple:
+        """The layers whose mixer keeps a state row (``SsmState``), in order."""
+        return tuple(li for li in range(self.num_layers)
+                     if self.layer_kind(li).recurrent)
+
+    def recurrent_shapes(self, li: int) -> tuple:
+        """``(taps, state)``: what one row of recurrent layer ``li`` holds —
+        the convolution's taps [K-1, channels] (the cache's two-byte dtype)
+        and the state (float32): Mamba-2's [H, P, N], Gated DeltaNet's
+        [Hv, Dv, Dk]."""
+        if self.layer_kind(li).mixer == "gdn":
+            return ((self.gdn_conv - 1, self.gdn_conv_dim),
+                    (self.gdn_value_heads, self.gdn_value_dim, self.gdn_key_dim))
+        return ((self.ssm_conv - 1, self.ssm_conv_dim),
+                (self.ssm_heads, self.ssm_head_dim, self.ssm_state))
 
     @property
     def ssm_row_bytes(self) -> int:
-        """Bytes of recurrent state one stream holds (0: no Mamba layer):
-        a layer's [H, P, N] float32 state and its K-1 convolution taps in
-        the cache's two-byte dtype."""
-        return len(self.mamba_layers) * (
-            self.ssm_inner * self.ssm_state * 4
-            + (self.ssm_conv - 1) * self.ssm_conv_dim * 2)
+        """Bytes of recurrent state one stream holds (0: no recurrent
+        layer): a layer's float32 state and its K-1 convolution taps in the
+        cache's two-byte dtype."""
+        import math
+
+        return sum(
+            math.prod(state) * 4 + math.prod(taps) * 2
+            for taps, state in map(self.recurrent_shapes, self.recurrent_layers))
 
     def layer_kind(self, li: int) -> "LayerKind":
         if self.layer_pattern:
@@ -406,18 +473,18 @@ class LlamaConfig:
             return LayerKind(
                 window=0, rope=c == "*" and not self.nope_on_full,
                 experts=c == "E", d_ff=self.d_ff if c == "E" else 0,
-                attention=self.attention if c == "*" else "",
-                mamba=c == "M", ffn=c == "E",
+                mixer={"*": self.attention, "M": "mamba2"}.get(c),
+                ffn=c == "E",
             )
-        window = self.window if (
-            self.layer_types and self.layer_types[li] == "window") else 0
+        kind = self.layer_types[li] if self.layer_types else "full"
+        window = self.window if kind == "window" else 0
         dense = li < self.num_dense_layers
         return LayerKind(
             window=window,
-            rope=bool(window) or not self.nope_on_full,
+            rope=kind != "linear" and (bool(window) or not self.nope_on_full),
             experts=bool(self.num_experts) and not dense,
             d_ff=self.d_ff_dense if dense else self.d_ff,
-            attention=self.attention,
+            mixer="gdn" if kind == "linear" else self.attention,
         )
 
     @property
@@ -431,22 +498,26 @@ class LayerKind:
     """What one layer is: ``window`` keys a query sees (0 = all before
     it), whether q and k are rotated, its FFN (``experts``: the
     sparse expert block of experts ``d_ff`` wide; else a dense SwiGLU of
-    width ``d_ff``) and its ``attention`` kind ("gqa" | "mla").  Under a
-    ``layer_pattern`` a layer is ONE of these alone: ``mamba`` (a Mamba-2
-    mixer; ``attention`` is then "") or an attention, or — ``ffn`` — the
-    FFN (``attention`` "" as well)."""
+    width ``d_ff``) and its ``mixer``: an attention ("gqa" | "mla"), a
+    recurrence ("mamba2" | "gdn") or None.  Under a ``layer_pattern`` a
+    layer is ONE sub-block alone: a mixer, or — ``ffn`` — the FFN."""
 
     window: int
     rope: bool
     experts: bool
     d_ff: int
-    attention: str = "gqa"
-    mamba: bool = False
+    mixer: str | None = "gqa"
     ffn: bool = True
 
     @property
-    def mixer(self) -> str | None:
-        return "mamba2" if self.mamba else (self.attention or None)
+    def attention(self) -> str:
+        """The attention kind of a layer that has a cache entry, else ""."""
+        return self.mixer if self.mixer in ("gqa", "mla") else ""
+
+    @property
+    def recurrent(self) -> bool:
+        """Whether the mixer keeps a state row: the one place that asks."""
+        return self.mixer in ("mamba2", "gdn")
 
 
 class SsmState(NamedTuple):
@@ -466,14 +537,15 @@ class SsmState(NamedTuple):
 
 def zero_ssm(cfg: "LlamaConfig", rows: int, dtype):
     """``rows`` zeroed state rows, ``row`` the identity (``()``, a decode
-    state's empty default, for a config without Mamba layers)."""
-    m = len(cfg.mamba_layers)
-    if not m:
+    state's empty default, for a config without recurrent layers)."""
+    shapes = [cfg.recurrent_shapes(li) for li in cfg.recurrent_layers]
+    if not shapes:
         return ()
-    conv = jnp.zeros((rows, cfg.ssm_conv - 1, cfg.ssm_conv_dim), dtype)
-    state = jnp.zeros(
-        (rows, cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state), jnp.float32)
-    return SsmState([conv] * m, [state] * m, jnp.arange(rows, dtype=jnp.int32))
+    zeros = functools.cache(lambda tail, dt: jnp.zeros((rows,) + tail, dt))
+    return SsmState(  # layers of one shape share one zeros, as ever
+        [zeros(taps, dtype) for taps, _ in shapes],
+        [zeros(state, jnp.float32) for _, state in shapes],
+        jnp.arange(rows, dtype=jnp.int32))
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -510,6 +582,15 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
     def norm_scale(k, n):
         return cast({"scale": 1.0 + normal_init(k, (n,), std=0.25)})
 
+    def block_norm(k, n, learned=False):
+        """A pre, post or final norm's leaf.  Under ``norm_gate_weight`` it
+        is drawn about 0 (the scale is then about half the weight) so that
+        a dropped or misread scale shows; else ones, or — ``learned``, the
+        post-norms — about 1."""
+        if cfg.norm_gate_weight:
+            return cast({"scale": normal_init(k, (n,), std=0.25)})
+        return norm_scale(k, n) if learned else cast(rmsnorm_init(n))
+
     def experts(k, shape):
         # The key ``dense_init`` would draw a [d_in, d_out] kernel from.
         return {"kernel": cast(normal_init(jax.random.split(k)[0], shape, std=0.02))}
@@ -520,7 +601,7 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
     params: Params = {
         "embed": {"embedding": cast(normal_init(keys[0], (cfg.vocab_size, d), std=0.02))},
         "layers": [],
-        "final_ln": cast(rmsnorm_init(d)),
+        "final_ln": block_norm(jax.random.fold_in(key, 3), d),
         "lm_head": {"kernel": cast(normal_init(keys[1], (d, cfg.vocab_size), std=0.02))},
     }
     for i in range(cfg.num_layers):
@@ -531,7 +612,7 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         def extra(n):  # leaves newer than the 7-way split: their own keys
             return jax.random.fold_in(lk, n)
 
-        if kind.mamba:
+        if kind.mixer == "mamba2":
             params["layers"].append(_init_mamba(cfg, extra, lin, norm_scale, cast))
             continue
         if not kind.attention:
@@ -568,7 +649,7 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
             attn["q_norm"] = norm_scale(extra(8), cfg.head_dim if per_head else qd)
             attn["k_norm"] = norm_scale(extra(9), cfg.head_dim if per_head else kv_dim)
         if cfg.attn_gate and attn is not None:
-            attn["gate"] = lin(extra(10), d, qd)
+            attn["gate"] = lin(extra(10), d, cfg.o_dim)
         gated = cfg.expert_act == "silu"
         if not kind.ffn:
             mlp = None
@@ -604,14 +685,57 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
             }
         layer = {}
         if attn is not None:
-            layer.update(attn_ln=cast(rmsnorm_init(d)), attn=attn)
+            layer.update(attn_ln=block_norm(extra(29), d), attn=attn)
+        if kind.mixer == "gdn":
+            layer.update(gdn_ln=block_norm(extra(29), d),
+                         gdn=_init_gdn(cfg, extra, lin, cast))
         if mlp is not None:
-            layer.update(mlp_ln=cast(rmsnorm_init(d)), mlp=mlp)
+            layer.update(mlp_ln=block_norm(extra(30), d), mlp=mlp)
         if cfg.sandwich_norm:
-            layer["attn_post_ln"] = norm_scale(extra(11), d)
-            layer["mlp_post_ln"] = norm_scale(extra(12), d)
+            post = "gdn_post_ln" if kind.mixer == "gdn" else "attn_post_ln"
+            layer[post] = block_norm(extra(11), d, learned=True)
+            layer["mlp_post_ln"] = block_norm(extra(12), d, learned=True)
+        if cfg.swiglu_limit and mlp is not None:
+            # Every SwiGLU's gate 8x wider and its down 8x narrower (exact
+            # in any float dtype): the clamp binds on a share of every
+            # FFN's hidden units, so a dropped clamp shows, and no expert's
+            # output is larger than its neighbours'.
+            for ffn in (mlp, mlp.get("shared")):
+                if ffn is not None and "gate" in ffn:
+                    ffn["gate"]["kernel"] = ffn["gate"]["kernel"] * 8
+                    ffn["down"]["kernel"] = ffn["down"]["kernel"] / 8
         params["layers"].append(layer)
     return params
+
+
+def _init_gdn(cfg: LlamaConfig, extra, lin, cast) -> dict:
+    """One Gated-DeltaNet mixer's leaves: ``qkvz`` [q | k | v | z] and
+    ``ba`` [b | a] wide, the convolution's taps (no bias) normal 0.4,
+    ``A_log`` / ``dt_bias`` drawn as Mamba's (``_init_mamba``: a head's
+    decay a token between about e^-1.6 and e^-0.001), the output norm's
+    zero-centred scale (``1 + w``) normal 0.25."""
+    d, hv = cfg.d_model, cfg.gdn_value_heads
+    inner = hv * cfg.gdn_value_dim
+    return {
+        "qkvz": lin(extra(31), d, cfg.gdn_conv_dim + inner),
+        "ba": lin(extra(32), d, 2 * hv),
+        "conv": {"kernel": cast(normal_init(
+            extra(34), (cfg.gdn_conv, cfg.gdn_conv_dim), std=0.4))},
+        **_decay_leaves(extra(33), extra(35), hv),
+        "norm": cast({"scale": normal_init(extra(36), (cfg.gdn_value_dim,), std=0.25)}),
+        "out": lin(extra(37), inner, d),
+    }
+
+
+def _decay_leaves(k_step, k_a, heads: int) -> dict:
+    """A recurrent layer's per-head decay scalars, float32 whatever the
+    tree's dtype: ``dt_bias`` the inverse softplus of a step log-uniform in
+    [0.001, 0.1], ``A_log`` with ``exp(A_log)`` uniform in [1, 16]."""
+    step = jnp.exp(jax.random.uniform(
+        k_step, (heads,), minval=jnp.log(0.001), maxval=jnp.log(0.1)))
+    return {"dt_bias": step + jnp.log(-jnp.expm1(-step)),
+            "A_log": jnp.log(jax.random.uniform(
+                k_a, (heads,), minval=1.0, maxval=16.0))}
 
 
 def _init_mamba(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
@@ -623,8 +747,6 @@ def _init_mamba(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
     ``D`` ones; the convolution's taps normal 0.4 and its bias normal 0.5
     beside inputs of about unit size; the gated norm's scale about 1."""
     d, inner, h = cfg.d_model, cfg.ssm_inner, cfg.ssm_heads
-    step = jnp.exp(jax.random.uniform(
-        extra(25), (h,), minval=jnp.log(0.001), maxval=jnp.log(0.1)))
     return {
         "ssm_ln": cast(rmsnorm_init(d)),
         "ssm": {
@@ -634,10 +756,7 @@ def _init_mamba(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
                     extra(23), (cfg.ssm_conv, cfg.ssm_conv_dim), std=0.4)),
                 "bias": cast(normal_init(extra(24), (cfg.ssm_conv_dim,), std=0.5)),
             },
-            # float32 whatever the tree's dtype: a head's scalars.
-            "dt_bias": step + jnp.log(-jnp.expm1(-step)),
-            "A_log": jnp.log(jax.random.uniform(
-                extra(26), (h,), minval=1.0, maxval=16.0)),
+            **_decay_leaves(extra(25), extra(26), h),
             "D": jnp.ones((h,), jnp.float32),
             "norm": norm_scale(extra(27), inner),
             "out": lin(extra(28), inner, d),
@@ -725,6 +844,28 @@ def _embed(params: Params, cfg: "LlamaConfig", ids, dtype):
     return x
 
 
+def _norm(cfg: "LlamaConfig", p, x):
+    """A block norm (pre, post or final): RMSNorm with its learned scale —
+    the leaf itself, or under ``cfg.norm_gate_weight`` that weight times
+    the leaf's sigmoid."""
+    if cfg.norm_gate_weight:
+        p = {"scale": cfg.norm_gate_weight * jax.nn.sigmoid(
+            p["scale"].astype(jnp.float32))}
+    return rmsnorm(p, x, eps=cfg.rms_eps)
+
+
+def _swiglu_gate(cfg: "LlamaConfig", gate):
+    """``silu(gate)`` of a SwiGLU, the gate clamped from above under
+    ``cfg.swiglu_limit`` (``_swiglu_up`` clamps the other factor)."""
+    return jax.nn.silu(
+        jnp.minimum(gate, cfg.swiglu_limit) if cfg.swiglu_limit else gate)
+
+
+def _swiglu_up(cfg: "LlamaConfig", up):
+    lim = cfg.swiglu_limit
+    return jnp.clip(up, -lim, lim) if lim else up
+
+
 def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
     """Pre-norm FFN block of layer ``li`` with its residual, under the
     ``mlp`` scope (the device trace's name for it in every step kind):
@@ -735,7 +876,7 @@ def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
     they get expert work; ``tally`` (a list) receives an expert layer's
     [E] count of their assignments."""
     with jax.named_scope("mlp"):
-        h = rmsnorm(layer["mlp_ln"], x, eps=cfg.rms_eps)
+        h = _norm(cfg, layer["mlp_ln"], x)
         m = layer["mlp"]
         if cfg.layer_kind(li).experts:
             from ..ops.moe import expert_ffn
@@ -747,17 +888,18 @@ def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
                 interpret=cfg.pallas_interpret, score=cfg.router_score,
                 route_scale=cfg.route_scale, n_group=cfg.n_group,
                 topk_group=cfg.topk_group, expert_first=cfg.expert_first,
-                act=cfg.expert_act,
+                act=cfg.expert_act, limit=cfg.swiglu_limit,
             )
             if tally is not None:
                 tally.append(counts)
             out = out.reshape(b, s, d)
         else:
             out = dense(
-                m["down"], jax.nn.silu(dense(m["gate"], h)) * dense(m["up"], h)
+                m["down"], _swiglu_gate(cfg, dense(m["gate"], h))
+                * _swiglu_up(cfg, dense(m["up"], h))
             )
         if cfg.sandwich_norm:
-            out = rmsnorm(layer["mlp_post_ln"], out, eps=cfg.rms_eps)
+            out = _norm(cfg, layer["mlp_post_ln"], out)
         return x + out
 
 
@@ -790,6 +932,13 @@ def _aproj(a, ad, name: str, li: int, x):
     return lora.apply(ad, name, li, x, dense(a[name], x))
 
 
+def _attn_gate(cfg: "LlamaConfig", a, ad, li: int, h):
+    """The attention output's gate ``sigmoid(h W_g)`` [.., o_dim], over the
+    merged heads elementwise (None without ``cfg.attn_gate``): GQA's and
+    the latent attention's alike; ``_attn_out`` applies it."""
+    return jax.nn.sigmoid(_aproj(a, ad, "gate", li, h)) if cfg.attn_gate else None
+
+
 def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
     """q [.., H, Dh], k and v [.., KVH, Dh] of layer ``li`` from the
     residual stream x [B, S, D], and the attention output's gate
@@ -800,9 +949,9 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
     rotated unless the layer's kind says not (``cfg.nope_on_full``)."""
     a = layer["attn"]
     if cfg.mla:
-        return _mla_qkv(cfg, layer, x, cos, sin)
+        return _mla_qkv(cfg, layer, x, cos, sin, ad, li)
     with jax.named_scope("qkv_rope"):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+        h = _norm(cfg, layer["attn_ln"], x)
         q = _aproj(a, ad, "q", li, h)
         k = _aproj(a, ad, "k", li, h)
         if cfg.qk_norm is True:
@@ -815,7 +964,7 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
         if cfg.layer_kind(li).rope:
             q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
         v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
-        g = jax.nn.sigmoid(_aproj(a, ad, "gate", li, h)) if cfg.attn_gate else None
+        g = _attn_gate(cfg, a, ad, li, h)
     return q, k, v, g
 
 
@@ -840,15 +989,15 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
 MLA_HEAD_BLOCK = 16
 
 
-def _mla_qkv(cfg: "LlamaConfig", layer, x, cos, sin):
+def _mla_qkv(cfg: "LlamaConfig", layer, x, cos, sin, ad=None, li: int = 0):
     """``((qn [.., H, nope], qr [.., H, rope]), latent [.., lanes], None,
-    None)`` of an MLA layer from the residual stream x [B, S, D] — its
+    gate)`` of an MLA layer from the residual stream x [B, S, D] — its
     ``qkv_rope`` scope: ``mla_q`` (down, norm, up, rotary) and ``mla_kv``
     (down, norm, rotary; the row the cache holds, zero past
-    ``latent_dim``)."""
+    ``latent_dim``); the output's gate as ``_attn_gate`` has it."""
     a, r = layer["attn"], cfg.kv_lora_rank
     with jax.named_scope("qkv_rope"):
-        h = rmsnorm(layer["attn_ln"], x, eps=cfg.rms_eps)
+        h = _norm(cfg, layer["attn_ln"], x)
         with jax.named_scope("mla_q"):
             cq = rmsnorm(a["q_a_norm"], dense(a["q_a"], h), eps=cfg.rms_eps)
             q = _split(dense(a["q_b"], cq), cfg.num_heads)
@@ -859,7 +1008,8 @@ def _mla_qkv(cfg: "LlamaConfig", layer, x, cos, sin):
             c = rmsnorm(a["kv_a_norm"], ckr[..., :r], eps=cfg.rms_eps)
             kr = _apply_rope(ckr[..., None, r:], cos, sin)[..., 0, :]
             latent = _latent_row(cfg, c, kr)
-    return (qn, qr), latent, None, None
+        g = _attn_gate(cfg, a, ad, li, h)
+    return (qn, qr), latent, None, g
 
 
 def _latent_row(cfg: "LlamaConfig", c, kr):
@@ -994,7 +1144,7 @@ def _attn_out(cfg: "LlamaConfig", layer, ad, li: int, x, ctx, g):
             y = y * g
         y = _aproj(layer["attn"], ad, "o", li, y)
         if cfg.sandwich_norm:
-            y = rmsnorm(layer["attn_post_ln"], y, eps=cfg.rms_eps)
+            y = _norm(cfg, layer["attn_post_ln"], y)
         return x + y
 
 
@@ -1082,18 +1232,83 @@ def _mamba_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
             return x + dense(m["out"], y), conv, s
 
 
-def _layers(params: Params, cfg: "LlamaConfig", x, attend, mamba, valid,
+def _gdn_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
+    """Pre-norm Gated-DeltaNet mixer with its residual, under the ``gdn``
+    scope: x [B, L, D] from each row's taps ``conv`` [B, K-1, channels] and
+    state ``s`` [B, Hv, Dv, Dk] -> (x + out, conv', s'); ``mask`` / ``live``
+    as ``_mamba_block`` has them.  q and k are L2-normalised a head (q
+    scaled by ``Dk^-1/2``), value head h reads key head ``h // (Hv / Hk)``;
+    ``y = RMSNorm_Dv(o; 1 + w) * gate_scale * sigmoid(z)``: the norm first,
+    then the gate."""
+    from ..ops import ssm
+
+    m = layer["gdn"]
+    b, length = x.shape[:2]
+    hk, hv, dk, dv = (cfg.gdn_key_heads, cfg.gdn_value_heads, cfg.gdn_key_dim,
+                      cfg.gdn_value_dim)
+    cd, f32 = cfg.gdn_conv_dim, jnp.float32
+    with jax.named_scope("gdn"):
+        u = _norm(cfg, layer["gdn_ln"], x)
+        with jax.named_scope("gdn_in_proj"):
+            qkvz = dense(m["qkvz"], u)
+            qkv, z = qkvz[..., :cd], qkvz[..., cd:]
+            ba = dense(m["ba"], u).astype(f32)
+        w = m["conv"]["kernel"]
+        with jax.named_scope("gdn_conv"):
+            if live is None:
+                qkv, conv = ssm.conv_scan(qkv, conv, w, None, mask)
+            else:
+                y1, conv = ssm.conv_step(qkv[:, 0], conv, w, None, live)
+                qkv = y1[:, None]
+        scan = "gdn_scan" if live is None else "gdn_step"
+        with jax.named_scope(scan):
+            def unit(t):  # a head's q or k at unit length, a value head each
+                t = t.astype(f32).reshape(b, length, hk, dk)
+                t = t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+                return jnp.repeat(t, hv // hk, axis=2)
+
+            q = unit(qkv[..., :hk * dk]) * dk ** -0.5
+            k = unit(qkv[..., hk * dk:2 * hk * dk])
+            v = qkv[..., 2 * hk * dk:].reshape(b, length, hv, dv)
+            beta = jax.nn.sigmoid(ba[..., :hv])
+            g = -jnp.exp(m["A_log"].astype(f32)) * jax.nn.softplus(
+                ba[..., hv:] + m["dt_bias"].astype(f32))
+            if live is None:
+                o, s = ssm.gdn_scan(q, k, v, g, beta, s, mask)
+            else:
+                o, s = ssm.gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
+                                    beta[:, 0], s, live)
+                o = o[:, None]
+        with jax.named_scope("gdn_gate_norm"):
+            o = rmsnorm({"scale": 1.0 + m["norm"]["scale"].astype(f32)}, o,
+                        eps=cfg.rms_eps)
+            y = o.reshape(b, length, hv * dv) * (
+                cfg.gdn_gate_scale * jax.nn.sigmoid(z.astype(f32)))
+        with jax.named_scope("gdn_out_proj"):
+            out = dense(m["out"], y.astype(x.dtype))
+            if cfg.sandwich_norm:
+                out = _norm(cfg, layer["gdn_post_ln"], out)
+            return x + out, conv, s
+
+
+def _recurrent_block(cfg: "LlamaConfig", li: int):
+    """The block of recurrent layer ``li``: ``block(cfg, layer, x, conv, s,
+    mask=, live=) -> (x, conv', s')``."""
+    return _gdn_block if cfg.layer_kind(li).mixer == "gdn" else _mamba_block
+
+
+def _layers(params: Params, cfg: "LlamaConfig", x, attend, recur, valid,
             tally=None):
     """x through every layer, each running the sub-blocks its kind has
-    (``cfg.layer_kind``): its mixer — ``mamba(layer, x)`` or ``attend(li,
-    layer, x)``, the step kind's own closures over its cache and state,
-    each with its residual — then its FFN (``_mlp_block``; ``valid()``
-    gives its rows' mask, asked for where an FFN runs).  The one walk every
-    step kind makes."""
+    (``cfg.layer_kind``): its mixer — ``recur(li, layer, x)`` (a Mamba-2 or
+    Gated-DeltaNet layer) or ``attend(li, layer, x)``, the step kind's own
+    closures over its cache and state, each with its residual — then its
+    FFN (``_mlp_block``; ``valid()`` gives its rows' mask, asked for where
+    an FFN runs).  The one walk every step kind makes."""
     for li, layer in enumerate(params["layers"]):
         kind = cfg.layer_kind(li)
-        if kind.mamba:
-            x = mamba(layer, x)
+        if kind.recurrent:
+            x = recur(li, layer, x)
         elif kind.attention:
             x = attend(li, layer, x)
         if kind.ffn:
@@ -1102,15 +1317,17 @@ def _layers(params: Params, cfg: "LlamaConfig", x, attend, mamba, valid,
 
 
 def _ssm_walker(cfg: "LlamaConfig", ssm, run):
-    """``(mamba, done)``: ``mamba(layer, x)`` runs the next Mamba layer
-    through ``run(layer, x, conv, s) -> (x, conv', s')`` on that layer's
-    entries of ``ssm``; ``done()`` is ``ssm`` with what the layers left
-    (``()`` for a config without any)."""
+    """``(recur, done)``: ``recur(li, layer, x)`` runs the next recurrent
+    layer through ``run(block, layer, x, conv, s) -> (x, conv', s')`` —
+    ``block`` that layer's ``_recurrent_block`` — on the layer's entries of
+    ``ssm``; ``done()`` is ``ssm`` with what the layers left (``()`` for a
+    config without any)."""
     convs, states = [], []
 
-    def mamba(layer, x):
+    def recur(li, layer, x):
         i = len(convs)
-        x, conv, s = run(layer, x, ssm.conv[i], ssm.state[i])
+        x, conv, s = run(_recurrent_block(cfg, li), layer, x, ssm.conv[i],
+                         ssm.state[i])
         convs.append(conv)
         states.append(s)
         return x
@@ -1118,7 +1335,7 @@ def _ssm_walker(cfg: "LlamaConfig", ssm, run):
     def done():
         return ssm._replace(conv=convs, state=states) if convs else ssm
 
-    return mamba, done
+    return recur, done
 
 
 # ---------------------------------------------------------------------------
@@ -1230,17 +1447,17 @@ def forward_hidden(
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
     ssm_mask = attention_mask
-    if ssm_out is not None and cfg.mamba_layers:
+    if ssm_out is not None and cfg.recurrent_layers:
         ssm_mask = attention_mask * (
             jnp.arange(s)[None, :] < attention_mask.sum(axis=-1, keepdims=True) - 1)
-    mamba, ssm_done = _ssm_walker(
+    recur, ssm_done = _ssm_walker(
         cfg, zero_ssm(cfg, b, dtype),
-        lambda layer, x, conv, st: _mamba_block(
+        lambda block, layer, x, conv, st: block(
             cfg, layer, x, conv, st, mask=ssm_mask))
-    x = _layers(params, cfg, x, attend, mamba, lambda: attention_mask != 0)
+    x = _layers(params, cfg, x, attend, recur, lambda: attention_mask != 0)
     if ssm_out is not None:
         ssm_out.append(ssm_done())
-    x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
+    x = _norm(cfg, params["final_ln"], x)
     return (x, kv) if collect_kv else x
 
 
@@ -1443,11 +1660,11 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
                 ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
-    mamba, ssm_done = _ssm_walker(
-        cfg, state.ssm, lambda layer, x, conv, st: _mamba_block(
+    recur, ssm_done = _ssm_walker(
+        cfg, state.ssm, lambda block, layer, x, conv, st: block(
             cfg, layer, x, conv, st, live=~state.done))
-    x = _layers(params, cfg, x, attend, mamba, lambda: ~state.done[:, None])
-    x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
+    x = _layers(params, cfg, x, attend, recur, lambda: ~state.done[:, None])
+    x = _norm(cfg, params["final_ln"], x)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
         GPTState(
@@ -1501,7 +1718,7 @@ def multi_step(
         ctx = _cache_attention(cfg, q, ck, cv, mask)
         x = _attn_out(cfg, layer, ad, li, x, ctx, g)
         x = _mlp_block(cfg, layer, li, x, ~state.done[:, None])
-    x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
+    x = _norm(cfg, params["final_ln"], x)
     logits = lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
     return new_k, new_v, logits  # [B, D, V]
 
@@ -1694,12 +1911,12 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
                 )
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
-    mamba, ssm_done = _ssm_walker(
-        cfg, state.ssm, _paged_ssm_step(cfg, state, table) if cfg.mamba_layers
-        else None)
-    x = _layers(params, cfg, x, attend, mamba, lambda: ~state.done[:, None],
+    recur, ssm_done = _ssm_walker(
+        cfg, state.ssm, _paged_ssm_step(cfg, state, table)
+        if cfg.recurrent_layers else None)
+    x = _layers(params, cfg, x, attend, recur, lambda: ~state.done[:, None],
                 moe_tally)
-    x = rmsnorm(params["final_ln"], x, eps=cfg.rms_eps)
+    x = _norm(cfg, params["final_ln"], x)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
         PagedState(
@@ -1712,7 +1929,7 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
 
 
 def _paged_ssm_step(cfg: LlamaConfig, state, table):
-    """A paged decode step's Mamba layer, ``run`` of ``_ssm_walker``.  The
+    """A paged decode step's recurrent layer, ``run`` of ``_ssm_walker``.  The
     state rows stay where they lie and are updated in place, ALL ``R`` of
     them under a mask: the step's small per-slot rows (the residual
     stream) are gathered to their state rows and the layer's output back
@@ -1733,8 +1950,8 @@ def _paged_ssm_step(cfg: LlamaConfig, state, table):
     from_slot = jnp.minimum(slot_of, b - 1)
     to_slot = jnp.minimum(state.ssm.row, n_rows - 1)
 
-    def run(layer, x, conv, st):
-        y, conv, st = _mamba_block(
+    def run(block, layer, x, conv, st):
+        y, conv, st = block(
             cfg, layer, jnp.take(x, from_slot, axis=0), conv, st, live=live_r)
         return jnp.where(
             live[:, None, None], jnp.take(y, to_slot, axis=0), x), conv, st
@@ -1782,7 +1999,7 @@ def empty_decode_state(
 
     total = s_total + max_len
     shape = (batch, total, cfg.num_kv_heads, cfg.head_dim)
-    cached = [li for li in range(cfg.num_layers) if cfg.layer_kind(li).attention]
+    cached = cfg.cache_layers
     if cfg.kv_quant:
         cache_k = [
             (jnp.zeros(shape, jnp.int8), jnp.ones(shape[:3] + (1,), dtype))
@@ -1848,11 +2065,11 @@ def prefill_chunk(
         ctx = _cache_attention(cfg, q, ck, cv, mask)
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
-    if cfg.mamba_layers:
+    if cfg.recurrent_layers:
         # registry refuses PAGED_KV=0 for it: this window has no way to
         # leave a prompt's last token out of the state (forward_hidden).
         raise NotImplementedError(
-            "Mamba layers prefill in windows through paged_prefill_chunk only")
+            "recurrent layers prefill in windows through paged_prefill_chunk only")
     _layers(params, cfg, x, attend, None, lambda: chunk_mask != 0)
     key_valid = state.key_valid.at[rows, pos_w].set(
         chunk_mask.astype(jnp.int32), mode="drop"
@@ -1923,6 +2140,8 @@ def prefill_tile_counts(cfg: LlamaConfig, c: int, t_w: int, bs: int,
         return 0, 0
     live = total = 0
     for li in range(cfg.num_layers):
+        if cfg.layer_kind(li).mixer == "gdn":  # no keys: no tile
+            continue
         window = cfg.layer_kind(li).window
         first, n = prefill_key_blocks(c, t_w, bs, start, window)
         tq, tk = tile_sizes(c, 1 if cfg.mla else cfg.n_rep, n * bs)
@@ -2031,21 +2250,21 @@ def paged_prefill_chunk(
             ctx = ctx[0] if b == 1 else jnp.concatenate(ctx, axis=0)
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
-    def scan(layer, x, conv, st):
+    def scan(block, layer, x, conv, st):
         at, fold = ssm_rows[:, 0], ssm_rows[:, 1]
 
         def rows_of(a):  # each prompt's row; zeros for a first window
             first = (starts == 0).reshape((b,) + (1,) * (a.ndim - 1))
             return jnp.where(first, 0, jnp.take(a, at, axis=0, mode="clip"))
 
-        x, conv1, st1 = _mamba_block(
+        x, conv1, st1 = block(
             cfg, layer, x, rows_of(conv), rows_of(st),
             mask=jnp.arange(c)[None, :] < fold[:, None])
         return (x, conv.at[at].set(conv1, mode="drop"),
                 st.at[at].set(st1, mode="drop"))
 
-    mamba, ssm_done = _ssm_walker(cfg, state.ssm, scan)
-    _layers(params, cfg, x, attend_rows, mamba, lambda: chunk_mask != 0)
+    recur, ssm_done = _ssm_walker(cfg, state.ssm, scan)
+    _layers(params, cfg, x, attend_rows, recur, lambda: chunk_mask != 0)
     return state._replace(cache_k=new_k, cache_v=new_v,
                           ssm=ssm_done())
 

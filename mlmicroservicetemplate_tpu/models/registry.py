@@ -902,6 +902,8 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             ("a group limit on the router", cfg.n_group > 1),
             ("a chip's share of the experts", cfg.experts_held),
             ("mixer-or-FFN layers (layer_pattern)", cfg.layer_pattern),
+            ("a clamped SwiGLU", cfg.swiglu_limit),
+            ("a gated norm scale", cfg.norm_gate_weight),
         ) if on
     ]
     if variants:
@@ -960,16 +962,19 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
                 "PROMPT_PREFIX": "the prefix overlay's prefill reads K and V "
                 "per head",
             })
-    if cfg.mamba_layers:
-        # A Mamba layer's state is a fixed-size row a stream beside the
-        # cache, carried by the prefill waves, the chunked paged prefill
-        # and the paged decode step (models/llama.py, engine/streams.py's
-        # state rows).  Every other reader or mover of a stream's state
-        # knows keys and values only and would drop, share or skip the
-        # recurrence in silence: refuse it.  TP>1 (no spec shards Mamba
-        # heads) and QUANTIZE refuse above.
+    if cfg.recurrent_layers:
+        # A recurrent layer's state (Mamba-2's, Gated DeltaNet's) is a
+        # fixed-size row a stream beside the cache — a K/V pool or, with
+        # attention='mla', a latent pool: the refusals above hold as well —
+        # carried by the prefill waves, the chunked paged prefill and the
+        # paged decode step (models/llama.py, engine/streams.py's state
+        # rows).  Every other reader or mover of a stream's state knows
+        # keys and values only and would drop, share or skip the recurrence
+        # in silence: refuse it.  TP>1 (no spec shards the recurrent heads)
+        # and QUANTIZE refuse above.
         _refuse_cache_readers(
-            svc_cfg, "Mamba layers (layer_pattern 'M')", {
+            svc_cfg, "Mamba layers (layer_pattern 'M')" if cfg.layer_pattern
+            else "Gated-DeltaNet layers (layer_types 'linear')", {
                 "PAGED_KV=0": "the contiguous slab's chunked prefill cannot "
                 "leave a prompt's last token out of the recurrent state: set "
                 "PAGED_KV=1",

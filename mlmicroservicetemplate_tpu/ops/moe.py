@@ -19,7 +19,8 @@ experts' only, never a dense pass over all experts under a mask.
     out = sum_j w_j * down_{e_j}(silu(gate_{e_j} h) * up_{e_j} h)
           + down_s(silu(gate_s h) * up_s h)    a shared expert, every token
 
-The block's shape is data.  ``act`` "relu2": an expert is the non-gated
+The block's shape is data.  ``limit`` > 0: every SwiGLU is clamped,
+``silu(min(gate, limit)) * clip(up, -limit, limit)``.  ``act`` "relu2": an expert is the non-gated
 ``down(relu(up v)^2)`` and the tree holds no ``gate`` stack, the shared
 expert likewise.  ``latent_down`` / ``latent_up`` in the tree (LatentMoE):
 the routed experts live in a narrower latent between one down- and one
@@ -158,7 +159,7 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
                interpret: bool = False, score: str = "softmax",
                route_scale: float = 1.0, n_group: int = 0,
                topk_group: int = 0, expert_first: int = 0,
-               act: str = "silu"):
+               act: str = "silu", limit: float = 0.0):
     """h [T, D] normed tokens, ``mlp`` the layer's expert leaves (router
     [D, E]; gate, up [held, D, W]; down [held, W, D], held = E unless the
     tree holds a chip's share; where the model has them ``router_bias``
@@ -167,8 +168,16 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
     wide where they were D; ``act`` "relu2": no ``gate``), valid [T] bool
     -> (out [T, D] in h's dtype, counts [E] int32 of valid assignments
     over the PUBLISHED experts).  ``interpret`` runs the kernel in
-    interpret mode (CPU tests)."""
+    interpret mode (CPU tests).  ``limit`` > 0 clamps every SwiGLU, routed
+    and shared: ``silu(min(gate, limit)) * clip(up, -limit, limit)``."""
     t, d = h.shape
+
+    def gate_act(gate):  # silu of a SwiGLU's gate, and its other factor
+        return jax.nn.silu(jnp.minimum(gate, limit) if limit else gate)
+
+    def clip(up_):
+        return jnp.clip(up_, -limit, limit) if limit else up_
+
     up, down = mlp["up"]["kernel"], mlp["down"]["kernel"]
     n_exp = up.shape[0]  # held
     n_pub = mlp["router"]["kernel"].shape[1]
@@ -214,7 +223,7 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
         if act == "relu2":
             mid = _relu2(mm(xs, up, sizes))
         else:
-            mid = jax.nn.silu(mm(xs, mlp["gate"]["kernel"], sizes)) * mm(xs, up, sizes)
+            mid = gate_act(mm(xs, mlp["gate"]["kernel"], sizes)) * clip(mm(xs, up, sizes))
         ys = mm(mid, down, sizes)  # [T*k, D]
     with jax.named_scope("moe_combine"):
         in_group = jnp.arange(t * k) < jnp.sum(sizes)
@@ -234,6 +243,6 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
                 return h @ sh[name]["kernel"].astype(h.dtype)
 
             mid = _relu2(proj("up")) if act == "relu2" else (
-                jax.nn.silu(proj("gate")) * proj("up"))
+                gate_act(proj("gate")) * clip(proj("up")))
             out = out + mid @ sh["down"]["kernel"].astype(h.dtype)
     return out, counts

@@ -44,16 +44,21 @@ import jax
 import jax.numpy as jnp
 
 
+def _bias(b):
+    """A convolution's bias in float32 (0 for ``None``: Gated DeltaNet's)."""
+    return 0.0 if b is None else b.astype(jnp.float32)
+
+
 def conv_scan(x: jax.Array, state: jax.Array, w: jax.Array, b: jax.Array,
               mask: jax.Array):
     """x [B, L, C] inputs, ``state`` [B, K-1, C] each row's last inputs
-    before them, ``w`` [K, C], ``b`` [C], ``mask`` [B, L] (1 on a PREFIX of
+    before them, ``w`` [K, C], ``b`` [C] | None, ``mask`` [B, L] (1 on a PREFIX of
     real tokens) -> (silu(b + sum_j w_j x_{t-K+1+j}) [B, L, C] in x's
     dtype, the new state: the last K-1 inputs up to each row's last real
     token — the old state for a row with none)."""
     k, length = w.shape[0], x.shape[1]
     full = jnp.concatenate([state.astype(x.dtype), x], axis=1)
-    y = b.astype(jnp.float32) + sum(
+    y = _bias(b) + sum(
         w[j].astype(jnp.float32) * full[:, j:j + length].astype(jnp.float32)
         for j in range(k))
     n = jnp.sum(mask != 0, axis=-1).astype(jnp.int32)  # [B] real tokens
@@ -68,7 +73,7 @@ def conv_step(x: jax.Array, state: jax.Array, w: jax.Array, b: jax.Array,
     (the convolution's output [B, C], the taps shifted by one where the
     row is live and as they were where it is not)."""
     full = jnp.concatenate([state.astype(x.dtype), x[:, None]], axis=1)
-    y = b.astype(jnp.float32) + jnp.sum(
+    y = _bias(b) + jnp.sum(
         w.astype(jnp.float32)[None] * full.astype(jnp.float32), axis=1)
     new = jnp.where(live[:, None, None], full[:, 1:].astype(state.dtype), state)
     return jax.nn.silu(y).astype(x.dtype), new
@@ -334,3 +339,148 @@ def ssm_step(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
     new = jnp.where(live[:, None, None, None], new, s)
     y = jnp.sum(new * ch[:, :, None, :], axis=-1) + x * d.astype(f32)[:, None]
     return y, new
+
+
+# ---------------------------------------------------------------------------
+# Gated DeltaNet (arXiv:2412.06464; ``gdn_scan`` / ``gdn_step``) is the other
+# recurrence kept in the same state rows: a head's state is a MATRIX ``S``
+# [Dv, Dk] float32 updated by a delta rule,
+#
+#     S_t = a_t S_{t-1} + b_t (v_t - a_t S_{t-1} k_t) k_t^T      o_t = S_t q_t
+#
+# ``a_t = exp(g_t)`` in (0, 1] a head's decay, ``b_t`` in (0, 1) how much of
+# the value the state held for ``k_t`` is replaced.  A masked token has
+# ``g = 0`` and ``b = 0`` and moves no state.  ``gdn_step`` is the rule
+# itself; ``gdn_scan`` its chunked form (the UT transform): within a chunk
+# the ``u_t = b_t (v_t - a_t S_{t-1} k_t)`` of all tokens at once through
+# the inverse of a unit lower-triangular matrix (``_unit_lower_inverse``),
+# across chunks the carried ``S``.  Its matmuls run at ``Precision.HIGHEST``
+# whatever the default: the inverse feeds every later token of the chunk,
+# and the operands are small beside the projections.
+
+
+def _hi(spec: str, *ops):
+    return jnp.einsum(spec, *ops, precision=jax.lax.Precision.HIGHEST,
+                      preferred_element_type=jnp.float32)
+
+
+#: Side of the diagonal blocks ``_unit_lower_inverse`` inverts by forward
+#: substitution, a row at a time.
+INVERSE_BLOCK = 16
+#: Tokens a chunk of ``gdn_scan``: the triangular inverse is within a chunk,
+#: the carried state across chunks.  The answer does not depend on it (no
+#: published key gives one); 64 keeps the ``[Q, Q]`` matrices small beside
+#: the ``[Q, 128]`` operands.
+GDN_CHUNK = 64
+
+
+def _unit_lower_inverse(m: jax.Array) -> jax.Array:
+    """``(I + M)^-1`` for ``m`` [..., Q, Q] strictly lower triangular: the
+    diagonal blocks of ``INVERSE_BLOCK`` rows by forward substitution (row
+    i is ``e_i - M[i, :i] X[:i]``: ``INVERSE_BLOCK - 1`` small steps, every
+    block of every chunk at once), then pairs of inverted blocks merged
+    upwards, ``[[A, 0], [C, D]]^-1 = [[A^-1, 0], [-D^-1 C A^-1, D^-1]]``:
+    two matmuls a level.  Each step is a substitution, so the error is the
+    inverse's own conditioning's.  (The finite Neumann series ``prod_j (I +
+    (-M)^(2^j))`` is all matmuls and exact on paper, but ``M^p`` sums
+    ``C(Q, p)`` paths that cancel: with the correlated keys a convolution
+    leaves, float32 overflowed at Q = 64 — my chip run, PR 47.)"""
+    lead, q = m.shape[:-2], m.shape[-1]
+    b = min(INVERSE_BLOCK, q)
+    if q % b or (q // b) & (q // b - 1):
+        raise ValueError(f"a chunk of {q} is not {b} times a power of two")
+    blocks = m.reshape(*lead, q // b, b, q // b, b)
+    diag = jnp.stack([blocks[..., i, :, i, :] for i in range(q // b)], axis=-3)
+    x = jnp.broadcast_to(jnp.eye(b, dtype=m.dtype), diag.shape)
+    for i in range(1, b):  # rows past i are still the identity's: no share
+        x = x.at[..., i, :].add(-jnp.sum(diag[..., i, :, None] * x, axis=-2))
+    while b < q:
+        pairs = x.reshape(*lead, q // (2 * b), 2, b, b)
+        a_inv, d_inv = pairs[..., 0, :, :], pairs[..., 1, :, :]
+        blocks = m.reshape(*lead, q // (2 * b), 2, b, q // (2 * b), 2, b)
+        c = jnp.stack([blocks[..., i, 1, :, i, 0, :]
+                       for i in range(q // (2 * b))], axis=-3)
+        low = -_hi("...ij,...jk->...ik", _hi("...ij,...jk->...ik", d_inv, c), a_inv)
+        x = jnp.concatenate([
+            jnp.concatenate([a_inv, jnp.zeros_like(a_inv)], axis=-1),
+            jnp.concatenate([low, d_inv], axis=-1)], axis=-2)
+        b *= 2
+    return x[..., 0, :, :]
+
+
+def gdn_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+             beta: jax.Array, s0: jax.Array, mask: jax.Array, *,
+             chunk: int = GDN_CHUNK):
+    """q, k [B, L, H, Dk] (normalised, q scaled), v [B, L, H, Dv], ``g``
+    [B, L, H] (log decay, <= 0), ``beta`` [B, L, H], ``s0`` [B, H, Dv, Dk]
+    float32, ``mask`` [B, L] (1 on a PREFIX of real tokens) -> (o
+    [B, L, H, Dv] float32, final state).  Any L: the tail is padded with
+    masked tokens.  With ``M[i, j] = b_i exp(G_i - G_j) k_i . k_j`` below
+    the diagonal (``G`` the running sum of ``g`` in the chunk) and ``T =
+    (I + M)^-1``:
+
+        U = T (b V) - T (b e^G K) S_0^T            the chunk's u_t, [Q, Dv]
+        O = (e^G Q) S_0^T + tril(Q K^T e^(G_i - G_j)) U
+        S = e^(G_Q) S_0 + U^T (e^(G_Q - G) K)
+
+    ``T`` by ``_unit_lower_inverse``; ``chunk`` is ``INVERSE_BLOCK`` times a
+    power of two, or at most ``INVERSE_BLOCK``."""
+    f32 = jnp.float32
+    bsz, length, h, dk = q.shape
+    pad = -length % chunk
+    if pad:
+        q, k, v, g, beta, mask = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (q, k, v, g, beta, mask))
+    nc = (length + pad) // chunk
+    live = (mask != 0)[..., None]
+    g = jnp.where(live, g.astype(f32), 0.0)
+    beta = jnp.where(live, beta.astype(f32), 0.0)
+
+    def chunks(t):  # [B, L, H, ...] -> [B, nc, H, Q, ...]
+        t = t.astype(f32).reshape(bsz, nc, chunk, *t.shape[2:])
+        return jnp.moveaxis(t, 2, 3)
+
+    qs, ks, vs, gs, bs = (chunks(t) for t in (q, k, v, g, beta))
+    cum = jnp.cumsum(gs, axis=-1)  # [B, nc, H, Q]
+    seg = cum[..., :, None] - cum[..., None, :]
+    idx = jnp.arange(chunk)
+    below, upto = idx[:, None] > idx[None, :], idx[:, None] >= idx[None, :]
+    decay = jnp.exp(jnp.where(upto, seg, -jnp.inf))  # [.., Q, K], 0 above
+    kk = _hi("bzhqd,bzhkd->bzhqk", ks, ks)
+    t = _unit_lower_inverse(jnp.where(below, kk * decay, 0.0) * bs[..., None])
+    w = _hi("bzhqk,bzhkv->bzhqv", t, vs * bs[..., None])
+    kc = _hi("bzhqk,bzhkd->bzhqd", t, ks * (bs * jnp.exp(cum))[..., None])
+    qk = _hi("bzhqd,bzhkd->bzhqk", qs, ks) * decay
+    q_in = qs * jnp.exp(cum)[..., None]
+    k_end = ks * jnp.exp(cum[..., -1:] - cum)[..., None]
+    whole = jnp.exp(cum[..., -1])  # [B, nc, H]
+
+    def carry(s, step):
+        w_z, kc_z, qk_z, q_z, k_z, a_z = step
+        u = w_z - _hi("bhqd,bhvd->bhqv", kc_z, s)
+        o = _hi("bhqd,bhvd->bhqv", q_z, s) + _hi("bhqk,bhkv->bhqv", qk_z, u)
+        s = s * a_z[..., None, None] + _hi("bhqv,bhqd->bhvd", u, k_z)
+        return s, o
+
+    s_last, o = jax.lax.scan(
+        carry, s0.astype(f32),
+        tuple(jnp.moveaxis(x, 1, 0) for x in (w, kc, qk, q_in, k_end, whole)))
+    # [nc, B, H, Q, Dv] -> [B, L, H, Dv]
+    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(bsz, nc * chunk, h, -1)
+    return o[:, :length], s_last
+
+
+def gdn_step(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
+             beta: jax.Array, s: jax.Array, live: jax.Array):
+    """One token a row: q, k [B, H, Dk], v [B, H, Dv], ``g`` / ``beta``
+    [B, H], ``s`` [B, H, Dv, Dk] float32, ``live`` [B] -> (o [B, H, Dv]
+    float32, the state: updated where the row is live, as it was where it
+    is not).  Products and sums on the vector unit, exact float32."""
+    f32 = jnp.float32
+    q, k, v = q.astype(f32), k.astype(f32), v.astype(f32)
+    kept = jnp.exp(g.astype(f32))[..., None, None] * s
+    u = beta.astype(f32)[..., None] * (v - jnp.sum(kept * k[:, :, None, :], axis=-1))
+    new = kept + u[..., None] * k[:, :, None, :]
+    new = jnp.where(live[:, None, None, None], new, s)
+    return jnp.sum(new * q[:, :, None, :], axis=-1), new

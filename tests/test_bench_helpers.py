@@ -306,7 +306,8 @@ def test_prefill_window_ms_is_a_window_executables_mean_time():
 BOOT_CELLS = [
     "mistral-7b-d8.decode-closed", "mistral-7b-d8.chat-open",
     "olmoe-1b-7b-d8.decode-closed", "trinity-mini-d5.longdoc-closed", DSV2_CELL,
-    "nemotron3-super-ep4-d11.longdoc-closed"]  # PR 40 appended its cell
+    "nemotron3-super-ep4-d11.longdoc-closed",  # PR 40 appended its cell
+    "gigachat35-ep16-d5.longdoc-closed"]  # and PR 47 its
 BOOT_ENTRIES = [
     ("boot_imports_s", "s", "boot_phase_seconds", {"phase": "imports"}),
     ("boot_weights_s", "s", "boot_phase_seconds", {"phase": "weights"}),
@@ -401,9 +402,9 @@ def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
     assert entry == {
         "name": name, "unit": "%", "better": "higher", "source": "program_counter",
         "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
-    # appended by PR 36 (nothing before them moved); PR 40's 24 and PR 41's
-    # two follow them
-    assert entry in per_layer[-28:-26]
+    # appended by PR 36 (nothing before them moved); PR 40's 24, PR 41's
+    # two and PR 47's 29 follow them
+    assert entry in per_layer[-57:-55]
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_counter_ratio" and callable(metric.read)
@@ -481,14 +482,14 @@ def test_nemotron_configuration_and_cell_are_in_the_benchmark():
     from cellbench import spec
 
     bench = spec.load_benchmark()
-    assert bench["configs"][-1]["name"] == "nemotron3-super-ep4-d11"  # appended
-    cfg = bench["configs"][-1]
+    assert bench["configs"][-2]["name"] == "nemotron3-super-ep4-d11"  # appended
+    cfg = bench["configs"][-2]  # (PR 47 appended one after it)
     assert cfg["file"] == "cellbench/configs/nemotron3-super-ep4-d11.json"
     assert cfg["source"] == (
         "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16"
         "/blob/main/config.json")
     assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    cell = bench["workloads"][-1]
+    cell = bench["workloads"][-2]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         NEMO_CELL, "nemotron3-super-ep4-d11", "longdoc-closed", 1)
     assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
@@ -496,7 +497,7 @@ def test_nemotron_configuration_and_cell_are_in_the_benchmark():
           if "workloads" not in m or NEMO_CELL in m["workloads"]]
     assert on == ["tbt_p99_ms", "setup_s"]
     boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(m["workloads"][-1] == NEMO_CELL for m in boots)
+    assert len(boots) == 7 and all(m["workloads"][-2] == NEMO_CELL for m in boots)
     file = spec.load_json(spec.REPO + "/" + cfg["file"])
     assert set(file["reduced"]) == set(cfg["reduced"])
     assert (file["num_hidden_layers"], file["n_routed_experts"],
@@ -573,7 +574,8 @@ def test_insert_rows_per_dispatch_resolves_in_its_cell(name, cell):
         "name": name, "unit": "rows", "better": "higher",
         "source": "program_counter", "layer": "engine", "moves": "tokens_per_s",
         "workloads": [cell]}
-    assert entry in per_layer[-2:]  # appended: nothing before them moved
+    # appended: nothing before them moved (PR 47's 29 follow them)
+    assert entry in per_layer[-31:-29]
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_hist" and callable(metric.read)
@@ -610,3 +612,92 @@ def test_insert_rows_per_dispatch_reads_the_programs_histogram():
     assert prom_hist.read(ctx(after, before), *args) == pytest.approx(64 / 3)
     assert prom_hist.read(ctx(after, after), *args) is None
     assert prom_hist.read(ctx({}, {}), *args) is None
+
+
+# ---------------------------------------------------------------------------
+# PR 47: GigaChat3.5 — one configuration, one cell, twenty-nine entries, a
+# cost file and one reader, every accepted file as it was
+
+GIGA_CELL = "gigachat35-ep16-d5.longdoc-closed"
+GIGA_ENTRIES = [
+    ("decode_step_ms", "ms", _T, "model step", "trace_module_ms"),
+    ("decode_step_roofline", "%", _T, "model step", "gigachat_roofline"),
+    ("decode_gdn_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("gdn_step_roofline", "%", _T, "kernels", "gigachat_roofline"),
+    ("gdn_proj_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("prefill_gdn_scan_ms", "ms", _T, "model step", "gigachat_roofline"),
+    ("gdn_scan_roofline", "%", _T, "kernels", "gigachat_roofline"),
+    ("gdn_state_share_pct", "%", _C, "engine", "gigachat_roofline"),
+    ("gdn_scan_masked_pct", "%", _C, "engine", "gigachat_roofline"),
+    ("decode_attn_latent_ms", "ms", _T, "model step", "trace_scope_ms"),
+    ("latent_decode_attention_roofline", "%", _T, "kernels", "gigachat_roofline"),
+    ("decode_moe_ms", "ms", _T, "model step", "trace_scope_ms"),
+    ("moe_experts_roofline", "%", _T, "kernels", "gigachat_roofline"),
+    ("moe_held_share_pct", "%", _C, "model step", "prom_counter_ratio"),
+    ("moe_imbalance", "ratio", _C, "engine", "prom_hist"),
+    ("streams_per_chunk", "streams", _C, "engine", "prom_hist"),
+    ("prefill_stall_ms", "ms/s", _C, "engine", "prom_counter_rate"),
+    ("prefill_window_ms", "ms", _T, "model step", "trace_module_ms"),
+    ("prefill_windows_batched_pct", "%", _C, "engine", "prom_counter_ratio"),
+    ("device_idle_pct", "%", _T, "device", "trace_idle_pct"),
+    # the accepted twins of the latent layer, the expert overhead and the table
+    ("mla_absorb_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("mla_proj_ms", "ms", _T, "model step", "trace_scope_ms"),
+    ("moe_overhead_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("moe_shared_ms", "ms", _T, "model step", "trace_subscope_ms"),
+    ("table_blocks_dead_pct", "%", _C, "kernels", "prom_counter_ratio"),
+    # a prompt-window dispatch, part by part
+    ("prefill_gdn_proj_ms", "ms", _T, "model step", "gigachat_roofline"),
+    ("prefill_attn_latent_ms", "ms", _T, "model step", "gigachat_roofline"),
+    ("prefill_mlp_ms", "ms", _T, "model step", "gigachat_roofline"),
+    ("prefill_moe_experts_ms", "ms", _T, "model step", "gigachat_roofline"),
+]
+
+
+def test_gigachat_configuration_and_cell_are_in_the_benchmark():
+    from cellbench import spec
+
+    bench = spec.load_benchmark()
+    cfg, cell = bench["configs"][-1], bench["workloads"][-1]  # appended
+    assert cfg["name"] == "gigachat35-ep16-d5"
+    assert cfg["file"] == "cellbench/configs/gigachat35-ep16-d5.json"
+    assert cfg["source"] == (
+        "https://huggingface.co/ai-sage/GigaChat3.5-432B-A28B/blob/main/config.json")
+    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts",
+                              "first_k_dense_replace", "vocab_size"]
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        GIGA_CELL, "gigachat35-ep16-d5", "longdoc-closed", 1)
+    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
+    on = [m["name"] for m in bench["end_to_end"]
+          if "workloads" not in m or GIGA_CELL in m["workloads"]]
+    assert on == ["tbt_p99_ms", "setup_s"]
+    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
+    assert len(boots) == 7 and all(m["workloads"][-1] == GIGA_CELL for m in boots)
+    assert [m["name"] for m in bench["per_layer"][-len(GIGA_ENTRIES):]] == [
+        n + ".gigachat" for n, *_ in GIGA_ENTRIES]
+    assert len(bench["per_layer"]) <= 128  # the file's limit
+    file = spec.load_json(spec.REPO + "/" + cfg["file"])
+    assert list(file["reduced"]) == cfg["reduced"]
+    assert (file["num_hidden_layers"], file["n_routed_experts"],
+            file["first_k_dense_replace"], file["vocab_size"]) == (5, 16, 1, 16032)
+    assert file["layer_types"] == ["linear"] * 4 + ["full"]
+    assert "deployment" in file["assumed"] and "mtp" in file["assumed"]
+    # the traffic file is the three other long-document cells', unchanged
+    assert spec.resolve(GIGA_CELL).traffic == spec.resolve(NEMO_CELL).traffic
+
+
+@pytest.mark.parametrize("name,unit,source,layer,reader", GIGA_ENTRIES)
+def test_gigachat_per_layer_entry_resolves(name, unit, source, layer, reader):
+    from cellbench import spec
+
+    name += ".gigachat"
+    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
+    assert entry == {
+        "name": name, "unit": unit,
+        "better": entry["better"], "source": source, "layer": layer,
+        "moves": "tbt_p99_ms", "workloads": [GIGA_CELL]}
+    assert entry["better"] in ("lower", "higher")
+    (resolved,) = [m for m in spec.resolve(GIGA_CELL).per_layer if m.name == name]
+    assert resolved.reader == reader and callable(resolved.read)
+    for other in spec.load_benchmark()["workloads"][:-1]:  # read in its own cell only
+        assert name not in [m.name for m in spec.resolve(other["name"]).per_layer]

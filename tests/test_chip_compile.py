@@ -808,3 +808,45 @@ def test_a_576_lane_latent_pool_is_refused_by_the_chips_compiler(chip):
     block out of it at 576."""
     with pytest.raises(Exception, match="aligned to tiling"):
         _latent_text(chip, 576, "b8-nat")
+
+
+def test_the_deltanet_cells_chunk_compiles_at_its_shapes(chip):
+    """GigaChat3.5's paged chunk at the cell's own shapes (32 slots, state
+    rows [32, 64, 128, 128] float32 a DeltaNet layer beside a [12544, 16,
+    640] latent pool): the one-token delta rule over the state rows and the
+    latent kernel at 64 heads compile for the described chip, and the
+    donated state is aliased.  (The three-window prompt dispatch compiles
+    too, 47 s here: ``tools/lowered_text`` lowers it, the chip runs it.)"""
+    import json
+
+    from cellbench import spec as bench_spec
+    from mlmicroservicetemplate_tpu.engine.engine import chunk_with_done
+    from mlmicroservicetemplate_tpu.models import llama as llama_mod
+    from mlmicroservicetemplate_tpu.models.gpt import PagedState
+    from mlmicroservicetemplate_tpu.models.sampling import greedy_params
+
+    config = bench_spec.load_json(
+        bench_spec.HERE + "/configs/gigachat35-ep16-d5.json")
+    over = json.loads(bench_spec.service_env(config)["LLAMA_CONFIG"])
+    cfg = LlamaConfig(**over, pallas_decode=True, pallas_variant="b4-hb")
+    b, bs, dt, t = 32, 16, jnp.bfloat16, 392
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: chip(x.shape, x.dtype), tree)
+
+    params = on_chip(jax.eval_shape(
+        lambda: llama_mod.init_params(jax.random.PRNGKey(0), cfg, dtype=dt)))
+    state = on_chip(jax.eval_shape(lambda: PagedState(
+        cache_k=[jnp.zeros((b * t, bs, cfg.latent_lanes), dt)], cache_v=[],
+        key_valid=jnp.zeros((b, t * bs), jnp.int32),
+        write_idx=jnp.zeros((b,), jnp.int32), pos=jnp.zeros((b,), jnp.int32),
+        last_token=jnp.zeros((b,), jnp.int32), done=jnp.ones((b,), bool),
+        tokens=jnp.zeros((b, 256), jnp.int32), sample=greedy_params(b),
+        ssm=llama_mod.zero_ssm(cfg, b, dt))))
+    assert [s.shape for s in state.ssm.state] == [(32, 64, 128, 128)] * 4
+    text = jax.jit(
+        chunk_with_done(lambda p, s, tb, n, sample: (
+            llama_mod.generate_chunk_paged(p, cfg, s, tb, n, sample))),
+        static_argnums=(3, 4), donate_argnums=(1,),
+    ).lower(params, state, chip((b, t), jnp.int32), 4, False).compile().as_text()
+    assert "input_output_alias" in text and text.count("tpu_custom_call") >= 5

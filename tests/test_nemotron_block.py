@@ -229,7 +229,7 @@ def test_the_toy_has_every_kind_of_layer(cfg, params):
         "mamba2", None, "mamba2", None, "mamba2", None, "mamba2", "gqa",
         None, "mamba2", None]
     assert [k.ffn for k in kinds] == [c == "E" for c in "MEMEMEM*EME"]
-    assert cfg.expert_layers == (1, 3, 5, 8, 10) and cfg.mamba_layers == (0, 2, 4, 6, 9)
+    assert cfg.expert_layers == (1, 3, 5, 8, 10) and cfg.recurrent_layers == (0, 2, 4, 6, 9)
     assert not any(k.rope for k in kinds)  # nope_on_full: no rotation
     assert sorted(params["layers"][0]) == ["ssm", "ssm_ln"]
     assert sorted(params["layers"][7]) == ["attn", "attn_ln"]
@@ -406,7 +406,7 @@ def test_a_pattern_that_does_not_add_up_is_refused(kw, bad, needle):
 def test_a_config_without_a_pattern_builds_the_kinds_it_built(over):
     c = llama_mod.LlamaConfig(vocab_size=97, d_model=64, num_heads=4, num_layers=3,
                               max_position=64, pallas_interpret=True, **over)
-    assert c.layer_pattern == "" and c.mamba_layers == () and c.ssm_row_bytes == 0
+    assert c.layer_pattern == "" and c.recurrent_layers == () and c.ssm_row_bytes == 0
     for li in range(3):
         k = c.layer_kind(li)
         window = c.window if c.layer_types and c.layer_types[li] == "window" else 0
@@ -414,7 +414,7 @@ def test_a_config_without_a_pattern_builds_the_kinds_it_built(over):
         assert k == llama_mod.LayerKind(
             window, bool(window) or not c.nope_on_full,
             bool(c.num_experts) and not dense, c.d_ff_dense if dense else c.d_ff)
-        assert (k.mixer, k.ffn, k.mamba) == ("gqa", True, False)
+        assert (k.mixer, k.ffn, k.recurrent) == ("gqa", True, False)
     # and its decode states carry no recurrent leaf
     p = llama_mod.init_params(jax.random.PRNGKey(0), c)
     ids = _ids(6, 3, 90)[None]

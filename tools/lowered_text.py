@@ -15,7 +15,8 @@ digests = the configuration's executables were left as they were.  Give
 both sides the SAME path (a symlink pointed at one checkout, then the
 other): a kernel's serialised body carries its source file's name.  Only
 what both sides have is used of the program (``models/llama.py`` functions
-that PR 36 had).
+that PR 36 had, and ``zero_ssm`` / ``ssm_rows`` for a configuration with
+recurrent layers, PR 40's).
 """
 
 from __future__ import annotations
@@ -93,6 +94,12 @@ def main(argv=None) -> int:
             tokens=rows(tmpl.tokens),
             sample=jax.tree.map(rows, jax.eval_shape(lambda: greedy_params(1))),
         )
+        # Recurrent layers (PR 40 on): a state row a slot beside the pool,
+        # and a window dispatch names each prompt's row.
+        ssm = jax.eval_shape(lambda: llama.zero_ssm(cfg, b, dt)) if hasattr(
+            llama, "zero_ssm") else ()
+        if ssm != ():
+            state = state._replace(ssm=ssm)
         i32 = jnp.int32
         steps = {"paged_chunk": (
             lambda p, s, t: llama.generate_chunk_paged(p, cfg, s, t, 4, False),
@@ -101,12 +108,14 @@ def main(argv=None) -> int:
         if c:
             for w in sorted({1, -(-int(env.get("PREFILL_BUDGET", c)) // c)}):
                 steps[f"paged_prefill_chunk_b{w}"] = (
-                    lambda p, s, t, i, m, st: llama.paged_prefill_chunk(
-                        p, cfg, s, t, i, m, st, dtype=dt),
+                    lambda p, s, t, i, m, st, *row: llama.paged_prefill_chunk(
+                        p, cfg, s, t, i, m, st, dtype=dt,
+                        **({"ssm_rows": row[0]} if row else {})),
                     (params, state, jax.ShapeDtypeStruct((w, t_w), i32),
                      jax.ShapeDtypeStruct((w, c), i32),
                      jax.ShapeDtypeStruct((w, c), i32),
-                     jax.ShapeDtypeStruct((w,), i32)))
+                     jax.ShapeDtypeStruct((w,), i32),
+                     *([jax.ShapeDtypeStruct((w, 2), i32)] if ssm != () else [])))
         for w in sorted({1, min(4, b), b}):
             steps[f"start_b{w}"] = (
                 lambda p, i, m: llama.generate_chunk(p, cfg, llama.init_decode_state(

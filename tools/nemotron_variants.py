@@ -26,9 +26,11 @@ these (its table; PERF.md section 4).
 wave's scan leaves and what every decode step leaves, rounded.  One wave
 forward rounds nothing a logit reads, so that variant is judged where the
 state is read: ``--served NAME`` boots the cell's own service
-(``cellbench/service.py``) with the variant's patches in place and prints
-what the cell's ``check`` says of it — tokens, logits, and the loop's
-state row against the reference's token scan.
+(``cellbench/service.py``) with the variant's patches, or its keyword
+overrides of ``LLAMA_CONFIG``, in place and prints what the cell's ``check``
+says of it — tokens, logits, and the loop's state row against the
+reference's token scan: the served path's reading of a variant (a cell
+whose traffic never runs the prefill wave is judged there).
 """
 
 from __future__ import annotations
@@ -40,6 +42,7 @@ import importlib
 import json
 import os
 import sys
+from typing import NamedTuple
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, os.path.dirname(HERE))
@@ -48,6 +51,16 @@ from tools.trinity_variants import _float8, readings  # noqa: E402  (one reading
 
 PKG = "mlmicroservicetemplate_tpu"
 CELL = "nemotron3-super-ep4-d11.longdoc-closed"
+
+
+class Family(NamedTuple):
+    """What ``main`` runs the variants of: a cell of the benchmark, its
+    configuration's reference and the table of broken rules (this file's,
+    or another family's: ``tools/gigachat_variants.py``)."""
+
+    cell: str
+    reference: str
+    variants: dict
 
 
 def _ssm_leaf(params: dict, path: tuple, fn) -> dict:
@@ -135,32 +148,40 @@ def patched(patches: dict):
             setattr(mod, attr, value)
 
 
-def broken(name: str, kw: dict, params: dict):
+def broken(name: str, kw: dict, params: dict, variants: dict | None = None):
     """(kwargs, params, patches) of variant ``name``."""
-    out = VARIANTS[name](kw, params)
+    out = (VARIANTS if variants is None else variants)[name](kw, params)
     return out if len(out) == 3 else (*out, {})
 
 
-def served(name: str, rehearse: str | None) -> int:
-    """The cell's own check of a service built with variant ``name`` (one
-    that is patches alone) in place: one JSON line, the check's."""
+def served(name: str, rehearse: str | None, family: Family) -> int:
+    """The cell's own check of a service built with variant ``name`` in
+    place — patches, keyword overrides of the service's ``LLAMA_CONFIG``
+    (the reference stays the published configuration's), or both; not one
+    that edits the weights: one JSON line, the check's."""
     import asyncio
 
     from cellbench import run as bench_run
     from cellbench import spec
     from cellbench.service import Service
 
-    cell = spec.resolve(CELL, spec.REPO)
+    cell = spec.resolve(family.cell, spec.REPO)
     if rehearse:
         cell.config = bench_run._merge(
             cell.config, spec.load_json(rehearse)["config"])
     kw, params, patches = {}, {"layers": []}, {}
     if name != "sound":
-        vkw, vparams, patches = broken(name, kw, params)
-        if vkw is not kw or vparams is not params:
-            raise SystemExit(f"--served {name}: only a variant that is patches alone")
-    ref = spec.load_module(os.path.join(spec.HERE, "references", "nemotron_h.py"),
-                           "cellbench_reference_nemotron_h")
+        vkw, vparams, patches = broken(name, kw, params, family.variants)
+        if vparams is not params:
+            raise SystemExit(f"--served {name}: not a variant that edits the weights")
+        # the service is built broken on purpose: what it is held to follows
+        cell.config = bench_run._merge(cell.config, {
+            "env_json": {"LLAMA_CONFIG": vkw},
+            "expect_cfg": {k: v for k, v in vkw.items()
+                           if k in cell.config.get("expect_cfg", {})}})
+    ref = spec.load_module(
+        os.path.join(spec.HERE, "references", family.reference + ".py"),
+        f"cellbench_reference_{family.reference}")
     work = os.path.join(spec.REPO, ".cellbench_work")
     os.makedirs(work, exist_ok=True)
     extra = {"DEVICE": "cpu" if rehearse else "tpu", "WARMUP": "1",
@@ -177,7 +198,8 @@ def served(name: str, rehearse: str | None) -> int:
     return 0
 
 
-def main(argv=None) -> int:
+def main(argv=None, family: Family | None = None) -> int:
+    family = family or Family(CELL, "nemotron_h", VARIANTS)
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--tokens", type=int, default=2560)
     ap.add_argument("--seed", type=int, default=20240924)
@@ -191,7 +213,7 @@ def main(argv=None) -> int:
     if a.rehearse:
         os.environ["JAX_PLATFORMS"] = "cpu"
     if a.served:
-        return served(a.served, a.rehearse)
+        return served(a.served, a.rehearse, family)
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -200,12 +222,13 @@ def main(argv=None) -> int:
     from cellbench import spec
     from mlmicroservicetemplate_tpu.models import llama
 
-    config = spec.load_json(
-        os.path.join(spec.HERE, "configs", "nemotron3-super-ep4-d11.json"))
+    config = spec.load_json(os.path.join(
+        spec.HERE, "configs", family.cell.split(".")[0] + ".json"))
     if a.rehearse:
         config = bench_run._merge(config, spec.load_json(a.rehearse)["config"])
-    ref = spec.load_module(os.path.join(spec.HERE, "references", "nemotron_h.py"),
-                           "cellbench_reference_nemotron_h")
+    ref = spec.load_module(
+        os.path.join(spec.HERE, "references", family.reference + ".py"),
+        f"cellbench_reference_{family.reference}")
     kw = json.loads(spec.service_env(config)["LLAMA_CONFIG"])
     kw["pallas_interpret"] = bool(a.rehearse)
     dtype = jnp.float32 if a.rehearse else jnp.bfloat16
@@ -216,10 +239,11 @@ def main(argv=None) -> int:
     # the reference is always of the SOUND weights and rules
     x = ref.hidden(params, ref.hyper(config), ids[None])[0]
     print(json.dumps({"device": jax.devices()[0].device_kind, "tokens": a.tokens,
-                      "pattern": cfg.layer_pattern, "held": cfg.held}), flush=True)
+                      "pattern": cfg.layer_pattern or list(cfg.layer_types),
+                      "held": cfg.held}), flush=True)
     # what ``readings`` needs of the sound tree, kept when the tree goes
     head = {"lm_head": {"kernel": jnp.copy(params["lm_head"]["kernel"])}}
-    for name in ["sound", *VARIANTS]:
+    for name in ["sound", *family.variants]:
         if a.only and name not in a.only.split(","):
             continue
         if name == "sound":
@@ -230,7 +254,7 @@ def main(argv=None) -> int:
             vparams = jax.jit(_float8, donate_argnums=0)(params)
             params = None
         else:
-            vkw, vparams, patches = broken(name, kw, params)
+            vkw, vparams, patches = broken(name, kw, params, family.variants)
         vcfg = llama.LlamaConfig(**vkw)
         with patched(patches):
             got = jax.jit(lambda p, i, c=vcfg: llama.lm_logits(
