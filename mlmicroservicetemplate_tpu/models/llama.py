@@ -1262,22 +1262,19 @@ def _gdn_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
                 qkv = y1[:, None]
         scan = "gdn_scan" if live is None else "gdn_step"
         with jax.named_scope(scan):
-            def unit(t):  # a head's q or k at unit length, a value head each
-                t = t.astype(f32).reshape(b, length, hk, dk)
-                t = t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
-                return jnp.repeat(t, hv // hk, axis=2)
-
-            q = unit(qkv[..., :hk * dk]) * dk ** -0.5
-            k = unit(qkv[..., hk * dk:2 * hk * dk])
-            v = qkv[..., 2 * hk * dk:].reshape(b, length, hv, dv)
             beta = jax.nn.sigmoid(ba[..., :hv])
             g = -jnp.exp(m["A_log"].astype(f32)) * jax.nn.softplus(
                 ba[..., hv:] + m["dt_bias"].astype(f32))
             if live is None:
-                o, s = ssm.gdn_scan(q, k, v, g, beta, s, mask)
+                # q, k and v where the convolution left them: the kernel
+                # reads them through block index maps and normalises a KEY
+                # head's q and k once, no float32 copy a value head.
+                o, s = ssm.gdn_scan(
+                    qkv, g, beta, s, mask, kernel=cfg.pallas_decode,
+                    interpret=cfg.pallas_interpret)
             else:
-                o, s = ssm.gdn_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0],
-                                    beta[:, 0], s, live)
+                o, s = ssm.gdn_step(*ssm.gdn_heads(qkv[:, 0], hk, hv, dk),
+                                    g[:, 0], beta[:, 0], s, live)
                 o = o[:, None]
         with jax.named_scope("gdn_gate_norm"):
             o = rmsnorm({"scale": 1.0 + m["norm"]["scale"].astype(f32)}, o,

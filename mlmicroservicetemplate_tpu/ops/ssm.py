@@ -353,10 +353,22 @@ def ssm_step(x: jax.Array, dt: jax.Array, a: jax.Array, b: jax.Array,
 # ``g = 0`` and ``b = 0`` and moves no state.  ``gdn_step`` is the rule
 # itself; ``gdn_scan`` its chunked form (the UT transform): within a chunk
 # the ``u_t = b_t (v_t - a_t S_{t-1} k_t)`` of all tokens at once through
-# the inverse of a unit lower-triangular matrix (``_unit_lower_inverse``),
-# across chunks the carried ``S``.  Its matmuls run at ``Precision.HIGHEST``
-# whatever the default: the inverse feeds every later token of the chunk,
-# and the operands are small beside the projections.
+# the inverse of a unit lower-triangular matrix, across chunks the carried
+# ``S``.  The scan has two forms behind one name, as ``ssm_scan`` has.
+# ``jax.numpy`` (``_gdn_scan_xla``): the tests' reference and the path
+# without kernels or at widths the chip's tiles do not divide; q and k are
+# normalised and repeated a value head in float32 and every ``[Q, Q]``
+# matrix goes through HBM.  The fused chunk kernel (``_gdn_kernel``, at the
+# bottom of this file, where the decode step runs its kernels): a program
+# is one row's ``GDN_STEP_HEADS`` KEY heads and ``GDN_STEP_CHUNKS`` chunks in
+# order; it reads q, k and their value heads' v from the convolution's
+# output where it lies, normalises q and k once a key head, and ``K K^T``,
+# ``Q K^T``, the decays, the inverse, ``U`` and the carried state never
+# leave VMEM.
+# Every matmul of both forms takes float32 operands at
+# ``Precision.HIGHEST`` whatever the default: the inverse feeds every later
+# token of the chunk and the state every later chunk, so one bfloat16 pass
+# would round what thousands of tokens then read; sums differ in order only.
 
 
 def _hi(spec: str, *ops):
@@ -364,8 +376,8 @@ def _hi(spec: str, *ops):
                       preferred_element_type=jnp.float32)
 
 
-#: Side of the diagonal blocks ``_unit_lower_inverse`` inverts by forward
-#: substitution, a row at a time.
+#: Side of the diagonal blocks the triangular inverse starts from (by
+#: substitution); pairs of inverted blocks are merged upwards by matmuls.
 INVERSE_BLOCK = 16
 #: Tokens a chunk of ``gdn_scan``: the triangular inverse is within a chunk,
 #: the carried state across chunks.  The answer does not depend on it (no
@@ -386,9 +398,7 @@ def _unit_lower_inverse(m: jax.Array) -> jax.Array:
     ``C(Q, p)`` paths that cancel: with the correlated keys a convolution
     leaves, float32 overflowed at Q = 64 — my chip run, PR 47.)"""
     lead, q = m.shape[:-2], m.shape[-1]
-    b = min(INVERSE_BLOCK, q)
-    if q % b or (q // b) & (q // b - 1):
-        raise ValueError(f"a chunk of {q} is not {b} times a power of two")
+    b = _inverse_block(q)
     blocks = m.reshape(*lead, q // b, b, q // b, b)
     diag = jnp.stack([blocks[..., i, :, i, :] for i in range(q // b)], axis=-3)
     x = jnp.broadcast_to(jnp.eye(b, dtype=m.dtype), diag.shape)
@@ -408,34 +418,79 @@ def _unit_lower_inverse(m: jax.Array) -> jax.Array:
     return x[..., 0, :, :]
 
 
-def gdn_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
-             beta: jax.Array, s0: jax.Array, mask: jax.Array, *,
-             chunk: int = GDN_CHUNK):
-    """q, k [B, L, H, Dk] (normalised, q scaled), v [B, L, H, Dv], ``g``
-    [B, L, H] (log decay, <= 0), ``beta`` [B, L, H], ``s0`` [B, H, Dv, Dk]
-    float32, ``mask`` [B, L] (1 on a PREFIX of real tokens) -> (o
-    [B, L, H, Dv] float32, final state).  Any L: the tail is padded with
-    masked tokens.  With ``M[i, j] = b_i exp(G_i - G_j) k_i . k_j`` below
-    the diagonal (``G`` the running sum of ``g`` in the chunk) and ``T =
-    (I + M)^-1``:
+def _inverse_block(q: int) -> int:
+    """Side of the diagonal blocks a chunk of ``q`` tokens starts from."""
+    b = min(INVERSE_BLOCK, q)
+    if q % b or (q // b) & (q // b - 1):
+        raise ValueError(f"a chunk of {q} is not {b} times a power of two")
+    return b
+
+
+def gdn_heads(qkv: jax.Array, hk: int, hv: int, dk: int):
+    """The convolution's output [..., Hk Dk | Hk Dk | Hv Dv] as the delta
+    rule's operands: q and k [..., Hv, Dk] float32 at unit length a KEY
+    head (q scaled ``Dk^-1/2``), value head h reading key head ``h // (Hv /
+    Hk)``, and v [..., Hv, Dv] as it is."""
+    lead = qkv.shape[:-1]
+
+    def unit(t):
+        t = t.astype(jnp.float32).reshape(*lead, hk, dk)
+        t = t * jax.lax.rsqrt(jnp.sum(t * t, axis=-1, keepdims=True) + 1e-6)
+        return jnp.repeat(t, hv // hk, axis=-2)
+
+    return (unit(qkv[..., :hk * dk]) * dk ** -0.5,
+            unit(qkv[..., hk * dk:2 * hk * dk]),
+            qkv[..., 2 * hk * dk:].reshape(*lead, hv, -1))
+
+
+def gdn_scan(qkv: jax.Array, g: jax.Array, beta: jax.Array, s0: jax.Array,
+             mask: jax.Array, *, chunk: int = GDN_CHUNK, kernel: bool = False,
+             interpret: bool = False):
+    """``qkv`` [B, L, Hk Dk | Hk Dk | Hv Dv] — q, k and v side by side as
+    ``conv_scan`` leaves them, q and k not yet normalised (``gdn_heads``) —,
+    ``g`` [B, L, Hv] (log decay, <= 0), ``beta`` [B, L, Hv], ``s0``
+    [B, Hv, Dv, Dk] float32, ``mask`` [B, L] (1 on a PREFIX of real tokens)
+    -> (o [B, L, Hv, Dv] float32, final state).  Any L: the tail is padded
+    with masked tokens.  With ``M[i, j] = b_i exp(G_i - G_j) k_i . k_j``
+    below the diagonal (``G`` the running sum of ``g`` in the chunk) and
+    ``T = (I + M)^-1``:
 
         U = T (b V) - T (b e^G K) S_0^T            the chunk's u_t, [Q, Dv]
         O = (e^G Q) S_0^T + tril(Q K^T e^(G_i - G_j)) U
         S = e^(G_Q) S_0 + U^T (e^(G_Q - G) K)
 
-    ``T`` by ``_unit_lower_inverse``; ``chunk`` is ``INVERSE_BLOCK`` times a
-    power of two, or at most ``INVERSE_BLOCK``."""
+    ``chunk`` is ``INVERSE_BLOCK`` times a power of two, or at most
+    ``INVERSE_BLOCK``.  With ``kernel`` (and widths the chip's tiles
+    divide) the fused chunk kernel at the bottom of this file, else — the
+    tests' reference and the path without kernels — ``jax.numpy``."""
     f32 = jnp.float32
-    bsz, length, h, dk = q.shape
-    pad = -length % chunk
+    length = qkv.shape[1]
+    hv, dv, dk = s0.shape[1:]
+    hk = (qkv.shape[-1] - hv * dv) // (2 * dk)
+    fused = kernel and _gdn_kernel_fits(hk, hv, dk, dv, chunk, interpret)
+    pad = -length % (chunk * GDN_STEP_CHUNKS if fused else chunk)
     if pad:
-        q, k, v, g, beta, mask = (
+        qkv, g, beta, mask = (
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
-            for t in (q, k, v, g, beta, mask))
-    nc = (length + pad) // chunk
+            for t in (qkv, g, beta, mask))
     live = (mask != 0)[..., None]
     g = jnp.where(live, g.astype(f32), 0.0)
     beta = jnp.where(live, beta.astype(f32), 0.0)
+    if fused:
+        o, s = _gdn_kernel_call(qkv, g, beta, s0, mask, chunk, interpret)
+    else:
+        o, s = _gdn_scan_xla(*gdn_heads(qkv, hk, hv, dk), g, beta, s0, chunk)
+    return o[:, :length], s
+
+
+def _gdn_scan_xla(q, k, v, g, beta, s0, chunk: int):
+    """The chunked delta rule in ``jax.numpy``: q, k [B, L, Hv, Dk] float32
+    (``gdn_heads``), v [B, L, Hv, Dv], ``g`` / ``beta`` [B, L, Hv] float32
+    and masked, L a multiple of ``chunk`` -> (o [B, L, Hv, Dv], final
+    state); ``T`` by ``_unit_lower_inverse``."""
+    f32 = jnp.float32
+    bsz, length, h, dk = q.shape
+    nc = length // chunk
 
     def chunks(t):  # [B, L, H, ...] -> [B, nc, H, Q, ...]
         t = t.astype(f32).reshape(bsz, nc, chunk, *t.shape[2:])
@@ -467,8 +522,7 @@ def gdn_scan(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
         carry, s0.astype(f32),
         tuple(jnp.moveaxis(x, 1, 0) for x in (w, kc, qk, q_in, k_end, whole)))
     # [nc, B, H, Q, Dv] -> [B, L, H, Dv]
-    o = jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(bsz, nc * chunk, h, -1)
-    return o[:, :length], s_last
+    return jnp.transpose(o, (1, 0, 3, 2, 4)).reshape(bsz, length, h, -1), s_last
 
 
 def gdn_step(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
@@ -484,3 +538,277 @@ def gdn_step(q: jax.Array, k: jax.Array, v: jax.Array, g: jax.Array,
     new = kept + u[..., None] * k[:, :, None, :]
     new = jnp.where(live[:, None, None, None], new, s)
     return jnp.sum(new * q[:, :, None, :], axis=-1), new
+
+
+# ---------------------------------------------------------------------------
+# the fused chunk kernel of the delta rule
+
+#: Chunks and key heads a grid step of the kernel: ``GDN_STEP_HEADS`` key
+#: heads' value heads over ``GDN_STEP_CHUNKS * chunk`` tokens.  A chunk of a
+#: key head is one long chain of dependent steps (the elimination, the
+#: merges, then U, O and the state, which the next chunk waits for), and
+#: the compiler keeps a chain in program order: the kernel walks it stage
+#: by stage over the step's (key head, chunk) pairs, so four independent
+#: chains stand side by side and fill each other's latencies (for a v5e a
+#: step of one key head and four chunks in chunk order held 3500 bundles a
+#: chunk, an MXU operation in 58 % of them; this form 1970 and 88 % —
+#: docs/kernel_tuning.md).
+GDN_STEP_CHUNKS, GDN_STEP_HEADS = 2, 2
+
+
+def _gdn_step_heads(hk: int, hv: int, dk: int, dv: int) -> int:
+    """Key heads a grid step takes: as many of ``GDN_STEP_HEADS`` as divide
+    ``hk`` with their value heads' v starting on a block of the
+    convolution's output; 0 where not even one does."""
+    for p in range(GDN_STEP_HEADS, 0, -1):
+        if not (hv % hk or hk % p or (2 * hk * dk) % (p * hv // hk * dv)):
+            return p
+    return 0
+
+
+def _gdn_kernel_fits(hk: int, hv: int, dk: int, dv: int, chunk: int,
+                     interpret: bool) -> bool:
+    """Whether the kernel's blocks are whole tiles of the chip: q, k and a
+    key head's value heads' v are read ``dk`` and ``r dv`` lanes at a time
+    from the convolution's output, a chunk's ``[Q, Q]`` matrices stand a
+    value head beside the other across the lanes, the diagonal blocks of
+    the inverse are whole sublane tiles.  The interpreter takes any widths
+    whose blocks start on a block."""
+    if not _gdn_step_heads(hk, hv, dk, dv):
+        return False
+    if _inverse_block(chunk) % 8 and not interpret:
+        return False
+    return interpret or not (dk % LANES or dv % LANES or (hv // hk * chunk) % LANES)
+
+
+def _gdn_kernel(real_ref, q_ref, k_ref, v_ref, gate_ref, s0_ref, o_ref, s_ref,
+                *, p: int, r: int, chunk: int):
+    """``GDN_STEP_CHUNKS`` chunks of one row's ``p`` key heads and their
+    ``r`` value heads each.  ``q_ref`` / ``k_ref`` [T, p Dk] and ``v_ref``
+    [T, p r Dv] as the convolution left them, ``gate_ref`` [p, 2 n (+
+    padding), r Q]: row c the running sum of ``g`` over chunk c and row
+    n + c its ``beta``, a value head beside the other across the lanes;
+    ``s_ref`` (the output block, resident over the row's steps) the carried
+    states [p, r Dv, Dk].
+
+    A chunk's ``[Q, Q]`` matrices are built ONCE for a key head's ``r``
+    value heads, side by side across the lanes ([Q, r Q]: ``K K^T`` and
+    ``Q K^T`` are the key head's, the decays a value head's), and enter the
+    matmuls block-diagonal ([r Q, r Q]) against operands that stack the
+    heads down the sublanes, so every matmul is whole tiles.  ``(I +
+    M)^-1``: the diagonal blocks of ``INVERSE_BLOCK`` rows by elimination a
+    column at a time — ``I + M`` is the product of ``I + m_c e_c^T`` over
+    its columns, so its inverse applies ``X <- X - m_c X[c]`` for c = 0, 1,
+    ...: an exact substitution, a rank-one step on the vector unit — with
+    every block of every head PACKED side by side across the lanes
+    ([base, r Q]: column c of each block spread over its block's lanes is
+    one lane gather), then pairs of blocks merged as ``_unit_lower_inverse``
+    merges them, ``X <- X - X C X``.  A step none of whose tokens is real
+    does no matmul; a masked chunk inside a live step folds nothing
+    (``beta = 0``, ``g = 0``: ``U = 0``, the state times one) and writes
+    zeros."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    n = q_ref.shape[0] // chunk
+    dk, dv, w = q_ref.shape[1] // p, v_ref.shape[1] // (p * r), r * chunk
+    base = _inverse_block(chunk)
+    bi, z = pl.program_id(0), pl.program_id(2)
+    items = [(h, c) for c in range(n) for h in range(p)]  # (key head, chunk)
+
+    def dot(lhs, rhs, contract=(1, 0)):
+        return jax.lax.dot_general(
+            lhs, rhs, ((contract[:1], contract[1:]), ((), ())),
+            precision=jax.lax.Precision.HIGHEST, preferred_element_type=f32)
+
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(jnp.int32, shape, axis)
+
+    def every(stage):  # one stage of the walk, over the step's chains
+        return {i: stage(*i) for i in items}
+
+    def heads_down(per_head):  # r arrays [Q, ...] -> [r Q, ...]
+        return jnp.concatenate([per_head(j) for j in range(r)], axis=0)
+
+    def live_step():
+        # Every mask once a step, and ``lax.div`` / ``lax.rem`` on the iotas
+        # (non-negative: ``//`` and ``%`` would lower a sign fix-up each):
+        # what a grid step's program holds is lowered anew for every
+        # executable of a boot (docs/kernel_tuning.md).
+        def div(x, by):
+            return jax.lax.div(x, jnp.int32(by))
+
+        lane = iota((1, w), 1)
+        lane_tok = jax.lax.rem(lane, jnp.int32(chunk))
+        tok = iota((chunk, 1), 0)
+        upto, below = tok >= lane_tok, tok > lane_tok
+        in_head = [div(lane, chunk) == j for j in range(r)]
+        in_block = [div(lane_tok, base) == b for b in range(chunk // base)]
+        in_state = [div(iota((r * dv, 1), 0), dv) == j for j in range(r)]
+        eye = jnp.where(iota((base, 1), 0) == jax.lax.rem(lane, jnp.int32(base)), 1.0, 0.0)
+        first_lane = jnp.broadcast_to(div(lane, base) * base, (base, w))
+        at = [slice(c * chunk, (c + 1) * chunk) for c in range(n)]
+        gates = [gate_ref[h] for h in range(p)]
+        gates_t = [g.T for g in gates]  # [r Q, rows]: a head's tokens down
+
+        def pick(per_head, among):  # each position its own head's value
+            out = per_head(r - 1)
+            for j in range(r - 2, -1, -1):
+                out = jnp.where(among[j], per_head(j), out)
+            return out
+
+        def block_diagonal(x):  # [Q, r Q], a head beside the other -> [r Q, r Q]
+            return heads_down(lambda j: jnp.where(in_head[j], x, 0.0))
+
+        def column(h, j, rows=slice(None)):  # of a key head's gates: [rows, 1]
+            return gates_t[h][rows, j:j + 1]
+
+        def last(h, c, j):  # the running sum at the end of chunk c, head j
+            return column(h, c, slice((j + 1) * chunk - 1, (j + 1) * chunk))
+
+        def beside(h, j):  # a gate's column a head, each across its head's lanes
+            return pick(lambda i: column(h, j, slice(i * chunk, (i + 1) * chunk)), in_head)
+
+        def unit(ref, h, c):
+            x = ref[at[c], h * dk:(h + 1) * dk].astype(f32)
+            return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
+
+        # what does not read the state: K K^T, Q K^T, the decays, the inverse
+        km = every(lambda h, c: unit(k_ref, h, c))
+        kq_down = every(lambda h, c: jnp.concatenate(
+            [km[h, c], unit(q_ref, h, c) * dk ** -0.5], axis=0))
+        k_down = every(lambda h, c: heads_down(lambda j: km[h, c]))
+        kq = every(lambda h, c: dot(kq_down[h, c], k_down[h, c], (1, 1)))  # [2 Q, r Q]
+        decay = every(lambda h, c: jnp.exp(jnp.where(
+            upto, beside(h, c) - gates[h][c:c + 1, :], -jnp.inf)))
+        m = every(lambda h, c: jnp.where(
+            below, kq[h, c][:chunk] * decay[h, c], 0.0) * beside(h, n + c))
+        within = every(lambda h, c: block_diagonal(kq[h, c][chunk:] * decay[h, c]))
+
+        def packed(x):  # the diagonal blocks [base, base] of [Q, r Q], each at its lanes
+            out = x[chunk - base:]
+            for b in range(chunk // base - 2, -1, -1):
+                out = jnp.where(in_block[b], x[b * base:(b + 1) * base], out)
+            return out
+
+        mp = every(lambda h, c: packed(m[h, c]))
+        x = every(lambda h, c: eye)
+        for j in range(base - 1):
+            lanes_j = first_lane + j  # column j of each block, over its lanes
+            x = every(lambda h, c: x[h, c] - jnp.take_along_axis(
+                mp[h, c], lanes_j, axis=1, mode="promise_in_bounds"
+            ) * x[h, c][j:j + 1, :])
+        x = every(lambda h, c: jnp.concatenate(
+            [jnp.where(blk, x[h, c], 0.0) for blk in in_block], axis=0))
+        size = base
+        while size < chunk:  # the lower-left block of each pair of blocks
+            lower = ((div(tok, 2 * size) == div(lane_tok, 2 * size))
+                     & (div(tok, size) > div(lane_tok, size)))
+            xc = every(lambda h, c: dot(
+                x[h, c], block_diagonal(jnp.where(lower, m[h, c], 0.0))))
+            x = every(lambda h, c: x[h, c] - dot(xc[h, c], block_diagonal(x[h, c])))
+            size *= 2
+        x = every(lambda h, c: block_diagonal(x[h, c]))
+        # what does: U, O and the state a value head, the heads down the rows
+        s = [s_ref[h] for h in range(p)]
+        for c in range(n):
+            live = (real_ref[bi, z * n + c] > 0).astype(f32)
+            e_in = [jnp.exp(column(h, c)) for h in range(p)]
+            from_s = {(h, j): dot(kq_down[h, c], s[h][j * dv:(j + 1) * dv, :], (1, 1))
+                      for h in range(p) for j in range(r)}
+            u = []
+            for h in range(p):
+                v = heads_down(lambda j: v_ref[
+                    at[c], (h * r + j) * dv:(h * r + j + 1) * dv].astype(f32))
+                u.append(dot(x[h, c], column(h, n + c) * (v - e_in[h] * heads_down(
+                    lambda j: from_s[h, j][:chunk]))))
+            for h in range(p):
+                o = live * (e_in[h] * heads_down(
+                    lambda j: from_s[h, j][chunk:]) + dot(within[h, c], u[h]))
+                for j in range(r):
+                    o_ref[at[c], (h * r + j) * dv:(h * r + j + 1) * dv] = (
+                        o[j * chunk:(j + 1) * chunk])
+            for h in range(p):
+                g_end = heads_down(lambda j: jnp.broadcast_to(last(h, c, j), (chunk, 1)))
+                u_end = (u[h] * jnp.exp(g_end - column(h, c))).T  # [Dv, r Q]
+                whole = pick(lambda j: jnp.exp(last(h, c, j)), in_state)
+                s[h] = s[h] * whole + dot(heads_down(
+                    lambda j: jnp.where(in_head[j], u_end, 0.0)), k_down[h, c])
+        for h in range(p):
+            s_ref[h] = s[h]
+
+    @pl.when(z == 0)
+    def _():
+        s_ref[...] = s0_ref[...].astype(f32)
+
+    real = real_ref[bi, z * n]  # of the step's first chunk: the mask is a prefix
+
+    @pl.when(real == 0)  # no token to fold: no matmul, the state as it was
+    def _():
+        o_ref[...] = jnp.zeros(o_ref.shape, f32)
+
+    @pl.when(real > 0)
+    def _():
+        live_step()
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+def _gdn_kernel_call(qkv, g, beta, s0, mask, chunk: int, interpret: bool):
+    """The kernel over ``(B, Hk / p, L / T)``, ``p`` key heads and ``T =
+    GDN_STEP_CHUNKS * chunk`` tokens a step, a row's steps innermost and in
+    order: q, k and v through block index maps from ``qkv`` where it lies,
+    the gates and the running sum of ``g`` (computed here, exact float32) a
+    token a lane, the count of real tokens a chunk as a scalar-prefetch
+    operand.  ``g`` and ``beta`` are float32 and masked, L a multiple of
+    T."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    bsz, length, hv = g.shape
+    dv, dk = s0.shape[2:]
+    hk = (qkv.shape[-1] - hv * dv) // (2 * dk)
+    r, n, p = hv // hk, GDN_STEP_CHUNKS, _gdn_step_heads(hk, hv, dk, dv)
+    t, steps = n * chunk, length // (n * chunk)
+    one = jnp.ones((), f32)
+    rows = -(-2 * n // 8) * 8
+
+    def a_step(x):  # [B, nc, Hv, Q] -> [B, Hk, steps, n, r Q]
+        x = x.reshape(bsz, steps, n, hk, r * chunk)
+        return jnp.transpose(x, (0, 3, 1, 2, 4))
+
+    gates = jnp.concatenate(
+        [a_step(_chunk_sums(g, one, chunk)[1]), a_step(_chunk_sums(beta, one, chunk)[0]),
+         jnp.zeros((bsz, hk, steps, rows - 2 * n, r * chunk), f32)], axis=3)
+    real = jnp.sum((mask != 0).reshape(bsz, steps * n, chunk), axis=-1,
+                   dtype=jnp.int32)
+
+    def spec(block, index):  # index(b, h, z) -> block indices
+        return pl.BlockSpec(block, lambda bi, hi, z, real: index(bi, hi, z))
+
+    state = spec((None, p, r * dv, dk), lambda bi, hi, z: (bi, hi, 0, 0))
+    values = spec((None, t, p * r * dv), lambda bi, hi, z: (bi, z, hi))
+    o, s = pl.pallas_call(
+        functools.partial(_gdn_kernel, p=p, r=r, chunk=chunk),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, hk // p, steps),
+            in_specs=[
+                spec((None, t, p * dk), lambda bi, hi, z: (bi, z, hi)),
+                spec((None, t, p * dk), lambda bi, hi, z: (bi, z, hk // p + hi)),
+                spec((None, t, p * r * dv),
+                     lambda bi, hi, z: (bi, z, 2 * hk * dk // (p * r * dv) + hi)),
+                spec((None, p, None, rows, r * chunk),
+                     lambda bi, hi, z: (bi, hi, z, 0, 0)),
+                state,
+            ],
+            out_specs=[values, state],
+        ),
+        out_shape=[jax.ShapeDtypeStruct((bsz, length, hv * dv), f32),
+                   jax.ShapeDtypeStruct((bsz, hk, r * dv, dk), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+        name="gdn_scan",
+    )(real, qkv, qkv, qkv, gates, s0.reshape(bsz, hk, r * dv, dk))
+    return o.reshape(bsz, length, hv, dv), s.reshape(bsz, hv, dv, dk)

@@ -631,6 +631,44 @@ def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows):
     assert scores_in_hbm(text(False), decay)
 
 
+@pytest.mark.parametrize("rows", [3, 1])
+def test_the_delta_rules_chunk_matrices_stay_on_the_chip(chip, rows):
+    """The chunked scan of a Gated-DeltaNet layer at GigaChat3.5's widths (32
+    key / 64 value heads of 128, chunks of 64) over a dispatch of ``rows``
+    windows of 1024 tokens, q, k and v read from the convolution's
+    ``[rows, 1024, 16384]`` bfloat16 where they lie: with ``kernel`` ONE
+    Mosaic kernel named ``gdn_scan`` and no float32 array of ``rows * 1024 *
+    64 * 64`` elements or more apart from ``o`` and the states (the ``[Q,
+    Q]`` chunk matrices a value head — 50 MB each at three windows — and
+    q / k / v repeated a value head, 100 MB each, which the ``jax.numpy``
+    form writes and reads back: the reader sees them there)."""
+    from mlmicroservicetemplate_tpu.ops import ssm
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
+
+    hk, hv, d, q, length = 32, 64, 128, ssm.GDN_CHUNK, 1024
+    f32 = jnp.float32
+    args = (chip((rows, length, (2 * hk + hv) * d), jnp.bfloat16),
+            chip((rows, length, hv), f32), chip((rows, length, hv), f32),
+            chip((rows, hv, d, d), f32), chip((rows, length), jnp.int32))
+
+    def text(kernel):
+        return _compiled_text(
+            chip, ("gdn_scan", rows, kernel),
+            lambda *a: ssm.gdn_scan(*a, kernel=kernel), *args)
+
+    chunk_matrices = rows * length * hv * q
+    fused = text(True)
+    calls = [ln for ln in fused.splitlines()
+             if "tpu_custom_call" in ln and "gdn_scan" in ln]
+    assert len(calls) == 1
+    # o [rows, 1024, 64 x 128] and the states [rows, 64, 128, 128] are the
+    # kernel's results and its one state operand: nothing else is that large
+    large = [ln for ln in scores_in_hbm(fused, chunk_matrices)
+             if "gdn_scan" not in ln and "parameter(" not in ln]
+    assert [ln for ln in large if "bitcast" not in ln and "tuple" not in ln] == []
+    assert len(scores_in_hbm(text(False), chunk_matrices)) > 8
+
+
 def test_the_toy_windows_scan_holds_no_decay_matrix_with_kernels_on():
     """The prompt-window executable of the toy Nemotron configuration
     (``tests/test_nemotron_serving.py``'s: 8 heads of 8 in 2 groups, state

@@ -81,29 +81,38 @@ def _close(got, want):
 # (i) the recurrence: the chunked UT form = the delta rule a token at a time
 
 
-def _rule_inputs(length, b=2, h=4, dk=16, dv=8, seed=0):
+def _rule_inputs(length, b=2, hk=2, r=2, dk=16, dv=8, seed=0):
+    """The scan's operands as the convolution leaves them: q and k of
+    ``hk`` key heads NOT yet normalised, v of ``hk * r`` value heads, side
+    by side; the gates a value head."""
     ks = jax.random.split(jax.random.PRNGKey(seed), 6)
-
-    def unit(x):
-        return x / jnp.sqrt(jnp.sum(x * x, axis=-1, keepdims=True) + 1e-6)
-
+    h = hk * r
     return dict(
-        q=unit(jax.random.normal(ks[0], (b, length, h, dk))) * dk ** -0.5,
-        k=unit(jax.random.normal(ks[1], (b, length, h, dk))),
-        v=jax.random.normal(ks[2], (b, length, h, dv)),
+        qkv=jnp.concatenate([
+            jax.random.normal(ks[0], (b, length, hk * dk)),
+            jax.random.normal(ks[1], (b, length, hk * dk)),
+            jax.random.normal(ks[2], (b, length, h * dv))], axis=-1),
         g=-jnp.exp(jax.random.normal(ks[3], (b, length, h)) - 1.0),
         beta=jax.nn.sigmoid(jax.random.normal(ks[4], (b, length, h))),
         s0=jax.random.normal(ks[5], (b, h, dv, dk)),  # a NON-ZERO initial state
     )
 
 
-_SEQ = ("q", "k", "v", "g", "beta")
+_SEQ = ("qkv", "g", "beta")
+KERNEL = pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def _scan(i, mask, s0=None, chunk=8):
+@functools.partial(jax.jit, static_argnames=("chunk", "kernel"))
+def _scan(i, mask, s0=None, chunk=8, kernel=False):
     return ssm.gdn_scan(*(i[n] for n in _SEQ), i["s0"] if s0 is None else s0,
-                        mask, chunk=chunk)
+                        mask, chunk=chunk, kernel=kernel, interpret=True)
+
+
+def _heads(i):
+    """``i`` with q, k (normalised, a value head each) and v for the step."""
+    hv, dv, dk = i["s0"].shape[1:]
+    hk = (i["qkv"].shape[-1] - hv * dv) // (2 * dk)
+    return (*ssm.gdn_heads(i["qkv"], hk, hv, dk), i["g"], i["beta"])
 
 
 @functools.partial(jax.jit, static_argnames=("bits",))
@@ -114,7 +123,7 @@ def _token_by_token(i, mask, bits=23):
         return jax.lax.reduce_precision(s, 8, bits), o
 
     s, o = jax.lax.scan(step, i["s0"], tuple(
-        jnp.moveaxis(x, 1, 0) for x in (*(i[n] for n in _SEQ), mask)))
+        jnp.moveaxis(x, 1, 0) for x in (*_heads(i), mask)))
     return jnp.moveaxis(o, 0, 1), s
 
 
@@ -123,14 +132,15 @@ def _mask(lens, length):
                        jnp.int32)
 
 
+@KERNEL
 @pytest.mark.parametrize("length,lens", [
     (1, (1, 0)), (9, (9, 8)), (37, (37, 20)), (150, (150, 77))])
-def test_the_chunked_form_is_the_delta_rule(length, lens):
+def test_the_chunked_form_is_the_delta_rule(length, lens, kernel):
     """Every length around the chunk's edge, one row shorter than the
     other: outputs on the real tokens and the final state agree; a row's
     padded tail moves no state."""
     i, mask = _rule_inputs(length), _mask(lens, length)
-    o, s = _scan(i, mask)
+    o, s = _scan(i, mask, kernel=kernel)
     o1, s1 = _token_by_token(i, mask)
     assert _close(o * mask[..., None, None], o1 * mask[..., None, None]) < TOL
     assert _close(s, s1) < TOL
@@ -138,45 +148,105 @@ def test_the_chunked_form_is_the_delta_rule(length, lens):
         assert _close(s[1], i["s0"][1]) == 0.0
 
 
+@KERNEL
 @pytest.mark.parametrize("cut", [8, 13])
-def test_two_windows_in_sequence_are_one_scan_of_both(cut):
+def test_two_windows_in_sequence_are_one_scan_of_both(cut, kernel):
     """A prompt's second window continues the state its first one left —
     on a chunk's edge and off it — and a decode step continues a window."""
+    scan = functools.partial(_scan, kernel=kernel)
     i, mask = _rule_inputs(30, seed=2), _mask((30, 21), 30)
-    _, whole = _scan(i, mask)
+    _, whole = scan(i, mask)
     first = {n: (v[:, :cut] if n in _SEQ else v) for n, v in i.items()}
     rest = {n: (v[:, cut:] if n in _SEQ else v) for n, v in i.items()}
-    _, s_a = _scan(first, mask[:, :cut])
-    o_b, s_b = _scan(rest, mask[:, cut:], s0=s_a)
+    _, s_a = scan(first, mask[:, :cut])
+    o_b, s_b = scan(rest, mask[:, cut:], s0=s_a)
     assert _close(s_b, whole) < TOL
-    o_whole, _ = _scan(i, mask)
+    o_whole, _ = scan(i, mask)
     assert _close(o_b[0], o_whole[0, cut:]) < TOL
     # one more token by the step = a scan one token longer
     j = _rule_inputs(31, seed=2)
     o_step, s_step = ssm.gdn_step(
-        *(j[n][:, 30] for n in _SEQ), _scan(
+        *(x[:, 30] for x in _heads(j)), scan(
             {n: (v[:, :30] if n in _SEQ else v) for n, v in j.items()},
             _mask((30, 30), 30))[1], jnp.asarray([True, False]))
-    o_long, s_long = _scan(j, _mask((31, 30), 31))
+    o_long, s_long = scan(j, _mask((31, 30), 31))
     assert _close(s_step, s_long) < TOL and _close(o_step[0], o_long[0, 30]) < TOL
 
 
+@KERNEL
 @pytest.mark.parametrize("corr", [0.5, 0.99])
-def test_correlated_keys_do_not_blow_up_the_chunks_inverse(corr):
+def test_correlated_keys_do_not_blow_up_the_chunks_inverse(corr, kernel):
     """Keys that share a direction (what a short convolution leaves) with
     beta near 1 and a chunk of 64: the triangular inverse's entries stay
     small while ``M``'s powers do not — a Neumann series of them overflowed
     float32 here (1e12 at 0.5, NaN at 0.9; my chip run, PR 47), the blocked
-    substitution reads 1e-6."""
-    i = _rule_inputs(128, b=1, h=2, dk=32, dv=16, seed=5)
-    shared = jax.random.normal(jax.random.PRNGKey(9), (1, 1, 2, 32))
-    k = corr * shared + (1 - corr) * i["k"]
-    i["k"] = k / jnp.sqrt(jnp.sum(k * k, axis=-1, keepdims=True))
+    substitution reads 1e-6 — in ``jax.numpy`` and in the kernel, whose
+    diagonal blocks are eliminated a column at a time."""
+    i = _rule_inputs(128, b=1, hk=1, r=2, dk=32, dv=16, seed=5)
+    shared = jax.random.normal(jax.random.PRNGKey(9), (1, 1, 32))
+    k = i["qkv"][..., 32:64]
+    k = k / jnp.sqrt(jnp.sum(k * k, axis=-1, keepdims=True))
+    i["qkv"] = i["qkv"].at[..., 32:64].set(corr * shared + (1 - corr) * k)
     i["beta"], i["g"] = jnp.full_like(i["beta"], 0.95), jnp.full_like(i["g"], -0.01)
     mask = _mask((128,), 128)
-    _, s = _scan(i, mask, chunk=64)
+    _, s = _scan(i, mask, chunk=64, kernel=kernel)
     want = _token_by_token(i, mask)[1]
     assert _close(s, want) < TOL * float(jnp.max(jnp.abs(want)) + 1.0)
+
+
+@pytest.mark.parametrize("b,lens", [(1, (21,)), (3, (70, 33, 0)), (3, (96, 5, 64))])
+def test_the_kernel_skips_the_chunks_past_a_rows_prefix(b, lens):
+    """Rows whose real prefix ends inside a chunk, on a chunk's edge, inside
+    the first chunk, or is empty, in a dispatch of one row and of three, over
+    three steps of the kernel's grid: a wholly masked chunk does no matmul
+    (``o`` is zero there, the state untouched — bit for bit where the row
+    has no token at all), and what is real agrees with the ``jax.numpy``
+    form and with the rule a token at a time."""
+    i, mask = _rule_inputs(96, b=b, seed=3), _mask(lens, 96)
+    o, s = _scan(i, mask, kernel=True)
+    o_x, s_x = _scan(i, mask)
+    o_1, s_1 = _token_by_token(i, mask)
+    live = mask[..., None, None]
+    assert _close(o * live, o_x * live) < TOL and _close(s, s_x) < TOL
+    assert _close(o * live, o_1 * live) < TOL and _close(s, s_1) < TOL
+    for row, n in enumerate(lens):
+        past = -(-n // 8) * 8  # the first wholly masked chunk
+        assert float(jnp.max(jnp.abs(o[row, past:]), initial=0.0)) == 0.0
+        if n == 0:
+            assert _close(s[row], i["s0"][row]) == 0.0
+
+
+@pytest.mark.parametrize("hk,r,dk,dv,chunk,fits", [
+    (2, 2, 16, 8, 8, True), (1, 1, 8, 8, 16, True), (4, 1, 8, 8, 32, True),
+    (2, 2, 8, 24, 8, False), (3, 1, 8, 16, 8, True)])
+def test_widths_the_blocks_do_not_divide_fall_back_to_jax_numpy(
+        hk, r, dk, dv, chunk, fits):
+    """One value head a key head or several, chunks under, at and over the
+    inverse's block: the kernel's answer is the ``jax.numpy`` form's; where
+    a key head's values do not start on a block of the convolution's output
+    (``2 Hk Dk`` not a multiple of ``r Dv``) ``kernel=True`` IS that form."""
+    assert ssm._gdn_kernel_fits(hk, hk * r, dk, dv, chunk, True) is fits
+    i = _rule_inputs(70, b=2, hk=hk, r=r, dk=dk, dv=dv, seed=6)
+    mask = _mask((70, 41), 70)
+    o, s = _scan(i, mask, chunk=chunk, kernel=True)
+    o_x, s_x = _scan(i, mask, chunk=chunk)
+    live = mask[..., None, None]
+    assert _close(o * live, o_x * live) < TOL and _close(s, s_x) < TOL
+    if not fits:
+        assert _close(o, o_x) == 0.0 and _close(s, s_x) == 0.0
+
+
+def test_the_chips_tiles_decide_whether_the_kernel_fits():
+    """Compiled, the kernel takes whole tiles only: the published widths
+    (32 key / 64 value heads of 128, chunks of 64) fit, narrower heads or a
+    chunk whose two heads do not fill the lanes do not."""
+    assert ssm._gdn_kernel_fits(32, 64, 128, 128, 64, False)
+    assert not ssm._gdn_kernel_fits(32, 64, 64, 128, 64, False)
+    assert not ssm._gdn_kernel_fits(32, 64, 128, 64, 64, False)
+    assert not ssm._gdn_kernel_fits(32, 32, 128, 128, 64, False)
+    assert not ssm._gdn_kernel_fits(32, 48, 128, 128, 64, False)
+    with pytest.raises(ValueError, match="power of two"):
+        ssm._gdn_kernel_fits(32, 64, 128, 128, 48, False)
 
 
 def test_a_bf16_state_drifts_where_a_float32_one_does_not():

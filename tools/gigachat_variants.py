@@ -69,15 +69,15 @@ def _beta_one() -> dict:
 
     from mlmicroservicetemplate_tpu.ops import ssm
 
-    def whole(fn):
+    def whole(fn, at):  # ``beta`` is argument ``at`` of both
         @functools.wraps(fn)
-        def run(q, k, v, g, beta, *args, **kw):
-            return fn(q, k, v, g, jnp.ones_like(beta), *args, **kw)
+        def run(*args, **kw):
+            return fn(*args[:at], jnp.ones_like(args[at]), *args[at + 1:], **kw)
 
         return run
 
-    return {"ops.ssm.gdn_scan": whole(ssm.gdn_scan),
-            "ops.ssm.gdn_step": whole(ssm.gdn_step)}
+    return {"ops.ssm.gdn_scan": whole(ssm.gdn_scan, 2),
+            "ops.ssm.gdn_step": whole(ssm.gdn_step, 4)}
 
 
 def _no_clamp() -> dict:
