@@ -97,6 +97,9 @@ async def request_id_middleware(request: web.Request, handler):
             status=500, headers={"X-Request-Id": rid},
         )
     finally:
+        # Every exit of a request that was counted towards the decode
+        # loop (400, shed, cancelled, a crash) stops being waited for.
+        _settle_arrival(request)
         if tr is not None:
             tr.add(
                 "request", cat="http", rid=rid, t0=t0,
@@ -105,6 +108,21 @@ async def request_id_middleware(request: web.Request, handler):
     if not resp.prepared:
         resp.headers.setdefault("X-Request-Id", rid)
     return resp
+
+
+def _expect_stream(request: web.Request) -> None:
+    """A streaming request's body is parsed (not earlier: a slow upload
+    is never waited for): from here until its stream is queued, or the
+    request ends without one, an idle decode loop counts it among what
+    is still coming (``Batcher.expect_stream``) and admits the burst it
+    belongs to as one wave."""
+    request["arrival"] = request.app[K_BATCHER].expect_stream()
+
+
+def _settle_arrival(request: web.Request) -> None:
+    arrival = request.get("arrival")
+    if arrival is not None:
+        arrival.settle()
 
 
 def build_app(cfg, bundle: ModelBundle, engine, batcher: Batcher) -> web.Application:
@@ -533,6 +551,8 @@ async def handle_predict(request: web.Request) -> web.StreamResponse:
         metrics.REQUESTS.labels(bundle.name, "400").inc()
         raise
     stream = item.stream or request.query.get("stream", "") in ("1", "true")
+    if stream and bundle.kind == KIND_SEQ2SEQ:
+        _expect_stream(request)
 
     loop = asyncio.get_running_loop()
     try:
@@ -727,6 +747,8 @@ async def _open_stream(request: web.Request, feats: dict, item: RawItem,
         resp = _shed_response(e)
         metrics.REQUESTS.labels(bundle.name, str(resp.status)).inc()
         raise resp
+    finally:
+        _settle_arrival(request)  # queued, or shed: no longer on its way
     events = _delta_stream(bundle, stream_iter, item)
     try:
         first = await events.__anext__()
@@ -970,6 +992,8 @@ async def _openai_prologue(request: web.Request, to_prompt):
     except web.HTTPBadRequest:
         metrics.REQUESTS.labels(bundle.name, "400").inc()
         raise
+    if item.stream:
+        _expect_stream(request)
     loop = asyncio.get_running_loop()
     try:
         feats = await loop.run_in_executor(None, bundle.preprocess, item)
@@ -1471,6 +1495,17 @@ async def handle_status(request: web.Request) -> web.Response:
             "prep_staged": getattr(cdl, "prep_staged", 0),
             "prep_hits": getattr(cdl, "prep_hits", 0),
             "prep_misses": getattr(cdl, "prep_misses", 0),
+            # Idle admission (the loop's _collect_burst): the requests
+            # the server has read and not yet queued, the waits an idle
+            # loop made for such, the rows those waits added to their
+            # waves, the waits that ended on their cap, seconds waited.
+            "idle_admit": {
+                "expected": cdl.queue.expected(),
+                "waits": cdl.idle_waits,
+                "rows": cdl.idle_wait_rows,
+                "capped": cdl.idle_waits_capped,
+                "wait_s": round(cdl.idle_wait_s, 6),
+            },
             # Per-site host-sync counts.
             "dispatch_counts": {
                 site: a["count"]

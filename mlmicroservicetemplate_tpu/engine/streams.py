@@ -707,11 +707,19 @@ class ContinuousDecodeLoop:
         self._stop = threading.Event()
         self._thread: threading.Thread | None = None
         self._thread_lock = threading.Lock()
-        import os
-
-        # Idle-burst admission grace (ms): how long an idle loop waits
-        # for the rest of a concurrent burst before admitting the wave.
-        self._admit_grace_s = float(os.environ.get("ADMIT_GRACE_MS", "8")) / 1e3
+        # Idle admission (``_collect_burst``): seconds the last wave of
+        # each rung took from its dispatch to its rows' first chunks —
+        # the longest the loop holds a wave of that rung for requests
+        # the server is still reading.  Warm-up seeds the lone rung with
+        # a chunk's measured round trip; nothing measured = no wait.
+        self._wave_seconds: dict[int, float] = {}
+        # The widest gap between two arrivals of the last idle wave (0.0
+        # where it was a lone row): what the next one's first row borrows.
+        self._idle_gap_s = 0.0
+        self.idle_waits = 0         # idle admissions that waited at all
+        self.idle_wait_rows = 0     # rows those waits added to their waves
+        self.idle_waits_capped = 0  # waits that ended on the cap
+        self.idle_wait_s = 0.0
         # Admissions dispatched but not yet fetched/inserted; the loop's
         # failure handler must terminate these consumers too.
         self._pending_admissions: list = []
@@ -1140,29 +1148,12 @@ class ContinuousDecodeLoop:
                         break
                     self._reserve(st)
                     wave.append(st)
-                # Cold-burst debounce: a concurrent burst's streams land
-                # on the queue microseconds apart, but the loop thread
-                # can outrace the submitting thread and admit a partial
-                # wave — each straggler then costs its own prefill-
-                # fetch round-trip (measured: one 200 ms 8-stream wave
-                # vs 2-3 separate ~120-240 ms fetches).  With no work
-                # in flight, a few ms of grace collects the burst; at
-                # chunk boundaries the in-flight work already gives
-                # stragglers that window.
+                # With no work in flight a partial wave costs every
+                # straggler a wave of its own: hold this one for the
+                # requests the server is still reading.  At a chunk
+                # boundary the work in flight gives them that window.
                 if wave and not self.active and not self._inflight_chunks:
-                    deadline = time.monotonic() + self._admit_grace_s
-                    with tracing.phase("loop/queue_pop"):
-                        while len(wave) < self.n_slots:
-                            remaining = deadline - time.monotonic()
-                            if remaining <= 0:
-                                break
-                            st = self.queue.pop(
-                                timeout=remaining, fits=self._fits
-                            )
-                            if st is None:
-                                break
-                            self._reserve(st)
-                            wave.append(st)
+                    self._collect_burst(wave)
                 self._class_gauges()
                 # Depth-D pipeline: keep up to chain_depth chunks in
                 # flight — chunk k's ~RTT-long fetch overlaps later
@@ -1178,9 +1169,8 @@ class ContinuousDecodeLoop:
                     with tracing.phase("loop/chunk_dispatch"):
                         self._dispatch_chunk()
                     dispatched = True
-                t_wave = (
-                    time.monotonic() if wave and self.active else None
-                )
+                t_admit = time.monotonic()
+                t_wave = t_admit if wave and self.active else None
                 if wave:
                     # Overlapped admission, AFTER the live chunk's
                     # dispatch: the wave's batched prefill queues
@@ -1195,8 +1185,10 @@ class ContinuousDecodeLoop:
                         self._pending_admissions = self._admit_dispatch(wave)
                 self._pending_wave = []
                 if self._pending_admissions:
+                    rows = self._wave_rows(len(self._pending_admissions))
                     self._admit_complete(self._pending_admissions)
                     self._pending_admissions = []
+                    self._wave_seconds[rows] = time.monotonic() - t_admit
                 self._note_wave_stall(t_wave)
                 # Chunked prefill rides BEHIND the decode dispatch and
                 # the wave admission: live streams' next chunk is
@@ -1320,6 +1312,72 @@ class ContinuousDecodeLoop:
                 self._journal_done(st)
                 st.emit(StreamClosedError("server stopping"))
             self._free_slot(slot)
+
+    def _burst_cap_s(self, k: int) -> float:
+        """The longest an idle loop holds a wave of ``k`` rows for more:
+        what the wave it would run now costs (``_wave_seconds``, the
+        nearest rung seen where this one has not run yet).  Past that
+        a second wave would have served the stragglers as soon."""
+        seen = self._wave_seconds
+        return seen.get(self._wave_rows(k)) or max(seen.values(), default=0.0)
+
+    def _collect_burst(self, wave: list) -> None:
+        """An idle loop's admission wave: keep popping while the wave
+        has room and the queue holds a row or a request the server has
+        read is still on its way to it (``DeadlineQueue.expected``: up
+        where the API has parsed a body, down where the stream was put
+        or the request failed), so a burst lands as ONE wave and not as
+        a lone start with its stragglers behind it.  Where the server
+        reads faster
+        than its clients write, the count touches zero between two
+        arrivals of one burst: the loop then stays for a quiet gap of
+        twice the widest gap between the arrivals it already holds or
+        of the last idle wave, whichever is wider (a burst pauses for
+        longer late than early, and a lone row has no gaps of its own).
+        After a burst the loop so expects another; after a lone request,
+        or on a fresh loop, it expects none, finds nothing announced
+        and goes at once.  Bounded by ``_burst_cap_s``."""
+        name = self.engine.bundle.name
+        t0 = time.monotonic()
+        n0 = len(wave)
+        waited = False
+        ts = sorted(st.t_queued for st in wave)
+        last = ts[-1]
+        own = max((b - a for a, b in zip(ts, ts[1:])), default=0.0)
+        with tracing.phase("loop/queue_pop"):
+            while len(wave) < self.n_slots:
+                st = self.queue.pop_nowait(fits=self._fits)
+                if st is None:
+                    now = time.monotonic()
+                    quiet = max(
+                        0.0, last + 2.0 * max(own, self._idle_gap_s) - now
+                    )
+                    if not quiet and not self.queue.expected():
+                        break
+                    waited = True
+                    left = t0 + self._burst_cap_s(len(wave)) - now
+                    st = self.queue.pop_expected(
+                        left, quiet, fits=self._fits
+                    )
+                    if st is None:
+                        break
+                self._reserve(st)
+                wave.append(st)
+                own = max(own, st.t_queued - last)
+                last = max(last, st.t_queued)
+        self._idle_gap_s = own
+        if not waited:
+            return
+        dt = time.monotonic() - t0
+        capped = self.queue.expected() > 0 and len(wave) < self.n_slots
+        self.idle_waits += 1
+        self.idle_wait_rows += len(wave) - n0
+        self.idle_waits_capped += capped
+        self.idle_wait_s += dt
+        metrics.IDLE_ADMIT_WAIT.labels(name).observe(dt)
+        metrics.IDLE_ADMIT_ROWS.labels(name).inc(len(wave) - n0)
+        if capped:
+            metrics.IDLE_ADMIT_CAPPED.labels(name).inc()
 
     def _record_iteration(self) -> None:
         """One flight-recorder frame per non-idle loop iteration: batch
@@ -5071,7 +5129,7 @@ class ContinuousDecodeLoop:
         sits in the process-level ExecutableCache — so skip the
         warm-dispatch grid entirely.  Build the device state (the one
         real dispatch), adopt the donor's measured chain depth and
-        admit grace instead of re-running the RTT calibration, and let
+        wave times instead of re-running the RTT calibration, and let
         the fleet's probe dispatch be the gate before routing.  On a
         1-core host this is the difference between a spawn that steals
         ~100 s of grid dispatches from the serving core and one that
@@ -5089,7 +5147,7 @@ class ContinuousDecodeLoop:
             if self._state is None:
                 self._build_empty_state()
             self.chain_depth = max(1, int(donor.chain_depth))
-            self._admit_grace_s = donor._admit_grace_s
+            self._wave_seconds = dict(donor._wave_seconds)
             metrics.CHAIN_DEPTH.labels(self.engine.bundle.name).set(
                 self.chain_depth
             )
@@ -5506,12 +5564,13 @@ class ContinuousDecodeLoop:
         metrics.CHAIN_DEPTH.labels(self.engine.bundle.name).set(
             self.chain_depth
         )
-        self._admit_grace_s = min(self._admit_grace_s, rtt / 10.0)
+        # No wave costs less than a chunk's round trip: the idle
+        # admission's cap until the loop has timed a wave of its own.
+        self._wave_seconds.setdefault(self._wave_rungs[0], rtt + compute)
         log.info(
             "continuous loop: chunk compute %.1f ms, dispatch RTT %.1f ms "
-            "-> chain depth %d, admit grace %.1f ms",
+            "-> chain depth %d",
             compute * 1e3, rtt * 1e3, self.chain_depth,
-            self._admit_grace_s * 1e3,
         )
 
     def _tune_chain_depth(self) -> None:
@@ -5552,8 +5611,4 @@ class ContinuousDecodeLoop:
         w5 = wall(5)
         compute = max((w5 - w1) / 4.0, 1e-4)
         rtt = max(w1 - compute, 0.0)
-        # The cold-burst grace is only worth paying when a wasted
-        # admission round-trip dwarfs it: scale it to the measured RTT
-        # so directly-attached chips (~1 ms dispatch) don't tax every
-        # isolated request ~8 ms of TTFT for a burst that never comes.
         self._apply_tuned_depth(rtt, compute)

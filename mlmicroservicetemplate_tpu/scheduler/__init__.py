@@ -19,6 +19,7 @@ from .policy import (  # noqa: F401
     BATCH,
     CLASSES,
     INTERACTIVE,
+    Arrival,
     DeadlineExceededError,
     DeadlineQueue,
 )

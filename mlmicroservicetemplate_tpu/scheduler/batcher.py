@@ -47,6 +47,7 @@ from .admission import AdmissionController
 from .policy import (  # noqa: F401  (QueueFullError re-exported here)
     BATCH,
     INTERACTIVE,
+    Arrival,
     DeadlineExceededError,
     DeadlineQueue,
     QueueFullError,
@@ -518,6 +519,20 @@ class Batcher:
         self._wake.set()
         self._depth_gauges()
         return await fut
+
+    def expect_stream(self) -> Arrival:
+        """The server has read a streaming request and is about to
+        preprocess it: count it on the decode loop's queue until it is
+        put there or fails (``Arrival.settle``), so an idle loop admits
+        the burst it belongs to as one wave.  Under a fleet every
+        replica's loop sees the server's count, not its own share: a
+        loop may wait for a request the router sends elsewhere, never
+        longer than a wave of its own costs."""
+        if self.fleet is not None:
+            return Arrival(rep.cdl.queue for rep in self.fleet.replicas)
+        if self._cdl is not None:
+            return Arrival([self._cdl.queue])
+        return Arrival()
 
     def submit_stream(self, feats: dict) -> AsyncIterator[np.ndarray]:
         """Streaming seq2seq: bridge the engine's blocking chunk

@@ -451,6 +451,31 @@ class ScalingGovernor:
         }
 
 
+class Arrival:
+    """One request the server has read and not yet queued, counted on
+    the decode loops' queues from construction to the first
+    ``settle()``: when its stream has been put, or when the request
+    failed on the way (400, shed, cancelled).  An idle loop's admission
+    waits for what is counted here and for nothing else
+    (``DeadlineQueue.pop_expected``).  Event-loop side only."""
+
+    def __init__(self, queues=()):
+        self._queues = tuple(queues)
+        for q in self._queues:
+            q.expect()
+
+    def settle(self) -> None:
+        queues, self._queues = self._queues, ()
+        for q in queues:
+            q.settle()
+
+    def __enter__(self) -> "Arrival":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.settle()
+
+
 class DeadlineQueue:
     """Bounded two-class EDF wait queue (see module docstring).
 
@@ -470,6 +495,9 @@ class DeadlineQueue:
         self._cond = threading.Condition()
         self._seq = itertools.count()
         self._streak = 0  # consecutive interactive pops while batch waits
+        # Requests the server has read and not yet put here (``Arrival``):
+        # what an idle decode loop's admission waits for (``pop_expected``).
+        self._expected = 0
         # Optional weighted fair share across tenants WITHIN a class
         # (tenancy/fairshare.py; set by the batcher when TENANTS is
         # configured).  None = plain EDF, bit-identical to pre-tenancy.
@@ -488,6 +516,23 @@ class DeadlineQueue:
     def waiting(self, klass: str) -> int:
         with self._cond:
             return self._count[klass]
+
+    def expected(self) -> int:
+        """Requests announced (``expect``) and not yet settled."""
+        with self._cond:
+            return self._expected
+
+    def expect(self) -> None:
+        """One more request is on its way to this queue (``Arrival``)."""
+        with self._cond:
+            self._expected += 1
+
+    def settle(self) -> None:
+        """An announced request was put, or will never be: a loop that
+        waits for it (``pop_expected``) looks again."""
+        with self._cond:
+            self._expected -= 1
+            self._cond.notify()
 
     def waiting_started(self) -> int:
         """Checkpointed (preempted) streams still waiting to resume."""
@@ -594,6 +639,28 @@ class DeadlineQueue:
                     return None
                 if not self._cond.wait(timeout=remaining):
                     return self._pop_locked(fits)
+
+    def pop_expected(self, timeout: float, quiet: float = 0.0, fits=None):
+        """Blocking pop for an idle decode loop that holds the first rows
+        of a burst: waits while a request is announced (``expected()``
+        above zero) and, past that, until ``quiet`` seconds from now
+        have brought none; never longer than ``timeout``.  None = go:
+        nothing is coming (or ``timeout`` ran out with ``expected()``
+        still above zero, which the caller can read)."""
+        now = self._clock()
+        deadline, quiet_until = now + timeout, now + quiet
+        with self._cond:
+            while True:
+                item = self._pop_locked(fits)
+                if item is not None:
+                    return item
+                until = deadline if self._expected > 0 else min(
+                    deadline, quiet_until
+                )
+                remaining = until - self._clock()
+                if remaining <= 0:
+                    return None
+                self._cond.wait(timeout=remaining)
 
     def set_fairshare(self, fs) -> None:
         """Attach (or detach, ``None``) a ``WeightedFairShare`` ledger:

@@ -126,6 +126,28 @@ STREAM_ADMIT = Histogram(
     "place in the wave's emit order",
     ["model"], buckets=_LATENCY_BUCKETS,
 )
+IDLE_ADMIT_WAIT = Histogram(
+    "idle_admit_wait_seconds",
+    "Seconds an idle decode loop held its first rows while requests "
+    "the server was still reading were on their way: one observation "
+    "per idle admission that found any (a lone request finds none and "
+    "is not observed)",
+    ["model"],
+    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+             0.5, 1.0),
+)
+IDLE_ADMIT_ROWS = Counter(
+    "idle_admit_rows_total",
+    "Rows the idle-admission waits added to their waves beyond the "
+    "rows that were there when the wait began",
+    ["model"],
+)
+IDLE_ADMIT_CAPPED = Counter(
+    "idle_admit_capped_total",
+    "Idle-admission waits that ended on their cap (what the wave costs) "
+    "with a request still announced",
+    ["model"],
+)
 PREFILL_WAVE_FILL = Histogram(
     "prefill_wave_fill",
     "Useful share of one prefill executable run: real prompt tokens "

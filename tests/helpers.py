@@ -6,6 +6,7 @@ stay quick; the golden tests cover full-size fidelity.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 
 import numpy as np
@@ -21,6 +22,7 @@ from mlmicroservicetemplate_tpu.models.registry import (
 )
 from mlmicroservicetemplate_tpu.models.tokenizer import build_tokenizer
 from mlmicroservicetemplate_tpu.runtime.device import default_policy
+from mlmicroservicetemplate_tpu.scheduler.policy import Arrival
 
 TINY_RESNET = functools.partial(
     resnet_mod.ResNetConfig,
@@ -238,3 +240,16 @@ def text_feats(tokenizer, text: str, max_len: int = 128) -> dict:
     ids, mask = tokenizer.encode(text, max_len)
     n = int(mask.sum())
     return {"input_ids": ids[:n], "length": np.int32(n)}
+
+
+@contextlib.contextmanager
+def one_wave(cdl, cap_s: float = 5.0):
+    """Submit a burst to an idle decode loop the way the API does:
+    announced on the loop's queue (``scheduler.policy.Arrival``) until
+    its streams are put, so the loop admits it as ONE wave.  A loop
+    holds a wave no longer than a wave of its rung last took; a test's
+    loop has timed none (or, on this CPU, one of a millisecond), so
+    every rung is given ``cap_s``."""
+    cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, cap_s)
+    with Arrival([cdl.queue]):
+        yield

@@ -23,7 +23,7 @@ import numpy as np
 import pytest
 from jax import lax
 
-from helpers import tiny_llama_bundle
+from helpers import one_wave, tiny_llama_bundle
 from test_nemotron_block import config, kw  # noqa: F401
 from mlmicroservicetemplate_tpu.engine import InferenceEngine
 from mlmicroservicetemplate_tpu.engine.streams import (
@@ -208,10 +208,11 @@ async def _consume(gen):
 
 
 def _burst(cdl, feats):
-    """Every stream queued before any is consumed: with the grace below,
-    one wave holds them all."""
+    """Every stream queued before any is consumed, announced as the API
+    announces a burst: one wave holds them all."""
     async def body():
-        gens = [cdl.submit_stream(dict(f)) for f in feats]
+        with one_wave(cdl):
+            gens = [cdl.submit_stream(dict(f)) for f in feats]
         return await asyncio.gather(*[_consume(g) for g in gens])
 
     return asyncio.run(body())
@@ -220,7 +221,6 @@ def _burst(cdl, feats):
 def _loop(bundle, cfg):
     eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
     cdl = ContinuousDecodeLoop(eng, cfg)
-    cdl._admit_grace_s = 0.5
     return eng, cdl
 
 

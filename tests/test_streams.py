@@ -22,7 +22,7 @@ from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
 from mlmicroservicetemplate_tpu.parallel import ReplicaSet, make_mesh
 from mlmicroservicetemplate_tpu.utils.config import ServiceConfig
 
-from helpers import text_feats, tiny_t5_bundle
+from helpers import one_wave, text_feats, tiny_t5_bundle
 
 
 def _cfg(**kw) -> ServiceConfig:
@@ -111,7 +111,8 @@ def _run_concurrent(loop_obj, feats_list):
         # Submit every stream before consuming any: all of them sit in
         # pending before the loop thread reaches its first admission
         # boundary, so one shared batch serves the whole wave.
-        gens = [loop_obj.submit_stream(dict(f)) for f in feats_list]
+        with one_wave(loop_obj):
+            gens = [loop_obj.submit_stream(dict(f)) for f in feats_list]
         return await asyncio.gather(*[_collect(g) for g in gens])
 
     return asyncio.run(body())
@@ -770,7 +771,6 @@ def test_wave_of_three_at_eight_slots_matches_solo(paged):
     """Three streams admitted as ONE wave at 8 slots run their rung
     (4 rows, not 8) and emit the tokens each emits served alone."""
     bundle, eng, cdl = _llama_loop(paged)
-    cdl._admit_grace_s = 0.5  # all three submits land in one wave
     ran = _spy_waves(cdl)
     feats = [text_feats(bundle.tokenizer, t) for t in
              ("the quick brown fox", "hi", "jumps over the lazy dog again")]
@@ -799,7 +799,6 @@ def test_warm_covers_every_wave_size(mode, monkeypatch):
     want, ran = [], None
     try:
         cdl.warm()
-        cdl._admit_grace_s = 0.15
         ran = _spy_waves(cdl)
         with CompileWindow() as w:
             for s, body in ((16, "x" * 6), (32, "x" * 20)):
@@ -838,7 +837,6 @@ def test_prefill_wave_rows_observes_the_rung():
     cfg = _cfg(max_streams=8)
     eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
     cdl = ContinuousDecodeLoop(eng, cfg)
-    cdl._admit_grace_s = 0.5
 
     def read():
         return tuple(
@@ -864,3 +862,239 @@ def test_prefill_wave_rows_observes_the_rung():
     assert (s1 - s0, c1 - c0) == (float(rung), 1.0) and rung < cdl.n_slots
     assert (s2 - s1, c2 - c1) == (1.0, 1.0)
     assert (s3 - s2, c3 - c2) == (float(cdl.n_slots), 1.0)
+
+
+# ---------------------------------------------------------------------------
+# Idle admission: the loop holds a wave for the requests the server is still
+# reading (``_collect_burst``), and for nothing else.
+
+
+def _idle_admit_samples(name: str) -> dict:
+    from prometheus_client import REGISTRY
+
+    return {
+        k: REGISTRY.get_sample_value(k, {"model": name}) or 0.0
+        for k in ("idle_admit_wait_seconds_count", "idle_admit_wait_seconds_sum",
+                  "idle_admit_rows_total", "idle_admit_capped_total",
+                  "stream_insert_rows_count", "stream_insert_rows_sum")
+    }
+
+
+async def _until(cond, what: str, timeout: float = 10.0) -> None:
+    t_end = time.monotonic() + timeout
+    while not cond():
+        assert time.monotonic() < t_end, what
+        await asyncio.sleep(0.002)
+
+
+@pytest.mark.parametrize("k", [5, 8], ids=["five", "n_slots"])
+def test_announced_burst_lands_as_one_wave(k):
+    """A burst whose requests are announced (the API's counted entry)
+    reaches an idle loop one row first, the rest 30 ms behind: the loop
+    holds the first row for them, so ONE prefill run and ONE insert
+    dispatch serve all ``k`` — and the wait's counters say so."""
+    from mlmicroservicetemplate_tpu.scheduler.policy import Arrival
+
+    bundle, eng, cdl = _llama_loop(True)
+    assert cdl.n_slots == 8
+    ran = _spy_waves(cdl)
+    feats = [text_feats(bundle.tokenizer, f"{i} burst row") for i in range(k)]
+    before = _idle_admit_samples(bundle.name)
+
+    async def body():
+        with one_wave(cdl):
+            with Arrival([cdl.queue]):
+                first = cdl.submit_stream(dict(feats[0]))
+            # The first row's own announcement is settled, the burst's is
+            # not: the loop pops the row and waits.
+            await _until(lambda: cdl.queue.qsize() == 0, "first row not popped")
+            await asyncio.sleep(0.03)
+            assert not ran and not cdl.active  # held, not started alone
+            rest = [cdl.submit_stream(dict(f)) for f in feats[1:]]
+        return await asyncio.gather(*[_collect(g) for g in [first] + rest])
+
+    try:
+        outs = asyncio.run(body())
+    finally:
+        cdl.stop()
+    assert [r for r, _ in ran] == [cdl.n_slots] == [cdl._wave_rows(k)]
+    for f, got in zip(feats, outs):
+        np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+    d = {n: v - before[n] for n, v in _idle_admit_samples(bundle.name).items()}
+    assert (d["stream_insert_rows_count"], d["stream_insert_rows_sum"]) == (1, k)
+    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, k - 1, 0)
+    assert d["idle_admit_wait_seconds_count"] == 1
+    assert d["idle_admit_rows_total"] == k - 1 and d["idle_admit_capped_total"] == 0
+    assert 0.03 <= cdl.idle_wait_s == pytest.approx(d["idle_admit_wait_seconds_sum"])
+
+
+@pytest.mark.parametrize("grace_env", [None, "500"], ids=["plain", "ADMIT_GRACE_MS"])
+def test_lone_submit_is_dispatched_without_waiting(grace_env, monkeypatch):
+    """A lone request with nothing announced finds nothing to wait for:
+    the loop's wait counter does not move (whatever wave times it has
+    seen).  ``ADMIT_GRACE_MS`` is gone: set, it changes nothing."""
+    if grace_env is not None:
+        monkeypatch.setenv("ADMIT_GRACE_MS", grace_env)
+    bundle, eng, cdl = _llama_loop(True)
+    cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 5.0)
+    before = _idle_admit_samples(bundle.name)
+    f = text_feats(bundle.tokenizer, "a lone request")
+    try:
+        got = asyncio.run(_consume(cdl, dict(f)))
+    finally:
+        cdl.stop()
+    np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_wait_s) == (0, 0, 0.0)
+    after = _idle_admit_samples(bundle.name)
+    assert after["idle_admit_wait_seconds_count"] == before["idle_admit_wait_seconds_count"]
+    assert not hasattr(cdl, "_admit_grace_s")
+
+
+@pytest.mark.parametrize("how", ["settled", "raised"])
+def test_failed_arrival_releases_a_waiting_loop(how):
+    """A counted request that fails before it queues (a 400 from
+    preprocess, a shed) lowers the count on its way out, and the loop
+    that waited for it goes at once with the rows it holds."""
+    from mlmicroservicetemplate_tpu.scheduler.policy import Arrival
+
+    bundle, eng, cdl = _llama_loop(True)
+    ran = _spy_waves(cdl)
+    f = text_feats(bundle.tokenizer, "the one that made it")
+
+    async def body():
+        cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 30.0)
+        failing = Arrival([cdl.queue])
+        gen = cdl.submit_stream(dict(f))
+        await _until(lambda: cdl.queue.qsize() == 0, "row not popped")
+        await asyncio.sleep(0.03)
+        assert not ran and cdl.queue.expected() == 1  # the loop waits
+        if how == "settled":
+            failing.settle()
+            failing.settle()  # every exit may settle: once counts
+        else:
+            with pytest.raises(ValueError), failing:
+                raise ValueError("undecodable payload")
+        assert cdl.queue.expected() == 0
+        return await _collect(gen)
+
+    try:
+        got = asyncio.run(body())
+    finally:
+        cdl.stop()
+    np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+    assert [r for r, _ in ran] == [1]
+    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, 0, 0)
+    assert 0.03 <= cdl.idle_wait_s < 30.0
+
+
+@pytest.mark.parametrize("k", [1, 3], ids=["lone_rung", "small_rung"])
+def test_count_that_never_falls_ends_on_the_cap(k):
+    """An announcement nobody settles holds the loop no longer than the
+    wave it would run now last took (by rung), and the wait is counted
+    as capped."""
+    from mlmicroservicetemplate_tpu.scheduler.policy import Arrival
+
+    bundle, eng, cdl = _llama_loop(True)
+    ran = _spy_waves(cdl)
+    feats = [text_feats(bundle.tokenizer, f"{i} held row") for i in range(k)]
+    rung = cdl._wave_rows(k)
+    caps = {1: 0.3, cdl._wave_rows(3): 0.6, cdl.n_slots: 30.0}
+    assert len(caps) == 3 and cdl._burst_cap_s(k) == 0.0  # nothing timed yet
+    before = _idle_admit_samples(bundle.name)
+
+    async def body():
+        cdl._wave_seconds = dict(caps)
+        stuck = Arrival([cdl.queue])
+        try:
+            gens = [cdl.submit_stream(dict(f)) for f in feats]
+            return await asyncio.gather(*[_collect(g) for g in gens])
+        finally:
+            stuck.settle()
+
+    try:
+        outs = asyncio.run(body())
+    finally:
+        cdl.stop()
+    for f, got in zip(feats, outs):
+        np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+    assert [r for r, _ in ran] == [rung]
+    assert (cdl.idle_waits, cdl.idle_waits_capped) == (1, 1)
+    assert caps[rung] <= cdl.idle_wait_s < caps[rung] + 5.0
+    after = _idle_admit_samples(bundle.name)
+    assert after["idle_admit_capped_total"] - before["idle_admit_capped_total"] == 1
+    # ... and the wave just timed is the next cap of its rung.
+    assert 0.0 < cdl._wave_seconds[rung] != caps[rung]
+
+
+def test_first_row_borrows_the_last_idle_waves_gap():
+    """A lone row has no gaps of its own: after a burst it waits twice
+    that burst's widest gap for a companion (unannounced: the server
+    read faster than its client wrote), after a lone request it waits
+    for nothing."""
+    bundle, eng, cdl = _llama_loop(True)
+    ran = _spy_waves(cdl)
+    f = [text_feats(bundle.tokenizer, f"{i} row") for i in range(4)]
+
+    async def idle():
+        await _until(lambda: not cdl.active and not cdl._inflight_chunks,
+                     "loop not idle")
+
+    async def body():
+        cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 5.0)
+        cdl._idle_gap_s = 0.5  # as a burst with a 500 ms pause left it
+        a = cdl.submit_stream(dict(f[0]))
+        await asyncio.sleep(0.03)
+        assert not ran  # held: up to a second past its arrival
+        b = cdl.submit_stream(dict(f[1]))
+        outs = list(await asyncio.gather(_collect(a), _collect(b)))
+        await idle()
+        assert (cdl.idle_waits, cdl.idle_wait_rows) == (1, 1)
+        assert 0.03 <= cdl._idle_gap_s < 0.5  # the pair's own gap now
+        cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 5.0)
+        outs.append(await _collect(cdl.submit_stream(dict(f[2]))))
+        await idle()  # waited (twice the pair's gap) and found nobody
+        assert (cdl.idle_waits, cdl.idle_wait_rows) == (2, 1)
+        assert cdl._idle_gap_s == 0.0
+        outs.append(await _collect(cdl.submit_stream(dict(f[3]))))
+        return outs
+
+    try:
+        outs = asyncio.run(body())
+    finally:
+        cdl.stop()
+    for feats, got in zip(f, outs):
+        np.testing.assert_array_equal(got, _solo_tokens(eng, feats))
+    assert [r for r, _ in ran] == [cdl._wave_rows(2), 1, 1]
+    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (2, 1, 0)
+
+
+@pytest.mark.parametrize("announced", [False, True], ids=["settled", "announced"])
+def test_rows_already_queued_join_the_wave(announced):
+    """Rows that were put while the loop thread was slow to wake are
+    part of the wave whether or not their announcement is still open:
+    the loop drains the queue before it decides there is nothing to
+    wait for (and having found them all, it does not wait)."""
+    from mlmicroservicetemplate_tpu.scheduler.policy import Arrival
+
+    bundle, eng, cdl = _llama_loop(True)
+    cdl._ensure_thread = lambda: None  # the test is the loop thread
+    cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 0.2)
+
+    async def body():
+        for i in range(3):
+            cdl.submit_stream(text_feats(bundle.tokenizer, f"{i} queued row"))
+        arrival = Arrival([cdl.queue] if announced else [])
+        await asyncio.sleep(0.05)  # the loop wakes late: past any quiet gap
+        wave = [cdl.queue.pop_nowait()]
+        t = time.monotonic()
+        cdl._collect_burst(wave)
+        arrival.settle()
+        return wave, time.monotonic() - t
+
+    wave, dt = asyncio.run(body())
+    assert len(wave) == 3 and cdl.queue.qsize() == 0
+    if announced:  # ... then held for the one still announced, to the cap
+        assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, 2, 1)
+        assert dt >= 0.2
+    else:
+        assert (cdl.idle_waits, cdl.idle_wait_s) == (0, 0.0)

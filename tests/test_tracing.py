@@ -37,7 +37,7 @@ from mlmicroservicetemplate_tpu.parallel import ReplicaSet, make_mesh
 from mlmicroservicetemplate_tpu.utils import tracing
 from mlmicroservicetemplate_tpu.utils.config import ServiceConfig
 
-from helpers import tiny_gpt_bundle, tiny_llama_bundle, text_feats
+from helpers import one_wave, tiny_gpt_bundle, tiny_llama_bundle, text_feats
 
 
 def _cfg(**kw) -> ServiceConfig:
@@ -352,7 +352,6 @@ def test_wave_and_queue_counters():
     tracing.configure(False)
     bundle, eng, cdl = _paged_llama(max_streams=8, max_decode_len=64,
                                     seq_buckets=(32,))
-    cdl._admit_grace_s = 1.0  # both submits land in ONE wave
     name = bundle.name
     f1 = text_feats(bundle.tokenizer, "the quick brown fox")
     f2 = text_feats(bundle.tokenizer, "jumps over the lazy dog again")
@@ -372,8 +371,10 @@ def test_wave_and_queue_counters():
             return n
 
         first = asyncio.Event()
-        a = asyncio.ensure_future(consume(f1, first))
-        b = asyncio.ensure_future(consume(f2))
+        with one_wave(cdl):  # both submits land in ONE wave
+            a = asyncio.ensure_future(consume(f1, first))
+            b = asyncio.ensure_future(consume(f2))
+            await asyncio.sleep(0)  # both tasks ran up to their submit
         await first.wait()
         mid = {k: _sample(k, name) for k in fams}
         live = len(cdl.active) > 0
@@ -653,3 +654,89 @@ def test_tbt_histogram_observed():
     # 16-token budget at 4-token chunks → ≥3 inter-chunk gaps.
     assert len(toks) > 0
     assert tbt_count() - before >= 2
+
+
+@pytest.mark.parametrize("route", ["/predict", "/v1/completions"])
+def test_idle_admission_counters_over_http(route, monkeypatch):
+    """The API counts a streaming request from its parsed body to its
+    queued stream, and the loop's wait shows in ``/metrics``
+    (``idle_admit_*``) and ``/status.decode.idle_admit``: a burst whose
+    requests leave preprocess 20 ms apart lands as ONE wave (waits 1,
+    rows 3); a counted request that fails in preprocess (400) or is
+    shed (503) lowers the count on its way out."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from mlmicroservicetemplate_tpu.api import build_app
+    from mlmicroservicetemplate_tpu.scheduler import Batcher
+
+    tracing.configure(False)
+    cfg = _cfg(max_decode_len=16, batch_timeout_ms=1.0)
+    bundle = tiny_gpt_bundle()
+    preprocess = bundle.preprocess
+
+    def slow(item):  # request i leaves the executor ~20 ms after i - 1,
+        # the first when the server has read (and counted) all four
+        if item.text and item.text[0].isdigit():
+            time.sleep(0.2 + 0.02 * int(item.text[0]))
+        if item.text == "bad":
+            raise ValueError("undecodable payload")
+        return preprocess(item)
+
+    monkeypatch.setattr(bundle, "preprocess", slow)
+
+    def body(text):
+        key = "text" if route == "/predict" else "prompt"
+        return {key: text, "stream": True, "max_tokens": 6}
+
+    async def drain(r):
+        async for _ in r.content:
+            pass
+
+    async def main():
+        engine = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+        batcher = Batcher(engine, cfg)
+        app = build_app(cfg, bundle, engine, batcher)
+        client = TestClient(TestServer(app))
+        await client.start_server()
+        try:
+            for _ in range(200):
+                if (await client.get("/readyz")).status == 200:
+                    break
+                await asyncio.sleep(0.05)
+            cdl = batcher._cdl
+            # (an unwarmed loop has timed no wave and would hold none)
+            cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 5.0)
+            rs = await asyncio.gather(*[
+                client.post(route, json=body(f"{i} burst row"))
+                for i in range(4)])
+            assert [r.status for r in rs] == [200] * 4
+            await asyncio.gather(*[drain(r) for r in rs])
+            burst = (await (await client.get("/status")).json())["decode"]
+            r = await client.post(route, json=body("bad"))
+            assert r.status == 400
+            assert cdl.queue.expected() == 0
+            batcher.begin_drain()  # every admission sheds: 503 `drain`
+            r = await client.post(route, json=body("refused"))
+            assert r.status == 503
+            status = (await (await client.get("/status")).json())["decode"]
+            text = await (await client.get("/metrics")).text()
+            return burst, status, text, cdl
+        finally:
+            await client.close()
+
+    fams = ("idle_admit_wait_seconds_count", "idle_admit_rows_total",
+            "idle_admit_capped_total")
+    before = {k: _sample(k, bundle.name) for k in fams}
+    burst, status, text, cdl = asyncio.run(main())
+    assert burst["idle_admit"] == {
+        "expected": 0, "waits": 1, "rows": 3, "capped": 0,
+        "wait_s": burst["idle_admit"]["wait_s"]}
+    assert 0.04 <= burst["idle_admit"]["wait_s"] < 5.0
+    assert status["idle_admit"]["expected"] == 0
+    assert status["idle_admit"]["waits"] == 1  # the 400 and the 503 queued nothing
+    d = {k: _sample(k, bundle.name) - before[k] for k in fams}
+    assert d == {"idle_admit_wait_seconds_count": 1.0,
+                 "idle_admit_rows_total": 3.0, "idle_admit_capped_total": 0.0}
+    for fam in ("idle_admit_wait_seconds", "idle_admit_rows_total",
+                "idle_admit_capped_total"):
+        assert f"# HELP {fam}" in text
