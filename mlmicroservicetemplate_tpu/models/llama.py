@@ -11,8 +11,9 @@ continuous batching, per-request sampling, TP sharding.
 Architecture: pre-norm RMSNorm blocks, rotary position embeddings
 (HF rotate-half convention), grouped-query attention (num_kv_heads <
 num_heads; K/V cached at KV width and broadcast to query heads at
-attention time), SwiGLU MLP (down(silu(gate)·up)), no biases anywhere,
-untied LM head.  Two published variations of the block are config
+attention time), SwiGLU MLP (down(silu(gate)·up)), no biases anywhere, an
+LM head of its own (``tie_embeddings``: the embedding table read again,
+transposed).  Two published variations of the block are config
 fields, off by default: a sparse expert FFN in place of the MLP
 (``num_experts``; ops/moe.py — OLMoE-1B-7B: 64 experts, top-8) and an
 RMSNorm on q and k before RoPE (``qk_norm``).
@@ -238,6 +239,19 @@ class LlamaConfig:
     # leaf itself; w > 0 = ``w * sigmoid(leaf)`` (a zero-centred gated
     # norm: a leaf of 0 scales by w / 2), the leaves then drawn about 0.
     norm_gate_weight: float = 0.0
+    # A Mamba-1 mixer (ops/ssm.py; Jamba-style) on the layers ``layer_types``
+    # names "mamba": such a layer is that mixer THEN its FFN, the others
+    # ``attention`` ("attention" is accepted for "full") then theirs.  It
+    # reads ``ssm_heads`` as its CHANNELS (``ssm_head_dim`` 1 and
+    # ``ssm_groups`` 1: a decay a channel and a state, one B and C for all),
+    # ``ssm_state``, ``ssm_conv`` and ``ssm_dt_rank``: the width of the
+    # low-rank step ``[dt | B | C] = x W_x``, each of the three through an
+    # RMSNorm of its own, ``Delta = softplus(dt W_dt + b_dt)``.  Its state —
+    # ``[ssm_state, channels]`` float32 and the taps — lives in the same
+    # state rows as the other two recurrences' (``SsmState``).
+    ssm_dt_rank: int = 0
+    # The LM head is the embedding table, transposed: no ``lm_head`` leaf.
+    tie_embeddings: bool = False
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -297,15 +311,15 @@ class LlamaConfig:
         if not self.head_dim:
             object.__setattr__(self, "head_dim", self.d_model // self.num_heads)
         hf = {"sliding_attention": "window", "full_attention": "full",
-              "linear_attention": "linear"}
+              "linear_attention": "linear", "attention": "full"}
         # A published pattern cut in depth: its first num_layers entries.
         types = tuple(hf.get(t, t) for t in self.layer_types)[: self.num_layers]
         object.__setattr__(self, "layer_types", types)
         if types and (len(types) != self.num_layers
-                      or set(types) - {"window", "full", "linear"}):
+                      or set(types) - {"window", "full", "linear", "mamba"}):
             raise ValueError(
                 f"layer_types must name each of the {self.num_layers} layers "
-                f"'window', 'full' or 'linear', got {types}"
+                f"'window', 'full', 'linear' or 'mamba', got {types}"
             )
         gdn_dims = (self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
                     self.gdn_value_dim)
@@ -360,8 +374,23 @@ class LlamaConfig:
                 raise ValueError(
                     "an 'M' layer needs ssm_heads (a multiple of ssm_groups), "
                     f"ssm_head_dim, ssm_groups and ssm_state, got {ssm_dims}")
-        if "M" not in pattern and any(ssm_dims):
-            raise ValueError(f"Mamba sizes {ssm_dims} need an 'M' layer")
+        if "mamba" in types:
+            if (self.ssm_heads <= 0 or self.ssm_state <= 0 or self.ssm_conv < 2
+                    or self.ssm_dt_rank <= 0 or self.ssm_head_dim != 1
+                    or self.ssm_groups != 1 or set(types) == {"mamba"}
+                    or self.mla):
+                raise ValueError(
+                    "a 'mamba' layer needs ssm_heads (its channels), ssm_state "
+                    "and ssm_dt_rank, ssm_head_dim 1 and ssm_groups 1 (Mamba-1 "
+                    "has neither heads nor groups), attention='gqa' and the "
+                    f"pattern one attention layer (the paged pool's), got "
+                    f"{ssm_dims} / ssm_dt_rank={self.ssm_dt_rank}")
+        elif self.ssm_dt_rank:
+            raise ValueError(
+                f"ssm_dt_rank={self.ssm_dt_rank} needs a 'mamba' layer")
+        if "M" not in pattern and "mamba" not in types and any(ssm_dims):
+            raise ValueError(
+                f"Mamba sizes {ssm_dims} need an 'M' or a 'mamba' layer")
 
     @property
     def n_rep(self) -> int:
@@ -449,12 +478,8 @@ class LlamaConfig:
         """``(taps, state)``: what one row of recurrent layer ``li`` holds —
         the convolution's taps [K-1, channels] (the cache's two-byte dtype)
         and the state (float32): Mamba-2's [H, P, N], Gated DeltaNet's
-        [Hv, Dv, Dk]."""
-        if self.layer_kind(li).mixer == "gdn":
-            return ((self.gdn_conv - 1, self.gdn_conv_dim),
-                    (self.gdn_value_heads, self.gdn_value_dim, self.gdn_key_dim))
-        return ((self.ssm_conv - 1, self.ssm_conv_dim),
-                (self.ssm_heads, self.ssm_head_dim, self.ssm_state))
+        [Hv, Dv, Dk], Mamba-1's [N, channels] (``RECURRENT``)."""
+        return RECURRENT[self.layer_kind(li).mixer].shapes(self)
 
     @property
     def ssm_row_bytes(self) -> int:
@@ -481,10 +506,11 @@ class LlamaConfig:
         dense = li < self.num_dense_layers
         return LayerKind(
             window=window,
-            rope=kind != "linear" and (bool(window) or not self.nope_on_full),
+            rope=kind not in ("linear", "mamba") and (
+                bool(window) or not self.nope_on_full),
             experts=bool(self.num_experts) and not dense,
             d_ff=self.d_ff_dense if dense else self.d_ff,
-            mixer="gdn" if kind == "linear" else self.attention,
+            mixer={"linear": "gdn", "mamba": "mamba1"}.get(kind, self.attention),
         )
 
     @property
@@ -499,7 +525,7 @@ class LayerKind:
     it), whether q and k are rotated, its FFN (``experts``: the
     sparse expert block of experts ``d_ff`` wide; else a dense SwiGLU of
     width ``d_ff``) and its ``mixer``: an attention ("gqa" | "mla"), a
-    recurrence ("mamba2" | "gdn") or None.  Under a ``layer_pattern`` a
+    recurrence (a key of ``RECURRENT``) or None.  Under a ``layer_pattern`` a
     layer is ONE sub-block alone: a mixer, or — ``ffn`` — the FFN."""
 
     window: int
@@ -517,7 +543,7 @@ class LayerKind:
     @property
     def recurrent(self) -> bool:
         """Whether the mixer keeps a state row: the one place that asks."""
-        return self.mixer in ("mamba2", "gdn")
+        return self.mixer in RECURRENT
 
 
 class SsmState(NamedTuple):
@@ -602,8 +628,10 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         "embed": {"embedding": cast(normal_init(keys[0], (cfg.vocab_size, d), std=0.02))},
         "layers": [],
         "final_ln": block_norm(jax.random.fold_in(key, 3), d),
-        "lm_head": {"kernel": cast(normal_init(keys[1], (d, cfg.vocab_size), std=0.02))},
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = {
+            "kernel": cast(normal_init(keys[1], (d, cfg.vocab_size), std=0.02))}
     for i in range(cfg.num_layers):
         lk = keys[2 + i]
         k = jax.random.split(lk, 7)
@@ -689,6 +717,9 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         if kind.mixer == "gdn":
             layer.update(gdn_ln=block_norm(extra(29), d),
                          gdn=_init_gdn(cfg, extra, lin, cast))
+        if kind.mixer == "mamba1":
+            layer.update(ssm_ln=block_norm(extra(29), d),
+                         ssm=_init_mamba1(cfg, extra, lin, norm_scale, cast))
         if mlp is not None:
             layer.update(mlp_ln=block_norm(extra(30), d), mlp=mlp)
         if cfg.sandwich_norm:
@@ -761,6 +792,37 @@ def _init_mamba(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
             "norm": norm_scale(extra(27), inner),
             "out": lin(extra(28), inner, d),
         },
+    }
+
+
+def _init_mamba1(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
+    """One Mamba-1 mixer's leaves: ``in`` [x | z] wide, the convolution over
+    x alone (taps normal 0.4, bias normal 0.5), ``x_proj`` [dt | B | C] wide
+    with an RMSNorm scale each (about 1), ``dt_proj`` (std ``rank^-1/2``,
+    its bias the inverse softplus of a step log-uniform in [0.001, 0.1],
+    float32), ``A_log`` = log(1..N) a channel (the family's; stored [N,
+    channels] as the state lies) and ``D`` about 1, both float32."""
+    d, ch, n, r = cfg.d_model, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    step = jnp.exp(jax.random.uniform(
+        extra(43), (ch,), minval=jnp.log(0.001), maxval=jnp.log(0.1)))
+    return {
+        "in": lin(extra(38), d, 2 * ch),
+        "conv": {
+            "kernel": cast(normal_init(extra(39), (cfg.ssm_conv, ch), std=0.4)),
+            "bias": cast(normal_init(extra(40), (ch,), std=0.5)),
+        },
+        "x_proj": lin(extra(41), ch, r + 2 * n),
+        "dt_norm": norm_scale(extra(45), r),
+        "b_norm": norm_scale(extra(46), n),
+        "c_norm": norm_scale(extra(47), n),
+        "dt_proj": {
+            "kernel": cast(normal_init(extra(42), (r, ch), std=r ** -0.5)),
+            "bias": step + jnp.log(-jnp.expm1(-step)),
+        },
+        "A_log": jnp.broadcast_to(
+            jnp.log(jnp.arange(1.0, n + 1.0))[:, None], (n, ch)),
+        "D": 1.0 + normal_init(extra(44), (ch,), std=0.25),
+        "out": lin(extra(48), ch, d),
     }
 
 
@@ -903,15 +965,21 @@ def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
         return x + out
 
 
+def _head_logits(params: Params, cfg: "LlamaConfig", x):
+    """Float32 logits of final-normed rows: through ``lm_head``, or under
+    ``cfg.tie_embeddings`` through the embedding table, transposed."""
+    if cfg.tie_embeddings:
+        return lm_head_logits(x, params["embed"]["embedding"], transposed=True)
+    return lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
+
+
 def _select_next(params: Params, cfg: "LlamaConfig", state, x_last,
                  sample: bool):
     """Final-normed hidden rows → (next token, sample params, done,
     tokens): the ``lm_head`` and ``sample`` scopes of a decode step."""
     rows = jnp.arange(state.last_token.shape[0])
     with jax.named_scope("lm_head"):
-        logits = lm_head_logits(
-            x_last, params["lm_head"]["kernel"], transposed=False
-        )
+        logits = _head_logits(params, cfg, x_last)
     with jax.named_scope("sample"):
         if sample:
             from .sampling import select_token
@@ -1288,10 +1356,95 @@ def _gdn_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
             return x + out, conv, s
 
 
+def _mamba1_gate(y, z):
+    """``y * silu(z)``, float32: Mamba-1's gate has NO norm behind it."""
+    return y * jax.nn.silu(z)
+
+
+def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
+    """Pre-norm Mamba-1 mixer with its residual, under the ``ssm`` scope
+    (Mamba-2's name: the two never share a model): x [B, L, D] from each
+    row's taps ``conv`` [B, K-1, channels] and state ``s`` [B, N, channels]
+    -> (x + out, conv', s'); ``mask`` / ``live`` as ``_mamba_block`` has
+    them.  The convolution runs over x alone; ``[dt | B | C] = x W_x`` —
+    ``ssm_x_proj``, each of the three through its own RMSNorm —; ``Delta =
+    softplus(dt W_dt + b_dt)`` in float32 — ``ssm_dt_proj`` —; ``y *
+    silu(z)`` with NO norm — ``ssm_gate``."""
+    from ..ops import ssm
+
+    m = layer["ssm"]
+    ch, n, r = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
+    f32 = jnp.float32
+    with jax.named_scope("ssm"):
+        u = _norm(cfg, layer["ssm_ln"], x)
+        with jax.named_scope("ssm_in_proj"):
+            xz = dense(m["in"], u)
+            xs, z = xz[..., :ch], xz[..., ch:]
+        w, bias = m["conv"]["kernel"], m["conv"]["bias"]
+        with jax.named_scope("ssm_conv"):
+            if live is None:
+                xs, conv = ssm.conv_scan(xs, conv, w, bias, mask)
+            else:
+                y1, conv = ssm.conv_step(xs[:, 0], conv, w, bias, live)
+                xs = y1[:, None]
+        with jax.named_scope("ssm_x_proj"):
+            dbc = dense(m["x_proj"], xs).astype(f32)  # the norms' outputs stay float32
+            dt = rmsnorm(m["dt_norm"], dbc[..., :r], eps=cfg.rms_eps)
+            bm = rmsnorm(m["b_norm"], dbc[..., r:r + n], eps=cfg.rms_eps)
+            cm = rmsnorm(m["c_norm"], dbc[..., r + n:], eps=cfg.rms_eps)
+        with jax.named_scope("ssm_dt_proj"):
+            delta = jax.nn.softplus(
+                jnp.einsum("blr,rc->blc", dt.astype(x.dtype),
+                           m["dt_proj"]["kernel"].astype(x.dtype),
+                           preferred_element_type=f32)
+                + m["dt_proj"]["bias"].astype(f32))
+        a = -jnp.exp(m["A_log"].astype(f32))
+        if live is None:
+            with jax.named_scope("ssm_scan"):
+                y, s = ssm.mamba1_scan(xs, delta, a, bm, cm, m["D"], s, mask)
+        else:
+            with jax.named_scope("ssm_step"):
+                y, s = ssm.mamba1_step(xs[:, 0], delta[:, 0], a, bm[:, 0],
+                                       cm[:, 0], m["D"], s, live)
+                y = y[:, None]
+        with jax.named_scope("ssm_gate"):
+            y = _mamba1_gate(y, z.astype(f32)).astype(x.dtype)
+        with jax.named_scope("ssm_out_proj"):
+            return x + dense(m["out"], y), conv, s
+
+
+class Recurrence(NamedTuple):
+    """A recurrent mixer kind: ``shapes(cfg) -> (taps, state)`` (what one
+    state row of such a layer holds, ``LlamaConfig.recurrent_shapes``),
+    ``block(cfg, layer, x, conv, s, mask=, live=) -> (x, conv', s')`` and
+    the name its boot refusals give it."""
+
+    shapes: Any
+    block: Any
+    name: str
+
+
+#: Every mixer that keeps a state row, by ``LayerKind.mixer``: the ONE table
+#: ``LayerKind.recurrent``, ``LlamaConfig.recurrent_shapes``,
+#: ``_recurrent_block`` and the registry's refusals read.
+RECURRENT = {
+    "mamba2": Recurrence(
+        lambda c: ((c.ssm_conv - 1, c.ssm_conv_dim),
+                   (c.ssm_heads, c.ssm_head_dim, c.ssm_state)),
+        _mamba_block, "Mamba-2 layers (layer_pattern 'M')"),
+    "gdn": Recurrence(
+        lambda c: ((c.gdn_conv - 1, c.gdn_conv_dim),
+                   (c.gdn_value_heads, c.gdn_value_dim, c.gdn_key_dim)),
+        _gdn_block, "Gated-DeltaNet layers (layer_types 'linear')"),
+    "mamba1": Recurrence(
+        lambda c: ((c.ssm_conv - 1, c.ssm_inner), (c.ssm_state, c.ssm_inner)),
+        _mamba1_block, "Mamba-1 layers (layer_types 'mamba')"),
+}
+
+
 def _recurrent_block(cfg: "LlamaConfig", li: int):
-    """The block of recurrent layer ``li``: ``block(cfg, layer, x, conv, s,
-    mask=, live=) -> (x, conv', s')``."""
-    return _gdn_block if cfg.layer_kind(li).mixer == "gdn" else _mamba_block
+    """The block of recurrent layer ``li`` (``RECURRENT``)."""
+    return RECURRENT[cfg.layer_kind(li).mixer].block
 
 
 def _layers(params: Params, cfg: "LlamaConfig", x, attend, recur, valid,
@@ -1473,7 +1626,7 @@ def lm_logits(
 ) -> jax.Array:
     """[B, S, V] next-token logits (the non-generative forward)."""
     x = forward_hidden(params, cfg, input_ids, attention_mask, dtype)
-    return lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
+    return _head_logits(params, cfg, x)
 
 
 # ---------------------------------------------------------------------------
@@ -1716,7 +1869,7 @@ def multi_step(
         x = _attn_out(cfg, layer, ad, li, x, ctx, g)
         x = _mlp_block(cfg, layer, li, x, ~state.done[:, None])
     x = _norm(cfg, params["final_ln"], x)
-    logits = lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
+    logits = _head_logits(params, cfg, x)
     return new_k, new_v, logits  # [B, D, V]
 
 
@@ -2137,7 +2290,7 @@ def prefill_tile_counts(cfg: LlamaConfig, c: int, t_w: int, bs: int,
         return 0, 0
     live = total = 0
     for li in range(cfg.num_layers):
-        if cfg.layer_kind(li).mixer == "gdn":  # no keys: no tile
+        if not cfg.layer_kind(li).attention:  # no keys: no tile
             continue
         window = cfg.layer_kind(li).window
         first, n = prefill_key_blocks(c, t_w, bs, start, window)

@@ -904,6 +904,7 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             ("mixer-or-FFN layers (layer_pattern)", cfg.layer_pattern),
             ("a clamped SwiGLU", cfg.swiglu_limit),
             ("a gated norm scale", cfg.norm_gate_weight),
+            ("a tied head", cfg.tie_embeddings),
         ) if on
     ]
     if variants:
@@ -963,7 +964,8 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
                 "per head",
             })
     if cfg.recurrent_layers:
-        # A recurrent layer's state (Mamba-2's, Gated DeltaNet's) is a
+        # A recurrent layer's state (``llama.RECURRENT``: Mamba-2's, Gated
+        # DeltaNet's, Mamba-1's) is a
         # fixed-size row a stream beside the cache — a K/V pool or, with
         # attention='mla', a latent pool: the refusals above hold as well —
         # carried by the prefill waves, the chunked paged prefill and the
@@ -973,8 +975,8 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         # in silence: refuse it.  TP>1 (no spec shards the recurrent heads)
         # and QUANTIZE refuse above.
         _refuse_cache_readers(
-            svc_cfg, "Mamba layers (layer_pattern 'M')" if cfg.layer_pattern
-            else "Gated-DeltaNet layers (layer_types 'linear')", {
+            svc_cfg, llama_mod.RECURRENT[cfg.layer_kind(
+                cfg.recurrent_layers[0]).mixer].name, {
                 "PAGED_KV=0": "the contiguous slab's chunked prefill cannot "
                 "leave a prompt's last token out of the recurrent state: set "
                 "PAGED_KV=1",

@@ -71,13 +71,13 @@ VMEM_LIMIT_BYTES = 64 * 1024 * 1024
 
 def tile_sizes(c: int, n_rep: int, k_len: int, q_tile: int = 0,
                key_tile: int = 0) -> tuple[int, int]:
-    """``(tq, tk)``: queries a q tile and keys a key tile for a window of
-    ``c`` queries over ``k_len`` keys — the arguments where given, else
-    ``Q_TILE_ROWS / n_rep`` queries (a divisor of ``c``) and ``KEY_TILE``
-    keys (at most ``k_len``)."""
-    tq = q_tile or min(c, max(Q_TILE_ROWS // n_rep, 8))
-    while c % tq:
-        tq //= 2
+    """``(tq, tk)`` for a window of ``c`` queries over ``k_len`` keys — the
+    arguments where given, else ``KEY_TILE`` keys (at most ``k_len``) and, of
+    the divisors of ``c`` within ``Q_TILE_ROWS / n_rep`` queries, that cap or
+    the largest of whole 8-row sublane tiles (32 at ``n_rep`` 20), else any."""
+    cap = min(c, max(Q_TILE_ROWS // n_rep, 8))
+    under = [] if q_tile or c % cap == 0 else [t for t in range(cap, 0, -1) if c % t == 0]
+    tq = q_tile or next((t for t in under if t % 8 == 0), under[0] if under else cap)
     return tq, min(key_tile or KEY_TILE, k_len)
 
 

@@ -1,6 +1,6 @@
-"""Selective state-space recurrence (Mamba-2), twice: a chunked scan for
-prompt windows and waves, a one-token update for the decode step.
-
+"""Three recurrences kept in the same state rows, each twice — a scan for
+prompt windows and waves, a one-token update for the decode step: Mamba-2
+(here), Gated DeltaNet and Mamba-1 (the comments before theirs, below).
 Head ``h`` of H (``P`` wide) reads group ``h // (H / G)`` of the G groups
 of B and C (``N`` wide); ``S`` [H, P, N] is a row's recurrent state:
 
@@ -812,3 +812,94 @@ def _gdn_kernel_call(qkv, g, beta, s0, mask, chunk: int, interpret: bool):
         name="gdn_scan",
     )(real, qkv, qkv, qkv, gates, s0.reshape(bsz, hk, r * dv, dk))
     return o.reshape(bsz, length, hv, dv), s.reshape(bsz, hv, dv, dk)
+
+
+# ---------------------------------------------------------------------------
+# Mamba-1 (arXiv:2312.00752; ``mamba1_scan`` / ``mamba1_step``) is the third
+# recurrence kept in the same state rows: no heads, a decay for every one of
+# ``C`` channels x ``N`` states,
+#
+#     h_t[n, c] = exp(D_t[c] A[n, c]) h_{t-1}[n, c] + D_t[c] B_t[n] x_t[c]
+#     y_t[c]    = sum_n h_t[n, c] C_t[n] + D[c] x_t[c]
+#
+# ``D_t`` (``delta``) the token's step a channel, after its softplus; ``A`` <
+# 0; ``B_t`` and ``C_t`` ``N`` wide, shared by every channel.  A masked token
+# has ``delta = 0``: a decay of 1 and no input.  The state is ``[N, C]``
+# float32, the channels the minor axis (lanes on the chip: ``[C, 16]`` would
+# pad every 16 states to a 128-lane tile, eight times its bytes in HBM).
+# Because the decay differs a channel AND a state, a chunk has no matmul
+# form (Mamba-2's ``C B^T`` times one decay matrix a head), and factoring it
+# as ``exp(cs_t) sum_s exp(-cs_s) ..`` overflows float32 inside one chunk
+# (``A`` to -16, an unbounded softplus).  So the scan is the recurrence
+# itself: a ``lax.scan`` over chunks of ``MAMBA1_CHUNK`` tokens whose body is
+# the token update (``_mamba1_token``, the decode step's) unrolled over the
+# chunk's tokens — elementwise operations on ``[B, N, C]`` and a sum over the
+# ``N`` sublanes, nothing else.  No ``[B, Q, N, C]`` array exists, and on the
+# chip the compiler keeps the trip's state and rows in VMEM between its
+# fusions (``S(1)`` in the compiled text): 1.98 ms a layer for three
+# 1024-token windows at the served widths (my chip run, PR 51).  Unrolled
+# state by state as well (sixteen ``[B, C]`` arrays, ONE fusion a trip) it
+# took 4.53 ms — three rows fill 3 of a tile's 8 sublanes — and five times
+# as long to compile, 26 layers an executable: the cold boot's largest part.
+# A fused vector-unit kernel that keeps the state in VMEM over a whole window
+# is the next step (PERF.md section 7).
+
+#: Tokens a trip of the prompt scan's loop folds (its body is unrolled over
+#: them).  The answer does not depend on it; 16 and 32 ran alike, 8 a tenth
+#: slower, 64 a third (my chip run, PR 51).
+MAMBA1_CHUNK = 16
+
+
+def _mamba1_token(h, x, delta, a, b, c, d):
+    """One token of the recurrence: ``h`` [B, N, C] float32, ``x`` / ``delta``
+    [B, C] float32, ``a`` [N, C], ``b`` / ``c`` [B, N], ``d`` [C] -> (the new
+    state, y [B, C])."""
+    h = jnp.exp(delta[:, None, :] * a) * h + (delta * x)[:, None, :] * b[:, :, None]
+    return h, jnp.sum(h * c[:, :, None], axis=1) + d * x
+
+
+def mamba1_scan(x: jax.Array, delta: jax.Array, a: jax.Array, b: jax.Array,
+                c: jax.Array, d: jax.Array, s0: jax.Array, mask: jax.Array, *,
+                chunk: int = MAMBA1_CHUNK):
+    """``x`` [B, L, C] (the convolution's output), ``delta`` [B, L, C] (after
+    softplus), ``a`` [N, C] (negative), ``b`` / ``c`` [B, L, N], ``d`` [C],
+    ``s0`` [B, N, C] float32, ``mask`` [B, L] -> (y [B, L, C] float32, final
+    state [B, N, C] float32).  Any L: the tail is padded with masked
+    tokens."""
+    f32 = jnp.float32
+    bsz, length, ch = x.shape
+    pad = -length % chunk
+    if pad:
+        x, delta, b, c, mask = (
+            jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            for t in (x, delta, b, c, mask))
+    delta = delta.astype(f32) * (mask != 0)[..., None]
+    a, d = a.astype(f32), d.astype(f32)
+
+    def chunks(t):  # [B, L, W] -> [L / Q, B, Q, W]: a chunk a trip
+        return jnp.moveaxis(
+            t.reshape(bsz, (length + pad) // chunk, chunk, t.shape[-1]), 1, 0)
+
+    def trip(h, step):
+        xq, dq, bq, cq = step
+        ys = []
+        for j in range(chunk):
+            h, y = _mamba1_token(h, xq[:, j].astype(f32), dq[:, j], a,
+                                 bq[:, j].astype(f32), cq[:, j].astype(f32), d)
+            ys.append(y)
+        return h, jnp.stack(ys, axis=1)
+
+    h, y = jax.lax.scan(trip, s0.astype(f32),
+                        (chunks(x), chunks(delta), chunks(b), chunks(c)))
+    y = jnp.moveaxis(y, 0, 1).reshape(bsz, length + pad, ch)
+    return y[:, :length], h
+
+
+def mamba1_step(x: jax.Array, delta: jax.Array, a: jax.Array, b: jax.Array,
+                c: jax.Array, d: jax.Array, s: jax.Array, live: jax.Array):
+    """One token a row: ``x`` / ``delta`` [B, C], ``a`` [N, C], ``b`` / ``c``
+    [B, N], ``d`` [C], ``s`` [B, N, C] float32, ``live`` [B] -> (y [B, C]
+    float32 — a live row's —, the state: updated where the row is live, as
+    it was where it is not)."""
+    new, y = _mamba1_token(s, *(t.astype(jnp.float32) for t in (x, delta, a, b, c, d)))
+    return y, jnp.where(live[:, None, None], new, s)

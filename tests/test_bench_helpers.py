@@ -307,7 +307,8 @@ BOOT_CELLS = [
     "mistral-7b-d8.decode-closed", "mistral-7b-d8.chat-open",
     "olmoe-1b-7b-d8.decode-closed", "trinity-mini-d5.longdoc-closed", DSV2_CELL,
     "nemotron3-super-ep4-d11.longdoc-closed",  # PR 40 appended its cell
-    "gigachat35-ep16-d5.longdoc-closed"]  # and PR 47 its
+    "gigachat35-ep16-d5.longdoc-closed",  # PR 47 its
+    "jamba2-3b-d28.longdoc-closed"]  # and PR 51 its
 BOOT_ENTRIES = [
     ("boot_imports_s", "s", "boot_phase_seconds", {"phase": "imports"}),
     ("boot_weights_s", "s", "boot_phase_seconds", {"phase": "weights"}),
@@ -403,8 +404,8 @@ def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
         "name": name, "unit": "%", "better": "higher", "source": "program_counter",
         "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
     # appended by PR 36 (nothing before them moved); PR 40's 24, PR 41's
-    # two and PR 47's 29 follow them
-    assert entry in per_layer[-57:-55]
+    # two, PR 47's 29 and PR 51's one follow them
+    assert entry in per_layer[-58:-56]
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_counter_ratio" and callable(metric.read)
@@ -482,14 +483,14 @@ def test_nemotron_configuration_and_cell_are_in_the_benchmark():
     from cellbench import spec
 
     bench = spec.load_benchmark()
-    assert bench["configs"][-2]["name"] == "nemotron3-super-ep4-d11"  # appended
-    cfg = bench["configs"][-2]  # (PR 47 appended one after it)
+    assert bench["configs"][-3]["name"] == "nemotron3-super-ep4-d11"  # appended
+    cfg = bench["configs"][-3]  # (PR 47 and PR 51 appended one each after it)
     assert cfg["file"] == "cellbench/configs/nemotron3-super-ep4-d11.json"
     assert cfg["source"] == (
         "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16"
         "/blob/main/config.json")
     assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    cell = bench["workloads"][-2]
+    cell = bench["workloads"][-3]
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         NEMO_CELL, "nemotron3-super-ep4-d11", "longdoc-closed", 1)
     assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
@@ -497,7 +498,7 @@ def test_nemotron_configuration_and_cell_are_in_the_benchmark():
           if "workloads" not in m or NEMO_CELL in m["workloads"]]
     assert on == ["tbt_p99_ms", "setup_s"]
     boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(m["workloads"][-2] == NEMO_CELL for m in boots)
+    assert len(boots) == 7 and all(m["workloads"][-3] == NEMO_CELL for m in boots)
     file = spec.load_json(spec.REPO + "/" + cfg["file"])
     assert set(file["reduced"]) == set(cfg["reduced"])
     assert (file["num_hidden_layers"], file["n_routed_experts"],
@@ -515,7 +516,8 @@ def test_nemotron_per_layer_entry_resolves(name, unit, source, layer, reader):
     assert entry == {
         "name": name, "unit": unit,
         "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms", "workloads": [NEMO_CELL]}
+        "moves": "tbt_p99_ms",
+        "workloads": [NEMO_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED)}
     assert entry["better"] in ("lower", "higher")
     (resolved,) = [m for m in spec.resolve(NEMO_CELL).per_layer if m.name == name]
     assert resolved.reader == reader and callable(resolved.read)
@@ -574,8 +576,8 @@ def test_insert_rows_per_dispatch_resolves_in_its_cell(name, cell):
         "name": name, "unit": "rows", "better": "higher",
         "source": "program_counter", "layer": "engine", "moves": "tokens_per_s",
         "workloads": [cell]}
-    # appended: nothing before them moved (PR 47's 29 follow them)
-    assert entry in per_layer[-31:-29]
+    # appended: nothing before them moved (PR 47's 29 and PR 51's one follow them)
+    assert entry in per_layer[-32:-30]
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_hist" and callable(metric.read)
@@ -658,7 +660,7 @@ def test_gigachat_configuration_and_cell_are_in_the_benchmark():
     from cellbench import spec
 
     bench = spec.load_benchmark()
-    cfg, cell = bench["configs"][-1], bench["workloads"][-1]  # appended
+    cfg, cell = bench["configs"][-2], bench["workloads"][-2]  # appended (PR 51's follow)
     assert cfg["name"] == "gigachat35-ep16-d5"
     assert cfg["file"] == "cellbench/configs/gigachat35-ep16-d5.json"
     assert cfg["source"] == (
@@ -672,8 +674,8 @@ def test_gigachat_configuration_and_cell_are_in_the_benchmark():
           if "workloads" not in m or GIGA_CELL in m["workloads"]]
     assert on == ["tbt_p99_ms", "setup_s"]
     boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(m["workloads"][-1] == GIGA_CELL for m in boots)
-    assert [m["name"] for m in bench["per_layer"][-len(GIGA_ENTRIES):]] == [
+    assert len(boots) == 7 and all(m["workloads"][-2] == GIGA_CELL for m in boots)
+    assert [m["name"] for m in bench["per_layer"][-len(GIGA_ENTRIES) - 1:-1]] == [
         n + ".gigachat" for n, *_ in GIGA_ENTRIES]
     assert len(bench["per_layer"]) <= 128  # the file's limit
     file = spec.load_json(spec.REPO + "/" + cfg["file"])
@@ -695,9 +697,83 @@ def test_gigachat_per_layer_entry_resolves(name, unit, source, layer, reader):
     assert entry == {
         "name": name, "unit": unit,
         "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms", "workloads": [GIGA_CELL]}
+        "moves": "tbt_p99_ms",
+        "workloads": [GIGA_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED)}
     assert entry["better"] in ("lower", "higher")
     (resolved,) = [m for m in spec.resolve(GIGA_CELL).per_layer if m.name == name]
     assert resolved.reader == reader and callable(resolved.read)
-    for other in spec.load_benchmark()["workloads"][:-1]:  # read in its own cell only
-        assert name not in [m.name for m in spec.resolve(other["name"]).per_layer]
+    for other in spec.load_benchmark()["workloads"]:  # read in the cells it lists only
+        if other["name"] not in entry["workloads"]:
+            assert name not in [m.name for m in spec.resolve(other["name"]).per_layer]
+
+
+# ---------------------------------------------------------------------------
+# jamba2-3b-d28 (PR 51): one configuration, one cell, ONE new per-layer entry
+# (the file's limit is 128 entries and 127 stood) and the cell's name appended
+# to the sibling entries whose readers read the same scopes and counters
+
+
+JAMBA_CELL = "jamba2-3b-d28.longdoc-closed"
+#: The accepted entries the cell is appended to (their readers are generic:
+#: the ``ssm`` / ``ssm_scan`` scopes, the shared ``ssm_*`` families, the
+#: loop's counters, the window executable by name).
+JAMBA_SHARED = [
+    "decode_ssm_ms.nemotron", "ssm_proj_ms.nemotron", "prefill_ssm_scan_ms.nemotron",
+    "ssm_scan_masked_pct.nemotron",
+    "decode_step_ms.nemotron", "decode_attn_ms.nemotron",
+    "table_blocks_dead_pct.nemotron", "streams_per_chunk.nemotron",
+    "prefill_stall_ms.nemotron", "prefill_window_ms.nemotron",
+    "prefill_windows_batched_pct.nemotron", "device_idle_pct.nemotron",
+    "prefill_mlp_ms.gigachat"]
+
+
+def test_jamba_configuration_and_cell_are_in_the_benchmark():
+    from cellbench import spec
+
+    bench = spec.load_benchmark()
+    cfg, cell = bench["configs"][-1], bench["workloads"][-1]  # appended
+    assert (cfg["name"], cfg["file"], cfg["reduced"]) == (
+        "jamba2-3b-d28", "cellbench/configs/jamba2-3b-d28.json", [])
+    assert cfg["source"] == (
+        "https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json")
+    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
+        JAMBA_CELL, "jamba2-3b-d28", "longdoc-closed", 1)
+    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
+    on = [m["name"] for m in bench["end_to_end"]
+          if "workloads" not in m or JAMBA_CELL in m["workloads"]]
+    assert on == ["tbt_p99_ms", "setup_s"]
+    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
+    assert len(boots) == 7 and all(m["workloads"][-1] == JAMBA_CELL for m in boots)
+    assert len(bench["configs"]) == 7 and len(bench["workloads"]) == 8
+    assert len(bench["per_layer"]) == 128  # the file's limit: one was left
+    file = spec.load_json(spec.REPO + "/" + cfg["file"])
+    assert file["reduced"] == {} and file["num_hidden_layers"] == 28
+    assert file["tie_word_embeddings"] is True and file["num_key_value_heads"] == 1
+    assert spec.resolve(JAMBA_CELL).traffic == spec.resolve(NEMO_CELL).traffic
+
+
+def test_jamba_per_layer_entries_resolve():
+    """The one new entry is the LAST one; every sibling entry the cell is
+    appended to lists it last, moves the end-to-end metric the cell reports
+    and resolves to a reader; no other cell reads the new entry."""
+    from cellbench import spec
+
+    per_layer = spec.load_benchmark()["per_layer"]
+    assert per_layer[-1] == {
+        "name": "ssm_scan_roofline.jamba2", "unit": "%", "better": "higher",
+        "source": "device_trace", "layer": "kernels", "moves": "tbt_p99_ms",
+        "workloads": [JAMBA_CELL]}
+    mine = {m.name: m for m in spec.resolve(JAMBA_CELL).per_layer}
+    assert mine["ssm_scan_roofline.jamba2"].reader == "jamba_roofline"
+    assert mine["ssm_scan_roofline.jamba2"].args == {"what": "ssm_scan"}
+    listed = [m["name"] for m in per_layer if JAMBA_CELL in m.get("workloads", [])]
+    assert sorted(listed) == sorted(
+        [*JAMBA_SHARED, "ssm_scan_roofline.jamba2",
+         *(m["name"] for m in per_layer if m["name"].startswith("boot_"))])
+    for m in per_layer:
+        if m["name"] in JAMBA_SHARED:
+            assert m["workloads"][-1] == JAMBA_CELL and m["moves"] == "tbt_p99_ms"
+            assert callable(mine[m["name"]].read)
+    for other in spec.load_benchmark()["workloads"][:-1]:
+        assert "ssm_scan_roofline.jamba2" not in [
+            m.name for m in spec.resolve(other["name"]).per_layer]

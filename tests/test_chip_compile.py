@@ -278,6 +278,8 @@ _KERNEL_CELLS = {
     "olmoe": (64, 16, 1, 28, 1810),
     "trinity-full": (32, 4, 8, 392, 12573),
     "trinity-view": (32, 4, 8, 136, 12573),
+    # 20 query heads on ONE KV head: 20 rows are no multiple of the 8 sublanes
+    "jamba": (32, 1, 20, 392, 13184),
 }
 
 
@@ -564,12 +566,16 @@ def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads, windows):
     (H, KVH, D, jnp.bfloat16),  # the default widths: heads of 64 (half a lane tile)
     (32, 8, 128, jnp.float32),  # Mistral's heads past a bucket, float32
     (16, 16, 128, jnp.bfloat16),  # OLMoE's: a KV head a query head
-], ids=["d64", "f32", "n_rep1"])
+    (20, 1, 128, jnp.bfloat16),  # Jamba's: 20 query heads on ONE KV head
+], ids=["d64", "f32", "n_rep1", "n_rep20"])
 def test_prompt_window_kernel_compiles_at_other_widths(chip, h, kvh, d, dt):
     """Any Llama-shaped deployment with ``PREFILL_CHUNK`` past its buckets
     reaches the kernel: heads narrower than the 128 lanes (padded in the
     wrapper — Mosaic slices no 64-lane KV head out of ``[K, KVH*64]``),
-    float32 operands, ``n_rep`` 1."""
+    float32 operands, ``n_rep`` 1, and an ``n_rep`` that is no power of two
+    (20: ``tile_sizes`` gives 32 queries a tile, the largest divisor of the
+    window under 1024 // 20 that is whole sublane tiles — the halving rule
+    it replaces ended at ONE query a tile, a block the compiler refuses)."""
     from mlmicroservicetemplate_tpu.ops.prefill_attention import prefill_attention
 
     text = _compiled_text(
@@ -629,6 +635,32 @@ def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows):
              if "tpu_custom_call" in ln and "ssm_scan" in ln]
     assert len(calls) == 1 and scores_in_hbm(fused, decay) == []
     assert scores_in_hbm(text(False), decay)
+
+
+@pytest.mark.parametrize("rows", [3, 1])
+def test_the_selective_scan_holds_no_state_a_token(chip, rows):
+    """The prompt scan of a Mamba-1 layer at Jamba2-3B's widths (5120 channels
+    x 16 states, a decay for each: no matmul form) over a dispatch of ``rows``
+    windows of 1024 tokens compiles for the chip as ONE loop over chunks of
+    ``MAMBA1_CHUNK`` tokens and holds no float32 array of ``rows x chunk x 16
+    x 5120`` elements or more: the state ``[rows, 16, 5120]`` is carried, never
+    kept a token (a pair-composing ``associative_scan`` would write ``[rows,
+    Q, 16, 5120]`` several times a chunk)."""
+    from mlmicroservicetemplate_tpu.ops import ssm
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
+
+    ch, n, length = 5120, 16, 1024
+    f32 = jnp.float32
+    text = _compiled_text(
+        chip, ("mamba1_scan", rows), ssm.mamba1_scan,
+        chip((rows, length, ch), jnp.bfloat16), chip((rows, length, ch), f32),
+        chip((n, ch), f32), chip((rows, length, n), f32),
+        chip((rows, length, n), f32), chip((ch,), f32), chip((rows, n, ch), f32),
+        chip((rows, length), jnp.int32))
+    assert text.count(" while(") == 1 and "tpu_custom_call" not in text
+    # y itself is [rows, 1024, 5120]: anything of a chunk's states is larger
+    assert scores_in_hbm(text, rows * ssm.MAMBA1_CHUNK * n * ch) == [
+        h for h in scores_in_hbm(text, rows * length * ch, exact=True)]
 
 
 @pytest.mark.parametrize("rows", [3, 1])

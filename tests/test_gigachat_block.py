@@ -421,7 +421,7 @@ def test_each_broken_variant_departs_from_the_reference(
     ({"layer_types": ["linear"] * 5}, "one attention layer"),
     ({"layer_types": ["full"] * 5}, "Gated-DeltaNet sizes .* need a 'linear' layer"),
     ({"layer_types": ["linear", "conv", "full", "full", "full"]},
-     "'window', 'full' or 'linear'"),
+     "'window', 'full', 'linear' or 'mamba'"),
 ])
 def test_a_config_that_does_not_add_up_is_refused(kw, bad, needle):
     with pytest.raises(ValueError, match=needle):

@@ -389,7 +389,7 @@ def test_the_checks_state_limit_reads_the_slow_heads_of_the_streams_row(ref, cas
     ({"layer_types": ("full",) * 11}, "stands instead of layer_types"),
     ({"num_dense_layers": 1, "d_ff_dense": 8}, "stands instead of layer_types"),
     ({"expert_act": "gelu"}, "expert_act"),
-    ({"layer_pattern": "*E*E*E*E*E*"}, "need an 'M' layer"),
+    ({"layer_pattern": "*E*E*E*E*E*"}, "need an 'M' or a 'mamba' layer"),
 ])
 def test_a_pattern_that_does_not_add_up_is_refused(kw, bad, needle):
     with pytest.raises(ValueError, match=needle):

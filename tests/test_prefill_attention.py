@@ -63,6 +63,39 @@ def test_kernel_matches_mha_attention_under_the_prefill_mask(n_rep, window,
     assert bool(jnp.isfinite(got).all())  # pad queries too
 
 
+@pytest.mark.parametrize("c,n_rep,cap,tq", [
+    (1024, 20, 1024, 32),  # Jamba's: 1024 // 20 = 51 divides nothing
+    (1024, 5, 1024, 128),  # 204 -> the largest whole-sublane divisor under it
+    (32, 5, 60, 8),  # the toy below
+    (24, 3, 30, 8),  # 10 -> 8, as the halving rule it replaces found
+    (12, 5, 35, 6),  # no multiple of 8 divides 12: the largest divisor
+    (1024, 8, 1024, 128), (2048, 1, 1024, 1024), (1024, 4, 1024, 256),  # as ever
+])
+def test_the_q_tile_divides_the_window_at_any_n_rep(monkeypatch, c, n_rep, cap, tq):
+    """``tile_sizes`` gives a q tile that DIVIDES the window whatever the
+    query-to-KV head ratio: the cap itself where it divides (every ratio the
+    benchmark ran before Jamba's: unchanged), else the largest divisor under
+    it that is whole 8-row sublane tiles, else the largest divisor."""
+    monkeypatch.setattr(pa, "Q_TILE_ROWS", cap)
+    got, _ = pa.tile_sizes(c, n_rep, 6272)
+    assert got == tq and c % got == 0 and got * n_rep <= max(cap, 8 * n_rep)
+
+
+@pytest.mark.parametrize("window", [0, 12], ids=["full", "w<C"])
+def test_five_query_heads_on_one_kv_head_with_a_cap_that_does_not_divide(
+        monkeypatch, window):
+    """``n_rep`` 5 on ONE KV head with 60 rows a program: 12 queries a tile
+    do not divide the 32-query window, 8 do — the kernel's answer is
+    ``mha_attention``'s under the prefill mask, no key tile given."""
+    monkeypatch.setattr(pa, "Q_TILE_ROWS", 60)
+    assert pa.tile_sizes(C, 5, 104)[0] == 8
+    kpos0, k_len = _span(40, window, 104)
+    q, k, v = _qkv(5, 1, k_len, seed=7)
+    mask = (jnp.arange(C) < 27).astype(jnp.int32)
+    got, want = _both(q, k, v, kpos0, 40, mask, window, key_tile=16)
+    np.testing.assert_allclose(got[:27], want[:27], atol=2e-5, rtol=2e-5)
+
+
 def test_latent_heads_score_192_dims_and_weigh_128():
     """DeepSeek-V2's expanded heads: KVH = H, 192 dims for scores (padded
     to 256 lanes inside), 128 for values, with its softmax scale."""

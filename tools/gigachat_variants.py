@@ -44,26 +44,6 @@ def _one_plus_w(cfg, p, x):
                    eps=cfg.rms_eps)
 
 
-def _bf16_stored() -> dict:
-    """``gdn_scan`` and ``gdn_step`` with the state they hand back rounded
-    to bfloat16 (``reduce_precision``: a cast there and back is folded
-    away under jit)."""
-    import jax
-
-    from mlmicroservicetemplate_tpu.ops import ssm
-
-    def rounded(fn):
-        @functools.wraps(fn)
-        def run(*args, **kw):
-            y, state = fn(*args, **kw)
-            return y, jax.lax.reduce_precision(state, 8, 7)
-
-        return run
-
-    return {"ops.ssm.gdn_scan": rounded(ssm.gdn_scan),
-            "ops.ssm.gdn_step": rounded(ssm.gdn_step)}
-
-
 def _beta_one() -> dict:
     import jax.numpy as jnp
 
@@ -111,7 +91,8 @@ VARIANTS = {
     "delta_beta_1": lambda kw, p: (kw, p, _beta_one()),
     "route_scale_1": lambda kw, p: ({**kw, "route_scale": 1.0}, p),
     "no_renormalisation": lambda kw, p: ({**kw, "norm_topk_prob": False}, p),
-    "state_bf16": lambda kw, p: (kw, p, _bf16_stored()),
+    "state_bf16": lambda kw, p: (
+        kw, p, nemotron_variants.bf16_stored("gdn_scan", "gdn_step")),
     "float8_weights": lambda kw, p: (kw, _float8(p)),
 }
 

@@ -88,10 +88,11 @@ def _norm_before_gate(y, z, scale, groups: int, eps: float):
     return y.reshape(shape) * scale * jax.nn.silu(z)
 
 
-def _bf16_stored() -> dict:
-    """``ssm_scan`` and ``ssm_step`` with the state they hand back rounded
-    to bfloat16 (``reduce_precision``: under jit the compiler folds a cast
-    there and back away — my chip run, PR 40)."""
+def bf16_stored(*names: str) -> dict:
+    """Patches for ``ops.ssm``'s ``names`` (a recurrence's scan and step)
+    with the state they hand back rounded to bfloat16 (``reduce_precision``:
+    under jit the compiler folds a cast there and back away — my chip run,
+    PR 40)."""
     import jax
 
     from mlmicroservicetemplate_tpu.ops import ssm
@@ -104,8 +105,7 @@ def _bf16_stored() -> dict:
 
         return run
 
-    return {"ops.ssm.ssm_scan": rounded(ssm.ssm_scan),
-            "ops.ssm.ssm_step": rounded(ssm.ssm_step)}
+    return {f"ops.ssm.{name}": rounded(getattr(ssm, name)) for name in names}
 
 
 def _relu(x):
@@ -127,7 +127,7 @@ VARIANTS = {
     "route_scale_1": lambda kw, p: ({**kw, "route_scale": 1.0}, p),
     "no_renormalisation": lambda kw, p: ({**kw, "norm_topk_prob": False}, p),
     "rotated_qk": lambda kw, p: ({**kw, "nope_on_full": False}, p),
-    "state_bf16": lambda kw, p: (kw, p, _bf16_stored()),
+    "state_bf16": lambda kw, p: (kw, p, bf16_stored("ssm_scan", "ssm_step")),
     "float8_weights": lambda kw, p: (kw, _float8(p)),
 }
 
@@ -242,7 +242,8 @@ def main(argv=None, family: Family | None = None) -> int:
                       "pattern": cfg.layer_pattern or list(cfg.layer_types),
                       "held": cfg.held}), flush=True)
     # what ``readings`` needs of the sound tree, kept when the tree goes
-    head = {"lm_head": {"kernel": jnp.copy(params["lm_head"]["kernel"])}}
+    at = "embed" if cfg.tie_embeddings else "lm_head"  # a tied head is the table
+    head = {at: jax.tree.map(jnp.copy, params[at])}
     for name in ["sound", *family.variants]:
         if a.only and name not in a.only.split(","):
             continue
