@@ -1525,6 +1525,13 @@ async def handle_status(request: web.Request) -> web.Response:
                 "bytes_held": held * engine.stream_fixed_bytes(),
                 "kv_token_bytes": engine.kv_token_bytes(),
             }
+        if getattr(cdl, "moe_rows", None):
+            # Expert FFN: assignment rows the block's row work ran over
+            # and rows it skipped (ops/moe.row_rungs), by step kind.
+            body["decode"]["expert_rows"] = {
+                kind: {"ran": ran, "skipped": skipped}
+                for kind, (ran, skipped) in cdl.moe_rows.items()
+            }
         kv_var = getattr(cdl, "kernel_variant", "")
         if kv_var or getattr(
                 getattr(engine, "cfg", None), "pallas_autotune", False):

@@ -543,6 +543,12 @@ class ContinuousDecodeLoop:
             int(getattr(bcfg, "expert_first", 0) or 0),
             int(getattr(bcfg, "held", 0) or 0),
         )
+        # A share's prompt dispatches since the last chunk dispatch: each
+        # one's (counts [L, E], tokens) on the device, fetched with that
+        # chunk (``_note_dispatched``).
+        self._moe_windows: list = []
+        # kind -> [rows ran, rows skipped] of the expert block (/status).
+        self.moe_rows: dict = {}
         if self.paged:
             from .kv_blocks import blocks_for
 
@@ -3113,7 +3119,7 @@ class ContinuousDecodeLoop:
                     (rows, self.nb_max), self.pool.num_blocks, np.int32)
                 tables[:n] = [job.table_row for job in jobs]
                 with eng._lock:
-                    self._state = eng.dispatch_guard(
+                    out = eng.dispatch_guard(
                         "prefill_chunk",
                         lambda: self._paged_prefill_fn()(
                             jparams, self._state, jnp.asarray(tables),
@@ -3122,6 +3128,13 @@ class ContinuousDecodeLoop:
                         ),
                         donates=self._state,
                     )
+                    if type(out) is tuple:
+                        # A chip's share of the experts: the window's
+                        # counts wait for the next chunk's fetch.
+                        out, counts = out
+                        prefetch_to_host(counts)
+                        self._moe_windows.append(counts)
+                    self._state = out
                 if self.admission is not None:
                     self.admission.note_pool()
                 if self._ssm_free is not None:
@@ -3391,12 +3404,14 @@ class ContinuousDecodeLoop:
                     # compiles while serving; the rows write the same
                     # warm blocks, which is harmless here.
                     for b in sorted({1, self._prefill_width}):
-                        self._state = self._paged_prefill_fn()(
+                        out = self._paged_prefill_fn()(
                             self._mp(n=b), self._state,
                             jnp.asarray(np.tile(table_row, (b, 1))),
                             np.tile(ids_w, (b, 1)), np.tile(mask_w, (b, 1)),
                             np.zeros(b, np.int32), *self._ssm_window_args(b),
                         )
+                        # (state, counts) from a chip's share of the experts.
+                        self._state = out[0] if type(out) is tuple else out
                     self._state = self._paged_handoff_fn()(
                         self._state,
                         np.zeros((1, self.nb_max * self.block_size), np.int32),
@@ -4824,6 +4839,11 @@ class ContinuousDecodeLoop:
             self._note_table_blocks(eng.chunk_tokens)
             if self._window_layers:
                 self._note_window_keys(eng.chunk_tokens)
+        if self._moe_windows:
+            # Dispatched before this chunk, so done before it: their counts
+            # ride its fetch and no fetch waits on a prompt dispatch.
+            entry = ((*entry[0], self._moe_windows), entry[1])
+            self._moe_windows = []
         self._inflight_chunks.append(entry)
 
     def _dispatch_chunk_inner(self, eng) -> None:
@@ -4898,11 +4918,17 @@ class ContinuousDecodeLoop:
         self._note_dispatched(entry)
 
     def _route_entry(self, fetched, snapshot) -> None:
-        """Route one fetched in-flight entry: a chunk's (toks, done)."""
-        toks_np, done_np = fetched
+        """Route one fetched in-flight entry: a chunk's (toks, done), and
+        behind them the (counts, tokens) of a share's prompt dispatches
+        since the chunk before."""
+        toks_np, done_np, *windows = fetched
         if isinstance(toks_np, tuple) and not self.spec:
             toks_np, counts = toks_np
             self._note_moe(counts)
+            self._note_moe_rows(
+                "decode", counts, self.n_slots, self.engine.chunk_tokens)
+        for counts, tokens in (windows[0] if windows else ()):
+            self._note_moe_rows("prefill", counts, int(tokens))
         self._route_chunk(toks_np, done_np, snapshot)
         if self.on_ok is not None:
             # One successfully fetched-and-routed dispatch closes the
@@ -5014,6 +5040,34 @@ class ContinuousDecodeLoop:
             metrics.MOE_LOAD_IMBALANCE.labels(name).observe(
                 float((held.max(axis=1) * held.shape[1] / here).mean())
             )
+
+    def _note_moe_rows(self, kind: str, counts, tokens: int,
+                       steps: int = 1) -> None:
+        """Assignment rows the expert block ran, and rows of its calls it
+        left out, in ``steps`` calls of ``tokens`` tokens an expert layer
+        whose counts ([L, E], summed over the steps) just arrived: each
+        call ran the rung of ``ops/moe.row_rungs`` that holds its held
+        assignments — the rule and the index the device branches on (a
+        window's counts leave out an expert FFN in the model's last
+        layer, which a window never runs: models/registry.py).  A
+        decode step's ladder has one rung at every slot count a cell
+        runs; a chunk whose steps had several would be counted at its
+        steps' mean held count."""
+        from ..ops.moe import row_rungs, rung_index
+
+        bcfg = self.engine.bundle.cfg
+        first, n_held = self._experts_held
+        n = tokens * bcfg.experts_per_token
+        rungs = row_rungs(n, n_held, bcfg.num_experts)
+        held = counts[:, first:first + n_held].sum(axis=1) // steps
+        ran = int(np.take(rungs, rung_index(held, rungs)).sum()) * steps
+        skipped = n * steps * len(counts) - ran
+        seen = self.moe_rows.setdefault(kind, [0, 0])
+        seen[0] += ran
+        seen[1] += skipped
+        name = self.engine.bundle.name
+        metrics.MOE_ROWS.labels(name, kind, "ran").inc(ran)
+        metrics.MOE_ROWS.labels(name, kind, "skipped").inc(skipped)
 
     def _deliver_oldest(self) -> None:
         import jax

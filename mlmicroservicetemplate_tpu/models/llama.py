@@ -2311,6 +2311,7 @@ def paged_prefill_chunk(
     starts: jax.Array,  # [B]
     dtype=jnp.float32,
     ssm_rows: jax.Array | None = None,  # [B, 2] each prompt's (state row, tokens to fold)
+    tally: list | None = None,  # receives each expert layer's [E] counts
 ):
     """One prompt window each of ``B`` different prompts straight into
     pool blocks (see ``gpt.paged_prefill_chunk``), at GQA width and
@@ -2414,7 +2415,8 @@ def paged_prefill_chunk(
                 st.at[at].set(st1, mode="drop"))
 
     recur, ssm_done = _ssm_walker(cfg, state.ssm, scan)
-    _layers(params, cfg, x, attend_rows, recur, lambda: chunk_mask != 0)
+    _layers(params, cfg, x, attend_rows, recur, lambda: chunk_mask != 0,
+            tally)
     return state._replace(cache_k=new_k, cache_v=new_v,
                           ssm=ssm_done())
 

@@ -1060,12 +1060,28 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             p, cfg, state, ids, mask, start, dtype=policy.compute_jnp
         )
 
+    # A chip's share of the experts: a window's expert block runs the rung
+    # of ops/moe.row_rungs its held assignments need, so the dispatch also
+    # returns what says which — (counts [L, E], the call's tokens) — for
+    # the loop's next fetch (moe_rows_total).  Not the LAST layer's: a
+    # window keeps no hidden state, so XLA removes that layer's FFN (and
+    # the attention output under it) from the executable, and asking for
+    # its counts would bring both back (+16 ms a GigaChat dispatch).
+    counted = sum(li != cfg.num_layers - 1 for li in cfg.expert_layers)
+    share = bool(counted) and cfg.held != cfg.num_experts
+
     def paged_prefill_chunk_fn(p, state, table_rows, ids, mask, starts,
                                ssm_rows=None):
-        return llama_mod.paged_prefill_chunk(
+        tally = [] if share else None
+        state = llama_mod.paged_prefill_chunk(
             p, cfg, state, table_rows, ids, mask, starts,
-            dtype=policy.compute_jnp, ssm_rows=ssm_rows,
+            dtype=policy.compute_jnp, ssm_rows=ssm_rows, tally=tally,
         )
+        if share:
+            import jax.numpy as jnp
+
+            return state, (jnp.stack(tally[:counted]), jnp.int32(ids.size))
+        return state
 
     from . import spec as spec_mod
 

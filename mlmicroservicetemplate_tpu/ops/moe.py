@@ -41,6 +41,20 @@ the same seam as an invalid row — past the last group, zeroed, adding
 nothing — but IS counted: ``counts`` stays ``[E]`` over the published
 experts, so held and absent assignments (what the exchange would carry)
 are both known.  Nothing stands in for the absent chips.
+
+**Row work by the rows held** (``row_rungs``).  The sort puts the held
+assignments first, so what XLA does around the grouped matmuls — the
+gather into expert order, the activation, the mask and the combine —
+needs only as many rows as this chip holds (the kernel already runs the
+row tiles its groups reach and no other).  That count is data, so the
+call carries a short ladder of STATIC row counts (``LADDER``: one below
+the whole call, as the cells run it) and each of the three
+pieces branches on the device (``lax.switch`` on ``sum(sizes)``) to the
+lowest rung that holds them all; the last rung is the whole ``t * k``, so
+no assignment is ever dropped or clipped: a router that sends everything
+here runs the top rung, which is the one-rung program.  Where the tree
+holds every expert, or the call is a few row tiles (a decode step), the
+ladder has ONE rung and the traced program holds no conditional.
 """
 
 from __future__ import annotations
@@ -90,6 +104,49 @@ def matmul_tiles(k: int, n: int, itemsize: int) -> tuple[int, int, int]:
             if tile_bytes(ROW_TILE, tk, tn, itemsize) <= VMEM_BUDGET:
                 return ROW_TILE, tk, tn
     raise ValueError(f"no tiling of a [{k}, {n}] expert fits {VMEM_BUDGET} B")
+
+
+#: Rungs of ``row_rungs`` below the whole call, as (numerator, denominator)
+#: multiples of the rows an even router sends this chip's share of the
+#: experts (``n * held / published``): ONE, a quarter more than that share
+#: (the cells' routers send 0.98-1.04 of it), then every row.  A rung is
+#: three small XLA branches an expert layer of an executable — the grouped
+#: matmuls and the sorts stay outside them — and still ~1.6 MiB of it,
+#: mostly its two gathers: a second rung at twice the share cost as much
+#: again in every boot for rows no cell's router sends (PERF.md section 6,
+#: PR 52).
+LADDER = ((5, 4),)
+
+#: Fewest rows a rung has to leave out to be a rung: below it (a decode
+#: step's 192-704 rows, a small wave, a lone 1024-token window of eight
+#: experts a token) the block is bound by the experts' bytes, not by its
+#: rows, a rung's branches (~1.6 MiB of executable an expert layer, mostly
+#: its two gathers) buy little, and the ladder has one rung.
+LADDER_MIN_SKIP = 64 * ROW_TILE
+
+
+def row_rungs(n: int, n_exp: int, n_pub: int) -> tuple[int, ...]:
+    """The static row counts ``expert_ffn`` may run its row work over, for
+    ``n = t * k`` assignments and a tree that holds ``n_exp`` of ``n_pub``
+    published experts: rising, each below the last a multiple of
+    ``ROW_TILE``, the last always ``n`` itself.  ``(n,)`` — no branch —
+    where every expert is held or no rung would leave out
+    ``LADDER_MIN_SKIP`` rows."""
+    rungs: list[int] = []
+    if n_exp != n_pub:
+        for num, den in LADDER:
+            r = -(-n * n_exp * num // (n_pub * den * ROW_TILE)) * ROW_TILE
+            if n - r >= LADDER_MIN_SKIP and (not rungs or r > rungs[-1]):
+                rungs.append(r)
+    return (*rungs, n)
+
+
+def rung_index(held, rungs: tuple[int, ...]):
+    """Index of the lowest rung of ``rungs`` that holds ``held`` rows
+    (``held`` at most the last rung): the one rule for the device's branch
+    (``held`` a traced scalar) and the host's count of the rows that ran
+    (an int or a numpy array of counts; the index has ``held``'s shape)."""
+    return sum(((held > r) * 1 for r in rungs[:-1]), held * 0)
 
 
 def grouped_matmul(lhs: jax.Array, rhs: jax.Array, group_sizes: jax.Array,
@@ -217,20 +274,74 @@ def expert_ffn(h: jax.Array, mlp, k: int, norm_topk: bool, valid: jax.Array,
             e = jnp.where(held, e - expert_first, n_exp)
             sizes = counts[expert_first:expert_first + n_exp]
         order = jnp.argsort(e)  # stable: assignment i of token i // k
-        xs = jnp.take(rows, order // k, axis=0)  # [T*k, D], sorted by expert
+    mm = functools.partial(grouped_matmul, interpret=interpret)
+    n_all, rungs = t * k, row_rungs(t * k, n_exp, n_pub)
+
+    def on_rung(work):
+        """``work(n)`` — a piece of the block's row work over the first
+        ``n`` sorted assignments, its result the whole call's shape — on
+        the lowest rung that holds every held assignment.  The grouped
+        matmuls stay OUTSIDE the branches, over all ``t * k`` rows (the
+        kernel runs the row tiles the groups reach and no other, whatever
+        M is), so a rung adds three small XLA branches an expert layer to
+        an executable and no kernel."""
+        if len(rungs) == 1:
+            return work(n_all)
+        return jax.lax.switch(
+            rung_index(jnp.sum(sizes), rungs),
+            [functools.partial(work, n) for n in rungs])
+
+    def head(a, n: int):  # a's first n rows
+        return a if n == n_all else a[:n]
+
+    def whole(a, n: int):  # n rows -> the call's t * k, zeros behind them
+        return a if n == n_all else jnp.pad(a, ((0, n_all - n), (0, 0)))
+
+    def over(base, a, n: int):  # base's first n rows replaced by a
+        return a if n == n_all else base.at[:n].set(a)
+
+    def combine(n: int):
+        if n == n_all:
+            in_group = jnp.arange(n_all) < jnp.sum(sizes)
+            back = jnp.take(
+                jnp.where(in_group[:, None], ys, 0),
+                jnp.argsort(order) if pos is None else pos, axis=0).reshape(
+                    t, k, rows.shape[1])
+            return jnp.sum(back.astype(jnp.float32) * w[:, :, None], axis=1)
+        # An assignment that joined no group reads the zero row behind the
+        # rung's rows, so a row past the last group is never read.
+        # Slot-major: a token's k rows are k slabs of [T, D], summed slab
+        # by slab.
+        at = pos.reshape(t, k).T
+        at = jnp.where(at < jnp.sum(sizes), at, n)
+        src = jnp.concatenate([ys[:n], jnp.zeros_like(ys[:1])])
+        back = jnp.take(src, at.reshape(-1), axis=0).reshape(
+            k, t, rows.shape[1])
+        return jnp.sum(back.astype(jnp.float32) * w.T[:, :, None], axis=0)
+
+    with jax.named_scope("moe_route"):
+        # [T*k, D], sorted by expert
+        xs = on_rung(lambda n: whole(
+            jnp.take(rows, head(order, n) // k, axis=0), n))
     with jax.named_scope("moe_experts"):
-        mm = functools.partial(grouped_matmul, interpret=interpret)
+        # A lower rung activates its rows where they lie: the rows behind
+        # them stay what the kernel left there (no group's, read by none).
         if act == "relu2":
-            mid = _relu2(mm(xs, up, sizes))
+            ups = mm(xs, up, sizes)
+            mid = on_rung(lambda n: over(ups, _relu2(head(ups, n)), n))
         else:
-            mid = gate_act(mm(xs, mlp["gate"]["kernel"], sizes)) * clip(mm(xs, up, sizes))
+            gates = gate_act(mm(xs, mlp["gate"]["kernel"], sizes))
+            ups = mm(xs, up, sizes)
+            mid = on_rung(lambda n: over(
+                gates, head(gates, n) * clip(head(ups, n)), n))
         ys = mm(mid, down, sizes)  # [T*k, D]
     with jax.named_scope("moe_combine"):
-        in_group = jnp.arange(t * k) < jnp.sum(sizes)
-        ys = jnp.where(in_group[:, None], ys, 0)
-        back = jnp.take(ys, jnp.argsort(order), axis=0).reshape(
-            t, k, rows.shape[1])
-        out = jnp.sum(back.astype(jnp.float32) * w[:, :, None], axis=1)
+        # Where each assignment's row lies in expert order — with rungs,
+        # sorted once outside the branches: a sort is megabytes of
+        # executable (one rung sorts where it always has: the program as
+        # it was, digest for digest, tools/lowered_text).
+        pos = None if len(rungs) == 1 else jnp.argsort(order)
+        out = on_rung(combine)
     out = out.astype(h.dtype)
     if "latent_up" in mlp:
         with jax.named_scope("moe_latent_up"):

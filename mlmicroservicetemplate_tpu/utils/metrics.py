@@ -198,6 +198,17 @@ MOE_ASSIGNMENTS_ABSENT = Counter(
     "carry off this chip",
     ["model"],
 )
+MOE_ROWS = Counter(
+    "moe_rows_total",
+    "Expert FFN: assignment rows (tokens x experts_per_token, an expert "
+    "layer a call) the row work around the grouped matmuls — gather, "
+    "activation, mask, combine — ran over, and rows of its calls it "
+    "skipped because this chip's held assignments fit a lower rung "
+    "(ops/moe.row_rungs), by step kind: decode (delivered paged chunks) "
+    "| prefill (prompt-window dispatches of a chip's share of the "
+    "experts), from each call's own counts",
+    ["model", "kind", "state"],
+)
 SSM_STATE_BYTES = Gauge(
     "ssm_state_bytes",
     "Recurrent layers: bytes of recurrent state (a layer's float32 state "

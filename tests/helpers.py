@@ -253,3 +253,27 @@ def one_wave(cdl, cap_s: float = 5.0):
     cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, cap_s)
     with Arrival([cdl.queue]):
         yield
+
+
+def expert_rungs_at_toy_size(monkeypatch, tile: int = 8) -> list:
+    """Let the expert block's ladder of row counts (``ops/moe.row_rungs``)
+    engage at a toy's few hundred assignments — rows by ``tile``, a rung for
+    ``tile`` rows left out — and record every EAGER ``expert_ffn`` call:
+    returns the list that receives ``(rungs, rows ran)``, the rung chosen
+    by the call's own held count as the device chooses it."""
+    from mlmicroservicetemplate_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "ROW_TILE", tile)
+    monkeypatch.setattr(moe, "LADDER_MIN_SKIP", tile)
+    calls, real = [], moe.expert_ffn
+
+    def keep(h, mlp, k, *args, expert_first=0, **kw):
+        out, counts = real(h, mlp, k, *args, expert_first=expert_first, **kw)
+        held = mlp["up"]["kernel"].shape[0]
+        rungs = moe.row_rungs(h.shape[0] * k, held, counts.shape[0])
+        here = int(counts[expert_first:expert_first + held].sum())
+        calls.append((rungs, rungs[int(moe.rung_index(here, rungs))]))
+        return out, counts
+
+    monkeypatch.setattr(moe, "expert_ffn", keep)
+    return calls

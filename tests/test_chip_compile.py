@@ -536,7 +536,7 @@ def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads, windows):
     layer and NO float32 array of heads x queries x keys
     (``prefill_scores_in_hbm: []``): until PR 34 XLA wrote and re-read one
     a layer (0.4-0.8 GB each).  The ``lax.switch`` over key widths is
-    gone with it: one attention a layer, no ``conditional``.  A batched
+    gone with it: one attention a layer, no ``conditional`` there.  A batched
     dispatch (``windows`` prompts' windows, PR 36) holds the kernel once a
     row a layer and still writes every row's keys into the pool in
     place: donated, aliased, no pool-sized copy."""
@@ -555,7 +555,11 @@ def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads, windows):
         assert len(calls) == windows * sum(
             "tpu_custom_call" in ln and "prefill_attention" in ln
             for ln in alone.splitlines())
-    assert " conditional(" not in text
+    # None but the expert block's of a chip's share of the experts, which
+    # branches on its held rows (ops/moe.row_rungs, PR 52): DeepSeek-V2's
+    # window, at most its gather, activation and combine of 4 layers (XLA
+    # folds some of the two-way branches).
+    assert text.count(" conditional(") <= (12 if cell == "dsv2" else 0)
     assert "input_output_alias" in text
     from mlmicroservicetemplate_tpu.ops.paged_attention import pool_relayouts
 
@@ -790,6 +794,42 @@ def test_grouped_matmul_compiles(chip, rows, experts, d, w):
             sizes,
         )
         assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("tokens,k,held,pub,d,latent,w,act", [
+    (3072, 22, 128, 512, 4096, 1024, 2688, "relu2"),
+    (3072, 8, 16, 256, 7168, 0, 2048, "silu"),
+    (2048, 6, 40, 160, 5120, 0, 1536, "silu"),
+], ids=["nemotron-dispatch", "gigachat-dispatch", "dsv2-window"])
+def test_the_expert_blocks_ladder_compiles_at_a_shares_prompt_shapes(
+        chip, tokens, k, held, pub, d, latent, w, act):
+    """One expert layer's block of the three cells that hold a chip's share,
+    at a prompt dispatch's tokens and the served widths: the gather, the
+    activation and the combine each a conditional with a branch a rung
+    (``ops/moe.row_rungs``: 5/4 of the share an even router sends the
+    held experts, then every row), the grouped matmuls outside them — as many kernels as the
+    one-rung program has (a kernel a branch was 5.7 MiB of executable a
+    rung a layer: PERF.md section 6, PR 52)."""
+    from mlmicroservicetemplate_tpu.ops import moe
+
+    rungs = moe.row_rungs(tokens * k, held, pub)
+    assert len(rungs) == 2 and rungs[0] * 16 * pub == tokens * k * held * 20
+    bf, wide = jnp.bfloat16, latent or d
+    mlp = {"router": {"kernel": chip((d, pub), jnp.float32)},
+           "up": {"kernel": chip((held, wide, w), bf)},
+           "down": {"kernel": chip((held, w, wide), bf)}}
+    if act == "silu":
+        mlp["gate"] = mlp["up"]
+    if latent:
+        mlp["latent_down"] = {"kernel": chip((d, latent), bf)}
+        mlp["latent_up"] = {"kernel": chip((latent, d), bf)}
+    text = _compiled_text(
+        chip, ("ladder", tokens, k, held, pub, d, w),
+        lambda h, m, valid: moe.expert_ffn(
+            h, m, k, True, valid, act=act, score="sigmoid"),
+        chip((tokens, d), bf), mlp, chip((tokens,), jnp.bool_))
+    assert text.count(" conditional(") == 3
+    assert text.count("tpu_custom_call") == (3 if act == "silu" else 2)
 
 
 def _largest_fit(kvh: int, d: int) -> int:

@@ -235,6 +235,26 @@ def test_program_matches_the_reference(ref, config, cfg, params, path, monkeypat
     assert float(jnp.abs(state.cache_k[1][..., : cfg.latent_dim]).max()) > 0.0
 
 
+def test_a_prompt_window_on_a_rung_below_the_top_is_the_reference(
+        ref, config, cfg, params, monkeypatch):
+    """Windows of 16 tokens (48 assignments, 4 of 16 experts held: rungs
+    16 and 48 once a rung is 8 rows) through the latent pool, then two
+    decode steps: the logits are the reference's, and expert layers ran
+    below the top rung — the rows an absent expert would have taken were
+    never gathered, multiplied or combined."""
+    from helpers import expert_rungs_at_toy_size
+
+    calls = expert_rungs_at_toy_size(monkeypatch)
+    prompts = [_ids(27, 41)]
+    got, toks, _ = _serve(
+        params, dataclasses.replace(cfg, pallas_decode=False), prompts, 2, 16,
+        monkeypatch)
+    assert _close(got, _teacher_forced(ref, config, params, prompts, toks)) < TOL
+    windows = [ran for rungs, ran in calls if rungs == (16, 48)]
+    assert len(windows) == 2 * len(cfg.expert_layers) and min(windows) < 48
+    assert all(len(rungs) == 1 for rungs, _ in calls if rungs[-1] == 3)  # the steps
+
+
 def test_prefill_wave_state_inserts_as_one_latent_slab_a_layer(cfg, params):
     """What the wave path hands ``engine/streams.paged_insert``: a latent
     slab a layer in ``cache_k``, nothing in ``cache_v`` — and straight

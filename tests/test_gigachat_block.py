@@ -357,6 +357,21 @@ def test_the_wave_forward_is_the_reference(ref, config, cfg, params, n):
     assert _close(got, ref.logits(params, ref.hyper(config), ids)) < TOL
 
 
+def test_a_prompts_forward_on_a_rung_below_the_top_is_the_reference(
+        ref, config, cfg, params, monkeypatch):
+    """One prompt of 45 tokens (180 assignments, 4 of 16 experts held:
+    rungs 64 and 180 once a rung is 8 rows): the logits are the
+    reference's with the expert layers' row work on a lower rung."""
+    from helpers import expert_rungs_at_toy_size
+
+    calls = expert_rungs_at_toy_size(monkeypatch)
+    ids = _ids(45, 5)[None]
+    got = llama_mod.lm_logits(params, cfg, ids, np.ones_like(ids))
+    assert _close(got, ref.logits(params, ref.hyper(config), ids)) < TOL
+    assert {rungs for rungs, _ in calls} == {(64, 180)}
+    assert len(calls) == len(cfg.expert_layers) and min(r for _, r in calls) < 180
+
+
 def test_prefill_then_decode_is_the_reference(ref, config, cfg, params):
     """A ragged wave's prefill, then six decode steps through the one-token
     delta rule and the absorbed attention: every token the reference's

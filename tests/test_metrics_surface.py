@@ -344,3 +344,24 @@ def test_recurrent_state_families_are_declared_and_a_row_ledger_feeds_them():
     states = {ln.split('state="')[1].split('"')[0]
               for ln in _sample_lines(text) if ln.startswith("ssm_state_rows{")}
     assert states <= {"live", "prefill", "free"}
+
+
+def test_expert_rows_family_is_declared_and_bounded():
+    """``moe_rows_total`` (PR 52): declared (so ``/metrics`` carries its
+    HELP line for every model), three labels, ``kind`` decode | prefill
+    and ``state`` ran | skipped — ``tests/test_moe.py`` holds the loop's
+    counting to the ladder's rule, ``tests/test_nemotron_serving.py``
+    drives the loop itself."""
+    assert "moe_rows" in _declared_families()
+    assert metrics.MOE_ROWS._labelnames == ("model", "kind", "state")
+    for kind, state, n in (("decode", "ran", 704), ("decode", "skipped", 0),
+                           ("prefill", "ran", 21120), ("prefill", "skipped", 46464)):
+        metrics.MOE_ROWS.labels("surface-check", kind, state).inc(n)
+    text = _scrape_body()
+    for line in (
+            'moe_rows_total{kind="prefill",model="surface-check",state="skipped"} 46464.0',
+            'moe_rows_total{kind="decode",model="surface-check",state="skipped"} 0.0'):
+        assert line in text, line
+    seen = {(ln.split('kind="')[1].split('"')[0], ln.split('state="')[1].split('"')[0])
+            for ln in _sample_lines(text) if ln.startswith("moe_rows_total{")}
+    assert seen <= {(k, s) for k in ("decode", "prefill") for s in ("ran", "skipped")}

@@ -599,3 +599,225 @@ def test_converter_round_trip(cfg, params):
     assert jax.tree.structure(back) == jax.tree.structure(params)
     for x, y in zip(jax.tree.leaves(back), jax.tree.leaves(params)):
         np.testing.assert_array_equal(np.asarray(x), np.asarray(y))
+
+
+# ---------------------------------------------------------------------------
+# the ladder of row counts (ops/moe.row_rungs): a chip's share of the experts
+
+
+# (tokens a decode step, k, held, published) of the five expert configurations'
+# cells; a prompt dispatch's tokens beside them for the three that hold a share.
+CELL_EXPERTS = {
+    "olmoe": (64, 8, 64, 64, 0),
+    "trinity": (32, 8, 128, 128, 0),
+    "deepseek-v2": (32, 6, 40, 160, 2048),
+    "nemotron": (32, 22, 128, 512, 3072),
+    "gigachat": (32, 8, 16, 256, 3072),
+}
+
+
+@pytest.mark.parametrize("n, held, pub", [
+    (67584, 128, 512), (22528, 128, 512), (24576, 16, 256), (8192, 16, 256),
+    (12288, 40, 160), (704, 128, 512), (65536, 64, 64), (100000, 3, 4),
+    (6000, 1, 2), (5000, 7, 8)])
+def test_the_rungs_rise_in_row_tiles_and_end_at_every_row(n, held, pub):
+    rungs = moe.row_rungs(n, held, pub)
+    assert rungs[-1] == n and list(rungs) == sorted(set(rungs))
+    for r in rungs[:-1]:
+        assert r % moe.ROW_TILE == 0 and n - r >= moe.LADDER_MIN_SKIP
+        assert r * pub >= n * held  # an even router's share fits every rung
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_EXPERTS))
+def test_a_decode_step_and_a_whole_tree_have_one_rung(cell):
+    t, k, held, pub, prompt = CELL_EXPERTS[cell]
+    assert moe.row_rungs(t * k, held, pub) == (t * k,)
+    assert moe.row_rungs(3072 * k, pub, pub) == (3072 * k,)
+    if prompt:  # a chip's share: the prompt dispatch has rungs below the top
+        rungs = moe.row_rungs(prompt * k, held, pub)
+        # 5/4 of the share an even router sends the held experts, then all
+        assert len(rungs) == 2 and rungs[0] * 16 * pub == prompt * k * held * 20
+
+
+def _share(key, d=16, w=8, pub=8, held=2):
+    """A tree that holds experts ``0 .. held - 1`` of ``pub``, and a router
+    whose three leading input features decide a token's two experts: (1,
+    0, 0) both held, (0, 1, 0) one held and one absent, (0, 0, 1) both
+    absent."""
+    ks = jax.random.split(key, 4)
+    router = jax.random.normal(ks[0], (d, pub)) * 0.01
+    router = router.at[:3].set(0.0).at[0, :2].set(1.0).at[1, 0].set(1.0)
+    router = router.at[1, 5].set(1.0).at[2, 4:6].set(1.0)
+    return {
+        "router": {"kernel": router},
+        "gate": {"kernel": jax.random.normal(ks[1], (held, d, w)) * 0.3},
+        "up": {"kernel": jax.random.normal(ks[2], (held, d, w)) * 0.3},
+        "down": {"kernel": jax.random.normal(ks[3], (held, w, d)) * 0.3},
+    }
+
+
+def _share_tokens(key, t, held, d=16):
+    """``t`` tokens of which exactly ``held`` assignments (two a token)
+    land on the held experts, the kinds shuffled."""
+    both, one = held // 2, held % 2
+    kind = jnp.asarray([0] * both + [1] * one + [2] * (t - both - one))
+    kind = jax.random.permutation(jax.random.fold_in(key, 1), kind)
+    h = jax.random.normal(key, (t, d))
+    return h.at[:, :3].set(20.0 * jax.nn.one_hot(kind, 3))
+
+
+def _share_reference(h, mlp, k):
+    """The held experts of the top-k over ALL published experts, densely,
+    in float32."""
+    p = jax.nn.softmax(h @ mlp["router"]["kernel"], axis=-1)
+    w, e = jax.lax.top_k(p, k)
+    gate, up, down = (mlp[n]["kernel"] for n in ("gate", "up", "down"))
+    y = jnp.einsum(
+        "tew,ewd->ted",
+        jax.nn.silu(jnp.einsum("td,edw->tew", h, gate))
+        * jnp.einsum("td,edw->tew", h, up), down)
+    weight = jnp.sum(
+        jnp.where(e[:, :, None] == jnp.arange(gate.shape[0]), w[:, :, None], 0.0),
+        axis=1)  # [T, held]
+    return jnp.einsum("te,ted->td", weight, y)
+
+
+def _count(jaxpr, name: str) -> int:
+    """Equations of primitive ``name`` in a jaxpr and in the jaxprs it
+    calls, a Pallas kernel's own body (its ``pl.when``) left out."""
+    n = 0
+    for eqn in jaxpr.eqns:
+        n += eqn.primitive.name == name
+        if eqn.primitive.name != "pallas_call":
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += _count(sub, name)
+    return n
+
+
+T_LADDER, K_LADDER = 512, 2
+TWO_BELOW = ((5, 4), (2, 1))  # a ladder of three rungs: the rule takes any
+RUNGS = (384, 512, 1024)  # of 1024 assignments, 2 of 8 experts held
+
+
+@pytest.fixture(scope="module")
+def ladder_fns():
+    """``expert_ffn`` over ``T_LADDER`` tokens with a ladder of three rungs
+    (a rung needs to leave out 128 rows here, not 8192) and with one rung,
+    each compiled once: the held count is data."""
+    def build(ladder):
+        def run(h, mlp):
+            keep = moe.LADDER, moe.LADDER_MIN_SKIP
+            moe.LADDER, moe.LADDER_MIN_SKIP = ladder, 128
+            try:
+                return moe.expert_ffn(
+                    h, mlp, K_LADDER, False, jnp.ones((h.shape[0],), bool),
+                    interpret=True)
+            finally:
+                moe.LADDER, moe.LADDER_MIN_SKIP = keep
+        return jax.jit(run)
+
+    return build(TWO_BELOW), build(())
+
+
+@pytest.mark.parametrize(
+    "held", [0, 383, 384, 385, 511, 512, 513, 1023, 1024])
+def test_every_rung_is_the_one_rung_program_and_the_reference(ladder_fns, held):
+    """The held count at 0, one under, at and one over every rung, and at
+    ``t * k`` (every assignment lands here: the top rung runs, nothing is
+    dropped): the output is the one-rung program's bit for bit, the plain
+    float32 reference's within rounding, the counts identical."""
+    ladder, one_rung = ladder_fns
+    mlp = _share(jax.random.PRNGKey(11))
+    h = _share_tokens(jax.random.PRNGKey(held), T_LADDER, held)
+    out, counts = ladder(h, mlp)
+    assert int(counts[:2].sum()) == held and int(counts.sum()) == 1024
+    want, want_counts = one_rung(h, mlp)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(want))
+    assert _close(out, _share_reference(h, mlp, K_LADDER)) < 1e-5
+
+
+def test_the_ladder_of_the_rung_tests_has_two_rungs_below_the_top(monkeypatch):
+    monkeypatch.setattr(moe, "LADDER_MIN_SKIP", 128)
+    assert moe.row_rungs(T_LADDER * K_LADDER, 2, 8) == (384, 1024)  # as shipped
+    monkeypatch.setattr(moe, "LADDER", TWO_BELOW)
+    assert moe.row_rungs(T_LADDER * K_LADDER, 2, 8) == RUNGS
+    mlp = _share(jax.random.PRNGKey(0))
+    jaxpr = jax.make_jaxpr(lambda h: moe.expert_ffn(
+        h, mlp, K_LADDER, False, jnp.ones((T_LADDER,), bool),
+        interpret=True))(jnp.zeros((T_LADDER, 16)))
+    # a branch each for the gather, the activation and the combine: the
+    # test below can see one; the grouped matmuls stay outside them
+    assert _count(jaxpr.jaxpr, "cond") == 3
+    assert _count(jaxpr.jaxpr, "pallas_call") == 3
+
+
+@pytest.mark.parametrize("held", [0, 1, 383, 384, 385, 512, 513, 1024])
+def test_the_hosts_rung_is_the_devices(held):
+    """``rung_index`` on a host int, on a numpy array of counts and on a
+    traced scalar (what ``lax.switch`` branches on) name the same rung:
+    the lowest that holds the count."""
+    want = next(i for i, r in enumerate(RUNGS) if held <= r)
+    assert moe.rung_index(held, RUNGS) == want
+    np.testing.assert_array_equal(
+        moe.rung_index(np.asarray([held, 0, 1024]), RUNGS), [want, 0, 2])
+    on_device = jax.jit(lambda c: moe.rung_index(c, RUNGS))(jnp.int32(held))
+    assert int(on_device) == want
+    assert moe.rung_index(held, (1024,)) == 0
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_EXPERTS))
+def test_one_rung_traces_no_conditional(cell):
+    """A decode step of each expert configuration (its experts' counts and
+    k at toy widths), and a prompt's worth of tokens over a tree that holds
+    every expert: the jaxpr has no ``cond``."""
+    t, k, held, pub, _ = CELL_EXPERTS[cell]
+    ks = jax.random.split(jax.random.PRNGKey(0), 4)
+    stack = lambda key, *shape: jax.ShapeDtypeStruct(shape, jnp.float32)  # noqa: E731
+    mlp = {"router": {"kernel": stack(ks[0], 16, pub)},
+           "gate": {"kernel": stack(ks[1], held, 16, 8)},
+           "up": {"kernel": stack(ks[2], held, 16, 8)},
+           "down": {"kernel": stack(ks[3], held, 8, 16)}}
+    whole = {n: {"kernel": stack(None, *((pub,) + v["kernel"].shape[1:]))}
+             if n != "router" else v for n, v in mlp.items()}
+    for tokens, tree in ((t, mlp), (3072, whole)):
+        jaxpr = jax.make_jaxpr(lambda h, m: moe.expert_ffn(
+            h, m, k, True, jnp.ones((h.shape[0],), bool), interpret=True))(
+                jax.ShapeDtypeStruct((tokens, 16), jnp.float32), tree)
+        assert _count(jaxpr.jaxpr, "cond") == 0
+
+
+@pytest.mark.parametrize("kind, tokens, steps, k, held, pub, here, ran", [
+    # Nemotron's three-window dispatch: a layer on the lower rung, one a row
+    # over it and one far over it on the whole call
+    ("prefill", 3072, 1, 22, 128, 512, [17000, 21121, 40000], 21120 + 67584 + 67584),
+    # GigaChat's: 6.5 % held, and a layer every assignment lands on
+    ("prefill", 3072, 1, 8, 16, 256, [1520, 24576], 1920 + 24576),
+    # a decode chunk of four steps: one rung, every row runs
+    ("decode", 32, 4, 22, 128, 512, [4 * 170, 4 * 700], 2 * 4 * 704),
+    # every expert held: one rung whatever the tokens
+    ("prefill", 3072, 1, 8, 64, 64, [24576], 24576),
+])
+def test_the_loop_counts_the_rows_each_call_ran_from_its_own_counts(
+        kind, tokens, steps, k, held, pub, here, ran):
+    """``_note_moe_rows`` on counts as they arrive ([L, E], this chip's
+    experts first): rows ran = each layer's rung by ITS held count — the
+    device's rule — and skipped the rest of ``L x tokens x k x steps``."""
+    from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
+    from mlmicroservicetemplate_tpu.utils import metrics
+
+    loop = ContinuousDecodeLoop.__new__(ContinuousDecodeLoop)
+    bcfg = type("C", (), {"experts_per_token": k, "num_experts": pub})
+    loop.engine = type("E", (), {"bundle": type(
+        "B", (), {"name": f"moe-rows-{kind}-{pub}-{held}", "cfg": bcfg})})()
+    loop._experts_held, loop.moe_rows = (0, held), {}
+    counts = np.zeros((len(here), pub), np.int64)
+    counts[:, 0] = here
+    if held != pub:
+        counts[:, held] = tokens * k * steps - np.asarray(here)  # the absent
+    loop._note_moe_rows(kind, counts, tokens, steps)
+    total = len(here) * tokens * k * steps
+    assert loop.moe_rows == {kind: [ran, total - ran]}
+    name = loop.engine.bundle.name
+    assert metrics.MOE_ROWS.labels(name, kind, "ran")._value.get() == ran
+    assert metrics.MOE_ROWS.labels(name, kind, "skipped")._value.get() == total - ran

@@ -254,6 +254,45 @@ def test_the_loop_serves_waves_and_windows_as_the_reference(monkeypatch, kw, ref
     assert eng.kv_bytes_estimate(feats[0]) == (16 + 12) * 384 + 2 * 4864
 
 
+def test_the_loop_counts_the_expert_rows_its_windows_ran_and_skipped(monkeypatch, kw):  # noqa: F811
+    """Two long prompts through windows (8 tokens x 5 experts a token, 4 of
+    16 experts held; a rung is 8 rows and leaves out 16 here): the prompt dispatches' counts
+    ride the next chunk's fetch, ``moe_rows_total`` and ``/status``'s
+    ``expert_rows`` count what each dispatch's expert layers ran and left
+    out from them — some rows skipped in the windows, none in the decode
+    steps, whose ladder has one rung."""
+    from mlmicroservicetemplate_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    monkeypatch.setattr(moe, "LADDER_MIN_SKIP", 16)  # a step's 20 rows: one rung
+    bundle = _bundle(monkeypatch, kw)
+    cfgc = _loop_cfg()
+    eng = InferenceEngine(bundle, cfgc, ReplicaSet(make_mesh(1)))
+
+    def seen():
+        return {(kind, state): metrics.MOE_ROWS.labels("llama", kind, state)._value.get()
+                for kind in ("decode", "prefill") for state in ("ran", "skipped")}
+
+    before = seen()
+    cdl = ContinuousDecodeLoop(eng, cfgc)
+    try:
+        outs = _run(cdl, _feats((30, 45), seed=3))
+        rows = {k: list(v) for k, v in cdl.moe_rows.items()}
+        windows, chunks = cdl.prefill_chunk_dispatches, cdl.chunk_dispatches
+    finally:
+        cdl.stop()
+    assert all(len(t) == 12 for t in outs) and not cdl._moe_windows
+    grown = {key: v - before[key] for key, v in seen().items()}
+    assert grown == {(kind, state): rows[kind][i] for kind in rows
+                     for i, state in enumerate(("ran", "skipped"))}
+    layers, k = len(bundle.cfg.expert_layers), bundle.cfg.experts_per_token
+    # a window alone is 8 tokens, a batch the full width of 3 x 8
+    assert sum(rows["prefill"]) % (layers * 8 * k) == 0
+    assert layers * 8 * k * windows <= sum(rows["prefill"]) <= layers * 24 * k * windows
+    assert rows["prefill"][1] > 0
+    assert rows["decode"] == [chunks * 4 * layers * cdl.n_slots * k, 0]
+
+
 def test_a_model_without_recurrent_layers_has_none_of_it():
     bundle = tiny_llama_bundle()
     cfgc = _cfg(paged_kv=True, kv_block_size=8, prefill_chunk=8, prefill_max_prompt=48)
