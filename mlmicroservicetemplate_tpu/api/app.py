@@ -23,7 +23,7 @@ from aiohttp import web
 
 from ..models.registry import KIND_SEQ2SEQ, ModelBundle, RawItem
 from ..scheduler import Batcher, DeadlineExceededError, QueueFullError
-from ..utils import metrics, tracing
+from ..utils import metrics, pauses, tracing
 
 log = logging.getLogger(__name__)
 
@@ -74,7 +74,9 @@ async def request_id_middleware(request: web.Request, handler):
     rid = request.headers.get("X-Request-Id") or uuid.uuid4().hex[:16]
     request["request_id"] = rid
     tr = tracing.tracer()
-    t0 = time.monotonic()
+    # The request's ONE start stamp: the "request" span, every latency
+    # histogram and the first token's stages count from here.
+    t0 = request["t0"] = time.monotonic()
     status = 500
     try:
         resp = await handler(request)
@@ -108,6 +110,32 @@ async def request_id_middleware(request: web.Request, handler):
     if not resp.prepared:
         resp.headers.setdefault("X-Request-Id", rid)
     return resp
+
+
+def _t0(request: web.Request) -> float:
+    """When the middleware first saw this request (a handler driven
+    without it stamps its own entry)."""
+    t0 = request.get("t0")
+    if t0 is None:
+        t0 = request["t0"] = time.monotonic()
+    return t0
+
+
+async def _json_body(request: web.Request):
+    """``request.json()`` with the parse itself as the phase
+    ``api/parse`` (reading the body is the client's time, not in it)."""
+    text = await request.text()
+    with tracing.phase(
+        "api/parse", cat="http", rid=request.get("request_id", "")
+    ):
+        return json.loads(text)
+
+
+def _preprocess(bundle: ModelBundle, item: RawItem, rid: str) -> dict:
+    """``bundle.preprocess`` on an executor thread, as the phase
+    ``api/tokenize``: the wait for that thread is not in it."""
+    with tracing.phase("api/tokenize", cat="http", rid=rid):
+        return bundle.preprocess(item)
 
 
 def _expect_stream(request: web.Request) -> None:
@@ -192,6 +220,11 @@ def build_app(cfg, bundle: ModelBundle, engine, batcher: Batcher) -> web.Applica
 async def _on_startup(app: web.Application) -> None:
     cfg, engine, batcher = app[K_CFG], app[K_ENGINE], app[K_BATCHER]
     await batcher.start()
+    # The process's own pauses (utils/pauses.py), always on: this event
+    # loop's lag and the collector's pauses.
+    pauses.GC.install()
+    app[K_STATE]["loop_lag"] = lag = pauses.EventLoopLag(app[K_BUNDLE].name)
+    lag.start(asyncio.get_running_loop())
 
     async def warm_then_ready():
         # Failures here must be loud and visible: a swallowed warmup
@@ -350,6 +383,9 @@ async def _replay_journal(app: web.Application) -> None:
 
 
 async def _on_cleanup(app: web.Application) -> None:
+    lag = app[K_STATE].get("loop_lag")
+    if lag is not None:
+        lag.stop()
     for task in app[K_STATE].get("_resume_tasks", ()):
         task.cancel()
         try:
@@ -462,7 +498,7 @@ async def _parse_request(request: web.Request) -> RawItem:
     ctype = request.content_type
     if ctype == "application/json":
         try:
-            body = await request.json()
+            body = await _json_body(request)
         except json.JSONDecodeError:
             raise web.HTTPBadRequest(reason="invalid JSON body")
         if not isinstance(body, dict):
@@ -541,7 +577,7 @@ def _parse_json_item(body: dict) -> RawItem:
 async def handle_predict(request: web.Request) -> web.StreamResponse:
     app = request.app
     bundle: ModelBundle = app[K_BUNDLE]
-    t0 = time.monotonic()
+    t0 = _t0(request)
     try:
         item = await _parse_request(request)
         sched = _sched_fields(request)
@@ -556,7 +592,8 @@ async def handle_predict(request: web.Request) -> web.StreamResponse:
 
     loop = asyncio.get_running_loop()
     try:
-        feats = await loop.run_in_executor(None, bundle.preprocess, item)
+        feats = await loop.run_in_executor(
+            None, _preprocess, bundle, item, request.get("request_id", ""))
     except (ValueError, OSError) as e:
         # OSError covers PIL's UnidentifiedImageError on corrupt bytes.
         metrics.REQUESTS.labels(bundle.name, "400").inc()
@@ -741,8 +778,10 @@ async def _open_stream(request: web.Request, feats: dict, item: RawItem,
 
     app = request.app
     bundle: ModelBundle = app[K_BUNDLE]
+    rid = request.get("request_id", "")
     try:
-        stream_iter = app[K_BATCHER].submit_stream(feats)
+        with tracing.phase("api/submit", cat="http", rid=rid):
+            stream_iter = app[K_BATCHER].submit_stream(feats)
     except QueueFullError as e:
         resp = _shed_response(e)
         metrics.REQUESTS.labels(bundle.name, str(resp.status)).inc()
@@ -772,9 +811,25 @@ async def _open_stream(request: web.Request, feats: dict, item: RawItem,
     # Admission-mode label: the continuous loop stamps "chunked" on the
     # feats dict it was handed when PREFILL_CHUNK routed this prompt to
     # windowed prefill; everything else is a monolithic prefill.
+    now = time.monotonic()
     metrics.TTFT.labels(
         bundle.name, feats.get("prefill_mode", "monolithic")
-    ).observe(time.monotonic() - t0)
+    ).observe(now - t0)
+    # The first token's sum closes here: handler entry to the queue
+    # (stream_api), the queue (stream_queue_wait), the wave
+    # (stream_admit), and the first emit's way back to this coroutine
+    # (stream_handoff).  The decode loop stamps the two instants on the
+    # feats dict it was handed; a path that does not (SPEC_DECODE's
+    # per-stream route) observes neither.
+    t_queued, t_emit = feats.get("t_queued"), feats.get("t_first_emit")
+    if t_queued is not None and t_emit is not None:
+        metrics.STREAM_API.labels(bundle.name).observe(max(0.0, t_queued - t0))
+        metrics.STREAM_HANDOFF.labels(bundle.name).observe(
+            max(0.0, now - t_emit))
+        tr = tracing.tracer()
+        if tr is not None:
+            tr.add("api", cat="http", rid=rid, t0=t0, dur=t_queued - t0)
+            tr.add("handoff", cat="http", rid=rid, t0=t_emit, dur=now - t_emit)
 
     async def chained():
         yield first
@@ -939,9 +994,9 @@ async def _openai_prologue(request: web.Request, to_prompt):
     if bundle.kind != KIND_SEQ2SEQ:
         metrics.REQUESTS.labels(bundle.name, "400").inc()
         raise web.HTTPBadRequest(reason=f"{bundle.name} is not a generative model")
-    t0 = time.monotonic()
+    t0 = _t0(request)
     try:
-        body = await request.json()
+        body = await _json_body(request)
         assert isinstance(body, dict)
     except Exception:
         metrics.REQUESTS.labels(bundle.name, "400").inc()
@@ -996,7 +1051,8 @@ async def _openai_prologue(request: web.Request, to_prompt):
         _expect_stream(request)
     loop = asyncio.get_running_loop()
     try:
-        feats = await loop.run_in_executor(None, bundle.preprocess, item)
+        feats = await loop.run_in_executor(
+            None, _preprocess, bundle, item, request.get("request_id", ""))
     except (ValueError, OSError) as e:
         metrics.REQUESTS.labels(bundle.name, "400").inc()
         raise web.HTTPBadRequest(reason=str(e) or "bad request")
@@ -1478,7 +1534,12 @@ async def handle_status(request: web.Request) -> web.Response:
         # (docs/replica-fleet.md).
         body["fleet"] = fleet.status()
     cdl = getattr(batcher, "_cdl", None)
+    # The server process's own pauses: event-loop lag, collector pauses,
+    # the decode loop thread's time runnable and on no CPU.
+    body["process"] = process = pauses.snapshot(
+        app[K_STATE].get("loop_lag"), getattr(cdl, "loop_time", None))
     if cdl is not None:
+        loop_time = cdl.loop_time.snapshot()
         # Decode dispatch shape: the auto-tuned chunk-chain pipelining
         # depth (STREAM_PIPELINE=0 picks it from measured RTT/compute
         # at warmup).
@@ -1495,17 +1556,14 @@ async def handle_status(request: web.Request) -> web.Response:
             "prep_staged": getattr(cdl, "prep_staged", 0),
             "prep_hits": getattr(cdl, "prep_hits", 0),
             "prep_misses": getattr(cdl, "prep_misses", 0),
-            # Idle admission (the loop's _collect_burst): the requests
-            # the server has read and not yet queued, the waits an idle
-            # loop made for such, the rows those waits added to their
-            # waves, the waits that ended on their cap, seconds waited.
-            "idle_admit": {
-                "expected": cdl.queue.expected(),
-                "waits": cdl.idle_waits,
-                "rows": cdl.idle_wait_rows,
-                "capped": cdl.idle_waits_capped,
-                "wait_s": round(cdl.idle_wait_s, 6),
-            },
+            "idle_admit": _idle_admit(cdl, loop_time),
+            # Where the loop thread's wall time went, by phase, since it
+            # started: wall_s = sum of phases[*].s + unnamed_s; the
+            # slowest iterations of the last 4096 (utils/tracing.LoopTable).
+            "loop_time": loop_time,
+            # /status.process again: a benchmark's window line prints
+            # this block, and a stalled run is read from both.
+            "process": process,
             # Per-site host-sync counts.
             "dispatch_counts": {
                 site: a["count"]
@@ -1627,6 +1685,22 @@ async def handle_status(request: web.Request) -> web.Response:
         if getattr(engine, "prefix_cache", None) is not None:
             body["prefix_cache"] = engine.prefix_cache.stats()
     return web.json_response(body)
+
+
+def _idle_admit(cdl, loop_time: dict) -> dict:
+    """Idle admission (the loop's ``_collect_burst``): the requests the
+    server has read and not yet queued, the waits an idle loop made for
+    such and their seconds (the loop table's ``idle_admit`` row), the
+    rows those waits added to their waves, the waits that ended on
+    their cap."""
+    row = loop_time["inside"].get("idle_admit", {"n": 0, "s": 0.0})
+    return {
+        "expected": cdl.queue.expected(),
+        "waits": row["n"],
+        "rows": cdl.idle_wait_rows,
+        "capped": cdl.idle_waits_capped,
+        "wait_s": row["s"],
+    }
 
 
 async def handle_metrics(request: web.Request) -> web.Response:

@@ -722,10 +722,14 @@ class ContinuousDecodeLoop:
         # The widest gap between two arrivals of the last idle wave (0.0
         # where it was a lone row): what the next one's first row borrows.
         self._idle_gap_s = 0.0
-        self.idle_waits = 0         # idle admissions that waited at all
-        self.idle_wait_rows = 0     # rows those waits added to their waves
+        self.idle_wait_rows = 0     # rows idle admissions' waits added
         self.idle_waits_capped = 0  # waits that ended on the cap
-        self.idle_wait_s = 0.0
+        # Where this loop's thread spends its wall time, by phase, always
+        # on (utils/tracing.LoopTable; /status.decode.loop_time).  The
+        # idle admissions that waited at all, and for how long, are its
+        # ``inside`` row ``idle_admit``.
+        self.loop_time = tracing.LoopTable(engine.bundle.name)
+        metrics.register_exporter(self.loop_time)
         # Admissions dispatched but not yet fetched/inserted; the loop's
         # failure handler must terminate these consumers too.
         self._pending_admissions: list = []
@@ -847,6 +851,9 @@ class ContinuousDecodeLoop:
             if j is not None and st.rid:
                 j.admit(st.rid, feats, st.klass, st.budget)
             st.t_queued = time.monotonic()
+            # Where stream_api_seconds ends and stream_queue_wait_seconds
+            # begins (api/app._open_stream closes the first token's sum).
+            feats["t_queued"] = st.t_queued
             self.queue.put(st, force=True)  # bound enforced just above
         self._ensure_thread()
         return self._consumer_gen(st)
@@ -1028,12 +1035,15 @@ class ContinuousDecodeLoop:
         handler cannot catch (BaseException), every consumer still
         gets a terminal error instead of hanging forever — a dead
         loop thread must never strand its clients."""
+        self.loop_time.bind()
         try:
             self._run_loop()
         except BaseException as e:  # pragma: no cover - defensive
             log.exception("decode loop thread died")
             self._abort_all(e)
             raise
+        finally:
+            self.loop_time.unbind()
 
     def _abort_all(self, exc: BaseException) -> None:
         """Terminal error to every queued, pending and active stream."""
@@ -1078,16 +1088,25 @@ class ContinuousDecodeLoop:
 
     def _run_loop(self) -> None:
         log.info("continuous decode loop up: %d slots", self.n_slots)
+        # Every stretch of an iteration runs under a phase (FLAT
+        # siblings, utils/tracing.phase): what the loop's table reads as
+        # ``unnamed`` is the glue between them.
+        n_wave = 0
         while not self._stop.is_set():
+            self.loop_time.lap(
+                len(self.active), n_wave, len(self._inflight_chunks)
+            )
+            n_wave = 0
             try:
                 # The fleet asked for this replica's streams (breaker
                 # open past FLEET_EVICT_S): evacuate at this iteration
                 # top — a clean boundary, nothing in flight is lost.
                 if self._evacuate_req.is_set() and self.failover is not None:
-                    self._evacuate(
-                        StreamClosedError("replica evicted by the fleet"),
-                        self._evict_cause,
-                    )
+                    with tracing.phase("loop/recover"):
+                        self._evacuate(
+                            StreamClosedError("replica evicted by the fleet"),
+                            self._evict_cause,
+                        )
                     continue
                 # A fatal fault parked by the prefill path (its streams
                 # already checkpoint-requeued): run the shared recovery
@@ -1095,16 +1114,17 @@ class ContinuousDecodeLoop:
                 if self._fault_pending is not None:
                     e, self._fault_pending = self._fault_pending, None
                     raise e
-                # Stale waiters shed as fast 504s BEFORE any admission
-                # work — never prefill a request nobody is waiting for.
-                self._expire_queued()
-                # Host KV tier drains, at the chunk boundary: pending
-                # swap-out copies materialize into the host buffers
-                # (the async device→host transfers started at gather
-                # time have usually landed), and evicted prefix pins
-                # queued for demotion gather out.
-                self._drain_swapouts()
-                self._drain_demotions()
+                with tracing.phase("loop/housekeeping"):
+                    # Stale waiters shed as fast 504s BEFORE any admission
+                    # work — never prefill a request nobody is waiting for.
+                    self._expire_queued()
+                    # Host KV tier drains, at the chunk boundary: pending
+                    # swap-out copies materialize into the host buffers
+                    # (the async device→host transfers started at gather
+                    # time have usually landed), and evicted prefix pins
+                    # queued for demotion gather out.
+                    self._drain_swapouts()
+                    self._drain_demotions()
                 # Already-landed in-flight results route NOW (paged):
                 # EOS'd rows' blocks return to the pool before this
                 # iteration's growth pass instead of after it, and the
@@ -1117,11 +1137,18 @@ class ContinuousDecodeLoop:
                     and not self._swapping
                     and self.queue.qsize() == 0
                 ):
-                    with tracing.phase("loop/queue_pop"):
+                    # The wait's name is its cause: a request the API
+                    # has read and not queued yet is the program's to
+                    # wait for, an empty server is the clients'.
+                    with tracing.phase(
+                        "loop/await_api" if self.queue.expected()
+                        else "loop/idle"
+                    ):
                         st = self.queue.pop(timeout=0.05, fits=self._fits)
                     if st is None:
                         continue
-                    self._reserve(st)
+                    with tracing.phase("loop/queue_pop"):
+                        self._reserve(st)
                     wave = [st]
                 else:
                     wave = []
@@ -1135,7 +1162,8 @@ class ContinuousDecodeLoop:
                     and not self.free
                     and self.queue.waiting("interactive") > 0
                 ):
-                    self._preempt_for_interactive()
+                    with tracing.phase("loop/preempt"):
+                        self._preempt_for_interactive()
                 # Chunk boundary: admit everything that fits, as ONE
                 # wave — N prefill dispatches queue on the device and a
                 # single combined transfer fetches all their first
@@ -1144,23 +1172,26 @@ class ContinuousDecodeLoop:
                 # bound too: they were admitted first and will need a
                 # slot at handoff — later short prompts must not
                 # strand them slot-less.
-                while (
-                    len(wave) + len(self.active) + len(self._prefilling)
-                    + len(self._swapping)
-                    < self.n_slots
-                ):
-                    st = self.queue.pop_nowait(fits=self._fits)
-                    if st is None:
-                        break
-                    self._reserve(st)
-                    wave.append(st)
+                with tracing.phase("loop/queue_pop"):
+                    while (
+                        len(wave) + len(self.active) + len(self._prefilling)
+                        + len(self._swapping)
+                        < self.n_slots
+                    ):
+                        st = self.queue.pop_nowait(fits=self._fits)
+                        if st is None:
+                            break
+                        self._reserve(st)
+                        wave.append(st)
                 # With no work in flight a partial wave costs every
                 # straggler a wave of its own: hold this one for the
                 # requests the server is still reading.  At a chunk
                 # boundary the work in flight gives them that window.
                 if wave and not self.active and not self._inflight_chunks:
                     self._collect_burst(wave)
-                self._class_gauges()
+                n_wave = len(wave)
+                with tracing.phase("loop/housekeeping"):
+                    self._class_gauges()
                 # Depth-D pipeline: keep up to chain_depth chunks in
                 # flight — chunk k's ~RTT-long fetch overlaps later
                 # chunks' dispatch + compute + async host copy, so the
@@ -1200,7 +1231,10 @@ class ContinuousDecodeLoop:
                 # the wave admission: live streams' next chunk is
                 # already queued on the device, so a window here delays
                 # decode cadence by at most its own compute.
-                advanced = self._advance_swapins()
+                advanced = False
+                if self._swapping:
+                    with tracing.phase("loop/swap_advance"):
+                        advanced = self._advance_swapins()
                 advanced = self._advance_prefill() or advanced
                 # Double-buffered host prep: with the chunk just
                 # dispatched still in flight (its fetch below blocks
@@ -1225,10 +1259,14 @@ class ContinuousDecodeLoop:
                 ):
                     # Waiters exist but none fit the KV budget (no
                     # admission, no work in flight): poll, don't spin.
-                    time.sleep(0.01)
-                self._record_iteration()
+                    with tracing.phase("loop/poll"):
+                        time.sleep(0.01)
+                with tracing.phase("loop/housekeeping"):
+                    self._record_iteration()
             except Exception as e:
-                if self._recover(e):
+                with tracing.phase("loop/recover"):
+                    recovered = self._recover(e)
+                if recovered:
                     continue
                 if self.failover is not None:
                     # Fleet mode: instead of error-terminating, hand
@@ -1350,37 +1388,39 @@ class ContinuousDecodeLoop:
         ts = sorted(st.t_queued for st in wave)
         last = ts[-1]
         own = max((b - a for a, b in zip(ts, ts[1:])), default=0.0)
-        with tracing.phase("loop/queue_pop"):
-            while len(wave) < self.n_slots:
-                st = self.queue.pop_nowait(fits=self._fits)
-                if st is None:
-                    now = time.monotonic()
-                    quiet = max(
-                        0.0, last + 2.0 * max(own, self._idle_gap_s) - now
-                    )
-                    if not quiet and not self.queue.expected():
-                        break
-                    waited = True
-                    left = t0 + self._burst_cap_s(len(wave)) - now
-                    st = self.queue.pop_expected(
-                        left, quiet, fits=self._fits
-                    )
+        st = None  # a row the blocking wait handed back, not yet taken
+        while len(wave) < self.n_slots:
+            with tracing.phase("loop/queue_pop"):
+                while len(wave) < self.n_slots:
+                    if st is None:
+                        st = self.queue.pop_nowait(fits=self._fits)
                     if st is None:
                         break
-                self._reserve(st)
-                wave.append(st)
-                own = max(own, st.t_queued - last)
-                last = max(last, st.t_queued)
+                    self._reserve(st)
+                    wave.append(st)
+                    own = max(own, st.t_queued - last)
+                    last = max(last, st.t_queued)
+                    st = None
+                now = time.monotonic()
+                quiet = max(0.0, last + 2.0 * max(own, self._idle_gap_s) - now)
+                left = t0 + self._burst_cap_s(len(wave)) - now
+            if len(wave) >= self.n_slots or (
+                not quiet and not self.queue.expected()
+            ):
+                break
+            waited = True
+            # Blocks under ``loop/await_api`` / ``loop/await_burst``, a
+            # slice of the wait at a time, each named by its cause.
+            st = self.queue.pop_expected(left, quiet, fits=self._fits)
+            if st is None:
+                break
         self._idle_gap_s = own
         if not waited:
             return
-        dt = time.monotonic() - t0
         capped = self.queue.expected() > 0 and len(wave) < self.n_slots
-        self.idle_waits += 1
+        self.loop_time.note("idle_admit", time.monotonic() - t0)
         self.idle_wait_rows += len(wave) - n0
         self.idle_waits_capped += capped
-        self.idle_wait_s += dt
-        metrics.IDLE_ADMIT_WAIT.labels(name).observe(dt)
         metrics.IDLE_ADMIT_ROWS.labels(name).inc(len(wave) - n0)
         if capped:
             metrics.IDLE_ADMIT_CAPPED.labels(name).inc()
@@ -2056,6 +2096,11 @@ class ContinuousDecodeLoop:
             j = self._journal()
             if j is not None and st.rid:
                 j.tokens(st.rid, arr)
+            if not st.t_emit:
+                # The first chunk leaves the loop thread here: where
+                # stream_admit_seconds ends and stream_handoff_seconds
+                # begins, stamped before the consumer can see the chunk.
+                t_first = st.feats["t_first_emit"] = time.monotonic()
             st.emit(arr)
             self.tokens_emitted += int(arr.size)
             metrics.TOKENS.labels(self.engine.bundle.name).inc(int(arr.size))
@@ -2079,8 +2124,14 @@ class ContinuousDecodeLoop:
                 # insert dispatch and this stream's place in the emit
                 # order.
                 metrics.STREAM_ADMIT.labels(self.engine.bundle.name).observe(
-                    max(0.0, now - st.t_reserved)
+                    max(0.0, t_first - st.t_reserved)
                 )
+                tr = tracing.tracer()
+                if tr is not None:
+                    tr.add(
+                        "admit", cat="sched", rid=st.rid, t0=st.t_reserved,
+                        dur=t_first - st.t_reserved,
+                    )
                 ttft = now - st.t_in
                 self.ttft_ewma_s = (
                     ttft if not self.ttft_ewma_s
@@ -2474,9 +2525,10 @@ class ContinuousDecodeLoop:
         if not started:
             return
         eng = self.engine
-        uniq: dict[int, Any] = {}
-        for _, state1, toks, _, _, _, _ in started:
-            uniq.setdefault(id(toks), (toks, state1.done))
+        with tracing.phase("loop/wave_complete"):
+            uniq: dict[int, Any] = {}
+            for _, state1, toks, _, _, _, _ in started:
+                uniq.setdefault(id(toks), (toks, state1.done))
         with tracing.phase("loop/wave_fetch"), eng._lock:
             try:
                 fetched = dict(zip(
@@ -3075,18 +3127,19 @@ class ContinuousDecodeLoop:
 
         if self.paged:
             grown = []
-            for job in jobs:
-                try:
-                    # Fault-injection point, like decode growth: an
-                    # injected OutOfBlocks exercises the mid-prefill
-                    # checkpoint path.
-                    eng.fault_point("grow")
-                    self._reclaim_then_ensure(job.sb, window_end(job))
-                except OutOfBlocks:
-                    self._stall_prefill_job(job)
-                    continue
-                job.table_row[: len(job.sb.ids)] = job.sb.ids
-                grown.append(job)
+            with tracing.phase("loop/prefill_advance"):
+                for job in jobs:
+                    try:
+                        # Fault-injection point, like decode growth: an
+                        # injected OutOfBlocks exercises the mid-prefill
+                        # checkpoint path.
+                        eng.fault_point("grow")
+                        self._reclaim_then_ensure(job.sb, window_end(job))
+                    except OutOfBlocks:
+                        self._stall_prefill_job(job)
+                        continue
+                    job.table_row[: len(job.sb.ids)] = job.sb.ids
+                    grown.append(job)
             jobs = grown
             if not jobs:
                 return jobs
@@ -3285,56 +3338,63 @@ class ContinuousDecodeLoop:
             return False
         from ..scheduler.policy import INTERACTIVE, DeadlineExceededError
 
+        # This function's own host work runs under ``loop/prefill_advance``,
+        # closed around each window's ``prefill_window`` phase (which
+        # ``_dispatch_prefill_window`` holds): top-level phases stay flat.
         advanced = False
         t0 = time.monotonic()
         live = bool(self.active)
-        interactive_live = any(
-            s.klass == INTERACTIVE and not s.cancelled.is_set()
-            for s in self.active.values()
-        )
-        # Stale/cancelled jobs drop before any device work.
-        for job in list(self._prefilling):
-            st = job.st
-            if st.cancelled.is_set():
+        with tracing.phase("loop/prefill_advance"):
+            interactive_live = any(
+                s.klass == INTERACTIVE and not s.cancelled.is_set()
+                for s in self.active.values()
+            )
+            # Stale/cancelled jobs drop before any device work.
+            for job in list(self._prefilling):
+                st = job.st
+                if st.cancelled.is_set():
+                    self._prefilling.remove(job)
+                    self._drop_job_resources(job)
+                    self._release(st)
+                elif (
+                    not st.started
+                    and st.deadline is not None
+                    and time.monotonic() > st.deadline
+                ):
+                    self._prefilling.remove(job)
+                    self._drop_job_resources(job)
+                    self._shed("deadline", st.tenant)
+                    self._finish(st, DeadlineExceededError(
+                        "deadline passed mid-prefill; stream shed before "
+                        "its first token"
+                    ))
+            # Ready jobs (prompt exhausted) wait only on a free slot.
+            for job in [j for j in self._prefilling if j.ready]:
+                if not self.free:
+                    break
                 self._prefilling.remove(job)
-                self._drop_job_resources(job)
-                self._release(st)
-            elif (
-                not st.started
-                and st.deadline is not None
-                and time.monotonic() > st.deadline
-            ):
-                self._prefilling.remove(job)
-                self._drop_job_resources(job)
-                self._shed("deadline", st.tenant)
-                self._finish(st, DeadlineExceededError(
-                    "deadline passed mid-prefill; stream shed before "
-                    "its first token"
-                ))
-        # Ready jobs (prompt exhausted) wait only on a free slot.
-        for job in [j for j in self._prefilling if j.ready]:
-            if not self.free:
-                break
-            self._prefilling.remove(job)
-            if self._handoff_job(job):
-                advanced = True
-        budget = self.prefill_budget if live else (1 << 30)
-        jobs = sorted(
-            [j for j in self._prefilling if not j.ready],
-            key=lambda j: (
-                0 if j.st.klass == INTERACTIVE else 1,
-                j.st.deadline if j.st.deadline is not None else float("inf"),
-                j.t_in,
-            ),
-        )
-        chosen = []
-        for job in jobs:
-            if budget <= 0:
-                break
-            if live and not self._pacer.allow(job.st.klass, interactive_live):
-                continue
-            chosen.append(job)
-            budget -= self.prefill_chunk
+                if self._handoff_job(job):
+                    advanced = True
+            budget = self.prefill_budget if live else (1 << 30)
+            jobs = sorted(
+                [j for j in self._prefilling if not j.ready],
+                key=lambda j: (
+                    0 if j.st.klass == INTERACTIVE else 1,
+                    j.st.deadline if j.st.deadline is not None
+                    else float("inf"),
+                    j.t_in,
+                ),
+            )
+            chosen = []
+            for job in jobs:
+                if budget <= 0:
+                    break
+                if live and not self._pacer.allow(
+                    job.st.klass, interactive_live
+                ):
+                    continue
+                chosen.append(job)
+                budget -= self.prefill_chunk
         # The chosen windows go out together, ``_prefill_width`` a
         # dispatch: what a boundary's budget admits is one dispatch.
         width = self._prefill_width
@@ -3350,24 +3410,26 @@ class ContinuousDecodeLoop:
                 if self._fault_pending is not None:
                     break  # shared recovery runs at the iteration top
                 continue
-            for job in batch:
-                advanced = True
-                if job.consumed >= job.L:
-                    job.ready = True
-                    if self.free:
-                        self._prefilling.remove(job)
-                        self._handoff_job(job)
-        if live and advanced:
-            # Host-observed decode-cadence delay: the time this chunk
-            # boundary spent on prefill dispatches while streams were
-            # live (the device-side window rides behind the decode
-            # dispatch, so this bounds — not equals — the stall).
-            dt = time.monotonic() - t0
-            self.prefill_stall_s += dt
-            metrics.PREFILL_STALL.labels(eng.bundle.name).inc(dt)
-        metrics.PREFILL_BACKLOG.labels(eng.bundle.name).set(
-            self.prefill_backlog_tokens()
-        )
+            with tracing.phase("loop/prefill_advance"):
+                for job in batch:
+                    advanced = True
+                    if job.consumed >= job.L:
+                        job.ready = True
+                        if self.free:
+                            self._prefilling.remove(job)
+                            self._handoff_job(job)
+        with tracing.phase("loop/prefill_advance"):
+            if live and advanced:
+                # Host-observed decode-cadence delay: the time this chunk
+                # boundary spent on prefill dispatches while streams were
+                # live (the device-side window rides behind the decode
+                # dispatch, so this bounds — not equals — the stall).
+                dt = time.monotonic() - t0
+                self.prefill_stall_s += dt
+                metrics.PREFILL_STALL.labels(eng.bundle.name).inc(dt)
+            metrics.PREFILL_BACKLOG.labels(eng.bundle.name).set(
+                self.prefill_backlog_tokens()
+            )
         return advanced
 
     def _warm_prefill(self) -> None:
@@ -5110,13 +5172,15 @@ class ContinuousDecodeLoop:
         import jax
 
         while self._inflight_chunks:
-            fetchables = self._inflight_chunks[0][0]
-            try:
-                if not all(
-                    leaf.is_ready() for leaf in jax.tree.leaves(fetchables)
-                ):
-                    return
-            except AttributeError:  # backend without is_ready probes
+            with tracing.phase("loop/housekeeping"):
+                fetchables = self._inflight_chunks[0][0]
+                try:
+                    landed = all(
+                        leaf.is_ready() for leaf in jax.tree.leaves(fetchables)
+                    )
+                except AttributeError:  # backend without is_ready probes
+                    landed = False
+            if not landed:
                 return
             self._deliver_oldest()
 

@@ -37,7 +37,7 @@ import threading
 import time
 from collections import deque
 
-from ..utils import metrics
+from ..utils import metrics, tracing
 
 INTERACTIVE = "interactive"
 BATCH = "batch"
@@ -654,13 +654,18 @@ class DeadlineQueue:
                 item = self._pop_locked(fits)
                 if item is not None:
                     return item
-                until = deadline if self._expected > 0 else min(
-                    deadline, quiet_until
-                )
+                api = self._expected > 0
+                until = deadline if api else min(deadline, quiet_until)
                 remaining = until - self._clock()
                 if remaining <= 0:
                     return None
-                self._cond.wait(timeout=remaining)
+                # One slice of the wait (a put or a settle ends it), named
+                # by what it waits for: a request the API still holds, or
+                # the clients' next write.
+                with tracing.phase(
+                    "loop/await_api" if api else "loop/await_burst"
+                ):
+                    self._cond.wait(timeout=remaining)
 
     def set_fairshare(self, fs) -> None:
         """Attach (or detach, ``None``) a ``WeightedFairShare`` ledger:

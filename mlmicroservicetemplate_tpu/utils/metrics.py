@@ -13,6 +13,7 @@ serving path never gains a hard dependency.
 from __future__ import annotations
 
 import os
+import weakref
 
 try:
     from prometheus_client import (
@@ -126,15 +127,22 @@ STREAM_ADMIT = Histogram(
     "place in the wave's emit order",
     ["model"], buckets=_LATENCY_BUCKETS,
 )
-IDLE_ADMIT_WAIT = Histogram(
-    "idle_admit_wait_seconds",
-    "Seconds an idle decode loop held its first rows while requests "
-    "the server was still reading were on their way: one observation "
-    "per idle admission that found any (a lone request finds none and "
-    "is not observed)",
-    ["model"],
-    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
-             0.5, 1.0),
+STREAM_API = Histogram(
+    "stream_api_seconds",
+    "Seconds from a streaming request's handler entry to its stream's "
+    "first place in the decode loop's queue: the body, its parse, the "
+    "executor's queue, the tokenizer, admission (first stage of "
+    "stream_ttft_seconds = stream_api + stream_queue_wait + "
+    "stream_admit + stream_handoff for a stream admitted once)",
+    ["model"], buckets=_LATENCY_BUCKETS,
+)
+STREAM_HANDOFF = Histogram(
+    "stream_handoff_seconds",
+    "Seconds from the decode loop thread's first emit of a stream to "
+    "the point where the API observes stream_ttft_seconds: the "
+    "call_soon_threadsafe hop, the event loop's turn, detokenize (last "
+    "stage of stream_ttft_seconds)",
+    ["model"], buckets=_LATENCY_BUCKETS,
 )
 IDLE_ADMIT_ROWS = Counter(
     "idle_admit_rows_total",
@@ -722,5 +730,71 @@ KV_POOL_SHARD_BLOCKS = Gauge(
 )
 
 
+# -- where a decode loop's wall time went, and the process's own pauses
+# (utils/tracing.LoopTable, utils/pauses.py; docs/observability.md).
+# The four counters are fed at render time (``register_exporter``): a
+# phase exit or a collection touches no Prometheus child.
+LOOP_PHASE_SECONDS = Counter(
+    "loop_phase_seconds_total",
+    "Wall seconds of the decode loop's thread by top-level phase "
+    "(utils/tracing.phase names: loop/idle = no request anywhere in the "
+    "server, loop/await_api = the API holds a request that has not "
+    "reached the queue, loop/await_burst = an idle wave's quiet gap, "
+    "loop/queue_pop, loop/wave_dispatch, loop/wave_fetch, loop/insert, "
+    "loop/chunk_dispatch, loop/stage_prep, loop/deliver, "
+    "loop/housekeeping, ...); with loop_unnamed_seconds_total they sum "
+    "to the loop's wall time; /status.decode.loop_time has counts, the "
+    "longest instance and the slowest iterations",
+    ["model", "phase"],
+)
+LOOP_UNNAMED_SECONDS = Counter(
+    "loop_unnamed_seconds_total",
+    "Wall seconds of the decode loop's thread under no phase at all",
+    ["model"],
+)
+LOOP_THREAD_RUN_DELAY = Counter(
+    "loop_thread_run_delay_seconds_total",
+    "Seconds the decode loop's thread was runnable and on no CPU "
+    "(/proc/self/task/<tid>/schedstat, Linux only): the host's cores "
+    "were someone else's",
+    ["model"],
+)
+GC_PAUSE_SECONDS = Counter(
+    "gc_pause_seconds_total",
+    "Seconds Python's cyclic collector held the interpreter, by "
+    "generation (gc.callbacks; /status.process.gc has the counts)",
+    ["generation"],
+)
+EVENT_LOOP_LAG = Histogram(
+    "event_loop_lag_seconds",
+    "How late the server's event loop ran a 20 Hz timer tick: the loop "
+    "that reads every request and writes every token event was held "
+    "that long by something else",
+    ["model"],
+    buckets=(0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1, 0.25,
+             0.5, 1.0, 2.5),
+)
+
+# Objects whose ``export_metrics()`` runs before every render: what
+# keeps its own sums off the hot path hands them on here.  Held weakly:
+# a decode loop that is gone exports nothing.
+_EXPORTERS: "weakref.WeakSet" = weakref.WeakSet()
+
+
+def register_exporter(obj) -> None:
+    _EXPORTERS.add(obj)
+
+
+def raise_to(child, seen: dict, key, total: float) -> None:
+    """Raise the counter ``child`` by what a running total kept
+    elsewhere grew since the last call (``seen[key]`` remembers it)."""
+    delta = total - seen.get(key, 0.0)
+    if delta > 0.0:
+        seen[key] = total
+        child.inc(delta)
+
+
 def render() -> tuple[bytes, str]:
+    for obj in list(_EXPORTERS):
+        obj.export_metrics()
     return generate_latest(), CONTENT_TYPE_LATEST

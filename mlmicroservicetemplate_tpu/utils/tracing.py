@@ -4,11 +4,27 @@ Two instruments, both import-safe (mirroring the ``metrics.py`` stub
 pattern — no OpenTelemetry or any other hard dependency beyond JAX):
 
 - **Phases** (``phase(name, ...)``): the ONE call every serving seam
-  goes through — admission/classify, the streaming loop's host phases
-  (``loop/queue_pop``, ``loop/wave_dispatch``, ``loop/wave_fetch``,
-  ``loop/insert``, ``loop/chunk_dispatch``, ``loop/stage_prep``,
-  ``loop/deliver``), each prefill window and every ``dispatch_guard``
-  site (``dispatch:<site>``).  A phase writes into two sinks:
+  goes through — admission/classify, the API's synchronous sections
+  (``api/parse``, ``api/tokenize``, ``api/submit``), the streaming
+  loop's host phases, each prefill window and every ``dispatch_guard``
+  site (``dispatch:<site>``).  The loop's phases, FLAT siblings that
+  between them cover every stretch of an iteration:
+
+  * its work: ``loop/queue_pop`` (the non-blocking pops),
+    ``loop/wave_dispatch``, ``loop/wave_complete``, ``loop/wave_fetch``,
+    ``loop/insert``, ``loop/chunk_dispatch``, ``loop/prefill_advance``
+    (around each ``prefill_window``), ``loop/swap_advance``,
+    ``loop/stage_prep``, ``loop/deliver``, ``loop/housekeeping``
+    (expiry, tier drains, gauges, the flight recorder's frame, the
+    readiness probe), ``loop/preempt``, ``loop/recover``;
+  * its waits, each named by its cause: ``loop/idle`` (nothing live, the
+    queue empty, no request announced: no request anywhere in the
+    server — the clients' time), ``loop/await_api`` (the API has read a
+    request that has not reached the queue — the program's),
+    ``loop/await_burst`` (an idle wave's quiet gap: the clients' next
+    write), ``loop/poll`` (waiters exist and none fits the KV budget).
+
+  A phase writes into three sinks:
 
   * always a ``jax.profiler.TraceAnnotation(name)``: it records only
     while a profiler session runs (``POST /debug/profile``, a
@@ -22,8 +38,20 @@ pattern — no OpenTelemetry or any other hard dependency beyond JAX):
     JSON from ``GET /debug/trace`` (loadable in Perfetto or
     ``chrome://tracing``).  When off, the module-level tracer is
     ``None`` and no ``Span`` is ever constructed (pinned by test).
+  * on a decode loop's thread, always, that loop's **table**
+    (``LoopTable``, the boot table's sibling for serving): seconds,
+    count and longest instance by phase name, ``wall_s`` = the top-level
+    phases + ``unnamed_s`` (a ``dispatch:<site>`` inside a phase is
+    counted apart, under ``inside``), and a ring of the last 4096
+    iterations' summaries that answers "the slowest" at read time —
+    so an untraced run that stalls says where its seconds went
+    (``/status.decode.loop_time``, ``loop_phase_seconds_total``,
+    ``loop_unnamed_seconds_total``).  Two clock reads and a locked
+    dictionary update a phase exit; other threads pay nothing.
+    ``utils/pauses.py`` reads the process's own pauses beside it
+    (event-loop lag, collector pauses, the loop thread's run delay).
 
-  Neither sink synchronises with the device: ``TRACE=1`` observes the
+  No sink synchronises with the device: ``TRACE=1`` observes the
   chunk pipeline without serialising it.  Device time per program part
   comes from the profiler's device trace (``jax.named_scope`` names in
   ``models/llama.py`` and on the loop's step kinds), not from here.
@@ -244,13 +272,17 @@ def configure(enabled: bool, ring: int = 4096) -> Tracer | None:
 
 class _Phase:
     """One ``phase()``: the profiler annotation plus, under TRACE=1,
-    the ring ``Span`` of the same name."""
+    the ring ``Span`` of the same name, plus, on a decode loop's thread,
+    a row of that loop's table."""
 
-    __slots__ = ("_ann", "_span")
+    __slots__ = ("_ann", "_span", "_name", "_table", "_t0")
 
-    def __init__(self, name: str, span: Span | None):
+    def __init__(self, name: str, span: Span | None,
+                 table: "LoopTable | None" = None):
         self._ann = TraceAnnotation(name)
         self._span = span
+        self._name = name
+        self._table = table
 
     def set(self, **kw) -> "_Phase":
         if self._span is not None:
@@ -261,9 +293,14 @@ class _Phase:
         self._ann.__enter__()
         if self._span is not None:
             self._span.__enter__()
+        if self._table is not None:
+            self._table.depth += 1
+            self._t0 = _now()
         return self
 
     def __exit__(self, etype, exc, tb):
+        if self._table is not None:
+            self._table.close(self._name, self._t0, _now())
         if self._span is not None:
             self._span.__exit__(etype, exc, tb)
         self._ann.__exit__(etype, exc, tb)
@@ -273,14 +310,194 @@ class _Phase:
 def phase(name: str, cat: str = "app", rid: str = "", **args) -> _Phase:
     """A context manager naming what the program does from here to its
     exit, in the profiler's trace (always; recorded only while a
-    profiler session runs) and in the TRACE=1 ring (same name, plus
-    ``rid`` and ``args``).  Phases on one thread are FLAT siblings
-    wherever a device idle gap should be attributable: never wrap a
-    whole loop iteration.  NOTE: kwargs are evaluated by the caller
-    either way — a hot path with expensive args checks ``tracer()``
-    and adds them with ``.set()``."""
+    profiler session runs), in the TRACE=1 ring (same name, plus
+    ``rid`` and ``args``) and, on a decode loop's thread, in that loop's
+    always-on table (``LoopTable``).  Phases on one thread are FLAT
+    siblings wherever a device idle gap should be attributable: never
+    wrap a whole loop iteration — the table counts a phase inside
+    another (``dispatch:<site>``) apart, and what no phase covers as
+    ``unnamed``.  NOTE: kwargs are evaluated by the caller either way —
+    a hot path with expensive args checks ``tracer()`` and adds them
+    with ``.set()``."""
     tr = _TRACER
-    return _Phase(name, None if tr is None else tr.span(name, cat, rid, **args))
+    return _Phase(name, None if tr is None else tr.span(name, cat, rid, **args),
+                  getattr(_LOOP, "table", None))
+
+
+# ---------------------------------------------------------------------------
+# loop table: where a decode loop thread's wall time went, always on
+
+
+_LOOP = threading.local()  # .table: the LoopTable bound to this thread
+
+
+class LoopTable:
+    """The boot table's sibling for serving: every ``phase()`` that
+    closes on the thread this table is bound to (``bind``, the decode
+    loop's) adds its seconds to a row by name — seconds, count, the
+    longest instance.  A phase with no other open around it is a
+    top-level phase (``phases``); one inside another
+    (``dispatch:<site>``) goes to ``inside`` and is not counted twice.
+    ``wall_s`` runs from ``bind`` to the last phase exit or ``lap``, and
+    what no top-level phase covers of it is ``unnamed_s``: ``wall_s`` =
+    sum of ``phases[*].s`` + ``unnamed_s``, at every read.
+
+    ``lap`` closes one iteration of the loop and opens the next; the
+    last ``RING`` iterations' summaries (start, wall, seconds by
+    top-level phase, live streams, wave rows, chunks in flight) answer
+    "the slowest" at read time, each with its longest phase named.  An
+    iteration that mostly waited with nothing in the server (``loop/idle``
+    its longest phase: the blocking pop timed out) leaves no summary.
+
+    One writer (the bound thread), any reader: a phase exit costs two
+    clock reads and one locked dictionary update, with tracing off."""
+
+    RING = 4096
+    SLOWEST = 8
+    IDLE = "loop/idle"
+
+    def __init__(self, model: str = ""):
+        self.model = model
+        self._lock = threading.Lock()
+        self.phases: dict[str, list] = {}  # name -> [s, n, max_s]
+        self.inside: dict[str, list] = {}
+        self.depth = 0  # phases open on the bound thread
+        self.wall = 0.0
+        self.iterations = 0
+        self.native_id: int | None = None  # the bound thread's, for /proc
+        self._edge: float | None = None  # the last instant accounted for
+        self._rows: collections.deque = collections.deque(maxlen=self.RING)
+        self._it: tuple | None = None  # open iteration: (t0, {phase: s})
+        self._exported: dict = {}  # what export_metrics has handed on
+
+    def bind(self) -> None:
+        """This thread is the loop's from here on; time since the last
+        binding (a loop thread that died and was revived) is nobody's."""
+        _LOOP.table = self
+        self.native_id = threading.get_native_id()
+        self.depth = 0
+        now = _now()
+        with self._lock:
+            self._edge = now
+            self._it = None  # the first ``lap`` opens the first iteration
+
+    def unbind(self) -> None:
+        if getattr(_LOOP, "table", None) is self:
+            _LOOP.table = None
+
+    @staticmethod
+    def _add(rows: dict, name: str, dt: float) -> None:
+        row = rows.get(name)
+        if row is None:
+            rows[name] = [dt, 1, dt]
+            return
+        row[0] += dt
+        row[1] += 1
+        if dt > row[2]:
+            row[2] = dt
+
+    def close(self, name: str, t0: float, now: float) -> None:
+        """A phase that opened at ``t0`` on the bound thread ends."""
+        self.depth -= 1
+        dt = now - t0
+        with self._lock:
+            if self.depth > 0:
+                self._add(self.inside, name, dt)
+                return
+            self._add(self.phases, name, dt)
+            self.wall += now - self._edge
+            self._edge = now
+            if self._it is not None:
+                mine = self._it[1]
+                mine[name] = mine.get(name, 0.0) + dt
+
+    def note(self, name: str, seconds: float) -> None:
+        """An interval that is no phase of its own — it spans several
+        (an idle admission's whole wait) — under ``inside``."""
+        with self._lock:
+            self._add(self.inside, name, seconds)
+
+    def lap(self, live: int = 0, rows: int = 0, chunks: int = 0) -> None:
+        """The open iteration ends here and the next begins."""
+        now = _now()
+        with self._lock:
+            self.wall += now - self._edge
+            self._edge = now
+            it, self._it = self._it, (now, {})
+            if it is None:
+                return
+            t0, mine = it
+            self.iterations += 1
+            if max(mine, key=mine.get, default="") != self.IDLE:
+                self._rows.append((t0, now - t0, mine, live, rows, chunks))
+
+    def snapshot(self) -> dict:
+        """``{wall_s, unnamed_s, iterations, phases, inside, slowest}``:
+        ``phases`` / ``inside`` map a name to ``{s, n, max_s}``;
+        ``slowest`` holds the ``SLOWEST`` longest of the ring's
+        iterations, longest first (``t`` on ``time.monotonic()``,
+        ``phase`` the one most of it went to, ``phases`` all of them)."""
+        with self._lock:
+            wall, n = self.wall, self.iterations
+            phases = {k: list(v) for k, v in self.phases.items()}
+            inside = {k: list(v) for k, v in self.inside.items()}
+            rows = list(self._rows)
+
+        def table(d):
+            return {k: {"s": round(v[0], 6), "n": v[1], "max_s": round(v[2], 6)}
+                    for k, v in sorted(d.items())}
+
+        def row(t0, w, mine, live, nrows, chunks):
+            top = max(mine, key=mine.get, default="")
+            return {"t": round(t0, 4), "wall_s": round(w, 6), "phase": top,
+                    "phase_s": round(mine.get(top, 0.0), 6),
+                    "unnamed_s": round(max(w - sum(mine.values()), 0.0), 6),
+                    "phases": {k: round(v, 6) for k, v in mine.items()},
+                    "live": live, "rows": nrows, "chunks": chunks}
+
+        rows.sort(key=lambda r: -r[1])
+        return {
+            "wall_s": round(wall, 6),
+            "unnamed_s": round(
+                max(wall - sum(v[0] for v in phases.values()), 0.0), 6),
+            "iterations": n,
+            "phases": table(phases),
+            "inside": table(inside),
+            "slowest": [row(*r) for r in rows[: self.SLOWEST]],
+        }
+
+    def thread_times(self) -> dict | None:
+        """``{run_delay_s, cpu_s}`` of the bound thread: the time it was
+        runnable and on no CPU, and the time it ran, where Linux says
+        (``/proc/self/task/<tid>/schedstat``); None elsewhere."""
+        if self.native_id is None:
+            return None
+        try:
+            with open(f"/proc/self/task/{self.native_id}/schedstat", "rb") as f:
+                cpu_ns, delay_ns = f.read().split()[:2]
+        except (OSError, ValueError):
+            return None
+        return {"run_delay_s": int(delay_ns) / 1e9, "cpu_s": int(cpu_ns) / 1e9}
+
+    def export_metrics(self) -> None:
+        """Hand what was added since the last call on to the counters
+        (``utils/metrics.render`` calls this: a phase exit touches no
+        Prometheus child)."""
+        from . import metrics
+
+        with self._lock:
+            phases = {k: v[0] for k, v in self.phases.items()}
+            wall = self.wall
+        seen, model = self._exported, self.model
+        for name, s in phases.items():
+            metrics.raise_to(
+                metrics.LOOP_PHASE_SECONDS.labels(model, name), seen, name, s)
+        metrics.raise_to(metrics.LOOP_UNNAMED_SECONDS.labels(model), seen,
+                         "", max(wall - sum(phases.values()), 0.0))
+        times = self.thread_times()
+        if times is not None:
+            metrics.raise_to(metrics.LOOP_THREAD_RUN_DELAY.labels(model), seen,
+                             "run_delay", times["run_delay_s"])
 
 
 # ---------------------------------------------------------------------------
@@ -412,12 +629,11 @@ def boot_table() -> BootTable:
 class _BootPhase(_Phase):
     """``phase()`` that also leaves a row in the boot table."""
 
-    __slots__ = ("_name", "_parent", "_args", "_t0", "seconds")
+    __slots__ = ("_parent", "_args", "seconds")
 
     def __init__(self, name: str, span: Span | None, parent: str | None,
                  args: dict):
         super().__init__(name, span)
-        self._name = name
         self._parent = parent
         self._args = args
         self.seconds = 0.0

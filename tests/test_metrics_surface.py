@@ -125,8 +125,19 @@ def test_every_declared_series_present_and_bounded():
         # shared executable on (a warm boot reads 0, not nothing)
         "boot_phase_seconds", "xla_executables_total",
         "xla_executable_seconds_total",
+        # every second of the decode loop has a name, a first token
+        # closes stage by stage, the process reports its own pauses
+        # (ISSUE 53): fed from the loop table at render time
+        "loop_phase_seconds_total", "loop_unnamed_seconds_total",
+        "stream_api_seconds_count", "stream_handoff_seconds_count",
+        "event_loop_lag_seconds_count", "gc_pause_seconds_total",
     ):
         assert need in sampled, f"{need} has no samples after smoke"
+    for phase in ("loop/queue_pop", "loop/wave_dispatch", "loop/deliver"):
+        assert f'loop_phase_seconds_total{{model="gpt2",phase="{phase}"}}' in text
+    for gen in "012":  # a generation that never ran reads 0, not nothing
+        assert f'gc_pause_seconds_total{{generation="{gen}"}}' in text
+    assert "idle_admit_wait_seconds" not in text  # PR 53: the table has it
     for phase in ("total", "unnamed"):
         assert f'boot_phase_seconds{{model="gpt2",phase="{phase}"}}' in text
     for outcome in ("compiled", "loaded"):

@@ -874,10 +874,23 @@ def _idle_admit_samples(name: str) -> dict:
 
     return {
         k: REGISTRY.get_sample_value(k, {"model": name}) or 0.0
-        for k in ("idle_admit_wait_seconds_count", "idle_admit_wait_seconds_sum",
-                  "idle_admit_rows_total", "idle_admit_capped_total",
+        for k in ("idle_admit_rows_total", "idle_admit_capped_total",
                   "stream_insert_rows_count", "stream_insert_rows_sum")
     }
+
+
+def _idle_waits(cdl) -> tuple:
+    """(waits, seconds) of the loop's idle admissions: the loop table's
+    ``idle_admit`` row, what ``/status.decode.idle_admit`` shows."""
+    row = cdl.loop_time.snapshot()["inside"].get("idle_admit")
+    return (row["n"], row["s"]) if row else (0, 0.0)
+
+
+def _waits(cdl) -> dict:
+    """Seconds of the loop's blocking waits so far, by cause."""
+    phases = cdl.loop_time.snapshot()["phases"]
+    return {k: phases.get(f"loop/{k}", {"s": 0.0})["s"]
+            for k in ("idle", "await_api", "await_burst")}
 
 
 async def _until(cond, what: str, timeout: float = 10.0) -> None:
@@ -922,10 +935,19 @@ def test_announced_burst_lands_as_one_wave(k):
         np.testing.assert_array_equal(got, _solo_tokens(eng, f))
     d = {n: v - before[n] for n, v in _idle_admit_samples(bundle.name).items()}
     assert (d["stream_insert_rows_count"], d["stream_insert_rows_sum"]) == (1, k)
-    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, k - 1, 0)
-    assert d["idle_admit_wait_seconds_count"] == 1
+    waits, wait_s = _idle_waits(cdl)
+    assert (waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, k - 1, 0)
     assert d["idle_admit_rows_total"] == k - 1 and d["idle_admit_capped_total"] == 0
-    assert 0.03 <= cdl.idle_wait_s == pytest.approx(d["idle_admit_wait_seconds_sum"])
+    # The wait was for the API (the burst's announcement stayed open), and
+    # the table's slices of it lie inside the admission's whole wait.
+    by_cause = _waits(cdl)
+    assert 0.03 <= by_cause["await_api"] <= wait_s + 1e-3
+    # A wave with room left then stays for the clients' quiet gap (twice
+    # the 30 ms the burst paused for); a full one goes at once.
+    if k == cdl.n_slots:
+        assert by_cause["await_burst"] == 0.0
+    else:
+        assert 0.03 <= by_cause["await_burst"] <= wait_s + 1e-3
 
 
 @pytest.mark.parametrize("grace_env", [None, "500"], ids=["plain", "ADMIT_GRACE_MS"])
@@ -944,9 +966,12 @@ def test_lone_submit_is_dispatched_without_waiting(grace_env, monkeypatch):
     finally:
         cdl.stop()
     np.testing.assert_array_equal(got, _solo_tokens(eng, f))
-    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_wait_s) == (0, 0, 0.0)
+    assert (*_idle_waits(cdl), cdl.idle_wait_rows) == (0, 0.0, 0)
+    by_cause = _waits(cdl)  # it waited for clients only, with an empty server
+    assert by_cause["await_api"] == by_cause["await_burst"] == 0.0
     after = _idle_admit_samples(bundle.name)
-    assert after["idle_admit_wait_seconds_count"] == before["idle_admit_wait_seconds_count"]
+    for fam in ("idle_admit_rows_total", "idle_admit_capped_total"):
+        assert after[fam] == before[fam]
     assert not hasattr(cdl, "_admit_grace_s")
 
 
@@ -983,8 +1008,9 @@ def test_failed_arrival_releases_a_waiting_loop(how):
         cdl.stop()
     np.testing.assert_array_equal(got, _solo_tokens(eng, f))
     assert [r for r, _ in ran] == [1]
-    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, 0, 0)
-    assert 0.03 <= cdl.idle_wait_s < 30.0
+    waits, wait_s = _idle_waits(cdl)
+    assert (waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, 0, 0)
+    assert 0.03 <= wait_s < 30.0
 
 
 @pytest.mark.parametrize("k", [1, 3], ids=["lone_rung", "small_rung"])
@@ -1018,8 +1044,9 @@ def test_count_that_never_falls_ends_on_the_cap(k):
     for f, got in zip(feats, outs):
         np.testing.assert_array_equal(got, _solo_tokens(eng, f))
     assert [r for r, _ in ran] == [rung]
-    assert (cdl.idle_waits, cdl.idle_waits_capped) == (1, 1)
-    assert caps[rung] <= cdl.idle_wait_s < caps[rung] + 5.0
+    waits, wait_s = _idle_waits(cdl)
+    assert (waits, cdl.idle_waits_capped) == (1, 1)
+    assert caps[rung] <= wait_s < caps[rung] + 5.0
     after = _idle_admit_samples(bundle.name)
     assert after["idle_admit_capped_total"] - before["idle_admit_capped_total"] == 1
     # ... and the wave just timed is the next cap of its rung.
@@ -1048,12 +1075,12 @@ def test_first_row_borrows_the_last_idle_waves_gap():
         b = cdl.submit_stream(dict(f[1]))
         outs = list(await asyncio.gather(_collect(a), _collect(b)))
         await idle()
-        assert (cdl.idle_waits, cdl.idle_wait_rows) == (1, 1)
+        assert (_idle_waits(cdl)[0], cdl.idle_wait_rows) == (1, 1)
         assert 0.03 <= cdl._idle_gap_s < 0.5  # the pair's own gap now
         cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 5.0)
         outs.append(await _collect(cdl.submit_stream(dict(f[2]))))
         await idle()  # waited (twice the pair's gap) and found nobody
-        assert (cdl.idle_waits, cdl.idle_wait_rows) == (2, 1)
+        assert (_idle_waits(cdl)[0], cdl.idle_wait_rows) == (2, 1)
         assert cdl._idle_gap_s == 0.0
         outs.append(await _collect(cdl.submit_stream(dict(f[3]))))
         return outs
@@ -1065,7 +1092,10 @@ def test_first_row_borrows_the_last_idle_waves_gap():
     for feats, got in zip(f, outs):
         np.testing.assert_array_equal(got, _solo_tokens(eng, feats))
     assert [r for r, _ in ran] == [cdl._wave_rows(2), 1, 1]
-    assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (2, 1, 0)
+    assert (_idle_waits(cdl)[0], cdl.idle_wait_rows, cdl.idle_waits_capped) == (2, 1, 0)
+    # Both waits were quiet gaps: nothing was announced, so none was the API's.
+    by_cause = _waits(cdl)
+    assert by_cause["await_burst"] >= 0.03 and by_cause["await_api"] == 0.0
 
 
 @pytest.mark.parametrize("announced", [False, True], ids=["settled", "announced"])
@@ -1094,7 +1124,98 @@ def test_rows_already_queued_join_the_wave(announced):
     wave, dt = asyncio.run(body())
     assert len(wave) == 3 and cdl.queue.qsize() == 0
     if announced:  # ... then held for the one still announced, to the cap
-        assert (cdl.idle_waits, cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, 2, 1)
+        assert (_idle_waits(cdl)[0], cdl.idle_wait_rows, cdl.idle_waits_capped) == (1, 2, 1)
         assert dt >= 0.2
     else:
-        assert (cdl.idle_waits, cdl.idle_wait_s) == (0, 0.0)
+        assert _idle_waits(cdl) == (0, 0.0)
+
+
+# ---------------------------------------------------------------------------
+# The loop table (utils/tracing.LoopTable): the loop's blocking waits carry
+# their cause, and an iteration's wall time adds up.
+
+
+@pytest.mark.parametrize("cause", ["idle", "await_api", "await_burst"])
+def test_blocking_waits_are_named_by_cause(cause):
+    """An empty server's wait is ``loop/idle`` (the clients'); with a
+    request the API has read and not queued (an ``Arrival`` held open) it
+    is ``loop/await_api`` and not ``loop/idle``; an idle wave's quiet gap
+    with nothing announced is ``loop/await_burst``."""
+    from mlmicroservicetemplate_tpu.scheduler.policy import Arrival
+
+    bundle, eng, cdl = _llama_loop(True)
+    f = text_feats(bundle.tokenizer, "a row")
+
+    async def body():
+        if cause == "await_burst":
+            # The last idle wave's rows came 50 ms apart: a lone row
+            # stays for twice that before it goes alone.
+            cdl._wave_seconds = dict.fromkeys(cdl._wave_rungs, 5.0)
+            cdl._idle_gap_s = 0.05
+            return await _collect(cdl.submit_stream(dict(f)))
+        arrival = Arrival([cdl.queue] if cause == "await_api" else [])
+        cdl._ensure_thread()  # the loop's first wait starts announced
+        await asyncio.sleep(0.15)
+        got = _waits(cdl)
+        arrival.settle()
+        return got
+
+    try:
+        got = asyncio.run(body())
+        by_cause = got if isinstance(got, dict) else _waits(cdl)
+    finally:
+        cdl.stop()
+    others = {k: v for k, v in by_cause.items() if k != cause}
+    assert by_cause[cause] >= 0.05
+    if cause == "await_burst":
+        np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+        assert others["await_api"] == 0.0  # (idle: before the row came)
+        assert _idle_waits(cdl)[0] == 1
+    else:
+        assert others == dict.fromkeys(others, 0.0)
+        assert _idle_waits(cdl) == (0, 0.0)  # no wave was held
+
+
+def test_loop_time_adds_up_and_names_a_slowed_iteration():
+    """After real waves and chunks the table closes — ``wall_s`` = the
+    top-level phases + ``unnamed_s``, every ``dispatch:<site>`` counted
+    inside its phase and not beside it — and an iteration slowed on
+    purpose (a ``_stage_host_prep`` that sleeps once) is the slowest
+    since the compiles, with its phase named."""
+    bundle, eng, cdl = _llama_loop(True)
+    feats = [text_feats(bundle.tokenizer, f"{i} row of the wave") for i in range(5)]
+    prep = cdl._stage_host_prep
+
+    def slow_prep():
+        cdl._stage_host_prep = prep  # once
+        time.sleep(0.25)
+        prep()
+
+    try:
+        outs = _run_concurrent(cdl, feats)  # (compiles: the slowest of all)
+        _run_concurrent(cdl, feats[:2])
+        t_warm = time.monotonic()
+        cdl._stage_host_prep = slow_prep
+        _run_concurrent(cdl, feats)
+    finally:
+        cdl.stop()
+    for f, got in zip(feats, outs):
+        np.testing.assert_array_equal(got, _solo_tokens(eng, f))
+    snap = cdl.loop_time.snapshot()
+    phases, inside = snap["phases"], snap["inside"]
+    named = sum(row["s"] for row in phases.values())
+    assert snap["wall_s"] == pytest.approx(
+        named + snap["unnamed_s"], abs=1e-6 * (len(phases) + 2))
+    assert snap["unnamed_s"] < 0.25 * snap["wall_s"]
+    assert all(k.startswith("loop/") for k in phases), sorted(phases)
+    assert {"loop/wave_dispatch", "loop/wave_fetch", "loop/insert",
+            "loop/chunk_dispatch", "loop/stage_prep", "loop/deliver",
+            "loop/housekeeping", "loop/queue_pop", "loop/idle"} <= set(phases)
+    sites = {k for k in inside if k.startswith("dispatch:")}
+    assert {"dispatch:prefill", "dispatch:chunk", "dispatch:fetch"} <= sites
+    assert inside["dispatch:chunk"]["s"] <= phases["loop/chunk_dispatch"]["s"]
+    assert inside["dispatch:chunk"]["n"] == phases["loop/chunk_dispatch"]["n"]
+    top = [r for r in snap["slowest"] if r["t"] >= t_warm][0]
+    assert top["phase"] == "loop/stage_prep" and top["phase_s"] >= 0.25
+    assert top["wall_s"] >= top["phase_s"] and top["live"] >= 1
+    assert len(snap["slowest"]) <= cdl.loop_time.SLOWEST

@@ -660,7 +660,8 @@ def test_tbt_histogram_observed():
 def test_idle_admission_counters_over_http(route, monkeypatch):
     """The API counts a streaming request from its parsed body to its
     queued stream, and the loop's wait shows in ``/metrics``
-    (``idle_admit_*``) and ``/status.decode.idle_admit``: a burst whose
+    (``idle_admit_*``) and ``/status.decode.idle_admit`` (its seconds
+    and count from the loop table): a burst whose
     requests leave preprocess 20 ms apart lands as ONE wave (waits 1,
     rows 3); a counted request that fails in preprocess (400) or is
     shed (503) lowers the count on its way out."""
@@ -724,8 +725,7 @@ def test_idle_admission_counters_over_http(route, monkeypatch):
         finally:
             await client.close()
 
-    fams = ("idle_admit_wait_seconds_count", "idle_admit_rows_total",
-            "idle_admit_capped_total")
+    fams = ("idle_admit_rows_total", "idle_admit_capped_total")
     before = {k: _sample(k, bundle.name) for k in fams}
     burst, status, text, cdl = asyncio.run(main())
     assert burst["idle_admit"] == {
@@ -735,8 +735,246 @@ def test_idle_admission_counters_over_http(route, monkeypatch):
     assert status["idle_admit"]["expected"] == 0
     assert status["idle_admit"]["waits"] == 1  # the 400 and the 503 queued nothing
     d = {k: _sample(k, bundle.name) - before[k] for k in fams}
-    assert d == {"idle_admit_wait_seconds_count": 1.0,
-                 "idle_admit_rows_total": 3.0, "idle_admit_capped_total": 0.0}
-    for fam in ("idle_admit_wait_seconds", "idle_admit_rows_total",
-                "idle_admit_capped_total"):
+    assert d == {"idle_admit_rows_total": 3.0, "idle_admit_capped_total": 0.0}
+    for fam in fams:
         assert f"# HELP {fam}" in text
+    assert "idle_admit_wait_seconds" not in text  # the loop table has it
+    # ... as its ``idle_admit`` row, whose waits are the API's by name.
+    row = status["loop_time"]["inside"]["idle_admit"]
+    assert (row["n"], row["s"]) == (1, status["idle_admit"]["wait_s"])
+    assert 0.04 <= status["loop_time"]["phases"]["loop/await_api"]["s"] <= row["s"] + 1e-3
+
+
+# ---------------------------------------------------------------------------
+# the loop table: where the decode loop thread's wall time went, TRACE=0
+
+
+def _on_thread(fn):
+    """``fn()`` on a thread of its own (a table binds to its thread)."""
+    import threading
+
+    box = []
+    th = threading.Thread(target=lambda: box.append(fn()))
+    th.start()
+    th.join(30.0)
+    assert not th.is_alive() and box
+    return box[0]
+
+
+def _closes(snap: dict, tol: float = 1e-4) -> None:
+    """``wall_s`` = sum of the top-level phases + ``unnamed_s``."""
+    named = sum(row["s"] for row in snap["phases"].values())
+    assert snap["wall_s"] == pytest.approx(named + snap["unnamed_s"], abs=tol)
+
+
+def test_loop_table_closes_and_counts_nested_phases_apart(monkeypatch):
+    """A bound thread's phases add up: top-level ones in ``phases``, a
+    ``dispatch:<site>`` inside one in ``inside`` (not counted twice),
+    the glue between them in ``unnamed_s`` — with TRACE=0 and no
+    ``Span``; another thread's phases never reach the table."""
+    tracing.configure(False)
+    monkeypatch.setattr(
+        tracing.Span, "__init__",
+        lambda self, *a, **kw: pytest.fail("a Span under TRACE=0"))
+    table = tracing.LoopTable("m")
+
+    def work():
+        table.bind()
+        try:
+            for _ in range(3):
+                table.lap(live=2)
+                with tracing.phase("loop/chunk_dispatch"):
+                    time.sleep(0.004)
+                    with tracing.phase("dispatch:chunk"):
+                        time.sleep(0.003)
+                time.sleep(0.002)  # under no phase
+                with tracing.phase("loop/deliver"):
+                    time.sleep(0.001)
+            table.lap()
+        finally:
+            table.unbind()
+        with tracing.phase("loop/deliver"):  # unbound: nobody's
+            pass
+        return table.snapshot()
+
+    with tracing.phase("loop/deliver"):  # this thread is not the loop's
+        snap = _on_thread(work)
+    _closes(snap, 1e-5)
+    assert snap["iterations"] == 3
+    assert {k: v["n"] for k, v in snap["phases"].items()} == {
+        "loop/chunk_dispatch": 3, "loop/deliver": 3}
+    assert {k: v["n"] for k, v in snap["inside"].items()} == {"dispatch:chunk": 3}
+    disp, inner = snap["phases"]["loop/chunk_dispatch"], snap["inside"]["dispatch:chunk"]
+    assert 0.009 <= inner["s"] <= disp["s"] - 0.012 + 1e-3
+    assert disp["max_s"] >= 0.007 and disp["s"] >= 3 * 0.007
+    assert 0.006 <= snap["unnamed_s"] < 0.5
+    assert snap["wall_s"] >= 3 * 0.010
+
+
+def test_loop_table_ring_is_bounded_and_names_the_slow_iteration(monkeypatch):
+    """The ring keeps the last ``RING`` iterations and ``slowest`` the
+    ``SLOWEST`` longest of them, longest first: one iteration slowed on
+    purpose comes out first with its phase named; an iteration that
+    only waited on an empty server leaves no row."""
+    assert tracing.LoopTable.RING == 4096
+    monkeypatch.setattr(tracing.LoopTable, "RING", 256)  # (a shorter test)
+    table = tracing.LoopTable("m")
+
+    def work():
+        table.bind()
+        try:
+            for i in range(2 * table.RING + 200):
+                table.lap(live=1, rows=0, chunks=1)
+                if i == 2 * table.RING:  # well inside what the ring still holds
+                    with tracing.phase("loop/insert"):
+                        time.sleep(0.05)
+                    continue
+                with tracing.phase("loop/housekeeping"):
+                    pass
+                with tracing.phase("loop/idle" if i % 2 else "loop/deliver"):
+                    time.sleep(0.0002)
+            table.lap(live=7, rows=3, chunks=2)
+        finally:
+            table.unbind()
+        return table.snapshot()
+
+    snap = _on_thread(work)
+    _closes(snap)
+    assert snap["iterations"] == 2 * table.RING + 200
+    # (half the iterations only idled: they are counted, and left no row;
+    # of the other half the ring holds the last RING)
+    assert snap["phases"]["loop/idle"]["n"] == table.RING + 100
+    assert len(table._rows) == table.RING
+    slowest = snap["slowest"]
+    assert len(slowest) == table.SLOWEST
+    assert [r["wall_s"] for r in slowest] == sorted(
+        (r["wall_s"] for r in slowest), reverse=True)
+    top = slowest[0]
+    assert top["phase"] == "loop/insert" and 0.05 <= top["phase_s"] <= top["wall_s"]
+    assert top["phases"] == {"loop/insert": top["phase_s"]}
+    assert (top["live"], top["rows"], top["chunks"]) == (1, 0, 1)
+    assert all(r["phase"] != "loop/idle" for r in slowest)
+
+
+@pytest.mark.parametrize("route", ["/predict", "/v1/completions"])
+def test_first_token_closes_stage_by_stage_over_http(route, traced):
+    """For every request of a burst ``stream_ttft_seconds`` is the sum
+    of its four stages — ``stream_api`` (handler entry to the queue),
+    ``stream_queue_wait``, ``stream_admit`` and ``stream_handoff`` (the
+    loop thread's first emit to the API's observation) — instant for
+    instant: the ring's ``api`` / ``queue_wait`` / ``admit`` / ``handoff``
+    spans of one request id tile its first token, and the histograms'
+    sums add up.  ``/status`` shows where the loop's wall time went
+    (``decode.loop_time``, which closes) and the process's own pauses
+    (``process``); ``/metrics`` renders the families fed from them."""
+    from aiohttp.test_utils import TestClient, TestServer
+
+    from mlmicroservicetemplate_tpu.api import build_app
+    from mlmicroservicetemplate_tpu.scheduler import Batcher
+
+    cfg = _cfg(max_decode_len=16, batch_timeout_ms=1.0)
+    bundle = tiny_gpt_bundle()
+    stages = ("stream_api_seconds", "stream_queue_wait_seconds",
+              "stream_admit_seconds", "stream_handoff_seconds")
+    fams = stages + ("stream_ttft_seconds",)
+    k = 4
+
+    def read():
+        def one(f, part):
+            labels = {"model": bundle.name}
+            if f == "stream_ttft_seconds":
+                labels["mode"] = "monolithic"
+            return _sample_labels(f"{f}_{part}", labels)
+
+        return {f: (one(f, "sum"), one(f, "count")) for f in fams}
+
+    async def main():
+        engine = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+        batcher = Batcher(engine, cfg)
+        app = build_app(cfg, bundle, engine, batcher)
+        client = TestClient(TestServer(app))
+        await client.start_server()
+        try:
+            for _ in range(200):
+                if (await client.get("/readyz")).status == 200:
+                    break
+                await asyncio.sleep(0.05)
+            key = "text" if route == "/predict" else "prompt"
+            before = read()
+            rs = await asyncio.gather(*[
+                client.post(route, headers={"X-Request-Id": f"burst-{i}"},
+                            json={key: f"{i} burst row", "stream": True,
+                                  "max_tokens": 6})
+                for i in range(k)])
+            assert [r.status for r in rs] == [200] * k
+            for r in rs:
+                async for _ in r.content:
+                    pass
+            await asyncio.sleep(0.12)  # a few ticks of the lag timer
+            status = await (await client.get("/status")).json()
+            text = await (await client.get("/metrics")).text()
+            return before, read(), status, text
+        finally:
+            await client.close()
+
+    before, after, status, text = asyncio.run(main())
+    d = {f: (after[f][0] - before[f][0], after[f][1] - before[f][1]) for f in fams}
+    assert {f: n for f, (_, n) in d.items()} == dict.fromkeys(fams, float(k))
+    assert sum(d[f][0] for f in stages) == pytest.approx(
+        d["stream_ttft_seconds"][0], abs=1e-6)
+    # ... and request by request, on one clock, with no gap between stages.
+    by_rid: dict = {}
+    for s in traced.snapshot():
+        if s.rid.startswith("burst-") and s.name in (
+            "api", "queue_wait", "admit", "handoff", "api/parse",
+            "api/tokenize", "api/submit",
+        ):
+            by_rid.setdefault(s.rid, {})[s.name] = s
+    assert sorted(by_rid) == [f"burst-{i}" for i in range(k)]
+    total = 0.0
+    for rid, sp in by_rid.items():
+        a, q, m, h = (sp[n] for n in ("api", "queue_wait", "admit", "handoff"))
+        for left, right in ((a, q), (q, m), (m, h)):
+            assert left.t0 + left.dur == pytest.approx(right.t0, abs=1e-9), rid
+        assert min(a.dur, m.dur, h.dur) > 0.0 and q.dur >= 0.0
+        # The API's synchronous sections lie inside its stage (the
+        # submit puts the stream on the queue, which ends the stage).
+        for name in ("api/parse", "api/tokenize"):
+            inner = sp[name]
+            assert a.t0 <= inner.t0 and inner.t0 + inner.dur <= q.t0, name
+        sub = sp["api/submit"]
+        assert a.t0 <= sub.t0 <= q.t0 <= sub.t0 + sub.dur
+        total += h.t0 + h.dur - a.t0
+    assert total == pytest.approx(d["stream_ttft_seconds"][0], abs=1e-6)
+
+    loop_time = status["decode"]["loop_time"]
+    _closes(loop_time, 1e-6 * (len(loop_time["phases"]) + 2))
+    assert set(loop_time) == {"wall_s", "unnamed_s", "iterations", "phases",
+                              "inside", "slowest"}
+    assert 1 <= len(loop_time["slowest"]) <= 8
+    assert set(loop_time["slowest"][0]) == {
+        "t", "wall_s", "phase", "phase_s", "unnamed_s", "phases", "live",
+        "rows", "chunks"}
+    process = status["process"]
+    assert status["decode"]["process"].keys() == process.keys()
+    assert process["event_loop_lag"]["ticks"] >= 2
+    assert 0.0 <= process["event_loop_lag"]["p50_s"] <= process["event_loop_lag"]["max_s"]
+    assert set(process["gc"]) == {"gen0", "gen1", "gen2"}
+    assert process["gc"]["gen0"].keys() == {"collections", "s", "max_s"}
+    if os.path.exists("/proc/self/schedstat"):
+        assert process["loop_thread"]["cpu_s"] > 0.0
+        assert process["loop_thread"]["run_delay_s"] >= 0.0
+    for fam in ("loop_phase_seconds_total", "loop_unnamed_seconds_total",
+                "gc_pause_seconds_total", "event_loop_lag_seconds",
+                "stream_api_seconds", "stream_handoff_seconds"):
+        assert f"# HELP {fam}" in text
+    # The counters are the table's own seconds, handed on at render time.
+    idle = _sample_labels("loop_phase_seconds_total",
+                          {"model": bundle.name, "phase": "loop/idle"})
+    assert idle >= loop_time["phases"]["loop/idle"]["s"] - 1e-6 > 0.0
+
+
+def _sample_labels(name: str, labels: dict) -> float:
+    from prometheus_client import REGISTRY
+
+    return REGISTRY.get_sample_value(name, labels) or 0.0
