@@ -522,8 +522,10 @@ class ContinuousDecodeLoop:
         # Recurrent state rows (``_ssm_take``): one a slot — admission keeps
         # the streams that can hold one (live or in prefill) to ``n_slots``.
         self._ssm_free = None
+        self._ssm_fused = False  # the rows' prompt scans run a fused kernel
         if self.paged and getattr(bcfg, "recurrent_layers", ()):
             self._ssm_free = list(range(self.n_slots))[::-1]
+            self._ssm_fused = bcfg.scan_fused
         # (window layers, window) of a per-layer pattern, or None.
         types = getattr(bcfg, "layer_types", ())
         self._window_layers = (
@@ -2957,6 +2959,8 @@ class ContinuousDecodeLoop:
         name = self.engine.bundle.name
         metrics.SSM_SCAN_TOKENS.labels(name).inc(scanned)
         metrics.SSM_SCAN_MASKED.labels(name).inc(scanned - real)
+        if self._ssm_fused:
+            metrics.SSM_SCAN_FUSED.labels(name).inc(scanned)
 
     def _ssm_window_args(self, rows: int, jobs=(), ends=()) -> tuple:
         """The trailing argument of a window dispatch: ``[rows, 2]`` —

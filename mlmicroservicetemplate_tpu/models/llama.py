@@ -482,6 +482,18 @@ class LlamaConfig:
         return RECURRENT[self.layer_kind(li).mixer].shapes(self)
 
     @property
+    def scan_fused(self) -> bool:
+        """Whether a prompt window's or wave's scan of the recurrent layers
+        takes its fused kernel (``ops/ssm.py``): the test the scans
+        themselves apply — the decode step runs its kernels and the shapes
+        are whole tiles of the chip (``RECURRENT``)."""
+        from ..ops import ssm
+
+        kinds = {self.layer_kind(li).mixer for li in self.recurrent_layers}
+        return bool(kinds) and self.pallas_decode and all(
+            RECURRENT[k].fits(ssm, self) for k in kinds)
+
+    @property
     def ssm_row_bytes(self) -> int:
         """Bytes of recurrent state one stream holds (0: no recurrent
         layer): a layer's float32 state and its K-1 convolution taps in the
@@ -1401,7 +1413,9 @@ def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
         a = -jnp.exp(m["A_log"].astype(f32))
         if live is None:
             with jax.named_scope("ssm_scan"):
-                y, s = ssm.mamba1_scan(xs, delta, a, bm, cm, m["D"], s, mask)
+                y, s = ssm.mamba1_scan(
+                    xs, delta, a, bm, cm, m["D"], s, mask,
+                    kernel=cfg.pallas_decode, interpret=cfg.pallas_interpret)
         else:
             with jax.named_scope("ssm_step"):
                 y, s = ssm.mamba1_step(xs[:, 0], delta[:, 0], a, bm[:, 0],
@@ -1416,12 +1430,15 @@ def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
 class Recurrence(NamedTuple):
     """A recurrent mixer kind: ``shapes(cfg) -> (taps, state)`` (what one
     state row of such a layer holds, ``LlamaConfig.recurrent_shapes``),
-    ``block(cfg, layer, x, conv, s, mask=, live=) -> (x, conv', s')`` and
-    the name its boot refusals give it."""
+    ``block(cfg, layer, x, conv, s, mask=, live=) -> (x, conv', s')``, the
+    name its boot refusals give it and ``fits(ssm, cfg)``: the shape gate
+    ``ops/ssm`` puts before its prompt scan's fused kernel
+    (``LlamaConfig.scan_fused``)."""
 
     shapes: Any
     block: Any
     name: str
+    fits: Any
 
 
 #: Every mixer that keeps a state row, by ``LayerKind.mixer``: the ONE table
@@ -1431,14 +1448,22 @@ RECURRENT = {
     "mamba2": Recurrence(
         lambda c: ((c.ssm_conv - 1, c.ssm_conv_dim),
                    (c.ssm_heads, c.ssm_head_dim, c.ssm_state)),
-        _mamba_block, "Mamba-2 layers (layer_pattern 'M')"),
+        _mamba_block, "Mamba-2 layers (layer_pattern 'M')",
+        lambda ssm, c: ssm._kernel_fits(
+            c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state, c.ssm_chunk,
+            c.pallas_interpret)),
     "gdn": Recurrence(
         lambda c: ((c.gdn_conv - 1, c.gdn_conv_dim),
                    (c.gdn_value_heads, c.gdn_value_dim, c.gdn_key_dim)),
-        _gdn_block, "Gated-DeltaNet layers (layer_types 'linear')"),
+        _gdn_block, "Gated-DeltaNet layers (layer_types 'linear')",
+        lambda ssm, c: ssm._gdn_kernel_fits(
+            c.gdn_key_heads, c.gdn_value_heads, c.gdn_key_dim, c.gdn_value_dim,
+            ssm.GDN_CHUNK, c.pallas_interpret)),
     "mamba1": Recurrence(
         lambda c: ((c.ssm_conv - 1, c.ssm_inner), (c.ssm_state, c.ssm_inner)),
-        _mamba1_block, "Mamba-1 layers (layer_types 'mamba')"),
+        _mamba1_block, "Mamba-1 layers (layer_types 'mamba')",
+        lambda ssm, c: ssm._mamba1_kernel_fits(
+            c.ssm_inner, c.ssm_state, c.pallas_interpret)),
 }
 
 

@@ -841,8 +841,10 @@ def _gdn_kernel_call(qkv, g, beta, s0, mask, chunk: int, interpret: bool):
 # state by state as well (sixteen ``[B, C]`` arrays, ONE fusion a trip) it
 # took 4.53 ms — three rows fill 3 of a tile's 8 sublanes — and five times
 # as long to compile, 26 layers an executable: the cold boot's largest part.
-# A fused vector-unit kernel that keeps the state in VMEM over a whole window
-# is the next step (PERF.md section 7).
+# Where the decode step runs its kernels the scan is ONE fused vector-unit
+# kernel a layer instead (``_mamba1_kernel``, at the bottom of this file: a
+# row's state stays in VMEM over a whole window); this loop stays the tests'
+# reference and the path without kernels.
 
 #: Tokens a trip of the prompt scan's loop folds (its body is unrolled over
 #: them).  The answer does not depend on it; 16 and 32 ran alike, 8 a tenth
@@ -860,20 +862,31 @@ def _mamba1_token(h, x, delta, a, b, c, d):
 
 def mamba1_scan(x: jax.Array, delta: jax.Array, a: jax.Array, b: jax.Array,
                 c: jax.Array, d: jax.Array, s0: jax.Array, mask: jax.Array, *,
-                chunk: int = MAMBA1_CHUNK):
+                chunk: int = MAMBA1_CHUNK, kernel: bool = False,
+                interpret: bool = False):
     """``x`` [B, L, C] (the convolution's output), ``delta`` [B, L, C] (after
     softplus), ``a`` [N, C] (negative), ``b`` / ``c`` [B, L, N], ``d`` [C],
     ``s0`` [B, N, C] float32, ``mask`` [B, L] -> (y [B, L, C] float32, final
     state [B, N, C] float32).  Any L: the tail is padded with masked
+    tokens.  With ``kernel`` (and widths the chip's tiles divide) the fused
+    kernel at the bottom of this file, else — the tests' reference and the
+    path without kernels — the ``jax.numpy`` loop over chunks of ``chunk``
     tokens."""
     f32 = jnp.float32
     bsz, length, ch = x.shape
+    fused = kernel and _mamba1_kernel_fits(ch, a.shape[0], interpret)
+    if fused:  # a grid step's tokens; a short wave is ONE block, in whole
+        # sublane tiles of bfloat16 x
+        chunk = min(MAMBA1_BLOCK, -(-length // 16) * 16)
     pad = -length % chunk
     if pad:
         x, delta, b, c, mask = (
             jnp.pad(t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
             for t in (x, delta, b, c, mask))
     delta = delta.astype(f32) * (mask != 0)[..., None]
+    if fused:
+        y, h = _mamba1_kernel_call(x, delta, a, b, c, d, s0, mask, chunk, interpret)
+        return y[:, :length], h
     a, d = a.astype(f32), d.astype(f32)
 
     def chunks(t):  # [B, L, W] -> [L / Q, B, Q, W]: a chunk a trip
@@ -903,3 +916,183 @@ def mamba1_step(x: jax.Array, delta: jax.Array, a: jax.Array, b: jax.Array,
     it was where it is not)."""
     new, y = _mamba1_token(s, *(t.astype(jnp.float32) for t in (x, delta, a, b, c, d)))
     return y, jnp.where(live[:, None, None], new, s)
+
+
+# ---------------------------------------------------------------------------
+# the fused vector-unit kernel of the selective scan
+
+# A channel's 16 states stand in 16 different vector registers and 1024
+# channels fill one of them: a token's channel tile FOLDED, its 8 lane tiles
+# down the 8 sublanes (``[8, 128]``).  So B_t[n] and C_t[n] are one scalar a
+# register (read as a row of equal lanes loaded onto every sublane: no vector
+# slot), the sum over the states is 15 register adds (no sublane traffic) and
+# the per-token work is the recurrence's own 7 operations a state element.
+# (States down the sublanes — ``[16, 1024]`` as two registers a lane tile —
+# read 49 bundles a token a tile against this form's 42: the sum over the
+# sublanes is rotations and selects, and 64 registers do not hold its
+# partial sums.  docs/kernel_tuning.md.)
+
+#: Channels a program of the kernel holds and tokens a grid step folds.  At
+#: 1024 channels the state is 16 vector registers and ``A`` 16 more of the
+#: chip's 64: both stay in registers over the token loop.  256 tokens x 1024
+#: channels of x (bfloat16), Delta and y (float32) are 0.5 + 1 + 1 MB,
+#: double-buffered 5 MB; x in float32 1 MB and the rows of B and C 2 x 2 MB
+#: beside them: 10 MB of the 16 a kernel may take, and a grid step's ~8 us of
+#: vector work hides its ~0.4 us of overhead.
+MAMBA1_TILE, MAMBA1_BLOCK = 1024, 256
+#: Tokens the token loop's body is unrolled over: one sublane tile of Delta,
+#: x and y.  States the body can be unrolled over as well: each is a register
+#: of the carried state (no published Mamba-1 has more than 16).
+_GROUP, _MAX_STATES = 8, 32
+_LOG2E = 1.4426950408889634
+
+
+def _mamba1_tile(channels: int) -> int:
+    """The channel tile: ``MAMBA1_TILE`` halved until it divides the channels
+    (down to one lane tile; all the channels where that does not either: the
+    interpreter's widths)."""
+    tile = MAMBA1_TILE
+    while tile > LANES and channels % tile:
+        tile //= 2
+    return channels if channels % tile else tile
+
+
+def _mamba1_kernel_fits(channels: int, states: int, interpret: bool) -> bool:
+    """Whether the kernel's blocks are whole tiles of the chip — the channels
+    whole lane tiles — and its loop's body holds the states in registers.
+    The interpreter takes any widths."""
+    return interpret or not (channels % LANES or states > _MAX_STATES)
+
+
+def _mamba1_fold(tile: int) -> tuple:
+    """``(sublanes, lanes)`` a token's ``tile`` channels fold to: lane tiles
+    down the sublanes (one lane tile where the lanes do not divide the tile:
+    the interpreter's widths)."""
+    lanes = LANES if tile % LANES == 0 else tile
+    return tile // lanes, lanes
+
+
+def _mamba1_kernel(upto_ref, x_ref, delta_ref, b_ref, c_ref, a_ref, d_ref,
+                   s0_ref, y_ref, s_ref, xf_ref, b_rows, c_rows):
+    """One block of tokens of one row's one channel tile.  ``x_ref`` /
+    ``delta_ref`` / ``y_ref`` [T, tile] (a token a sublane), ``b_ref`` /
+    ``c_ref`` [T, N], ``a_ref`` [N, fold, lanes] (this tile's ``A log2 e``,
+    folded), ``d_ref`` [1, tile]; ``s_ref`` [tiles, N, fold, lanes] (the
+    output block, resident over ALL of the row's grid steps) holds the
+    row's carried state, folded; ``xf_ref`` [T, tile] is scratch for x in
+    float32, ``b_rows`` / ``c_rows`` [N T, lanes] for B and C with each
+    value spread over a whole row of lanes (filled at the row's first
+    channel tile of a token block, read by all of them)."""
+    from jax.experimental import pallas as pl
+
+    f32 = jnp.float32
+    t, tile = y_ref.shape
+    n, fold, lanes = a_ref.shape
+    bi, z, ci = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    upto = upto_ref[bi, z]
+
+    @pl.when(z == 0)
+    def _():
+        s_ref[ci] = s0_ref[ci].astype(f32)
+
+    @pl.when(upto < t)  # rows past the last group: all of them where no token is real
+    def _():
+        y_ref[...] = jnp.zeros_like(y_ref)
+
+    @pl.when((upto > 0) & (ci == 0))
+    def _():
+        for i in range(n):
+            b_rows[i * t:(i + 1) * t] = jnp.broadcast_to(b_ref[:, i:i + 1], (t, lanes))
+            c_rows[i * t:(i + 1) * t] = jnp.broadcast_to(c_ref[:, i:i + 1], (t, lanes))
+
+    @pl.when(upto > 0)
+    def _():
+        xf_ref[...] = x_ref[...].astype(f32)
+        a2, d = [a_ref[i] for i in range(n)], d_ref[...]
+        row = jax.lax.broadcasted_iota(jnp.int32, (_GROUP, tile), 0)
+
+        def group(g, h):
+            r0 = pl.multiple_of(g * _GROUP, _GROUP)
+            at = pl.ds(r0, _GROUP)
+            h = list(h)
+            xg, dg = xf_ref[at, :], delta_ref[at, :]
+            deltas = dg.reshape(_GROUP, fold, lanes)
+            dxs = (dg * xg).reshape(_GROUP, fold, lanes)
+            ys = []
+            for j in range(_GROUP):
+                ps = []
+                for i in range(n):
+                    col = pl.ds(i * t + r0 + j, 1)  # one row, loaded onto every sublane
+                    h[i] = (jnp.exp2(deltas[j] * a2[i]) * h[i]
+                            + dxs[j] * jnp.broadcast_to(b_rows[col, :], (fold, lanes)))
+                    ps.append(h[i] * jnp.broadcast_to(c_rows[col, :], (fold, lanes)))
+                while len(ps) > 1:  # pairs: a chain of adds would be the schedule
+                    ps = [u + v for u, v in zip(ps[::2], ps[1::2])] + ps[len(ps) & ~1:]
+                ys.append(ps[0])
+            y = jnp.stack(ys).reshape(_GROUP, tile) + d * xg
+            y_ref[at, :] = jnp.where(row + r0 < upto, y, 0.0)
+            return tuple(h)
+
+        groups = jax.lax.div(upto + (_GROUP - 1), jnp.int32(_GROUP))
+        h = jax.lax.fori_loop(0, groups, group, tuple(s_ref[ci, i] for i in range(n)))
+        for i in range(n):
+            s_ref[ci, i] = h[i]
+
+
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def _mamba1_kernel_call(x, delta, a, b, c, d, s0, mask, block: int,
+                        interpret: bool):
+    """The kernel over ``(B, L / block, C / tile)``, a row's token blocks in
+    order and the channel tiles innermost (B and C spread over the lanes
+    once a token block, for all the tiles): x and Delta through block index
+    maps where they lie, one past each block's last real token as a
+    scalar-prefetch operand; ``A log2 e`` and the state folded here (a
+    channel tile's lane tiles down the sublanes: 0.3 MB a row, against the
+    60 MB of x and Delta no copy is made of).  Delta is float32 and masked,
+    L a multiple of ``block``."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    f32 = jnp.float32
+    bsz, length, ch = x.shape
+    n = a.shape[0]
+    tile, nb = _mamba1_tile(ch), length // block
+    fold, lanes = _mamba1_fold(tile)
+    tiles = ch // tile
+    real = (mask != 0).reshape(bsz, nb, block)
+    upto = jnp.max(real * jnp.arange(1, block + 1, dtype=jnp.int32), axis=-1)
+
+    def folded(m):  # [..., N, C] -> [..., tiles, N, fold, lanes]
+        m = m.astype(f32).reshape(*m.shape[:-1], tiles, fold, lanes)
+        return jnp.moveaxis(m, -4, -3)
+
+    def spec(shape, index):  # index(b, z, c) -> block indices
+        return pl.BlockSpec(shape, lambda bi, z, ci, upto: index(bi, z, ci))
+
+    tokens = spec((None, block, tile), lambda bi, z, ci: (bi, z, ci))
+    columns = spec((None, block, n), lambda bi, z, ci: (bi, z, 0))
+    state = spec((None, tiles, n, fold, lanes), lambda bi, z, ci: (bi, 0, 0, 0, 0))
+    y, s = pl.pallas_call(
+        _mamba1_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz, nb, tiles),
+            in_specs=[
+                tokens, tokens, columns, columns,
+                spec((None, n, fold, lanes), lambda bi, z, ci: (ci, 0, 0, 0)),
+                spec((1, tile), lambda bi, z, ci: (0, ci)),
+                state,
+            ],
+            out_specs=[tokens, state],
+            scratch_shapes=[pltpu.VMEM((block, tile), f32)]
+            + [pltpu.VMEM((n * block, lanes), f32)] * 2,
+        ),
+        out_shape=[jax.ShapeDtypeStruct((bsz, length, ch), f32),
+                   jax.ShapeDtypeStruct((bsz, tiles, n, fold, lanes), f32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")),
+        interpret=interpret,
+        name="mamba1_scan",
+    )(upto, x, delta, b.astype(f32), c.astype(f32), folded(a * _LOG2E),
+      d.astype(f32).reshape(1, ch), folded(s0))
+    return y, jnp.moveaxis(s, -4, -3).reshape(bsz, n, ch)

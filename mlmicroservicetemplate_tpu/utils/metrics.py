@@ -246,6 +246,14 @@ SSM_SCAN_MASKED = Counter(
     "no state",
     ["model"],
 )
+SSM_SCAN_FUSED = Counter(
+    "ssm_scan_fused_tokens_total",
+    "Recurrent layers: the positions of ssm_scan_tokens_total whose scan "
+    "took its fused kernel (ops/ssm.py: the decode step runs its kernels "
+    "and the shapes are whole tiles of the chip; LlamaConfig.scan_fused): "
+    "all of them or none, by the loaded configuration",
+    ["model"],
+)
 SSM_STATE_RECOMPUTES = Counter(
     "ssm_state_recomputes_total",
     "Recurrent layers: recurrent states rebuilt by recomputing a "

@@ -667,6 +667,32 @@ def test_the_selective_scan_holds_no_state_a_token(chip, rows):
         h for h in scores_in_hbm(text, rows * length * ch, exact=True)]
 
 
+@pytest.mark.parametrize("rows,length,ch,n", [
+    (3, 1024, 5120, 16), (1, 1024, 5120, 16), (2, 48, 5120, 16),
+    (2, 512, 1536, 8), (1, 256, 384, 8)])
+def test_the_selective_scans_kernel_compiles(chip, rows, length, ch, n):
+    """With ``kernel`` the same scan is ONE Mosaic kernel named ``mamba1_scan``
+    and no loop of XLA's: at Jamba2-3B's widths over a dispatch of three
+    windows and of one and over a short wave (one block of 48 tokens), and at
+    widths whose channel tile is 512 and 128 (a tile's lane tiles fold down
+    4 sublanes, 1): the in-kernel relayout ``[8, tile] -> [8, tile / 128,
+    128]`` is the chip's compiler's to take or refuse."""
+    from mlmicroservicetemplate_tpu.ops import ssm
+
+    f32 = jnp.float32
+    assert ssm._mamba1_kernel_fits(ch, n, False)
+    text = _compiled_text(
+        chip, ("mamba1_scan", rows, length, ch, "kernel"),
+        lambda *a: ssm.mamba1_scan(*a, kernel=True),
+        chip((rows, length, ch), jnp.bfloat16), chip((rows, length, ch), f32),
+        chip((n, ch), f32), chip((rows, length, n), f32),
+        chip((rows, length, n), f32), chip((ch,), f32), chip((rows, n, ch), f32),
+        chip((rows, length), jnp.int32))
+    calls = [ln for ln in text.splitlines()
+             if "tpu_custom_call" in ln and "mamba1_scan" in ln]
+    assert len(calls) == 1 and " while(" not in text
+
+
 @pytest.mark.parametrize("rows", [3, 1])
 def test_the_delta_rules_chunk_matrices_stay_on_the_chip(chip, rows):
     """The chunked scan of a Gated-DeltaNet layer at GigaChat3.5's widths (32

@@ -104,10 +104,17 @@ _SEQ = ("x", "delta", "b", "c")
 _ORDER = ("x", "delta", "a", "b", "c", "d")
 
 
-@functools.partial(jax.jit, static_argnames=("chunk",))
-def _scan(i, mask, chunk=8, s0=None):
+@functools.partial(jax.jit, static_argnames=("chunk", "kernel"))
+def _scan(i, mask, chunk=8, s0=None, kernel=False):
+    """``mamba1_scan`` on ``_scan_inputs``' arrays; ``kernel``: the fused
+    kernel in interpret mode, else the ``jax.numpy`` loop."""
     return ssm.mamba1_scan(*(i[k] for k in _ORDER),
-                           i["s0"] if s0 is None else s0, mask, chunk=chunk)
+                           i["s0"] if s0 is None else s0, mask, chunk=chunk,
+                           kernel=kernel, interpret=True)
+
+
+#: The scan's two forms: every property below holds of both.
+FORMS = pytest.mark.parametrize("kernel", [False, True], ids=["xla", "kernel"])
 
 
 def _upto(i, lo, hi):
@@ -136,15 +143,17 @@ def _mask(lens, length):
                        jnp.int32)
 
 
+@FORMS
 @pytest.mark.parametrize("chunk", [8, 5])
 @pytest.mark.parametrize("length,lens", [
     (1, (1, 0)), (8, (8, 3)), (9, (9, 8)), (37, (37, 20))])
-def test_the_chunk_loop_is_the_recurrence(length, lens, chunk):
+def test_the_chunk_loop_is_the_recurrence(length, lens, chunk, kernel):
     """Every length around the chunk's edge, one row shorter than the
     other: outputs on the real tokens and the final state agree; a row's
-    padded tail (and a row with no token at all) moves no state."""
+    padded tail (and a row with no token at all) moves no state.  (The
+    kernel's token block is its own constant: ``chunk`` is the loop's.)"""
     i, mask = _scan_inputs(length), _mask(lens, length)
-    y, s = _scan(i, mask, chunk=chunk)
+    y, s = _scan(i, mask, chunk=chunk, kernel=kernel)
     y1, s1 = _token_by_token(i, mask)
     assert _close(y * mask[..., None], y1 * mask[..., None]) < 1e-5
     assert _close(s, s1) < 1e-5
@@ -152,27 +161,29 @@ def test_the_chunk_loop_is_the_recurrence(length, lens, chunk):
         assert _close(s[1], i["s0"][1]) == 0.0
 
 
+@FORMS
 @pytest.mark.parametrize("cut", [8, 13])
-def test_two_windows_in_sequence_are_one_scan_of_both(cut):
+def test_two_windows_in_sequence_are_one_scan_of_both(cut, kernel):
     """A prompt's second window continues the state its first one left — on
     a chunk's edge and off it — and a decode step continues a window."""
     i, mask = _scan_inputs(30, seed=2), _mask((30, 21), 30)
-    y_whole, whole = _scan(i, mask)
-    _, s_a = _scan(_upto(i, 0, cut), mask[:, :cut])
-    y_b, s_b = _scan(_upto(i, cut, 30), mask[:, cut:], s0=s_a)
+    y_whole, whole = _scan(i, mask, kernel=kernel)
+    _, s_a = _scan(_upto(i, 0, cut), mask[:, :cut], kernel=kernel)
+    y_b, s_b = _scan(_upto(i, cut, 30), mask[:, cut:], s0=s_a, kernel=kernel)
     assert _close(s_b, whole) < 1e-5 and _close(y_b[0], y_whole[0, cut:]) < 1e-5
     # one more token by the step = a scan one token longer
     j = _scan_inputs(31, seed=2)
-    _, s30 = _scan(_upto(j, 0, 30), _mask((30, 30), 30))
+    _, s30 = _scan(_upto(j, 0, 30), _mask((30, 30), 30), kernel=kernel)
     y_step, s_step = jax.jit(ssm.mamba1_step)(
         j["x"][:, 30], j["delta"][:, 30], j["a"], j["b"][:, 30], j["c"][:, 30],
         j["d"], s30, jnp.asarray([True, False]))
-    y_long, s_long = _scan(j, _mask((31, 30), 31))
+    y_long, s_long = _scan(j, _mask((31, 30), 31), kernel=kernel)
     assert _close(s_step, s_long) < 1e-5 and _close(y_step[0], y_long[0, 30]) < 1e-5
     assert _close(s_step[1], s30[1]) == 0.0  # the row that is not live
 
 
-def test_a_chunk_whose_decay_would_overflow_a_factored_form_stays_finite():
+@FORMS
+def test_a_chunk_whose_decay_would_overflow_a_factored_form_stays_finite(kernel):
     """Steps of about 10 with ``A`` to -15: a chunk's running sum of
     ``Delta A`` passes -1000, so ``exp(-cs)`` of a factored chunk
     (``exp(cs_t) sum_s exp(-cs_s) ..``) is infinite in float32 — the
@@ -180,12 +191,79 @@ def test_a_chunk_whose_decay_would_overflow_a_factored_form_stays_finite():
     i, mask = _scan_inputs(24, seed=4, step=12.0), _mask((24, 17), 24)
     cs = jnp.cumsum(i["delta"][:, :8, None, :] * i["a"], axis=1)
     assert not bool(jnp.isfinite(jnp.exp(-cs)).all())  # the trap is real here
-    y, s = _scan(i, mask)
+    y, s = _scan(i, mask, kernel=kernel)
     y1, s1 = _token_by_token(i, mask)
     assert bool(jnp.isfinite(y).all()) and bool(jnp.isfinite(s).all())
     scale = float(jnp.max(jnp.abs(y1))) + 1.0
     assert _close(y * mask[..., None], y1 * mask[..., None]) < 1e-5 * scale
     assert _close(s, s1) < 1e-5 * scale
+
+
+@pytest.mark.parametrize("length", [37, 300, 600])
+@pytest.mark.parametrize("lens", [(1.0,), (1.0, 0.0, 0.4)], ids=["b1", "b3"])
+def test_the_fused_kernel_is_the_loop_and_the_recurrence(lens, length):
+    """The kernel (interpret mode) against the ``jax.numpy`` loop AND the
+    token scan, over lengths no multiple of its token block (one block, two
+    and three): a batch of one and of three; row 0 whole, row 1 with no real
+    token — its state comes back bit for bit —, row 2's real prefix two
+    fifths of the row, so its last blocks are all fill and fold nothing.
+    Past a row's last real token y is zeros."""
+    lens = tuple(int(f * length) for f in lens)
+    i, mask = _scan_inputs(length, b=len(lens), seed=length), _mask(lens, length)
+    y, s = _scan(i, mask, kernel=True)
+    ref_y, ref_s = _scan(i, mask)
+    tok_y, tok_s = _token_by_token(i, mask)
+    assert s.dtype == y.dtype == jnp.float32
+    assert s.shape == i["s0"].shape and y.shape == ref_y.shape
+    for row, n in enumerate(lens):
+        assert n == length or _close(y[row, n:], 0.0) == 0.0
+        if not n:
+            assert _close(s[row], i["s0"][row]) == 0.0
+            continue
+        assert _close(y[row, :n], ref_y[row, :n]) < 1e-5
+        assert _close(y[row, :n], tok_y[row, :n]) < 1e-5
+    assert _close(s, ref_s) < 1e-5 and _close(s, tok_s) < 1e-5
+
+
+def test_a_masked_tail_leaves_the_state_bit_for_bit():
+    """The same row scanned with 40 and with 400 masked positions behind
+    its 21 real tokens (a window's unfilled tail: one partly real block |
+    that and a wholly masked one): the two states are EQUAL, not close."""
+    i = _scan_inputs(400, b=1, seed=7)
+    _, short = _scan(_upto(i, 0, 61), _mask((21,), 61), kernel=True)
+    y, long = _scan(i, _mask((21,), 400), kernel=True)
+    assert _close(short, long) == 0.0 and _close(y[0, 21:], 0.0) == 0.0
+
+
+@pytest.mark.parametrize("channels,states,fits", [
+    (5120, 16, True), (1536, 8, True), (5100, 16, False), (5120, 64, False)])
+def test_the_kernels_shape_gate(channels, states, fits):
+    """Whole lane tiles of channels (and no more states than the loop's body
+    holds in registers) take the kernel — the served 5120 x 16 among them, in
+    tiles of 1024 channels —, anything else the ``jax.numpy`` loop; the
+    interpreter takes any widths."""
+    assert ssm._mamba1_kernel_fits(channels, states, False) == fits
+    assert ssm._mamba1_kernel_fits(channels, states, True)
+    if fits:
+        tile = ssm._mamba1_tile(channels)
+        assert channels % tile == 0 and tile % ssm.LANES == 0
+        assert tile == {5120: 1024, 1536: 512}[channels]
+
+
+def test_a_width_no_lane_tile_divides_falls_back_and_still_agrees(monkeypatch):
+    """At 12 channels x 4 states with the interpreter off the gate refuses:
+    ``kernel=True`` runs the ``jax.numpy`` loop (no kernel is traced) and
+    answers as ``kernel=False`` does, bit for bit."""
+    i, mask = _scan_inputs(20), _mask((20, 11), 20)
+
+    def no_kernel(*a, **k):
+        raise AssertionError("the gate let the kernel through")
+
+    monkeypatch.setattr(ssm, "_mamba1_kernel_call", no_kernel)
+    args = (*(i[k] for k in _ORDER), i["s0"], mask)
+    y, s = ssm.mamba1_scan(*args, kernel=True)
+    y0, s0 = ssm.mamba1_scan(*args)
+    assert _close(y, y0) == 0.0 and _close(s, s0) == 0.0
 
 
 def test_a_bf16_state_drifts_where_a_float32_one_does_not():
@@ -378,6 +456,23 @@ def test_one_table_names_every_recurrent_kind(cfg):
         assert kind.recurrent == (mixer in llama_mod.RECURRENT)
     assert llama_mod._recurrent_block(cfg, 0) is llama_mod._mamba1_block
     assert llama_mod.RECURRENT["mamba1"].shapes(cfg) == cfg.recurrent_shapes(0)
+
+
+@pytest.mark.parametrize("name,fused", [
+    ("jamba2-3b-d28", True), ("nemotron3-super-ep4-d11", True),
+    ("gigachat35-ep16-d5", True), ("mistral-7b-d8", False),
+    ("trinity-mini-d5", False)])
+def test_scan_fused_is_the_gate_the_scans_apply(name, fused):
+    """``LlamaConfig.scan_fused`` (what ``ssm_scan_fused_tokens_total`` counts
+    by) at the benchmark's configurations: the three recurrences' served
+    widths pass their kernels' shape gates (``RECURRENT[kind].fits``) once
+    the decode step runs its kernels; a configuration with no recurrent
+    layer, and any with the kernels off, is not fused."""
+    config = bench_spec.load_json(f"{bench_spec.HERE}/configs/{name}.json")
+    kwargs = json.loads(bench_spec.service_env(config)["LLAMA_CONFIG"])
+    for kernels in (False, True):
+        c = llama_mod.LlamaConfig(**kwargs, pallas_decode=kernels, eos_id=2, pad_id=0)
+        assert c.scan_fused == (fused and kernels)
 
 
 def _svc(monkeypatch, kw, **knobs):

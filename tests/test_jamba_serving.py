@@ -143,15 +143,24 @@ def _bundle(monkeypatch, kw):  # noqa: F811
     return bundle
 
 
-def test_the_loop_serves_waves_and_windows_as_the_reference(monkeypatch, kw, ref, config):  # noqa: F811
+@pytest.mark.parametrize("kernels", [False, True], ids=["xla", "kernel"])
+def test_the_loop_serves_waves_and_windows_as_the_reference(
+        monkeypatch, kw, ref, config, kernels):  # noqa: F811
     """Short prompts (the wave path: the state inserted into a row, the keys
     into blocks) and long ones (windows, three different prompts a dispatch)
     together: every stream's tokens are the plain reference's greedy
     continuation, teacher-forced; the rows, the blocks and the shared
-    ``ssm_*`` counters add up afterwards."""
+    ``ssm_*`` counters add up afterwards.  With the kernels on
+    (``USE_PALLAS_DECODE``, interpret mode) the scans take the fused kernel
+    and ``ssm_scan_fused_tokens_total`` counts every scanned position; with
+    them off it counts none."""
     from test_jamba_block import SMALL
 
+    if kernels:
+        monkeypatch.setenv("USE_PALLAS_DECODE", "1")
     bundle = _bundle(monkeypatch, kw)
+    assert bundle.cfg.pallas_decode == bundle.cfg.scan_fused == kernels
+    fused0 = metrics.SSM_SCAN_FUSED.labels("llama")._value.get()
     cfgc = _loop_cfg()
     eng = InferenceEngine(bundle, cfgc, ReplicaSet(make_mesh(1)))
     feats = _feats((7, 30, 45, 30, 12))
@@ -181,6 +190,8 @@ def test_the_loop_serves_waves_and_windows_as_the_reference(monkeypatch, kw, ref
     scanned = metrics.SSM_SCAN_TOKENS.labels("llama")._value.get() - scanned0
     masked = metrics.SSM_SCAN_MASKED.labels("llama")._value.get() - masked0
     assert scanned - masked == sum(int(f["length"]) for f in feats) and masked > 0
+    fused = metrics.SSM_SCAN_FUSED.labels("llama")._value.get() - fused0
+    assert fused == (scanned if kernels else 0)
     # three Mamba layers' [4, 160] float32 state and taps a stream; ONE
     # attention layer's K and V of ONE 16-wide head a token
     assert eng.stream_fixed_bytes() == bundle.cfg.ssm_row_bytes == 3 * (2560 + 960)

@@ -337,19 +337,22 @@ def test_recurrent_state_families_are_declared_and_a_row_ledger_feeds_them():
     — ``tests/test_nemotron_serving.py`` drives the loop itself."""
     declared = _declared_families()
     names = {"ssm_state_bytes", "ssm_state_rows", "ssm_scan_tokens",
-             "ssm_scan_masked_tokens", "ssm_state_recomputes"}
+             "ssm_scan_masked_tokens", "ssm_scan_fused_tokens",
+             "ssm_state_recomputes"}
     assert names <= set(declared), names - set(declared)
     for state in ("live", "prefill", "free"):
         metrics.SSM_STATE_ROWS.labels("surface-check", state).set(1)
     metrics.SSM_STATE_BYTES.labels("surface-check").set(2 * 21278720)
     metrics.SSM_SCAN_TOKENS.labels("surface-check").inc(3072)
     metrics.SSM_SCAN_MASKED.labels("surface-check").inc(40)
+    metrics.SSM_SCAN_FUSED.labels("surface-check").inc(3072)
     metrics.SSM_STATE_RECOMPUTES.labels("surface-check").inc()
     text = _scrape_body()
     for line in ('ssm_state_rows{model="surface-check",state="prefill"} 1.0',
                  'ssm_state_bytes{model="surface-check"} 4.255744e+07',
                  'ssm_scan_tokens_total{model="surface-check"} 3072.0',
                  'ssm_scan_masked_tokens_total{model="surface-check"} 40.0',
+                 'ssm_scan_fused_tokens_total{model="surface-check"} 3072.0',
                  'ssm_state_recomputes_total{model="surface-check"} 1.0'):
         assert line in text, line
     states = {ln.split('state="')[1].split('"')[0]
