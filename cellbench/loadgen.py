@@ -12,7 +12,8 @@ waits for what is in flight (bounded), writes one JSON record per
 request and exits 0.  A closed loop's callers each ask again when their
 answer is complete; with ``barrier`` they ask again together, when the
 last answer of the round is complete (callers that work through a
-batch in rounds).
+batch in rounds); with ``stagger_s`` caller j sends its first request
+j x stagger_s after the ramp begins.
 
 Record: ``{"i", "client", "due", "sent", "first", "events": [[t, n],
 ...], "done", "status", "tokens", "error"}`` — times in seconds from
@@ -112,6 +113,9 @@ async def run(schedule: dict, out_path: str) -> None:
                 rec["due"] = rec["sent"]  # a closed loop has no schedule
 
             async def client(j: int) -> None:
+                late = start + j * stagger - time.monotonic()
+                if late > 0:
+                    await asyncio.sleep(late)
                 k = 0
                 while time.monotonic() - t0 < end:
                     await ask(j, k)
@@ -123,7 +127,9 @@ async def run(schedule: dict, out_path: str) -> None:
                     await asyncio.gather(*(ask(j, k) for j in by_client))
                     k += 1
 
-            delay = t0 - schedule["ramp_s"] - time.monotonic()
+            start = t0 - schedule["ramp_s"]
+            stagger = float(schedule.get("stagger_s", 0.0))
+            delay = start - time.monotonic()
             if delay > 0:
                 await asyncio.sleep(delay)
             if schedule.get("barrier"):

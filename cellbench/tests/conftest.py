@@ -26,3 +26,16 @@ def root_with_bert(tmp_path) -> str:
     os.symlink(spec.HERE, tmp_path / "cellbench")
     (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
     return str(tmp_path)
+
+
+def entry_reading(cell: str, reader: str, **args):
+    """The ONE per-layer metric of ``cell`` that reads ``reader`` with
+    ``args`` among its arguments: an entry is found by what it reads, so
+    a definition that moves under another name (one entry a definition,
+    PR 55) breaks no test."""
+    from cellbench import spec
+
+    hit = [m for m in spec.resolve(cell).per_layer if m.reader == reader
+           and all(m.args.get(k) == v for k, v in args.items())]
+    assert len(hit) == 1, (cell, reader, args, [m.name for m in hit])
+    return hit[0]

@@ -148,12 +148,12 @@ def test_entries_resolve_by_name(config):
     entry = [w for w in bench["workloads"] if w["name"] == CELL][0]
     assert entry["chips"] == 1 and entry["traffic"] == "longdoc-closed"
     assert len(entry["why"]) <= 200
-    mine = [m for m in bench["per_layer"] if m["name"].endswith(".dsv2")]
-    assert [m["name"] for m in mine] == NEW_PER_LAYER
-    for m in mine:
-        assert m["workloads"] == [CELL] and m["moves"] == "tbt_p99_ms"
+    mine = [m for m in bench["per_layer"] if m["name"] in NEW_PER_LAYER]
+    assert [m["name"] for m in mine] == NEW_PER_LAYER  # PR 33's sixteen, by NAME
+    for m in mine:  # later cells append themselves to an entry's list
+        assert CELL in m["workloads"] and m["moves"] == "tbt_p99_ms"
     cell = spec.resolve(CELL)
-    assert [m.name for m in cell.per_layer] == NEW_PER_LAYER
+    assert set(NEW_PER_LAYER) <= {m.name for m in cell.per_layer}
     assert [m.name for m in cell.end_to_end] == ["tbt_p99_ms", "setup_s"]
     mix = cell.traffic
     assert mix["loop"] == "closed" and mix["clients"] == 32 and not mix["barrier"]

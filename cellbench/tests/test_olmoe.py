@@ -11,16 +11,27 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from conftest import entry_reading
+
 from cellbench import costs, costs_moe, spec
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 CELL = "olmoe-1b-7b-d8.decode-closed"
-NEW_PER_LAYER = [
-    "decode_step_ms.olmoe", "decode_step_roofline.olmoe", "decode_moe_ms.olmoe",
-    "moe_overhead_ms.olmoe", "moe_experts_roofline.olmoe", "decode_attn_ms.olmoe",
-    "paged_decode_attention_roofline.olmoe", "moe_imbalance.olmoe",
-    "device_idle_pct.olmoe", "streams_per_chunk.olmoe",
-    "stream_queue_wait_ms.olmoe", "stream_admit_ms.olmoe",
+#: What PR 27 made the cell read, as (reader, arguments): seven of its twelve
+#: entries were twins of Mistral's and live under those names since PR 55.
+NEW_READINGS = [
+    ("trace_module_ms", {"module": "jit_paged_chunk_fn", "per": "step"}),
+    ("moe_roofline", {"what": "step"}),
+    ("trace_scope_ms", {"scopes": ["mlp"]}),
+    ("trace_subscope_ms", {"scopes": ["moe_route", "moe_combine"]}),
+    ("moe_roofline", {"what": "experts"}),
+    ("trace_scope_ms", {"scopes": ["kv_write", "attn"]}),
+    ("decode_roofline", {"what": "attention"}),
+    ("prom_hist", {"family": "moe_load_imbalance"}),
+    ("trace_idle_pct", {}),
+    ("prom_hist", {"family": "stream_batch_size"}),
+    ("prom_hist", {"family": "stream_queue_wait_seconds"}),
+    ("prom_hist", {"family": "stream_admit_seconds"}),
 ]
 
 
@@ -111,16 +122,15 @@ def test_costs_against_hand_computed_numbers(config):
 
 def test_entries_resolve_by_name(config):
     """One configuration, one cell, the three end-to-end lists and the
-    twelve per-layer entries appended, each resolving to its files."""
+    twelve readings of PR 27, each found by NAME or by what it reads:
+    later PRs append cells and fold twin entries (test_entries.py holds
+    every pair)."""
     bench = spec.load_benchmark()
-    assert [c["name"] for c in bench["configs"]][-1] == "olmoe-1b-7b-d8"
-    assert [w["name"] for w in bench["workloads"]][-1] == CELL
-    names = [m["name"] for m in bench["per_layer"]]
-    assert names[-len(NEW_PER_LAYER):] == NEW_PER_LAYER
-    for m in bench["per_layer"][-len(NEW_PER_LAYER):]:
-        assert m["workloads"] == [CELL]
+    assert "olmoe-1b-7b-d8" in [c["name"] for c in bench["configs"]]
+    assert CELL in [w["name"] for w in bench["workloads"]]
     cell = spec.resolve(CELL)
-    assert [m.name for m in cell.per_layer] == NEW_PER_LAYER
+    for reader, args in NEW_READINGS:
+        assert callable(entry_reading(CELL, reader, **args).read)
     assert [m.name for m in cell.end_to_end] == [
         "ttft_p95_ms", "tbt_p95_ms", "tokens_per_s", "setup_s"]
     env = spec.service_env(cell.config)
@@ -135,9 +145,9 @@ def test_entries_resolve_by_name(config):
     assert {k for k in set(mistral) | set(cell.config["env"])
             if mistral.get(k) != cell.config["env"].get(k)} == {
         "SEQ_BUCKETS", "KV_BUDGET_MB"}
-    # the cells that were there keep their metrics
+    # the cell that was there reads nothing of the expert block
     old = spec.resolve("mistral-7b-d8.decode-closed")
-    assert not {m.name for m in old.per_layer} & set(NEW_PER_LAYER)
+    assert not {m.reader for m in old.per_layer} & {"moe_roofline", "trace_subscope_ms"}
 
 
 def test_catalog_keys_are_the_sources(config):
@@ -232,5 +242,6 @@ def test_rehearsal_end_to_end():
     last = json.loads(r.stdout.strip().splitlines()[-1])
     assert last["rehearsal"] is True and last["metrics"] == {}
     assert last["correct"] is True and last["failed"] == 0 and last["attempted"] > 0
-    assert {"moe_imbalance.olmoe", "streams_per_chunk.olmoe"} <= set(
+    assert {entry_reading(CELL, "prom_hist", family=f).name
+            for f in ("moe_load_imbalance", "stream_batch_size")} <= set(
         last["rehearsal_values"])

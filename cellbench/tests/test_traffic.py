@@ -4,6 +4,11 @@ same multiset of sizes and gaps; lengths inside their clips."""
 import json
 import os
 import random
+import subprocess
+import sys
+import threading
+import time
+from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
 
 import pytest
 
@@ -45,6 +50,69 @@ def test_same_seed_same_schedule_other_seed_same_work(name, prompt):
         assert all(olo <= r["output_tokens"] <= ohi for r in a["requests"])
         assert all(r["body"]["max_tokens"] == r["output_tokens"] and
                    r["body"]["stream"] for r in a["requests"])
+
+
+def test_only_the_free_running_mix_staggers_its_callers():
+    s = traffic.build(mix("longdoc-closed"), PIECES, 1, 40)
+    assert s["stagger_s"] == 0.1 and not s["barrier"]
+    # all in well inside the ramp, each first send past the loop's wait for a burst
+    assert s["stagger_s"] * (s["clients"] - 1) < s["ramp_s"] / 2
+    for name, prompt in (("decode-closed", PIECES), ("chat-open", PIECES),
+                         ("predict-closed", BYTES)):
+        assert "stagger_s" not in traffic.build(mix(name), prompt, 1, 20)
+    with pytest.raises(ValueError, match="without a barrier"):
+        traffic.build({**mix("decode-closed"), "stagger_s": 0.1}, PIECES, 1, 20)
+
+
+class _Unary(BaseHTTPRequestHandler):
+    def do_GET(self):
+        self._say()
+
+    def do_POST(self):
+        self.rfile.read(int(self.headers.get("Content-Length", 0)))
+        self._say()
+
+    def _say(self):
+        self.send_response(200)
+        self.send_header("Content-Length", "2")
+        self.end_headers()
+        self.wfile.write(b"ok")
+
+    def log_message(self, *a):
+        pass
+
+
+@pytest.mark.parametrize("stagger", [0.15, None])
+def test_the_load_generator_staggers_the_first_requests_only(stagger, tmp_path):
+    srv = ThreadingHTTPServer(("127.0.0.1", 0), _Unary)
+    threading.Thread(target=srv.serve_forever, daemon=True).start()
+    base = f"http://127.0.0.1:{srv.server_address[1]}"
+    m = {"loop": "closed", "clients": 4, "ramp_s": 1.0, "endpoint": "unary",
+         "prompt_tokens": {"dist": "fixed", "value": 8}, "pool": 8}
+    if stagger:
+        m["stagger_s"] = stagger
+    sched = {**traffic.build(m, BYTES, 5, 0.5), "url": base + "/predict",
+             "ready_url": base + "/healthz"}
+    (tmp_path / "s.json").write_text(json.dumps(sched))
+    child = subprocess.Popen(
+        [sys.executable, os.path.join(os.path.dirname(HERE), "loadgen.py"),
+         str(tmp_path / "s.json"), str(tmp_path / "r.jsonl")],
+        stdin=subprocess.PIPE, stdout=subprocess.PIPE, text=True)
+    try:
+        assert child.stdout.readline().strip() == "ready"
+        child.stdin.write(f"{time.monotonic() + 0.2 + m['ramp_s']!r}\n")
+        child.stdin.flush()
+        assert child.wait(timeout=60) == 0
+    finally:
+        child.kill()
+        srv.shutdown()
+    recs = [json.loads(ln) for ln in (tmp_path / "r.jsonl").read_text().splitlines()]
+    first = {r["client"]: r["sent"] for r in recs if r["i"] == 0}
+    for j in range(4):  # caller j leaves j x stagger after the ramp's start
+        assert first[j] == pytest.approx(-1.0 + j * (stagger or 0.0), abs=0.05)
+    assert all(r["status"] == 200 for r in recs)
+    # and asks again at once: far more requests than callers
+    assert len(recs) > 40
 
 
 def test_open_loop_arrivals():

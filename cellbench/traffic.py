@@ -19,6 +19,10 @@ Mix file keys
   arrivals    open loop: {"dist": "poisson"} or {"dist": "gamma", "cv": 3}
   ramp_s      seconds of the same traffic sent before the window opens
               (counted as set-up; brings the loop to its steady state)
+  stagger_s   closed loop without a barrier: caller j sends its first
+              request j x stagger_s after the ramp begins, so the
+              service's first admission is one request and not a race
+              for how many of a burst it catches (default 0: all at once)
   endpoint    "stream" (POST /predict stream=true) | "unary"
   prompt_tokens / output_tokens
               {"dist": "uniform"|"lognormal"|"fixed", ...}, in the
@@ -147,6 +151,10 @@ def build(mix: dict, prompt: dict, seed: int, seconds: float) -> dict:
             reqs.append({**request(i), "due": t})
         out["requests"] = reqs
     elif mix["loop"] == "closed":
+        if "stagger_s" in mix:
+            if out["barrier"]:
+                raise ValueError("stagger_s is for callers without a barrier")
+            out["stagger_s"] = float(mix["stagger_s"])
         c = out["clients"]
         per_client = max(pool // c, 1)
         out["requests"] = [
