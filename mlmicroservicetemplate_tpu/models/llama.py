@@ -252,6 +252,23 @@ class LlamaConfig:
     ssm_dt_rank: int = 0
     # The LM head is the embedding table, transposed: no ``lm_head`` leaf.
     tie_embeddings: bool = False
+    # A Mamba-2 mixer THEN its FFN (Granite-4.0-H style) on the layers
+    # ``layer_types`` names "mamba2" — the ``layer_pattern`` "M" mixer
+    # (``ssm_heads`` .. ``ssm_chunk``, the same leaves, block and state rows)
+    # with the layer's FFN behind it, the others ``attention`` then theirs.
+    # A name of its own: the published ``layer_types`` of that family says
+    # "mamba", which here is Jamba's Mamba-1; a configuration file
+    # translates.
+    # Four published scalars (Granite's; each default leaves the block as it
+    # is, bit for bit): the embedded rows times ``embedding_multiplier``;
+    # ``attention_multiplier`` the softmax scale in place of
+    # ``head_dim^-1/2`` (0 = that); every sub-block's output times
+    # ``residual_multiplier`` before its residual add; the logits divided by
+    # ``logits_scaling``.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -316,10 +333,12 @@ class LlamaConfig:
         types = tuple(hf.get(t, t) for t in self.layer_types)[: self.num_layers]
         object.__setattr__(self, "layer_types", types)
         if types and (len(types) != self.num_layers
-                      or set(types) - {"window", "full", "linear", "mamba"}):
+                      or set(types) - {"window", "full", "linear", "mamba",
+                                       "mamba2"}):
             raise ValueError(
                 f"layer_types must name each of the {self.num_layers} layers "
-                f"'window', 'full', 'linear' or 'mamba', got {types}"
+                f"'window', 'full', 'linear' or 'mamba' (Mamba-1; 'mamba2' a "
+                f"Mamba-2 mixer then its FFN), got {types}"
             )
         gdn_dims = (self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
                     self.gdn_value_dim)
@@ -367,13 +386,20 @@ class LlamaConfig:
                     "num_dense_layers, over attention='gqa'")
             if "E" in pattern and not self.num_experts:
                 raise ValueError("an 'E' layer needs num_experts")
-            if "M" in pattern and (
-                    not all(d > 0 for d in ssm_dims) or self.ssm_conv < 2
+        if "M" in pattern or "mamba2" in types:
+            if (not all(d > 0 for d in ssm_dims) or self.ssm_conv < 2
                     or self.ssm_heads % self.ssm_groups
                     or self.ssm_inner % self.ssm_groups):
+                what = "an 'M'" if "M" in pattern else "a 'mamba2'"
                 raise ValueError(
-                    "an 'M' layer needs ssm_heads (a multiple of ssm_groups), "
+                    f"{what} layer needs ssm_heads (a multiple of ssm_groups), "
                     f"ssm_head_dim, ssm_groups and ssm_state, got {ssm_dims}")
+        if "mamba2" in types and (
+                set(types) == {"mamba2"} or "mamba" in types or self.mla):
+            raise ValueError(
+                "'mamba2' layers need attention='gqa', the pattern one "
+                "attention layer (the paged pool's) and no 'mamba' (Mamba-1) "
+                f"layer beside them, got {types}")
         if "mamba" in types:
             if (self.ssm_heads <= 0 or self.ssm_state <= 0 or self.ssm_conv < 2
                     or self.ssm_dt_rank <= 0 or self.ssm_head_dim != 1
@@ -388,9 +414,17 @@ class LlamaConfig:
         elif self.ssm_dt_rank:
             raise ValueError(
                 f"ssm_dt_rank={self.ssm_dt_rank} needs a 'mamba' layer")
-        if "M" not in pattern and "mamba" not in types and any(ssm_dims):
+        if ("M" not in pattern and not {"mamba", "mamba2"} & set(types)
+                and any(ssm_dims)):
             raise ValueError(
-                f"Mamba sizes {ssm_dims} need an 'M' or a 'mamba' layer")
+                f"Mamba sizes {ssm_dims} need an 'M' or a 'mamba' layer (or "
+                "layer_types 'mamba2')")
+        if (self.embedding_multiplier <= 0 or self.attention_multiplier < 0
+                or self.residual_multiplier <= 0 or self.logits_scaling <= 0):
+            raise ValueError(
+                "embedding_multiplier, residual_multiplier and logits_scaling "
+                "must be positive (1 = none) and attention_multiplier "
+                "non-negative (0 = head_dim^-1/2)")
 
     @property
     def n_rep(self) -> int:
@@ -432,13 +466,21 @@ class LlamaConfig:
 
     @property
     def attn_scale(self) -> float:
-        """The softmax scale: ``head_dim^-1/2`` and, under YaRN,
-        ``mscale(factor, mscale_all_dim)^2`` (DeepSeek-V2: 1.2608^2)."""
-        scale = self.head_dim ** -0.5
+        """The softmax scale: ``head_dim^-1/2`` — ``attention_multiplier``
+        where the model states one — and, under YaRN, ``mscale(factor,
+        mscale_all_dim)^2`` (DeepSeek-V2: 1.2608^2)."""
+        scale = self.attention_multiplier or self.head_dim ** -0.5
         if self.rope_scaling is not None:
             y = dict(self.rope_scaling)
             scale *= yarn_mscale(y["factor"], y["mscale_all_dim"]) ** 2
         return scale
+
+    @property
+    def gqa_scale(self) -> float | None:
+        """What the GQA paths hand their kernels and ``mha_attention`` as
+        ``scale``: None — each one's own ``head_dim^-1/2``, the program as
+        it ever was — unless the model states an ``attention_multiplier``."""
+        return self.attention_multiplier or None
 
     @property
     def held(self) -> int:
@@ -518,11 +560,12 @@ class LlamaConfig:
         dense = li < self.num_dense_layers
         return LayerKind(
             window=window,
-            rope=kind not in ("linear", "mamba") and (
+            rope=kind not in ("linear", "mamba", "mamba2") and (
                 bool(window) or not self.nope_on_full),
             experts=bool(self.num_experts) and not dense,
             d_ff=self.d_ff_dense if dense else self.d_ff,
-            mixer={"linear": "gdn", "mamba": "mamba1"}.get(kind, self.attention),
+            mixer={"linear": "gdn", "mamba": "mamba1",
+                   "mamba2": "mamba2"}.get(kind, self.attention),
         )
 
     @property
@@ -652,7 +695,7 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         def extra(n):  # leaves newer than the 7-way split: their own keys
             return jax.random.fold_in(lk, n)
 
-        if kind.mixer == "mamba2":
+        if kind.mixer == "mamba2" and not kind.ffn:
             params["layers"].append(_init_mamba(cfg, extra, lin, norm_scale, cast))
             continue
         if not kind.attention:
@@ -732,6 +775,8 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         if kind.mixer == "mamba1":
             layer.update(ssm_ln=block_norm(extra(29), d),
                          ssm=_init_mamba1(cfg, extra, lin, norm_scale, cast))
+        if kind.mixer == "mamba2":  # then its FFN: layer_types "mamba2"
+            layer.update(_init_mamba(cfg, extra, lin, norm_scale, cast))
         if mlp is not None:
             layer.update(mlp_ln=block_norm(extra(30), d), mlp=mlp)
         if cfg.sandwich_norm:
@@ -915,7 +960,19 @@ def _embed(params: Params, cfg: "LlamaConfig", ids, dtype):
         x = embed(params["embed"], ids, dtype)
         if cfg.mup_embed:
             x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
     return x
+
+
+def _residual(cfg: "LlamaConfig", x, out):
+    """``x + out``: a sub-block's residual add, ``out`` times
+    ``cfg.residual_multiplier`` where the model states one — the product and
+    the sum in float32, rounded once (0.22 is no bfloat16 number)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + out
+    return (x.astype(jnp.float32) + out.astype(jnp.float32)
+            * cfg.residual_multiplier).astype(x.dtype)
 
 
 def _norm(cfg: "LlamaConfig", p, x):
@@ -974,15 +1031,18 @@ def _mlp_block(cfg: "LlamaConfig", layer, li: int, x, valid, tally=None):
             )
         if cfg.sandwich_norm:
             out = _norm(cfg, layer["mlp_post_ln"], out)
-        return x + out
+        return _residual(cfg, x, out)
 
 
 def _head_logits(params: Params, cfg: "LlamaConfig", x):
     """Float32 logits of final-normed rows: through ``lm_head``, or under
-    ``cfg.tie_embeddings`` through the embedding table, transposed."""
+    ``cfg.tie_embeddings`` through the embedding table, transposed; divided
+    by ``cfg.logits_scaling`` where the model states one."""
     if cfg.tie_embeddings:
-        return lm_head_logits(x, params["embed"]["embedding"], transposed=True)
-    return lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
+        logits = lm_head_logits(x, params["embed"]["embedding"], transposed=True)
+    else:
+        logits = lm_head_logits(x, params["lm_head"]["kernel"], transposed=False)
+    return logits if cfg.logits_scaling == 1.0 else logits / cfg.logits_scaling
 
 
 def _select_next(params: Params, cfg: "LlamaConfig", state, x_last,
@@ -1225,7 +1285,7 @@ def _attn_out(cfg: "LlamaConfig", layer, ad, li: int, x, ctx, g):
         y = _aproj(layer["attn"], ad, "o", li, y)
         if cfg.sandwich_norm:
             y = _norm(cfg, layer["attn_post_ln"], y)
-        return x + y
+        return _residual(cfg, x, y)
 
 
 def _attn_scope(cfg: "LlamaConfig", li: int):
@@ -1309,7 +1369,7 @@ def _mamba_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
                 y.reshape(b, length, inner), z.astype(f32),
                 m["norm"]["scale"].astype(f32), g, cfg.rms_eps).astype(x.dtype)
         with jax.named_scope("ssm_out_proj"):
-            return x + dense(m["out"], y), conv, s
+            return _residual(cfg, x, dense(m["out"], y)), conv, s
 
 
 def _gdn_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
@@ -1365,7 +1425,7 @@ def _gdn_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
             out = dense(m["out"], y.astype(x.dtype))
             if cfg.sandwich_norm:
                 out = _norm(cfg, layer["gdn_post_ln"], out)
-            return x + out, conv, s
+            return _residual(cfg, x, out), conv, s
 
 
 def _mamba1_gate(y, z):
@@ -1424,7 +1484,7 @@ def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
         with jax.named_scope("ssm_gate"):
             y = _mamba1_gate(y, z.astype(f32)).astype(x.dtype)
         with jax.named_scope("ssm_out_proj"):
-            return x + dense(m["out"], y), conv, s
+            return _residual(cfg, x, dense(m["out"], y)), conv, s
 
 
 class Recurrence(NamedTuple):
@@ -1448,7 +1508,7 @@ RECURRENT = {
     "mamba2": Recurrence(
         lambda c: ((c.ssm_conv - 1, c.ssm_conv_dim),
                    (c.ssm_heads, c.ssm_head_dim, c.ssm_state)),
-        _mamba_block, "Mamba-2 layers (layer_pattern 'M')",
+        _mamba_block, "Mamba-2 layers (layer_pattern 'M' / layer_types 'mamba2')",
         lambda ssm, c: ssm._kernel_fits(
             c.ssm_heads, c.ssm_head_dim, c.ssm_groups, c.ssm_state, c.ssm_chunk,
             c.pallas_interpret)),
@@ -1618,6 +1678,7 @@ def forward_hidden(
                 ctx = mha_attention(
                     q, _repeat_kv(k, cfg.n_rep), _repeat_kv(v, cfg.n_rep),
                     mask=band if cfg.layer_kind(li).window else mask,
+                    scale=cfg.gqa_scale,
                 )
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
@@ -1785,21 +1846,23 @@ def _cache_attention(cfg: LlamaConfig, q, ck, cv, mask):
             ctx = decode_attention(
                 q[:, 0], ck[0], cv[0], m2, k_scale=ck[1], v_scale=cv[1],
                 interpret=cfg.pallas_interpret, variant=vkey, tp=cfg.tp,
+                scale=cfg.gqa_scale,
             )
         else:
             ctx = decode_attention(q[:, 0], ck, cv, m2,
                                    interpret=cfg.pallas_interpret,
-                                   variant=vkey, tp=cfg.tp)
+                                   variant=vkey, tp=cfg.tp, scale=cfg.gqa_scale)
         return ctx[:, None]  # [B, 1, H, D]
     if isinstance(ck, tuple):
         return mha_attention_kv8(
             q,
             _repeat_kv(ck[0], cfg.n_rep), _repeat_kv(ck[1], cfg.n_rep),
             _repeat_kv(cv[0], cfg.n_rep), _repeat_kv(cv[1], cfg.n_rep),
-            mask=mask,
+            mask=mask, scale=cfg.gqa_scale,
         )
     return mha_attention(
-        q, _repeat_kv(ck, cfg.n_rep), _repeat_kv(cv, cfg.n_rep), mask=mask
+        q, _repeat_kv(ck, cfg.n_rep), _repeat_kv(cv, cfg.n_rep), mask=mask,
+        scale=cfg.gqa_scale,
     )
 
 
@@ -1967,12 +2030,13 @@ def _paged_cache_attention(cfg: LlamaConfig, q, ck, cv, table, key_valid,
         if quant:
             ctx = paged_decode_attention(
                 q[:, 0], ck[0], cv[0], table, key_valid, bs,
-                k_scale=ck[1], v_scale=cv[1],
+                k_scale=ck[1], v_scale=cv[1], scale=cfg.gqa_scale,
                 interpret=cfg.pallas_interpret, variant=vkey, tp=cfg.tp,
             )
         else:
             ctx = paged_decode_attention(q[:, 0], ck, cv, table, key_valid,
-                                         bs, interpret=cfg.pallas_interpret,
+                                         bs, scale=cfg.gqa_scale,
+                                         interpret=cfg.pallas_interpret,
                                          variant=vkey, tp=cfg.tp)
         return ctx[:, None]
     return _gathered_attention(
@@ -1995,9 +2059,10 @@ def _gathered_attention(cfg: LlamaConfig, q, ck, cv, table, bs: int, mask):
     if isinstance(ck, tuple):
         return mha_attention_kv8(
             q, dense(ck[0], d), dense(ck[1], 1), dense(cv[0], d),
-            dense(cv[1], 1), mask=mask,
+            dense(cv[1], 1), mask=mask, scale=cfg.gqa_scale,
         )
-    return mha_attention(q, dense(ck, d), dense(cv, d), mask=mask)
+    return mha_attention(q, dense(ck, d), dense(cv, d), mask=mask,
+                         scale=cfg.gqa_scale)
 
 
 #: A window layer's table view is a multiple of this many blocks wide, so
@@ -2400,7 +2465,7 @@ def paged_prefill_chunk(
         return prefill_attention(
             q[0], gather_pages(ck, rows, bs, tail)[0],
             gather_pages(cv, rows, bs, tail)[0], *window_at, window=window,
-            interpret=cfg.pallas_interpret)[None]
+            scale=cfg.gqa_scale, interpret=cfg.pallas_interpret)[None]
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []

@@ -23,8 +23,10 @@ The scan has two forms behind one name.  ``jax.numpy`` (``_scan_xla``): the
 tests' reference and the path without kernels; XLA writes its decay
 matrices ``[B, nc, G, r, Q, K]`` and the state every chunk starts from to
 HBM.  The fused chunk kernel (``_scan_kernel``, where the decode step runs
-its kernels): a program is one row's one head group and one chunk, the
-chunks in order; the carried state ``[r P, N]`` lives in VMEM from the
+its kernels): a program is one row's one block of a group's heads
+(``_head_block``: the whole group at Nemotron's 16 heads a group, 16 of
+Granite's 128 heads in ONE group) and one chunk, the chunks in order; the
+carried state ``[r P, N]`` lives in VMEM from the
 row's initial state to its final one, ``C B^T`` and the decay matrices
 never leave VMEM, x, B and C are read from the convolution's output where
 it lies and y is written token-major — docs/kernel_tuning.md.  Both take
@@ -182,20 +184,47 @@ def _heads_a_tile(r: int, p: int) -> int:
     return t
 
 
+#: Lanes of x and y a program's block of heads spans at most: Nemotron's
+#: block, 16 heads of 64 — x ``[128, 1024]`` bfloat16, y and the two copies of
+#: the state ``[1024, 128]`` float32, each twice for the pipeline's two slots:
+#: 3.5 MB of the 16 MB of scoped VMEM beside the chunk's ``[Q, Q]`` decay
+#: matrices.  A whole group of Granite's (128 heads: x and y ``[128, 8192]``,
+#: the state ``[8192, 128]``) would take over 20 MB.  Blocks of 32 and 64
+#: heads compile too and read the SAME time on the chip (0.456 / 0.471 /
+#: 0.456 ms a layer at ``[3, 1024]``: PERF.md section 6, PR 56), so the bound
+#: stays where the accepted configuration's blocks are.
+HEAD_BLOCK_LANES = 1024
+
+
+def _head_block(r: int, p: int) -> int:
+    """Heads of a group one program takes: the group whole where it spans
+    at most ``HEAD_BLOCK_LANES`` lanes (Nemotron's: its programs, and so its
+    executable, are what they were before groups were tiled), else the most
+    heads that do, divide ``r`` and are whole sublane tiles of the running
+    sums (a multiple of 8; a group no such count divides stays whole and
+    ``_kernel_fits`` says no)."""
+    if r * p <= HEAD_BLOCK_LANES:
+        return r
+    fit = [b for b in range(8, HEAD_BLOCK_LANES // p + 1, 8) if r % b == 0]
+    return fit[-1] if fit else r
+
+
 def _kernel_fits(h: int, p: int, g: int, n: int, chunk: int,
                  interpret: bool) -> bool:
-    """Whether the kernel's blocks are whole tiles of the chip: B and C
-    are read ``n`` lanes at a time from lane ``H P`` on, a group's heads
-    ``r P`` lanes at a time, a chunk's tokens as sublanes of x and as
-    lanes of the running sums, whose rows are a group's heads.  The
+    """Whether the kernel's blocks are whole tiles of the chip and fit its
+    VMEM: B and C are read ``n`` lanes at a time from lane ``H P`` on, a
+    block of a group's heads (``_head_block``) ``rb P`` lanes at a time — at
+    most ``HEAD_BLOCK_LANES`` —, a chunk's tokens as sublanes of x and as
+    lanes of the running sums, whose rows are the block's heads.  The
     interpreter takes any widths whose blocks start on a block."""
     if h % g or (h * p) % n:
         return False
     if interpret:
         return True
-    r = h // g
-    return not (n % LANES or (r * p) % LANES or chunk % LANES or r % 8
-                or (_heads_a_tile(r, p) * p) % LANES)
+    rb = _head_block(h // g, p)
+    return not (n % LANES or (rb * p) % LANES or chunk % LANES or rb % 8
+                or rb * p > HEAD_BLOCK_LANES
+                or (_heads_a_tile(rb, p) * p) % LANES)
 
 
 def _pick(per_head, head_of, t: int):
@@ -209,10 +238,11 @@ def _pick(per_head, head_of, t: int):
 
 def _scan_kernel(real_ref, x_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, s0_ref,
                  y_ref, s_ref, *, r: int, p: int, t: int):
-    """One chunk of one row's one head group.  ``x_ref`` [Q, r P], ``b_ref``
-    / ``c_ref`` [Q, N], ``dt_ref`` / ``cum_ref`` [r, Q] (a head a row),
-    ``d_ref`` [1, r P]; ``s_ref`` (the output block, resident over the
-    row's chunks) is the carried state [r P, N]."""
+    """One chunk of one row's one block of ``r`` heads of a group.  ``x_ref``
+    [Q, r P], ``b_ref`` / ``c_ref`` [Q, N] (their group's), ``dt_ref`` /
+    ``cum_ref`` [r, Q] (a head a row), ``d_ref`` [1, r P]; ``s_ref`` (the
+    output block, resident over the row's chunks) is the carried state
+    [r P, N]."""
     from jax.experimental import pallas as pl
 
     f32 = jnp.float32
@@ -236,7 +266,7 @@ def _scan_kernel(real_ref, x_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, s0_ref,
                 preferred_element_type=f32)
 
         bm, cm = b_ref[...], c_ref[...]
-        cb = dot(cm, bm, (1, 1))  # C B^T [Q, K], once a group
+        cb = dot(cm, bm, (1, 1))  # C B^T [Q, K], once a block of heads
         tril = (jax.lax.broadcasted_iota(jnp.int32, (q, q), 0)
                 >= jax.lax.broadcasted_iota(jnp.int32, (q, q), 1))
         dt, cum = dt_ref[...], cum_ref[...]  # [r, Q]: a head's tokens a row
@@ -274,8 +304,12 @@ def _scan_kernel(real_ref, x_ref, b_ref, c_ref, dt_ref, cum_ref, d_ref, s0_ref,
 @functools.partial(jax.jit, static_argnames=("g", "n", "chunk", "interpret"))
 def _scan_kernel_call(xbc, dt, a, d, s0, mask, g: int, n: int, chunk: int,
                       interpret: bool):
-    """The kernel over ``(B, G, L / chunk)``, the chunks innermost and in
-    order: x, B and C through block index maps from ``xbc`` where it lies,
+    """The kernel over ``(B, G x nb, L / chunk)`` — ``nb`` blocks of
+    ``_head_block`` heads a group, a group's blocks side by side as its
+    heads lie, so a head block's index IS its place among all of them and
+    only B and C's maps ask which group it belongs to; one block a group
+    (``nb`` 1) is the grid ``(B, G, L / chunk)`` —, the chunks innermost and
+    in order: x, B and C through block index maps from ``xbc`` where it lies,
     dt and its running sums (computed here, exact float32) a head a row,
     the count of real tokens a chunk as a scalar-prefetch operand.  dt is
     float32 and masked, L a multiple of ``chunk``."""
@@ -284,15 +318,20 @@ def _scan_kernel_call(xbc, dt, a, d, s0, mask, g: int, n: int, chunk: int,
 
     f32 = jnp.float32
     bsz, length, h = dt.shape
-    r, nc = h // g, length // chunk
+    nc = length // chunk
     inner = xbc.shape[-1] - 2 * g * n
     p = inner // h
+    r = _head_block(h // g, p)  # heads a program
+    nb = h // g // r  # programs a group
     dt_t, cum_t = _chunk_sums(dt, a, chunk)  # [B, nc, H, Q]
     real = jnp.sum((mask != 0).reshape(bsz, nc, chunk), axis=-1, dtype=jnp.int32)
-    d_lanes = jnp.repeat(d.astype(f32), p).reshape(g, 1, r * p)
+    d_lanes = jnp.repeat(d.astype(f32), p).reshape(g * nb, 1, r * p)
 
-    def spec(block, index):  # index(b, g, z) -> block indices
+    def spec(block, index):  # index(b, head block, z) -> block indices
         return pl.BlockSpec(block, lambda bi, gi, z, real: index(bi, gi, z))
+
+    def group(gi):  # the group head block ``gi`` reads its B and C from
+        return gi if nb == 1 else gi // nb
 
     per_head = spec((None, None, r, chunk), lambda bi, gi, z: (bi, z, gi, 0))
     state = spec((None, None, r * p, n), lambda bi, gi, z: (bi, gi, 0, 0))
@@ -301,11 +340,13 @@ def _scan_kernel_call(xbc, dt, a, d, s0, mask, g: int, n: int, chunk: int,
         functools.partial(_scan_kernel, r=r, p=p, t=_heads_a_tile(r, p)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
-            grid=(bsz, g, nc),
+            grid=(bsz, g * nb, nc),
             in_specs=[
                 heads,
-                spec((None, chunk, n), lambda bi, gi, z: (bi, z, inner // n + gi)),
-                spec((None, chunk, n), lambda bi, gi, z: (bi, z, inner // n + g + gi)),
+                spec((None, chunk, n),
+                     lambda bi, gi, z: (bi, z, inner // n + group(gi))),
+                spec((None, chunk, n),
+                     lambda bi, gi, z: (bi, z, inner // n + g + group(gi))),
                 per_head, per_head,
                 spec((None, 1, r * p), lambda bi, gi, z: (gi, 0, 0)),
                 state,
@@ -313,13 +354,13 @@ def _scan_kernel_call(xbc, dt, a, d, s0, mask, g: int, n: int, chunk: int,
             out_specs=[heads, state],
         ),
         out_shape=[jax.ShapeDtypeStruct((bsz, length, inner), f32),
-                   jax.ShapeDtypeStruct((bsz, g, r * p, n), f32)],
+                   jax.ShapeDtypeStruct((bsz, g * nb, r * p, n), f32)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
         name="ssm_scan",
     )(real, xbc, xbc, xbc, dt_t, cum_t, d_lanes,
-      s0.reshape(bsz, g, r * p, n))
+      s0.reshape(bsz, g * nb, r * p, n))
     return y, s.reshape(bsz, h, p, n)
 
 

@@ -163,6 +163,25 @@ def test_table_blocks_dead_pct_reads_the_programs_counters():
 # per-layer entries, held by NAME (cellbench/tests/*::test_entries_resolve_by_name
 # want older lists to be the tail of theirs)
 
+def _named(entries: list, name: str) -> dict:
+    """The ONE entry of a ``BENCHMARK.json`` list called ``name``: a
+    configuration, a cell or a metric is held by its NAME, never by its place
+    in the list — every later PR appends (PR 56)."""
+    (entry,) = [e for e in entries if e["name"] == name]
+    return entry
+
+
+def _lists(entry: dict, standing: list) -> bool:
+    """Whether ``entry``'s ``workloads`` begin with its ``standing`` members
+    in their order: a later PR appends its cell behind them and changes
+    nothing else (one entry a definition, PR 55)."""
+    return entry["workloads"][:len(standing)] == standing
+
+
+def _without_workloads(entry: dict) -> dict:
+    return {k: v for k, v in entry.items() if k != "workloads"}
+
+
 DSV2_CELL = "deepseek-v2-ep4-d5.longdoc-closed"
 DSV2_ENTRIES = [
     ("decode_step_ms.dsv2", "ms", "device_trace", "model step", "trace_module_ms"),
@@ -210,11 +229,12 @@ def test_dsv2_configuration_and_cell_are_in_the_benchmark():
 def test_dsv2_per_layer_entry_resolves(name, unit, source, layer, reader):
     from cellbench import spec
 
-    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
-    assert entry == {
+    entry = _named(spec.load_benchmark()["per_layer"], name)
+    assert _without_workloads(entry) == {
         "name": name, "unit": unit,
         "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms", "workloads": [DSV2_CELL]}
+        "moves": "tbt_p99_ms"}
+    assert _lists(entry, [DSV2_CELL])
     assert entry["better"] in ("lower", "higher")
     (resolved,) = [m for m in spec.resolve(DSV2_CELL).per_layer if m.name == name]
     assert resolved.reader == reader and callable(resolved.read)
@@ -328,11 +348,16 @@ def test_boot_entry_resolves_in_every_cell(name, unit, family, labels):
     from cellbench import spec
     from mlmicroservicetemplate_tpu.utils import metrics
 
-    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
-    assert entry == {
+    bench = spec.load_benchmark()
+    entry = _named(bench["per_layer"], name)
+    assert _without_workloads(entry) == {
         "name": name, "unit": unit, "better": "lower", "source": "program_counter",
-        "layer": "compile", "moves": "setup_s", "workloads": BOOT_CELLS}
-    for cell in BOOT_CELLS:
+        "layer": "compile", "moves": "setup_s"}
+    # every cell reports setup_s, so every cell lists the boot entries: the
+    # standing eight at the head, each later PR's cell behind them
+    assert _lists(entry, BOOT_CELLS)
+    assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
+    for cell in entry["workloads"]:
         (resolved,) = [m for m in spec.resolve(cell).per_layer if m.name == name]
         assert resolved.reader == "prom_labelled" and callable(resolved.read)
         assert resolved.args["family"] == family
@@ -403,9 +428,12 @@ def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
     assert entry == {
         "name": name, "unit": "%", "better": "higher", "source": "program_counter",
         "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
-    # appended by PR 36 (nothing before them moved); PR 40's 24, PR 41's
-    # two, PR 47's 29 and PR 51's one follow them
-    assert entry in per_layer[-58:-56]
+    # appended by PR 36 behind the boot entries, the two side by side (by
+    # NAME: every later PR's entries follow them)
+    names = [m["name"] for m in per_layer]
+    assert names.index(name) > names.index("boot_unnamed_pct")
+    assert abs(names.index("prefill_windows_batched_pct.trinity")
+               - names.index("prefill_windows_batched_pct.dsv2")) == 1
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_counter_ratio" and callable(metric.read)
@@ -483,14 +511,13 @@ def test_nemotron_configuration_and_cell_are_in_the_benchmark():
     from cellbench import spec
 
     bench = spec.load_benchmark()
-    assert bench["configs"][-3]["name"] == "nemotron3-super-ep4-d11"  # appended
-    cfg = bench["configs"][-3]  # (PR 47 and PR 51 appended one each after it)
+    cfg = _named(bench["configs"], "nemotron3-super-ep4-d11")  # by NAME
     assert cfg["file"] == "cellbench/configs/nemotron3-super-ep4-d11.json"
     assert cfg["source"] == (
         "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16"
         "/blob/main/config.json")
     assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    cell = bench["workloads"][-3]
+    cell = _named(bench["workloads"], NEMO_CELL)
     assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
         NEMO_CELL, "nemotron3-super-ep4-d11", "longdoc-closed", 1)
     assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
@@ -498,7 +525,7 @@ def test_nemotron_configuration_and_cell_are_in_the_benchmark():
           if "workloads" not in m or NEMO_CELL in m["workloads"]]
     assert on == ["tbt_p99_ms", "setup_s"]
     boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(m["workloads"][-3] == NEMO_CELL for m in boots)
+    assert len(boots) == 7 and all(NEMO_CELL in m["workloads"] for m in boots)
     file = spec.load_json(spec.REPO + "/" + cfg["file"])
     assert set(file["reduced"]) == set(cfg["reduced"])
     assert (file["num_hidden_layers"], file["n_routed_experts"],
@@ -512,12 +539,12 @@ def test_nemotron_per_layer_entry_resolves(name, unit, source, layer, reader):
     from cellbench import spec
 
     name += ".nemotron"
-    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
-    assert entry == {
+    entry = _named(spec.load_benchmark()["per_layer"], name)
+    assert _without_workloads(entry) == {
         "name": name, "unit": unit,
         "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms",
-        "workloads": [NEMO_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED)}
+        "moves": "tbt_p99_ms"}
+    assert _lists(entry, [NEMO_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED))
     assert entry["better"] in ("lower", "higher")
     (resolved,) = [m for m in spec.resolve(NEMO_CELL).per_layer if m.name == name]
     assert resolved.reader == reader and callable(resolved.read)
@@ -576,8 +603,12 @@ def test_insert_rows_per_dispatch_resolves_in_its_cell(name, cell):
         "name": name, "unit": "rows", "better": "higher",
         "source": "program_counter", "layer": "engine", "moves": "tokens_per_s",
         "workloads": [cell]}
-    # appended: nothing before them moved (PR 47's 29 and PR 51's one follow them)
-    assert entry in per_layer[-32:-30]
+    # appended behind PR 40's entries, the two side by side (by NAME: every
+    # later PR's entries follow them)
+    names = [m["name"] for m in per_layer]
+    assert names.index(name) > names.index("device_idle_pct.nemotron")
+    assert abs(names.index("insert_rows_per_dispatch.decode")
+               - names.index("insert_rows_per_dispatch.olmoe")) == 1
     resolved = spec.resolve(cell)
     (metric,) = [m for m in resolved.per_layer if m.name == name]
     assert metric.reader == "prom_hist" and callable(metric.read)
@@ -660,8 +691,8 @@ def test_gigachat_configuration_and_cell_are_in_the_benchmark():
     from cellbench import spec
 
     bench = spec.load_benchmark()
-    cfg, cell = bench["configs"][-2], bench["workloads"][-2]  # appended (PR 51's follow)
-    assert cfg["name"] == "gigachat35-ep16-d5"
+    cfg = _named(bench["configs"], "gigachat35-ep16-d5")  # by NAME
+    cell = _named(bench["workloads"], GIGA_CELL)
     assert cfg["file"] == "cellbench/configs/gigachat35-ep16-d5.json"
     assert cfg["source"] == (
         "https://huggingface.co/ai-sage/GigaChat3.5-432B-A28B/blob/main/config.json")
@@ -674,8 +705,10 @@ def test_gigachat_configuration_and_cell_are_in_the_benchmark():
           if "workloads" not in m or GIGA_CELL in m["workloads"]]
     assert on == ["tbt_p99_ms", "setup_s"]
     boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(m["workloads"][-2] == GIGA_CELL for m in boots)
-    assert [m["name"] for m in bench["per_layer"][-len(GIGA_ENTRIES) - 1:-1]] == [
+    assert len(boots) == 7 and all(GIGA_CELL in m["workloads"] for m in boots)
+    names = [m["name"] for m in bench["per_layer"]]
+    first = names.index(GIGA_ENTRIES[0][0] + ".gigachat")  # the 29 stand together
+    assert names[first:first + len(GIGA_ENTRIES)] == [
         n + ".gigachat" for n, *_ in GIGA_ENTRIES]
     assert len(bench["per_layer"]) <= 128  # the file's limit
     file = spec.load_json(spec.REPO + "/" + cfg["file"])
@@ -693,12 +726,12 @@ def test_gigachat_per_layer_entry_resolves(name, unit, source, layer, reader):
     from cellbench import spec
 
     name += ".gigachat"
-    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
-    assert entry == {
+    entry = _named(spec.load_benchmark()["per_layer"], name)
+    assert _without_workloads(entry) == {
         "name": name, "unit": unit,
         "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms",
-        "workloads": [GIGA_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED)}
+        "moves": "tbt_p99_ms"}
+    assert _lists(entry, [GIGA_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED))
     assert entry["better"] in ("lower", "higher")
     (resolved,) = [m for m in spec.resolve(GIGA_CELL).per_layer if m.name == name]
     assert resolved.reader == reader and callable(resolved.read)
@@ -731,7 +764,8 @@ def test_jamba_configuration_and_cell_are_in_the_benchmark():
     from cellbench import spec
 
     bench = spec.load_benchmark()
-    cfg, cell = bench["configs"][-1], bench["workloads"][-1]  # appended
+    cfg = _named(bench["configs"], "jamba2-3b-d28")  # by NAME
+    cell = _named(bench["workloads"], JAMBA_CELL)
     assert (cfg["name"], cfg["file"], cfg["reduced"]) == (
         "jamba2-3b-d28", "cellbench/configs/jamba2-3b-d28.json", [])
     assert cfg["source"] == (
@@ -743,9 +777,9 @@ def test_jamba_configuration_and_cell_are_in_the_benchmark():
           if "workloads" not in m or JAMBA_CELL in m["workloads"]]
     assert on == ["tbt_p99_ms", "setup_s"]
     boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(m["workloads"][-1] == JAMBA_CELL for m in boots)
-    assert len(bench["configs"]) == 7 and len(bench["workloads"]) == 8
-    assert len(bench["per_layer"]) == 128  # the file's limit: one was left
+    assert len(boots) == 7 and all(JAMBA_CELL in m["workloads"] for m in boots)
+    assert len(bench["configs"]) >= 7 and len(bench["workloads"]) >= 8
+    assert len(bench["per_layer"]) <= 128  # the file's limit
     file = spec.load_json(spec.REPO + "/" + cfg["file"])
     assert file["reduced"] == {} and file["num_hidden_layers"] == 28
     assert file["tie_word_embeddings"] is True and file["num_key_value_heads"] == 1
@@ -753,13 +787,13 @@ def test_jamba_configuration_and_cell_are_in_the_benchmark():
 
 
 def test_jamba_per_layer_entries_resolve():
-    """The one new entry is the LAST one; every sibling entry the cell is
-    appended to lists it last, moves the end-to-end metric the cell reports
-    and resolves to a reader; no other cell reads the new entry."""
+    """PR 51's one new entry, by NAME; every sibling entry the cell was
+    appended to lists it, moves the end-to-end metric the cell reports and
+    resolves to a reader; no other cell reads the new entry."""
     from cellbench import spec
 
     per_layer = spec.load_benchmark()["per_layer"]
-    assert per_layer[-1] == {
+    assert _named(per_layer, "ssm_scan_roofline.jamba2") == {
         "name": "ssm_scan_roofline.jamba2", "unit": "%", "better": "higher",
         "source": "device_trace", "layer": "kernels", "moves": "tbt_p99_ms",
         "workloads": [JAMBA_CELL]}
@@ -767,13 +801,128 @@ def test_jamba_per_layer_entries_resolve():
     assert mine["ssm_scan_roofline.jamba2"].reader == "jamba_roofline"
     assert mine["ssm_scan_roofline.jamba2"].args == {"what": "ssm_scan"}
     listed = [m["name"] for m in per_layer if JAMBA_CELL in m.get("workloads", [])]
-    assert sorted(listed) == sorted(
-        [*JAMBA_SHARED, "ssm_scan_roofline.jamba2",
-         *(m["name"] for m in per_layer if m["name"].startswith("boot_"))])
+    # PR 51's, and the three PR 55 landed for the cell (the step's and the
+    # attention kernel's shares its reader already computed, the loop's
+    # unnamed share); a later PR may list the cell elsewhere too
+    assert set(listed) >= {
+        *JAMBA_SHARED, "ssm_scan_roofline.jamba2", "decode_step_roofline.jamba2",
+        "paged_decode_attention_roofline.jamba2", "loop_unnamed_pct.serve",
+        *(m["name"] for m in per_layer if m["name"].startswith("boot_"))}
     for m in per_layer:
         if m["name"] in JAMBA_SHARED:
-            assert m["workloads"][-1] == JAMBA_CELL and m["moves"] == "tbt_p99_ms"
+            assert JAMBA_CELL in m["workloads"] and m["moves"] == "tbt_p99_ms"
             assert callable(mine[m["name"]].read)
-    for other in spec.load_benchmark()["workloads"][:-1]:
-        assert "ssm_scan_roofline.jamba2" not in [
-            m.name for m in spec.resolve(other["name"]).per_layer]
+    for other in spec.load_benchmark()["workloads"]:
+        if other["name"] != JAMBA_CELL:
+            assert "ssm_scan_roofline.jamba2" not in [
+                m.name for m in spec.resolve(other["name"]).per_layer]
+
+
+# ---------------------------------------------------------------------------
+# granite-4.0-h-small-ep2-d10 (PR 56): one configuration, one cell, FIVE new
+# per-layer entries (each a new definition: the file stands at its limit of
+# 128) and the cell's name appended to the thirty standing entries whose
+# definitions read its scopes and counters — all held by NAME
+
+
+GRANITE_CELL = "granite-4.0-h-small-ep2-d10.longdoc-closed"
+GRANITE_ENTRIES = [
+    ("decode_step_roofline.granite", "model step", "step"),
+    ("moe_experts_roofline.granite", "kernels", "experts"),
+    ("ssm_scan_roofline.granite", "kernels", "ssm_scan"),
+    ("ssm_step_roofline.granite", "kernels", "ssm_step"),
+    ("paged_decode_attention_roofline.granite", "kernels", "attention"),
+]
+#: The standing entries the cell is appended to (one entry a definition, PR
+#: 55): the loop's and the process's counters, the long-document cells'
+#: spans, Nemotron's readings of the Mamba-2 scopes, the expert block's.
+GRANITE_SHARED = [
+    "loop_unnamed_pct.serve", "event_loop_lag_p99_ms",
+    "device_idle_pct.nemotron", "streams_per_chunk.nemotron",
+    "prefill_stall_ms.nemotron", "table_blocks_dead_pct.nemotron",
+    "prefill_window_ms.nemotron", "prefill_windows_batched_pct.nemotron",
+    "decode_step_ms.nemotron", "decode_ssm_ms.nemotron", "ssm_proj_ms.nemotron",
+    "decode_attn_ms.nemotron", "decode_moe_ms.nemotron", "moe_overhead_ms.nemotron",
+    "moe_shared_ms.nemotron", "moe_imbalance.nemotron", "moe_held_share_pct.nemotron",
+    "moe_rows_skipped_pct.dsv2", "prefill_ssm_scan_ms.nemotron",
+    "ssm_scan_masked_pct.nemotron", "ssm_state_share_pct.nemotron",
+    "prefill_mlp_ms.gigachat", "prefill_moe_experts_ms.gigachat"]
+
+
+def test_granite_configuration_and_cell_are_in_the_benchmark():
+    from cellbench import spec
+
+    bench = spec.load_benchmark()
+    cfg = _named(bench["configs"], "granite-4.0-h-small-ep2-d10")
+    cell = _named(bench["workloads"], GRANITE_CELL)
+    assert cfg["file"] == "cellbench/configs/granite-4.0-h-small-ep2-d10.json"
+    assert cfg["source"] == (
+        "https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.json")
+    assert cfg["reduced"] == ["num_hidden_layers", "num_local_experts", "vocab_size"]
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        "granite-4.0-h-small-ep2-d10", "longdoc-closed", 1)
+    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
+    on = [m["name"] for m in bench["end_to_end"]
+          if "workloads" not in m or GRANITE_CELL in m["workloads"]]
+    assert on == ["tbt_p99_ms", "setup_s"]
+    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
+    assert len(boots) == 7 and all(GRANITE_CELL in m["workloads"] for m in boots)
+    assert len(bench["per_layer"]) <= 128  # the file's limit
+    file = spec.load_json(spec.REPO + "/" + cfg["file"])
+    assert list(file["reduced"]) == cfg["reduced"]
+    assert (file["num_hidden_layers"], file["num_local_experts"], file["router_experts"],
+            file["vocab_size"]) == (10, 36, 72, 50176)
+    assert file["layer_types"][:10] == ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
+    assert file["llama_layer_types"][:10] == ["mamba2"] * 5 + ["attention"] + ["mamba2"] * 4
+    assert "deployment" in file["assumed"] and "router" in file["assumed"]
+    # the traffic file is the other long-document cells', unchanged
+    assert spec.resolve(GRANITE_CELL).traffic == spec.resolve(NEMO_CELL).traffic
+
+
+@pytest.mark.parametrize("name,layer,what", GRANITE_ENTRIES)
+def test_granite_per_layer_entry_resolves(name, layer, what):
+    from cellbench import spec
+
+    bench = spec.load_benchmark()
+    assert _named(bench["per_layer"], name) == {
+        "name": name, "unit": "%", "better": "higher", "source": "device_trace",
+        "layer": layer, "moves": "tbt_p99_ms", "workloads": [GRANITE_CELL]}
+    (resolved,) = [m for m in spec.resolve(GRANITE_CELL).per_layer if m.name == name]
+    assert resolved.reader == "granite_roofline" and resolved.args == {"what": what}
+    for other in bench["workloads"]:  # read in its own cell only
+        if other["name"] != GRANITE_CELL:
+            assert name not in [m.name for m in spec.resolve(other["name"]).per_layer]
+
+
+@pytest.mark.parametrize("name", GRANITE_SHARED)
+def test_granite_is_appended_to_the_entry_that_has_its_definition(name):
+    """The standing entry lists the cell BEHIND the cells it had, moves the
+    end-to-end metric the cell reports and resolves there to the reader and
+    arguments it resolves to in its first cell: one definition, read twice."""
+    from cellbench import spec
+
+    entry = _named(spec.load_benchmark()["per_layer"], name)
+    assert entry["workloads"][-1] == GRANITE_CELL and len(entry["workloads"]) >= 2
+    assert entry["moves"] == "tbt_p99_ms"
+    (mine,) = [m for m in spec.resolve(GRANITE_CELL).per_layer if m.name == name]
+    (first,) = [m for m in spec.resolve(entry["workloads"][0]).per_layer if m.name == name]
+    assert (mine.reader, mine.args) == (first.reader, first.args) and callable(mine.read)
+
+
+def test_granite_rooflines_read_nothing_from_a_program_without_the_scopes():
+    """The five shares through the reader their entries name: untraced, or on
+    a trace without the executable (the parent), no value and no raise."""
+    import types
+
+    from cellbench import spec
+
+    cell = spec.resolve(GRANITE_CELL)
+    own = [m for m in cell.per_layer if m.reader == "granite_roofline"]
+    assert len(own) == 5
+    empty = types.SimpleNamespace(module_time=lambda m: (0.0, 0), ops={})
+    for trace in (None, empty):
+        ctx = types.SimpleNamespace(
+            trace=trace, peaks={"hbm_bytes_per_s": 8.19e11, "bf16_flops_per_s": 1.97e14},
+            prom_after={}, prom_before={}, notes={}, config=cell.config,
+            engine={"chunk_tokens": 4}, prom_delta=lambda family: None)
+        assert [m.read(ctx, **m.args) for m in own] == [None] * 5

@@ -607,10 +607,14 @@ def test_the_scores_reader_sees_xlas_scores(chip):
     assert scores_in_hbm(text, 32 * 1024 * 1024)
 
 
-@pytest.mark.parametrize("rows", [3, 1])
-def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows):
+@pytest.mark.parametrize("rows,g", [(3, 8), (1, 8), (3, 1)],
+                         ids=["3", "1", "granite-one-group-3"])
+def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows, g):
     """The chunked scan of a Mamba-2 layer at Nemotron's widths (128 heads
-    of 64 in 8 groups, state 128, chunks of 128) over a dispatch of
+    of 64 in 8 groups, state 128, chunks of 128) — and at Granite's, the same
+    heads in ONE group, whose 128 heads the kernel tiles over its grid 16 at
+    a time (a whole group's blocks would not fit the scoped VMEM: Mosaic
+    refuses them here, not in a cell's boot) — over a dispatch of
     ``rows`` windows of 1024 tokens, x, B and C read from the
     convolution's ``[rows, 1024, 10240]`` bfloat16 where they lie: with
     ``kernel`` ONE Mosaic kernel named ``ssm_scan`` and no float32 array
@@ -621,7 +625,8 @@ def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows):
     from mlmicroservicetemplate_tpu.ops import ssm
     from mlmicroservicetemplate_tpu.ops.prefill_attention import scores_in_hbm
 
-    h, p, g, n, q, length = 128, 64, 8, 128, 128, 1024
+    h, p, n, q, length = 128, 64, 128, 128, 1024
+    assert ssm._kernel_fits(h, p, g, n, q, False)
     f32 = jnp.float32
     args = (chip((rows, length, h * p + 2 * g * n), jnp.bfloat16),
             chip((rows, length, h), f32), chip((h,), f32), chip((h,), f32),
@@ -629,7 +634,7 @@ def test_the_prompt_scans_decay_matrices_stay_on_the_chip(chip, rows):
 
     def text(kernel):
         return _compiled_text(
-            chip, ("ssm_scan", rows, kernel),
+            chip, ("ssm_scan", rows, g, kernel),
             lambda *a: ssm.ssm_scan(*a, groups=g, state=n, chunk=q, kernel=kernel),
             *args)
 
@@ -801,13 +806,15 @@ def test_an_undonated_insert_copies_every_pool(chip):
 @pytest.mark.parametrize("rows,experts,d,w", [
     (512, 64, 2048, 1024), (65536, 64, 2048, 1024), (24576, 128, 2048, 1024),
     (12288, 40, 5120, 1536), (67584, 128, 1024, 2688),
+    (30720, 36, 4096, 768), (320, 36, 4096, 768),
 ], ids=["olmoe-step", "olmoe-wave", "trinity-dispatch", "dsv2-window",
-        "nemotron-dispatch"])
+        "nemotron-dispatch", "granite-dispatch", "granite-step"])
 def test_grouped_matmul_compiles(chip, rows, experts, d, w):
     """A decode step's 512 assignments, a 64 x 128 wave's 65 536 and the
     prompt dispatches of the long-document cells (three windows of 1024
     tokens x top-8 over Trinity's 128 experts, one of 2048 x top-6 over
-    DeepSeek-V2's 40 held, three x top-22 over Nemotron's 128 held)
+    DeepSeek-V2's 40 held, three x top-22 over Nemotron's 128 held, three x
+    top-10 over Granite's 36 held experts 768 wide and its 32-row step)
     through both expert matmul shapes (gate / up and down): a tiling
     over the scoped VMEM limit fails here, not in a cell's boot."""
     from mlmicroservicetemplate_tpu.ops.moe import grouped_matmul
@@ -826,7 +833,9 @@ def test_grouped_matmul_compiles(chip, rows, experts, d, w):
     (3072, 22, 128, 512, 4096, 1024, 2688, "relu2"),
     (3072, 8, 16, 256, 7168, 0, 2048, "silu"),
     (2048, 6, 40, 160, 5120, 0, 1536, "silu"),
-], ids=["nemotron-dispatch", "gigachat-dispatch", "dsv2-window"])
+    (3072, 10, 36, 72, 4096, 0, 768, "silu"),
+], ids=["nemotron-dispatch", "gigachat-dispatch", "dsv2-window",
+        "granite-dispatch"])
 def test_the_expert_blocks_ladder_compiles_at_a_shares_prompt_shapes(
         chip, tokens, k, held, pub, d, latent, w, act):
     """One expert layer's block of the three cells that hold a chip's share,

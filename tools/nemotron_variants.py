@@ -223,7 +223,7 @@ def main(argv=None, family: Family | None = None) -> int:
     from mlmicroservicetemplate_tpu.models import llama
 
     config = spec.load_json(os.path.join(
-        spec.HERE, "configs", family.cell.split(".")[0] + ".json"))
+        spec.HERE, "configs", family.cell.rsplit(".", 1)[0] + ".json"))
     if a.rehearse:
         config = bench_run._merge(config, spec.load_json(a.rehearse)["config"])
     ref = spec.load_module(
