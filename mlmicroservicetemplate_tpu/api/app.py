@@ -1585,9 +1585,11 @@ async def handle_status(request: web.Request) -> web.Response:
             }
         if getattr(cdl, "moe_rows", None):
             # Expert FFN: assignment rows the block's row work ran over
-            # and rows it skipped (ops/moe.row_rungs), by step kind.
+            # and rows it skipped (ops/moe.row_rungs), and the held rows of
+            # calls whose shuffles took the DMA kernels, by step kind.
             body["decode"]["expert_rows"] = {
-                kind: {"ran": ran, "skipped": skipped}
+                kind: {"ran": ran, "skipped": skipped,
+                       "fused": cdl.moe_rows_fused.get(kind, 0)}
                 for kind, (ran, skipped) in cdl.moe_rows.items()
             }
         kv_var = getattr(cdl, "kernel_variant", "")

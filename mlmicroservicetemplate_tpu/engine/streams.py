@@ -549,8 +549,10 @@ class ContinuousDecodeLoop:
         # one's (counts [L, E], tokens) on the device, fetched with that
         # chunk (``_note_dispatched``).
         self._moe_windows: list = []
-        # kind -> [rows ran, rows skipped] of the expert block (/status).
+        # kind -> [rows ran, rows skipped] of the expert block, and kind ->
+        # held rows of calls whose shuffles took the DMA kernels (/status).
         self.moe_rows: dict = {}
+        self.moe_rows_fused: dict = {}
         if self.paged:
             from .kv_blocks import blocks_for
 
@@ -5119,7 +5121,7 @@ class ContinuousDecodeLoop:
         decode step's ladder has one rung at every slot count a cell
         runs; a chunk whose steps had several would be counted at its
         steps' mean held count."""
-        from ..ops.moe import row_rungs, rung_index
+        from ..ops.moe import row_kernels_fit, row_rungs, rung_index
 
         bcfg = self.engine.bundle.cfg
         first, n_held = self._experts_held
@@ -5128,12 +5130,19 @@ class ContinuousDecodeLoop:
         held = counts[:, first:first + n_held].sum(axis=1) // steps
         ran = int(np.take(rungs, rung_index(held, rungs)).sum()) * steps
         skipped = n * steps * len(counts) - ran
+        # The held rows themselves where the call's two shuffles took the
+        # DMA kernels: the rule the traced program read, on the same shape.
+        fused = int(held.sum()) * steps if row_kernels_fit(
+            n, bcfg.moe_latent or bcfg.d_model,
+            self.engine.bundle.policy.compute_jnp) else 0
         seen = self.moe_rows.setdefault(kind, [0, 0])
         seen[0] += ran
         seen[1] += skipped
+        self.moe_rows_fused[kind] = self.moe_rows_fused.get(kind, 0) + fused
         name = self.engine.bundle.name
         metrics.MOE_ROWS.labels(name, kind, "ran").inc(ran)
         metrics.MOE_ROWS.labels(name, kind, "skipped").inc(skipped)
+        metrics.MOE_ROWS_FUSED.labels(name, kind).inc(fused)
 
     def _deliver_oldest(self) -> None:
         import jax

@@ -1067,8 +1067,15 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
     # window keeps no hidden state, so XLA removes that layer's FFN (and
     # the attention output under it) from the executable, and asking for
     # its counts would bring both back (+16 ms a GigaChat dispatch).
+    # Likewise a tree whose rows are wide enough for the block's DMA
+    # kernels (ops/moe.row_kernels_fit): the loop counts the held rows of
+    # the calls that took them (moe_rows_fused_total).
+    from ..ops.moe import rows_fit_kernels
+
     counted = sum(li != cfg.num_layers - 1 for li in cfg.expert_layers)
-    share = bool(counted) and cfg.held != cfg.num_experts
+    share = bool(counted) and (
+        cfg.held != cfg.num_experts
+        or rows_fit_kernels(cfg.moe_latent or cfg.d_model, policy.compute_jnp))
 
     def paged_prefill_chunk_fn(p, state, table_rows, ids, mask, starts,
                                ssm_rows=None):

@@ -217,6 +217,15 @@ MOE_ROWS = Counter(
     "experts), from each call's own counts",
     ["model", "kind", "state"],
 )
+MOE_ROWS_FUSED = Counter(
+    "moe_rows_fused_total",
+    "Expert FFN: held assignment rows of the calls whose two row shuffles "
+    "— rows into expert order, a token's rows back weighted and summed — "
+    "took the DMA kernels (ops/moe.row_kernels_fit, a rule on the call's "
+    "static shape; a call it leaves to XLA adds nothing), by step kind as "
+    "moe_rows_total, from each call's own counts",
+    ["model", "kind"],
+)
 SSM_STATE_BYTES = Gauge(
     "ssm_state_bytes",
     "Recurrent layers: bytes of recurrent state (a layer's float32 state "

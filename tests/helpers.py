@@ -277,3 +277,15 @@ def expert_rungs_at_toy_size(monkeypatch, tile: int = 8) -> list:
 
     monkeypatch.setattr(moe, "expert_ffn", keep)
     return calls
+
+
+def expert_row_kernels_at_toy_size(monkeypatch, rows: int = 128,
+                                   row_bytes: int = 512) -> None:
+    """Let the expert block's two DMA kernels (``ops/moe.row_kernels_fit``)
+    take a toy's calls: ``rows`` assignment rows of ``row_bytes`` are
+    enough, where the rule as shipped wants a prompt dispatch's thousands
+    of rows of 8 KB."""
+    from mlmicroservicetemplate_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "ROW_KERNELS_MIN_ROWS", rows)
+    monkeypatch.setattr(moe, "ROW_KERNELS_MIN_ROW_BYTES", row_bytes)

@@ -863,8 +863,45 @@ def test_the_expert_blocks_ladder_compiles_at_a_shares_prompt_shapes(
         lambda h, m, valid: moe.expert_ffn(
             h, m, k, True, valid, act=act, score="sigmoid"),
         chip((tokens, d), bf), mlp, chip((tokens,), jnp.bool_))
-    assert text.count(" conditional(") == 3
-    assert text.count("tpu_custom_call") == (3 if act == "silu" else 2)
+    # 16 384 rows and more of 8 KB and more (Granite, GigaChat; not
+    # Nemotron's latent 2 KB, not DeepSeek-V2's 12 288 rows): the gather
+    # and the combine are DMA kernels outside the conditionals — the row
+    # gather, the slabs and the combine beside the grouped matmuls.
+    fused = moe.row_kernels_fit(tokens * k, wide, jnp.bfloat16)
+    assert fused == (latent == 0 and tokens * k >= 16384)
+    assert text.count(" conditional(") == (1 if fused else 3)
+    assert text.count("tpu_custom_call") == (
+        (3 if act == "silu" else 2) + (3 if fused else 0))
+
+
+@pytest.mark.parametrize("tokens,k,wide", [
+    (3072, 10, 4096), (2048, 6, 5120), (3072, 8, 7168), (3072, 22, 1024),
+    (3072, 8, 2048), (8192, 8, 2048),
+], ids=["granite-dispatch", "dsv2-window", "gigachat-dispatch",
+        "nemotron-dispatch", "trinity-dispatch", "olmoe-wave"])
+def test_the_expert_blocks_row_kernels_compile(chip, tokens, k, wide):
+    """``sorted_rows`` and ``combine_rows`` (with its ``moe_row_slabs``) at
+    the rows the six expert cells' prompt dispatches and OLMoE's 64-row wave
+    move, whether the shape rule gives them the call or not: the index
+    arrays — up to 67 584 int32 for Nemotron, with as many float32 weights
+    — ride as scalar-prefetch operands, a row is a slab of whole tiles
+    (5120 and 7168 lanes pad to 24 and 32 sublanes, 1024 to 8), the
+    combine's two slots of ``combine_tile`` tokens x k slabs fit beside its
+    output block."""
+    from mlmicroservicetemplate_tpu.ops import moe
+
+    bf, m = jnp.bfloat16, tokens * k
+    live = chip((), jnp.int32)
+    text = _compiled_text(
+        chip, ("moe-sorted-rows", tokens, k, wide), moe.sorted_rows,
+        chip((tokens, wide), bf), chip((m,), jnp.int32), live)
+    assert text.count("tpu_custom_call") == 1
+    text = _compiled_text(
+        chip, ("moe-combine-rows", tokens, k, wide), moe.combine_rows,
+        chip((m, wide), bf), chip((tokens, k), jnp.int32),
+        chip((tokens, k), jnp.float32), live)
+    assert text.count("tpu_custom_call") == 2
+    assert f"f32[{k},{tokens},{wide}]" not in text  # no [k, T, D] in HBM
 
 
 def _largest_fit(kvh: int, d: int) -> int:

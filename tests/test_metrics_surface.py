@@ -379,3 +379,21 @@ def test_expert_rows_family_is_declared_and_bounded():
     seen = {(ln.split('kind="')[1].split('"')[0], ln.split('state="')[1].split('"')[0])
             for ln in _sample_lines(text) if ln.startswith("moe_rows_total{")}
     assert seen <= {(k, s) for k in ("decode", "prefill") for s in ("ran", "skipped")}
+
+
+def test_fused_expert_rows_family_is_declared_and_bounded():
+    """``moe_rows_fused_total`` (PR 57): declared, two labels, ``kind``
+    decode | prefill as ``moe_rows_total`` — ``tests/test_moe.py`` holds the
+    loop's counting to the shape rule, ``tests/test_nemotron_serving.py``
+    drives the loop itself."""
+    assert "moe_rows_fused" in _declared_families()
+    assert metrics.MOE_ROWS_FUSED._labelnames == ("model", "kind")
+    metrics.MOE_ROWS_FUSED.labels("surface-check", "prefill").inc(15360)
+    metrics.MOE_ROWS_FUSED.labels("surface-check", "decode").inc(0)
+    text = _scrape_body()
+    for line in ('moe_rows_fused_total{kind="prefill",model="surface-check"} 15360.0',
+                 'moe_rows_fused_total{kind="decode",model="surface-check"} 0.0'):
+        assert line in text, line
+    seen = {ln.split('kind="')[1].split('"')[0]
+            for ln in _sample_lines(text) if ln.startswith("moe_rows_fused_total{")}
+    assert seen <= {"decode", "prefill"}

@@ -802,15 +802,11 @@ def test_the_loop_counts_the_rows_each_call_ran_from_its_own_counts(
         kind, tokens, steps, k, held, pub, here, ran):
     """``_note_moe_rows`` on counts as they arrive ([L, E], this chip's
     experts first): rows ran = each layer's rung by ITS held count — the
-    device's rule — and skipped the rest of ``L x tokens x k x steps``."""
-    from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
+    device's rule — and skipped the rest of ``L x tokens x k x steps``.
+    (Rows 2 KB wide: no call takes the DMA kernels, none is counted fused.)"""
     from mlmicroservicetemplate_tpu.utils import metrics
 
-    loop = ContinuousDecodeLoop.__new__(ContinuousDecodeLoop)
-    bcfg = type("C", (), {"experts_per_token": k, "num_experts": pub})
-    loop.engine = type("E", (), {"bundle": type(
-        "B", (), {"name": f"moe-rows-{kind}-{pub}-{held}", "cfg": bcfg})})()
-    loop._experts_held, loop.moe_rows = (0, held), {}
+    loop = _counting_loop(f"moe-rows-{kind}-{pub}-{held}", k, held, pub, 1024)
     counts = np.zeros((len(here), pub), np.int64)
     counts[:, 0] = here
     if held != pub:
@@ -821,3 +817,306 @@ def test_the_loop_counts_the_rows_each_call_ran_from_its_own_counts(
     name = loop.engine.bundle.name
     assert metrics.MOE_ROWS.labels(name, kind, "ran")._value.get() == ran
     assert metrics.MOE_ROWS.labels(name, kind, "skipped")._value.get() == total - ran
+    assert loop.moe_rows_fused == {kind: 0}
+    assert metrics.MOE_ROWS_FUSED.labels(name, kind)._value.get() == 0
+
+
+def _counting_loop(name, k, held, pub, width, latent=0):
+    """A loop that is only what ``_note_moe_rows`` reads: a bfloat16 tree of
+    ``held`` of ``pub`` experts, rows ``latent or width`` wide."""
+    from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
+
+    loop = ContinuousDecodeLoop.__new__(ContinuousDecodeLoop)
+    bcfg = type("C", (), {"experts_per_token": k, "num_experts": pub,
+                          "d_model": width, "moe_latent": latent})
+    policy = type("P", (), {"compute_jnp": jnp.dtype(jnp.bfloat16)})
+    loop.engine = type("E", (), {"bundle": type(
+        "B", (), {"name": name, "cfg": bcfg, "policy": policy})})()
+    loop._experts_held, loop.moe_rows, loop.moe_rows_fused = (0, held), {}, {}
+    return loop
+
+
+@pytest.mark.parametrize("kind,tokens,steps,k,held,pub,width,latent,here,fused", [
+    # Granite's three-window dispatch, 8 KB rows: the held rows themselves
+    ("prefill", 3072, 1, 10, 36, 72, 4096, 0, [15000, 19300], 15000 + 19300),
+    # its decode chunk of four 32-row steps: XLA's form, nothing counted
+    ("decode", 32, 4, 10, 36, 72, 4096, 0, [4 * 150, 4 * 170], 0),
+    # GigaChat's three-window dispatch (14 KB rows); its lone window's
+    # 8192 rows and DeepSeek-V2's 12 288 (10 KB) are too few for the rule
+    ("prefill", 3072, 1, 8, 16, 256, 7168, 0, [1520, 24576], 1520 + 24576),
+    ("prefill", 1024, 1, 8, 16, 256, 7168, 0, [500], 0),
+    ("prefill", 2048, 1, 6, 40, 160, 5120, 0, [3000], 0),
+    # Nemotron's latent rows (2 KB) and OLMoE's (4 KB) stay with XLA
+    ("prefill", 3072, 1, 22, 128, 512, 4096, 1024, [16000], 0),
+    ("prefill", 3072, 1, 8, 64, 64, 2048, 0, [24576], 0),
+])
+def test_the_loop_counts_the_held_rows_of_the_calls_that_took_the_kernels(
+        kind, tokens, steps, k, held, pub, width, latent, here, fused):
+    """``moe_rows_fused_total``: the held assignment rows of the calls the
+    shape rule (``row_kernels_fit``, as the traced program read it) gave
+    the two DMA kernels — a prompt dispatch of wide rows — and nothing for
+    a decode chunk or for rows the rule leaves to XLA; ``moe_rows_total``
+    counts the rungs as it did."""
+    from mlmicroservicetemplate_tpu.utils import metrics
+
+    name = f"moe-fused-{kind}-{tokens}-{pub}-{width}-{latent}"
+    loop = _counting_loop(name, k, held, pub, width, latent)
+    counts = np.zeros((len(here), pub), np.int64)
+    counts[:, 0] = here
+    if held != pub:
+        counts[:, held] = tokens * k * steps - np.asarray(here)  # the absent
+    loop._note_moe_rows(kind, counts, tokens, steps)
+    assert loop.moe_rows_fused == {kind: fused}
+    assert metrics.MOE_ROWS_FUSED.labels(name, kind)._value.get() == fused
+    assert sum(loop.moe_rows[kind]) == len(here) * tokens * k * steps
+    assert bool(fused) == moe.row_kernels_fit(tokens * k, latent or width, jnp.bfloat16)
+
+
+# ---------------------------------------------------------------------------
+# the two row shuffles as DMA kernels (``sorted_rows``, ``combine_rows``)
+#
+# TOLERANCE of the combine: product and sum in float32 in slot order, ONE
+# rounding to the rows' dtype.  Against numpy's float32 loop in that order
+# the kernel is within one float32 ulp of the running sum a step BEFORE the
+# rounding (the interpreter's XLA may fuse a multiply into its add, which
+# rounds once where numpy rounds twice): float32 rows agree to 1e-6 of the
+# terms' size, bfloat16 outputs to the last bit except where that ulp
+# straddles a rounding boundary — at most one bfloat16 ulp, in under 1 % of
+# the elements.  A combine that rounded each product, or summed in
+# bfloat16, misses by several ulps in most elements (shown below).
+
+#: Decode-step tokens, prompt-dispatch tokens, k, width the routed experts
+#: see, and whether the dispatch takes the kernels (the chip's table,
+#: docs/kernel_tuning.md: 16 384 rows and more of 8 KB and more win, 4 KB
+#: and 2 KB rows lose).
+CELL_ROWS = {
+    "olmoe": (64, 8192, 8, 2048, False),
+    "trinity": (32, 3072, 8, 2048, False),
+    "deepseek-v2": (32, 2048, 6, 5120, False),  # 12 288 rows: too few
+    "nemotron": (32, 3072, 22, 1024, False),
+    "gigachat": (32, 3072, 8, 7168, True),
+    "granite": (32, 3072, 10, 4096, True),
+}
+
+
+@pytest.mark.parametrize("call", ["step", "dispatch"])
+@pytest.mark.parametrize("cell", sorted(CELL_ROWS))
+def test_the_rule_leaves_every_decode_step_to_xla(cell, call):
+    """``row_kernels_fit`` at the shapes the six expert cells run in
+    bfloat16: no decode step's few hundred rows fit; a prompt dispatch fits
+    where it has 16 384 rows of 8 KB or more."""
+    step, prompt, k, width, fits = CELL_ROWS[cell]
+    if call == "step":
+        assert not moe.row_kernels_fit(step * k, width, jnp.bfloat16)
+        assert not moe.row_kernels_fit(4 * step * k, width, jnp.bfloat16)
+    else:
+        assert moe.row_kernels_fit(prompt * k, width, jnp.bfloat16) == fits
+        assert not moe.row_kernels_fit(prompt * k + 1, width, jnp.bfloat16)  # row tiles
+        assert not moe.row_kernels_fit(prompt * k, width + 64, jnp.bfloat16)  # lanes
+        assert not moe.row_kernels_fit(16256, width, jnp.bfloat16)  # 127 tiles
+        assert moe.row_kernels_fit(16384, width, jnp.bfloat16) == (width >= 4096)
+
+
+def _slot_order_sum(ys, pos, w, n_live):
+    """numpy: ``sum_j w[t, j] * ys[pos[t, j]]`` over ``pos < n_live`` in
+    float32, slot by slot."""
+    pos, w = np.asarray(pos), np.asarray(w, np.float32)
+    back = np.where((pos < n_live)[:, :, None],
+                    np.nan_to_num(np.asarray(ys, np.float32))[pos], 0)
+    acc = np.zeros((pos.shape[0], ys.shape[1]), np.float32)
+    for j in range(pos.shape[1]):
+        acc = acc + back[:, j] * w[:, j, None]
+    return acc
+
+
+def _ulps_off(got, want):
+    """``got`` against float32 ``want`` rounded once to got's dtype, in
+    ulps of that dtype (bit patterns apart)."""
+    bits = {2: np.int16, 4: np.int32}[got.dtype.itemsize]
+    once = jnp.asarray(want).astype(got.dtype)
+    return np.abs(np.asarray(jax.lax.bitcast_convert_type(got, bits), np.int64)
+                  - np.asarray(jax.lax.bitcast_convert_type(once, bits), np.int64))
+
+
+ROWS_M, ROWS_T, ROWS_K = 384, 96, 3  # sorted rows, tokens (1.5 tiles), slots
+
+
+@pytest.mark.parametrize("n_live", [0, 1, 128, 200, 384])
+@pytest.mark.parametrize("dtype,d", [
+    ("float32", 128), ("float32", 1152), ("bfloat16", 256), ("bfloat16", 2560)])
+def test_rows_into_expert_order_by_dma_over_the_live_tiles(dtype, d, n_live):
+    """``sorted_rows``: every row below the live count, rounded up to its
+    row tile, is its source row bit for bit — none, one, a whole tile, a
+    tile and a part, every row; slabs of whole tiles and padded ones."""
+    rng = np.random.default_rng(d + n_live)
+    rows = jnp.asarray(rng.standard_normal((ROWS_T, d)), dtype)
+    src = jnp.asarray(rng.integers(0, ROWS_T, ROWS_M), jnp.int32)
+    xs = moe.sorted_rows(rows, src, jnp.int32(n_live), interpret=True)
+    assert xs.shape == (ROWS_M, d) and xs.dtype == rows.dtype
+    up = -(-n_live // moe.ROW_TILE) * moe.ROW_TILE
+    np.testing.assert_array_equal(
+        np.asarray(xs[:up], np.float32), np.asarray(rows[src[:up]], np.float32))
+
+
+@pytest.mark.parametrize("n_live", [0, 1, 128, 200, 384])
+@pytest.mark.parametrize("dtype,d", [
+    ("float32", 128), ("float32", 1152), ("bfloat16", 256), ("bfloat16", 2560)])
+def test_a_tokens_rows_back_summed_once_with_the_dead_rows_poisoned(
+        dtype, d, n_live):
+    """``combine_rows`` with NaN in every row of ``ys`` at or past the live
+    count: an assignment there adds exactly zero (a select, never a
+    product), no NaN comes out, and the live ones sum in float32 in slot
+    order with one rounding (the tolerance above)."""
+    rng = np.random.default_rng(d + n_live)
+    ys = jnp.asarray(rng.standard_normal((ROWS_M, d)), dtype)
+    ys = ys.at[n_live:].set(jnp.nan)
+    pos = jnp.asarray(rng.permutation(ROWS_M)[:ROWS_T * ROWS_K].reshape(
+        ROWS_T, ROWS_K), jnp.int32)
+    w = jnp.asarray(rng.random((ROWS_T, ROWS_K)), jnp.float32)
+    out = moe.combine_rows(ys, pos, w, jnp.int32(n_live), interpret=True)
+    assert out.shape == (ROWS_T, d) and out.dtype == ys.dtype
+    assert bool(jnp.isfinite(out).all())
+    want = _slot_order_sum(ys, pos, w, n_live)
+    dead = np.all(np.asarray(pos) >= n_live, axis=1)
+    assert dead.any() or n_live == ROWS_M
+    np.testing.assert_array_equal(np.asarray(out, np.float32)[dead], 0.0)
+    if dtype == "float32":
+        np.testing.assert_allclose(np.asarray(out), want, rtol=0, atol=1e-6)
+    else:
+        off = _ulps_off(out, want)
+        assert off.max() <= 1 and (off > 0).mean() < 0.01
+
+
+def test_a_combine_that_rounds_more_than_once_shows():
+    """What the tolerance above separates: the same rows with each product
+    rounded to bfloat16 before the sum are several ulps off in most
+    elements, so a kernel held to one ulp in 1 % multiplies and sums in
+    float32."""
+    rng = np.random.default_rng(5)
+    ys = jnp.asarray(rng.standard_normal((ROWS_M, 256)), jnp.bfloat16)
+    pos = jnp.asarray(rng.permutation(ROWS_M)[:ROWS_T * ROWS_K].reshape(
+        ROWS_T, ROWS_K), jnp.int32)
+    w = jnp.asarray(rng.random((ROWS_T, ROWS_K)), jnp.float32)
+    want = _slot_order_sum(ys, pos, w, ROWS_M)
+    each = sum((ys[pos[:, j]].astype(jnp.float32) * w[:, j, None]).astype(
+        jnp.bfloat16) for j in range(ROWS_K))
+    off = _ulps_off(each, want)
+    assert (off > 0).mean() > 0.2
+    got = moe.combine_rows(ys, pos, w, jnp.int32(ROWS_M), interpret=True)
+    assert (_ulps_off(got, want) > 0).mean() < 0.01
+
+
+def _block_fns(k, **kw):
+    """``expert_ffn`` jitted with the two kernels and with XLA's shuffles
+    (the rule forced each way at trace time)."""
+    def build(fit):
+        def run(h, mlp, valid):
+            keep = moe.row_kernels_fit
+            moe.row_kernels_fit = lambda *a: fit
+            try:
+                return moe.expert_ffn(h, mlp, k, False, valid, interpret=True, **kw)
+            finally:
+                moe.row_kernels_fit = keep
+        return jax.jit(run)
+    return build(True), build(False)
+
+
+@pytest.fixture(scope="module")
+def share_fns():
+    return _block_fns(K_LADDER)
+
+
+@pytest.mark.parametrize("held", [0, 1, 128, 200, 256, 511, 512])
+def test_the_kernels_are_xlas_shuffles_on_a_held_share(share_fns, held):
+    """A tree that holds 2 of 8 experts, 256 tokens x top-2 at 128 lanes:
+    the held count at none, one, a row tile, off a tile, and every
+    assignment — the block with the two DMA kernels is XLA's form to the
+    float32 ulp (a multiply may fuse into its add), the plain reference's
+    within rounding, the counts identical; absent experts add zero."""
+    kernels, xla = share_fns
+    mlp = _share(jax.random.PRNGKey(11), d=128)
+    h = _share_tokens(jax.random.PRNGKey(held), 256, held, d=128)
+    valid = jnp.ones((256,), bool)
+    out, counts = kernels(h, mlp, valid)
+    want, want_counts = xla(h, mlp, valid)
+    assert int(counts[:2].sum()) == held
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    assert bool(jnp.isfinite(out).all()) and _close(out, want) < 1e-5
+    assert _close(out, _share_reference(h, mlp, K_LADDER)) < 1e-4
+    if held == 0:
+        np.testing.assert_array_equal(np.asarray(out), 0.0)
+
+
+def _whole_tree(key, d, w, e, act="silu", latent=0):
+    ks = jax.random.split(key, 6)
+    wide = latent or d
+    mlp = {"router": {"kernel": jax.random.normal(ks[0], (d, e))},
+           "up": {"kernel": jax.random.normal(ks[1], (e, wide, w)) * 0.2},
+           "down": {"kernel": jax.random.normal(ks[2], (e, w, wide)) * 0.2}}
+    if act == "silu":
+        mlp["gate"] = {"kernel": jax.random.normal(ks[3], (e, wide, w)) * 0.2}
+    if latent:
+        mlp["latent_down"] = {"kernel": jax.random.normal(ks[4], (d, latent)) * 0.2}
+        mlp["latent_up"] = {"kernel": jax.random.normal(ks[5], (latent, d)) * 0.2}
+    return mlp
+
+
+@pytest.mark.parametrize("case", [
+    "whole-tree", "one-expert", "invalid-rows", "latent-relu2", "bfloat16"])
+def test_the_kernels_are_xlas_shuffles_on_a_whole_tree(case):
+    """Every expert held (XLA's ``n == n_all`` combine): the router as it
+    falls; every token to ONE expert (one group holds every row); a third
+    of the rows invalid (they add exactly zero and come out zero); the
+    latent pair around a non-gated ``relu2`` expert (128-lane latent rows
+    of a 64-wide model); bfloat16 rows (packed slabs)."""
+    act, latent, d = ("relu2", 128, 64) if case == "latent-relu2" else ("silu", 0, 128)
+    mlp = _whole_tree(jax.random.PRNGKey(3), d, 16, 4, act, latent)
+    h = jax.random.normal(jax.random.PRNGKey(4), (128, d))
+    valid = jnp.ones((128,), bool)
+    k = 2
+    if case == "one-expert":
+        k = 1
+        mlp["router"]["kernel"] = jnp.zeros((d, 4)).at[:, 2].set(1.0)
+        h = jnp.abs(h)
+    if case == "invalid-rows":
+        valid = jnp.arange(128) % 3 != 0
+    if case == "bfloat16":
+        d = 256
+        mlp = jax.tree.map(lambda a: a.astype(jnp.bfloat16),
+                           _whole_tree(jax.random.PRNGKey(3), d, 16, 4))
+        mlp["router"]["kernel"] = mlp["router"]["kernel"].astype(jnp.float32)
+        h = jax.random.normal(jax.random.PRNGKey(4), (128, d)).astype(jnp.bfloat16)
+    kernels, xla = _block_fns(k, act=act)
+    out, counts = kernels(h, mlp, valid)
+    want, want_counts = xla(h, mlp, valid)
+    np.testing.assert_array_equal(np.asarray(counts), np.asarray(want_counts))
+    assert int(counts.sum()) == int(valid.sum()) * k
+    assert bool(jnp.isfinite(out.astype(jnp.float32)).all())
+    if case == "one-expert":
+        assert counts.tolist() == [0, 0, 128, 0]
+    if case == "invalid-rows":
+        np.testing.assert_array_equal(np.asarray(out)[~np.asarray(valid)], 0.0)
+    if case == "bfloat16":  # one rounding each: a bfloat16 ulp where the
+        # float32 sums straddle a boundary (XLA sums token-major here)
+        off = _ulps_off(out, want.astype(jnp.float32))
+        assert off.max() <= 1 and (off > 0).mean() < 0.02
+    else:  # float32 ulps of the largest output (the latent case's are ~40)
+        assert _close(out, want) < 2e-6 * max(1.0, float(jnp.max(jnp.abs(want))))
+
+
+def test_a_call_the_kernels_take_traces_no_conditional_for_its_shuffles(monkeypatch):
+    """A share's prompt-sized call under the rule: the gather and the
+    combine are kernels outside any branch (their trip count is the held
+    count itself), the activation alone keeps the ladder's conditional."""
+    from helpers import expert_row_kernels_at_toy_size
+
+    expert_row_kernels_at_toy_size(monkeypatch)
+    monkeypatch.setattr(moe, "LADDER_MIN_SKIP", 128)
+    mlp = _share(jax.random.PRNGKey(0), d=128)
+    assert moe.row_kernels_fit(T_LADDER * K_LADDER, 128, jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda h: moe.expert_ffn(
+        h, mlp, K_LADDER, False, jnp.ones((T_LADDER,), bool),
+        interpret=True))(jnp.zeros((T_LADDER, 128)))
+    assert _count(jaxpr.jaxpr, "cond") == 1
+    # three grouped matmuls, the row gather, the slabs and the combine
+    assert _count(jaxpr.jaxpr, "pallas_call") == 6

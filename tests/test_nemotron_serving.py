@@ -293,6 +293,53 @@ def test_the_loop_counts_the_expert_rows_its_windows_ran_and_skipped(monkeypatch
     assert rows["decode"] == [chunks * 4 * layers * cdl.n_slots * k, 0]
 
 
+def test_the_loop_counts_the_held_rows_of_the_dispatches_that_took_the_kernels(monkeypatch, kw):  # noqa: F811
+    """The same two prompts with the latent 128 lanes wide and the shape
+    rule (``ops/moe.row_kernels_fit``) lowered to a window's 40 rows of
+    512 B: the windows' expert blocks run the two DMA kernels (interpret
+    mode), ``moe_rows_fused_total{kind="prefill"}`` and ``/status``'s
+    ``fused`` grow by exactly the HELD rows of those dispatches' counts,
+    a decode chunk's 20-row steps keep XLA's form and add nothing, and
+    ``moe_rows_total`` still counts every row of every call."""
+    from helpers import expert_row_kernels_at_toy_size
+    from mlmicroservicetemplate_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "ROW_TILE", 8)
+    monkeypatch.setattr(moe, "LADDER_MIN_SKIP", 16)
+    expert_row_kernels_at_toy_size(monkeypatch, rows=40, row_bytes=512)
+    bundle = _bundle(monkeypatch, {**kw, "moe_latent": 128})
+    k, first, held = (bundle.cfg.experts_per_token, bundle.cfg.expert_first,
+                      bundle.cfg.held)
+    assert moe.row_kernels_fit(8 * k, 128, jnp.float32) and moe.row_kernels_fit(24 * k, 128, jnp.float32)
+    assert not moe.row_kernels_fit(4 * k, 128, jnp.float32)  # a step of the 4 slots
+    cfgc = _loop_cfg()
+    eng = InferenceEngine(bundle, cfgc, ReplicaSet(make_mesh(1)))
+
+    def fused():
+        return {kind: metrics.MOE_ROWS_FUSED.labels("llama", kind)._value.get()
+                for kind in ("decode", "prefill")}
+
+    before = fused()
+    cdl = ContinuousDecodeLoop(eng, cfgc)
+    arrived, note = {"decode": 0, "prefill": 0}, cdl._note_moe_rows
+
+    def keep(kind, counts, tokens, steps=1):
+        arrived[kind] += int(np.asarray(counts)[:, first:first + held].sum())
+        return note(kind, counts, tokens, steps)
+
+    cdl._note_moe_rows = keep
+    try:
+        outs = _run(cdl, _feats((30, 45), seed=3))
+        rows, seen = dict(cdl.moe_rows), dict(cdl.moe_rows_fused)
+    finally:
+        cdl.stop()
+    assert all(len(t) == 12 for t in outs)
+    assert arrived["prefill"] > 0 and arrived["decode"] > 0
+    assert seen == {"prefill": arrived["prefill"], "decode": 0}
+    assert {kind: v - before[kind] for kind, v in fused().items()} == seen
+    assert 0 < seen["prefill"] <= rows["prefill"][0] and rows["decode"][1] == 0
+
+
 def test_a_model_without_recurrent_layers_has_none_of_it():
     bundle = tiny_llama_bundle()
     cfgc = _cfg(paged_kv=True, kv_block_size=8, prefill_chunk=8, prefill_max_prompt=48)
