@@ -183,3 +183,35 @@ def _locktrace_guard():
         "locktrace violations recorded during this test "
         f"(docs/static-analysis.md): {new}"
     )
+
+
+# ---------------------------------------------------------------------------
+# JAX's persistent compilation cache is process-wide: a test that turns it on
+# (``runtime/device.enable_compilation_cache`` with a directory under its
+# ``tmp_path``: tests/test_compile_cache.py, tests/test_config.py) left the
+# directory set — or, where it put the value back itself, the cache OBJECT
+# latched on it — for whatever that xdist worker ran next, which then wrote
+# its executables there and read them back (a worker lost in the read, PR
+# 54's and PR 57's tier-1 runs).  After such a test: the values as they
+# were, and no cache object.
+
+_CACHE_KNOBS = (
+    "jax_compilation_cache_dir",
+    "jax_persistent_cache_min_compile_time_secs",
+    "jax_persistent_cache_min_entry_size_bytes",
+    "jax_compilation_cache_include_metadata_in_key",
+)
+
+
+@pytest.fixture(autouse=True)
+def _compile_cache_guard():
+    from jax._src import compilation_cache
+
+    knobs = {k: getattr(jax.config, k) for k in _CACHE_KNOBS}
+    cache = compilation_cache._cache
+    yield
+    if (compilation_cache._cache is not cache
+            or knobs != {k: getattr(jax.config, k) for k in _CACHE_KNOBS}):
+        for k, v in knobs.items():
+            jax.config.update(k, v)
+        compilation_cache.reset_cache()

@@ -4,10 +4,15 @@
 the text-format parsing and the bucket-percentile arithmetic are pinned
 here (pure logic, no service)."""
 
+import functools
+import hashlib
+import json
 import math
+import os
 
 import pytest
 
+from cellbench import spec
 from cellbench.reduce import hist_delta, hist_pctile, parse_prom
 
 SCRAPE = """\
@@ -109,27 +114,297 @@ def test_latency_buckets_env_overrides_defaults():
     assert m.parse_buckets("2,1") is None
 
 
+
 # ---------------------------------------------------------------------------
-# table_blocks_dead_pct.* (PR 32): the entries resolve in their cells, and
-# the reader finds the program's counters under the names it exports
+# BENCHMARK.json, held by what a cell READS and by (cell, entry) PAIR — never
+# by what an entry is called, where it stands or what stands beside it (PR
+# 58).  A ``benchmark`` PR may rename, fold and append entries and may not
+# edit ``tests/``: no case below names an entry, and how many cases there are
+# is a property of this directory (``bench_cell_readings.json``), not of the
+# benchmark.
+
+TABLE = os.path.join(os.path.dirname(os.path.abspath(__file__)), "bench_cell_readings.json")
+COLUMNS = ["reader", "args", "unit", "better", "source", "layer", "moves"]
+SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+PROM_READERS = ("prom_hist", "prom_counter_ratio", "prom_counter_rate", "prom_labelled")
+#: Entries the parent's files fail ``test_every_pair_of_the_benchmark_resolves``
+#: on, by fault as the test words it, with the reason (PERF.md section 7 lists
+#: them for the ``benchmark`` PR that repairs them).  None on PR 58's tree.
+KNOWN: dict[str, str] = {}
 
 
-@pytest.mark.parametrize("metric,moves,cells", [
-    ("table_blocks_dead_pct.decode", "tbt_p95_ms",
-     ["mistral-7b-d8.decode-closed", "olmoe-1b-7b-d8.decode-closed"]),
-    ("table_blocks_dead_pct.chat", "tbt_p99_ms",
-     ["mistral-7b-d8.chat-open", "trinity-mini-d5.longdoc-closed"]),
-])
-def test_table_blocks_dead_pct_resolves_in_its_cells(metric, moves, cells):
-    from cellbench import spec
+_bench = functools.lru_cache(maxsize=None)(spec.load_benchmark)
 
-    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == metric]
-    assert entry["workloads"] == cells and entry["moves"] == moves
-    assert entry["layer"] == "kernels" and entry["source"] == "program_counter"
-    for cell in cells:
-        resolved = spec.resolve(cell)
-        assert metric in [m.name for m in resolved.per_layer]
-        assert moves in [m.name for m in resolved.end_to_end]
+
+def _cells() -> list[str]:
+    return [w["name"] for w in _bench()["workloads"]]
+
+
+@functools.lru_cache(maxsize=None)
+def _resolved(cell: str) -> spec.Cell:
+    return spec.resolve(cell)
+
+
+def _key(row: list) -> tuple:
+    """A row of the table as it is compared: the arguments in one spelling."""
+    reader, args, *rest = row
+    return (reader, json.dumps(args, sort_keys=True), *rest)
+
+
+def _reading(entry: dict, reader: str, args: dict) -> tuple:
+    """What an entry reads, ``COLUMNS`` of it and its data file: no name."""
+    return _key([reader, args, *(entry[k] for k in COLUMNS[2:])])
+
+
+@functools.lru_cache(maxsize=None)
+def _read_in(cell: str) -> dict:
+    """``{reading: [metric, ...]}`` of what ``cell`` resolves on these files."""
+    entry = {m["name"]: m for m in _bench()["per_layer"]}
+    out: dict = {}
+    for m in _resolved(cell).per_layer:
+        out.setdefault(_reading(entry[m.name], m.reader, m.args), []).append(m)
+    return out
+
+
+def _reads(cell: str, reader: str, **args) -> spec.Metric:
+    """The ONE per-layer metric of ``cell`` that ``reader`` reads with ``args``
+    among its arguments: a reader's test finds its entry by definition."""
+    hit = [m for m in _resolved(cell).per_layer if m.reader == reader
+           and all(m.args.get(k) == v for k, v in args.items())]
+    assert len(hit) == 1, (cell, reader, args, len(hit))
+    return hit[0]
+
+
+def _table(path: str = TABLE) -> dict:
+    return spec.load_json(path) if os.path.exists(path) else {
+        "columns": COLUMNS, "cells": {}}
+
+
+def freeze_missing_cells(path: str = TABLE) -> list[str]:
+    """ADD to the table the cells ``BENCHMARK.json`` has and the table lacks
+    and return their names.  A cell the table has is never rewritten:
+    re-freezing after a loss would hide the loss.  The PR that adds a cell
+    (and may edit ``tests/``) runs, from the root of the repo, ``python -c
+    "import sys; sys.path.insert(0, 'tests'); import test_bench_helpers as t;
+    print(t.freeze_missing_cells())"``."""
+    table = _table(path)
+    added = [cell for cell in _cells() if cell not in table["cells"]]
+    for cell in added:
+        twice = {k: [m.name for m in ms] for k, ms in _read_in(cell).items() if len(ms) > 1}
+        if twice:  # one row a pair: two entries of a cell with one reading are twins
+            raise ValueError(f"{cell}: entries that read the same {twice}")
+        table["cells"][cell] = [[k[0], json.loads(k[1]), *k[2:]] for k in sorted(_read_in(cell))]
+    if added:
+        cells = ",\n".join(
+            f"  {json.dumps(cell)}: [\n"
+            + ",\n".join("   " + json.dumps(row, sort_keys=True) for row in rows) + "\n  ]"
+            for cell, rows in table["cells"].items())
+        with open(path, "w", encoding="utf-8") as f:
+            f.write('{\n "columns": %s,\n "cells": {\n%s\n }\n}\n'
+                    % (json.dumps(table["columns"]), cells))
+    return added
+
+
+ROWS = [(cell, row) for cell, rows in _table()["cells"].items() for row in rows]
+
+
+def _row_id(param: tuple) -> str:
+    cell, row = param
+    return f"{cell}-{row[0]}-{hashlib.sha1(_key(row)[1].encode()).hexdigest()[:6]}"
+
+
+@pytest.mark.parametrize("cell,row", ROWS, ids=[_row_id(p) for p in ROWS])
+def test_reading_still_read(cell, row):
+    """The cell still resolves a metric with exactly this reading, under
+    whatever name, through a callable reader.  A cell the benchmark no longer
+    has holds nothing: ``test_configuration_and_cell`` reports it, once."""
+    if cell not in _cells():
+        return
+    held = _read_in(cell).get(_key(row), [])
+    assert held and all(callable(m.read) for m in held), (
+        f"{cell} no longer reads {dict(zip(COLUMNS, row))}")
+
+
+def test_freezing_adds_the_cells_a_table_lacks_and_rewrites_none(tmp_path):
+    path = str(tmp_path / "table.json")
+    first, *others = _cells()
+    assert freeze_missing_cells(path) == [first, *others]
+    whole = _table(path)["cells"]
+    assert all(len({_key(r) for r in whole[c]}) == len(_resolved(c).per_layer) for c in whole)
+    with open(path, "w", encoding="utf-8") as f:  # a cell went, another lost readings
+        json.dump({"columns": COLUMNS, "cells": {first: whole[first][:1]}}, f)
+    assert freeze_missing_cells(path) == others and freeze_missing_cells(path) == []
+    assert _table(path)["cells"] == {**whole, first: whole[first][:1]}
+
+
+@functools.lru_cache(maxsize=None)
+def _declared() -> dict:
+    """``{family: metric object}`` of what ``utils/metrics.py`` declares."""
+    from mlmicroservicetemplate_tpu.utils import metrics
+
+    return {getattr(obj, "_name", None): obj for obj in vars(metrics).values()}
+
+
+def _prom_faults(reader: str, args: dict) -> list[str]:
+    """What a ``prom_*`` data file asks of ``/metrics`` that
+    ``utils/metrics.py`` does not declare: a family, or a label name."""
+    declared, out = _declared(), []
+    for family in [args[k] for k in ("family", "part") if k in args] + args.get("rest", []):
+        base = family[:-len("_total")] if family.endswith("_total") else family
+        if base not in declared:
+            out.append(f"{reader} reads {family}, which the program does not declare")
+            continue
+        for pick in ("labels", "over"):
+            extra = set(args.get(pick) or {}) - set(declared[base]._labelnames)
+            if extra:
+                out.append(f"{reader} picks {family} by {sorted(extra)}, no label of it")
+    return out
+
+
+def test_every_pair_of_the_benchmark_resolves():
+    """Every (cell, entry) pair ``BENCHMARK.json`` has (247 on PR 58's files),
+    in ONE case that lists every offender: the entry resolves in the cell to
+    one metric with a callable reader and the entry's unit; it moves an
+    end-to-end metric that cell reports; no cell outside its list resolves
+    it; the readings of the ``compile`` layer (a boot's) are the only ones
+    that move ``setup_s`` and every cell reads them; a ``prom_*`` data file
+    names families and labels the program declares."""
+    bench, cells = _bench(), _cells()
+    names = [m["name"] for m in bench["per_layer"]]
+    wrong = [f"{n}: {names.count(n)} entries of one name" for n in set(names)
+             if names.count(n) > 1]
+    for entry in bench["per_layer"]:
+        name, listed = entry["name"], entry.get("workloads", cells)
+        faults = [f"{field} {entry[field]!r}" for field, known in
+                  (("source", SOURCES), ("better", ("lower", "higher")))
+                  if entry[field] not in known]
+        if len(set(listed)) != len(listed) or not set(listed) <= set(cells):
+            faults.append(f"lists {listed}")
+        if (entry["moves"] == "setup_s") != (entry["layer"] == "compile"):
+            faults.append(f"layer {entry['layer']!r} moves {entry['moves']}")
+        if entry["moves"] == "setup_s" and set(listed) != set(cells):
+            faults.append("a boot reading that some cell does not read")
+        for cell in cells:
+            hits = [m for m in _resolved(cell).per_layer if m.name == name]
+            if cell not in listed:
+                faults += [f"read in {cell}, which it does not list"] * bool(hits)
+            elif len(hits) != 1 or not callable(hits[0].read) or hits[0].unit != entry["unit"]:
+                faults.append(f"resolves in {cell} to {hits}")
+            elif entry["moves"] not in [m.name for m in _resolved(cell).end_to_end]:
+                faults.append(f"moves {entry['moves']}, which {cell} does not report")
+        data = spec.load_json(os.path.join(spec.HERE, "layer_metrics", name + ".json"))
+        if data["reader"] in PROM_READERS:
+            faults += _prom_faults(data["reader"], data.get("args", {}))
+        wrong += [f"{name}: {f}" for f in faults if f"{name}: {f}" not in KNOWN]
+    assert not wrong, "\n".join(wrong)
+
+
+def test_one_entry_a_definition():
+    """No two entries agree in every field but ``name`` and ``workloads`` and
+    in their data file's reader and arguments — but for the twins the
+    benchmark's own ``twins_waiting.json`` lists, a list that may only shrink
+    (39 wait on PR 58's files; a fold empties it and changes no case here)."""
+    bench = _bench()
+    waiting = spec.load_json(
+        os.path.join(spec.HERE, "tests", "twins_waiting.json"))["groups"]
+    groups: dict = {}
+    for m in bench["per_layer"]:
+        d = spec.load_json(os.path.join(spec.HERE, "layer_metrics", m["name"] + ".json"))
+        groups.setdefault(_reading(m, d["reader"], d.get("args", {})), []).append(m["name"])
+    twins = [names for names in groups.values() if len(names) > 1]
+    for names in twins:
+        assert any(set(names) <= set(g) for g in waiting), names
+    assert len(bench["per_layer"]) <= 128  # the file's limit
+    assert sum(len(names) - 1 for names in twins) <= 39
+
+
+#: The configurations the benchmark holds and their cells:
+#: ``(name, source, reduced, holds, {cell: (traffic, chips, end-to-end)})``.
+#: ``holds`` is what the configuration's file says at its cut keys and of its
+#: layer pattern (a string or a list is held by its head).
+_HF = "https://huggingface.co/"
+_CLOSED = ["ttft_p95_ms", "tbt_p95_ms", "tokens_per_s", "setup_s"]
+_LONGDOC = ("longdoc-closed", 1, ["tbt_p99_ms", "setup_s"])
+CONFIGS = [
+    ("mistral-7b-d8", _HF + "mistralai/Mistral-7B-v0.1/blob/main/config.json",
+     ["num_hidden_layers"], {"num_hidden_layers": 8},
+     {"mistral-7b-d8.decode-closed": ("decode-closed", 1, _CLOSED),
+      "mistral-7b-d8.chat-open": ("chat-open", 1, ["ttft_p95_ms", "tbt_p99_ms", "setup_s"])}),
+    ("olmoe-1b-7b-d8", _HF + "allenai/OLMoE-1B-7B-0125-Instruct/blob/main/config.json",
+     ["num_hidden_layers"], {"num_hidden_layers": 8, "num_experts": 64},
+     {"olmoe-1b-7b-d8.decode-closed": ("decode-closed", 1, _CLOSED)}),
+    ("trinity-mini-d5", _HF + "arcee-ai/Trinity-Mini/blob/main/config.json",
+     ["num_hidden_layers", "num_dense_layers"],
+     {"num_hidden_layers": 5, "num_dense_layers": 1, "num_experts": 128,
+      "layer_types": ["sliding_attention"] * 3 + ["full_attention", "sliding_attention"]},
+     {"trinity-mini-d5.longdoc-closed": _LONGDOC}),
+    ("deepseek-v2-ep4-d5", _HF + "deepseek-ai/DeepSeek-V2/blob/main/config.json",
+     ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+     {"num_hidden_layers": 5, "n_routed_experts": 40, "vocab_size": 25600,
+      "router_experts": 160},
+     {"deepseek-v2-ep4-d5.longdoc-closed": _LONGDOC}),
+    ("nemotron3-super-ep4-d11",
+     _HF + "nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16/blob/main/config.json",
+     ["num_hidden_layers", "n_routed_experts", "vocab_size"],
+     {"num_hidden_layers": 11, "n_routed_experts": 128, "vocab_size": 32768,
+      "hybrid_override_pattern": "MEMEMEM*EME"},
+     {"nemotron3-super-ep4-d11.longdoc-closed": _LONGDOC}),
+    ("gigachat35-ep16-d5", _HF + "ai-sage/GigaChat3.5-432B-A28B/blob/main/config.json",
+     ["num_hidden_layers", "n_routed_experts", "first_k_dense_replace", "vocab_size"],
+     {"num_hidden_layers": 5, "n_routed_experts": 16, "first_k_dense_replace": 1,
+      "vocab_size": 16032, "layer_types": ["linear"] * 4 + ["full"]},
+     {"gigachat35-ep16-d5.longdoc-closed": _LONGDOC}),
+    ("jamba2-3b-d28", _HF + "ai21labs/AI21-Jamba2-3B/blob/main/config.json",
+     [], {"num_hidden_layers": 28, "tie_word_embeddings": True, "num_key_value_heads": 1},
+     {"jamba2-3b-d28.longdoc-closed": _LONGDOC}),
+    ("granite-4.0-h-small-ep2-d10",
+     _HF + "ibm-granite/granite-4.0-h-small/blob/main/config.json",
+     ["num_hidden_layers", "num_local_experts", "vocab_size"],
+     {"num_hidden_layers": 10, "num_local_experts": 36, "vocab_size": 50176,
+      "router_experts": 72,
+      "layer_types": ["mamba"] * 5 + ["attention"] + ["mamba"] * 4,
+      "llama_layer_types": ["mamba2"] * 5 + ["attention"] + ["mamba2"] * 4},
+     {"granite-4.0-h-small-ep2-d10.longdoc-closed": _LONGDOC}),
+]
+
+
+@pytest.mark.parametrize("name,source,reduced,holds,cells", CONFIGS,
+                         ids=[c[0] for c in CONFIGS])
+def test_configuration_and_cell(name, source, reduced, holds, cells):
+    """The configuration is in the benchmark with its published source, its
+    file and the keys it was cut at; the file says what each was cut to; each
+    of its cells is there with its traffic, its chips and the end-to-end
+    metrics it reports.  A cell that went fails here and nowhere else."""
+    bench = _bench()
+    (cfg,) = [c for c in bench["configs"] if c["name"] == name]
+    assert (cfg["file"], cfg["source"], cfg["reduced"]) == (
+        f"cellbench/configs/{name}.json", source, reduced)
+    assert len(cfg["why"]) <= 200
+    file = spec.load_json(os.path.join(spec.REPO, cfg["file"]))
+    assert list(file["reduced"]) == reduced and "deployment" in file["assumed"]
+    for key, cut in file["reduced"].items():
+        assert cut["here"] == file[key] == holds[key] != cut["source"]
+    for key, want in holds.items():
+        have = file[key]
+        assert (have[:len(want)] if isinstance(want, (str, list)) else have) == want, key
+    have = {w["name"]: w for w in bench["workloads"]}
+    for cell, (traffic, chips, end_to_end) in cells.items():
+        assert cell in have, f"the benchmark no longer has the cell {cell}"
+        w = have[cell]
+        assert (w["config"], w["traffic"], w["chips"]) == (name, traffic, chips)
+        assert len(w["why"]) <= 200
+        assert [m.name for m in _resolved(cell).end_to_end] == end_to_end
+        assert _resolved(cell).config == file
+
+
+# ---------------------------------------------------------------------------
+# The readers, on the program's own families and scopes: each finds its entry
+# by what it reads (reader and arguments), and reads no value — never 0 —
+# from a program without the family or the scope (the parent)
+
+DSV2_CELL = "deepseek-v2-ep4-d5.longdoc-closed"
+NEMO_CELL = "nemotron3-super-ep4-d11.longdoc-closed"
+GRANITE_CELL = "granite-4.0-h-small-ep2-d10.longdoc-closed"
 
 
 def test_table_blocks_dead_pct_reads_the_programs_counters():
@@ -158,95 +433,12 @@ def test_table_blocks_dead_pct_reads_the_programs_counters():
     assert prom_counter_ratio.read(ctx({}, {}), *args) is None
 
 
-# ---------------------------------------------------------------------------
-# deepseek-v2-ep4-d5 (PR 33): the configuration, the cell and its sixteen
-# per-layer entries, held by NAME (cellbench/tests/*::test_entries_resolve_by_name
-# want older lists to be the tail of theirs)
-
-def _named(entries: list, name: str) -> dict:
-    """The ONE entry of a ``BENCHMARK.json`` list called ``name``: a
-    configuration, a cell or a metric is held by its NAME, never by its place
-    in the list — every later PR appends (PR 56)."""
-    (entry,) = [e for e in entries if e["name"] == name]
-    return entry
-
-
-def _lists(entry: dict, standing: list) -> bool:
-    """Whether ``entry``'s ``workloads`` begin with its ``standing`` members
-    in their order: a later PR appends its cell behind them and changes
-    nothing else (one entry a definition, PR 55)."""
-    return entry["workloads"][:len(standing)] == standing
-
-
-def _without_workloads(entry: dict) -> dict:
-    return {k: v for k, v in entry.items() if k != "workloads"}
-
-
-DSV2_CELL = "deepseek-v2-ep4-d5.longdoc-closed"
-DSV2_ENTRIES = [
-    ("decode_step_ms.dsv2", "ms", "device_trace", "model step", "trace_module_ms"),
-    ("decode_step_roofline.dsv2", "%", "device_trace", "model step", "mla_roofline"),
-    ("decode_attn_latent_ms.dsv2", "ms", "device_trace", "model step", "trace_scope_ms"),
-    ("mla_absorb_ms.dsv2", "ms", "device_trace", "model step", "trace_subscope_ms"),
-    ("mla_proj_ms.dsv2", "ms", "device_trace", "model step", "trace_scope_ms"),
-    ("latent_decode_attention_roofline.dsv2", "%", "device_trace", "kernels", "mla_roofline"),
-    ("decode_moe_ms.dsv2", "ms", "device_trace", "model step", "trace_scope_ms"),
-    ("moe_experts_roofline.dsv2", "%", "device_trace", "kernels", "mla_roofline"),
-    ("moe_overhead_ms.dsv2", "ms", "device_trace", "model step", "trace_subscope_ms"),
-    ("moe_shared_ms.dsv2", "ms", "device_trace", "model step", "trace_subscope_ms"),
-    ("moe_held_share_pct.dsv2", "%", "program_counter", "model step", "prom_counter_ratio"),
-    ("moe_imbalance.dsv2", "ratio", "program_counter", "engine", "prom_hist"),
-    ("table_blocks_dead_pct.dsv2", "%", "program_counter", "kernels", "prom_counter_ratio"),
-    ("streams_per_chunk.dsv2", "streams", "program_counter", "engine", "prom_hist"),
-    ("prefill_stall_ms.dsv2", "ms/s", "program_counter", "engine", "prom_counter_rate"),
-    ("device_idle_pct.dsv2", "%", "device_trace", "device", "trace_idle_pct"),
-]
-
-
-def test_dsv2_configuration_and_cell_are_in_the_benchmark():
-    from cellbench import spec
-
-    bench = spec.load_benchmark()
-    (cfg,) = [c for c in bench["configs"] if c["name"] == "deepseek-v2-ep4-d5"]
-    assert cfg["file"] == "cellbench/configs/deepseek-v2-ep4-d5.json"
-    assert cfg["source"] == (
-        "https://huggingface.co/deepseek-ai/DeepSeek-V2/blob/main/config.json")
-    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    (cell,) = [w for w in bench["workloads"] if w["name"] == DSV2_CELL]
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "deepseek-v2-ep4-d5", "longdoc-closed", 1)
-    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
-    on = [m["name"] for m in bench["end_to_end"]
-          if "workloads" not in m or DSV2_CELL in m["workloads"]]
-    assert on == ["tbt_p99_ms", "setup_s"]
-    # PR 32's lists keep the cells they had (this cell has an entry of its own)
-    for m in bench["per_layer"]:
-        if m["name"].startswith("table_blocks_dead_pct.") and not m["name"].endswith("dsv2"):
-            assert DSV2_CELL not in m["workloads"]
-
-
-@pytest.mark.parametrize("name,unit,source,layer,reader", DSV2_ENTRIES)
-def test_dsv2_per_layer_entry_resolves(name, unit, source, layer, reader):
-    from cellbench import spec
-
-    entry = _named(spec.load_benchmark()["per_layer"], name)
-    assert _without_workloads(entry) == {
-        "name": name, "unit": unit,
-        "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms"}
-    assert _lists(entry, [DSV2_CELL])
-    assert entry["better"] in ("lower", "higher")
-    (resolved,) = [m for m in spec.resolve(DSV2_CELL).per_layer if m.name == name]
-    assert resolved.reader == reader and callable(resolved.read)
-
-
 def test_dsv2_held_share_reads_the_programs_counters():
-    """``moe_held_share_pct.dsv2`` through the reader its entry names, off
-    the families as ``/metrics`` exports them; a program without them (the
-    parent) reads no value."""
+    """The held share of a cell's expert assignments through the reader its
+    entry names, off the families as ``/metrics`` exports them; a program
+    without them (the parent) reads no value."""
     import types
 
-    from cellbench import spec
     from cellbench.readers import prom_counter_ratio
     from mlmicroservicetemplate_tpu.utils import metrics
     from prometheus_client import generate_latest
@@ -265,33 +457,11 @@ def test_dsv2_held_share_reads_the_programs_counters():
                 None if fam not in after
                 else hist_delta(after[fam], before.get(fam))))
 
-    (m,) = [m for m in spec.resolve(DSV2_CELL).per_layer
-            if m.name == "moe_held_share_pct.dsv2"]
+    m = _reads(DSV2_CELL, "prom_counter_ratio", part="moe_assignments_held")
     assert m.args == {"part": "moe_assignments_held",
                       "rest": ["moe_assignments_absent"]}
     assert prom_counter_ratio.read(ctx(after, base), **m.args) == 25.0
     assert prom_counter_ratio.read(ctx({}, {}), **m.args) is None
-
-
-# ---------------------------------------------------------------------------
-# prefill_window_ms.* (PR 34): two entries, data files only, read by the
-# reader the benchmark has, off the prompt-window executable's own name
-
-
-@pytest.mark.parametrize("name,cell", [
-    ("prefill_window_ms.trinity", "trinity-mini-d5.longdoc-closed"),
-    ("prefill_window_ms.dsv2", DSV2_CELL),
-])
-def test_prefill_window_ms_resolves_in_its_cell(name, cell):
-    from cellbench import spec
-
-    (entry,) = [m for m in spec.load_benchmark()["per_layer"] if m["name"] == name]
-    assert entry == {
-        "name": name, "unit": "ms", "better": "lower", "source": "device_trace",
-        "layer": "model step", "moves": "tbt_p99_ms", "workloads": [cell]}
-    (resolved,) = [m for m in spec.resolve(cell).per_layer if m.name == name]
-    assert resolved.reader == "trace_module_ms" and callable(resolved.read)
-    assert resolved.args == {"module": "jit_paged_prefill_chunk_fn", "per": "run"}
 
 
 def test_prefill_window_ms_is_a_window_executables_mean_time():
@@ -316,70 +486,6 @@ def test_prefill_window_ms_is_a_window_executables_mean_time():
     assert trace_module_ms.read(
         ctx({"jit_paged_prefill_chunk_fn": (0.30, 20)}), **args) == pytest.approx(15.0)
     assert trace_module_ms.read(ctx({}), **args) is None
-
-
-# ---------------------------------------------------------------------------
-# boot_* (PR 35): the first per-layer entries that move setup_s — seven
-# entries, seven data files, one reader (prom_labelled), nothing edited
-
-
-BOOT_CELLS = [
-    "mistral-7b-d8.decode-closed", "mistral-7b-d8.chat-open",
-    "olmoe-1b-7b-d8.decode-closed", "trinity-mini-d5.longdoc-closed", DSV2_CELL,
-    "nemotron3-super-ep4-d11.longdoc-closed",  # PR 40 appended its cell
-    "gigachat35-ep16-d5.longdoc-closed",  # PR 47 its
-    "jamba2-3b-d28.longdoc-closed"]  # and PR 51 its
-BOOT_ENTRIES = [
-    ("boot_imports_s", "s", "boot_phase_seconds", {"phase": "imports"}),
-    ("boot_weights_s", "s", "boot_phase_seconds", {"phase": "weights"}),
-    ("boot_warm_s", "s", "boot_phase_seconds", {"phase": "warm"}),
-    ("boot_xla_compiled", "executables", "xla_executables_total",
-     {"outcome": "compiled", "when": "boot"}),
-    ("boot_xla_compile_s", "s", "xla_executable_seconds_total",
-     {"outcome": "compiled", "stage": "backend", "when": "boot"}),
-    ("boot_xla_load_s", "s", "xla_executable_seconds_total",
-     {"outcome": "loaded", "stage": "backend", "when": "boot"}),
-    ("boot_unnamed_pct", "%", "boot_phase_seconds", {"phase": "unnamed"}),
-]
-
-
-@pytest.mark.parametrize("name,unit,family,labels", BOOT_ENTRIES)
-def test_boot_entry_resolves_in_every_cell(name, unit, family, labels):
-    from cellbench import spec
-    from mlmicroservicetemplate_tpu.utils import metrics
-
-    bench = spec.load_benchmark()
-    entry = _named(bench["per_layer"], name)
-    assert _without_workloads(entry) == {
-        "name": name, "unit": unit, "better": "lower", "source": "program_counter",
-        "layer": "compile", "moves": "setup_s"}
-    # every cell reports setup_s, so every cell lists the boot entries: the
-    # standing eight at the head, each later PR's cell behind them
-    assert _lists(entry, BOOT_CELLS)
-    assert entry["workloads"] == [w["name"] for w in bench["workloads"]]
-    for cell in entry["workloads"]:
-        (resolved,) = [m for m in spec.resolve(cell).per_layer if m.name == name]
-        assert resolved.reader == "prom_labelled" and callable(resolved.read)
-        assert resolved.args["family"] == family
-        assert resolved.args["labels"] == labels
-    # the family is one the program declares, with the labels the file picks by
-    declared = {getattr(getattr(metrics, a), "_name", None): getattr(metrics, a)
-                for a in dir(metrics)}
-    fam = declared[family[:-len("_total")] if family.endswith("_total") else family]
-    assert set(labels) <= set(fam._labelnames)
-
-
-def test_boot_entries_stand_together_and_are_the_only_ones_that_move_setup_s():
-    from cellbench import spec
-
-    per_layer = spec.load_benchmark()["per_layer"]
-    names = [m["name"] for m in per_layer]
-    first = names.index(BOOT_ENTRIES[0][0])  # by name: later PRs append after them
-    assert names[first:first + 7] == [e[0] for e in BOOT_ENTRIES]
-    assert [m["name"] for m in per_layer if m["moves"] == "setup_s"] == [
-        e[0] for e in BOOT_ENTRIES]
-    assert [m["name"] for m in per_layer if m["layer"] == "compile"] == [
-        e[0] for e in BOOT_ENTRIES]
 
 
 def test_prom_labelled_keeps_children_apart_and_reads_nothing_from_a_parent():
@@ -408,38 +514,6 @@ xla_executables_created{outcome="loaded",when="boot"} 1.7e+09
     phases = prom_labelled.children(text, "boot_phase_seconds")
     assert prom_labelled.pick(phases, {"phase": "imports"}) == 9.5
     assert parse_prom(text)["boot_phase_seconds"]["value"] == 60.0  # summed there
-
-
-# ---------------------------------------------------------------------------
-# prefill_windows_batched_pct.* (PR 36): how often a boundary's prompt
-# windows share their dispatch — two entries, two data files, the reader
-# the benchmark has (prom_counter_ratio), nothing edited
-
-
-@pytest.mark.parametrize("name,cell", [
-    ("prefill_windows_batched_pct.trinity", "trinity-mini-d5.longdoc-closed"),
-    ("prefill_windows_batched_pct.dsv2", DSV2_CELL),
-])
-def test_prefill_windows_batched_pct_resolves_in_its_cell(name, cell):
-    from cellbench import spec
-
-    per_layer = spec.load_benchmark()["per_layer"]
-    (entry,) = [m for m in per_layer if m["name"] == name]
-    assert entry == {
-        "name": name, "unit": "%", "better": "higher", "source": "program_counter",
-        "layer": "engine", "moves": "tbt_p99_ms", "workloads": [cell]}
-    # appended by PR 36 behind the boot entries, the two side by side (by
-    # NAME: every later PR's entries follow them)
-    names = [m["name"] for m in per_layer]
-    assert names.index(name) > names.index("boot_unnamed_pct")
-    assert abs(names.index("prefill_windows_batched_pct.trinity")
-               - names.index("prefill_windows_batched_pct.dsv2")) == 1
-    resolved = spec.resolve(cell)
-    (metric,) = [m for m in resolved.per_layer if m.name == name]
-    assert metric.reader == "prom_counter_ratio" and callable(metric.read)
-    assert metric.args == {
-        "part": "prefill_windows_batched", "rest": ["prefill_windows_alone"]}
-    assert "tbt_p99_ms" in [m.name for m in resolved.end_to_end]
 
 
 def test_prefill_windows_batched_pct_reads_the_programs_counters():
@@ -473,83 +547,6 @@ def test_prefill_windows_batched_pct_reads_the_programs_counters():
     assert prom_counter_ratio.read(ctx({}, {}), *args) is None
 
 
-# ---------------------------------------------------------------------------
-# nemotron3-super-ep4-d11 (PR 40): the configuration, the cell and its
-# twenty-four per-layer entries, held by NAME
-
-NEMO_CELL = "nemotron3-super-ep4-d11.longdoc-closed"
-_T, _C = "device_trace", "program_counter"
-NEMO_ENTRIES = [
-    ("decode_ssm_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("ssm_step_roofline", "%", _T, "kernels", "nemotron_roofline"),
-    ("ssm_proj_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("prefill_ssm_scan_ms", "ms", _T, "model step", "nemotron_roofline"),
-    ("ssm_scan_roofline", "%", _T, "kernels", "nemotron_roofline"),
-    ("moe_latent_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("ssm_state_share_pct", "%", _C, "engine", "nemotron_roofline"),
-    ("ssm_scan_masked_pct", "%", _C, "engine", "nemotron_roofline"),
-    ("decode_step_ms", "ms", _T, "model step", "trace_module_ms"),
-    ("decode_step_roofline", "%", _T, "model step", "nemotron_roofline"),
-    ("decode_moe_ms", "ms", _T, "model step", "trace_scope_ms"),
-    ("moe_experts_roofline", "%", _T, "kernels", "nemotron_roofline"),
-    ("moe_overhead_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("moe_shared_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("moe_held_share_pct", "%", _C, "model step", "prom_counter_ratio"),
-    ("moe_imbalance", "ratio", _C, "engine", "prom_hist"),
-    ("decode_attn_ms", "ms", _T, "model step", "trace_scope_ms"),
-    ("paged_decode_attention_roofline", "%", _T, "kernels", "nemotron_roofline"),
-    ("table_blocks_dead_pct", "%", _C, "kernels", "prom_counter_ratio"),
-    ("streams_per_chunk", "streams", _C, "engine", "prom_hist"),
-    ("prefill_stall_ms", "ms/s", _C, "engine", "prom_counter_rate"),
-    ("prefill_window_ms", "ms", _T, "model step", "trace_module_ms"),
-    ("prefill_windows_batched_pct", "%", _C, "engine", "prom_counter_ratio"),
-    ("device_idle_pct", "%", _T, "device", "trace_idle_pct"),
-]
-
-
-def test_nemotron_configuration_and_cell_are_in_the_benchmark():
-    from cellbench import spec
-
-    bench = spec.load_benchmark()
-    cfg = _named(bench["configs"], "nemotron3-super-ep4-d11")  # by NAME
-    assert cfg["file"] == "cellbench/configs/nemotron3-super-ep4-d11.json"
-    assert cfg["source"] == (
-        "https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16"
-        "/blob/main/config.json")
-    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts", "vocab_size"]
-    cell = _named(bench["workloads"], NEMO_CELL)
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        NEMO_CELL, "nemotron3-super-ep4-d11", "longdoc-closed", 1)
-    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
-    on = [m["name"] for m in bench["end_to_end"]
-          if "workloads" not in m or NEMO_CELL in m["workloads"]]
-    assert on == ["tbt_p99_ms", "setup_s"]
-    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(NEMO_CELL in m["workloads"] for m in boots)
-    file = spec.load_json(spec.REPO + "/" + cfg["file"])
-    assert set(file["reduced"]) == set(cfg["reduced"])
-    assert (file["num_hidden_layers"], file["n_routed_experts"],
-            file["vocab_size"]) == (11, 128, 32768)
-    assert file["hybrid_override_pattern"][:11] == "MEMEMEM*EME"
-    assert "deployment" in file["assumed"] and "mtp" in file["assumed"]
-
-
-@pytest.mark.parametrize("name,unit,source,layer,reader", NEMO_ENTRIES)
-def test_nemotron_per_layer_entry_resolves(name, unit, source, layer, reader):
-    from cellbench import spec
-
-    name += ".nemotron"
-    entry = _named(spec.load_benchmark()["per_layer"], name)
-    assert _without_workloads(entry) == {
-        "name": name, "unit": unit,
-        "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms"}
-    assert _lists(entry, [NEMO_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED))
-    assert entry["better"] in ("lower", "higher")
-    (resolved,) = [m for m in spec.resolve(NEMO_CELL).per_layer if m.name == name]
-    assert resolved.reader == reader and callable(resolved.read)
-
-
 def test_nemotron_counter_readings_read_the_programs_families():
     """``ssm_scan_masked_pct`` and ``ssm_state_share_pct`` through the
     reader their entries name, off the families as ``/metrics`` exports
@@ -557,7 +554,6 @@ def test_nemotron_counter_readings_read_the_programs_families():
     raise."""
     import types
 
-    from cellbench import spec
     from mlmicroservicetemplate_tpu.utils import metrics
     from prometheus_client import generate_latest
 
@@ -573,50 +569,14 @@ def test_nemotron_counter_readings_read_the_programs_families():
                 None if fam not in after
                 else hist_delta(after[fam], before.get(fam))))
 
-    by_name = {m.name: m for m in spec.resolve(NEMO_CELL).per_layer}
-    masked = by_name["ssm_scan_masked_pct.nemotron"]
+    masked = _reads(NEMO_CELL, "nemotron_roofline", what="masked_pct")
     assert masked.read(ctx(after, base), **masked.args) == 25.0
-    share = by_name["ssm_state_share_pct.nemotron"]
+    share = _reads(NEMO_CELL, "nemotron_roofline", what="state_share")
     gauges = {"ssm_state_bytes": {"value": 3.0e8}, "kv_committed_bytes": {"value": 1.0e8}}
     assert share.read(ctx(gauges, {}), **share.args) == 75.0
-    for m in by_name.values():
+    for m in _resolved(NEMO_CELL).per_layer:
         if m.reader == "nemotron_roofline":
             assert m.read(ctx({}, {}), **m.args) is None
-
-
-# ---------------------------------------------------------------------------
-# insert_rows_per_dispatch.* (PR 41): how many rows a paged insert dispatch
-# lands — two entries, two data files, the reader the benchmark has
-# (prom_hist), nothing edited
-
-
-@pytest.mark.parametrize("name,cell", [
-    ("insert_rows_per_dispatch.decode", "mistral-7b-d8.decode-closed"),
-    ("insert_rows_per_dispatch.olmoe", "olmoe-1b-7b-d8.decode-closed"),
-])
-def test_insert_rows_per_dispatch_resolves_in_its_cell(name, cell):
-    from cellbench import spec
-
-    per_layer = spec.load_benchmark()["per_layer"]
-    (entry,) = [m for m in per_layer if m["name"] == name]
-    assert entry == {
-        "name": name, "unit": "rows", "better": "higher",
-        "source": "program_counter", "layer": "engine", "moves": "tokens_per_s",
-        "workloads": [cell]}
-    # appended behind PR 40's entries, the two side by side (by NAME: every
-    # later PR's entries follow them)
-    names = [m["name"] for m in per_layer]
-    assert names.index(name) > names.index("device_idle_pct.nemotron")
-    assert abs(names.index("insert_rows_per_dispatch.decode")
-               - names.index("insert_rows_per_dispatch.olmoe")) == 1
-    resolved = spec.resolve(cell)
-    (metric,) = [m for m in resolved.per_layer if m.name == name]
-    assert metric.reader == "prom_hist" and callable(metric.read)
-    assert metric.args == {"family": "stream_insert_rows", "stat": "mean"}
-    assert "tokens_per_s" in [m.name for m in resolved.end_to_end]
-    others = [w["name"] for w in spec.load_benchmark()["workloads"] if w["name"] != cell]
-    for other in others:  # read in its own cell only
-        assert name not in [m.name for m in spec.resolve(other).per_layer]
 
 
 def test_insert_rows_per_dispatch_reads_the_programs_histogram():
@@ -647,276 +607,12 @@ def test_insert_rows_per_dispatch_reads_the_programs_histogram():
     assert prom_hist.read(ctx({}, {}), *args) is None
 
 
-# ---------------------------------------------------------------------------
-# PR 47: GigaChat3.5 — one configuration, one cell, twenty-nine entries, a
-# cost file and one reader, every accepted file as it was
-
-GIGA_CELL = "gigachat35-ep16-d5.longdoc-closed"
-GIGA_ENTRIES = [
-    ("decode_step_ms", "ms", _T, "model step", "trace_module_ms"),
-    ("decode_step_roofline", "%", _T, "model step", "gigachat_roofline"),
-    ("decode_gdn_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("gdn_step_roofline", "%", _T, "kernels", "gigachat_roofline"),
-    ("gdn_proj_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("prefill_gdn_scan_ms", "ms", _T, "model step", "gigachat_roofline"),
-    ("gdn_scan_roofline", "%", _T, "kernels", "gigachat_roofline"),
-    ("gdn_state_share_pct", "%", _C, "engine", "gigachat_roofline"),
-    ("gdn_scan_masked_pct", "%", _C, "engine", "gigachat_roofline"),
-    ("decode_attn_latent_ms", "ms", _T, "model step", "trace_scope_ms"),
-    ("latent_decode_attention_roofline", "%", _T, "kernels", "gigachat_roofline"),
-    ("decode_moe_ms", "ms", _T, "model step", "trace_scope_ms"),
-    ("moe_experts_roofline", "%", _T, "kernels", "gigachat_roofline"),
-    ("moe_held_share_pct", "%", _C, "model step", "prom_counter_ratio"),
-    ("moe_imbalance", "ratio", _C, "engine", "prom_hist"),
-    ("streams_per_chunk", "streams", _C, "engine", "prom_hist"),
-    ("prefill_stall_ms", "ms/s", _C, "engine", "prom_counter_rate"),
-    ("prefill_window_ms", "ms", _T, "model step", "trace_module_ms"),
-    ("prefill_windows_batched_pct", "%", _C, "engine", "prom_counter_ratio"),
-    ("device_idle_pct", "%", _T, "device", "trace_idle_pct"),
-    # the accepted twins of the latent layer, the expert overhead and the table
-    ("mla_absorb_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("mla_proj_ms", "ms", _T, "model step", "trace_scope_ms"),
-    ("moe_overhead_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("moe_shared_ms", "ms", _T, "model step", "trace_subscope_ms"),
-    ("table_blocks_dead_pct", "%", _C, "kernels", "prom_counter_ratio"),
-    # a prompt-window dispatch, part by part
-    ("prefill_gdn_proj_ms", "ms", _T, "model step", "gigachat_roofline"),
-    ("prefill_attn_latent_ms", "ms", _T, "model step", "gigachat_roofline"),
-    ("prefill_mlp_ms", "ms", _T, "model step", "gigachat_roofline"),
-    ("prefill_moe_experts_ms", "ms", _T, "model step", "gigachat_roofline"),
-]
-
-
-def test_gigachat_configuration_and_cell_are_in_the_benchmark():
-    from cellbench import spec
-
-    bench = spec.load_benchmark()
-    cfg = _named(bench["configs"], "gigachat35-ep16-d5")  # by NAME
-    cell = _named(bench["workloads"], GIGA_CELL)
-    assert cfg["file"] == "cellbench/configs/gigachat35-ep16-d5.json"
-    assert cfg["source"] == (
-        "https://huggingface.co/ai-sage/GigaChat3.5-432B-A28B/blob/main/config.json")
-    assert cfg["reduced"] == ["num_hidden_layers", "n_routed_experts",
-                              "first_k_dense_replace", "vocab_size"]
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        GIGA_CELL, "gigachat35-ep16-d5", "longdoc-closed", 1)
-    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
-    on = [m["name"] for m in bench["end_to_end"]
-          if "workloads" not in m or GIGA_CELL in m["workloads"]]
-    assert on == ["tbt_p99_ms", "setup_s"]
-    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(GIGA_CELL in m["workloads"] for m in boots)
-    names = [m["name"] for m in bench["per_layer"]]
-    first = names.index(GIGA_ENTRIES[0][0] + ".gigachat")  # the 29 stand together
-    assert names[first:first + len(GIGA_ENTRIES)] == [
-        n + ".gigachat" for n, *_ in GIGA_ENTRIES]
-    assert len(bench["per_layer"]) <= 128  # the file's limit
-    file = spec.load_json(spec.REPO + "/" + cfg["file"])
-    assert list(file["reduced"]) == cfg["reduced"]
-    assert (file["num_hidden_layers"], file["n_routed_experts"],
-            file["first_k_dense_replace"], file["vocab_size"]) == (5, 16, 1, 16032)
-    assert file["layer_types"] == ["linear"] * 4 + ["full"]
-    assert "deployment" in file["assumed"] and "mtp" in file["assumed"]
-    # the traffic file is the three other long-document cells', unchanged
-    assert spec.resolve(GIGA_CELL).traffic == spec.resolve(NEMO_CELL).traffic
-
-
-@pytest.mark.parametrize("name,unit,source,layer,reader", GIGA_ENTRIES)
-def test_gigachat_per_layer_entry_resolves(name, unit, source, layer, reader):
-    from cellbench import spec
-
-    name += ".gigachat"
-    entry = _named(spec.load_benchmark()["per_layer"], name)
-    assert _without_workloads(entry) == {
-        "name": name, "unit": unit,
-        "better": entry["better"], "source": source, "layer": layer,
-        "moves": "tbt_p99_ms"}
-    assert _lists(entry, [GIGA_CELL] + [JAMBA_CELL] * (name in JAMBA_SHARED))
-    assert entry["better"] in ("lower", "higher")
-    (resolved,) = [m for m in spec.resolve(GIGA_CELL).per_layer if m.name == name]
-    assert resolved.reader == reader and callable(resolved.read)
-    for other in spec.load_benchmark()["workloads"]:  # read in the cells it lists only
-        if other["name"] not in entry["workloads"]:
-            assert name not in [m.name for m in spec.resolve(other["name"]).per_layer]
-
-
-# ---------------------------------------------------------------------------
-# jamba2-3b-d28 (PR 51): one configuration, one cell, ONE new per-layer entry
-# (the file's limit is 128 entries and 127 stood) and the cell's name appended
-# to the sibling entries whose readers read the same scopes and counters
-
-
-JAMBA_CELL = "jamba2-3b-d28.longdoc-closed"
-#: The accepted entries the cell is appended to (their readers are generic:
-#: the ``ssm`` / ``ssm_scan`` scopes, the shared ``ssm_*`` families, the
-#: loop's counters, the window executable by name).
-JAMBA_SHARED = [
-    "decode_ssm_ms.nemotron", "ssm_proj_ms.nemotron", "prefill_ssm_scan_ms.nemotron",
-    "ssm_scan_masked_pct.nemotron",
-    "decode_step_ms.nemotron", "decode_attn_ms.nemotron",
-    "table_blocks_dead_pct.nemotron", "streams_per_chunk.nemotron",
-    "prefill_stall_ms.nemotron", "prefill_window_ms.nemotron",
-    "prefill_windows_batched_pct.nemotron", "device_idle_pct.nemotron",
-    "prefill_mlp_ms.gigachat"]
-
-
-def test_jamba_configuration_and_cell_are_in_the_benchmark():
-    from cellbench import spec
-
-    bench = spec.load_benchmark()
-    cfg = _named(bench["configs"], "jamba2-3b-d28")  # by NAME
-    cell = _named(bench["workloads"], JAMBA_CELL)
-    assert (cfg["name"], cfg["file"], cfg["reduced"]) == (
-        "jamba2-3b-d28", "cellbench/configs/jamba2-3b-d28.json", [])
-    assert cfg["source"] == (
-        "https://huggingface.co/ai21labs/AI21-Jamba2-3B/blob/main/config.json")
-    assert (cell["name"], cell["config"], cell["traffic"], cell["chips"]) == (
-        JAMBA_CELL, "jamba2-3b-d28", "longdoc-closed", 1)
-    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
-    on = [m["name"] for m in bench["end_to_end"]
-          if "workloads" not in m or JAMBA_CELL in m["workloads"]]
-    assert on == ["tbt_p99_ms", "setup_s"]
-    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(JAMBA_CELL in m["workloads"] for m in boots)
-    assert len(bench["configs"]) >= 7 and len(bench["workloads"]) >= 8
-    assert len(bench["per_layer"]) <= 128  # the file's limit
-    file = spec.load_json(spec.REPO + "/" + cfg["file"])
-    assert file["reduced"] == {} and file["num_hidden_layers"] == 28
-    assert file["tie_word_embeddings"] is True and file["num_key_value_heads"] == 1
-    assert spec.resolve(JAMBA_CELL).traffic == spec.resolve(NEMO_CELL).traffic
-
-
-def test_jamba_per_layer_entries_resolve():
-    """PR 51's one new entry, by NAME; every sibling entry the cell was
-    appended to lists it, moves the end-to-end metric the cell reports and
-    resolves to a reader; no other cell reads the new entry."""
-    from cellbench import spec
-
-    per_layer = spec.load_benchmark()["per_layer"]
-    assert _named(per_layer, "ssm_scan_roofline.jamba2") == {
-        "name": "ssm_scan_roofline.jamba2", "unit": "%", "better": "higher",
-        "source": "device_trace", "layer": "kernels", "moves": "tbt_p99_ms",
-        "workloads": [JAMBA_CELL]}
-    mine = {m.name: m for m in spec.resolve(JAMBA_CELL).per_layer}
-    assert mine["ssm_scan_roofline.jamba2"].reader == "jamba_roofline"
-    assert mine["ssm_scan_roofline.jamba2"].args == {"what": "ssm_scan"}
-    listed = [m["name"] for m in per_layer if JAMBA_CELL in m.get("workloads", [])]
-    # PR 51's, and the three PR 55 landed for the cell (the step's and the
-    # attention kernel's shares its reader already computed, the loop's
-    # unnamed share); a later PR may list the cell elsewhere too
-    assert set(listed) >= {
-        *JAMBA_SHARED, "ssm_scan_roofline.jamba2", "decode_step_roofline.jamba2",
-        "paged_decode_attention_roofline.jamba2", "loop_unnamed_pct.serve",
-        *(m["name"] for m in per_layer if m["name"].startswith("boot_"))}
-    for m in per_layer:
-        if m["name"] in JAMBA_SHARED:
-            assert JAMBA_CELL in m["workloads"] and m["moves"] == "tbt_p99_ms"
-            assert callable(mine[m["name"]].read)
-    for other in spec.load_benchmark()["workloads"]:
-        if other["name"] != JAMBA_CELL:
-            assert "ssm_scan_roofline.jamba2" not in [
-                m.name for m in spec.resolve(other["name"]).per_layer]
-
-
-# ---------------------------------------------------------------------------
-# granite-4.0-h-small-ep2-d10 (PR 56): one configuration, one cell, FIVE new
-# per-layer entries (each a new definition: the file stands at its limit of
-# 128) and the cell's name appended to the thirty standing entries whose
-# definitions read its scopes and counters — all held by NAME
-
-
-GRANITE_CELL = "granite-4.0-h-small-ep2-d10.longdoc-closed"
-GRANITE_ENTRIES = [
-    ("decode_step_roofline.granite", "model step", "step"),
-    ("moe_experts_roofline.granite", "kernels", "experts"),
-    ("ssm_scan_roofline.granite", "kernels", "ssm_scan"),
-    ("ssm_step_roofline.granite", "kernels", "ssm_step"),
-    ("paged_decode_attention_roofline.granite", "kernels", "attention"),
-]
-#: The standing entries the cell is appended to (one entry a definition, PR
-#: 55): the loop's and the process's counters, the long-document cells'
-#: spans, Nemotron's readings of the Mamba-2 scopes, the expert block's.
-GRANITE_SHARED = [
-    "loop_unnamed_pct.serve", "event_loop_lag_p99_ms",
-    "device_idle_pct.nemotron", "streams_per_chunk.nemotron",
-    "prefill_stall_ms.nemotron", "table_blocks_dead_pct.nemotron",
-    "prefill_window_ms.nemotron", "prefill_windows_batched_pct.nemotron",
-    "decode_step_ms.nemotron", "decode_ssm_ms.nemotron", "ssm_proj_ms.nemotron",
-    "decode_attn_ms.nemotron", "decode_moe_ms.nemotron", "moe_overhead_ms.nemotron",
-    "moe_shared_ms.nemotron", "moe_imbalance.nemotron", "moe_held_share_pct.nemotron",
-    "moe_rows_skipped_pct.dsv2", "prefill_ssm_scan_ms.nemotron",
-    "ssm_scan_masked_pct.nemotron", "ssm_state_share_pct.nemotron",
-    "prefill_mlp_ms.gigachat", "prefill_moe_experts_ms.gigachat"]
-
-
-def test_granite_configuration_and_cell_are_in_the_benchmark():
-    from cellbench import spec
-
-    bench = spec.load_benchmark()
-    cfg = _named(bench["configs"], "granite-4.0-h-small-ep2-d10")
-    cell = _named(bench["workloads"], GRANITE_CELL)
-    assert cfg["file"] == "cellbench/configs/granite-4.0-h-small-ep2-d10.json"
-    assert cfg["source"] == (
-        "https://huggingface.co/ibm-granite/granite-4.0-h-small/blob/main/config.json")
-    assert cfg["reduced"] == ["num_hidden_layers", "num_local_experts", "vocab_size"]
-    assert (cell["config"], cell["traffic"], cell["chips"]) == (
-        "granite-4.0-h-small-ep2-d10", "longdoc-closed", 1)
-    assert len(cell["why"]) <= 200 and len(cfg["why"]) <= 200
-    on = [m["name"] for m in bench["end_to_end"]
-          if "workloads" not in m or GRANITE_CELL in m["workloads"]]
-    assert on == ["tbt_p99_ms", "setup_s"]
-    boots = [m for m in bench["per_layer"] if m["name"].startswith("boot_")]
-    assert len(boots) == 7 and all(GRANITE_CELL in m["workloads"] for m in boots)
-    assert len(bench["per_layer"]) <= 128  # the file's limit
-    file = spec.load_json(spec.REPO + "/" + cfg["file"])
-    assert list(file["reduced"]) == cfg["reduced"]
-    assert (file["num_hidden_layers"], file["num_local_experts"], file["router_experts"],
-            file["vocab_size"]) == (10, 36, 72, 50176)
-    assert file["layer_types"][:10] == ["mamba"] * 5 + ["attention"] + ["mamba"] * 4
-    assert file["llama_layer_types"][:10] == ["mamba2"] * 5 + ["attention"] + ["mamba2"] * 4
-    assert "deployment" in file["assumed"] and "router" in file["assumed"]
-    # the traffic file is the other long-document cells', unchanged
-    assert spec.resolve(GRANITE_CELL).traffic == spec.resolve(NEMO_CELL).traffic
-
-
-@pytest.mark.parametrize("name,layer,what", GRANITE_ENTRIES)
-def test_granite_per_layer_entry_resolves(name, layer, what):
-    from cellbench import spec
-
-    bench = spec.load_benchmark()
-    assert _named(bench["per_layer"], name) == {
-        "name": name, "unit": "%", "better": "higher", "source": "device_trace",
-        "layer": layer, "moves": "tbt_p99_ms", "workloads": [GRANITE_CELL]}
-    (resolved,) = [m for m in spec.resolve(GRANITE_CELL).per_layer if m.name == name]
-    assert resolved.reader == "granite_roofline" and resolved.args == {"what": what}
-    for other in bench["workloads"]:  # read in its own cell only
-        if other["name"] != GRANITE_CELL:
-            assert name not in [m.name for m in spec.resolve(other["name"]).per_layer]
-
-
-@pytest.mark.parametrize("name", GRANITE_SHARED)
-def test_granite_is_appended_to_the_entry_that_has_its_definition(name):
-    """The standing entry lists the cell BEHIND the cells it had, moves the
-    end-to-end metric the cell reports and resolves there to the reader and
-    arguments it resolves to in its first cell: one definition, read twice."""
-    from cellbench import spec
-
-    entry = _named(spec.load_benchmark()["per_layer"], name)
-    assert entry["workloads"][-1] == GRANITE_CELL and len(entry["workloads"]) >= 2
-    assert entry["moves"] == "tbt_p99_ms"
-    (mine,) = [m for m in spec.resolve(GRANITE_CELL).per_layer if m.name == name]
-    (first,) = [m for m in spec.resolve(entry["workloads"][0]).per_layer if m.name == name]
-    assert (mine.reader, mine.args) == (first.reader, first.args) and callable(mine.read)
-
-
 def test_granite_rooflines_read_nothing_from_a_program_without_the_scopes():
     """The five shares through the reader their entries name: untraced, or on
     a trace without the executable (the parent), no value and no raise."""
     import types
 
-    from cellbench import spec
-
-    cell = spec.resolve(GRANITE_CELL)
+    cell = _resolved(GRANITE_CELL)
     own = [m for m in cell.per_layer if m.reader == "granite_roofline"]
     assert len(own) == 5
     empty = types.SimpleNamespace(module_time=lambda m: (0.0, 0), ops={})

@@ -89,6 +89,16 @@ def test_every_declared_series_present_and_bounded():
 
                 if _json.loads(line).get("done"):
                     break
+            # The record that says "done" is routed INSIDE the loop's
+            # deliver phase, which closes after it, on the loop's
+            # thread: the scrape waits for the phase as it waited for
+            # readiness (alone in a process no earlier test has made
+            # the gpt2 child, and a loaded worker lost the race).
+            for _ in range(200):
+                status = await (await client.get("/status")).json()
+                if "loop/deliver" in status["decode"]["loop_time"]["phases"]:
+                    break
+                await asyncio.sleep(0.05)
             # Path 3: a shed — drain refuses admission with 503.
             batcher.begin_drain()
             r = await client.post("/predict", json={"text": "refused"})
