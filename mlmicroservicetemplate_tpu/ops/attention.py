@@ -195,10 +195,12 @@ def _decode_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     feeds bf16 slabs at storage width, ``fold_scales`` keeps int8
     payloads unscaled through the dots.  The block axis
     (``blocks_per_step``) has no meaning — there is no block table.
-    Refs: q ([1, KVH, R, D], or block-diagonal [1, H, KVH*D]), k
-    [1, T, KVH*D] (+ [1, T, KVH] scales when quant), v (+ scales),
-    mask [1, 1, T], output (shaped like q), m/l/acc scratch."""
-    from .paged_attention import _fold_block
+    Refs: q ([1, KVH, R, D], or [1, H, D] as it lies when head-batched:
+    its block-diagonal operand is built, and the diagonal read out, here
+    in VMEM by the paged kernel's two helpers), k [1, T, KVH*D] (+
+    [1, T, KVH] scales when quant), v (+ scales), mask [1, 1, T], output
+    (shaped like q), m/l/acc scratch."""
+    from .paged_attention import _fold_block, block_diagonal_q, diagonal_out
 
     it = iter(refs)
     q_ref, k_ref = next(it), next(it)
@@ -211,14 +213,15 @@ def _decode_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     l_scr[...] = jnp.zeros_like(l_scr)
     a_scr[...] = jnp.zeros_like(a_scr)
     f32 = jnp.float32
+    hb = var.head_batched
     _fold_block(
         q_ref, k_ref[0], ks_ref[0].astype(f32) if quant else None,
         v_ref[0], vs_ref[0].astype(f32) if quant else None, mask_ref[0],
         m_scr, l_scr, a_scr, scale=scale, kvh=kvh, n_rep=n_rep, d=d, var=var,
+        q_diag=block_diagonal_q(q_ref[0], kvh, n_rep) if hb else None,
     )
-    o_ref[0] = (a_scr[...] / jnp.maximum(l_scr[...], 1e-20)).astype(
-        o_ref.dtype
-    )
+    acc = diagonal_out(a_scr[...], kvh, n_rep) if hb else a_scr[...]
+    o_ref[0] = (acc / jnp.maximum(l_scr[...], 1e-20)).astype(o_ref.dtype)
 
 
 # Per-program VMEM for the whole-slab decode kernel: the raw K+V slabs,
@@ -341,12 +344,9 @@ def decode_attention(
     else:
         # Variant kernels take lane-dense [1, T, KVH*D] slabs (trailing
         # dims merged, a bitcast in HBM) — see _fold_block.
-        from .paged_attention import head_batched_q, softmax_scratch
+        from .paged_attention import softmax_scratch
 
-        qk = (
-            head_batched_q(q, kvh) if var.head_batched
-            else q.reshape(b, kvh, n_rep, d)
-        )
+        qk = q if var.head_batched else q.reshape(b, kvh, n_rep, d)
         q_spec = pl.BlockSpec(
             (1,) + qk.shape[1:], lambda i: (i,) + (0,) * (qk.ndim - 1)
         )
@@ -356,7 +356,9 @@ def decode_attention(
             quant=quant, var=var,
         )
         slabs = (k.reshape(b, t, kvh * d), v.reshape(b, t, kvh * d))
-        scratch = softmax_scratch(qk.shape[1:], jnp.float32)
+        scratch = softmax_scratch(
+            (h, kvh * d) if var.head_batched else qk.shape[1:], jnp.float32
+        )
     if not quant:
         in_specs = [q_spec, kv_spec, kv_spec, mask_spec]
         args = (qk, *slabs, mask3)
@@ -375,10 +377,6 @@ def decode_attention(
         scratch_shapes=scratch,
         interpret=interpret,
     )(*args)
-    if var.head_batched:
-        from .paged_attention import head_batched_out
-
-        return head_batched_out(out, kvh)
     return out.reshape(b, h, d)
 
 

@@ -69,6 +69,18 @@ LATENT_PROBE_SCALE = 0.1
 SWEEP_ITERS = 4
 SWEEP_REPS = 3
 
+#: Revision of the paged and whole-slab kernels AS THE SWEEP TIMES THEM
+#: (``_make_call`` times the whole jitted wrapper), carried in their
+#: ``tune_key``: the table file lives in the compile-cache directory and
+#: outlives a checkout's code, and a winner timed on one kernel must not be
+#: served to another.  2 (PR 59): the head-batched kernels lay out their
+#: own q in VMEM — revision 1's ``-hb`` timings held XLA's block-diagonal q
+#: and read-out around the call.  Revision 1 keys carry no token, so the
+#: tables written before stay valid for the code that wrote them, and the
+#: two keep separate entries in one file.  The latent kernel has not
+#: changed: its keys carry none.
+KERNEL_REVISION = 2
+
 _LOCK = threading.RLock()
 _TABLE: dict[str, str] = {}
 _RESULTS: dict[str, dict] = {}
@@ -110,11 +122,14 @@ def tune_key(kind: str, *, b: int, kvh: int, n_rep: int,
     parallel width the kernel runs under: each shard's kernel sees
     kvh/tp local heads AND a different compute/VMEM surface (the
     shard_map body), so TP entries must never alias single-device ones.
-    tp=1 appends nothing — every pre-TP persisted table stays valid."""
+    tp=1 appends nothing — every pre-TP persisted table stays valid.
+    The kind carries its kernel's revision (``KERNEL_REVISION``:
+    ``paged_decode.r2/...``), on every kind but the latent kernel's."""
     q8 = "-q8" if quant else ""
     tps = f"-tp{tp}" if int(tp) > 1 else ""
+    rev = "" if kind == "latent_decode" else f".r{KERNEL_REVISION}"
     return (
-        f"{kind}/B{b}-G{kvh}-R{n_rep}-D{d}"
+        f"{kind}{rev}/B{b}-G{kvh}-R{n_rep}-D{d}"
         f"-bs{block_size}-T{t}-{dtype}{q8}{tps}"
     )
 
@@ -144,10 +159,15 @@ def paged_vmem_bytes(var: Variant, *, bs: int, kvh: int, d: int,
     lane-dense ``[K*BS, KVH*D]`` (``_fold_block``), so bytes are what the
     arrays hold, with no (8, 128) padding blow-up to model: TWO slots of
     K raw K/V blocks (a trip's, and the next trip's in flight); the
-    dequant/upcast f32 copies (``native_mxu`` skips them); q/out tiles
-    and online-softmax scratch — ``[H, KVH*D]`` wide when
-    ``head_batched`` (block-diagonal q, diagonal read-out), ``[H, D]``
-    otherwise; and the score/prob temporaries.  What rides a row at a
+    dequant/upcast f32 copies (``native_mxu`` skips them); the q and
+    output blocks ``[H, D]``, double-buffered by the pipeline (at the
+    payload's width when ``head_batched``: q crosses as it lies; a
+    ``[KVH, R, D]`` block pads R to a sublane tile, counted at 4 bytes);
+    when ``head_batched`` over more than one KV head ONE block-diagonal
+    q ``[H, KVH*D]`` scratch, built in VMEM once a row, and its f32 copy
+    where the fold upcasts; online-softmax scratch — acc ``[H, KVH*D]``
+    wide when ``head_batched``, ``[H, D]`` otherwise; and the score/prob
+    temporaries.  What rides a row at a
     time grows with the table's width ``t`` (0: not counted), double-
     buffered by the pipeline with its minor dim padded to a lane tile:
     the mask ``[T/K, K*BS]`` and, when quant, the row's gathered K and V
@@ -158,10 +178,14 @@ def paged_vmem_bytes(var: Variant, *, bs: int, kvh: int, d: int,
     payload = 2 * 2 * kb * kvh * d * payload_bytes
     mask = 2 * (t // var.blocks_per_step) * max(kb, 128) * 4
     scales = 2 * 2 * t * bs * max(kvh, 128) * 4 if quant else 0
-    f32_copies = 0 if (var.native_mxu and not quant) else 2 * kb * kvh * d * 4
+    upcast = not (var.native_mxu and not quant)
+    f32_copies = 2 * kb * kvh * d * 4 if upcast else 0
     h = kvh * n_rep
     cols = kvh * d if var.head_batched else d
-    q_out = 2 * 2 * h * cols * 4
+    q_bytes = payload_bytes if var.head_batched and not quant else 4
+    q_out = 2 * 2 * h * d * q_bytes
+    if var.head_batched and kvh > 1:
+        q_out += h * cols * (q_bytes + (4 if upcast else 0))
     acc = 4 if var.acc_dtype == "f32" else 2
     scratch = (2 * h + h * cols) * acc
     scores = 2 * h * kb * 4  # s and p live together briefly

@@ -56,7 +56,8 @@ its head too; a hole inside the range is folded masked.  The range is
 the second scalar-prefetch operand and the trip count of the row's
 block loop: a table entry outside it costs no program, no copy and no
 fold, and a row with no live entry costs one empty grid step (~0.6 us
-on a v5e: its q, mask and output blocks still ride) and reads zeros.
+on a v5e: its q, mask and output blocks still ride — ``[H, D]`` each,
+whatever the variant) and reads zeros.
 A fully masked fold after live ones leaves ``m``, ``l`` and ``acc``
 bit for bit as they were, so every live row's output is what a walk of
 the whole table computes, to the last bit
@@ -70,9 +71,13 @@ Every variant computes the same masked online softmax in the same
 f32 accumulators — variants rearrange WHERE work happens (grid
 folding, head batching, dequant placement, MXU input width), never
 WHAT is accumulated, which is what keeps each one token-identical to
-``paged_attention_ref`` by construction.  The only lossy axis
-(``accbf16`` scratch) is excluded from sweeps and reachable solely
-through an explicit ``PALLAS_VARIANT`` pin.
+``paged_attention_ref`` by construction.  q crosses HBM as ``[B, H, D]``
+and so does the output, under every variant: the ``head_batched`` fold's
+block-diagonal ``[H, KVH*D]`` operand is built (``block_diagonal_q``),
+and its accumulator's diagonal read out (``diagonal_out``), in VMEM, once
+a row (PR 59; XLA built and undid that layout around the call before).
+The only lossy axis (``accbf16`` scratch) is excluded from sweeps and
+reachable solely through an explicit ``PALLAS_VARIANT`` pin.
 
 Sentinel table entries (freed slots, a row's tail) come to the kernel
 as they are — out of range is how it tells them from blocks; it clamps
@@ -223,37 +228,50 @@ def scatter_pages(
     return scatter_rows(pool, blk * block_size + p % block_size, values)
 
 
-def head_batched_q(q: jax.Array, kvh: int) -> jax.Array:
-    """``[B, H, D]`` -> block-diagonal ``[B, H, KVH*D]``: head h's
-    vector in its KV group's lane slice, zeros elsewhere — the q
-    operand of the ``head_batched`` kernels (see ``_fold_block``)."""
-    b, h, d = q.shape
-    group = jnp.arange(h) // (h // kvh)
-    diag = (group[:, None] == jnp.arange(kvh)[None, :]).astype(q.dtype)
-    return (q[:, :, None, :] * diag[None, :, :, None]).reshape(b, h, kvh * d)
+def block_diagonal_q(q, kvh: int, n_rep: int):
+    """In VMEM: a row's ``[H, D]`` q -> the ``head_batched`` fold's
+    operand ``[H, KVH*D]`` (see ``_fold_block``) — head h's vector in its
+    KV group's lane slice, zeros elsewhere: q laid KVH times along lanes,
+    piece g kept on group g's rows (``g*R <= row < (g+1)*R``, a 2-D iota:
+    no sublane slice at any ``n_rep``).  At one KV head the operand IS q."""
+    if kvh == 1:
+        return q
+    row = jax.lax.broadcasted_iota(jnp.int32, q.shape, 0)
+    zero = jnp.zeros_like(q)
+    return jnp.concatenate(
+        [jnp.where((row >= g * n_rep) & (row < (g + 1) * n_rep), q, zero)
+         for g in range(kvh)], axis=1,
+    )
 
 
-def head_batched_out(out: jax.Array, kvh: int) -> jax.Array:
-    """Inverse read-out: ``[B, H, KVH*D]`` -> ``[B, H, D]``, each
-    head's output taken from its group's diagonal ``[R, D]`` block."""
-    b, h, gd = out.shape
-    group = jnp.arange(h) // (h // kvh)
-    return jnp.take_along_axis(
-        out.reshape(b, h, kvh, gd // kvh), group[None, :, None, None], axis=2
-    )[:, :, 0]
+def diagonal_out(acc, kvh: int, n_rep: int):
+    """The inverse read-out, in VMEM: ``[H, KVH*D]`` -> ``[H, D]``, row
+    h's lanes taken from its group's slice ``[g(h)*D, (g(h)+1)*D)`` — the
+    diagonal ``[R, D]`` blocks of the ``head_batched`` accumulator, by a
+    chain of selects on the same row-group iota (exact: every output is
+    one element of ``acc``)."""
+    if kvh == 1:
+        return acc
+    d = acc.shape[1] // kvh
+    row = jax.lax.broadcasted_iota(jnp.int32, (acc.shape[0], d), 0)
+    out = acc[:, (kvh - 1) * d:]
+    for g in reversed(range(kvh - 1)):
+        out = jnp.where(row < (g + 1) * n_rep, acc[:, g * d:(g + 1) * d], out)
+    return out
 
 
-def softmax_scratch(q_block: tuple, dtype) -> list:
-    """m/l/acc VMEM scratch for a q block ``[*rows, C]`` (its leading
-    batch-1 dim dropped): statistics ``[*rows, 1]``, accumulator
+def softmax_scratch(acc_block: tuple, dtype) -> list:
+    """m/l/acc VMEM scratch for an accumulator ``[*rows, C]`` (a q block
+    with its leading batch-1 dim dropped, or ``[H, KVH*D]`` under
+    ``head_batched``): statistics ``[*rows, 1]``, accumulator
     ``[*rows, C]``."""
     from jax.experimental.pallas import tpu as pltpu
 
-    rows = tuple(q_block[:-1])
+    rows = tuple(acc_block[:-1])
     return [
         pltpu.VMEM(rows + (1,), dtype),
         pltpu.VMEM(rows + (1,), dtype),
-        pltpu.VMEM(tuple(q_block), dtype),
+        pltpu.VMEM(tuple(acc_block), dtype),
     ]
 
 
@@ -314,7 +332,7 @@ def _attend_tile(q, k, v, ks_t, vs_t, valid, m_prev, l_prev, a_prev, *,
 
 def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
                 a_scr, *, scale: float, kvh: int, n_rep: int, d: int,
-                var: Variant):
+                var: Variant, q_diag=None):
     """Fold one key/value block into the online-softmax accumulators.
 
     Tiles are 2-D and lane-dense: ``k_blk``/``v_blk`` are ``[KB, KVH*D]``
@@ -329,13 +347,16 @@ def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
       slice ``[g*D, (g+1)*D)``, q/out tiles ``[R, D]`` of the
       ``[1, KVH, R, D]`` blocks, scratch m/l ``[KVH, R, 1]`` and acc
       ``[KVH, R, D]``.
-    - ``head_batched``: q arrives block-diagonal ``[H, KVH*D]`` (head
-      h's vector in its group's lane slice, zeros elsewhere), so ONE
-      ``[H, KVH*D] x [KB, KVH*D]`` MXU issue scores every head and one
-      ``[H, KB] x [KB, KVH*D]`` issue forms every head's output in the
-      diagonal ``[R, D]`` blocks of acc ``[H, KVH*D]`` (the wrapper
-      reads the diagonal; off-diagonal blocks are finite garbage).
-      Scratch m/l are ``[H, 1]``.
+    - ``head_batched``: the q operand is block-diagonal ``[H, KVH*D]``
+      (head h's vector in its group's lane slice, zeros elsewhere) —
+      ``q_diag``, which the kernel built in VMEM from the row's
+      ``[1, H, D]`` block (``block_diagonal_q``; a ref or a value), or
+      ``q_ref[0]`` itself at one KV head — so ONE ``[H, KVH*D] x
+      [KB, KVH*D]`` MXU issue scores every head and one ``[H, KB] x
+      [KB, KVH*D]`` issue forms every head's output in the diagonal
+      ``[R, D]`` blocks of acc ``[H, KVH*D]`` (the kernel reads the
+      diagonal out at finalize, ``diagonal_out``; off-diagonal blocks
+      are finite garbage).  Scratch m/l are ``[H, 1]``.
     """
     f32 = jnp.float32
     quant = ks_blk is not None
@@ -384,7 +405,8 @@ def _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid, m_scr, l_scr,
         return up(sel(k_blk)), up(sel(v_blk)), None, None
 
     if var.head_batched:
-        fold(slice(None), up(q_ref[0]), *tiles(None))
+        q = q_ref[0] if q_diag is None else q_diag[...]
+        fold(slice(None), up(q), *tiles(None))
         return
     for g in range(kvh):
         fold(g, up(q_ref[0, g]), *tiles(g))
@@ -426,14 +448,18 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     rows (a row's first trip is the batch's ``base``-th).  A row with no
     live program starts no copy, folds nothing and writes zeros (acc = 0,
     l = 0).  Ref layout: tbl and the rows' ``(first, last, base)``
-    (prefetch), q ([1, KVH, R, D], or block-diagonal
-    [1, H, KVH*D] when head-batched), the k pool, the v pool (any memory
-    space), when quant the row's k and v scales [1, T*BS, KVH], the row's
-    mask [1, T/K, K*BS], the output (shaped like q), then m/l/acc
-    scratch, the k and v slots [2, K*BS, KVH*D] and the DMA semaphores
-    [2].  ``latent`` (``latent_decode_attention``): ONE pool and one slot
-    pair — no v pool and no v slot among the refs; a block is copied
-    once and its first ``latent`` lanes are the fold's values."""
+    (prefetch), q ([1, KVH, R, D], or [1, H, D] as it lies when
+    head-batched), the k pool, the v pool (any memory space), when quant
+    the row's k and v scales [1, T*BS, KVH], the row's mask
+    [1, T/K, K*BS], the output (shaped like q), then m/l/acc scratch
+    (acc [H, KVH*D] when head-batched), when head-batched over more than
+    one KV head the block-diagonal q [H, KVH*D] — built once a row before
+    the block loop, its diagonal read out of acc once a row at finalize:
+    the layout exists in VMEM only —, the k and v slots [2, K*BS, KVH*D]
+    and the DMA semaphores [2].  ``latent`` (``latent_decode_attention``):
+    ONE pool and one slot pair — no v pool and no v slot among the refs; a
+    block is copied once and its first ``latent`` lanes are the fold's
+    values."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -444,6 +470,7 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     ks_ref, vs_ref = (next(it), next(it)) if quant else (None, None)
     valid_ref, o_ref = next(it), next(it)
     m_scr, l_scr, a_scr = next(it), next(it), next(it)
+    qd_scr = next(it) if var.head_batched and kvh > 1 else None
     kbuf = next(it)
     vbuf = None if latent else next(it)
     sem = next(it)
@@ -476,6 +503,8 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
     m_scr[...] = jnp.full_like(m_scr, -1e30)
     l_scr[...] = jnp.zeros_like(l_scr)
     a_scr[...] = jnp.zeros_like(a_scr)
+    if qd_scr is not None:
+        qd_scr[...] = block_diagonal_q(q_ref[0], kvh, n_rep)
 
     def trip(s, carry):
         j, slot = first + s, (base + s) % 2
@@ -502,11 +531,13 @@ def _paged_kernel_v(*refs, scale: float, kvh: int, n_rep: int, d: int,
         v_blk = k_blk[:, :latent] if latent else vbuf[slot]
         _fold_block(q_ref, k_blk, ks_blk, v_blk, vs_blk, valid,
                     m_scr, l_scr, a_scr, scale=scale, kvh=kvh, n_rep=n_rep,
-                    d=d, var=var)
+                    d=d, var=var, q_diag=qd_scr)
         return carry
 
     jax.lax.fori_loop(0, n, trip, 0)
     acc = a_scr[...].astype(jnp.float32)
+    if qd_scr is not None:
+        acc = diagonal_out(acc, kvh, n_rep)
     l = l_scr[...].astype(jnp.float32)
     o_ref[0] = (acc / jnp.maximum(l, 1e-20)).astype(o_ref.dtype)
 
@@ -650,10 +681,9 @@ def paged_decode_attention(
     quant = k_scale is not None
     acc_jnp = jnp.float32 if var.acc_dtype == "f32" else jnp.bfloat16
     tbl, live, validb = _table_operands(table, key_valid, nb_pool, bs, K)
-    if var.head_batched:
-        qk = head_batched_q(q, kvh)
-    else:
-        qk = q.reshape(b, kvh, n_rep, d)
+    # head-batched: q crosses as it lies (the kernel lays it out in VMEM)
+    qk = q if var.head_batched else q.reshape(b, kvh, n_rep, d)
+    acc_block = (h, gd) if var.head_batched else qk.shape[1:]
 
     row_spec = _row_spec
     q_spec = row_spec(qk.shape)
@@ -677,7 +707,9 @@ def paged_decode_attention(
         in_specs=[*in_specs, row_spec(validb.shape)],
         out_specs=q_spec,
         scratch_shapes=[
-            *softmax_scratch(qk.shape[1:], acc_jnp),
+            *softmax_scratch(acc_block, acc_jnp),
+            *([pltpu.VMEM((h, gd), q.dtype)]
+              if var.head_batched and kvh > 1 else []),
             pltpu.VMEM((2, K * bs, gd), k_pool.dtype),
             pltpu.VMEM((2, K * bs, gd), v_pool.dtype),
             pltpu.SemaphoreType.DMA((2,)),
@@ -689,8 +721,6 @@ def paged_decode_attention(
         out_shape=jax.ShapeDtypeStruct(qk.shape, q.dtype),
         interpret=interpret,
     )(*args, validb)
-    if var.head_batched:
-        return head_batched_out(out, kvh)
     return out.reshape(b, h, d)
 
 

@@ -509,6 +509,30 @@ def test_donated_state_is_not_copied_at_entry(chip, cell, what):
     assert not pool_relayouts(text, [payload_n, scale_n], in_loop_only=True)
 
 
+@pytest.mark.parametrize("cell,b,h,kvh", [
+    ("mistral", 64, 32, 8), ("olmoe", 64, 16, 16), ("trinity", 32, 32, 4),
+])
+def test_the_block_diagonal_q_stays_in_the_kernel(chip, cell, b, h, kvh):
+    """The head-batched kernel lays out its own q (PR 59): the compiled
+    decode chunk at the cells' shapes, ``b4-hb`` pinned, holds no array of
+    the block-diagonal operand's shape ``[B, H, KVH*D]`` (nor its 4-D form
+    ``[B, H, KVH, D]``) anywhere outside the kernel — q enters and the
+    output leaves the call as ``[B, H, D]``.  With XLA's layout around the
+    call Mistral's chunk held a ``bf16[64,32,8,128]`` broadcast-multiply,
+    its reshape and the read-out's gather a layer: 0.58 of the 1.73 ms of
+    its ``attn`` scope (PERF.md section 6, PR 59)."""
+    import re
+
+    text, _ = _serving_program(chip, cell, "chunk")
+    calls = [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+             and "paged_decode_attention" in ln.split(" = ")[0]]
+    assert calls and all(
+        f" = bf16[{b},{h},128]" in ln for ln in calls), calls[:1]
+    wide = re.findall(
+        rf"\w+\[{b},{h},(?:{kvh * 128}|{kvh},128)\]", text)
+    assert not wide, sorted(set(wide))
+
+
 def test_window_layers_run_the_kernel_at_their_views_width(chip):
     """Trinity's chunk at the cell's shapes holds the paged kernel at BOTH
     table widths: the full layer's rows are T = 392 entries (98 trips at

@@ -132,6 +132,9 @@ def test_kernel_reads_the_pool_as_it_lies():
         (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
         payloads = [jaxpr.invars[i] for i in (1, 2)]
         assert [v for v in call.invars if v in payloads] == payloads, variant
+        if "hb" in variant:  # q too: the kernel lays out its own (PR 59)
+            assert jaxpr.invars[0] in call.invars
+            assert call.outvars[0].aval.shape == (b, h, d)
         assert [v.aval.shape for v in call.invars].count((b, t * bs, kvh)) == 2
 
 

@@ -49,12 +49,13 @@ def _fresh_autotuner():
 
 
 def _paged_problem(b=2, kvh=2, n_rep=2, d=8, bs=4, t=4, quant=False,
-                   seed=0, all_invalid=False, tail=True):
-    """Deterministic paged decode problem + its jnp reference."""
+                   seed=0, all_invalid=False, tail=True, dtype=jnp.float32):
+    """Deterministic paged decode problem + its jnp reference (``dtype``:
+    q's and a dense pool's; an int8 pool's scales stay float32)."""
     rng = np.random.default_rng(seed)
     h = kvh * n_rep
     nb_pool = t + 2
-    q = jnp.asarray(rng.normal(size=(b, h, d)).astype(np.float32))
+    q = jnp.asarray(rng.normal(size=(b, h, d)).astype(np.float32), dtype=dtype)
     kf = rng.normal(size=(nb_pool, bs, kvh, d)).astype(np.float32)
     vf = rng.normal(size=(nb_pool, bs, kvh, d)).astype(np.float32)
     table = np.stack(
@@ -76,10 +77,11 @@ def _paged_problem(b=2, kvh=2, n_rep=2, d=8, bs=4, t=4, quant=False,
         vs = jnp.asarray(vsf.astype(np.float32))
 
     def pool(x):  # the pool's layout: [NB, BS, C], token dims merged
-        return jnp.asarray(x.reshape(nb_pool, bs, -1))
+        x = x.reshape(nb_pool, bs, -1)
+        return jnp.asarray(x, dtype=dtype if x.dtype == np.float32 else None)
 
     if quant:
-        ks, vs = pool(ks), pool(vs)
+        ks, vs = (jnp.asarray(x).reshape(nb_pool, bs, -1) for x in (ks, vs))
     args = (q, pool(kf), pool(vf), jnp.asarray(table), jnp.asarray(valid))
     ref = paged_attention_ref(*args, bs, k_scale=ks, v_scale=vs)
     return args, ks, vs, ref
@@ -150,13 +152,15 @@ _RAGGED = {"one_block": (0, 3), "part_tail": (0, 14), "full": (0, 64),
 _RAGGED_T, _RAGGED_BS = 16, 4
 
 
-def _ragged_problem(quant: bool, t: int = _RAGGED_T, rows=_RAGGED, seed=5):
+def _ragged_problem(quant: bool, t: int = _RAGGED_T, rows=_RAGGED, seed=5,
+                    **shape):
     """``(args, ks, vs, live)``: the rows of ``rows`` over a pool of
     ``64`` blocks, in a table ``t`` entries wide; ``live`` marks the rows
-    that hold a key.  The same rows get the same blocks at every ``t``."""
+    that hold a key.  The same rows get the same blocks at every ``t``.
+    ``shape``: ``_paged_problem``'s ``kvh`` / ``n_rep`` / ``d`` / ``dtype``."""
     bs, nb = _RAGGED_BS, 64
     base, ks, vs, _ = _paged_problem(
-        b=len(rows), t=nb - 2, bs=bs, quant=quant, seed=seed
+        b=len(rows), t=nb - 2, bs=bs, quant=quant, seed=seed, **shape
     )
     q, kp, vp = base[0], base[1], base[2]
     rng = np.random.default_rng(seed)
@@ -263,6 +267,158 @@ def test_slab_decode_variants_match_reference():
     )
 
 
+# The head-batched kernels lay out their own q (PR 59): q and the output
+# cross as [B, H, D], the block-diagonal operand is built and its diagonal
+# read out in VMEM (``block_diagonal_q`` / ``diagonal_out``).  Head layouts:
+# Mistral's, OLMoE's (MHA: a row a group), a wide repeat, half-tile heads
+# (the default Llama's D = 64) and Jamba's one KV head (the operand IS q).
+_HB_SHAPES = [(8, 4, 128), (16, 1, 128), (2, 16, 128), (4, 8, 64), (1, 20, 128)]
+#: (id, q's dtype, int8 pool, the variant's flags)
+_HB_MODES = [
+    ("f32", jnp.float32, False, ""), ("bf16", jnp.bfloat16, False, ""),
+    ("bf16-nat", jnp.bfloat16, False, "-nat"),
+    ("f32-int8", jnp.float32, True, ""), ("f32-int8-fs", jnp.float32, True, "-fs"),
+    ("bf16-int8", jnp.bfloat16, True, ""),
+    ("bf16-int8-fs", jnp.bfloat16, True, "-fs"),
+]
+
+
+def _hb_cases(folds):
+    return [
+        pytest.param(shape, mode, k, id=f"G{shape[0]}R{shape[1]}D{shape[2]}-"
+                                        f"{mode[0]}-b{k}")
+        for shape in _HB_SHAPES for mode in _HB_MODES for k in folds
+    ]
+
+
+def _hb_tol(dtype):
+    """(against the f32 reference, against the other layout of the same
+    fold): bf16 outputs differ by a rounding of the last bit at most."""
+    if dtype == jnp.bfloat16:
+        return dict(rtol=3e-2, atol=3e-2), dict(rtol=1e-2, atol=1e-2)
+    return dict(rtol=1e-5, atol=2e-5), dict(rtol=1e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("kvh,n_rep,d", _HB_SHAPES)
+def test_the_two_layout_helpers_element_for_element(kvh, n_rep, d):
+    """``block_diagonal_q`` is the operand XLA used to build around the
+    call — head h's vector in its group's lane slice, zeros elsewhere —
+    and ``diagonal_out`` takes each head's ``[D]`` back out of its group's
+    slice, bit for bit (selects only: no arithmetic touches a value)."""
+    from mlmicroservicetemplate_tpu.ops.paged_attention import (
+        block_diagonal_q,
+        diagonal_out,
+    )
+
+    h = kvh * n_rep
+    rng = np.random.default_rng(kvh)
+    q = rng.normal(size=(h, d)).astype(np.float32)
+    want = np.zeros((h, kvh, d), np.float32)
+    want[np.arange(h), np.arange(h) // n_rep] = q
+    got = np.asarray(block_diagonal_q(jnp.asarray(q, jnp.bfloat16), kvh, n_rep))
+    np.testing.assert_array_equal(
+        got, np.asarray(jnp.asarray(want.reshape(h, kvh * d), jnp.bfloat16)))
+    acc = rng.normal(size=(h, kvh, d)).astype(np.float32)  # off-diagonal: garbage
+    acc[0, 0, 0] = -0.0
+    out = np.asarray(diagonal_out(jnp.asarray(acc.reshape(h, kvh * d)), kvh, n_rep))
+    np.testing.assert_array_equal(out, acc[np.arange(h), np.arange(h) // n_rep])
+    assert np.signbit(out[0, 0])
+
+
+@pytest.mark.parametrize("shape,mode,fold", _hb_cases((1, 4)))
+def test_head_batched_paged_kernel_lays_out_its_own_q(shape, mode, fold):
+    """Every ``-hb`` variant on ragged rows — a whole table, a part-filled
+    tail, a window view's dead head, a freed slot, no key at all — reads
+    the reference's answer and the per-group variant's of the same fold;
+    a row with no live key reads zeros."""
+    kvh, n_rep, d = shape
+    _, dtype, quant, flags = mode
+    args, ks, vs, live = _ragged_problem(
+        quant, kvh=kvh, n_rep=n_rep, d=d, dtype=dtype)
+    ref = np.asarray(paged_attention_ref(
+        *args, _RAGGED_BS, k_scale=ks, v_scale=vs), np.float32)
+
+    def run(vkey):
+        return np.asarray(paged_decode_attention(
+            *args, _RAGGED_BS, k_scale=ks, v_scale=vs, interpret=True,
+            variant=vkey), np.float32)
+
+    got = run(f"b{fold}-hb{flags}")
+    assert got.shape == (len(live), kvh * n_rep, d)
+    to_ref, to_twin = _hb_tol(dtype)
+    np.testing.assert_allclose(got[live], ref[live], **to_ref)
+    np.testing.assert_allclose(got, run(f"b{fold}{flags}"), **to_twin)
+    assert (got[~live] == 0).all()
+
+
+@pytest.mark.parametrize("shape,mode,fold", _hb_cases((1,)))
+def test_head_batched_slab_kernel_lays_out_its_own_q(shape, mode, fold):
+    """The whole-slab kernel takes the same two helpers: every ``-hb``
+    variant against the jnp reference and the per-group variant, a row
+    with a dead head among the rows."""
+    kvh, n_rep, d = shape
+    _, dtype, quant, flags = mode
+    t = 24
+    (q, k, v, _, ks, vs), _ = autotune._probe(  # the sweep's own slab problem
+        "decode", b=3, kvh=kvh, n_rep=n_rep, d=d, bs=t, t=t,
+        dtype=jnp.dtype(dtype).name, quant=quant, seed=11)
+    mask = np.ones((3, t), np.int32)
+    mask[0, -5:] = 0
+    mask[1, :9] = 0  # a window view: dead keys at the head
+    mask[1, 20:] = 0
+    mask = jnp.asarray(mask)
+    ref = np.asarray(autotune._slab_ref(q, k, v, mask, ks, vs), np.float32)
+
+    def run(vkey):
+        return np.asarray(decode_attention(
+            q, k, v, mask, ks, vs, interpret=True, variant=vkey), np.float32)
+
+    got = run(f"b{fold}-hb{flags}")
+    to_ref, to_twin = _hb_tol(dtype)
+    np.testing.assert_allclose(got, ref, **to_ref)
+    np.testing.assert_allclose(got, run(f"b{fold}{flags}"), **to_twin)
+
+
+#: The latent kernel's Mosaic module at DeepSeek-V2's serving shapes
+#: (32 rows x 128 heads x 640 lanes, 392 table entries), a digest a
+#: variant, taken on PR 58's tree: ``tools.lowered_text.kernel_texts``
+#: prints a module without locations, so the digest is of the program
+#: and not of the lines its source stood on.
+_LATENT_KERNEL_DIGESTS = {
+    "b8": "ecd03b47ef0646ace157c7996a69de6461eb0bb4dc0a66f9c35d580e148df9fc",
+    "b8-nat": "b4ce8c460b723ea74a6b820b0c2415a59d5d4bd09f8f8980f49453e59cc0c893",
+    "b56-nat": "c6cf1e8c08b5d0f718943ef6cac6d8bd718febcd27fce94bd2c21a394c81fc9e",
+}
+
+
+@pytest.mark.parametrize("vkey", list(_LATENT_KERNEL_DIGESTS))
+def test_the_latent_kernel_lowers_as_it_did(vkey):
+    """One KV head: the block-diagonal operand IS q, so the kernel body
+    that builds it elsewhere (PR 59) must leave the latent kernel the
+    program it was — no scratch, no mask, no read-out."""
+    import jax
+
+    from mlmicroservicetemplate_tpu.ops.paged_attention import (
+        latent_decode_attention,
+    )
+    from tools.lowered_text import digest, kernel_texts
+
+    b, h, c, v, bs, t = 32, 128, 640, 512, 16, 392
+    shape = jax.ShapeDtypeStruct
+    with jax.default_matmul_precision(None):  # serving's, not conftest's
+        lowered = jax.jit(
+            lambda q, pool, tbl, valid: latent_decode_attention(
+                q, pool, tbl, valid, bs, v, 0.1, variant=vkey)
+        ).trace(
+            shape((b, h, c), jnp.bfloat16),
+            shape((b * t, bs, c), jnp.bfloat16),
+            shape((b, t), jnp.int32), shape((b, t * bs), jnp.int32),
+        ).lower(lowering_platforms=("tpu",)).as_text()
+    (kernel,) = kernel_texts(lowered)
+    assert "latent_decode_attention" in kernel
+    assert digest(kernel) == _LATENT_KERNEL_DIGESTS[vkey]
+
+
 # ---------------------------------------------------------------------------
 # 2. grammar + cost model
 
@@ -358,10 +514,19 @@ def test_tune_key_is_shape_only():
     same decode shape share one tuning entry (the λScale property)."""
     k = autotune.tune_key("paged_decode", b=2, kvh=2, n_rep=2, d=8,
                           block_size=4, t=4, dtype="float32", quant=False)
-    assert k == "paged_decode/B2-G2-R2-D8-bs4-T4-float32"
+    assert k == "paged_decode.r2/B2-G2-R2-D8-bs4-T4-float32"
     kq = autotune.tune_key("paged_decode", b=2, kvh=2, n_rep=2, d=8,
                            block_size=4, t=4, dtype="float32", quant=True)
     assert kq.endswith("-q8") and kq != k
+    # the slab kind carries the revision too; the latent kernel, which
+    # has not changed, keeps the keys its tables hold
+    assert autotune.tune_key("decode", b=2, kvh=2, n_rep=2, d=8, block_size=0,
+                             t=8, dtype="float32", quant=False
+                             ).startswith("decode.r2/")
+    assert autotune.tune_key(
+        "latent_decode", b=32, kvh=1, n_rep=128, d=640, block_size=16, t=392,
+        dtype="bfloat16", quant=False,
+    ) == "latent_decode/B32-G1-R128-D640-bs16-T392-bfloat16"
 
 
 # ---------------------------------------------------------------------------
@@ -439,6 +604,36 @@ def test_table_persists_across_restart(tmp_path):
     )
     c = autotune.stats()["counts"]
     assert again == winner and c["sweeps"] == 0 and c["hits"] == 1
+
+
+@pytest.mark.parametrize("pin", [None, "b2-hb"], ids=["swept", "pinned"])
+def test_a_winner_timed_on_the_old_kernel_is_not_served(tmp_path, pin):
+    """The table file outlives a checkout's code (the compile-cache
+    directory): an entry under the key the kernel had BEFORE it laid out
+    its own q — timed with XLA's layout around the call — answers nothing
+    now.  The new kernel sweeps once and both entries stay in the file;
+    a pinned ``PALLAS_VARIANT`` is served whatever the file holds."""
+    path = str(tmp_path / "tune.json")
+    new = autotune.tune_key("paged_decode", **_SHAPE, dtype="float32",
+                            quant=False)
+    old = new.replace("paged_decode.r2/", "paged_decode/")
+    assert old != new
+    with open(path, "w") as f:
+        json.dump({"version": 1, "table": {old: "b4-hb"}}, f)
+    got = autotune.ensure_tuned(
+        "paged_decode", _Bundle(), None, **_SHAPE,
+        interpret=True, table_path=path, pin=pin,
+    )
+    c = autotune.stats()["counts"]
+    assert c["hits"] == 0
+    assert autotune.lookup("paged_decode", **_SHAPE, dtype="float32",
+                           quant=False) == got
+    if pin:
+        assert got == pin and c["pins"] == 1 and c["sweeps"] == 0
+        return
+    assert c["sweeps"] == 1
+    table = json.load(open(path))["table"]
+    assert table == {old: "b4-hb", new: got}
 
 
 def test_corrupt_table_is_nonfatal(tmp_path):

@@ -17,15 +17,72 @@ other): a kernel's serialised body carries its source file's name.  Only
 what both sides have is used of the program (``models/llama.py`` functions
 that PR 36 had, and ``zero_ssm`` / ``ssm_rows`` for a configuration with
 recurrent layers, PR 40's).
+
+A line also carries ``kernels``: a digest for each Pallas kernel of the
+step, of its Mosaic module printed WITHOUT locations (``kernel_texts``) —
+equal there = the kernel is the same program even where an edit moved its
+source lines, which the whole text's digest cannot say — and
+``sha256_sans_locations``: the text's digest with every kernel's body
+replaced by that digest (``sans_locations``), equal wherever a PR left a
+step's program as it was and only moved lines of a kernel's file.
 """
 
 from __future__ import annotations
 
 import argparse
+import base64
+import functools
 import hashlib
 import json
 import os
+import re
 import sys
+
+
+_BACKEND_CONFIG = re.compile(r'backend_config = "((?:[^"\\]|\\.)*)"')
+
+
+@functools.lru_cache(maxsize=None)
+def _kernel_text(config: str) -> str | None:
+    """A ``backend_config``'s Mosaic module printed without debug
+    locations; None for another custom call's configuration."""
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+
+    try:
+        body = json.loads(config.replace("\\22", '"').replace("\\5C", "\\"))[
+            "custom_call_config"]["body"]
+    except (ValueError, KeyError, TypeError):
+        return None
+    ctx = mlir.make_ir_context()
+    ctx.allow_unregistered_dialects = True  # the serialised dialect
+    with ctx:
+        module = ir.Module.parse(base64.b64decode(body))
+        return module.operation.get_asm(enable_debug_info=False)
+
+
+def kernel_texts(lowered: str) -> list:
+    """The Mosaic module of every ``tpu_custom_call`` in a lowered text
+    (``.lower(lowering_platforms=("tpu",)).as_text()``), in order, each
+    printed without debug locations: what the kernel IS, whatever file
+    and line its operations came from."""
+    texts = map(_kernel_text, _BACKEND_CONFIG.findall(lowered))
+    return [t for t in texts if t is not None]
+
+
+def sans_locations(lowered: str) -> str:
+    """A lowered text with each kernel's serialised body (which carries
+    its operations' source lines) replaced by the digest of its module
+    printed without them."""
+    def plain(match):
+        text = _kernel_text(match.group(1))
+        return match.group(0) if text is None else f'kernel = "{digest(text)}"'
+
+    return _BACKEND_CONFIG.sub(plain, lowered)
+
+
+def digest(text: str) -> str:
+    return hashlib.sha256(text.encode()).hexdigest()
 
 
 def main(argv=None) -> int:
@@ -130,7 +187,11 @@ def main(argv=None) -> int:
                 with open(os.path.join(a.dump, f"{name}.{step}.mlir"), "w") as f:
                     f.write(text)
             print(json.dumps({"config": name, "step": step, "bytes": len(text),
-                              "sha256": hashlib.sha256(text.encode()).hexdigest()}),
+                              "sha256": digest(text),
+                              "sha256_sans_locations": digest(
+                                  sans_locations(text)),
+                              "kernels": [digest(k)[:16]
+                                          for k in kernel_texts(text)]}),
                   flush=True)
     return 0
 
