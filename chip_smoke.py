@@ -398,12 +398,13 @@ def pool_moves(cdl, bucket: int) -> tuple[list[str], list[str]]:
 
     st = cdl._state
     sizes = {int(x.size) for x in jax.tree.leaves((st.cache_k, st.cache_v))}
-    chunk = cdl.paged_chunk_hlo(compiled=True)
+    progs = cdl.programs
+    chunk = progs.paged_chunk_hlo(st, cdl._table, compiled=True)
     return (
         pool_relayouts(chunk, sizes, in_loop_only=True),
         pool_relayouts(chunk, sizes)
-        + pool_relayouts(cdl.paged_insert_hlo(bucket), sizes)
-        + pool_relayouts(cdl.paged_insert_hlo(bucket, WAVE_ROWS), sizes),
+        + pool_relayouts(progs.paged_insert_hlo(st, bucket), sizes)
+        + pool_relayouts(progs.paged_insert_hlo(st, bucket, WAVE_ROWS), sizes),
     )
 
 
@@ -427,7 +428,8 @@ async def run_streams(svc: Service, prompts: list[str], model: str,
     st = await svc.status()
     dec = st.get("decode", {})
     counts = dec.get("autotune", {})
-    hlo_calls = [cdl.paged_chunk_hlo().count("tpu_custom_call")
+    hlo_calls = [cdl.programs.paged_chunk_hlo(cdl._state, cdl._table)
+                 .count("tpu_custom_call")
                  for cdl in svc.decode_loops()]
     moves = [pool_moves(cdl, bucket) for cdl in svc.decode_loops()]
     mean_batch = (s1 - s0) / max(c1 - c0, 1.0)

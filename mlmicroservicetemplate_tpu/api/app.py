@@ -1549,10 +1549,9 @@ async def handle_status(request: web.Request) -> web.Response:
             "chunk_tokens": engine.chunk_tokens,
             "chunk_dispatches": cdl.chunk_dispatches,
             "tokens_emitted": getattr(cdl, "tokens_emitted", 0),
-            # Double-buffered host prep (HOST_PREP_DOUBLE;
-            # docs/compilation.md): staged plans and how many were
-            # consumed as-is vs rolled back and re-prepped inline.
-            "host_prep_double": getattr(cdl, "host_prep_double", False),
+            # Double-buffered host prep (docs/compilation.md): staged
+            # plans and how many were consumed as-is vs rolled back and
+            # re-prepped inline.
             "prep_staged": getattr(cdl, "prep_staged", 0),
             "prep_hits": getattr(cdl, "prep_hits", 0),
             "prep_misses": getattr(cdl, "prep_misses", 0),

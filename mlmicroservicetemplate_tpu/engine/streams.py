@@ -73,6 +73,8 @@ from typing import Any, AsyncIterator
 import numpy as np
 
 from ..utils import metrics, tracing
+from . import warm
+from .programs import LoopPrograms
 
 log = logging.getLogger(__name__)
 
@@ -110,121 +112,6 @@ def wave_rungs(n_slots: int, multiple: int = 1) -> tuple[int, ...]:
     loop rounds it)."""
     below = {-(-r // multiple) * multiple for r in (1, _SMALL_WAVE_ROWS)}
     return tuple(sorted(r for r in below if r < n_slots)) + (n_slots,)
-
-
-def _ins_row(dst, src, slot, row):
-    """Row ``row`` of ``src`` (a lone or batched prefill state's leaf:
-    a wave prefills as one batch and each row lands in its own slot),
-    zero-padded to the slot shape, written into row ``slot`` of ``dst``.
-    One row only: a full-width dynamic_update_slice would clobber the
-    adjacent live slots."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    src = lax.dynamic_slice_in_dim(src, row, 1, axis=0)
-    pad = [(0, 0)] + [
-        (0, int(d) - int(s)) for d, s in zip(dst.shape[1:], src.shape[1:])
-    ]
-    srcp = jnp.pad(src.astype(dst.dtype), pad)
-    return lax.dynamic_update_slice(
-        dst, srcp, (slot,) + (0,) * (dst.ndim - 1)
-    )
-
-
-def _ins_rows(dst, src, slots):
-    """Every row of ``src`` (a wave's prefill state's leaf), zero-padded
-    to the slot shape, written into row ``slots[i]`` of ``dst``; a row
-    whose slot is out of range drops."""
-    import jax.numpy as jnp
-
-    pad = [(0, 0)] + [
-        (0, int(d) - int(s)) for d, s in zip(dst.shape[1:], src.shape[1:])
-    ]
-    return dst.at[slots].set(jnp.pad(src.astype(dst.dtype), pad), mode="drop")
-
-
-def paged_insert(block_size: int):
-    """The paged slot insert as a function to jit: a wave's rows land in
-    their slots in ONE dispatch.  Row ``i`` of ``single`` (the wave's
-    prefill state, ``Bw`` rows: its rung; a lone start is the rung of 1)
-    scatters its positions [s_lo, s_cut) into the blocks ``table_rows[i]``
-    names (CoW prefix rows [0, s_lo) are the donor's blocks and are never
-    rewritten) — one ``scatter_rows`` a pool over the wave's ``Bw * W``
-    positions — and its per-row fields land in slot ``slots[i]``.  A row
-    that does not insert (a pad row of the rung, a row that finished in
-    its first chunk or was re-queued) carries an all-sentinel table row
-    and an out-of-range slot (and state row): every one of its writes
-    drops."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..models.gpt import PagedState
-    from ..ops.paged_attention import scatter_rows
-
-    def insert(batched, single, table_rows, slots, s_lo: int, s_cut: int,
-               ssm_rows=None):
-        # One destination a position of the wave, for every pool (one
-        # pool geometry serves all layers): out of range where sentinel.
-        p = s_lo + jnp.arange(s_cut - s_lo)
-        blk = jnp.take(  # [Bw, W]
-            table_rows, p // block_size, axis=1, mode="fill",
-            fill_value=jax.tree.leaves(batched.cache_k)[0].shape[0],
-        )
-        dest = (blk * block_size + p % block_size).reshape(-1)
-
-        def scat(pool, src):
-            vals = src[:, s_lo:s_cut]
-            return scatter_rows(
-                pool, dest, vals.reshape((-1,) + vals.shape[2:])
-            )
-
-        def scat_entry(pc, sc):
-            if isinstance(pc, tuple):
-                return (scat(pc[0], sc[0]), scat(pc[1], sc[1]))
-            return scat(pc, sc)
-
-        def rows(d, s):
-            return _ins_rows(d, s, slots)
-
-        return PagedState(
-            cache_k=[
-                scat_entry(d, s)
-                for d, s in zip(batched.cache_k, single.cache_k)
-            ],
-            cache_v=[
-                scat_entry(d, s)
-                for d, s in zip(batched.cache_v, single.cache_v)
-            ],
-            key_valid=rows(batched.key_valid, single.key_valid),
-            write_idx=rows(batched.write_idx, single.write_idx),
-            pos=rows(batched.pos, single.pos),
-            last_token=rows(batched.last_token, single.last_token),
-            done=rows(batched.done, single.done),
-            tokens=rows(batched.tokens, single.tokens),
-            sample=jax.tree.map(rows, batched.sample, single.sample),
-            **_insert_ssm(batched, single, slots, ssm_rows),
-        )
-
-    return insert
-
-
-def _insert_ssm(batched, single, slots, ssm_rows) -> dict:
-    """Each wave row's recurrent state into state row ``ssm_rows[i]``
-    (past the last row: dropped — warm-up, a row that does not insert),
-    and its slot pointed at it; nothing for a model without recurrent
-    layers (its ``ssm`` is the empty default)."""
-    if ssm_rows is None:
-        return {"ssm": batched.ssm}
-
-    def put(dst, src):  # [R, ...] <- [Bw, ...]
-        return dst.at[ssm_rows].set(src.astype(dst.dtype), mode="drop")
-
-    b, s = batched.ssm, single.ssm
-    return {"ssm": b._replace(
-        conv=[put(d, x) for d, x in zip(b.conv, s.conv)],
-        state=[put(d, x) for d, x in zip(b.state, s.state)],
-        row=b.row.at[slots].set(ssm_rows, mode="drop"),
-    )}
 
 
 class StreamClosedError(Exception):
@@ -487,11 +374,6 @@ class ContinuousDecodeLoop:
             self._pacer = PrefillPacer(
                 weight=int(getattr(cfg, "class_weight", 4))
             )
-            self._prefill_jit = None
-            self._paged_prefill_jit = None
-            self._empty_state_jit = None
-            self._seed_prefix_fns: dict[int, Any] = {}
-            self._paged_handoff = None
         # Slot count must divide over the replica mesh's batch axis.
         mult = engine.replicas.pad_multiple()
         self.n_slots = -(-self.max_streams // mult) * mult
@@ -568,23 +450,23 @@ class ContinuousDecodeLoop:
             self._table = np.full(
                 (self.n_slots, self.nb_max), self.pool.num_blocks, np.int32
             )
-            self._paged_chunk = None
-            self._paged_insert = None
-            self._gather_prefix_fns: dict[int, Any] = {}
             self._kv_tails: list[tuple] = []  # set with the pools
             self._dispatched_steps: dict[int, int] = {}
-            if not self.prefill_chunk:
-                self._paged_handoff = None  # swap-resume handoff seam
-            # Host-RAM KV tier (KV_HOST_BUDGET_MB; docs/kv-tiering.md):
-            # checkpointed streams gather the blocks behind their
-            # resume prompt device→host instead of freeing-and-
-            # recomputing, and resume by prefetching them back —
-            # KV_PREFETCH_BLOCKS per iteration while decode is live,
-            # unbounded on idle — through the same interleave seam as
-            # chunked prefill.  The tier object lives on the ENGINE
-            # (it survives reset_device_state; a fleet shares one).
-            self._swap_gather_jit = None
-            self._swap_scatter_jit = None
+        # The loop's executables (engine/programs.py), each built at its
+        # first use; warm-up (engine/warm.py) walks them before serving.
+        self.programs = LoopPrograms(
+            engine, n_slots=self.n_slots, spec=self.spec,
+            block_size=getattr(self, "block_size", 0),
+            nb_max=getattr(self, "nb_max", 0),
+            state_rows=self._ssm_free is not None, params_for=self._mp,
+        )
+        # Host-RAM KV tier (KV_HOST_BUDGET_MB; docs/kv-tiering.md):
+        # checkpointed streams gather the blocks behind their resume
+        # prompt device→host instead of freeing-and-recomputing, and
+        # resume by prefetching them back — KV_PREFETCH_BLOCKS per
+        # iteration while decode is live, unbounded on idle — through the
+        # same interleave seam as chunked prefill.  The tier object lives
+        # on the ENGINE (it survives reset_device_state; a fleet shares one).
         # Swap-resume jobs + swap-out copies pending materialization
         # (exist in contiguous mode too so the shared loop code never
         # branches on their presence; only paged loops populate them).
@@ -602,32 +484,14 @@ class ContinuousDecodeLoop:
         self.prefetch_blocks_total = 0
         self.prefetch_blocks_live = 0
         self.host_prefix_promotes = 0
-        # Double-buffered host prep (HOST_PREP_DOUBLE, default on;
-        # docs/compilation.md): with chunk N in flight, iteration
-        # N+1's host-side dispatch prep — the paged growth pass (block
-        # grants + table assembly) and the table's host→device upload
-        # — is STAGED immediately after N's dispatch, overlapping N's
-        # device compute and its RTT-long fetch instead of serializing
-        # between dispatches.  The staged plan is consumed at the next
-        # dispatch only if the loop state it derived from is
-        # bit-identical (same tenants, same dispatched-step cursors,
-        # same table bytes); anything that moved rolls the staged
-        # grants back and re-preps inline — so the dispatched table is
-        # identical either way and token identity is structural, not
-        # probabilistic.  Contiguous mode has no growth/table prep to
-        # stage; the knob is a no-op there.
-        self.host_prep_double = bool(
-            getattr(cfg, "host_prep_double", True)
-        )
+        # Double-buffered host prep (``_stage_host_prep``;
+        # docs/compilation.md): iteration N+1's paged growth pass and
+        # table upload, staged while chunk N is in flight and consumed
+        # only if nothing it derived from has moved since.
         self._staged_prep: dict | None = None
         self.prep_staged = 0
         self.prep_hits = 0
         self.prep_misses = 0
-        # Active Pallas decode-kernel variant ("" = default kernel).
-        # Resolved once at warm time (_autotune_kernel) BEFORE the
-        # paged executables trace; also the statics entry that keys
-        # those executables in the shared cache (docs/kernel_tuning.md).
-        self.kernel_variant = ""
         self.tokens_emitted = 0
         # SLA scheduling (scheduler/policy.py): the old unbounded
         # handoff Queue + instant reject past max_streams is now a
@@ -664,7 +528,6 @@ class ContinuousDecodeLoop:
         self.sampled_slots: set[int] = set()
         self.free: list[int] = list(range(self.n_slots))
         self._state = None  # batched decode state (device), loop-thread-owned
-        self._insert = None
         # Depth-D decode pipelining: the state chain is pure
         # device-side, so up to ``chain_depth`` chunk dispatches ride
         # in flight before the oldest is fetched — steady-state
@@ -2623,7 +2486,7 @@ class ContinuousDecodeLoop:
             try:
                 with eng._lock:
                     self._state = eng.dispatch_guard(
-                        "insert", lambda: self._paged_insert_fn()(
+                        "insert", lambda: self.programs.paged_insert_fn()(
                             self._state, state1, table_rows, slots,
                             s_lo, s_cut, *ssm_arg,
                         ),
@@ -2738,7 +2601,7 @@ class ContinuousDecodeLoop:
                     if self.spec:
                         hist_row = self._hist_row(st.feats, toks_np[row])
                         self._state = eng.dispatch_guard(
-                            "insert", lambda: self._insert_fn()(
+                            "insert", lambda: self.programs.insert_fn()(
                                 self._state, state1, ids, mask, hist_row,
                                 np.int32(slot), np.int32(row),
                             ),
@@ -2746,7 +2609,7 @@ class ContinuousDecodeLoop:
                         )
                     else:
                         self._state = eng.dispatch_guard(
-                            "insert", lambda: self._insert_fn()(
+                            "insert", lambda: self.programs.insert_fn()(
                                 self._state, state1, np.int32(slot),
                                 np.int32(row),
                             ),
@@ -2773,124 +2636,6 @@ class ContinuousDecodeLoop:
         """Prompt tokens admitted but not yet prefilled (observability;
         read from other threads as a snapshot)."""
         return sum(max(0, j.L - j.consumed) for j in list(self._prefilling))
-
-    def _shared_jit(self, kind: str, build, statics: tuple = ()):
-        """Loop-owned executables route through the engine's
-        process-level ExecutableCache too (runtime/compile_cache.py):
-        every replica's loop shares one wrapper per (bundle, kind,
-        statics, placement), so a spawned replica's warm() re-traces
-        nothing.  Duck-typed test engines without the helper keep
-        private wrappers."""
-        shared = getattr(self.engine, "_shared_jit", None)
-        if shared is None:
-            return build()
-        return shared(kind, build, statics)
-
-    def _prefill_fn(self):
-        if self._prefill_jit is None:
-            import jax
-
-            self._prefill_jit = self._shared_jit(
-                "prefill_chunk",
-                lambda: jax.jit(self.engine.bundle.prefill_chunk_fn,
-                                donate_argnums=(1,)),
-            )
-        return self._prefill_jit
-
-    def _paged_prefill_fn(self):
-        if self._paged_prefill_jit is None:
-            import jax
-
-            self._paged_prefill_jit = self._shared_jit(
-                "paged_prefill_chunk",
-                lambda: jax.jit(self.engine.bundle.paged_prefill_chunk_fn,
-                                donate_argnums=(1,)),
-            )
-        return self._paged_prefill_jit
-
-    def _empty_prefill_fn(self):
-        if self._empty_state_jit is None:
-            import jax
-
-            self._empty_state_jit = self._shared_jit(
-                "empty_state",
-                lambda: jax.jit(self.engine.bundle.empty_state_fn,
-                                static_argnums=(1, 2, 3)),
-            )
-        return self._empty_state_jit
-
-    def _seed_prefix_state(self, state, pkv, p_len: int):
-        """Copy a contiguous prefix-cache hit's KV into rows [0, p_len)
-        of a fresh chunked-prefill state and mark them valid — the
-        chunked counterpart of ``_start_prefixed``'s cache seeding; the
-        suffix then prefills window by window from position p_len."""
-        if p_len not in self._seed_prefix_fns:
-            import jax
-
-            def seed(st, pk):
-                def put(c, e):
-                    if isinstance(c, tuple):  # (int8 payload, scale)
-                        return tuple(
-                            ci.at[:, :p_len].set(ei.astype(ci.dtype))
-                            for ci, ei in zip(c, e)
-                        )
-                    return c.at[:, :p_len].set(e.astype(c.dtype))
-
-                return st._replace(
-                    cache_k=[put(c, e) for c, e in zip(st.cache_k, pk["k"])],
-                    cache_v=[put(c, e) for c, e in zip(st.cache_v, pk["v"])],
-                    key_valid=st.key_valid.at[:, :p_len].set(1),
-                )
-
-            self._seed_prefix_fns[p_len] = self._shared_jit(
-                "seed_prefix", lambda: jax.jit(seed, donate_argnums=(0,)),
-                statics=(p_len,),
-            )
-        return self._seed_prefix_fns[p_len](state, pkv)
-
-    def _paged_handoff_fn(self):
-        """Paged handoff: the stream's KV already lives in its blocks
-        (the windows wrote it), so going live is pure row-field
-        surgery — key_valid/write_idx/pos/last_token/done/tokens/sample
-        of one slot row."""
-        if self._paged_handoff is None:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
-
-            def ins_row(dst, src, slot):
-                pad = [(0, 0)] + [
-                    (0, int(d) - int(s))
-                    for d, s in zip(dst.shape[1:], src.shape[1:])
-                ]
-                srcp = jnp.pad(src.astype(dst.dtype), pad)
-                start = (slot,) + (0,) * (dst.ndim - 1)
-                return lax.dynamic_update_slice(dst, srcp, start)
-
-            def handoff(batched, kv_row, w_idx, pos, last, done, toks, sp,
-                        slot, ssm_row=None):
-                if ssm_row is not None:
-                    # The state is the stream's already (its windows wrote
-                    # its row): the slot is pointed at it, nothing moves.
-                    batched = batched._replace(ssm=batched.ssm._replace(
-                        row=batched.ssm.row.at[slot].set(ssm_row)))
-                return batched._replace(
-                    key_valid=ins_row(batched.key_valid, kv_row, slot),
-                    write_idx=ins_row(batched.write_idx, w_idx, slot),
-                    pos=ins_row(batched.pos, pos, slot),
-                    last_token=ins_row(batched.last_token, last, slot),
-                    done=ins_row(batched.done, done, slot),
-                    tokens=ins_row(batched.tokens, toks, slot),
-                    sample=jax.tree.map(
-                        lambda d, s: ins_row(d, s, slot), batched.sample, sp
-                    ),
-                )
-
-            self._paged_handoff = self._shared_jit(
-                "paged_handoff",
-                lambda: jax.jit(handoff, donate_argnums=(0,)),
-            )
-        return self._paged_handoff
 
     def _chunked_prefix_usable(self, L: int):
         """Static-shape guard for prefix-cache hits on the CHUNKED
@@ -3050,13 +2795,13 @@ class ContinuousDecodeLoop:
                 job.s_total = p_len + s_suf
                 with eng._lock:
                     # graftlint: unguarded(detached empty-state template build — no stream tokens flow; failures classify via the caller's _fail_streams, and guarding would renumber the pinned prefill_chunk schedules)
-                    job.state = self._empty_prefill_fn()(
+                    job.state = self.programs.empty_prefill_fn()(
                         self._mp(rows=[st.adapter_slot]), 1, job.s_total,
                         eng.max_decode_len,
                     )
                     if p_len:
-                        job.state = self._seed_prefix_state(
-                            job.state, pkv, p_len
+                        job.state = self.programs.seed_prefix_fn(p_len)(
+                            job.state, pkv
                         )
         except Exception as e:
             self._drop_job_resources(job)
@@ -3180,7 +2925,7 @@ class ContinuousDecodeLoop:
                 with eng._lock:
                     out = eng.dispatch_guard(
                         "prefill_chunk",
-                        lambda: self._paged_prefill_fn()(
+                        lambda: self.programs.paged_prefill_fn()(
                             jparams, self._state, jnp.asarray(tables),
                             ids_w, mask_w, starts,
                             *self._ssm_window_args(rows, jobs, ends),
@@ -3203,7 +2948,7 @@ class ContinuousDecodeLoop:
                 with eng._lock:
                     job.state = eng.dispatch_guard(
                         "prefill_chunk",
-                        lambda: self._prefill_fn()(
+                        lambda: self.programs.prefill_fn()(
                             jparams, job.state, ids_w, mask_w, starts[0]
                         ),
                         donates=job.state,
@@ -3260,7 +3005,7 @@ class ContinuousDecodeLoop:
                     # schedules chaos tests pin.
                     self._state = eng.dispatch_guard(
                         "handoff",
-                        lambda: self._paged_handoff_fn()(
+                        lambda: self.programs.paged_handoff_fn()(
                             self._state, kv_row, w_idx, zero, last,
                             not_done, toks_row, sp, np.int32(slot),
                             *self._ssm_row_arg(st),
@@ -3281,7 +3026,7 @@ class ContinuousDecodeLoop:
                 with eng._lock:
                     self._state = eng.dispatch_guard(
                         "handoff",
-                        lambda: self._insert_fn()(
+                        lambda: self.programs.insert_fn()(
                             self._state, final, np.int32(slot), np.int32(0)
                         ),
                         donates=self._state,
@@ -3438,70 +3183,6 @@ class ContinuousDecodeLoop:
             )
         return advanced
 
-    def _warm_prefill(self) -> None:
-        """Compile the chunked-prefill executables off the request
-        path: the empty-state builder + window forward per bucket
-        width (contiguous) or the pool-writing window at both batch
-        widths a dispatch can have + row handoff (paged).  Long prompts
-        past the bucket list still compile their width on first
-        admission (contiguous) — the documented cost of lifting the
-        prompt ceiling."""
-        import jax.numpy as jnp
-
-        eng = self.engine
-        c = self.prefill_chunk
-        ids_w = np.ones((1, c), np.int32)
-        mask_w = np.ones((1, c), np.int32)
-        if self.paged:
-            from .kv_blocks import OutOfBlocks, StreamBlocks
-
-            sb = StreamBlocks(self.pool, self.block_size)
-            try:
-                sb.ensure(c)
-            except OutOfBlocks:
-                return
-            table_row = np.full(self.nb_max, self.pool.num_blocks, np.int32)
-            table_row[: len(sb.ids)] = sb.ids
-            try:
-                sp, _ = eng._collate_sample(
-                    [{"input_ids": ids_w[0], "length": np.int32(c)}], 1
-                )
-                with eng._lock:
-                    # The two widths a dispatch has (a window alone, and
-                    # what a boundary's budget admits), so no window
-                    # compiles while serving; the rows write the same
-                    # warm blocks, which is harmless here.
-                    for b in sorted({1, self._prefill_width}):
-                        out = self._paged_prefill_fn()(
-                            self._mp(n=b), self._state,
-                            jnp.asarray(np.tile(table_row, (b, 1))),
-                            np.tile(ids_w, (b, 1)), np.tile(mask_w, (b, 1)),
-                            np.zeros(b, np.int32), *self._ssm_window_args(b),
-                        )
-                        # (state, counts) from a chip's share of the experts.
-                        self._state = out[0] if type(out) is tuple else out
-                    self._state = self._paged_handoff_fn()(
-                        self._state,
-                        np.zeros((1, self.nb_max * self.block_size), np.int32),
-                        np.zeros(1, np.int32), np.zeros(1, np.int32),
-                        np.zeros(1, np.int32), np.ones(1, bool),
-                        np.zeros((1, eng.max_decode_len), np.int32),
-                        sp, np.int32(0), *self._ssm_row_arg(),
-                    )
-            finally:
-                sb.release()
-            return
-        for s in eng.seq_buckets:
-            if not eng.chunked_prefill_applies(s):
-                continue
-            with eng._lock:
-                st1 = self._empty_prefill_fn()(
-                    self._mp(n=1), 1, s, eng.max_decode_len
-                )
-                self._prefill_fn()(
-                    self._mp(n=1), st1, ids_w, mask_w, np.int32(0)
-                )
-
     def _build_empty_state(self) -> None:
         """All-slots-done decode state from a max-bucket prefill
         template (shapes/dtypes only; every row starts dead).  Spec
@@ -3522,19 +3203,15 @@ class ContinuousDecodeLoop:
         s_max = max(eng.seq_buckets) if self.paged else self.max_prompt
         feats = {"input_ids": np.ones(s_max, np.int32), "length": np.int32(s_max)}
         with eng._lock:
-            ids, mask, _ = eng._collate_text([feats])
-            sp, _ = eng._collate_sample([feats], ids.shape[0])
-            ids, mask = eng.replicas.place_batch(ids, mask)
+            ids, mask, sp = self.programs.placed_batch([feats])
             # graftlint: unguarded(all-dead template build carries no stream data; it rebuilds at recovery, where guarding would renumber every deterministic FAULT_SPEC schedule the chaos suites pin)
             template, _ = eng._start(
                 self._mp(n=int(ids.shape[0])), ids, mask, sp,
                 eng.max_decode_len, eng.chunk_tokens, False,
             )
             if self.spec:
-                template = self._shared_jit(
-                    "init_spec_template",
-                    lambda: jax.jit(eng.bundle.init_spec_fn),
-                )(template, ids, mask)
+                template = self.programs.init_spec_template_fn()(
+                    template, ids, mask)
         if self.paged:
             self._build_empty_paged(template)
             return
@@ -3738,155 +3415,6 @@ class ContinuousDecodeLoop:
             row[0, base : base + min(chunk.size, room)] = chunk[:room]
         return row
 
-    def _insert_fn(self):
-        if self._insert is None:
-            import jax
-            import jax.numpy as jnp
-            from jax import lax
-
-            if self.spec:
-                bundle = self.engine.bundle
-
-                def insert_spec(batched, single, ids, mask, hist_row,
-                                slot, row):
-                    # The family's init_spec_fn recasts the prefill
-                    # state to the spec base (adds key_valid/write_idx
-                    # for T5; identity for decoder-only).  Its device-
-                    # built history is DISCARDED: per-bucket widths and
-                    # the encoder-decoder layout offset don't pad to
-                    # the slot shape — the host-built ``hist_row``
-                    # already has the slot's exact layout.
-                    ss = bundle.init_spec_fn(single, ids, mask)
-                    base = jax.tree.map(
-                        lambda d, s: _ins_row(d, s, slot, row),
-                        batched.base, ss.base,
-                    )
-                    hist = lax.dynamic_update_slice(
-                        batched.history, hist_row.astype(jnp.int32),
-                        (slot, 0),
-                    )
-                    return type(batched)(base=base, history=hist)
-
-                self._insert = self._shared_jit(
-                    "insert_spec", lambda: jax.jit(
-                        tracing.scoped("slot_insert", insert_spec),
-                        donate_argnums=(0,),
-                    )
-                )
-            else:
-                def insert(batched, single, slot, row):
-                    return jax.tree.map(
-                        lambda d, s: _ins_row(d, s, slot, row),
-                        batched, single,
-                    )
-
-                # The batched state is donated (the module docstring's
-                # rule): one row is written in place.  In-flight chunks
-                # hold outputs of their own (toks, done), never a leaf
-                # of the pre-insert state.  ``single`` is a wave's
-                # prefill state, read by every row's insert: not donated.
-                self._insert = self._shared_jit(
-                    "insert", lambda: jax.jit(
-                        tracing.scoped("slot_insert", insert),
-                        donate_argnums=(0,),
-                    )
-                )
-        return self._insert
-
-    # -- paged executables ---------------------------------------------
-
-    def _paged_chunk_fn(self):
-        if self._paged_chunk is None:
-            import jax
-
-            from .engine import chunk_with_done
-
-            self._paged_chunk = self._shared_jit(
-                "paged_chunk",
-                lambda: jax.jit(
-                    tracing.scoped(
-                        "decode_chunk",
-                        chunk_with_done(self.engine.bundle.paged_chunk_fn),
-                    ),
-                    static_argnums=(3, 4), donate_argnums=(1,),
-                ),
-                # The traced program embeds the tuned kernel variant
-                # (resolved at trace time via ops/autotune.lookup) —
-                # replicas tuned differently must not share a wrapper.
-                statics=(self.kernel_variant,),
-            )
-        return self._paged_chunk
-
-    def paged_chunk_hlo(self, debug_info: bool = False,
-                        compiled: bool = False) -> str:
-        """Lowered text of the paged decode chunk at this loop's
-        serving shapes — the program the chunk dispatches run.  What
-        ``chip_smoke.py`` reads to show which attention path is in the
-        step: the Pallas kernel lowers to a ``tpu_custom_call``, the
-        ``gather_pages`` path to none.  ``debug_info`` adds each
-        operation's location, which carries its ``named_scope`` path;
-        ``compiled`` gives the backend's optimised text instead (layouts
-        assigned: where a pool-sized relayout would show)."""
-        import jax.numpy as jnp
-
-        with self.engine._lock:
-            lowered = self._paged_chunk_fn().lower(
-                self._mp(n=self.n_slots), self._state,
-                jnp.asarray(self._table), self.engine.chunk_tokens, False,
-            )
-            if compiled:
-                return lowered.compile().as_text()
-            return lowered.as_text(debug_info=debug_info)
-
-    def paged_insert_hlo(self, s: int, rows: int = 1) -> str:
-        """The backend's optimised text of the insert of a ``rows``-row
-        wave of a ``s``-token bucket (1: a lone prefill) into this
-        loop's state — the chunk's twin for ``chip_smoke.py``: with the
-        state donated no pool is copied in it either."""
-        with self.engine._lock:
-            state1 = self._warm_wave(s, rows)[0]
-            return self._paged_insert_fn().lower(
-                *self._warm_insert_args(state1, s)
-            ).compile().as_text()
-
-    def _warm_insert_args(self, state1, s: int, ids=()) -> tuple:
-        """The arguments of a warm-up insert of ``state1``, a wave of
-        bucket ``s``: row 0 into slot 0 and the blocks ``ids``, every
-        other row — and every row's recurrent state — dropped."""
-        rows = int(state1.done.shape[0])
-        table_rows = np.full(
-            (rows, self.nb_max), self.pool.num_blocks, np.int32
-        )
-        table_rows[0, : len(ids)] = ids
-        past = np.full(rows, self.n_slots, np.int32)
-        slots = past.copy()
-        slots[0] = 0
-        ssm = () if self._ssm_free is None else (past,)
-        return (self._state, state1, table_rows, slots,
-                0, s + self.engine.chunk_tokens, *ssm)
-
-    def _paged_insert_fn(self):
-        """Paged wave insert (``paged_insert``): one executable per
-        wave rung and static (s_lo, s_cut) pair — the (prefix bucket,
-        suffix bucket) grid, like the prefixed starts.  The batched
-        state is donated (the module docstring's rule): the scatters
-        write the streams' blocks in place; ``single``, the wave's
-        prefill state, is not: no leaf of it has an output's shape to
-        alias, and it dies with the wave's ``started`` entries."""
-        if self._paged_insert is None:
-            import jax
-
-            bs = self.block_size
-            self._paged_insert = self._shared_jit(
-                "paged_insert",
-                lambda: jax.jit(
-                    tracing.scoped("slot_insert", paged_insert(bs)),
-                    static_argnums=(4, 5), donate_argnums=(0,),
-                ),
-                statics=(bs,),
-            )
-        return self._paged_insert
-
     def _gather_prefix(self, p_len: int, block_ids) -> Any:
         """Dense ``{"k": [...], "v": [...]}`` view of a pinned prefix's
         blocks, gathered from the CURRENT pools — what the prefixed
@@ -3894,32 +3422,11 @@ class ContinuousDecodeLoop:
         ``eng._lock``; the blocks are write-once (streams never write
         positions below their prefix), so any pool version at or past
         the donor's insert reads the right rows."""
-        import jax
         import jax.numpy as jnp
 
-        from ..ops.paged_attention import gather_pages
-
-        if p_len not in self._gather_prefix_fns:
-            bs = self.block_size
-
-            tails = self._kv_tails
-
-            def gather(state, blocks):
-                pools, treedef = jax.tree.flatten(
-                    (state.cache_k, state.cache_v)
-                )
-                k, v = jax.tree.unflatten(treedef, [
-                    gather_pages(pool, blocks[None], bs, tail)[:, :p_len]
-                    for pool, tail in zip(pools, tails)
-                ])
-                return {"k": k, "v": v}
-
-            self._gather_prefix_fns[p_len] = self._shared_jit(
-                "gather_prefix", lambda: jax.jit(gather),
-                statics=(p_len, bs),
-            )
         blocks = jnp.asarray(np.asarray(block_ids, np.int32))
-        return self._gather_prefix_fns[p_len](self._state, blocks)
+        return self.programs.prefix_rows_fn(p_len, self._kv_tails)(
+            self._state, blocks)
 
     def _donate_paged(self, st: _Stream, slot: int) -> None:
         """Paged prefix donation: pin the slot's prompt blocks by
@@ -4062,47 +3569,6 @@ class ContinuousDecodeLoop:
         leaves = jax.tree.leaves((self._state.cache_k, self._state.cache_v))
         return [(tuple(x.shape[1:]), x.dtype) for x in leaves]
 
-    def _swap_gather_fn(self):
-        """Jitted device-side block gather: pool[ids] per KV leaf.
-        ``ids`` is padded to a power of two (repeating the last id) so
-        the executable grid stays log2(nb_max), not one per length."""
-        if self._swap_gather_jit is None:
-            import jax
-
-            def gather(state, ids):
-                return jax.tree.map(
-                    lambda pool: pool[ids], (state.cache_k, state.cache_v)
-                )
-
-            self._swap_gather_jit = self._shared_jit(
-                "swap_gather", lambda: jax.jit(gather)
-            )
-        return self._swap_gather_jit
-
-    def _swap_scatter_fn(self):
-        """Jitted host→device block write: pool.at[ids].set(vals) per
-        KV leaf.  One executable total — every call is padded to the
-        fixed KV_PREFETCH_BLOCKS chunk width."""
-        if self._swap_scatter_jit is None:
-            import jax
-
-            def scatter(state, ids, vals):
-                flat, treedef = jax.tree.flatten(
-                    (state.cache_k, state.cache_v)
-                )
-                new = [
-                    p.at[ids].set(v.astype(p.dtype))
-                    for p, v in zip(flat, vals)
-                ]
-                ck, cv = jax.tree.unflatten(treedef, new)
-                return state._replace(cache_k=ck, cache_v=cv)
-
-            self._swap_scatter_jit = self._shared_jit(
-                "swap_scatter",
-                lambda: jax.jit(scatter, donate_argnums=(0,)),
-            )
-        return self._swap_scatter_jit
-
     def _gather_to_pending(self, block_ids: list[int]):
         """Dispatch one padded gather of ``block_ids`` and start the
         async device→host copies; returns the gathered leaves (their
@@ -4123,7 +3589,7 @@ class ContinuousDecodeLoop:
             leaves = self.engine.dispatch_guard(
                 "swap",
                 lambda: jax.tree.leaves(
-                    self._swap_gather_fn()(self._state, pids)
+                    self.programs.swap_gather_fn()(self._state, pids)
                 ),
             )
         prefetch_to_host(*leaves)
@@ -4331,8 +3797,8 @@ class ContinuousDecodeLoop:
         # Guarded swap-site dispatch: prefetch scatters get the same
         # watchdog/retry/attribution coverage as every other dispatch.
         self._state = self.engine.dispatch_guard(
-            "swap",
-            lambda: self._swap_scatter_fn()(self._state, ids_p, vals_p),
+            "swap", lambda: self.programs.swap_scatter_fn()(
+                self._state, ids_p, vals_p),
             donates=self._state,
         )
 
@@ -4787,7 +4253,7 @@ class ContinuousDecodeLoop:
         if grew and self.admission is not None:
             self.admission.note_pool()
 
-    # -- double-buffered host prep (HOST_PREP_DOUBLE) -------------------
+    # -- double-buffered host prep (docs/compilation.md) ----------------
 
     def _stage_host_prep(self) -> None:
         """Stage iteration N+1's host prep while N is in flight: run
@@ -4803,7 +4269,7 @@ class ContinuousDecodeLoop:
         chaos target (``rN:prep:fatal@K`` kills a replica mid-staging
         — the recovery/evacuation paths discard the staged plan)."""
         self._rollback_staged_prep()
-        if not (self.host_prep_double and self.paged and self.active):
+        if not (self.paged and self.active):
             return
         if not self._work_remains():
             return
@@ -4941,7 +4407,7 @@ class ContinuousDecodeLoop:
                 # ride the same fetch.
                 self._state, toks, done = eng.dispatch_guard(
                     "chunk",
-                    lambda: self._paged_chunk_fn()(
+                    lambda: self.programs.paged_chunk_fn()(
                         dparams, self._state, table,
                         eng.chunk_tokens, use_sample,
                     ),
@@ -5228,518 +4694,17 @@ class ContinuousDecodeLoop:
                 st.emit(_END)
                 self._free_slot(slot)
 
-    # -- warmup --------------------------------------------------------
+    # -- executables and warm-up (engine/programs.py, engine/warm.py) ---
+
+    @property
+    def kernel_variant(self) -> str:
+        """The tuned Pallas decode kernel the paged chunk traced with."""
+        return self.programs.kernel_variant
 
     def warm(self) -> None:
-        """Compile the loop's executables off the request path: the
-        empty-state template, the insert scatter per seq bucket, and
-        the batched chunk in both greedy and sampled variants.  With
-        the fleet-shared ExecutableCache every wrapper may already
-        exist (a sibling replica built it), in which case this whole
-        pass is dispatches only — zero XLA compiles, the property the
-        spawn fast-path banks on (docs/compilation.md)."""
-        from ..runtime.compile_cache import note_warm_phase
+        """Compile the loop's executables off the request path."""
+        warm.warm(self)
 
-        model = self.engine.bundle.name
-        if self._state is None:
-            with tracing.boot_phase("boot/engine_build", what="empty_state"):
-                self._build_empty_state()
-        # Before the paged executables trace: the winner lands in the
-        # tuning table their kernel call sites resolve at trace time.
-        with tracing.boot_phase("boot/warm/autotune") as ph:
-            self._autotune_kernel()
-        note_warm_phase(model, "autotune", ph.seconds)
-        with tracing.boot_phase("boot/warm/loop") as ph:
-            self._warm_inner()
-        note_warm_phase(model, "loop", ph.seconds)
-
-    def warm_spawn(self, donor: "ContinuousDecodeLoop | None" = None
-                   ) -> None:
-        """λScale spawn warm (docs/compilation.md): with a donor loop
-        alive, every executable this loop will ever dispatch already
-        sits in the process-level ExecutableCache — so skip the
-        warm-dispatch grid entirely.  Build the device state (the one
-        real dispatch), adopt the donor's measured chain depth and
-        wave times instead of re-running the RTT calibration, and let
-        the fleet's probe dispatch be the gate before routing.  On a
-        1-core host this is the difference between a spawn that steals
-        ~100 s of grid dispatches from the serving core and one that
-        costs a single template build (the pre-round BASELINE record
-        (removed in PR 22) r19).  Variants the
-        donor never compiled (e.g. sampled executables under
-        WARMUP_SAMPLING=0) defer to first use — exactly the donor's
-        own behavior.  No donor → the full warm."""
-        if donor is None:
-            self.warm()
-            return
-        from ..runtime.compile_cache import note_warm_phase
-
-        with tracing.boot_phase("boot/warm/loop", spawn=True) as ph:
-            if self._state is None:
-                self._build_empty_state()
-            self.chain_depth = max(1, int(donor.chain_depth))
-            self._wave_seconds = dict(donor._wave_seconds)
-            metrics.CHAIN_DEPTH.labels(self.engine.bundle.name).set(
-                self.chain_depth
-            )
-        note_warm_phase(self.engine.bundle.name, "loop", ph.seconds)
-
-    def _warm_wave(self, s: int, n_batch: int, sampled: bool = False):
-        """Run the batched start for ``n_batch`` full rows of bucket
-        ``s`` (caller holds ``eng._lock``): (state1, ids, mask)."""
-        eng = self.engine
-        feats_list = [
-            {"input_ids": np.ones(s, np.int32), "length": np.int32(s)}
-        ] * n_batch
-        ids, mask, _ = eng._collate_text(feats_list)
-        sp, _ = eng._collate_sample(feats_list, ids.shape[0])
-        ids, mask = eng.replicas.place_batch(ids, mask)
-        state1, _ = eng._start(
-            self._mp(n=int(ids.shape[0])), ids, mask, sp,
-            eng.max_decode_len, eng.chunk_tokens, sampled,
-        )
-        return state1, ids, mask
-
-    def _warm_inner(self) -> None:
-        import jax
-
-        eng = self.engine
-        import os as _os
-
-        if self._state is None:
-            self._build_empty_state()
-        warm_sampled = _os.environ.get(
-            "WARMUP_SAMPLING", "1"
-        ).lower() not in ("0", "false", "no")
-        if self.paged:
-            self._warm_paged(warm_sampled)
-            return
-
-        def do_insert(state1, ids, mask, s: int):
-            if self.spec:
-                feats0 = {
-                    "input_ids": np.ones(s, np.int32), "length": np.int32(s)
-                }
-                hist_row = self._hist_row(
-                    feats0, np.zeros(eng.chunk_tokens, np.int32)
-                )
-                self._state = self._insert_fn()(
-                    self._state, state1, ids, mask, hist_row,
-                    np.int32(0), np.int32(0),
-                )
-            else:
-                self._state = self._insert_fn()(
-                    self._state, state1, np.int32(0), np.int32(0)
-                )
-
-        # Wave sizes to warm: every rung a wave can run at (the lowest
-        # is the solo shape).  Under the prefix cache these still serve
-        # grouped MISSES (hits go through the grouped prefixed waves
-        # warmed below).
-        for s in eng.seq_buckets:
-            for n_batch in self._wave_rungs:
-                for flag in (False, True) if (
-                    warm_sampled and n_batch > 1
-                ) else (False,):
-                    with eng._lock:
-                        state1, ids, mask = self._warm_wave(s, n_batch, flag)
-                        do_insert(state1, ids, mask, s)
-        for flag in (False, True) if (warm_sampled or not self.spec) else (
-            False,
-        ):
-            with eng._lock:
-                if self.spec:
-                    self._state, out, _, _ = eng._spec_chunk(
-                        eng.params, self._state, eng.chunk_tokens,
-                        eng.spec_k, flag,
-                    )
-                    jax.device_get(out)
-                else:
-                    self._state, toks, _ = eng._gen_chunk(
-                        self._mp(n=self.n_slots), self._state,
-                        eng.chunk_tokens, flag,
-                    )
-                    jax.device_get(toks)
-        # Re-warm the inserts in SERVING order — against a chunk-OUTPUT
-        # batched state.  The first such call in a process pays a
-        # one-time cost of seconds (pre-round record; absent when
-        # the batched-state operand comes from the warm-up's device_put
-        # path), which would otherwise land on the first admission
-        # after serving starts.
-        for s in eng.seq_buckets:
-            for n_batch in self._wave_rungs:
-                with eng._lock:
-                    state1, ids, mask = self._warm_wave(s, n_batch)
-                    do_insert(state1, ids, mask, s)
-                    # Miss-wave donation slicers specialize on the
-                    # batched state shape — warm them here so the first
-                    # grouped miss wave never compiles a capture on the
-                    # request path.
-                    if eng.prefix_cache is not None and n_batch > 1:
-                        for p_ins in eng.seq_buckets:
-                            if p_ins <= s:
-                                eng._capture_prefix(state1, p_ins, 0)
-                jax.block_until_ready(jax.tree.leaves(self._state)[0])
-        # Prefix-cache grid: a cache hit's state has width
-        # p_len+s_suf+max_decode — a shape none of the inserts above
-        # ever saw, so the FIRST hit admission would otherwise compile
-        # the insert on the request path (seconds, in a pre-round record).
-        # Warm the insert against B=1 hit states AND the grouped
-        # (_start_prefixed_wave) states per reachable (prefix, suffix)
-        # pair, plus the wave executables themselves and their hit-path
-        # donation slicers.  (The B=1 starts run sample=False only:
-        # engine.warmup already compiled both sample variants of
-        # _start_prefixed, and the INSERT executable this block exists
-        # for is sample-agnostic — state shapes don't depend on it.)
-        if eng.prefix_cache is not None:
-            s_max = max(eng.seq_buckets)
-            with eng._lock:
-                template, _, _ = self._warm_wave(s_max, 1)
-            for p_len in eng.seq_buckets:
-                if p_len > s_max - 1:
-                    continue
-                with eng._lock:
-                    pkv = eng._capture_prefix(template, p_len)
-                for s_suf in eng.seq_buckets:
-                    if p_len + s_suf > s_max:
-                        continue
-                    sfeats = {
-                        "input_ids": np.ones(s_suf, np.int32),
-                        "length": np.int32(s_suf),
-                    }
-                    with eng._lock:
-                        sids, smask, _ = eng._collate_text([sfeats])
-                        ssp, _ = eng._collate_sample([sfeats], sids.shape[0])
-                        sids, smask = eng.replicas.place_batch(sids, smask)
-                        st1, _ = eng._start_prefixed(
-                            self._mp(n=1), pkv, sids, smask, ssp,
-                            eng.max_decode_len, eng.chunk_tokens, False,
-                        )
-                        # Spec mode warms the init_spec_fn-recasting
-                        # insert against the hit-state shape (full
-                        # prompt = prefix + suffix for the hist row).
-                        do_insert(st1, sids, smask, p_len + s_suf)
-                    for n_batch in self._wave_rungs:
-                        if n_batch < 2:
-                            continue  # solo hits: the B=1 start above
-                        wfeats = [sfeats] * n_batch
-                        with eng._lock:
-                            wids, wmask, _ = eng._collate_text(wfeats)
-                            wsp, _ = eng._collate_sample(
-                                wfeats, wids.shape[0]
-                            )
-                            wids, wmask = eng.replicas.place_batch(
-                                wids, wmask
-                            )
-                            pkvs = (pkv,) * wids.shape[0]
-                            for flag in (
-                                (False, True) if warm_sampled else (False,)
-                            ):
-                                stw, tw = eng._start_prefixed_wave(
-                                    self._mp(n=int(wids.shape[0])),
-                                    pkvs, wids, wmask, wsp,
-                                    eng.max_decode_len, eng.chunk_tokens,
-                                    flag,
-                                )
-                                jax.device_get(tw)
-                            do_insert(stw, wids, wmask, p_len + s_suf)
-                            # Wave-state donation slicers (growing
-                            # conversations donate per row from the
-                            # grouped hit state).
-                            for p_ins in eng.seq_buckets:
-                                if p_len < p_ins <= p_len + s_suf - 1:
-                                    eng._capture_prefix(stw, p_ins, 0)
-                    jax.block_until_ready(
-                        jax.tree.leaves(self._state)[0]
-                    )
-        if self.prefill_chunk:
-            with tracing.boot_phase("boot/warm/loop/prefill_window"):
-                self._warm_prefill()
-        if self._auto_depth:
-            with tracing.boot_phase("boot/warm/loop/chain_depth"):
-                self._tune_chain_depth()
-        # Reset to all-dead so warm inserts never leak into serving.
-        self._build_empty_state()
-
-    def _autotune_kernel(self) -> None:
-        """Warm-time Pallas kernel-variant resolution (ops/autotune.py,
-        docs/kernel_tuning.md).  Runs BEFORE the paged executables
-        below trace: a PALLAS_VARIANT pin is validated and installed,
-        else PALLAS_AUTOTUNE runs the measured sweep (verify-then-time
-        every feasible variant at this loop's exact decode shapes) —
-        either way the winner lands in the process tuning table, where
-        the model's kernel call sites resolve it at trace time, and in
-        the fleet-shared ExecutableCache + persisted table, so replica
-        spawns/rebuilds/replays inherit it with zero extra compiles.
-        No knob set, or the bundle not on the kernel path: no-op,
-        ``self.kernel_variant`` stays "" (the default kernel)."""
-        eng = self.engine
-        bcfg = getattr(eng.bundle, "cfg", None)
-        scfg = getattr(eng, "cfg", None)
-        if not (self.paged and getattr(bcfg, "pallas_decode", False)):
-            return
-        pin = (getattr(scfg, "pallas_variant", None)
-               or getattr(bcfg, "pallas_variant", "") or None)
-        if not (pin or getattr(scfg, "pallas_autotune", False)):
-            return
-        import numpy as np_
-
-        from ..ops import autotune
-
-        path = autotune.default_table_path(
-            getattr(scfg, "device", None),
-            getattr(scfg, "compile_cache_dir", None),
-        )
-        kvh = int(getattr(bcfg, "num_kv_heads", bcfg.num_heads))
-        kind, d = "paged_decode", int(bcfg.head_dim)
-        if getattr(bcfg, "latent_lanes", 0):
-            # One KV "head" every query head shares, as wide as the pool.
-            kind, kvh, d = "latent_decode", 1, int(bcfg.latent_lanes)
-
-        def tuned(t: int) -> str:
-            return autotune.ensure_tuned(
-                kind, eng.bundle, eng.replicas,
-                b=self.n_slots, kvh=kvh,
-                n_rep=int(bcfg.num_heads) // kvh, d=d,
-                block_size=self.block_size, t=t,
-                dtype=str(np_.dtype(eng.bundle.policy.compute_jnp)),
-                quant=bool(getattr(bcfg, "kv_quant", False)),
-                interpret=bool(getattr(bcfg, "pallas_interpret", False)),
-                pin=pin, table_path=path,
-            )
-
-        self.kernel_variant = tuned(self.nb_max)
-        if getattr(bcfg, "window", 0):
-            # A window layer's kernel runs at the width of its table
-            # view (models/llama.window_view): a tuning problem of its own.
-            from ..models.llama import window_view_blocks
-
-            tw = window_view_blocks(bcfg.window, self.block_size, self.nb_max)
-            if tw != self.nb_max:
-                tuned(tw)
-
-    def _warm_paged(self, warm_sampled: bool) -> None:
-        """Paged-mode warmup: the start and the paged insert per (wave
-        rung × seq bucket) and the paged chunk in both sample variants,
-        against temporarily-allocated blocks that are returned (and the
-        state reset) before serving.  The prefixed-hit insert variants
-        ((s_lo, s_cut) pairs) compile on first hit — paged deployments
-        restrict SEQ_BUCKETS anyway (the PREFIX_CACHE guidance), and a
-        one-off compile beats warming a grid most cells of which are
-        never served."""
-        from concurrent.futures import ThreadPoolExecutor
-
-        import jax
-        import jax.numpy as jnp
-
-        from .kv_blocks import OutOfBlocks, StreamBlocks, blocks_for
-
-        eng = self.engine
-
-        # One scratch block list serves the whole grid (every insert
-        # writes slot 0; warm-up resets the state below); a bucket the
-        # pool cannot hold is unservable and stays cold.
-        sb = StreamBlocks(self.pool, self.block_size)
-        grid = []
-        for s in sorted(eng.seq_buckets):
-            try:
-                sb.ensure(s + eng.chunk_tokens)
-            except OutOfBlocks:
-                break
-            grid += [(s, n_batch) for n_batch in self._wave_rungs]
-
-        insert = self._paged_insert_fn()
-        one_insert = threading.Lock()
-        parent = tracing.boot_current()
-
-        def warm_one(cell: tuple[int, int]) -> None:
-            s, n_batch = cell
-            with tracing.boot_phase("boot/warm/loop/grid", parent,
-                                    bucket=s, rung=n_batch), eng._lock:
-                state1 = self._warm_wave(s, n_batch)[0]
-                # One insert at a time: it consumes the state (donated)
-                # and the next thread's takes its successor.
-                with one_insert:
-                    n_blocks = blocks_for(s + eng.chunk_tokens, self.block_size)
-                    self._state = insert(*self._warm_insert_args(
-                        state1, s, sb.ids[:n_blocks]
-                    ))
-                    jax.block_until_ready(self._state.done)
-
-        # A warm start is tracing plus the runtime loading a cached
-        # executable of tens of MB, ~2.6 s a (rung, bucket) pair, and the
-        # ladder's extra pairs cost a boot more than ``setup_s`` may move
-        # when they load one after another.  On three threads the loads
-        # overlap (the tracing does not): the grid's extra cost falls to
-        # less than half (PERF.md section 6, PR 26).  Largest first, so
-        # no thread starts the longest load last; up to three wave
-        # states are alive at once instead of one.
-        grid.sort(key=lambda cell: -cell[0] * cell[1])
-        try:
-            with ThreadPoolExecutor(3, "warm-rung") as pool:
-                list(pool.map(warm_one, grid))  # list(): raise what failed
-        finally:
-            sb.release()
-        for flag in (False, True) if warm_sampled else (False,):
-            with tracing.boot_phase("boot/warm/loop/chunk", sampled=flag), \
-                    eng._lock:
-                self._state, toks, _ = self._paged_chunk_fn()(
-                    self._mp(n=self.n_slots), self._state,
-                    jnp.asarray(self._table), eng.chunk_tokens, flag,
-                )
-                jax.device_get(toks)
-        with tracing.boot_phase("boot/warm/loop/swap"):
-            self._warm_swap()
-        if self.prefill_chunk:
-            with tracing.boot_phase("boot/warm/loop/prefill_window"):
-                self._warm_prefill()
-        if self._auto_depth:
-            with tracing.boot_phase("boot/warm/loop/chain_depth"):
-                self._tune_chain_depth_paged()
-        with tracing.boot_phase("boot/warm/loop/empty_state"):
-            self._build_empty_state()
-
-    def _warm_swap(self) -> None:
-        """Compile the host-tier swap executables off the request path
-        (the round-14 honest negative: the FIRST host-tier resume paid
-        a one-off scatter + handoff compile on the request path).
-        Warms the fixed-width host→device scatter, the device→host
-        gather at every power-of-two width the swap-out padder can
-        emit (log2(nb_max) executables, bounded), and — when chunked
-        prefill won't warm it — the paged row handoff the swap resume
-        flips live through."""
-        tier = self._host_tier()
-        if tier is None or not self.paged:
-            return
-        import jax
-
-        eng = self.engine
-        if not tier.ensure_pool(self._host_leaf_specs()):
-            return
-        specs = self._host_leaf_specs()
-        K = self.swap_chunk_blocks
-        ids = np.zeros(K, np.int32)
-        vals = [
-            np.zeros((K,) + tuple(shape), dtype) for shape, dtype in specs
-        ]
-        with eng._lock:
-            # Scatter writes zeros into block 0 of the warm state —
-            # harmless: _build_empty_state resets everything after
-            # warmup, before serving.
-            self._state = self._swap_scatter_fn()(self._state, ids, vals)
-            w = 1
-            cap = 1 << max(0, self.nb_max - 1).bit_length()
-            while w <= cap:
-                self._swap_gather_fn()(self._state, np.zeros(w, np.int32))
-                w *= 2
-            if not self.prefill_chunk:
-                # Swap-resume handoff (chunked deployments warm it in
-                # _warm_prefill; without PREFILL_CHUNK it would compile
-                # on the first resume).
-                sp, _ = eng._collate_sample(
-                    [{"input_ids": np.ones(1, np.int32),
-                      "length": np.int32(1)}], 1
-                )
-                self._state = self._paged_handoff_fn()(
-                    self._state,
-                    np.zeros(
-                        (1, self.nb_max * self.block_size), np.int32
-                    ),
-                    np.zeros(1, np.int32), np.zeros(1, np.int32),
-                    np.zeros(1, np.int32), np.ones(1, bool),
-                    np.zeros((1, eng.max_decode_len), np.int32),
-                    sp, np.int32(0),
-                )
-            jax.block_until_ready(jax.tree.leaves(self._state)[0])
-
-    def _tune_chain_depth_paged(self) -> None:
-        """Paged variant of ``_tune_chain_depth`` (the chunk takes the
-        table operand)."""
-        import time as _time
-
-        import jax
-        import jax.numpy as jnp
-
-        eng = self.engine
-        table = jnp.asarray(self._table)
-        wp = self._mp(n=self.n_slots)
-
-        def wall(k: int) -> float:
-            t0 = _time.perf_counter()
-            with eng._lock:
-                for _ in range(k):
-                    # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                    self._state, toks, _ = self._paged_chunk_fn()(
-                        wp, self._state, table, eng.chunk_tokens, False
-                    )
-                # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                jax.device_get(toks)
-            return _time.perf_counter() - t0
-
-        wall(1)
-        w1 = wall(1)
-        w5 = wall(5)
-        compute = max((w5 - w1) / 4.0, 1e-4)
-        rtt = max(w1 - compute, 0.0)
-        self._apply_tuned_depth(rtt, compute)
-
-    @staticmethod
-    def depth_from(rtt_s: float, compute_s: float) -> int:
-        """Chain depth from measured numbers: cadence ≈ max(RTT/D,
-        chunk compute), so D ≈ RTT/compute closes the gap to the wire;
-        clamped to [1, 8] (deeper chains only add fetch latency)."""
-        return max(1, min(8, round(rtt_s / max(compute_s, 1e-4))))
-
-    def _apply_tuned_depth(self, rtt: float, compute: float) -> None:
-        self.chain_depth = self.depth_from(rtt, compute)
-        metrics.CHAIN_DEPTH.labels(self.engine.bundle.name).set(
-            self.chain_depth
-        )
-        # No wave costs less than a chunk's round trip: the idle
-        # admission's cap until the loop has timed a wave of its own.
-        self._wave_seconds.setdefault(self._wave_rungs[0], rtt + compute)
-        log.info(
-            "continuous loop: chunk compute %.1f ms, dispatch RTT %.1f ms "
-            "-> chain depth %d",
-            compute * 1e3, rtt * 1e3, self.chain_depth,
-        )
-
-    def _tune_chain_depth(self) -> None:
-        """Pick the chunk-chain pipelining depth from measured numbers:
-        cadence ≈ max(RTT/D, chunk compute), so D ≈ RTT/compute closes
-        the gap to the wire.  Chained dispatches against the SAME warm
-        executable separate the two: wall(k chained chunks + fetch) =
-        RTT + k·compute, so compute = (wall_5 − wall_1)/4 and RTT
-        falls out — no extra compiles, ~6 dispatches total."""
-        import time as _time
-
-        import jax
-
-        eng = self.engine
-        wp = eng.params if self.spec else self._mp(n=self.n_slots)
-
-        def wall(k: int) -> float:
-            t0 = _time.perf_counter()
-            with eng._lock:
-                for _ in range(k):
-                    if self.spec:
-                        # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                        self._state, toks, _, _ = eng._spec_chunk(
-                            eng.params, self._state, eng.chunk_tokens,
-                            eng.spec_k, False,
-                        )
-                    else:
-                        # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                        self._state, toks, _ = eng._gen_chunk(
-                            wp, self._state, eng.chunk_tokens, False
-                        )
-                # graftlint: unguarded(warm-time RTT calibration probe — the raw wire is the measurement; a guard's bookkeeping is the thing being measured)
-                jax.device_get(toks)
-            return _time.perf_counter() - t0
-
-        wall(1)  # prime any lazy transfer
-        w1 = wall(1)
-        w5 = wall(5)
-        compute = max((w5 - w1) / 4.0, 1e-4)
-        rtt = max(w1 - compute, 0.0)
-        self._apply_tuned_depth(rtt, compute)
+    def warm_spawn(self, donor=None) -> None:
+        """``warm`` for a replica spawned beside a live ``donor`` loop."""
+        warm.warm_spawn(self, donor)

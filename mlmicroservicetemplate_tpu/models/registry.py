@@ -933,8 +933,8 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         # over ALL the keys and serve another model in silence: refuse it.
         _refuse_cache_readers(
             svc_cfg, f"window layers (layer_types / window={cfg.window})", {
-                "PAGED_KV=0": "the contiguous slab's decode step, chunked "
-                "prefill and fused decode window apply no window: set PAGED_KV=1",
+                "PAGED_KV=0": "the contiguous slab's decode step and chunked "
+                "prefill apply no window: set PAGED_KV=1",
                 "SPEC_DECODE": "speculative verification (llama.multi_step) "
                 "applies no window",
                 "QUANT_KV": "the int8 pool pairs were never run under a window view",
@@ -951,9 +951,8 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
         # TP>1 (the latent would replicate) and QUANTIZE refuse above.
         _refuse_cache_readers(
             svc_cfg, "latent attention (attention='mla')", {
-                "PAGED_KV=0": "the contiguous slab's chunked prefill, fused "
-                "decode window and streaming loop read K and V per head: set "
-                "PAGED_KV=1",
+                "PAGED_KV=0": "the contiguous slab's chunked prefill and "
+                "streaming loop read K and V per head: set PAGED_KV=1",
                 "SPEC_DECODE": "speculative verification (llama.multi_step) "
                 "reads K and V per head",
                 "QUANT_KV": "the int8 pool pairs quantise per token-head; a "

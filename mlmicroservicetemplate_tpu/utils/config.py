@@ -365,16 +365,6 @@ class ServiceConfig(BaseModel):
     # the continuous loop's slot width (contiguous mode) / block-table
     # width (paged), so cap it when HBM is tight.
     prefill_max_prompt: int = 0
-    # Double-buffered host dispatch prep (engine/streams.py,
-    # docs/compilation.md): while chunk N is in flight, the loop
-    # stages iteration N+1's host-side prep — the paged block-growth
-    # pass, table assembly and the table's host→device upload — so it
-    # overlaps N's device compute instead of serializing between
-    # dispatches.  Token-identical by construction (a stale staged
-    # plan rolls back and re-preps inline); measured at
-    # dispatch_host_seconds{site="prep"}.  Off = the serial prep
-    # order, exactly.
-    host_prep_double: bool = True
     # Interactive arrivals may preempt batch-class streams (checkpoint
     # the cursor, free the slot, re-queue for token-identical resume)
     # when every slot is busy.  Only reachable with MAX_STREAM_QUEUE>0.
@@ -1068,7 +1058,7 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
       SCALE_UP_KV_FRAC, SCALE_UP_TTFT_MS, SCALE_UP_COOLDOWN_S,
       SCALE_DOWN_LOAD, SCALE_DOWN_COOLDOWN_S, SCALE_PERIOD_S,
       TRACE, TRACE_RING, FLIGHT_RING, PROFILE_DIR, LOG_FORMAT,
-      COMPILE_CACHE_DIR, HOST_PREP_DOUBLE,
+      COMPILE_CACHE_DIR,
       LATENCY_BUCKETS, SLO_TTFT_MS, SLO_TBT_MS, SLO_BATCH_TTFT_MS,
       SLO_BATCH_TBT_MS, SLO_TARGET, SLO_WINDOWS_S, SCALE_UP_SLO_BURN.
     """
@@ -1192,9 +1182,6 @@ def load_config(env: dict[str, str] | None = None) -> ServiceConfig:
     v = get("PREEMPT")
     if v is not None:
         kwargs["preempt"] = v.lower() not in ("0", "false", "no")
-    v = get("HOST_PREP_DOUBLE")
-    if v is not None:
-        kwargs["host_prep_double"] = v.lower() not in ("0", "false", "no")
     v = get("PAGED_KV")
     if v is not None:
         kwargs["paged_kv"] = v.lower() not in ("0", "false", "no")

@@ -130,7 +130,7 @@ fi
 # Staged-prep smoke (r19, docs/compilation.md): an rN:-scoped fatal
 # on replica 1's double-buffered host-prep upload (site `prep`) must
 # fail its streams over token-identically onto the survivor with both
-# pool ledgers drained — the chaos pin that HOST_PREP_DOUBLE's staged
+# pool ledgers drained — the chaos pin that the staged prep's
 # grants never outlive a dead replica (chaos tier, so it stays out of
 # tier-1).  PREP_SMOKE=0 skips.
 if [ "${PREP_SMOKE:-1}" != "0" ]; then

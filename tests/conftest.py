@@ -203,6 +203,40 @@ _CACHE_KNOBS = (
 )
 
 
+def _mappings() -> tuple[int, int]:
+    """(this process's memory mappings, the kernel's limit a process);
+    (0, 0) where the kernel does not say."""
+    try:
+        with open("/proc/self/maps", "rb") as f:
+            held = sum(1 for _ in f)
+        with open("/proc/sys/vm/max_map_count", "rb") as f:
+            return held, int(f.read())
+    except (OSError, ValueError):
+        return 0, 0
+
+
+@pytest.fixture(autouse=True)
+def _mapping_guard():
+    """XLA's CPU backend keeps ~40 memory mappings an executable for as
+    long as JAX caches it, and a tier-1 worker compiles thousands: by the
+    last tenth of a whole run its count reaches the kernel's
+    ``vm.max_map_count`` (65530), the next compile's ``mmap`` fails inside
+    the compiler and the worker dies of a segmentation fault or an abort in
+    whatever test compiles next (PERF.md section 7, PR 60: a worker read
+    65121 at its last sample).  After a test, a worker past seven tenths
+    of the limit drops JAX's caches, which unmaps them: once a run for most
+    workers (what it drops is compiled again, ~30 s of test time a drop),
+    with room for one more test's executables (a whole FILE's can be
+    25,000 mappings)."""
+    yield
+    held, limit = _mappings()
+    if held > limit * 7 // 10 > 0:
+        import gc
+
+        jax.clear_caches()
+        gc.collect()
+
+
 @pytest.fixture(autouse=True)
 def _compile_cache_guard():
     from jax._src import compilation_cache

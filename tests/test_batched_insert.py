@@ -26,10 +26,8 @@ from jax import lax
 from helpers import one_wave, tiny_llama_bundle
 from test_nemotron_block import config, kw  # noqa: F401
 from mlmicroservicetemplate_tpu.engine import InferenceEngine
-from mlmicroservicetemplate_tpu.engine.streams import (
-    ContinuousDecodeLoop,
-    paged_insert,
-)
+from mlmicroservicetemplate_tpu.engine.programs import paged_insert
+from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
 from mlmicroservicetemplate_tpu.models.gpt import PagedState
 from mlmicroservicetemplate_tpu.models.llama import SsmState
 from mlmicroservicetemplate_tpu.models.sampling import SampleParams

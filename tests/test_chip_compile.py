@@ -409,13 +409,13 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True,
                      windows: int = 1) -> tuple:
     """The text of the loop's own executable compiled for the described
     chip at a cell's shapes — ``what`` = the paged chunk
-    (``chunk_with_done(generate_chunk_paged)``, as ``_paged_chunk_fn``
+    (``chunk_with_done(generate_chunk_paged)``, as ``paged_chunk_fn``
     jits it), the insert of a lone start or (``insert_wave``) of a wave at
-    the slot count (``streams.paged_insert``) or the prompt
+    the slot count (``programs.paged_insert``) or the prompt
     windows of ``windows`` prompts in one dispatch — and the element counts of a payload and a scale pool.  Shapes only: no
     weight is made."""
     from mlmicroservicetemplate_tpu.engine.engine import chunk_with_done
-    from mlmicroservicetemplate_tpu.engine.streams import paged_insert
+    from mlmicroservicetemplate_tpu.engine.programs import paged_insert
     from mlmicroservicetemplate_tpu.models import llama as llama_mod
     from mlmicroservicetemplate_tpu.models.gpt import PagedState
     from mlmicroservicetemplate_tpu.models.sampling import greedy_params
@@ -463,7 +463,7 @@ def _serving_program(chip, cell: str, what: str, donate: bool = True,
                 llama_mod.generate_chunk_paged(p, cfg, s, tb, n, sample))),
             static_argnums=(3, 4), **donated,
         ).lower(params, state, chip((b, t), jnp.int32), steps, False)
-    elif what == "prefill":  # registry.paged_prefill_chunk_fn, as _paged_prefill_fn jits it
+    elif what == "prefill":  # registry.paged_prefill_chunk_fn, as programs.paged_prefill_fn jits it
         w = _WINDOWS[cell]
         lowered = jax.jit(
             lambda p, s, rows, ids, mask, starts: llama_mod.paged_prefill_chunk(

@@ -15,7 +15,7 @@ The contracts under test:
    ServiceConfig knob now; a path enables the disk cache (entries
    really land on disk — the layer that carries compiles across
    process restarts / journal replays), "0" disables, CPU default off.
-4. **Double-buffered host prep** (HOST_PREP_DOUBLE): token identity
+4. **Double-buffered host prep** (docs/compilation.md): token identity
    across gpt/llama × {contig, paged} × {greedy, pinned-seed sampled}
    vs the serial-prep loop, with staged plans actually consumed.
 5. **Mid-prep fatal** → supervised checkpoint-resume, token-identical,
@@ -302,16 +302,19 @@ def _identity_cfg(paged: bool, **kw) -> ServiceConfig:
 @pytest.mark.parametrize("sampled", [False, True],
                          ids=["greedy", "sampled"])
 def test_double_buffer_token_identity(family, paged, sampled):
-    """HOST_PREP_DOUBLE=1 (default) is token-identical to the serial
-    prep order across the matrix — and in paged mode the staged plans
-    are genuinely consumed, not always rolled back."""
+    """Staged host prep is token-identical to the serial prep order (the
+    inline pass, forced through the loop by a no-op ``_stage_host_prep``)
+    across the matrix — and in paged mode the staged plans are genuinely
+    consumed, not always rolled back."""
     bundle = _BUNDLES[family]
     prompts = ["the quick brown fox", "pack my box", "jinx"]
 
     def run(double: bool):
-        cfg = _identity_cfg(paged, host_prep_double=double)
+        cfg = _identity_cfg(paged)
         eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
         cdl = ContinuousDecodeLoop(eng, cfg)
+        if not double:
+            cdl._stage_host_prep = lambda: None
         feats = []
         for i, t in enumerate(prompts):
             f = text_feats(bundle.tokenizer, t)
@@ -326,7 +329,7 @@ def test_double_buffer_token_identity(family, paged, sampled):
         return outs, cdl
 
     base, cdl_base = run(double=False)
-    assert cdl_base.prep_staged == 0  # knob off = serial order exactly
+    assert cdl_base.prep_staged == 0  # nothing staged = serial order exactly
     dbl, cdl_dbl = run(double=True)
     for got, want in zip(dbl, base):
         np.testing.assert_array_equal(got, want)

@@ -14,7 +14,7 @@ The judged contracts:
    token-identically (supervised rebuild).
 5. Admission rides BEHIND the live chunk: with streams live, a wave's
    prefill is dispatched after the iteration's chunk.
-6. The auto-tuned chain depth is pinned (``depth_from``) and surfaced
+6. The auto-tuned chain depth is pinned (``warm.depth_from``) and surfaced
    (stream_chain_depth gauge + /status.decode), beside the staged
    host prep's counters.
 7. Nothing of a fused decode window is left on any surface.
@@ -26,7 +26,7 @@ import time
 import numpy as np
 import pytest
 
-from mlmicroservicetemplate_tpu.engine import InferenceEngine
+from mlmicroservicetemplate_tpu.engine import InferenceEngine, warm
 from mlmicroservicetemplate_tpu.engine.kv_blocks import (
     BlockPool,
     StreamBlocks,
@@ -368,7 +368,7 @@ def test_depth_from_pins():
     """The auto chain-depth formula (STREAM_PIPELINE=0): D ≈
     RTT/compute, clamped to [1, 8] — pinned so the tuner can't drift
     silently."""
-    d = ContinuousDecodeLoop.depth_from
+    d = warm.depth_from
     assert d(0.0, 0.005) == 1  # direct-attached: no pipelining
     assert d(0.010, 0.005) == 2
     assert d(0.100, 0.012) == 8  # long round-trip regime
@@ -406,18 +406,18 @@ def test_status_surfaces_chain_depth_and_prep_counters():
         _cfg(stream_pipeline=2, paged_kv=True, kv_block_size=8),
         tiny_gpt_bundle(), drive))
     assert dec["chain_depth"] == 2 and dec["chain_depth_auto"] is False
-    assert dec["chunk_tokens"] == 4 and dec["host_prep_double"] is True
+    assert dec["chunk_tokens"] == 4
     assert {"chunk_dispatches", "tokens_emitted", "prep_staged", "prep_hits",
             "prep_misses", "dispatch_counts"} <= set(dec)
 
 
 def test_chain_depth_gauge_set_on_tune():
-    """_apply_tuned_depth publishes stream_chain_depth."""
+    """``warm.apply_tuned_depth`` publishes stream_chain_depth."""
     bundle = tiny_gpt_bundle()
     cfg = _cfg(stream_pipeline=0)
     cdl = ContinuousDecodeLoop(_engine(bundle, cfg), cfg)
     try:
-        cdl._apply_tuned_depth(rtt=0.02, compute=0.005)
+        warm.apply_tuned_depth(cdl, rtt=0.02, compute=0.005)
         assert cdl.chain_depth == 4
         if metrics.HAVE_PROM:
             assert metrics.CHAIN_DEPTH.labels("gpt2")._value.get() == 4

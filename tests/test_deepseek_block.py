@@ -256,7 +256,7 @@ def test_a_prompt_window_on_a_rung_below_the_top_is_the_reference(
 
 
 def test_prefill_wave_state_inserts_as_one_latent_slab_a_layer(cfg, params):
-    """What the wave path hands ``engine/streams.paged_insert``: a latent
+    """What the wave path hands ``engine/programs.paged_insert``: a latent
     slab a layer in ``cache_k``, nothing in ``cache_v`` — and straight
     into pool blocks the same rows (``init_paged_state``)."""
     ids = jnp.asarray(np.stack([_ids(8, 1), _ids(8, 2)]))
@@ -270,7 +270,7 @@ def test_prefill_wave_state_inserts_as_one_latent_slab_a_layer(cfg, params):
     np.testing.assert_array_equal(  # row 1's first block = its first two tokens
         np.asarray(ps.cache_k[2][6]), np.asarray(st.cache_k[2][1, :2]))
     # ... and the wave's ONE insert lands both rows' slabs in those blocks
-    from mlmicroservicetemplate_tpu.engine.streams import paged_insert
+    from mlmicroservicetemplate_tpu.engine.programs import paged_insert
 
     empty = ps._replace(
         cache_k=[jnp.zeros_like(c) for c in ps.cache_k],

@@ -30,6 +30,9 @@ from tools.graftlint.core import find_repo_root
 
 STREAMS_REL = "mlmicroservicetemplate_tpu/engine/streams.py"
 POLICY_REL = "mlmicroservicetemplate_tpu/scheduler/policy.py"
+ENGINE_DIR = "mlmicroservicetemplate_tpu/engine"
+PROGRAMS_REL = f"{ENGINE_DIR}/programs.py"
+WARM_REL = f"{ENGINE_DIR}/warm.py"
 
 REPO_ROOT = Path(__file__).resolve().parent.parent
 
@@ -108,6 +111,47 @@ def test_dispatch_guard_waiver_and_empty_reason():
     # An empty waiver is itself an unwaived finding.
     assert len(unwaived(empty)) == 1
     assert "no reason" in unwaived(empty)[0].message
+
+
+@pytest.mark.parametrize("rel", [STREAMS_REL, PROGRAMS_REL, WARM_REL])
+def test_dispatch_guard_fires_on_the_programs_accessor_idiom(rel):
+    """An unguarded ``programs.<x>_fn()(...)`` is a finding in the loop, in
+    ``engine/programs.py`` itself and in ``engine/warm.py`` (outside a
+    ``warm*`` function); under the guard it is clean."""
+    fs = lint_source(_src("""
+        def step(loop, wp, table):
+            return loop.programs.paged_chunk_fn()(
+                wp, loop._state, table, 4, False)
+
+        def guarded(loop, eng, wp, table):
+            return eng.dispatch_guard(
+                "chunk", lambda: loop.programs.paged_chunk_fn()(
+                    wp, loop._state, table, 4, False))
+    """), rel, "dispatch-guard")
+    assert [f.line for f in unwaived(fs)] == [3]
+    assert "paged_chunk_fn()" in fs[0].message
+
+
+@pytest.mark.parametrize("name", ["programs", "warm"])
+def test_programs_and_warm_import_no_streams(name):
+    """The arrow points one way: the loop imports its executables and its
+    warm-up, never the reverse."""
+    import ast
+
+    tree = ast.parse((REPO_ROOT / ENGINE_DIR / f"{name}.py").read_text())
+    imported = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            imported |= {a.name for a in node.names}
+        elif isinstance(node, ast.ImportFrom):
+            imported.add(node.module or "")
+            imported |= {f"{node.module or ''}.{a.name}" for a in node.names}
+    assert not [m for m in imported if m.split(".")[-1] == "streams"]
+    if name == "warm":  # ... and warm-up walks programs, not the reverse
+        prog = ast.parse((REPO_ROOT / ENGINE_DIR / "programs.py").read_text())
+        assert not [n for n in ast.walk(prog)
+                    if isinstance(n, ast.ImportFrom)
+                    and "warm" in [a.name for a in n.names] + [n.module]]
 
 
 def test_dispatch_guard_out_of_scope_files_ignored():

@@ -406,7 +406,7 @@ def test_every_batch_width_is_warmed(budget, monkeypatch):
     bundle = tiny_gpt_bundle()
     _, eng, cdl = _paged_loop(bundle, prefill_budget=budget)
     seen = _spy_dispatches(cdl)
-    shapes, fn = set(), cdl._paged_prefill_fn
+    shapes, fn = set(), cdl.programs.paged_prefill_fn
 
     def spy_fn():
         inner = fn()
@@ -417,7 +417,7 @@ def test_every_batch_width_is_warmed(budget, monkeypatch):
 
         return call
 
-    cdl._paged_prefill_fn = spy_fn
+    cdl.programs.paged_prefill_fn = spy_fn
     try:
         cdl.warm()
         assert eng.kv_pool.used_blocks == 0

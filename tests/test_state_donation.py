@@ -37,6 +37,8 @@ from mlmicroservicetemplate_tpu.engine.faults import (
     Watchdog,
     is_consumed,
 )
+from mlmicroservicetemplate_tpu.engine.kv_blocks import blocks_for
+from mlmicroservicetemplate_tpu.engine.programs import LoopPrograms
 from mlmicroservicetemplate_tpu.engine.streams import ContinuousDecodeLoop
 from mlmicroservicetemplate_tpu.engine.supervisor import Supervisor
 from mlmicroservicetemplate_tpu.parallel import ReplicaSet, make_mesh
@@ -215,15 +217,15 @@ def _handoff_args(eng, cdl):
 
 
 def _step_chunk(eng, cdl):
-    return cdl._paged_chunk_fn()(
+    return cdl.programs.paged_chunk_fn()(
         cdl._mp(n=cdl.n_slots), cdl._state, jnp.asarray(cdl._table),
         eng.chunk_tokens, False,
     )
 
 
 def _step_insert(eng, cdl):
-    state1 = cdl._warm_wave(16, 1)[0]
-    new = cdl._paged_insert_fn()(
+    state1 = cdl.programs.warm_wave(16, 1)[0]
+    new = cdl.programs.paged_insert_fn()(
         cdl._state, state1, np.asarray(_table_row(cdl, 3))[None],
         np.zeros(1, np.int32), 0, 16 + eng.chunk_tokens,
     )
@@ -233,12 +235,12 @@ def _step_insert(eng, cdl):
 
 
 def _step_handoff(eng, cdl):
-    return (cdl._paged_handoff_fn()(cdl._state, *_handoff_args(eng, cdl)),)
+    return (cdl.programs.paged_handoff_fn()(cdl._state, *_handoff_args(eng, cdl)),)
 
 
 def _step_prefill_window(eng, cdl):
     c = cdl.prefill_chunk
-    return (cdl._paged_prefill_fn()(
+    return (cdl.programs.paged_prefill_fn()(
         cdl._mp(n=1), cdl._state, _table_row(cdl, 2)[None],
         np.ones((1, c), np.int32), np.ones((1, c), np.int32),
         np.zeros(1, np.int32),
@@ -251,7 +253,7 @@ def _step_swap_scatter(eng, cdl):
         np.zeros((k,) + tuple(shape), dtype)
         for shape, dtype in cdl._host_leaf_specs()
     ]
-    return (cdl._swap_scatter_fn()(cdl._state, np.zeros(k, np.int32), vals),)
+    return (cdl.programs.swap_scatter_fn()(cdl._state, np.zeros(k, np.int32), vals),)
 
 
 STEPS = {
@@ -287,8 +289,47 @@ def test_state_to_state_executables_consume_their_state(kind):
         cdl.stop()
 
 
+@pytest.mark.parametrize("what", ["paged_chunk", "paged_insert"])
+def test_programs_lower_from_an_engine_and_shapes_alone(what):
+    """``LoopPrograms`` needs an engine and static shapes, no loop object:
+    it lowers the paged chunk and the paged insert over a state built by
+    hand (what ``_build_empty_paged`` builds), and builds nothing else."""
+    from mlmicroservicetemplate_tpu.models.gpt import PagedState
+
+    eng = _engine(tiny_llama_bundle(), _cfg())
+    bs, nbp, n = eng.kv_block_size, eng.kv_pool.num_blocks, 4
+    nb_max = blocks_for(32 + eng.max_decode_len, bs)
+    progs = LoopPrograms(eng, n_slots=n, block_size=bs, nb_max=nb_max)
+    with eng._lock:
+        template = progs.warm_wave(16, 1)[0]
+
+    def pool(x):
+        return jnp.zeros((nbp, bs, int(np.prod(x.shape[2:]))), x.dtype)
+
+    def rows(x):
+        return jnp.zeros((n,) + tuple(x.shape[1:]), x.dtype)
+
+    state = PagedState(
+        cache_k=[pool(c) for c in template.cache_k],
+        cache_v=[pool(c) for c in template.cache_v],
+        key_valid=jnp.zeros((n, nb_max * bs), jnp.int32),
+        write_idx=jnp.zeros(n, jnp.int32), pos=jnp.zeros(n, jnp.int32),
+        last_token=jnp.zeros(n, jnp.int32), done=jnp.ones(n, bool),
+        tokens=rows(template.tokens),
+        sample=jax.tree.map(rows, template.sample),
+    )
+    if what == "paged_chunk":
+        text = progs.paged_chunk_hlo(
+            state, np.full((n, nb_max), nbp, np.int32), debug_info=True)
+        assert "decode_chunk" in text
+    else:
+        text = progs.paged_insert_hlo(state, 16)
+        assert "HloModule" in text and "scatter" in text
+    assert set(progs.built) == {what} and not is_consumed(state)
+
+
 def _read_swap_gather(eng, cdl):
-    return cdl._swap_gather_fn()(cdl._state, np.zeros(2, np.int32))
+    return cdl.programs.swap_gather_fn()(cdl._state, np.zeros(2, np.int32))
 
 
 def _read_gather_prefix(eng, cdl):
@@ -296,7 +337,7 @@ def _read_gather_prefix(eng, cdl):
 
 
 def _read_hlo(eng, cdl):
-    return cdl.paged_chunk_hlo()
+    return cdl.programs.paged_chunk_hlo(cdl._state, cdl._table)
 
 
 @pytest.mark.parametrize(
@@ -332,16 +373,16 @@ def test_contiguous_chunk_and_insert_consume_their_state(family):
         assert cdl.spec == spec
         cdl._build_empty_state()
         prev = cdl._state
-        state1, ids, mask = cdl._warm_wave(16, 1)
+        state1, ids, mask = cdl.programs.warm_wave(16, 1)
         if spec:
             feats0 = {"input_ids": np.ones(16, np.int32),
                       "length": np.int32(16)}
             hist = cdl._hist_row(feats0, np.zeros(eng.chunk_tokens, np.int32))
-            cdl._state = cdl._insert_fn()(
+            cdl._state = cdl.programs.insert_fn()(
                 prev, state1, ids, mask, hist, np.int32(0), np.int32(0)
             )
         else:
-            cdl._state = cdl._insert_fn()(
+            cdl._state = cdl.programs.insert_fn()(
                 prev, state1, np.int32(0), np.int32(0)
             )
         assert all(x.is_deleted() for x in _leaves(prev))
@@ -462,10 +503,10 @@ def _fail_after_consuming(cdl, attr: str, nth: int):
     """Make the ``nth`` call of the loop's executable ``attr`` run for
     real — consuming the state it donates — and then fail like a flaky
     link.  Returns the list of ``is_consumed(state)`` seen at each call."""
-    getattr(cdl, f"{attr}_fn")()  # build the wrapper
-    real = getattr(cdl, attr)
+    getattr(cdl.programs, f"{attr}_fn")()  # build the wrapper
+    real = cdl.programs.built[attr]
     seen = []
-    state_arg = 1 if attr == "_paged_chunk" else 0
+    state_arg = 1 if attr == "paged_chunk" else 0
 
     def flaky(*args):
         seen.append(is_consumed(args[state_arg]))
@@ -474,11 +515,11 @@ def _fail_after_consuming(cdl, attr: str, nth: int):
             raise ConnectionError("link flapped after the dispatch")
         return out
 
-    setattr(cdl, attr, flaky)
+    cdl.programs.built[attr] = flaky
     return seen
 
 
-@pytest.mark.parametrize("attr,nth", [("_paged_chunk", 2), ("_paged_insert", 2)])
+@pytest.mark.parametrize("attr,nth", [("paged_chunk", 2), ("paged_insert", 2)])
 def test_failure_after_consumption_rebuilds_and_holds_the_swap(attr, nth):
     bundle = tiny_llama_bundle()
     cfg = _cfg(dispatch_retries=2, dispatch_backoff_s=0.001,
@@ -497,7 +538,7 @@ def test_failure_after_consumption_rebuilds_and_holds_the_swap(attr, nth):
                 asyncio.ensure_future(_consume(cdl.submit_stream(dict(f))))
                 for f in feats[:2]
             ]
-            if attr == "_paged_insert":
+            if attr == "paged_insert":
                 # A wave is ONE insert: the third stream comes as a wave
                 # of its own, and its insert (the nth) consumes the
                 # state the first wave's streams live in.
@@ -562,7 +603,7 @@ def test_unsupervised_failure_after_consumption_rebuilds_lazily():
     eng = _engine(bundle, cfg)
     feats = _prompts(2)
     cdl = ContinuousDecodeLoop(eng, cfg)
-    seen = _fail_after_consuming(cdl, "_paged_chunk", 2)
+    seen = _fail_after_consuming(cdl, "paged_chunk", 2)
     try:
         async def doomed():
             return await asyncio.gather(

@@ -809,8 +809,12 @@ def test_warm_covers_every_wave_size(mode, monkeypatch):
                     feats = [text_feats(tok, f"{k}{i}-{body}")
                              for i in range(k)]
                     for hit in range(passes):
+                        # An IDLE loop holds an announced burst for its
+                        # stragglers (``_collect_burst``, ``one_wave``'s
+                        # ``Arrival``); one with a chunk still in flight
+                        # admits what is queued at its boundary.
                         t_end = time.monotonic() + 5.0
-                        while cdl.active:  # the last wave's slots
+                        while not cdl.idle():  # the last wave, all of it
                             assert time.monotonic() < t_end
                             time.sleep(0.002)
                         outs = _run_concurrent(cdl, feats)

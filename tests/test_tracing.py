@@ -409,8 +409,9 @@ def test_paged_decode_chunk_carries_scope_names():
     bundle, eng, cdl = _paged_llama()
     try:
         _consume(cdl, text_feats(bundle.tokenizer, "hello world"))
-        hlo = cdl.paged_chunk_hlo(debug_info=True)
-        bare = cdl.paged_chunk_hlo()
+        hlo = cdl.programs.paged_chunk_hlo(
+            cdl._state, cdl._table, debug_info=True)
+        bare = cdl.programs.paged_chunk_hlo(cdl._state, cdl._table)
     finally:
         cdl.stop()
     for scope in ("decode_chunk", "embed", "qkv_rope", "kv_write", "attn",

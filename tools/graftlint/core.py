@@ -111,7 +111,8 @@ def _parse_waivers(source: str) -> dict[int, list[tuple[str, str]]]:
 
 def callee_name(node: ast.AST) -> str:
     """Best-effort name of a call's target: the attribute/identifier,
-    or — for immediately-invoked accessors like ``self._paged_chunk_fn()(…)``
+    or — for immediately-invoked accessors like
+    ``self.programs.paged_chunk_fn()(…)`` (engine/programs.py)
     — the INNER accessor's name (what the repo's rules key on)."""
     func = node.func if isinstance(node, ast.Call) else node
     if isinstance(func, ast.Attribute):

@@ -13,8 +13,8 @@ none the wiser.
 This rule flags calls inside ``engine/`` and ``scheduler/`` that hit a
 device-dispatch surface — registry decode/prefill executables
 (``generate_chunk*``, ``prefill_chunk*``), the repo's
-immediately-invoked jit accessors (``self._paged_chunk_fn()(…)``,
-``self._paged_handoff_fn()(…)``, …) and host↔device syncs
+immediately-invoked jit accessors (``programs.paged_chunk_fn()(…)``,
+``programs.paged_handoff_fn()(…)``, … of ``engine/programs.py``) and host↔device syncs
 (``jax.device_get`` / ``device_put`` / ``block_until_ready``) — unless
 the call sits inside a callable passed to ``dispatch_guard`` (or the
 watchdog's ``run``), or carries an explicit waiver::
@@ -44,7 +44,8 @@ import re
 
 from ..core import Context, Finding, callee_name, dotted_name
 
-# Immediately-invoked jit-accessor idiom: ``self._paged_chunk_fn()(…)``.
+# Immediately-invoked jit-accessor idiom of engine/programs.py:
+# ``self.programs.paged_chunk_fn()(…)``.
 _ACCESSOR_RE = re.compile(
     r"^_?[a-z0-9_]*(chunk|prefill|handoff|scatter|gather|swap)"
     r"[a-z0-9_]*_fn$"
