@@ -591,6 +591,10 @@ class ContinuousDecodeLoop:
         self._idle_gap_s = 0.0
         self.idle_wait_rows = 0     # rows idle admissions' waits added
         self.idle_waits_capped = 0  # waits that ended on the cap
+        # Waves that met chunks in flight, and the chunks delivered ahead
+        # of those waves' fetches (``_deliver_ahead_of_wave``).
+        self.waves_behind_chunks = 0
+        self.chunks_ahead_of_wave = 0
         # Where this loop's thread spends its wall time, by phase, always
         # on (utils/tracing.LoopTable; /status.decode.loop_time).  The
         # idle admissions that waited at all, and for how long, are its
@@ -1078,18 +1082,21 @@ class ContinuousDecodeLoop:
                 if wave:
                     # Overlapped admission, AFTER the live chunk's
                     # dispatch: the wave's batched prefill queues
-                    # BEHIND it on the device, so live streams never
-                    # pay the prefill compute (round-3 verdict missing
-                    # #2) — the admitted streams pay one ~chunk-compute
-                    # delay instead, a better trade at every model
-                    # size.  The prefill FETCH then also rides behind
-                    # the chunk dispatch (async host copies started at
-                    # dispatch).
+                    # BEHIND every chunk in flight on the device, and
+                    # the host reads in the device's order — each of
+                    # those chunks is delivered as it lands, and only
+                    # then does the loop block on the wave's fetch.
+                    # A live stream pays the prefill compute ONCE, in
+                    # the one gap that spans the admission: start +
+                    # insert + the next chunk, and nothing else (the
+                    # admitted streams pay the chunks queued ahead of
+                    # their start).
                     with tracing.phase("loop/wave_dispatch"):
                         self._pending_admissions = self._admit_dispatch(wave)
                 self._pending_wave = []
                 if self._pending_admissions:
                     rows = self._wave_rows(len(self._pending_admissions))
+                    self._deliver_ahead_of_wave()
                     self._admit_complete(self._pending_admissions)
                     self._pending_admissions = []
                     self._wave_seconds[rows] = time.monotonic() - t_admit
@@ -2386,7 +2393,11 @@ class ContinuousDecodeLoop:
         """Phase 2: one combined ``device_get`` fetches every admitted
         stream's first chunk + done flag (a wave costs ~one RTT, not
         N — batched waves share one (toks, done) pair, fetched once),
-        then emit + insert into free slots."""
+        then emit + insert into free slots.  The caller has drained the
+        chunks in flight first (``_deliver_ahead_of_wave``): this fetch
+        is for the LAST thing in the device's queue, so nothing that
+        has landed waits behind it, and a live stream's gap across the
+        admission is start + insert + its next chunk."""
         import jax
 
         if not started:
@@ -4621,6 +4632,27 @@ class ContinuousDecodeLoop:
                 "fetch", lambda: jax.device_get(fetchables)
             )
             self._route_entry(fetched, snapshot)
+
+    def _deliver_ahead_of_wave(self) -> None:
+        """A wave's prefill was just dispatched beside chunks in flight:
+        every one of them was dispatched ahead of it, so each lands
+        before it — deliver them oldest first, EACH in a fetch of its
+        own as it lands (one combined fetch would hold the oldest until
+        the newest has landed), before the caller blocks on the wave's
+        fetch.  The device's queue is as it was; only the host reads it
+        in its order.  A delivery may end a stream and free its slot and
+        blocks before the insert takes one.  A fault raised here leaves
+        ``_pending_admissions`` set for ``_recover`` and the loop's
+        handler, as one raised around the wave's own insert does."""
+        if not self._inflight_chunks:
+            return
+        name = self.engine.bundle.name
+        self.waves_behind_chunks += 1
+        metrics.WAVES_BEHIND_CHUNKS.labels(name).inc()
+        while self._inflight_chunks:
+            self._deliver_oldest()
+            self.chunks_ahead_of_wave += 1
+            metrics.CHUNKS_AHEAD_OF_WAVE.labels(name).inc()
 
     def _deliver_all(self) -> None:
         """Drain every in-flight dispatch with ONE combined device_get."""

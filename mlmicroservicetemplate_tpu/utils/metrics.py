@@ -156,6 +156,20 @@ IDLE_ADMIT_CAPPED = Counter(
     "with a request still announced",
     ["model"],
 )
+WAVES_BEHIND_CHUNKS = Counter(
+    "stream_waves_behind_chunks_total",
+    "Admission waves whose prefill was dispatched beside decode chunks "
+    "in flight (a wave on an idle loop is not counted)",
+    ["model"],
+)
+CHUNKS_AHEAD_OF_WAVE = Counter(
+    "stream_chunks_ahead_of_wave_total",
+    "In-flight decode chunks delivered, oldest first and each in a "
+    "fetch of its own, ahead of a wave's fetch: over "
+    "stream_waves_behind_chunks_total, the chunks a wave (about the "
+    "chain depth + 1 where admissions meet live streams)",
+    ["model"],
+)
 PREFILL_WAVE_FILL = Histogram(
     "prefill_wave_fill",
     "Useful share of one prefill executable run: real prompt tokens "

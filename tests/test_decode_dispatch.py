@@ -13,7 +13,12 @@ The judged contracts:
    checkpoints at the delivered-token cursor and resumes
    token-identically (supervised rebuild).
 5. Admission rides BEHIND the live chunk: with streams live, a wave's
-   prefill is dispatched after the iteration's chunk.
+   prefill is dispatched after the iteration's chunk, and the chunks in
+   flight are delivered, oldest first and each in a fetch of its own,
+   BEFORE the loop blocks on the wave's fetch (the host reads in the
+   device's order; no token changes; a stream that ends there frees its
+   slot before the insert; ``stream_chunks_ahead_of_wave_total`` /
+   ``stream_waves_behind_chunks_total`` count it).
 6. The auto-tuned chain depth is pinned (``warm.depth_from``) and surfaced
    (stream_chain_depth gauge + /status.decode), beside the staged
    host prep's counters.
@@ -360,6 +365,181 @@ def test_admission_rides_behind_the_live_chunk():
     assert after_b.index("loop/insert") < after_b.index("loop/chunk_dispatch")
 
 
+def _layout(paged: bool) -> dict:
+    return dict(paged_kv=True, kv_block_size=8) if paged else {}
+
+
+def _b_meets_a_live(cdl):
+    """Hold the loop's thread at the top of the first iteration that
+    finds a stream live until a newcomer sits in the queue, so that
+    iteration dispatches the live stream's chunk, pops the newcomer and
+    admits it as a wave BESIDE that chunk — whatever the threads' pace."""
+    real, met = cdl._expire_queued, []
+
+    def gate():
+        if cdl.active and not met:
+            met.append(_wait(lambda: cdl.queue.qsize() > 0))
+        real()
+
+    cdl._expire_queued = gate
+    return met
+
+
+def _a_then_b(cdl, fa, fb):
+    """A's first chunk, then B submitted and read to its end, then the
+    rest of A: (A's tokens, B's tokens)."""
+    async def body():
+        gen_a = cdl.submit_stream(dict(fa))
+        first = np.asarray(await gen_a.__anext__()).tolist()
+        out_b = await _consume(cdl.submit_stream(dict(fb)))
+        return first + await _consume(gen_a), out_b
+
+    return asyncio.run(body())
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_chunks_in_flight_are_delivered_ahead_of_the_wave(paged):
+    """With A live, B's admission shows ``loop/deliver`` spans — one a
+    chunk in flight — BETWEEN B's ``loop/wave_dispatch`` and its
+    ``loop/wave_fetch`` on the loop's thread, and every token of A from
+    a chunk dispatched ahead of B's start is emitted before B's first
+    token: nothing that landed waits behind the wave's fetch."""
+    bundle = tiny_gpt_bundle()
+    cfg = _cfg(max_decode_len=160, **_layout(paged))
+    cdl = ContinuousDecodeLoop(_engine(bundle, cfg), cfg)
+    rng = np.random.default_rng(8)
+    fa, fb = _feats(rng, 9), _feats(rng, 6, max_tokens=8)
+    met = _b_meets_a_live(cdl)
+    seen = []  # per wave: (chunks in flight, chunks dispatched so far)
+    emitted = []  # (prompt length, tokens) in the loop's emit order
+    real_ahead, real_emit = cdl._deliver_ahead_of_wave, cdl._emit_tokens
+
+    def ahead():
+        seen.append((len(cdl._inflight_chunks), cdl.chunk_dispatches))
+        real_ahead()
+
+    def emit(st, chunk):
+        emitted.append((int(st.feats["length"]), int(np.asarray(chunk).size)))
+        real_emit(st, chunk)
+
+    cdl._deliver_ahead_of_wave, cdl._emit_tokens = ahead, emit
+    tr = tracing.configure(True, 8192)
+    try:
+        out_a, out_b = _a_then_b(cdl, fa, fb)
+        spans = tr.snapshot()
+    finally:
+        tracing.configure(False)
+        cdl.stop()
+    assert met == [True] and len(out_a) == 160 and len(out_b) == 8
+    assert len(seen) == 2 and seen[0][0] == 0  # A's wave met an idle loop
+    n_flight, n_dispatched = seen[1]
+    assert 1 <= n_flight <= cdl.chain_depth + 1
+    loop = [s.name for s in sorted(
+        (s for s in spans if s.name.startswith("loop/")), key=lambda s: s.t0)]
+    waves = [i for i, n in enumerate(loop) if n == "loop/wave_dispatch"]
+    fetches = [i for i, n in enumerate(loop) if n == "loop/wave_fetch"]
+    assert len(waves) == 2 and len(fetches) == 2
+    # A's own wave: nothing in flight, today's path, no delivery in it.
+    assert loop[waves[0] + 1: fetches[0]] == ["loop/wave_complete"]
+    assert loop[waves[1] + 1: fetches[1]] == (
+        ["loop/deliver"] * n_flight + ["loop/wave_complete"])
+    # B's first token: A has by then been handed its start's chunk and
+    # every chunk that was dispatched before B's start.
+    first_b = emitted.index((6, 4))
+    a_before = sum(n for length, n in emitted[:first_b] if length == 9)
+    assert a_before == 4 * (1 + n_dispatched)
+    assert (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave) == (1, n_flight)
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_reading_ahead_of_the_wave_changes_no_token(paged):
+    """A and B admitted side by side read token for token what each reads
+    admitted alone on an idle loop — the device's queue is the same,
+    only the host's order of reads differs — and an A that ENDS in a
+    chunk delivered ahead of B's wave has left its slot (and, paged, its
+    blocks) before B's insert takes one."""
+    bundle = tiny_gpt_bundle()
+    cfg = _cfg(max_decode_len=24, max_streams=2, **_layout(paged))
+    eng = _engine(bundle, cfg)
+    rng = np.random.default_rng(21)
+    # A: its start's chunk + ONE decode chunk, the one B's wave meets.
+    fa, fb = _feats(rng, 9, max_tokens=8), _feats(rng, 6, max_tokens=12)
+    idle = ContinuousDecodeLoop(eng, cfg)
+    try:
+        alone = [_run(idle, [f])[0] for f in (fa, fb)]
+        assert (idle.waves_behind_chunks, idle.chunks_ahead_of_wave) == (0, 0)
+    finally:
+        idle.stop()
+    cdl = ContinuousDecodeLoop(eng, cfg)
+    met = _b_meets_a_live(cdl)
+    at_insert = []
+    real_insert = cdl._emit_and_insert
+
+    def insert(started, fetched):
+        at_insert.append((
+            len(cdl.active), len(cdl.free),
+            eng.kv_pool.used_blocks if paged else 0))
+        real_insert(started, fetched)
+
+    cdl._emit_and_insert = insert
+    try:
+        out_a, out_b = _a_then_b(cdl, fa, fb)
+        assert met == [True]
+        assert [out_a, out_b] == alone
+        assert len(out_a) == 8 and len(out_b) == 12
+        assert (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave) == (1, 1)
+        # A's insert found the loop empty; so did B's: A ended in the
+        # chunk delivered ahead of B's wave and gave everything back.
+        assert at_insert == [(0, 2, 0), (0, 2, 0)]
+        if paged:
+            assert _wait(lambda: eng.kv_pool.used_blocks == 0)
+    finally:
+        cdl.stop()
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+def test_ahead_of_wave_counters(depth):
+    """``stream_chunks_ahead_of_wave_total`` grows by the chunks in
+    flight when a wave is dispatched beside live streams — at most the
+    chain depth + 1 — and ``stream_waves_behind_chunks_total`` by one;
+    a wave on an idle loop moves neither."""
+    bundle = tiny_gpt_bundle()
+    cfg = _cfg(max_decode_len=64, stream_pipeline=depth, paged_kv=True,
+               kv_block_size=8)
+    cdl = ContinuousDecodeLoop(_engine(bundle, cfg), cfg)
+    rng = np.random.default_rng(13)
+    fa, fb = _feats(rng, 9), _feats(rng, 6, max_tokens=8)
+
+    def read():
+        if not metrics.HAVE_PROM:
+            return cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave
+        return (int(metrics.WAVES_BEHIND_CHUNKS.labels("gpt2")._value.get()),
+                int(metrics.CHUNKS_AHEAD_OF_WAVE.labels("gpt2")._value.get()))
+
+    flight = []
+    real_ahead = cdl._deliver_ahead_of_wave
+
+    def ahead():
+        flight.append(len(cdl._inflight_chunks))
+        real_ahead()
+
+    cdl._deliver_ahead_of_wave = ahead
+    try:
+        before = read()
+        _run(cdl, [fb])  # a wave on an idle loop
+        assert read() == before and flight == [0]
+        met = _b_meets_a_live(cdl)
+        _a_then_b(cdl, fa, fb)
+        after = read()
+    finally:
+        cdl.stop()
+    # ... A's own wave met an idle loop too; B's met A's chunks.
+    assert met == [True] and flight[:2] == [0, 0] and len(flight) == 3
+    assert 1 <= flight[2] <= depth + 1
+    assert (after[0] - before[0], after[1] - before[1]) == (1, flight[2])
+    assert (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave) == (1, flight[2])
+
+
 # ---------------------------------------------------------------------------
 # 6. chain depth: pinned and surfaced
 
@@ -409,6 +589,7 @@ def test_status_surfaces_chain_depth_and_prep_counters():
     assert dec["chunk_tokens"] == 4
     assert {"chunk_dispatches", "tokens_emitted", "prep_staged", "prep_hits",
             "prep_misses", "dispatch_counts"} <= set(dec)
+    assert dec["ahead_of_wave"] == {"waves": 0, "chunks": 0}
 
 
 def test_chain_depth_gauge_set_on_tune():

@@ -498,6 +498,90 @@ def test_injected_oob_checkpoints_and_resumes():
         cdl.stop()
 
 
+@pytest.mark.parametrize("supervised", [True, False],
+                         ids=["supervised", "unsupervised"])
+def test_fetch_fault_ahead_of_a_wave_settles_its_streams_once(supervised):
+    """The loop delivers the chunks in flight before it blocks on a
+    wave's fetch.  A fetch that fails among THOSE deliveries reaches the
+    loop's handler with the wave still pending: supervised, the wave's
+    stream and the live one are each checkpointed and re-queued once and
+    finish token-identically after one rebuild; unsupervised, each
+    consumer gets the error once.  Either way the loop admits the next
+    request."""
+    from test_decode_dispatch import _b_meets_a_live, _wait
+
+    cfg = _paged_cfg(max_decode_len=24)
+    bundle = tiny_gpt_bundle()
+    eng = InferenceEngine(bundle, cfg, ReplicaSet(make_mesh(1)))
+    fa, fb, fc = (text_feats(bundle.tokenizer, t) for t in
+                  ("the live stream's prompt", "a newcomer", "the next one"))
+    solos = [_solo_tokens(eng, f).tolist() for f in (fa, fb, fc)]
+    cdl = (_supervised_cdl if supervised else ContinuousDecodeLoop)(eng, cfg)
+    met = _b_meets_a_live(cdl)
+    fired, settled = [], []
+    real_oldest, real_requeue, real_finish = (
+        cdl._deliver_oldest, cdl._checkpoint_requeue, cdl._finish)
+
+    def oldest():
+        if not cdl._pending_admissions or fired:
+            return real_oldest()
+        # The first delivery ahead of the newcomer's wave: the entry
+        # leaves the chain as the real one's does, then its fetch fails.
+        fired.append(len(cdl._inflight_chunks))
+        cdl._inflight_chunks.pop(0)
+
+        def lost():
+            raise FatalDeviceError("injected: a fetch ahead of the wave")
+
+        eng.dispatch_guard("fetch", lost)
+
+    def requeue(st):
+        settled.append(("requeued", int(st.feats["length"])))
+        return real_requeue(st)
+
+    def finish(st, *item):
+        if item and isinstance(item[0], Exception):
+            settled.append(("failed", int(st.feats["length"])))
+        return real_finish(st, *item)
+
+    cdl._deliver_oldest, cdl._checkpoint_requeue, cdl._finish = (
+        oldest, requeue, finish)
+
+    async def outcome(gen):
+        try:
+            return (await _collect(gen)).tolist()
+        except FatalDeviceError as e:
+            return e
+
+    async def body():
+        gen_a = cdl.submit_stream(dict(fa))
+        first = np.asarray(await gen_a.__anext__()).tolist()
+        out_b, rest_a = await asyncio.gather(
+            outcome(cdl.submit_stream(dict(fb))), outcome(gen_a))
+        out_c = await outcome(cdl.submit_stream(dict(fc)))
+        return first, rest_a, out_b, out_c
+
+    try:
+        first, rest_a, out_b, out_c = asyncio.run(body())
+        assert met == [True] and len(fired) == 1 and fired[0] >= 1
+        len_a, len_b = int(fa["length"]), int(fb["length"])
+        if supervised:
+            assert first + rest_a == solos[0] and out_b == solos[1]
+            assert cdl.supervisor.restarts == 1
+            assert sorted(settled) == sorted(
+                [("requeued", len_a), ("requeued", len_b)])
+        else:
+            assert isinstance(rest_a, FatalDeviceError)
+            assert isinstance(out_b, FatalDeviceError)
+            # the wave's stream through the handler's pending list, once
+            assert settled == [("failed", len_b)]
+        assert out_c == solos[2]  # ... and the loop admits the next request
+        assert _wait(lambda: eng.kv_pool.used_blocks == 0), eng.kv_pool.stats()
+        assert sorted(cdl.free) == list(range(cdl.n_slots)) and not cdl.active
+    finally:
+        cdl.stop()
+
+
 # ---------------------------------------------------------------------------
 # 6. chaos tier (kept out of tier-1; scripts/check.sh runs it)
 

@@ -109,14 +109,18 @@ GOLDEN = {
 def _staggered(cdl, feats):
     """Two streams, two more once chunks are in flight, the last two
     into a full loop (they admit as slots free, chunks still flying)."""
-    depth_at_insert = []
-    orig = cdl._emit_and_insert
+    depth_at_start, depth_at_insert = [], []
+    orig, orig_ahead = cdl._emit_and_insert, cdl._deliver_ahead_of_wave
 
     def spy(started, fetched):
         depth_at_insert.append(len(cdl._inflight_chunks))
         return orig(started, fetched)
 
-    cdl._emit_and_insert = spy
+    def spy_ahead():
+        depth_at_start.append(len(cdl._inflight_chunks))
+        return orig_ahead()
+
+    cdl._emit_and_insert, cdl._deliver_ahead_of_wave = spy, spy_ahead
 
     async def until(cond):
         for _ in range(400):
@@ -141,7 +145,7 @@ def _staggered(cdl, feats):
         ]
         return await asyncio.gather(*tasks)
 
-    return asyncio.run(body()), depth_at_insert
+    return asyncio.run(body()), (depth_at_start, depth_at_insert)
 
 
 @pytest.mark.parametrize("family", ["llama", "gpt"])
@@ -154,14 +158,18 @@ def test_pipelined_paged_loop_is_token_identical(family):
     cdl = ContinuousDecodeLoop(eng, cfg)
     assert cdl.chain_depth == 3
     try:
-        outs, depth_at_insert = _staggered(cdl, feats)
+        outs, (depth_at_start, depth_at_insert) = _staggered(cdl, feats)
     finally:
         cdl.stop()
     assert outs == solos
     assert [(sum(o), o[-4:]) for o in outs] == GOLDEN[family]
-    # The case the old "NOT donated" comment feared: rows were inserted
-    # while earlier chunks' (toks, done) were still to be fetched.
-    assert len(depth_at_insert) >= 3 and max(depth_at_insert) >= 1
+    # The case the old "NOT donated" comment feared: a wave's start went
+    # out while earlier chunks' (toks, done) were still to be fetched,
+    # after later dispatches had consumed the states they came from.
+    # (They are fetched before the wave's own fetch now, so the insert
+    # that donates the state finds none of them left.)
+    assert len(depth_at_insert) >= 3 and max(depth_at_start) >= 1
+    assert max(depth_at_insert) == 0
     assert cdl.chunk_dispatches >= 6
     assert not is_consumed(cdl._state)
 
