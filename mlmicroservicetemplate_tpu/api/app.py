@@ -1589,6 +1589,22 @@ async def handle_status(request: web.Request) -> web.Response:
                 "bytes_held": held * engine.stream_fixed_bytes(),
                 "kv_token_bytes": engine.kv_token_bytes(),
             }
+            # The three stores apart, as allocated: the paged pool (the
+            # layers that keep every key), the window layers' rings and the
+            # recurrent rows (both a fixed size a stream).
+            mcfg = engine.bundle.cfg
+            pool = getattr(engine, "kv_pool", None)
+            body["decode"]["stores"] = {
+                "pool_bytes": (pool.num_blocks * engine.kv_block_bytes()
+                               if pool is not None else 0),
+                "window_store_bytes": cdl.n_slots * mcfg.window_row_bytes,
+                "state_bytes": cdl.n_slots * mcfg.ssm_row_bytes,
+                "pool_layers": len(mcfg.cache_layers),
+                "window_store_layers": len(mcfg.ring_layers),
+                "shared_pool_readers": sum(
+                    1 for li in range(mcfg.num_layers)
+                    if mcfg.layer_kind(li).store == "shared"),
+            }
         if getattr(cdl, "moe_rows", None):
             # Expert FFN: assignment rows the block's row work ran over
             # and rows it skipped (ops/moe.row_rungs), and the held rows of

@@ -737,8 +737,11 @@ class InferenceEngine:
 
     def stream_fixed_bytes(self) -> int:
         """Bytes a stream holds whatever its length: a row of recurrent
-        state (a model with Mamba layers; 0 otherwise)."""
-        return int(getattr(self.bundle.cfg, "ssm_row_bytes", 0) or 0)
+        state (a model with Mamba layers) and its window layers' rings (a
+        model whose window store is a ring: ``window_ring``); 0 otherwise."""
+        cfg = self.bundle.cfg
+        return int((getattr(cfg, "ssm_row_bytes", 0) or 0)
+                   + (getattr(cfg, "window_row_bytes", 0) or 0))
 
     def kv_block_bytes(self) -> int:
         """Bytes one ``KV_BLOCK_SIZE``-token block costs (paged mode)."""

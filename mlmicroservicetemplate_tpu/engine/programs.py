@@ -129,10 +129,11 @@ def paged_insert(block_size: int):
 
 
 def _insert_ssm(batched, single, slots, ssm_rows) -> dict:
-    """Each wave row's recurrent state into state row ``ssm_rows[i]``
-    (past the last row: dropped — warm-up, a row that does not insert),
-    and its slot pointed at it; nothing for a model without recurrent
-    layers (its ``ssm`` is the empty default)."""
+    """Each wave row's state — a recurrent layer's taps and state, a window
+    layer's ring: every per-row leaf of ``SsmState`` — into state row
+    ``ssm_rows[i]`` (past the last row: dropped — warm-up, a row that does
+    not insert), and its slot pointed at it; nothing for a model without
+    state rows (its ``ssm`` is the empty default)."""
     if ssm_rows is None:
         return {"ssm": batched.ssm}
 
@@ -141,9 +142,9 @@ def _insert_ssm(batched, single, slots, ssm_rows) -> dict:
 
     b, s = batched.ssm, single.ssm
     return {"ssm": b._replace(
-        conv=[put(d, x) for d, x in zip(b.conv, s.conv)],
-        state=[put(d, x) for d, x in zip(b.state, s.state)],
         row=b.row.at[slots].set(ssm_rows, mode="drop"),
+        **{f: [put(d, x) for d, x in zip(dst, getattr(s, f))]
+           for f, dst in b.leaves.items()},
     )}
 
 

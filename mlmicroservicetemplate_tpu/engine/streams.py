@@ -405,9 +405,18 @@ class ContinuousDecodeLoop:
         # the streams that can hold one (live or in prefill) to ``n_slots``.
         self._ssm_free = None
         self._ssm_fused = False  # the rows' prompt scans run a fused kernel
-        if self.paged and getattr(bcfg, "recurrent_layers", ()):
+        if self.paged and getattr(bcfg, "state_rows", False):
             self._ssm_free = list(range(self.n_slots))[::-1]
             self._ssm_fused = bcfg.scan_fused
+        # A cross-decoder (layers that own nothing and run only where a
+        # logit is read): prompt positions are counted through each half.
+        self._cross_decoder = (
+            getattr(bcfg, "cross_from", 0) < getattr(bcfg, "num_layers", 0))
+        # (ring layers, keys a ring holds) of window layers whose store is a
+        # ring a stream beside its state row, or None.
+        self._ring = (
+            (len(bcfg.ring_layers), int(bcfg.window_ring))
+            if getattr(bcfg, "ring_layers", ()) else None)
         # (window layers, window) of a per-layer pattern, or None.
         types = getattr(bcfg, "layer_types", ())
         self._window_layers = (
@@ -417,7 +426,9 @@ class ContinuousDecodeLoop:
         # Layers that cache keys: the attention layers (a recurrent or an
         # FFN-only layer has no pool), every layer of a model without kinds.
         self._attn_layers = (
-            len(bcfg.cache_layers) if hasattr(bcfg, "cache_layers")
+            sum(1 for li in range(bcfg.num_layers)
+                if bcfg.layer_kind(li).attention)
+            if hasattr(bcfg, "layer_kind")
             else int(getattr(bcfg, "num_layers", 0)))
         # Those whose cache is a latent row a token (all of them, or none).
         self._latent_layers = (
@@ -2182,6 +2193,7 @@ class ContinuousDecodeLoop:
                         st.s_base = s_own
                     self.prefill_dispatches += 1
                     self._note_wave_fill(L, 1, s_own)
+                    self._note_prompt_positions(L, went_live=1)
                     prefetch_to_host(toks, state1.done)
                     started.append((st, state1, toks, sampled, 0, None, None))
                 return started
@@ -2214,6 +2226,8 @@ class ContinuousDecodeLoop:
                 sum(int(st.feats["length"]) for st in ok),
                 int(ids.shape[0]), int(ids.shape[1]),
             )
+            self._note_prompt_positions(
+                sum(int(st.feats["length"]) for st in ok), went_live=len(ok))
             prefetch_to_host(toks, state1.done)
             for row, st in enumerate(ok):
                 # Slot sampling is PER ROW, not the wave-level flag the
@@ -2293,6 +2307,7 @@ class ContinuousDecodeLoop:
             self._note_wave_fill(
                 real_tokens, int(ids.shape[0]), int(ids.shape[1])
             )
+            self._note_prompt_positions(real_tokens, went_live=len(streams))
             prefetch_to_host(toks, state1.done)
             for row, st in enumerate(streams):
                 row_sampled = float(st.feats.get("temperature", 0.0)) > 0.0
@@ -2710,8 +2725,37 @@ class ContinuousDecodeLoop:
         for state, n in (("live", live), ("prefill", held - live),
                          ("free", len(self._ssm_free))):
             metrics.SSM_STATE_ROWS.labels(name, state).set(n)
-        metrics.SSM_STATE_BYTES.labels(name).set(
-            held * self.engine.bundle.cfg.ssm_row_bytes)
+        bcfg = self.engine.bundle.cfg
+        metrics.SSM_STATE_BYTES.labels(name).set(held * bcfg.ssm_row_bytes)
+        if self._ring is not None:
+            metrics.KV_WINDOW_STORE_BYTES.labels(name).set(
+                held * bcfg.window_row_bytes)
+
+    def _note_prompt_positions(self, real: int, went_live: int = 0) -> None:
+        """Prompt positions a dispatch ran: ``real`` through the
+        self-decoder (a window's or a wave's real tokens), ``went_live``
+        through the cross-decoder — a prompt's LAST position, which the
+        first decode step of a stream that just went live (a handoff, or a
+        wave's own first chunk) runs through every layer; no window and no
+        wave's prefill runs a cross-decoder layer (models/llama._layers'
+        ``self_only``).  Counted for a model with a
+        cross-decoder alone."""
+        if not self._cross_decoder:
+            return
+        name = self.engine.bundle.name
+        if real:
+            metrics.PREFILL_SELF_POSITIONS.labels(name).inc(real)
+        if went_live:
+            metrics.PREFILL_CROSS_POSITIONS.labels(name).inc(went_live)
+
+    def _note_ring_keys(self, overwritten: int) -> None:
+        """Window keys the rings overwrote (a ring layer each): positions
+        written at or past ``window_ring``, each landing on the key
+        ``window_ring`` before it — what a table would have kept behind the
+        window."""
+        if overwritten:
+            metrics.KV_WINDOW_KEYS_OVERWRITTEN.labels(
+                self.engine.bundle.name).inc(overwritten * self._ring[0])
 
     def _note_ssm_scan(self, scanned: int, real: int) -> None:
         name = self.engine.bundle.name
@@ -2954,6 +2998,11 @@ class ContinuousDecodeLoop:
                     self.admission.note_pool()
                 if self._ssm_free is not None:
                     self._note_ssm_scan(rows * c, int(mask_w.sum()))
+                self._note_prompt_positions(int(mask_w.sum()))
+                if self._ring is not None:
+                    self._note_ring_keys(
+                        sum(max(end - max(job.consumed, self._ring[1]), 0)
+                            for job, end in zip(jobs, ends)))
             else:
                 (job,) = jobs
                 with eng._lock:
@@ -3054,6 +3103,7 @@ class ContinuousDecodeLoop:
             return False
         self.active[slot] = st
         self._note_ssm_rows()
+        self._note_prompt_positions(0, went_live=1)
         if sampled:
             self.sampled_slots.add(slot)
         # Chunked streams donate like monolithic admissions do at
@@ -3291,6 +3341,14 @@ class ContinuousDecodeLoop:
                 eng._host_demote_on = prev
         bs = self.block_size
         nbp = self.pool.num_blocks
+        # The template is a slab state: an entry a layer that owns keys.
+        # Only those that are pools under the block table become pools (a
+        # window layer's ring is a state row's: ``SsmState.ring_k``).
+        entries = getattr(eng.bundle.cfg, "pool_entries", None)
+        if entries is not None and len(entries) != len(template.cache_k):
+            template = template._replace(
+                cache_k=[template.cache_k[i] for i in entries],
+                cache_v=[template.cache_v[i] for i in entries])
         # A token's dims as the contiguous prefill state carries them
         # ((KVH, D) payload, (KVH, 1) scale), in _host_leaf_specs'
         # leaf order: what a dense gather unmerges (_gather_prefix).
@@ -3348,8 +3406,8 @@ class ContinuousDecodeLoop:
                         for x in leaves]
 
             empty = empty._replace(ssm=t._replace(
-                conv=rows(t.conv), state=rows(t.state),
                 row=np.full((self.n_slots,), self.n_slots, np.int32),
+                **{f: rows(v) for f, v in t.leaves.items()},
             ))
             self._note_ssm_rows()
         # Pool leaves commit sharded over 'tp' on the merged heads axis
@@ -4384,6 +4442,13 @@ class ContinuousDecodeLoop:
             self._note_table_blocks(eng.chunk_tokens)
             if self._window_layers:
                 self._note_window_keys(eng.chunk_tokens)
+            if self._ring is not None:
+                # a chunk writes positions [last - steps, last) of a stream
+                steps, rlen = eng.chunk_tokens, self._ring[1]
+                lasts = [st.s_base + self._dispatched_steps.get(slot, steps)
+                         for slot, st in self.active.items()]
+                self._note_ring_keys(sum(
+                    max(last - max(last - steps, rlen), 0) for last in lasts))
         if self._moe_windows:
             # Dispatched before this chunk, so done before it: their counts
             # ride its fetch and no fetch waits on a prompt dispatch.

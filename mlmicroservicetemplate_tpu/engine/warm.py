@@ -295,8 +295,11 @@ def autotune_kernel(loop) -> None:
         getattr(scfg, "device", None),
         getattr(scfg, "compile_cache_dir", None),
     )
-    kvh = int(getattr(bcfg, "num_kv_heads", bcfg.num_heads))
-    kind, d = "paged_decode", int(bcfg.head_dim)
+    # KV heads and their width AS THE KERNEL SEES THEM (a differential
+    # pair is one head two heads wide: LlamaConfig.kv_groups / kv_tail).
+    kvh = int(getattr(bcfg, "kv_groups", 0)
+              or getattr(bcfg, "num_kv_heads", bcfg.num_heads))
+    kind, d = "paged_decode", int(getattr(bcfg, "kv_tail", (0, bcfg.head_dim))[1])
     if getattr(bcfg, "latent_lanes", 0):
         # One KV "head" every query head shares, as wide as the pool.
         kind, kvh, d = "latent_decode", 1, int(bcfg.latent_lanes)

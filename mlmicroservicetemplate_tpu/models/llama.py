@@ -16,7 +16,12 @@ LM head of its own (``tie_embeddings``: the embedding table read again,
 transposed).  Two published variations of the block are config
 fields, off by default: a sparse expert FFN in place of the MLP
 (``num_experts``; ops/moe.py — OLMoE-1B-7B: 64 experts, top-8) and an
-RMSNorm on q and k before RoPE (``qk_norm``).
+RMSNorm on q and k before RoPE (``qk_norm``).  Later variations, each a
+config field whose default leaves the block as it was, are documented on
+``LlamaConfig``: per-layer patterns and window layers, latent attention, three
+recurrences with state rows beside the pool, and a cross-decoder whose layers
+read what an earlier layer of the same step produced (``layer_types`` "cross"
+/ "gmu", differential attention, window rings).
 
 Decode reuses ``gpt.GPTState`` verbatim — the per-row
 (write_idx/key_valid/pos/rng) state contract is what the continuous
@@ -46,6 +51,7 @@ from .common import (
     dense_init,
     embed,
     kv_quantize,
+    layernorm,
     lm_head_logits,
     merge_heads,
     mha_attention,
@@ -269,6 +275,46 @@ class LlamaConfig:
     attention_multiplier: float = 0.0
     residual_multiplier: float = 1.0
     logits_scaling: float = 1.0
+    # A self-decoder / cross-decoder pair (SambaY; Phi-4-mini-flash): after
+    # the self-decoder's layers, ``layer_types`` names layers that OWN nothing
+    # and read what an earlier layer of the same step produced — "cross": an
+    # attention with a query and an output projection alone, its keys and
+    # values the pool of the last "full" layer before the first of them
+    # (``kv_layer``), read after that layer's write of the same step; "gmu": a
+    # Gated Memory Unit ``W_out(silu(W_in u) * m)``, ``m`` the scan output
+    # (with the D skip, before the gate) of the last "mamba" layer before the
+    # first of them (``memory_layer``) at the same positions.  Every layer
+    # from the first of them on (``cross_from``) is one of the two, so none
+    # carries anything from one position to the next: a prompt window runs the
+    # layers before ``cross_from`` alone (its last position's logits are the
+    # first decode step's, as ever).
+    # ``attention="diff"`` is differential attention: the query heads pair up
+    # adjacent (q1, q2), the KV heads likewise (k1, k2) and (v1, v2), a query
+    # pair j reads KV pair ``j // (pairs a KV pair)``; ``softmax(q1 k1^T)
+    # [v1 | v2] - lambda softmax(q2 k2^T) [v1 | v2]``, ``lambda`` from four
+    # learned vectors a layer and the layer's depth, an RMSNorm over the
+    # ``2 head_dim`` of a pair's output, times ``1 - lambda_init``.  The
+    # kernels see it as grouped-query attention over ``num_kv_heads / 2`` KV
+    # heads ``2 head_dim`` wide — a cached token's [k1 | k2] and [v1 | v2] as
+    # they lie — with each query placed in its key's half of the lanes
+    # (``_diff_place``): every cached byte crosses HBM once a layer a step.
+    # ``norm`` "rms" | "layer": the block norms (pre, final) as LayerNorm with
+    # scale AND bias; ``attn_bias``: biases on q, k, v and o;
+    # ``nope_on_window``: no rotation on window layers either;
+    # ``ssm_inner_norms``: a Mamba-1 layer's three inner RMSNorms (Jamba's;
+    # false = the plain Mamba-1 block).
+    norm: str = "rms"
+    attn_bias: bool = False
+    nope_on_window: bool = False
+    ssm_inner_norms: bool = True
+    # Keys a window layer's store holds a stream, where it is a RING of the
+    # stream's own beside its state row and not blocks of the paged pool (0:
+    # the pool, every block behind the window kept): the key at position p
+    # lies at ``p % window_ring`` of the stream's row, so the store stops
+    # growing whatever the context.  A multiple of the pool's block size that
+    # holds a prompt window's view (``window - 1 + PREFILL_CHUNK`` keys and a
+    # block; the registry checks it against the environment).
+    window_ring: int = 0
 
     def __post_init__(self):
         if self.num_experts and not (
@@ -278,8 +324,16 @@ class LlamaConfig:
                 f"experts_per_token={self.experts_per_token} must lie in "
                 f"1..num_experts={self.num_experts}"
             )
-        if self.attention not in ("gqa", "mla"):
-            raise ValueError(f"attention={self.attention!r} ('gqa', 'mla')")
+        if self.attention not in ("gqa", "mla", "diff"):
+            raise ValueError(
+                f"attention={self.attention!r} ('gqa', 'mla', 'diff')")
+        if self.norm not in ("rms", "layer"):
+            raise ValueError(f"norm={self.norm!r} ('rms', 'layer')")
+        if self.diff and (self.num_heads % 2 or self.num_kv_heads % 2
+                          or self.num_heads % self.num_kv_heads):
+            raise ValueError(
+                "attention='diff' pairs heads: num_heads and num_kv_heads must "
+                f"be even, got {self.num_heads} / {self.num_kv_heads}")
         mla_dims = (self.q_lora_rank, self.kv_lora_rank, self.qk_nope_head_dim,
                     self.qk_rope_head_dim, self.v_head_dim)
         if self.mla:
@@ -334,12 +388,29 @@ class LlamaConfig:
         object.__setattr__(self, "layer_types", types)
         if types and (len(types) != self.num_layers
                       or set(types) - {"window", "full", "linear", "mamba",
-                                       "mamba2"}):
+                                       "mamba2", "cross", "gmu"}):
             raise ValueError(
                 f"layer_types must name each of the {self.num_layers} layers "
                 f"'window', 'full', 'linear' or 'mamba' (Mamba-1; 'mamba2' a "
-                f"Mamba-2 mixer then its FFN), got {types}"
+                f"Mamba-2 mixer then its FFN; 'cross' / 'gmu' a cross-decoder's "
+                f"layers), got {types}"
             )
+        if self.cross_from < len(types):
+            before, after = types[: self.cross_from], types[self.cross_from:]
+            if (set(after) - {"cross", "gmu"} or self.mla
+                    or ("cross" in after and "full" not in before)
+                    or ("gmu" in after and "mamba" not in before)):
+                raise ValueError(
+                    "a 'cross' layer needs a 'full' layer before the first "
+                    "cross-decoder layer (the pool it reads), a 'gmu' layer a "
+                    "'mamba' layer there (the memory it gates), every layer "
+                    "from the first of them on is 'cross' or 'gmu', and the "
+                    f"attention is not 'mla', got {types}")
+        if self.window_ring and (not self.window
+                                 or self.window_ring < self.window):
+            raise ValueError(
+                f"window_ring={self.window_ring} needs window layers and holds "
+                f"at least their window={self.window}")
         gdn_dims = (self.gdn_key_heads, self.gdn_value_heads, self.gdn_key_dim,
                     self.gdn_value_dim)
         if "linear" in types:
@@ -427,8 +498,49 @@ class LlamaConfig:
                 "non-negative (0 = head_dim^-1/2)")
 
     @property
+    def diff(self) -> bool:
+        return self.attention == "diff"
+
+    @property
+    def kv_groups(self) -> int:
+        """KV heads as the attention kernels see them: under ``diff`` a pair
+        (k1, k2) is one head ``2 head_dim`` wide."""
+        return self.num_kv_heads // 2 if self.diff else self.num_kv_heads
+
+    @property
+    def kv_tail(self) -> tuple:
+        """A cached token's dims unmerged, as the attention reads them."""
+        return (self.kv_groups, self.head_dim * self.num_kv_heads // self.kv_groups)
+
+    @property
     def n_rep(self) -> int:
-        return self.num_heads // self.num_kv_heads
+        return self.num_heads // self.kv_groups
+
+    @property
+    def layer_counts(self) -> dict:
+        """How many layers ``layer_types`` names of each kind."""
+        return {t: self.layer_types.count(t) for t in dict.fromkeys(self.layer_types)}
+
+    @property
+    def cross_from(self) -> int:
+        """The first cross-decoder layer ('cross' / 'gmu'); ``num_layers``
+        where there is none."""
+        return next((i for i, t in enumerate(self.layer_types)
+                     if t in ("cross", "gmu")), self.num_layers)
+
+    def _last_before_cross(self, name: str) -> int:
+        return max((i for i, t in enumerate(self.layer_types[: self.cross_from])
+                    if t == name), default=-1)
+
+    @property
+    def kv_layer(self) -> int:
+        """The layer whose pool the 'cross' layers read (-1: no such layer)."""
+        return self._last_before_cross("full") if "cross" in self.layer_types else -1
+
+    @property
+    def memory_layer(self) -> int:
+        """The Mamba layer whose scan output the 'gmu' layers gate (-1)."""
+        return self._last_before_cross("mamba") if "gmu" in self.layer_types else -1
 
     @property
     def q_dim(self) -> int:
@@ -480,7 +592,8 @@ class LlamaConfig:
         """What the GQA paths hand their kernels and ``mha_attention`` as
         ``scale``: None — each one's own ``head_dim^-1/2``, the program as
         it ever was — unless the model states an ``attention_multiplier``."""
-        return self.attention_multiplier or None
+        return self.attention_multiplier or (
+            self.head_dim ** -0.5 if self.diff else None)  # not the view's width
 
     @property
     def held(self) -> int:
@@ -505,10 +618,44 @@ class LlamaConfig:
 
     @property
     def cache_layers(self) -> tuple:
-        """The layers with a cache entry (a pool): those whose mixer is an
-        attention, in order."""
+        """The layers with a cache entry (a pool under the block table): those
+        whose mixer is an attention that owns its keys there, in order."""
         return tuple(li for li in range(self.num_layers)
-                     if self.layer_kind(li).attention)
+                     if self.layer_kind(li).store == "table")
+
+    @property
+    def own_layers(self) -> tuple:
+        """The attention layers that own their keys and values, pool or ring,
+        in order: an entry each of a contiguous cache and of
+        ``forward_hidden``'s collected keys."""
+        return tuple(li for li in range(self.num_layers)
+                     if self.layer_kind(li).store in ("table", "ring"))
+
+    @property
+    def pool_entries(self) -> tuple:
+        """Which entries of a contiguous cache (``own_layers``' order) are
+        pools under the block table: what the paged loop shapes its pools
+        from (every one, unless window layers keep rings)."""
+        return tuple(i for i, li in enumerate(self.own_layers)
+                     if li in self.cache_layers)
+
+    @property
+    def ring_layers(self) -> tuple:
+        """The window layers whose keys lie in a ring a stream beside its
+        state row (``window_ring``), in order."""
+        return tuple(li for li in range(self.num_layers)
+                     if self.layer_kind(li).store == "ring")
+
+    @property
+    def state_rows(self) -> bool:
+        """Whether a stream holds a row beside the pool (``SsmState``)."""
+        return bool(self.recurrent_layers or self.ring_layers)
+
+    @property
+    def window_row_bytes(self) -> int:
+        """Bytes of window keys and values one stream's rings hold (0: none)."""
+        return (len(self.ring_layers) * self.window_ring
+                * 2 * self.num_kv_heads * self.head_dim * 2)
 
     @property
     def recurrent_layers(self) -> tuple:
@@ -553,19 +700,22 @@ class LlamaConfig:
                 window=0, rope=c == "*" and not self.nope_on_full,
                 experts=c == "E", d_ff=self.d_ff if c == "E" else 0,
                 mixer={"*": self.attention, "M": "mamba2"}.get(c),
-                ffn=c == "E",
+                ffn=c == "E", store="table" if c == "*" else "",
             )
         kind = self.layer_types[li] if self.layer_types else "full"
         window = self.window if kind == "window" else 0
         dense = li < self.num_dense_layers
+        attends = kind in ("window", "full", "cross")
         return LayerKind(
             window=window,
-            rope=kind not in ("linear", "mamba", "mamba2") and (
-                bool(window) or not self.nope_on_full),
+            rope=attends and not (
+                self.nope_on_window if window else self.nope_on_full),
             experts=bool(self.num_experts) and not dense,
             d_ff=self.d_ff_dense if dense else self.d_ff,
-            mixer={"linear": "gdn", "mamba": "mamba1",
-                   "mamba2": "mamba2"}.get(kind, self.attention),
+            mixer={"linear": "gdn", "mamba": "mamba1", "mamba2": "mamba2",
+                   "gmu": "gmu"}.get(kind, self.attention),
+            store=("" if not attends else "shared" if kind == "cross"
+                   else "ring" if window and self.window_ring else "table"),
         )
 
     @property
@@ -579,9 +729,11 @@ class LayerKind:
     """What one layer is: ``window`` keys a query sees (0 = all before
     it), whether q and k are rotated, its FFN (``experts``: the
     sparse expert block of experts ``d_ff`` wide; else a dense SwiGLU of
-    width ``d_ff``) and its ``mixer``: an attention ("gqa" | "mla"), a
-    recurrence (a key of ``RECURRENT``) or None.  Under a ``layer_pattern`` a
-    layer is ONE sub-block alone: a mixer, or — ``ffn`` — the FFN."""
+    width ``d_ff``) and its ``mixer``: an attention ("gqa" | "mla" | "diff";
+    ``store`` says whose keys it reads and whether it writes any), a
+    recurrence (a key of ``RECURRENT``), "gmu" (a Gated Memory Unit: no
+    state, no cache) or None.  Under a ``layer_pattern`` a layer is ONE
+    sub-block alone: a mixer, or — ``ffn`` — the FFN."""
 
     window: int
     rope: bool
@@ -589,11 +741,16 @@ class LayerKind:
     d_ff: int
     mixer: str | None = "gqa"
     ffn: bool = True
+    # Where an attention layer's keys and values lie: "table" its own pool
+    # under the block table, "ring" its own ring a stream (``window_ring``),
+    # "shared" nowhere of its own — a 'cross' layer reads ``cfg.kv_layer``'s
+    # pool and writes none; "" for a layer that attends to nothing.
+    store: str = "table"
 
     @property
     def attention(self) -> str:
-        """The attention kind of a layer that has a cache entry, else ""."""
-        return self.mixer if self.mixer in ("gqa", "mla") else ""
+        """The attention kind of a layer that attends, else ""."""
+        return self.mixer if self.mixer in ("gqa", "mla", "diff") else ""
 
     @property
     def recurrent(self) -> bool:
@@ -614,19 +771,32 @@ class SsmState(NamedTuple):
     conv: Any
     state: Any
     row: jax.Array
+    # A window layer's ring (``cfg.window_ring``; empty without one): its
+    # keys and its values [R, window_ring, KVH*D] a ring layer, the key at
+    # position p at ``p % window_ring`` of the stream's row.
+    ring_k: Any = ()
+    ring_v: Any = ()
+
+    @property
+    def leaves(self) -> dict:
+        """The per-row leaves by field (everything but ``row``): what an
+        insert copies and a rebuild zeroes, a row a stream."""
+        return {f: getattr(self, f) for f in self._fields if f != "row"}
 
 
 def zero_ssm(cfg: "LlamaConfig", rows: int, dtype):
     """``rows`` zeroed state rows, ``row`` the identity (``()``, a decode
-    state's empty default, for a config without recurrent layers)."""
+    state's empty default, for a config without state rows)."""
     shapes = [cfg.recurrent_shapes(li) for li in cfg.recurrent_layers]
-    if not shapes:
+    if not cfg.state_rows:
         return ()
     zeros = functools.cache(lambda tail, dt: jnp.zeros((rows,) + tail, dt))
+    ring = (cfg.window_ring, cfg.num_kv_heads * cfg.head_dim)
+    rings = [zeros(ring, dtype) for _ in cfg.ring_layers]
     return SsmState(  # layers of one shape share one zeros, as ever
         [zeros(taps, dtype) for taps, _ in shapes],
         [zeros(state, jnp.float32) for _, state in shapes],
-        jnp.arange(rows, dtype=jnp.int32))
+        jnp.arange(rows, dtype=jnp.int32), rings, list(rings))
 
 
 def yarn_mscale(factor: float, mscale: float) -> float:
@@ -670,7 +840,20 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
         post-norms — about 1."""
         if cfg.norm_gate_weight:
             return cast({"scale": normal_init(k, (n,), std=0.25)})
+        if cfg.norm == "layer":
+            # Scale and bias drawn off 1 and 0, so that either dropped shows.
+            return cast({"scale": 1.0 + normal_init(k, (n,), std=0.1),
+                         "bias": normal_init(jax.random.fold_in(k, 1), (n,), std=0.1)})
         return norm_scale(k, n) if learned else cast(rmsnorm_init(n))
+
+    def alin(k, d_in, d_out, bias_std):
+        """An attention projection: under ``cfg.attn_bias`` with a bias large
+        enough beside its output that dropping it shows."""
+        p = lin(k, d_in, d_out)
+        if cfg.attn_bias:
+            p["bias"] = cast(normal_init(jax.random.fold_in(k, 1), (d_out,),
+                                         std=bias_std))
+        return p
 
     def experts(k, shape):
         # The key ``dense_init`` would draw a [d_in, d_out] kernel from.
@@ -719,12 +902,16 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
                 "o": lin(k[3], cfg.o_dim, d),
             }
         else:
-            attn = {
-                "q": lin(k[0], d, qd),
-                "k": lin(k[1], d, kv_dim),
-                "v": lin(k[2], d, kv_dim),
-                "o": lin(k[3], qd, d),
-            }
+            attn = {"q": alin(k[0], d, qd, 0.3), "o": alin(k[3], qd, d, 0.1)}
+            if kind.store != "shared":  # a 'cross' layer has no k and no v
+                attn.update(k=alin(k[1], d, kv_dim, 0.3),
+                            v=alin(k[2], d, kv_dim, 0.3))
+        if cfg.diff and attn is not None:
+            # lambda's four vectors (float32, std 0.2: the learned part moves
+            # lambda by about a third either way of lambda_init) and the
+            # sub-norm's scale about 1: a dropped piece shows.
+            attn["lambda"] = normal_init(extra(49), (4, cfg.head_dim), std=0.2)
+            attn["subln"] = norm_scale(extra(50), 2 * cfg.head_dim)
         if cfg.qk_norm and attn is not None:
             # Learned scales have no reason to be 1: drawn about it, so a
             # served path that drops the norm departs from one that has it.
@@ -777,6 +964,10 @@ def init_params(key, cfg: LlamaConfig = LlamaConfig(), dtype=None) -> Params:
                          ssm=_init_mamba1(cfg, extra, lin, norm_scale, cast))
         if kind.mixer == "mamba2":  # then its FFN: layer_types "mamba2"
             layer.update(_init_mamba(cfg, extra, lin, norm_scale, cast))
+        if kind.mixer == "gmu":
+            layer.update(gmu_ln=block_norm(extra(29), d),
+                         gmu={"in": lin(extra(51), d, cfg.ssm_inner),
+                              "out": lin(extra(52), cfg.ssm_inner, d)})
         if mlp is not None:
             layer.update(mlp_ln=block_norm(extra(30), d), mlp=mlp)
         if cfg.sandwich_norm:
@@ -862,6 +1053,9 @@ def _init_mamba1(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
     d, ch, n, r = cfg.d_model, cfg.ssm_inner, cfg.ssm_state, cfg.ssm_dt_rank
     step = jnp.exp(jax.random.uniform(
         extra(43), (ch,), minval=jnp.log(0.001), maxval=jnp.log(0.1)))
+    norms = {"dt_norm": norm_scale(extra(45), r),
+             "b_norm": norm_scale(extra(46), n),
+             "c_norm": norm_scale(extra(47), n)} if cfg.ssm_inner_norms else {}
     return {
         "in": lin(extra(38), d, 2 * ch),
         "conv": {
@@ -869,9 +1063,7 @@ def _init_mamba1(cfg: LlamaConfig, extra, lin, norm_scale, cast) -> dict:
             "bias": cast(normal_init(extra(40), (ch,), std=0.5)),
         },
         "x_proj": lin(extra(41), ch, r + 2 * n),
-        "dt_norm": norm_scale(extra(45), r),
-        "b_norm": norm_scale(extra(46), n),
-        "c_norm": norm_scale(extra(47), n),
+        **norms,
         "dt_proj": {
             "kernel": cast(normal_init(extra(42), (r, ch), std=r ** -0.5)),
             "bias": step + jnp.log(-jnp.expm1(-step)),
@@ -982,6 +1174,8 @@ def _norm(cfg: "LlamaConfig", p, x):
     if cfg.norm_gate_weight:
         p = {"scale": cfg.norm_gate_weight * jax.nn.sigmoid(
             p["scale"].astype(jnp.float32))}
+    if cfg.norm == "layer":  # LayerNorm: mean taken out, scale and bias
+        return layernorm(p, x, eps=cfg.rms_eps)
     return rmsnorm(p, x, eps=cfg.rms_eps)
 
 
@@ -1090,9 +1284,16 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
     a = layer["attn"]
     if cfg.mla:
         return _mla_qkv(cfg, layer, x, cos, sin, ad, li)
+    kind = cfg.layer_kind(li)
     with jax.named_scope("qkv_rope"):
         h = _norm(cfg, layer["attn_ln"], x)
         q = _aproj(a, ad, "q", li, h)
+        if kind.store == "shared":  # a 'cross' layer: a query alone
+            q = _split(q, cfg.num_heads)
+            if kind.rope:
+                q = _apply_rope(q, cos, sin)
+            return (_diff_place(q) if cfg.diff else q, None, None,
+                    _attn_gate(cfg, a, ad, li, h))
         k = _aproj(a, ad, "k", li, h)
         if cfg.qk_norm is True:
             q = rmsnorm(a["q_norm"], q, eps=cfg.rms_eps)
@@ -1101,11 +1302,71 @@ def _qkv_rope(cfg: "LlamaConfig", layer, ad, li: int, x, cos, sin):
         if cfg.qk_norm == "head":
             q = rmsnorm(a["q_norm"], q, eps=cfg.rms_eps)
             k = rmsnorm(a["k_norm"], k, eps=cfg.rms_eps)
-        if cfg.layer_kind(li).rope:
+        if kind.rope:
             q, k = _apply_rope(q, cos, sin), _apply_rope(k, cos, sin)
         v = _split(_aproj(a, ad, "v", li, h), cfg.num_kv_heads)
+        if cfg.diff:  # [k1 | k2] and [v1 | v2] as the cache holds them
+            q, k, v = _diff_place(q), _split(merge_heads(k), cfg.kv_groups), \
+                _split(merge_heads(v), cfg.kv_groups)
         g = _attn_gate(cfg, a, ad, li, h)
     return q, k, v, g
+
+
+def _diff_place(q):
+    """Differential attention's queries [.., H, Dh] as the kernels take them,
+    [.., H, 2 Dh]: a pair's first head (even) in the lanes of its KV pair's
+    k1, the second in k2's, zeros in the other half — ``q' . [k1 | k2]`` is
+    then ``q . k1`` or ``q . k2``, and plain grouped-query attention over the
+    pairs weighs the 2 Dh-wide value [v1 | v2] with each head's own softmax."""
+    z = jnp.zeros_like(q)
+    odd = (jnp.arange(q.shape[-2]) % 2 == 1)[:, None]
+    return jnp.where(odd, jnp.concatenate([z, q], axis=-1),
+                     jnp.concatenate([q, z], axis=-1))
+
+
+def diff_lambda_init(li: int) -> float:
+    """Differential attention's ``lambda_init`` at depth ``li``."""
+    import math
+
+    return 0.8 - 0.6 * math.exp(-0.3 * li)
+
+
+def _diff_combine(cfg: "LlamaConfig", a, li: int, ctx):
+    """A pair's two attentions [.., H, 2 Dh] -> its output [.., H / 2, 2 Dh]
+    — the ``attn_diff_combine`` scope: ``o1 - lambda o2``, ``lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init``, an RMSNorm over the
+    pair's 2 Dh with its learned scale, times ``1 - lambda_init``; float32."""
+    with jax.named_scope("attn_diff_combine"):
+        f32 = jnp.float32
+        init = diff_lambda_init(li)
+        lam = _diff_lambda(a["lambda"].astype(f32), init)
+        c = ctx.astype(f32).reshape(
+            ctx.shape[:-2] + (ctx.shape[-2] // 2, 2, ctx.shape[-1]))
+        o = c[..., 0, :] - lam * c[..., 1, :]
+        o = _diff_subnorm(a["subln"], o, cfg.rms_eps) * (1.0 - init)
+        return o.astype(ctx.dtype)
+
+
+def _diff_lambda(lv, init: float):
+    """``exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init`` of a layer's four
+    vectors ``lv`` [4, Dh], float32."""
+    return jnp.exp(jnp.sum(lv[0] * lv[1])) - jnp.exp(jnp.sum(lv[2] * lv[3])) + init
+
+
+def _diff_subnorm(p, o, eps: float):
+    """The pair's sub-norm: an RMSNorm over its 2 Dh with a learned scale."""
+    return rmsnorm(p, o, eps=eps)
+
+
+def _gmu_memory(y, z):
+    """What the memory layer hands the Gated Memory Units: the scan's output
+    with the D skip, BEFORE its own gate ``silu(z)`` (which is not read)."""
+    return y
+
+
+def _kv_source(cfg: "LlamaConfig") -> int:
+    """The layer whose keys and values a 'cross' layer reads."""
+    return cfg.kv_layer
 
 
 # ---------------------------------------------------------------------------
@@ -1279,6 +1540,8 @@ def _attn_out(cfg: "LlamaConfig", layer, ad, li: int, x, ctx, g):
     ``_qkv_rope``), projected by ``W_o`` and, under
     ``cfg.sandwich_norm``, normed again before the residual."""
     with jax.named_scope("attn_out"):
+        if cfg.diff:
+            ctx = _diff_combine(cfg, layer["attn"], li, ctx)
         y = merge_heads(ctx)
         if g is not None:
             y = y * g
@@ -1291,12 +1554,17 @@ def _attn_out(cfg: "LlamaConfig", layer, ad, li: int, x, ctx, g):
 def _attn_scope(cfg: "LlamaConfig", li: int):
     """``attn`` and, for a config with a layer pattern, the layer's kind
     inside it (``attn_window`` / ``attn_full``): the scope a layer's
-    attention runs under in every step kind."""
+    attention runs under in every step kind — ``attn_cross`` alone for a
+    layer that reads another layer's pool, apart from the owner's ``attn``."""
     stack = contextlib.ExitStack()
+    kind = cfg.layer_kind(li)
+    if kind.store == "shared":  # a reader of ``cfg.kv_layer``'s pool
+        stack.enter_context(jax.named_scope("attn_cross"))
+        return stack
     stack.enter_context(jax.named_scope("attn"))
     if cfg.layer_types or cfg.layer_pattern:
         stack.enter_context(jax.named_scope(
-            "attn_window" if cfg.layer_kind(li).window else "attn_full"))
+            "attn_window" if kind.window else "attn_full"))
     return stack
 
 
@@ -1433,15 +1701,18 @@ def _mamba1_gate(y, z):
     return y * jax.nn.silu(z)
 
 
-def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
+def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None,
+                  memory: list | None = None):
     """Pre-norm Mamba-1 mixer with its residual, under the ``ssm`` scope
     (Mamba-2's name: the two never share a model): x [B, L, D] from each
     row's taps ``conv`` [B, K-1, channels] and state ``s`` [B, N, channels]
     -> (x + out, conv', s'); ``mask`` / ``live`` as ``_mamba_block`` has
     them.  The convolution runs over x alone; ``[dt | B | C] = x W_x`` —
-    ``ssm_x_proj``, each of the three through its own RMSNorm —; ``Delta =
-    softplus(dt W_dt + b_dt)`` in float32 — ``ssm_dt_proj`` —; ``y *
-    silu(z)`` with NO norm — ``ssm_gate``."""
+    ``ssm_x_proj``, each of the three through its own RMSNorm under
+    ``cfg.ssm_inner_norms`` —; ``Delta = softplus(dt W_dt + b_dt)`` in float32
+    — ``ssm_dt_proj`` —; ``y * silu(z)`` with NO norm — ``ssm_gate``.  A list
+    given as ``memory`` receives ``y`` [B, L, channels] float32, the scan's
+    output with the D skip BEFORE the gate: what a Gated Memory Unit gates."""
     from ..ops import ssm
 
     m = layer["ssm"]
@@ -1461,9 +1732,12 @@ def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
                 xs = y1[:, None]
         with jax.named_scope("ssm_x_proj"):
             dbc = dense(m["x_proj"], xs).astype(f32)  # the norms' outputs stay float32
-            dt = rmsnorm(m["dt_norm"], dbc[..., :r], eps=cfg.rms_eps)
-            bm = rmsnorm(m["b_norm"], dbc[..., r:r + n], eps=cfg.rms_eps)
-            cm = rmsnorm(m["c_norm"], dbc[..., r + n:], eps=cfg.rms_eps)
+            if cfg.ssm_inner_norms:
+                dt = rmsnorm(m["dt_norm"], dbc[..., :r], eps=cfg.rms_eps)
+                bm = rmsnorm(m["b_norm"], dbc[..., r:r + n], eps=cfg.rms_eps)
+                cm = rmsnorm(m["c_norm"], dbc[..., r + n:], eps=cfg.rms_eps)
+            else:
+                dt, bm, cm = dbc[..., :r], dbc[..., r:r + n], dbc[..., r + n:]
         with jax.named_scope("ssm_dt_proj"):
             delta = jax.nn.softplus(
                 jnp.einsum("blr,rc->blc", dt.astype(x.dtype),
@@ -1481,10 +1755,28 @@ def _mamba1_block(cfg: "LlamaConfig", layer, x, conv, s, mask=None, live=None):
                 y, s = ssm.mamba1_step(xs[:, 0], delta[:, 0], a, bm[:, 0],
                                        cm[:, 0], m["D"], s, live)
                 y = y[:, None]
+        if memory is not None:
+            memory.append(_gmu_memory(y, z.astype(f32)))
         with jax.named_scope("ssm_gate"):
             y = _mamba1_gate(y, z.astype(f32)).astype(x.dtype)
         with jax.named_scope("ssm_out_proj"):
             return _residual(cfg, x, dense(m["out"], y)), conv, s
+
+
+def _gmu_block(cfg: "LlamaConfig", layer, x, m):
+    """Pre-norm Gated Memory Unit with its residual, under the ``gmu`` scope:
+    ``x + W_out(silu(W_in u) * m)``, ``m`` [B, L, channels] float32 the
+    memory layer's scan output at the same positions (``_mamba1_block``'s
+    ``memory``); the gate's product in float32, rounded once.  No state, no
+    cache, no convolution."""
+    g = layer["gmu"]
+    with jax.named_scope("gmu"):
+        u = _norm(cfg, layer["gmu_ln"], x)
+        with jax.named_scope("gmu_in_proj"):
+            gate = dense(g["in"], u)
+        y = (jax.nn.silu(gate.astype(jnp.float32)) * m).astype(x.dtype)
+        with jax.named_scope("gmu_out_proj"):
+            return _residual(cfg, x, dense(g["out"], y))
 
 
 class Recurrence(NamedTuple):
@@ -1533,36 +1825,49 @@ def _recurrent_block(cfg: "LlamaConfig", li: int):
 
 
 def _layers(params: Params, cfg: "LlamaConfig", x, attend, recur, valid,
-            tally=None):
+            tally=None, memory=(), self_only: bool = False):
     """x through every layer, each running the sub-blocks its kind has
-    (``cfg.layer_kind``): its mixer — ``recur(li, layer, x)`` (a Mamba-2 or
-    Gated-DeltaNet layer) or ``attend(li, layer, x)``, the step kind's own
-    closures over its cache and state, each with its residual — then its
-    FFN (``_mlp_block``; ``valid()`` gives its rows' mask, asked for where
-    an FFN runs).  The one walk every step kind makes."""
-    for li, layer in enumerate(params["layers"]):
+    (``cfg.layer_kind``): its mixer — ``recur(li, layer, x)`` (a recurrent
+    layer), ``attend(li, layer, x)``, the step kind's own closures over its
+    cache and state, or a Gated Memory Unit on ``memory[0]`` (what the
+    memory layer's ``recur`` left there: ``_ssm_walker``), each with its
+    residual — then its FFN (``_mlp_block``; ``valid()`` gives its rows' mask,
+    asked for where an FFN runs).  The one walk every step kind makes;
+    ``self_only`` stops it before the cross-decoder (``cfg.cross_from``): a
+    step that reads no logit — a prompt window, a wave's prefill — owes those
+    layers nothing, for they leave nothing behind."""
+    upto = cfg.cross_from if self_only else cfg.num_layers
+    for li, layer in enumerate(params["layers"][:upto]):
         kind = cfg.layer_kind(li)
         if kind.recurrent:
             x = recur(li, layer, x)
         elif kind.attention:
             x = attend(li, layer, x)
+        elif kind.mixer == "gmu":
+            x = _gmu_block(cfg, layer, x, memory[0])
         if kind.ffn:
             x = _mlp_block(cfg, layer, li, x, valid(), tally)
     return x
 
 
-def _ssm_walker(cfg: "LlamaConfig", ssm, run):
+def _ssm_walker(cfg: "LlamaConfig", ssm, run, memory=None, lift=None):
     """``(recur, done)``: ``recur(li, layer, x)`` runs the next recurrent
     layer through ``run(block, layer, x, conv, s) -> (x, conv', s')`` —
     ``block`` that layer's ``_recurrent_block`` — on the layer's entries of
     ``ssm``; ``done()`` is ``ssm`` with what the layers left (``()`` for a
-    config without any)."""
+    config without any).  A list given as ``memory`` receives what
+    ``cfg.memory_layer``'s block hands the Gated Memory Units (through
+    ``lift``, where the step runs its blocks on other rows than its own)."""
     convs, states = [], []
 
     def recur(li, layer, x):
         i = len(convs)
-        x, conv, s = run(_recurrent_block(cfg, li), layer, x, ssm.conv[i],
-                         ssm.state[i])
+        block, got = _recurrent_block(cfg, li), []
+        if memory is not None and li == cfg.memory_layer:
+            block = functools.partial(block, memory=got)
+        x, conv, s = run(block, layer, x, ssm.conv[i], ssm.state[i])
+        if got:
+            memory.append(lift(got[0]) if lift else got[0])
         convs.append(conv)
         states.append(s)
         return x
@@ -1628,7 +1933,10 @@ def forward_hidden(
     ssm_out: list | None = None,
 ):
     """Hidden states [B, S, D] (+ per-layer ROTATED prompt K / V, an entry
-    an ATTENTION layer).  A list given as ``ssm_out`` receives the
+    a layer that owns its keys — ``cfg.own_layers``: a 'cross' layer has
+    none; with ``collect_kv`` the pass stops before the cross-decoder,
+    ``_layers``' ``self_only``, and the hidden states are no forward
+    pass's).  A list given as ``ssm_out`` receives the
     ``SsmState`` (B rows) a decode state starts from: what the Mamba
     layers' scans from zeros leave BEFORE each row's last real token —
     the first decode step embeds that token again (``write_idx`` is its
@@ -1656,12 +1964,16 @@ def forward_hidden(
         )
     band = mask & _band(pos, jnp.arange(p_len + s), cfg.window) if cfg.window else None
     ad = lora.adapter_tables(params)
-    kv = []
+    kv, shared = [], []
 
     def attend(li, layer, x):
         q, k, v, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
-        if collect_kv:
+        if cfg.layer_kind(li).store == "shared":
+            k, v = shared[0]  # the kv layer's keys, of this same pass
+        elif collect_kv:
             kv.append((k, v))
+        if li == _kv_source(cfg):
+            shared.append((k, v))
         with _attn_scope(cfg, li):
             if cfg.mla:  # k is the window's latent rows: expanded attention
                 ctx = _mla_expanded_attention(cfg, layer, q, k, mask)
@@ -1686,15 +1998,38 @@ def forward_hidden(
     if ssm_out is not None and cfg.recurrent_layers:
         ssm_mask = attention_mask * (
             jnp.arange(s)[None, :] < attention_mask.sum(axis=-1, keepdims=True) - 1)
+    memory: list = []
     recur, ssm_done = _ssm_walker(
         cfg, zero_ssm(cfg, b, dtype),
         lambda block, layer, x, conv, st: block(
-            cfg, layer, x, conv, st, mask=ssm_mask))
-    x = _layers(params, cfg, x, attend, recur, lambda: attention_mask != 0)
+            cfg, layer, x, conv, st, mask=ssm_mask), memory)
+    # A pass that collects the cache reads no logit: the cross-decoder's
+    # layers leave nothing in a cache or a state, so it does not run them.
+    x = _layers(params, cfg, x, attend, recur, lambda: attention_mask != 0,
+                memory=memory, self_only=collect_kv)
     if ssm_out is not None:
         ssm_out.append(ssm_done())
     x = _norm(cfg, params["final_ln"], x)
     return (x, kv) if collect_kv else x
+
+
+def _with_rings(cfg: LlamaConfig, ssm, rings):
+    """``ssm`` with a wave's window keys and values ``rings`` ([B, S, ..] a
+    ring layer, positions 0..S-1) laid into its rings' first S places."""
+    if not rings:
+        return ssm
+    s = rings[0][0].shape[1]
+    if s > cfg.window_ring:
+        raise ValueError(
+            f"a wave of {s} positions does not fit a window ring of "
+            f"{cfg.window_ring}: longer prompts prefill in windows")
+
+    def lay(dst, src):  # [B, ring, C] <- [B, S, KVH, D]
+        return dst.at[:, :s].set(merge_heads(src).astype(dst.dtype))
+
+    return ssm._replace(
+        ring_k=[lay(d, k) for d, (k, _) in zip(ssm.ring_k, rings)],
+        ring_v=[lay(d, v) for d, (_, v) in zip(ssm.ring_v, rings)])
 
 
 def compute_prefix_kv(params: Params, cfg: LlamaConfig, prefix_ids, dtype=jnp.float32):
@@ -1777,7 +2112,7 @@ def init_decode_state(
                 cache_k.append((ck8, cks))
                 cache_v.append((cv8, cvs))
                 continue
-            ck = jnp.zeros((b, total, cfg.num_kv_heads, cfg.head_dim), k.dtype)
+            ck = jnp.zeros((b, total) + cfg.kv_tail, k.dtype)
             cv = ck
             if p_len:
                 pk, pv = prefix_kv[li]
@@ -1804,6 +2139,9 @@ def init_decode_state(
         done=lengths == 0,
         tokens=jnp.full((b, max_len), cfg.pad_id, jnp.int32),
         sample=sample if sample is not None else greedy_params(b),
+        # (a ring layer's keys lie in the slab whole; the rings ride along
+        # zeroed, a row a batch row: the paged loop's template shapes its
+        # state rows from them)
         ssm=ssm_out[0],
     )
 
@@ -1881,27 +2219,41 @@ def _decode_step(params: Params, cfg: LlamaConfig, state: GPTState, sample: bool
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
+    total = key_valid.shape[1]
+    banded = bool(cfg.window) and total > cfg.window  # a slab past the window
+    if banded:
+        band = attn_mask & _band(
+            t[:, None], jnp.arange(total)[None], cfg.window)[:, None]
 
     def attend(li, layer, x):
-        ai = len(new_k)  # the layer's cache entry: one an attention layer
+        kind = cfg.layer_kind(li)
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
-        with jax.named_scope("kv_write"):
-            ck = _write_kv(state.cache_k[ai], rows, t, k1[:, 0], dtype)
-            if not cfg.mla:
-                cv = _write_kv(state.cache_v[ai], rows, t, v1[:, 0], dtype)
-                new_v.append(cv)
-        new_k.append(ck)
-        with jax.named_scope("attn"):
+        if kind.store == "shared":  # the kv layer's entry, after its write
+            ai = cfg.own_layers.index(_kv_source(cfg))
+            ck, cv = new_k[ai], new_v[ai]
+        else:
+            ai = len(new_k)  # the layer's cache entry: one an owning layer
+            with jax.named_scope("kv_write"):
+                ck = _write_kv(state.cache_k[ai], rows, t, k1[:, 0], dtype)
+                if not cfg.mla:
+                    cv = _write_kv(state.cache_v[ai], rows, t, v1[:, 0], dtype)
+                    new_v.append(cv)
+            new_k.append(ck)
+        with jax.named_scope(
+                "attn_cross" if kind.store == "shared" else "attn"):
             if cfg.mla:  # the latent slab, absorbed, in XLA (unary requests)
                 ctx = _mla_decode_attention(cfg, layer, q, ck, None, key_valid, 0)
             else:
-                ctx = _cache_attention(cfg, q, ck, cv, attn_mask)
+                ctx = _cache_attention(
+                    cfg, q, ck, cv, band if banded and kind.window else attn_mask)
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
+    memory: list = []
     recur, ssm_done = _ssm_walker(
         cfg, state.ssm, lambda block, layer, x, conv, st: block(
-            cfg, layer, x, conv, st, live=~state.done))
-    x = _layers(params, cfg, x, attend, recur, lambda: ~state.done[:, None])
+            cfg, layer, x, conv, st, live=~state.done), memory)
+    x = _layers(params, cfg, x, attend, recur, lambda: ~state.done[:, None],
+                memory=memory)
     x = _norm(cfg, params["final_ln"], x)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
@@ -2022,7 +2374,7 @@ def _paged_cache_attention(cfg: LlamaConfig, q, ck, cv, table, key_valid,
 
         quant = isinstance(ck, tuple)
         vkey = cfg.pallas_variant or autotune.lookup(
-            "paged_decode", b=q.shape[0], kvh=cfg.num_kv_heads,
+            "paged_decode", b=q.shape[0], kvh=cfg.kv_groups,
             n_rep=cfg.n_rep, d=q.shape[3],
             block_size=bs, t=table.shape[1], dtype=str(q.dtype), quant=quant,
             tp=cfg.tp,
@@ -2052,10 +2404,10 @@ def _gathered_attention(cfg: LlamaConfig, q, ck, cv, table, bs: int, mask):
 
     def dense(pool, last):
         return _repeat_kv(
-            gather_pages(pool, table, bs, (cfg.num_kv_heads, last)), cfg.n_rep
+            gather_pages(pool, table, bs, (cfg.kv_groups, last)), cfg.n_rep
         )
 
-    d = cfg.head_dim
+    d = cfg.kv_tail[1]
     if isinstance(ck, tuple):
         return mha_attention_kv8(
             q, dense(ck[0], d), dense(ck[1], 1), dense(cv[0], d),
@@ -2080,7 +2432,7 @@ def window_view_blocks(window: int, bs: int, t_width: int) -> int:
     return tw if tw < t_width else t_width
 
 
-def window_view(table, key_valid, t, window: int, bs: int):
+def window_view(table, key_valid, t, window: int, bs: int, ring=None):
     """What a window layer attends over in a decode step whose newest
     key lies at position ``t`` [B]: ``(table [B, Tw], key_valid
     [B, Tw*bs])`` — per row the ``Tw`` consecutive table ENTRIES from the
@@ -2088,7 +2440,11 @@ def window_view(table, key_valid, t, window: int, bs: int):
     and the matching slice of ``key_valid`` with every key older than
     ``t-window+1`` cleared.  The kernel and the gathered path take it in
     place of the whole table: a row's live range lies within ``Tw/K``
-    programs, and its mask and table row are ``Tw`` wide, not ``T``."""
+    programs, and its mask and table row are ``Tw`` wide, not ``T``.
+    ``ring`` = ``(row [B], ring blocks, rows)``: the window layers' keys lie
+    in rings (``cfg.window_ring``), and the view's entries are the same
+    logical blocks' ids there (``ring_blocks``), ``table`` giving only its
+    width."""
     t_width = table.shape[1]
     tw = window_view_blocks(window, bs, t_width)
     lo = jnp.maximum(t - window + 1, 0)  # oldest key in the window
@@ -2100,9 +2456,45 @@ def window_view(table, key_valid, t, window: int, bs: int):
             lambda r, s: jax.lax.dynamic_slice_in_dim(r, s, n))(a, start)
 
     valid = rows(key_valid, first * bs, tw * bs) * (pos >= lo[:, None])
+    if ring is not None:  # the view's blocks lie in the rows' rings
+        row, rb, n_rows = ring
+        return ring_blocks(row, first, tw, rb, n_rows), valid
     if tw == t_width:
         return table, valid
     return rows(table, first, tw), valid
+
+
+def ring_blocks(row, first, n: int, rb: int, n_rows: int):
+    """The ``n`` consecutive logical blocks from ``first`` [...] of the rings
+    of state rows ``row`` [...] as block ids of the rings' pool view
+    ``[n_rows * rb, BS, C]``: logical block j of row r lies at ``r * rb + j %
+    rb``.  A row past the last (a dead slot's, a filler's) gives ids past the
+    pool: no block — a write there drops, the kernel reads nothing."""
+    row, first = jnp.asarray(row), jnp.asarray(first)
+    return row[..., None] * rb + (first[..., None] + jnp.arange(n)) % rb
+
+
+def _ring_write(ssm, ri: int, dest, k_rows, v_rows, bs: int):
+    """K and V rows ``[N, ...]`` at flat positions ``dest`` [N] (``row *
+    window_ring + position % window_ring``; out of range drops) of ring layer
+    ``ri``'s rings -> the two rings as the kernels read them: pools of ``[rows
+    * window_ring / bs, bs, C]`` blocks (a re-view, written in place)."""
+    from ..ops.paged_attention import scatter_rows
+
+    def put(ring, rows):
+        return scatter_rows(ring.reshape((-1, bs) + ring.shape[2:]), dest, rows)
+
+    return put(ssm.ring_k[ri], k_rows), put(ssm.ring_v[ri], v_rows)
+
+
+def _rings_done(ssm, ring_k, ring_v):
+    """``ssm`` with the pools ``_ring_write`` left a ring layer, a row a
+    stream again (``ssm`` itself where no layer keeps a ring)."""
+    if not ring_k:
+        return ssm
+    shape = ssm.ring_k[0].shape
+    return ssm._replace(ring_k=[r.reshape(shape) for r in ring_k],
+                        ring_v=[r.reshape(shape) for r in ring_v])
 
 
 def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
@@ -2125,51 +2517,78 @@ def _paged_decode_step(params: Params, cfg: LlamaConfig, state, table,
     cos, sin = cos[:, None, None, :], sin[:, None, None, :]
     key_valid = state.key_valid.at[rows, t].set(1, mode="drop")
     full = (table, key_valid)
+    ring = None
+    if cfg.ring_layers:
+        # A slot's ring is its state row's; a slot that is not live (done,
+        # or freed: ``_paged_ssm_step``'s rule) points past the last row, so
+        # its stale ``row`` can touch no ring given to another stream.
+        n_rows, rlen = state.ssm.ring_k[0].shape[:2]
+        live = ~state.done & (table[:, 0] < entry.shape[0])
+        ring_row = jnp.where(live, state.ssm.row, n_rows)
+        ring = (ring_row, rlen // bs, n_rows)
+        ring_dest = ring_row * rlen + t % rlen  # this step's key, a slot
     if cfg.window:
         # Once a step, shared by the window layers.
         with jax.named_scope("attn"), jax.named_scope("attn_window_view"):
-            view = window_view(table, key_valid, t, cfg.window, bs)
+            view = window_view(table, key_valid, t, cfg.window, bs, ring)
 
     ad = lora.adapter_tables(params)
     new_k, new_v, moe_tally = [], [], []
+    ring_k, ring_v = [], []
 
     def attend(li, layer, x):
-        ai = len(new_k)  # the layer's pool: one an attention layer
+        kind = cfg.layer_kind(li)
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
-        with jax.named_scope("kv_write"):
-            ck = _paged_write_kv(state.cache_k[ai], table, t, k1[:, 0], bs, dtype)
-            if not cfg.mla:  # a latent row is written once: there is no V pool
-                cv = _paged_write_kv(state.cache_v[ai], table, t, v1[:, 0], bs, dtype)
-                new_v.append(cv)
-        new_k.append(ck)
+        if kind.store == "shared":
+            # The kv layer's pool AFTER its write of this step: nothing of
+            # its own to write, nothing held.
+            ai = cfg.cache_layers.index(_kv_source(cfg))
+            ck, cv = new_k[ai], new_v[ai]
+        elif kind.store == "ring":
+            with jax.named_scope("kv_write"):
+                ck, cv = _ring_write(state.ssm, len(ring_k), ring_dest,
+                                     k1[:, 0], v1[:, 0], bs)
+            ring_k.append(ck)
+            ring_v.append(cv)
+        else:
+            ai = len(new_k)  # the layer's pool: one a layer that owns one
+            with jax.named_scope("kv_write"):
+                ck = _paged_write_kv(state.cache_k[ai], table, t, k1[:, 0], bs, dtype)
+                if not cfg.mla:  # a latent row is written once: there is no V pool
+                    cv = _paged_write_kv(state.cache_v[ai], table, t, v1[:, 0], bs, dtype)
+                    new_v.append(cv)
+            new_k.append(ck)
         with _attn_scope(cfg, li):
             if cfg.mla:
                 ctx = _mla_decode_attention(cfg, layer, q, ck, *full, bs)
             else:
                 ctx = _paged_cache_attention(
-                    cfg, q, ck, cv, *(view if cfg.layer_kind(li).window else full), bs
+                    cfg, q, ck, cv, *(view if kind.window else full), bs
                 )
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
 
-    recur, ssm_done = _ssm_walker(
-        cfg, state.ssm, _paged_ssm_step(cfg, state, table)
-        if cfg.recurrent_layers else None)
+    memory: list = []
+    run, lift = (_paged_ssm_step(cfg, state, table) if cfg.recurrent_layers
+                 else (None, None))
+    recur, ssm_done = _ssm_walker(cfg, state.ssm, run, memory, lift)
     x = _layers(params, cfg, x, attend, recur, lambda: ~state.done[:, None],
-                moe_tally)
+                moe_tally, memory)
     x = _norm(cfg, params["final_ln"], x)
     next_tok, sp, done, tokens = _select_next(params, cfg, state, x[:, 0], sample)
     return (
         PagedState(
             cache_k=new_k, cache_v=new_v, key_valid=key_valid,
             write_idx=t + 1, pos=state.pos + 1, last_token=next_tok,
-            done=done, tokens=tokens, sample=sp, ssm=ssm_done(),
+            done=done, tokens=tokens, sample=sp,
+            ssm=_rings_done(ssm_done(), ring_k, ring_v),
         ),
         (next_tok, jnp.stack(moe_tally)) if moe_tally else next_tok,
     )
 
 
 def _paged_ssm_step(cfg: LlamaConfig, state, table):
-    """A paged decode step's recurrent layer, ``run`` of ``_ssm_walker``.  The
+    """A paged decode step's recurrent layer, ``(run, lift)`` of
+    ``_ssm_walker``.  The
     state rows stay where they lie and are updated in place, ALL ``R`` of
     them under a mask: the step's small per-slot rows (the residual
     stream) are gathered to their state rows and the layer's output back
@@ -2196,7 +2615,10 @@ def _paged_ssm_step(cfg: LlamaConfig, state, table):
         return jnp.where(
             live[:, None, None], jnp.take(y, to_slot, axis=0), x), conv, st
 
-    return run
+    def lift(m):  # the memory layer's scan output, state rows -> slots
+        return jnp.take(m, to_slot, axis=0)
+
+    return run, lift
 
 
 def generate_chunk_paged(params: Params, cfg: LlamaConfig, state, table,
@@ -2238,8 +2660,8 @@ def empty_decode_state(
     from .sampling import greedy_params
 
     total = s_total + max_len
-    shape = (batch, total, cfg.num_kv_heads, cfg.head_dim)
-    cached = cfg.cache_layers
+    shape = (batch, total) + cfg.kv_tail
+    cached = cfg.own_layers
     if cfg.kv_quant:
         cache_k = [
             (jnp.zeros(shape, jnp.int8), jnp.ones(shape[:3] + (1,), dtype))
@@ -2379,9 +2801,7 @@ def prefill_tile_counts(cfg: LlamaConfig, c: int, t_w: int, bs: int,
     if not cfg.pallas_decode or cfg.kv_quant:
         return 0, 0
     live = total = 0
-    for li in range(cfg.num_layers):
-        if not cfg.layer_kind(li).attention:  # no keys: no tile
-            continue
+    for li in cfg.own_layers:  # a layer with no keys of its own: no tile
         window = cfg.layer_kind(li).window
         first, n = prefill_key_blocks(c, t_w, bs, start, window)
         tq, tk = tile_sizes(c, 1 if cfg.mla else cfg.n_rep, n * bs)
@@ -2427,7 +2847,11 @@ def paged_prefill_chunk(
     ``ssm_rows[r, 1]`` tokens: all of them, but for a prompt's LAST window,
     which leaves the prompt's last token to the first decode step
     (``forward_hidden``'s rule).  A filled-up row (no token; its row index
-    past the last row) reads a clamped row and writes none."""
+    past the last row) reads a clamped row and writes none.  A window layer
+    whose store is a ring (``cfg.window_ring``) writes its keys into row
+    ``ssm_rows[r, 0]``'s ring at ``position % window_ring`` and attends over
+    the same logical blocks there (``ring_blocks``); a cross-decoder's layers
+    do not run (``_layers``' ``self_only``): a window reads no logit."""
     from ..ops.paged_attention import gather_pages
 
     b, c = chunk_ids.shape
@@ -2440,11 +2864,22 @@ def paged_prefill_chunk(
     t_w = table_rows.shape[1]
     kernel = cfg.pallas_decode and not isinstance(entry, tuple)
 
-    def attend(layer, r: int, q, ck, cv, window: int):
+    rlen = cfg.window_ring
+    if cfg.ring_layers:
+        n_rows = state.ssm.ring_k[0].shape[0]
+        # Every row's window, a position each, in its prompt's ring (a
+        # filler's row lies past the last: its writes drop).
+        ring_dest = (ssm_rows[:, :1] * rlen + pos_w % rlen).reshape(-1)
+
+    def attend(layer, r: int, q, ck, cv, window: int, ring: bool):
         """Row ``r``'s window: q [1, C, ...] over its own table."""
         start = starts[r]
         first, n = prefill_key_blocks(c, t_w, bs, start, window)
-        rows = jax.lax.dynamic_slice_in_dim(table_rows[r], first, n)[None]
+        if ring:  # the same logical blocks, where the prompt's ring has them
+            rows = ring_blocks(jnp.minimum(ssm_rows[r, 0], n_rows - 1), first,
+                               n, rlen // bs, n_rows)[None]
+        else:
+            rows = jax.lax.dynamic_slice_in_dim(table_rows[r], first, n)[None]
         window_at = (first * bs, start, chunk_mask[r])
         mask = None if kernel else _prefill_mask(
             first * bs + jnp.arange(n * bs), chunk_mask[r:r + 1], start, window)
@@ -2461,7 +2896,7 @@ def paged_prefill_chunk(
             return _gathered_attention(cfg, q, ck, cv, rows, bs, mask)
         from ..ops.prefill_attention import prefill_attention
 
-        tail = (cfg.num_kv_heads, cfg.head_dim)
+        tail = cfg.kv_tail
         return prefill_attention(
             q[0], gather_pages(ck, rows, bs, tail)[0],
             gather_pages(cv, rows, bs, tail)[0], *window_at, window=window,
@@ -2469,24 +2904,36 @@ def paged_prefill_chunk(
 
     ad = lora.adapter_tables(params)
     new_k, new_v = [], []
+    ring_k, ring_v = [], []
 
     def attend_rows(li, layer, x):
-        ai = len(new_k)
         q, k1, v1, g = _qkv_rope(cfg, layer, ad, li, x, cos, sin)
+        ring = cfg.layer_kind(li).store == "ring"
         # Every row's keys land before any row attends: the pool is
         # written in place, then only read.
-        ck, cv = state.cache_k[ai], None if cfg.mla else state.cache_v[ai]
-        with jax.named_scope("kv_write"):
-            for r in range(b):
-                ck = _paged_scatter_entry(ck, table_rows[r], k1[r], bs, starts[r], dtype)
-                if not cfg.mla:
-                    cv = _paged_scatter_entry(cv, table_rows[r], v1[r], bs, starts[r], dtype)
-        new_k.append(ck)
-        if not cfg.mla:
-            new_v.append(cv)
+        if ring:
+            with jax.named_scope("kv_write"):
+                ck, cv = _ring_write(
+                    state.ssm, len(ring_k), ring_dest,
+                    k1.reshape((b * c,) + k1.shape[2:]),
+                    v1.reshape((b * c,) + v1.shape[2:]), bs)
+            ring_k.append(ck)
+            ring_v.append(cv)
+        else:
+            ai = len(new_k)
+            ck, cv = state.cache_k[ai], None if cfg.mla else state.cache_v[ai]
+            with jax.named_scope("kv_write"):
+                for r in range(b):
+                    ck = _paged_scatter_entry(ck, table_rows[r], k1[r], bs, starts[r], dtype)
+                    if not cfg.mla:
+                        cv = _paged_scatter_entry(cv, table_rows[r], v1[r], bs, starts[r], dtype)
+            new_k.append(ck)
+            if not cfg.mla:
+                new_v.append(cv)
         with _attn_scope(cfg, li):
             window = cfg.layer_kind(li).window
-            ctx = [attend(layer, r, jax.tree.map(lambda a: a[r:r + 1], q), ck, cv, window)
+            ctx = [attend(layer, r, jax.tree.map(lambda a: a[r:r + 1], q), ck, cv,
+                          window, ring)
                    for r in range(b)]
             ctx = ctx[0] if b == 1 else jnp.concatenate(ctx, axis=0)
         return _attn_out(cfg, layer, ad, li, x, ctx, g)
@@ -2505,10 +2952,13 @@ def paged_prefill_chunk(
                 st.at[at].set(st1, mode="drop"))
 
     recur, ssm_done = _ssm_walker(cfg, state.ssm, scan)
+    # A window reads no logit (the prompt's last token is the first decode
+    # step's): the cross-decoder's layers, which leave nothing behind, do
+    # not run — a prompt costs them nothing, whatever its length.
     _layers(params, cfg, x, attend_rows, recur, lambda: chunk_mask != 0,
-            tally)
+            tally, self_only=True)
     return state._replace(cache_k=new_k, cache_v=new_v,
-                          ssm=ssm_done())
+                          ssm=_rings_done(ssm_done(), ring_k, ring_v))
 
 
 def init_paged_state(
@@ -2543,6 +2993,9 @@ def init_paged_state(
     # scales [NB, BS, KVH].
     shape = (num_blocks, block_size, cfg.num_kv_heads * cfg.head_dim)
     sc_shape = (num_blocks, block_size, cfg.num_kv_heads)
+    # A ring layer's keys go to the rows' rings, not to a pool.
+    rings = [e for li, e in zip(cfg.own_layers, kv) if li in cfg.ring_layers]
+    kv = [e for li, e in zip(cfg.own_layers, kv) if li not in cfg.ring_layers]
     for k, v in kv:
         if cfg.mla:  # one latent pool a layer
             ck = jnp.zeros((num_blocks, block_size, cfg.latent_lanes), k.dtype)
@@ -2587,5 +3040,5 @@ def init_paged_state(
         done=lengths == 0,
         tokens=jnp.full((b, max_len), cfg.pad_id, jnp.int32),
         sample=sample if sample is not None else greedy_params(b),
-        ssm=ssm_out[0],
+        ssm=_with_rings(cfg, ssm_out[0], rings),
     )

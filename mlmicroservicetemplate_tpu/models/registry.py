@@ -905,6 +905,9 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
             ("a clamped SwiGLU", cfg.swiglu_limit),
             ("a gated norm scale", cfg.norm_gate_weight),
             ("a tied head", cfg.tie_embeddings),
+            ("differential attention", cfg.diff),
+            ("LayerNorm block norms", cfg.norm == "layer"),
+            ("attention biases", cfg.attn_bias),
         ) if on
     ]
     if variants:
@@ -993,6 +996,54 @@ def _build_llama(svc_cfg, policy: DtypePolicy) -> ModelBundle:
                 "KV_DISK_BUDGET_MB": "the disk tier moves blocks of keys, no "
                 "recurrent state",
             })
+    if cfg.cross_from < cfg.num_layers or cfg.diff or cfg.window_ring:
+        # A cross-decoder's layers own nothing: a 'cross' layer reads another
+        # layer's pool after that layer's write of the same step, a Gated
+        # Memory Unit the memory layer's scan output of the same positions;
+        # a window layer's ring (window_ring) is a row a stream beside the
+        # pool, not blocks; differential attention reads a cached token as
+        # pairs [k1 | k2], [v1 | v2].  The prefill waves, the chunked paged
+        # prefill and the paged decode step carry all of it (models/llama.py,
+        # engine/streams.py's state rows).  Every other reader or mover of a
+        # stream's keys knows one pool a layer, K and V a head, and would
+        # share, move or attend something else in silence: refuse it.  TP>1
+        # and QUANTIZE refuse above.
+        _refuse_cache_readers(
+            svc_cfg, "a cross-decoder, a window ring or differential attention "
+            "(layer_types 'cross' / 'gmu', window_ring, attention='diff')", {
+                "PAGED_KV=0": "the contiguous slab's chunked prefill and "
+                "streaming loop know one cache entry a layer, each its own: "
+                "set PAGED_KV=1",
+                "SPEC_DECODE": "speculative verification (llama.multi_step) "
+                "walks one cache entry a layer and carries no memory",
+                "QUANT_KV": "the int8 pool pairs quantise per token-head; a "
+                "differential pair is read two heads wide",
+                "PREFIX_CACHE": "a prefix hit shares blocks of the one pool; "
+                "the window rings and the state at the prefix's end are kept "
+                "nowhere",
+                "PROMPT_PREFIX": "the prefix overlay holds one entry a layer, "
+                "no ring and no state",
+                "KV_HOST_BUDGET_MB": "the swap tiers move blocks of the pool; "
+                "a resumed stream's window rings would be missing",
+                "KV_DISK_BUDGET_MB": "the disk tier moves blocks of the pool, "
+                "no window ring",
+            })
+    if cfg.window_ring:
+        bs = int(getattr(svc_cfg, "kv_block_size", 16))
+        c = int(getattr(svc_cfg, "prefill_chunk", 0) or 0)
+        # what a prompt window's view spans (llama.prefill_key_blocks)
+        need = (-(-(c + cfg.window - 1) // bs) + 1) * bs
+        if cfg.window_ring % bs or cfg.window_ring < need:
+            raise ValueError(
+                f"window_ring={cfg.window_ring} must be a multiple of "
+                f"KV_BLOCK_SIZE={bs} and hold a prompt window's view: "
+                f"PREFILL_CHUNK={c} + window={cfg.window} - 1 keys and a "
+                f"block = {need}")
+        if max(svc_cfg.seq_buckets) > cfg.window_ring:
+            raise ValueError(
+                f"SEQ_BUCKETS up to {max(svc_cfg.seq_buckets)} do not fit a "
+                f"window ring of {cfg.window_ring} keys: a wave's prompt lands "
+                "in the ring whole; longer prompts prefill in windows")
     if cfg.num_experts and not _pallas_backend_ok(svc_cfg):
         raise RuntimeError(
             "the expert FFN's grouped matmul (ops/moe.py) is a Pallas TPU "

@@ -248,6 +248,40 @@ SSM_STATE_BYTES = Gauge(
     "beside the paged KV that grows a token at a time",
     ["model"],
 )
+KV_WINDOW_STORE_BYTES = Gauge(
+    "kv_window_store_bytes",
+    "Window layers whose store is a ring a stream (LLAMA_CONFIG "
+    "window_ring): bytes of window keys and values held by streams that "
+    "have a state row — every ring layer's K and V ring; a fixed size a "
+    "stream whatever its context, beside ssm_state_bytes (the recurrent "
+    "rows) and the paged pool (kv_pool_blocks x the block's bytes: the "
+    "layers that keep every key)",
+    ["model"],
+)
+KV_WINDOW_KEYS_OVERWRITTEN = Counter(
+    "kv_window_keys_overwritten_total",
+    "Window layers whose store is a ring: keys a ring overwrote, a ring "
+    "layer each — every position written at or past window_ring lands on "
+    "the key window_ring before it (what a block table keeps allocated "
+    "behind the window), from the host's own stream lengths, prompt "
+    "windows and delivered decode chunks alike",
+    ["model"],
+)
+PREFILL_SELF_POSITIONS = Counter(
+    "prefill_self_positions_total",
+    "A model with a cross-decoder (layer_types 'cross' / 'gmu'): real "
+    "prompt positions run through the self-decoder's layers by prompt-"
+    "window and prefill-wave dispatches",
+    ["model"],
+)
+PREFILL_CROSS_POSITIONS = Counter(
+    "prefill_cross_positions_total",
+    "A model with a cross-decoder: prompt positions run through the "
+    "cross-decoder's layers — one a prompt, its last, by the first decode "
+    "step of the stream that went live; a window or a wave runs none (over "
+    "prefill_self_positions_total: the split's share of a prompt)",
+    ["model"],
+)
 SSM_STATE_ROWS = Gauge(
     "ssm_state_rows",
     "Recurrent layers: rows of the recurrent state by what holds them: "

@@ -365,6 +365,12 @@ CONFIGS = [
       "layer_types": ["mamba"] * 5 + ["attention"] + ["mamba"] * 4,
       "llama_layer_types": ["mamba2"] * 5 + ["attention"] + ["mamba2"] * 4},
      {"granite-4.0-h-small-ep2-d10.longdoc-closed": _LONGDOC}),
+    ("phi4-mini-flash-d32",
+     _HF + "microsoft/Phi-4-mini-flash-reasoning/blob/main/config.json",
+     [], {"num_hidden_layers": 32, "tie_word_embeddings": True, "sliding_window": 512,
+          "num_key_value_heads": 20, "vocab_size": 200064, "window_ring": 1552,
+          "layers_block_type": ["mamba", "window"] * 8 + ["mamba", "full", "gmu", "cross"]},
+     {"phi4-mini-flash-d32.longdoc-closed": _LONGDOC}),
 ]
 
 

@@ -280,6 +280,11 @@ _KERNEL_CELLS = {
     "trinity-view": (32, 4, 8, 136, 12573),
     # 20 query heads on ONE KV head: 20 rows are no multiple of the 8 sublanes
     "jamba": (32, 1, 20, 392, 13184),
+    # Phi-4-mini-flash's differential pairs: 10 KV pairs 128 wide, 4 query
+    # heads a pair — the one pool (eight layers' reads) and a window layer's
+    # view of its ring (40 of a row's 97 blocks; 32 rows of 97)
+    "phi4flash-pool": (32, 10, 4, 392, 12545),
+    "phi4flash-ring": (32, 10, 4, 40, 3104),
 }
 
 
@@ -595,7 +600,8 @@ def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads, windows):
     (32, 8, 128, jnp.float32),  # Mistral's heads past a bucket, float32
     (16, 16, 128, jnp.bfloat16),  # OLMoE's: a KV head a query head
     (20, 1, 128, jnp.bfloat16),  # Jamba's: 20 query heads on ONE KV head
-], ids=["d64", "f32", "n_rep1", "n_rep20"])
+    (40, 10, 128, jnp.bfloat16),  # Phi-4-mini-flash's: 10 differential pairs of 128
+], ids=["d64", "f32", "n_rep1", "n_rep20", "diff_pairs"])
 def test_prompt_window_kernel_compiles_at_other_widths(chip, h, kvh, d, dt):
     """Any Llama-shaped deployment with ``PREFILL_CHUNK`` past its buckets
     reaches the kernel: heads narrower than the 128 lanes (padded in the
