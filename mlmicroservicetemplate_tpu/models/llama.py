@@ -1385,9 +1385,23 @@ def _kv_source(cfg: "LlamaConfig") -> int:
 #   ``k_b`` [H, nope, r] = W_UK, ``v_b`` [H, r, v] = W_UV: W_UKV split once
 #   at init (``init_params``).
 
-#: Heads a block of the expanded attention holds keys, values and float32
-#: scores for: 128 heads x 1024 queries x 6272 keys would be 3.3 GB.
+#: Heads a block of a prefill WAVE's expanded attention holds keys, values
+#: and float32 scores for: 128 heads x 1024 queries x 6272 keys would be
+#: 3.3 GB.  A prompt window's scores stay in the kernel's VMEM: its blocks
+#: answer to another need (``MLA_WINDOW_BYTES``).
 MLA_HEAD_BLOCK = 16
+
+#: Bytes of expanded keys, values, padded queries and output ONE static
+#: head block of a prompt window's attention may hold in HBM: DeepSeek-V2's
+#: heads of 256 + 128 lanes, 2048 queries over 6272 keys in bfloat16, are
+#: 6.4 MB each — 16 heads a block, 8 blocks a layer, 0.1 GB of transients
+#: where all 128 at once are 0.8.  The value is measured, not reckoned
+#: (PERF.md section 6, PR 64; one window alone on a v5e, ms at start 0 /
+#: 4096): 1 block 62.6 / 84.7, 2 62.9 / 85.1, 4 63.0 / 85.2, 8 61.9 / 84.0,
+#: 16 61.7 / 83.9 — the kernel fetches its key tiles 8 % faster from rows
+#: 8 KB wide than from rows 64 KB wide; in the cell 8 blocks read
+#: ``tbt_p99_ms`` 1.3 ms under one block's and ``setup_s`` 3 s over it.
+MLA_WINDOW_BYTES = 128 << 20
 
 
 def _mla_qkv(cfg: "LlamaConfig", layer, x, cos, sin, ad=None, li: int = 0):
@@ -1425,13 +1439,21 @@ def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask,
                             window_at=None):
     """Expanded attention of q = (qn [B, Sq, H, nope], qr [B, Sq, H, rope])
     over the keys ``latent`` [B, Sk, lanes] -> [B, Sq, H, v]: the prefill
-    form, ``MLA_HEAD_BLOCK`` heads at a time — a block expands its heads'
-    keys and values from the latents (``mla_expand``), scores them in
-    float32 and weighs its values: in XLA under ``mask`` [B, 1, Sq, Sk]
-    (a prefill wave), or, for one prompt window (``window_at`` =
-    ``(kpos0, start, chunk_mask [C])``, B = 1, no ``mask``), through the
-    prompt-window kernel with the expanded heads as its KV heads — a
-    head's key is its 128 nope dims beside the token's one rotary key."""
+    form, keys and values made again from the latents (``mla_expand``).
+    Two branches that share the latent's split and nothing else:
+
+    - a prefill WAVE (``mask`` [B, 1, Sq, Sk], ``window_at`` None; the
+      check prompts' and the unary path's forward too): XLA's attention
+      under the mask, ``MLA_HEAD_BLOCK`` heads at a time inside
+      ``lax.map`` — a block expands its heads' keys and values, scores
+      them in float32 and weighs its values; the blocks are what keeps the
+      float32 scores of all heads out of HBM;
+    - one prompt WINDOW (``window_at`` = ``(kpos0, start, chunk_mask
+      [C])``, B = 1, no ``mask``): ``_mla_window_attention`` — the heads'
+      keys and values each out of one matmul in the layout the
+      prompt-window kernel reads, in a few static head blocks, no loop."""
+    if window_at is not None:
+        return _mla_window_attention(cfg, layer, q, latent, window_at)
     a, r = layer["attn"], cfg.kv_lora_rank
     qn, qr = q
     b, sq, hn, dn = qn.shape
@@ -1444,36 +1466,8 @@ def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask,
         shape = x.shape[:axis] + (nblk, hb) + x.shape[axis + 1:]
         return jnp.moveaxis(x.reshape(shape), axis, 0)
 
-    def window_block(qn_h, qr_h, kb, vb):
-        """A head block through the prompt-window kernel.  Keys and values
-        are built 2-D and lane-dense, ``[K, hb * lanes]`` as the kernel
-        reads them — a head's ``nope`` dims, the token's rotary key, zero
-        lanes up to a multiple of 128 (192 -> 256: the MXU contracts 256
-        in the passes 192 take): on the chip a ``[K, hb, lanes]`` array is
-        tiled over (head, lane) and merging its trailing dims is a
-        relayout of every byte (51 MB a block, PERF.md section 6, PR 34)."""
-        from ..ops.prefill_attention import prefill_attention
-
-        k_len, dr, dv = c.shape[1], kr.shape[-1], cfg.v_head_dim
-        pad = -(dn + dr) % 128
-        with jax.named_scope("mla_expand"):
-            kn = jnp.einsum("kr,mr->km", c[0], kb.reshape(hb * dn, r).astype(c.dtype))
-            v = jnp.einsum("kr,mr->km", c[0], jnp.swapaxes(vb, 1, 2).reshape(
-                hb * dv, r).astype(c.dtype))
-            kr_p = jnp.pad(kr[0], ((0, 0), (0, pad)))
-            k = jnp.concatenate(
-                [x for h in range(hb) for x in (kn[:, h * dn:(h + 1) * dn], kr_p)],
-                axis=1)
-        qh = jnp.pad(jnp.concatenate([qn_h[0], qr_h[0]], axis=-1),
-                     ((0, 0), (0, 0), (0, pad)))
-        return prefill_attention(
-            qh, k.reshape(k_len, hb, -1), v.reshape(k_len, hb, dv), *window_at,
-            scale=cfg.attn_scale, interpret=cfg.pallas_interpret)[None]
-
     def block(args):
         qn_h, qr_h, kb, vb = args
-        if window_at is not None:
-            return window_block(qn_h, qr_h, kb, vb)
         with jax.named_scope("mla_expand"):
             kn = jnp.einsum("bkr,hnr->bkhn", c, kb.astype(c.dtype))
             v = jnp.einsum("bkr,hrv->bkhv", c, vb.astype(c.dtype))
@@ -1489,6 +1483,81 @@ def _mla_expanded_attention(cfg: "LlamaConfig", layer, q, latent, mask,
         blocks(a["k_b"]["kernel"], 0), blocks(a["v_b"]["kernel"], 0)))
     # [nblk, B, Sq, hb, v] -> [B, Sq, H, v]
     return jnp.moveaxis(out, 0, 2).reshape(b, sq, hn, cfg.v_head_dim)
+
+
+def mla_window_head_blocks(heads: int, c: int, k_len: int, dk: int, dv: int,
+                           itemsize: int) -> int:
+    """Static head blocks a prompt window's expanded attention goes in:
+    the FEWEST (a divisor of ``heads``) whose keys ``[k_len, hb * dk]``,
+    values ``[k_len, hb * dv]``, padded queries ``[c, hb * dk]`` and
+    output ``[c, hb * dv]`` together stay within ``MLA_WINDOW_BYTES`` — a
+    rule of the shapes: 8 blocks of 16 heads at DeepSeek-V2's served
+    widths (102 MB a block), one wherever a layer's heads fit whole."""
+    a_head = (k_len + c) * (dk + dv) * itemsize
+    return next((n for n in range(1, heads) if heads % n == 0
+                 and heads // n * a_head <= MLA_WINDOW_BYTES), heads)
+
+
+def _mla_window_attention(cfg: "LlamaConfig", layer, q, latent, window_at):
+    """One prompt window's expanded attention, q = (qn [1, C, H, nope],
+    qr [1, C, H, rope]) over the row's latents [1, K, lanes] as the pool
+    holds them ([c | kr | zeros]) -> [1, C, H, v], through the
+    prompt-window kernel with the expanded heads as its KV heads.
+
+    Keys and values are each WRITTEN ONCE, 2-D, lane-dense and row-major,
+    by the matmul whose output the kernel reads (``mla_expand``): ``k``
+    ``[K, hb * dk]`` = the latent rows times an expansion matrix (held
+    transposed, ``[hb * dk, lanes]``) whose rows ``h * dk .. + nope`` are
+    W_UK's head h, the next ``rope`` an identity from the rotary lanes, the
+    rest zeros up to ``dk`` = a multiple of 128 lanes (192 -> 256: the MXU
+    contracts 256 in the passes 192 take) — products with 1 and 0 are
+    exact under the float32 accumulator, so a head's key is its nope dims
+    beside the token's one rotary key, as the wave branch scores them;
+    ``v`` ``[K, hb * v]`` = c times W_UV.  The layout is STATED
+    (``with_layout_constraint``): left to itself the chip's compiler
+    writes both products key-minor and copies them (PERF.md section 6,
+    PR 64).  No ``kn`` that is read back, no ``[K, hb, lanes]`` array
+    (tiled over (head, lane) on the chip: merging its trailing dims relays
+    every byte out), no per-head concatenate, no ``lax.map`` and no stack
+    to transpose out of: q goes in ``[C, hb, dk]`` (nope | rotary | zero
+    lanes, one pad), the output comes back ``[C, hb, v]``.  The matrices
+    are made from ``k_b`` / ``v_b`` as they are.  The heads go in
+    ``mla_window_head_blocks`` static blocks (a Python loop), outputs
+    joined on the heads axis — ``[C, H, v]`` as ``merge_heads`` wants it."""
+    from jax.experimental.layout import Layout, with_layout_constraint
+
+    from ..ops.prefill_attention import prefill_attention
+
+    a, r = layer["attn"], cfg.kv_lora_rank
+    qn, qr = q[0][0], q[1][0]
+    c, hn, dn = qn.shape
+    dr, dv = qr.shape[-1], cfg.v_head_dim
+    rows = latent[0]
+    k_len, lanes = rows.shape
+    dt = rows.dtype
+    dk = dn + dr + -(dn + dr) % 128
+    hb = hn // mla_window_head_blocks(hn, c, k_len, dk, dv, dt.itemsize)
+    row_major = Layout(major_to_minor=(0, 1))
+    # A head's lanes nope .. nope + rope <- the rotary lanes of a latent row.
+    rot = jnp.zeros((dk, lanes), dt).at[
+        dn + jnp.arange(dr), r + jnp.arange(dr)].set(1)
+    out = []
+    for lo in range(0, hn, hb):
+        with jax.named_scope("mla_expand"):
+            w_k = jnp.pad(a["k_b"]["kernel"][lo:lo + hb].astype(dt),
+                          ((0, 0), (0, dk - dn), (0, lanes - r))) + rot
+            w_v = jnp.swapaxes(a["v_b"]["kernel"][lo:lo + hb].astype(dt), 1, 2)
+            k = with_layout_constraint(jnp.einsum(
+                "kl,ml->km", rows, w_k.reshape(hb * dk, lanes)), row_major)
+            v = with_layout_constraint(jnp.einsum(
+                "kr,mr->km", rows[:, :r], w_v.reshape(hb * dv, r)), row_major)
+        qh = jnp.pad(
+            jnp.concatenate([qn[:, lo:lo + hb], qr[:, lo:lo + hb]], axis=-1),
+            ((0, 0), (0, 0), (0, dk - dn - dr)))
+        out.append(prefill_attention(
+            qh, k.reshape(k_len, hb, dk), v.reshape(k_len, hb, dv), *window_at,
+            scale=cfg.attn_scale, interpret=cfg.pallas_interpret))
+    return (out[0] if len(out) == 1 else jnp.concatenate(out, axis=1))[None]
 
 
 def _mla_decode_attention(cfg: "LlamaConfig", layer, q, pool, table,

@@ -289,3 +289,40 @@ def expert_row_kernels_at_toy_size(monkeypatch, rows: int = 128,
 
     monkeypatch.setattr(moe, "ROW_KERNELS_MIN_ROWS", rows)
     monkeypatch.setattr(moe, "ROW_KERNELS_MIN_ROW_BYTES", row_bytes)
+
+
+def latent_window_both_ways(cfg, layer, li: int, start: int, n_valid: int,
+                            c: int = 8, k_len: int = 24, seed: int = 5):
+    """A latent layer's prompt window of ``c`` queries from ``start``
+    (``n_valid`` real tokens, then pad) over a row of ``k_len`` table keys
+    whose first ``start + c`` are written: ``(got, want)`` [c, H, v] —
+    ``_mla_expanded_attention``'s window branch through the prompt-window
+    kernel, and ``prefill_attention_ref`` over keys and values expanded the
+    plain way (the wave branch's einsums; a head's key = its nope dims
+    beside the token's one rotary key)."""
+    import jax
+    import jax.numpy as jnp
+
+    from mlmicroservicetemplate_tpu.models import llama as llama_mod
+    from mlmicroservicetemplate_tpu.ops.prefill_attention import prefill_attention_ref
+
+    n = start + c
+    x = jax.random.normal(jax.random.PRNGKey(seed), (1, n, cfg.d_model)) * 0.5
+    cos, sin = llama_mod._rope_tables(cfg, jnp.arange(n, dtype=jnp.int32), jnp.float32)
+    (qn, qr), latent, _, _ = llama_mod._qkv_rope(
+        cfg, layer, None, li, x, cos[None, :, None, :], sin[None, :, None, :])
+    rows = jnp.zeros((1, k_len, cfg.latent_lanes)).at[:, :n].set(latent)
+    chunk_mask = (jnp.arange(c) < n_valid).astype(jnp.int32)
+    got = llama_mod._mla_expanded_attention(
+        cfg, layer, (qn[:, start:], qr[:, start:]), rows, None,
+        (0, start, chunk_mask))[0]
+    a, r = layer["attn"], cfg.kv_lora_rank
+    lat, kr = rows[0, :, :r], rows[0, :, r:cfg.latent_dim]
+    kn = jnp.einsum("kr,hnr->khn", lat, a["k_b"]["kernel"])
+    v = jnp.einsum("kr,hrv->khv", lat, a["v_b"]["kernel"])
+    k = jnp.concatenate(
+        [kn, jnp.broadcast_to(kr[:, None], kn.shape[:2] + kr.shape[-1:])], axis=-1)
+    want = prefill_attention_ref(
+        jnp.concatenate([qn[0, start:], qr[0, start:]], axis=-1), k, v, 0, start,
+        chunk_mask, scale=cfg.attn_scale)
+    return got, want

@@ -561,7 +561,7 @@ def test_a_prompt_windows_scores_stay_on_the_chip(chip, cell, heads, windows):
     """The prompt-window executable at the cells' shapes (Trinity: 1024
     queries, 32 heads, a window layer over 3088 gathered keys and the full
     layer over the table's 6272; DeepSeek-V2: 2048 queries, 16 expanded
-    heads at a time over 6272) holds the prompt-window kernel at every
+    heads a static block over 6272) holds the prompt-window kernel at every
     layer and NO float32 array of heads x queries x keys
     (``prefill_scores_in_hbm: []``): until PR 34 XLA wrote and re-read one
     a layer (0.4-0.8 GB each).  The ``lax.switch`` over key widths is

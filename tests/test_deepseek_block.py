@@ -309,7 +309,7 @@ def test_absorbed_is_expanded_on_the_same_weights(cfg, params):
 
 def test_the_prompt_window_kernel_is_the_xla_window(cfg, params, monkeypatch):
     """``paged_prefill_chunk`` through the prompt-window kernel
-    (``cfg.pallas_decode``: the row's latents expanded 16 heads at a time)
+    (``cfg.pallas_decode``: the row's latents expanded once a layer)
     against the XLA form under ``_prefill_mask``, in windows of 8 over
     prompts that end inside a window, at its end and in a second one:
     every pool holds the same rows, and the next token's logits (one
@@ -336,6 +336,135 @@ def test_the_prompt_window_kernel_is_the_xla_window(cfg, params, monkeypatch):
             params, dataclasses.replace(cfg, pallas_decode=False), st, table)
     kernel_logits, xla_logits = seen.seen
     assert _close(kernel_logits, xla_logits) < TOL
+
+
+@pytest.mark.parametrize("start,n_valid", [(0, 8), (8, 5)],
+                         ids=["first-window", "behind-a-full-window-padded"])
+@pytest.mark.parametrize("blocks", [1, 2], ids=["one-call", "two-head-blocks"])
+def test_the_window_branch_is_the_plain_expansion(cfg, params, monkeypatch,
+                                                  blocks, start, n_valid):
+    """``_mla_expanded_attention``'s window branch — keys ``[K, H * dk]``
+    and values ``[K, H * v]`` each out of ONE matmul of the latent rows
+    (the rotary key through an identity block of the expansion matrix),
+    the kernel called once over all heads, or, past ``MLA_WINDOW_BYTES``,
+    once a static head block — against ``prefill_attention_ref`` over keys
+    expanded the plain way: a window at start 0, and one behind a full
+    earlier window with pad tokens at its end."""
+    from helpers import latent_window_both_ways
+    from mlmicroservicetemplate_tpu.ops import prefill_attention as pa
+
+    c, k_len, dk = 8, 24, 128
+    if blocks == 2:  # two heads' keys, values, queries and output, float32
+        monkeypatch.setattr(llama_mod, "MLA_WINDOW_BYTES",
+                            2 * (k_len + c) * (dk + cfg.v_head_dim) * 4)
+    assert llama_mod.mla_window_head_blocks(
+        cfg.num_heads, c, k_len, dk, cfg.v_head_dim, 4) == blocks
+    calls, real = [], pa.prefill_attention
+    monkeypatch.setattr(pa, "prefill_attention", lambda q, k, v, *a, **kw: (
+        calls.append((q.shape, k.shape, v.shape)), real(q, k, v, *a, **kw))[1])
+    got, want = latent_window_both_ways(
+        cfg, params["layers"][1], 1, start, n_valid, c, k_len)
+    hb = cfg.num_heads // blocks
+    assert calls == [((c, hb, dk), (k_len, hb, dk), (k_len, hb, cfg.v_head_dim))] * blocks
+    assert got.shape == want.shape == (c, cfg.num_heads, cfg.v_head_dim)
+    assert _close(got, want) < TOL
+    assert float(jnp.max(jnp.abs(want[:n_valid]))) > 100 * TOL
+
+
+def _window_program(kw, **over) -> tuple:
+    """The toy's ``paged_prefill_chunk`` with kernels on, traced on shapes
+    (nothing compiled or run): ``(traced, element count of a layer's
+    expanded keys, layers)``.  ``qk_nope_head_dim`` 136 makes a key 256
+    lanes, so that no other array of the program (the values padded to 128
+    lanes, the queries) has as many elements."""
+    from mlmicroservicetemplate_tpu.models.gpt import PagedState
+    from mlmicroservicetemplate_tpu.models.sampling import greedy_params
+
+    wcfg = llama_mod.LlamaConfig(**{
+        **kw, "qk_nope_head_dim": 136, "pallas_interpret": False,
+        "pallas_decode": True, **over})
+    b, c, bs, nb, t_w = 1, 8, 2, 24, 12
+    p = jax.eval_shape(lambda: llama_mod.init_params(jax.random.PRNGKey(0), wcfg))
+    state = jax.eval_shape(lambda: PagedState(
+        cache_k=[jnp.zeros((nb, bs, wcfg.latent_lanes))] * wcfg.num_layers, cache_v=[],
+        key_valid=jnp.zeros((b, t_w * bs), jnp.int32),
+        write_idx=jnp.zeros((b,), jnp.int32), pos=jnp.zeros((b,), jnp.int32),
+        last_token=jnp.zeros((b,), jnp.int32), done=jnp.ones((b,), bool),
+        tokens=jnp.zeros((b, 8), jnp.int32), sample=greedy_params(b)))
+    i32 = jnp.int32
+    traced = jax.jit(
+        lambda p, s, tabs, ids, mask, starts: llama_mod.paged_prefill_chunk(
+            p, wcfg, s, tabs, ids, mask, starts),
+    ).trace(p, state, jax.ShapeDtypeStruct((b, t_w), i32),
+            jax.ShapeDtypeStruct((b, c), i32), jax.ShapeDtypeStruct((b, c), i32),
+            jax.ShapeDtypeStruct((b,), i32))
+    return traced, t_w * bs * wcfg.num_heads * 256, wcfg.num_layers
+
+
+def _loops_under(jaxpr, scope: str) -> list:
+    """The name stacks of the ``while`` / ``scan`` equations under the
+    named scope ``scope``, nested jaxprs included (a kernel's body is the
+    kernel's own business)."""
+    from jax._src import core
+
+    hits = []
+
+    def walk(jp, outer):
+        for e in jp.eqns:
+            stack = outer + [str(e.source_info.name_stack)]
+            if e.primitive.name in ("while", "scan") and scope in "/".join(stack).split("/"):
+                hits.append("/".join(stack))
+            if e.primitive.name != "pallas_call":
+                for sub in core.jaxprs_in_params(e.params):
+                    walk(sub, stack)
+
+    walk(jaxpr.jaxpr, [])
+    return hits
+
+
+def _ops_of_size(text: str, n_elems: int) -> list:
+    """``(op, result type)`` of the StableHLO operations whose result holds
+    ``n_elems`` elements, whatever its shape — but for a ``pad`` of zero
+    widths and the call that wraps it (the kernel's wrapper pads every
+    head dim to whole lane tiles: nothing, at 256)."""
+    import math
+    import re
+
+    hits = []
+    for line in text.splitlines():
+        if re.search(r"pad .*low = \[0(, 0)*\], high = \[0(, 0)*\], interior = \[0(, 0)*\]"
+                     r"|call @_pad", line):
+            continue
+        m = re.search(r"= (?:stablehlo\.custom_call @|call @|stablehlo\.|\")?"
+                      r"([\w.]+).*-> tensor<((?:\d+x)+)[a-z]\w*>\s*(?:loc.*)?$", line)
+        if m and math.prod(map(int, m.group(2)[:-1].split("x"))) == n_elems:
+            hits.append((m.group(1), m.group(2)))
+    return hits
+
+
+def test_the_windows_keys_are_written_once_in_the_kernels_layout(kw):
+    """The layout is the change, so the lowered program is held to it: a
+    prompt window of the toy latent configuration with kernels on holds no
+    loop under ``attn`` (the wave branch's ``lax.map`` over head blocks is
+    one) and, of all operations whose result is as large as a layer's
+    expanded keys, ONE matmul a layer that writes ``[K, H * dk]`` — then
+    only the layout's statement and reshapes that move nothing, down to
+    the kernel's operand: no concatenate, pad, transpose, broadcast or
+    update of that size."""
+    traced, n_keys, layers = _window_program(kw)
+    assert _loops_under(traced.jaxpr, "attn") == []
+    text = traced.lower(lowering_platforms=("tpu",)).as_text()
+    # (the last layer's attention is dead code: a window reads no logit)
+    assert text.count("prefill_attention") >= layers - 1  # the kernel, a layer
+    ops = _ops_of_size(text, n_keys)
+    assert [o for o, _ in ops].count("dot_general") == layers - 1
+    assert {o for o, _ in ops} <= {"dot_general", "LayoutConstraint", "reshape"}, ops
+    two_d = f"24x{n_keys // 24}x"  # [K, H * dk], as the kernel's operand lies
+    assert all(t == two_d for o, t in ops if o != "reshape"), ops
+    # ... and the reader sees what it is there to see: without the kernels
+    # the window runs the wave branch, whose map over head blocks is a loop
+    traced, _, _ = _window_program(kw, pallas_decode=False)
+    assert len(_loops_under(traced.jaxpr, "attn")) >= layers - 1
 
 
 # ---------------------------------------------------------------------------

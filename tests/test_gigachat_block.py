@@ -321,6 +321,25 @@ def test_a_layer_is_the_reference(ref, config, cfg, params, li):
         assert 0.05 < held < 0.6  # some assignments land here, most do not
 
 
+def test_a_prompt_window_at_64_heads_is_the_plain_expansion(kw):
+    """The latent layer's prompt window at the PUBLISHED 64 heads (the
+    toy's other widths): keys and values of all 64 out of one matmul each,
+    one call of the prompt-window kernel, against ``prefill_attention_ref``
+    over keys expanded the plain way — a window behind a full earlier one,
+    pad tokens at its end."""
+    from helpers import latent_window_both_ways
+
+    wide = llama_mod.LlamaConfig(**{**kw, "num_heads": 64})
+    li = wide.num_layers - 1
+    assert wide.layer_kind(li).attention == "mla"
+    layer = llama_mod.init_params(jax.random.PRNGKey(3), wide)["layers"][li]
+    assert llama_mod.mla_window_head_blocks(64, 8, 24, 128, wide.v_head_dim, 4) == 1
+    got, want = latent_window_both_ways(wide, layer, li, 8, 5)
+    assert got.shape == want.shape == (8, 64, wide.v_head_dim)
+    assert _close(got, want) < TOL
+    assert float(jnp.max(jnp.abs(want[:5]))) > 100 * TOL
+
+
 def test_the_shares_add_up_to_the_uncut_layer(ref, config, kw):
     """The expert FFN on each of the four chips of the toy's deployment (4
     of 16 experts held, ``expert_first`` 0 / 4 / 8 / 12, slices of ONE uncut
