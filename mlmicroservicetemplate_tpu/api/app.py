@@ -1556,12 +1556,14 @@ async def handle_status(request: web.Request) -> web.Response:
             "prep_hits": getattr(cdl, "prep_hits", 0),
             "prep_misses": getattr(cdl, "prep_misses", 0),
             "idle_admit": _idle_admit(cdl, loop_time),
-            # Waves that met chunks in flight, and the chunks the loop
+            # Waves that met chunks in flight, the chunks the loop
             # delivered ahead of those waves' fetches (chunks / waves =
-            # about the chain depth + 1).
+            # about the chain depth), and the waves whose start went
+            # out ahead of their iteration's chunk.
             "ahead_of_wave": {
                 "waves": cdl.waves_behind_chunks,
                 "chunks": cdl.chunks_ahead_of_wave,
+                "waves_ahead_of_chunk": cdl.waves_ahead_of_chunk,
             },
             # Where the loop thread's wall time went, by phase, since it
             # started: wall_s = sum of phases[*].s + unnamed_s; the

@@ -602,10 +602,12 @@ class ContinuousDecodeLoop:
         self._idle_gap_s = 0.0
         self.idle_wait_rows = 0     # rows idle admissions' waits added
         self.idle_waits_capped = 0  # waits that ended on the cap
-        # Waves that met chunks in flight, and the chunks delivered ahead
-        # of those waves' fetches (``_deliver_ahead_of_wave``).
+        # Waves that met chunks in flight, the chunks delivered ahead
+        # of those waves' fetches (``_deliver_ahead_of_wave``), and the
+        # waves whose start went out ahead of their iteration's chunk.
         self.waves_behind_chunks = 0
         self.chunks_ahead_of_wave = 0
+        self.waves_ahead_of_chunk = 0
         # Where this loop's thread spends its wall time, by phase, always
         # on (utils/tracing.LoopTable; /status.decode.loop_time).  The
         # idle admissions that waited at all, and for how long, are its
@@ -1082,32 +1084,59 @@ class ContinuousDecodeLoop:
                 # active stream's budget is covered by chunks already
                 # in flight, dispatching more only wastes device/link
                 # bandwidth and delays completion detection.
+                #
+                # The order of an iteration is the chunk's HOST half
+                # (paged: the live rows' growth pass and the table),
+                # the wave's start, the chunk's device dispatch.  A
+                # start reads the request's own inputs and the
+                # parameters and returns a state of its own — nothing of
+                # the decode state or the pool — and joins the batch by
+                # the insert after its fetch, so the chunk holds the
+                # rows it held wherever the start sits.  The device
+                # runs what was in flight, the start, then the chunk: a
+                # newcomer's first token does not wait out a chunk that
+                # computes nothing of its, and a live stream's one gap
+                # across the admission is start + chunk, with no host
+                # round trip inside it.  (A wave that is all prompt
+                # windows or swap-ins dispatches nothing and hands back
+                # no admissions.)  The growth pass stays ahead of the
+                # wave because the wave may take from the pool (a
+                # swap-in's blocks, a promoted prefix): live streams
+                # keep their claim on a dry pool and a resumed stream
+                # waits for blocks, never the other way round.  The
+                # wave is pending from before any of it, so a fault in
+                # either half of the chunk finds its streams.
                 dispatched = False
                 self._pending_wave = wave
-                if self.active and self._work_remains():
-                    with tracing.phase("loop/chunk_dispatch"):
-                        self._dispatch_chunk()
-                    dispatched = True
+                live = bool(self.active) and self._work_remains()
+                table = None
+                if live and self.paged:
+                    with tracing.phase("loop/chunk_prep"):
+                        table = self._chunk_table()
+                    live = table is not None  # a dry pool took every row
                 t_admit = time.monotonic()
                 t_wave = t_admit if wave and self.active else None
+                n_ahead = len(self._inflight_chunks)
                 if wave:
-                    # Overlapped admission, AFTER the live chunk's
-                    # dispatch: the wave's batched prefill queues
-                    # BEHIND every chunk in flight on the device, and
-                    # the host reads in the device's order — each of
-                    # those chunks is delivered as it lands, and only
-                    # then does the loop block on the wave's fetch.
-                    # A live stream pays the prefill compute ONCE, in
-                    # the one gap that spans the admission: start +
-                    # insert + the next chunk, and nothing else (the
-                    # admitted streams pay the chunks queued ahead of
-                    # their start).
                     with tracing.phase("loop/wave_dispatch"):
                         self._pending_admissions = self._admit_dispatch(wave)
                 self._pending_wave = []
+                if live:
+                    with tracing.phase("loop/chunk_dispatch"):
+                        self._dispatch_chunk(table)
+                    dispatched = True
                 if self._pending_admissions:
+                    if dispatched:
+                        self.waves_ahead_of_chunk += 1
+                        metrics.WAVES_AHEAD_OF_CHUNK.labels(
+                            self.engine.bundle.name
+                        ).inc()
                     rows = self._wave_rows(len(self._pending_admissions))
-                    self._deliver_ahead_of_wave()
+                    # The host reads in the device's order: the chunks
+                    # dispatched before the start as they land, then the
+                    # start; the chunk behind it stays in flight for
+                    # this iteration's usual delivery below.
+                    self._deliver_ahead_of_wave(n_ahead)
                     self._admit_complete(self._pending_admissions)
                     self._pending_admissions = []
                     self._wave_seconds[rows] = time.monotonic() - t_admit
@@ -2101,8 +2130,9 @@ class ContinuousDecodeLoop:
     def _admit_dispatch(self, wave: list[_Stream]) -> list:
         """Phase 1 of admission: queue the wave's prefill work on the
         device and start async host copies of the first chunks — NO
-        blocking fetch here, so the caller can slide the next shared
-        chunk dispatch in front of the fetch round-trip.
+        blocking fetch here, so the caller can dispatch the live
+        streams' next chunk BEHIND it before it waits on anything: the
+        device goes from the start straight into that chunk.
 
         A multi-stream wave prefills as ONE batched ``_start`` dispatch
         (``_wave_rows`` rows, at the widest prompt bucket in the wave):
@@ -2408,11 +2438,15 @@ class ContinuousDecodeLoop:
         """Phase 2: one combined ``device_get`` fetches every admitted
         stream's first chunk + done flag (a wave costs ~one RTT, not
         N — batched waves share one (toks, done) pair, fetched once),
-        then emit + insert into free slots.  The caller has drained the
-        chunks in flight first (``_deliver_ahead_of_wave``): this fetch
-        is for the LAST thing in the device's queue, so nothing that
-        has landed waits behind it, and a live stream's gap across the
-        admission is start + insert + its next chunk."""
+        then emit + insert into free slots.  The caller has delivered
+        the chunks dispatched ahead of the wave first
+        (``_deliver_ahead_of_wave``), so nothing that has landed waits
+        behind this fetch; where streams are live, their next chunk is
+        already queued BEHIND the start and stays in flight across the
+        insert (its ``(toks, done)`` are its own outputs, copied to the
+        host from dispatch, and it is routed by its own snapshot of the
+        slots), so a live stream's gap across the admission is start +
+        that chunk."""
         import jax
 
         if not started:
@@ -4272,16 +4306,20 @@ class ContinuousDecodeLoop:
         """Block-by-block growth at the dispatch boundary: every live
         row's table must cover the positions the NEXT chunk will write.
         A row whose growth finds the pool dry — after reclaiming
-        prefix pins — is checkpointed and re-queued (token-identical
-        resume when blocks free), the paged equivalent of vLLM's
-        preempt-on-OOM; admission's worst-case bound guarantees a
-        stream running alone always fits, so this terminates."""
+        prefix pins and delivering what is in flight
+        (``_ensure_on_dry_pool``) — is checkpointed and re-queued
+        (token-identical resume when blocks free), the paged equivalent
+        of vLLM's preempt-on-OOM; admission's worst-case bound
+        guarantees a stream running alone always fits, so this
+        terminates."""
         from .kv_blocks import OutOfBlocks
 
         eng = self.engine
         chunk = eng.chunk_tokens
         grew = False
         for slot, st in list(self.active.items()):
+            if self.active.get(slot) is not st:
+                continue  # ended in a delivery a dry pool forced below
             if st.cancelled.is_set() or st.blocks is None:
                 continue  # frees at the next delivery; writes drop
             steps = self._dispatched_steps.get(slot, 0) + chunk
@@ -4294,10 +4332,10 @@ class ContinuousDecodeLoop:
                 eng.fault_point("grow")
                 fresh = st.blocks.ensure(need)
             except OutOfBlocks:
-                try:
-                    self._reclaim_then_ensure(st.blocks, need)
-                    fresh = st.blocks.ids[-1:]  # table refresh below
-                except OutOfBlocks:
+                fresh = self._ensure_on_dry_pool(slot, st, need)
+                if fresh is None:
+                    if self.active.get(slot) is not st:
+                        continue  # its last chunk was in flight
                     metrics.KV_GROWTH_STALLS.labels(eng.bundle.name).inc()
                     if self._flight is not None:
                         self._flight.event(
@@ -4322,6 +4360,34 @@ class ContinuousDecodeLoop:
         if grew and self.admission is not None:
             self.admission.note_pool()
 
+    def _ensure_on_dry_pool(self, slot: int, st: _Stream, need: int):
+        """A live row's growth found the pool dry: reclaim prefix pins;
+        still dry with chunks in flight, deliver them and try once more.
+        A row checkpointed with its chunk in flight loses that chunk's
+        tokens, and two rows that each need the pool's other half could
+        then preempt each other in turn with neither advancing (a
+        wave's insert meets the iteration's chunk in flight): delivered
+        first, every row keeps what the device has computed for it, so
+        each turn advances, and a row that ENDS in the delivery gives
+        its blocks back.  The fresh block ids (the caller refreshes the
+        table row), or None: the row ended there, or the pool is dry
+        for good and the caller checkpoints it."""
+        from .kv_blocks import OutOfBlocks
+
+        try:
+            self._reclaim_then_ensure(st.blocks, need)
+        except OutOfBlocks:
+            if not self._inflight_chunks:
+                return None
+            self._deliver_all()
+            if self.active.get(slot) is not st:
+                return None
+            try:
+                self._reclaim_then_ensure(st.blocks, need)
+            except OutOfBlocks:
+                return None
+        return st.blocks.ids[-1:]
+
     # -- double-buffered host prep (docs/compilation.md) ----------------
 
     def _stage_host_prep(self) -> None:
@@ -4330,9 +4396,10 @@ class ContinuousDecodeLoop:
         start the table's host→device upload, so the next dispatch's
         host work collapses to a validity check.  Growth here is the
         SAME ``_grow_for_dispatch`` the inline path runs — a dry pool
-        checkpoints exactly as it would one iteration later (the
-        in-flight chunk's undelivered tokens are not part of any
-        checkpoint, so resume stays token-identical).  The upload runs
+        checkpoints exactly as it would one iteration later, and like
+        it delivers what is in flight first (``_ensure_on_dry_pool``):
+        there, and only there, staging blocks on a fetch inside the
+        window it overlaps.  The upload runs
         under the ``prep`` dispatch site: measured in
         ``dispatch_host_seconds{site="prep"}``, watchdogged, and a
         chaos target (``rN:prep:fatal@K`` kills a replica mid-staging
@@ -4419,11 +4486,11 @@ class ContinuousDecodeLoop:
         self._rollback_staged_prep()
         return None
 
-    def _dispatch_chunk(self) -> None:
+    def _dispatch_chunk(self, table) -> None:
         eng = self.engine
         tr = tracing.tracer()
         if tr is None:
-            self._dispatch_chunk_inner(eng)
+            self._dispatch_chunk_inner(eng, table)
             return
         # Ring only (TRACE=1): which requests rode this chunk.  The
         # profiler's trace names the interval ``loop/chunk_dispatch``.
@@ -4432,7 +4499,7 @@ class ContinuousDecodeLoop:
             streams=[st.rid for st in self.active.values()],
             paged=self.paged,
         ):
-            self._dispatch_chunk_inner(eng)
+            self._dispatch_chunk_inner(eng, table)
 
     def _note_dispatched(self, entry) -> None:
         eng = self.engine
@@ -4456,26 +4523,34 @@ class ContinuousDecodeLoop:
             self._moe_windows = []
         self._inflight_chunks.append(entry)
 
-    def _dispatch_chunk_inner(self, eng) -> None:
-        if self.paged:
-            # Double-buffered prep: the staged plan (growth already
-            # ran, table already uploading) is used when still valid;
-            # otherwise fall back to the inline pass.
-            table = self._consume_staged_prep()
-            if table is None:
-                self._grow_for_dispatch()
-                if not self.active:  # every row checkpointed, dry pool
-                    return
-            use_sample = bool(self.sampled_slots)
+    def _chunk_table(self):
+        """The host half of a paged chunk dispatch: the live rows'
+        growth and the device block table the chunk reads.  Double-
+        buffered prep: the staged plan (growth already ran, table
+        already uploading) is used when still valid; otherwise the
+        inline pass.  None where every row was checkpointed on a dry
+        pool: no chunk goes out.  ``_run_loop`` calls it once an
+        iteration, ahead of the wave's dispatch, so a wave draws on the
+        pool after the live rows have."""
+        table = self._consume_staged_prep()
+        if table is None:
+            self._grow_for_dispatch()
+            if not self.active:  # every row checkpointed, dry pool
+                return None
             import jax.numpy as jnp
 
+            with self.engine._lock:
+                # A snapshot: the dispatch is asynchronous and the
+                # next handoff, growth or release writes ``_table``
+                # (the CPU backend aliases an aligned host buffer).
+                table = jnp.asarray(self._table.copy())
+        return table
+
+    def _dispatch_chunk_inner(self, eng, table) -> None:
+        if self.paged:
+            use_sample = bool(self.sampled_slots)
             dparams = self._mp()
             with eng._lock:
-                if table is None:
-                    # A snapshot: the dispatch is asynchronous and the
-                    # next handoff, growth or release writes ``_table``
-                    # (the CPU backend aliases an aligned host buffer).
-                    table = jnp.asarray(self._table.copy())
                 # ``done`` is an output of the chunk, as ``toks`` is:
                 # the entry is fetched after later dispatches have
                 # consumed this state.  With experts ``toks`` is
@@ -4698,23 +4773,26 @@ class ContinuousDecodeLoop:
             )
             self._route_entry(fetched, snapshot)
 
-    def _deliver_ahead_of_wave(self) -> None:
-        """A wave's prefill was just dispatched beside chunks in flight:
-        every one of them was dispatched ahead of it, so each lands
-        before it — deliver them oldest first, EACH in a fetch of its
-        own as it lands (one combined fetch would hold the oldest until
-        the newest has landed), before the caller blocks on the wave's
-        fetch.  The device's queue is as it was; only the host reads it
-        in its order.  A delivery may end a stream and free its slot and
+    def _deliver_ahead_of_wave(self, n_ahead: int) -> None:
+        """A wave's start was just dispatched behind ``n_ahead`` chunks
+        in flight (and ahead of its own iteration's chunk, the newest
+        entry where one went out): those land before it — deliver them
+        oldest first, EACH in a fetch of its own as it lands (one
+        combined fetch would hold the oldest until the newest has
+        landed), before the caller blocks on the wave's fetch.  The
+        chunk dispatched behind the start is NOT read here: waiting for
+        it would hold the newcomer's first token for a chunk that lands
+        after it.  A delivery may end a stream and free its slot and
         blocks before the insert takes one.  A fault raised here leaves
         ``_pending_admissions`` set for ``_recover`` and the loop's
-        handler, as one raised around the wave's own insert does."""
-        if not self._inflight_chunks:
+        handler, as one raised by the chunk's dispatch or around the
+        wave's own insert does."""
+        if not n_ahead:
             return
         name = self.engine.bundle.name
         self.waves_behind_chunks += 1
         metrics.WAVES_BEHIND_CHUNKS.labels(name).inc()
-        while self._inflight_chunks:
+        for _ in range(n_ahead):
             self._deliver_oldest()
             self.chunks_ahead_of_wave += 1
             metrics.CHUNKS_AHEAD_OF_WAVE.labels(name).inc()

@@ -167,7 +167,15 @@ CHUNKS_AHEAD_OF_WAVE = Counter(
     "In-flight decode chunks delivered, oldest first and each in a "
     "fetch of its own, ahead of a wave's fetch: over "
     "stream_waves_behind_chunks_total, the chunks a wave (about the "
-    "chain depth + 1 where admissions meet live streams)",
+    "chain depth where admissions meet live streams)",
+    ["model"],
+)
+WAVES_AHEAD_OF_CHUNK = Counter(
+    "stream_waves_ahead_of_chunk_total",
+    "Admission waves whose start was dispatched ahead of their "
+    "iteration's decode chunk, which stays in flight across the wave's "
+    "fetch and insert (a wave on an idle loop, or one that is all "
+    "prompt windows, is not counted)",
     ["model"],
 )
 PREFILL_WAVE_FILL = Histogram(
@@ -806,7 +814,7 @@ LOOP_PHASE_SECONDS = Counter(
     "server, loop/await_api = the API holds a request that has not "
     "reached the queue, loop/await_burst = an idle wave's quiet gap, "
     "loop/queue_pop, loop/wave_dispatch, loop/wave_fetch, loop/insert, "
-    "loop/chunk_dispatch, loop/stage_prep, loop/deliver, "
+    "loop/chunk_prep, loop/chunk_dispatch, loop/stage_prep, loop/deliver, "
     "loop/housekeeping, ...); with loop_unnamed_seconds_total they sum "
     "to the loop's wall time; /status.decode.loop_time has counts, the "
     "longest instance and the slowest iterations",

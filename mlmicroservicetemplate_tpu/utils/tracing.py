@@ -12,7 +12,8 @@ pattern — no OpenTelemetry or any other hard dependency beyond JAX):
 
   * its work: ``loop/queue_pop`` (the non-blocking pops),
     ``loop/wave_dispatch``, ``loop/wave_complete``, ``loop/wave_fetch``,
-    ``loop/insert``, ``loop/chunk_dispatch``, ``loop/prefill_advance``
+    ``loop/insert``, ``loop/chunk_prep`` (a paged chunk's host half),
+    ``loop/chunk_dispatch``, ``loop/prefill_advance``
     (around each ``prefill_window``), ``loop/swap_advance``,
     ``loop/stage_prep``, ``loop/deliver``, ``loop/housekeeping``
     (expiry, tier drains, gauges, the flight recorder's frame, the

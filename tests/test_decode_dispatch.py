@@ -12,13 +12,18 @@ The judged contracts:
 4. A fatal device fault at the chunk site with chunks in flight
    checkpoints at the delivered-token cursor and resumes
    token-identically (supervised rebuild).
-5. Admission rides BEHIND the live chunk: with streams live, a wave's
-   prefill is dispatched after the iteration's chunk, and the chunks in
-   flight are delivered, oldest first and each in a fetch of its own,
-   BEFORE the loop blocks on the wave's fetch (the host reads in the
-   device's order; no token changes; a stream that ends there frees its
-   slot before the insert; ``stream_chunks_ahead_of_wave_total`` /
-   ``stream_waves_behind_chunks_total`` count it).
+5. Admission goes AHEAD of the live chunk: with streams live, a wave's
+   start is dispatched before the iteration's chunk, the chunks that
+   were in flight before it are delivered, oldest first and each in a
+   fetch of its own, BEFORE the loop blocks on the wave's fetch, and the
+   chunk dispatched behind the start is still in flight at that fetch
+   and at the insert (the host reads in the device's order; no token
+   changes; a stream that ends in a chunk ahead frees its slot before
+   the insert; a wave on an idle loop, or one that is all prompt
+   windows, dispatches as it always did;
+   ``stream_chunks_ahead_of_wave_total`` /
+   ``stream_waves_behind_chunks_total`` /
+   ``stream_waves_ahead_of_chunk_total`` count it).
 6. The auto-tuned chain depth is pinned (``warm.depth_from``) and surfaced
    (stream_chain_depth gauge + /status.decode), beside the staged
    host prep's counters.
@@ -26,6 +31,7 @@ The judged contracts:
 """
 
 import asyncio
+import threading
 import time
 
 import numpy as np
@@ -268,6 +274,83 @@ def test_eos_row_blocks_freed_while_others_decode(family):
         cdl.stop()
 
 
+@pytest.mark.parametrize("fetch", ["lands", "fails"])
+def test_dry_pool_checkpoint_keeps_the_chunk_in_flight(fetch):
+    """Two streams that each need half of a six-block pool, with the
+    host tier to swap into: the second joins beside the first's chunk
+    (still in flight at the insert), the growth pass behind the insert
+    finds the pool dry and checkpoints a row.  What was in flight is
+    delivered FIRST — no row is ever checkpointed there with its chunk
+    in flight, whose tokens it would lose — so every turn advances (a
+    checkpoint that dropped the chunk let the two preempt each other in
+    turn, forever), both finish token-identically, and the pool drains.
+    That delivery is a blocking fetch inside the growth pass: where it
+    FAILS (a device fault surfaces at a fetch), the supervised loop
+    checkpoints every stream once, rebuilds, and both still read what
+    they read alone."""
+    from mlmicroservicetemplate_tpu.scheduler.admission import (
+        AdmissionController,
+    )
+
+    bundle = tiny_gpt_bundle()
+    layout = dict(paged_kv=True, kv_block_size=8)
+    bb = _engine(bundle, _cfg(**layout)).kv_pool.block_bytes
+    cfg = _cfg(max_decode_len=12, max_stream_queue=4, engine_restarts_max=2,
+               kv_budget_mb=6 * bb / 1e6, kv_host_budget_mb=1.0, **layout)
+    eng = _engine(bundle, cfg)
+    rng = np.random.default_rng(3)
+    feats = [_feats(rng, 14), _feats(rng, 14)]
+    solos = [_solo_tokens(_engine(bundle, _cfg(max_decode_len=12)), f)
+             for f in feats]
+    cdl = ContinuousDecodeLoop(eng, cfg)
+    cdl.admission = AdmissionController(cfg, eng)
+    in_flight_at_checkpoint = []
+    real_requeue = cdl._requeue_preempted
+
+    def requeue(st):
+        in_flight_at_checkpoint.append(len(cdl._inflight_chunks))
+        return real_requeue(st)
+
+    cdl._requeue_preempted = requeue
+    faults = []
+    if fetch == "fails":
+        cdl.supervisor = Supervisor(cfg)
+        real_dry, real_all = cdl._ensure_on_dry_pool, cdl._deliver_all
+        on_dry_pool = []
+
+        def dry(*args):
+            on_dry_pool.append(True)
+            try:
+                return real_dry(*args)
+            finally:
+                on_dry_pool.pop()
+
+        def deliver_all():
+            if on_dry_pool and not faults:
+                faults.append(len(cdl._inflight_chunks))
+                raise RuntimeError("device fault at the dry pool's fetch")
+            real_all()
+
+        cdl._ensure_on_dry_pool, cdl._deliver_all = dry, deliver_all
+
+    async def body():
+        return await asyncio.wait_for(asyncio.gather(
+            *[_consume(cdl.submit_stream(dict(f))) for f in feats]), 60)
+
+    try:
+        assert asyncio.run(body()) == solos
+        if fetch == "fails":
+            assert len(faults) == 1 and faults[0] >= 1
+            assert cdl.supervisor.restarts == 1 and not cdl.supervisor.failed
+            assert not cdl._pending_admissions and not cdl._pending_wave
+        else:  # (a recovery's own checkpoints drop the chunk that failed)
+            assert in_flight_at_checkpoint, "the pool never ran dry"
+            assert set(in_flight_at_checkpoint) == {0}
+        assert _wait(lambda: eng.kv_pool.used_blocks == 0)
+    finally:
+        cdl.stop()
+
+
 def test_stream_blocks_trim():
     pool = BlockPool(16)
     sb = StreamBlocks(pool, 8)
@@ -325,14 +408,17 @@ def test_mid_chunk_fatal_checkpoint_resume(paged):
 # 5. the one order of an iteration
 
 
-def test_admission_rides_behind_the_live_chunk():
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+def test_admission_goes_ahead_of_the_live_chunk(paged):
     """With a stream live, the iteration that admits a newcomer
-    dispatches the live chunk FIRST: the ring span before the wave's
+    dispatches the wave's start FIRST: the ring span after the wave's
     ``loop/wave_dispatch`` on the loop's thread is that iteration's
-    ``loop/chunk_dispatch``, so the prefill queues behind the chunk on
-    the device."""
+    ``loop/chunk_dispatch`` (none goes before it in the iteration), so
+    the chunk queues behind the start on the device.  A paged chunk's
+    host half (``loop/chunk_prep``: the live rows' growth pass) stays
+    AHEAD of the wave, which may take from the pool."""
     bundle = tiny_gpt_bundle()
-    cfg = _cfg(max_decode_len=160)
+    cfg = _cfg(max_decode_len=160, **_layout(paged))
     eng = _engine(bundle, cfg)
     cdl = ContinuousDecodeLoop(eng, cfg)
     rng = np.random.default_rng(8)
@@ -357,11 +443,16 @@ def test_admission_rides_behind_the_live_chunk():
     waves = [i for i, s in enumerate(loop) if s.name == "loop/wave_dispatch"]
     assert len(waves) == 2  # A alone on an idle loop, then B beside A
     assert len({s.tid for s in loop}) == 1
-    before_b = loop[waves[1] - 1]
-    assert before_b.name == "loop/chunk_dispatch", [
-        s.name for s in loop[max(0, waves[1] - 4): waves[1] + 2]]
-    # ... and B's fetch and insert follow before the next chunk goes out.
-    after_b = [s.name for s in loop[waves[1] + 1:]]
+    around_b = [s.name for s in loop[max(0, waves[1] - 4): waves[1] + 2]]
+    assert loop[waves[1] + 1].name == "loop/chunk_dispatch", around_b
+    # ... and none before it since the iteration's pop: the old order.
+    pop = max(i for i in range(waves[1]) if loop[i].name == "loop/queue_pop")
+    before_b = [s.name for s in loop[pop: waves[1]]]
+    assert "loop/chunk_dispatch" not in before_b, around_b
+    assert before_b.count("loop/chunk_prep") == int(paged), around_b
+    # B's fetch and insert follow before the NEXT chunk goes out.
+    after_b = [s.name for s in loop[waves[1] + 2:]]
+    assert after_b.index("loop/wave_fetch") < after_b.index("loop/insert")
     assert after_b.index("loop/insert") < after_b.index("loop/chunk_dispatch")
 
 
@@ -369,15 +460,27 @@ def _layout(paged: bool) -> dict:
     return dict(paged_kv=True, kv_block_size=8) if paged else {}
 
 
-def _b_meets_a_live(cdl):
+def _b_meets_a_live(cdl, in_flight: int = 0):
     """Hold the loop's thread at the top of the first iteration that
-    finds a stream live until a newcomer sits in the queue, so that
-    iteration dispatches the live stream's chunk, pops the newcomer and
-    admits it as a wave BESIDE that chunk — whatever the threads' pace."""
+    finds a stream live with ``in_flight`` chunks in flight until a
+    newcomer sits in the queue, so that iteration pops the newcomer and
+    admits it as a wave BESIDE the live stream — whatever the threads'
+    pace.  ``in_flight`` 0 is the first iteration after the live
+    stream's own insert (nothing is ahead of the wave); the chain depth
+    is a loop in its stride.  With chunks to be met in flight the
+    opportunistic ``_deliver_ready`` is off, so a chunk that has landed
+    by the iteration's top still counts.  ``_a_then_b`` submits the
+    newcomer once the loop is held (``cdl.held``), not before: one that
+    arrived earlier would be admitted by an earlier iteration."""
     real, met = cdl._expire_queued, []
+    cdl.held = threading.Event()
+    if in_flight:
+        cdl._deliver_ready = lambda: None
 
     def gate():
-        if cdl.active and not met:
+        if (cdl.active and len(cdl._inflight_chunks) >= in_flight
+                and not met):
+            cdl.held.set()
             met.append(_wait(lambda: cdl.queue.qsize() > 0))
         real()
 
@@ -385,38 +488,197 @@ def _b_meets_a_live(cdl):
     return met
 
 
+async def _until_held(cdl):
+    """Return once ``_b_meets_a_live``'s gate holds the loop (at once
+    where the loop has no gate)."""
+    held = getattr(cdl, "held", None)
+    while held is not None and not held.is_set():
+        await asyncio.sleep(0.005)
+
+
 def _a_then_b(cdl, fa, fb):
-    """A's first chunk, then B submitted and read to its end, then the
-    rest of A: (A's tokens, B's tokens)."""
+    """A's first chunk, then B submitted (where ``_b_meets_a_live``
+    gates the loop, once it is held) and read to its end, then the rest
+    of A: (A's tokens, B's tokens)."""
     async def body():
         gen_a = cdl.submit_stream(dict(fa))
         first = np.asarray(await gen_a.__anext__()).tolist()
+        await _until_held(cdl)
         out_b = await _consume(cdl.submit_stream(dict(fb)))
         return first + await _consume(gen_a), out_b
 
     return asyncio.run(body())
 
 
+def _counters(cdl) -> tuple:
+    """(waves beside chunks, chunks ahead of them, waves ahead of their
+    iteration's chunk), as the loop counts them."""
+    return (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave,
+            cdl.waves_ahead_of_chunk)
+
+
+def _spy_order(cdl) -> tuple:
+    """Record what an iteration does to the device's queue and when the
+    host reads it: ``("wave", rows popped, chunks in flight)`` at the
+    wave's dispatch, ``("chunk", entry)`` after a chunk's,
+    ``("deliver", entry)`` at each delivery of one entry and
+    ``("fetch", [entries in flight])`` where the loop turns to the
+    wave's fetch — an in-flight entry by ``tag``, its serial number in
+    the order the spies met it.  Returns (events, tag)."""
+    events, met = [], []
+
+    def tag(entry) -> int:
+        for i, e in enumerate(met):
+            if e is entry:
+                return i
+        met.append(entry)
+        return len(met) - 1
+
+    admit, chunk = cdl._admit_dispatch, cdl._dispatch_chunk_inner
+    oldest, complete = cdl._deliver_oldest, cdl._admit_complete
+
+    def spy_admit(wave):
+        events.append(("wave", len(wave), len(cdl._inflight_chunks)))
+        return admit(wave)
+
+    def spy_chunk(*args):
+        n = len(cdl._inflight_chunks)
+        chunk(*args)
+        if len(cdl._inflight_chunks) > n:
+            events.append(("chunk", tag(cdl._inflight_chunks[-1])))
+
+    def spy_oldest():
+        if cdl._inflight_chunks:
+            events.append(("deliver", tag(cdl._inflight_chunks[0])))
+        oldest()
+
+    def spy_complete(started):
+        events.append(("fetch", [tag(e) for e in cdl._inflight_chunks]))
+        complete(started)
+
+    cdl._admit_dispatch, cdl._dispatch_chunk_inner = spy_admit, spy_chunk
+    cdl._deliver_oldest, cdl._admit_complete = spy_oldest, spy_complete
+    return events, tag
+
+
+@pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
+@pytest.mark.parametrize("depth", [1, 2])
+def test_wave_goes_out_ahead_of_its_chunk_which_stays_in_flight(depth, paged):
+    """B meets A in its stride (``depth`` chunks in flight): B's start
+    is dispatched, THEN the iteration's chunk; the chunks that were in
+    flight are delivered, oldest first, and at the wave's fetch the one
+    entry left in flight is the very chunk dispatched behind the start —
+    it is delivered only after B's insert, and every stream reads what it
+    reads alone."""
+    bundle = tiny_gpt_bundle()
+    cfg = _cfg(max_decode_len=64, stream_pipeline=depth, **_layout(paged))
+    eng = _engine(bundle, cfg)
+    rng = np.random.default_rng(8)
+    fa, fb = _feats(rng, 9), _feats(rng, 6, max_tokens=8)
+    alone = [_solo_tokens(eng, f) for f in (fa, fb)]
+    cdl = ContinuousDecodeLoop(eng, cfg)
+    assert cdl.chain_depth == depth
+    met = _b_meets_a_live(cdl, in_flight=depth)
+    events, tag = _spy_order(cdl)
+    inserted = []
+    real_insert = cdl._emit_and_insert
+
+    def insert(started, fetched):
+        inserted.append([tag(e) for e in cdl._inflight_chunks])
+        real_insert(started, fetched)
+        events.append(("inserted",))
+
+    cdl._emit_and_insert = insert
+    try:
+        out_a, out_b = _a_then_b(cdl, fa, fb)
+    finally:
+        cdl.stop()
+    assert met == [True] and [out_a, out_b] == alone
+    waves = [i for i, e in enumerate(events) if e[0] == "wave"]
+    assert len(waves) == 2 and events[waves[0]] == ("wave", 1, 0)
+    # A's wave met an idle loop: straight to its fetch, nothing in flight.
+    assert events[waves[0] + 1] == ("fetch", [])
+    assert events[waves[1]] == ("wave", 1, depth)
+    kind, behind = events[waves[1] + 1]
+    assert kind == "chunk"
+    ahead = [e[1] for e in events[:waves[1]] if e[0] == "chunk"][-depth:]
+    assert events[waves[1] + 2: waves[1] + 2 + depth] == [
+        ("deliver", entry) for entry in ahead]
+    kind, left = events[waves[1] + 2 + depth]
+    assert kind == "fetch" and left == [behind]
+    # The insert met that chunk in flight, and it was read after it.
+    assert inserted == [[], [behind]]
+    done = events.index(("inserted",), waves[1])
+    assert events.index(("deliver", behind)) > done
+    assert _counters(cdl) == (1, depth, 1)
+
+
+@pytest.mark.parametrize("case", ["idle", "prompt_windows"])
+def test_waves_that_dispatch_as_before(case):
+    """A wave that meets an idle loop dispatches no chunk and goes
+    straight to its fetch; a wave whose one stream is a prompt of several
+    windows beside a live stream dispatches nothing itself, hands back no
+    admissions and never turns to a wave's fetch — the iteration's chunk
+    goes out, and the windows ride behind it.  None of the three counters
+    moves, and no token does."""
+    bundle = tiny_gpt_bundle()
+    cfg = _cfg(max_decode_len=32, paged_kv=True, kv_block_size=8,
+               prefill_chunk=8, prefill_max_prompt=48)
+    eng = _engine(bundle, cfg)
+    rng = np.random.default_rng(17)
+    fa, fb = _feats(rng, 7), _feats(rng, 6 if case == "idle" else 20,
+                                    max_tokens=8)
+    alone = [_solo_tokens(eng, f) for f in (fa, fb)]
+    cdl = ContinuousDecodeLoop(eng, cfg)
+    events, _ = _spy_order(cdl)
+    try:
+        if case == "idle":
+            outs = [_run(cdl, [f])[0] for f in (fa, fb)]
+        else:
+            met = _b_meets_a_live(cdl, in_flight=cdl.chain_depth)
+            outs = list(_a_then_b(cdl, fa, fb))
+            assert met == [True]
+    finally:
+        cdl.stop()
+    assert outs == alone
+    waves = [i for i, e in enumerate(events) if e[0] == "wave"]
+    assert len(waves) == 2
+    assert events[waves[0]: waves[0] + 2] == [("wave", 1, 0), ("fetch", [])]
+    if case == "idle":
+        assert events[waves[1]: waves[1] + 2] == [
+            ("wave", 1, 0), ("fetch", [])]
+    else:
+        assert events[waves[1]] == ("wave", 1, cdl.chain_depth)
+        assert events[waves[1] + 1][0] == "chunk"
+        assert not [e for e in events[waves[1]:] if e[0] == "fetch"]
+        assert cdl.prefill_chunk_dispatches >= 3  # 20 tokens, windows of 8
+    assert _counters(cdl) == (0, 0, 0)
+
+
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
 def test_chunks_in_flight_are_delivered_ahead_of_the_wave(paged):
-    """With A live, B's admission shows ``loop/deliver`` spans — one a
-    chunk in flight — BETWEEN B's ``loop/wave_dispatch`` and its
-    ``loop/wave_fetch`` on the loop's thread, and every token of A from
-    a chunk dispatched ahead of B's start is emitted before B's first
-    token: nothing that landed waits behind the wave's fetch."""
+    """With A live, B's admission shows, BETWEEN B's
+    ``loop/wave_dispatch`` and its ``loop/wave_fetch`` on the loop's
+    thread, the iteration's ``loop/chunk_dispatch`` and then
+    ``loop/deliver`` spans — one a chunk that was in flight before B's
+    start, none for the chunk behind it — and every token of A from a
+    chunk dispatched ahead of B's start is emitted before B's first
+    token, none of the chunk behind it: nothing that landed waits behind
+    the wave's fetch, and the wave's fetch waits for nothing behind it."""
     bundle = tiny_gpt_bundle()
     cfg = _cfg(max_decode_len=160, **_layout(paged))
     cdl = ContinuousDecodeLoop(_engine(bundle, cfg), cfg)
     rng = np.random.default_rng(8)
     fa, fb = _feats(rng, 9), _feats(rng, 6, max_tokens=8)
-    met = _b_meets_a_live(cdl)
-    seen = []  # per wave: (chunks in flight, chunks dispatched so far)
+    met = _b_meets_a_live(cdl, in_flight=cdl.chain_depth)
+    seen = []  # per wave: (chunks ahead, in flight, dispatched so far)
     emitted = []  # (prompt length, tokens) in the loop's emit order
     real_ahead, real_emit = cdl._deliver_ahead_of_wave, cdl._emit_tokens
 
-    def ahead():
-        seen.append((len(cdl._inflight_chunks), cdl.chunk_dispatches))
-        real_ahead()
+    def ahead(n_ahead):
+        seen.append(
+            (n_ahead, len(cdl._inflight_chunks), cdl.chunk_dispatches))
+        real_ahead(n_ahead)
 
     def emit(st, chunk):
         emitted.append((int(st.feats["length"]), int(np.asarray(chunk).size)))
@@ -431,33 +693,38 @@ def test_chunks_in_flight_are_delivered_ahead_of_the_wave(paged):
         tracing.configure(False)
         cdl.stop()
     assert met == [True] and len(out_a) == 160 and len(out_b) == 8
-    assert len(seen) == 2 and seen[0][0] == 0  # A's wave met an idle loop
-    n_flight, n_dispatched = seen[1]
-    assert 1 <= n_flight <= cdl.chain_depth + 1
+    assert len(seen) == 2 and seen[0][:2] == (0, 0)  # A's: an idle loop
+    n_ahead, n_flight, n_dispatched = seen[1]
+    assert n_ahead == cdl.chain_depth and n_flight == n_ahead + 1
     loop = [s.name for s in sorted(
         (s for s in spans if s.name.startswith("loop/")), key=lambda s: s.t0)]
     waves = [i for i, n in enumerate(loop) if n == "loop/wave_dispatch"]
     fetches = [i for i, n in enumerate(loop) if n == "loop/wave_fetch"]
     assert len(waves) == 2 and len(fetches) == 2
-    # A's own wave: nothing in flight, today's path, no delivery in it.
+    # A's own wave: nothing in flight, no chunk, no delivery in it.
     assert loop[waves[0] + 1: fetches[0]] == ["loop/wave_complete"]
     assert loop[waves[1] + 1: fetches[1]] == (
-        ["loop/deliver"] * n_flight + ["loop/wave_complete"])
+        ["loop/chunk_dispatch"] + ["loop/deliver"] * n_ahead
+        + ["loop/wave_complete"])
     # B's first token: A has by then been handed its start's chunk and
-    # every chunk that was dispatched before B's start.
+    # every chunk that was dispatched before B's start — and not the one
+    # dispatched behind it.
     first_b = emitted.index((6, 4))
     a_before = sum(n for length, n in emitted[:first_b] if length == 9)
-    assert a_before == 4 * (1 + n_dispatched)
-    assert (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave) == (1, n_flight)
+    assert a_before == 4 * n_dispatched  # the start's + (n_dispatched - 1)
+    assert _counters(cdl) == (1, n_ahead, 1)
 
 
 @pytest.mark.parametrize("paged", [False, True], ids=["slab", "paged"])
 def test_reading_ahead_of_the_wave_changes_no_token(paged):
     """A and B admitted side by side read token for token what each reads
-    admitted alone on an idle loop — the device's queue is the same,
-    only the host's order of reads differs — and an A that ENDS in a
-    chunk delivered ahead of B's wave has left its slot (and, paged, its
-    blocks) before B's insert takes one."""
+    admitted alone on an idle loop — every chunk holds the rows it held,
+    only the start's place in the device's queue and the host's order of
+    reads differ — and an A that ENDS in a chunk delivered ahead of B's
+    wave has left its slot (and, paged, its blocks) before B's insert
+    takes one.  A's last chunk covers its budget, so B's iteration
+    dispatches no chunk behind the start: a wave beside a chunk in flight
+    that is ahead of none."""
     bundle = tiny_gpt_bundle()
     cfg = _cfg(max_decode_len=24, max_streams=2, **_layout(paged))
     eng = _engine(bundle, cfg)
@@ -467,11 +734,11 @@ def test_reading_ahead_of_the_wave_changes_no_token(paged):
     idle = ContinuousDecodeLoop(eng, cfg)
     try:
         alone = [_run(idle, [f])[0] for f in (fa, fb)]
-        assert (idle.waves_behind_chunks, idle.chunks_ahead_of_wave) == (0, 0)
+        assert _counters(idle) == (0, 0, 0)
     finally:
         idle.stop()
     cdl = ContinuousDecodeLoop(eng, cfg)
-    met = _b_meets_a_live(cdl)
+    met = _b_meets_a_live(cdl, in_flight=1)
     at_insert = []
     real_insert = cdl._emit_and_insert
 
@@ -487,7 +754,7 @@ def test_reading_ahead_of_the_wave_changes_no_token(paged):
         assert met == [True]
         assert [out_a, out_b] == alone
         assert len(out_a) == 8 and len(out_b) == 12
-        assert (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave) == (1, 1)
+        assert _counters(cdl) == (1, 1, 0)
         # A's insert found the loop empty; so did B's: A ended in the
         # chunk delivered ahead of B's wave and gave everything back.
         assert at_insert == [(0, 2, 0), (0, 2, 0)]
@@ -497,12 +764,16 @@ def test_reading_ahead_of_the_wave_changes_no_token(paged):
         cdl.stop()
 
 
+@pytest.mark.parametrize("first_live", [False, True], ids=["stride", "first"])
 @pytest.mark.parametrize("depth", [1, 2])
-def test_ahead_of_wave_counters(depth):
-    """``stream_chunks_ahead_of_wave_total`` grows by the chunks in
-    flight when a wave is dispatched beside live streams — at most the
-    chain depth + 1 — and ``stream_waves_behind_chunks_total`` by one;
-    a wave on an idle loop moves neither."""
+def test_ahead_of_wave_counters(depth, first_live):
+    """A wave dispatched beside live streams in their stride moves
+    ``stream_waves_behind_chunks_total`` by one,
+    ``stream_chunks_ahead_of_wave_total`` by the chunks in flight before
+    it — the chain depth, and not the chunk dispatched behind it — and
+    ``stream_waves_ahead_of_chunk_total`` by one; one that meets the live
+    stream's first iteration has nothing ahead and moves the third alone;
+    a wave on an idle loop moves none."""
     bundle = tiny_gpt_bundle()
     cfg = _cfg(max_decode_len=64, stream_pipeline=depth, paged_kv=True,
                kv_block_size=8)
@@ -512,32 +783,36 @@ def test_ahead_of_wave_counters(depth):
 
     def read():
         if not metrics.HAVE_PROM:
-            return cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave
-        return (int(metrics.WAVES_BEHIND_CHUNKS.labels("gpt2")._value.get()),
-                int(metrics.CHUNKS_AHEAD_OF_WAVE.labels("gpt2")._value.get()))
+            return _counters(cdl)
+        return tuple(
+            int(fam.labels("gpt2")._value.get())
+            for fam in (metrics.WAVES_BEHIND_CHUNKS,
+                        metrics.CHUNKS_AHEAD_OF_WAVE,
+                        metrics.WAVES_AHEAD_OF_CHUNK))
 
     flight = []
     real_ahead = cdl._deliver_ahead_of_wave
 
-    def ahead():
-        flight.append(len(cdl._inflight_chunks))
-        real_ahead()
+    def ahead(n_ahead):
+        flight.append(n_ahead)
+        real_ahead(n_ahead)
 
     cdl._deliver_ahead_of_wave = ahead
     try:
         before = read()
         _run(cdl, [fb])  # a wave on an idle loop
         assert read() == before and flight == [0]
-        met = _b_meets_a_live(cdl)
+        met = _b_meets_a_live(cdl, in_flight=0 if first_live else depth)
         _a_then_b(cdl, fa, fb)
         after = read()
     finally:
         cdl.stop()
     # ... A's own wave met an idle loop too; B's met A's chunks.
-    assert met == [True] and flight[:2] == [0, 0] and len(flight) == 3
-    assert 1 <= flight[2] <= depth + 1
-    assert (after[0] - before[0], after[1] - before[1]) == (1, flight[2])
-    assert (cdl.waves_behind_chunks, cdl.chunks_ahead_of_wave) == (1, flight[2])
+    n_ahead = 0 if first_live else depth
+    want = (int(n_ahead > 0), n_ahead, 1)
+    assert met == [True] and flight == [0, 0, n_ahead]
+    assert tuple(a - b for a, b in zip(after, before)) == want
+    assert _counters(cdl) == want
 
 
 # ---------------------------------------------------------------------------
@@ -589,7 +864,8 @@ def test_status_surfaces_chain_depth_and_prep_counters():
     assert dec["chunk_tokens"] == 4
     assert {"chunk_dispatches", "tokens_emitted", "prep_staged", "prep_hits",
             "prep_misses", "dispatch_counts"} <= set(dec)
-    assert dec["ahead_of_wave"] == {"waves": 0, "chunks": 0}
+    assert dec["ahead_of_wave"] == {
+        "waves": 0, "chunks": 0, "waves_ahead_of_chunk": 0}
 
 
 def test_chain_depth_gauge_set_on_tune():

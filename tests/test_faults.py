@@ -501,14 +501,17 @@ def test_injected_oob_checkpoints_and_resumes():
 @pytest.mark.parametrize("supervised", [True, False],
                          ids=["supervised", "unsupervised"])
 def test_fetch_fault_ahead_of_a_wave_settles_its_streams_once(supervised):
-    """The loop delivers the chunks in flight before it blocks on a
-    wave's fetch.  A fetch that fails among THOSE deliveries reaches the
+    """The loop delivers the chunks that were in flight before a wave's
+    start before it blocks on the wave's fetch (the newcomer meets the
+    live stream in its stride: one chunk ahead of its start, the
+    iteration's own behind it).  A fetch that fails among THOSE
+    deliveries reaches the
     loop's handler with the wave still pending: supervised, the wave's
     stream and the live one are each checkpointed and re-queued once and
     finish token-identically after one rebuild; unsupervised, each
     consumer gets the error once.  Either way the loop admits the next
     request."""
-    from test_decode_dispatch import _b_meets_a_live, _wait
+    from test_decode_dispatch import _b_meets_a_live, _until_held, _wait
 
     cfg = _paged_cfg(max_decode_len=24)
     bundle = tiny_gpt_bundle()
@@ -517,7 +520,7 @@ def test_fetch_fault_ahead_of_a_wave_settles_its_streams_once(supervised):
                   ("the live stream's prompt", "a newcomer", "the next one"))
     solos = [_solo_tokens(eng, f).tolist() for f in (fa, fb, fc)]
     cdl = (_supervised_cdl if supervised else ContinuousDecodeLoop)(eng, cfg)
-    met = _b_meets_a_live(cdl)
+    met = _b_meets_a_live(cdl, in_flight=1)
     fired, settled = [], []
     real_oldest, real_requeue, real_finish = (
         cdl._deliver_oldest, cdl._checkpoint_requeue, cdl._finish)
@@ -556,6 +559,7 @@ def test_fetch_fault_ahead_of_a_wave_settles_its_streams_once(supervised):
     async def body():
         gen_a = cdl.submit_stream(dict(fa))
         first = np.asarray(await gen_a.__anext__()).tolist()
+        await _until_held(cdl)  # B arrives once the loop is held
         out_b, rest_a = await asyncio.gather(
             outcome(cdl.submit_stream(dict(fb))), outcome(gen_a))
         out_c = await outcome(cdl.submit_stream(dict(fc)))

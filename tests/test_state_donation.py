@@ -108,17 +108,23 @@ GOLDEN = {
 
 def _staggered(cdl, feats):
     """Two streams, two more once chunks are in flight, the last two
-    into a full loop (they admit as slots free, chunks still flying)."""
+    into a full loop (they admit as slots free, chunks still flying).
+    The opportunistic ``_deliver_ready`` is off: every entry is fetched
+    as late as the loop's order allows, whatever the CPU's pace — the
+    hard case for a ``(toks, done)`` read after its state was consumed.
+    Returns the outputs and, a wave, (chunks in flight ahead of its
+    start, chunks in flight at its insert)."""
     depth_at_start, depth_at_insert = [], []
+    cdl._deliver_ready = lambda: None
     orig, orig_ahead = cdl._emit_and_insert, cdl._deliver_ahead_of_wave
 
     def spy(started, fetched):
         depth_at_insert.append(len(cdl._inflight_chunks))
         return orig(started, fetched)
 
-    def spy_ahead():
-        depth_at_start.append(len(cdl._inflight_chunks))
-        return orig_ahead()
+    def spy_ahead(n_ahead):
+        depth_at_start.append(n_ahead)
+        return orig_ahead(n_ahead)
 
     cdl._emit_and_insert, cdl._deliver_ahead_of_wave = spy, spy_ahead
 
@@ -165,11 +171,12 @@ def test_pipelined_paged_loop_is_token_identical(family):
     assert [(sum(o), o[-4:]) for o in outs] == GOLDEN[family]
     # The case the old "NOT donated" comment feared: a wave's start went
     # out while earlier chunks' (toks, done) were still to be fetched,
-    # after later dispatches had consumed the states they came from.
-    # (They are fetched before the wave's own fetch now, so the insert
-    # that donates the state finds none of them left.)
+    # after later dispatches had consumed the states they came from —
+    # and the insert that donates the state meets the chunk dispatched
+    # BEHIND the start still in flight: its (toks, done) are fetched
+    # after the insert consumed the state they came out with.
     assert len(depth_at_insert) >= 3 and max(depth_at_start) >= 1
-    assert max(depth_at_insert) == 0
+    assert max(depth_at_insert) >= 1
     assert cdl.chunk_dispatches >= 6
     assert not is_consumed(cdl._state)
 
@@ -567,6 +574,84 @@ def test_failure_after_consumption_rebuilds_and_holds_the_swap(attr, nth):
         assert _retries() == retries0, "retried as if transient"
         assert cdl.supervisor.restarts == 1 and not cdl.supervisor.failed
         assert cdl.swap_outs == 0, "swapped out of pools that were gone"
+        assert not is_consumed(cdl._state)
+        for _ in range(100):
+            if eng.kv_pool.used_blocks == 0:
+                break
+            time.sleep(0.05)
+        assert eng.kv_pool.used_blocks == 0
+    finally:
+        cdl.stop()
+
+
+@pytest.mark.parametrize("half", ["host", "device"])
+@pytest.mark.parametrize("supervised", [True, False])
+def test_chunk_fault_in_a_wave_iteration_loses_no_stream(supervised, half):
+    """An iteration that holds a wave beside live work runs the chunk's
+    host half (the growth pass and the table), the wave's start, then
+    the chunk's device dispatch.  A fault in the host half finds the
+    wave's streams popped and reserved in ``_pending_wave``; one raised
+    by the device dispatch finds them in ``_pending_admissions``, started
+    and not yet fetched.  Supervised, ``_recover`` requeues them beside
+    the live stream and every stream reads what it reads alone, once;
+    unsupervised, the loop's handler ends each with the error — none
+    hangs, none is answered twice — and the next request is served from
+    a rebuilt state.  Either way the pool drains."""
+    bundle = tiny_llama_bundle()
+    cfg = _cfg(engine_restarts_max=2)
+    eng = _engine(bundle, cfg)
+    fa, fb = _prompts(2)
+    solos = [_solo(_engine(bundle, _cfg(paged_kv=False)), f) for f in (fa, fb)]
+    cdl = ContinuousDecodeLoop(eng, cfg)
+    if supervised:
+        cdl.supervisor = Supervisor(cfg)
+    from test_decode_dispatch import _b_meets_a_live
+
+    # B is popped by an iteration that finds A live, whatever the pace:
+    # the first chunk whose half runs after the gate opened is that
+    # iteration's, whether or not the loop has the wave on its books.
+    met = _b_meets_a_live(cdl)
+    attr = "_chunk_table" if half == "host" else "_dispatch_chunk_inner"
+    real_half = getattr(cdl, attr)
+    pending_at_fault = []
+
+    def faulty(*args):
+        if met and not pending_at_fault:
+            pending_at_fault.append((
+                [st.rid for st in cdl._pending_wave],
+                [st.rid for st, *_ in cdl._pending_admissions]))
+            raise RuntimeError("device fault at the chunk beside a wave")
+        return real_half(*args)
+
+    setattr(cdl, attr, faulty)
+    try:
+        async def drive():
+            gen_a = cdl.submit_stream(dict(fa))
+            got_a = np.asarray(await gen_a.__anext__()).tolist()
+            b = asyncio.ensure_future(_consume(cdl.submit_stream(dict(fb))))
+
+            async def rest_of_a():
+                return got_a + await _consume(gen_a)
+
+            return await asyncio.wait_for(asyncio.gather(
+                rest_of_a(), b, return_exceptions=True), 120)
+
+        got = asyncio.run(drive())
+        assert met == [True]
+        (in_wave, started), = pending_at_fault
+        assert (len(in_wave), len(started)) == (
+            (1, 0) if half == "host" else (0, 1))
+        assert not cdl._pending_admissions and not cdl._pending_wave
+        if supervised:
+            assert got == solos
+            assert cdl.supervisor.restarts == 1
+        else:
+            assert all(isinstance(g, RuntimeError) for g in got), got
+
+            async def after():
+                return await _consume(cdl.submit_stream(dict(fb)))
+
+            assert asyncio.run(after()) == solos[1]
         assert not is_consumed(cdl._state)
         for _ in range(100):
             if eng.kv_pool.used_blocks == 0:

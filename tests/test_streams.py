@@ -459,13 +459,15 @@ def test_chunk_dispatch_failure_does_not_orphan_wave():
     f_b = text_feats(bundle.tokenizer, "bb")
 
     # Raise ONLY when this iteration popped a wave: the exact
-    # interleaving the orphan bug needed.
+    # interleaving the orphan bug needed.  (The wave's start goes out
+    # ahead of the chunk, so by the chunk's dispatch its streams are the
+    # iteration's pending admissions.)
     orig_dc = ContinuousDecodeLoop._dispatch_chunk
 
-    def dc(self):
-        if self._pending_wave:
+    def dc(self, *args):
+        if self._pending_wave or self._pending_admissions:
             raise RuntimeError("injected dispatch failure")
-        return orig_dc(self)
+        return orig_dc(self, *args)
 
     cdl._dispatch_chunk = dc.__get__(cdl)
 
@@ -1213,12 +1215,15 @@ def test_loop_time_adds_up_and_names_a_slowed_iteration():
     assert snap["unnamed_s"] < 0.25 * snap["wall_s"]
     assert all(k.startswith("loop/") for k in phases), sorted(phases)
     assert {"loop/wave_dispatch", "loop/wave_fetch", "loop/insert",
-            "loop/chunk_dispatch", "loop/stage_prep", "loop/deliver",
-            "loop/housekeeping", "loop/queue_pop", "loop/idle"} <= set(phases)
+            "loop/chunk_prep", "loop/chunk_dispatch", "loop/stage_prep",
+            "loop/deliver", "loop/housekeeping", "loop/queue_pop",
+            "loop/idle"} <= set(phases)
     sites = {k for k in inside if k.startswith("dispatch:")}
     assert {"dispatch:prefill", "dispatch:chunk", "dispatch:fetch"} <= sites
     assert inside["dispatch:chunk"]["s"] <= phases["loop/chunk_dispatch"]["s"]
     assert inside["dispatch:chunk"]["n"] == phases["loop/chunk_dispatch"]["n"]
+    # A paged chunk's host half, once a chunk (no pool ran dry here).
+    assert phases["loop/chunk_prep"]["n"] == phases["loop/chunk_dispatch"]["n"]
     top = [r for r in snap["slowest"] if r["t"] >= t_warm][0]
     assert top["phase"] == "loop/stage_prep" and top["phase_s"] >= 0.25
     assert top["wall_s"] >= top["phase_s"] and top["live"] >= 1
